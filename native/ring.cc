@@ -70,6 +70,13 @@ size_t total_size(uint64_t capacity, uint64_t slot_stride) {
   return sizeof(Header) + capacity * sizeof(CellHeader) + capacity * slot_stride;
 }
 
+// nullptr with errno = err: the clean-up calls between a refusal and the
+// return would otherwise overwrite the one fact the caller can report
+void* fail(int err) {
+  errno = err;
+  return nullptr;
+}
+
 }  // namespace
 
 extern "C" {
@@ -78,27 +85,30 @@ extern "C" {
 // The ring is initialised in a temp file and atomically renamed over the
 // target, so re-creating a ring never truncates the inode that still-attached
 // workers have mapped (they keep the old ring; new attachers get the new one).
-// Returns an opaque handle or nullptr.
+// Returns an opaque handle, or nullptr with errno saying which call refused
+// (EFBIG: a file-size limit; ENOSPC/ENOMEM: the directory or the mapping).
 void* scr_create(const char* path, uint64_t capacity, uint64_t slot_size) {
-  if (capacity == 0 || (capacity & (capacity - 1)) != 0) return nullptr;
+  if (capacity == 0 || (capacity & (capacity - 1)) != 0) return fail(EINVAL);
   uint64_t stride = (slot_size + 63) & ~63ull;  // 64B-align slots
   size_t len = total_size(capacity, stride);
 
   char tmp[4096];
   int n = ::snprintf(tmp, sizeof(tmp), "%s.tmp.%d", path, ::getpid());
-  if (n < 0 || static_cast<size_t>(n) >= sizeof(tmp)) return nullptr;
+  if (n < 0 || static_cast<size_t>(n) >= sizeof(tmp)) return fail(ENAMETOOLONG);
   int fd = ::open(tmp, O_RDWR | O_CREAT | O_TRUNC, 0600);
   if (fd < 0) return nullptr;
   if (::ftruncate(fd, static_cast<off_t>(len)) != 0) {
+    int err = errno;
     ::close(fd);
     ::unlink(tmp);
-    return nullptr;
+    return fail(err);
   }
   void* mem = ::mmap(nullptr, len, PROT_READ | PROT_WRITE, MAP_SHARED, fd, 0);
+  int map_err = errno;
   ::close(fd);
   if (mem == MAP_FAILED) {
     ::unlink(tmp);
-    return nullptr;
+    return fail(map_err);
   }
 
   auto* h = static_cast<Header*>(mem);
@@ -117,10 +127,11 @@ void* scr_create(const char* path, uint64_t capacity, uint64_t slot_size) {
   }
   h->magic.store(kMagic, std::memory_order_release);
   if (::rename(tmp, path) != 0) {
+    int err = errno;
     ::munmap(mem, len);
     ::unlink(tmp);
     delete ring;
-    return nullptr;
+    return fail(err);
   }
   return ring;
 }

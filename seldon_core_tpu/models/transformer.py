@@ -140,7 +140,8 @@ class TransformerConfig:
     # serves either layout.
     kv_cache_dtype: str = "bf16"
     # Fuse each block's residual-add + ffn RMSNorm into one Pallas pass
-    # (ops/fused_norm.py; falls back to the identical XLA expression off-TPU).
+    # (ops/fused_norm.py: compiled on a TPU, interpreted elsewhere; within
+    # 1 bf16 ulp of the unfused graph, not bit-equal).
     fused_norm: bool = False
     mesh: Any = None
 
@@ -234,7 +235,7 @@ def gather_paged_view(cache, block_tables: jnp.ndarray, dtype):
     (k_all, v_all, pos_view) of [b, n_pages*page_size, kvh, hd] / [b, L].
 
     The ONE copy of the block-table read semantics: both the attention
-    fallback below and ops/paged_attention.py's ``paged_attention_ref``
+    read below and ops/paged_attention.py's ``paged_attention_ref``
     (the kernel's parity oracle) address the pool through this gather, so
     a change to the page addressing can never desynchronize them. int8
     pools (5-tuple) dequantize here — the gather moves bytes, never
@@ -351,7 +352,6 @@ class Attention(nn.Module):
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
 
-        use_paged_kernel = False
         if cache is not None and block_tables is not None:
             # Paged pool: write each token's K/V at the (page, offset) its
             # block table maps its position to; read by gathering the pages
@@ -377,16 +377,14 @@ class Attention(nn.Module):
                 pos_pool = pos_pool.at[entry, off].set(
                     positions.astype(pos_pool.dtype))
                 new_cache = (k_pool, v_pool, pos_pool)
-            from seldon_core_tpu.ops.paged_attention import paged_kernel_viable
-
-            use_paged_kernel = s == 1 and paged_kernel_viable()
-            if not use_paged_kernel:
-                # pure-gather fallback: reconstruct the logical view and fall
-                # through to the SAME masked einsum the dense layout uses —
-                # paged == dense bit-for-bit (masked positions contribute
-                # exact zeros).
-                k_all, v_all, pos_view = gather_paged_view(new_cache, bt, dt)
-                mask = pos_view[:, None, :] <= positions[:, :, None]
+            # The read on every backend, the TPU included: gather the
+            # logical view and fall through to the SAME masked einsum the
+            # dense layout uses — paged == dense bit-for-bit (masked
+            # positions contribute exact zeros). The Pallas page-streaming
+            # kernel (ops/paged_attention.py) does not lower for a TPU and
+            # is not reachable from here.
+            k_all, v_all, pos_view = gather_paged_view(new_cache, bt, dt)
+            mask = pos_view[:, None, :] <= positions[:, :, None]
         elif cache is not None and len(cache) == 5:
             # int8 cache: (k_q, k_scale, v_q, v_scale, pos). Quantize-on-write
             # (new K/V rows become int8 + per-head scales before the scatter),
@@ -469,14 +467,7 @@ class Attention(nn.Module):
             mask = positions[:, None, :] <= positions[:, :, None]  # [b, s, kv]
             new_cache = (k, v)
 
-        if use_paged_kernel:
-            # TPU decode fast path: one Pallas pass streams ONLY the pages
-            # each sequence's block table names (probe-gated; every other
-            # platform took the gather fallback above).
-            from seldon_core_tpu.ops.paged_attention import paged_attention
-
-            out = paged_attention(q, new_cache, bt, positions)
-        elif cache is None and cfg.attention_impl == "ring":
+        if cache is None and cfg.attention_impl == "ring":
             from seldon_core_tpu.ops.ring_attention import ring_attention
 
             # ring is GQA-aware: unrepeated KV rides the ring
@@ -586,8 +577,8 @@ class TransformerBlock(nn.Module):
         if cfg.fused_norm:
             # residual-add + RMSNorm in one HBM pass (ops/fused_norm.py):
             # collapses the per-layer norm chains the decode profile flags
-            # (~7.5 us each on [8, 2048] tensors — DECODE_NOTES.md). Off-TPU
-            # this lowers to the identical XLA expression.
+            # (~7.5 us each on [8, 2048] tensors — DECODE_NOTES.md). A TPU
+            # compiles the kernel or raises; other backends interpret it.
             from seldon_core_tpu.ops.fused_norm import fused_residual_rmsnorm
 
             x, ffn_in = fused_residual_rmsnorm(x, h, ffn_norm(), cfg.norm_eps)

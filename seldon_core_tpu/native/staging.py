@@ -12,6 +12,7 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
+import shutil
 import struct
 import subprocess
 import threading
@@ -29,13 +30,36 @@ _lib = None
 _lib_lock = threading.Lock()
 
 
-def build_native(force: bool = False) -> str:
-    """Build the native library if needed; returns the .so path."""
-    if os.path.exists(_SO_PATH) and not force:
-        src_mtime = os.path.getmtime(os.path.join(_NATIVE_DIR, "ring.cc"))
-        if os.path.getmtime(_SO_PATH) >= src_mtime:
+class ToolchainMissing(RuntimeError):
+    """No ``make`` / C++ compiler here and nothing built: the native plane
+    cannot exist on this machine (tests skip on it; a failed compile is a
+    different thing and raises ``NativeBuildError``)."""
+
+
+class NativeBuildError(RuntimeError):
+    """``make`` ran and failed; the message carries its stderr."""
+
+
+def build_native() -> str:
+    """Bring ``native/build`` up to date and return the .so path.
+
+    ``make`` is the arbiter of staleness: it compares every target with
+    every prerequisite the Makefile lists (headers included), so a stale
+    binary left in a checkout is rebuilt and a fresh one costs a few
+    milliseconds. On a machine with no toolchain, what is already built is
+    used as it is."""
+    cxx = os.environ.get("CXX", "g++").split()[0]  # the Makefile's default
+    if shutil.which("make") is None or shutil.which(cxx) is None:
+        if os.path.exists(_SO_PATH):
             return _SO_PATH
-    subprocess.run(["make", "-C", _NATIVE_DIR], check=True, capture_output=True)
+        raise ToolchainMissing(
+            "no make/C++ compiler on PATH and native/build is empty")
+    proc = subprocess.run(["make", "-C", _NATIVE_DIR], capture_output=True,
+                          text=True)
+    if proc.returncode != 0:
+        raise NativeBuildError(
+            f"make -C {_NATIVE_DIR} failed ({proc.returncode}):\n"
+            f"{proc.stderr.strip()}")
     return _SO_PATH
 
 
@@ -45,7 +69,7 @@ def _load():
         if _lib is not None:
             return _lib
         path = build_native()
-        lib = ctypes.CDLL(path)
+        lib = ctypes.CDLL(path, use_errno=True)
         lib.scr_create.restype = ctypes.c_void_p
         lib.scr_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint64]
         lib.scr_attach.restype = ctypes.c_void_p
@@ -89,7 +113,7 @@ def native_available() -> bool:
     try:
         _load()
         return True
-    except Exception as e:  # toolchain missing
+    except ToolchainMissing as e:
         logger.warning("native staging unavailable: %s", e)
         return False
 
@@ -115,10 +139,17 @@ class SharedRing:
         self.path = path
         if create:
             self._h = self._lib.scr_create(path.encode(), capacity, slot_size)
+            if not self._h:
+                # the ring file is sparse, so this is a limit on the file or
+                # the mapping (EFBIG: RLIMIT_FSIZE), not memory in use
+                err = ctypes.get_errno()
+                raise OSError(
+                    err, f"could not create ring ({capacity} slots x "
+                    f"{slot_size} bytes): {os.strerror(err)}", path)
         else:
             self._h = self._lib.scr_attach(path.encode())
-        if not self._h:
-            raise RuntimeError(f"could not {'create' if create else 'attach'} ring at {path}")
+            if not self._h:
+                raise RuntimeError(f"could not attach ring at {path}")
         self.capacity = int(self._lib.scr_capacity(self._h))
         self.slot_size = int(self._lib.scr_slot_size(self._h))
         self._popbuf = ctypes.create_string_buffer(self.slot_size)

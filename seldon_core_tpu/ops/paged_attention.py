@@ -1,9 +1,20 @@
-"""Pallas TPU kernel: paged-attention decode read.
+"""Pallas kernel: paged-attention decode read — NOT on the serving path.
 
-Why this kernel exists: the paged KV cache (models/transformer.py
+Serving reads the paged pool with the XLA gather in models/transformer.py
+on every backend, the v5e included. This kernel does not lower for a TPU
+(jax 0.9.0 / libtpu 0.0.34, confirmed on the chip in PR 21; PERF.md has the
+compiler's words): the ``(1, page_size)`` position-pool block fails the
+"last two dimensions divisible by 8 and 128" rule, and past that
+``einsum("hd,phd->hp")`` becomes a ``tpu.dot_dimension_numbers`` with no
+lhs non-contracting dimension. Until ROADMAP A3 rewrites or deletes it, it
+runs only under ``interpret=True`` (its parity test, tests/test_paged_kv.py);
+``interpret=False`` hands it to the compiler and raises what the compiler
+says.
+
+Why it was written: the paged KV cache (models/transformer.py
 ``init_paged_kv_caches`` + runtime/batcher.py block tables) bills HBM for
-pages actually written instead of ``max_len`` per slot — but the pure-XLA
-fallback read still GATHERS the full logical view ([slots, n_pages*page_size])
+pages actually written instead of ``max_len`` per slot — but the XLA
+gather read still GATHERS the full logical view ([slots, n_pages*page_size])
 back into a contiguous buffer before the attention einsum, i.e. it buys
 capacity, not bandwidth. This kernel does what the gather cannot: for each
 (sequence, page) grid step it streams exactly ONE page of K/V from HBM into
@@ -17,13 +28,8 @@ Numerics: masking uses the pooled position rows exactly like the dense path
 the online-softmax accumulation runs in f32. The kernel is NOT bit-identical
 to the XLA einsum (different reduction order); the bit-exactness contract of
 paged-vs-dense serving (tests/test_paged_kv.py) is carried by the gather
-fallback, which IS the dense einsum on gathered bytes. Kernel parity tests
+read, which IS the dense einsum on gathered bytes. Kernel parity tests
 run interpret-mode under the ``pallas`` marker with tolerances.
-
-Follows the ops/fused_norm.py probe/fallback pattern: on TPU a one-time
-compile probe gates the compiled kernel; every other platform — or a TPU
-whose probe fails — keeps the gather fallback inside models/transformer.py,
-so the paged layout is safe to enable everywhere.
 """
 
 from __future__ import annotations
@@ -33,10 +39,10 @@ import functools
 
 def paged_attention_ref(q, cache, block_tables, positions):
     """Pure-XLA reference: gather the logical view through the block table
-    (models/transformer.py ``gather_paged_view`` — the SAME gather the
-    serving fallback uses, so the two can't drift) and run the dense
-    masked-softmax einsum chain (identical op order to the in-line
-    fallback's shared einsum). q: [b, 1, h, hd]; cache: the paged 3-tuple
+    (models/transformer.py ``gather_paged_view`` — the SAME gather
+    serving uses, so the two can't drift) and run the dense
+    masked-softmax einsum chain (identical op order to serving's shared
+    einsum). q: [b, 1, h, hd]; cache: the paged 3-tuple
     (bf16) or 5-tuple (int8) pool; block_tables: [b, n_pages];
     positions: [b, 1]. Returns [b, 1, h, hd] in q.dtype."""
     import jax
@@ -118,68 +124,17 @@ def _kernel(quantized: bool, n_pages: int, scale: float,
         o_ref[0] = (acc_ref[...] / l_ref[:, 0][:, None]).astype(o_ref.dtype)
 
 
-_TPU_COMPILE_STATUS: str | None = None
-
-
-def probe_tpu_compile(force: bool = False) -> str:
-    """Attempt one tiny paged_attention Pallas compile+run on the TPU
-    backend and cache the outcome for this process ("ok" or "error: ...").
-    Backend Pallas support has flapped across rounds (ops/pallas_int8.py),
-    so the serving path re-verifies on first TPU use and keeps the gather
-    fallback when the kernel can't compile — the paged layout never
-    surfaces a backend compile error."""
-    global _TPU_COMPILE_STATUS
-    if _TPU_COMPILE_STATUS is not None and not force:
-        return _TPU_COMPILE_STATUS
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    # shardlint: allow-mesh-rederivation(Pallas backend probe: asks which platform compiles, no mesh/device-world is derived)
-    if jax.devices()[0].platform != "tpu":
-        _TPU_COMPILE_STATUS = "error: no TPU backend in this process"
-        return _TPU_COMPILE_STATUS
-    try:
-        from seldon_core_tpu.models.transformer import PAD_POS
-
-        ps, hd = 8, 128
-        pools = (jnp.zeros((3, ps, 1, hd), jnp.bfloat16),
-                 jnp.zeros((3, ps, 1, hd), jnp.bfloat16),
-                 jnp.full((3, ps), PAD_POS, jnp.int32))
-        q = jnp.zeros((1, 1, 1, hd), jnp.bfloat16)
-        bt = jnp.full((1, 1), 2, jnp.int32)
-        out = paged_attention(q, pools, bt, jnp.zeros((1, 1), jnp.int32),
-                              interpret=False, _probe=True)
-        # graftlint: allow-host-sync-in-hot-path(one-time startup probe: the sync is the point — prove the kernel compiles AND runs before enabling the compiled path)
-        np.asarray(out)
-        _TPU_COMPILE_STATUS = "ok"
-    except Exception as e:  # noqa: BLE001 — any compile/runtime failure gates the path
-        _TPU_COMPILE_STATUS = f"error: {type(e).__name__}: {str(e)[:300]}"
-    return _TPU_COMPILE_STATUS
-
-
-def paged_kernel_viable() -> bool:
-    """Trace-time gate the transformer's paged decode read uses: compiled
-    Pallas path only on a TPU whose probe passed; everywhere else the
-    gather fallback (which is the bit-exactness carrier) stays."""
-    import jax
-
-    # shardlint: allow-mesh-rederivation(Pallas backend probe: asks which platform compiles, no mesh/device-world is derived)
-    return (jax.devices()[0].platform == "tpu"
-            and probe_tpu_compile() == "ok")
-
-
-def paged_attention(q, cache, block_tables, positions,
-                    interpret: bool | None = None, _probe: bool = False):
+def paged_attention(q, cache, block_tables, positions, *, interpret: bool):
     """q: [b, 1, h, hd]; cache: paged pool tuple (bf16 3-tuple or int8
     5-tuple, [pages, page_size, kvh, hd] buffers); block_tables: [b,
     n_pages] int32; positions: [b, 1] int32 query positions. Returns
     [b, 1, h, hd] in q.dtype.
 
-    On TPU the read is one Pallas pass per (sequence, page) streaming only
-    block-table-named pages; with ``interpret=True`` the same kernel runs
-    under the Pallas interpreter (CI parity tests); any other platform
-    takes the gather reference."""
+    One Pallas pass per (sequence, page) streaming only block-table-named
+    pages. ``interpret=True`` runs it under the Pallas interpreter (the
+    parity test); ``interpret=False`` compiles it, which no TPU toolchain
+    installed here accepts (module docstring) — the error is the
+    compiler's, nothing stands in."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -190,15 +145,6 @@ def paged_attention(q, cache, block_tables, positions,
     quantized = len(cache) == 5
     ps = cache[0].shape[1]
     n_pages = int(block_tables.shape[1])
-
-    # shardlint: allow-mesh-rederivation(Pallas backend probe: asks which platform compiles, no mesh/device-world is derived)
-    platform = jax.devices()[0].platform
-    if interpret is None:
-        interpret = False
-    if not interpret and (
-        platform != "tpu" or (not _probe and probe_tpu_compile() != "ok")
-    ):
-        return paged_attention_ref(q, cache, block_tables, positions)
 
     bt = jnp.asarray(block_tables, jnp.int32)
     qpos = jnp.asarray(positions, jnp.int32)[:, 0]  # [b]
@@ -262,6 +208,4 @@ def paged_attention(q, cache, block_tables, positions,
 __all__ = [
     "paged_attention",
     "paged_attention_ref",
-    "paged_kernel_viable",
-    "probe_tpu_compile",
 ]

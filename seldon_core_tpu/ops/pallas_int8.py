@@ -9,22 +9,22 @@ the quantization-kernel pattern from the TPU Pallas playbook. Its role: an
 explicit-control experiment (``int8_dense`` / ``int8_matmul``) for
 validating/benching the XLA fusion path against a known-good explicit
 schedule. The public serving entry point (``ops.quantize.quantized_matmul``)
-uses the fused XLA expression — the round-4 TPU decision bench measured
-this kernel at 0.55-0.79x XLA on the decode GEMM shapes, so swapping it
-into the model families stays gated on a benchmark win that hasn't
-materialised.
+uses the fused XLA expression — the round-4 decision bench (earlier
+harness) measured this kernel at 0.55-0.79x XLA on the decode GEMM shapes,
+so swapping it into the model families stays gated on a benchmark win that
+hasn't materialised.
 
-``int8_matmul`` pads all dims to MXU-friendly tiles, runs the kernel on
-TPU, and falls back to the equivalent XLA expression elsewhere (tests run
-the kernel itself via the Pallas interpreter, so the body is exercised on
-CPU).
+``int8_matmul`` pads all dims to MXU-friendly tiles. Mosaic compiles the
+kernel on a TPU and a compile error there is raised (v5e, PR 21: at
+[8, 4096] x [4096, 4096] it does not compile — the ``(128,)`` scale block
+fails Mosaic's layout check against XLA's ``T(1024)`` layout of
+``f32[4096]``; PERF.md has the message); every other backend runs the same
+body under the Pallas interpreter (``ops.pallas_interpret_default``).
 """
 
 from __future__ import annotations
 
 import functools
-
-import numpy as np
 
 
 def _kernel(x_ref, q_ref, s_ref, o_ref):
@@ -45,74 +45,26 @@ def _tile_sizes(m: int, n: int):
     return tm, 128
 
 
-_TPU_COMPILE_STATUS: str | None = None
-
-
-def probe_tpu_compile(force: bool = False) -> str:
-    """Attempt one tiny int8_matmul Pallas compile+run on the TPU backend
-    and cache the outcome for this process ("ok" or "error: ...").
-
-    Backend support has flapped across rounds (rejected everything in round
-    3, accepted in round 4 — benchmarks/MFU_NOTES.md measurement log);
-    rather than letting either state go stale, ``int8_matmul`` re-verifies
-    it here on first TPU use each process and falls back to the XLA-fused
-    dequant expression when the kernel can't compile, so the explicit
-    kernel entry points (int8_matmul / int8_dense) never surface a backend
-    compile error. The *serving* path (ops.quantize.quantized_matmul) uses
-    the XLA expression unconditionally — a measured decision, not a
-    compile fallback (round-4 bench: the kernel is 0.55-0.79x XLA on the
-    decode GEMM shapes)."""
-    global _TPU_COMPILE_STATUS
-    if _TPU_COMPILE_STATUS is not None and not force:
-        return _TPU_COMPILE_STATUS
-    import jax
-    import jax.numpy as jnp
-
-    # shardlint: allow-mesh-rederivation(Pallas backend probe: asks which platform compiles, no mesh/device-world is derived)
-    if jax.devices()[0].platform != "tpu":
-        _TPU_COMPILE_STATUS = "error: no TPU backend in this process"
-        return _TPU_COMPILE_STATUS
-    try:
-        x = jnp.zeros((8, 128), jnp.bfloat16)
-        q = jnp.zeros((128, 128), jnp.int8)
-        s = jnp.ones((128,), jnp.float32)
-        # graftlint: allow-host-sync-in-hot-path(one-time startup probe: the sync is the point — prove the kernel compiles AND runs before enabling the compiled path)
-        np.asarray(int8_matmul(x, q, s, interpret=False, _probe=True))
-        _TPU_COMPILE_STATUS = "ok"
-    except Exception as e:  # noqa: BLE001 — any compile/runtime failure gates the path
-        _TPU_COMPILE_STATUS = f"error: {type(e).__name__}: {str(e)[:300]}"
-    return _TPU_COMPILE_STATUS
-
-
-def int8_matmul(x, q, scale, out_dtype=None, interpret: bool | None = None,
-                _probe: bool = False):
+def int8_matmul(x, q, scale, out_dtype=None, interpret: bool | None = None):
     """x [M, K] float; q [K, N] int8; scale [N] f32 -> [M, N].
 
-    Equivalent to ``x @ (q * scale)`` with f32 accumulation. On TPU the
-    weight tiles stream into VMEM as int8; elsewhere (or with
-    ``interpret=True``) the same kernel runs under the Pallas interpreter.
+    Equivalent to ``x @ (q * scale)`` with f32 accumulation; the weight
+    tiles stream into VMEM as int8. ``interpret=None`` compiles the kernel
+    on a TPU and interprets it on any other backend; pass a bool to force
+    either.
     """
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    from seldon_core_tpu.ops import pallas_interpret_default
+
     m, k = x.shape
     kq, n = q.shape
     assert k == kq and scale.shape == (n,), (x.shape, q.shape, scale.shape)
     out_dtype = out_dtype or x.dtype
-
-    # shardlint: allow-mesh-rederivation(Pallas backend probe: asks which platform compiles, no mesh/device-world is derived)
-    platform = jax.devices()[0].platform
     if interpret is None:
-        interpret = False
-    if not interpret and (
-        platform != "tpu" or (not _probe and probe_tpu_compile() != "ok")
-    ):
-        # the Pallas interpreter is a test/debug vehicle only (orders of
-        # magnitude slower); every non-TPU production platform — and a TPU
-        # backend whose compile probe failed — takes the equivalent XLA
-        # expression
-        return (x.astype(jnp.float32) @ (q.astype(jnp.float32) * scale[None, :])).astype(out_dtype)
+        interpret = pallas_interpret_default()
 
     tm, tn = _tile_sizes(m, n)
     pm = -(-m // tm) * tm

@@ -115,13 +115,12 @@ def quantized_matmul(x, qt: QuantizedTensor, out_dtype=None):
     """Public int8-weight matmul for user components.
 
     Serving path is the XLA-fused dequant expression on every backend: the
-    round-4 decision bench on the real chip (tpu_sweep_results.jsonl
-    int8-gemm-*, 2026-07-30) measured the explicit Pallas kernel at
-    0.55-0.79x the fused XLA expression on the decode GEMM shapes now that
-    the backend accepts Pallas at all — XLA's fusion of convert+multiply
-    into the consuming matmul beats the hand-tiled schedule here. The
-    kernel stays available as ``ops.pallas_int8.int8_dense`` (probe-gated)
-    for explicit experiments."""
+    round-4 decision bench (earlier harness; record removed in PR 21)
+    measured the explicit Pallas kernel at 0.55-0.79x the fused XLA
+    expression on the decode GEMM shapes — XLA's fusion of
+    convert+multiply into the consuming matmul beats the hand-tiled
+    schedule here. The kernel stays available as
+    ``ops.pallas_int8.int8_dense`` for explicit experiments."""
     out_dtype = out_dtype or qt.orig_dtype
     # dequant in the activation dtype (the compute dtype): XLA fuses the
     # convert+multiply into the matmul, weights stay int8 in HBM

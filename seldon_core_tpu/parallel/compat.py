@@ -1,16 +1,13 @@
-"""JAX version compatibility for ``shard_map``.
+"""The single import site for ``shard_map`` and ``axis_size``.
 
-The API moved twice under us: it grew up in
-``jax.experimental.shard_map.shard_map`` (keyword ``check_rep``), was
-promoted to ``jax.shard_map`` in newer releases, and the promotion renamed
-the replication-check keyword to ``check_vma``. Every caller in this tree
-imports from HERE so the resolution happens exactly once:
+Every caller in this tree imports from HERE:
 
     from seldon_core_tpu.parallel.compat import shard_map
 
-The shim keeps the OLD keyword name (``check_rep``) as its public surface
-— the tree predates the rename — and translates when running on a JAX
-that wants ``check_vma``.
+so the next JAX rename is absorbed in one file. The tree predates
+``jax.shard_map``'s ``check_vma`` keyword and keeps the old name
+(``check_rep``) as its public surface; this module translates. Written for
+the one installed JAX (0.9.0) — no branch for a version that is not here.
 
 Routing through this module is ENFORCED: graftlint's ``compat-drift``
 rule flags any direct ``jax.shard_map`` / ``jax.experimental.shard_map``
@@ -19,34 +16,16 @@ rule flags any direct ``jax.shard_map`` / ``jax.experimental.shard_map``
 
 from __future__ import annotations
 
-try:  # newer JAX: promoted API, check_vma keyword
-    from jax import shard_map as _shard_map_impl
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # older JAX: experimental API, check_rep keyword
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _CHECK_KW = "check_rep"
+import jax
 
 __all__ = ["shard_map", "axis_size"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = False):
-    """``jax.shard_map`` resolved across JAX versions (see module docs)."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KW: check_rep})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
 
 def axis_size(axis_name) -> int:
-    """Size of a mapped mesh axis from inside a shard_map/pmap body.
-
-    ``jax.lax.axis_size`` only exists on newer JAX; older versions get the
-    same static int from ``psum(1, axis)`` (a constant fold — the reduction
-    of 1 over the axis is the axis size, resolved at trace time).
-    """
-    import jax
-
-    impl = getattr(jax.lax, "axis_size", None)
-    if impl is not None:
-        return impl(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Size of a mapped mesh axis from inside a shard_map/pmap body."""
+    return jax.lax.axis_size(axis_name)

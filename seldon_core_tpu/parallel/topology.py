@@ -31,11 +31,14 @@ the linter reads them with ``ast`` without importing anything.
 
 from __future__ import annotations
 
+import logging
 import threading
 from dataclasses import dataclass, field, replace
 from typing import Dict, Mapping, Optional, Sequence, Tuple
 
 from seldon_core_tpu.parallel import mesh as _mesh
+
+logger = logging.getLogger(__name__)
 
 # ----------------------------------------------------------------------
 # declared registries (read statically by tools/shardlint — keep literal)
@@ -117,6 +120,19 @@ class Topology:
     process_count: int = 1
     slice_map: Optional[Mapping[int, tuple]] = field(default=None)
 
+    # -- device identity -------------------------------------------------
+
+    @property
+    def platform(self) -> str:
+        """What JAX says the devices are ("tpu", "cpu", ...): from
+        outside, a server on the CPU and one on the chip otherwise look
+        the same."""
+        return self.default_device.platform
+
+    @property
+    def device_kind(self) -> str:
+        return self.default_device.device_kind
+
     # -- derivation ----------------------------------------------------
 
     @classmethod
@@ -128,7 +144,7 @@ class Topology:
 
         devices = tuple(jax.devices())
         sm = physical_slice_map(devices)
-        return cls(
+        topo = cls(
             devices=devices,
             local_devices=tuple(jax.local_devices()),
             process_index=jax.process_index(),
@@ -136,6 +152,8 @@ class Topology:
             slice_map=None if sm is None else {
                 k: tuple(v) for k, v in sm.items()},
         )
+        logger.info("detected %r", topo)
+        return topo
 
     def sub_topology(self, devices: Sequence) -> "Topology":
         """A view of this topology restricted to ``devices`` (a disagg
@@ -226,7 +244,9 @@ class Topology:
         return dm
 
     def __repr__(self) -> str:  # keep logs short: devices can be many
-        return (f"Topology(devices={self.device_count}, "
+        return (f"Topology(platform={self.platform}, "
+                f"device_kind={self.device_kind!r}, "
+                f"devices={self.device_count}, "
                 f"process={self.process_index}/{self.process_count}, "
                 f"slices={self.num_slices})")
 
@@ -247,6 +267,25 @@ def get_topology() -> Topology:
         if _PROCESS_TOPOLOGY is None:
             _PROCESS_TOPOLOGY = Topology.detect()
         return _PROCESS_TOPOLOGY
+
+
+def log_device_memory() -> None:
+    """One INFO line per local device of the process topology: what its
+    allocator holds now and its high-water mark. Serving entry points call
+    this on the way out, so a run's footprint is in the server's own log
+    (chip_smoke.py reads it there). Silent when this process never
+    detected a topology — no device work happened."""
+    with _TOPO_LOCK:
+        topo = _PROCESS_TOPOLOGY
+    if topo is None:
+        return
+    for d in topo.local_devices:
+        stats = d.memory_stats() or {}  # None on backends that keep none (CPU)
+        logger.info(
+            "device %d (%s) memory: bytes_in_use=%s peak_bytes_in_use=%s "
+            "bytes_limit=%s", d.id, d.device_kind,
+            stats.get("bytes_in_use"), stats.get("peak_bytes_in_use"),
+            stats.get("bytes_limit"))
 
 
 def set_topology(topo: Optional[Topology]) -> Optional[Topology]:

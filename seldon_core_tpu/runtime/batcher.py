@@ -967,6 +967,14 @@ class ContinuousBatcher:
         self._cache_nbytes = sum(
             int(getattr(leaf, "nbytes", 0)) for leaf in jax.tree.leaves(self._caches)
         )
+        if self.paged:
+            # the read is the XLA gather on every backend (models/
+            # transformer.py; the Pallas page-streaming kernel does not
+            # lower for a TPU) — said here so a server's log names it
+            logger.info(
+                "paged KV pool: %d pages x %d tokens, %s, %.2f GB; "
+                "decode read: gather", self.pool_pages, self.page_size,
+                server.kv_cache_dtype, self._cache_nbytes / 1e9)
 
         if self.paged:
             # Paged pool: no insert — chunked prefill writes straight into

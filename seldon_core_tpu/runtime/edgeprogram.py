@@ -14,8 +14,6 @@ from __future__ import annotations
 
 import json
 import os
-import shutil
-import subprocess
 from typing import Any, Dict, List, Optional
 
 from seldon_core_tpu.contracts.graph import (
@@ -106,22 +104,18 @@ def numpy_stream_parity_ok() -> bool:
 
 
 def build_edge_binaries() -> bool:
-    """Build the native edge/loadgens if needed; False when no toolchain."""
-    binaries = (EDGE_BINARY, LOADGEN_BINARY, LOADGEN_BINARY + "_grpc")
-    if all(os.path.exists(b) for b in binaries):
-        src = max(
-            os.path.getmtime(os.path.join(_NATIVE_DIR, f))
-            for f in ("edge.cc", "ring.cc", "loadgen_http.cc", "loadgen_grpc.cc")
-        )
-        if min(os.path.getmtime(b) for b in binaries) >= src:
-            return True
-    if shutil.which("make") is None:
-        return False
+    """Bring the native edge/loadgens up to date (one ``make`` covers every
+    target — native/staging.py ``build_native``); False only when this
+    machine has no toolchain and nothing built. A compile that fails
+    raises with make's stderr."""
+    from seldon_core_tpu.native.staging import ToolchainMissing, build_native
+
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR], check=True, capture_output=True)
-        return True
-    except subprocess.CalledProcessError:
+        build_native()
+    except ToolchainMissing:
         return False
+    return all(os.path.exists(b) for b in
+               (EDGE_BINARY, LOADGEN_BINARY, LOADGEN_BINARY + "_grpc"))
 
 
 def compile_edge_program(

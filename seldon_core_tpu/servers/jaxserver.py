@@ -72,7 +72,9 @@ class JAXServer(SeldonComponent):
         # (XLA hoists the convert; see benchmarks/DECODE_NOTES.md). The knob
         # stays for HBM-residency-bound configs.
         self.param_dtype = param_dtype
-        self.batch_buckets = tuple(batch_buckets) if batch_buckets else DEFAULT_BUCKETS
+        # None = the checkpoint's config.json "batch_buckets", else the
+        # codec default ladder (resolved at load())
+        self.batch_buckets = tuple(batch_buckets) if batch_buckets else None
         self.ready = False
         self._apply = None
         self._params = None
@@ -91,6 +93,9 @@ class JAXServer(SeldonComponent):
             raise SeldonError(f"JAXServer checkpoint missing config.json at {path}", status_code=500)
         with open(cfg_path) as f:
             self._config = json.load(f)
+        if self.batch_buckets is None:
+            self.batch_buckets = tuple(
+                self._config.get("batch_buckets") or DEFAULT_BUCKETS)
 
         from seldon_core_tpu.models import get_model
 
@@ -98,10 +103,12 @@ class JAXServer(SeldonComponent):
         module = get_model(name, **self._config.get("kwargs", {}))
         self._module = module
 
-        if self.mesh is None and self.tensor_parallel > 1:
-            from seldon_core_tpu.parallel.topology import get_topology
+        from seldon_core_tpu.parallel.topology import get_topology
 
-            self.topology = self.topology or get_topology()
+        # adopted unconditionally, like LLMServer: detection is what puts
+        # the platform and device_kind this server runs on into its log
+        self.topology = self.topology or get_topology()
+        if self.mesh is None and self.tensor_parallel > 1:
             n = self.topology.device_count
             if n % self.tensor_parallel:
                 raise SeldonError(
@@ -169,6 +176,10 @@ class JAXServer(SeldonComponent):
             )
         else:
             self._apply = jax.jit(apply_fn)
+            # a msgpack restore yields host (numpy) arrays; left there, jit
+            # uploads every weight again on every call (seen on the chip in
+            # PR 21: 39 MB resident behind a 102 MB ResNet-50)
+            params = jax.device_put(params)
         self._params = params
         self.ready = True
         logger.info("JAXServer loaded model %s from %s", name, path)
@@ -257,6 +268,7 @@ def export_checkpoint(
     apply_kwargs: Optional[Dict[str, Any]] = None,
     class_names: Optional[Sequence[str]] = None,
     use_orbax: bool = True,
+    batch_buckets: Optional[Sequence[int]] = None,
 ) -> str:
     """Write a JAXServer-servable checkpoint directory."""
     os.makedirs(out_dir, exist_ok=True)
@@ -271,6 +283,8 @@ def export_checkpoint(
         cfg["apply_kwargs"] = apply_kwargs
     if class_names:
         cfg["class_names"] = list(class_names)
+    if batch_buckets:
+        cfg["batch_buckets"] = [int(b) for b in batch_buckets]
     with open(os.path.join(out_dir, "config.json"), "w") as f:
         json.dump(cfg, f)
     if use_orbax:

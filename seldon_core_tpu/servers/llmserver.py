@@ -689,6 +689,10 @@ class LLMServer(SeldonComponent):
 
             logical = logical_axis_tree(self._module, jax.ShapeDtypeStruct((1, 8), jnp.int32))
             params = shard_params(params, self.mesh, logical)
+        else:
+            # a msgpack restore yields host (numpy) arrays; left there, jit
+            # uploads every weight again on every call (servers/jaxserver.py)
+            params = jax.device_put(params)
         self._params = params
 
         # Draft model for spec_mode="draft": loaded alongside the target,
@@ -1347,8 +1351,8 @@ class LLMServer(SeldonComponent):
         Returns ``(pools, last_tok, next_pos, keys, tokens[slots, k])`` with
         the same donation shape as the dense step (pools, next_pos, keys
         donated; last_tok not, for the same stacked-output aliasing reason).
-        Token parity with the dense step is bit-exact on the gather
-        fallback (tests/test_paged_kv.py); the compiled-form contract is
+        Token parity with the dense step is bit-exact — the pool is read
+        with the XLA gather (tests/test_paged_kv.py); the compiled-form contract is
         pinned as llm.paged_decode_step_s4 in tools/hlolint."""
         key = ("pagedstep", slots, n_pages, k, lora)
         fn = self._decode_cache.get(key)

@@ -13,6 +13,7 @@ JSON spec), the role of the reference's JVM engine bootstrap
 from __future__ import annotations
 
 import argparse
+import contextlib
 import importlib
 import json
 import logging
@@ -47,6 +48,28 @@ def setup_logging() -> None:
         level=getattr(logging, level, logging.INFO),
         format="%(asctime)s %(levelname)s %(name)s: %(message)s",
     )
+
+
+def _start_serving() -> None:
+    """First thing in every serving command: logging, then the compile
+    cache — before anything can compile."""
+    from seldon_core_tpu.utils import configure_compile_cache
+
+    setup_logging()
+    configure_compile_cache()
+
+
+@contextlib.contextmanager
+def _device_footprint_on_exit():
+    """Wraps the serve call of the commands chip_smoke.py drives
+    (microservice, edge): on the way out — while what was served is still
+    alive — each device's memory goes into the log."""
+    from seldon_core_tpu.parallel.topology import log_device_memory
+
+    try:
+        yield
+    finally:
+        log_device_memory()
 
 
 def import_interface(name: str):
@@ -107,7 +130,7 @@ def build_component(interface_name: str, persistence: bool = False):
 
 
 def run_microservice(args: argparse.Namespace) -> None:
-    setup_logging()
+    _start_serving()
     _bootstrap_multihost()
     component, _ = build_component(args.interface_name, persistence=args.persistence)
     port = args.port or int(os.environ.get("PREDICTIVE_UNIT_SERVICE_PORT", "5000"))
@@ -118,13 +141,15 @@ def run_microservice(args: argparse.Namespace) -> None:
     if api == "REST":
         from seldon_core_tpu.transport.rest import make_component_app, serve
 
-        serve(make_component_app(component, unit_id=unit_id, annotations=annotations),
-              host=args.host, port=port)
+        with _device_footprint_on_exit():
+            serve(make_component_app(component, unit_id=unit_id, annotations=annotations),
+                  host=args.host, port=port)
     elif api == "GRPC":
         from seldon_core_tpu.transport.grpc_server import serve_component
 
-        serve_component(component, host=args.host, port=port, unit_id=unit_id,
-                        annotations=annotations)
+        with _device_footprint_on_exit():
+            serve_component(component, host=args.host, port=port, unit_id=unit_id,
+                            annotations=annotations)
     else:
         raise SystemExit(f"Unknown API type {api} (use REST or GRPC)")
 
@@ -139,7 +164,7 @@ def _bootstrap_multihost() -> None:
 
 
 def run_engine(args: argparse.Namespace) -> None:
-    setup_logging()
+    _start_serving()
     _bootstrap_multihost()
     from seldon_core_tpu.metrics.registry import MetricsRegistry
     from seldon_core_tpu.runtime.engine import GraphEngine
@@ -200,7 +225,7 @@ def run_edge(args: argparse.Namespace) -> None:
     import subprocess
     import tempfile
 
-    setup_logging()
+    _start_serving()
     from seldon_core_tpu.runtime.edgeprogram import (
         EDGE_BINARY,
         build_edge_binaries,
@@ -251,6 +276,7 @@ def run_edge(args: argparse.Namespace) -> None:
         ModelExecutor,
         cleanup_rings,
         default_ring_dir,
+        ring_geometry,
     )
 
     engine = GraphEngine(spec, annotations=load_annotations())
@@ -290,7 +316,9 @@ def run_edge(args: argparse.Namespace) -> None:
     # drain up to 256 frames per FFI crossing: under a 512-stream gRPC load
     # one cycle then feeds the micro-batcher a full compile bucket instead
     # of four 64-frame nibbles (pop_many is one C call either way)
+    capacity, slot_size = ring_geometry(executor.models if executor else ())
     server = IPCEngineServer(engine, base, n_workers=n_workers,
+                             capacity=capacity, slot_size=slot_size,
                              model_executor=executor, batch=256)
     edge_argv_tail = []
     if grpc_port:
@@ -323,7 +351,10 @@ def run_edge(args: argparse.Namespace) -> None:
             await serve_task
 
     try:
-        asyncio.run(run())
+        with _device_footprint_on_exit():
+            asyncio.run(run())
+    except KeyboardInterrupt:
+        logger.info("interrupted; stopping the edge frontends")
     finally:
         for e in edges:
             if e.poll() is None:

@@ -74,6 +74,34 @@ KIND_PROTO_PREDICT = 3
 KIND_PROTO_FEEDBACK = 4
 
 
+# what one ring's mapped file may span once its slots outgrow the default
+# (the file is sparse, but RLIMIT_FSIZE and quotas count its length: a 1 GiB
+# ring was refused with EFBIG on a machine whose limit was not ours to set)
+RING_FILE_BYTES = 1 << 28
+
+
+def ring_geometry(models) -> tuple:
+    """``(capacity, slot_size)`` for rings that can carry every frame these
+    device models can be sent: one request of a model's largest batch
+    bucket, packed as the f64 the edge ships. The 1 MiB default slot cannot
+    hold ONE 224x224x3 image (1.2 MB) — the edge answers "tensor larger
+    than ring slot" — so the slot grows to that frame (64 B aligned, no
+    more) and the capacity shrinks to the power of two that keeps the ring's
+    file within ``RING_FILE_BYTES``, never below 2. Small-tensor models keep
+    the default (1024 x 1 MiB)."""
+    need = 0
+    for m in models:
+        shape = (getattr(m, "_config", None) or {}).get("input_shape")
+        if shape:
+            rows = max(getattr(m, "batch_buckets", None) or (1,))
+            need = max(need, 8 * rows * int(np.prod(shape)) + 4096)
+    if need <= 1 << 20:
+        return 1024, 1 << 20
+    slot = (need + 63) & ~63
+    fit = RING_FILE_BYTES // slot
+    return (1 << (fit.bit_length() - 1) if fit >= 2 else 2), slot
+
+
 class ModelExecutor:
     """Executes kind-2 device-model frames for the native edge.
 
@@ -114,7 +142,10 @@ class ModelExecutor:
         """Compile every (bucket, feature-shape) pair up front. Without this
         a load burst walks the bucket ladder one compile at a time while
         requests queue behind each compile (measured: a 10s load window
-        collapsed to ~94 rps from compile storms)."""
+        collapsed to ~94 rps from compile storms). A bucket that cannot
+        compile or run (e.g. one too large for the device's memory) raises
+        here, at start-up: a server that came up without it would compile
+        — and fail — under traffic. Size ``batch_buckets`` to the device."""
         for i, component in enumerate(self.models):
             shape = None
             cfg = getattr(component, "_config", None)
@@ -126,11 +157,8 @@ class ModelExecutor:
             for b in sorted(set(getattr(component, "batch_buckets", ()) or (1,))):
                 if b > self.max_rows[i]:
                     continue
-                try:
-                    component.predict(np.zeros((b, *shape), dtype), [], meta={})
-                except Exception:
-                    logger.exception("warmup failed for model %d bucket %d", i, b)
-                    break
+                logger.info("warming model %d bucket %d", i, b)
+                component.predict(np.zeros((b, *shape), dtype), [], meta={})
 
     # ---- frame codecs -------------------------------------------------
     @staticmethod

@@ -1,8 +1,9 @@
 """Fused residual+RMSNorm Pallas kernel: interpret-mode parity with the
 reference XLA expression (and the model's unfused path), padding behaviour,
 jit-ability, and the TransformerBlock fused_norm flag. Runs the kernel body
-under the Pallas interpreter on CPU (ops/pallas_int8.py pattern); the
-compiled path is probe-gated on real TPUs."""
+under the Pallas interpreter on CPU; on a TPU the same entry point compiles
+the kernel or raises (tests/test_kernel_lowering.py checks that lowering
+from here)."""
 
 import numpy as np
 import pytest
@@ -14,7 +15,6 @@ from seldon_core_tpu.models import get_model
 from seldon_core_tpu.models.transformer import rms_norm
 from seldon_core_tpu.ops.fused_norm import (
     fused_residual_rmsnorm,
-    probe_tpu_compile,
     residual_rmsnorm_ref,
 )
 
@@ -77,27 +77,32 @@ def test_kernel_is_jittable():
     np.testing.assert_allclose(_f32(o), _f32(o_ref), rtol=1e-5, atol=1e-5)
 
 
-def test_cpu_fallback_is_reference_expression():
-    """Without interpret=True on a non-TPU backend, the entry point must
-    return the XLA reference (never attempt a TPU Pallas compile)."""
-    assert probe_tpu_compile().startswith("error: no TPU")
+def test_off_tpu_default_runs_the_kernel_not_a_reference():
+    """With no ``interpret`` argument a non-TPU backend interprets the SAME
+    kernel — no XLA reference stands in. The padded (2, 16) case makes the
+    two distinguishable: the kernel divides a padded-lane sum by d, the
+    reference takes a mean."""
     x = jnp.asarray(np.random.default_rng(3).standard_normal((2, 16)), jnp.float32)
     w = jnp.ones((16,), jnp.float32)
     y, o = fused_residual_rmsnorm(x, x, w, 1e-5)
+    yi, oi = fused_residual_rmsnorm(x, x, w, 1e-5, interpret=True)
+    np.testing.assert_array_equal(_f32(o), _f32(oi))
     y_ref, o_ref = residual_rmsnorm_ref(x, x, w, 1e-5)
-    np.testing.assert_array_equal(_f32(o), _f32(o_ref))
+    np.testing.assert_allclose(_f32(o), _f32(o_ref), rtol=1e-5, atol=1e-5)
 
 
 def test_transformer_fused_norm_flag_matches_unfused():
-    """Same params, fused_norm on vs off: identical logits (on CPU the flag
-    lowers to the identical XLA expression, so this is exact)."""
+    """Same params, fused_norm on vs off: the same logits to f32 rounding
+    (the flag runs the kernel — interpreted here — whose reduction order
+    differs from the unfused mean)."""
     full = get_model("llama-tiny")
     fused = get_model("llama-tiny", fused_norm=True)
     tokens = jnp.asarray(np.random.default_rng(4).integers(0, 255, (2, 16)), jnp.int32)
     variables = full.init(jax.random.PRNGKey(0), tokens)
     ref, _ = full.apply(variables, tokens)
     out, _ = fused.apply(variables, tokens)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
+                               rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.slow  # tier-1 870s budget: redundant coverage — runs in CI's unfiltered unit step

@@ -1,0 +1,115 @@
+"""Which Pallas kernels the compiled serving path can reach on a TPU, and
+that each of them lowers for one — checked from the CPU with
+``jax.export(platforms=["tpu"])`` at the 7B serving shapes, so a kernel that
+Mosaic's front end refuses is caught on every push instead of on the first
+chip run. (What only the chip can say — that Mosaic then compiles and runs
+the kernel, and how close it lands to the reference — is in PERF.md.)
+
+Also the regression for the old probe gates: the implementation choice is a
+host fact, identical inside and outside a ``jax.jit`` trace, and a compile
+error on a TPU raises instead of serving an XLA reference.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax import export
+
+from seldon_core_tpu import ops
+from seldon_core_tpu.models import get_model
+from seldon_core_tpu.models.transformer import init_paged_kv_caches
+from seldon_core_tpu.ops.fused_norm import fused_residual_rmsnorm
+from seldon_core_tpu.ops.paged_attention import paged_attention
+from seldon_core_tpu.ops.pallas_int8 import int8_matmul
+
+S = jax.ShapeDtypeStruct
+MOSAIC_CALL = "tpu_custom_call"  # how a lowered Pallas TPU kernel appears
+
+
+def tpu_mlir(fn, *specs) -> str:
+    return export.export(jax.jit(fn), platforms=["tpu"])(*specs).mlir_module()
+
+
+@pytest.mark.parametrize("rows,dim", [(8, 4096), (8, 2048), (256, 4096)])
+def test_fused_norm_lowers_for_tpu(rows, dim):
+    """decode batch at 7B / 0.7B width, and a prefill chunk's rows"""
+    text = tpu_mlir(
+        lambda x, h, w: fused_residual_rmsnorm(x, h, w, 1e-5, interpret=False),
+        S((rows, dim), jnp.bfloat16), S((rows, dim), jnp.bfloat16),
+        S((dim,), jnp.float32))
+    assert MOSAIC_CALL in text
+
+
+def _paged_decode_mlir(**model_kwargs) -> str:
+    """One paged decode step of the tiny transformer, lowered for a TPU."""
+    model = get_model("llama-tiny", dtype="bfloat16", **model_kwargs)
+    cfg = model.cfg
+    tokens = jnp.zeros((2, 1), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    pools = jax.eval_shape(lambda: init_paged_kv_caches(cfg, 6, 8))
+
+    def step(params, pools, tokens, positions, block_tables):
+        return model.apply(params, tokens, positions=positions, caches=pools,
+                           block_tables=block_tables)
+
+    return tpu_mlir(step, params, pools, S((2, 1), jnp.int32),
+                    S((2, 1), jnp.int32), S((2, 2), jnp.int32))
+
+
+def test_paged_decode_reaches_no_pallas_kernel_by_default():
+    """The paged read is the XLA gather on a TPU too: the page-streaming
+    kernel is unreachable from the compiled path (it does not lower —
+    below), and fused_norm is off by default."""
+    assert MOSAIC_CALL not in _paged_decode_mlir()
+
+
+def test_fused_norm_flag_puts_the_kernel_on_the_tpu_path(monkeypatch):
+    """flag on, in a process whose backend is a TPU: the step carries the
+    Mosaic kernel — there is no reference to fall to"""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert MOSAIC_CALL in _paged_decode_mlir(fused_norm=True)
+
+
+def test_paged_attention_kernel_does_not_lower_for_tpu():
+    """The finding ROADMAP A3 starts from, kept executable: at the 7B
+    serving shapes the kernel is refused before Mosaic sees it. When this
+    test fails the kernel lowers — put it on the chip, then decide."""
+    pools = (S((138, 64, 32, 128), jnp.bfloat16),
+             S((138, 64, 32, 128), jnp.bfloat16), S((138, 64), jnp.int32))
+    with pytest.raises(ValueError, match="divisible by 8 and 128"):
+        tpu_mlir(
+            lambda q, k, v, p, bt, pos: paged_attention(
+                q, (k, v, p), bt, pos, interpret=False),
+            S((8, 1, 32, 128), jnp.bfloat16), *pools, S((8, 17), jnp.int32),
+            S((8, 1), jnp.int32))
+
+
+def test_kernel_choice_is_the_same_inside_and_outside_a_trace():
+    """The old gate probed with concrete arrays and np.asarray, so its first
+    call from inside jit raised TracerArrayConversionError, which it cached
+    as "no kernel" for the life of the process."""
+    outside = ops.pallas_interpret_default()
+    seen = []
+
+    @jax.jit
+    def traced(x):
+        seen.append(ops.pallas_interpret_default())
+        return x
+
+    traced(jnp.zeros(()))
+    assert seen == [outside] and outside is True  # CPU here: interpreter
+
+
+def test_compile_error_on_a_tpu_raises(monkeypatch):
+    """On a (monkey-patched) TPU platform the kernels are handed to the
+    compiler; this backend cannot compile them, and that error must come
+    out — not the XLA expression the deleted gates used to return."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = jnp.ones((8, 128), jnp.float32)
+    w = jnp.ones((128,), jnp.float32)
+    with pytest.raises(ValueError, match="interpret mode"):
+        fused_residual_rmsnorm(x, x, w, 1e-5)
+    with pytest.raises(ValueError, match="interpret mode"):
+        jax.jit(lambda x, w: fused_residual_rmsnorm(x, x, w, 1e-5))(x, w)
+    with pytest.raises(ValueError, match="interpret mode"):
+        int8_matmul(x, jnp.ones((128, 128), jnp.int8), w)

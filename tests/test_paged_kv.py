@@ -3,7 +3,7 @@
 The contract: swapping the batcher's dense ``[S, max_len, ...]`` slot pool
 for the global page pool + block tables changes NOTHING about tokens —
 greedy and seeded-sampled decode are bit-exact against ``generate()`` under
-both KV dtypes (the gather fallback feeds the identical masked einsum) —
+both KV dtypes (the gather read feeds the identical masked einsum) —
 while admission prefill chunks interleave with in-flight decode, pages
 recycle exactly through the allocator, prefix-cache hits land directly in
 paged slots, and pool exhaustion sheds (503 + Retry-After) instead of
