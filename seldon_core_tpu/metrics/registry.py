@@ -22,9 +22,10 @@ from prometheus_client import (
 )
 
 from seldon_core_tpu.contracts.payload import Feedback, SeldonMessage
-
-LATENCY_BUCKETS = (
-    0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
+from seldon_core_tpu.metrics.local import (
+    HOST_LAG_BUCKETS,
+    LATENCY_BUCKETS,
+    QUEUE_WAIT_BUCKETS,
 )
 
 
@@ -345,9 +346,68 @@ class MetricsRegistry:
             "Steps the host trailed the device at each drain (>=2 means "
             "the pipeline is actually ahead)",
             base,
-            buckets=(0, 1, 2, 3, 4, 6, 8, 16, 32),
+            buckets=HOST_LAG_BUCKETS,
             registry=self.registry,
         )
+        # The batcher loop's time budget (runtime/batcher.py LoopPhases,
+        # docs/observability.md "Loop phases"): every second of a loop turn
+        # is put down to exactly one phase, so the per-phase rates stack to
+        # 1.0 per loop. drain_wait / first_token_wait are the host blocked
+        # on the device; idle is nothing to do; the rest is host work the
+        # device may or may not be hidden behind.
+        self._loop_seconds = Counter(
+            "seldon_llm_loop_seconds_total",
+            "Batcher loop wall seconds by phase (the phases partition the "
+            "loop's time: their sum is the wall)",
+            base + ["phase"],
+            registry=self.registry,
+        )
+        self._loop_phase = Counter(
+            "seldon_llm_loop_phase_total",
+            "Occurrences of each batcher loop phase",
+            base + ["phase"],
+            registry=self.registry,
+        )
+        self._loop_turns = Counter(
+            "seldon_llm_loop_turns_total",
+            "Batcher loop turns",
+            base,
+            registry=self.registry,
+        )
+        self._queue_wait = Histogram(
+            "seldon_llm_queue_wait_seconds",
+            "Time from request submission to its slot reservation "
+            "(batcher path)",
+            base,
+            buckets=QUEUE_WAIT_BUCKETS,
+            registry=self.registry,
+        )
+        self._slots_active = Gauge(
+            "seldon_llm_slots_active",
+            "Continuous-batching slots holding an active request "
+            "(sampled at scrape)",
+            base,
+            registry=self.registry,
+        )
+        self._slot_seconds = Counter(
+            "seldon_llm_slot_seconds_total",
+            "Active slots x seconds, accumulated once per loop turn: its "
+            "rate is the mean number of active slots",
+            base,
+            registry=self.registry,
+        )
+        # the transport's own cost per streamed token (transport/rest.py
+        # SSE writer): stamped when the batcher surfaces the token,
+        # observed when the socket write returns
+        self._emit_delay = Histogram(
+            "seldon_llm_emit_delay_seconds",
+            "Time from the batcher surfacing a token to its SSE event "
+            "being written to the socket",
+            base,
+            buckets=LATENCY_BUCKETS,
+            registry=self.registry,
+        )
+        self._emit_delay_bound: Any = None
         # Speculative decoding (runtime/batcher.py + runtime/spec.py): the
         # accept rate and tokens-per-forward pair is the whole story —
         # tokens/forward > 1 is the >1-accepted-token-per-KV-read
@@ -714,6 +774,30 @@ class MetricsRegistry:
         if delta > 0:
             bound.inc(delta)
 
+    def _histogram_catch_up(self, histogram, snapshot: Optional[dict]) -> None:
+        """Histogram catch-up from a loop-side accumulator's lifetime
+        tallies (metrics/local.py ``HistogramAccumulator.snapshot``): the
+        counter catch-up idiom, bucket by bucket and for the sum, so an
+        observation is exported exactly once however long the scrape
+        interval. The accumulator's buckets must be the histogram's."""
+        if not snapshot:
+            return
+        bound = histogram.labels(**self._base())
+        for i, n in snapshot["buckets"].items():
+            delta = n - bound._buckets[i].get()
+            if delta > 0:
+                bound._buckets[i].inc(delta)
+        delta = snapshot["sum"] - bound._sum.get()
+        if delta > 0:
+            bound._sum.inc(delta)
+
+    def observe_emit_delay(self, seconds: float) -> None:
+        """Once per streamed token, on the transport's loop: the labelled
+        child is bound once, not looked up per token."""
+        if self._emit_delay_bound is None:
+            self._emit_delay_bound = self._emit_delay.labels(**self._base())
+        self._emit_delay_bound.observe(seconds)
+
     def sync_controlplane(self, source: Any = None) -> None:
         """Refresh autoscaler / canary / shadow series at scrape time.
         ``source`` is an engine (its graph nodes are walked for canary and
@@ -832,15 +916,31 @@ class MetricsRegistry:
             delta = stats.get(key, 0) - bound._value.get()
             if delta > 0:
                 bound.inc(delta)
-        hist = self._decode_step.labels(**self._base())
-        for seconds in stats.get("decode_step_times_s", ()):
-            hist.observe(seconds)
-        ttft = self._ttft.labels(**self._base())
-        for seconds in stats.get("ttft_s", ()):
-            ttft.observe(seconds)
-        gap = self._inter_token.labels(**self._base())
-        for seconds in stats.get("inter_token_s", ()):
-            gap.observe(seconds)
+        # loop-side accumulators (lifetime bucket tallies), caught up by
+        # difference: nothing is lost between scrapes. The raw lists
+        # llm_stats still returns are a bounded window of recent samples
+        # for tests and debugging, not what the histograms are fed from.
+        hists = stats.get("histograms", {})
+        for histogram, key in (
+            (self._decode_step, "decode_step_s"),
+            (self._ttft, "ttft_s"),
+            (self._inter_token, "inter_token_s"),
+            (self._decode_host_lag, "decode_host_lag_steps"),
+            (self._queue_wait, "queue_wait_s"),
+        ):
+            self._histogram_catch_up(histogram, hists.get(key))
+        # the loop's time budget: per-phase seconds and occurrences, turns,
+        # and the slot-occupancy integral (counted on the loop, caught up
+        # here — same idiom as the page-shed counter above)
+        for phase, seconds in stats.get("loop_seconds", {}).items():
+            self._counter_catch_up(self._loop_seconds, seconds, phase=phase)
+        for phase, n in stats.get("loop_phase_counts", {}).items():
+            self._counter_catch_up(self._loop_phase, n, phase=phase)
+        self._counter_catch_up(self._loop_turns, stats.get("loop_turns", 0))
+        self._counter_catch_up(self._slot_seconds,
+                               stats.get("slot_seconds", 0.0))
+        self._slots_active.labels(**self._base()).set(
+            stats.get("slots_active", 0))
         handoff = self._handoff.labels(**self._base())
         for seconds in stats.get("handoff_times_s", ()):
             handoff.observe(seconds)
@@ -864,9 +964,6 @@ class MetricsRegistry:
         sync = self._decode_sync.labels(**self._base())
         for seconds in stats.get("decode_sync_times_s", ()):
             sync.observe(seconds)
-        lag = self._decode_host_lag.labels(**self._base())
-        for steps in stats.get("decode_host_lag_steps", ()):
-            lag.observe(steps)
         self._decode_steps_in_flight.labels(**self._base()).set(
             stats.get("decode_steps_in_flight", 0)
         )

@@ -98,6 +98,17 @@ completion, rooted at the transport ingress that carried the request's
 ``traceparent``. Disabled tracing leaves ``_flight`` None and every hook
 is a None check; the compiled step programs are identical either way.
 
+Loop time budget (PR 23): every second of a loop turn is put down to exactly
+one phase (``LoopPhases``: admit, handoff, dispatch, prefill,
+first_token_wait, first_token, drain_wait, emit, idle, and hop for what is
+left of the turn — event loop, ``to_thread`` hand-offs). Each phase opens a
+``jax.profiler.TraceAnnotation`` named ``llm.<phase>`` under the turn's
+``llm.turn``, so a device trace's idle gaps can be put down to the host
+phase that covers them on the profiler's own clock, and adds its
+``perf_counter`` wall to a per-phase accumulator that ``llm_stats`` exports
+(``seldon_llm_loop_seconds_total{phase}``). Always on: an inactive
+annotation and two clock reads per phase against a turn of milliseconds.
+
 Paged KV cache (PR 7): with ``kv_cache_layout="paged"`` (the default) the
 dense ``[S, max_len, ...]`` slot pool is replaced by a GLOBAL pool of
 fixed-size KV pages plus a device-resident per-slot block table — the
@@ -117,8 +128,10 @@ steps in device program order, exactly like the dense ``insert``.
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import threading
+import time
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -489,6 +502,111 @@ class _Slot:
 
     def dispatched_pos(self) -> int:
         return self.true_len + self.disp_new - 1
+
+
+LOOP_PHASES = ("admit", "handoff", "dispatch", "prefill", "first_token_wait",
+               "first_token", "drain_wait", "emit", "idle", "hop")
+
+
+class _Phase:
+    """One open phase: a context manager that times itself on
+    ``time.perf_counter`` and shows in a profiler trace as ``llm.<name>``.
+    ``t0``/``t1``/``seconds`` stay readable after exit, so a site that
+    needs its own timestamps takes them from here: one clock pair a site."""
+
+    __slots__ = ("owner", "name", "t0", "t1", "seconds", "nested", "_ann")
+
+    def __init__(self, owner: "LoopPhases", name: str):
+        self.owner = owner
+        self.name = name
+        self.nested = 0.0
+
+    def __enter__(self) -> "_Phase":
+        self._ann = self.owner._annotation("llm." + self.name)
+        self._ann.__enter__()
+        self.owner._open.append(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self.seconds = self.t1 - self.t0
+        self.owner._close(self)
+        self._ann.__exit__(*exc)
+
+
+class LoopPhases:
+    """The batcher loop's time budget. Single writer, like the flight
+    recorder: phases open and close only in the loop's own serialized
+    context (the loop coroutine and the worker threads it awaits one at a
+    time), so there is no lock. A phase opened inside another takes its
+    time out of the outer one (``first_token_wait`` inside ``prefill``,
+    ``drain_wait`` inside ``emit``): every second belongs to the innermost
+    phase, and the phases of a turn plus its ``hop`` ARE the turn's wall.
+    Readers (``stats()`` at a scrape) may be one phase behind."""
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self.seconds: Dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
+        self.counts: Dict[str, int] = dict.fromkeys(LOOP_PHASES, 0)
+        self.turns = 0
+        self.slot_seconds = 0.0
+        self._open: List[_Phase] = []
+        self._turn: Optional[Any] = None   # the open turn's annotation
+        self._turn_t0 = 0.0
+        self._turn_phases = 0.0            # phase seconds inside this turn
+
+    def phase(self, name: str) -> _Phase:
+        return _Phase(self, name)
+
+    def _close(self, ph: _Phase) -> None:
+        self._open.pop()        # ph: phases close innermost first
+        own = ph.seconds - ph.nested
+        self.seconds[ph.name] += own
+        self.counts[ph.name] += 1
+        self._turn_phases += own
+        if self._open:
+            self._open[-1].nested += ph.seconds
+
+    def turn(self, active_slots: int) -> None:
+        """Top of a loop turn: close the previous one (what its phases did
+        not cover is ``hop``; its wall times the active slots goes into the
+        occupancy integral) and open the next."""
+        self.end_turn(active_slots)
+        self._turn = self._annotation("llm.turn")
+        self._turn.__enter__()
+        self._turn_t0 = time.perf_counter()
+        self._turn_phases = 0.0
+
+    def end_turn(self, active_slots: int) -> None:
+        if self._turn is None:
+            return
+        wall = time.perf_counter() - self._turn_t0
+        self._turn.__exit__(None, None, None)
+        self._turn = None
+        self.seconds["hop"] += max(wall - self._turn_phases, 0.0)
+        self.counts["hop"] += 1
+        self.turns += 1
+        self.slot_seconds += active_slots * wall
+
+    def stats(self) -> dict:
+        return {"loop_seconds": dict(self.seconds),
+                "loop_phase_counts": dict(self.counts),
+                "loop_turns": self.turns,
+                "slot_seconds": self.slot_seconds}
+
+
+def _in_phase(name: str):
+    """Run a batcher method as one loop phase."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(self, *args, **kwargs):
+            with self._phases.phase(name):
+                return fn(self, *args, **kwargs)
+        return run
+    return wrap
 
 
 class _InFlight:
@@ -892,6 +1010,8 @@ class ContinuousBatcher:
         self._inflight_hwm = 0       # max steps in flight ever reached
         self._last_admit_inflight = 0  # steps in flight at the last admit
         self._last_drain_t: Optional[float] = None
+        # the loop's time budget (module docstring): always on
+        self._phases = LoopPhases()
         # Disaggregated prefill/decode (module docstring): remote-prefill
         # admission stages jobs on prefill-slice workers and consumes
         # finished handoffs from the TransferQueue instead of prefilling
@@ -1561,7 +1681,7 @@ class ContinuousBatcher:
         # the baseline the next token's gap measures from
         now = time.perf_counter()
         if t_arrival is not None:
-            self.server._ttft_times.append(now - t_arrival)
+            self.server.observe("ttft_s", now - t_arrival)
             self.server._ttft_by_class.append(
                 (slot.slo_class, now - t_arrival))
         self._pending.count_tokens(slot.tenant, slot.slo_class, 1)
@@ -1624,6 +1744,7 @@ class ContinuousBatcher:
         self._draft_caches = self._draft_insert(
             self._draft_caches, dcache, jnp.asarray(i, jnp.int32))
 
+    @_in_phase("admit")
     def _admit(self, req) -> bool:
         """Dense-layout admission: one-shot prefill into a 1-sequence cache,
         jitted insert into the free slot. ``req`` is the scheduler's
@@ -1637,9 +1758,7 @@ class ContinuousBatcher:
             return False
         ids, plen = self._truncate_prompt(req.ids, req.max_new, req.info)
         L = len(ids)
-        if self._flight is not None:
-            self._flight.begin(free, req.trace, req.t_arrival, L,
-                               tags=self._flight_tags(req))
+        self._begin(free, req, L)
         tokens = np.zeros((1, plen), np.int32)
         positions = np.full((1, plen), PAD_POS, np.int32)
         tokens[0, :L] = ids
@@ -1658,17 +1777,30 @@ class ContinuousBatcher:
             logits, cache1 = prefill(self.server._params, jnp.asarray(tokens),
                                      jnp.asarray(positions))
         self._caches = self._insert(self._caches, cache1, free)
-        # graftlint: allow-host-sync-in-hot-path(admission-time sync, once per request not per token: the first sampled token must reach the host to seed slot bookkeeping before the slot joins the pipelined batch)
-        first_logits = np.asarray(logits[0, L - 1]).astype(np.float32)
+        with self._phases.phase("first_token_wait"):
+            # graftlint: allow-host-sync-in-hot-path(admission-time sync, once per request not per token: the first sampled token must reach the host to seed slot bookkeeping before the slot joins the pipelined batch)
+            first_logits = np.asarray(logits[0, L - 1]).astype(np.float32)
         if self._flight is not None:
             self._flight.record(free, EV_PREFILL, tokens=L,
                                 dur_s=time.perf_counter() - t0)
-        first, key = self._sample_first(first_logits, req.seed,
-                                        req.resume_tokens)
-        self._commit_slot(free, first, key, L, req.max_new, req.fut,
-                          req.on_token, ids=ids, t_arrival=req.t_arrival,
-                          req=req)
+        with self._phases.phase("first_token"):
+            first, key = self._sample_first(first_logits, req.seed,
+                                            req.resume_tokens)
+            self._commit_slot(free, first, key, L, req.max_new, req.fut,
+                              req.on_token, ids=ids, t_arrival=req.t_arrival,
+                              req=req)
         return True
+
+    def _begin(self, slot: int, req, prompt_tokens: int) -> None:
+        """A slot is reserved for ``req``: its queue wait ends here (counted
+        whether or not tracing is on) and, with tracing, its flight-recorder
+        timeline starts."""
+        if req.t_arrival is not None:
+            self.server.observe("queue_wait_s",
+                                time.perf_counter() - req.t_arrival)
+        if self._flight is not None:
+            self._flight.begin(slot, req.trace, req.t_arrival, prompt_tokens,
+                               tags=self._flight_tags(req))
 
     @staticmethod
     def _flight_tags(req) -> Optional[dict]:
@@ -1684,6 +1816,7 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------
     # Disaggregated admission: stage remote jobs, consume handoffs
     # ------------------------------------------------------------------
+    @_in_phase("admit")
     def _admit_remote(self, req) -> bool:
         """Remote-prefill admission, decode-side half: reserve a slot,
         consult the radix trie so the prefill slice only computes the
@@ -1766,9 +1899,8 @@ class ContinuousBatcher:
         if k0:
             # once per funded admission, like the local path
             self._radix.record_hit(k0, len(shared), False)
+        self._begin(free, req, L)
         if self._flight is not None:
-            self._flight.begin(free, req.trace, req.t_arrival, L,
-                               tags=self._flight_tags(req))
             if k0:
                 self._flight.record(free, EV_PREFIX_HIT, tokens=k0,
                                     blocks=len(shared))
@@ -1791,6 +1923,7 @@ class ContinuousBatcher:
             self._remote.submit(req)
         return True
 
+    @_in_phase("handoff")
     def _consume_handoffs(self):
         """Drain every READY handoff: import the staged KV into the slot
         pool (one donated jitted scatter through the slot's block row;
@@ -1883,12 +2016,13 @@ class ContinuousBatcher:
                 self._flight.record(job.slot, EV_HANDOFF_IMPORT,
                                     bytes=h.transfer_bytes,
                                     dur_s=time.perf_counter() - t0)
-            first, key = self._sample_first(
-                h.first_logits, job.seed,
-                job.req.resume_tokens if job.req is not None else 0)
-            self._commit_slot(job.slot, first, key, job.L, job.max_new,
-                              job.fut, job.on_token, ids=job.ids,
-                              t_arrival=job.t_arrival, req=job.req)
+            with self._phases.phase("first_token"):
+                first, key = self._sample_first(
+                    h.first_logits, job.seed,
+                    job.req.resume_tokens if job.req is not None else 0)
+                self._commit_slot(job.slot, first, key, job.L, job.max_new,
+                                  job.fut, job.on_token, ids=job.ids,
+                                  t_arrival=job.t_arrival, req=job.req)
 
     def _shed_remote_job(self, job_id: int, why: str):
         """Shed a staged remote admission (page pressure / shutdown): the
@@ -1954,6 +2088,7 @@ class ContinuousBatcher:
             return None
         return self._allocator.alloc(n)
 
+    @_in_phase("admit")
     def _admit_begin(self, req) -> bool:
         """Paged admission, phase 1 (host-side, cheap): match the prompt
         against the radix prefix cache (shared full blocks enter the block
@@ -2028,9 +2163,7 @@ class ContinuousBatcher:
         slot.on_token = req.on_token
         slot.tenant = req.tenant
         slot.slo_class = req.slo_class
-        if self._flight is not None:
-            self._flight.begin(free, req.trace, req.t_arrival, L,
-                               tags=self._flight_tags(req))
+        self._begin(free, req, L)
         # neutralize the FRESH pages' previous-owner positions BEFORE any
         # write lands through them (stale real positions would make this
         # slot's mask attend another sequence's leftover KV). Shared trie
@@ -2073,6 +2206,7 @@ class ContinuousBatcher:
         self._prefill = job
         return True
 
+    @_in_phase("prefill")
     def _prefill_step(self):
         """One chunked-prefill dispatch (worker thread): write the next
         ``chunk`` prompt tokens into the pool through the job's block-table
@@ -2114,9 +2248,14 @@ class ContinuousBatcher:
             self._flight.record(job.slot, EV_PREFILL_CHUNK, start=start,
                                 tokens=n, dur_s=time.perf_counter() - t0)
         if job.next >= job.L:
-            # graftlint: allow-host-sync-in-hot-path(admission-time sync, once per request not per chunk: the LAST chunk's logits seed the first sampled token; earlier chunks were enqueue-only)
-            first_logits = np.asarray(logits[0, n - 1]).astype(np.float32)
-            self._activate(job, first_logits)
+            # the loop stands still here until the device has run every
+            # step queued ahead of this chunk and the chunk itself: no new
+            # decode step is dispatched before the token is committed
+            with self._phases.phase("first_token_wait"):
+                # graftlint: allow-host-sync-in-hot-path(admission-time sync, once per request not per chunk: the LAST chunk's logits seed the first sampled token; earlier chunks were enqueue-only)
+                first_logits = np.asarray(logits[0, n - 1]).astype(np.float32)
+            with self._phases.phase("first_token"):
+                self._activate(job, first_logits)
 
     def _activate(self, job: _PrefillJob, first_logits: np.ndarray):
         """Paged admission, final phase: sample the first token on
@@ -2314,6 +2453,7 @@ class ContinuousBatcher:
                 pass
         self._resolve(job.fut, exc=self._shed_error(why))
 
+    @_in_phase("admit")
     def _preempt_for_interactive(self) -> bool:
         """Deadline-aware slot reclamation (docs/multitenancy.md): an
         interactive admission blocked on occupied slots pushes ONE staged
@@ -2515,6 +2655,9 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------
     # Pipelined decode: dispatch (producer) / drain (consumer)
     # ------------------------------------------------------------------
+    def active_slots(self) -> int:
+        return sum(1 for s in self._slots if s.active)
+
     def _dispatch_eligible(self) -> List[int]:
         """Slots worth stepping: active AND not yet dispatched through their
         token budget or cache length. A budget-exhausted slot still rides
@@ -2547,11 +2690,19 @@ class ContinuousBatcher:
     def _dispatch(self):
         """Enqueue one (possibly K-fused) decode step on the device WITHOUT
         waiting for its tokens: the state arrays are threaded from the
-        previous step's outputs, so the device runs ahead of the host."""
-        import time
+        previous step's outputs, so the device runs ahead of the host.
+        The ``dispatch`` phase is page growth + the enqueue, and its clock
+        pair is also the step's dispatch timestamp and the
+        ``seldon_llm_decode_dispatch_seconds`` observation."""
+        with self._phases.phase("dispatch") as ph:
+            if self.spec_mode != "off":
+                enqueued = self._dispatch_spec(ph.t0)
+            else:
+                enqueued = self._dispatch_plain(ph.t0)
+        if enqueued:
+            self.server._decode_dispatch_times.append(ph.seconds)
 
-        if self.spec_mode != "off":
-            return self._dispatch_spec()
+    def _dispatch_plain(self, t0: float) -> bool:
         k = self._pick_k()
         if self.paged:
             # grow every eligible slot's pages to cover this dispatch's k
@@ -2565,7 +2716,7 @@ class ContinuousBatcher:
                     self._ensure_slot_pages(
                         i, self._slots[i].dispatched_pos() + k - 1)
             if not self._dispatch_eligible():
-                return
+                return False
         # adapted steps (llm.lora_decode_step): the pool/id pair rides at
         # the end of either signature, un-donated — same idiom as the
         # spec-step dispatch below
@@ -2575,7 +2726,6 @@ class ContinuousBatcher:
         if self.paged:
             fn = self.server._get_decode_step_paged(
                 self.S, self.n_pages, k, lora=lora)
-            t0 = time.perf_counter()
             (self._caches, self._last_tok, self._next_pos, self._keys,
              toks) = fn(self.server._params, self._caches, self._last_tok,
                         self._next_pos, self._keys, self._temp,
@@ -2583,19 +2733,18 @@ class ContinuousBatcher:
         else:
             fn = self.server._get_decode_step(self.S, self.max_len, k,
                                               lora=lora)
-            t0 = time.perf_counter()
             (self._caches, self._last_tok, self._next_pos, self._keys,
              toks) = fn(self.server._params, self._caches, self._last_tok,
                         self._next_pos, self._keys, self._temp, *extra)
-        self.server._decode_dispatch_times.append(time.perf_counter() - t0)
         snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
         for i, _ in snapshot:
             self._slots[i].disp_new += k
         self._inflight.append(_InFlight(toks, k, snapshot, t0))
         if len(self._inflight) > self._inflight_hwm:
             self._inflight_hwm = len(self._inflight)
+        return True
 
-    def _dispatch_spec(self):
+    def _dispatch_spec(self, t0: float) -> bool:
         """Enqueue one fused draft+verify step (``LLMServer._get_spec_step``)
         WITHOUT waiting for its tokens. Each slot advances a data-dependent
         1..cap+1 tokens known only at drain time, so the dispatch side books
@@ -2605,8 +2754,6 @@ class ContinuousBatcher:
         advance. The per-slot cap clamps the drafts offered: the
         acceptance-rate controller's depth, the remaining token budget
         (emits <= cap+1), and the cache edge (writes reach next_pos+cap)."""
-        import time
-
         import jax.numpy as jnp
 
         K = self.spec_k
@@ -2627,7 +2774,7 @@ class ContinuousBatcher:
                     self._ensure_slot_pages(
                         i, self._slots[i].dispatched_pos() + int(caps[i]))
             if not self._dispatch_eligible():
-                return
+                return False
             fn = self.server._get_spec_step(
                 self.S, K, self.hist_len, mode=self.spec_mode,
                 layout="paged", n_pages=self.n_pages,
@@ -2642,7 +2789,6 @@ class ContinuousBatcher:
         # the end of every signature variant, un-donated
         extra = () if self._adapters is None else (
             self._adapters.pool(), self._adapter_ids)
-        t0 = time.perf_counter()
         if self.paged and draft:
             (self._caches, self._last_tok, self._next_pos, self._keys,
              self._hist, toks, acc, self._draft_caches) = fn(
@@ -2669,7 +2815,6 @@ class ContinuousBatcher:
                 self.server._params, self._caches, self._last_tok,
                 self._next_pos, self._keys, self._temp, self._hist,
                 cap_dev, *extra)
-        self.server._decode_dispatch_times.append(time.perf_counter() - t0)
         snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
         booked = {}
         for i, _ in snapshot:
@@ -2679,27 +2824,28 @@ class ContinuousBatcher:
                                         booked=booked))
         if len(self._inflight) > self._inflight_hwm:
             self._inflight_hwm = len(self._inflight)
+        return True
 
+    @_in_phase("emit")
     def _drain_one(self):
-        """Consume the OLDEST in-flight step: block until its tokens land,
-        then run all host bookkeeping (EOS, budgets, streaming callbacks,
-        slot release). Later steps stay dispatched while this runs — the
-        host trails the device, never the other way around."""
-        import time
-
+        """Consume the OLDEST in-flight step: block until its tokens land
+        (the ``drain_wait`` phase), then run all host bookkeeping (EOS,
+        budgets, streaming callbacks, slot release: the rest is ``emit``).
+        Later steps stay dispatched while this runs — the host trails the
+        device, never the other way around."""
         rec: _InFlight = self._inflight.popleft()
         # host lag in decode STEPS, not dispatch records: a fused record
         # covers k steps, so depth 2 at K=8 is a 16-step lag
         lag = rec.k + sum(r.k for r in self._inflight)
-        t0 = time.perf_counter()
-        # graftlint: allow-host-sync-in-hot-path(the consumer's deliberate drain sync: the host reads tokens one pipeline_depth BEHIND the device, so this blocks on the oldest step only while newer steps keep the chip busy — docs/performance.md)
-        arr = np.asarray(rec.tokens)  # [S, k] — the only per-step host sync
-        if rec.acc is not None:
-            # graftlint: allow-host-sync-in-hot-path(part of the same drain sync: the verify step's per-slot accepted counts land with its tokens — the program already finished for the token read above)
-            accs = np.asarray(rec.acc)  # [S] accepted counts, 1..K+1
-        now = time.perf_counter()
-        self.server._decode_sync_times.append(now - t0)
-        self.server._decode_host_lag.append(lag)
+        with self._phases.phase("drain_wait") as wait:
+            # graftlint: allow-host-sync-in-hot-path(the consumer's deliberate drain sync: the host reads tokens one pipeline_depth BEHIND the device, so this blocks on the oldest step only while newer steps keep the chip busy — docs/performance.md)
+            arr = np.asarray(rec.tokens)  # [S, k] — the only per-step host sync
+            if rec.acc is not None:
+                # graftlint: allow-host-sync-in-hot-path(part of the same drain sync: the verify step's per-slot accepted counts land with its tokens — the program already finished for the token read above)
+                accs = np.asarray(rec.acc)  # [S] accepted counts, 1..K+1
+        now = wait.t1
+        self.server._decode_sync_times.append(wait.seconds)
+        self.server.observe("decode_host_lag_steps", lag)
         # steady-state step time: interval since the previous drain (the
         # pipeline overlaps dispatch+sync with device compute, so per-step
         # wall is drain-to-drain), floored at this record's dispatch time so
@@ -2707,8 +2853,7 @@ class ContinuousBatcher:
         base = rec.t_dispatch if self._last_drain_t is None else max(
             self._last_drain_t, rec.t_dispatch)
         per_step = max(now - base, 0.0) / rec.k
-        for _ in range(rec.k):
-            self.server._decode_step_times.append(per_step)
+        self.server.observe("decode_step_s", per_step, weight=rec.k)
         self._last_drain_t = now
         self.server._last_decode_kv_bytes = self._cache_nbytes
         if rec.acc is not None:
@@ -2732,7 +2877,7 @@ class ContinuousBatcher:
                 # inter-token gap at this drain (a fused block surfaces
                 # its k tokens in one burst: trailing tokens record ~0)
                 if slot.t_last is not None:
-                    self.server._inter_token_times.append(now - slot.t_last)
+                    self.server.observe("inter_token_s", now - slot.t_last)
                 slot.t_last = now
                 if slot.on_token is not None and tok != self.eos_id:
                     slot.on_token(tok)
@@ -2762,8 +2907,6 @@ class ContinuousBatcher:
         block cuts the credit loop there — the device ran ahead past it,
         exactly like a trailing run-ahead step, and the leftover tokens
         are dropped, never surfaced."""
-        import time
-
         now = time.perf_counter()
         for i, gen in rec.snapshot:
             slot = self._slots[i]
@@ -2794,7 +2937,7 @@ class ContinuousBatcher:
                 # its trailing tokens record ~0 gaps — the block's real
                 # cadence is the first token's gap)
                 if slot.t_last is not None:
-                    self.server._inter_token_times.append(now - slot.t_last)
+                    self.server.observe("inter_token_s", now - slot.t_last)
                 slot.t_last = now
                 if slot.on_token is not None and tok != self.eos_id:
                     slot.on_token(tok)
@@ -2817,8 +2960,12 @@ class ContinuousBatcher:
 
     async def _run(self):
         self.crashed = None  # a restarted loop is a recovered loop
+        phases = self._phases
         try:
             while True:
+                # the turn's time budget closes here and nowhere else: what
+                # the previous turn's phases did not cover is its hop
+                phases.turn(self.active_slots())
                 # liveness heartbeat + deterministic chaos injection: both
                 # happen in the loop's own serialized context, so a raising
                 # chaos hook dies exactly like a device fault mid-turn
@@ -2915,7 +3062,9 @@ class ContinuousBatcher:
                     continue
                 self._wakeup.clear()
                 try:
-                    await asyncio.wait_for(self._wakeup.wait(), timeout=0.5)
+                    with phases.phase("idle"):
+                        await asyncio.wait_for(self._wakeup.wait(),
+                                               timeout=0.5)
                 except asyncio.TimeoutError:
                     if self._closed:
                         return
@@ -2959,3 +3108,5 @@ class ContinuousBatcher:
                         pass
                 self._resolve(req.fut, exc=e)
             raise
+        finally:
+            phases.end_turn(self.active_slots())

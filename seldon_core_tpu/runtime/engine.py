@@ -865,9 +865,10 @@ class ReplicaSet(SeldonComponent):
         return out
 
     def llm_stats(self) -> Dict[str, Any]:
-        """Aggregated snapshot for /metrics: numeric gauges/counters sum,
-        drained lists concatenate (each replica's deques drain exactly
-        once, same as solo), strings/configs come from replica 0."""
+        """Aggregated snapshot for /metrics: numeric gauges/counters sum
+        (inside dicts too: per-phase tallies, histogram buckets), drained
+        lists concatenate (each replica's deques drain exactly once, same
+        as solo), strings/configs come from replica 0."""
         stats_list = [r.llm_stats() for r in self.members()
                       if hasattr(r, "llm_stats")]
         if not stats_list:
@@ -875,15 +876,26 @@ class ReplicaSet(SeldonComponent):
         fractions = ("kv_occupancy", "kv_page_fragmentation",
                      "spec_accept_rate", "spec_tokens_per_forward",
                      "spec_draft_overhead_fraction")
+
+        def add(cur, v):
+            if isinstance(v, list) and isinstance(cur, list):
+                return cur + v
+            if isinstance(v, dict) and isinstance(cur, dict):
+                # per-phase tallies and histogram buckets: key by key
+                out = dict(cur)
+                for k, x in v.items():
+                    out[k] = add(out[k], x) if k in out else x
+                return out
+            if isinstance(v, (int, float)) and isinstance(
+                    cur, (int, float)) and not isinstance(v, bool):
+                return cur + v
+            return cur
+
         merged = dict(stats_list[0])
         for stats in stats_list[1:]:
             for k, v in stats.items():
-                cur = merged.get(k)
-                if isinstance(v, list) and isinstance(cur, list):
-                    merged[k] = cur + v
-                elif isinstance(v, (int, float)) and isinstance(
-                        cur, (int, float)) and not isinstance(v, bool):
-                    merged[k] = cur + v
+                if k in merged:
+                    merged[k] = add(merged[k], v)
         for k in fractions:  # fractions average; sums would exceed 1.0
             if isinstance(merged.get(k), (int, float)):
                 merged[k] = merged[k] / len(stats_list)

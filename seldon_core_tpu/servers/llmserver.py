@@ -486,10 +486,42 @@ class LLMServer(SeldonComponent):
         # disaggregated serving: per-handoff wall (prefill-slice compute +
         # device-to-device transfer + decode-side import)
         self._handoff_times: Any = deque(maxlen=4096)
+        # what the /metrics histograms are fed from: lifetime bucket tallies
+        # counted where the work happens (metrics/local.py), caught up by
+        # difference at the scrape, so a long scrape interval loses nothing.
+        # The deques above keep a bounded window of the same samples for
+        # llm_stats' raw lists (tests, debugging).
+        from seldon_core_tpu.metrics.local import (
+            HOST_LAG_BUCKETS, LATENCY_BUCKETS, QUEUE_WAIT_BUCKETS,
+            HistogramAccumulator)
+
+        self._hists: Dict[str, Any] = {
+            "decode_step_s": HistogramAccumulator(LATENCY_BUCKETS),
+            "ttft_s": HistogramAccumulator(LATENCY_BUCKETS),
+            "inter_token_s": HistogramAccumulator(LATENCY_BUCKETS),
+            "decode_host_lag_steps": HistogramAccumulator(HOST_LAG_BUCKETS),
+            "queue_wait_s": HistogramAccumulator(QUEUE_WAIT_BUCKETS),
+        }
+        self._recent: Dict[str, Any] = {
+            "decode_step_s": self._decode_step_times,
+            "ttft_s": self._ttft_times,
+            "inter_token_s": self._inter_token_times,
+            "decode_host_lag_steps": self._decode_host_lag,
+        }
         # per-device committed param copies for prefill-slice workers
         # (runtime/disagg.py); built on first use under its own lock
         self._device_params: Dict[Any, Any] = {}
         self._device_params_lock = threading.Lock()
+
+    def observe(self, key: str, value: float, weight: int = 1) -> None:
+        """One loop-side observation of a /metrics histogram (single
+        writer: the batcher loop's serialized context, or generate()):
+        ``weight`` equal observations go into the lifetime accumulator,
+        one sample into the recent window."""
+        self._hists[key].observe(value, weight)
+        recent = self._recent.get(key)
+        if recent is not None:
+            recent.append(value)
 
     # ------------------------------------------------------------------
     def load(self) -> None:
@@ -1870,9 +1902,8 @@ class LLMServer(SeldonComponent):
             )
             # graftlint: allow-host-sync-in-hot-path(generate()'s one deliberate result sync: the whole fused decode ran device-side; callers that must not block use the pipelined batcher instead)
             toks = np.asarray(toks)  # blocks: the wall below covers device time
-            self._decode_step_times.append(
-                (_time.perf_counter() - t0) / (max_new - 1)
-            )
+            self.observe("decode_step_s",
+                         (_time.perf_counter() - t0) / (max_new - 1))
             out_tokens.append(toks)
         all_toks = np.concatenate(out_tokens, axis=1)[:n]  # drop batch padding
 
@@ -2014,10 +2045,14 @@ class LLMServer(SeldonComponent):
             adapter_stats = {k: snap[k] for k in adapter_stats}
         tenant_counters: List[dict] = []
         queue_by_class: Dict[str, int] = {}
+        slots_active = 0
+        loop_stats: Dict[str, Any] = {}
         svc = getattr(self, "_batcher_service", None)
         if svc is not None:
             batcher = svc.batcher
-            occupancy = sum(1 for s in batcher._slots if s.active) / max(batcher.S, 1)
+            slots_active = batcher.active_slots()
+            occupancy = slots_active / max(batcher.S, 1)
+            loop_stats = batcher._phases.stats()
             slot_bytes = self._entry_nbytes(batcher._caches, None)
             in_flight = len(batcher._inflight)
             inflight_hwm = batcher._inflight_hwm
@@ -2049,6 +2084,14 @@ class LLMServer(SeldonComponent):
             **page_stats,
             "kv_cache_bytes": slot_bytes + prefix_bytes,
             "kv_occupancy": occupancy,
+            "slots_active": slots_active,
+            # the batcher loop's time budget (runtime/batcher.py LoopPhases):
+            # loop_seconds / loop_phase_counts by phase, loop_turns,
+            # slot_seconds (active slots x seconds, per turn)
+            **loop_stats,
+            # lifetime bucket tallies of the histograms counted on the loop
+            # (sync_llm catches the Prometheus histograms up to them)
+            "histograms": {k: h.snapshot() for k, h in self._hists.items()},
             "kv_bytes_per_step": self._last_decode_kv_bytes,
             "decode_step_times_s": drain(self._decode_step_times),
             # pipelined decode: dispatch (enqueue-only) vs sync (host block)

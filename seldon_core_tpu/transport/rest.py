@@ -160,6 +160,61 @@ async def parse_framed_message(request: web.Request) -> SeldonMessage:
 # Microservice app: one component
 # ---------------------------------------------------------------------------
 
+def make_profile_handler() -> Callable:
+    """POST /profile?seconds=N on both apps: capture a jax.profiler trace
+    (device planes + the host plane, which carries the batcher's ``llm.*``
+    loop phases on the same clock — docs/observability.md "Loop phases")
+    and write it under SELDON_PROFILE_DIR. Gated by that env var:
+    profiling allocates and serializes device state, so it is opt-in. Only
+    the process that holds the chip can trace it, hence a route."""
+    state = {"active": False}
+
+    async def profile(request: web.Request) -> web.Response:
+        base = os.environ.get("SELDON_PROFILE_DIR", "")
+        if not base:
+            return web.json_response(
+                {"status": {"code": 403, "info": "set SELDON_PROFILE_DIR to enable"}},
+                status=403,
+            )
+        if state["active"]:
+            return web.json_response(
+                {"status": {"code": 409, "info": "profile already running"}}, status=409
+            )
+        import math
+
+        try:
+            seconds = float(request.query.get("seconds", "2"))
+        except ValueError:
+            seconds = 2.0
+        if not (math.isfinite(seconds) and 0 < seconds <= 60):
+            seconds = 2.0
+
+        import jax
+
+        options = jax.profiler.ProfileOptions()
+        # the Python tracer slows the host it measures; TraceAnnotations
+        # (level 2) are what the host plane is read for
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        out_dir = os.path.join(base, f"trace_{int(time.time())}")
+        state["active"] = True
+        started = False
+        try:
+            jax.profiler.start_trace(out_dir, profiler_options=options)
+            started = True
+            await asyncio.sleep(seconds)
+        finally:
+            state["active"] = False
+            if started:
+                try:
+                    jax.profiler.stop_trace()
+                except Exception:  # double-stop on teardown races
+                    logger.exception("stop_trace failed")
+        return web.json_response({"trace_dir": out_dir, "seconds": seconds})
+
+    return profile
+
+
 def make_component_app(
     component: Any,
     unit_id: str = "",
@@ -271,6 +326,7 @@ def make_component_app(
     app.router.add_get("/metrics", prom)
     app.router.add_get("/prometheus", prom)
     app.router.add_get("/debug/timeline", debug_timeline)
+    app.router.add_post("/profile", make_profile_handler())
 
     if hasattr(component, "generate"):
         _add_generate_routes(app, component, metrics)
@@ -445,7 +501,13 @@ def _add_generate_routes(app: web.Application, component: Any,
             q: asyncio.Queue = asyncio.Queue()
 
             def on_token(tok):
-                loop.call_soon_threadsafe(q.put_nowait, tok)
+                # called on the batcher's worker thread at the moment the
+                # token is surfaced: the stamp beside it starts the
+                # transport's own clock (call_soon_threadsafe, queue, SSE
+                # framing, socket write) — seldon_llm_emit_delay_seconds
+                loop.call_soon_threadsafe(
+                    q.put_nowait,
+                    None if tok is None else (tok, time.perf_counter()))
 
             if svc is None:
                 # no batcher configured: stream via a shared 1-slot service
@@ -466,7 +528,7 @@ def _add_generate_routes(app: web.Application, component: Any,
                 # any token (closed batcher, bad prompt) never sends the None
                 # sentinel, and waiting only on the queue would hang the
                 # connection forever.
-                async def write_tok(tok):
+                async def write_tok(tok, surfaced):
                     if isinstance(tok, ResumeMarker):
                         # fleet recovery re-attached this stream after a
                         # replica death: an in-band marker, never a token
@@ -478,16 +540,17 @@ def _add_generate_routes(app: web.Application, component: Any,
                              and isinstance(prompt, str) else None)
                     await resp.write(
                         f"data: {json.dumps({'token': tok, 'text': piece})}\n\n".encode())
+                    metrics.observe_emit_delay(time.perf_counter() - surfaced)
 
                 while True:
                     getter = asyncio.ensure_future(q.get())
                     done, _ = await asyncio.wait(
                         {getter, fut}, return_when=asyncio.FIRST_COMPLETED)
                     if getter in done:
-                        tok = getter.result()
-                        if tok is None:
+                        item = getter.result()
+                        if item is None:
                             break
-                        await write_tok(tok)
+                        await write_tok(*item)
                         continue
                     # fut resolved first. The old code took AT MOST ONE
                     # leftover token here, so tokens enqueued between the
@@ -499,19 +562,19 @@ def _add_generate_routes(app: web.Application, component: Any,
                     # (the None sentinel, if queued, still terminates).
                     getter.cancel()
                     try:
-                        tok = await getter
+                        item = await getter
                     except asyncio.CancelledError:
-                        tok = False  # cancelled clean: claimed nothing
-                    leftovers = [] if tok is False else [tok]
+                        item = False  # cancelled clean: claimed nothing
+                    leftovers = [] if item is False else [item]
                     while True:
                         try:
                             leftovers.append(q.get_nowait())
                         except asyncio.QueueEmpty:
                             break
-                    for tok in leftovers:
-                        if tok is None:
+                    for item in leftovers:
+                        if item is None:
                             break
-                        await write_tok(tok)
+                        await write_tok(*item)
                     break
                 toks = await fut
                 text = decode.decode(toks) if (decode is not None
@@ -733,50 +796,6 @@ def make_engine_app(
 
         return web.json_response(engine_spec())
 
-    profile_state = {"active": False}
-
-    async def profile(request):
-        """Device-level profiling (SURVEY.md §5: the XLA/jax-profiler half of
-        the tracing story): capture a jax.profiler trace for ?seconds=N and
-        write it under SELDON_PROFILE_DIR. Gated by that env var — profiling
-        allocates and serializes device state, so it is opt-in."""
-        base = os.environ.get("SELDON_PROFILE_DIR", "")
-        if not base:
-            return web.json_response(
-                {"status": {"code": 403, "info": "set SELDON_PROFILE_DIR to enable"}},
-                status=403,
-            )
-        if profile_state["active"]:
-            return web.json_response(
-                {"status": {"code": 409, "info": "profile already running"}}, status=409
-            )
-        import math
-
-        try:
-            seconds = float(request.query.get("seconds", "2"))
-        except ValueError:
-            seconds = 2.0
-        if not (math.isfinite(seconds) and 0 < seconds <= 60):
-            seconds = 2.0
-
-        import jax
-
-        out_dir = os.path.join(base, f"trace_{int(time.time())}")
-        profile_state["active"] = True
-        started = False
-        try:
-            jax.profiler.start_trace(out_dir)
-            started = True
-            await asyncio.sleep(seconds)
-        finally:
-            profile_state["active"] = False
-            if started:
-                try:
-                    jax.profiler.stop_trace()
-                except Exception:  # double-stop on teardown races
-                    logger.exception("stop_trace failed")
-        return web.json_response({"trace_dir": out_dir, "seconds": seconds})
-
     app.router.add_post("/api/v0.1/predictions", predictions)
     app.router.add_post("/predict", predictions)
     app.router.add_post("/api/v0.1/feedback", feedback)
@@ -792,7 +811,7 @@ def make_engine_app(
     app.router.add_get("/prometheus", prom)
     app.router.add_get("/seldon.json", openapi)
     app.router.add_get("/debug/timeline", debug_timeline)
-    app.router.add_post("/profile", profile)
+    app.router.add_post("/profile", make_profile_handler())
     return app
 
 
