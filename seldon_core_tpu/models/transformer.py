@@ -239,8 +239,8 @@ def gather_paged_view(cache, block_tables: jnp.ndarray, dtype):
     (the kernel's parity oracle) address the pool through this gather, so
     a change to the page addressing can never desynchronize them. int8
     pools (5-tuple) dequantize here — the gather moves bytes, never
-    arithmetic, so the view feeds any downstream einsum exactly as the
-    dense layout would."""
+    arithmetic, so the view feeds ``grouped_query_attention`` exactly as
+    the dense layout would, n_kv_heads wide."""
     bt = jnp.asarray(block_tables, jnp.int32)
     b = bt.shape[0]
     ps = cache[0].shape[1]
@@ -258,6 +258,35 @@ def gather_paged_view(cache, block_tables: jnp.ndarray, dtype):
         k_all = k_pool[bt].reshape(b, L, kvh, hd)
         v_all = v_pool[bt].reshape(b, L, kvh, hd)
     return k_all, v_all, pos_pool[bt].reshape(b, L)
+
+
+def grouped_query_attention(q: jnp.ndarray, k_all: jnp.ndarray,
+                            v_all: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+    """Masked softmax attention of ``q`` [b, s, n_heads, hd] over K/V
+    [b, L, n_kv_heads, hd] as they are stored — the KV heads are never
+    expanded to n_heads. ``mask``: [b, s, L] bool. Returns [b, s, n_heads, hd].
+
+    The query heads fold into [n_kv_heads, rep] (head ``h = g * rep + r``
+    reads KV head ``g = h // rep``) and both contractions batch over
+    (b, n_kv_heads), so each K/V row is read once for its whole group.
+    ``rep`` comes from the shapes; ``rep == 1`` (MHA) is the same expression
+    with a size-1 axis. bf16 operands, float32 logits and softmax; masked
+    positions get ``finfo.min`` and contribute exact zeros.
+
+    The ONE copy of the chain: ``Attention`` (every cache layout but the
+    ring) and ops/paged_attention.py's ``paged_attention_ref`` both call
+    it, so the kernel's oracle cannot drift from what serves."""
+    b, s, n_heads, hd = q.shape
+    kvh = k_all.shape[2]
+    dt = q.dtype
+    qg = q.reshape(b, s, kvh, n_heads // kvh, hd)
+    logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_all.astype(dt)) * hd**-0.5
+    logits = logits.astype(jnp.float32)
+    logits = jnp.where(mask[:, None, None, :, :], logits,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(dt)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_all.astype(dt))
+    return out.reshape(b, s, n_heads, hd)
 
 
 def lora_delta(x: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
@@ -310,8 +339,9 @@ class Attention(nn.Module):
         [b, max_len]) shared by all sequences. Each token writes at the pool
         coordinate its block table maps its position to, and attention reads
         gather the per-sequence logical view back through the table — the
-        gathered view feeds the IDENTICAL masked einsum as the dense path,
-        so paged and dense decode are bit-exact (tests/test_paged_kv.py).
+        gathered view feeds the IDENTICAL chain (grouped_query_attention)
+        as the dense path, so paged and dense decode are bit-exact
+        (tests/test_paged_kv.py).
         cache_index is ignored (positions alone address the pool).
         Without a cache: full causal attention, returns (out, (k, v))."""
         cfg = self.cfg
@@ -378,9 +408,10 @@ class Attention(nn.Module):
                     positions.astype(pos_pool.dtype))
                 new_cache = (k_pool, v_pool, pos_pool)
             # The read on every backend, the TPU included: gather the
-            # logical view and fall through to the SAME masked einsum the
-            # dense layout uses — paged == dense bit-for-bit (masked
-            # positions contribute exact zeros). The Pallas page-streaming
+            # logical view and fall through to the SAME chain the dense
+            # layout uses (grouped_query_attention) — paged == dense
+            # bit-for-bit (masked positions contribute exact zeros). The
+            # view keeps the pool's n_kv_heads. The Pallas page-streaming
             # kernel (ops/paged_attention.py) does not lower for a TPU and
             # is not reachable from here.
             k_all, v_all, pos_view = gather_paged_view(new_cache, bt, dt)
@@ -475,17 +506,8 @@ class Attention(nn.Module):
                 q, k_all.astype(dt), v_all.astype(dt), positions, positions, mesh=cfg.mesh
             )
         else:
-            # GQA: repeat kv heads up to n_heads for the dense einsum
-            if cfg.n_kv_heads != cfg.n_heads:
-                rep = cfg.n_heads // cfg.n_kv_heads
-                k_all = jnp.repeat(k_all, rep, axis=2)
-                v_all = jnp.repeat(v_all, rep, axis=2)
-            scale = hd**-0.5
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_all.astype(dt)) * scale
-            logits = logits.astype(jnp.float32)
-            logits = jnp.where(mask[:, None, :, :], logits, jnp.finfo(jnp.float32).min)
-            probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-            out = jnp.einsum("bhqk,bkhd->bqhd", probs, v_all.astype(dt))
+            # every cache layout ends here: K/V stay n_kv_heads wide
+            out = grouped_query_attention(q, k_all, v_all, mask)
         out = out.reshape(b, s, cfg.n_heads * hd)
         proj = out @ wo.astype(dt)
         if adapters is not None:

@@ -28,8 +28,9 @@ Numerics: masking uses the pooled position rows exactly like the dense path
 the online-softmax accumulation runs in f32. The kernel is NOT bit-identical
 to the XLA einsum (different reduction order); the bit-exactness contract of
 paged-vs-dense serving (tests/test_paged_kv.py) is carried by the gather
-read, which IS the dense einsum on gathered bytes. Kernel parity tests
-run interpret-mode under the ``pallas`` marker with tolerances.
+read, which IS the dense chain (``grouped_query_attention``) on gathered
+bytes. Kernel parity tests run interpret-mode under the ``pallas`` marker
+with tolerances.
 """
 
 from __future__ import annotations
@@ -39,32 +40,20 @@ import functools
 
 def paged_attention_ref(q, cache, block_tables, positions):
     """Pure-XLA reference: gather the logical view through the block table
-    (models/transformer.py ``gather_paged_view`` — the SAME gather
-    serving uses, so the two can't drift) and run the dense
-    masked-softmax einsum chain (identical op order to serving's shared
-    einsum). q: [b, 1, h, hd]; cache: the paged 3-tuple
-    (bf16) or 5-tuple (int8) pool; block_tables: [b, n_pages];
+    (models/transformer.py ``gather_paged_view``) and run serving's own
+    masked-softmax chain on it (``grouped_query_attention``, K/V kept
+    n_kv_heads wide) — the SAME two functions serving calls, so the
+    oracle cannot drift from what serves. q: [b, 1, h, hd]; cache: the paged
+    3-tuple (bf16) or 5-tuple (int8) pool; block_tables: [b, n_pages];
     positions: [b, 1]. Returns [b, 1, h, hd] in q.dtype."""
-    import jax
-    import jax.numpy as jnp
+    from seldon_core_tpu.models.transformer import (
+        gather_paged_view,
+        grouped_query_attention,
+    )
 
-    from seldon_core_tpu.models.transformer import gather_paged_view
-
-    b, s, h, hd = q.shape
-    dt = q.dtype
-    k_all, v_all, pos_view = gather_paged_view(cache, block_tables, dt)
-    kvh = k_all.shape[2]
+    k_all, v_all, pos_view = gather_paged_view(cache, block_tables, q.dtype)
     mask = pos_view[:, None, :] <= positions[:, :, None]  # [b, s, L]
-    if kvh != h:
-        rep = h // kvh
-        k_all = jnp.repeat(k_all, rep, axis=2)
-        v_all = jnp.repeat(v_all, rep, axis=2)
-    scale = hd**-0.5
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_all.astype(dt)) * scale
-    logits = logits.astype(jnp.float32)
-    logits = jnp.where(mask[:, None, :, :], logits, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all.astype(dt))
+    return grouped_query_attention(q, k_all, v_all, mask)
 
 
 def _kernel(quantized: bool, n_pages: int, scale: float,

@@ -207,6 +207,91 @@ def test_dtype_widened_output_fires():
 
 
 # ---------------------------------------------------------------------------
+# shape: grouped-query attention never materializes K/V n_heads wide
+# ---------------------------------------------------------------------------
+
+def _result_sizes(text):
+    """Element counts of every array type in StableHLO (``tensor<3x40x8xbf16>``)
+    or HLO (``bf16[3,40,8]``) text."""
+    import re
+
+    sizes = set()
+    for dims in re.findall(r"tensor<((?:\d+x)+)\w+>", text):
+        sizes.add(int(np.prod([int(d) for d in dims.split("x") if d])))
+    for dims in re.findall(r"\b[a-z]+\d+\[([\d,]+)\]", text):
+        sizes.add(int(np.prod([int(d) for d in dims.split(",")])))
+    return sizes
+
+
+def _lowered_and_compiled(fn, args):
+    lowered = fn.lower(*args)
+    return lowered.as_text(), lowered.compile().as_text()
+
+
+# slots x cache length x n_heads x head_dim of the rep = 4 config below: a
+# size no weight, pool, view or logits tensor of that config shares
+GQA_SLOTS, GQA_PAGES, GQA_PAGE = 3, 5, 8
+GQA_HEADS, GQA_KV_HEADS, GQA_HEAD_DIM = 8, 2, 8
+GQA_EXPANDED = GQA_SLOTS * GQA_PAGES * GQA_PAGE * GQA_HEADS * GQA_HEAD_DIM
+
+
+def test_expanded_kv_scan_sees_a_repeat():
+    """Mutation: the pre-PR-24 chain (``jnp.repeat`` up to n_heads, then the
+    n_heads einsum) — the scan the next test relies on must see its
+    [slots, L, n_heads, hd] tensor in both texts."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def expanded(q, k):
+        k = jnp.repeat(k, GQA_HEADS // GQA_KV_HEADS, axis=2)
+        return jnp.einsum("bqhd,bkhd->bhqk", q, k)
+
+    L = GQA_PAGES * GQA_PAGE
+    texts = _lowered_and_compiled(expanded, (
+        _sds((GQA_SLOTS, 1, GQA_HEADS, GQA_HEAD_DIM), "bfloat16"),
+        _sds((GQA_SLOTS, L, GQA_KV_HEADS, GQA_HEAD_DIM), "bfloat16")))
+    assert GQA_EXPANDED in _result_sizes(texts[0])
+    assert GQA_EXPANDED in _result_sizes(texts[1])
+
+
+def test_paged_decode_step_holds_no_expanded_kv():
+    """The paged decode step of a rep = 4 config (Mistral-7B's ratio): no
+    instruction, lowered or compiled, has slots x L x n_heads x hd elements
+    — K/V stay n_kv_heads wide from the gather through both contractions
+    (models/transformer.py ``grouped_query_attention``). On the chip the
+    expanded copy was 54 ms of a 71 ms step (PERF.md, PR 24)."""
+    import jax
+
+    from seldon_core_tpu.models.transformer import (
+        RESERVED_PAGES,
+        init_paged_kv_caches,
+    )
+    from seldon_core_tpu.servers.llmserver import LLMServer
+
+    s = LLMServer(
+        model="transformer",
+        model_kwargs=dict(vocab_size=96, dim=GQA_HEADS * GQA_HEAD_DIM,
+                          n_layers=2, n_heads=GQA_HEADS,
+                          n_kv_heads=GQA_KV_HEADS, ffn_dim=160,
+                          max_seq_len=GQA_PAGES * GQA_PAGE, dtype="bfloat16"),
+        init_random=True, len_buckets=(16,), seed=3)
+    s.load()
+    pools = jax.eval_shape(lambda: init_paged_kv_caches(
+        s._cfg, RESERVED_PAGES + GQA_SLOTS * GQA_PAGES, GQA_PAGE, "bf16"))
+    fn = s._get_decode_step_paged(GQA_SLOTS, GQA_PAGES, 1)
+    lowered, compiled = _lowered_and_compiled(fn, (
+        s._params, pools, _sds((GQA_SLOTS,), "int32"),
+        _sds((GQA_SLOTS,), "int32"), _sds((GQA_SLOTS, 2), "uint32"),
+        _sds((), "float32"), _sds((GQA_SLOTS, GQA_PAGES), "int32")))
+    view = GQA_EXPANDED // (GQA_HEADS // GQA_KV_HEADS)
+    for text in (lowered, compiled):
+        sizes = _result_sizes(text)
+        assert view in sizes          # the gathered view is there, unexpanded
+        assert GQA_EXPANDED not in sizes
+
+
+# ---------------------------------------------------------------------------
 # collective: exact count-per-kind budget
 # ---------------------------------------------------------------------------
 
