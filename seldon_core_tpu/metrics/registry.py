@@ -374,6 +374,32 @@ class MetricsRegistry:
             base,
             registry=self.registry,
         )
+        # An MoE model's routing (runtime/batcher.py MoECounters,
+        # docs/observability.md "Expert routing"): counted on the loop from
+        # arrays that leave the step programs beside their tokens, absent
+        # for a dense model. By program kind (decode step, prefill chunk)
+        # what the device did; by expert what was delivered.
+        moe_help = {
+            "calls": "Step-program calls of an MoE model",
+            "live_rows": "Rows of those calls that were tokens (not a dead "
+                         "slot, not a chunk's padding)",
+            "routed_pairs": "(token, expert) pairs routed, over all layers",
+            "experts_touched": "Distinct experts with at least one row, "
+                               "summed over layer-calls",
+            "max_group": "Rows of the largest expert group, summed over "
+                         "layer-calls",
+            "layer_calls": "MoE layer executions (calls x layers)",
+        }
+        self._moe = {
+            field: Counter(f"seldon_llm_moe_{field}_total", text,
+                           base + ["program"], registry=self.registry)
+            for field, text in moe_help.items()}
+        self._moe_expert_tokens = Counter(
+            "seldon_llm_moe_expert_tokens_total",
+            "Delivered tokens routed to each expert, summed over layers",
+            base + ["expert"],
+            registry=self.registry,
+        )
         self._queue_wait = Histogram(
             "seldon_llm_queue_wait_seconds",
             "Time from request submission to its slot reservation "
@@ -937,6 +963,15 @@ class MetricsRegistry:
         for phase, n in stats.get("loop_phase_counts", {}).items():
             self._counter_catch_up(self._loop_phase, n, phase=phase)
         self._counter_catch_up(self._loop_turns, stats.get("loop_turns", 0))
+        for program, tally in stats.get("moe_by_program", {}).items():
+            for field, n in tally.items():
+                self._counter_catch_up(self._moe[field], n, program=program)
+            self._counter_catch_up(
+                self._moe["layer_calls"], tally["calls"] * stats["moe_layers"],
+                program=program)
+        for expert, n in enumerate(stats.get("moe_expert_tokens", ())):
+            self._counter_catch_up(self._moe_expert_tokens, n,
+                                   expert=str(expert))
         self._counter_catch_up(self._slot_seconds,
                                stats.get("slot_seconds", 0.0))
         self._slots_active.labels(**self._base()).set(

@@ -55,6 +55,21 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
         )
     if getattr(hf_config, "attention_bias", False) or getattr(hf_config, "mlp_bias", False):
         raise ValueError("attention/mlp biases are not supported by the native transformer")
+    moe = {}
+    if getattr(hf_config, "model_type", "") == "olmoe":
+        if getattr(hf_config, "clip_qkv", None) is not None:
+            raise ValueError(
+                f"clip_qkv={hf_config.clip_qkv} is not supported by the native "
+                "transformer (it has no clamp on q/k/v); converting would "
+                "silently diverge from HF wherever a projection passes the clip")
+        # intermediate_size is ONE expert's width; the q/k RMSNorms span the
+        # whole projection; the router is softmax over all experts, then top-k
+        moe = {
+            "n_experts": hf_config.num_experts,
+            "n_experts_per_token": hf_config.num_experts_per_tok,
+            "router_renormalize": bool(hf_config.norm_topk_prob),
+            "qk_norm": True,
+        }
     return {
         "vocab_size": hf_config.vocab_size,
         "dim": hf_config.hidden_size,
@@ -68,6 +83,7 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
         "norm_eps": hf_config.rms_norm_eps,
         "tie_embeddings": bool(getattr(hf_config, "tie_word_embeddings", False)),
         **({"rope_scaling": rope_scaling} if rope_scaling else {}),
+        **moe,
     }
 
 
@@ -87,12 +103,15 @@ def convert_llama_state_dict(
     n_layers: int,
     dtype: str = "float32",
     tie_embeddings: bool = False,
+    n_experts: int = 0,
 ) -> Dict[str, Any]:
-    """HF Llama state dict -> our flax param tree ({"params": ...}).
-    ``tie_embeddings`` must mirror the HF config: tied checkpoints still
-    carry an lm_head entry in state_dict(), but exporting it would add a
-    vocab*dim param the module doesn't define (breaking sharding-spec
-    alignment for tensor parallelism)."""
+    """HF Llama (or, with ``n_experts``, OLMoE) state dict -> our flax param
+    tree ({"params": ...}). ``tie_embeddings`` must mirror the HF config:
+    tied checkpoints still carry an lm_head entry in state_dict(), but
+    exporting it would add a vocab*dim param the module doesn't define
+    (breaking sharding-spec alignment for tensor parallelism). OLMoE's
+    per-expert matrices stack into [e, d, f] leaves, and its q/k norms and
+    router join the layer."""
     np_dtype = _np_dtype(dtype)
     consumed = set()
 
@@ -109,21 +128,32 @@ def convert_llama_state_dict(
     }
     for i in range(n_layers):
         hf = f"model.layers.{i}"
-        params[f"layer_{i}"] = {
+        layer = params[f"layer_{i}"] = {
             "attention": {
                 "wq": t(f"{hf}.self_attn.q_proj.weight").T,
                 "wk": t(f"{hf}.self_attn.k_proj.weight").T,
                 "wv": t(f"{hf}.self_attn.v_proj.weight").T,
                 "wo": t(f"{hf}.self_attn.o_proj.weight").T,
             },
-            "ffn": {
-                "w1": t(f"{hf}.mlp.gate_proj.weight").T,
-                "w2": t(f"{hf}.mlp.down_proj.weight").T,
-                "w3": t(f"{hf}.mlp.up_proj.weight").T,
-            },
             "attention_norm": {"weight": t(f"{hf}.input_layernorm.weight")},
             "ffn_norm": {"weight": t(f"{hf}.post_attention_layernorm.weight")},
         }
+        if n_experts:
+            for ours in ("q_norm", "k_norm"):
+                layer["attention"][ours] = {
+                    "weight": t(f"{hf}.self_attn.{ours}.weight")}
+            layer["moe"] = {"router": t(f"{hf}.mlp.gate.weight").T}
+            for ours, theirs in (("w1", "gate_proj"), ("w2", "down_proj"),
+                                 ("w3", "up_proj")):
+                layer["moe"][ours] = np.stack([
+                    t(f"{hf}.mlp.experts.{e}.{theirs}.weight").T
+                    for e in range(n_experts)])
+        else:
+            layer["ffn"] = {
+                "w1": t(f"{hf}.mlp.gate_proj.weight").T,
+                "w2": t(f"{hf}.mlp.down_proj.weight").T,
+                "w3": t(f"{hf}.mlp.up_proj.weight").T,
+            }
     if not tie_embeddings and "lm_head.weight" in state_dict:
         params["lm_head"] = t("lm_head.weight").T  # [dim, vocab]
 
@@ -142,13 +172,15 @@ def convert_llama_state_dict(
 
 
 def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
-    """In-memory transformers LlamaForCausalLM -> (our module, variables)."""
+    """In-memory transformers LlamaForCausalLM or OlmoeForCausalLM ->
+    (our module, variables)."""
     from seldon_core_tpu.models import get_model
 
     kwargs = config_kwargs_from_hf(hf_model.config)
     variables = convert_llama_state_dict(
         hf_model.state_dict(), n_layers=kwargs["n_layers"],
         tie_embeddings=kwargs["tie_embeddings"],
+        n_experts=kwargs.get("n_experts", 0),
     )
     module = get_model("transformer", dtype="float32", **kwargs)
     return module, variables
@@ -170,6 +202,7 @@ def convert_checkpoint(hf_path: str, out_dir: str, dtype: str = "bfloat16") -> s
     variables = convert_llama_state_dict(
         model.state_dict(), n_layers=kwargs["n_layers"], dtype=dtype,
         tie_embeddings=kwargs["tie_embeddings"],
+        n_experts=kwargs.get("n_experts", 0),
     )
 
     from seldon_core_tpu.servers.jaxserver import export_checkpoint

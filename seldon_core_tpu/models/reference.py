@@ -1,0 +1,148 @@
+"""Plain reference forward pass of the decoder family models/transformer.py
+serves: what the served path is compared with (tests/test_reference.py at a
+small size on the CPU; perf/reference/ at published widths beside the chip).
+
+Straightforward ``jax.numpy`` in float32 under
+``jax.default_matmul_precision("highest")``: one sequence, no cache, no
+batching, no kernels, the experts as a loop with a mask. It shares the
+parameter tree's NAMES with models/transformer.py and no code: a fault there
+cannot repeat itself here. The equations are OLMoE's
+(``transformers/models/olmoe/modeling_olmoe.py``; tests/test_reference.py holds
+this file to ``OlmoeForCausalLM`` on converted weights), of which Llama and
+Mistral are the case without experts and without QK-norm:
+
+    n1  = RMSNorm(x)
+    q   = RoPE(heads(RMSNorm_q(Wq n1)))     RMSNorm_q / RMSNorm_k (cfg.qk_norm)
+    k   = RoPE(heads(RMSNorm_k(Wk n1)))     span the whole projection, not a head
+    h   = x + Wo Attn(q, k, heads(Wv n1))   causal; a KV head serves its group
+    n2  = RMSNorm(h)
+    p   = softmax(Wr n2) over ALL experts, in float32
+    out = h + sum_{e in top-k(p)} p_e W2_e(silu(W1_e n2) * W3_e n2)
+          (the k weights divided by their sum only if cfg.router_renormalize)
+
+One departure, stated because it is part of what is compared: a leaf that is
+int8 in the tree (ops/quantize.py, the configuration's stated weight precision)
+is used at its int8-rounded value, dequantized in float32. The reference then
+answers "what do THESE weights give in exact arithmetic", and the served
+path's distance from it is its activation arithmetic alone.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+
+
+def _f32(leaf, index: Optional[int] = None):
+    """A leaf (or matrix ``index`` of a stack) in float32; int8 leaves
+    dequantized with their own scales."""
+    if hasattr(leaf, "q"):
+        q, scale = leaf.q, leaf.scale
+        if index is not None:
+            q, scale = q[index], (scale[index] if scale.ndim == 2 else scale)
+        elif scale.ndim == 2:
+            scale = scale[:, None, :]
+        return jnp.asarray(q, jnp.float32) * jnp.asarray(scale, jnp.float32)
+    return jnp.asarray(leaf if index is None else leaf[index], jnp.float32)
+
+
+def _rms_norm(x, weight, eps: float):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * _f32(weight)
+
+
+def _rope(x, theta: float):
+    """x [s, heads, hd] at positions 0..s-1; the halves rotate as pairs."""
+    s, _, hd = x.shape
+    inv_freq = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    lo, hi = x[..., : hd // 2], x[..., hd // 2:]
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], axis=-1)
+
+
+def _attention(p: dict, x, cfg):
+    s = x.shape[0]
+    hd = cfg.dim // cfg.n_heads
+    q, k, v = x @ _f32(p["wq"]), x @ _f32(p["wk"]), x @ _f32(p["wv"])
+    if cfg.qk_norm:
+        q = _rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
+        k = _rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
+    q = _rope(q.reshape(s, cfg.n_heads, hd), cfg.rope_theta)
+    k = _rope(k.reshape(s, cfg.n_kv_heads, hd), cfg.rope_theta)
+    v = v.reshape(s, cfg.n_kv_heads, hd)
+    group = cfg.n_heads // cfg.n_kv_heads
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    scores = jnp.einsum("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.float32(hd))
+    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    probs = jax.nn.softmax(jnp.where(causal[None], scores, -jnp.inf), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", probs, v).reshape(s, cfg.n_heads * hd) @ _f32(p["wo"])
+
+
+def _swiglu(x, w1, w2, w3):
+    return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
+
+
+def _experts(p: dict, x, cfg, leave_out_rank: Optional[int]):
+    """The expert FFN of one layer, and what the router chose: ``experts``
+    [s, k] best first, their ``weights`` [s, k], and ``margin`` [s], by how
+    much the last chosen probability beats the best one not chosen (a tie
+    the served path's bf16 activations may break the other way)."""
+    n, k = cfg.n_experts, min(cfg.n_experts_per_token, cfg.n_experts)
+    probs = jax.nn.softmax(x @ _f32(p["router"]), axis=-1)
+    ranked_p, ranked = jax.lax.top_k(probs, min(k + 1, n))
+    experts, weights = ranked[:, :k], ranked_p[:, :k]
+    margin = weights[:, -1] - ranked_p[:, k] if k < n else jnp.full(x.shape[:1], jnp.inf)
+    if cfg.router_renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    used = weights if leave_out_rank is None else weights.at[:, leave_out_rank].set(0.0)
+    out = jnp.zeros_like(x)
+    for e in range(n):
+        share = jnp.sum(jnp.where(experts == e, used, 0.0), axis=-1)  # [s]; 0 = not chosen
+        if bool(jnp.any(share > 0)):
+            out = out + share[:, None] * _swiglu(
+                x, _f32(p["w1"], e), _f32(p["w2"], e), _f32(p["w3"], e))
+    return out, {"experts": experts, "weights": weights, "margin": margin}
+
+
+def forward(params: Any, cfg: Any, tokens, leave_out_rank: Optional[int] = None):
+    """``tokens`` [s] -> (logits [s, vocab] float32, routing): ``routing`` has
+    one entry per layer (``_experts``), and is empty for a dense model.
+
+    ``params`` is the tree models/transformer.py's Transformer takes (with or
+    without the outer "params" key); ``cfg`` is anything with its fields (a
+    TransformerConfig will do). ``leave_out_rank`` computes a WRONG model, for
+    showing that a tolerance is tight: every token loses its rank-th expert."""
+    if getattr(cfg, "rope_scaling", None):
+        raise NotImplementedError("the reference has no scaled RoPE")
+    p = params.get("params", params)
+    routing = []
+    with jax.default_matmul_precision("highest"):
+        x = _f32(p["tok_embeddings"])[jnp.asarray(tokens, jnp.int32)]
+        for i in range(cfg.n_layers):
+            layer = p[f"layer_{i}"]
+            x = x + _attention(
+                layer["attention"],
+                _rms_norm(x, layer["attention_norm"]["weight"], cfg.norm_eps), cfg)
+            n2 = _rms_norm(x, layer["ffn_norm"]["weight"], cfg.norm_eps)
+            if cfg.n_experts > 0:
+                out, chose = _experts(layer["moe"], n2, cfg, leave_out_rank)
+                routing.append(chose)
+            else:
+                f = layer["ffn"]
+                out = _swiglu(n2, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
+            x = x + out
+        x = _rms_norm(x, p["norm"]["weight"], cfg.norm_eps)
+        head = _f32(p["tok_embeddings"]).T if cfg.tie_embeddings else _f32(p["lm_head"])
+        return x @ head, routing
+
+
+def expert_token_counts(routing: list, n_experts: int, rows=slice(None)):
+    """[n_experts] tokens routed to each expert over all layers, for the
+    positions ``rows`` selects: what the served path's counters must equal."""
+    counts = jnp.zeros((n_experts,), jnp.int32)
+    for layer in routing:
+        counts = counts + jnp.bincount(
+            layer["experts"][rows].reshape(-1), length=n_experts).astype(jnp.int32)
+    return counts

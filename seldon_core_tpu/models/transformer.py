@@ -9,7 +9,8 @@ TPU-first:
 - GQA attention, rotary embeddings, RMSNorm, SwiGLU — bfloat16 on the MXU.
 - decode path uses a static-shape KV cache (scatter at position index), so
   jit compiles one program per bucketed cache length.
-- optional mixture-of-experts FFN (expert-parallel 'expert' axis) for EP.
+- optional mixture-of-experts FFN: sparse (each token computes its top-k
+  experts only, a grouped matmul over the routed rows sorted by expert).
 
 No reference counterpart: the reference (a serving platform) has no model code
 at all; this is the native model family the TPU build adds (SURVEY.md §5
@@ -125,9 +126,17 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16
     # Llama-2 uses an untied lm_head; tie only for small/test configs.
     tie_embeddings: bool = False
-    # MoE: 0 = dense FFN; otherwise number of experts with top-2 routing.
+    # MoE: 0 = dense FFN; otherwise the number of experts, of which each
+    # token takes its n_experts_per_token best (softmax over ALL experts,
+    # then top-k). router_renormalize divides the k weights by their sum
+    # (Mixtral; the same as a softmax over the top-k logits); OLMoE leaves
+    # them as they are (norm_topk_prob false: they sum to < 1).
     n_experts: int = 0
     n_experts_per_token: int = 2
+    router_renormalize: bool = True
+    # RMSNorm on the q and k projections, over the WHOLE projection before
+    # the split into heads (OLMoE), not per head.
+    qk_norm: bool = False
     # "full" = dense attention (GSPMD gathers KV when seq-sharded);
     # "ring" = sequence-parallel ring attention over mesh axis 'seq'
     # (ops.ring_attention) for long-context cache-less forward/training.
@@ -199,12 +208,13 @@ def apply_rotary(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndar
 class RMSNorm(nn.Module):
     dim: int
     eps: float = 1e-5
+    axis: str = "embed"
 
     @nn.compact
     def __call__(self, x=None):
         """x=None returns the bare weight (same param path, so fused callers
         share checkpoints with the unfused graph)."""
-        w = param_with_axes("weight", nn.initializers.ones_init(), (self.dim,), jnp.float32, axes=("embed",))
+        w = param_with_axes("weight", nn.initializers.ones_init(), (self.dim,), jnp.float32, axes=(self.axis,))
         if x is None:
             return w
         return rms_norm(x, w, self.eps)
@@ -374,8 +384,12 @@ class Attention(nn.Module):
             # the paged pool/prefix machinery stays tenant-agnostic
             q_flat = q_flat + lora_delta(x, *adapters["wq"], adapter_ids,
                                          adapters["scale"])
+        k_flat = x @ wk.astype(dt)
+        if cfg.qk_norm:
+            q_flat = RMSNorm(cfg.n_heads * hd, cfg.norm_eps, "heads", name="q_norm")(q_flat)
+            k_flat = RMSNorm(cfg.n_kv_heads * hd, cfg.norm_eps, "kv_heads", name="k_norm")(k_flat)
         q = q_flat.reshape(b, s, cfg.n_heads, hd)
-        k = (x @ wk.astype(dt)).reshape(b, s, cfg.n_kv_heads, hd)
+        k = k_flat.reshape(b, s, cfg.n_kv_heads, hd)
         v = (x @ wv.astype(dt)).reshape(b, s, cfg.n_kv_heads, hd)
 
         cos, sin = rotary_embedding(positions, hd, cfg.rope_theta, cfg.rope_scaling)
@@ -546,42 +560,104 @@ class DenseFFN(nn.Module):
 
 
 class MoEFFN(nn.Module):
-    """Top-k token-choice MoE with an 'expert' partition axis (EP). Dense
-    einsum formulation — every expert computes every token, weighted by the
-    router — which is XLA-friendly at small expert counts and shards cleanly
-    over the expert axis."""
+    """Top-k token-choice MoE, sparse: each token computes its k experts and
+    no other. The (token, expert) pairs are sorted by expert and the three
+    projections are grouped matmuls over the sorted rows
+    (``jax.lax.ragged_dot``), so an expert's weights are read once per call,
+    and only if a row chose it.
+
+    ``valid`` ([b, s] bool) marks the rows that are live tokens. The step
+    programs have static shapes: a decode step computes every slot and a
+    chunk is padded to its program's length, and a row that is no token must
+    not choose experts (it would make their weights be read, and count as
+    load). Such rows join no group and come out as zeros.
+
+    An int8 stack (ops/quantize.py, per-expert scales [e, f]) goes into the
+    grouped matmul as int8, and its scale multiplies the product, where it
+    commutes: no floating copy of a stack exists.
+
+    When the "moe" collection is mutable the layer sows ``tokens`` [b, e]
+    int32: how many tokens of each sequence went to each expert
+    (``moe_routing_stats`` reduces them over the layers)."""
 
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, valid: Optional[jnp.ndarray] = None):
+        from seldon_core_tpu.ops.quantize import QuantizedTensor
+
         cfg = self.cfg
         e = cfg.n_experts
         dt = cfg.dtype
         router = param_with_axes("router", nn.initializers.lecun_normal(), (cfg.dim, e), jnp.float32,
                                  axes=("embed", "expert"))
-        w1 = param_with_axes("w1", nn.initializers.lecun_normal(), (e, cfg.dim, cfg.ffn_dim), jnp.float32,
+        # a stack of e matrices: the fan-in is one matrix's, not the stack's
+        stack_init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w1 = param_with_axes("w1", stack_init, (e, cfg.dim, cfg.ffn_dim), jnp.float32,
                              axes=("expert", "embed", "mlp"))
-        w2 = param_with_axes("w2", nn.initializers.lecun_normal(), (e, cfg.ffn_dim, cfg.dim), jnp.float32,
+        w2 = param_with_axes("w2", stack_init, (e, cfg.ffn_dim, cfg.dim), jnp.float32,
                              axes=("expert", "mlp", "embed"))
-        w3 = param_with_axes("w3", nn.initializers.lecun_normal(), (e, cfg.dim, cfg.ffn_dim), jnp.float32,
+        w3 = param_with_axes("w3", stack_init, (e, cfg.dim, cfg.ffn_dim), jnp.float32,
                              axes=("expert", "embed", "mlp"))
-
-        gate_logits = (x.astype(jnp.float32) @ router)  # [b, s, e]
+        b, s, d = x.shape
+        t = b * s
         k = min(cfg.n_experts_per_token, e)
-        topv, topi = jax.lax.top_k(gate_logits, k)
-        gates = jax.nn.softmax(topv, axis=-1)  # [b, s, k]
-        # dense weights [b, s, e]: scatter top-k gates
-        dense_gates = jnp.zeros_like(gate_logits).at[
-            jnp.arange(x.shape[0])[:, None, None],
-            jnp.arange(x.shape[1])[None, :, None],
-            topi,
-        ].set(gates)
-        h = jax.nn.silu(jnp.einsum("bsd,edf->bsef", x, w1.astype(dt))) * jnp.einsum(
-            "bsd,edf->bsef", x, w3.astype(dt)
-        )
-        y = jnp.einsum("bsef,efd->bsed", h, w2.astype(dt))
-        return jnp.einsum("bsed,bse->bsd", y, dense_gates.astype(dt))
+        xf = x.reshape(t, d)
+
+        with jax.named_scope("moe.route"):
+            probs = jax.nn.softmax(xf.astype(jnp.float32) @ router, axis=-1)
+            gates, chosen = jax.lax.top_k(probs, k)  # [t, k]
+            if cfg.router_renormalize:
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            if valid is not None:
+                # group e does not exist: its rows sort behind every expert's
+                chosen = jnp.where(valid.reshape(t, 1), chosen, e)
+            pair_expert = chosen.reshape(t * k)
+            order = jnp.argsort(pair_expert)
+            row_expert = pair_expert[order]
+            by_token = jnp.sum(
+                chosen[:, :, None] == jnp.arange(e, dtype=chosen.dtype), axis=1,
+                dtype=jnp.int32)  # [t, e]
+            group_sizes = jnp.sum(by_token, axis=0)
+            if self.is_mutable_collection("moe") and not self.is_initializing():
+                self.sow("moe", "tokens", jnp.sum(by_token.reshape(b, s, e), axis=1))
+
+        with jax.named_scope("moe.experts"):
+            row_scale = jnp.minimum(row_expert, e - 1)
+
+            def grouped(lhs, w):
+                if isinstance(w, QuantizedTensor):
+                    out = jax.lax.ragged_dot(lhs, w.q, group_sizes,
+                                             preferred_element_type=jnp.float32)
+                    return out * w.scale[row_scale]
+                return jax.lax.ragged_dot(lhs, w.astype(lhs.dtype), group_sizes,
+                                          preferred_element_type=jnp.float32)
+
+            rows = xf[order // k].astype(dt)  # [t*k, d], sorted by expert
+            h = jax.nn.silu(grouped(rows, w1)) * grouped(rows, w3)
+            y = grouped(h.astype(dt), w2)
+            # what lies behind the last group is not the matmul's to define
+            y = jnp.where((row_expert < e)[:, None], y, 0.0)
+            y = y[jnp.argsort(order)].reshape(t, k, d)
+            out = jnp.einsum("tkd,tk->td", y, gates)
+        return out.reshape(b, s, d).astype(x.dtype)
+
+
+def moe_routing_stats(sown: dict, cfg: TransformerConfig):
+    """Reduce what the MoE layers of one forward sowed (the "moe" collection
+    of ``Transformer.apply(..., mutable=["moe"])``) to ``(tokens, stats)``:
+    ``tokens`` [b, e] int32, tokens of each sequence routed to each expert,
+    summed over layers; ``stats`` [4] int32 = live rows of the call, routed
+    (token, expert) pairs, distinct experts touched and the largest expert
+    group, the last three summed over the ``n_layers`` layer-calls."""
+    per_layer = jnp.stack([sown[f"layer_{i}"]["moe"]["tokens"][0]
+                           for i in range(cfg.n_layers)])  # [L, b, e]
+    groups = jnp.sum(per_layer, axis=1)  # [L, e]
+    k = min(cfg.n_experts_per_token, cfg.n_experts)
+    stats = jnp.stack([
+        jnp.sum(per_layer[0]) // k, jnp.sum(groups),
+        jnp.sum(groups > 0, dtype=jnp.int32), jnp.sum(jnp.max(groups, axis=1))])
+    return jnp.sum(per_layer, axis=0), stats.astype(jnp.int32)
 
 
 class TransformerBlock(nn.Module):
@@ -589,12 +665,14 @@ class TransformerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, cache=None, cache_index=None,
-                 block_tables=None, adapters=None, adapter_ids=None):
+                 block_tables=None, adapters=None, adapter_ids=None,
+                 valid=None):
         cfg = self.cfg
-        h, new_cache = Attention(cfg, name="attention")(
-            RMSNorm(cfg.dim, cfg.norm_eps, name="attention_norm")(x), positions, cache, cache_index,
-            block_tables, adapters, adapter_ids,
-        )
+        with jax.named_scope("attn"):
+            h, new_cache = Attention(cfg, name="attention")(
+                RMSNorm(cfg.dim, cfg.norm_eps, name="attention_norm")(x), positions, cache, cache_index,
+                block_tables, adapters, adapter_ids,
+            )
         ffn_norm = RMSNorm(cfg.dim, cfg.norm_eps, name="ffn_norm")
         if cfg.fused_norm:
             # residual-add + RMSNorm in one HBM pass (ops/fused_norm.py):
@@ -608,7 +686,7 @@ class TransformerBlock(nn.Module):
             x = x + h
             ffn_in = ffn_norm(x)
         if cfg.n_experts > 0:
-            f = MoEFFN(cfg, name="moe")(ffn_in)
+            f = MoEFFN(cfg, name="moe")(ffn_in, valid)
         else:
             f = DenseFFN(cfg, name="ffn")(ffn_in, adapters, adapter_ids)
         return x + f, new_cache
@@ -644,6 +722,13 @@ class Transformer(nn.Module):
         )
         x = emb.astype(cfg.dtype)[tokens]
         x = with_sharding_constraint(x, ("batch", "seq", "embed"))
+        valid = None
+        if cfg.n_experts > 0:
+            # rows that are tokens: not the padding of a chunk (PAD_POS), and
+            # not a slot nobody holds, whose block-table row is all TRASH_PAGE
+            valid = positions < PAD_POS
+            if block_tables is not None:
+                valid &= (jnp.asarray(block_tables)[:, :1] != TRASH_PAGE)
         new_caches = []
         for i in range(cfg.n_layers):
             layer_cache = caches[i] if caches is not None else None
@@ -657,7 +742,7 @@ class Transformer(nn.Module):
                 layer_adapters["scale"] = adapters["scale"]
             x, nc = TransformerBlock(cfg, name=f"layer_{i}")(
                 x, positions, layer_cache, cache_index, block_tables,
-                layer_adapters, adapter_ids)
+                layer_adapters, adapter_ids, valid)
             new_caches.append(nc)
         x = RMSNorm(cfg.dim, cfg.norm_eps, name="norm")(x)
         if cfg.tie_embeddings:

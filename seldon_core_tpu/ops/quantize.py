@@ -11,6 +11,13 @@ here it is a first-class transform on any checkpoint.)
 Scheme: symmetric per-output-channel int8 (scale = max|w| / 127 over all
 dims but the last). 1-D leaves (biases, norms) and integer leaves pass
 through unquantized — they are tiny and precision-critical.
+
+3-D leaves are STACKS ([e, d, f]: one matrix per expert) and keep one scale
+per matrix per channel ([e, f]): experts of different magnitude would
+otherwise share the largest one's step. A stack is also not dequantized by
+``dequantize_params(keep_stacks=True)``: its consumer (a grouped matmul) takes
+the int8 array and applies the scale to the product, so no floating copy of
+the stack is ever made.
 """
 
 from __future__ import annotations
@@ -28,7 +35,7 @@ class QuantizedTensor:
     metadata, so one compiled program per dtype)."""
 
     q: Any  # int8 [..., C]
-    scale: Any  # f32 [C]
+    scale: Any  # f32 [C]; [E, C] for a stack of matrices [E, ..., C]
     orig_dtype: str = "bfloat16"
 
     @property
@@ -38,6 +45,10 @@ class QuantizedTensor:
     @property
     def dtype(self):
         return self.q.dtype
+
+    @property
+    def stacked(self) -> bool:
+        return self.scale.ndim == 2
 
 
 def _register_pytree() -> None:
@@ -54,24 +65,28 @@ def _register_pytree() -> None:
 
 
 def quantize_array(w, bits: int = 8):
-    """Symmetric per-last-dim-channel quantization of one float array."""
+    """Symmetric per-last-dim-channel quantization of one float array; a
+    3-D array is a stack of matrices and keeps its leading axis in the scale."""
     import jax.numpy as jnp
 
     qmax = 2 ** (bits - 1) - 1
     w = jnp.asarray(w)
     orig_dtype = str(w.dtype)
-    reduce_dims = tuple(range(w.ndim - 1))
-    amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=reduce_dims)
+    stack = w.ndim == 3
+    reduce_dims = (1,) if stack else tuple(range(w.ndim - 1))
+    amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=reduce_dims, keepdims=stack)
     scale = jnp.where(amax > 0, amax / qmax, 1.0).astype(jnp.float32)
     q = jnp.clip(jnp.round(w.astype(jnp.float32) / scale), -qmax - 1, qmax).astype(jnp.int8)
-    return QuantizedTensor(q=q, scale=scale, orig_dtype=orig_dtype)
+    return QuantizedTensor(q=q, scale=scale[:, 0, :] if stack else scale,
+                           orig_dtype=orig_dtype)
 
 
 def dequantize_array(t: QuantizedTensor, dtype=None):
     import jax.numpy as jnp
 
     dtype = jnp.dtype(dtype or t.orig_dtype)
-    return t.q.astype(dtype) * t.scale.astype(dtype)
+    scale = t.scale[:, None, :] if t.stacked else t.scale
+    return t.q.astype(dtype) * scale.astype(dtype)
 
 
 def _is_quantizable(leaf) -> bool:
@@ -98,15 +113,20 @@ def quantize_params(params: Any, bits: int = 8) -> Any:
     return jax.tree.map(visit, params)
 
 
-def dequantize_params(params: Any, dtype=None) -> Any:
+def dequantize_params(params: Any, dtype=None, keep_stacks: bool = False) -> Any:
     """Inverse transform, used INSIDE the jitted forward so XLA fuses the
-    dequant into consumers (int8 stays the HBM format)."""
+    dequant into consumers (int8 stays the HBM format). ``keep_stacks`` leaves
+    stacked leaves quantized for a module that consumes them as they are
+    (models/transformer.py MoEFFN): XLA fuses a dequant into a plain dot,
+    not into a grouped matmul, where it would write the whole stack out."""
     import jax
 
     _register_pytree()
 
     def visit(leaf):
-        return dequantize_array(leaf, dtype) if isinstance(leaf, QuantizedTensor) else leaf
+        if not isinstance(leaf, QuantizedTensor) or (keep_stacks and leaf.stacked):
+            return leaf
+        return dequantize_array(leaf, dtype)
 
     return jax.tree.map(visit, params, is_leaf=lambda x: isinstance(x, QuantizedTensor))
 

@@ -63,7 +63,8 @@ def shard_params(params: Any, mesh, logical_specs: Any, rules=DEFAULT_LOGICAL_RU
     Int8-quantized leaves (ops.quantize.QuantizedTensor) shard too: the
     weight's logical spec applies to ``q`` unchanged (same shape as the
     original float leaf), and the per-output-channel ``scale`` [C] takes the
-    spec's LAST axis (the channel dim it broadcasts over) — so int8 serving
+    spec's LAST axis (the channel dim it broadcasts over; a stack's [E, C]
+    scale its first and last) — so int8 serving
     composes with tensor parallelism instead of excluding it."""
     import jax
     from flax.linen import partitioning as nn_partitioning
@@ -99,7 +100,9 @@ def shard_params(params: Any, mesh, logical_specs: Any, rules=DEFAULT_LOGICAL_RU
                 mesh_spec = list(to_mesh_spec(s))
                 wsh = NamedSharding(mesh, P(*mesh_spec))
                 last = mesh_spec[-1] if mesh_spec else None
-                ssh = NamedSharding(mesh, P(last))
+                # a stack's scale [E, C] keeps the stack axis beside the channel
+                ssh = NamedSharding(
+                    mesh, P(mesh_spec[0], last) if p.stacked else P(last))
             else:
                 wsh = ssh = replicated
             out.append(QuantizedTensor(

@@ -386,10 +386,13 @@ class _PrefillJob:
 
     __slots__ = ("slot", "ids", "L", "next", "chunk", "max_new", "fut",
                  "on_token", "info", "seed", "bt_row", "pages", "t_arrival",
-                 "req")
+                 "req", "asides")
 
     def __init__(self, slot, ids, start, chunk, max_new, fut, on_token,
                  info, seed, bt_row, pages, t_arrival=None, req=None):
+        # (aside, flight event) of each chunk dispatched so far: read with
+        # the last chunk's first-token sync, never by a sync of their own
+        self.asides: List[tuple] = []
         self.slot = slot
         self.ids = ids
         self.L = len(ids)
@@ -448,10 +451,14 @@ class _Slot:
     __slots__ = ("future", "tokens", "true_len", "n_new", "max_new", "active",
                  "on_token", "gen", "disp_new", "pages", "shared", "ids",
                  "prefilling", "admit_seq", "t_last", "tenant", "slo_class",
-                 "adapter_id")
+                 "adapter_id", "logits")
 
     def __init__(self):
         self.active = False
+        # a probe request's float32 logits, one row per generated token (the
+        # distribution it was sampled from): the request's own ``info``
+        # list, or None for every request that did not ask
+        self.logits: Optional[List[np.ndarray]] = None
         # multi-tenant identity (runtime/scheduler.py): who this occupant
         # belongs to, which SLO class its latency counts against, and the
         # LoRA adapter row every adapted step gathers for it (0=identity).
@@ -609,6 +616,46 @@ def _in_phase(name: str):
     return wrap
 
 
+MOE_PROGRAMS = ("decode", "chunk")
+
+
+class MoECounters:
+    """Routing tallies of an MoE model, single writer like LoopPhases. By
+    program kind, what the DEVICE did (a slot whose budget is spent rides
+    along in a step until the drain releases it: its row chose experts and
+    their weights were read): calls, live rows, routed (token, expert) pairs,
+    and, summed over the ``n_layers`` layer-calls of each call, the distinct
+    experts touched and the largest expert group. ``expert_tokens`` [e] is
+    what was DELIVERED: prompt tokens, and decode rows whose token was
+    credited to a request, by expert, summed over layers."""
+
+    FIELDS = ("calls", "live_rows", "routed_pairs", "experts_touched",
+              "max_group")
+
+    def __init__(self, n_experts: int, n_layers: int):
+        self.n_layers = n_layers
+        self.by_program = {kind: dict.fromkeys(self.FIELDS, 0)
+                           for kind in MOE_PROGRAMS}
+        self.expert_tokens = np.zeros((n_experts,), np.int64)
+
+    def add(self, kind: str, stats: np.ndarray) -> None:
+        """``stats`` [calls, 4]: moe_routing_stats of each call."""
+        tally = self.by_program[kind]
+        tally["calls"] += len(stats)
+        for name, total in zip(self.FIELDS[1:], stats.sum(axis=0)):
+            tally[name] += int(total)
+
+    def flight_fields(self, stats: np.ndarray) -> dict:
+        """One call's ``stats`` [4] as the fields its flight events carry."""
+        return {"moe_live": int(stats[0]),
+                "moe_touched": round(float(stats[2]) / self.n_layers, 2)}
+
+    def stats(self) -> dict:
+        return {"moe_layers": self.n_layers,
+                "moe_by_program": {k: dict(v) for k, v in self.by_program.items()},
+                "moe_expert_tokens": self.expert_tokens.tolist()}
+
+
 class _InFlight:
     """One dispatched (possibly K-fused) decode step the host has not yet
     drained: the device token array, the per-slot (index, gen) snapshot
@@ -619,10 +666,14 @@ class _InFlight:
     and ``booked`` (slot -> the pessimistic K+1 maximum the dispatch
     side credited to ``disp_new``; the drain reconciles the difference)."""
 
-    __slots__ = ("tokens", "k", "snapshot", "t_dispatch", "acc", "booked")
+    __slots__ = ("tokens", "k", "snapshot", "t_dispatch", "acc", "booked",
+                 "aside")
 
     def __init__(self, tokens, k, snapshot, t_dispatch, acc=None,
-                 booked=None):
+                 booked=None, aside=None):
+        # what left the step beside its tokens (LLMServer._get_decode_step):
+        # device arrays the drain reads only after the tokens have landed
+        self.aside = aside or {}
         self.tokens = tokens
         self.k = k
         self.snapshot = snapshot
@@ -1012,6 +1063,9 @@ class ContinuousBatcher:
         self._last_drain_t: Optional[float] = None
         # the loop's time budget (module docstring): always on
         self._phases = LoopPhases()
+        cfg = server._cfg
+        self._moe = (MoECounters(cfg.n_experts, cfg.n_layers)
+                     if cfg.n_experts > 0 else None)
         # Disaggregated prefill/decode (module docstring): remote-prefill
         # admission stages jobs on prefill-slice workers and consumes
         # finished handoffs from the TransferQueue instead of prefilling
@@ -2232,21 +2286,25 @@ class ContinuousBatcher:
         if self._adapters is not None:
             fn = self.server._get_prefill_chunk(C, self.n_pages, lora=True)
             aid = job.req.adapter_id if job.req is not None else 0
-            logits, self._caches = fn(
+            logits, self._caches, aside = fn(
                 self.server._params, self._caches, job.bt_row,
                 jnp.asarray(toks), jnp.asarray(pos), self._adapters.pool(),
                 jnp.asarray([aid], jnp.int32))
         else:
             fn = self.server._get_prefill_chunk(C, self.n_pages)
-            logits, self._caches = fn(self.server._params, self._caches,
-                                      job.bt_row, jnp.asarray(toks),
-                                      jnp.asarray(pos))
+            logits, self._caches, aside = fn(
+                self.server._params, self._caches, job.bt_row,
+                jnp.asarray(toks), jnp.asarray(pos))
         job.next = start + n
+        event = None
         if self._flight is not None:
             # dispatch wall (enqueue-only); the last chunk's logits sync
             # below lands in the gap before the first_token event
-            self._flight.record(job.slot, EV_PREFILL_CHUNK, start=start,
-                                tokens=n, dur_s=time.perf_counter() - t0)
+            event = self._flight.record(
+                job.slot, EV_PREFILL_CHUNK, start=start, tokens=n,
+                dur_s=time.perf_counter() - t0)
+        if self._moe is not None:
+            job.asides.append((aside, event))
         if job.next >= job.L:
             # the loop stands still here until the device has run every
             # step queued ahead of this chunk and the chunk itself: no new
@@ -2255,7 +2313,22 @@ class ContinuousBatcher:
                 # graftlint: allow-host-sync-in-hot-path(admission-time sync, once per request not per chunk: the LAST chunk's logits seed the first sampled token; earlier chunks were enqueue-only)
                 first_logits = np.asarray(logits[0, n - 1]).astype(np.float32)
             with self._phases.phase("first_token"):
+                self._count_chunks(job)
                 self._activate(job, first_logits)
+
+    def _count_chunks(self, job: _PrefillJob) -> None:
+        """The routing tallies of a finished admission's chunks. They ran
+        before the chunk whose logits the caller has just read, so every
+        array here is ready: no read below waits for the device."""
+        for aside, event in job.asides:
+            # graftlint: allow-host-sync-in-hot-path(no wait: these programs finished before the first-token sync the caller just made, once per request)
+            stats = np.asarray(aside["moe_stats"])
+            self._moe.add("chunk", stats[None])
+            # graftlint: allow-host-sync-in-hot-path(same: a finished chunk's [1, n_experts] tally)
+            self._moe.expert_tokens += np.asarray(aside["moe_tokens"])[0]
+            if event is not None:
+                event.update(self._moe.flight_fields(stats))
+        job.asides.clear()
 
     def _activate(self, job: _PrefillJob, first_logits: np.ndarray):
         """Paged admission, final phase: sample the first token on
@@ -2272,6 +2345,11 @@ class ContinuousBatcher:
             self._block_tables, jnp.asarray(job.slot, jnp.int32),
             job.bt_row[0])
         self._prefill = None
+        if job.info is not None and "logits" in job.info:
+            # a probe asked for logits (transport/rest.py): the prompt's last
+            # position first, then one row per decode step (_drain_one)
+            job.info["logits"].append(first_logits)
+            self._slots[job.slot].logits = job.info["logits"]
         self._commit_slot(job.slot, first, key, job.L, job.max_new, job.fut,
                           job.on_token, ids=job.ids, t_arrival=job.t_arrival,
                           req=job.req)
@@ -2513,6 +2591,7 @@ class ContinuousBatcher:
         slot.prefilling = False
         slot.future = None
         slot.on_token = None
+        slot.logits = None
         slot.ids = None
         slot.tenant = ""
         slot.slo_class = "interactive"
@@ -2727,19 +2806,21 @@ class ContinuousBatcher:
             fn = self.server._get_decode_step_paged(
                 self.S, self.n_pages, k, lora=lora)
             (self._caches, self._last_tok, self._next_pos, self._keys,
-             toks) = fn(self.server._params, self._caches, self._last_tok,
-                        self._next_pos, self._keys, self._temp,
-                        self._block_tables, *extra)
+             toks, aside) = fn(
+                self.server._params, self._caches, self._last_tok,
+                self._next_pos, self._keys, self._temp,
+                self._block_tables, *extra)
         else:
             fn = self.server._get_decode_step(self.S, self.max_len, k,
                                               lora=lora)
             (self._caches, self._last_tok, self._next_pos, self._keys,
-             toks) = fn(self.server._params, self._caches, self._last_tok,
-                        self._next_pos, self._keys, self._temp, *extra)
+             toks, aside) = fn(
+                self.server._params, self._caches, self._last_tok,
+                self._next_pos, self._keys, self._temp, *extra)
         snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
         for i, _ in snapshot:
             self._slots[i].disp_new += k
-        self._inflight.append(_InFlight(toks, k, snapshot, t0))
+        self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside))
         if len(self._inflight) > self._inflight_hwm:
             self._inflight_hwm = len(self._inflight)
         return True
@@ -2843,6 +2924,15 @@ class ContinuousBatcher:
             if rec.acc is not None:
                 # graftlint: allow-host-sync-in-hot-path(part of the same drain sync: the verify step's per-slot accepted counts land with its tokens — the program already finished for the token read above)
                 accs = np.asarray(rec.acc)  # [S] accepted counts, 1..K+1
+            moe_tokens, moe_fields = None, {}
+            if self._moe is not None and rec.acc is None:
+                # graftlint: allow-host-sync-in-hot-path(part of the same drain sync: the step's routing tallies land with its tokens — the program already finished for the token read above)
+                moe_stats = np.asarray(rec.aside["moe_stats"])    # [k, 4]
+                # graftlint: allow-host-sync-in-hot-path(same: [k, S, n_experts] int32, 8 KB a step at 32 slots x 64 experts)
+                moe_tokens = np.asarray(rec.aside["moe_tokens"])
+                self._moe.add("decode", moe_stats)
+                moe_fields = self._moe.flight_fields(moe_stats[0])
+                delivered = np.zeros(moe_tokens.shape[:2], bool)   # [k, S]
         now = wait.t1
         self.server._decode_sync_times.append(wait.seconds)
         self.server.observe("decode_host_lag_steps", lag)
@@ -2874,6 +2964,9 @@ class ContinuousBatcher:
                 slot.tokens.append(tok)
                 slot.n_new += 1
                 credited += 1
+                if slot.logits is not None:
+                    # graftlint: allow-host-sync-in-hot-path(a probe request only: one [vocab] row of a step that has finished)
+                    slot.logits.append(np.asarray(rec.aside["logits"][j, i]))
                 # inter-token gap at this drain (a fused block surfaces
                 # its k tokens in one burst: trailing tokens record ~0)
                 if slot.t_last is not None:
@@ -2888,14 +2981,18 @@ class ContinuousBatcher:
             if credited:
                 self._pending.count_tokens(slot.tenant, slot.slo_class,
                                            credited)
+            if moe_tokens is not None:
+                delivered[:credited, i] = True
             if self._flight is not None and credited:
                 # one step event per slot per drain, BEFORE any finish
                 # materializes the segment: tokens credited this drain plus
                 # the step's device dwell (dispatch -> drain)
                 self._flight.record(i, EV_STEP, tokens=credited,
-                                    t_dispatch=rec.t_dispatch)
+                                    t_dispatch=rec.t_dispatch, **moe_fields)
             if finish:
                 self._finish(i)
+        if moe_tokens is not None:
+            self._moe.expert_tokens += moe_tokens[delivered].sum(axis=0)
 
     def _credit_spec(self, rec: _InFlight, arr: np.ndarray,
                      accs: np.ndarray):

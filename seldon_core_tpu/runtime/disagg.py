@@ -576,8 +576,8 @@ class PrefillWorker:
             pos = np.full((1, C), PAD_POS, np.int32)
             toks[0, :n] = part
             pos[0, :n] = np.arange(start, start + n)
-            logits, self._staging = fn(self._params, self._staging, bt_row,
-                                       jnp.asarray(toks), jnp.asarray(pos))
+            logits, self._staging, _ = fn(self._params, self._staging, bt_row,
+                                          jnp.asarray(toks), jnp.asarray(pos))
             start += n
         # graftlint: allow-host-sync-in-hot-path(admission-time sync on the PREFILL worker thread, once per request: the LAST chunk's logits seed the first sampled token; the decode slice never blocks on it)
         first_logits = np.asarray(logits[0, n - 1]).astype(np.float32)

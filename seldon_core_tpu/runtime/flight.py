@@ -173,10 +173,14 @@ class FlightRecorder:
                                     prompt_tokens, self.ring_size,
                                     tags=tags)
 
-    def record(self, slot: int, kind: str, **fields: Any) -> None:
+    def record(self, slot: int, kind: str, **fields: Any) -> Optional[dict]:
+        """Returns the event's own fields (None with no segment open): the
+        single writer may add to them what it learns later, before the
+        segment completes (a chunk's routing tallies, known at the
+        first-token sync)."""
         seg = self._segs[slot]
         if seg is None:
-            return
+            return None
         seg.total += 1
         t = self._clock()
         if kind == EV_FIRST_TOKEN or kind == EV_STEP:
@@ -189,6 +193,7 @@ class FlightRecorder:
                     seg.worst_gap = gap
             seg.last_surface = t
         seg.ring.append((t, kind, fields))
+        return fields
 
     def extend(self, slot: int, events) -> None:
         """Copy worker-stamped events (Handoff.events: (t, kind, fields)

@@ -714,7 +714,9 @@ class LLMServer(SeldonComponent):
             from seldon_core_tpu.ops.quantize import dequantize_params, quantize_params
 
             params = self._streamed_quantized_init() if streamed else quantize_params(params)
-            self._dequant = dequantize_params
+            # expert stacks stay int8 inside the programs: MoEFFN's grouped
+            # matmul takes them as they are (ops/quantize.py)
+            self._dequant = partial(dequantize_params, keep_stacks=True)
 
         if self.mesh is not None:
             from seldon_core_tpu.parallel.sharding import logical_axis_tree, shard_params
@@ -821,7 +823,10 @@ class LLMServer(SeldonComponent):
         become QuantizedTensor, 1-D leaves stay float) but not in exact
         values: leaves draw from per-leaf keys (seed folded with the leaf
         path) with variance-scaled normals (std = 1/sqrt(fan_in)) for ≥2-D
-        leaves, ones for 1-D scale/weight (norm) leaves, zeros otherwise.
+        leaves, ones for 1-D scale/weight (norm) leaves, zeros otherwise. A
+        3-D leaf is a stack of matrices [e, d, f] and its fan_in is one
+        matrix's d: counted over the stack, every expert's output would be
+        sqrt(e) too small, and a wrong expert layer too faint to notice.
         jit caches by (shape, std), so the 32 identical layers of a 7B
         config cost ~a dozen compiles, not ~200."""
         import zlib
@@ -850,7 +855,7 @@ class LLMServer(SeldonComponent):
             name = keystr(path)
             if jnp.issubdtype(spec.dtype, jnp.floating) and spec.ndim >= 2:
                 key = jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-                fan_in = int(np.prod(spec.shape[:-1]))
+                fan_in = int(np.prod(spec.shape[1 if spec.ndim == 3 else 0:-1]))
                 leaves.append(make_quantized(key, spec.shape, 1.0 / float(fan_in) ** 0.5))
             elif jnp.issubdtype(spec.dtype, jnp.floating):
                 fill = 1.0 if ("norm" in name.lower() or "scale" in name.lower()
@@ -1183,6 +1188,23 @@ class LLMServer(SeldonComponent):
         self._decode_cache[key] = decode
         return decode
 
+    def _forward_with_aside(self, params, tokens, **kwargs):
+        """``module.apply`` for the batcher's step programs: (logits, caches,
+        aside). ``aside`` is what leaves a program beside its tokens and costs
+        no sync of its own (the host reads it after the tokens have landed):
+        for an MoE model ``moe_tokens`` [b, n_experts] and ``moe_stats`` [4]
+        (models/transformer.py moe_routing_stats); empty for a dense one."""
+        if self._cfg.n_experts == 0:
+            logits, caches = self._module.apply(
+                self._dequant(params), tokens, **kwargs)
+            return logits, caches, {}
+        from seldon_core_tpu.models.transformer import moe_routing_stats
+
+        (logits, caches), sown = self._module.apply(
+            self._dequant(params), tokens, mutable=["moe"], **kwargs)
+        moe_tokens, moe_stats = moe_routing_stats(sown["moe"], self._cfg)
+        return logits, caches, {"moe_tokens": moe_tokens, "moe_stats": moe_stats}
+
     def _get_decode_step(self, slots: int, max_len: int, k: int = 1,
                          lora: bool = False):
         """Compiled pipelined decode step for the ContinuousBatcher: runs
@@ -1192,7 +1214,11 @@ class LLMServer(SeldonComponent):
         threaded from output to input across calls, so the host never
         round-trips token/position state through NumPy between steps.
 
-        Returns ``(caches, last_tok, next_pos, keys, tokens[slots, k])``.
+        Returns ``(caches, last_tok, next_pos, keys, tokens[slots, k],
+        aside)``: ``aside["logits"]`` [k, slots, vocab] float32 is what each
+        token was sampled from (the host fetches a slot's rows only for a
+        request that asked for logits), plus ``_forward_with_aside``'s
+        entries, each with a leading [k].
         The cache pytree, position array and key array are donated (the
         per-step scatter updates in place; the caller reassigns from the
         outputs). ``last_tok`` is deliberately NOT donated: the stacked
@@ -1215,9 +1241,8 @@ class LLMServer(SeldonComponent):
         import jax
         import jax.numpy as jnp
 
-        module = self._module
         top_k = self.top_k
-        deq = self._dequant
+        forward = self._forward_with_aside
 
         def core(params, caches, last_tok, next_pos, keys, temperature,
                  adapter_pool, adapter_ids):
@@ -1225,18 +1250,18 @@ class LLMServer(SeldonComponent):
 
             def step(carry, _):
                 caches, tok, pos, keys = carry
-                logits, caches = module.apply(
-                    deq(params), tok[:, None], positions=pos[:, None],
+                logits, caches, aside = forward(
+                    params, tok[:, None], positions=pos[:, None],
                     caches=caches, cache_index=pos,
                     adapters=adapter_pool, adapter_ids=adapter_ids,
                 )
-                keys, nxt = sample(keys, logits[:, -1].astype(jnp.float32),
-                                   temperature)
-                return (caches, nxt, pos + 1, keys), nxt
+                last = logits[:, -1].astype(jnp.float32)
+                keys, nxt = sample(keys, last, temperature)
+                return (caches, nxt, pos + 1, keys), (nxt, {"logits": last, **aside})
 
-            (caches, tok, pos, keys), toks = jax.lax.scan(
+            (caches, tok, pos, keys), (toks, aside) = jax.lax.scan(
                 step, (caches, last_tok, next_pos, keys), None, length=k)
-            return caches, tok, pos, keys, toks.T  # tokens [slots, k]
+            return caches, tok, pos, keys, toks.T, aside  # tokens [slots, k]
 
         if lora:
             # adapted variant (llm.lora_decode_step hlolint contract): the
@@ -1268,15 +1293,15 @@ class LLMServer(SeldonComponent):
         its whole compile bucket (Sarathi-Serve-style chunked prefill;
         Agrawal et al., OSDI 2024). The pool pytree is donated: the scatter
         updates in place, and the batcher threads the returned pool into
-        the next dispatch. Returns (logits [1, chunk, vocab], pools)."""
+        the next dispatch. Returns (logits [1, chunk, vocab], pools, aside):
+        ``_forward_with_aside``'s entries, for the chunk's live rows."""
         key = ("pchunk", chunk, n_pages, lora)
         fn = self._prefill_cache.get(key)
         if fn is not None:
             return fn
         import jax
 
-        module = self._module
-        deq = self._dequant
+        forward = self._forward_with_aside
 
         if lora:
             # adapted chunked prefill: the admitted sequence's adapter id
@@ -1286,20 +1311,18 @@ class LLMServer(SeldonComponent):
             @partial(jax.jit, donate_argnums=(1,))
             def prefill_chunk(params, pools, block_row, tokens, positions,
                               adapter_pool, adapter_ids):
-                logits, pools = module.apply(
-                    deq(params), tokens, positions=positions, caches=pools,
+                return forward(
+                    params, tokens, positions=positions, caches=pools,
                     block_tables=block_row, adapters=adapter_pool,
                     adapter_ids=adapter_ids,
                 )
-                return logits, pools
         else:
             @partial(jax.jit, donate_argnums=(1,))
             def prefill_chunk(params, pools, block_row, tokens, positions):
-                logits, pools = module.apply(
-                    deq(params), tokens, positions=positions, caches=pools,
+                return forward(
+                    params, tokens, positions=positions, caches=pools,
                     block_tables=block_row,
                 )
-                return logits, pools
 
         self._prefill_cache[key] = prefill_chunk
         return prefill_chunk
@@ -1380,8 +1403,8 @@ class LLMServer(SeldonComponent):
         through the batcher's jitted table ops between dispatches, and
         device program order serializes those against in-flight steps.
 
-        Returns ``(pools, last_tok, next_pos, keys, tokens[slots, k])`` with
-        the same donation shape as the dense step (pools, next_pos, keys
+        Returns ``(pools, last_tok, next_pos, keys, tokens[slots, k], aside)``
+        with the same donation shape as the dense step (pools, next_pos, keys
         donated; last_tok not, for the same stacked-output aliasing reason).
         Token parity with the dense step is bit-exact — the pool is read
         with the XLA gather (tests/test_paged_kv.py); the compiled-form contract is
@@ -1393,9 +1416,8 @@ class LLMServer(SeldonComponent):
         import jax
         import jax.numpy as jnp
 
-        module = self._module
         top_k = self.top_k
-        deq = self._dequant
+        forward = self._forward_with_aside
 
         def core(params, pools, last_tok, next_pos, keys, temperature,
                  block_tables, adapter_pool, adapter_ids):
@@ -1403,18 +1425,18 @@ class LLMServer(SeldonComponent):
 
             def step(carry, _):
                 pools, tok, pos, keys = carry
-                logits, pools = module.apply(
-                    deq(params), tok[:, None], positions=pos[:, None],
+                logits, pools, aside = forward(
+                    params, tok[:, None], positions=pos[:, None],
                     caches=pools, block_tables=block_tables,
                     adapters=adapter_pool, adapter_ids=adapter_ids,
                 )
-                keys, nxt = sample(keys, logits[:, -1].astype(jnp.float32),
-                                   temperature)
-                return (pools, nxt, pos + 1, keys), nxt
+                last = logits[:, -1].astype(jnp.float32)
+                keys, nxt = sample(keys, last, temperature)
+                return (pools, nxt, pos + 1, keys), (nxt, {"logits": last, **aside})
 
-            (pools, tok, pos, keys), toks = jax.lax.scan(
+            (pools, tok, pos, keys), (toks, aside) = jax.lax.scan(
                 step, (pools, last_tok, next_pos, keys), None, length=k)
-            return pools, tok, pos, keys, toks.T  # tokens [slots, k]
+            return pools, tok, pos, keys, toks.T, aside  # tokens [slots, k]
 
         if lora:
             # adapted paged step (llm.lora_decode_step hlolint contract):
@@ -2053,6 +2075,10 @@ class LLMServer(SeldonComponent):
             slots_active = batcher.active_slots()
             occupancy = slots_active / max(batcher.S, 1)
             loop_stats = batcher._phases.stats()
+            if batcher._moe is not None:
+                # an MoE model's routing tallies (runtime/batcher.py
+                # MoECounters; metrics/registry.py seldon_llm_moe_*)
+                loop_stats.update(batcher._moe.stats())
             slot_bytes = self._entry_nbytes(batcher._caches, None)
             in_flight = len(batcher._inflight)
             inflight_hwm = batcher._inflight_hwm

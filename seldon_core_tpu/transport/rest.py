@@ -19,12 +19,14 @@ Request parsing accepts raw JSON bodies, form field ``json=``, and multipart
 from __future__ import annotations
 
 import asyncio
+import base64
 import json
 import logging
 import os
 import time
 from typing import Any, Callable, Optional
 
+import numpy as np
 from aiohttp import web
 
 from seldon_core_tpu.codec.framing import (
@@ -340,7 +342,11 @@ def _add_generate_routes(app: web.Application, component: Any,
           prompt; with the component's continuous_batching on, concurrent
           requests JOIN the in-flight decode batch (runtime/batcher.py)
           instead of each running a private generate(); "stream": true
-          sends tokens as SSE events as they decode.
+          sends tokens as SSE events as they decode. "logits": true (a
+          probe: plain reply, batched path only) adds the float32 logits
+          each token was sampled from, one row per token, taken from the
+          step programs that serve every request:
+          {"shape": [n, vocab], "dtype": "float32", "base64": ...}.
       {"prompts": [...], ...} — explicit batch, served by one generate().
     No reference counterpart (its servers are request/response classifiers);
     this is the BASELINE.json LLM stretch surface."""
@@ -433,6 +439,15 @@ def _add_generate_routes(app: web.Application, component: Any,
             decode = getattr(component, "_tokenizer", None)
 
             info: dict = {}
+            if body.get("logits"):
+                if stream or svc is None or not getattr(svc.batcher, "paged", False) \
+                        or svc.batcher.spec_mode != "off":
+                    raise SeldonError(
+                        "'logits' is a probe of the batched path: a plain "
+                        "(not streamed) request to a server with paged "
+                        "continuous batching, no per-request temperature "
+                        "and no speculation", status_code=400)
+                info["logits"] = []   # the batcher appends a row per token
             if not stream:
                 if svc is not None:
                     toks = await svc.submit(prompt, max_new, info=info,
@@ -462,6 +477,11 @@ def _add_generate_routes(app: web.Application, component: Any,
                     out["trace_id"] = trace.trace_id
                 if info.get("truncated_prompt"):
                     out["truncated_prompt"] = info["truncated_prompt"]
+                if info.get("logits"):
+                    rows = np.stack(info["logits"]).astype("<f4")
+                    out["logits"] = {
+                        "shape": list(rows.shape), "dtype": "float32",
+                        "base64": base64.b64encode(rows.tobytes()).decode()}
                 return web.json_response(out)
 
             if custom_sampling:

@@ -196,3 +196,64 @@ def test_llama3_rope_scaling_matches_hf():
         hf_logits = model(torch.from_numpy(tokens)).logits.numpy()
     ours, _ = module.apply(variables, jnp.asarray(tokens, jnp.int32))
     np.testing.assert_allclose(np.asarray(ours), hf_logits, atol=3e-4, rtol=3e-4)
+
+
+# ---------------------------------------------------------------------------
+# OLMoE (model_type "olmoe"): 64-expert-style sparse FFN, QK-norm, raw top-k
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tiny_olmoe():
+    from transformers import OlmoeConfig, OlmoeForCausalLM
+
+    config = OlmoeConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        num_experts=16, num_experts_per_tok=4, max_position_embeddings=64,
+        norm_topk_prob=False, rms_norm_eps=1e-5)
+    torch.manual_seed(1)
+    model = OlmoeForCausalLM(config).eval()
+    with torch.no_grad():
+        for name, w in model.named_parameters():
+            if "experts" in name:   # 0.02 would make the experts a rumour
+                w.mul_(15.0)
+            elif "norm" in name:    # ones would hide a misplaced norm weight
+                w.copy_(1.0 + 0.3 * torch.randn_like(w))
+    return model
+
+
+def test_olmoe_config_mapping(tiny_olmoe):
+    kw = config_kwargs_from_hf(tiny_olmoe.config)
+    assert kw == {
+        "vocab_size": 128, "dim": 64, "n_layers": 2, "n_heads": 4,
+        "n_kv_heads": 4, "ffn_dim": 32, "max_seq_len": 64,
+        "rope_theta": 10000.0, "norm_eps": 1e-5, "tie_embeddings": False,
+        "n_experts": 16, "n_experts_per_token": 4,
+        "router_renormalize": False, "qk_norm": True,
+    }
+
+
+def test_olmoe_converted_logits_match_hf(tiny_olmoe):
+    """Weight names (q_norm / k_norm, mlp.gate, experts.N.{gate,up,down}_proj
+    stacked into [e, d, f]) and the block's mathematics, through the module
+    that serves: float32 both sides, 2e-4 is summation order."""
+    import jax.numpy as jnp
+
+    module, variables = convert_hf_model(tiny_olmoe)
+    moe = variables["params"]["layer_1"]["moe"]
+    assert moe["w1"].shape == (16, 64, 32) and moe["w2"].shape == (16, 32, 64)
+    assert moe["router"].shape == (64, 16)
+    tokens = np.array([[5, 97, 31, 100, 7, 1, 42, 13, 77, 3, 9, 64]], dtype=np.int64)
+    with torch.no_grad():
+        hf_logits = tiny_olmoe(torch.from_numpy(tokens)).logits.numpy()
+    ours, _ = module.apply(variables, jnp.asarray(tokens, jnp.int32))
+    np.testing.assert_allclose(np.asarray(ours), hf_logits, atol=2e-4, rtol=2e-4)
+
+
+def test_olmoe_clip_qkv_refused(tiny_olmoe):
+    import copy
+
+    cfg = copy.deepcopy(tiny_olmoe.config)
+    cfg.clip_qkv = 8.0
+    with pytest.raises(ValueError, match="clip_qkv"):
+        config_kwargs_from_hf(cfg)

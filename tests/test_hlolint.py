@@ -292,6 +292,71 @@ def test_paged_decode_step_holds_no_expanded_kv():
 
 
 # ---------------------------------------------------------------------------
+# sparse MoE: no dense form, no floating expert stack (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+def _moe_contracts():
+    from tools.hlolint.contracts import all_contracts
+
+    return [c for c in all_contracts() if c.name.startswith("llm.moe_")]
+
+
+@pytest.mark.parametrize("name", ["llm.moe_paged_decode_step_s4",
+                                  "llm.moe_prefill_chunk_c8"])
+def test_moe_step_programs_hold_no_dense_form_and_no_float_stack(name):
+    """The paged decode step and chunk of a small OLMoE shape with int8
+    weights, lowered for a TPU: no result shaped [rows, n_experts,
+    expert_width], no floating copy of an expert stack; the pool donated, no
+    transfer. (Lowered for the CPU, ``ragged_dot`` IS a dense masked
+    expansion: that is jax's fallback where the tests run, not the chip's.)"""
+    (contract,) = [c for c in _moe_contracts() if c.name == name]
+    reported, *_ = run_one(contract, checks=("alias", "transfer", "dtype",
+                                             "collective"))
+    assert reported == []
+
+
+def _build_dense_moe(dequantized_stack: bool):
+    """The formulation the sparse FFN replaced, at the MoE contract's dims."""
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        from tools.hlolint.contracts import (
+            MOE_DIM, MOE_EXPERTS, MOE_WIDTH, SLOTS)
+
+        def dense(x, q, scale, gates):
+            if dequantized_stack:   # dequantize_params' habit, on a stack
+                w1 = q.astype(jnp.bfloat16) * scale[:, None, :].astype(jnp.bfloat16)
+                return jax.lax.ragged_dot(
+                    x[:, 0], w1, jnp.full((MOE_EXPERTS,), 0, jnp.int32).at[0].set(SLOTS))
+            h = jnp.einsum("bsd,edf->bsef", x, q.astype(jnp.bfloat16))
+            return jnp.einsum("bsef,bse->bsf", h, gates)
+
+        return jax.jit(dense), (
+            _sds((SLOTS, 1, MOE_DIM), "bfloat16"),
+            _sds((MOE_EXPERTS, MOE_DIM, MOE_WIDTH), "int8"),
+            _sds((MOE_EXPERTS, MOE_WIDTH), "float32"),
+            _sds((SLOTS, 1, MOE_EXPERTS), "bfloat16"))
+
+    return build
+
+
+@pytest.mark.parametrize("dequantized_stack", [False, True])
+def test_moe_scan_sees_the_dense_form_when_it_is_there(dequantized_stack):
+    """The twin: the same two signatures fire on the dense einsum and on a
+    dequantized stack, so their silence above is not blindness."""
+    from tools.hlolint.contracts import MOE_DENSE_FORM, MOE_FLOAT_STACK
+
+    c = Contract("fix.moe", "t", _build_dense_moe(dequantized_stack),
+                 forbid_dtypes=(MOE_DENSE_FORM, MOE_FLOAT_STACK),
+                 lowering_platform="tpu")
+    reported, *_ = run_one(c)
+    assert set(checks_of(reported)) == {"dtype"}
+    wanted = MOE_FLOAT_STACK if dequantized_stack else MOE_DENSE_FORM
+    assert wanted[0] in {f.detail for f in reported}
+
+
+# ---------------------------------------------------------------------------
 # collective: exact count-per-kind budget
 # ---------------------------------------------------------------------------
 

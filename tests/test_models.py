@@ -84,7 +84,15 @@ def test_transformer_prefill_then_decode():
     )
 
 
-def test_transformer_moe():
+@pytest.mark.parametrize("case", ["renormalised-top2-of-4", "olmoe"])
+def test_transformer_moe(case):
+    """The MoE block against the plain reference (models/reference.py), as a
+    case of tests/test_reference.py's comparison: today's semantics (4
+    experts, top-2, weights renormalised: what this test covered by shape
+    and finiteness alone before there was a reference) and OLMoE's."""
+    from test_reference import check_full_forward
+
+    check_full_forward(case)
     model = get_model("llama-tiny", n_experts=4)
     tokens = jnp.array([[1, 2, 3]], dtype=jnp.int32)
     variables = model.init(jax.random.PRNGKey(0), tokens)

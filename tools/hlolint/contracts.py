@@ -55,6 +55,15 @@ RESERVED_PAGES_N = 2
 # budgets.json; at serving dims the margin only widens.
 LORA_RANK = 2
 LORA_ADAPTERS = 4
+# sparse MoE (ISSUE 25): a small OLMoE shape (QK-norm, top-4 of 16 experts,
+# weights not renormalised), int8 weights with per-expert scales, bf16
+# paged KV. The three sizes differ so that a shape names one thing: an
+# expert stack is [16, 64, 32] or [16, 32, 64], the dense form's
+# intermediate is [rows.., 16, 32]
+MOE_EXPERTS = 16
+MOE_TOP_K = 4
+MOE_DIM = 64
+MOE_WIDTH = 32
 
 
 def ensure_platform() -> None:
@@ -164,6 +173,29 @@ def _lora_server():
         return _STATE["lora_server"]
 
 
+def _moe_server():
+    """int8 weights, bf16 compute OLMoE-shaped LLMServer at test dims."""
+    with _STATE_LOCK:
+        if "moe_server" not in _STATE:
+            ensure_platform()
+            from seldon_core_tpu.servers.llmserver import LLMServer
+
+            s = LLMServer(
+                model="transformer",
+                model_kwargs=dict(
+                    vocab_size=96, dim=MOE_DIM, n_layers=2, n_heads=4,
+                    n_kv_heads=4, ffn_dim=MOE_WIDTH,
+                    max_seq_len=PAGES_PER_SLOT * PAGE_SIZE,
+                    n_experts=MOE_EXPERTS, n_experts_per_token=MOE_TOP_K,
+                    router_renormalize=False, qk_norm=True,
+                    dtype="bfloat16"),
+                quantize="int8", init_random=True, len_buckets=(PLEN,),
+                seed=7)
+            s.load()
+            _STATE["moe_server"] = s
+        return _STATE["moe_server"]
+
+
 def _batcher():
     with _STATE_LOCK:  # nests into _base_server's hold: RLock
         if "batcher" not in _STATE:
@@ -243,9 +275,53 @@ def _f32_pool_sig() -> str:
     return rf"tensor<{POOL_PAGES}x{PAGE_SIZE}x{KV_HEADS}x{HEAD_DIM}xf32>"
 
 
+# the sparse MoE's two promises, as signatures in the LOWERED module. The
+# dense formulation ("bsd,edf->bsef") computes every expert for every row
+# and holds a floating [rows.., experts, width] intermediate; a dequantized
+# expert stack is a floating [experts, dim, width] / [experts, width, dim]
+MOE_DENSE_FORM = (
+    rf"tensor<(\d+x)+{MOE_EXPERTS}x{MOE_WIDTH}x(bf16|f16|f32)>",
+    "a floating [rows, n_experts, expert_width] result: every expert is "
+    "computing every row (n_experts / top_k times the FLOPs, and the "
+    "temporary), which the sparse expert FFN exists to avoid")
+MOE_FLOAT_STACK = (
+    rf"tensor<{MOE_EXPERTS}x({MOE_DIM}x{MOE_WIDTH}|{MOE_WIDTH}x{MOE_DIM})"
+    r"x(bf16|f16|f32)>",
+    "a floating copy of an int8 expert stack: the grouped matmul takes the "
+    "int8 array and scales the product; dequantizing the stack writes and "
+    "re-reads 2-4x its bytes every layer of every step")
+
+
+def _moe_pool_specs():
+    import jax
+
+    from seldon_core_tpu.models.transformer import init_paged_kv_caches
+
+    s = _moe_server()
+    return jax.eval_shape(
+        lambda: init_paged_kv_caches(s._cfg, POOL_PAGES, PAGE_SIZE, "bf16"))
+
+
 # ----------------------------------------------------------------------
 # builders
 # ----------------------------------------------------------------------
+
+def _build_moe_paged_decode_step():
+    s = _moe_server()
+    fn = s._get_decode_step_paged(SLOTS, PAGES_PER_SLOT, 1)
+    return fn, (s._params, _moe_pool_specs(), _sds((SLOTS,), "int32"),
+                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
+                _sds((), "float32"),
+                _sds((SLOTS, PAGES_PER_SLOT), "int32"))
+
+
+def _build_moe_prefill_chunk():
+    s = _moe_server()
+    fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
+    return fn, (s._params, _moe_pool_specs(),
+                _sds((1, PAGES_PER_SLOT), "int32"),
+                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"))
+
 
 def _build_prefill():
     s = _base_server()
@@ -588,6 +664,29 @@ def all_contracts() -> List[Contract]:
             build=_build_paged_decode_step,
             donated=(1, 3, 4),
             forbid_dtypes=((_f32_pool_sig(), F32_CACHE_WHY),),
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.moe_paged_decode_step_s4",
+            description="PAGED decode step of a sparse MoE (16 experts "
+                        "top-4, int8 stacks): each row computes its own "
+                        "experts, from int8",
+            build=_build_moe_paged_decode_step,
+            donated=(1, 3, 4),
+            forbid_dtypes=(MOE_DENSE_FORM, MOE_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.moe_prefill_chunk_c8",
+            description="chunked admission prefill of the same sparse MoE: "
+                        "the grouped matmul over the chunk's routed rows",
+            build=_build_moe_prefill_chunk,
+            donated=(1,),
+            forbid_dtypes=(MOE_DENSE_FORM, MOE_FLOAT_STACK),
+            lowering_platform="tpu",
             collectives={},
             cost=True,
         ),

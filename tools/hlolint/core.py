@@ -126,6 +126,12 @@ class Contract:
     collectives: Optional[Dict[str, int]] = None
     # check flops / bytes-accessed against budgets.json under this name
     cost: bool = False
+    # lower the module ``forbid_dtypes`` reads for this platform instead of
+    # the one the tests run on: where jax lowers an op differently per
+    # platform (``ragged_dot`` is one grouped-matmul op for a TPU and a dense
+    # masked expansion elsewhere), the promise is about what the chip's
+    # compiler is given. Aliases, transfers and costs stay the local compile's
+    lowering_platform: Optional[str] = None
     # "check:detail" -> reason; the contract-local analogue of graftlint's
     # inline suppression — the reason is mandatory
     waivers: Dict[str, str] = field(default_factory=dict)
@@ -139,6 +145,9 @@ class Artifact:
         self.args = args
         lowered = fn.lower(*args)
         self.stablehlo = lowered.as_text()
+        if contract.lowering_platform:
+            self.stablehlo = fn.trace(*args).lower(
+                lowering_platforms=(contract.lowering_platform,)).as_text()
         self.compiled = lowered.compile()
         self.hlo = self.compiled.as_text()
         self._header = self.hlo.splitlines()[0] if self.hlo else ""
