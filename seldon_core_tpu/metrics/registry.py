@@ -374,6 +374,14 @@ class MetricsRegistry:
             base,
             registry=self.registry,
         )
+        self._first_token_reads = Counter(
+            "seldon_llm_first_token_reads_total",
+            "Reads of a prompt's first token off the drain pipeline, by "
+            "whether it was already computed when its record was drained "
+            "(ready=no: the read waited for the device)",
+            base + ["ready"],
+            registry=self.registry,
+        )
         # An MoE model's routing (runtime/batcher.py MoECounters,
         # docs/observability.md "Expert routing"): counted on the loop from
         # arrays that leave the step programs beside their tokens, absent
@@ -963,6 +971,8 @@ class MetricsRegistry:
         for phase, n in stats.get("loop_phase_counts", {}).items():
             self._counter_catch_up(self._loop_phase, n, phase=phase)
         self._counter_catch_up(self._loop_turns, stats.get("loop_turns", 0))
+        for ready, n in stats.get("first_token_reads", {}).items():
+            self._counter_catch_up(self._first_token_reads, n, ready=ready)
         for program, tally in stats.get("moe_by_program", {}).items():
             for field, n in tally.items():
                 self._counter_catch_up(self._moe[field], n, program=program)

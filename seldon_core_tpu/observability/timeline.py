@@ -105,7 +105,7 @@ def scaling_snapshot(component: Any, batcher: Any = None,
             snap["queue_depth"] = sum(by_class.values())
         else:
             snap["queue_depth"] = len(sched)
-        snap["steps_in_flight"] = len(batcher._inflight)
+        snap["steps_in_flight"] = batcher.steps_in_flight()
         snap["draining"] = bool(getattr(batcher, "draining", False))
         if getattr(batcher, "paged", False):
             pages = batcher.page_stats()

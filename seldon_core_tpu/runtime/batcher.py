@@ -42,6 +42,20 @@ slot only while ``(slot, gen)`` still matches the dispatch-time snapshot
 and the slot still has budget, so trailing tokens of ANY width for a
 finished or replaced occupant are dropped, never surfaced.
 
+First-token activation (PR 26): an admission reads nothing. The prompt's
+first token is drawn on the device from the last chunk's logits by the
+sampler the steps use (``LLMServer._get_first_token``), threaded into the
+slot's device state by ``set_slot``, and queued in ``_inflight`` as a
+``_FirstToken`` record behind the steps dispatched before the chunk; the
+slot joins the decode batch at the next dispatch and the token reaches the
+host when its record drains (``_drain_first``), in device order, with the
+same ``(slot, gen)`` masking as a step's tokens. The loop holds back the
+read of such a record only while the token is not there yet, nothing is
+queued behind it and the turn still found something to enqueue
+(``_first_token_can_wait``): the slot's next step, or the next request's
+first chunk, is queued behind a last chunk before anything waits for its
+token, and with that behind it the read may block.
+
 When the admit queue is empty, ``decode_fuse_steps`` K>1 fuses K steps into
 one device-side ``lax.scan`` between syncs (one dispatch + one host read
 per K tokens).
@@ -384,18 +398,19 @@ class _PrefillJob:
     row its chunks write through. Only one job runs at a time; decode
     dispatches interleave between its chunks."""
 
-    __slots__ = ("slot", "ids", "L", "next", "chunk", "max_new", "fut",
+    __slots__ = ("slot", "ids", "L", "start", "next", "chunk", "max_new", "fut",
                  "on_token", "info", "seed", "bt_row", "pages", "t_arrival",
                  "req", "asides")
 
     def __init__(self, slot, ids, start, chunk, max_new, fut, on_token,
                  info, seed, bt_row, pages, t_arrival=None, req=None):
-        # (aside, flight event) of each chunk dispatched so far: read with
-        # the last chunk's first-token sync, never by a sync of their own
+        # (aside, flight event) of each chunk dispatched so far: read when
+        # the first token's record drains, never by a sync of their own
         self.asides: List[tuple] = []
         self.slot = slot
         self.ids = ids
         self.L = len(ids)
+        self.start = start           # first position of the uncached suffix
         self.next = start            # first position the next chunk writes
         self.chunk = chunk
         self.max_new = max_new
@@ -547,9 +562,8 @@ class LoopPhases:
     recorder: phases open and close only in the loop's own serialized
     context (the loop coroutine and the worker threads it awaits one at a
     time), so there is no lock. A phase opened inside another takes its
-    time out of the outer one (``first_token_wait`` inside ``prefill``,
-    ``drain_wait`` inside ``emit``): every second belongs to the innermost
-    phase, and the phases of a turn plus its ``hop`` ARE the turn's wall.
+    time out of the outer one (``drain_wait`` inside ``emit``): every
+    second belongs to the innermost phase, and the phases of a turn plus its ``hop`` ARE the turn's wall.
     Readers (``stats()`` at a scrape) may be one phase behind."""
 
     def __init__(self):
@@ -560,6 +574,9 @@ class LoopPhases:
         self.counts: Dict[str, int] = dict.fromkeys(LOOP_PHASES, 0)
         self.turns = 0
         self.slot_seconds = 0.0
+        # how each first_token_wait found its token: already computed
+        # ("yes") or still behind queued device work, so the read waited
+        self.first_token_reads = {"yes": 0, "no": 0}
         self._open: List[_Phase] = []
         self._turn: Optional[Any] = None   # the open turn's annotation
         self._turn_t0 = 0.0
@@ -602,7 +619,8 @@ class LoopPhases:
         return {"loop_seconds": dict(self.seconds),
                 "loop_phase_counts": dict(self.counts),
                 "loop_turns": self.turns,
-                "slot_seconds": self.slot_seconds}
+                "slot_seconds": self.slot_seconds,
+                "first_token_reads": dict(self.first_token_reads)}
 
 
 def _in_phase(name: str):
@@ -680,6 +698,31 @@ class _InFlight:
         self.t_dispatch = t_dispatch
         self.acc = acc
         self.booked = booked
+
+
+class _FirstToken:
+    """One activation the host has not yet read: the prompt's first token
+    as a device scalar, queued in ``_inflight`` behind the steps that were
+    dispatched before its last chunk, with the ``(slot, gen)`` it was
+    sampled for. Its drain surfaces the token (TTFT, ``on_token``, EOS and
+    ``max_new <= 1`` finishes) and is the one place that reads what the
+    admission left on the device: the chunks' ``asides`` and, for a probe
+    that asked for logits (``info``), the prompt's last ``row``. ``k`` = 0:
+    it is no decode step, takes no place of ``pipeline_depth`` and adds
+    nothing to the host's lag."""
+
+    __slots__ = ("slot", "gen", "token", "row", "asides", "info",
+                 "t_arrival")
+    k = 0
+
+    def __init__(self, slot, gen, token, row, asides, info, t_arrival):
+        self.slot = slot
+        self.gen = gen
+        self.token = token
+        self.row = row
+        self.asides = asides
+        self.info = info
+        self.t_arrival = t_arrival
 
 
 class BatcherService:
@@ -1057,7 +1100,10 @@ class ContinuousBatcher:
         # slipped through the routing race window is still served (a drain
         # may delay detach; it must never fail a client).
         self.draining = False
+        # dispatched decode steps (_InFlight) and activations (_FirstToken)
+        # the host has not read yet, in device program order
         self._inflight: Any = deque()
+        self._steps_in_flight = 0    # the _InFlight records among them
         self._inflight_hwm = 0       # max steps in flight ever reached
         self._last_admit_inflight = 0  # steps in flight at the last admit
         self._last_drain_t: Optional[float] = None
@@ -1637,86 +1683,77 @@ class ContinuousBatcher:
                 self.max_len - plen, max_new, self.max_len, plen)
         return ids[-plen:], plen
 
-    def _sample_first(self, first_logits: np.ndarray, seed: Optional[int],
+    def _sample_first(self, logits, idx: int, seed: Optional[int],
                       resume_tokens: int = 0):
-        """Host-side first-token draw from the prefill logits, on exactly
-        generate()'s rng chain (PRNGKey -> split for the first token ->
-        split per decode step). Returns (token, per-slot device key).
+        """The prompt's first token, drawn ON THE DEVICE from
+        ``logits[0, idx]`` by the sampler every decode step uses
+        (``LLMServer._get_first_token``: split -> lax.top_k descending ->
+        categorical -> gather, argmax under temperature <= 0), on exactly
+        generate()'s rng chain (PRNGKey -> one split per emitted token, the
+        first included). Returns device arrays ``(token, key', row)``:
+        nothing here waits for the device, and ``key'`` is the key the
+        slot's decode steps go on from.
 
         ``resume_tokens`` > 0 means this admission RESUMES a generation
         interrupted after that many delivered tokens (fleet recovery,
         docs/resilience.md): the prompt already carries the generated
         prefix and the token drawn here is token ``resume_tokens`` of the
-        ORIGINAL chain — which the device sampler would have produced. The
-        chain consumes exactly one first-component split per emitted token
-        (host first draw and every device step alike), so fast-forwarding
-        PRNGKey(seed) by ``resume_tokens`` splits and then drawing with the
-        DEVICE sampler's op order (split -> lax.top_k descending ->
-        categorical -> gather) reproduces it bit-exactly. The host path's
-        argsort ordering must NOT be used here: categorical over a
-        differently-ordered top-k draws a different index for the same
-        key."""
+        ORIGINAL chain. The chain consumes exactly one first-component
+        split per emitted token, so fast-forwarding PRNGKey(seed) by
+        ``resume_tokens`` splits and drawing through the one sampler
+        reproduces it bit-exactly (greedy takes no notice of the key)."""
         import jax
         import jax.numpy as jnp
 
         # Per-request rng: an explicit seed reproduces generate(seed=...)'s
         # exact chain; otherwise derive an independent key from the batcher
         # rng so concurrent requests don't share a stream.
-        if seed is not None:
-            key = jax.random.PRNGKey(int(seed))
-        else:
+        if seed is None:
             self._rng, key = jax.random.split(self._rng)
-        if float(self._temp) <= 0.0:
-            # greedy is key-independent (the device sampler selects argmax
-            # through jnp.where regardless of the key), so resume needs no
-            # fast-forward: argmax over the re-prefilled logits IS token N
-            first = int(first_logits.argmax())
-        elif resume_tokens > 0 and seed is not None:
+        elif resume_tokens > 0:
             from seldon_core_tpu.servers.llmserver import fast_forward_key
 
             key = fast_forward_key(seed, resume_tokens)
-            key, sub = jax.random.split(key)
-            k = min(self.server.top_k, first_logits.shape[-1])
-            topv, topi = jax.lax.top_k(jnp.asarray(first_logits), k)
-            draw = jax.random.categorical(
-                sub, topv / max(float(self._temp), 1e-6))
-            # graftlint: allow-host-sync-in-hot-path(single admission-time sync of the resumed token, once per recovery; the device sampler's exact op order is required for bit-exact continuation)
-            first = int(np.asarray(topi[draw]))
         else:
-            key, sub = jax.random.split(key)
-            k = min(self.server.top_k, first_logits.shape[-1])
-            topi = np.argsort(first_logits)[-k:]
-            # graftlint: allow-host-sync-in-hot-path(admission-time sample of the prefill token, once per request; generate()'s exact rng chain requires drawing it here)
-            draw = int(np.asarray(jax.random.categorical(
-                sub, jnp.asarray(first_logits[topi]) / max(float(self._temp), 1e-6))))
-            first = int(topi[draw])
-        return first, key
+            key = jax.random.PRNGKey(int(seed))
+        return self.server._get_first_token()(
+            logits, jnp.asarray(idx, jnp.int32), key, self._temp)
 
-    def _commit_slot(self, i: int, first: int, key, L: int, max_new: int,
-                     fut: asyncio.Future, on_token: Optional[Any],
+    def _commit_slot(self, i: int, logits, idx: int, seed: Optional[int],
+                     L: int, max_new: int, fut: asyncio.Future,
+                     on_token: Optional[Any],
                      ids: Optional[List[int]] = None,
                      t_arrival: Optional[float] = None,
-                     req: Optional[Any] = None):
-        """Slot bookkeeping shared by dense admission and paged activation:
-        thread the new occupant's state into the device arrays and surface
-        the first token. Program order on the device stream puts the
-        set_slot after every already-dispatched step, so in-flight steps
-        still see (and waste compute on) the old state while step N+1 picks
-        up the new occupant. ``ids`` (the truncated prompt) seeds the
-        speculative token history and the draft-model cache when
-        speculation is on."""
-        import time
-
+                     req: Optional[Any] = None,
+                     info: Optional[dict] = None, asides: Sequence = ()):
+        """Activation, shared by dense admission, paged activation and a
+        consumed handoff: draw the first token from ``logits[0, idx]`` on
+        the device, thread it and the new occupant's state into the device
+        arrays, and queue a ``_FirstToken`` record behind the steps already
+        in flight. NO host read: the slot joins the decode batch at the
+        next dispatch, and its first token is surfaced when the record
+        drains (``_drain_first``), like any step's tokens. Program order on
+        the device stream puts the set_slot after every already-dispatched
+        step, so in-flight steps still see (and waste compute on) the old
+        state while step N+1 picks up the new occupant. ``ids`` (the
+        truncated prompt) seeds the speculative token history and the
+        draft-model cache when speculation is on."""
         import jax.numpy as jnp
 
+        resume_tokens = req.resume_tokens if req is not None else 0
+        first, key, row = self._sample_first(logits, idx, seed, resume_tokens)
         slot = self._slots[i]
         slot.active = True
         slot.prefilling = False
         slot.future = fut
         slot.true_len = L
         slot.max_new = max_new
-        slot.n_new = 1
-        slot.tokens = [first]
+        # the host has processed nothing yet: the first token is credited
+        # where its record drains, before any step that decoded for this
+        # occupant (the record is queued ahead of them)
+        slot.n_new = 0
+        slot.tokens = []
+        slot.t_last = None
         slot.on_token = on_token
         # multi-tenant identity rides the slot for the whole occupancy:
         # tenant token/shed accounting, per-class TTFT, and the adapter
@@ -1731,30 +1768,13 @@ class ContinuousBatcher:
         # the truncated prompt feeds the radix trie's completion-time
         # insertion (prompt + generated blocks re-enter the cache)
         slot.ids = list(ids) if ids is not None else None
-        # first token surfaced NOW: time-to-first-token from submit(), and
-        # the baseline the next token's gap measures from
-        now = time.perf_counter()
-        if t_arrival is not None:
-            self.server.observe("ttft_s", now - t_arrival)
-            self.server._ttft_by_class.append(
-                (slot.slo_class, now - t_arrival))
-        self._pending.count_tokens(slot.tenant, slot.slo_class, 1)
-        slot.t_last = now
-        if self._flight is not None:
-            if req is not None and getattr(req, "resume_tokens", 0):
-                # fleet recovery: this admission continues an interrupted
-                # generation — mark the timeline so the span tree shows
-                # where the failover re-attached (docs/resilience.md)
-                self._flight.record(i, EV_RESUME,
-                                    tokens=int(req.resume_tokens))
-            self._flight.record(i, EV_FIRST_TOKEN, tokens=1)
         slot.gen += 1          # invalidates in-flight tokens for the old occupant
         slot.disp_new = 1      # the prefill-sampled first token counts
         self._admit_seq += 1
         slot.admit_seq = self._admit_seq
+        slot_i = jnp.asarray(i, jnp.int32)
         self._last_tok, self._next_pos, self._keys = self._set_slot(
-            self._last_tok, self._next_pos, self._keys,
-            jnp.asarray(i, jnp.int32), jnp.asarray(first, jnp.int32),
+            self._last_tok, self._next_pos, self._keys, slot_i, first,
             jnp.asarray(L, jnp.int32), key)
         if self.spec_mode != "off" and ids is not None:
             # Seed the slot's device-resident token history: prompt at
@@ -1762,19 +1782,72 @@ class ContinuousBatcher:
             # (L <= max_len - 1 — _truncate_prompt leaves decode room).
             # Overwriting the WHOLE row retires the previous occupant's
             # tokens, exactly like the dense cache insert.
-            row = np.zeros((self.hist_len,), np.int32)
-            row[:L] = ids
-            row[L] = first
+            row_np = np.zeros((self.hist_len,), np.int32)
+            row_np[:L] = ids
             self._hist = self._set_hist_row(
-                self._hist, jnp.asarray(i, jnp.int32), jnp.asarray(row))
+                self._hist, slot_i, jnp.asarray(row_np))
+            # the one entry the host does not know: written on the device
+            # by the table ops' entry write ([slot, index] of any int32 table)
+            self._hist = self._set_block_entry(
+                self._hist, slot_i, jnp.asarray(L, jnp.int32), first)
             self._spec.reset(i)
             if self.spec_mode == "draft":
                 self._draft_prefill_slot(i, ids)
-        self._last_admit_inflight = len(self._inflight)
-        if on_token is not None and first != self.eos_id:
-            on_token(first)
-        if first == self.eos_id or max_new <= 1:
-            self._finish(i)
+        self._last_admit_inflight = self.steps_in_flight()
+        if self._flight is not None and resume_tokens:
+            # fleet recovery: this admission continues an interrupted
+            # generation — mark the timeline so the span tree shows where
+            # the failover re-attached (docs/resilience.md)
+            self._flight.record(i, EV_RESUME, tokens=int(resume_tokens))
+        probe = info is not None and "logits" in info
+        self._inflight.append(_FirstToken(
+            i, slot.gen, first, row if probe else None, list(asides), info,
+            t_arrival))
+
+    def _drain_first(self, rec: _FirstToken):
+        """Consume an activation's record: read the first token
+        (``first_token_wait``: the read waits for whatever device work is
+        still queued ahead of the prompt's last chunk, and for the chunk),
+        then do the host's half of the commit (``first_token``): TTFT and
+        token accounting, the flight event, ``on_token``, and ``_finish`` on
+        EOS or ``max_new <= 1`` (steps dispatched for the slot meanwhile
+        are run-ahead tokens, masked as after any EOS). A record whose
+        ``(slot, gen)`` no longer matches (shed, cancel, deadline between
+        activation and read) surfaces nothing, like a stale step's tokens."""
+        ready = rec.token.is_ready()
+        with self._phases.phase("first_token_wait"):
+            # graftlint: allow-host-sync-in-hot-path(the drain's read of an activation, once per request: queued behind the steps dispatched before the prompt's last chunk and read in their order, while newer steps and chunks keep the chip busy)
+            first = int(np.asarray(rec.token))
+        with self._phases.phase("first_token"):
+            self._phases.first_token_reads["yes" if ready else "no"] += 1
+            self._count_chunks(rec.asides)
+            i = rec.slot
+            slot = self._slots[i]
+            if not slot.active or slot.gen != rec.gen:
+                return
+            if rec.row is not None:
+                # a probe asked for logits (transport/rest.py): the prompt's
+                # last position first, then one row per decode step
+                # graftlint: allow-host-sync-in-hot-path(a probe request only: one [vocab] row of a program that has finished)
+                rec.info["logits"].append(np.asarray(rec.row))
+                slot.logits = rec.info["logits"]
+            slot.n_new = 1
+            slot.tokens = [first]
+            # first token surfaced NOW: time-to-first-token from submit(),
+            # and the baseline the next token's gap measures from
+            now = time.perf_counter()
+            if rec.t_arrival is not None:
+                self.server.observe("ttft_s", now - rec.t_arrival)
+                self.server._ttft_by_class.append(
+                    (slot.slo_class, now - rec.t_arrival))
+            self._pending.count_tokens(slot.tenant, slot.slo_class, 1)
+            slot.t_last = now
+            if self._flight is not None:
+                self._flight.record(i, EV_FIRST_TOKEN, tokens=1)
+            if slot.on_token is not None and first != self.eos_id:
+                slot.on_token(first)
+            if first == self.eos_id or slot.max_new <= 1:
+                self._finish(i)
 
     def _draft_prefill_slot(self, i: int, ids: List[int]):
         """spec_mode='draft': prefill the slot's DENSE draft-model cache
@@ -1831,18 +1904,13 @@ class ContinuousBatcher:
             logits, cache1 = prefill(self.server._params, jnp.asarray(tokens),
                                      jnp.asarray(positions))
         self._caches = self._insert(self._caches, cache1, free)
-        with self._phases.phase("first_token_wait"):
-            # graftlint: allow-host-sync-in-hot-path(admission-time sync, once per request not per token: the first sampled token must reach the host to seed slot bookkeeping before the slot joins the pipelined batch)
-            first_logits = np.asarray(logits[0, L - 1]).astype(np.float32)
         if self._flight is not None:
+            # dispatch wall (enqueue-only, like a chunk's)
             self._flight.record(free, EV_PREFILL, tokens=L,
                                 dur_s=time.perf_counter() - t0)
-        with self._phases.phase("first_token"):
-            first, key = self._sample_first(first_logits, req.seed,
-                                            req.resume_tokens)
-            self._commit_slot(free, first, key, L, req.max_new, req.fut,
-                              req.on_token, ids=ids, t_arrival=req.t_arrival,
-                              req=req)
+        self._commit_slot(free, logits, L - 1, req.seed, L, req.max_new,
+                          req.fut, req.on_token, ids=ids,
+                          t_arrival=req.t_arrival, req=req, info=req.info)
         return True
 
     def _begin(self, slot: int, req, prompt_tokens: int) -> None:
@@ -2070,13 +2138,13 @@ class ContinuousBatcher:
                 self._flight.record(job.slot, EV_HANDOFF_IMPORT,
                                     bytes=h.transfer_bytes,
                                     dur_s=time.perf_counter() - t0)
-            with self._phases.phase("first_token"):
-                first, key = self._sample_first(
-                    h.first_logits, job.seed,
-                    job.req.resume_tokens if job.req is not None else 0)
-                self._commit_slot(job.slot, first, key, job.L, job.max_new,
-                                  job.fut, job.on_token, ids=job.ids,
-                                  t_arrival=job.t_arrival, req=job.req)
+            # the worker read the logits row on its own slice; it goes
+            # through the same sampler and the same record as a local one
+            self._commit_slot(job.slot, jnp.asarray(h.first_logits[None, None]),
+                              0, job.seed, job.L, job.max_new, job.fut,
+                              job.on_token, ids=job.ids,
+                              t_arrival=job.t_arrival, req=job.req,
+                              info=job.info)
 
     def _shed_remote_job(self, job_id: int, why: str):
         """Shed a staged remote admission (page pressure / shutdown): the
@@ -2264,9 +2332,10 @@ class ContinuousBatcher:
     def _prefill_step(self):
         """One chunked-prefill dispatch (worker thread): write the next
         ``chunk`` prompt tokens into the pool through the job's block-table
-        row. Only the LAST chunk syncs (the first-token logits must reach
-        the host) — intermediate chunks are enqueue-only, so decode steps
-        interleave between them and in-flight requests keep streaming."""
+        row. Enqueue-only, the last chunk included: it ends in the slot's
+        activation (``_activate``), whose first token is read when its
+        record drains, so decode steps interleave between chunks and behind
+        the last one, and in-flight requests keep streaming."""
         import jax.numpy as jnp
 
         job = self._prefill
@@ -2298,61 +2367,46 @@ class ContinuousBatcher:
         job.next = start + n
         event = None
         if self._flight is not None:
-            # dispatch wall (enqueue-only); the last chunk's logits sync
-            # below lands in the gap before the first_token event
+            # dispatch wall (enqueue-only)
             event = self._flight.record(
                 job.slot, EV_PREFILL_CHUNK, start=start, tokens=n,
                 dur_s=time.perf_counter() - t0)
         if self._moe is not None:
             job.asides.append((aside, event))
         if job.next >= job.L:
-            # the loop stands still here until the device has run every
-            # step queued ahead of this chunk and the chunk itself: no new
-            # decode step is dispatched before the token is committed
-            with self._phases.phase("first_token_wait"):
-                # graftlint: allow-host-sync-in-hot-path(admission-time sync, once per request not per chunk: the LAST chunk's logits seed the first sampled token; earlier chunks were enqueue-only)
-                first_logits = np.asarray(logits[0, n - 1]).astype(np.float32)
-            with self._phases.phase("first_token"):
-                self._count_chunks(job)
-                self._activate(job, first_logits)
+            self._activate(job, logits, n - 1)
 
-    def _count_chunks(self, job: _PrefillJob) -> None:
-        """The routing tallies of a finished admission's chunks. They ran
-        before the chunk whose logits the caller has just read, so every
+    def _count_chunks(self, asides: Sequence) -> None:
+        """The routing tallies of an admission's chunks. They ran before
+        the program whose first token the caller has just read, so every
         array here is ready: no read below waits for the device."""
-        for aside, event in job.asides:
-            # graftlint: allow-host-sync-in-hot-path(no wait: these programs finished before the first-token sync the caller just made, once per request)
+        for aside, event in asides:
+            # graftlint: allow-host-sync-in-hot-path(no wait: these programs finished before the first token the caller just read, once per request)
             stats = np.asarray(aside["moe_stats"])
             self._moe.add("chunk", stats[None])
             # graftlint: allow-host-sync-in-hot-path(same: a finished chunk's [1, n_experts] tally)
             self._moe.expert_tokens += np.asarray(aside["moe_tokens"])[0]
             if event is not None:
                 event.update(self._moe.flight_fields(stats))
-        job.asides.clear()
 
-    def _activate(self, job: _PrefillJob, first_logits: np.ndarray):
-        """Paged admission, final phase: sample the first token on
-        generate()'s rng chain, point the slot's DEVICE block-table row at
-        the real pages (decode writes route through it from the next
-        dispatch; in-flight steps still see the trash row in program
-        order), and commit the slot into the decode batch."""
+    def _activate(self, job: _PrefillJob, logits, idx: int):
+        """Paged admission, final phase, all of it enqueued: point the
+        slot's DEVICE block-table row at the real pages (decode writes
+        route through it from the next dispatch; in-flight steps still see
+        the trash row in program order) and commit the slot into the decode
+        batch with its first token drawn from the last chunk's
+        ``logits[0, idx]`` on the device. The job is done with its last
+        chunk's enqueue: the next admission can start on the next turn."""
         import jax.numpy as jnp
 
-        first, key = self._sample_first(
-            first_logits, job.seed,
-            job.req.resume_tokens if job.req is not None else 0)
         self._block_tables = self._set_block_row(
             self._block_tables, jnp.asarray(job.slot, jnp.int32),
             job.bt_row[0])
         self._prefill = None
-        if job.info is not None and "logits" in job.info:
-            # a probe asked for logits (transport/rest.py): the prompt's last
-            # position first, then one row per decode step (_drain_one)
-            job.info["logits"].append(first_logits)
-            self._slots[job.slot].logits = job.info["logits"]
-        self._commit_slot(job.slot, first, key, job.L, job.max_new, job.fut,
-                          job.on_token, ids=job.ids, t_arrival=job.t_arrival,
-                          req=job.req)
+        self._commit_slot(job.slot, logits, idx, job.seed, job.L,
+                          job.max_new, job.fut, job.on_token, ids=job.ids,
+                          t_arrival=job.t_arrival, req=job.req,
+                          info=job.info, asides=job.asides)
 
     # ------------------------------------------------------------------
     # Page accounting: growth, exhaustion shedding, release
@@ -2766,9 +2820,10 @@ class ContinuousBatcher:
         )
         return self.fuse_steps if room >= self.fuse_steps else 1
 
-    def _dispatch(self):
+    def _dispatch(self) -> bool:
         """Enqueue one (possibly K-fused) decode step on the device WITHOUT
-        waiting for its tokens: the state arrays are threaded from the
+        waiting for its tokens (False: nothing left to step after page
+        growth): the state arrays are threaded from the
         previous step's outputs, so the device runs ahead of the host.
         The ``dispatch`` phase is page growth + the enqueue, and its clock
         pair is also the step's dispatch timestamp and the
@@ -2780,6 +2835,7 @@ class ContinuousBatcher:
                 enqueued = self._dispatch_plain(ph.t0)
         if enqueued:
             self.server._decode_dispatch_times.append(ph.seconds)
+        return enqueued
 
     def _dispatch_plain(self, t0: float) -> bool:
         k = self._pick_k()
@@ -2821,8 +2877,7 @@ class ContinuousBatcher:
         for i, _ in snapshot:
             self._slots[i].disp_new += k
         self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside))
-        if len(self._inflight) > self._inflight_hwm:
-            self._inflight_hwm = len(self._inflight)
+        self._count_steps()
         return True
 
     def _dispatch_spec(self, t0: float) -> bool:
@@ -2903,18 +2958,56 @@ class ContinuousBatcher:
             self._slots[i].disp_new += booked[i]
         self._inflight.append(_InFlight(toks, 1, snapshot, t0, acc=acc,
                                         booked=booked))
-        if len(self._inflight) > self._inflight_hwm:
-            self._inflight_hwm = len(self._inflight)
+        self._count_steps()
         return True
 
-    @_in_phase("emit")
+    def steps_in_flight(self) -> int:
+        """Dispatched decode steps the host has not drained (first-token
+        records queue with them and are not steps)."""
+        return self._steps_in_flight
+
+    def _count_steps(self) -> None:
+        """After a step joined or left ``_inflight``: recount (a handful of
+        records; scrapes on other threads read the plain integer)."""
+        self._steps_in_flight = sum(1 for rec in self._inflight if rec.k)
+        if self._steps_in_flight > self._inflight_hwm:
+            self._inflight_hwm = self._steps_in_flight
+
     def _drain_one(self):
-        """Consume the OLDEST in-flight step: block until its tokens land
+        """Consume the OLDEST record in flight: a decode step's tokens
+        (``_drain_step``) or an activation's first token
+        (``_drain_first``), in the order the device runs them."""
+        rec = self._inflight.popleft()
+        if rec.k:
+            self._count_steps()
+            self._drain_step(rec)
+        else:
+            self._drain_first(rec)
+
+    def _first_token_can_wait(self, enqueued: bool) -> bool:
+        """Would reading the oldest record leave the device with nothing to
+        do? Yes when it is an activation whose token is not there yet and
+        NOTHING is queued behind it (no step, no later activation, no chunk
+        of the staged job), while this turn still found something to
+        enqueue (an admission, a step, a chunk): then the next turn's
+        enqueues go first, so a prompt's last chunk has the slot's next
+        step or the next request's first chunk queued behind it before
+        anything waits for its token. With a program behind it the read may
+        block: the device has that to run meanwhile. A turn that enqueued
+        nothing lets the read block too."""
+        head = self._inflight[0]
+        job = self._prefill
+        return (head.k == 0 and enqueued and len(self._inflight) == 1
+                and (job is None or job.next == job.start)
+                and not head.token.is_ready())
+
+    @_in_phase("emit")
+    def _drain_step(self, rec: _InFlight):
+        """Consume a dispatched step: block until its tokens land
         (the ``drain_wait`` phase), then run all host bookkeeping (EOS,
         budgets, streaming callbacks, slot release: the rest is ``emit``).
         Later steps stay dispatched while this runs — the host trails the
         device, never the other way around."""
-        rec: _InFlight = self._inflight.popleft()
         # host lag in decode STEPS, not dispatch records: a fused record
         # covers k steps, so depth 2 at K=8 is a 16-step lag
         lag = rec.k + sum(r.k for r in self._inflight)
@@ -3063,6 +3156,9 @@ class ContinuousBatcher:
                 # the turn's time budget closes here and nowhere else: what
                 # the previous turn's phases did not cover is its hop
                 phases.turn(self.active_slots())
+                # did this turn put anything on the device's queue or take a
+                # request off the scheduler's (_first_token_can_wait)
+                enqueued = False
                 # liveness heartbeat + deterministic chaos injection: both
                 # happen in the loop's own serialized context, so a raising
                 # chaos hook dies exactly like a device fault mid-turn
@@ -3116,31 +3212,38 @@ class ContinuousBatcher:
                     # an _admit_* shed path already removed req from the
                     # scheduler (counting the shed); commit is a no-op then
                     self._pending.commit(req)
+                    enqueued = True
                 # disaggregated: activate every finished handoff (import +
                 # commit — one jitted scatter each, no prefill compute on
                 # this slice)
                 if self._transfer is not None and self._transfer.ready_depth():
                     await asyncio.to_thread(self._consume_handoffs)
+                    enqueued = True
                 # producer: keep the device pipeline_depth steps ahead of
                 # the host — dispatch is enqueue-only, no sync
-                while (len(self._inflight) < self.pipeline_depth
+                while (self.steps_in_flight() < self.pipeline_depth
                        and self._dispatch_eligible()):
-                    await asyncio.to_thread(self._dispatch)
+                    if await asyncio.to_thread(self._dispatch):
+                        enqueued = True
                 # chunked prefill interleaves: ONE chunk per loop turn, so a
                 # long admission prefill shares the device with the decode
                 # dispatches above instead of stalling them for its whole
-                # compile bucket (only the last chunk syncs)
+                # compile bucket (no chunk syncs: the last one ends in the
+                # slot's activation, read below like a step's tokens)
                 if self._prefill is not None:
                     await asyncio.to_thread(self._prefill_step)
-                    if self._inflight:
-                        await asyncio.to_thread(self._drain_one)
-                    # never fall through to the idle wait on a prefill turn:
-                    # the chunk either advanced the job or ACTIVATED the
-                    # slot (now dispatch-eligible) — loop back to dispatch
-                    continue
-                # consumer: drain the oldest step one (or more) behind
-                if self._inflight:
+                    enqueued = True
+                # consumer: drain the oldest record one (or more) behind,
+                # unless it is a first token that the next turn's enqueues
+                # should not stand behind
+                if self._inflight and not self._first_token_can_wait(enqueued):
                     await asyncio.to_thread(self._drain_one)
+                    continue
+                if enqueued:
+                    # never fall through to the idle wait on a turn that
+                    # enqueued: a chunk advanced its job or ACTIVATED the
+                    # slot (now dispatch-eligible), or there is more to
+                    # enqueue ahead of a first token — loop back
                     continue
                 if self._closed:
                     # staged remote jobs would leave futures hanging past
@@ -3173,6 +3276,7 @@ class ContinuousBatcher:
             self.crashed = e
             logger.exception("batcher loop died: %s", e)
             self._inflight.clear()
+            self._steps_in_flight = 0
             self._prefill = None
             if self._remote_jobs:
                 # cancel staged handoffs first: their slots then read as

@@ -705,7 +705,7 @@ class ReplicaSet(SeldonComponent):
         Determinism: an unseeded request gets a journaled random seed
         BEFORE first dispatch, so greedy and sampled generations alike
         live on one pinned rng chain that a resume can fast-forward
-        (batcher._sample_first). The ``ResumeJournal`` records each token
+        (batcher._sample_first, on the device). The ``ResumeJournal`` records each token
         under its lock BEFORE forwarding it to the client, so a resume
         skips exactly the delivered prefix — at-most-once delivery, never
         a duplicate. The batcher's crash handler fires ``on_token(None)``

@@ -105,24 +105,46 @@ def _cast_params(params, param_dtype: str, module_dtype) -> Any:
     return jax.tree.map(cast, params)
 
 
+def _top_k_candidates(lg, top_k: int):
+    """What every emitted token is chosen among: the greedy token and the
+    top-k logits with their indices in ``lax.top_k``'s order (descending,
+    ties by index). ``lg`` [rows, vocab] float32."""
+    import jax
+    import jax.numpy as jnp
+
+    greedy = jnp.argmax(lg, axis=-1)
+    topv, topi = jax.lax.top_k(lg, min(top_k, lg.shape[-1]))
+    return greedy, topv, topi
+
+
+def _choose(greedy, topi, draw, temperature):
+    """The token of each row: ``draw`` [rows] indexes the row's top-k;
+    greedy under temperature <= 0."""
+    import jax.numpy as jnp
+
+    sampled = jnp.take_along_axis(topi, draw[:, None], axis=-1)[:, 0]
+    return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
 def _slot_sampler(top_k: int):
-    """The per-slot sampling chain shared by every compiled batcher step
-    (`_get_decode_step`, `_get_decode_step_paged`, `_get_spec_step`): one
-    key split + top-k categorical per emitted token per slot, greedy under
-    temperature <= 0. The speculative verify step is bit-exact vs plain
-    decode ONLY while all three sample through this single definition —
-    any fork of this code re-opens the parity hazard the CI suites
-    (tests/test_batcher_pipeline.py, tests/test_speculative.py) exist to
-    catch. generate()'s batch decode keeps its own variant: it draws one
-    categorical for the whole batch from a single pre-split key, a
-    different (batch-level) chain by design."""
+    """The per-slot sampling chain of every token the batcher emits: the
+    compiled steps (`_get_decode_step`, `_get_decode_step_paged`,
+    `_get_spec_step`) and the prompt's first token (`_get_first_token`).
+    One key split + top-k categorical per emitted token per slot, greedy
+    under temperature <= 0. The speculative verify step, a resumed
+    generation and the first token are bit-exact vs plain decode ONLY while
+    all of them sample through this single definition — any fork of this
+    code re-opens the parity hazard the CI suites
+    (tests/test_batcher_pipeline.py, tests/test_speculative.py,
+    tests/test_chaos.py) exist to catch. generate() draws a whole batch
+    from one key (`_batch_sampler`): the same candidates and the same
+    choice, a batch-level key chain by design, and the per-slot chain
+    exactly at batch 1."""
     import jax
     import jax.numpy as jnp
 
     def sample(keys, lg, temperature):
-        greedy = jnp.argmax(lg, axis=-1)
-        kk = min(top_k, lg.shape[-1])
-        topv, topi = jax.lax.top_k(lg, kk)
+        greedy, topv, topi = _top_k_candidates(lg, top_k)
 
         def one(key, tv):
             key, sub = jax.random.split(key)
@@ -130,8 +152,23 @@ def _slot_sampler(top_k: int):
                 sub, tv / jnp.maximum(temperature, 1e-6))
 
         keys, draw = jax.vmap(one)(keys, topv)
-        sampled = jnp.take_along_axis(topi, draw[:, None], axis=-1)[:, 0]
-        return keys, jnp.where(temperature <= 0.0, greedy, sampled)
+        return keys, _choose(greedy, topi, draw, temperature)
+
+    return sample
+
+
+def _batch_sampler(top_k: int):
+    """generate()'s sampler: one categorical for the whole batch from one
+    key (its first token and every step of its decode scan), over
+    `_slot_sampler`'s candidates and choice."""
+    import jax
+    import jax.numpy as jnp
+
+    def sample(logits, key, temperature):
+        greedy, topv, topi = _top_k_candidates(logits, top_k)
+        draw = jax.random.categorical(
+            key, topv / jnp.maximum(temperature, 1e-6))
+        return _choose(greedy, topi, draw, temperature)
 
     return sample
 
@@ -140,8 +177,8 @@ def fast_forward_key(seed: int, n_tokens: int):
     """The per-request rng key after ``n_tokens`` emitted tokens of a
     seeded generation — the deterministic-resume half of fleet fault
     tolerance (docs/resilience.md). The chain consumes EXACTLY one
-    first-component split per emitted token (`_sample_first`'s host draw
-    for the first token, then `_slot_sampler`'s per-step split), so
+    first-component split per emitted token (`_slot_sampler`'s, for the
+    first token and for every step alike), so
     replaying ``n_tokens`` splits from PRNGKey(seed) lands on the key the
     dead replica's slot held when it died. The caller then draws token
     ``n_tokens`` with `_slot_sampler`'s exact op order (split ->
@@ -454,7 +491,7 @@ class LLMServer(SeldonComponent):
         self.ready = False
         self._eos_override = eos_id
         self._prefill_cache: Dict[Tuple[int, int], Any] = {}
-        self._decode_cache: Dict[Tuple[int, int], Any] = {}
+        self._decode_cache: Dict[tuple, Any] = {}
         self._request_count = 0
         # decode observability (metrics.registry sync_llm drains these at
         # /metrics scrape time): per-step wall times and the KV bytes the
@@ -1137,13 +1174,7 @@ class LLMServer(SeldonComponent):
             """last_tok [b], true_len [b]; returns (tokens [b, n_steps],
             final caches — returned so donation can alias input to output)."""
 
-            def sample(logits, key):
-                greedy = jnp.argmax(logits, axis=-1)
-                k = min(top_k, logits.shape[-1])
-                topv, topi = jax.lax.top_k(logits, k)
-                draw = jax.random.categorical(key, topv / jnp.maximum(temperature, 1e-6))
-                sampled = jnp.take_along_axis(topi, draw[:, None], axis=-1)[:, 0]
-                return jnp.where(temperature <= 0.0, greedy, sampled)
+            sample = _batch_sampler(top_k)
 
             def step(carry, _):
                 caches, tok, offset, done, key = carry
@@ -1157,7 +1188,7 @@ class LLMServer(SeldonComponent):
                     cache_index=cache_index,
                 )
                 key, sub = jax.random.split(key)
-                nxt = sample(logits[:, -1].astype(jnp.float32), sub)
+                nxt = sample(logits[:, -1].astype(jnp.float32), sub, temperature)
                 nxt = jnp.where(done, eos_id, nxt)
                 done = done | (nxt == eos_id)
                 return (caches, nxt, offset + 1, done, key), nxt
@@ -1187,6 +1218,47 @@ class LLMServer(SeldonComponent):
             decode = partial(jax.jit, static_argnames=("n_steps",), **donate_kw)(decode)
         self._decode_cache[key] = decode
         return decode
+
+    def _get_first_token(self):
+        """Compiled first-token draw for the ContinuousBatcher's activation:
+        ``(logits [1, T, vocab], idx, key [2], temperature)`` ->
+        ``(token, key', row)``, all on the device. ``row`` is
+        ``logits[0, idx]`` in float32 (what a probe that asked for logits
+        gets), the token is `_slot_sampler`'s draw on that one row with the
+        request's key, and ``key'`` is the key the slot decodes on: the
+        prompt's first token leaves the device through the batcher's drain
+        like any step's, never by a sync of its own. ``idx`` is traced, so
+        one compile serves every prompt length of a chunk shape."""
+        key = ("first_token",)
+        fn = self._decode_cache.get(key)
+        if fn is not None:
+            return fn
+        import jax
+        import jax.numpy as jnp
+
+        sample = _slot_sampler(self.top_k)
+
+        @jax.jit
+        def first_token(logits, idx, key, temperature):
+            row = jax.lax.dynamic_index_in_dim(
+                logits[0], idx, axis=0, keepdims=False).astype(jnp.float32)
+            keys, tok = sample(key[None], row[None], temperature)
+            return tok[0], keys[0], row
+
+        self._decode_cache[key] = first_token
+        return first_token
+
+    def _get_first_draw(self):
+        """generate()'s first-token draw, compiled: `_batch_sampler` over
+        the prefill's last-position logits [b, vocab], the same function
+        its decode scan samples every later token with."""
+        key = ("first_draw",)
+        fn = self._decode_cache.get(key)
+        if fn is None:
+            import jax
+
+            fn = self._decode_cache[key] = jax.jit(_batch_sampler(self.top_k))
+        return fn
 
     def _forward_with_aside(self, params, tokens, **kwargs):
         """``module.apply`` for the batcher's step programs: (logits, caches,
@@ -1901,16 +1973,12 @@ class LLMServer(SeldonComponent):
             int(seed) if seed is not None else self.seed + request_index
         )
 
-        if temp <= 0.0:
-            first_tok = first_logits.argmax(-1).astype(np.int32)
-        else:
-            k = min(self.top_k, first_logits.shape[-1])
-            rng, sub = jax.random.split(rng)
-            topv = np.sort(first_logits, axis=-1)[:, -k:]
-            topi = np.argsort(first_logits, axis=-1)[:, -k:]
-            # graftlint: allow-host-sync-in-hot-path(once-per-request first-token sample on generate()'s rng chain — the per-token path stays device-resident)
-            draw = np.asarray(jax.random.categorical(sub, jnp.asarray(topv) / max(temp, 1e-6)))
-            first_tok = topi[np.arange(nb), draw].astype(np.int32)
+        # one split per emitted token, the first included, and the device
+        # sampler's order of the top-k (greedy takes no notice of the key)
+        rng, sub = jax.random.split(rng)
+        # graftlint: allow-host-sync-in-hot-path(generate() is the synchronous API: its first token is read once per request, before the decode scan is dispatched)
+        first_tok = np.asarray(self._get_first_draw()(
+            jnp.asarray(first_logits), sub, jnp.asarray(temp, jnp.float32)))
 
         out_tokens = [first_tok[:, None]]
         if max_new > 1:
@@ -2080,7 +2148,7 @@ class LLMServer(SeldonComponent):
                 # MoECounters; metrics/registry.py seldon_llm_moe_*)
                 loop_stats.update(batcher._moe.stats())
             slot_bytes = self._entry_nbytes(batcher._caches, None)
-            in_flight = len(batcher._inflight)
+            in_flight = batcher.steps_in_flight()
             inflight_hwm = batcher._inflight_hwm
             depth = batcher.pipeline_depth
             fuse = batcher.fuse_steps

@@ -113,6 +113,9 @@ def test_phases_partition_the_loop_wall():
         assert st["loop_phase_counts"][phase] > 0 and st["loop_seconds"][phase] > 0.0, phase
     assert st["loop_phase_counts"]["first_token"] == len(PROMPTS) * 2
     assert st["loop_phase_counts"]["first_token_wait"] == len(PROMPTS) * 2
+    # one read a first token, waited for or not
+    assert set(st["first_token_reads"]) == {"yes", "no"}
+    assert sum(st["first_token_reads"].values()) == len(PROMPTS) * 2
     assert st["loop_phase_counts"]["drain_wait"] == st["loop_phase_counts"]["emit"]
     # two slots, six tokens a request: the occupancy integral is positive and
     # cannot pass slots x wall
@@ -167,6 +170,9 @@ def test_queue_wait_counts_every_admission(tracing_on):
             assert series(text, "seldon_llm_queue_wait_seconds_count") == len(PROMPTS)
             assert series(text, "seldon_llm_queue_wait_seconds_sum") >= 0.0
             assert series(text, "seldon_llm_ttft_seconds_count") == len(PROMPTS)
+            assert series(text, "seldon_llm_first_token_reads_total") == len(PROMPTS)
+            assert series(text, "seldon_llm_first_token_reads_total", 'ready="no"') \
+                + series(text, "seldon_llm_first_token_reads_total", 'ready="yes"') == len(PROMPTS)
             assert series(text, "seldon_llm_loop_turns_total") > 0
             assert series(text, "seldon_llm_slots_active") == 0
             assert series(text, "seldon_llm_slot_seconds_total") > 0.0

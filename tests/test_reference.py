@@ -192,13 +192,15 @@ BF16_ATOL = 0.015
 # top-k is discrete: a probability nearer to the next one than bf16's noise
 # on it may be chosen the other way, after which the two sides compute
 # different functions. The reference reports every choice's margin. Flips were
-# seen at margins up to 9e-4 and none above; the seed below is chosen so that
+# seen at margins up to 9e-4 and none above; the seeds below (the weights', and
+# the requests': the tokens they draw are positions too) are chosen so that
 # no margin of either request is under twice that (asserted: 2.2e-3 and
-# 3.1e-3), so NO disagreement is admitted, the logits tolerance covers every
+# 4.8e-3), so NO disagreement is admitted, the logits tolerance covers every
 # position and the counts are exact. (On the chip, at 16 layers x 64 experts,
 # near-ties are certain: the benchmark's tolerance is measured with them in.)
 MIN_MARGIN = 2e-3
 SERVED_SEED = 23
+REQUEST_SEED = 17
 
 
 @pytest.fixture(scope="module")
@@ -220,7 +222,7 @@ def served():
     async def run():
         infos = [{"logits": []} for _ in prompts]
         outs = await asyncio.gather(*(
-            batcher.submit(p, n, info=i, seed=7 + j)
+            batcher.submit(p, n, info=i, seed=REQUEST_SEED + j)
             for j, (p, n, i) in enumerate(zip(prompts, budgets, infos))))
         await batcher.close()
         return outs, infos
@@ -238,7 +240,7 @@ def test_served_logits_match_reference(served):
         margin = min(float(jnp.min(layer["margin"])) for layer in routing)
         assert margin > MIN_MARGIN, (
             f"a choice of expert is within {margin:.2g} of the next: bf16 may flip it. "
-            "Pick another SERVED_SEED (the weights moved), do not widen the tolerance")
+            "Pick another SERVED_SEED or REQUEST_SEED (the weights or the draws moved), do not widen the tolerance")
         # row j is what token j was sampled from: positions len(prompt)-1 ..
         want = np.asarray(ref)[len(prompt) - 1:len(prompt) - 1 + len(out)]
         assert np.abs(want).max() > 0.3

@@ -384,6 +384,16 @@ def _build_batcher_set_slot():
                          _sds((), "int32"), _sds((2,), "uint32"))
 
 
+def _build_first_token():
+    """The prompt's first token, sampled on the device from the last
+    chunk's logits (PR 26): the activation reads nothing, so the program
+    that replaced the host-side draw must not reach the host either."""
+    s = _base_server()
+    return s._get_first_token(), (
+        _sds((1, PAGE_SIZE, s._cfg.vocab_size), "bfloat16"),
+        _sds((), "int32"), _sds((2,), "uint32"), _sds((), "float32"))
+
+
 def _build_paged_decode_step():
     s = _base_server()
     fn = s._get_decode_step_paged(SLOTS, PAGES_PER_SLOT, 1)
@@ -850,6 +860,17 @@ def all_contracts() -> List[Contract]:
                         "must be donated through the scatter",
             build=_build_batcher_insert,
             donated=(0,),
+            collectives={},
+        ),
+        Contract(
+            name="llm.first_token",
+            description="first-token draw of an activation: the step "
+                        "sampler on the last chunk's logits row, token, "
+                        "key and float32 row left on the device for "
+                        "set_slot and the drain (no host transfer: the "
+                        "admission paths read nothing)",
+            build=_build_first_token,
+            out_dtypes=((0, "s32"), (2, "f32")),
             collectives={},
         ),
         Contract(
