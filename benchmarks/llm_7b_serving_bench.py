@@ -138,7 +138,6 @@ def main() -> None:
                   batch_buckets=(1, 8), temperature=0.0, eos_id=-1,
                   continuous_batching=8, prefix_cache_size=8,
                   kv_cache_dtype=os.environ.get("KV_CACHE_DTYPE", ""),
-                  kv_cache_layout=os.environ.get("KV_CACHE_LAYOUT", ""),
                   kv_page_size=int(os.environ.get("KV_PAGE_SIZE", "0")),
                   kv_pool_pages=int(os.environ.get("KV_POOL_PAGES", "0")),
                   prefill_chunk=int(os.environ.get("PREFILL_CHUNK", "0")),
@@ -274,8 +273,7 @@ def _paged_arm(server, report, rng, vocab, plen, max_new, on_tpu) -> None:
 
     async def capacity_run():
         b = ContinuousBatcher(server, max_slots=2 * slots_dense,
-                              max_len=max_len, layout="paged",
-                              page_size=page_size, pool_pages=pool_pages,
+                              max_len=max_len, page_size=page_size, pool_pages=pool_pages,
                               prefill_chunk=chunk)
         prompts = [rng.integers(1, vocab, size=max(L, 1)).tolist()
                    for L in lens]
@@ -309,7 +307,7 @@ def _paged_arm(server, report, rng, vocab, plen, max_new, on_tpu) -> None:
     def adversary_run(chunk_size):
         async def go():
             b = ContinuousBatcher(server, max_slots=2, max_len=long_len + max_new,
-                                  layout="paged", page_size=page_size,
+                                  page_size=page_size,
                                   prefill_chunk=chunk_size)
             gaps, last = [], [None]
 
@@ -1028,8 +1026,7 @@ def _multitenant_arm(server, report, rng, vocab, plen, max_new,
 
         async def go():
             b = ContinuousBatcher(ls, max_slots=slots, max_len=mlen,
-                                  len_buckets=(plen,), layout="paged",
-                                  page_size=page_size)
+                                  len_buckets=(plen,), page_size=page_size)
             ttfts = [None] * len(reqs)
             outs = [None] * len(reqs)
             sheds = [0]
@@ -1175,7 +1172,7 @@ def _disagg_arm(server, report, rng, vocab, plen, max_new, on_tpu) -> None:
     def adversary_run(disagg):
         async def go():
             kw = dict(max_slots=2, max_len=long_len + max_new,
-                      layout="paged", page_size=page_size,
+                      page_size=page_size,
                       prefill_chunk=chunk, disaggregation=disagg)
             if disagg != "off":
                 kw["disagg_mesh"] = mesh
@@ -1314,7 +1311,7 @@ def _network_handoff_arm(server, report, rng, vocab, plen, max_new,
         async def go():
             b = ContinuousBatcher(
                 server, max_slots=clients, max_len=plen + gen,
-                layout="paged", page_size=page_size,
+                page_size=page_size,
                 disaggregation="remote_prefill", disagg_mesh=mesh,
                 handoff_transport=transport)
             # a per-token callback keeps this the batch-8 CONCURRENT

@@ -107,12 +107,8 @@ def main() -> None:
     ap.add_argument("--fuse-steps", type=int, default=0,
                     help="K fused device-side decode steps per host sync "
                          "when the admit queue is empty (0 = off)")
-    ap.add_argument("--kv-cache-layout", default="",
-                    choices=("", "paged", "dense"),
-                    help="batcher KV layout (default paged)")
     ap.add_argument("--kv-page-size", type=int, default=0,
-                    help="tokens per KV page for the paged layout "
-                         "(0 = default 64)")
+                    help="tokens per KV page (0 = default 64)")
     ap.add_argument("--kv-pool-pages", type=int, default=0,
                     help="total pages in the global pool (0 = fully "
                          "provisioned; smaller oversubscribes)")
@@ -189,7 +185,6 @@ def main() -> None:
                        len_buckets=(plen,), batch_buckets=(1, args.clients),
                        temperature=0.0, eos_id=-1,
                        kv_cache_dtype=args.kv_cache_dtype,
-                       kv_cache_layout=args.kv_cache_layout,
                        kv_page_size=args.kv_page_size,
                        kv_pool_pages=args.kv_pool_pages,
                        prefill_chunk=args.prefill_chunk,
@@ -427,9 +422,8 @@ def main() -> None:
                    "max_new_tokens": max_new, "prompt_len": plen,
                    "model": kwargs},
         "kv_cache": {"dtype": server.kv_cache_dtype,
-                     "layout": server.kv_cache_layout,
                      "bytes_per_token": kv_per_tok,
-                     # paged pool accounting (zeros when dense): resident
+                     # page pool accounting: resident
                      # HBM is pool pages, not slots x max_len
                      "pages": {k: v for k, v in server.llm_stats().items()
                                if k.startswith("kv_page")}},

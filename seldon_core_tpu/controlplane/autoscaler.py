@@ -29,7 +29,7 @@ with two actuators:
   decode pages stay slack), devices move from the decode slice to the
   prefill slice, and back when the mix shifts short.  The rebalance is
   bit-exact: workers run the server's SAME compiled prefill programs on
-  the re-split mesh (tests/test_autoscaler.py parity, dense + paged).
+  the re-split mesh (tests/test_autoscaler.py parity).
 
 Determinism discipline (docs/control-plane.md):
 

@@ -45,12 +45,12 @@ PAD_POS = 1 << 28
 KV_CACHE_DTYPES = ("bf16", "int8")
 _KV_QMAX = 127.0
 
-# KV-cache layouts. "dense" is the historical per-slot [b, max_len, ...]
-# allocation; "paged" stores KV in a global pool of fixed-size pages
-# ([pages, page_size, ...]) addressed through per-sequence block tables —
-# the vLLM/PagedAttention design (Kwon et al., SOSP 2023), which bills HBM
-# for pages actually written instead of max_len per slot.
-KV_CACHE_LAYOUTS = ("dense", "paged")
+# Two cache structures, told apart by what the caller passes: a dense
+# per-sequence [b, max_len, ...] cache (init_kv_caches: generate(), the
+# draft model) and a global pool of fixed-size pages ([pages, page_size,
+# ...]) addressed through per-sequence block tables (init_paged_kv_caches:
+# the continuous batcher) — the vLLM/PagedAttention design (Kwon et al.,
+# SOSP 2023), which bills HBM for pages actually written.
 
 # Reserved page ids in every paged pool. NULL_PAGE backs unallocated
 # block-table tail entries: its position row is PAD_POS forever (writes
@@ -73,19 +73,6 @@ def normalize_kv_cache_dtype(value) -> str:
         return "int8"
     raise ValueError(
         f"unknown kv_cache_dtype {value!r}: expected one of {KV_CACHE_DTYPES}"
-    )
-
-
-def normalize_kv_cache_layout(value) -> str:
-    """Canonical kv_cache_layout ("dense" or "paged"); raises ValueError on
-    anything else so misconfiguration fails at load() time, not inside jit."""
-    v = str(value or "paged").strip().lower()
-    if v in ("paged", "page", "block"):
-        return "paged"
-    if v in ("dense", "slot", "flat"):
-        return "dense"
-    raise ValueError(
-        f"unknown kv_cache_layout {value!r}: expected one of {KV_CACHE_LAYOUTS}"
     )
 
 

@@ -107,11 +107,10 @@ def scaling_snapshot(component: Any, batcher: Any = None,
             snap["queue_depth"] = len(sched)
         snap["steps_in_flight"] = batcher.steps_in_flight()
         snap["draining"] = bool(getattr(batcher, "draining", False))
-        if getattr(batcher, "paged", False):
-            pages = batcher.page_stats()
-            total = max(pages["kv_pages_total"], 1)
-            snap["page_pressure"] = pages["kv_pages_in_use"] / total
-            snap["page_sheds_total"] = pages["kv_page_sheds"]
+        pages = batcher.page_stats()
+        total = max(pages["kv_pages_total"], 1)
+        snap["page_pressure"] = pages["kv_pages_in_use"] / total
+        snap["page_sheds_total"] = pages["kv_page_sheds"]
         if getattr(batcher, "_remote", None) is not None:
             snap["handoff_queue_depth"] = (
                 batcher.handoff_stats()["handoff_queue_depth"])

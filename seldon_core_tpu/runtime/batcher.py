@@ -88,7 +88,7 @@ their devices and ``jax.device_put`` the written KV straight onto the
 decode device, and the admission path here stages remote jobs and
 consumes finished handoffs instead of prefilling locally: one donated
 jitted scatter imports the staged pages into the slot's pool pages
-(``_get_handoff_import``; dense handoffs reuse ``insert``), then the slot
+(``_get_handoff_import``), then the slot
 commits exactly as a local admission would. Because the prefill programs
 and the sampling chain are shared with the local path, remote-prefill
 serving is bit-exact against single-slice serving (tests/test_disagg.py);
@@ -123,8 +123,7 @@ phase that covers them on the profiler's own clock, and adds its
 (``seldon_llm_loop_seconds_total{phase}``). Always on: an inactive
 annotation and two clock reads per phase against a turn of milliseconds.
 
-Paged KV cache (PR 7): with ``kv_cache_layout="paged"`` (the default) the
-dense ``[S, max_len, ...]`` slot pool is replaced by a GLOBAL pool of
+Paged KV cache (PR 7): the slots' KV lives in a GLOBAL pool of
 fixed-size KV pages plus a device-resident per-slot block table — the
 vLLM/PagedAttention design (Kwon et al., SOSP 2023). HBM is billed for
 pages actually written, so a deliberately undersized pool
@@ -136,7 +135,7 @@ decode dispatches (Sarathi-Serve; Agrawal et al., OSDI 2024), so a
 2k-token prompt never stalls in-flight decodes for its whole compile
 bucket. Page bookkeeping is host-side (PageAllocator, lock-guarded);
 block-table updates are jitted device ops that serialize behind in-flight
-steps in device program order, exactly like the dense ``insert``.
+steps in device program order.
 """
 
 from __future__ import annotations
@@ -155,14 +154,12 @@ from seldon_core_tpu.models.transformer import (
     PAD_POS,
     RESERVED_PAGES,
     TRASH_PAGE,
-    normalize_kv_cache_layout,
 )
 from seldon_core_tpu.runtime.flight import (
     EV_FIRST_TOKEN,
     EV_HANDOFF_IMPORT,
     EV_HANDOFF_STAGED,
     EV_PAGE_GROW,
-    EV_PREFILL,
     EV_PREFILL_CHUNK,
     EV_PREFIX_HIT,
     EV_RESUME,
@@ -430,7 +427,7 @@ class _PrefillJob:
 class _RemoteJob:
     """One admission staged on the prefill slice (disaggregated serving):
     the slot reserved for it, the (already truncated) prompt, the
-    decode-side pages allocated for the import (paged layout; ``row`` is
+    decode-side pages allocated for the import (``row`` is
     the NULL-padded host block row those pages form, led by
     ``prefix_pages`` shared radix-trie pages the worker never recomputes),
     and the request bookkeeping the consume path needs to commit the
@@ -498,7 +495,7 @@ class _Slot:
         # clamp the fused-K block so it never overruns max_new/max_len
         self.gen = 0
         self.disp_new = 0
-        # paged layout: the slot's OWNED page ids (host mirror of the
+        # the slot's OWNED page ids (host mirror of the
         # owned tail of its block-table row — freed, or adopted by the
         # radix trie, at release), the SHARED trie pages its row leads
         # with (radix prefix hit: pinned at admission, unpinned at
@@ -925,7 +922,6 @@ class ContinuousBatcher:
         len_buckets: Optional[Sequence[int]] = None,
         pipeline_depth: Optional[int] = None,
         fuse_steps: Optional[int] = None,
-        layout: Optional[str] = None,
         page_size: Optional[int] = None,
         pool_pages: Optional[int] = None,
         prefill_chunk: Optional[int] = None,
@@ -1044,45 +1040,38 @@ class ContinuousBatcher:
                     "spec_mode='draft' needs the server loaded with a "
                     "draft model (draft_model= / draft_model_uri=)")
             self._spec = SpecController(self.S, self.spec_k)
-        # KV layout: paged (global page pool + per-slot block tables) or the
-        # historical dense slot pool. max_len keeps its requested value —
-        # truncation/budget semantics are layout-independent — and the
-        # block-table view simply spans ceil(max_len/page_size) pages (the
-        # past-max_len tail of the last page is never written and its
-        # PAD_POS rows are never attended).
-        if layout is None:
-            layout = getattr(server, "kv_cache_layout", "dense")
-        self.paged = normalize_kv_cache_layout(layout) == "paged"
-        if self.paged:
-            ps = int(page_size if page_size is not None else
-                     getattr(server, "kv_page_size", 0) or 0) or DEFAULT_PAGE_SIZE
-            if ps <= 0:
-                raise ValueError(f"kv_page_size={ps} must be positive")
-            self.page_size = ps
-            self.n_pages = -(-self.max_len // ps)   # pages per slot
-            pool = int(pool_pages if pool_pages is not None else
-                       getattr(server, "kv_pool_pages", 0) or 0)
-            # 0 = fully provisioned (every slot can reach max_len at once —
-            # never sheds on pages); smaller pools oversubscribe
-            self.pool_pages = pool or (self.S * self.n_pages + RESERVED_PAGES)
-            if self.pool_pages - RESERVED_PAGES < self.n_pages:
-                raise ValueError(
-                    f"kv_pool_pages={self.pool_pages} cannot hold even one "
-                    f"max_len sequence ({self.n_pages} pages of {ps} tokens "
-                    f"+ {RESERVED_PAGES} reserved)")
-            chunk = int(prefill_chunk if prefill_chunk is not None else
-                        getattr(server, "prefill_chunk", 0) or 0)
-            self.prefill_chunk = chunk or DEFAULT_PREFILL_CHUNK
-            self._allocator = PageAllocator(self.pool_pages, ps)
+        # KV store: a global page pool + per-slot block tables. max_len keeps
+        # its requested value and the block-table view simply spans
+        # ceil(max_len/page_size) pages (the past-max_len tail of the last
+        # page is never written and its PAD_POS rows are never attended).
+        ps = int(page_size if page_size is not None else
+                 getattr(server, "kv_page_size", 0) or 0) or DEFAULT_PAGE_SIZE
+        if ps <= 0:
+            raise ValueError(f"kv_page_size={ps} must be positive")
+        self.page_size = ps
+        self.n_pages = -(-self.max_len // ps)   # pages per slot
+        pool = int(pool_pages if pool_pages is not None else
+                   getattr(server, "kv_pool_pages", 0) or 0)
+        # 0 = fully provisioned (every slot can reach max_len at once —
+        # never sheds on pages); smaller pools oversubscribe
+        self.pool_pages = pool or (self.S * self.n_pages + RESERVED_PAGES)
+        if self.pool_pages - RESERVED_PAGES < self.n_pages:
+            raise ValueError(
+                f"kv_pool_pages={self.pool_pages} cannot hold even one "
+                f"max_len sequence ({self.n_pages} pages of {ps} tokens "
+                f"+ {RESERVED_PAGES} reserved)")
+        chunk = int(prefill_chunk if prefill_chunk is not None else
+                    getattr(server, "prefill_chunk", 0) or 0)
+        self.prefill_chunk = chunk or DEFAULT_PREFILL_CHUNK
+        self._allocator = PageAllocator(self.pool_pages, ps)
         # Radix prefix cache (runtime/radix.py, docs/performance.md "Radix
-        # prefix cache"): paged layout + prefix caching opted in. The trie
+        # prefix cache"): prefix caching opted in. The trie
         # shares pool pages between cached prefixes and live slots
         # (refcounted, copy-on-write), so a hit costs block-table entries
         # instead of a page gather/copy; completed slots insert their
-        # blocks back in place. The dense layout keeps no batcher-side
-        # prefix reuse (its slots pre-reserve whole caches).
+        # blocks back in place.
         self._radix = None
-        if self.paged and int(getattr(server, "prefix_cache_size", 0)) > 0:
+        if int(getattr(server, "prefix_cache_size", 0)) > 0:
             from seldon_core_tpu.models.transformer import \
                 kv_cache_bytes_per_token
             from seldon_core_tpu.runtime.radix import RadixPrefixCache
@@ -1169,61 +1158,36 @@ class ContinuousBatcher:
         from functools import partial
 
         server, cfg = self.server, self.server._cfg
-        # slot caches inherit the server's KV storage format (int8 halves
+        # the pool inherits the server's KV storage format (int8 halves
         # the per-step attention read traffic — the dominant b8 term in
         # benchmarks/DECODE_NOTES.md)
-        if self.paged:
-            from seldon_core_tpu.models.transformer import (
-                PAD_POS, init_paged_kv_caches)
+        from seldon_core_tpu.models.transformer import init_paged_kv_caches
 
-            self._caches = jax.jit(
-                lambda: init_paged_kv_caches(
-                    cfg, self.pool_pages, self.page_size, server.kv_cache_dtype)
-            )()
-        else:
-            self._caches = jax.jit(
-                lambda: init_kv_caches(cfg, self.S, self.max_len, server.kv_cache_dtype)
-            )()
+        self._caches = jax.jit(
+            lambda: init_paged_kv_caches(
+                cfg, self.pool_pages, self.page_size, server.kv_cache_dtype)
+        )()
         self._cache_nbytes = sum(
             int(getattr(leaf, "nbytes", 0)) for leaf in jax.tree.leaves(self._caches)
         )
-        if self.paged:
-            # the read is the XLA gather on every backend (models/
-            # transformer.py; the Pallas page-streaming kernel does not
-            # lower for a TPU) — said here so a server's log names it
-            logger.info(
-                "paged KV pool: %d pages x %d tokens, %s, %.2f GB; "
-                "decode read: gather", self.pool_pages, self.page_size,
-                server.kv_cache_dtype, self._cache_nbytes / 1e9)
+        # the read is the XLA gather on every backend (models/
+        # transformer.py; the Pallas page-streaming kernel does not
+        # lower for a TPU) — said here so a server's log names it
+        logger.info(
+            "paged KV pool: %d pages x %d tokens, %s, %.2f GB; "
+            "decode read: gather", self.pool_pages, self.page_size,
+            server.kv_cache_dtype, self._cache_nbytes / 1e9)
 
-        if self.paged:
-            # Paged pool: no insert — chunked prefill writes straight into
-            # the pool through the slot's block-table row. The device block
-            # table (one row per slot) starts all-TRASH so inactive slots'
-            # ride-along decode writes land in the trash page; rows switch
-            # to real pages at activation and back to trash at release.
-            # Every table/pos mutation is a donated jit, so program order on
-            # the device stream serializes it behind in-flight steps exactly
-            # like the dense insert (see module docstring).
-            self._block_tables = jnp.full(
-                (self.S, self.n_pages), TRASH_PAGE, jnp.int32)
-            self._trash_row = jnp.full((self.n_pages,), TRASH_PAGE, jnp.int32)
-        else:
-            # donate the big slot cache through both mutating jits (insert
-            # and the decode step): self._caches is reassigned from the
-            # output each time, so XLA aliases the buffers and updates in
-            # place instead of copying S x max_len of KV per call. These
-            # donations are verified at the COMPILED level
-            # (input_output_alias) by the batcher.insert / batcher.set_slot
-            # / llm.decode_step_s4 contracts in tools/hlolint — a
-            # cache-structure change that silently breaks the aliasing fails
-            # CI, not a 7B perf round. (small is NOT donated: its 1-slot
-            # buffers can alias no output, XLA would just drop it.)
-            @partial(jax.jit, donate_argnums=(0,))
-            def insert(big, small, slot):
-                return jax.tree.map(lambda b, s: b.at[slot].set(s[0]), big, small)
-
-            self._insert = insert
+        # No insert: chunked prefill writes straight into the pool through
+        # the slot's block-table row. The device block table (one row per
+        # slot) starts all-TRASH so inactive slots' ride-along decode writes
+        # land in the trash page; rows switch to real pages at activation
+        # and back to trash at release. Every table/pos mutation is a
+        # donated jit, so program order on the device stream serializes it
+        # behind in-flight steps (see module docstring).
+        self._block_tables = jnp.full(
+            (self.S, self.n_pages), TRASH_PAGE, jnp.int32)
+        self._trash_row = jnp.full((self.n_pages,), TRASH_PAGE, jnp.int32)
 
         # jitted table/slot-state ops are process-shared singletons
         # (_page_table_ops): a fresh batcher reuses the compiled code of
@@ -1249,8 +1213,10 @@ class ContinuousBatcher:
             if self.spec_mode == "draft":
                 # The draft model's KV is always DENSE [S, max_len]: the
                 # draft is small by construction, so paging it would buy
-                # nothing and cost a second allocator. Prompt prefill lands
-                # through the same insert idiom as the dense target path.
+                # nothing and cost a second allocator. Its prompt prefill
+                # lands through a donated insert: the big cache is
+                # reassigned from the output, so XLA updates it in place
+                # (the batcher.insert contract in tools/hlolint).
                 dcfg = server._draft_cfg
                 self._draft_caches = jax.jit(
                     lambda: init_kv_caches(dcfg, self.S, self.max_len))()
@@ -1317,11 +1283,8 @@ class ContinuousBatcher:
             receiver_addr = self._receiver.addr
         self._remote = PrefillWorkerPool(
             server, devices, default,
-            layout="paged" if self.paged else "dense",
-            max_len=self.max_len,
-            page_size=self.page_size if self.paged else 0,
-            n_pages=self.n_pages if self.paged else 0,
-            prefill_chunk=self.prefill_chunk if self.paged else 0,
+            max_len=self.max_len, page_size=self.page_size,
+            n_pages=self.n_pages, prefill_chunk=self.prefill_chunk,
             queue=queue, transport=self.handoff_transport,
             receiver_addr=receiver_addr)
         self._transfer = self._remote.queue
@@ -1339,7 +1302,7 @@ class ContinuousBatcher:
           finish staged jobs and publish them before their threads join;
         - workers run the server's own cached compiled prefill programs,
           so WHERE prefill runs changes, never which KV bits come out
-          (tests/test_autoscaler.py parity, dense + paged).
+          (tests/test_autoscaler.py parity).
 
         Returns False when disaggregation is off, the split is already
         there, or the requested split is infeasible (decode must keep the
@@ -1362,11 +1325,8 @@ class ContinuousBatcher:
         old = self._remote
         new_pool = PrefillWorkerPool(
             self.server, mesh.prefill_devices, default,
-            layout="paged" if self.paged else "dense",
-            max_len=self.max_len,
-            page_size=self.page_size if self.paged else 0,
-            n_pages=self.n_pages if self.paged else 0,
-            prefill_chunk=self.prefill_chunk if self.paged else 0,
+            max_len=self.max_len, page_size=self.page_size,
+            n_pages=self.n_pages, prefill_chunk=self.prefill_chunk,
             queue=self._transfer, transport=old.transport,
             receiver_addr=old.receiver_addr)
         self.disagg_mesh = mesh
@@ -1430,11 +1390,10 @@ class ContinuousBatcher:
             1 for s in self._slots if s.active or s.prefilling)
         waves = -(-queued // max(self.S, 1))
         hint = base * max(waves, 1)
-        if self.paged:
-            total, in_use, _ = self._allocator.stats()
-            usable = max(total - RESERVED_PAGES, 1)
-            if in_use / usable >= 0.9:
-                hint *= 2
+        total, in_use, _ = self._allocator.stats()
+        usable = max(total - RESERVED_PAGES, 1)
+        if in_use / usable >= 0.9:
+            hint *= 2
         # the cap must never undercut an explicitly configured base: a
         # 60s floor stays 60s, it does not become 30s
         return float(min(max(hint, base), max(30.0, base)))
@@ -1606,11 +1565,11 @@ class ContinuousBatcher:
             n = len(self.server._tokenizer.encode(prompt))
         else:
             n = int(np.asarray(prompt).size)
-        # _admit's exact prompt cap: beyond it the batcher keeps the tail
-        # (generate() only truncates past the model context, which is
-        # covered by the same min) — and the slot cache must leave the
-        # whole token budget behind the prompt (the batcher stops at the
-        # cache edge; generate() never clips)
+        # admission's exact prompt cap (_truncate_prompt): beyond it the
+        # batcher keeps the tail (generate() only truncates past the model
+        # context, which is covered by the same min) — and the slot cache
+        # must leave the whole token budget behind the prompt (the batcher
+        # stops at the cache edge; generate() never clips)
         plen = min(_bucket(n, self.len_buckets), self.server._cfg.max_seq_len,
                    self.max_len - 1)
         max_new = int(max_new_tokens or self.server.max_new_tokens)
@@ -1726,7 +1685,7 @@ class ContinuousBatcher:
                      t_arrival: Optional[float] = None,
                      req: Optional[Any] = None,
                      info: Optional[dict] = None, asides: Sequence = ()):
-        """Activation, shared by dense admission, paged activation and a
+        """Activation, shared by local admission (``_activate``) and a
         consumed handoff: draw the first token from ``logits[0, idx]`` on
         the device, thread it and the new occupant's state into the device
         arrays, and queue a ``_FirstToken`` record behind the steps already
@@ -1781,7 +1740,7 @@ class ContinuousBatcher:
             # positions 0..L-1, the prefill-sampled first token at L
             # (L <= max_len - 1 — _truncate_prompt leaves decode room).
             # Overwriting the WHOLE row retires the previous occupant's
-            # tokens, exactly like the dense cache insert.
+            # tokens.
             row_np = np.zeros((self.hist_len,), np.int32)
             row_np[:L] = ids
             self._hist = self._set_hist_row(
@@ -1853,7 +1812,7 @@ class ContinuousBatcher:
         """spec_mode='draft': prefill the slot's DENSE draft-model cache
         over the (already truncated) prompt and insert it whole — the
         fresh cache covers all max_len positions, so the previous
-        occupant's rows are retired exactly like the dense target insert.
+        occupant's rows are retired.
         The draft's logits are discarded: drafting always restarts from
         the last accepted TARGET token inside the verify step."""
         import jax.numpy as jnp
@@ -1870,48 +1829,6 @@ class ContinuousBatcher:
                        jnp.asarray(pos))
         self._draft_caches = self._draft_insert(
             self._draft_caches, dcache, jnp.asarray(i, jnp.int32))
-
-    @_in_phase("admit")
-    def _admit(self, req) -> bool:
-        """Dense-layout admission: one-shot prefill into a 1-sequence cache,
-        jitted insert into the free slot. ``req`` is the scheduler's
-        PendingRequest (tenant/SLO/adapter identity rides it)."""
-        import time
-
-        import jax.numpy as jnp
-
-        free = next((i for i, s in enumerate(self._slots) if not s.active), None)
-        if free is None:
-            return False
-        ids, plen = self._truncate_prompt(req.ids, req.max_new, req.info)
-        L = len(ids)
-        self._begin(free, req, L)
-        tokens = np.zeros((1, plen), np.int32)
-        positions = np.full((1, plen), PAD_POS, np.int32)
-        tokens[0, :L] = ids
-        positions[0, :L] = np.arange(L)
-
-        t0 = time.perf_counter()
-        if self._adapters is not None:
-            prefill = self.server._get_prefill(1, plen, self.max_len,
-                                               lora=True)
-            logits, cache1 = prefill(
-                self.server._params, jnp.asarray(tokens),
-                jnp.asarray(positions), self._adapters.pool(),
-                jnp.asarray([req.adapter_id], jnp.int32))
-        else:
-            prefill = self.server._get_prefill(1, plen, self.max_len)
-            logits, cache1 = prefill(self.server._params, jnp.asarray(tokens),
-                                     jnp.asarray(positions))
-        self._caches = self._insert(self._caches, cache1, free)
-        if self._flight is not None:
-            # dispatch wall (enqueue-only, like a chunk's)
-            self._flight.record(free, EV_PREFILL, tokens=L,
-                                dur_s=time.perf_counter() - t0)
-        self._commit_slot(free, logits, L - 1, req.seed, L, req.max_new,
-                          req.fut, req.on_token, ids=ids,
-                          t_arrival=req.t_arrival, req=req, info=req.info)
-        return True
 
     def _begin(self, slot: int, req, prompt_tokens: int) -> None:
         """A slot is reserved for ``req``: its queue wait ends here (counted
@@ -1957,51 +1874,47 @@ class ContinuousBatcher:
             return False
         ids, plen = self._truncate_prompt(req.ids, req.max_new, req.info)
         L = len(ids)
-        pages: List[int] = []
         shared: List[int] = []
-        row = None
         prefix_staged = None
         k0 = 0
-        n0 = 0
-        if self.paged:
-            n0 = -(-L // self.page_size)
-            if self._radix is not None:
-                # whole blocks only: the worker's suffix prefill starts at
-                # a page boundary and partial-block COW stays a local
-                # (decode-side) move — capped at L-1 so the worker always
-                # computes the first-token logits
-                # leaklint: allow-leak-on-path(full_blocks_only=True guarantees cow is None — no cow pin is ever taken, so the discarded third element holds nothing)
-                k0, shared, _ = self._radix.match_and_pin(
-                    ids, limit=L - 1, full_blocks_only=True)
-            got = self._alloc_pages(n0 - len(shared))
-            if got is None:
-                if shared:
-                    self._allocator.free(shared)  # drop pins: retry later
-                # same liveness posture as _admit_begin: with no tenant in
-                # flight anywhere (active, local prefill, or staged remote
-                # — remote slots hold prefilling=True), nothing will ever
-                # free a page, so shed now instead of queueing forever
-                if not any(s.active or s.prefilling for s in self._slots):
-                    self._shed_queued_request(
-                        req,
-                        f"admission needs {n0} KV pages "
-                        f"(pool capacity {self._allocator.capacity}, "
-                        f"{self._allocator.stats()[1]} in use)")
-                    return True
-                return False
-            pages = got
-            row = np.full((self.n_pages,), NULL_PAGE, np.int32)
-            row[:n0] = shared + pages
+        n0 = -(-L // self.page_size)
+        if self._radix is not None:
+            # whole blocks only: the worker's suffix prefill starts at
+            # a page boundary and partial-block COW stays a local
+            # (decode-side) move — capped at L-1 so the worker always
+            # computes the first-token logits
+            # leaklint: allow-leak-on-path(full_blocks_only=True guarantees cow is None — no cow pin is ever taken, so the discarded third element holds nothing)
+            k0, shared, _ = self._radix.match_and_pin(
+                ids, limit=L - 1, full_blocks_only=True)
+        got = self._alloc_pages(n0 - len(shared))
+        if got is None:
             if shared:
-                # export the matched blocks as a power-of-two page bucket
-                # (handoff-shaped: RESERVED leading rows, then pages) the
-                # worker imports into its staging pool — D2D forward
-                # shipment of already-computed KV, never a recompute
-                b = pow2_bucket(len(shared), self.n_pages)
-                idx = np.full((RESERVED_PAGES + b,), TRASH_PAGE, np.int32)
-                idx[RESERVED_PAGES:RESERVED_PAGES + len(shared)] = shared
-                prefix_staged = self._export_pages(self._caches,
-                                                   jnp.asarray(idx))
+                self._allocator.free(shared)  # drop pins: retry later
+            # same liveness posture as _admit_begin: with no tenant in
+            # flight anywhere (active, local prefill, or staged remote
+            # — remote slots hold prefilling=True), nothing will ever
+            # free a page, so shed now instead of queueing forever
+            if not any(s.active or s.prefilling for s in self._slots):
+                self._shed_queued_request(
+                    req,
+                    f"admission needs {n0} KV pages "
+                    f"(pool capacity {self._allocator.capacity}, "
+                    f"{self._allocator.stats()[1]} in use)")
+                return True
+            return False
+        pages = got
+        row = np.full((self.n_pages,), NULL_PAGE, np.int32)
+        row[:n0] = shared + pages
+        if shared:
+            # export the matched blocks as a power-of-two page bucket
+            # (handoff-shaped: RESERVED leading rows, then pages) the
+            # worker imports into its staging pool — D2D forward
+            # shipment of already-computed KV, never a recompute
+            b = pow2_bucket(len(shared), self.n_pages)
+            idx = np.full((RESERVED_PAGES + b,), TRASH_PAGE, np.int32)
+            idx[RESERVED_PAGES:RESERVED_PAGES + len(shared)] = shared
+            prefix_staged = self._export_pages(self._caches,
+                                               jnp.asarray(idx))
         from seldon_core_tpu.runtime.disagg import PrefillRequest
 
         slot = self._slots[free]
@@ -2048,8 +1961,8 @@ class ContinuousBatcher:
     @_in_phase("handoff")
     def _consume_handoffs(self):
         """Drain every READY handoff: import the staged KV into the slot
-        pool (one donated jitted scatter through the slot's block row;
-        dense handoffs reuse the insert), then commit the slot exactly as
+        pool (one donated jitted scatter through the slot's block row),
+        then commit the slot exactly as
         a local admission would — same first-token sampling chain, so
         tokens are bit-identical to single-slice serving."""
         import time
@@ -2084,34 +1997,30 @@ class ContinuousBatcher:
                 self._flight.extend(job.slot, h.events)
             try:
                 t0 = time.perf_counter()
-                if self.paged:
-                    import jax
+                import jax
 
-                    n0 = -(-job.L // self.page_size)
-                    # only the SUFFIX pages travelled (the prefix blocks
-                    # never left this device — they are shared trie pages
-                    # already in the row's lead); import targets row
-                    # entries past them
-                    n_suffix = n0 - job.prefix_pages
-                    # the worker shipped a power-of-two page bucket; the
-                    # buffer's own shape names the compile to import it
-                    staged_pages = (jax.tree.leaves(h.staged)[0].shape[0]
-                                    - RESERVED_PAGES)
-                    imp = self._get_handoff_import(staged_pages)
-                    row_suffix = np.full((self.n_pages,), NULL_PAGE,
-                                         np.int32)
-                    row_suffix[:n_suffix] = job.row[
-                        job.prefix_pages:job.prefix_pages + n_suffix]
-                    self._caches = imp(self._caches, h.staged,
-                                       jnp.asarray(row_suffix),
-                                       jnp.asarray(n_suffix, jnp.int32))
-                    self._block_tables = self._set_block_row(
-                        self._block_tables,
-                        jnp.asarray(job.slot, jnp.int32),
-                        jnp.asarray(job.row))
-                else:
-                    self._caches = self._insert(self._caches, h.staged,
-                                                job.slot)
+                n0 = -(-job.L // self.page_size)
+                # only the SUFFIX pages travelled (the prefix blocks
+                # never left this device — they are shared trie pages
+                # already in the row's lead); import targets row
+                # entries past them
+                n_suffix = n0 - job.prefix_pages
+                # the worker shipped a power-of-two page bucket; the
+                # buffer's own shape names the compile to import it
+                staged_pages = (jax.tree.leaves(h.staged)[0].shape[0]
+                                - RESERVED_PAGES)
+                imp = self._get_handoff_import(staged_pages)
+                row_suffix = np.full((self.n_pages,), NULL_PAGE,
+                                     np.int32)
+                row_suffix[:n_suffix] = job.row[
+                    job.prefix_pages:job.prefix_pages + n_suffix]
+                self._caches = imp(self._caches, h.staged,
+                                   jnp.asarray(row_suffix),
+                                   jnp.asarray(n_suffix, jnp.int32))
+                self._block_tables = self._set_block_row(
+                    self._block_tables,
+                    jnp.asarray(job.slot, jnp.int32),
+                    jnp.asarray(job.row))
             except Exception as e:
                 # poisoned handoff (malformed staged payload, import
                 # raising): fail THIS request and free its slot + staging
@@ -2157,8 +2066,7 @@ class ContinuousBatcher:
         if job is None:
             return
         self._transfer.cancel(job_id)
-        if self.paged:
-            self._allocator.count_shed()
+        self._allocator.count_shed()
         if job.req is not None:
             self._pending.count_shed(job.req.tenant, job.req.slo_class)
             # adapters reject disaggregation at load() today, so this is
@@ -2438,8 +2346,8 @@ class ContinuousBatcher:
                 victim = self._pick_page_victim()
                 if victim is None:
                     # sole tenant outgrew the pool: stop generating with the
-                    # tokens it has — the same cache-edge truncation posture
-                    # as the dense layout's max_len stop, never an error
+                    # tokens it has — the same posture as the cache edge's
+                    # max_len stop, never an error
                     logger.warning(
                         "kv page pool exhausted with no shed candidate: "
                         "slot %d ends at %d generated tokens", i, slot.n_new)
@@ -2469,7 +2377,7 @@ class ContinuousBatcher:
                 jnp.asarray(page, jnp.int32))
             slot.pages.append(page)
         if self._flight is not None and slot.covered_pages() > n0_pages:
-            # mid-decode page growth is the paged layout's stall risk: the
+            # mid-decode page growth is the pool's stall risk: the
             # allocation (and any shed it forced) ran between this slot's
             # dispatches — the timeline shows it where the gap opened
             self._flight.record(i, EV_PAGE_GROW,
@@ -2538,8 +2446,7 @@ class ContinuousBatcher:
 
     def _shed_slot(self, i: int, why: str):
         """Shed an ACTIVE slot mid-decode to relieve page exhaustion: its
-        tokens are discarded and the client gets 503 + Retry-After (the
-        dense layout can never hit this — its slots pre-reserve max_len)."""
+        tokens are discarded and the client gets 503 + Retry-After."""
         slot = self._slots[i]
         self._allocator.count_shed()
         self._pending.count_shed(slot.tenant, slot.slo_class)
@@ -2662,32 +2569,26 @@ class ContinuousBatcher:
                 self._adapter_ids, _jnp.asarray(i, _jnp.int32),
                 _jnp.asarray(0, _jnp.int32))
         slot.adapter_id = 0
-        if self.paged:
-            if slot.pages:
-                self._allocator.free(slot.pages)
-                slot.pages = []
-            if slot.shared:
-                self._allocator.free(slot.shared)  # unpin: refs -= 1
-                slot.shared = []
-            import jax.numpy as jnp
+        if slot.pages:
+            self._allocator.free(slot.pages)
+            slot.pages = []
+        if slot.shared:
+            self._allocator.free(slot.shared)  # unpin: refs -= 1
+            slot.shared = []
+        import jax.numpy as jnp
 
-            self._block_tables = self._set_block_row(
-                self._block_tables, jnp.asarray(i, jnp.int32), self._trash_row)
+        self._block_tables = self._set_block_row(
+            self._block_tables, jnp.asarray(i, jnp.int32), self._trash_row)
 
     def page_stats(self, radix_stats: Optional[dict] = None) -> dict:
         """Pool gauges for llm_stats/metrics: in-use/total pages plus
         internal fragmentation (1 - tokens written / page tokens held) —
         the slack the page-size knob trades against table overhead.
-        All-zero under the dense layout (no pool exists). Each allocated
-        page's tokens count exactly ONCE: slots count only their OWNED
+        Each allocated page's tokens count exactly ONCE: slots count only their OWNED
         pages' tokens, trie-held blocks (shared ones included — sharing
         is the trie's page) count as full blocks via ``radix_stats``
         (pass a precomputed ``RadixPrefixCache.stats()`` snapshot to
         avoid a second O(nodes) walk per scrape)."""
-        if not self.paged:
-            return {"kv_pages_total": 0, "kv_pages_in_use": 0,
-                    "kv_page_size": 0, "kv_page_fragmentation": 0.0,
-                    "kv_page_sheds": 0}
         total, in_use, sheds = self._allocator.stats()
         ps = self.page_size
         used_tokens = 0
@@ -2839,40 +2740,31 @@ class ContinuousBatcher:
 
     def _dispatch_plain(self, t0: float) -> bool:
         k = self._pick_k()
-        if self.paged:
-            # grow every eligible slot's pages to cover this dispatch's k
-            # writes FIRST — positions dispatched_pos()..dispatched_pos()+k-1
-            # (the device's next_pos equals dispatched_pos()). An exhaustion
-            # shed inside the loop can deactivate a LATER slot of this
-            # snapshot, so re-check activity before touching each one:
-            # growing a released slot would allocate pages nothing owns.
-            for i in self._dispatch_eligible():
-                if self._slots[i].active:
-                    self._ensure_slot_pages(
-                        i, self._slots[i].dispatched_pos() + k - 1)
-            if not self._dispatch_eligible():
-                return False
+        # grow every eligible slot's pages to cover this dispatch's k
+        # writes FIRST — positions dispatched_pos()..dispatched_pos()+k-1
+        # (the device's next_pos equals dispatched_pos()). An exhaustion
+        # shed inside the loop can deactivate a LATER slot of this
+        # snapshot, so re-check activity before touching each one:
+        # growing a released slot would allocate pages nothing owns.
+        for i in self._dispatch_eligible():
+            if self._slots[i].active:
+                self._ensure_slot_pages(
+                    i, self._slots[i].dispatched_pos() + k - 1)
+        if not self._dispatch_eligible():
+            return False
         # adapted steps (llm.lora_decode_step): the pool/id pair rides at
-        # the end of either signature, un-donated — same idiom as the
+        # the end of the signature, un-donated — same idiom as the
         # spec-step dispatch below
         lora = self._adapters is not None
         extra = () if not lora else (self._adapters.pool(),
                                      self._adapter_ids)
-        if self.paged:
-            fn = self.server._get_decode_step_paged(
-                self.S, self.n_pages, k, lora=lora)
-            (self._caches, self._last_tok, self._next_pos, self._keys,
-             toks, aside) = fn(
-                self.server._params, self._caches, self._last_tok,
-                self._next_pos, self._keys, self._temp,
-                self._block_tables, *extra)
-        else:
-            fn = self.server._get_decode_step(self.S, self.max_len, k,
-                                              lora=lora)
-            (self._caches, self._last_tok, self._next_pos, self._keys,
-             toks, aside) = fn(
-                self.server._params, self._caches, self._last_tok,
-                self._next_pos, self._keys, self._temp, *extra)
+        fn = self.server._get_decode_step_paged(
+            self.S, self.n_pages, k, lora=lora)
+        (self._caches, self._last_tok, self._next_pos, self._keys,
+         toks, aside) = fn(
+            self.server._params, self._caches, self._last_tok,
+            self._next_pos, self._keys, self._temp,
+            self._block_tables, *extra)
         snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
         for i, _ in snapshot:
             self._slots[i].disp_new += k
@@ -2900,57 +2792,38 @@ class ContinuousBatcher:
                       s.max_new - s.disp_new - 1,
                       (self.max_len - 1) - s.dispatched_pos())
             caps[i] = max(int(cap), 0)
-        if self.paged:
-            # provision pages to the step's FURTHEST possible write
-            # (next_pos + cap); an exhaustion shed inside the loop can
-            # deactivate a later slot of this snapshot — re-check activity
-            # (same discipline as the plain dispatch)
-            for i in self._dispatch_eligible():
-                if self._slots[i].active:
-                    self._ensure_slot_pages(
-                        i, self._slots[i].dispatched_pos() + int(caps[i]))
-            if not self._dispatch_eligible():
-                return False
-            fn = self.server._get_spec_step(
-                self.S, K, self.hist_len, mode=self.spec_mode,
-                layout="paged", n_pages=self.n_pages,
-                lora=self._adapters is not None)
-        else:
-            fn = self.server._get_spec_step(
-                self.S, K, self.hist_len, mode=self.spec_mode,
-                layout="dense", lora=self._adapters is not None)
+        # provision pages to the step's FURTHEST possible write
+        # (next_pos + cap); an exhaustion shed inside the loop can
+        # deactivate a later slot of this snapshot — re-check activity
+        # (same discipline as the plain dispatch)
+        for i in self._dispatch_eligible():
+            if self._slots[i].active:
+                self._ensure_slot_pages(
+                    i, self._slots[i].dispatched_pos() + int(caps[i]))
+        if not self._dispatch_eligible():
+            return False
+        fn = self.server._get_spec_step(
+            self.S, K, self.hist_len, mode=self.spec_mode,
+            n_pages=self.n_pages, lora=self._adapters is not None)
         cap_dev = jnp.asarray(caps)
         draft = self.spec_mode == "draft"
         # adapted verify (llm.lora_verify_step): the pool/id pair rides at
-        # the end of every signature variant, un-donated
+        # the end of either signature, un-donated
         extra = () if self._adapters is None else (
             self._adapters.pool(), self._adapter_ids)
-        if self.paged and draft:
+        if draft:
             (self._caches, self._last_tok, self._next_pos, self._keys,
              self._hist, toks, acc, self._draft_caches) = fn(
                 self.server._params, self._caches, self._last_tok,
                 self._next_pos, self._keys, self._temp, self._block_tables,
                 self._hist, cap_dev, self.server._draft_params,
                 self._draft_caches, *extra)
-        elif self.paged:
+        else:
             (self._caches, self._last_tok, self._next_pos, self._keys,
              self._hist, toks, acc) = fn(
                 self.server._params, self._caches, self._last_tok,
                 self._next_pos, self._keys, self._temp, self._block_tables,
                 self._hist, cap_dev, *extra)
-        elif draft:
-            (self._caches, self._last_tok, self._next_pos, self._keys,
-             self._hist, toks, acc, self._draft_caches) = fn(
-                self.server._params, self._caches, self._last_tok,
-                self._next_pos, self._keys, self._temp, self._hist,
-                cap_dev, self.server._draft_params, self._draft_caches,
-                *extra)
-        else:
-            (self._caches, self._last_tok, self._next_pos, self._keys,
-             self._hist, toks, acc) = fn(
-                self.server._params, self._caches, self._last_tok,
-                self._next_pos, self._keys, self._temp, self._hist,
-                cap_dev, *extra)
         snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
         booked = {}
         for i, _ in snapshot:
@@ -3193,11 +3066,9 @@ class ContinuousBatcher:
                         # can be in flight while decode keeps dispatching
                         admitted = await asyncio.to_thread(
                             self._admit_remote, req)
-                    elif self.paged:
+                    else:
                         admitted = await asyncio.to_thread(
                             self._admit_begin, req)
-                    else:
-                        admitted = await asyncio.to_thread(self._admit, req)
                     if not admitted:
                         # deadline-aware preemption: an interactive head
                         # blocked on occupied slots may push ONE staged

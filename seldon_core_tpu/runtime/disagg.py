@@ -19,19 +19,17 @@ This module is the host half of that split:
   are freed exactly once even when a shed races the worker's put (the
   interleavings tests/test_schedules.py explores).
 - ``PrefillWorker`` — one worker thread per prefill-slice device: it keeps
-  a committed copy of the params and (paged layout) a single-sequence
-  staging page pool on its device, runs the server's own compiled prefill
-  programs there (``_get_prefill`` dense, ``_get_prefill_chunk`` paged —
-  the SAME programs local admission compiles, so the written KV is
-  bit-identical), then moves the result onto the decode device with
+  a committed copy of the params and a single-sequence staging page pool
+  on its device, runs the server's own compiled chunk program there
+  (``_get_prefill_chunk`` — the SAME program local admission compiles, so
+  the written KV is bit-identical), then moves the result onto the decode device with
   ``jax.device_put`` — a direct device-to-device copy, no host round trip
   for the KV — and publishes the handoff.
 - ``PrefillWorkerPool`` — M workers behind least-backlog dispatch.
 
 The decode side (runtime/batcher.py ``disaggregation="remote_prefill"``)
 imports a ready handoff into its slot pool with one donated jitted scatter
-(``ContinuousBatcher._get_handoff_import``; dense handoffs reuse the
-existing ``insert``), pinned by the ``disagg.import_pages`` hlolint
+(``ContinuousBatcher._get_handoff_import``), pinned by the ``disagg.import_pages`` hlolint
 contract: zero infeed/outfeed, donation aliasing intact, bytes within the
 committed budget.
 """
@@ -84,8 +82,8 @@ def normalize_disaggregation(value) -> str:
 
 class PrefillRequest:
     """What a worker needs to prefill one admission: the (already
-    truncated) prompt, its dense prefill bucket, and the page count the
-    decode side allocated for it (paged layout). ``record_events`` asks
+    truncated) prompt, its length bucket (caps the chunk width), and the
+    page count the decode side allocated for it. ``record_events`` asks
     the worker to stamp flight-recorder stage events into the Handoff
     (set when the decode side's recorder is running).
 
@@ -263,12 +261,12 @@ class PrefillWorker:
     to the decode device.
 
     The worker keeps a committed copy of the params on its device
-    (``LLMServer._params_on``) and, under the paged layout, a
+    (``LLMServer._params_on``) and a
     single-sequence staging page pool (``RESERVED_PAGES + n_pages`` pages
     — pages 2.. back the sequence; the batcher's block-row width is
     reused so the chunk program has the batcher's exact shape contract).
     Prefill itself is the SAME compiled program local admission runs
-    (``_get_prefill`` / ``_get_prefill_chunk``), just dispatched on the
+    (``_get_prefill_chunk``), just dispatched on the
     prefill device — which is what makes remote-prefill serving
     bit-exact against single-slice serving (tests/test_disagg.py).
 
@@ -277,9 +275,9 @@ class PrefillWorker:
     the worker thread after ``__init__``."""
 
     def __init__(self, server: Any, queue: TransferQueue, device: Any,
-                 decode_device: Any, *, layout: str, max_len: int,
-                 page_size: int = 0, n_pages: int = 0,
-                 prefill_chunk: int = 0, name: str = "prefill-worker",
+                 decode_device: Any, *, max_len: int, page_size: int,
+                 n_pages: int, prefill_chunk: int,
+                 name: str = "prefill-worker",
                  transport: str = "device",
                  receiver_addr: Optional[tuple] = None):
         if transport not in HANDOFF_TRANSPORTS:
@@ -293,7 +291,6 @@ class PrefillWorker:
         self.queue = queue
         self.device = device
         self.decode_device = decode_device
-        self.layout = layout
         self.max_len = int(max_len)
         self.page_size = int(page_size)
         self.n_pages = int(n_pages)
@@ -363,7 +360,7 @@ class PrefillWorker:
 
         if self._params is None:
             self._params = self.server._params_on(self.device)
-        if self.layout == "paged" and self._staging is None:
+        if self._staging is None:
             from seldon_core_tpu.models.transformer import RESERVED_PAGES
 
             # server-cached compile: M workers share one staging-init
@@ -377,10 +374,7 @@ class PrefillWorker:
 
         t0 = time.perf_counter()
         self._ensure_state()
-        if self.layout == "paged":
-            staged, first_logits = self._prefill_paged(req)
-        else:
-            staged, first_logits = self._prefill_dense(req)
+        staged, first_logits = self._prefill_paged(req)
         import jax
 
         t1 = time.perf_counter()
@@ -498,26 +492,6 @@ class PrefillWorker:
                         self._sock = None
                 if attempt:
                     raise
-
-    def _prefill_dense(self, req: PrefillRequest):
-        """One-shot dense prefill at the request's bucket — the same
-        compiled program (and therefore the same KV bits) as the local
-        dense admission path (``ContinuousBatcher._admit``)."""
-        import jax.numpy as jnp
-
-        from seldon_core_tpu.models.transformer import PAD_POS
-
-        L = len(req.ids)
-        toks = np.zeros((1, req.plen), np.int32)
-        pos = np.full((1, req.plen), PAD_POS, np.int32)
-        toks[0, :L] = req.ids
-        pos[0, :L] = np.arange(L)
-        fn = self.server._get_prefill(1, req.plen, self.max_len)
-        logits, cache1 = fn(self._params, jnp.asarray(toks),
-                            jnp.asarray(pos))
-        # graftlint: allow-host-sync-in-hot-path(admission-time sync on the PREFILL worker thread, once per request: the first sampled token's logits must reach the host; the decode slice never blocks on it)
-        first_logits = np.asarray(logits[0, L - 1]).astype(np.float32)
-        return cache1, first_logits
 
     def _prefill_paged(self, req: PrefillRequest):
         """Chunked prefill into the staging pool through a staging block
@@ -862,8 +836,8 @@ class PrefillWorkerPool:
     more devices than workers just leaves slices idle."""
 
     def __init__(self, server: Any, devices: Sequence, decode_device: Any,
-                 *, layout: str, max_len: int, page_size: int = 0,
-                 n_pages: int = 0, prefill_chunk: int = 0,
+                 *, max_len: int, page_size: int, n_pages: int,
+                 prefill_chunk: int,
                  queue: Optional[TransferQueue] = None,
                  transport: str = "device",
                  receiver_addr: Optional[tuple] = None):
@@ -877,7 +851,7 @@ class PrefillWorkerPool:
         self.receiver_addr = receiver_addr
         self.workers = [
             PrefillWorker(server, self.queue, dev, decode_device,
-                          layout=layout, max_len=max_len,
+                          max_len=max_len,
                           page_size=page_size, n_pages=n_pages,
                           prefill_chunk=prefill_chunk,
                           name=f"prefill-worker-{i}",

@@ -142,14 +142,11 @@ def replica_load(component: Any) -> Tuple[float, float]:
     b = svc.batcher
     queued = len(b._pending) + sum(
         1 for s in b._slots if s.active or s.prefilling)
-    pages = 0.0
-    if getattr(b, "paged", False):
-        from seldon_core_tpu.models.transformer import RESERVED_PAGES
+    from seldon_core_tpu.models.transformer import RESERVED_PAGES
 
-        total, in_use, _ = b._allocator.stats()
-        usable = max(total - RESERVED_PAGES, 1)
-        pages = in_use / usable
-    return (float(queued), pages)
+    total, in_use, _ = b._allocator.stats()
+    usable = max(total - RESERVED_PAGES, 1)
+    return (float(queued), in_use / usable)
 
 
 class _ResumeEntry:

@@ -49,7 +49,6 @@ from seldon_core_tpu.tracing import Span, TraceContext, Tracer, now as wall_now
 # event kinds (timeline "kind" field / span names); slot reservation and
 # queue wait are segment FIELDS (begin()), not ring events
 EV_PREFILL_CHUNK = "prefill_chunk"  # one chunked-prefill dispatch
-EV_PREFILL = "prefill"              # one-shot dense prefill
 EV_PREFIX_HIT = "prefix_hit"        # radix prefix-cache hit: tokens served
 #                                     from shared pages (fields: tokens
 #                                     matched, blocks = block-table entries

@@ -297,7 +297,6 @@ class LLMServer(SeldonComponent):
         quantize: str = "",
         param_dtype: str = "",
         kv_cache_dtype: str = "",
-        kv_cache_layout: str = "",
         kv_page_size: int = 0,
         kv_pool_pages: int = 0,
         prefill_chunk: int = 0,
@@ -367,15 +366,11 @@ class LLMServer(SeldonComponent):
         # read traffic that dominates the b8 decode step —
         # benchmarks/DECODE_NOTES.md). Normalized + validated at load().
         self.kv_cache_dtype = kv_cache_dtype
-        # KV-cache layout for the continuous batcher's slot pool: "paged"
-        # (default — global pool of fixed-size KV pages addressed through
-        # per-slot block tables, so HBM is billed for pages actually written
-        # and admission prefill can run in chunks interleaved with decode)
-        # or "dense" (the historical [S, max_len, ...] allocation, kept for
-        # A/B and parity testing). Normalized + validated at load().
-        # generate()'s per-request caches stay dense either way.
-        self.kv_cache_layout = kv_cache_layout
-        # Tokens per KV page (paged layout; 0 = default 64). The batcher
+        # The continuous batcher's KV store is a global pool of fixed-size
+        # pages addressed through per-slot block tables: HBM is billed for
+        # pages actually written and admission prefill runs in chunks
+        # interleaved with decode. generate()'s per-request caches are dense.
+        # kv_page_size: tokens per KV page (0 = default 64); the batcher
         # rounds its cache length up to a page multiple.
         self.kv_page_size = int(kv_page_size)
         # Total pages in the global pool (0 = fully provisioned: every slot
@@ -384,7 +379,7 @@ class LLMServer(SeldonComponent):
         # byte, with page-exhaustion shed (503 + Retry-After) as the relief
         # valve — docs/performance.md "Paged KV".
         self.kv_pool_pages = int(kv_pool_pages)
-        # Admission prefill chunk size (paged layout; 0 = default 256).
+        # Admission prefill chunk size (0 = default 256).
         # A long prompt prefills chunk-by-chunk between decode steps so
         # admission never stalls serving for a whole compile bucket.
         self.prefill_chunk = int(prefill_chunk)
@@ -581,12 +576,8 @@ class LLMServer(SeldonComponent):
         # Validate dtype knobs HERE, with a clear ValueError, instead of
         # letting an unknown string explode later inside a jitted cast or
         # cache init (where the traceback names nothing actionable).
-        from seldon_core_tpu.models.transformer import normalize_kv_cache_layout
-
         # racelint: allow-unguarded-shared-state(load()-time config normalization: runs once, before any serving thread or batcher loop exists — nothing can interleave with it)
         self.kv_cache_dtype = normalize_kv_cache_dtype(self.kv_cache_dtype)
-        # racelint: allow-unguarded-shared-state(load()-time config normalization: runs once, before any serving thread or batcher loop exists — nothing can interleave with it)
-        self.kv_cache_layout = normalize_kv_cache_layout(self.kv_cache_layout)
         if self.kv_page_size < 0:
             raise ValueError(
                 f"kv_page_size={self.kv_page_size} must be >= 0 "
@@ -1101,13 +1092,8 @@ class LLMServer(SeldonComponent):
                 self._prefix_index.remove(evicted_key)
                 self._prefix_bytes -= entry[-1]
 
-    def _get_prefill(self, b: int, plen: int, max_len: int,
-                     lora: bool = False):
-        """``lora=True`` compiles the adapted variant: two extra trailing
-        args (adapter_pool pytree, adapter_ids [b]) apply each sequence's
-        low-rank q/o/FFN delta inside the same program
-        (models/transformer.py ``lora_delta``)."""
-        key = (b, plen, max_len, lora)
+    def _get_prefill(self, b: int, plen: int, max_len: int):
+        key = (b, plen, max_len)
         fn = self._prefill_cache.get(key)
         if fn is not None:
             return fn
@@ -1120,22 +1106,12 @@ class LLMServer(SeldonComponent):
 
         kvd = self.kv_cache_dtype
 
-        if lora:
-            def prefill(params, tokens, positions, adapter_pool, adapter_ids):
-                caches = init_kv_caches(cfg, tokens.shape[0], max_len, kvd)
-                logits, caches = module.apply(
-                    deq(params), tokens, positions=positions, caches=caches,
-                    cache_index=0, adapters=adapter_pool,
-                    adapter_ids=adapter_ids,
-                )
-                return logits, caches
-        else:
-            def prefill(params, tokens, positions):
-                caches = init_kv_caches(cfg, tokens.shape[0], max_len, kvd)
-                logits, caches = module.apply(
-                    deq(params), tokens, positions=positions, caches=caches, cache_index=0
-                )
-                return logits, caches
+        def prefill(params, tokens, positions):
+            caches = init_kv_caches(cfg, tokens.shape[0], max_len, kvd)
+            logits, caches = module.apply(
+                deq(params), tokens, positions=positions, caches=caches, cache_index=0
+            )
+            return logits, caches
 
         cache_shardings = self._cache_shardings(b, max_len)
         if cache_shardings is not None:
@@ -1277,84 +1253,6 @@ class LLMServer(SeldonComponent):
         moe_tokens, moe_stats = moe_routing_stats(sown["moe"], self._cfg)
         return logits, caches, {"moe_tokens": moe_tokens, "moe_stats": moe_stats}
 
-    def _get_decode_step(self, slots: int, max_len: int, k: int = 1,
-                         lora: bool = False):
-        """Compiled pipelined decode step for the ContinuousBatcher: runs
-        ``k`` decode micro-steps device-side (``lax.scan``) over ``slots``
-        cache slots, with the sampling state IN the loop — per-slot rng
-        keys, last token and next position all live on device and are
-        threaded from output to input across calls, so the host never
-        round-trips token/position state through NumPy between steps.
-
-        Returns ``(caches, last_tok, next_pos, keys, tokens[slots, k],
-        aside)``: ``aside["logits"]`` [k, slots, vocab] float32 is what each
-        token was sampled from (the host fetches a slot's rows only for a
-        request that asked for logits), plus ``_forward_with_aside``'s
-        entries, each with a leading [k].
-        The cache pytree, position array and key array are donated (the
-        per-step scatter updates in place; the caller reassigns from the
-        outputs). ``last_tok`` is deliberately NOT donated: the stacked
-        ``tokens`` output can alias the final-token carry buffer (reshape
-        bitcasts), and the host reads ``tokens`` while the next step — which
-        would invalidate a donated ``last_tok`` — is already in flight.
-
-        Per-slot sampling reproduces generate()'s chain exactly (split then
-        top-k categorical per step, one key per sequence), so a slot seeded
-        like a generate() request emits identical tokens — the parity bar in
-        tests/test_batcher_pipeline.py. The donation/transfer/dtype shape of
-        the COMPILED step is pinned by the llm.decode_step_s4 contract in
-        tools/hlolint (docs/static-analysis.md): changing the carry
-        structure here must keep every donated leaf aliasable or CI goes
-        red on the dropped donation."""
-        key = ("pipestep", slots, max_len, k, lora)
-        fn = self._decode_cache.get(key)
-        if fn is not None:
-            return fn
-        import jax
-        import jax.numpy as jnp
-
-        top_k = self.top_k
-        forward = self._forward_with_aside
-
-        def core(params, caches, last_tok, next_pos, keys, temperature,
-                 adapter_pool, adapter_ids):
-            sample = _slot_sampler(top_k)
-
-            def step(carry, _):
-                caches, tok, pos, keys = carry
-                logits, caches, aside = forward(
-                    params, tok[:, None], positions=pos[:, None],
-                    caches=caches, cache_index=pos,
-                    adapters=adapter_pool, adapter_ids=adapter_ids,
-                )
-                last = logits[:, -1].astype(jnp.float32)
-                keys, nxt = sample(keys, last, temperature)
-                return (caches, nxt, pos + 1, keys), (nxt, {"logits": last, **aside})
-
-            (caches, tok, pos, keys), (toks, aside) = jax.lax.scan(
-                step, (caches, last_tok, next_pos, keys), None, length=k)
-            return caches, tok, pos, keys, toks.T, aside  # tokens [slots, k]
-
-        if lora:
-            # adapted variant (llm.lora_decode_step hlolint contract): the
-            # pool/id args are NOT donated — the pool is the registry's
-            # long-lived shared state and the ids array is host-managed
-            # like the block tables
-            @partial(jax.jit, donate_argnums=(1, 3, 4))
-            def decode_step(params, caches, last_tok, next_pos, keys,
-                            temperature, adapter_pool, adapter_ids):
-                return core(params, caches, last_tok, next_pos, keys,
-                            temperature, adapter_pool, adapter_ids)
-        else:
-            @partial(jax.jit, donate_argnums=(1, 3, 4))
-            def decode_step(params, caches, last_tok, next_pos, keys,
-                            temperature):
-                return core(params, caches, last_tok, next_pos, keys,
-                            temperature, None, None)
-
-        self._decode_cache[key] = decode_step
-        return decode_step
-
     def _get_prefill_chunk(self, chunk: int, n_pages: int,
                            lora: bool = False):
         """Compiled chunked-prefill step for the PAGED continuous batcher:
@@ -1466,21 +1364,38 @@ class LLMServer(SeldonComponent):
 
     def _get_decode_step_paged(self, slots: int, n_pages: int, k: int = 1,
                                lora: bool = False):
-        """Compiled pipelined decode step over the PAGED pool: identical
-        sampling state machine to ``_get_decode_step`` (per-slot rng keys,
-        device-resident token/position state, k-step ``lax.scan``), with the
-        KV read/write routed through per-slot block tables instead of a
-        dense [S, max_len] slot cache. The block tables are an extra input,
-        NOT donated and NOT modified by the step — the host updates them
-        through the batcher's jitted table ops between dispatches, and
+        """Compiled pipelined decode step for the ContinuousBatcher: runs
+        ``k`` decode micro-steps device-side (``lax.scan``) over ``slots``
+        slots of the page pool, with the sampling state IN the loop —
+        per-slot rng keys, last token and next position all live on device
+        and are threaded from output to input across calls, so the host
+        never round-trips token/position state through NumPy between steps.
+        The KV read/write is routed through per-slot block tables: an extra
+        input, NOT donated and NOT modified by the step — the host updates
+        them through the batcher's jitted table ops between dispatches, and
         device program order serializes those against in-flight steps.
 
-        Returns ``(pools, last_tok, next_pos, keys, tokens[slots, k], aside)``
-        with the same donation shape as the dense step (pools, next_pos, keys
-        donated; last_tok not, for the same stacked-output aliasing reason).
-        Token parity with the dense step is bit-exact — the pool is read
-        with the XLA gather (tests/test_paged_kv.py); the compiled-form contract is
-        pinned as llm.paged_decode_step_s4 in tools/hlolint."""
+        Returns ``(pools, last_tok, next_pos, keys, tokens[slots, k],
+        aside)``: ``aside["logits"]`` [k, slots, vocab] float32 is what each
+        token was sampled from (the host fetches a slot's rows only for a
+        request that asked for logits), plus ``_forward_with_aside``'s
+        entries, each with a leading [k]. The pools, position array and key
+        array are donated (the per-step scatter updates in place; the caller
+        reassigns from the outputs). ``last_tok`` is deliberately NOT
+        donated: the stacked ``tokens`` output can alias the final-token
+        carry buffer (reshape bitcasts), and the host reads ``tokens`` while
+        the next step — which would invalidate a donated ``last_tok`` — is
+        already in flight.
+
+        Per-slot sampling reproduces generate()'s chain exactly (split then
+        top-k categorical per step, one key per sequence), so a slot seeded
+        like a generate() request emits identical tokens — the parity bar in
+        tests/test_batcher_pipeline.py; the pool is read with the XLA gather
+        (tests/test_paged_kv.py). The donation/transfer/dtype shape of the
+        COMPILED step is pinned as llm.paged_decode_step_s4 in tools/hlolint
+        (docs/static-analysis.md): changing the carry structure here must
+        keep every donated leaf aliasable or CI goes red on the dropped
+        donation."""
         key = ("pagedstep", slots, n_pages, k, lora)
         fn = self._decode_cache.get(key)
         if fn is not None:
@@ -1532,8 +1447,8 @@ class LLMServer(SeldonComponent):
         return decode_step
 
     def _get_draft_prefill(self, b: int, plen: int, max_len: int):
-        """DRAFT-model prompt prefill into a fresh dense cache (the dense
-        batcher's draft admission): same shape contract as ``_get_prefill``
+        """DRAFT-model prompt prefill into a fresh dense cache (the
+        batcher's draft admission, ``_draft_admit``): same shape contract as ``_get_prefill``
         but over the draft module; the logits are discarded — only the
         written KV matters, drafting always restarts from the last accepted
         target token."""
@@ -1560,8 +1475,8 @@ class LLMServer(SeldonComponent):
         return fn
 
     def _get_spec_step(self, slots: int, spec_k: int, hist_len: int, *,
-                       mode: str = "ngram", layout: str = "paged",
-                       n_pages: int = 0, lora: bool = False):
+                       mode: str = "ngram", n_pages: int,
+                       lora: bool = False):
         """Compiled speculative decode step for the ContinuousBatcher: ONE
         dispatch drafts up to K tokens per slot, verifies them in a single
         K+1-token target forward, and accepts the longest prefix that
@@ -1576,8 +1491,8 @@ class LLMServer(SeldonComponent):
         followed it are proposed. ``mode="draft"`` runs K+1 sequential
         greedy forwards of the small draft model over its own cache
         (drafting consumes NO slot rng — the chain belongs to the target).
-        The draft cache is always DENSE [S, max_len] regardless of the
-        target layout: the draft is small by construction, so paging it
+        The draft cache is always DENSE [S, max_len] while the target's
+        is the page pool: the draft is small by construction, so paging it
         would buy nothing and cost a second allocator. Either way the
         per-slot ``draft_cap`` input clamps the offer (the batcher's
         acceptance-rate controller + cache-edge headroom).
@@ -1610,8 +1525,7 @@ class LLMServer(SeldonComponent):
         pinned by the llm.verify_step_k4 / llm.draft_verify_step_k4
         contracts in tools/hlolint (zero host transfers, intact aliasing,
         cost bands)."""
-        key = ("specstep", slots, spec_k, hist_len, mode, layout, n_pages,
-               lora)
+        key = ("specstep", slots, spec_k, hist_len, mode, n_pages, lora)
         fn = self._decode_cache.get(key)
         if fn is not None:
             return fn
@@ -1629,7 +1543,6 @@ class LLMServer(SeldonComponent):
         H = int(hist_len)
         NGRAM = max(int(self.spec_ngram) or 3, 1)
         draft_mode = mode == "draft"
-        paged = layout == "paged"
         if draft_mode:
             dmodule = self._draft_module
             ddeq = self._draft_dequant
@@ -1706,16 +1619,10 @@ class LLMServer(SeldonComponent):
             # stay base-model — proposals are only proposals, and the
             # chain-exact accept loop below enforces the ADAPTED target's
             # distribution either way
-            if bt is None:
-                logits, caches = module.apply(
-                    deq(params), tokens_in, positions=positions,
-                    caches=caches, cache_index=next_pos,
-                    adapters=apool, adapter_ids=aids)
-            else:
-                logits, caches = module.apply(
-                    deq(params), tokens_in, positions=positions,
-                    caches=caches, block_tables=bt,
-                    adapters=apool, adapter_ids=aids)
+            logits, caches = module.apply(
+                deq(params), tokens_in, positions=positions,
+                caches=caches, block_tables=bt,
+                adapters=apool, adapter_ids=aids)
             lg32 = logits.astype(jnp.float32)
 
             # chain-exact accept loop: sample column j -> token j+1; rng
@@ -1748,7 +1655,8 @@ class LLMServer(SeldonComponent):
             # reject repair: columns a..K lost verification — reset their
             # position rows to PAD_POS (unattendable now, overwritten when
             # the true tokens reach those positions). Surviving columns map
-            # to PAD_POS write targets (dense: dropped; paged: trash).
+            # to PAD_POS write targets (the dense draft cache: dropped; the
+            # pool: trash).
             rcols = jnp.arange(1, K + 1)
             rej = rcols[None, :] >= a[:, None]
             rpos = jnp.where(rej, next_pos[:, None] + rcols[None, :], PAD_POS)
@@ -1773,7 +1681,7 @@ class LLMServer(SeldonComponent):
         # lora=True appends (adapter_pool, adapter_ids) to each signature
         # (un-donated, like the block tables); the donation shape of the
         # serving state is identical to the base variant
-        if paged and draft_mode and lora:
+        if draft_mode and lora:
             @partial(jax.jit, donate_argnums=(1, 3, 4, 7, 10))
             def spec_step(params, pools, last_tok, next_pos, keys,
                           temperature, block_tables, hist, draft_cap,
@@ -1783,7 +1691,7 @@ class LLMServer(SeldonComponent):
                             temperature, hist, draft_cap, block_tables,
                             draft_params, draft_caches, adapter_pool,
                             adapter_ids)
-        elif paged and draft_mode:
+        elif draft_mode:
             @partial(jax.jit, donate_argnums=(1, 3, 4, 7, 10))
             def spec_step(params, pools, last_tok, next_pos, keys,
                           temperature, block_tables, hist, draft_cap,
@@ -1791,7 +1699,7 @@ class LLMServer(SeldonComponent):
                 return core(params, pools, last_tok, next_pos, keys,
                             temperature, hist, draft_cap, block_tables,
                             draft_params, draft_caches)
-        elif paged and lora:
+        elif lora:
             @partial(jax.jit, donate_argnums=(1, 3, 4, 7))
             def spec_step(params, pools, last_tok, next_pos, keys,
                           temperature, block_tables, hist, draft_cap,
@@ -1799,44 +1707,13 @@ class LLMServer(SeldonComponent):
                 return core(params, pools, last_tok, next_pos, keys,
                             temperature, hist, draft_cap, block_tables,
                             None, None, adapter_pool, adapter_ids)
-        elif paged:
+        else:
             @partial(jax.jit, donate_argnums=(1, 3, 4, 7))
             def spec_step(params, pools, last_tok, next_pos, keys,
                           temperature, block_tables, hist, draft_cap):
                 return core(params, pools, last_tok, next_pos, keys,
                             temperature, hist, draft_cap, block_tables,
                             None, None)
-        elif draft_mode and lora:
-            @partial(jax.jit, donate_argnums=(1, 3, 4, 6, 9))
-            def spec_step(params, caches, last_tok, next_pos, keys,
-                          temperature, hist, draft_cap, draft_params,
-                          draft_caches, adapter_pool, adapter_ids):
-                return core(params, caches, last_tok, next_pos, keys,
-                            temperature, hist, draft_cap, None,
-                            draft_params, draft_caches, adapter_pool,
-                            adapter_ids)
-        elif draft_mode:
-            @partial(jax.jit, donate_argnums=(1, 3, 4, 6, 9))
-            def spec_step(params, caches, last_tok, next_pos, keys,
-                          temperature, hist, draft_cap, draft_params,
-                          draft_caches):
-                return core(params, caches, last_tok, next_pos, keys,
-                            temperature, hist, draft_cap, None,
-                            draft_params, draft_caches)
-        elif lora:
-            @partial(jax.jit, donate_argnums=(1, 3, 4, 6))
-            def spec_step(params, caches, last_tok, next_pos, keys,
-                          temperature, hist, draft_cap, adapter_pool,
-                          adapter_ids):
-                return core(params, caches, last_tok, next_pos, keys,
-                            temperature, hist, draft_cap, None, None, None,
-                            adapter_pool, adapter_ids)
-        else:
-            @partial(jax.jit, donate_argnums=(1, 3, 4, 6))
-            def spec_step(params, caches, last_tok, next_pos, keys,
-                          temperature, hist, draft_cap):
-                return core(params, caches, last_tok, next_pos, keys,
-                            temperature, hist, draft_cap, None, None, None)
 
         self._decode_cache[key] = spec_step
         return spec_step
@@ -2157,8 +2034,7 @@ class LLMServer(SeldonComponent):
                 # ONE trie walk per scrape: page_stats reuses the snapshot
                 radix_stats = batcher._radix.stats()
                 prefix_stats.update(radix_stats)
-            if getattr(batcher, "paged", False):
-                page_stats = batcher.page_stats(radix_stats=radix_stats)
+            page_stats = batcher.page_stats(radix_stats=radix_stats)
             if getattr(batcher, "spec_mode", "off") != "off":
                 spec_stats.update(batcher.spec_stats())
             if getattr(batcher, "_remote", None) is not None:
@@ -2171,8 +2047,7 @@ class LLMServer(SeldonComponent):
             prefix_bytes = self._prefix_bytes
         return {
             "kv_cache_dtype": self.kv_cache_dtype,
-            "kv_cache_layout": self.kv_cache_layout,
-            # paged-pool accounting (zeros under the dense layout):
+            # page-pool accounting (zeros until a batcher is attached):
             # in-use/total page gauge pair plus internal fragmentation —
             # the slack between tokens written and pages held
             **page_stats,

@@ -305,8 +305,7 @@ class HandoffPoisoner:
     Wraps every PrefillWorker's ``_prefill_one``: the prefill itself runs
     and publishes normally, but the handoff arrives READY with ``staged``
     replaced by an unimportable payload (a bare string has no pages to
-    slice dense-insert or tree-import, so both layouts raise inside
-    ``_consume_handoffs``). Poisons the first ``first_n`` handoffs, then
+    tree-import, so the import raises inside ``_consume_handoffs``). Poisons the first ``first_n`` handoffs, then
     passes everything through untouched — one bad handoff amid good ones,
     the shape the batcher's containment must survive.
 
@@ -397,8 +396,8 @@ class LeakSweep:
     ========================  =============================================
 
     ``boundaries()`` returns the subset applicable to the batcher's
-    configuration (paged? radix? adapters? disaggregated? fleet engine?),
-    so one parametrized test sweeps every layout without dead arms.
+    configuration (radix? adapters? disaggregated? fleet engine?),
+    so one parametrized test sweeps every configuration without dead arms.
     """
 
     POISON = "leaksweep-poisoned-kv"
@@ -416,10 +415,9 @@ class LeakSweep:
         b, out = self.batcher, []
         if getattr(b, "_adapters", None) is not None:
             out.append("adapter-pin")
-        if getattr(b, "paged", False):
-            out.append("page-alloc")
-            if getattr(b, "_radix", None) is not None:
-                out.append("radix-cow")
+        out.append("page-alloc")
+        if getattr(b, "_radix", None) is not None:
+            out.append("radix-cow")
         if getattr(b, "_remote", None) is not None:
             out.append("prefill-stage")
             out.append("handoff-import")
@@ -545,16 +543,15 @@ class LeakSweep:
         still > 1 while no slot references it)."""
         b = self.batcher
         out = {}
-        if getattr(b, "paged", False):
-            _, in_use, _ = b._allocator.stats()
-            cached = 0
-            shared_pins = 0
-            if b._radix is not None:
-                rs = b._radix.stats()
-                cached = rs["prefix_cached_blocks"]
-                shared_pins = rs["prefix_shared_pages"]
-            out["slot_pages"] = in_use - cached
-            out["shared_pins"] = shared_pins
+        _, in_use, _ = b._allocator.stats()
+        cached = 0
+        shared_pins = 0
+        if b._radix is not None:
+            rs = b._radix.stats()
+            cached = rs["prefix_cached_blocks"]
+            shared_pins = rs["prefix_shared_pages"]
+        out["slot_pages"] = in_use - cached
+        out["shared_pins"] = shared_pins
         if getattr(b, "_adapters", None) is not None:
             out["adapter_pins"] = sum(
                 b._adapters.stats()["adapter_pins"].values())

@@ -440,11 +440,11 @@ def _add_generate_routes(app: web.Application, component: Any,
 
             info: dict = {}
             if body.get("logits"):
-                if stream or svc is None or not getattr(svc.batcher, "paged", False) \
+                if stream or svc is None \
                         or svc.batcher.spec_mode != "off":
                     raise SeldonError(
                         "'logits' is a probe of the batched path: a plain "
-                        "(not streamed) request to a server with paged "
+                        "(not streamed) request to a server with "
                         "continuous batching, no per-request temperature "
                         "and no speculation", status_code=400)
                 info["logits"] = []   # the batcher appends a row per token

@@ -3,7 +3,7 @@
 The acceptance bar this file pins (CI "Multi-tenant suite"):
 heterogeneous-adapter parity — a continuous batch mixing >= 3 adapters
 plus the identity is BIT-EXACT per slot against each adapter served solo
-(greedy + seeded-sampled, dense + paged layouts, bf16 + int8 KV, and the
+(greedy + seeded-sampled, bf16 + int8 KV, and the
 speculative verify path), the identity slots additionally bit-exact
 against plain base-model generate(); plus the registry's load/evict/
 refcount discipline (k/v rejection, pinned-eviction refusal, pool
@@ -82,15 +82,14 @@ def make_registry(max_adapters=6):
     return reg
 
 
-def batch_serve(server, prompts, adapters, *, layout, seed=None,
+def batch_serve(server, prompts, adapters, *, seed=None,
                 max_new=6, slots=None):
     """Serve all prompts CONCURRENTLY through one batcher (mixed batch)
     and return the per-request token lists."""
 
     async def go():
         b = ContinuousBatcher(server, max_slots=slots or len(prompts),
-                              max_len=40, len_buckets=(8,), layout=layout,
-                              page_size=8)
+                              max_len=40, len_buckets=(8,), page_size=8)
         outs = await asyncio.gather(*[
             b.submit(p, max_new_tokens=max_new, adapter=a, seed=seed,
                      tenant=a or "base")
@@ -101,10 +100,10 @@ def batch_serve(server, prompts, adapters, *, layout, seed=None,
     return asyncio.run(go())
 
 
-def solo_serve(server, prompt, adapter, *, layout, seed=None, max_new=6):
+def solo_serve(server, prompt, adapter, *, seed=None, max_new=6):
     """The same request alone in a fresh single-slot batcher — the solo
     reference the mixed batch must match bit-for-bit."""
-    return batch_serve(server, [prompt], [adapter], layout=layout,
+    return batch_serve(server, [prompt], [adapter],
                        seed=seed, max_new=max_new, slots=1)[0]
 
 
@@ -213,7 +212,7 @@ def test_load_uri_roundtrip(tmp_path):
     aid = s.adapter_registry.load_uri("stored", str(d))
     assert s.adapter_registry.resolve("stored") == aid
     # the stored artifact serves
-    out_uri = solo_serve(s, PROMPTS[0], "stored", layout="paged")
+    out_uri = solo_serve(s, PROMPTS[0], "stored")
     assert len(out_uri) == 6
     # and lands the IDENTICAL pool row an in-memory load would: the wq
     # factors cast to the pool dtype, everything else zeros, scale =
@@ -242,7 +241,7 @@ def test_unknown_adapter_and_class_rejected_at_submit():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=1, max_len=40, len_buckets=(8,),
-                              layout="paged", page_size=8)
+                              page_size=8)
         with pytest.raises(SeldonError, match="unknown adapter"):
             await b.submit(PROMPTS[0], max_new_tokens=2, adapter="nope")
         with pytest.raises(SeldonError, match="SLO class"):
@@ -256,23 +255,18 @@ def test_unknown_adapter_and_class_rejected_at_submit():
 # heterogeneous-adapter parity (the acceptance bar)
 # ---------------------------------------------------------------------------
 
-# tier-1 runs one representative per axis (paged+greedy+bf16,
-# paged+seeded+int8, dense+seeded+bf16 — each param builds and compiles
-# its own server, ~25 s apiece against the 870 s verify budget); the
+# tier-1 runs one representative (seeded+int8 — each param builds and
+# compiles its own server, ~25 s apiece against the 870 s verify budget); the
 # slow-marked rest of the matrix runs UNFILTERED in CI's pinned
 # Multi-tenant suite step, the PR 7/9/10 rebalancing idiom.
 @pytest.mark.parametrize(
-    "layout,kv_dtype,seed",
-    [pytest.param("paged", "bf16", None, marks=pytest.mark.slow),
-     # tier-1 870s budget: one rep — paged/int8/seeded is the densest cell
-     ("paged", "int8", 1234),
-     pytest.param("dense", "bf16", 1234, marks=pytest.mark.slow),
-     pytest.param("paged", "bf16", 1234, marks=pytest.mark.slow),
-     pytest.param("paged", "int8", None, marks=pytest.mark.slow),
-     pytest.param("dense", "bf16", None, marks=pytest.mark.slow),
-     pytest.param("dense", "int8", None, marks=pytest.mark.slow),
-     pytest.param("dense", "int8", 1234, marks=pytest.mark.slow)])
-def test_mixed_batch_bit_exact_vs_solo(layout, kv_dtype, seed):
+    "kv_dtype,seed",
+    [pytest.param("bf16", None, marks=pytest.mark.slow),
+     # tier-1 870s budget: one rep — int8/seeded is the densest cell
+     ("int8", 1234),
+     pytest.param("bf16", 1234, marks=pytest.mark.slow),
+     pytest.param("int8", None, marks=pytest.mark.slow)])
+def test_mixed_batch_bit_exact_vs_solo(kv_dtype, seed):
     """>= 3 adapters + identity in ONE continuous batch: every slot's
     tokens equal the same request served solo, and the identity slot
     equals plain base generate(). Greedy (seed=None at temperature 0)
@@ -281,13 +275,12 @@ def test_mixed_batch_bit_exact_vs_solo(layout, kv_dtype, seed):
     s = make_server(kv_cache_dtype=kv_dtype, temperature=temp)
     names = load_adapters(s, 3)
     adapters = names + [None]                 # 3 tenants + identity
-    mixed = batch_serve(s, PROMPTS, adapters, layout=layout, seed=seed)
+    mixed = batch_serve(s, PROMPTS, adapters, seed=seed)
     for prompt, adapter, got in zip(PROMPTS, adapters, mixed):
-        solo = solo_serve(s, prompt, adapter, layout=layout, seed=seed)
-        assert got == solo, (adapter, layout, kv_dtype, seed)
+        solo = solo_serve(s, prompt, adapter, seed=seed)
+        assert got == solo, (adapter, kv_dtype, seed)
     # at least one adapted slot must actually diverge from base output
-    base = [solo_serve(s, p, None, layout=layout, seed=seed)
-            for p in PROMPTS[:3]]
+    base = [solo_serve(s, p, None, seed=seed) for p in PROMPTS[:3]]
     assert any(m != b for m, b in zip(mixed[:3], base))
     # identity slot == plain generate() (the zero-delta bitwise guarantee)
     g = s.generate([PROMPTS[3]], max_new_tokens=6, seed=seed)
@@ -295,8 +288,7 @@ def test_mixed_batch_bit_exact_vs_solo(layout, kv_dtype, seed):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("layout", ["paged", "dense"])
-def test_mixed_batch_parity_spec_verify(layout):
+def test_mixed_batch_parity_spec_verify():
     """The speculative verify path (llm.lora_verify_step): mixed
     adapters through ngram speculation stay bit-exact vs solo AND vs the
     non-speculative adapted batcher — speculation changes tokens per
@@ -307,15 +299,14 @@ def test_mixed_batch_parity_spec_verify(layout):
     # repetitive prompts so the ngram proposer actually fires
     prompts = [[7, 8, 9, 7, 8, 9, 7, 8], [4, 4, 4, 4, 4],
                [1, 2, 1, 2, 1, 2], [5, 6, 5, 6, 5, 6, 5]]
-    mixed = batch_serve(s, prompts, adapters, layout=layout, max_new=8)
+    mixed = batch_serve(s, prompts, adapters, max_new=8)
     for prompt, adapter, got in zip(prompts, adapters, mixed):
-        assert got == solo_serve(s, prompt, adapter, layout=layout,
-                                 max_new=8)
+        assert got == solo_serve(s, prompt, adapter, max_new=8)
     # vs the NON-speculative adapted batcher (identical model seed +
     # identical adapter factors — load_adapters is deterministic)
     plain = make_server()
     load_adapters(plain, 3)
-    ref = batch_serve(plain, prompts, adapters, layout=layout, max_new=8)
+    ref = batch_serve(plain, prompts, adapters, max_new=8)
     assert mixed == ref
 
 
@@ -323,14 +314,12 @@ def test_identity_program_matches_unadapted_program():
     """adapter_id 0 through the ADAPTED compiled step reproduces the
     UNADAPTED server's batcher byte-for-byte — one program shape serves
     base traffic with zero output drift (the S-LoRA identity-row
-    property the budgets band also bounds in cost). One test for both
-    layouts so the two server builds amortize (tier-1 budget)."""
+    property the budgets band also bounds in cost)."""
     s_lora = make_server()
     s_base = make_server(lora_rank=0)
-    for layout in ("paged", "dense"):
-        a = batch_serve(s_lora, PROMPTS[:2], [None, None], layout=layout)
-        b = batch_serve(s_base, PROMPTS[:2], [None, None], layout=layout)
-        assert a == b, layout
+    a = batch_serve(s_lora, PROMPTS[:2], [None, None])
+    b = batch_serve(s_base, PROMPTS[:2], [None, None])
+    assert a == b
 
 
 def test_adapted_requests_skip_radix_trie():
@@ -345,7 +334,7 @@ def test_adapted_requests_skip_radix_trie():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=1, max_len=48, len_buckets=(16,),
-                              layout="paged", page_size=4)
+                              page_size=4)
         assert b._radix is not None
         adapted = await b.submit(prompt, max_new_tokens=4, adapter=name)
         stats_after_adapted = b._radix.stats()
@@ -375,7 +364,7 @@ def test_eviction_blocked_while_request_queued_or_active():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=1, max_len=40, len_buckets=(8,),
-                              layout="paged", page_size=8)
+                              page_size=8)
         fut = asyncio.ensure_future(
             b.submit(PROMPTS[0], max_new_tokens=16, adapter=name))
         # while queued/active the pin holds (poll until the pin appears,
@@ -410,7 +399,7 @@ def test_staged_prefill_shed_releases_adapter_pin():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=1, max_len=48, len_buckets=(16,),
-                              layout="paged", page_size=4, prefill_chunk=2)
+                              page_size=4, prefill_chunk=2)
         b._loop = asyncio.get_running_loop()  # submit() normally sets it
         aid = reg.resolve_and_pin(name)
         fut = asyncio.get_running_loop().create_future()

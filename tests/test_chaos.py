@@ -3,7 +3,7 @@
 Headline: a streaming batch spread across a 3-replica fleet; deterministic
 chaos injection kills the busiest replica's batcher loop mid-decode; every
 client still receives the BIT-EXACT token sequence of an unfaulted run
-(greedy and seeded sampling, dense and paged KV), with zero duplicate
+(greedy and seeded sampling), with zero duplicate
 tokens, the corpse ejected from dispatch, the recovery visible in the
 fleet metrics, and the autoscaler replacing the dead replica on its next
 tick. Everything is event-driven — zero ``time.sleep`` in this file: kills
@@ -19,6 +19,7 @@ and fail with exact diffs when the protocol drifts.
 from __future__ import annotations
 
 import asyncio
+import types
 
 import pytest
 
@@ -98,22 +99,17 @@ class _CountingFactory:
         return _Stub()
 
 
-# tier-1 870s budget: one rep — seeded paged, the densest cell (paged
-# accounting + rng-chain resume in one run); the other three ride CI's
+# tier-1 870s budget: one rep — seeded, the densest cell (page
+# accounting + rng-chain resume in one run); greedy rides CI's
 # pinned unfiltered chaos step
-@pytest.mark.parametrize("layout,temperature", [
-    pytest.param("dense", 0.0, marks=pytest.mark.slow),
-    pytest.param("dense", 0.8, marks=pytest.mark.slow),
-    pytest.param("paged", 0.0, marks=pytest.mark.slow),
-    ("paged", 0.8),
-], ids=["dense-greedy", "dense-seeded", "paged-greedy", "paged-seeded"])
-def test_kill_busiest_replica_mid_decode_streams_stay_bit_exact(
-        layout, temperature):
-    extra = dict(temperature=temperature)
+@pytest.mark.parametrize("temperature", [
+    pytest.param(0.0, marks=pytest.mark.slow),
+    0.8,
+], ids=["greedy", "seeded"])
+def test_kill_busiest_replica_mid_decode_streams_stay_bit_exact(temperature):
+    extra = dict(temperature=temperature, kv_page_size=8)
     if temperature > 0:
         extra.update(top_k=20)
-    if layout == "paged":
-        extra.update(kv_cache_layout="paged", kv_page_size=8)
     reps = [make_server(**extra) for _ in range(3)]
 
     # the unfaulted truth, per request: batched continuous serving is
@@ -279,23 +275,14 @@ def test_ejected_replica_reinstates_through_halfopen_probe():
 # failed on that shape before the containment landed in runtime/batcher.py.
 # ---------------------------------------------------------------------------
 
-# tier-1 870s budget: paged is the richer cell (page accounting on the
-# containment path); dense rides CI's pinned unfiltered chaos step
-@pytest.mark.parametrize("layout", [
-    pytest.param("dense", marks=pytest.mark.slow),
-    "paged",
-])
-def test_poisoned_handoff_fails_one_request_not_the_batch(layout):
+def test_poisoned_handoff_fails_one_request_not_the_batch():
     s = make_server(disaggregation="remote_prefill", prefill_devices=2,
                     max_new_tokens=4)
     expected = s.generate([[5, 9, 17]], max_new_tokens=4)["tokens"][0]
 
     async def go():
-        kw = dict(max_slots=2, max_len=32, len_buckets=(8,), layout=layout,
-                  disaggregation="remote_prefill")
-        if layout == "paged":
-            kw.update(page_size=8)
-        b = ContinuousBatcher(s, **kw)
+        b = ContinuousBatcher(s, max_slots=2, max_len=32, len_buckets=(8,),
+                              page_size=8, disaggregation="remote_prefill")
         HandoffPoisoner(b, first_n=1)
         with pytest.raises(Exception):
             await b.submit([40, 3, 22, 8], max_new_tokens=4)
@@ -303,9 +290,7 @@ def test_poisoned_handoff_fails_one_request_not_the_batch(layout):
         # and the NEXT request serves bit-exact
         assert b.crashed is None
         ok = await b.submit([5, 9, 17], max_new_tokens=4)
-        pages_ok = True
-        if b.paged:
-            pages_ok = b.page_stats()["kv_pages_in_use"] == 0
+        pages_ok = b.page_stats()["kv_pages_in_use"] == 0
         await b.close()
         return ok, pages_ok
 
@@ -322,7 +307,8 @@ class _StubBatcher:
     def __init__(self):
         self._pending = []
         self._slots = []
-        self.paged = False
+        # replica_load's page-pressure read: an empty pool
+        self._allocator = types.SimpleNamespace(stats=lambda: (0, 0, 0))
         self.crashed = None
         self._task = None
         self.heartbeat = 0.0

@@ -3,8 +3,8 @@
 The contract: moving admission prefill onto a separate PREFILL slice and
 handing the written KV device-to-device into the decode slice's pool
 changes NOTHING about tokens — remote-prefill serving is bit-exact against
-single-slice serving for greedy and seeded sampling, dense and paged
-layouts, bf16 and int8 KV, including admissions landing while decode steps
+single-slice serving for greedy and seeded sampling,
+bf16 and int8 KV, including admissions landing while decode steps
 are in flight — while the TransferQueue delivers every handoff exactly
 once, sheds cancel staged jobs without double-freeing their decode-side
 pages, and worker failures resolve their own request without touching the
@@ -65,7 +65,6 @@ def run_batch(server, prompts, *, n=8, seeds=None, disaggregation=None,
     overrides the server's mode, so the SAME server object produces both
     the single-slice baseline and the disaggregated run (identical params,
     identical rng chain — any token difference is the handoff's fault)."""
-    batcher_kw.setdefault("layout", "paged")
     batcher_kw.setdefault("page_size", 8)
 
     async def go():
@@ -76,7 +75,7 @@ def run_batch(server, prompts, *, n=8, seeds=None, disaggregation=None,
                      seed=None if seeds is None else seeds[i])
             for i, p in enumerate(prompts)])
         stats = {"handoff": b.handoff_stats(),
-                 "pages": b.page_stats() if b.paged else None}
+                 "pages": b.page_stats()}
         await b.close()
         return outs, stats
 
@@ -88,44 +87,29 @@ PROMPTS = [[5, 9, 17], [40, 3, 22, 8, 11, 60, 2, 33, 7, 7, 12, 13],
 
 
 # ---------------------------------------------------------------- parity
-@pytest.mark.parametrize("fixt", [
-    "server",
-    # tier-1 keeps the bf16 pair; int8 rides CI's unfiltered step AND the
-    # pinned disaggregation-parity step (ci.yaml runs this file unfiltered)
-    pytest.param("int8_server", marks=pytest.mark.slow),
-])
-@pytest.mark.parametrize("layout", [
-    # tier-1 870s budget: the full cross rides the pinned unfiltered
-    # disagg CI step; tier-1 keeps seeded[paged] below plus the greedy
-    # paged anchor test_remote_admission_mid_decode_steps_in_flight
-    pytest.param("paged", marks=pytest.mark.slow),
-    pytest.param("dense", marks=pytest.mark.slow),
-])
-def test_remote_prefill_greedy_parity(fixt, layout, request):
+# tier-1 870s budget: both ride the pinned unfiltered disagg CI step;
+# tier-1 keeps the seeded parity below plus the greedy anchor
+# test_remote_admission_mid_decode_steps_in_flight
+@pytest.mark.slow
+@pytest.mark.parametrize("fixt", ["server", "int8_server"])
+def test_remote_prefill_greedy_parity(fixt, request):
     """The acceptance bar: prefill-on-slice-A + decode-on-slice-B equals
-    single-slice serving token for token, both layouts, both KV dtypes —
+    single-slice serving token for token, both KV dtypes —
     and the handoffs actually happened (every admission crossed the
     TransferQueue, none were served by local prefill)."""
     s = request.getfixturevalue(fixt)
-    base, _ = run_batch(s, PROMPTS, disaggregation="off", layout=layout,
+    base, _ = run_batch(s, PROMPTS, disaggregation="off",
                         max_slots=3, max_len=40, len_buckets=(8,))
-    dis, stats = run_batch(s, PROMPTS, layout=layout,
+    dis, stats = run_batch(s, PROMPTS,
                            max_slots=3, max_len=40, len_buckets=(8,))
     assert dis == base
     assert stats["handoff"]["handoffs_total"] == len(PROMPTS)
     assert stats["handoff"]["handoff_queue_depth"] == 0
     assert stats["handoff"]["handoff_transfer_bytes_total"] > 0
-    if layout == "paged":
-        assert stats["pages"]["kv_pages_in_use"] == 0
+    assert stats["pages"]["kv_pages_in_use"] == 0
 
 
-@pytest.mark.parametrize("layout", [
-    "paged",
-    # tier-1 870s budget: dense greedy parity above keeps the dense axis;
-    # dense seeded runs in CI (the pinned disagg step is unfiltered)
-    pytest.param("dense", marks=pytest.mark.slow),
-])
-def test_remote_prefill_seeded_parity(sampled_server, layout):
+def test_remote_prefill_seeded_parity(sampled_server):
     """Seeded sampling through the disaggregated path reproduces the
     single-slice chain exactly: the first token samples from the worker's
     handed-off logits on the same per-request key, and every later token
@@ -133,9 +117,9 @@ def test_remote_prefill_seeded_parity(sampled_server, layout):
     prompts = [[5, 9, 17, 2], [40, 3, 22], [7, 7, 7, 7, 7]]
     seeds = [42, 1234, 7]
     base, _ = run_batch(sampled_server, prompts, seeds=seeds,
-                        disaggregation="off", layout=layout,
+                        disaggregation="off",
                         max_slots=3, max_len=40, len_buckets=(8,))
-    dis, _ = run_batch(sampled_server, prompts, seeds=seeds, layout=layout,
+    dis, _ = run_batch(sampled_server, prompts, seeds=seeds,
                        max_slots=3, max_len=40, len_buckets=(8,))
     assert dis == base
 
@@ -164,7 +148,7 @@ def test_remote_admission_mid_decode_steps_in_flight(server):
     async def go():
         b = ContinuousBatcher(server, max_slots=2, max_len=64,
                               len_buckets=(32,), pipeline_depth=3,
-                              layout="paged", page_size=8, prefill_chunk=8)
+                              page_size=8, prefill_chunk=8)
         t1 = asyncio.ensure_future(b.submit(p1, max_new_tokens=24))
         for _ in range(400):
             if b._inflight_hwm >= 2 and any(s.active for s in b._slots):
@@ -276,7 +260,7 @@ def test_worker_exception_propagates_to_submitter():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=2, max_len=32, len_buckets=(8,),
-                              layout="dense")
+                              page_size=8)
         # monkeypatch the pool to fail one specific job
         worker = b._remote.workers[0]
         real = worker._prefill_one
@@ -310,8 +294,7 @@ def test_pool_exhaustion_sheds_staged_remote_job_503(server):
 
     async def go():
         b = ContinuousBatcher(server, max_slots=2, max_len=32,
-                              len_buckets=(8,), layout="paged",
-                              page_size=4, pool_pages=10)
+                              len_buckets=(8,), page_size=4, pool_pages=10)
         t1 = asyncio.ensure_future(b.submit(p1, max_new_tokens=24))
         await asyncio.sleep(0)  # keep admission order deterministic
         t2 = asyncio.ensure_future(b.submit([40, 3, 22, 8],
@@ -340,7 +323,7 @@ def test_close_fails_staged_jobs_instead_of_hanging():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=2, max_len=32, len_buckets=(8,),
-                              layout="paged", page_size=8)
+                              page_size=8)
         worker = b._remote.workers[0]
 
         def stall(req):
@@ -435,7 +418,7 @@ def test_decode_slice_must_hold_default_device(server):
     bad = DisaggregatedMesh(devs[:2], devs[2:])  # default dev 0 in PREFILL
     with pytest.raises(ValueError, match="default device"):
         ContinuousBatcher(server, max_slots=2, max_len=32, len_buckets=(8,),
-                          layout="dense", disagg_mesh=bad)
+                          page_size=8, disagg_mesh=bad)
 
 
 # ------------------------------------------------------------- validation
@@ -499,7 +482,7 @@ def test_ttft_and_gaps_recorded_without_disaggregation():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=2, max_len=32, len_buckets=(8,),
-                              layout="paged", page_size=8)
+                              page_size=8)
         out = await b.submit([5, 9, 17], max_new_tokens=6)
         await b.close()
         return out
@@ -585,5 +568,5 @@ def test_replica_set_routes_llm_replicas_end_to_end():
     expected = r1.generate([[5, 9, 17]], max_new_tokens=6)["tokens"][0]
     out = rs.generate([[5, 9, 17]], max_new_tokens=6)
     assert out["tokens"][0] == expected
-    assert rs.llm_stats()["kv_cache_layout"] == r1.llm_stats()[
-        "kv_cache_layout"]
+    assert rs.llm_stats()["kv_cache_dtype"] == r1.llm_stats()[
+        "kv_cache_dtype"]

@@ -3,12 +3,16 @@
 The contract: a prompt's first token is sampled on the device by the sampler
 every decode step uses, threaded into the slot on the device, and read through
 the drain pipeline behind the steps that were queued before its last chunk. So
-nothing in ``_prefill_step`` (or the dense ``_admit``) reads the device; decode
+nothing in ``_prefill_step`` reads the device; decode
 steps are dispatched and older ones drained between a last chunk's enqueue and
 its token's read; with no step in flight the next request's chunks are enqueued
 before anything waits for the token; a record whose occupant is gone surfaces
 nothing; EOS and ``max_new == 1`` finish at the read; and every emitted token,
-the first included, is the one ``generate(seed=...)`` draws. CPU toy model."""
+the first included, is the one ``generate(seed=...)`` draws. Each of these
+holds for a cold admission (the whole prompt in chunks) and for one behind a
+radix-trie hit (``prefix_hit``: the prompt's family was served before, so
+``match_and_pin`` shares its blocks and the first token comes from a suffix
+chunk that starts mid-prompt). CPU toy model."""
 
 from __future__ import annotations
 
@@ -51,13 +55,49 @@ def sampled():
     return make_server(temperature=0.8, top_k=20, seed=5)
 
 
-def make_batcher(server, layout="paged", **kw) -> ContinuousBatcher:
+@pytest.fixture(scope="module")
+def greedy_radix():
+    return make_server(prefix_cache_size=8)
+
+
+@pytest.fixture(scope="module")
+def sampled_radix():
+    return make_server(temperature=0.8, top_k=20, seed=5, prefix_cache_size=8)
+
+
+ADMISSIONS = ["cold", "prefix_hit"]
+
+
+def served_by(request, name, admission):
+    """The server the batcher runs on: the same weights, with the radix
+    prefix cache on for ``prefix_hit``. References come from the plain one."""
+    return request.getfixturevalue(
+        name + ("_radix" if admission == "prefix_hit" else ""))
+
+
+def make_batcher(server, **kw) -> ContinuousBatcher:
     kw.setdefault("max_slots", 3)
     kw.setdefault("max_len", 64)
-    if layout == "paged":
-        kw.setdefault("page_size", 8)
-        kw.setdefault("prefill_chunk", 8)
-    return ContinuousBatcher(server, layout=layout, **kw)
+    kw.setdefault("page_size", 8)
+    kw.setdefault("prefill_chunk", 8)
+    return ContinuousBatcher(server, **kw)
+
+
+async def serve_once(b, admission, prompts, **kw):
+    """``prefix_hit``: each prompt's family is served once first, so the
+    trie holds its blocks when the admission under test arrives."""
+    if admission == "prefix_hit":
+        for p in prompts:
+            await b.submit(p, 2, **kw)
+
+
+def check_hits(b, admission, prompts):
+    """``prefix_hit``: ``match_and_pin`` shared all of each prompt but its
+    last token (capped at L-1: that one's logits seed the first token). A
+    batcher without a trie, or a miss, fails here."""
+    if admission == "prefix_hit":
+        assert b._radix.stats()["prefix_hit_tokens"] == sum(
+            len(p) - 1 for p in prompts)
 
 
 def log_calls(b: ContinuousBatcher, names) -> list:
@@ -118,14 +158,17 @@ async def live_stream(b, prompt, n):
 
 
 # ------------------------------------------------ (a) nothing stands still
-@pytest.mark.parametrize("layout", ["paged", "dense"])
-def test_steps_flow_between_a_last_chunk_and_its_token(greedy, layout, monkeypatch):
+@pytest.mark.parametrize("admission", ADMISSIONS)
+def test_steps_flow_between_a_last_chunk_and_its_token(greedy, admission, request,
+                                                       monkeypatch):
     watch = _NumpyWatch()
     monkeypatch.setattr(batcher_module, "np", watch)
-    admit = "_prefill_step" if layout == "paged" else "_admit"
+    admit = "_prefill_step"
 
     async def go():
-        b = make_batcher(greedy, layout, pipeline_depth=2)
+        b = make_batcher(served_by(request, "greedy", admission), pipeline_depth=2)
+        await serve_once(b, admission, [LONG])
+        before = sum(b._phases.first_token_reads.values())
         events = log_calls(b, [admit, "_commit_slot", "_dispatch",
                                "_drain_step", "_drain_first"])
         inner = getattr(b, admit)
@@ -141,7 +184,8 @@ def test_steps_flow_between_a_last_chunk_and_its_token(greedy, layout, monkeypat
         fut, _ = await live_stream(b, SHORT, 40)
         out = await b.submit(LONG, 4)
         first = await fut
-        reads = dict(b._phases.first_token_reads)
+        reads = sum(b._phases.first_token_reads.values()) - before
+        check_hits(b, admission, [LONG])
         await b.close()
         return events, out, first, reads
 
@@ -156,7 +200,7 @@ def test_steps_flow_between_a_last_chunk_and_its_token(greedy, layout, monkeypat
     assert len(commits) == len(reads_at) == 2
     between = [e[0] for e in events[commits[1]:reads_at[1]] if e[1] == "in"]
     assert "_dispatch" in between and "_drain_step" in between, between
-    assert sum(reads.values()) == 2
+    assert reads == 2
 
 
 class _NeverReady:
@@ -178,7 +222,7 @@ def test_next_request_is_enqueued_before_a_lone_token_is_read(greedy):
     for R's token, and no further ahead than that."""
 
     async def go():
-        b = make_batcher(greedy, "paged", pipeline_depth=2)
+        b = make_batcher(greedy, pipeline_depth=2)
         commit = b._commit_slot
 
         def commit_unready(*args, **kwargs):
@@ -233,37 +277,47 @@ def test_when_a_first_token_can_wait(greedy, ready, behind, enqueued, waits):
 
 
 # ------------------------------------------- (b) one sampler, one key chain
-@pytest.mark.parametrize("layout", ["paged", "dense"])
+@pytest.mark.parametrize("admission", ADMISSIONS)
 @pytest.mark.parametrize("fixt", ["greedy", "sampled"])
-def test_seeded_tokens_equal_generate(fixt, layout, request):
+def test_seeded_tokens_equal_generate(fixt, admission, request):
     s = request.getfixturevalue(fixt)
     prompts, seeds = [SHORT, LONG, OTHER], [42, 1234, 7]
     expected = [s.generate([p], max_new_tokens=8, seed=sd)["tokens"][0]
                 for p, sd in zip(prompts, seeds)]
 
     async def go():
-        b = make_batcher(s, layout)
+        b = make_batcher(served_by(request, fixt, admission))
+        await serve_once(b, admission, prompts, seed=1)
         outs = await asyncio.gather(*[b.submit(p, 8, seed=sd)
                                       for p, sd in zip(prompts, seeds)])
+        check_hits(b, admission, prompts)
         await b.close()
         return outs
 
-    assert asyncio.run(go()) == expected
+    outs = asyncio.run(go())
+    assert outs == expected
 
 
-@pytest.mark.parametrize("layout", ["paged", "dense"])
+@pytest.mark.parametrize("admission", ADMISSIONS)
 @pytest.mark.parametrize("delivered", [1, 4])
-def test_resumed_generation_continues_the_chain(sampled, layout, delivered):
+def test_resumed_generation_continues_the_chain(sampled, admission, delivered, request):
+    """``prefix_hit``: the resume lands where the interrupted request ran,
+    so the trie already holds the prompt and what was delivered."""
     whole = sampled.generate([OTHER], max_new_tokens=8, seed=99)["tokens"][0]
+    resumed = OTHER + whole[:delivered]
 
     async def go():
-        b = make_batcher(sampled, layout)
-        rest = await b.submit(OTHER + whole[:delivered], 8 - delivered, seed=99,
+        b = make_batcher(served_by(request, "sampled", admission))
+        if admission == "prefix_hit":
+            assert await b.submit(OTHER, 8, seed=99) == whole
+        rest = await b.submit(resumed, 8 - delivered, seed=99,
                               resume_tokens=delivered)
+        check_hits(b, admission, [resumed])
         await b.close()
         return rest
 
-    assert asyncio.run(go()) == whole[delivered:]
+    rest = asyncio.run(go())
+    assert rest == whole[delivered:]
 
 
 def test_first_token_program_is_the_step_sampler_on_one_row(sampled):
@@ -293,7 +347,7 @@ def test_first_token_program_is_the_step_sampler_on_one_row(sampled):
 @pytest.mark.parametrize("how", ["shed", "finished"])
 def test_stale_first_token_surfaces_nothing_and_frees_pages_once(greedy, how):
     async def go():
-        b = make_batcher(greedy, "paged", pipeline_depth=2)
+        b = make_batcher(greedy, pipeline_depth=2)
         gone = []
 
         def chaos(batcher):
@@ -336,33 +390,42 @@ def test_stale_first_token_surfaces_nothing_and_frees_pages_once(greedy, how):
 
 
 # --------------------------------- (d) requests that end at their first token
-@pytest.mark.parametrize("layout", ["paged", "dense"])
+@pytest.mark.parametrize("admission", ADMISSIONS)
 @pytest.mark.parametrize("ends_by", ["max_new", "eos"])
-def test_request_finishes_at_the_read_of_its_first_token(greedy, layout, ends_by):
+def test_request_finishes_at_the_read_of_its_first_token(greedy, admission, ends_by,
+                                                         request):
     first = greedy.generate([LONG], max_new_tokens=1)["tokens"][0][0]
-    server = greedy if ends_by == "max_new" else make_server(eos_id=first)
+    if ends_by == "max_new":
+        server = served_by(request, "greedy", admission)
+    else:
+        server = make_server(eos_id=first,
+                             prefix_cache_size=8 * (admission == "prefix_hit"))
     neighbour = server.generate([SHORT], max_new_tokens=30)["tokens"][0]
 
     async def go():
-        b = make_batcher(server, layout, pipeline_depth=2)
+        b = make_batcher(server, pipeline_depth=2)
+        await serve_once(b, admission, [LONG])
         events = log_calls(b, ["_drain_first", "_finish", "_dispatch"])
         fut, _ = await live_stream(b, SHORT, 30)
         seen = []
         out = await b.submit(LONG, 1 if ends_by == "max_new" else 8,
                              on_token=seen.append)
         rest = await fut
-        pages = b.page_stats()
+        # pages some slot still holds: what the trie caches is not a leak
+        held = b.page_stats()["kv_pages_in_use"] - (
+            b._radix.stats()["prefix_cached_blocks"] if b._radix else 0)
+        check_hits(b, admission, [LONG])
         await b.close()
-        return events, out, seen, rest, pages
+        return events, out, seen, rest, held
 
-    events, out, seen, rest, pages = asyncio.run(go())
+    events, out, seen, rest, held = asyncio.run(go())
     if ends_by == "max_new":
         assert out == [first] and seen == [first, None]
     else:
         assert out == [] and seen == [None]     # EOS is trimmed, never streamed
     # the neighbour's steps carried the slot along meanwhile: masked
     assert rest == neighbour
-    assert pages["kv_pages_in_use"] == 0
+    assert held == 0
     # LONG's _finish ran inside the read of its first token
     depth, finished_inside = 0, 0
     for name, edge, _ in events:
@@ -374,16 +437,20 @@ def test_request_finishes_at_the_read_of_its_first_token(greedy, layout, ends_by
 
 
 # ------------------------------------------------------ (e) the logits probe
-@pytest.mark.parametrize("layout", ["paged", "dense"])
-def test_probe_gets_the_prompts_last_row_first_then_a_row_a_step(greedy, layout):
+@pytest.mark.parametrize("admission", ADMISSIONS)
+def test_probe_gets_the_prompts_last_row_first_then_a_row_a_step(greedy, admission,
+                                                                 request):
     async def go():
-        b = make_batcher(greedy, layout)
+        b = make_batcher(served_by(request, "greedy", admission))
+        await serve_once(b, admission, [LONG])
         info = {"logits": []}
         plain, out = await asyncio.gather(b.submit(SHORT, 6), b.submit(LONG, 6, info=info))
+        check_hits(b, admission, [LONG])
         await b.close()
         return plain, out, info
 
     plain, out, info = asyncio.run(go())
+    assert out == greedy.generate([LONG], max_new_tokens=6)["tokens"][0]
     assert plain == greedy.generate([SHORT], max_new_tokens=6)["tokens"][0]
     rows = np.stack(info["logits"])
     assert rows.shape == (6, 96) and rows.dtype == np.float32

@@ -3,10 +3,9 @@ serving hot path.
 
 The contract: with the tracer enabled, every request served by the
 continuous batcher yields ONE span tree rooted at the transport ingress
-containing queue-wait, every prefill chunk (or the dense one-shot
-prefill), the handoff stages when disaggregated, and a decode lifetime
-whose per-step token counts sum to the generated length — dense + paged,
-disagg on + off, greedy + seeded — while TRACING off leaves the batcher
+containing queue-wait, every prefill chunk, the handoff stages when
+disaggregated, and a decode lifetime whose per-step token counts sum to
+the generated length — disagg on + off, greedy + seeded — while TRACING off leaves the batcher
 with no recorder and zero added work. Tail sampling retains unsampled
 slow requests; /debug/timeline (REST + gRPC mirror) exposes the recent
 timelines and the scaling snapshot. Runs on the virtual 8-device CPU
@@ -100,22 +99,12 @@ def _tree_for(spans, trace_id):
 # The acceptance matrix: one span tree per request, token counts exact
 # ---------------------------------------------------------------------------
 
-# the slow-marked combos exist only for the local tier-1 870s budget —
-# the pinned CI tracing step runs the FULL matrix unfiltered (each axis
-# keeps a cheaper tier-1 representative: dense x greedy, paged x seeded)
-@pytest.mark.parametrize("layout,seeded", [
-    ("dense", False),
-    pytest.param("dense", True, marks=pytest.mark.slow),
-    pytest.param("paged", False, marks=pytest.mark.slow),
-    ("paged", True),
-])
-def test_span_tree_per_request(server, enabled_tracer, layout, seeded):
+@pytest.mark.parametrize("seeded", [False, True])
+def test_span_tree_per_request(server, enabled_tracer, seeded):
     seeds = [11, 22, 33, 44] if seeded else None
     ctxs = [TraceContext.from_traceparent(None, ingress="rest:/v1/generate")
             for _ in PROMPTS]
-    kw = dict(max_slots=3, layout=layout)
-    if layout == "paged":
-        kw.update(page_size=8, prefill_chunk=4)
+    kw = dict(max_slots=3, page_size=8, prefill_chunk=4)
     outs, recorder = run_batch(server, PROMPTS, seeds=seeds, ctxs=ctxs, **kw)
     spans = enabled_tracer.drain()
     timelines = {t["trace_id"]: t for t in recorder.timelines()}
@@ -126,12 +115,9 @@ def test_span_tree_per_request(server, enabled_tracer, layout, seeded):
         assert names["queue.wait"] == 1
         assert names["llm.first_token"] == 1
         assert names["llm.decode"] == 1
-        if layout == "paged":
-            # every prefill chunk of the (4-token) chunked admission
-            L = len(PROMPTS[i])
-            assert names["llm.prefill_chunk"] == -(-L // 4)
-        else:
-            assert names["llm.prefill"] == 1
+        # every prefill chunk of the (4-token) chunked admission
+        L = len(PROMPTS[i])
+        assert names["llm.prefill_chunk"] == -(-L // 4)
         # decode lifetime: per-step token counts sum to the generated
         # length (first token + step events == credited tokens == output)
         step_tokens = sum(c.tags["tokens"] for c in children
@@ -152,7 +138,7 @@ def test_span_tree_disaggregated(disagg_server, enabled_tracer):
     ctxs = [TraceContext.from_traceparent(None, ingress="grpc:GenerateStream")
             for _ in PROMPTS]
     outs, recorder = run_batch(disagg_server, PROMPTS, ctxs=ctxs,
-                               max_slots=3, layout="paged", page_size=8,
+                               max_slots=3, page_size=8,
                                disaggregation="remote_prefill")
     spans = enabled_tracer.drain()
     for i, ctx in enumerate(ctxs):
@@ -173,7 +159,7 @@ def test_inbound_traceparent_roots_the_tree(server, enabled_tracer):
     ctx = TraceContext.from_traceparent(
         f"00-{parent_trace}-{parent_span}-01", ingress="rest:/v1/generate")
     outs, _ = run_batch(server, [PROMPTS[0]], ctxs=[ctx], max_slots=2,
-                        layout="paged", page_size=8)
+                        page_size=8)
     spans = enabled_tracer.drain()
     root, _children = _tree_for(spans, parent_trace)
     # the ingress root hangs under the CALLER's span, same trace id
@@ -185,7 +171,7 @@ def test_tracing_disabled_means_no_recorder_and_no_spans(server):
     tracer = get_tracer()
     assert not tracer.enabled  # default test environment
     outs, recorder = run_batch(server, [PROMPTS[0]], max_slots=2,
-                               layout="paged", page_size=8)
+                               page_size=8)
     assert recorder is None
     assert tracer.drain() == []
     assert len(outs[0]) == 8
@@ -197,10 +183,10 @@ def test_tokens_identical_with_and_without_tracing(server, enabled_tracer):
     ctxs = [TraceContext.from_traceparent(None, ingress="x")
             for _ in PROMPTS]
     traced, _ = run_batch(server, PROMPTS, ctxs=ctxs, max_slots=3,
-                          layout="paged", page_size=8)
+                          page_size=8)
     enabled_tracer.drain()
     untraced, _ = run_batch(server, PROMPTS, max_slots=3,
-                            layout="paged", page_size=8, tracing=False)
+                            page_size=8, tracing=False)
     assert traced == untraced
 
 
@@ -212,7 +198,7 @@ def test_unsampled_request_dropped_without_thresholds(server, enabled_tracer):
     ctx = TraceContext.from_traceparent(None, ingress="x")
     ctx.sampled = False
     outs, recorder = run_batch(server, [PROMPTS[0]], ctxs=[ctx],
-                               max_slots=2, layout="paged", page_size=8)
+                               max_slots=2, page_size=8)
     # no spans exported for the head-dropped request...
     assert [s for s in enabled_tracer.drain()
             if s.trace_id == ctx.trace_id] == []
@@ -229,7 +215,7 @@ def test_tail_retention_overrides_head_drop(server, enabled_tracer,
     ctx = TraceContext.from_traceparent(None, ingress="x")
     ctx.sampled = False
     outs, recorder = run_batch(server, [PROMPTS[0]], ctxs=[ctx],
-                               max_slots=2, layout="paged", page_size=8)
+                               max_slots=2, page_size=8)
     spans = [s for s in enabled_tracer.drain() if s.trace_id == ctx.trace_id]
     assert spans, "tail sampling must retain the slow unsampled request"
     tl = recorder.timelines()[-1]

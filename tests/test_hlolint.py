@@ -530,7 +530,7 @@ def cli(*args):
 def test_cli_list_and_usage_errors():
     res = cli("--list")
     assert res.returncode == 0
-    assert "llm.decode_step_s4" in res.stdout
+    assert "llm.paged_decode_step_s4" in res.stdout
     assert cli("--contracts", "no.such.contract").returncode == 2
     assert cli("--checks", "no-such-check").returncode == 2
     assert cli("no/such/path").returncode == 2

@@ -10,19 +10,20 @@ asserts every refcount the unwind owns is back to zero: pages held by
 slots, elevated trie pins, adapter pins, staged remote jobs, undelivered
 handoffs, journal entries.
 
-Coverage crosses layouts the way the burned-down leaks did: the local
-paged sweep replays the PR 7 / PR 12 / PR 15 shapes (prefix-pin drop on
+Coverage crosses configurations the way the burned-down leaks did: the local
+sweep replays the PR 7 / PR 12 / PR 15 shapes (prefix-pin drop on
 exhaustion, cow-source-pin drop-and-retry, adapter-pin on the 400 path),
 the disaggregated sweeps replay the staging/import containment, and the
 stub-fleet sweep replays the PR 16 journal-entry lifetime — plus a
 negative control proving the harness actually detects a planted leak.
 
-Tier-1 runs the paged local sweep, the paged disaggregated sweep, and
-the millisecond stub tests; the dense disaggregated transpose rides
-CI's unfiltered step (slow).
+Tier-1 runs the local sweep, the disaggregated sweep, and
+the millisecond stub tests.
 """
 
 from __future__ import annotations
+
+import types
 
 import numpy as np
 import pytest
@@ -64,8 +65,8 @@ def make_server(**extra) -> LLMServer:
 @pytest.fixture(scope="module")
 def local_server():
     # one server covers three boundaries: LoRA registry (adapter-pin),
-    # paged pool (page-alloc), radix trie (radix-cow)
-    return make_server(kv_cache_layout="paged", kv_page_size=8,
+    # page pool (page-alloc), radix trie (radix-cow)
+    return make_server(kv_page_size=8,
                        prefix_cache_size=8, lora_rank=RANK,
                        lora_max_adapters=4)
 
@@ -73,12 +74,7 @@ def local_server():
 @pytest.fixture(scope="module")
 def disagg_server():
     return make_server(disaggregation="remote_prefill", prefill_devices=2,
-                       kv_cache_layout="paged", kv_page_size=8)
-
-
-@pytest.fixture(scope="module")
-def dense_disagg_server():
-    return make_server(disaggregation="remote_prefill", prefill_devices=2)
+                       kv_page_size=8)
 
 
 def load_one_adapter(server) -> str:
@@ -179,16 +175,14 @@ def test_leak_sweep_never_fired_is_an_error(local_server):
 
 
 # ---------------------------------------------------------------------------
-# disaggregated serving: staging + import boundaries, paged and dense
+# disaggregated serving: staging + import boundaries
 # ---------------------------------------------------------------------------
 
 def _sweep_disagg(server):
     svc = ensure_stream_service(server)
     b = svc.batcher
     sweep = LeakSweep(b)
-    want = {"prefill-stage", "handoff-import"}
-    if b.paged:
-        want.add("page-alloc")
+    want = {"prefill-stage", "handoff-import", "page-alloc"}
     assert set(sweep.boundaries()) == want
 
     assert svc.submit_sync(WARM, 4)  # compile + prove the happy path
@@ -220,13 +214,6 @@ def test_leak_sweep_disagg_paged(disagg_server):
     _sweep_disagg(disagg_server)
 
 
-@pytest.mark.slow
-def test_leak_sweep_disagg_dense(dense_disagg_server):
-    # the dense transpose rides CI's unfiltered step: same boundaries,
-    # no page pool — staging/import residue is staged jobs + handoffs
-    _sweep_disagg(dense_disagg_server)
-
-
 # ---------------------------------------------------------------------------
 # resume journal boundary on a stub fleet (no jax, milliseconds)
 # ---------------------------------------------------------------------------
@@ -235,7 +222,9 @@ class _StubBatcher:
     def __init__(self):
         self._pending = []
         self._slots = []
-        self.paged = False
+        # what LeakSweep and replica_load read of the pool: empty, no trie
+        self._allocator = types.SimpleNamespace(stats=lambda: (0, 0, 0))
+        self._radix = None
         self.crashed = None
         self._task = None
         self.heartbeat = 0.0
@@ -270,13 +259,13 @@ def test_leak_sweep_journal_record(monkeypatch):
     fleet keeps dispatching afterwards."""
     fleet = ReplicaSet([_StubReplica(), _StubReplica()])
     sweep = LeakSweep(_StubBatcher(), engine=fleet)
-    assert sweep.boundaries() == ["journal-record"]
+    assert sweep.boundaries() == ["page-alloc", "journal-record"]
 
     def drive(boundary):
         with pytest.raises(SeldonError):
             fleet.submit_sync([1, 2, 3], 4, seed=5)
 
-    assert sweep.sweep(drive) == ["journal-record"]
+    assert sweep.sweep(drive, ["journal-record"]) == ["journal-record"]
     assert fleet.submit_sync([1, 2, 3], 4, seed=5) == [10, 11, 12, 13]
     sweep.assert_clean("post-sweep fleet submit")
 

@@ -3,7 +3,7 @@
 The contract: swapping the prefill->decode transport from ``jax.device_put``
 to a framed TCP stream changes NOTHING about tokens — network-handoff
 serving is bit-exact against device-handoff serving for greedy and seeded
-sampling, dense and paged layouts — while the receiver publishes through
+sampling — while the receiver publishes through
 the SAME TransferQueue, so cancel/shed/poison and exactly-once semantics
 are transport-independent: a replayed frame cannot double-deliver, a
 corrupt frame fails ONE request (the metadata section rides ahead of the
@@ -66,7 +66,6 @@ def run_batch(server, prompts, *, n=8, seeds=None, transport="device",
     """One batch through a fresh ContinuousBatcher; ``transport`` selects
     the handoff path on the SAME server object (identical params, identical
     rng chain — any token difference is the wire's fault)."""
-    batcher_kw.setdefault("layout", "paged")
     batcher_kw.setdefault("page_size", 8)
 
     async def go():
@@ -77,7 +76,7 @@ def run_batch(server, prompts, *, n=8, seeds=None, transport="device",
                      seed=None if seeds is None else seeds[i])
             for i, p in enumerate(prompts)])
         stats = {"handoff": b.handoff_stats(),
-                 "pages": b.page_stats() if b.paged else None}
+                 "pages": b.page_stats()}
         await b.close()
         return outs, stats
 
@@ -89,46 +88,36 @@ PROMPTS = [[5, 9, 17], [40, 3, 22, 8, 11, 60, 2, 33, 7, 7, 12, 13],
 
 
 # ---------------------------------------------------------------- parity
-@pytest.mark.parametrize("layout", [
-    # tier-1 870s budget: tier-1 keeps seeded[paged] below (the denser
-    # cell — paged accounting + rng chain over the wire); the pinned
-    # network-handoff CI step runs this file unfiltered
-    pytest.param("dense", marks=pytest.mark.slow),
-    pytest.param("paged", marks=pytest.mark.slow),
-])
-def test_network_handoff_greedy_parity(server, layout):
+# tier-1 870s budget: tier-1 keeps the seeded parity below (the denser
+# cell — page accounting + rng chain over the wire); the pinned
+# network-handoff CI step runs this file unfiltered
+@pytest.mark.slow
+def test_network_handoff_greedy_parity(server):
     """The acceptance bar: KV streamed header+raw over a socket decodes
     into the exact tokens the device-to-device copy produces — and the
     bytes really crossed the wire (the device path reports zero)."""
-    base, dstats = run_batch(server, PROMPTS, layout=layout,
+    base, dstats = run_batch(server, PROMPTS,
                              max_slots=3, max_len=40, len_buckets=(8,))
     net, nstats = run_batch(server, PROMPTS, transport="network",
-                            layout=layout, max_slots=3, max_len=40,
-                            len_buckets=(8,))
+                            max_slots=3, max_len=40, len_buckets=(8,))
     assert net == base
     assert nstats["handoff"]["handoffs_total"] == len(PROMPTS)
     assert nstats["handoff"]["handoff_queue_depth"] == 0
     assert nstats["handoff"]["handoff_network_bytes_total"] > 0
     assert dstats["handoff"]["handoff_network_bytes_total"] == 0
-    if layout == "paged":
-        assert nstats["pages"]["kv_pages_in_use"] == 0
+    assert nstats["pages"]["kv_pages_in_use"] == 0
 
 
-@pytest.mark.parametrize("layout", [
-    "paged",
-    # tier-1 870s budget: dense rides the greedy cell above; CI unfiltered
-    pytest.param("dense", marks=pytest.mark.slow),
-])
-def test_network_handoff_seeded_parity(sampled_server, layout):
+def test_network_handoff_seeded_parity(sampled_server):
     """Seeded sampling across the socket: the first token samples from the
     worker's logits AFTER an encode/decode/device_put round trip, on the
     same per-request key — bf16/f32 buffers must survive bit-for-bit."""
     prompts = [[5, 9, 17, 2], [40, 3, 22], [7, 7, 7, 7, 7]]
     seeds = [42, 1234, 7]
-    base, _ = run_batch(sampled_server, prompts, seeds=seeds, layout=layout,
+    base, _ = run_batch(sampled_server, prompts, seeds=seeds,
                         max_slots=3, max_len=40, len_buckets=(8,))
     net, _ = run_batch(sampled_server, prompts, seeds=seeds,
-                       transport="network", layout=layout,
+                       transport="network",
                        max_slots=3, max_len=40, len_buckets=(8,))
     assert net == base
 
@@ -145,7 +134,7 @@ def test_server_level_transport_config():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=2, max_len=32, len_buckets=(8,),
-                              layout="dense")
+                              page_size=8)
         assert b.handoff_transport == "network"
         outs = await asyncio.gather(*[
             b.submit(p, max_new_tokens=4) for p in PROMPTS[:2]])
@@ -175,7 +164,7 @@ def test_poisoned_network_handoff_fails_one_request_not_the_batch():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=2, max_len=32, len_buckets=(8,),
-                              layout="paged", page_size=8,
+                              page_size=8,
                               handoff_transport="network")
         HandoffPoisoner(b, first_n=1)
         with pytest.raises(Exception):
@@ -383,7 +372,7 @@ def test_load_validates_handoff_transport():
 def test_batcher_validates_handoff_transport(server):
     with pytest.raises(ValueError, match="unknown handoff_transport"):
         ContinuousBatcher(server, max_slots=2, max_len=32, len_buckets=(8,),
-                          layout="dense", handoff_transport="banana")
+                          page_size=8, handoff_transport="banana")
 
 
 @pytest.mark.slow  # tier-1 870s budget: network bit-exactness is proven by the
@@ -394,7 +383,7 @@ def test_rebalance_preserves_network_transport(server):
 
     async def go():
         b = ContinuousBatcher(server, max_slots=2, max_len=32,
-                              len_buckets=(8,), layout="dense",
+                              len_buckets=(8,), page_size=8,
                               handoff_transport="network")
         addr_before = b._remote.receiver_addr
         assert b.rebalance_disagg(3)

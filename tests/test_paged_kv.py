@@ -1,7 +1,7 @@
 """Paged KV cache + chunked prefill correctness (ISSUE 7 tentpole).
 
-The contract: swapping the batcher's dense ``[S, max_len, ...]`` slot pool
-for the global page pool + block tables changes NOTHING about tokens —
+The contract: serving from the global page pool + block tables changes
+NOTHING about tokens against ``generate()``'s dense caches —
 greedy and seeded-sampled decode are bit-exact against ``generate()`` under
 both KV dtypes (the gather read feeds the identical masked einsum) —
 while admission prefill chunks interleave with in-flight decode, pages
@@ -54,7 +54,6 @@ def sampled_int8_server():
 
 
 def run_batch(server, prompts, *, n=8, seeds=None, **batcher_kw):
-    batcher_kw.setdefault("layout", "paged")
     batcher_kw.setdefault("page_size", 8)
 
     async def go():
@@ -107,7 +106,7 @@ def test_paged_greedy_parity_with_generate(fixt, request):
 def test_paged_seeded_sampled_parity_with_generate(fixt, request):
     """A seeded request through the PAGED batcher decodes the IDENTICAL
     token sequence generate() produces for the same seed — the per-slot
-    device rng chain is untouched by the cache layout."""
+    device rng chain is untouched by the pool."""
     s = request.getfixturevalue(fixt)
     prompts = [[5, 9, 17, 2], [40, 3, 22], [7, 7, 7, 7, 7]]
     seeds = [42, 1234, 7]
@@ -116,19 +115,6 @@ def test_paged_seeded_sampled_parity_with_generate(fixt, request):
     outs, _ = run_batch(s, prompts, seeds=seeds, max_slots=3, max_len=40,
                         len_buckets=(8,), pipeline_depth=2)
     assert outs == expected
-
-
-@pytest.mark.slow
-def test_paged_matches_dense_batcher(sampled_server):
-    """Layout A/B through the SAME batcher machinery: paged and dense
-    decode the same seeded requests to identical tokens."""
-    prompts = [[5, 9, 17], [40, 3, 22, 8, 11]]
-    seeds = [11, 99]
-    dense, _ = run_batch(sampled_server, prompts, seeds=seeds, max_slots=2,
-                         max_len=32, len_buckets=(8,), layout="dense")
-    paged, _ = run_batch(sampled_server, prompts, seeds=seeds, max_slots=2,
-                         max_len=32, len_buckets=(8,), layout="paged")
-    assert paged == dense
 
 
 @pytest.mark.slow
@@ -176,7 +162,7 @@ def test_chunked_prefill_admission_mid_decode(server):
     async def go():
         b = ContinuousBatcher(server, max_slots=2, max_len=64,
                               len_buckets=(32,), pipeline_depth=3,
-                              layout="paged", page_size=8, prefill_chunk=8)
+                              page_size=8, prefill_chunk=8)
         t1 = asyncio.ensure_future(b.submit(p1, max_new_tokens=24))
         for _ in range(400):
             if b._inflight_hwm >= 2 and any(s.active for s in b._slots):
@@ -210,8 +196,7 @@ def test_page_reuse_after_slot_free(server):
         # 2 slots x 3 pages would need 14 pages fully provisioned; 7 (5
         # usable) forces reuse across sequential occupancies
         b = ContinuousBatcher(server, max_slots=2, max_len=24,
-                              len_buckets=(16,), layout="paged",
-                              page_size=8, pool_pages=7)
+                              len_buckets=(16,), page_size=8, pool_pages=7)
         o1 = await b.submit(p1, max_new_tokens=8)
         first_pages_in_use = b.page_stats()["kv_pages_in_use"]
         o2 = await b.submit(p2, max_new_tokens=8)
@@ -240,8 +225,7 @@ def test_pool_exhaustion_sheds_newest_503(server):
         # capacity 8 pages of 4 tokens: two 4-token prompts decoding 24
         # tokens each need ~7 pages apiece — the pool can only feed one
         b = ContinuousBatcher(server, max_slots=2, max_len=32,
-                              len_buckets=(8,), layout="paged",
-                              page_size=4, pool_pages=10)
+                              len_buckets=(8,), page_size=4, pool_pages=10)
         t1 = asyncio.ensure_future(b.submit(p1, max_new_tokens=24))
         await asyncio.sleep(0)  # keep admission order deterministic
         t2 = asyncio.ensure_future(b.submit(p2, max_new_tokens=24))
@@ -269,8 +253,7 @@ def test_admission_that_can_never_fit_sheds_immediately(server):
 
     async def go():
         b = ContinuousBatcher(server, max_slots=1, max_len=24,
-                              len_buckets=(16,), layout="paged",
-                              page_size=8, pool_pages=5)  # capacity 3
+                              len_buckets=(16,), page_size=8, pool_pages=5)  # capacity 3
         try:
             with pytest.raises(ShedError):
                 # 16-token bucket needs 2 pages — fits; drain the pool
@@ -327,7 +310,7 @@ def test_radix_prefix_hit_lands_in_paged_slot(kvd):
 
     async def go():
         b = ContinuousBatcher(s, max_slots=2, max_len=32, len_buckets=(16,),
-                              layout="paged", page_size=4, prefill_chunk=4)
+                              page_size=4, prefill_chunk=4)
         assert b._radix is not None
         o1 = await b.submit(system, max_new_tokens=8)
         st1 = dict(b._radix.stats())
@@ -408,7 +391,6 @@ def test_page_gauges_reach_llm_stats_and_metrics(server):
         out = svc.submit_sync([3, 1, 4, 1, 5], 8)
         assert len(out) == 8
         st = s.llm_stats()
-        assert st["kv_cache_layout"] == "paged"
         assert st["kv_pages_total"] > 0
         assert st["kv_page_size"] == 8
         assert 0.0 <= st["kv_page_fragmentation"] <= 1.0
@@ -432,7 +414,7 @@ def test_fragmentation_gauge_math(server):
 
     async def go():
         b = ContinuousBatcher(server, max_slots=1, max_len=32,
-                              len_buckets=(8,), layout="paged", page_size=8)
+                              len_buckets=(8,), page_size=8)
         out = await b.submit([5, 9, 17], max_new_tokens=4)
         # after completion everything is freed -> fragmentation 0
         st = b.page_stats()
@@ -447,8 +429,6 @@ def test_fragmentation_gauge_math(server):
 
 # ------------------------------------------------------------ validation
 def test_layout_validated_at_load():
-    with pytest.raises(ValueError, match="kv_cache_layout"):
-        make_server(kv_cache_layout="banana")
     with pytest.raises(ValueError, match="kv_page_size"):
         make_server(kv_page_size=-1)
     with pytest.raises(ValueError, match="prefill_chunk"):
@@ -460,7 +440,7 @@ def test_layout_validated_at_load():
 def test_pool_too_small_for_one_sequence_rejected(server):
     with pytest.raises(ValueError, match="kv_pool_pages"):
         ContinuousBatcher(server, max_slots=1, max_len=32, len_buckets=(8,),
-                          layout="paged", page_size=8, pool_pages=3)
+                          page_size=8, pool_pages=3)
 
 
 # ------------------------------------------------------------- kernel

@@ -61,7 +61,6 @@ def chat_turns(server, turns, *, n=6, seeds=None, disaggregation=None,
     prompt = previous prompt + previous answer + the turn's user tokens
     (exactly the traffic the radix trie exists for). Returns (outputs,
     per-turn radix stats snapshots, final page stats)."""
-    batcher_kw.setdefault("layout", "paged")
     batcher_kw.setdefault("page_size", 4)
     batcher_kw.setdefault("max_len", 64)
     batcher_kw.setdefault("len_buckets", (16, 32))
@@ -160,7 +159,7 @@ def test_disagg_suffix_only_handoff(server):
     """The D2D handoff carries ONLY the uncached suffix: a turn-2 prompt
     that extends turn 1 ships fewer bytes than its cold equivalent even
     though its prompt is LONGER."""
-    batcher_kw = dict(layout="paged", page_size=4, max_len=64,
+    batcher_kw = dict(page_size=4, max_len=64,
                       len_buckets=(16, 32), prefill_chunk=8)
 
     async def go():
@@ -286,8 +285,7 @@ def test_cow_pin_never_starves_an_idle_minimum_pool(server):
     async def go():
         # capacity 4 = exactly one max_len sequence's pages
         b = ContinuousBatcher(server, max_slots=2, max_len=16,
-                              len_buckets=(16,), layout="paged",
-                              page_size=4, pool_pages=6, prefill_chunk=4)
+                              len_buckets=(16,), page_size=4, pool_pages=6, prefill_chunk=4)
         o1 = await b.submit([1, 2, 3, 4, 5, 6, 7, 8], max_new_tokens=3)
         st1 = dict(b._radix.stats())
         # 15-token prompt: matches 2 full blocks + part-way into the
@@ -319,8 +317,7 @@ def test_batcher_eviction_relieves_pool_pressure(server):
 
     async def go():
         b = ContinuousBatcher(server, max_slots=2, max_len=32,
-                              len_buckets=(16,), layout="paged",
-                              page_size=4, pool_pages=12,  # 10 usable
+                              len_buckets=(16,), page_size=4, pool_pages=12,  # 10 usable
                               prefill_chunk=8)
         # fill the trie: two distinct 4-token prompts x (4 + 5 written)
         o1 = await b.submit([10, 11, 12, 13], max_new_tokens=6)
@@ -456,8 +453,7 @@ def test_flight_recorder_prefix_hit_span_carries_blocks(server):
 
     async def go():
         b = ContinuousBatcher(server, max_slots=2, max_len=32,
-                              len_buckets=(16,), layout="paged",
-                              page_size=4, prefill_chunk=8, tracing=True)
+                              len_buckets=(16,), page_size=4, prefill_chunk=8, tracing=True)
         prompt = [7, 6, 5, 4, 3, 2, 1, 0, 9]
         await b.submit(prompt, max_new_tokens=6)
         await b.submit(prompt, max_new_tokens=6)

@@ -66,7 +66,7 @@ def live_snapshot():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=2, max_len=40, len_buckets=(8,),
-                              layout="paged", page_size=8, tracing=True)
+                              page_size=8, tracing=True)
         await b.submit([5, 9, 17], max_new_tokens=4)
         snap = scaling_snapshot(object(), batcher=b, recorder=b._flight)
         await b.close()
@@ -144,7 +144,7 @@ def test_retry_after_hint_scales_with_backlog():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=2, max_len=40, len_buckets=(8,),
-                              layout="paged", page_size=8)
+                              page_size=8)
         idle = b.retry_after_hint()
         # 8 queued requests over 2 slots = 4 drain waves ahead (the loop
         # never ran: no submit ever started it, so poking the scheduler
@@ -203,7 +203,7 @@ def test_batcher_page_shed_uses_the_hint():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=2, max_len=40, len_buckets=(8,),
-                              layout="paged", page_size=8)
+                              page_size=8)
         reqs = _queue_dummy_requests(b, 8)
         err = b._shed_error("test")
         for r in reqs:

@@ -242,7 +242,7 @@ def test_interactive_preempts_staged_batch_prefill_never_active():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=1, max_len=48, len_buckets=(16,),
-                              layout="paged", page_size=4, prefill_chunk=2)
+                              page_size=4, prefill_chunk=2)
         batch_fut = asyncio.ensure_future(
             b.submit(long_prompt, max_new_tokens=4, tenant="bulk",
                      slo_class="batch"))
@@ -277,7 +277,7 @@ def test_batch_outputs_unchanged_by_preemption():
 
     async def once(preempt: bool):
         b = ContinuousBatcher(s, max_slots=1, max_len=48, len_buckets=(16,),
-                              layout="paged", page_size=4, prefill_chunk=2)
+                              page_size=4, prefill_chunk=2)
         fut = asyncio.ensure_future(
             b.submit(prompt, max_new_tokens=5, slo_class="batch"))
         if preempt:
@@ -307,7 +307,7 @@ def test_per_class_ttft_and_tenant_tokens_flow_metrics():
         from seldon_core_tpu.metrics.registry import MetricsRegistry
 
         b = ContinuousBatcher(s, max_slots=2, max_len=40, len_buckets=(8,),
-                              layout="paged", page_size=8)
+                              page_size=8)
         s._batcher_service = type("Svc", (), {"batcher": b})()
         try:
             await b.submit([5, 9], max_new_tokens=4, tenant="acme",
@@ -345,7 +345,7 @@ def test_quota_shed_is_503_with_retry_after():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=1, max_len=40, len_buckets=(8,),
-                              layout="paged", page_size=8)
+                              page_size=8)
         futs = [asyncio.ensure_future(
             b.submit([5, 9], max_new_tokens=4, tenant="noisy",
                      slo_class="batch")) for _ in range(5)]
@@ -370,7 +370,7 @@ def test_scaling_snapshot_reports_queue_by_class():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=1, max_len=40, len_buckets=(8,),
-                              layout="paged", page_size=8)
+                              page_size=8)
         for r in [PendingRequest(ids=[1], max_new=1, fut=None,
                                  slo_class=cls)
                   for cls in (INTERACTIVE, INTERACTIVE, BATCH)]:
@@ -391,7 +391,7 @@ def test_flight_timeline_carries_tenant_tags():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=1, max_len=40, len_buckets=(8,),
-                              layout="paged", page_size=8, tracing=True)
+                              page_size=8, tracing=True)
         await b.submit([5, 9, 2], max_new_tokens=3, tenant="acme",
                        slo_class="batch")
         await b.submit([5, 9, 2], max_new_tokens=3)
@@ -467,7 +467,7 @@ def test_slo_isolation_under_deterministic_load():
 
     async def go():
         b = ContinuousBatcher(s, max_slots=2, max_len=40, len_buckets=(8,),
-                              layout="paged", page_size=8)
+                              page_size=8)
         flood = [asyncio.ensure_future(
             b.submit([9, 9, 9], max_new_tokens=6, tenant="bulk",
                      slo_class="batch")) for _ in range(8)]

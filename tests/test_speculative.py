@@ -4,7 +4,7 @@ The bar is the same one the pipelined batcher (PR 3) and the paged cache
 (PR 7) already hold: speculation may change HOW MANY tokens arrive per
 target forward, never WHICH tokens. Greedy and seeded-sampled outputs
 through the speculative batcher must be bit-exact vs non-speculative
-``generate()`` across K in {1, 2, 4}, both KV dtypes and both layouts —
+``generate()`` across K in {1, 2, 4} and both KV dtypes —
 the rng chain advances per ACCEPTED token, so the key state after any
 prefix equals sequential decode's after the same prefix.
 
@@ -25,8 +25,9 @@ KW = dict(vocab_size=96, dim=32, n_layers=2, n_heads=2, n_kv_heads=2,
           ffn_dim=64, max_seq_len=96)
 
 # one shape vocabulary for every batcher in this file, so jit caches hit
-# across tests (each (S, K, hist_len, mode, layout) tuple is a compile)
-BKW = dict(max_slots=2, max_len=32, len_buckets=(8,), pipeline_depth=2)
+# across tests (each (S, K, hist_len, mode, n_pages) tuple is a compile)
+BKW = dict(max_slots=2, max_len=32, len_buckets=(8,), pipeline_depth=2,
+           page_size=8)
 
 
 def make_server(**extra) -> LLMServer:
@@ -97,30 +98,23 @@ def expected(server):
 
 
 # ----------------------------------------------------------- greedy parity
-@pytest.mark.parametrize("k", [
-    # tier-1 870s budget keeps the default depth; the K sweep rides CI's
-    # unfiltered steps
-    pytest.param(1, marks=pytest.mark.slow),
-    pytest.param(2, marks=pytest.mark.slow),
-    4,
-])
-def test_ngram_greedy_parity_dense(server, expected, k):
-    outs, _ = run_batch(server, PROMPTS, layout="dense", spec_mode="ngram",
-                        spec_k=k)
-    assert outs == expected
-
-
-def test_ngram_greedy_parity_paged(server, expected):
-    outs, _ = run_batch(server, PROMPTS, layout="paged", page_size=8,
-                        spec_mode="ngram", spec_k=4)
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_ngram_greedy_parity_paged(server, expected, kv):
+    """int8: quantize-on-write of a K-token verify block into the pool
+    must round-trip identically to sequential single-token writes."""
+    if kv == "int8":
+        server = make_server(kv_cache_dtype="int8")
+        expected = [server.generate([p], max_new_tokens=8)["tokens"][0]
+                    for p in PROMPTS]
+    outs, _ = run_batch(server, PROMPTS, spec_mode="ngram", spec_k=4)
+    assert server.kv_cache_dtype == kv
     assert outs == expected
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("k", [1, 2])
 def test_ngram_greedy_parity_paged_small_k(server, expected, k):
-    outs, _ = run_batch(server, PROMPTS, layout="paged", page_size=8,
-                        spec_mode="ngram", spec_k=k)
+    outs, _ = run_batch(server, PROMPTS, spec_mode="ngram", spec_k=k)
     assert outs == expected
 
 
@@ -129,62 +123,36 @@ SEEDED_PROMPTS = [[5, 9, 17, 2], [40, 3, 22], [7, 7, 7, 7, 7]]
 SEEDS = [42, 1234, 7]
 
 
-@pytest.mark.parametrize("layout", [
-    # tier-1 870s budget keeps paged (the serving default; dense greedy
-    # parity stays tier-1 above) — dense seeded rides CI's unfiltered steps
-    pytest.param("dense", marks=pytest.mark.slow),
-    "paged",
-])
-def test_ngram_seeded_parity(sampled_server, layout):
+def test_ngram_seeded_parity(sampled_server):
     """Seeded sampling through the verify step stays on generate()'s exact
     per-slot rng chain: one split per ACCEPTED token, never per forward."""
     expected = [sampled_server.generate([p], max_new_tokens=8, seed=s)["tokens"][0]
                 for p, s in zip(SEEDED_PROMPTS, SEEDS)]
-    kw = dict(page_size=8) if layout == "paged" else {}
     outs, _ = run_batch(sampled_server, SEEDED_PROMPTS, seeds=SEEDS,
-                        layout=layout, spec_mode="ngram", spec_k=4, **kw)
+                        spec_mode="ngram", spec_k=4)
     assert outs == expected
 
 
-@pytest.mark.parametrize("layout", [
-    # dense int8 is the redundant corner (dense layout + int8 write path
-    # are each already covered tier-1); the paged param keeps int8 KV in
-    # the tier-1 matrix — same trim as the paged parity suite (PR 7)
-    pytest.param("dense", marks=pytest.mark.slow),
-    # tier-1 870s budget: int8+spec rides CI's unfiltered speculative
-    # step; tier-1 keeps seeded spec via test_ngram_seeded_parity[paged]
-    pytest.param("paged", marks=pytest.mark.slow),
-])
-def test_int8_seeded_parity(int8_server, layout):
-    """int8 KV x both layouts: quantize-on-write of a K-token verify block
+# tier-1 870s budget: seeded int8+spec rides CI's unfiltered speculative
+# step; tier-1 keeps seeded spec via test_ngram_seeded_parity and int8
+# spec via test_ngram_greedy_parity_paged[int8]
+@pytest.mark.slow
+def test_int8_seeded_parity(int8_server):
+    """int8 KV: quantize-on-write of a K-token verify block
     must round-trip identically to sequential single-token writes (scales
     are per-position, so block width cannot change them)."""
     expected = [int8_server.generate([p], max_new_tokens=8, seed=s)["tokens"][0]
                 for p, s in zip(SEEDED_PROMPTS, SEEDS)]
-    kw = dict(page_size=8) if layout == "paged" else {}
     outs, _ = run_batch(int8_server, SEEDED_PROMPTS, seeds=SEEDS,
-                        layout=layout, spec_mode="ngram", spec_k=4, **kw)
-    assert outs == expected
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_int8_greedy_parity(layout):
-    s8 = make_server(kv_cache_dtype="int8")
-    expected = [s8.generate([p], max_new_tokens=8)["tokens"][0]
-                for p in PROMPTS]
-    kw = dict(page_size=8) if layout == "paged" else {}
-    outs, _ = run_batch(s8, PROMPTS, layout=layout, spec_mode="ngram",
-                        spec_k=4, **kw)
+                        spec_mode="ngram", spec_k=4)
     assert outs == expected
 
 
 # --------------------------------------------------------- draft-model path
-def test_draft_model_greedy_parity_dense(draft_server):
+def test_draft_model_greedy_parity(draft_server):
     expected = [draft_server.generate([p], max_new_tokens=8)["tokens"][0]
                 for p in PROMPTS]
-    outs, st = run_batch(draft_server, PROMPTS, layout="dense",
-                         spec_mode="draft", spec_k=4)
+    outs, st = run_batch(draft_server, PROMPTS, spec_mode="draft", spec_k=4)
     assert outs == expected
     # the perfect drafter's proposals all verify: acceptance 1.0 and the
     # multiplier approaches K+1 (EOS-less 8-token budgets cap the tail)
@@ -198,8 +166,8 @@ def test_draft_model_seeded_parity_paged():
                     draft_model_kwargs=KW, temperature=0.8, top_k=20, seed=5)
     expected = [s.generate([p], max_new_tokens=8, seed=sd)["tokens"][0]
                 for p, sd in zip(SEEDED_PROMPTS, SEEDS)]
-    outs, _ = run_batch(s, SEEDED_PROMPTS, seeds=SEEDS, layout="paged",
-                        page_size=8, spec_mode="draft", spec_k=4)
+    outs, _ = run_batch(s, SEEDED_PROMPTS, seeds=SEEDS, spec_mode="draft",
+                        spec_k=4)
     assert outs == expected
 
 
@@ -212,8 +180,7 @@ def test_eos_inside_accepted_draft_block():
     s = make_server(spec_mode="draft", draft_model="transformer",
                     draft_model_kwargs=KW, eos_id=6)
     expected = s.generate([REP], max_new_tokens=8)["tokens"][0]
-    outs, st = run_batch(s, [REP], layout="dense", spec_mode="draft",
-                         spec_k=4)
+    outs, st = run_batch(s, [REP], spec_mode="draft", spec_k=4)
     assert outs[0] == expected
     # proof the EOS really landed INSIDE an accepted block: the device
     # advanced further per forward than the host surfaced (trailing
@@ -230,8 +197,7 @@ def test_midstream_admit_with_steps_in_flight(server, expected):
     prompts = PROMPTS + [[12, 13], [80, 2, 5]]
     exp = expected + [server.generate([p], max_new_tokens=8)["tokens"][0]
                       for p in [[12, 13], [80, 2, 5]]]
-    outs, st = run_batch(server, prompts, layout="paged", page_size=8,
-                         spec_mode="ngram", spec_k=4)
+    outs, st = run_batch(server, prompts, spec_mode="ngram", spec_k=4)
     assert outs == exp
     # 6 requests through 2 slots: later admits MUST have found steps in
     # flight (the pipeline keeps dispatching while slots turn over)
@@ -244,8 +210,7 @@ def test_repetitive_text_beats_1_5_tokens_per_forward(server):
     """The ISSUE 8 acceptance bar: >1.5 accepted tokens per target forward
     at K=4 with the n-gram drafter on repetitive text."""
     expected = server.generate([REP], max_new_tokens=18)["tokens"][0]
-    outs, st = run_batch(server, [REP], n=18, layout="paged", page_size=8,
-                         spec_mode="ngram", spec_k=4)
+    outs, st = run_batch(server, [REP], n=18, spec_mode="ngram", spec_k=4)
     assert outs[0] == expected
     assert st["spec_tokens_per_forward"] > 1.5, st
     assert st["spec_accept_rate"] > 0.0

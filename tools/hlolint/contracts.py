@@ -30,11 +30,11 @@ SLOTS = 4          # continuous-batcher slots
 N_STEPS = 7        # decode scan length (max_new_tokens - 1)
 KV_HEADS = 2       # llama-tiny n_kv_heads
 HEAD_DIM = 16      # llama-tiny head_dim
-# paged layout (PR 7): 8-token pages, 3 pages/slot view, and an
+# the page pool (PR 7): 8-token pages, 3 pages/slot view, and an
 # OVERSUBSCRIBED pool (10 pages = 8 usable + 2 reserved, vs the 12 a fully
 # provisioned 4-slot pool would need) — the contract compiles the pool
-# shape serving actually runs, so the cost budget records the paged step's
-# bytes against a pool smaller than the dense slot cache
+# shape serving actually runs, so the cost budget records the step's
+# bytes against a pool smaller than S x max_len
 PAGE_SIZE = 8
 PAGES_PER_SLOT = 3  # ceil(MAX_LEN / PAGE_SIZE)
 POOL_PAGES = 10
@@ -196,19 +196,6 @@ def _moe_server():
         return _STATE["moe_server"]
 
 
-def _batcher():
-    with _STATE_LOCK:  # nests into _base_server's hold: RLock
-        if "batcher" not in _STATE:
-            from seldon_core_tpu.runtime.batcher import ContinuousBatcher
-
-            # layout pinned: these contracts cover the DENSE slot pool
-            # (insert/set_slot); the paged pool has its own contracts below
-            _STATE["batcher"] = ContinuousBatcher(
-                _base_server(), max_slots=SLOTS, max_len=MAX_LEN,
-                layout="dense")
-        return _STATE["batcher"]
-
-
 def _paged_batcher():
     with _STATE_LOCK:  # nests into _base_server's hold: RLock
         if "paged_batcher" not in _STATE:
@@ -216,7 +203,7 @@ def _paged_batcher():
 
             _STATE["paged_batcher"] = ContinuousBatcher(
                 _base_server(), max_slots=SLOTS, max_len=MAX_LEN,
-                layout="paged", page_size=PAGE_SIZE, pool_pages=POOL_PAGES,
+                page_size=PAGE_SIZE, pool_pages=POOL_PAGES,
                 prefill_chunk=PAGE_SIZE)
         return _STATE["paged_batcher"]
 
@@ -344,14 +331,6 @@ def _build_decode_scan():
                 _sds((), "float32"))
 
 
-def _build_decode_step():
-    s = _base_server()
-    fn = s._get_decode_step(SLOTS, MAX_LEN, 1)
-    return fn, (s._params, _cache_specs(SLOTS), _sds((SLOTS,), "int32"),
-                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
-                _sds((), "float32"))
-
-
 def _build_decode_scan_tp2():
     import jax
 
@@ -366,19 +345,24 @@ def _build_decode_scan_tp2():
 
 
 def _build_batcher_insert():
-    b = _batcher()
+    """The one whole-cache insert left: a draft model's prompt prefill
+    landing in its dense [S, max_len] cache (spec_mode='draft')."""
     import jax
 
     from seldon_core_tpu.models.transformer import init_kv_caches
+    from seldon_core_tpu.runtime.batcher import ContinuousBatcher
 
-    s = _base_server()
+    s = _draft_server()
+    b = ContinuousBatcher(
+        s, max_slots=SLOTS, max_len=MAX_LEN, page_size=PAGE_SIZE,
+        pool_pages=POOL_PAGES, prefill_chunk=PAGE_SIZE)
     small = jax.eval_shape(
-        lambda: init_kv_caches(s._cfg, 1, MAX_LEN, s.kv_cache_dtype))
-    return b._insert, (b._caches, small, _sds((), "int32"))
+        lambda: init_kv_caches(s._draft_cfg, 1, MAX_LEN))
+    return b._draft_insert, (b._draft_caches, small, _sds((), "int32"))
 
 
 def _build_batcher_set_slot():
-    b = _batcher()
+    b = _paged_batcher()
     return b._set_slot, (b._last_tok, b._next_pos, b._keys,
                          _sds((), "int32"), _sds((), "int32"),
                          _sds((), "int32"), _sds((2,), "uint32"))
@@ -447,11 +431,11 @@ def _build_handoff_import():
 
 
 def _build_verify_step_k4():
-    """ngram spec step over the PAGED pool: the serving-default
+    """ngram spec step over the page pool: the serving-default
     speculative hot function (self-draft, zero extra weights)."""
     s = _base_server()
     fn = s._get_spec_step(SLOTS, SPEC_K, MAX_LEN, mode="ngram",
-                          layout="paged", n_pages=PAGES_PER_SLOT)
+                          n_pages=PAGES_PER_SLOT)
     return fn, (s._params, _paged_cache_specs(), _sds((SLOTS,), "int32"),
                 _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
                 _sds((), "float32"),
@@ -459,35 +443,23 @@ def _build_verify_step_k4():
                 _sds((SLOTS, MAX_LEN), "int32"), _sds((SLOTS,), "int32"))
 
 
-def _build_verify_step_dense_k4():
-    """ngram spec step over the DENSE slot cache (the A/B reference
-    layout): same program shape, per-position scatter instead of the
-    block-table redirect."""
-    s = _base_server()
-    fn = s._get_spec_step(SLOTS, SPEC_K, MAX_LEN, mode="ngram",
-                          layout="dense")
-    return fn, (s._params, _cache_specs(SLOTS), _sds((SLOTS,), "int32"),
-                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
-                _sds((), "float32"),
-                _sds((SLOTS, MAX_LEN), "int32"), _sds((SLOTS,), "int32"))
-
-
 def _build_draft_verify_step_k4():
-    """draft-model spec step (dense layout): K+1 sequential draft
-    forwards fused with the single K+1-token target verify, the draft's
-    own dense cache donated through the program alongside the target's."""
+    """draft-model spec step: K+1 sequential draft forwards fused with the
+    single K+1-token target verify over the page pool, the draft's own
+    dense cache donated through the program alongside the pool."""
     import jax
 
     from seldon_core_tpu.models.transformer import init_kv_caches
 
     s = _draft_server()
     fn = s._get_spec_step(SLOTS, SPEC_K, MAX_LEN, mode="draft",
-                          layout="dense")
+                          n_pages=PAGES_PER_SLOT)
     dcaches = jax.eval_shape(
         lambda: init_kv_caches(s._draft_cfg, SLOTS, MAX_LEN))
-    return fn, (s._params, _cache_specs(SLOTS), _sds((SLOTS,), "int32"),
+    return fn, (s._params, _paged_cache_specs(), _sds((SLOTS,), "int32"),
                 _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
                 _sds((), "float32"),
+                _sds((SLOTS, PAGES_PER_SLOT), "int32"),
                 _sds((SLOTS, MAX_LEN), "int32"), _sds((SLOTS,), "int32"),
                 s._draft_params, dcaches)
 
@@ -514,7 +486,7 @@ def _build_lora_verify_step():
     loop enforces the adapted distribution either way)."""
     s = _lora_server()
     fn = s._get_spec_step(SLOTS, SPEC_K, MAX_LEN, mode="ngram",
-                          layout="paged", n_pages=PAGES_PER_SLOT, lora=True)
+                          n_pages=PAGES_PER_SLOT, lora=True)
     return fn, (s._params, _paged_cache_specs(), _sds((SLOTS,), "int32"),
                 _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
                 _sds((), "float32"),
@@ -524,7 +496,7 @@ def _build_lora_verify_step():
 
 
 def _build_set_hist_row():
-    b = _batcher()
+    b = _paged_batcher()
     return b._set_hist_row, (_sds((SLOTS, MAX_LEN), "int32"),
                              _sds((), "int32"), _sds((MAX_LEN,), "int32"))
 
@@ -636,16 +608,6 @@ def all_contracts() -> List[Contract]:
             cost=True,
         ),
         Contract(
-            name="llm.decode_step_s4",
-            description="ContinuousBatcher pipelined decode step (S=4, k=1): "
-                        "THE hot function of served decode",
-            build=_build_decode_step,
-            donated=(1, 3, 4),
-            forbid_dtypes=((_f32_cache_sig(SLOTS), F32_CACHE_WHY),),
-            collectives={},
-            cost=True,
-        ),
-        Contract(
             name="llm.decode_scan_tp2",
             description="decode scan under tensor_parallel=2 on the virtual "
                         "8-mesh: the TP collective budget",
@@ -668,9 +630,9 @@ def all_contracts() -> List[Contract]:
         ),
         Contract(
             name="llm.paged_decode_step_s4",
-            description="ContinuousBatcher PAGED pipelined decode step "
+            description="ContinuousBatcher pipelined decode step "
                         "(S=4, k=1, 8-token pages, oversubscribed 10-page "
-                        "pool): the hot function of paged served decode",
+                        "pool): THE hot function of served decode",
             build=_build_paged_decode_step,
             donated=(1, 3, 4),
             forbid_dtypes=((_f32_pool_sig(), F32_CACHE_WHY),),
@@ -727,26 +689,15 @@ def all_contracts() -> List[Contract]:
             cost=True,
         ),
         Contract(
-            name="llm.verify_step_dense_k4",
-            description="speculative ngram draft+verify step over the "
-                        "dense slot cache (the A/B reference layout): "
-                        "PAD_POS columns drop their writes instead of "
-                        "redirecting to the trash page",
-            build=_build_verify_step_dense_k4,
-            donated=(1, 3, 4, 6),
-            forbid_dtypes=((_f32_cache_sig(SLOTS), F32_CACHE_WHY),),
-            collectives={},
-            cost=True,
-        ),
-        Contract(
             name="llm.draft_verify_step_k4",
-            description="draft-model spec step (S=4, K=4, dense): K+1 "
+            description="draft-model spec step (S=4, K=4): K+1 "
                         "sequential greedy draft forwards fused with the "
-                        "single K+1-token target verify; BOTH caches "
-                        "(target + draft) must donate through the program",
+                        "single K+1-token target verify; BOTH caches (the "
+                        "target's pool + the draft's dense cache) must "
+                        "donate through the program",
             build=_build_draft_verify_step_k4,
-            donated=(1, 3, 4, 6, 9),
-            forbid_dtypes=((_f32_cache_sig(SLOTS), F32_CACHE_WHY),),
+            donated=(1, 3, 4, 7, 10),
+            forbid_dtypes=((_f32_pool_sig(), F32_CACHE_WHY),),
             collectives={},
             cost=True,
         ),
@@ -856,8 +807,9 @@ def all_contracts() -> List[Contract]:
         ),
         Contract(
             name="batcher.insert",
-            description="ContinuousBatcher slot insert: the big slot cache "
-                        "must be donated through the scatter",
+            description="ContinuousBatcher draft-cache insert "
+                        "(spec_mode='draft'): the big [S, max_len] draft "
+                        "cache must be donated through the scatter",
             build=_build_batcher_insert,
             donated=(0,),
             collectives={},
