@@ -40,6 +40,8 @@ def _f32(leaf, index: Optional[int] = None):
     dequantized with their own scales."""
     if hasattr(leaf, "q"):
         q, scale = leaf.q, leaf.scale
+        if leaf.out_major:  # held [out, in]: the logical matrix is its transpose
+            return (jnp.asarray(q, jnp.float32) * jnp.asarray(scale, jnp.float32)[:, None]).T
         if index is not None:
             q, scale = q[index], (scale[index] if scale.ndim == 2 else scale)
         elif scale.ndim == 2:

@@ -100,6 +100,7 @@ def int8_dense(x, qt, out_dtype=None):
     lead = x.shape[:-1]
     k = x.shape[-1]
     x2 = x.reshape((-1, k)) if lead else x.reshape((1, k))
-    out = int8_matmul(x2, qt.q, qt.scale, out_dtype=out_dtype)
+    out = int8_matmul(x2, qt.q.T if qt.out_major else qt.q, qt.scale,
+                      out_dtype=out_dtype)
     n = out.shape[-1]
     return out.reshape((*lead, n)) if lead else out.reshape((n,))

@@ -3,10 +3,34 @@
 TPU serving is usually HBM-bandwidth-bound; storing weights as int8 halves
 the weight traffic vs bf16 while the MXU still computes in bf16: inside the
 jitted forward each quantized leaf is dequantized as ``q.astype(bf16) *
-scale`` and XLA fuses the convert+multiply into the consuming matmul/conv —
-weights live in HBM as int8, dequant happens on the fly in VMEM. (The
-reference's native-performance path delegates to TensorRT for this role;
-here it is a first-class transform on any checkpoint.)
+scale``, and weights live in HBM as int8. (The reference's native-performance
+path delegates to TensorRT for this role; here it is a first-class transform
+on any checkpoint.)
+
+What the v5e's compiler does with that expression depends on the consumer
+(read from the compiled step programs, PERF.md section 3 "Reading a program's
+ops without a chip"):
+
+- a plain ``x @ W`` (the transformer's ``wo``, ``w1``, ``w3``, ``w2``): the
+  convert+multiply fuses INTO the matmul's own fusion, the int8 bytes are read
+  once and no floating copy of the weight exists;
+- a projection whose output is split into heads (``wq``, ``wk``, ``wv``: the
+  matmul fuses with the reshape and the rotary instead): the dequant stays a
+  fusion of its own that writes the weight out in bf16, and the convolution
+  wants that array with the CONTRACTED dimension minor. Held ``[in, out]`` it
+  was therefore also transposed, a second whole-weight pass that computes
+  nothing (``copy bf16[4096,4096]`` + 2 x ``copy bf16[1024,4096]``, 1.74 ms
+  of an 18.75 ms Mistral step). Such a leaf is held OUTPUT-MAJOR: ``q`` is
+  ``[out, in]`` in HBM (``out_major``), the transpose back to the logical
+  ``[in, out]`` is a bitcast, and the copy is gone. The standalone dequant
+  remains;
+- the head (``lm_head``, a float32 matmul): a ``bf16[vocab, dim]`` copy a step.
+
+The orientation rule: a matrix is held in the order its consumer reads it.
+Which leaves those are is read off the module's logical axes (an output axis
+of ``heads`` / ``kv_heads``: parallel/sharding.py ``head_split_outputs``), not
+off a model's name or an option; the matrix, its scales and every dequantized
+value are the same either way.
 
 Scheme: symmetric per-output-channel int8 (scale = max|w| / 127 over all
 dims but the last). 1-D leaves (biases, norms) and integer leaves pass
@@ -31,16 +55,20 @@ import numpy as np
 @dataclass
 class QuantizedTensor:
     """int8 values + per-channel f32 scales (broadcast over the last dim).
-    ``orig_dtype`` records the dtype dequantization restores (static pytree
-    metadata, so one compiled program per dtype)."""
+    ``orig_dtype`` records the dtype dequantization restores and
+    ``out_major`` that ``q`` holds the matrix transposed, ``[C, K]`` for a
+    logical ``[K, C]`` (both static pytree metadata, so one compiled program
+    per dtype and orientation)."""
 
-    q: Any  # int8 [..., C]
+    q: Any  # int8 [..., C]; [C, K] when out_major
     scale: Any  # f32 [C]; [E, C] for a stack of matrices [E, ..., C]
     orig_dtype: str = "bfloat16"
+    out_major: bool = False
 
     @property
     def shape(self):
-        return self.q.shape
+        """The logical matrix's shape, however ``q`` is held."""
+        return self.q.shape[::-1] if self.out_major else self.q.shape
 
     @property
     def dtype(self):
@@ -57,16 +85,18 @@ def _register_pytree() -> None:
     try:
         jax.tree_util.register_pytree_node(
             QuantizedTensor,
-            lambda t: ((t.q, t.scale), t.orig_dtype),
-            lambda aux, children: QuantizedTensor(*children, orig_dtype=aux),
+            lambda t: ((t.q, t.scale), (t.orig_dtype, t.out_major)),
+            lambda aux, children: QuantizedTensor(*children, *aux),
         )
     except ValueError:
         pass  # already registered
 
 
-def quantize_array(w, bits: int = 8):
+def quantize_array(w, bits: int = 8, out_major: bool = False):
     """Symmetric per-last-dim-channel quantization of one float array; a
-    3-D array is a stack of matrices and keeps its leading axis in the scale."""
+    3-D array is a stack of matrices and keeps its leading axis in the scale.
+    ``out_major`` holds a matrix's int8 values transposed (the same values
+    and scales, the byte order its consumer reads)."""
     import jax.numpy as jnp
 
     qmax = 2 ** (bits - 1) - 1
@@ -77,14 +107,22 @@ def quantize_array(w, bits: int = 8):
     amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=reduce_dims, keepdims=stack)
     scale = jnp.where(amax > 0, amax / qmax, 1.0).astype(jnp.float32)
     q = jnp.clip(jnp.round(w.astype(jnp.float32) / scale), -qmax - 1, qmax).astype(jnp.int8)
+    if out_major:
+        if w.ndim != 2:
+            raise ValueError(f"out_major holds a matrix, not shape {w.shape}")
+        q = q.T
     return QuantizedTensor(q=q, scale=scale[:, 0, :] if stack else scale,
-                           orig_dtype=orig_dtype)
+                           orig_dtype=orig_dtype, out_major=out_major)
 
 
 def dequantize_array(t: QuantizedTensor, dtype=None):
     import jax.numpy as jnp
 
     dtype = jnp.dtype(dtype or t.orig_dtype)
+    if t.out_major:
+        # the logical [K, C] matrix: the transpose of what is held is a
+        # bitcast to a consumer that contracts over K
+        return (t.q.astype(dtype) * t.scale.astype(dtype)[:, None]).T
     scale = t.scale[:, None, :] if t.stacked else t.scale
     return t.q.astype(dtype) * scale.astype(dtype)
 
@@ -100,17 +138,21 @@ def _is_quantizable(leaf) -> bool:
     return jnp.issubdtype(jnp.dtype(str(dtype)), jnp.floating) and getattr(leaf, "ndim", 0) >= 2
 
 
-def quantize_params(params: Any, bits: int = 8) -> Any:
+def quantize_params(params: Any, bits: int = 8, out_major: Any = None) -> Any:
     """Quantize every ≥2-D float leaf of a param pytree; the rest passes
-    through. Returns a tree mixing QuantizedTensor and original leaves."""
+    through. Returns a tree mixing QuantizedTensor and original leaves.
+    ``out_major`` is a tree of bools shaped like ``params`` (parallel/
+    sharding.py ``head_split_outputs``): the leaves to hold output-major."""
     import jax
 
     _register_pytree()
 
-    def visit(leaf):
-        return quantize_array(leaf, bits) if _is_quantizable(leaf) else leaf
+    def visit(leaf, transposed=False):
+        return quantize_array(leaf, bits, transposed) if _is_quantizable(leaf) else leaf
 
-    return jax.tree.map(visit, params)
+    if out_major is None:
+        return jax.tree.map(visit, params)
+    return jax.tree.map(visit, params, out_major)
 
 
 def dequantize_params(params: Any, dtype=None, keep_stacks: bool = False) -> Any:

@@ -42,7 +42,6 @@ def logical_axis_tree(module, example_input):
     """Abstract-init the module to recover the logical PartitionSpec tree for
     its params (the 'params_axes' collection), without allocating memory."""
     import jax
-    from flax.linen import partitioning as nn_partitioning
 
     def _init():
         x = example_input
@@ -50,10 +49,38 @@ def logical_axis_tree(module, example_input):
             x = jax.numpy.zeros(x.shape, x.dtype)
         return module.init(jax.random.PRNGKey(0), x)
 
-    abstract = jax.eval_shape(_init)
+    return logical_axes_of(jax.eval_shape(_init))
+
+
+def logical_axes_of(abstract):
+    """The logical PartitionSpec tree of an (abstractly) initialized
+    module's variables; None where the module names no axes."""
+    from flax.linen import partitioning as nn_partitioning
+
     if "params_axes" not in abstract:
         return None
     return nn_partitioning.get_axis_names(abstract["params_axes"])
+
+
+# an output axis that the consumer splits into attention heads
+HEAD_SPLIT_AXES = ("heads", "kv_heads")
+
+
+def head_split_outputs(params: Any, logical_specs: Any):
+    """Per leaf of ``params``: is it a matrix whose OUTPUT axis is split into
+    attention heads (the q/k/v projections of models/transformer.py
+    Attention)? The TPU compiler fuses such a projection with the split and
+    the rotary and reads the weight output-major, so that is how its int8
+    values are held (ops/quantize.py, "the orientation rule"). No leaf where
+    the module names no axes."""
+    import jax
+
+    if logical_specs is None:
+        return jax.tree.map(lambda _: False, params)
+    return jax.tree.map(
+        lambda s: s is not None and len(s) == 2 and s[-1] in HEAD_SPLIT_AXES,
+        _align_specs(params, logical_specs),
+        is_leaf=lambda x: x is None or _is_spec(x))
 
 
 def shard_params(params: Any, mesh, logical_specs: Any, rules=DEFAULT_LOGICAL_RULES):
@@ -61,11 +88,11 @@ def shard_params(params: Any, mesh, logical_specs: Any, rules=DEFAULT_LOGICAL_RU
     Params without a spec (or when logical_specs is None) are replicated.
 
     Int8-quantized leaves (ops.quantize.QuantizedTensor) shard too: the
-    weight's logical spec applies to ``q`` unchanged (same shape as the
-    original float leaf), and the per-output-channel ``scale`` [C] takes the
-    spec's LAST axis (the channel dim it broadcasts over; a stack's [E, C]
-    scale its first and last) — so int8 serving
-    composes with tensor parallelism instead of excluding it."""
+    weight's logical spec applies to ``q`` (same shape as the original float
+    leaf; reversed with the array for a leaf held ``out_major``), and the
+    per-output-channel ``scale`` [C] takes the spec's LAST axis (the channel
+    dim it broadcasts over; a stack's [E, C] scale its first and last) — so
+    int8 serving composes with tensor parallelism instead of excluding it."""
     import jax
     from flax.linen import partitioning as nn_partitioning
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -98,7 +125,8 @@ def shard_params(params: Any, mesh, logical_specs: Any, rules=DEFAULT_LOGICAL_RU
         if is_q(p):
             if s is not None:
                 mesh_spec = list(to_mesh_spec(s))
-                wsh = NamedSharding(mesh, P(*mesh_spec))
+                wsh = NamedSharding(
+                    mesh, P(*(mesh_spec[::-1] if p.out_major else mesh_spec)))
                 last = mesh_spec[-1] if mesh_spec else None
                 # a stack's scale [E, C] keeps the stack axis beside the channel
                 ssh = NamedSharding(
@@ -109,6 +137,7 @@ def shard_params(params: Any, mesh, logical_specs: Any, rules=DEFAULT_LOGICAL_RU
                 q=jax.device_put(p.q, wsh),
                 scale=jax.device_put(p.scale, ssh),
                 orig_dtype=p.orig_dtype,
+                out_major=p.out_major,
             ))
         else:
             out.append(jax.device_put(p, to_sharding(s) if s is not None else replicated))

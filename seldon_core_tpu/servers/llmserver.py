@@ -695,6 +695,7 @@ class LLMServer(SeldonComponent):
 
         self._module = get_model(name, **cfg_kwargs)
         self._cfg = self._module.cfg
+        self._abstract_init = None  # _init_shapes(), of this module
 
         # Big-config random init (e.g. Llama-2-7B dims for capacity/perf
         # work): whole-tree f32 init is 4 bytes/param — 27 GB at 7B, over
@@ -739,18 +740,30 @@ class LLMServer(SeldonComponent):
         if self.quantize:
             if self.quantize != "int8":
                 raise SeldonError(f"unsupported quantize={self.quantize!r} (int8 only)", status_code=500)
-            from seldon_core_tpu.ops.quantize import dequantize_params, quantize_params
+            from seldon_core_tpu.ops.quantize import (
+                QuantizedTensor, dequantize_params, quantize_params)
+            from seldon_core_tpu.parallel.sharding import head_split_outputs
 
-            params = self._streamed_quantized_init() if streamed else quantize_params(params)
+            # the projections that feed the head split are held output-major,
+            # the order their consumer reads (ops/quantize.py)
+            if streamed:
+                params = self._streamed_quantized_init()
+            else:
+                params = quantize_params(params, out_major=head_split_outputs(
+                    params, self._logical_axes()))
+            is_q = lambda x: isinstance(x, QuantizedTensor)  # noqa: E731
+            held = sum(is_q(leaf) and leaf.out_major
+                       for leaf in jax.tree.leaves(params, is_leaf=is_q))
+            logger.info("int8 weights: %d leaves held output-major "
+                        "(the q/k/v projections)", held)
             # expert stacks stay int8 inside the programs: MoEFFN's grouped
             # matmul takes them as they are (ops/quantize.py)
             self._dequant = partial(dequantize_params, keep_stacks=True)
 
         if self.mesh is not None:
-            from seldon_core_tpu.parallel.sharding import logical_axis_tree, shard_params
+            from seldon_core_tpu.parallel.sharding import shard_params
 
-            logical = logical_axis_tree(self._module, jax.ShapeDtypeStruct((1, 8), jnp.int32))
-            params = shard_params(params, self.mesh, logical)
+            params = shard_params(params, self.mesh, self._logical_axes())
         else:
             # a msgpack restore yields host (numpy) arrays; left there, jit
             # uploads every weight again on every call (servers/jaxserver.py)
@@ -832,12 +845,20 @@ class LLMServer(SeldonComponent):
             return params
 
     def _init_shapes(self):
+        """The module's variables as shapes (its logical axes among them),
+        traced once a load: a 32-layer module takes about a second."""
         import jax
         import jax.numpy as jnp
 
-        return jax.eval_shape(
-            self._module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
-        )
+        if self._abstract_init is None:
+            self._abstract_init = jax.eval_shape(
+                self._module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+        return self._abstract_init
+
+    def _logical_axes(self):
+        from seldon_core_tpu.parallel.sharding import logical_axes_of
+
+        return logical_axes_of(self._init_shapes())
 
     def _init_nbytes_f32(self) -> int:
         import jax
@@ -845,7 +866,9 @@ class LLMServer(SeldonComponent):
         return sum(leaf.size * 4 for leaf in jax.tree.leaves(self._init_shapes()))
 
     def _streamed_quantized_init(self):
-        """Leaf-by-leaf on-device random init + int8 quantize.
+        """Leaf-by-leaf on-device random init + int8 quantize; the leaves
+        that ``quantize_params`` is told to hold output-major for a
+        checkpoint are held so here.
 
         Semantics match the whole-tree path in kind (≥2-D float leaves
         become QuantizedTensor, 1-D leaves stay float) but not in exact
@@ -865,26 +888,30 @@ class LLMServer(SeldonComponent):
         from jax.tree_util import keystr, tree_flatten_with_path
 
         from seldon_core_tpu.ops.quantize import _register_pytree, quantize_array
+        from seldon_core_tpu.parallel.sharding import head_split_outputs
 
         _register_pytree()  # jit returns QuantizedTensor leaves
         target = jnp.dtype(self._cfg.dtype) if self.param_dtype == "auto" else (
             jnp.dtype(self.param_dtype) if self.param_dtype else jnp.float32
         )
 
-        @_partial(jax.jit, static_argnums=(1, 2))
-        def make_quantized(key, shape, std):
+        @_partial(jax.jit, static_argnums=(1, 2, 3))
+        def make_quantized(key, shape, std, out_major):
             w = jax.random.normal(key, shape, jnp.float32) * std
-            return quantize_array(w.astype(target))
+            return quantize_array(w.astype(target), out_major=out_major)
 
-        flat, treedef = tree_flatten_with_path(self._init_shapes())
+        shapes = self._init_shapes()
+        flat, treedef = tree_flatten_with_path(shapes)
+        transposed = jax.tree.leaves(head_split_outputs(shapes, self._logical_axes()))
         root = jax.random.PRNGKey(self.seed)
         leaves = []
-        for path, spec in flat:
+        for (path, spec), out_major in zip(flat, transposed):
             name = keystr(path)
             if jnp.issubdtype(spec.dtype, jnp.floating) and spec.ndim >= 2:
                 key = jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
                 fan_in = int(np.prod(spec.shape[1 if spec.ndim == 3 else 0:-1]))
-                leaves.append(make_quantized(key, spec.shape, 1.0 / float(fan_in) ** 0.5))
+                leaves.append(make_quantized(
+                    key, spec.shape, 1.0 / float(fan_in) ** 0.5, out_major))
             elif jnp.issubdtype(spec.dtype, jnp.floating):
                 fill = 1.0 if ("norm" in name.lower() or "scale" in name.lower()
                                or name.lower().endswith("weight']")) else 0.0

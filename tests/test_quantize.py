@@ -306,10 +306,192 @@ def test_streamed_init_gives_each_expert_its_own_fan_in(monkeypatch):
         jax.random.PRNGKey(5),
         zlib.crc32(b"['params']['layer_0']['attention']['wq']") & 0x7FFFFFFF)
     drawn = jax.random.normal(key, (256, 256), jnp.float32) / 16.0
-    want = quantize_params({"w": drawn})["w"]
+    want = quantize_params({"w": drawn}, out_major={"w": True})["w"]
     got = server._params["params"]["layer_0"]["attention"]["wq"]
+    assert got.out_major  # held in the order its consumer reads (same values)
     # (the server draws and quantizes inside one jit: the scale may differ
     # from this eager one in its last bit, and a value on a rounding edge by 1)
     np.testing.assert_allclose(np.asarray(got.scale), np.asarray(want.scale), rtol=1e-6)
     off = np.asarray(got.q, np.int32) - np.asarray(want.q, np.int32)
     assert np.abs(off).max() <= 1 and np.mean(off != 0) < 1e-3
+
+
+# ---------------------------------------------------------------------------
+# leaves held output-major (the q/k/v projections): the same matrix, the same
+# scales, the int8 bytes in the order the consumer reads them
+# ---------------------------------------------------------------------------
+
+TINY = dict(vocab_size=96, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
+            ffn_dim=64, max_seq_len=96)
+HELD_OUT_MAJOR = ("wq", "wk", "wv")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [(40, 24), (32, 32), (24, 40)])
+def test_out_major_leaf_is_the_same_matrix(shape, dtype):
+    """Only the order of the bytes changes: q is the transpose, the scales are
+    the same array, every dequantized value is the same bit for bit (in the
+    stored dtype and in another), and the reported size is the same."""
+    from seldon_core_tpu.ops.quantize import dequantize_array, quantize_array, quantized_bytes
+
+    w = jnp.asarray(np.random.default_rng(3).normal(0, 0.3, size=shape), dtype)
+    plain, held = quantize_array(w), quantize_array(w, out_major=True)
+    assert held.out_major and not plain.out_major
+    assert held.q.shape == shape[::-1] and held.shape == plain.shape == shape
+    assert np.array_equal(np.asarray(held.q), np.asarray(plain.q).T)
+    assert np.array_equal(np.asarray(held.scale), np.asarray(plain.scale))
+    for to in (None, jnp.bfloat16, jnp.float32):
+        a, b = dequantize_array(plain, to), dequantize_array(held, to)
+        assert a.shape == b.shape == shape and a.dtype == b.dtype
+        assert np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+    assert quantized_bytes({"w": held}) == quantized_bytes({"w": plain})
+    # static metadata: the orientation survives a jit boundary and a tree map
+    back = jax.jit(lambda t: t)(held)
+    assert back.out_major and jax.tree.map(lambda x: x, held).out_major
+    with pytest.raises(ValueError):
+        quantize_array(jnp.zeros((2, 4, 4)), out_major=True)
+
+
+def test_out_major_is_read_off_the_logical_axes():
+    """Which leaves: those whose OUTPUT axis is split into attention heads
+    (wq, wk, wv), by the module's own axis names; the projections that a plain
+    matmul consumes (wo, the FFN, the head, the embedding) stay as they were,
+    and so does everything for a module that names no axes."""
+    from seldon_core_tpu.parallel.sharding import head_split_outputs, logical_axis_tree
+
+    module = get_model("transformer", qk_norm=True, **TINY)
+    example = jax.ShapeDtypeStruct((1, 8), jnp.int32)
+    shapes = jax.eval_shape(module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    chosen = head_split_outputs(shapes, logical_axis_tree(module, example))
+    flat = {jax.tree_util.keystr(path): v
+            for path, v in jax.tree_util.tree_flatten_with_path(chosen)[0]}
+    assert len(flat) == len(jax.tree.leaves(shapes))
+    assert {k for k, v in flat.items() if v} == {
+        f"['params']['layer_{i}']['attention']['{w}']"
+        for i in range(TINY["n_layers"]) for w in HELD_OUT_MAJOR}
+    assert not any(jax.tree.leaves(head_split_outputs(shapes, None)))
+
+
+def _int8_server(**kwargs):
+    from seldon_core_tpu.servers.llmserver import LLMServer
+
+    server = LLMServer(model="transformer", model_kwargs=dict(TINY, qk_norm=True),
+                       init_random=True, quantize="int8", seed=5, len_buckets=(16,),
+                       batch_buckets=(1,), temperature=0.0, eos_id=-1, **kwargs)
+    server.load()
+    return server
+
+
+def test_checkpoint_and_streamed_init_hold_the_same_tree(monkeypatch, caplog):
+    """The two ways a served int8 tree is made, the streamed random init (the
+    7B cells) and quantize_params on a checkpoint's floats, give the same
+    tree for the same floats: same structure and orientation per leaf, same
+    int8 values, same scales. And the load log says how many leaves are held
+    output-major."""
+    import logging
+
+    import seldon_core_tpu.servers.llmserver as llmserver_mod
+    from seldon_core_tpu.parallel.sharding import head_split_outputs
+
+    monkeypatch.setattr(llmserver_mod, "STREAM_INIT_THRESHOLD_BYTES", 0)
+    with caplog.at_level(logging.INFO, logger="seldon_core_tpu.servers.llmserver"):
+        streamed = _int8_server()
+    assert "6 leaves held output-major" in caplog.text  # 3 a layer, 2 layers
+    is_q = lambda x: isinstance(x, QuantizedTensor)  # noqa: E731
+    floats = dequantize_params(streamed._params)  # the checkpoint these weights would be
+    from_checkpoint = quantize_params(
+        floats, out_major=head_split_outputs(floats, streamed._logical_axes()))
+    assert (jax.tree.structure(from_checkpoint) == jax.tree.structure(streamed._params))
+    for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(streamed._params, is_leaf=is_q)[0],
+            jax.tree.leaves(from_checkpoint, is_leaf=is_q)):
+        name = jax.tree_util.keystr(path)
+        if not is_q(a):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), name
+            continue
+        assert a.out_major == b.out_major == (path[-1].key in HELD_OUT_MAJOR), name
+        assert np.array_equal(np.asarray(a.q), np.asarray(b.q)), name
+        np.testing.assert_allclose(np.asarray(a.scale), np.asarray(b.scale), rtol=1e-6)
+
+
+def test_served_logits_equal_the_float_orientation_paths():
+    """Prefill then decode through the served programs, with the q/k/v leaves
+    held output-major (as load() makes them) and with the same leaves held
+    [in, out] as before: the same logits, the same greedy tokens."""
+    from seldon_core_tpu.ops.quantize import dequantize_array, quantize_array
+
+    server = _int8_server(max_new_tokens=6)
+    attention = server._params["params"]["layer_0"]["attention"]
+    assert all(attention[w].out_major for w in HELD_OUT_MAJOR)
+    assert not attention["wo"].out_major
+    is_q = lambda x: isinstance(x, QuantizedTensor)  # noqa: E731
+    as_before = jax.tree.map(
+        lambda t: quantize_array(dequantize_array(t)) if is_q(t) and t.out_major else t,
+        server._params, is_leaf=is_q)
+    assert not any(t.out_major for t in jax.tree.leaves(as_before, is_leaf=is_q) if is_q(t))
+
+    prompt = [5, 9, 17, 33, 2, 7]
+    tokens = jnp.asarray([prompt], jnp.int32)
+    positions = jnp.arange(len(prompt))[None, :]
+    prefill = server._get_prefill(1, len(prompt), 16)
+    got, got_cache = prefill(server._params, tokens, positions)
+    want, want_cache = prefill(as_before, tokens, positions)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                               rtol=0, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32),
+                                   rtol=0, atol=1e-5)
+    out = server.generate([prompt], max_new_tokens=6)["tokens"][0]
+    server._params = as_before
+    assert server.generate([prompt], max_new_tokens=6)["tokens"][0] == out
+
+
+def test_shard_params_follows_the_held_orientation(eight_devices):
+    """Under tensor parallelism a leaf held output-major is split along the
+    SAME logical axis as before: q [out, in] takes the float leaf's spec
+    reversed, the scale [out] the channel axis, and the sharded tree
+    dequantizes to the unsharded one's values."""
+    from seldon_core_tpu.parallel.mesh import make_mesh
+    from seldon_core_tpu.parallel.sharding import head_split_outputs, shard_params
+
+    mesh = make_mesh({"data": 2, "model": 4})
+    rng = np.random.default_rng(0)
+    params = {"params": {
+        "wq": rng.standard_normal((16, 32)).astype(np.float32),   # heads over 'model'
+        "wk": rng.standard_normal((16, 8)).astype(np.float32),
+        "wo": rng.standard_normal((32, 16)).astype(np.float32),
+    }}
+    logical = {"params": {"wq": ("embed", "heads"), "wk": ("embed", "kv_heads"),
+                          "wo": ("heads", "embed")}}
+    chosen = head_split_outputs(params, logical)
+    assert chosen == {"params": {"wq": True, "wk": True, "wo": False}}
+    qp = quantize_params(params, out_major=chosen)
+    sharded = shard_params(qp, mesh, logical)
+    wq, wk, wo = (sharded["params"][k] for k in ("wq", "wk", "wo"))
+    assert wq.out_major and wk.out_major and not wo.out_major
+    # wq held [32, 16]: the heads axis (now the first) over 'model' (4)
+    assert wq.q.sharding.shard_shape(wq.q.shape) == (8, 16)
+    assert wq.scale.sharding.shard_shape(wq.scale.shape) == (8,)
+    assert wk.q.sharding.shard_shape(wk.q.shape) == (2, 16)
+    assert wk.scale.sharding.shard_shape(wk.scale.shape) == (2,)
+    # wo as it was: rows (heads) over 'model', channel scale replicated
+    assert wo.q.sharding.shard_shape(wo.q.shape) == (8, 16)
+    assert wo.scale.sharding.shard_shape(wo.scale.shape) == (16,)
+    back, want = dequantize_params(sharded), dequantize_params(qp)
+    for k in ("wq", "wk", "wo"):
+        assert back["params"][k].shape == params["params"][k].shape
+        np.testing.assert_allclose(np.asarray(back["params"][k]),
+                                   np.asarray(want["params"][k]), rtol=0, atol=0)
+
+
+def test_int8_dense_takes_an_out_major_leaf():
+    """The explicit kernel's wrapper reads the container, not its bytes."""
+    from seldon_core_tpu.ops.pallas_int8 import int8_dense
+    from seldon_core_tpu.ops.quantize import quantize_array
+
+    rng = np.random.default_rng(4)
+    w = jnp.asarray(rng.normal(0, 0.3, size=(128, 256)), jnp.float32)
+    x = jnp.asarray(rng.normal(0, 1.0, size=(8, 128)), jnp.float32)
+    a = int8_dense(x, quantize_array(w))
+    b = int8_dense(x, quantize_array(w, out_major=True))
+    assert np.array_equal(np.asarray(a), np.asarray(b))

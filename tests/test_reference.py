@@ -284,3 +284,23 @@ def test_int8_expert_stacks_never_become_floats(served):
     kept = server._dequant(server._params)["params"]["layer_0"]
     assert kept["moe"]["w2"].q.dtype == jnp.int8
     assert kept["attention"]["wq"].dtype != jnp.int8      # 2-D leaves dequantize as before
+
+
+def test_reference_reads_the_served_tree_in_either_orientation(served):
+    """The served tree holds wq / wk / wv output-major ([out, in] int8 bytes,
+    ops/quantize.py); the reference reads the container, so it answers for the
+    SAME matrices: bit for bit what it gives for that tree's own dequantized
+    float32 leaves (the form test_reference_matches_hf_olmoe holds to
+    transformers' OLMoE)."""
+    from seldon_core_tpu.ops.quantize import dequantize_params
+
+    server, _, prompts = served[:3]
+    attention = server._params["params"]["layer_0"]["attention"]
+    assert attention["wq"].out_major and attention["wk"].out_major and attention["wv"].out_major
+    assert attention["wq"].q.shape == (64, 64) and not attention["wo"].out_major
+    floats = dequantize_params(server._params, jnp.float32)
+    assert floats["params"]["layer_0"]["attention"]["wk"].dtype == jnp.float32
+    tokens = prompts[0]
+    got, _ = reference.forward(server._params, server._cfg, tokens)
+    want, _ = reference.forward(floats, server._cfg, tokens)
+    assert np.array_equal(np.asarray(got), np.asarray(want))

@@ -1,0 +1,183 @@
+"""What the TPU compiler makes of the batcher's step programs, read without a
+chip: libtpu describes a v5e (``jax.experimental.topologies``), the step
+program is lowered for ShapeDtypeStructs placed on that device, and the
+compiled module's text carries the op names a device trace would show
+(PERF.md section 3, "Reading a program's ops without a chip").
+
+Held here: no program writes a TRANSPOSED floating copy of a weight. The
+q/k/v projections fuse with the head split and the rotary, and that fusion
+reads the weight output-major; held ``[in, out]`` the dequantized weight was
+copied into that order every layer of every step (``copy bf16[4096,4096]`` and
+two ``copy bf16[1024,4096]``: 1.74 ms of an 18.75 ms Mistral step, PERF.md
+section 6, PR 28). ops/quantize.py holds those leaves ``out_major``.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+FLOATS = ("bf16", "f16", "f32")
+# one layer at the published widths; the vocabulary is not what is looked at
+MISTRAL = dict(vocab_size=256, dim=4096, n_layers=1, n_heads=32, n_kv_heads=8,
+               ffn_dim=14336, max_seq_len=1024, dtype="bfloat16")
+# OLMoE's attention (16 = 16 heads, QK-norm between projection and split);
+# its expert FFN has no part in this
+OLMOE_ATTENTION = dict(vocab_size=256, dim=2048, n_layers=1, n_heads=16, n_kv_heads=16,
+                       ffn_dim=1024, max_seq_len=1024, dtype="bfloat16", qk_norm=True)
+PAGE, POOL_PAGES = 64, 514
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """A described v5e chip as a sharding: nothing runs on it, programs
+    compile for it."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1", chip_config_name="default",
+            chips_per_host_bounds=(1, 1, 1), num_slices=1)
+    except Exception as exc:  # no libtpu, or one that cannot describe a v5e
+        pytest.skip(f"libtpu cannot describe a v5e here: {type(exc).__name__}: {exc}")
+    return SingleDeviceSharding(topology.devices[0])
+
+
+def _served(model_kwargs):
+    """The int8 tree as the 7B cells hold it (the streamed init), one layer.
+    Only its shapes, dtypes and orientation are looked at, so the weights are
+    zeros: drawing 218 M normals would hold every core for seconds while the
+    other workers' timing-sensitive tests run."""
+    import seldon_core_tpu.servers.llmserver as llmserver_mod
+    from seldon_core_tpu.servers.llmserver import LLMServer
+
+    server = LLMServer(model="transformer", model_kwargs=model_kwargs, init_random=True,
+                       quantize="int8", kv_cache_dtype="bf16", len_buckets=(16,))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(llmserver_mod, "STREAM_INIT_THRESHOLD_BYTES", 0)
+        # (a function of the key, or XLA folds the constant through the quantizer)
+        patch.setattr(jax.random, "normal", lambda key, shape, dtype=jnp.float32:
+                      jnp.broadcast_to(jnp.ravel(key)[0].astype(dtype) * 0, shape))
+        server.load()
+    return server
+
+
+@pytest.fixture(scope="module")
+def servers():
+    made = {}
+
+    def get(name):
+        if name not in made:
+            made[name] = _served({"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION}[name])
+        return made[name]
+
+    return get
+
+
+def compiled_text(server, program: str, sharding) -> str:
+    from seldon_core_tpu.models.transformer import init_paged_kv_caches
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+    def abstract(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    params = abstract(server._params)
+    pools = abstract(jax.eval_shape(
+        lambda: init_paged_kv_caches(server._cfg, POOL_PAGES, PAGE, "bf16")))
+    if program == "decode_step":  # the chat cell's: 32 slots x 1024 tokens
+        slots, pages = 32, 1024 // PAGE
+        lowered = server._get_decode_step_paged(slots, pages, 1).lower(
+            params, pools, sds((slots,), "int32"), sds((slots,), "int32"),
+            sds((slots, 2), "uint32"), sds((), "float32"), sds((slots, pages), "int32"))
+    else:                         # the docs cell's: 256 tokens into a 4096-token slot
+        chunk, pages = 256, 4096 // PAGE
+        lowered = server._get_prefill_chunk(chunk, pages).lower(
+            params, pools, sds((1, pages), "int32"), sds((1, chunk), "int32"),
+            sds((1, chunk), "int32"))
+    return lowered.compile().as_text()
+
+
+_HEAD = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->\s+.*\{\s*$")
+_INSTR = re.compile(
+    r"^\s*(ROOT\s+)?%?([\w.\-]+)\s+=\s+(\w+)\[([\d,]*)\]\S*\s+([\w\-]+)\((.*)$")
+
+
+def weight_copies(hlo: str, weight_shapes) -> list:
+    """Instructions that are executed as ops of their own (those of the entry
+    computation and of loop bodies, not the insides of a fusion), are a copy or
+    a transpose, or a fusion whose root is one, and whose result is a floating
+    array of a weight's shape."""
+    computations, name = {}, None
+    for line in hlo.splitlines():
+        head = _HEAD.match(line)
+        if head:
+            name = head.group(1)
+            computations[name] = []
+        elif name is not None and (m := _INSTR.match(line)):
+            shape = tuple(int(n) for n in m.group(4).split(",") if n)
+            computations[name].append(
+                (bool(m.group(1)), m.group(2), m.group(3), shape, m.group(5), m.group(6)))
+    roots = {name: next((i[4] for i in instrs if i[0]), None)
+             for name, instrs in computations.items()}
+    fused = {m.group(1) for instrs in computations.values() for i in instrs
+             if i[4] == "fusion" and (m := re.search(r"calls=%?([\w.\-]+)", i[5]))}
+    found = []
+    for name, instrs in computations.items():
+        if name in fused:
+            continue
+        for _, instr, dtype, shape, opcode, rest in instrs:
+            if dtype not in FLOATS or shape not in weight_shapes:
+                continue
+            if opcode == "fusion":
+                called = re.search(r"calls=%?([\w.\-]+)", rest)
+                opcode = roots.get(called.group(1)) if called else None
+            if opcode in ("copy", "transpose"):
+                found.append(f"{instr} = {dtype}{list(shape)} in {name}")
+    return found
+
+
+def test_the_parser_sees_a_weight_copy():
+    """The check itself, on the parent's own lines (PR 27's program): the
+    standalone copy and a fusion whose root is one are both found; a dequant
+    fusion and a copy of another shape are not."""
+    hlo = """
+%fused_computation.24 (p0: s8[2048,2048], p1: f32[2048]) -> bf16[2048,2048] {
+  %mul = f32[2048,2048]{1,0:T(8,128)} multiply(%c, %b)
+  ROOT %convert.1 = bf16[2048,2048]{1,0:T(8,128)(2,1)} convert(%mul)
+}
+%fused_computation.9 (p0: bf16[2048,2048]) -> bf16[2048,2048] {
+  ROOT %copy.3 = bf16[2048,2048]{0,1:T(8,128)(2,1)} copy(%p0)
+}
+ENTRY %main.1 (a: s8[2048,2048], s: f32[2048]) -> bf16[2048,2048] {
+  %fusion.15 = bf16[2048,2048]{0,1:T(8,128)(2,1)S(1)} fusion(%a, %s), kind=kLoop, calls=%fused_computation.24
+  %copy.18 = bf16[2048,2048]{1,0:T(8,128)(2,1)S(1)} copy(%fusion.15), backend_config={}
+  %fusion.16 = bf16[2048,2048]{1,0:T(8,128)(2,1)} fusion(%copy.18), kind=kLoop, calls=%fused_computation.9
+  %copy.19 = bf16[32,2048]{1,0:T(8,128)(2,1)} copy(%x)
+  ROOT %copy.20 = s8[2048,2048]{1,0} copy(%a)
+}
+"""
+    found = weight_copies(hlo, {(2048, 2048)})
+    assert [f.split(" ")[0] for f in found] == ["copy.18", "fusion.16"]
+
+
+@pytest.mark.parametrize("config,program", [
+    ("mistral", "decode_step"), ("mistral", "prefill_chunk"), ("olmoe", "decode_step")])
+def test_no_transposed_copy_of_a_weight(v5e, servers, config, program):
+    from seldon_core_tpu.ops.quantize import QuantizedTensor
+
+    server = servers(config)
+    layer = server._params["params"]["layer_0"]
+    matrices = [leaf for leaf in jax.tree.leaves(
+        layer, is_leaf=lambda x: isinstance(x, QuantizedTensor))
+        if isinstance(leaf, QuantizedTensor)]
+    assert len(matrices) == 7  # wq wk wv wo w1 w2 w3
+    shapes = {m.q.shape for m in matrices} | {m.q.shape[::-1] for m in matrices}
+    hlo = compiled_text(server, program, v5e)
+    # the program is the one the chip runs: the q projection's dequant is there
+    held = layer["attention"]["wq"].q.shape
+    assert re.search(rf"= bf16\[{held[0]},{held[1]}\]\S* fusion\(", hlo), "no dequant of wq?"
+    assert weight_copies(hlo, shapes) == []
