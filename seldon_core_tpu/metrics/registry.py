@@ -382,6 +382,15 @@ class MetricsRegistry:
             base + ["ready"],
             registry=self.registry,
         )
+        self._attn_context = {
+            key: Counter(f"seldon_llm_attn_{key}_total", text,
+                         base + ["program"], registry=self.registry)
+            for key, text in (
+                ("calls", "Step-program calls whose attention read the cache"),
+                ("context_tokens",
+                 "Cached rows those calls' attention had to read: the live "
+                 "context of each row of the call, the row it wrote included "
+                 "(not the block-table view's length)"))}
         # An MoE model's routing (runtime/batcher.py MoECounters,
         # docs/observability.md "Expert routing"): counted on the loop from
         # arrays that leave the step programs beside their tokens, absent
@@ -973,6 +982,9 @@ class MetricsRegistry:
         self._counter_catch_up(self._loop_turns, stats.get("loop_turns", 0))
         for ready, n in stats.get("first_token_reads", {}).items():
             self._counter_catch_up(self._first_token_reads, n, ready=ready)
+        for key, counter in self._attn_context.items():
+            for program, n in stats.get(f"attn_{key}", {}).items():
+                self._counter_catch_up(counter, n, program=program)
         for program, tally in stats.get("moe_by_program", {}).items():
             for field, n in tally.items():
                 self._counter_catch_up(self._moe[field], n, program=program)

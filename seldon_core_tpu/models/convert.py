@@ -29,9 +29,17 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
     would convert cleanly and serve wrong logits."""
     scaling = getattr(hf_config, "rope_scaling", None)
     rope_scaling = None
+    deepseek = getattr(hf_config, "model_type", "") == "deepseek_v2"
     if scaling:
         rope_type = scaling.get("rope_type", scaling.get("type", "default"))
-        if rope_type == "llama3":
+        if rope_type == "yarn" and deepseek:
+            # YaRN is built for latent attention (models/transformer.py
+            # _yarn_scaled_freqs; the softmax scale takes mscale_all_dim^2 as
+            # the published model does, which transformers' port leaves out)
+            keys = ("factor", "original_max_position_embeddings", "beta_fast",
+                    "beta_slow", "mscale", "mscale_all_dim")
+            rope_scaling = {"type": "yarn", **{k: scaling[k] for k in keys if k in scaling}}
+        elif rope_type == "llama3":
             # supported natively (models/transformer._llama3_scaled_freqs,
             # parity-tested against transformers)
             required = ("factor", "low_freq_factor", "high_freq_factor",
@@ -48,7 +56,7 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
             )
     head_dim = getattr(hf_config, "head_dim", None)
     derived = hf_config.hidden_size // hf_config.num_attention_heads
-    if head_dim is not None and head_dim != derived:
+    if head_dim is not None and head_dim != derived and not deepseek:
         raise ValueError(
             f"explicit head_dim={head_dim} != hidden_size/num_heads={derived}; "
             "the native transformer derives head_dim from dim//n_heads"
@@ -70,6 +78,30 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
             "router_renormalize": bool(hf_config.norm_topk_prob),
             "qk_norm": True,
         }
+    ffn_dim = hf_config.intermediate_size
+    if deepseek:
+        for key, only in (("q_lora_rank", None), ("topk_method", "greedy"),
+                          ("moe_layer_freq", 1), ("scoring_func", "softmax")):
+            if getattr(hf_config, key, only) != only:
+                raise ValueError(
+                    f"deepseek_v2 with {key}={getattr(hf_config, key)!r} is not "
+                    f"supported by the native transformer (only {only!r})")
+        # latent attention; layer 0.. dense at intermediate_size, the others
+        # n_routed_experts of moe_intermediate_size + the shared experts
+        ffn_dim = hf_config.moe_intermediate_size
+        moe = {
+            "n_experts": hf_config.n_routed_experts,
+            "n_experts_per_token": hf_config.num_experts_per_tok,
+            "router_renormalize": bool(hf_config.norm_topk_prob),
+            "routed_scaling_factor": float(hf_config.routed_scaling_factor),
+            "n_shared_experts": int(hf_config.n_shared_experts or 0),
+            "first_dense_layers": hf_config.first_k_dense_replace,
+            "dense_ffn_dim": hf_config.intermediate_size,
+            "kv_lora_rank": hf_config.kv_lora_rank,
+            "qk_nope_head_dim": hf_config.qk_nope_head_dim,
+            "qk_rope_head_dim": hf_config.qk_rope_head_dim,
+            "v_head_dim": hf_config.v_head_dim,
+        }
     return {
         "vocab_size": hf_config.vocab_size,
         "dim": hf_config.hidden_size,
@@ -77,7 +109,7 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
         "n_heads": hf_config.num_attention_heads,
         "n_kv_heads": getattr(hf_config, "num_key_value_heads", None)
         or hf_config.num_attention_heads,
-        "ffn_dim": hf_config.intermediate_size,
+        "ffn_dim": ffn_dim,
         "max_seq_len": hf_config.max_position_embeddings,
         "rope_theta": getattr(hf_config, "rope_theta", 10000.0),
         "norm_eps": hf_config.rms_norm_eps,
@@ -98,6 +130,23 @@ def _np_dtype(name: str):
         return np.dtype(getattr(ml_dtypes, name))
 
 
+def _tensor_reader(state_dict: Dict[str, Any], dtype: str):
+    """(read, consumed): ``read(key)`` gives a state-dict entry as a numpy
+    array of ``dtype`` (torch tensors detached to the CPU) and records the
+    key, so that a converter can refuse what it did not map."""
+    np_dtype = _np_dtype(dtype)
+    consumed = set()
+
+    def read(key: str) -> np.ndarray:
+        consumed.add(key)
+        w = state_dict[key]
+        if hasattr(w, "detach"):  # torch tensor
+            w = w.detach().to("cpu").float().numpy()
+        return np.asarray(w).astype(np_dtype)
+
+    return read, consumed
+
+
 def convert_llama_state_dict(
     state_dict: Dict[str, Any],
     n_layers: int,
@@ -112,15 +161,7 @@ def convert_llama_state_dict(
     (breaking sharding-spec alignment for tensor parallelism). OLMoE's
     per-expert matrices stack into [e, d, f] leaves, and its q/k norms and
     router join the layer."""
-    np_dtype = _np_dtype(dtype)
-    consumed = set()
-
-    def t(key: str) -> np.ndarray:
-        consumed.add(key)
-        w = state_dict[key]
-        if hasattr(w, "detach"):  # torch tensor
-            w = w.detach().to("cpu").float().numpy()
-        return np.asarray(w).astype(np_dtype)
+    t, consumed = _tensor_reader(state_dict, dtype)
 
     params: Dict[str, Any] = {
         "tok_embeddings": t("model.embed_tokens.weight"),  # [vocab, dim]
@@ -171,17 +212,81 @@ def convert_llama_state_dict(
     return {"params": params}
 
 
+def _half_split(w: np.ndarray, rope: int) -> np.ndarray:
+    """The last ``rope`` columns of ``w`` from interleaved pairs (2i, 2i+1)
+    (DeepSeek's apply_rotary_emb multiplies them as complex numbers) to the
+    half-split order this repo's apply_rotary rotates (i, i + rope/2). q and
+    k are permuted alike, so every dot product is the one it was."""
+    order = np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
+    return np.concatenate([w[..., :-rope], w[..., -rope:][..., order]], axis=-1)
+
+
+def convert_deepseek_v2_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str, Any],
+                                   dtype: str = "float32") -> Dict[str, Any]:
+    """HF DeepseekV2ForCausalLM state dict -> our flax param tree. ``kwargs``
+    are ``config_kwargs_from_hf``'s. kv_b_proj [H * (nope + v), latent]
+    splits per head into W_UK [H, nope, latent] and W_UV [H, latent, v] (the
+    order the absorbed products read them); the rope columns of q_proj (per
+    head) and of kv_a_proj_with_mqa go from interleaved pairs to halves."""
+    t, consumed = _tensor_reader(state_dict, dtype)
+
+    def swiglu(prefix: str) -> Dict[str, Any]:
+        return {"w1": t(f"{prefix}.gate_proj.weight").T, "w2": t(f"{prefix}.down_proj.weight").T,
+                "w3": t(f"{prefix}.up_proj.weight").T}
+
+    H, dn, dr = kwargs["n_heads"], kwargs["qk_nope_head_dim"], kwargs["qk_rope_head_dim"]
+    dc, dv, dim = kwargs["kv_lora_rank"], kwargs["v_head_dim"], kwargs["dim"]
+    params: Dict[str, Any] = {
+        "tok_embeddings": t("model.embed_tokens.weight"),
+        "norm": {"weight": t("model.norm.weight")},
+        "lm_head": t("lm_head.weight").T,
+    }
+    for i in range(kwargs["n_layers"]):
+        hf = f"model.layers.{i}"
+        wq = _half_split(t(f"{hf}.self_attn.q_proj.weight").T.reshape(dim, H, dn + dr), dr)
+        kv_b = t(f"{hf}.self_attn.kv_b_proj.weight").reshape(H, dn + dv, dc)
+        layer = params[f"layer_{i}"] = {
+            "attention": {
+                "wq": wq.reshape(dim, H * (dn + dr)),
+                "wkv_a": _half_split(t(f"{hf}.self_attn.kv_a_proj_with_mqa.weight").T, dr),
+                "kv_norm": {"weight": t(f"{hf}.self_attn.kv_a_layernorm.weight")},
+                "w_uk": kv_b[:, :dn, :],
+                "w_uv": kv_b[:, dn:, :].transpose(0, 2, 1),
+                "wo": t(f"{hf}.self_attn.o_proj.weight").T,
+            },
+            "attention_norm": {"weight": t(f"{hf}.input_layernorm.weight")},
+            "ffn_norm": {"weight": t(f"{hf}.post_attention_layernorm.weight")},
+        }
+        if i < kwargs["first_dense_layers"]:
+            layer["ffn"] = swiglu(f"{hf}.mlp")
+            continue
+        layer["moe"] = {"router": t(f"{hf}.mlp.gate.weight").T}
+        experts = [swiglu(f"{hf}.mlp.experts.{e}") for e in range(kwargs["n_experts"])]
+        for name in ("w1", "w2", "w3"):
+            layer["moe"][name] = np.stack([e[name] for e in experts])
+        if kwargs["n_shared_experts"]:
+            layer["moe"]["shared"] = swiglu(f"{hf}.mlp.shared_experts")
+    leftover = [k for k in state_dict if k not in consumed and not k.endswith("inv_freq")]
+    if leftover:
+        raise ValueError(
+            f"unmapped weights in state dict (conversion would drop them): {leftover[:8]}")
+    return {"params": params}
+
+
 def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
-    """In-memory transformers LlamaForCausalLM or OlmoeForCausalLM ->
-    (our module, variables)."""
+    """In-memory transformers LlamaForCausalLM, OlmoeForCausalLM or
+    DeepseekV2ForCausalLM -> (our module, variables)."""
     from seldon_core_tpu.models import get_model
 
     kwargs = config_kwargs_from_hf(hf_model.config)
-    variables = convert_llama_state_dict(
-        hf_model.state_dict(), n_layers=kwargs["n_layers"],
-        tie_embeddings=kwargs["tie_embeddings"],
-        n_experts=kwargs.get("n_experts", 0),
-    )
+    if kwargs.get("kv_lora_rank"):
+        variables = convert_deepseek_v2_state_dict(hf_model.state_dict(), kwargs)
+    else:
+        variables = convert_llama_state_dict(
+            hf_model.state_dict(), n_layers=kwargs["n_layers"],
+            tie_embeddings=kwargs["tie_embeddings"],
+            n_experts=kwargs.get("n_experts", 0),
+        )
     module = get_model("transformer", dtype="float32", **kwargs)
     return module, variables
 
@@ -199,11 +304,14 @@ def convert_checkpoint(hf_path: str, out_dir: str, dtype: str = "bfloat16") -> s
     )
     kwargs = config_kwargs_from_hf(hf_config)
     # weights stored in the serving dtype (bf16 halves checkpoint size vs f32)
-    variables = convert_llama_state_dict(
-        model.state_dict(), n_layers=kwargs["n_layers"], dtype=dtype,
-        tie_embeddings=kwargs["tie_embeddings"],
-        n_experts=kwargs.get("n_experts", 0),
-    )
+    if kwargs.get("kv_lora_rank"):
+        variables = convert_deepseek_v2_state_dict(model.state_dict(), kwargs, dtype)
+    else:
+        variables = convert_llama_state_dict(
+            model.state_dict(), n_layers=kwargs["n_layers"], dtype=dtype,
+            tie_embeddings=kwargs["tie_embeddings"],
+            n_experts=kwargs.get("n_experts", 0),
+        )
 
     from seldon_core_tpu.servers.jaxserver import export_checkpoint
 

@@ -105,9 +105,11 @@ class TransformerConfig:
     ffn_dim: int = 11008
     max_seq_len: int = 4096
     rope_theta: float = 10000.0
-    # Llama-3.x frequency rescaling: tuple of (key, value) pairs (hashable
-    # frozen-dataclass field) with factor / low_freq_factor /
-    # high_freq_factor / original_max_position_embeddings; None = plain RoPE.
+    # RoPE frequency rescaling: tuple of (key, value) pairs (hashable
+    # frozen-dataclass field); None = plain RoPE. Llama-3.x: factor /
+    # low_freq_factor / high_freq_factor / original_max_position_embeddings.
+    # YaRN ("type": "yarn"): factor / original_max_position_embeddings /
+    # beta_fast / beta_slow / mscale / mscale_all_dim.
     rope_scaling: Any = None
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
@@ -124,6 +126,24 @@ class TransformerConfig:
     # RMSNorm on the q and k projections, over the WHOLE projection before
     # the split into heads (OLMoE), not per head.
     qk_norm: bool = False
+    # The first ``first_dense_layers`` layers of an MoE model keep a dense
+    # SwiGLU of width ``dense_ffn_dim`` (DeepSeek's first_k_dense_replace);
+    # ``n_shared_experts`` adds to every MoE layer one dense SwiGLU of width
+    # n_shared_experts * ffn_dim that every token takes, beside its routed
+    # experts; ``routed_scaling_factor`` multiplies the routed weights.
+    first_dense_layers: int = 0
+    dense_ffn_dim: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    # Multi-head latent attention (DeepSeek-V2; LatentAttention below):
+    # kv_lora_rank > 0 caches ONE row [c_t ; RoPE(k^R_t)] of kv_lora_rank +
+    # qk_rope_head_dim values a token a layer, shared by all heads, in place
+    # of per-head K and V. A query head is qk_nope_head_dim (no position) +
+    # qk_rope_head_dim (rotated) wide and a value head v_head_dim.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # "full" = dense attention (GSPMD gathers KV when seq-sharded);
     # "ring" = sequence-parallel ring attention over mesh axis 'seq'
     # (ops.ring_attention) for long-context cache-less forward/training.
@@ -144,6 +164,20 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.first_dense_layers if self.n_experts > 0 else 0
+
+    @property
+    def latent_row_dim(self) -> int:
+        """Width of a latent-attention cache row, 0 for per-head K/V:
+        kv_lora_rank + qk_rope_head_dim rounded up to whole 128-lane tiles
+        (576 -> 640, zeros behind). The chip tiles a 576-wide row to 640 lanes
+        in HBM either way, and with the odd width the compiler wants the pool
+        in another layout for the gather than for the scatter: two copies of
+        the whole pool a layer a call (PERF.md section 6, PR 29)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128 if self.kv_lora_rank else 0
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -171,14 +205,59 @@ def _llama3_scaled_freqs(freqs: jnp.ndarray, scaling: dict) -> jnp.ndarray:
     return jnp.where(is_medium, smoothed, scaled)
 
 
+def _is_yarn(scaling: dict) -> bool:
+    return scaling.get("rope_type", scaling.get("type")) == "yarn"
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_scaled_freqs(freqs: jnp.ndarray, head_dim: int, theta: float,
+                       scaling: dict) -> jnp.ndarray:
+    """YaRN (parity with transformers' _compute_yarn_parameters): the
+    dimensions that turn more than beta_fast times in the original context
+    keep their frequency, those that turn less than beta_slow times divide
+    it by ``factor``, and a linear ramp over the dimension index joins them."""
+    import math
+
+    factor = float(scaling["factor"])
+    old_len = float(scaling["original_max_position_embeddings"])
+
+    def dim_of(turns: float) -> float:
+        return head_dim * math.log(old_len / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(float(scaling.get("beta_fast") or 32))), 0)
+    high = min(math.ceil(dim_of(float(scaling.get("beta_slow") or 1))), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
 def rotary_embedding(
     positions: jnp.ndarray, head_dim: int, theta: float, rope_scaling=None
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """cos/sin tables for the given absolute positions: [..., seq, head_dim/2]."""
     freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    attention_factor = 1.0
     if rope_scaling:
-        freqs = _llama3_scaled_freqs(freqs, dict(rope_scaling))
+        scaling = dict(rope_scaling)
+        if _is_yarn(scaling):
+            freqs = _yarn_scaled_freqs(freqs, head_dim, theta, scaling)
+            if scaling.get("mscale") and scaling.get("mscale_all_dim"):
+                attention_factor = (yarn_mscale(scaling["factor"], scaling["mscale"])
+                                    / yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]))
+            else:
+                attention_factor = yarn_mscale(scaling["factor"])
+        else:
+            freqs = _llama3_scaled_freqs(freqs, scaling)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # [..., seq, hd/2]
+    if attention_factor != 1.0:
+        return jnp.cos(angles) * attention_factor, jnp.sin(angles) * attention_factor
     return jnp.cos(angles), jnp.sin(angles)
 
 
@@ -517,18 +596,179 @@ class Attention(nn.Module):
         return proj, new_cache
 
 
+def latent_attention_scale(cfg: TransformerConfig) -> float:
+    """Softmax scale of latent attention: (nope + rope)^-0.5, times YaRN's
+    mscale_all_dim temperature SQUARED when the config carries one (the
+    published DeepSeek-V2 modeling file: ``softmax_scale *= mscale * mscale``;
+    transformers' port of it leaves the factor out)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    scaling = dict(cfg.rope_scaling or ())
+    if _is_yarn(scaling) and scaling.get("mscale_all_dim"):
+        scale *= yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]) ** 2
+    return scale
+
+
+def absorbed_latent_attention(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
+                              w_uk: jnp.ndarray, w_uv: jnp.ndarray,
+                              rows: jnp.ndarray, mask: jnp.ndarray,
+                              scale: float) -> jnp.ndarray:
+    """Masked softmax attention of every head over cached LATENT rows, in
+    the absorbed form: the per-head key and value expansions W_UK / W_UV
+    move onto the query and the output, so no per-head K or V of the context
+    is ever made.
+
+    ``q_nope`` [b, s, H, dn] / ``q_rope`` [b, s, H, dr] (rotated);
+    ``w_uk`` [H, dn, dc], ``w_uv`` [H, dc, dv]; ``rows`` [b, L, >= dc + dr] =
+    [c_t ; RoPE(k^R_t) ; zeros to the cached width] as cached, one row a
+    token for all heads; ``mask`` [b, s, L] bool. Returns [b, s, H, dv].
+
+        q~_h  = W_UK,h^T q^N_h                      (dc wide)
+        score = ([q~_h ; q^R_h] . row_t) * scale    (= q_h . [W_UK,h c_t ; k^R_t])
+        o_h   = W_UV,h (sum_t p_t c_t)
+
+    One expression for any [b, s]: the decode step (s = 1), a prefill chunk
+    over the gathered view (b = 1), the speculative verify and the dense
+    cache of generate(). bf16 operands, float32 logits and softmax; masked
+    positions get ``finfo.min`` and contribute exact zeros."""
+    dt = q_nope.dtype
+    dc = w_uk.shape[-1]
+    rows = rows.astype(dt)
+    q_lat = jnp.einsum("bshn,hnc->bshc", q_nope, w_uk.astype(dt))
+    pad = rows.shape[-1] - dc - q_rope.shape[-1]
+    q_cat = jnp.concatenate(
+        [q_lat, q_rope] + ([jnp.zeros(q_rope.shape[:-1] + (pad,), dt)] if pad else []), axis=-1)
+    logits = jnp.einsum("bshc,blc->bhsl", q_cat, rows).astype(jnp.float32) * scale
+    logits = jnp.where(mask[:, None, :, :], logits, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(dt)
+    ctx = jnp.einsum("bhsl,blc->bshc", probs, rows[..., :dc])
+    return jnp.einsum("bshc,hcv->bshv", ctx, w_uv.astype(dt))
+
+
+def _dense_stack(w, dtype):
+    """A [H, ., .] stack a module multiplies densely (not a grouped matmul):
+    an int8 stack (ops/quantize.py keeps stacks quantized into the module)
+    is dequantized where it is used; it is a megabyte."""
+    from seldon_core_tpu.ops.quantize import QuantizedTensor, dequantize_array
+
+    if isinstance(w, QuantizedTensor):
+        return dequantize_array(w, dtype)
+    return w.astype(dtype)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv 2405.04434 sec. 2.1;
+    no query compression: q_lora_rank null).
+
+        q_h     = [q^N_h ; RoPE(q^R_h)]              from wq, H heads
+        c_t     = RMSNorm(W_DKV x_t)   k^R_t = RoPE(W_KR x_t)     (wkv_a = [W_DKV ; W_KR])
+        row_t   = [c_t ; k^R_t]        the ONE thing cached a token, no head axis
+        out     = wo concat_h absorbed_latent_attention(...)_h
+
+    The cache is a 2-tuple ``(rows, pos)``: [b, max_len, W] / [b, max_len]
+    dense, or a paged pool [pages, page_size, W] / [pages, page_size]
+    addressed through ``block_tables``, W = cfg.latent_row_dim (dc + dr in
+    whole 128-lane tiles, zeros behind) — the same (values...,
+    positions) layer shape the batcher's page operations are generic over,
+    written and read under the same rules as ``Attention``'s (PAD_POS marks
+    empty rows; unallocated pages redirect to TRASH_PAGE). Without a cache:
+    full causal attention, returns (out, (rows,))."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions, cache=None, cache_index=None,
+                 block_tables=None, adapters=None, adapter_ids=None):
+        cfg = self.cfg
+        if adapters is not None:
+            raise ValueError("LoRA adapters do not apply to latent attention")
+        b, s, _ = x.shape
+        H, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        dc, dv = cfg.kv_lora_rank, cfg.v_head_dim
+        dt = cfg.dtype
+        stack = dict(batch_axis=(0,))
+
+        wq = param_with_axes(
+            "wq", nn.initializers.lecun_normal(), (cfg.dim, H * (dn + dr)), jnp.float32,
+            axes=("embed", "heads"))
+        wkv_a = param_with_axes(
+            "wkv_a", nn.initializers.lecun_normal(), (cfg.dim, dc + dr), jnp.float32,
+            axes=("embed", "kv_latent"))
+        # held in the order the absorbed products read them: [H, K, N] with
+        # the contracted axis in the middle. W_UK's fan-in is the LATENT
+        # axis (k^N = W_UK c), its last
+        w_uk = param_with_axes(
+            "w_uk", nn.initializers.lecun_normal(in_axis=-1, out_axis=-2, **stack),
+            (H, dn, dc), jnp.float32, axes=("heads", "head_nope", "kv_latent"))
+        w_uv = param_with_axes(
+            "w_uv", nn.initializers.lecun_normal(**stack), (H, dc, dv), jnp.float32,
+            axes=("heads", "kv_latent", "head_v"))
+        wo = param_with_axes(
+            "wo", nn.initializers.lecun_normal(), (H * dv, cfg.dim), jnp.float32,
+            axes=("heads", "embed"))
+
+        cos, sin = rotary_embedding(positions, dr, cfg.rope_theta, cfg.rope_scaling)
+        q = (x @ wq.astype(dt)).reshape(b, s, H, dn + dr)
+        q_nope, q_rope = q[..., :dn], apply_rotary(q[..., dn:], cos, sin)
+        with jax.named_scope("attn.latent.write"):
+            kv_a = x @ wkv_a.astype(dt)
+            c = RMSNorm(dc, cfg.norm_eps, "kv_latent", name="kv_norm")(kv_a[..., :dc])
+            k_rope = apply_rotary(kv_a[..., None, dc:], cos, sin)[:, :, 0]
+            row = jnp.concatenate([c, k_rope], axis=-1)  # [b, s, dc + dr]
+            if cache is not None and cfg.latent_row_dim > dc + dr:
+                row = jnp.pad(row, ((0, 0), (0, 0), (0, cfg.latent_row_dim - dc - dr)))
+            if cache is None:
+                rows, pos_view, new_cache = row, positions, (row,)
+            else:
+                pool, pos_pool = cache
+                row = row.astype(pool.dtype)
+                wpos = positions.astype(pos_pool.dtype)
+                if block_tables is not None:
+                    bt = jnp.asarray(block_tables, jnp.int32)
+                    at = paged_write_targets(bt, positions, pool.shape[1])
+                    pool, pos_pool = pool.at[at].set(row), pos_pool.at[at].set(wpos)
+                else:
+                    idx = jnp.asarray(cache_index, dtype=jnp.int32)
+                    if idx.ndim == 0:   # one offset for the batch: prefill
+                        pool = jax.lax.dynamic_update_slice(pool, row, (0, idx, 0))
+                        pos_pool = jax.lax.dynamic_update_slice(pos_pool, wpos, (0, idx))
+                    else:
+                        # per-sequence offsets: one token at its slot's index,
+                        # or (speculative verify) each at its own position,
+                        # PAD_POS columns dropped
+                        at = ((jnp.arange(b), idx) if s == 1 else
+                              (jnp.arange(b)[:, None], positions.astype(jnp.int32)))
+                        new, newpos = (row[:, 0], wpos[:, 0]) if s == 1 else (row, wpos)
+                        pool = pool.at[at].set(new, mode="drop")
+                        pos_pool = pos_pool.at[at].set(newpos, mode="drop")
+                new_cache = (pool, pos_pool)
+        with jax.named_scope("attn.latent.read"):
+            if cache is not None and block_tables is not None:
+                L = bt.shape[1] * pool.shape[1]
+                rows, pos_view = pool[bt].reshape(b, L, -1), pos_pool[bt].reshape(b, L)
+            elif cache is not None:
+                rows, pos_view = pool, pos_pool
+            # one predicate for causality, empty rows (PAD_POS) and padding
+            mask = pos_view[:, None, :] <= positions[:, :, None]
+            out = absorbed_latent_attention(
+                q_nope, q_rope, _dense_stack(w_uk, dt), _dense_stack(w_uv, dt),
+                rows, mask, latent_attention_scale(cfg))
+        return out.reshape(b, s, H * dv) @ wo.astype(dt), new_cache
+
+
 class DenseFFN(nn.Module):
     cfg: TransformerConfig
+    width: int = 0   # 0 = cfg.ffn_dim
 
     @nn.compact
     def __call__(self, x, adapters: Optional[dict] = None,
                  adapter_ids: Optional[jnp.ndarray] = None):
         cfg = self.cfg
-        w1 = param_with_axes("w1", nn.initializers.lecun_normal(), (cfg.dim, cfg.ffn_dim), jnp.float32,
+        width = self.width or cfg.ffn_dim
+        w1 = param_with_axes("w1", nn.initializers.lecun_normal(), (cfg.dim, width), jnp.float32,
                              axes=("embed", "mlp"))
-        w2 = param_with_axes("w2", nn.initializers.lecun_normal(), (cfg.ffn_dim, cfg.dim), jnp.float32,
+        w2 = param_with_axes("w2", nn.initializers.lecun_normal(), (width, cfg.dim), jnp.float32,
                              axes=("mlp", "embed"))
-        w3 = param_with_axes("w3", nn.initializers.lecun_normal(), (cfg.dim, cfg.ffn_dim), jnp.float32,
+        w3 = param_with_axes("w3", nn.initializers.lecun_normal(), (cfg.dim, width), jnp.float32,
                              axes=("embed", "mlp"))
         dt = cfg.dtype
         up = x @ w1.astype(dt)
@@ -596,6 +836,8 @@ class MoEFFN(nn.Module):
             gates, chosen = jax.lax.top_k(probs, k)  # [t, k]
             if cfg.router_renormalize:
                 gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            if cfg.routed_scaling_factor != 1.0:
+                gates = gates * cfg.routed_scaling_factor
             if valid is not None:
                 # group e does not exist: its rows sort behind every expert's
                 chosen = jnp.where(valid.reshape(t, 1), chosen, e)
@@ -627,7 +869,13 @@ class MoEFFN(nn.Module):
             y = jnp.where((row_expert < e)[:, None], y, 0.0)
             y = y[jnp.argsort(order)].reshape(t, k, d)
             out = jnp.einsum("tkd,tk->td", y, gates)
-        return out.reshape(b, s, d).astype(x.dtype)
+        out = out.reshape(b, s, d).astype(x.dtype)
+        if cfg.n_shared_experts > 0:
+            # the shared experts are one dense SwiGLU every token takes
+            with jax.named_scope("moe.shared"):
+                out = out + DenseFFN(cfg, cfg.n_shared_experts * cfg.ffn_dim,
+                                     name="shared")(x)
+        return out
 
 
 def moe_routing_stats(sown: dict, cfg: TransformerConfig):
@@ -636,9 +884,9 @@ def moe_routing_stats(sown: dict, cfg: TransformerConfig):
     ``tokens`` [b, e] int32, tokens of each sequence routed to each expert,
     summed over layers; ``stats`` [4] int32 = live rows of the call, routed
     (token, expert) pairs, distinct experts touched and the largest expert
-    group, the last three summed over the ``n_layers`` layer-calls."""
+    group, the last three summed over the ``n_moe_layers`` layer-calls."""
     per_layer = jnp.stack([sown[f"layer_{i}"]["moe"]["tokens"][0]
-                           for i in range(cfg.n_layers)])  # [L, b, e]
+                           for i in range(cfg.first_dense_layers, cfg.n_layers)])  # [L, b, e]
     groups = jnp.sum(per_layer, axis=1)  # [L, e]
     k = min(cfg.n_experts_per_token, cfg.n_experts)
     stats = jnp.stack([
@@ -649,14 +897,16 @@ def moe_routing_stats(sown: dict, cfg: TransformerConfig):
 
 class TransformerBlock(nn.Module):
     cfg: TransformerConfig
+    layer: int = 0   # decides the FFN's kind (cfg.first_dense_layers)
 
     @nn.compact
     def __call__(self, x, positions, cache=None, cache_index=None,
                  block_tables=None, adapters=None, adapter_ids=None,
                  valid=None):
         cfg = self.cfg
+        attention = LatentAttention if cfg.kv_lora_rank else Attention
         with jax.named_scope("attn"):
-            h, new_cache = Attention(cfg, name="attention")(
+            h, new_cache = attention(cfg, name="attention")(
                 RMSNorm(cfg.dim, cfg.norm_eps, name="attention_norm")(x), positions, cache, cache_index,
                 block_tables, adapters, adapter_ids,
             )
@@ -672,10 +922,11 @@ class TransformerBlock(nn.Module):
         else:
             x = x + h
             ffn_in = ffn_norm(x)
-        if cfg.n_experts > 0:
+        if cfg.n_experts > 0 and self.layer >= cfg.first_dense_layers:
             f = MoEFFN(cfg, name="moe")(ffn_in, valid)
         else:
-            f = DenseFFN(cfg, name="ffn")(ffn_in, adapters, adapter_ids)
+            width = cfg.dense_ffn_dim if cfg.n_experts > 0 else 0
+            f = DenseFFN(cfg, width, name="ffn")(ffn_in, adapters, adapter_ids)
         return x + f, new_cache
 
 
@@ -727,7 +978,7 @@ class Transformer(nn.Module):
                     for proj, ab in adapters.items() if proj != "scale"
                 }
                 layer_adapters["scale"] = adapters["scale"]
-            x, nc = TransformerBlock(cfg, name=f"layer_{i}")(
+            x, nc = TransformerBlock(cfg, i, name=f"layer_{i}")(
                 x, positions, layer_cache, cache_index, block_tables,
                 layer_adapters, adapter_ids, valid)
             new_caches.append(nc)
@@ -743,6 +994,24 @@ class Transformer(nn.Module):
         return logits, new_caches
 
 
+LATENT_INT8_REFUSAL = (
+    "kv_cache_dtype='int8' is not built for latent attention (kv_lora_rank "
+    "> 0): a latent row has no head axis to scale by, and a per-row scale "
+    "over 512 + 64 mixed values is untested; serve it with the bf16 cache")
+
+
+def _init_latent_caches(cfg: TransformerConfig, lead: Tuple[int, int], kvd: str):
+    """(rows, pos) per layer with leading dims ``lead``: [b, max_len] dense
+    or [pages, page_size] paged."""
+    if kvd == "int8":
+        raise ValueError(LATENT_INT8_REFUSAL)
+    return [
+        (jnp.zeros(lead + (cfg.latent_row_dim,), dtype=cfg.dtype),
+         jnp.full(lead, PAD_POS, dtype=jnp.int32))
+        for _ in range(cfg.n_layers)
+    ]
+
+
 def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
                    kv_cache_dtype: Optional[str] = None):
     """Static-shape KV caches: one (k, v, pos) triple per layer —
@@ -750,8 +1019,11 @@ def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
     slots hold PAD_POS (never attended). With kv_cache_dtype="int8" each
     layer is a (k_q, k_scale, v_q, v_scale, pos) 5-tuple: int8 values plus
     f32 [b, max_len, kvh] per-head per-position scales (initialised to 1 so
-    empty slots dequantize to exact zeros)."""
+    empty slots dequantize to exact zeros). A latent-attention layer
+    (cfg.kv_lora_rank) is a (rows, pos) pair: [b, max_len, latent_row_dim]."""
     kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
+    if cfg.kv_lora_rank:
+        return _init_latent_caches(cfg, (batch, max_len), kvd)
     shape = (batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     if kvd == "int8":
         scale_shape = (batch, max_len, cfg.n_kv_heads)
@@ -784,12 +1056,16 @@ def init_paged_kv_caches(cfg: TransformerConfig, num_pages: int,
     ``num_pages`` serves ``num_pages - RESERVED_PAGES`` tokens' worth of
     allocatable KV. Position rows initialise to PAD_POS (never attended);
     int8 pools carry f32 [num_pages, page_size, kvh] scale planes
-    initialised to 1 (empty slots dequantize to exact zeros)."""
+    initialised to 1 (empty slots dequantize to exact zeros). A
+    latent-attention layer is a (rows, pos) pair: [num_pages, page_size,
+    latent_row_dim] with no head axis."""
     if num_pages <= RESERVED_PAGES:
         raise ValueError(
             f"paged KV pool needs > {RESERVED_PAGES} pages "
             f"(got {num_pages}; pages 0/1 are reserved)")
     kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
+    if cfg.kv_lora_rank:
+        return _init_latent_caches(cfg, (num_pages, page_size), kvd)
     shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     if kvd == "int8":
         scale_shape = (num_pages, page_size, cfg.n_kv_heads)
@@ -821,6 +1097,8 @@ def kv_cache_bytes_per_token(cfg: TransformerConfig,
     bytes/step ~= batch * cache_len * this. Reported by the LLM benches so
     BENCH rounds can attribute bandwidth regressions."""
     kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
+    if cfg.kv_lora_rank:   # one latent row for all heads, in the model dtype
+        return cfg.n_layers * (cfg.latent_row_dim * jnp.dtype(cfg.dtype).itemsize + 4)
     per_pos = cfg.n_kv_heads * cfg.head_dim
     if kvd == "int8":
         per_layer = 2 * (per_pos * 1 + cfg.n_kv_heads * 4)  # int8 + f32 scale

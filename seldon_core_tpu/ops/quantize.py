@@ -41,7 +41,12 @@ per matrix per channel ([e, f]): experts of different magnitude would
 otherwise share the largest one's step. A stack is also not dequantized by
 ``dequantize_params(keep_stacks=True)``: its consumer (a grouped matmul) takes
 the int8 array and applies the scale to the product, so no floating copy of
-the stack is ever made.
+the stack is ever made. A stack that its module multiplies DENSELY, batched
+over its first axis (latent attention's per-head ``W_UK`` [H, nope, latent] and
+``W_UV`` [H, latent, v], a megabyte each: models/transformer.py
+``_dense_stack``), arrives int8 by the same rule and is dequantized where it is
+used; it is held ``[H, K, N]``, the contracted axis in the middle, which is the
+order those batched products read.
 """
 
 from __future__ import annotations

@@ -523,6 +523,8 @@ class _Slot:
         return self.true_len + self.disp_new - 1
 
 
+MOE_PROGRAMS = ("decode", "chunk")   # the step programs the loop counts by
+
 LOOP_PHASES = ("admit", "handoff", "dispatch", "prefill", "first_token_wait",
                "first_token", "drain_wait", "emit", "idle", "hop")
 
@@ -574,6 +576,10 @@ class LoopPhases:
         # how each first_token_wait found its token: already computed
         # ("yes") or still behind queued device work, so the read waited
         self.first_token_reads = {"yes": 0, "no": 0}
+        # cached rows each program's attention had to read (the live context
+        # of its rows, written row included), from host integers at dispatch
+        self.attn_calls = dict.fromkeys(MOE_PROGRAMS, 0)
+        self.attn_context_tokens = dict.fromkeys(MOE_PROGRAMS, 0)
         self._open: List[_Phase] = []
         self._turn: Optional[Any] = None   # the open turn's annotation
         self._turn_t0 = 0.0
@@ -617,7 +623,13 @@ class LoopPhases:
                 "loop_phase_counts": dict(self.counts),
                 "loop_turns": self.turns,
                 "slot_seconds": self.slot_seconds,
-                "first_token_reads": dict(self.first_token_reads)}
+                "first_token_reads": dict(self.first_token_reads),
+                "attn_calls": dict(self.attn_calls),
+                "attn_context_tokens": dict(self.attn_context_tokens)}
+
+    def count_attention(self, program: str, context_tokens: int) -> None:
+        self.attn_calls[program] += 1
+        self.attn_context_tokens[program] += context_tokens
 
 
 def _in_phase(name: str):
@@ -631,15 +643,12 @@ def _in_phase(name: str):
     return wrap
 
 
-MOE_PROGRAMS = ("decode", "chunk")
-
-
 class MoECounters:
     """Routing tallies of an MoE model, single writer like LoopPhases. By
     program kind, what the DEVICE did (a slot whose budget is spent rides
     along in a step until the drain releases it: its row chose experts and
     their weights were read): calls, live rows, routed (token, expert) pairs,
-    and, summed over the ``n_layers`` layer-calls of each call, the distinct
+    and, summed over the ``n_layers`` MoE layer-calls of each call, the distinct
     experts touched and the largest expert group. ``expert_tokens`` [e] is
     what was DELIVERED: prompt tokens, and decode rows whose token was
     credited to a request, by expert, summed over layers."""
@@ -1099,7 +1108,7 @@ class ContinuousBatcher:
         # the loop's time budget (module docstring): always on
         self._phases = LoopPhases()
         cfg = server._cfg
-        self._moe = (MoECounters(cfg.n_experts, cfg.n_layers)
+        self._moe = (MoECounters(cfg.n_experts, cfg.n_moe_layers)
                      if cfg.n_experts > 0 else None)
         # Disaggregated prefill/decode (module docstring): remote-prefill
         # admission stages jobs on prefill-slice workers and consumes
@@ -1173,10 +1182,15 @@ class ContinuousBatcher:
         # the read is the XLA gather on every backend (models/
         # transformer.py; the Pallas page-streaming kernel does not
         # lower for a TPU) — said here so a server's log names it
+        from seldon_core_tpu.models.transformer import kv_cache_bytes_per_token
+
         logger.info(
-            "paged KV pool: %d pages x %d tokens, %s, %.2f GB; "
-            "decode read: gather", self.pool_pages, self.page_size,
-            server.kv_cache_dtype, self._cache_nbytes / 1e9)
+            "paged KV pool: %d pages x %d tokens, %s, %d B a token (%s), "
+            "%.2f GB; decode read: gather", self.pool_pages, self.page_size,
+            server.kv_cache_dtype,
+            kv_cache_bytes_per_token(cfg, server.kv_cache_dtype),
+            "latent rows" if cfg.kv_lora_rank else "per-head K/V",
+            self._cache_nbytes / 1e9)
 
         # No insert: chunked prefill writes straight into the pool through
         # the slot's block-table row. The device block table (one row per
@@ -2273,6 +2287,7 @@ class ContinuousBatcher:
                 self.server._params, self._caches, job.bt_row,
                 jnp.asarray(toks), jnp.asarray(pos))
         job.next = start + n
+        self._phases.count_attention("chunk", start + n)
         event = None
         if self._flight is not None:
             # dispatch wall (enqueue-only)
@@ -2766,8 +2781,12 @@ class ContinuousBatcher:
             self._next_pos, self._keys, self._temp,
             self._block_tables, *extra)
         snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
+        context = 0
         for i, _ in snapshot:
+            # micro-step j writes row pos + j and reads rows 0 .. pos + j
+            context += k * (self._slots[i].dispatched_pos() + 1) + k * (k - 1) // 2
             self._slots[i].disp_new += k
+        self._phases.count_attention("decode", context)
         self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside))
         self._count_steps()
         return True

@@ -650,6 +650,25 @@ class LLMServer(SeldonComponent):
                 raise ValueError(
                     "lora_rank > 0 does not support MoE FFNs: adapters "
                     "target the dense q/o/FFN projections")
+        if int(self.model_kwargs.get("kv_lora_rank", 0) or 0) > 0:
+            # latent attention (models/transformer.py LatentAttention): what
+            # is not built is refused here, by name (ROADMAP C1)
+            from seldon_core_tpu.models.transformer import LATENT_INT8_REFUSAL
+
+            if self.kv_cache_dtype == "int8":
+                raise ValueError(LATENT_INT8_REFUSAL)
+            if self.tensor_parallel > 1 or self.sequence_parallel > 1 \
+                    or self.mesh is not None:
+                raise ValueError(
+                    "latent attention (kv_lora_rank > 0) does not compose "
+                    "with tensor/sequence parallelism or a mesh: its cache "
+                    "row has no head axis to shard, and a replicated latent "
+                    "pool under sharded heads is not built")
+            if self.lora_rank > 0:
+                raise ValueError(
+                    "lora_rank > 0 does not support latent attention: "
+                    "adapters target the per-head q/o projections of "
+                    "Attention")
         from seldon_core_tpu.runtime.scheduler import normalize_slo_class
 
         for cls in self.slo_class_weights:
@@ -877,7 +896,8 @@ class LLMServer(SeldonComponent):
         leaves, ones for 1-D scale/weight (norm) leaves, zeros otherwise. A
         3-D leaf is a stack of matrices [e, d, f] and its fan_in is one
         matrix's d: counted over the stack, every expert's output would be
-        sqrt(e) too small, and a wrong expert layer too faint to notice.
+        sqrt(e) too small, and a wrong expert layer too faint to notice
+        (latent attention's W_UK is held output-first: its own line below).
         jit caches by (shape, std), so the 32 identical layers of a 7B
         config cost ~a dozen compiles, not ~200."""
         import zlib
@@ -910,6 +930,11 @@ class LLMServer(SeldonComponent):
             if jnp.issubdtype(spec.dtype, jnp.floating) and spec.ndim >= 2:
                 key = jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
                 fan_in = int(np.prod(spec.shape[1 if spec.ndim == 3 else 0:-1]))
+                if name.endswith("['w_uk']"):
+                    # latent attention's key expansion is held [H, nope,
+                    # latent], the order q~ = W_UK^T q reads it; as a map it
+                    # is k = W_UK c, so its fan-in is the latent axis
+                    fan_in = spec.shape[-1]
                 leaves.append(make_quantized(
                     key, spec.shape, 1.0 / float(fan_in) ** 0.5, out_major))
             elif jnp.issubdtype(spec.dtype, jnp.floating):

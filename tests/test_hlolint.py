@@ -315,6 +315,49 @@ def test_moe_step_programs_hold_no_dense_form_and_no_float_stack(name):
     assert reported == []
 
 
+@pytest.mark.parametrize("name", ["llm.mla_paged_decode_step_s4",
+                                  "llm.mla_prefill_chunk_c8"])
+def test_latent_step_programs_never_expand_the_cached_view(name):
+    """DeepSeek-V2's block at test dims (ISSUE 29): the absorbed read leaves
+    no floating [view rows, heads, head width] array, the MoE promises hold
+    for its routed experts beside the shared ones, the latent pool is
+    donated, no transfer."""
+    from tools.hlolint.contracts import all_contracts
+
+    (contract,) = [c for c in all_contracts() if c.name == name]
+    reported, *_ = run_one(contract, checks=("alias", "transfer", "dtype",
+                                             "collective"))
+    assert reported == []
+
+
+def test_latent_scan_sees_expanded_keys_when_they_are_there():
+    """The expanded formulation at the contract's dims: per-head keys made
+    from the gathered view are named by the scan."""
+    from tools.hlolint.contracts import (
+        LATENT_ROW, MLA_EXPANDED_KV, MLA_HEADS, MLA_LATENT, MLA_NOPE,
+        PAGE_SIZE, PAGES_PER_SLOT, SLOTS)
+
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        def expanded(q_nope, rows, w_uk):
+            k_nope = jnp.einsum("blc,hnc->blhn", rows[..., :MLA_LATENT], w_uk)
+            return jnp.einsum("bshn,blhn->bhsl", q_nope, k_nope)
+
+        view = PAGES_PER_SLOT * PAGE_SIZE
+        return jax.jit(expanded), (
+            _sds((SLOTS, 1, MLA_HEADS, MLA_NOPE), "bfloat16"),
+            _sds((SLOTS, view, LATENT_ROW), "bfloat16"),
+            _sds((MLA_HEADS, MLA_NOPE, MLA_LATENT), "bfloat16"))
+
+    contract = Contract(name="test.mla_expanded", description="expanded keys",
+                        build=build, forbid_dtypes=(MLA_EXPANDED_KV,),
+                        lowering_platform="tpu")
+    reported, *_ = run_one(contract, checks=("dtype",))
+    assert len(reported) == 1 and "expanded into per-head keys" in reported[0].message
+
+
 def _build_dense_moe(dequantized_stack: bool):
     """The formulation the sparse FFN replaced, at the MoE contract's dims."""
     def build():

@@ -26,6 +26,16 @@ MISTRAL = dict(vocab_size=256, dim=4096, n_layers=1, n_heads=32, n_kv_heads=8,
 # its expert FFN has no part in this
 OLMOE_ATTENTION = dict(vocab_size=256, dim=2048, n_layers=1, n_heads=16, n_kv_heads=16,
                        ffn_dim=1024, max_seq_len=1024, dtype="bfloat16", qk_norm=True)
+# DeepSeek-V2-Lite's two kinds of layer (the dense leading one, one MoE layer
+# with its shared experts) around latent attention, published widths
+DEEPSEEK = dict(vocab_size=256, dim=2048, n_layers=2, n_heads=16, n_kv_heads=16, ffn_dim=1408,
+                max_seq_len=1024, dtype="bfloat16", n_experts=64, n_experts_per_token=6,
+                router_renormalize=False, first_dense_layers=1, dense_ffn_dim=10944,
+                n_shared_experts=2, kv_lora_rank=512, qk_nope_head_dim=128,
+                qk_rope_head_dim=64, v_head_dim=128, norm_eps=1e-6,
+                rope_scaling={"type": "yarn", "factor": 40, "original_max_position_embeddings": 4096,
+                              "beta_fast": 32, "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707})
+CONFIGS = {"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "deepseek": DEEPSEEK}
 PAGE, POOL_PAGES = 64, 514
 
 
@@ -70,7 +80,7 @@ def servers():
 
     def get(name):
         if name not in made:
-            made[name] = _served({"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION}[name])
+            made[name] = _served(CONFIGS[name])
         return made[name]
 
     return get
@@ -165,19 +175,33 @@ ENTRY %main.1 (a: s8[2048,2048], s: f32[2048]) -> bf16[2048,2048] {
 
 
 @pytest.mark.parametrize("config,program", [
-    ("mistral", "decode_step"), ("mistral", "prefill_chunk"), ("olmoe", "decode_step")])
+    ("mistral", "decode_step"), ("mistral", "prefill_chunk"), ("olmoe", "decode_step"),
+    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk")])
 def test_no_transposed_copy_of_a_weight(v5e, servers, config, program):
+    """(DeepSeek: wq is head-split and held output-major like the others'; the
+    latent projection, W_UK / W_UV in the order the absorbed products read them,
+    wo, the dense layer, the shared experts and the expert stacks leave no
+    floating copy of themselves either, in either order.)"""
     from seldon_core_tpu.ops.quantize import QuantizedTensor
 
     server = servers(config)
     layer = server._params["params"]["layer_0"]
     matrices = [leaf for leaf in jax.tree.leaves(
-        layer, is_leaf=lambda x: isinstance(x, QuantizedTensor))
-        if isinstance(leaf, QuantizedTensor)]
-    assert len(matrices) == 7  # wq wk wv wo w1 w2 w3
-    shapes = {m.q.shape for m in matrices} | {m.q.shape[::-1] for m in matrices}
+        server._params["params"] if config == "deepseek" else layer,
+        is_leaf=lambda x: isinstance(x, QuantizedTensor))
+        if isinstance(leaf, QuantizedTensor) and 256 not in leaf.q.shape]   # not the toy vocabulary's
+    # wq wk wv wo w1 w2 w3; DeepSeek: 5 attention + 3 dense, 5 + router + 3 stacks + 3 shared
+    assert len(matrices) == (20 if config == "deepseek" else 7)
+    shapes = {m.q.shape for m in matrices} | {m.q.shape[:-2] + m.q.shape[:-3:-1] for m in matrices}
     hlo = compiled_text(server, program, v5e)
     # the program is the one the chip runs: the q projection's dequant is there
     held = layer["attention"]["wq"].q.shape
     assert re.search(rf"= bf16\[{held[0]},{held[1]}\]\S* fusion\(", hlo), "no dequant of wq?"
     assert weight_copies(hlo, shapes) == []
+    if config == "deepseek":
+        # nor a copy of the latent pool: with the row at its bare 576 values the
+        # gather wanted the pool in another layout than the scatter, two whole-
+        # pool copies a layer a call (17.7 ms of a 118 ms chunk, PERF.md section
+        # 6, PR 29); the row is held in whole 128-lane tiles (640)
+        assert server._cfg.latent_row_dim == 640
+        assert weight_copies(hlo, {(POOL_PAGES, PAGE, 640), (POOL_PAGES, PAGE, 576)}) == []

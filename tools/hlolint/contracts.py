@@ -196,6 +196,44 @@ def _moe_server():
         return _STATE["moe_server"]
 
 
+# latent attention (ISSUE 29): DeepSeek-V2's block at test dims (a leading
+# dense layer, then routed + shared experts; one LATENT_ROW-wide cached row
+# a token for all heads)
+MLA_HEADS = 4
+MLA_NOPE = 24
+MLA_LATENT = 32
+LATENT_ROW = MLA_LATENT + 8
+
+
+def _mla_server():
+    """int8 weights, bf16 compute DeepSeek-V2-shaped LLMServer at test dims."""
+    with _STATE_LOCK:
+        if "mla_server" not in _STATE:
+            ensure_platform()
+            from seldon_core_tpu.servers.llmserver import LLMServer
+
+            s = LLMServer(
+                model="transformer",
+                model_kwargs=dict(
+                    vocab_size=96, dim=MOE_DIM, n_layers=2, n_heads=MLA_HEADS,
+                    n_kv_heads=MLA_HEADS, ffn_dim=MOE_WIDTH,
+                    max_seq_len=PAGES_PER_SLOT * PAGE_SIZE,
+                    n_experts=MOE_EXPERTS, n_experts_per_token=MOE_TOP_K,
+                    router_renormalize=False, first_dense_layers=1,
+                    dense_ffn_dim=96, n_shared_experts=2,
+                    kv_lora_rank=MLA_LATENT, qk_nope_head_dim=MLA_NOPE,
+                    qk_rope_head_dim=LATENT_ROW - MLA_LATENT, v_head_dim=MLA_NOPE,
+                    rope_scaling={"type": "yarn", "factor": 40,
+                                  "original_max_position_embeddings": 16,
+                                  "mscale": 0.707, "mscale_all_dim": 0.707},
+                    dtype="bfloat16"),
+                quantize="int8", init_random=True, len_buckets=(PLEN,),
+                seed=7)
+            s.load()
+            _STATE["mla_server"] = s
+        return _STATE["mla_server"]
+
+
 def _paged_batcher():
     with _STATE_LOCK:  # nests into _base_server's hold: RLock
         if "paged_batcher" not in _STATE:
@@ -279,14 +317,29 @@ MOE_FLOAT_STACK = (
     "re-reads 2-4x its bytes every layer of every step")
 
 
-def _moe_pool_specs():
+# latent attention's promise: the cached view is never expanded into
+# per-head keys or values. An expanded K or V of the gathered view is a
+# floating [.., view rows, heads, nope (+ rope)] array
+MLA_EXPANDED_KV = (
+    rf"tensor<(\d+x)*{PAGES_PER_SLOT * PAGE_SIZE}x{MLA_HEADS}x"
+    rf"({MLA_NOPE}|{MLA_NOPE + LATENT_ROW - MLA_LATENT})x(bf16|f16|f32)>",
+    "a floating [view rows, heads, head width] array: the cached latents are "
+    "being expanded into per-head keys or values, heads x (nope + v) / "
+    "latent_row times the bytes of the view, every layer of every call; the "
+    "absorbed read multiplies the latent rows as they are cached")
+
+
+def _pool_specs_of(server):
     import jax
 
     from seldon_core_tpu.models.transformer import init_paged_kv_caches
 
-    s = _moe_server()
     return jax.eval_shape(
-        lambda: init_paged_kv_caches(s._cfg, POOL_PAGES, PAGE_SIZE, "bf16"))
+        lambda: init_paged_kv_caches(server._cfg, POOL_PAGES, PAGE_SIZE, "bf16"))
+
+
+def _moe_pool_specs():
+    return _pool_specs_of(_moe_server())
 
 
 # ----------------------------------------------------------------------
@@ -306,6 +359,23 @@ def _build_moe_prefill_chunk():
     s = _moe_server()
     fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
     return fn, (s._params, _moe_pool_specs(),
+                _sds((1, PAGES_PER_SLOT), "int32"),
+                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"))
+
+
+def _build_mla_paged_decode_step():
+    s = _mla_server()
+    fn = s._get_decode_step_paged(SLOTS, PAGES_PER_SLOT, 1)
+    return fn, (s._params, _pool_specs_of(s), _sds((SLOTS,), "int32"),
+                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
+                _sds((), "float32"),
+                _sds((SLOTS, PAGES_PER_SLOT), "int32"))
+
+
+def _build_mla_prefill_chunk():
+    s = _mla_server()
+    fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
+    return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
                 _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"))
 
@@ -658,6 +728,31 @@ def all_contracts() -> List[Contract]:
             build=_build_moe_prefill_chunk,
             donated=(1,),
             forbid_dtypes=(MOE_DENSE_FORM, MOE_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.mla_paged_decode_step_s4",
+            description="PAGED decode step of a latent-attention MoE "
+                        "(DeepSeek-V2's block: one cached row a token for "
+                        "all heads, read absorbed; routed + shared experts "
+                        "behind a dense first layer)",
+            build=_build_mla_paged_decode_step,
+            donated=(1, 3, 4),
+            forbid_dtypes=(MLA_EXPANDED_KV, MOE_DENSE_FORM, MOE_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.mla_prefill_chunk_c8",
+            description="chunked admission prefill of the same model: the "
+                        "chunk's rows read the latent view absorbed, and "
+                        "the scatter updates the latent pool in place",
+            build=_build_mla_prefill_chunk,
+            donated=(1,),
+            forbid_dtypes=(MLA_EXPANDED_KV, MOE_DENSE_FORM, MOE_FLOAT_STACK),
             lowering_platform="tpu",
             collectives={},
             cost=True,
