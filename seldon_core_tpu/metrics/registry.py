@@ -405,6 +405,9 @@ class MetricsRegistry:
                                "summed over layer-calls",
             "max_group": "Rows of the largest expert group, summed over "
                          "layer-calls",
+            "tile_rows": "Rows the grouped-matmul kernel multiplied (visits x "
+                         "row tile), over all layers: routed_pairs over this "
+                         "is the tile's fill; 0 where ragged_dot serves",
             "layer_calls": "MoE layer executions (calls x layers)",
         }
         self._moe = {

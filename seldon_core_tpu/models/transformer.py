@@ -789,23 +789,38 @@ class DenseFFN(nn.Module):
 class MoEFFN(nn.Module):
     """Top-k token-choice MoE, sparse: each token computes its k experts and
     no other. The (token, expert) pairs are sorted by expert and the three
-    projections are grouped matmuls over the sorted rows
-    (``jax.lax.ragged_dot``), so an expert's weights are read once per call,
-    and only if a row chose it.
+    projections are grouped matmuls over the sorted rows, so an expert's
+    weights are read once per call, and only if a row chose it.
+
+    The grouped matmul is chosen by the platform the program is LOWERED for
+    (``jax.lax.platform_dependent``). For a TPU it is the repo's own Pallas
+    kernel (ops/grouped_matmul.py): an expert's whole matrix is one block,
+    and the sorted rows are walked in a row tile that follows the call's
+    static shapes (rows / experts = the mean group; ``row_tile``), one visit
+    per (expert, row tile) pair, all three projections over one visit list.
+    Everywhere else (tier-1 on the CPU, the float32 checks), and where the
+    stacks are sharded over a mesh (``cfg.mesh``, which LLMServer sets when
+    it shards: the kernel is one device's program), it is
+    ``jax.lax.ragged_dot``, which on a TPU is XLA's own kernel with a 256-row
+    tile (two thirds of a DeepSeek chunk before PR 30, PERF.md section 6).
 
     ``valid`` ([b, s] bool) marks the rows that are live tokens. The step
     programs have static shapes: a decode step computes every slot and a
     chunk is padded to its program's length, and a row that is no token must
     not choose experts (it would make their weights be read, and count as
-    load). Such rows join no group and come out as zeros.
+    load). Such rows join no group and come out as zeros: the kernel writes
+    them; behind ``ragged_dot`` they are masked.
 
     An int8 stack (ops/quantize.py, per-expert scales [e, f]) goes into the
     grouped matmul as int8, and its scale multiplies the product, where it
-    commutes: no floating copy of a stack exists.
+    commutes: no floating copy of a stack exists in HBM (the kernel converts
+    an expert's block in VMEM).
 
     When the "moe" collection is mutable the layer sows ``tokens`` [b, e]
-    int32: how many tokens of each sequence went to each expert
-    (``moe_routing_stats`` reduces them over the layers)."""
+    int32, how many tokens of each sequence went to each expert, and
+    ``tile_rows``, the rows the kernel multiplied (its visits x the row tile;
+    0 behind ``ragged_dot``): ``moe_routing_stats`` reduces them over the
+    layers."""
 
     cfg: TransformerConfig
 
@@ -848,25 +863,54 @@ class MoEFFN(nn.Module):
                 chosen[:, :, None] == jnp.arange(e, dtype=chosen.dtype), axis=1,
                 dtype=jnp.int32)  # [t, e]
             group_sizes = jnp.sum(by_token, axis=0)
-            if self.is_mutable_collection("moe") and not self.is_initializing():
+            sowing = self.is_mutable_collection("moe") and not self.is_initializing()
+            if sowing:
                 self.sow("moe", "tokens", jnp.sum(by_token.reshape(b, s, e), axis=1))
 
         with jax.named_scope("moe.experts"):
-            row_scale = jnp.minimum(row_expert, e - 1)
-
-            def grouped(lhs, w):
-                if isinstance(w, QuantizedTensor):
-                    out = jax.lax.ragged_dot(lhs, w.q, group_sizes,
-                                             preferred_element_type=jnp.float32)
-                    return out * w.scale[row_scale]
-                return jax.lax.ragged_dot(lhs, w.astype(lhs.dtype), group_sizes,
-                                          preferred_element_type=jnp.float32)
-
             rows = xf[order // k].astype(dt)  # [t*k, d], sorted by expert
-            h = jax.nn.silu(grouped(rows, w1)) * grouped(rows, w3)
-            y = grouped(h.astype(dt), w2)
-            # what lies behind the last group is not the matmul's to define
-            y = jnp.where((row_expert < e)[:, None], y, 0.0)
+
+            def split(w):  # an int8 stack goes in as int8, with its scales
+                return (w.q, w.scale) if isinstance(w, QuantizedTensor) else (w, None)
+
+            def swiglu(grouped):
+                h = jax.nn.silu(grouped(rows, *split(w1))) * grouped(rows, *split(w3))
+                return grouped(h.astype(dt), *split(w2))
+
+            def experts_kernel():
+                from seldon_core_tpu.ops.grouped_matmul import (
+                    grouped_matmul, make_visits, row_tile)
+
+                visits = make_visits(group_sizes, t * k, row_tile(t * k, e))
+                y = swiglu(lambda lhs, w, scale: grouped_matmul(
+                    lhs, w, visits, scale, interpret=False))
+                return y, visits.count * visits.rows
+
+            def experts_ragged_dot():
+                row_scale = jnp.minimum(row_expert, e - 1)
+
+                def grouped(lhs, w, scale):
+                    if scale is None:   # a floating stack, in the rows' dtype
+                        return jax.lax.ragged_dot(lhs, w.astype(lhs.dtype), group_sizes,
+                                                  preferred_element_type=jnp.float32)
+                    return jax.lax.ragged_dot(
+                        lhs, w, group_sizes,
+                        preferred_element_type=jnp.float32) * scale[row_scale]
+
+                # what lies behind the last group is not ragged_dot's to define
+                y = jnp.where((row_expert < e)[:, None], swiglu(grouped), 0.0)
+                return y, jnp.zeros((), jnp.int32)
+
+            # the kernel is one device's program, compiled by Mosaic: stacks
+            # sharded over a mesh, and every lowering that is not for a TPU
+            # (tier-1, the float32 checks), keep ragged_dot
+            if cfg.mesh is None:
+                y, tile_rows = jax.lax.platform_dependent(
+                    tpu=experts_kernel, default=experts_ragged_dot)
+            else:
+                y, tile_rows = experts_ragged_dot()
+            if sowing:
+                self.sow("moe", "tile_rows", tile_rows)
             y = y[jnp.argsort(order)].reshape(t, k, d)
             out = jnp.einsum("tkd,tk->td", y, gates)
         out = out.reshape(b, s, d).astype(x.dtype)
@@ -882,16 +926,19 @@ def moe_routing_stats(sown: dict, cfg: TransformerConfig):
     """Reduce what the MoE layers of one forward sowed (the "moe" collection
     of ``Transformer.apply(..., mutable=["moe"])``) to ``(tokens, stats)``:
     ``tokens`` [b, e] int32, tokens of each sequence routed to each expert,
-    summed over layers; ``stats`` [4] int32 = live rows of the call, routed
-    (token, expert) pairs, distinct experts touched and the largest expert
-    group, the last three summed over the ``n_moe_layers`` layer-calls."""
-    per_layer = jnp.stack([sown[f"layer_{i}"]["moe"]["tokens"][0]
-                           for i in range(cfg.first_dense_layers, cfg.n_layers)])  # [L, b, e]
+    summed over layers; ``stats`` [5] int32 = live rows of the call, routed
+    (token, expert) pairs, distinct experts touched, the largest expert
+    group, and the rows the grouped-matmul kernel multiplied (visits x row
+    tile; 0 where ``ragged_dot`` served), the last four summed over the
+    ``n_moe_layers`` layer-calls."""
+    layers = [sown[f"layer_{i}"]["moe"] for i in range(cfg.first_dense_layers, cfg.n_layers)]
+    per_layer = jnp.stack([layer["tokens"][0] for layer in layers])  # [L, b, e]
     groups = jnp.sum(per_layer, axis=1)  # [L, e]
     k = min(cfg.n_experts_per_token, cfg.n_experts)
     stats = jnp.stack([
         jnp.sum(per_layer[0]) // k, jnp.sum(groups),
-        jnp.sum(groups > 0, dtype=jnp.int32), jnp.sum(jnp.max(groups, axis=1))])
+        jnp.sum(groups > 0, dtype=jnp.int32), jnp.sum(jnp.max(groups, axis=1)),
+        sum(layer["tile_rows"][0] for layer in layers)])
     return jnp.sum(per_layer, axis=0), stats.astype(jnp.int32)
 
 

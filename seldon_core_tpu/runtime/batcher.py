@@ -649,12 +649,15 @@ class MoECounters:
     along in a step until the drain releases it: its row chose experts and
     their weights were read): calls, live rows, routed (token, expert) pairs,
     and, summed over the ``n_layers`` MoE layer-calls of each call, the distinct
-    experts touched and the largest expert group. ``expert_tokens`` [e] is
+    experts touched, the largest expert group and the rows the grouped-matmul
+    kernel multiplied (``tile_rows``: visits x row tile, so ``routed_pairs`` /
+    ``tile_rows`` is the tile's fill; 0 where ``ragged_dot`` serves).
+    ``expert_tokens`` [e] is
     what was DELIVERED: prompt tokens, and decode rows whose token was
     credited to a request, by expert, summed over layers."""
 
     FIELDS = ("calls", "live_rows", "routed_pairs", "experts_touched",
-              "max_group")
+              "max_group", "tile_rows")
 
     def __init__(self, n_experts: int, n_layers: int):
         self.n_layers = n_layers
@@ -663,14 +666,14 @@ class MoECounters:
         self.expert_tokens = np.zeros((n_experts,), np.int64)
 
     def add(self, kind: str, stats: np.ndarray) -> None:
-        """``stats`` [calls, 4]: moe_routing_stats of each call."""
+        """``stats`` [calls, 5]: moe_routing_stats of each call."""
         tally = self.by_program[kind]
         tally["calls"] += len(stats)
         for name, total in zip(self.FIELDS[1:], stats.sum(axis=0)):
             tally[name] += int(total)
 
     def flight_fields(self, stats: np.ndarray) -> dict:
-        """One call's ``stats`` [4] as the fields its flight events carry."""
+        """One call's ``stats`` [5] as the fields its flight events carry."""
         return {"moe_live": int(stats[0]),
                 "moe_touched": round(float(stats[2]) / self.n_layers, 2)}
 
@@ -2912,7 +2915,7 @@ class ContinuousBatcher:
             moe_tokens, moe_fields = None, {}
             if self._moe is not None and rec.acc is None:
                 # graftlint: allow-host-sync-in-hot-path(part of the same drain sync: the step's routing tallies land with its tokens — the program already finished for the token read above)
-                moe_stats = np.asarray(rec.aside["moe_stats"])    # [k, 4]
+                moe_stats = np.asarray(rec.aside["moe_stats"])    # [k, 5]
                 # graftlint: allow-host-sync-in-hot-path(same: [k, S, n_experts] int32, 8 KB a step at 32 slots x 64 experts)
                 moe_tokens = np.asarray(rec.aside["moe_tokens"])
                 self._moe.add("decode", moe_stats)

@@ -712,6 +712,23 @@ class LLMServer(SeldonComponent):
         if name is None:
             raise SeldonError("LLMServer needs model_uri or model=<registry name>", status_code=500)
 
+        if self.mesh is None and (self.tensor_parallel > 1 or self.sequence_parallel > 1):
+            tp = max(self.tensor_parallel, 1)
+            sp = max(self.sequence_parallel, 1)
+            n = topo.device_count
+            if n % (tp * sp):
+                raise SeldonError(
+                    f"tensor_parallel={tp} * sequence_parallel={sp} does not "
+                    f"divide {n} available devices",
+                    status_code=500,
+                )
+            self.mesh = topo.mesh({"data": -1, "seq": sp, "model": tp})
+        if self.mesh is not None and int(cfg_kwargs.get("n_experts", 0) or 0) > 0:
+            # MoEFFN's grouped-matmul kernel is one device's program: with the
+            # expert stacks sharded over a mesh it keeps jax.lax.ragged_dot,
+            # which GSPMD partitions (models/transformer.py)
+            cfg_kwargs.setdefault("mesh", self.mesh)
+
         self._module = get_model(name, **cfg_kwargs)
         self._cfg = self._module.cfg
         self._abstract_init = None  # _init_shapes(), of this module
@@ -739,18 +756,6 @@ class LLMServer(SeldonComponent):
 
         if not streamed:
             params = _cast_params(params, self.param_dtype, self._cfg.dtype)
-
-        if self.mesh is None and (self.tensor_parallel > 1 or self.sequence_parallel > 1):
-            tp = max(self.tensor_parallel, 1)
-            sp = max(self.sequence_parallel, 1)
-            n = topo.device_count
-            if n % (tp * sp):
-                raise SeldonError(
-                    f"tensor_parallel={tp} * sequence_parallel={sp} does not "
-                    f"divide {n} available devices",
-                    status_code=500,
-                )
-            self.mesh = topo.mesh({"data": -1, "seq": sp, "model": tp})
 
         # quantize BEFORE sharding: shard_params understands QuantizedTensor
         # leaves (q under the weight's logical spec, scale under the channel
@@ -1292,7 +1297,7 @@ class LLMServer(SeldonComponent):
         """``module.apply`` for the batcher's step programs: (logits, caches,
         aside). ``aside`` is what leaves a program beside its tokens and costs
         no sync of its own (the host reads it after the tokens have landed):
-        for an MoE model ``moe_tokens`` [b, n_experts] and ``moe_stats`` [4]
+        for an MoE model ``moe_tokens`` [b, n_experts] and ``moe_stats`` [5]
         (models/transformer.py moe_routing_stats); empty for a dense one."""
         if self._cfg.n_experts == 0:
             logits, caches = self._module.apply(

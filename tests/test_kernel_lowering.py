@@ -19,6 +19,7 @@ from seldon_core_tpu import ops
 from seldon_core_tpu.models import get_model
 from seldon_core_tpu.models.transformer import init_paged_kv_caches
 from seldon_core_tpu.ops.fused_norm import fused_residual_rmsnorm
+from seldon_core_tpu.ops.grouped_matmul import grouped_matmul, make_visits, row_tile
 from seldon_core_tpu.ops.paged_attention import paged_attention
 from seldon_core_tpu.ops.pallas_int8 import int8_matmul
 
@@ -38,6 +39,36 @@ def test_fused_norm_lowers_for_tpu(rows, dim):
         S((rows, dim), jnp.bfloat16), S((rows, dim), jnp.bfloat16),
         S((dim,), jnp.float32))
     assert MOSAIC_CALL in text
+
+
+@pytest.mark.parametrize("rows,dim,width", [
+    (32 * 8, 2048, 1024), (128 * 8, 2048, 1024), (256 * 8, 2048, 1024),   # OLMoE: step, chunks
+    (8 * 6, 2048, 1408), (128 * 6, 2048, 1408), (256 * 6, 2048, 1408)])   # DeepSeek-V2-Lite
+def test_grouped_matmul_lowers_for_tpu(rows, dim, width):
+    """the routed experts' two orientations (gate / up, down) at the six
+    served shapes, int8 stacks and per-expert scales, at the rule's row tile"""
+    def swiglu(x, sizes, w1, s1, w2, s2):
+        visits = make_visits(sizes, rows, row_tile(rows, 64))
+        h = grouped_matmul(x, w1, visits, s1, interpret=False)
+        return grouped_matmul(h.astype(x.dtype), w2, visits, s2, interpret=False)
+
+    text = tpu_mlir(
+        swiglu, S((rows, dim), jnp.bfloat16), S((64,), jnp.int32),
+        S((64, dim, width), jnp.int8), S((64, width), jnp.float32),
+        S((64, width, dim), jnp.int8), S((64, dim), jnp.float32))
+    assert text.count(MOSAIC_CALL) == 2
+
+
+def test_the_routed_experts_reach_the_kernel_on_a_tpu_and_ragged_dot_elsewhere():
+    """``MoEFFN`` chooses by the platform the program is LOWERED for, not the
+    process's backend (tools/hlolint lowers for a TPU from a CPU process)."""
+    model = get_model("llama-tiny", dtype="bfloat16", n_experts=8, n_experts_per_token=2)
+    tokens = jnp.zeros((2, 4), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    forward = jax.jit(lambda params, tokens: model.apply(params, tokens)[0])
+    n_layers = model.cfg.n_layers
+    assert tpu_mlir(forward, params, tokens).count(MOSAIC_CALL) == 3 * n_layers
+    assert MOSAIC_CALL not in forward.lower(params, tokens).as_text()
 
 
 def _paged_decode_mlir(**model_kwargs) -> str:

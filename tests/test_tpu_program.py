@@ -4,12 +4,17 @@ program is lowered for ShapeDtypeStructs placed on that device, and the
 compiled module's text carries the op names a device trace would show
 (PERF.md section 3, "Reading a program's ops without a chip").
 
-Held here: no program writes a TRANSPOSED floating copy of a weight. The
+Held here, first: no program writes a TRANSPOSED floating copy of a weight. The
 q/k/v projections fuse with the head split and the rotary, and that fusion
 reads the weight output-major; held ``[in, out]`` the dequantized weight was
 copied into that order every layer of every step (``copy bf16[4096,4096]`` and
 two ``copy bf16[1024,4096]``: 1.74 ms of an 18.75 ms Mistral step, PERF.md
 section 6, PR 28). ops/quantize.py holds those leaves ``out_major``.
+
+Second (PR 30): the routed experts of OLMoE and DeepSeek-V2-Lite run the repo's
+own grouped-matmul kernel (ops/grouped_matmul.py), three calls an MoE layer, on
+the int8 stacks as they are held; XLA's own ``ragged-dot-none`` kernel (a 256-row
+tile, 40.6 ms of a 61.9 ms DeepSeek chunk) is in no step program.
 """
 
 import re
@@ -35,7 +40,9 @@ DEEPSEEK = dict(vocab_size=256, dim=2048, n_layers=2, n_heads=16, n_kv_heads=16,
                 qk_rope_head_dim=64, v_head_dim=128, norm_eps=1e-6,
                 rope_scaling={"type": "yarn", "factor": 40, "original_max_position_embeddings": 4096,
                               "beta_fast": 32, "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707})
-CONFIGS = {"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "deepseek": DEEPSEEK}
+# ... and one whole OLMoE layer: 64 experts of width 1024, 8 a token
+OLMOE = dict(OLMOE_ATTENTION, n_experts=64, n_experts_per_token=8, router_renormalize=False)
+CONFIGS = {"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK}
 PAGE, POOL_PAGES = 64, 514
 
 
@@ -205,3 +212,30 @@ def test_no_transposed_copy_of_a_weight(v5e, servers, config, program):
         # 6, PR 29); the row is held in whole 128-lane tiles (640)
         assert server._cfg.latent_row_dim == 640
         assert weight_copies(hlo, {(POOL_PAGES, PAGE, 640), (POOL_PAGES, PAGE, 576)}) == []
+
+
+@pytest.mark.parametrize("config,program", [
+    ("olmoe_moe", "decode_step"), ("olmoe_moe", "prefill_chunk"),
+    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk")])
+def test_the_routed_experts_run_the_repos_grouped_matmul(v5e, servers, config, program):
+    """Three Mosaic kernels of the repo's own an MoE layer (gate, up, down),
+    float32 [rows x k, width] out of the int8 stacks; none of XLA's own
+    grouped-matmul kernels, whose name the device trace showed as
+    ``ragged-dot-none_*``; and no floating array of a stack's shape anywhere
+    (an expert's block is converted in VMEM)."""
+    from seldon_core_tpu.ops.grouped_matmul import KERNEL_NAME
+
+    server = servers(config)
+    cfg = server._cfg
+    hlo = compiled_text(server, program, v5e)
+    calls = [line for line in hlo.splitlines()
+             if re.match(rf"\s*%{KERNEL_NAME}[\w.]* = ", line)]
+    assert len(calls) == 3 * cfg.n_moe_layers
+    rows = (32 if program == "decode_step" else 256) * cfg.n_experts_per_token
+    shapes = sorted(re.search(r"= (\w+\[[\d,]+\])", line).group(1) for line in calls)
+    assert shapes == sorted([f"f32[{rows},{cfg.ffn_dim}]"] * 2 + [f"f32[{rows},{cfg.dim}]"])
+    assert all('custom_call_target="tpu_custom_call"' in line for line in calls)
+    assert all(f"s8[{cfg.n_experts}," in line for line in calls), "the stacks go in as int8"
+    assert not re.search(r"%ragged-dot", hlo)
+    e, d, f = cfg.n_experts, cfg.dim, cfg.ffn_dim
+    assert not re.search(rf"(bf16|f16|f32)\[{e},({d},{f}|{f},{d})\]", hlo)
