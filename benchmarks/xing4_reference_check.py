@@ -1,0 +1,215 @@
+"""How far the SERVED logits of a benchmark configuration are from its plain
+float32 reference, and how far WRONG references are: the readings a
+configuration's `reference_tolerance` is set from (perf/configs/<config>.json).
+
+    chiprun -- python benchmarks/xing4_reference_check.py \
+        --workload xing4-reasoning-decode --probes 12 --wrong-probes 2 --out chiprun_out/pr31r/reference_check.json
+    (then once more with --probes 2 --wrong-probes 2 --only-low: the 4-bit tree in a call of its
+    own, because the machine's host holds 40 GiB and a 15-layer tree is 11.5 GB of it)
+
+In one process on the chip: the server the cell's files describe (the
+configuration's `weights_seed`, the cell's slots and cache length) and its
+batcher serve `--probes` seeded probes of the cell's probe size (a prompt that
+crosses a chunk boundary + decoded rows, logits asked, out of the step programs
+that serve every request) and, with `--long 1`, one request at the cell's longest
+prompt and answer. Then the server is dropped, the int8 tree is taken to the
+host, and seldon_core_tpu/models/reference.py computes each comparison on the
+chip in float32 at highest matmul precision, a leaf at a time: the right
+reference for every probe, FOLLOWING the experts the served path took (the
+probe's `routing`; perf/planes/llm_rest_followed_reference.py says why) and,
+for the first `--free-probes`, choosing for itself; and for the first
+`--wrong-probes` each wrong one (`reference.WRONG`'s keywords, and the weights
+rounded to 4 bits, the nearest precision below the configuration's int8), also
+following. Readings are the plane's two: max |served - reference| over
+max |reference|, and how far the furthest served choice lies behind the
+reference's own.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import gc
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+WRONGS = {
+    "plain_residual": {"streams": False}, "one_sinkhorn_iteration": {"sinkhorn_iters": 1},
+    "no_selection_bias": {"select_bias": False}, "softmax_scores": {"router_score": "softmax"},
+    "no_q_norm": {"q_norm": False}, "no_shared_expert": {"shared": False},
+    "largest_expert_left_out": {"leave_out_rank": 0}, "no_mscale_squared": {"scale_mscale": False},
+}
+
+
+def merge(base: dict, over: dict) -> dict:
+    out = dict(base)
+    for k, v in over.items():
+        out[k] = merge(out[k], v) if isinstance(v, dict) and isinstance(out.get(k), dict) else v
+    return out
+
+
+def load(kind: str, name: str, rehearse: bool) -> dict:
+    """A benchmark file; with ``rehearse`` at the toy sizes of its block, as
+    perf/run.py --rehearse-cpu reads it."""
+    with open(os.path.join(REPO, "perf", kind, name + ".json")) as f:
+        data = json.load(f)
+    toy = data.pop("rehearse", {})
+    return merge(data, toy) if rehearse else data
+
+
+def four_bits(params):
+    """The int8 tree with every quantized leaf rounded to 4 bits on its own
+    scale (q in [-8, 7], the scale 127 / 7 times as coarse)."""
+    import jax
+
+    from seldon_core_tpu.ops.quantize import QuantizedTensor
+
+    def visit(leaf):
+        if not isinstance(leaf, QuantizedTensor):
+            return leaf
+        q = np.clip(np.rint(np.asarray(leaf.q, np.float32) * (7.0 / 127.0)), -8, 7).astype(np.int8)
+        return QuantizedTensor(q, np.asarray(leaf.scale) * np.float32(127.0 / 7.0),
+                               leaf.orig_dtype, leaf.out_major)
+
+    return jax.tree.map(visit, params, is_leaf=lambda x: isinstance(x, QuantizedTensor))
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--probes", type=int, default=6)
+    ap.add_argument("--long", type=int, default=0)
+    ap.add_argument("--wrong-probes", type=int, default=1)
+    ap.add_argument("--only-low", action="store_true",
+                    help="of the wrong references, the 4-bit weights alone")
+    ap.add_argument("--free-probes", type=int, default=2,
+                    help="probes also compared with the reference choosing for itself")
+    ap.add_argument("--seed", type=int, default=3000005900)
+    ap.add_argument("--out", required=True)
+    ap.add_argument("--rehearse", action="store_true", help="toy sizes, on whatever platform")
+    ap.add_argument("--set", action="append", default=[], metavar="KEY=JSON",
+                    help="override a key of the configuration's file (e.g. num_hidden_layers=5)")
+    args = ap.parse_args()
+
+    import jax
+
+    from seldon_core_tpu.models import reference
+    from seldon_core_tpu.runtime.batcher import ContinuousBatcher
+    from seldon_core_tpu.servers.llmserver import LLMServer
+
+    cell = load("workloads", args.workload, args.rehearse)
+    cfg = load("configs", cell["config"], args.rehearse)
+    for item in args.set:
+        key, _, value = item.partition("=")
+        cfg[key] = json.loads(value)
+    server_kw = merge(cfg["server"], cell.get("server", {}))
+    slots, max_len = server_kw.pop("continuous_batching"), server_kw.pop("continuous_batching_max_len")
+    server_kw["model_kwargs"] = {ours: cfg[theirs] for ours, theirs in cfg["model_kwargs_from"].items()}
+    server_kw["seed"] = cfg["weights_seed"]
+    t0 = time.monotonic()
+    server = LLMServer(**server_kw)
+    server.load()
+    print(f"server loaded in {time.monotonic() - t0:.0f}s on {jax.devices()[0].device_kind}", flush=True)
+    batcher = ContinuousBatcher(server, max_slots=slots, max_len=max_len)
+    rng = np.random.default_rng(args.seed)
+    probe = cell["probe"]
+    sizes = [(probe["prompt_tokens"], probe["output_tokens"])] * args.probes
+    if args.long:
+        request = cell["traffic"]["request"]
+        sizes.append((request["prompt_tokens"]["max"], request["output_tokens"]["max"]))
+    asks = [(rng.integers(97, 123, size=n).tolist(), new) for n, new in sizes]
+
+    async def serve():
+        served = []
+        for i, (prompt, new) in enumerate(asks):
+            info = {"logits": []}
+            t1 = time.monotonic()
+            out = await batcher.submit(prompt, new, info=info, seed=1234 + i)
+            print(f"served {len(prompt)} + {len(out)} in {time.monotonic() - t1:.1f}s", flush=True)
+            assert info["routing_start"] == 0
+            served.append((prompt, out, np.stack(info["logits"]), np.stack(info["routing"])))
+        await batcher.close()
+        return served
+
+    served = asyncio.run(serve())
+    stats = jax.devices()[0].memory_stats() or {}
+    model_cfg = server._cfg
+    params = jax.device_get(server._params)
+    del server, batcher
+    gc.collect()
+    jax.clear_caches()
+    if args.only_low:
+        # the 4-bit tree in the int8 one's place, before anything else is on
+        # the host: both, beside what the readings leave there, pass 40 GiB
+        params = four_bits(params)
+        gc.collect()
+
+    def reading(tree, prompt, out, got, took, **wrong) -> dict:
+        """``took`` [tokens, MoE layers, k]: the served experts, which the
+        reference follows (None = it chooses for itself, "free")."""
+        first = len(prompt) - 1
+        t1 = time.monotonic()
+        ref, routing = reference.forward(tree, model_cfg, prompt + out,
+                                         rows=slice(first, first + len(out)), follow=took, **wrong)
+        ref = np.asarray(ref)
+        scale = float(np.abs(ref).max())
+        per_row = np.abs(got - ref).max(axis=1) / scale
+        margins = np.stack([np.asarray(layer["margin"]) for layer in routing])
+        behind = np.stack([np.asarray(layer["behind"]) for layer in routing])[:, :first + len(out)]
+        return {"over_scale": float(per_row.max()), "scale": scale,
+                "first_row": float(per_row[0]), "rows_mean": float(per_row.mean()),
+                "margins_under_1e-3": int((margins < 1e-3).sum()), "margins": int(margins.size),
+                "behind_max": float(behind.max()), "fell_the_other_way": int((behind > 0).sum()),
+                "seconds": time.monotonic() - t1}
+
+    result = {"workload": args.workload, "layers": model_cfg.n_layers, "set": args.set,
+              "peak_bytes_in_use": stats.get("peak_bytes_in_use"), "probes": [], "wrong": {}, "long": None}
+
+    def save():
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(result, f, indent=1)
+
+    for i, (prompt, out, got, took) in enumerate(served[:0 if args.only_low else args.probes]):
+        right = reading(params, prompt, out, got, took)
+        if i < args.free_probes:
+            right["free"] = reading(params, prompt, out, got, None)["over_scale"]
+        print(f"probe {i}: {len(prompt)} + {len(out)}: {json.dumps(right)}", flush=True)
+        result["probes"].append(right)
+        save()
+    def against(name: str, tree, i: int, **wrong) -> None:
+        prompt, out, got, took = served[i]
+        r = reading(tree, prompt, out, got, took, **wrong)
+        result["wrong"].setdefault(name, []).append([r["over_scale"], r["behind_max"]])
+        print(f"probe {i} against {name}: logits {r['over_scale']:.4f}, furthest choice behind "
+              f"{r['behind_max']:.4f}", flush=True)
+        save()
+
+    for i in range(0 if args.only_low else min(args.wrong_probes, args.probes)):
+        for name, wrong in WRONGS.items():
+            against(name, params, i, **wrong)
+    if args.wrong_probes:
+        # a second tree on the host: made late and dropped before the one-off,
+        # whose 2,048 rows of logits are 1 GB a copy (the machine has 40 GiB)
+        low = params if args.only_low else four_bits(params)
+        for i in range(min(args.wrong_probes, args.probes)):
+            against("weights_at_4_bits", low, i)
+        del low
+        gc.collect()
+    for prompt, out, got, took in served[args.probes:]:
+        right = reading(params, prompt, out, got, took)
+        print(f"one-off: {len(prompt)} + {len(out)}: {json.dumps(right)}", flush=True)
+        result["long"] = {"prompt": len(prompt), "decoded": len(out), **right}
+        save()
+    print(json.dumps(result))
+
+
+if __name__ == "__main__":
+    main()

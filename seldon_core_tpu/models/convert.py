@@ -29,7 +29,8 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
     would convert cleanly and serve wrong logits."""
     scaling = getattr(hf_config, "rope_scaling", None)
     rope_scaling = None
-    deepseek = getattr(hf_config, "model_type", "") == "deepseek_v2"
+    model_type = getattr(hf_config, "model_type", "")
+    deepseek = model_type in ("deepseek_v2", "deepseek_v3")
     if scaling:
         rope_type = scaling.get("rope_type", scaling.get("type", "default"))
         if rope_type == "yarn" and deepseek:
@@ -80,16 +81,31 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
         }
     ffn_dim = hf_config.intermediate_size
     if deepseek:
-        for key, only in (("q_lora_rank", None), ("topk_method", "greedy"),
-                          ("moe_layer_freq", 1), ("scoring_func", "softmax")):
-            if getattr(hf_config, key, only) != only:
+        # V3's router is sigmoid scores + a selection bias (noaux_tc); its
+        # config class carries neither key, V2-shaped configs name both
+        v3 = model_type == "deepseek_v3"
+        score = getattr(hf_config, "scoring_func", "sigmoid" if v3 else "softmax")
+        method = getattr(hf_config, "topk_method", "noaux_tc" if v3 else "greedy")
+        for key, got, known in (
+                ("scoring_func", score, ("softmax", "sigmoid")),
+                ("topk_method", method, ("greedy", "noaux_tc")),
+                ("moe_layer_freq", getattr(hf_config, "moe_layer_freq", 1), (1,)),
+                # a group limit on the top-k (n_group > 1) is not built
+                ("n_group", getattr(hf_config, "n_group", 1) or 1, (1,)),
+                # the leaves' names in a checkpoint are not known here
+                ("hc_mult", getattr(hf_config, "hc_mult", 0) or 0, (0, 1))):
+            if got not in known:
                 raise ValueError(
-                    f"deepseek_v2 with {key}={getattr(hf_config, key)!r} is not "
-                    f"supported by the native transformer (only {only!r})")
+                    f"{model_type} with {key}={got!r} is not supported by the "
+                    f"native transformer (only {' / '.join(repr(k) for k in known)})")
         # latent attention; layer 0.. dense at intermediate_size, the others
         # n_routed_experts of moe_intermediate_size + the shared experts
         ffn_dim = hf_config.moe_intermediate_size
         moe = {
+            "q_lora_rank": int(getattr(hf_config, "q_lora_rank", None) or 0),
+            "router_score": score,
+            "router_bias": method == "noaux_tc",
+            "mtp_layers": int(getattr(hf_config, "num_nextn_predict_layers", 0) or 0),
             "n_experts": hf_config.n_routed_experts,
             "n_experts_per_token": hf_config.num_experts_per_tok,
             "router_renormalize": bool(hf_config.norm_topk_prob),
@@ -221,14 +237,31 @@ def _half_split(w: np.ndarray, rope: int) -> np.ndarray:
     return np.concatenate([w[..., :-rope], w[..., -rope:][..., order]], axis=-1)
 
 
+def has_mtp_weights(state_dict: Dict[str, Any], kwargs: Dict[str, Any]) -> bool:
+    """The MTP module is the layer behind the last (``model.layers.<n_layers>``).
+    transformers' DeepseekV3ForCausalLM drops it when it loads a checkpoint, so
+    a state dict that went through the port has none whatever its config says."""
+    return f"model.layers.{kwargs['n_layers']}.eh_proj.weight" in state_dict
+
+
 def convert_deepseek_v2_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str, Any],
-                                   dtype: str = "float32") -> Dict[str, Any]:
-    """HF DeepseekV2ForCausalLM state dict -> our flax param tree. ``kwargs``
-    are ``config_kwargs_from_hf``'s. kv_b_proj [H * (nope + v), latent]
-    splits per head into W_UK [H, nope, latent] and W_UV [H, latent, v] (the
-    order the absorbed products read them); the rope columns of q_proj (per
-    head) and of kv_a_proj_with_mqa go from interleaved pairs to halves."""
+                                   dtype: str = "float32",
+                                   rope_interleaved: bool = True) -> Dict[str, Any]:
+    """HF DeepseekV2ForCausalLM / DeepseekV3ForCausalLM state dict -> our flax
+    param tree. ``kwargs`` are ``config_kwargs_from_hf``'s. kv_b_proj
+    [H * (nope + v), latent] splits per head into W_UK [H, nope, latent] and
+    W_UV [H, latent, v] (the order the absorbed products read them); the rope
+    columns of q_proj / q_b_proj (per head) and of kv_a_proj_with_mqa go from
+    interleaved pairs to halves (unless the config says ``rope_interleave``
+    false). With kwargs["q_lora_rank"] the queries are q_a_proj,
+    q_a_layernorm, q_b_proj; with kwargs["router_bias"] the gate's
+    e_score_correction_bias is the selection bias; with kwargs["mtp_layers"]
+    the layer behind the last is the MTP module: its eh_proj takes
+    [enorm(emb) ; hnorm(h)] in the published checkpoints, ours [h ; emb] (the
+    paper's order), so the halves swap; its embed_tokens and shared_head.head
+    repeat the main model's and are dropped."""
     t, consumed = _tensor_reader(state_dict, dtype)
+    split = _half_split if rope_interleaved else (lambda w, _rope: w)
 
     def swiglu(prefix: str) -> Dict[str, Any]:
         return {"w1": t(f"{prefix}.gate_proj.weight").T, "w2": t(f"{prefix}.down_proj.weight").T,
@@ -236,19 +269,15 @@ def convert_deepseek_v2_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str,
 
     H, dn, dr = kwargs["n_heads"], kwargs["qk_nope_head_dim"], kwargs["qk_rope_head_dim"]
     dc, dv, dim = kwargs["kv_lora_rank"], kwargs["v_head_dim"], kwargs["dim"]
-    params: Dict[str, Any] = {
-        "tok_embeddings": t("model.embed_tokens.weight"),
-        "norm": {"weight": t("model.norm.weight")},
-        "lm_head": t("lm_head.weight").T,
-    }
-    for i in range(kwargs["n_layers"]):
-        hf = f"model.layers.{i}"
-        wq = _half_split(t(f"{hf}.self_attn.q_proj.weight").T.reshape(dim, H, dn + dr), dr)
+    rank = kwargs.get("q_lora_rank", 0)
+
+    def block(hf: str, dense: bool) -> Dict[str, Any]:
+        q_name, q_in = ("q_b_proj", rank) if rank else ("q_proj", dim)
+        wq = split(t(f"{hf}.self_attn.{q_name}.weight").T.reshape(q_in, H, dn + dr), dr)
         kv_b = t(f"{hf}.self_attn.kv_b_proj.weight").reshape(H, dn + dv, dc)
-        layer = params[f"layer_{i}"] = {
+        layer = {
             "attention": {
-                "wq": wq.reshape(dim, H * (dn + dr)),
-                "wkv_a": _half_split(t(f"{hf}.self_attn.kv_a_proj_with_mqa.weight").T, dr),
+                "wkv_a": split(t(f"{hf}.self_attn.kv_a_proj_with_mqa.weight").T, dr),
                 "kv_norm": {"weight": t(f"{hf}.self_attn.kv_a_layernorm.weight")},
                 "w_uk": kv_b[:, :dn, :],
                 "w_uv": kv_b[:, dn:, :].transpose(0, 2, 1),
@@ -257,15 +286,46 @@ def convert_deepseek_v2_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str,
             "attention_norm": {"weight": t(f"{hf}.input_layernorm.weight")},
             "ffn_norm": {"weight": t(f"{hf}.post_attention_layernorm.weight")},
         }
-        if i < kwargs["first_dense_layers"]:
+        if rank:
+            layer["attention"].update(
+                wq_a=t(f"{hf}.self_attn.q_a_proj.weight").T,
+                q_norm={"weight": t(f"{hf}.self_attn.q_a_layernorm.weight")},
+                wq_b=wq.reshape(rank, H * (dn + dr)))
+        else:
+            layer["attention"]["wq"] = wq.reshape(dim, H * (dn + dr))
+        if dense:
             layer["ffn"] = swiglu(f"{hf}.mlp")
-            continue
+            return layer
         layer["moe"] = {"router": t(f"{hf}.mlp.gate.weight").T}
+        if kwargs.get("router_bias"):
+            layer["moe"]["router_bias"] = t(f"{hf}.mlp.gate.e_score_correction_bias")
         experts = [swiglu(f"{hf}.mlp.experts.{e}") for e in range(kwargs["n_experts"])]
         for name in ("w1", "w2", "w3"):
             layer["moe"][name] = np.stack([e[name] for e in experts])
         if kwargs["n_shared_experts"]:
             layer["moe"]["shared"] = swiglu(f"{hf}.mlp.shared_experts")
+        return layer
+
+    params: Dict[str, Any] = {
+        "tok_embeddings": t("model.embed_tokens.weight"),
+        "norm": {"weight": t("model.norm.weight")},
+        "lm_head": t("lm_head.weight").T,
+    }
+    n_layers = kwargs["n_layers"]
+    for i in range(n_layers):
+        params[f"layer_{i}"] = block(f"model.layers.{i}", i < kwargs["first_dense_layers"])
+    if kwargs.get("mtp_layers"):
+        hf = f"model.layers.{n_layers}"
+        eh = t(f"{hf}.eh_proj.weight").T          # [2 dim, dim], rows [emb ; h]
+        params["mtp"] = {
+            "eh_proj": np.concatenate([eh[dim:], eh[:dim]]),
+            "enorm": {"weight": t(f"{hf}.enorm.weight")},
+            "hnorm": {"weight": t(f"{hf}.hnorm.weight")},
+            "norm": {"weight": t(f"{hf}.shared_head.norm.weight")},
+            "block": block(hf, dense=False),
+        }
+        consumed.update(k for k in (f"{hf}.embed_tokens.weight", f"{hf}.shared_head.head.weight")
+                        if k in state_dict)
     leftover = [k for k in state_dict if k not in consumed and not k.endswith("inv_freq")]
     if leftover:
         raise ValueError(
@@ -274,13 +334,16 @@ def convert_deepseek_v2_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str,
 
 
 def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
-    """In-memory transformers LlamaForCausalLM, OlmoeForCausalLM or
-    DeepseekV2ForCausalLM -> (our module, variables)."""
+    """In-memory transformers LlamaForCausalLM, OlmoeForCausalLM,
+    DeepseekV2ForCausalLM or DeepseekV3ForCausalLM -> (our module, variables)."""
     from seldon_core_tpu.models import get_model
 
     kwargs = config_kwargs_from_hf(hf_model.config)
     if kwargs.get("kv_lora_rank"):
-        variables = convert_deepseek_v2_state_dict(hf_model.state_dict(), kwargs)
+        state_dict = hf_model.state_dict()
+        kwargs["mtp_layers"] *= has_mtp_weights(state_dict, kwargs)
+        variables = convert_deepseek_v2_state_dict(
+            state_dict, kwargs, rope_interleaved=getattr(hf_model.config, "rope_interleave", True))
     else:
         variables = convert_llama_state_dict(
             hf_model.state_dict(), n_layers=kwargs["n_layers"],
@@ -305,7 +368,9 @@ def convert_checkpoint(hf_path: str, out_dir: str, dtype: str = "bfloat16") -> s
     kwargs = config_kwargs_from_hf(hf_config)
     # weights stored in the serving dtype (bf16 halves checkpoint size vs f32)
     if kwargs.get("kv_lora_rank"):
-        variables = convert_deepseek_v2_state_dict(model.state_dict(), kwargs, dtype)
+        kwargs["mtp_layers"] *= has_mtp_weights(model.state_dict(), kwargs)
+        variables = convert_deepseek_v2_state_dict(
+            model.state_dict(), kwargs, dtype, getattr(hf_config, "rope_interleave", True))
     else:
         variables = convert_llama_state_dict(
             model.state_dict(), n_layers=kwargs["n_layers"], dtype=dtype,

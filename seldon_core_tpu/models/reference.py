@@ -42,6 +42,55 @@ follows the published model, so the comparison with ``DeepseekV2ForCausalLM``
 the YaRN frequencies are held to transformers' ``_compute_yarn_parameters`` on
 their own. ``scale_mscale=False`` computes the port's reading: a WRONG model here.
 
+DeepSeek-V3's forms (arXiv 2412.19437; ``transformers/models/deepseek_v3``,
+which tests/test_reference_xing4.py holds this file to with the streams off):
+
+    q_h   = [q^N_h ; RoPE(q^R_h)] from W_qb RMSNorm_q(W_qa n1)       (cfg.q_lora_rank > 0)
+    s     = sigmoid(Wr n2) over ALL experts                            (cfg.router_score)
+    chosen: top-k of s + b, b a per-expert SELECTION bias (cfg.router_bias) that
+            never enters a weight; weights s_e / (sum of the k s + 1e-20) x
+            routed_scaling_factor; no group limit (n_group = topk_group = 1)
+    MTP   : h' = W_eh [RMSNorm_h(h_t) ; RMSNorm_e(emb(x_t+1))], one MoE block, its
+            own final norm, the main model's embedding and head (``forward_mtp``);
+            h_t is the main model's hidden state BEFORE its final norm. The paper
+            writes [h ; emb]; the published checkpoints' eh_proj takes [emb ; h]
+            (models/convert.py swaps the halves)
+
+Manifold-constrained hyper-connections (cfg.hc_mult = n > 1 residual streams;
+mHC, arXiv 2512.24880, over Hyper-Connections, arXiv 2409.19606). Per token,
+X in R^{n x C}, every sub-layer F (attention or FFN) with its own Phi
+[nC, 2n + n^2], alpha = (a_pre, a_post, a_res), b_pre, b_post, B_res:
+
+    v      = vec(X);  r = (mean(v^2) + norm_eps)^-1/2;   m = r (v Phi)
+    H_pre  = sigmoid(a_pre m[:n] + b_pre)        H_post = 2 sigmoid(a_post m[n:2n] + b_post)
+    M      = exp(clamp(a_res mat(m[2n:]) + B_res, -hc_res_clamp, +hc_res_clamp))
+    hc_sinkhorn_iters times:  M <- M / (rowsum(M) + hc_eps);  M <- M / (colsum(M) + hc_eps)
+    u      = sum_i H_pre[i] X[i];   y = F(RMSNorm(u));   X'[i] = sum_j M[i, j] X[j] + H_post[i] y
+
+What this file fixes where neither the config nor arXiv 2512.24880 does, or
+where it reads the paper one way (the served path computes the same):
+(1) the streams are ENTERED by copying the embedding n times and LEFT by their
+sum, before the final norm (Hyper-Connections section 3; the config has no key
+for either); (2) an iteration normalises ROWS, then COLUMNS, so after the last
+one the columns sum to 1 and the rows to 1 within the iteration's error;
+(3) hc_eps is added to every row and column sum, and the clamp (the config's
+mhc_h_res_clamp_min / _max) is applied before the exponential, neither of which
+the paper's equations carry; (4) the normalisation of vec(X) has no weight of
+its own (a weight there is a row scaling of Phi) and uses the model's
+rms_norm_eps; (5) one matrix Phi holds the three maps' columns [pre ; post ; res],
+mat() fills rows first; (6) the MTP block has streams of its own, entered and
+left like the main model's. ``streams=False`` computes the plain residual on
+the same weights, ``sinkhorn_iters=1`` stops after one iteration,
+``select_bias=False`` / ``router_score="softmax"`` / ``q_norm=False`` leave
+one piece of the V3 forms out: WRONG models, for showing a tolerance is tight.
+
+``follow=`` makes the forward take the experts the SERVED path took (a logits
+probe's ``routing``): where this router's last chosen and first unchosen expert
+score within the served arithmetic's noise of each other, which one is taken is
+not a property of the program, and with four experts weighed ~0.5 each one such
+choice moves everything after it; followed, the comparison is of the arithmetic,
+and ``behind`` in the routing says whether the served CHOICES are this router's.
+
 One departure, stated because it is part of what is compared: a leaf that is
 int8 in the tree (ops/quantize.py, the configuration's stated weight precision)
 is used at its int8-rounded value, dequantized in float32. The reference then
@@ -138,7 +187,8 @@ def _attention(p: dict, x, cfg):
     return jnp.einsum("hqk,khd->qhd", probs, v).reshape(s, cfg.n_heads * hd) @ _f32(p["wo"])
 
 
-def _latent_attention(p: dict, x, cfg, scale_mscale: bool = True, block: int = 512):
+def _latent_attention(p: dict, x, cfg, scale_mscale: bool = True, block: int = 512,
+                      q_norm: bool = True):
     """DeepSeek-V2's attention with every head's keys and values expanded
     from the latents (the form the paper defines; the served path never
     expands). Queries go in blocks of ``block`` rows so that a 16 k context
@@ -149,7 +199,13 @@ def _latent_attention(p: dict, x, cfg, scale_mscale: bool = True, block: int = 5
     scaling = dict(cfg.rope_scaling or ())
     if scaling and scaling.get("rope_type", scaling.get("type")) != "yarn":
         raise NotImplementedError("the reference's latent attention knows YaRN only")
-    q = (x @ _f32(p["wq"])).reshape(s, heads, dn + dr)
+    if getattr(cfg, "q_lora_rank", 0):
+        c_q = x @ _f32(p["wq_a"])
+        if q_norm:
+            c_q = _rms_norm(c_q, p["q_norm"]["weight"], cfg.norm_eps)
+        q = (c_q @ _f32(p["wq_b"])).reshape(s, heads, dn + dr)
+    else:
+        q = (x @ _f32(p["wq"])).reshape(s, heads, dn + dr)
     q = jnp.concatenate([q[..., :dn], _rope(q[..., dn:], cfg.rope_theta, scaling)], axis=-1)
     kv_a = x @ _f32(p["wkv_a"])
     c = _rms_norm(kv_a[:, :dc], p["kv_norm"]["weight"], cfg.norm_eps)          # [s, dc]
@@ -174,18 +230,37 @@ def _swiglu(x, w1, w2, w3):
     return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
 
 
-def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True):
+def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True,
+             select_bias: bool = True, router_score: Optional[str] = None, follow=None):
     """The expert FFN of one layer, and what the router chose: ``experts``
-    [s, k] best first, their ``weights`` [s, k], and ``margin`` [s], by how
-    much the last chosen probability beats the best one not chosen (a tie
-    the served path's bf16 activations may break the other way)."""
+    [s, k] largest weight first, their ``weights`` [s, k], and ``margin`` [s],
+    by how much the last chosen score (with its selection bias) beats the
+    best one not chosen (a tie the served path's bf16 activations may break
+    the other way). ``follow`` [t, k] are the experts the SERVED path took for
+    the first t rows: those rows take them too, weighed by this side's own
+    scores, and ``behind`` [s] says by how much this side's last choice beats
+    the worst expert followed (0 where both chose the same four; the size of
+    the served noise where a near-tie fell the other way; more is a served
+    path that chooses by another rule)."""
     n, k = cfg.n_experts, min(cfg.n_experts_per_token, cfg.n_experts)
-    probs = jax.nn.softmax(x @ _f32(p["router"]), axis=-1)
-    ranked_p, ranked = jax.lax.top_k(probs, min(k + 1, n))
-    experts, weights = ranked[:, :k], ranked_p[:, :k]
-    margin = weights[:, -1] - ranked_p[:, k] if k < n else jnp.full(x.shape[:1], jnp.inf)
+    logits = x @ _f32(p["router"])
+    sigmoid = (router_score or getattr(cfg, "router_score", "softmax")) == "sigmoid"
+    probs = jax.nn.sigmoid(logits) if sigmoid else jax.nn.softmax(logits, axis=-1)
+    pick = probs
+    if getattr(cfg, "router_bias", False) and select_bias:
+        pick = probs + _f32(p["router_bias"])      # chooses; weighs nothing
+    ranked_pick, ranked = jax.lax.top_k(pick, min(k + 1, n))
+    margin = ranked_pick[:, k - 1] - ranked_pick[:, k] if k < n else jnp.full(x.shape[:1], jnp.inf)
+    took, behind = ranked[:, :k], jnp.zeros(x.shape[:1])
+    if follow is not None:
+        took = jnp.concatenate([jnp.asarray(follow, took.dtype), took[len(follow):]])
+        behind = ranked_pick[:, k - 1] - jnp.min(jnp.take_along_axis(pick, took, axis=-1), axis=-1)
+    weights = jnp.take_along_axis(probs, took, axis=-1)
+    by_weight = jnp.argsort(-weights, axis=-1)
+    experts = jnp.take_along_axis(took, by_weight, axis=-1)
+    weights = jnp.take_along_axis(weights, by_weight, axis=-1)
     if cfg.router_renormalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + (1e-20 if sigmoid else 0.0))
     weights = weights * getattr(cfg, "routed_scaling_factor", 1.0)
     used = weights if leave_out_rank is None else weights.at[:, leave_out_rank].set(0.0)
     out = jnp.zeros_like(x)
@@ -197,48 +272,140 @@ def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True
     if "shared" in p and shared:
         f = p["shared"]
         out = out + _swiglu(x, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
-    return out, {"experts": experts, "weights": weights, "margin": margin}
+    return out, {"experts": experts, "weights": weights, "margin": margin, "behind": behind}
 
 
-def forward(params: Any, cfg: Any, tokens, leave_out_rank: Optional[int] = None,
-            scale_mscale: bool = True, shared: bool = True, rows=slice(None)):
+def _mix(p: dict, X, cfg, iters: int):
+    """One sub-layer's hyper-connection maps from the streams ``X`` [s, n, C]:
+    (u [s, C], H_post [s, n], H_res [s, n, n]); the Sinkhorn iterations are a
+    plain loop over a [s, n, n] array."""
+    s, n, _ = X.shape
+    v = X.reshape(s, -1)
+    m = jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + cfg.norm_eps) * (v @ _f32(p["phi"]))
+    a_pre, a_post, a_res = _f32(p["alpha"])
+    h_pre = jax.nn.sigmoid(a_pre * m[:, :n] + _f32(p["b_pre"]))
+    h_post = 2.0 * jax.nn.sigmoid(a_post * m[:, n:2 * n] + _f32(p["b_post"]))
+    mat = jnp.exp(jnp.clip(a_res * m[:, 2 * n:].reshape(s, n, n) + _f32(p["b_res"]),
+                           -cfg.hc_res_clamp, cfg.hc_res_clamp))
+    for _ in range(iters):
+        mat = mat / (jnp.sum(mat, axis=2, keepdims=True) + cfg.hc_eps)    # rows
+        mat = mat / (jnp.sum(mat, axis=1, keepdims=True) + cfg.hc_eps)    # then columns
+    return jnp.einsum("si,sic->sc", h_pre, X), h_post, mat
+
+
+def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=None):
+    """One decoder block on the residual ``x`` [s, C], or on the streams
+    [s, n, C] where the layer has mixing parameters and ``streams`` is on."""
+    latent = getattr(cfg, "kv_lora_rank", 0) > 0
+    mixed = x.ndim == 3
+    iters = wrong["sinkhorn_iters"] if wrong["sinkhorn_iters"] is not None else getattr(
+        cfg, "hc_sinkhorn_iters", 0)
+
+    def sub_layer(x, name, norm, f):
+        if not mixed:
+            return x + f(_rms_norm(x, layer[norm]["weight"], cfg.norm_eps))
+        u, h_post, h_res = _mix(layer[name], x, cfg, iters)
+        y = f(_rms_norm(u, layer[norm]["weight"], cfg.norm_eps))
+        return jnp.einsum("sij,sjc->sic", h_res, x) + h_post[:, :, None] * y[:, None, :]
+
+    def attention(n1):
+        if latent:
+            return _latent_attention(layer["attention"], n1, cfg, wrong["scale_mscale"],
+                                     q_norm=wrong["q_norm"])
+        return _attention(layer["attention"], n1, cfg)
+
+    def ffn(n2):
+        if moe:
+            out, chose = _experts(layer["moe"], n2, cfg, wrong["leave_out_rank"], wrong["shared"],
+                                  wrong["select_bias"], wrong["router_score"],
+                                  None if follow is None else follow[:, len(routing)])
+            routing.append(chose)
+            return out
+        f = layer["ffn"]
+        return _swiglu(n2, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
+
+    x = sub_layer(x, "attention_hc", "attention_norm", attention)
+    return sub_layer(x, "ffn_hc", "ffn_norm", ffn)
+
+
+def _enter(x, cfg, wrong: dict):
+    n = getattr(cfg, "hc_mult", 0)
+    return jnp.repeat(x[:, None, :], n, axis=1) if n > 1 and wrong["streams"] else x
+
+
+def _leave(x):
+    return jnp.sum(x, axis=1) if x.ndim == 3 else x
+
+
+WRONG = {"leave_out_rank": None, "scale_mscale": True, "shared": True, "streams": True,
+         "sinkhorn_iters": None, "select_bias": True, "router_score": None, "q_norm": True}
+
+
+def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None):
+    """The main model's hidden state [s, C] after the last block (and the
+    streams' exit), before the final norm; and the routing."""
+    latent = getattr(cfg, "kv_lora_rank", 0) > 0
+    if getattr(cfg, "rope_scaling", None) and not latent:
+        raise NotImplementedError("the reference has scaled RoPE for latent attention only")
+    first_dense = getattr(cfg, "first_dense_layers", 0)
+    routing: list = []
+    x = _enter(_f32(p["tok_embeddings"])[jnp.asarray(tokens, jnp.int32)], cfg, wrong)
+    for i in range(cfg.n_layers):
+        x = _block(p[f"layer_{i}"], x, cfg, cfg.n_experts > 0 and i >= first_dense, routing, wrong,
+                   follow)
+    return _leave(x), routing
+
+
+def _head(p: dict, cfg: Any):
+    return _f32(p["tok_embeddings"]).T if cfg.tie_embeddings else _f32(p["lm_head"])
+
+
+def forward(params: Any, cfg: Any, tokens, rows=slice(None), follow=None, **wrong):
     """``tokens`` [s] -> (logits [s, vocab] float32, routing): ``routing`` has
     one entry per MoE layer (``_experts``), and is empty for a dense model.
 
     ``params`` is the tree models/transformer.py's Transformer takes (with or
     without the outer "params" key); ``cfg`` is anything with its fields (a
-    TransformerConfig will do). ``leave_out_rank`` computes a WRONG model, for
-    showing that a tolerance is tight: every token loses its rank-th expert.
-    So do ``scale_mscale=False`` (latent attention without YaRN's m^2 on the
-    softmax scale) and ``shared=False`` (no shared experts). ``rows`` selects
-    the positions whose logits are returned (a 16 k context times a 100 k
-    vocabulary is more float32 than it is worth keeping)."""
-    latent = getattr(cfg, "kv_lora_rank", 0) > 0
-    if getattr(cfg, "rope_scaling", None) and not latent:
-        raise NotImplementedError("the reference has scaled RoPE for latent attention only")
-    first_dense = getattr(cfg, "first_dense_layers", 0)
+    TransformerConfig will do). The keywords of ``WRONG`` compute a WRONG
+    model, for showing that a tolerance is tight: ``leave_out_rank`` (every
+    token loses its rank-th expert, 0 the one of largest weight),
+    ``scale_mscale=False`` (latent attention without YaRN's m^2 on the softmax
+    scale), ``shared=False`` (no shared experts), and the hyper-connection and
+    V3 ones the module's docstring lists. ``rows`` selects the positions whose
+    logits are returned (a 16 k context times a 100 k vocabulary is more
+    float32 than it is worth keeping). ``follow`` [t, n_moe_layers, k] int: the
+    experts the served path took for the first t tokens (a probe's "routing",
+    transport/rest.py): this forward takes the same ones there, so that the
+    comparison holds the arithmetic and not which way a near-tie fell; what
+    the served path may NOT do, choose by another rule, shows in the
+    routing's ``behind`` (``_experts``)."""
+    unknown = set(wrong) - set(WRONG)
+    if unknown:
+        raise TypeError(f"unknown keywords {sorted(unknown)}; the wrong models are {sorted(WRONG)}")
+    wrong = {**WRONG, **wrong}
     p = params.get("params", params)
-    routing = []
     with jax.default_matmul_precision("highest"):
-        x = _f32(p["tok_embeddings"])[jnp.asarray(tokens, jnp.int32)]
-        for i in range(cfg.n_layers):
-            layer = p[f"layer_{i}"]
-            n1 = _rms_norm(x, layer["attention_norm"]["weight"], cfg.norm_eps)
-            if latent:
-                x = x + _latent_attention(layer["attention"], n1, cfg, scale_mscale)
-            else:
-                x = x + _attention(layer["attention"], n1, cfg)
-            n2 = _rms_norm(x, layer["ffn_norm"]["weight"], cfg.norm_eps)
-            if cfg.n_experts > 0 and i >= first_dense:
-                out, chose = _experts(layer["moe"], n2, cfg, leave_out_rank, shared)
-                routing.append(chose)
-            else:
-                f = layer["ffn"]
-                out = _swiglu(n2, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
-            x = x + out
+        x, routing = _hidden(p, cfg, tokens, wrong, follow)
         x = _rms_norm(x, p["norm"]["weight"], cfg.norm_eps)
-        head = _f32(p["tok_embeddings"]).T if cfg.tie_embeddings else _f32(p["lm_head"])
-        return x[rows] @ head, routing
+        return x[rows] @ _head(p, cfg), routing
+
+
+def forward_mtp(params: Any, cfg: Any, tokens):
+    """The MTP module's logits [s - 1, vocab] over ``tokens`` [s]: row t, from
+    the main model's hidden state of token t and the embedding of token t + 1,
+    predicts token t + 2."""
+    p = params.get("params", params)
+    tokens = jnp.asarray(tokens, jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        hidden, _ = _hidden(p, cfg, tokens[:-1], WRONG)
+        m = p["mtp"]
+        both = jnp.concatenate(
+            [_rms_norm(hidden, m["hnorm"]["weight"], cfg.norm_eps),
+             _rms_norm(_f32(p["tok_embeddings"])[tokens[1:]], m["enorm"]["weight"], cfg.norm_eps)],
+            axis=-1)
+        x = _block(m["block"], _enter(both @ _f32(m["eh_proj"]), cfg, WRONG), cfg,
+                   cfg.n_experts > 0, [], WRONG)
+        return _rms_norm(_leave(x), m["norm"]["weight"], cfg.norm_eps) @ _head(p, cfg)
 
 
 def expert_token_counts(routing: list, n_experts: int, rows=slice(None)):

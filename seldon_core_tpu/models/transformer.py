@@ -20,6 +20,7 @@ at all; this is the native model family the TPU build adds (SURVEY.md §5
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -95,6 +96,13 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
     return q.astype(dtype) * scale[..., None].astype(dtype)
 
 
+FUSED_NORM_STREAMS_REFUSAL = (
+    "fused_norm does not compose with hyper-connections (hc_mult > 1): the "
+    "kernel fuses the residual ADD with the ffn norm, and with several streams "
+    "the block has no such add (TransformerBlock writes back through H_res / "
+    "H_post); serve it with fused_norm off")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -144,6 +152,25 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # Compressed queries (DeepSeek-V2's full form): q_lora_rank > 0 puts
+    # wq_a [dim, rank], an RMSNorm over the rank and wq_b [rank, heads] in
+    # place of wq.
+    q_lora_rank: int = 0
+    # The router's score ("softmax" over all experts, or "sigmoid" of each
+    # logit: DeepSeek-V3) and a per-expert selection bias that is added to the
+    # scores for the top-k CHOICE only and never enters a weight (noaux_tc).
+    router_score: str = "softmax"
+    router_bias: bool = False
+    # Hyper-connections (HyperConnection below): hc_mult > 1 residual streams,
+    # mixed per token around every sub-layer; the residual matrix is made
+    # doubly stochastic by hc_sinkhorn_iters Sinkhorn iterations over
+    # exp(clamp(., -hc_res_clamp, +hc_res_clamp)). 0 / 1 = the plain residual.
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    # Multi-token-prediction modules behind the last layer (MTPModule): 0 or 1.
+    mtp_layers: int = 0
     # "full" = dense attention (GSPMD gathers KV when seq-sharded);
     # "ring" = sequence-parallel ring attention over mesh axis 'seq'
     # (ops.ring_attention) for long-context cache-less forward/training.
@@ -160,6 +187,18 @@ class TransformerConfig:
     # 1 bf16 ulp of the unfused graph, not bit-equal).
     fused_norm: bool = False
     mesh: Any = None
+
+    def __post_init__(self):
+        # what is not built is refused where the config is made: at load()
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown router_score {self.router_score!r}: expected 'softmax' or 'sigmoid'")
+        if self.fused_norm and self.hc_mult > 1:
+            raise ValueError(FUSED_NORM_STREAMS_REFUSAL)
+        if self.mtp_layers > 1:
+            raise ValueError(
+                f"mtp_layers={self.mtp_layers} is not built: one multi-token-prediction "
+                "module (DeepSeek-V3's depth 1) is")
 
     @property
     def head_dim(self) -> int:
@@ -656,10 +695,10 @@ def _dense_stack(w, dtype):
 
 
 class LatentAttention(nn.Module):
-    """Multi-head latent attention (DeepSeek-V2, arXiv 2405.04434 sec. 2.1;
-    no query compression: q_lora_rank null).
+    """Multi-head latent attention (DeepSeek-V2, arXiv 2405.04434 sec. 2.1).
 
-        q_h     = [q^N_h ; RoPE(q^R_h)]              from wq, H heads
+        q_h     = [q^N_h ; RoPE(q^R_h)]              from wq, H heads; with cfg.q_lora_rank
+                                                     from wq_b RMSNorm_q(wq_a x_t)
         c_t     = RMSNorm(W_DKV x_t)   k^R_t = RoPE(W_KR x_t)     (wkv_a = [W_DKV ; W_KR])
         row_t   = [c_t ; k^R_t]        the ONE thing cached a token, no head axis
         out     = wo concat_h absorbed_latent_attention(...)_h
@@ -687,9 +726,19 @@ class LatentAttention(nn.Module):
         dt = cfg.dtype
         stack = dict(batch_axis=(0,))
 
-        wq = param_with_axes(
-            "wq", nn.initializers.lecun_normal(), (cfg.dim, H * (dn + dr)), jnp.float32,
-            axes=("embed", "heads"))
+        if cfg.q_lora_rank:
+            # compressed queries: c^Q = RMSNorm_q(W_qa x), q = W_qb c^Q. wq_b
+            # feeds the head split, so its int8 leaf is held output-major
+            wq_a = param_with_axes(
+                "wq_a", nn.initializers.lecun_normal(), (cfg.dim, cfg.q_lora_rank), jnp.float32,
+                axes=("embed", "q_latent"))
+            wq_b = param_with_axes(
+                "wq_b", nn.initializers.lecun_normal(), (cfg.q_lora_rank, H * (dn + dr)),
+                jnp.float32, axes=("q_latent", "heads"))
+        else:
+            wq = param_with_axes(
+                "wq", nn.initializers.lecun_normal(), (cfg.dim, H * (dn + dr)), jnp.float32,
+                axes=("embed", "heads"))
         wkv_a = param_with_axes(
             "wkv_a", nn.initializers.lecun_normal(), (cfg.dim, dc + dr), jnp.float32,
             axes=("embed", "kv_latent"))
@@ -707,7 +756,13 @@ class LatentAttention(nn.Module):
             axes=("heads", "embed"))
 
         cos, sin = rotary_embedding(positions, dr, cfg.rope_theta, cfg.rope_scaling)
-        q = (x @ wq.astype(dt)).reshape(b, s, H, dn + dr)
+        if cfg.q_lora_rank:
+            with jax.named_scope("attn.latent.q"):
+                c_q = RMSNorm(cfg.q_lora_rank, cfg.norm_eps, "q_latent", name="q_norm")(
+                    x @ wq_a.astype(dt))
+                q = (c_q @ wq_b.astype(dt)).reshape(b, s, H, dn + dr)
+        else:
+            q = (x @ wq.astype(dt)).reshape(b, s, H, dn + dr)
         q_nope, q_rope = q[..., :dn], apply_rotary(q[..., dn:], cos, sin)
         with jax.named_scope("attn.latent.write"):
             kv_a = x @ wkv_a.astype(dt)
@@ -846,13 +901,30 @@ class MoEFFN(nn.Module):
         k = min(cfg.n_experts_per_token, e)
         xf = x.reshape(t, d)
 
+        if cfg.router_bias:
+            select_bias = param_with_axes("router_bias", small_leaf_init("router_bias"), (e,),
+                                          jnp.float32, axes=("expert_select",))
         with jax.named_scope("moe.route"):
-            probs = jax.nn.softmax(xf.astype(jnp.float32) @ router, axis=-1)
-            gates, chosen = jax.lax.top_k(probs, k)  # [t, k]
+            logits = xf.astype(jnp.float32) @ router
+            if cfg.router_score == "sigmoid":
+                probs = jax.nn.sigmoid(logits)
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)
+            if cfg.router_bias:
+                # the bias chooses and does not weigh
+                _, chosen = jax.lax.top_k(probs + select_bias.astype(jnp.float32), k)
+                gates = jnp.take_along_axis(probs, chosen, axis=-1)
+            else:
+                gates, chosen = jax.lax.top_k(probs, k)  # [t, k]
             if cfg.router_renormalize:
-                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+                total = jnp.sum(gates, axis=-1, keepdims=True)
+                gates = gates / (total + 1e-20 if cfg.router_score == "sigmoid" else total)
             if cfg.routed_scaling_factor != 1.0:
                 gates = gates * cfg.routed_scaling_factor
+            sowing = self.is_mutable_collection("moe") and not self.is_initializing()
+            if sowing:
+                # which experts each row took: what a logits probe's reference follows
+                self.sow("moe", "choice", chosen.reshape(b, s, k).astype(jnp.int32))
             if valid is not None:
                 # group e does not exist: its rows sort behind every expert's
                 chosen = jnp.where(valid.reshape(t, 1), chosen, e)
@@ -863,7 +935,6 @@ class MoEFFN(nn.Module):
                 chosen[:, :, None] == jnp.arange(e, dtype=chosen.dtype), axis=1,
                 dtype=jnp.int32)  # [t, e]
             group_sizes = jnp.sum(by_token, axis=0)
-            sowing = self.is_mutable_collection("moe") and not self.is_initializing()
             if sowing:
                 self.sow("moe", "tokens", jnp.sum(by_token.reshape(b, s, e), axis=1))
 
@@ -922,6 +993,14 @@ class MoEFFN(nn.Module):
         return out
 
 
+def moe_choices(sown: dict, cfg: TransformerConfig) -> jnp.ndarray:
+    """[b, s, n_moe_layers, k] int32 out of the same collection: the experts
+    each row of the call took in each MoE layer, in the router's order (a row
+    that is padding holds whatever its padding scored)."""
+    return jnp.stack([sown[f"layer_{i}"]["moe"]["choice"][0]
+                      for i in range(cfg.first_dense_layers, cfg.n_layers)], axis=2)
+
+
 def moe_routing_stats(sown: dict, cfg: TransformerConfig):
     """Reduce what the MoE layers of one forward sowed (the "moe" collection
     of ``Transformer.apply(..., mutable=["moe"])``) to ``(tokens, stats)``:
@@ -942,7 +1021,149 @@ def moe_routing_stats(sown: dict, cfg: TransformerConfig):
     return jnp.sum(per_layer, axis=0), stats.astype(jnp.int32)
 
 
+# Leaves that stay float32 in every tree (never int8, never cast to the
+# serving dtype): the stream mixing's maps, scalars and biases and the router's
+# selection bias. They are told apart by a logical axis (FLOAT32_AXES:
+# parallel/sharding.py ``float32_leaves``), and their seeded init is here, by
+# leaf name, for the module's own init and for the server's streamed one:
+# normal(mean, std); std None = 1 / sqrt(fan_in). The sizes are chosen to be
+# VISIBLE in the logits (tests/test_reference_xing4.py's wrong references)
+# and such that the configured 20 Sinkhorn iterations converge: H_pre spread
+# over (0.25, 0.75), H_post over (0.5, 1.5), the residual matrix's logarithm
+# a_res m_res + B_res of spread (0.7^2 + 0.5^2)^1/2 = 0.86 (a seeded doubly
+# stochastic matrix far from both I and 1/n, whose rows sum to 1 within 2e-6
+# after 20 iterations; at a spread of 1.4 three tokens in a hundred are still
+# 1e-4 off), a selection bias about the spread of the top sigmoid scores.
+FLOAT32_AXES = ("hc_maps", "expert_select")
+SMALL_LEAF_INIT = {
+    "phi": (0.0, None), "alpha": (0.7, 0.05), "b_pre": (0.0, 0.5), "b_post": (0.0, 0.5),
+    "b_res": (0.0, 0.5), "router_bias": (0.0, 0.1),
+}
+
+
+def draw_small_leaf(name: str, key, shape) -> jnp.ndarray:
+    mean, std = SMALL_LEAF_INIT[name]
+    if std is None:
+        std = float(shape[0]) ** -0.5
+    return mean + std * jax.random.normal(key, shape, jnp.float32)
+
+
+def small_leaf_init(name: str):
+    return lambda key, shape, dtype=jnp.float32: draw_small_leaf(name, key, shape).astype(dtype)
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def sinkhorn_entrywise(m: jnp.ndarray, iters: int, eps: float) -> jnp.ndarray:
+    """``iters`` Sinkhorn iterations on n x n matrices ``m`` [n, n, ...] (the
+    tokens behind the matrix axes): rows divided by their sums, then columns
+    by theirs. Written entry by entry, n^2 arrays of tokens, so that every
+    iteration is elementwise over the tokens (ops/sinkhorn.py has the one
+    body). A jit of its own: the 60 sub-layer calls of a 30-layer program trace
+    it once and lower to calls of one function."""
+    from seldon_core_tpu.ops.sinkhorn import entrywise_iteration
+
+    n = m.shape[0]
+    rows = jax.lax.fori_loop(0, iters, lambda _, rows: entrywise_iteration(rows, eps),
+                             [[m[i, j] for j in range(n)] for i in range(n)])
+    return jnp.stack([jnp.stack(row) for row in rows])
+
+
+def sinkhorn(m: jnp.ndarray, iters: int, eps: float, kernel: bool = True) -> jnp.ndarray:
+    """One iteration body, run by the platform the program is LOWERED for (as
+    MoEFFN's grouped matmul is chosen): for a TPU the repo's Pallas kernel
+    (ops/sinkhorn.py: the sixteen entries in registers through all the
+    iterations, one op a sub-layer), because no form of the chain as XLA ops is
+    both few ops on the device and few instructions for the compiler; a loop
+    of the same body elsewhere, and where ``kernel`` is false (on a mesh: the
+    kernel is one device's program). The arithmetic and its order are the same."""
+    from seldon_core_tpu.ops.sinkhorn import sinkhorn as sinkhorn_kernel
+
+    if not kernel:
+        return sinkhorn_entrywise(m, iters, eps)
+    return jax.lax.platform_dependent(
+        m, tpu=lambda m: sinkhorn_kernel(m, iters, eps, interpret=False),
+        default=lambda m: sinkhorn_entrywise(m, iters, eps))
+
+
+class HyperConnection(nn.Module):
+    """One sub-layer's manifold-constrained hyper-connection (mHC, arXiv
+    2512.24880, over Hyper-Connections, arXiv 2409.19606): how the sub-layer
+    reads the n = cfg.hc_mult residual streams and writes back to them.
+    ``X`` [b, s, n, C] in cfg.dtype; everything named H or m is float32.
+
+        v      = vec(X) in R^{nC};  r = (mean(v^2) + norm_eps)^-1/2
+        m      = r (v Phi)                     -> [m_pre (n) ; m_post (n) ; m_res (n^2)]
+        H_pre  = sigmoid(a_pre m_pre + b_pre)
+        H_post = 2 sigmoid(a_post m_post + b_post)
+        M      = exp(clamp(a_res mat(m_res) + B_res, -hc_res_clamp, +hc_res_clamp))
+        H_res  = hc_sinkhorn_iters x (rows / (rowsum + hc_eps), then columns / (colsum + hc_eps))
+        u      = sum_i H_pre[i] X[i]           the sub-layer's input, before its RMSNorm
+        X'[i]  = sum_j H_res[i, j] X[j] + H_post[i] y       (``write_back``)
+
+    ``__call__`` returns (u, H_post [n, b, s], H_res [n, n, b, s]) under the
+    scope ``resid.hc.pre``: the maps are held maps-major, the tokens the minor
+    axes of every small array. Departures from the paper are in
+    models/reference.py's docstring, which computes the same equations with
+    none of this code."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, X):
+        cfg = self.cfg
+        n, C = cfg.hc_mult, cfg.dim
+        maps = 2 * n + n * n
+        phi = param_with_axes("phi", small_leaf_init("phi"), (n * C, maps), jnp.float32,
+                              axes=("hc_embed", "hc_maps"))
+        alpha = param_with_axes("alpha", small_leaf_init("alpha"), (3,), jnp.float32,
+                                axes=("hc_maps",))
+        b_pre = param_with_axes("b_pre", small_leaf_init("b_pre"), (n,), jnp.float32,
+                                axes=("hc_maps",))
+        b_post = param_with_axes("b_post", small_leaf_init("b_post"), (n,), jnp.float32,
+                                 axes=("hc_maps",))
+        b_res = param_with_axes("b_res", small_leaf_init("b_res"), (n, n), jnp.float32,
+                                axes=("hc_maps", "hc_maps"))
+        b, s = X.shape[:2]
+        with jax.named_scope("resid.hc.pre"):
+            v = X.reshape(b, s, n * C).astype(jnp.float32)
+            r = jax.lax.rsqrt(jnp.mean(v * v, axis=-1) + cfg.norm_eps)          # [b, s]
+            # maps-major [maps, b, s]: the tokens are the minor axis of every
+            # small array below
+            m = jnp.einsum("bsk,km->mbs", v, phi.astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST) * r
+            # each map's scalar and bias as vectors over the maps (the TPU
+            # compiler still splits alpha into three scalars in tiny fusions of
+            # their own, 2 us each in a step and 13 us in a chunk: PERF.md
+            # section 7)
+            which = jnp.asarray([0] * n + [1] * n + [2] * n * n)
+            bias = jnp.concatenate([b_pre, b_post, b_res.reshape(-1)]).astype(jnp.float32)
+            m = m * alpha.astype(jnp.float32)[which][:, None, None] + bias[:, None, None]
+            h_pre = jax.nn.sigmoid(m[:n])
+            h_post = 2.0 * jax.nn.sigmoid(m[n:2 * n])
+            raw = jnp.exp(jnp.clip(m[2 * n:].reshape(n, n, b, s),
+                                   -cfg.hc_res_clamp, cfg.hc_res_clamp))
+            h_res = sinkhorn(raw, cfg.hc_sinkhorn_iters, cfg.hc_eps, kernel=cfg.mesh is None)
+            u = sum(h_pre[i][..., None] * X[:, :, i].astype(jnp.float32) for i in range(n))
+        return u.astype(X.dtype), h_post, h_res
+
+
+def hc_write_back(X, y, h_post, h_res):
+    """X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y, float32 inside one fusion,
+    the streams stored in X's dtype."""
+    n = X.shape[2]
+    with jax.named_scope("resid.hc.post"):
+        streams = [X[:, :, j].astype(jnp.float32) for j in range(n)]
+        y32 = y.astype(jnp.float32)
+        return jnp.stack(
+            [(sum(h_res[i, j][..., None] * streams[j] for j in range(n))
+              + h_post[i][..., None] * y32).astype(X.dtype) for i in range(n)], axis=2)
+
+
 class TransformerBlock(nn.Module):
+    """``x`` is the residual [b, s, dim] or, with cfg.hc_mult > 1, the residual
+    streams [b, s, hc_mult, dim]: each sub-layer then reads a mix of the
+    streams and writes back through its HyperConnection."""
+
     cfg: TransformerConfig
     layer: int = 0   # decides the FFN's kind (cfg.first_dense_layers)
 
@@ -952,13 +1173,20 @@ class TransformerBlock(nn.Module):
                  valid=None):
         cfg = self.cfg
         attention = LatentAttention if cfg.kv_lora_rank else Attention
+        streams = cfg.hc_mult > 1
+        if streams:
+            X, (x, h_post, h_res) = x, HyperConnection(cfg, name="attention_hc")(x)
         with jax.named_scope("attn"):
             h, new_cache = attention(cfg, name="attention")(
                 RMSNorm(cfg.dim, cfg.norm_eps, name="attention_norm")(x), positions, cache, cache_index,
                 block_tables, adapters, adapter_ids,
             )
         ffn_norm = RMSNorm(cfg.dim, cfg.norm_eps, name="ffn_norm")
-        if cfg.fused_norm:
+        if streams:
+            X = hc_write_back(X, h, h_post, h_res)
+            x, h_post, h_res = HyperConnection(cfg, name="ffn_hc")(X)
+            ffn_in = ffn_norm(x)
+        elif cfg.fused_norm:
             # residual-add + RMSNorm in one HBM pass (ops/fused_norm.py):
             # collapses the per-layer norm chains the decode profile flags
             # (~7.5 us each on [8, 2048] tensors — DECODE_NOTES.md). A TPU
@@ -974,7 +1202,52 @@ class TransformerBlock(nn.Module):
         else:
             width = cfg.dense_ffn_dim if cfg.n_experts > 0 else 0
             f = DenseFFN(cfg, width, name="ffn")(ffn_in, adapters, adapter_ids)
+        if streams:
+            return hc_write_back(X, f, h_post, h_res), new_cache
         return x + f, new_cache
+
+
+def enter_streams(x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
+    """[b, s, dim] -> the residual streams [b, s, hc_mult, dim], each a copy
+    (Hyper-Connections, arXiv 2409.19606 section 3); the identity without."""
+    if cfg.hc_mult > 1:
+        return jnp.broadcast_to(x[:, :, None, :], x.shape[:2] + (cfg.hc_mult, cfg.dim))
+    return x
+
+
+def leave_streams(x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
+    """The streams' sum (float32 inside), [b, s, dim]; the identity without."""
+    if cfg.hc_mult > 1:   # widened one stream at a time, as everywhere
+        return sum(x[:, :, i].astype(jnp.float32) for i in range(cfg.hc_mult)).astype(x.dtype)
+    return x
+
+
+class MTPModule(nn.Module):
+    """DeepSeek-V3's multi-token-prediction module (arXiv 2412.19437 section
+    2.2), depth 1: from the main model's hidden state ``h`` [b, s, dim] of
+    token t (after the streams' exit, BEFORE the final norm) and the embedding
+    of token t + 1, the hidden state that the shared head turns into the logits
+    of token t + 2.
+
+        h' = W_eh [RMSNorm_h(h_t) ; RMSNorm_e(emb(x_t+1))]          W_eh [2 dim, dim]
+        out = RMSNorm(leave(Block(enter(h'))))      one MoE block, its own final norm
+
+    The block is a TransformerBlock of the model's MoE kind with its own
+    residual streams, entered and left as the main model's. It runs without a
+    cache (the full causal forward): serving from it is not wired."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h, next_emb, positions, valid=None):
+        cfg = self.cfg
+        w_eh = param_with_axes("eh_proj", nn.initializers.lecun_normal(), (2 * cfg.dim, cfg.dim),
+                               jnp.float32, axes=("embed_pair", "embed"))
+        both = jnp.concatenate([RMSNorm(cfg.dim, cfg.norm_eps, name="hnorm")(h),
+                                RMSNorm(cfg.dim, cfg.norm_eps, name="enorm")(next_emb)], axis=-1)
+        x = enter_streams(both @ w_eh.astype(cfg.dtype), cfg)
+        x, _ = TransformerBlock(cfg, cfg.n_layers, name="block")(x, positions, valid=valid)
+        return RMSNorm(cfg.dim, cfg.norm_eps, name="norm")(leave_streams(x, cfg))
 
 
 class Transformer(nn.Module):
@@ -982,8 +1255,11 @@ class Transformer(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, caches=None, cache_index=None,
-                 block_tables=None, adapters=None, adapter_ids=None):
+                 block_tables=None, adapters=None, adapter_ids=None, next_tokens=None):
         """tokens: [b, s] int32. Returns (logits [b, s, vocab], new_caches).
+        With ``next_tokens`` ([b, s] int32: each position's NEXT token) and
+        cfg.mtp_layers, a cache-less forward also returns the MTP module's
+        logits [b, s, vocab] (position t: token t + 2), as a third value.
         ``block_tables`` ([b, n_pages] int32, shared by every layer) switches
         the caches to the paged-pool layout — see Attention.
 
@@ -1006,7 +1282,7 @@ class Transformer(nn.Module):
             jnp.float32, axes=("vocab", "embed"),
         )
         x = emb.astype(cfg.dtype)[tokens]
-        x = with_sharding_constraint(x, ("batch", "seq", "embed"))
+        x = enter_streams(with_sharding_constraint(x, ("batch", "seq", "embed")), cfg)
         valid = None
         if cfg.n_experts > 0:
             # rows that are tokens: not the padding of a chunk (PAD_POS), and
@@ -1029,15 +1305,24 @@ class Transformer(nn.Module):
                 x, positions, layer_cache, cache_index, block_tables,
                 layer_adapters, adapter_ids, valid)
             new_caches.append(nc)
-        x = RMSNorm(cfg.dim, cfg.norm_eps, name="norm")(x)
+        hidden = leave_streams(x, cfg)
+        x = RMSNorm(cfg.dim, cfg.norm_eps, name="norm")(hidden)
         if cfg.tie_embeddings:
-            logits = x.astype(jnp.float32) @ emb.T
+            head = emb.T
         else:
-            lm_head = param_with_axes(
+            head = param_with_axes(
                 "lm_head", nn.initializers.normal(stddev=0.02), (cfg.dim, cfg.vocab_size),
                 jnp.float32, axes=("embed", "vocab"),
             )
-            logits = x.astype(jnp.float32) @ lm_head
+        logits = x.astype(jnp.float32) @ head
+        if cfg.mtp_layers and (next_tokens is not None or self.is_initializing()):
+            # the embedding and the head are the main model's, the norms its own
+            if caches is not None:
+                raise ValueError("the MTP module runs cache-less: serving from it is not wired")
+            after = tokens if next_tokens is None else next_tokens
+            mtp = MTPModule(cfg, name="mtp")(hidden, emb.astype(cfg.dtype)[after], positions, valid)
+            if next_tokens is not None:
+                return logits, new_caches, mtp.astype(jnp.float32) @ head
         return logits, new_caches
 
 

@@ -143,21 +143,27 @@ def _is_quantizable(leaf) -> bool:
     return jnp.issubdtype(jnp.dtype(str(dtype)), jnp.floating) and getattr(leaf, "ndim", 0) >= 2
 
 
-def quantize_params(params: Any, bits: int = 8, out_major: Any = None) -> Any:
+def quantize_params(params: Any, bits: int = 8, out_major: Any = None,
+                    keep: Any = None) -> Any:
     """Quantize every ≥2-D float leaf of a param pytree; the rest passes
     through. Returns a tree mixing QuantizedTensor and original leaves.
     ``out_major`` is a tree of bools shaped like ``params`` (parallel/
-    sharding.py ``head_split_outputs``): the leaves to hold output-major."""
+    sharding.py ``head_split_outputs``): the leaves to hold output-major.
+    ``keep`` is another (``float32_leaves``): the leaves to leave as they are."""
     import jax
 
     _register_pytree()
 
-    def visit(leaf, transposed=False):
-        return quantize_array(leaf, bits, transposed) if _is_quantizable(leaf) else leaf
+    def visit(leaf, transposed=False, kept=False):
+        if kept or not _is_quantizable(leaf):
+            return leaf
+        return quantize_array(leaf, bits, transposed)
 
-    if out_major is None:
+    if out_major is None and keep is None:
         return jax.tree.map(visit, params)
-    return jax.tree.map(visit, params, out_major)
+    false = jax.tree.map(lambda _: False, params)
+    return jax.tree.map(visit, params, false if out_major is None else out_major,
+                        false if keep is None else keep)
 
 
 def dequantize_params(params: Any, dtype=None, keep_stacks: bool = False) -> Any:

@@ -66,6 +66,18 @@ def logical_axes_of(abstract):
 HEAD_SPLIT_AXES = ("heads", "kv_heads")
 
 
+def _leaves_whose_spec(params: Any, logical_specs: Any, holds) -> Any:
+    """A tree of bools shaped like ``params``: ``holds(spec)`` of each leaf's
+    logical spec; False for every leaf where the module names no axes."""
+    import jax
+
+    if logical_specs is None:
+        return jax.tree.map(lambda _: False, params)
+    return jax.tree.map(
+        lambda s: s is not None and holds(s), _align_specs(params, logical_specs),
+        is_leaf=lambda x: x is None or _is_spec(x))
+
+
 def head_split_outputs(params: Any, logical_specs: Any):
     """Per leaf of ``params``: is it a matrix whose OUTPUT axis is split into
     attention heads (the q/k/v projections of models/transformer.py
@@ -73,14 +85,19 @@ def head_split_outputs(params: Any, logical_specs: Any):
     the rotary and reads the weight output-major, so that is how its int8
     values are held (ops/quantize.py, "the orientation rule"). No leaf where
     the module names no axes."""
-    import jax
+    return _leaves_whose_spec(
+        params, logical_specs, lambda s: len(s) == 2 and s[-1] in HEAD_SPLIT_AXES)
 
-    if logical_specs is None:
-        return jax.tree.map(lambda _: False, params)
-    return jax.tree.map(
-        lambda s: s is not None and len(s) == 2 and s[-1] in HEAD_SPLIT_AXES,
-        _align_specs(params, logical_specs),
-        is_leaf=lambda x: x is None or _is_spec(x))
+
+def float32_leaves(params: Any, logical_specs: Any):
+    """Per leaf of ``params``: does it name one of models/transformer.py's
+    FLOAT32_AXES (the stream mixing's maps, scalars and biases, the router's
+    selection bias)? Such a leaf is precision-critical and small: no tree
+    holds it in int8 or casts it to the serving dtype."""
+    from seldon_core_tpu.models.transformer import FLOAT32_AXES
+
+    return _leaves_whose_spec(
+        params, logical_specs, lambda s: any(axis in FLOAT32_AXES for axis in s))
 
 
 def shard_params(params: Any, mesh, logical_specs: Any, rules=DEFAULT_LOGICAL_RULES):

@@ -401,7 +401,7 @@ class _PrefillJob:
 
     def __init__(self, slot, ids, start, chunk, max_new, fut, on_token,
                  info, seed, bt_row, pages, t_arrival=None, req=None):
-        # (aside, flight event) of each chunk dispatched so far: read when
+        # (aside, flight event, first position, tokens) of each chunk dispatched so far: read when
         # the first token's record drains, never by a sync of their own
         self.asides: List[tuple] = []
         self.slot = slot
@@ -463,7 +463,7 @@ class _Slot:
     __slots__ = ("future", "tokens", "true_len", "n_new", "max_new", "active",
                  "on_token", "gen", "disp_new", "pages", "shared", "ids",
                  "prefilling", "admit_seq", "t_last", "tenant", "slo_class",
-                 "adapter_id", "logits")
+                 "adapter_id", "logits", "routing")
 
     def __init__(self):
         self.active = False
@@ -471,6 +471,9 @@ class _Slot:
         # distribution it was sampled from): the request's own ``info``
         # list, or None for every request that did not ask
         self.logits: Optional[List[np.ndarray]] = None
+        # and, for an MoE model, the experts each of its tokens took
+        # ([n_moe_layers, k] a token: the prompt's, then one a decode step)
+        self.routing: Optional[List[np.ndarray]] = None
         # multi-tenant identity (runtime/scheduler.py): who this occupant
         # belongs to, which SLO class its latency counts against, and the
         # LoRA adapter row every adapted step gathers for it (0=identity).
@@ -1807,6 +1810,15 @@ class ContinuousBatcher:
                 # graftlint: allow-host-sync-in-hot-path(a probe request only: one [vocab] row of a program that has finished)
                 rec.info["logits"].append(np.asarray(rec.row))
                 slot.logits = rec.info["logits"]
+                if self._moe is not None and rec.asides:
+                    # and the experts the prompt's tokens took, chunk by chunk
+                    # (from ``routing_start`` on: a reused prefix ran no chunk)
+                    rec.info["routing_start"] = rec.asides[0][2]
+                    took: List[np.ndarray] = []
+                    for aside, _, _, n in rec.asides:
+                        # graftlint: allow-host-sync-in-hot-path(a probe request only: [chunk, n_moe_layers, k] int32 of a chunk that has finished)
+                        took.extend(np.asarray(aside["moe_choice"])[0, :n])
+                    slot.routing = rec.info["routing"] = took
             slot.n_new = 1
             slot.tokens = [first]
             # first token surfaced NOW: time-to-first-token from submit(),
@@ -2298,7 +2310,7 @@ class ContinuousBatcher:
                 job.slot, EV_PREFILL_CHUNK, start=start, tokens=n,
                 dur_s=time.perf_counter() - t0)
         if self._moe is not None:
-            job.asides.append((aside, event))
+            job.asides.append((aside, event, start, n))
         if job.next >= job.L:
             self._activate(job, logits, n - 1)
 
@@ -2306,7 +2318,7 @@ class ContinuousBatcher:
         """The routing tallies of an admission's chunks. They ran before
         the program whose first token the caller has just read, so every
         array here is ready: no read below waits for the device."""
-        for aside, event in asides:
+        for aside, event, _, _ in asides:
             # graftlint: allow-host-sync-in-hot-path(no wait: these programs finished before the first token the caller just read, once per request)
             stats = np.asarray(aside["moe_stats"])
             self._moe.add("chunk", stats[None])
@@ -2571,6 +2583,7 @@ class ContinuousBatcher:
         slot.future = None
         slot.on_token = None
         slot.logits = None
+        slot.routing = None
         slot.ids = None
         slot.tenant = ""
         slot.slo_class = "interactive"
@@ -2955,6 +2968,9 @@ class ContinuousBatcher:
                 if slot.logits is not None:
                     # graftlint: allow-host-sync-in-hot-path(a probe request only: one [vocab] row of a step that has finished)
                     slot.logits.append(np.asarray(rec.aside["logits"][j, i]))
+                    if slot.routing is not None:
+                        # graftlint: allow-host-sync-in-hot-path(same probe: the [n_moe_layers, k] experts this step's token took)
+                        slot.routing.append(np.asarray(rec.aside["moe_choice"][j, i, 0]))
                 # inter-token gap at this drain (a fused block surfaces
                 # its k tokens in one burst: trailing tokens record ~0)
                 if slot.t_last is not None:

@@ -83,11 +83,13 @@ class HFTokenizer:
         return self._tok.decode([int(i) for i in ids])
 
 
-def _cast_params(params, param_dtype: str, module_dtype) -> Any:
+def _cast_params(params, param_dtype: str, module_dtype, keep=None) -> Any:
     """Cast float32 param leaves to the serving dtype ("auto" = the module's
     compute dtype). The module casts weights to its compute dtype inside
     every matmul anyway; pre-casting stores them that way in HBM, halving
-    weight-streaming bytes for bf16 models (benchmarks/DECODE_NOTES.md)."""
+    weight-streaming bytes for bf16 models (benchmarks/DECODE_NOTES.md).
+    ``keep`` (a tree of bools, parallel/sharding.py ``float32_leaves``) marks
+    the leaves that stay float32."""
     if not param_dtype:
         return params
     import jax
@@ -97,12 +99,14 @@ def _cast_params(params, param_dtype: str, module_dtype) -> Any:
     if target == jnp.float32:
         return params
 
-    def cast(leaf):
-        if hasattr(leaf, "dtype") and leaf.dtype == jnp.float32:
+    def cast(leaf, kept=False):
+        if not kept and hasattr(leaf, "dtype") and leaf.dtype == jnp.float32:
             return leaf.astype(target)
         return leaf
 
-    return jax.tree.map(cast, params)
+    if keep is None:
+        return jax.tree.map(cast, params)
+    return jax.tree.map(cast, params, keep)
 
 
 def _top_k_candidates(lg, top_k: int):
@@ -754,8 +758,13 @@ class LLMServer(SeldonComponent):
                 jax.random.PRNGKey(self.seed), jnp.zeros((1, 8), jnp.int32)
             )
 
+        # the stream mixing's leaves and the selection bias stay float32 in
+        # every tree (models/transformer.py FLOAT32_AXES)
+        from seldon_core_tpu.parallel.sharding import float32_leaves
+
         if not streamed:
-            params = _cast_params(params, self.param_dtype, self._cfg.dtype)
+            keep = float32_leaves(params, self._logical_axes())
+            params = _cast_params(params, self.param_dtype, self._cfg.dtype, keep)
 
         # quantize BEFORE sharding: shard_params understands QuantizedTensor
         # leaves (q under the weight's logical spec, scale under the channel
@@ -774,7 +783,7 @@ class LLMServer(SeldonComponent):
                 params = self._streamed_quantized_init()
             else:
                 params = quantize_params(params, out_major=head_split_outputs(
-                    params, self._logical_axes()))
+                    params, self._logical_axes()), keep=keep)
             is_q = lambda x: isinstance(x, QuantizedTensor)  # noqa: E731
             held = sum(is_q(leaf) and leaf.out_major
                        for leaf in jax.tree.leaves(params, is_leaf=is_q))
@@ -912,8 +921,9 @@ class LLMServer(SeldonComponent):
         import jax.numpy as jnp
         from jax.tree_util import keystr, tree_flatten_with_path
 
+        from seldon_core_tpu.models.transformer import draw_small_leaf
         from seldon_core_tpu.ops.quantize import _register_pytree, quantize_array
-        from seldon_core_tpu.parallel.sharding import head_split_outputs
+        from seldon_core_tpu.parallel.sharding import float32_leaves, head_split_outputs
 
         _register_pytree()  # jit returns QuantizedTensor leaves
         target = jnp.dtype(self._cfg.dtype) if self.param_dtype == "auto" else (
@@ -928,12 +938,16 @@ class LLMServer(SeldonComponent):
         shapes = self._init_shapes()
         flat, treedef = tree_flatten_with_path(shapes)
         transposed = jax.tree.leaves(head_split_outputs(shapes, self._logical_axes()))
+        kept = jax.tree.leaves(float32_leaves(shapes, self._logical_axes()))
         root = jax.random.PRNGKey(self.seed)
         leaves = []
-        for (path, spec), out_major in zip(flat, transposed):
+        for (path, spec), out_major, keep in zip(flat, transposed, kept):
             name = keystr(path)
-            if jnp.issubdtype(spec.dtype, jnp.floating) and spec.ndim >= 2:
-                key = jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+            key = jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+            if keep:
+                # float32 as drawn, by the module's own rule for the leaf
+                leaves.append(draw_small_leaf(path[-1].key, key, spec.shape))
+            elif jnp.issubdtype(spec.dtype, jnp.floating) and spec.ndim >= 2:
                 fan_in = int(np.prod(spec.shape[1 if spec.ndim == 3 else 0:-1]))
                 if name.endswith("['w_uk']"):
                     # latent attention's key expansion is held [H, nope,
@@ -1298,17 +1312,20 @@ class LLMServer(SeldonComponent):
         aside). ``aside`` is what leaves a program beside its tokens and costs
         no sync of its own (the host reads it after the tokens have landed):
         for an MoE model ``moe_tokens`` [b, n_experts] and ``moe_stats`` [5]
-        (models/transformer.py moe_routing_stats); empty for a dense one."""
+        (models/transformer.py moe_routing_stats) and ``moe_choice``
+        [b, s, n_moe_layers, k], the experts every row took, which only a
+        logits probe reads (its reference follows them); empty for a dense one."""
         if self._cfg.n_experts == 0:
             logits, caches = self._module.apply(
                 self._dequant(params), tokens, **kwargs)
             return logits, caches, {}
-        from seldon_core_tpu.models.transformer import moe_routing_stats
+        from seldon_core_tpu.models.transformer import moe_choices, moe_routing_stats
 
         (logits, caches), sown = self._module.apply(
             self._dequant(params), tokens, mutable=["moe"], **kwargs)
         moe_tokens, moe_stats = moe_routing_stats(sown["moe"], self._cfg)
-        return logits, caches, {"moe_tokens": moe_tokens, "moe_stats": moe_stats}
+        return logits, caches, {"moe_tokens": moe_tokens, "moe_stats": moe_stats,
+                                "moe_choice": moe_choices(sown["moe"], self._cfg)}
 
     def _get_prefill_chunk(self, chunk: int, n_pages: int,
                            lora: bool = False):

@@ -346,7 +346,12 @@ def _add_generate_routes(app: web.Application, component: Any,
           probe: plain reply, batched path only) adds the float32 logits
           each token was sampled from, one row per token, taken from the
           step programs that serve every request:
-          {"shape": [n, vocab], "dtype": "float32", "base64": ...}.
+          {"shape": [n, vocab], "dtype": "float32", "base64": ...}; for a
+          mixture-of-experts model also "routing", the experts every
+          processed token took in every MoE layer, out of the same programs:
+          {"first_token": i, "shape": [tokens, moe_layers, k], "dtype":
+          "int32", "base64": ...} (a reference that follows them is held to
+          the arithmetic and not to how a near-tie fell).
       {"prompts": [...], ...} — explicit batch, served by one generate().
     No reference counterpart (its servers are request/response classifiers);
     this is the BASELINE.json LLM stretch surface."""
@@ -482,6 +487,15 @@ def _add_generate_routes(app: web.Application, component: Any,
                     out["logits"] = {
                         "shape": list(rows.shape), "dtype": "float32",
                         "base64": base64.b64encode(rows.tobytes()).decode()}
+                if info.get("routing"):
+                    # an MoE model: the experts each processed token took
+                    # (every token but the last one sampled), for a reference
+                    # that follows the served choices
+                    took = np.stack(info["routing"]).astype("<i4")
+                    out["routing"] = {
+                        "first_token": info["routing_start"],
+                        "shape": list(took.shape), "dtype": "int32",
+                        "base64": base64.b64encode(took.tobytes()).decode()}
                 return web.json_response(out)
 
             if custom_sampling:

@@ -330,6 +330,42 @@ def test_latent_step_programs_never_expand_the_cached_view(name):
     assert reported == []
 
 
+@pytest.mark.parametrize("name", ["llm.xing4_paged_decode_step_s4",
+                                  "llm.xing4_prefill_chunk_c8"])
+def test_stream_step_programs_keep_the_streams_in_the_models_dtype(name):
+    """Xing4.0's block at test dims (ISSUE 31): no float32 [.., streams, dim]
+    array in the lowered module (the mixing widens one stream at a time), and
+    the latent and MoE promises hold under the hyper-connections; the pool is
+    donated, no transfer."""
+    from tools.hlolint.contracts import all_contracts
+
+    (contract,) = [c for c in all_contracts() if c.name == name]
+    reported, *_ = run_one(contract, checks=("alias", "transfer", "dtype",
+                                             "collective"))
+    assert reported == []
+
+
+def test_stream_scan_sees_a_widened_stream_when_it_is_there():
+    from tools.hlolint.contracts import HC_FLOAT32_STREAMS, HC_STREAMS, MOE_DIM, SLOTS
+
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        def widened(X, h):   # the write-back as one float32 einsum over the whole stream
+            return jnp.einsum("bsij,bsjc->bsic", h, X.astype(jnp.float32)).astype(X.dtype)
+
+        return jax.jit(widened), (
+            _sds((SLOTS, 1, HC_STREAMS, MOE_DIM), "bfloat16"),
+            _sds((SLOTS, 1, HC_STREAMS, HC_STREAMS), "float32"))
+
+    contract = Contract(name="test.hc_widened", description="float32 streams",
+                        build=build, forbid_dtypes=(HC_FLOAT32_STREAMS,),
+                        lowering_platform="tpu")
+    reported, *_ = run_one(contract, checks=("dtype",))
+    assert len(reported) == 1 and "float32 [.., streams, dim]" in reported[0].message
+
+
 def test_latent_scan_sees_expanded_keys_when_they_are_there():
     """The expanded formulation at the contract's dims: per-head keys made
     from the gathered view are named by the scan."""

@@ -22,6 +22,7 @@ from seldon_core_tpu.ops.fused_norm import fused_residual_rmsnorm
 from seldon_core_tpu.ops.grouped_matmul import grouped_matmul, make_visits, row_tile
 from seldon_core_tpu.ops.paged_attention import paged_attention
 from seldon_core_tpu.ops.pallas_int8 import int8_matmul
+from seldon_core_tpu.ops.sinkhorn import sinkhorn
 
 S = jax.ShapeDtypeStruct
 MOSAIC_CALL = "tpu_custom_call"  # how a lowered Pallas TPU kernel appears
@@ -57,6 +58,26 @@ def test_grouped_matmul_lowers_for_tpu(rows, dim, width):
         S((64, dim, width), jnp.int8), S((64, width), jnp.float32),
         S((64, width, dim), jnp.int8), S((64, dim), jnp.float32))
     assert text.count(MOSAIC_CALL) == 2
+
+
+@pytest.mark.parametrize("tokens", [(32, 1), (1, 256), (1, 4096)])
+def test_sinkhorn_lowers_for_tpu(tokens):
+    """the hyper-connections' Sinkhorn chain at a decode step's rows, a
+    chunk's, and a cache-less forward's over a whole 4,096-token slot"""
+    text = tpu_mlir(lambda m: sinkhorn(m, 20, 1e-6, interpret=False),
+                    S((4, 4) + tokens, jnp.float32))
+    assert text.count(MOSAIC_CALL) == 1
+
+
+def test_the_sinkhorn_chain_reaches_the_kernel_on_a_tpu_and_the_loop_elsewhere():
+    """``HyperConnection`` chooses as ``MoEFFN`` does: one kernel a sub-layer
+    in a program lowered for a TPU, none in any other."""
+    model = get_model("llama-tiny", dtype="bfloat16", hc_mult=4)
+    tokens = jnp.zeros((2, 4), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    forward = jax.jit(lambda params, tokens: model.apply(params, tokens)[0])
+    assert tpu_mlir(forward, params, tokens).count(MOSAIC_CALL) == 2 * model.cfg.n_layers
+    assert MOSAIC_CALL not in forward.lower(params, tokens).as_text()
 
 
 def test_the_routed_experts_reach_the_kernel_on_a_tpu_and_ragged_dot_elsewhere():

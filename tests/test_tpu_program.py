@@ -42,7 +42,19 @@ DEEPSEEK = dict(vocab_size=256, dim=2048, n_layers=2, n_heads=16, n_kv_heads=16,
                               "beta_fast": 32, "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707})
 # ... and one whole OLMoE layer: 64 experts of width 1024, 8 a token
 OLMOE = dict(OLMOE_ATTENTION, n_experts=64, n_experts_per_token=8, router_renormalize=False)
-CONFIGS = {"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK}
+# Xing4.0-29B-A4B's two kinds of layer at published widths: compressed-query
+# latent attention, 64 sigmoid-routed experts top-4 + 1 shared, four residual
+# streams mixed around every sub-layer
+XING4 = dict(vocab_size=256, dim=3584, n_layers=2, n_heads=32, n_kv_heads=32, ffn_dim=1024,
+             max_seq_len=4096, dtype="bfloat16", n_experts=64, n_experts_per_token=4,
+             router_renormalize=True, routed_scaling_factor=2.0, router_score="sigmoid",
+             router_bias=True, first_dense_layers=1, dense_ffn_dim=9216, n_shared_experts=1,
+             kv_lora_rank=512, q_lora_rank=768, qk_nope_head_dim=128, qk_rope_head_dim=64,
+             v_head_dim=128, norm_eps=1e-6, hc_mult=4,
+             rope_scaling={"type": "yarn", "factor": 64, "original_max_position_embeddings": 4096,
+                           "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1})
+CONFIGS = {"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
+           "xing4": XING4}
 PAGE, POOL_PAGES = 64, 514
 
 
@@ -183,7 +195,8 @@ ENTRY %main.1 (a: s8[2048,2048], s: f32[2048]) -> bf16[2048,2048] {
 
 @pytest.mark.parametrize("config,program", [
     ("mistral", "decode_step"), ("mistral", "prefill_chunk"), ("olmoe", "decode_step"),
-    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk")])
+    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk"),
+    ("xing4", "decode_step"), ("xing4", "prefill_chunk")])
 def test_no_transposed_copy_of_a_weight(v5e, servers, config, program):
     """(DeepSeek: wq is head-split and held output-major like the others'; the
     latent projection, W_UK / W_UV in the order the absorbed products read them,
@@ -193,19 +206,21 @@ def test_no_transposed_copy_of_a_weight(v5e, servers, config, program):
 
     server = servers(config)
     layer = server._params["params"]["layer_0"]
+    latent = config in ("deepseek", "xing4")
     matrices = [leaf for leaf in jax.tree.leaves(
-        server._params["params"] if config == "deepseek" else layer,
+        server._params["params"] if latent else layer,
         is_leaf=lambda x: isinstance(x, QuantizedTensor))
         if isinstance(leaf, QuantizedTensor) and 256 not in leaf.q.shape]   # not the toy vocabulary's
-    # wq wk wv wo w1 w2 w3; DeepSeek: 5 attention + 3 dense, 5 + router + 3 stacks + 3 shared
-    assert len(matrices) == (20 if config == "deepseek" else 7)
+    # wq wk wv wo w1 w2 w3; DeepSeek: 5 attention + 3 dense, 5 + router + 3 stacks + 3 shared;
+    # Xing4.0: wq_a and wq_b (head-split: output-major) in wq's place, 6 + 3, 6 + 7
+    assert len(matrices) == {"deepseek": 20, "xing4": 22}.get(config, 7)
     shapes = {m.q.shape for m in matrices} | {m.q.shape[:-2] + m.q.shape[:-3:-1] for m in matrices}
     hlo = compiled_text(server, program, v5e)
     # the program is the one the chip runs: the q projection's dequant is there
-    held = layer["attention"]["wq"].q.shape
+    held = layer["attention"]["wq_b" if config == "xing4" else "wq"].q.shape
     assert re.search(rf"= bf16\[{held[0]},{held[1]}\]\S* fusion\(", hlo), "no dequant of wq?"
     assert weight_copies(hlo, shapes) == []
-    if config == "deepseek":
+    if latent:
         # nor a copy of the latent pool: with the row at its bare 576 values the
         # gather wanted the pool in another layout than the scatter, two whole-
         # pool copies a layer a call (17.7 ms of a 118 ms chunk, PERF.md section
@@ -216,7 +231,8 @@ def test_no_transposed_copy_of_a_weight(v5e, servers, config, program):
 
 @pytest.mark.parametrize("config,program", [
     ("olmoe_moe", "decode_step"), ("olmoe_moe", "prefill_chunk"),
-    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk")])
+    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk"),
+    ("xing4", "decode_step"), ("xing4", "prefill_chunk")])
 def test_the_routed_experts_run_the_repos_grouped_matmul(v5e, servers, config, program):
     """Three Mosaic kernels of the repo's own an MoE layer (gate, up, down),
     float32 [rows x k, width] out of the int8 stacks; none of XLA's own
@@ -239,3 +255,55 @@ def test_the_routed_experts_run_the_repos_grouped_matmul(v5e, servers, config, p
     assert not re.search(r"%ragged-dot", hlo)
     e, d, f = cfg.n_experts, cfg.dim, cfg.ffn_dim
     assert not re.search(rf"(bf16|f16|f32)\[{e},({d},{f}|{f},{d})\]", hlo)
+
+
+def entry_ops(hlo: str) -> list:
+    """(name, dtype, shape, opcode, scope path) of the entry computation's
+    instructions: the ops a device trace would show as events of their own."""
+    entry = hlo[hlo.index("\nENTRY"):]
+    out = []
+    for line in entry.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            path = re.search(r'op_name="([^"]*)"', line)
+            out.append((m.group(2), m.group(3), tuple(int(n) for n in m.group(4).split(",") if n),
+                        m.group(5), path.group(1) if path else ""))
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_the_streams_stay_narrow_and_the_sinkhorn_chain_is_one_kernel(
+        v5e, servers, program):
+    """Xing4.0's hyper-connections as the chip's compiler leaves them. The four
+    streams [rows, 4, 3584] are bf16 arrays: no op of the program writes them,
+    or the [rows, 14336] vector the maps are read from, in float32 (the mixing
+    widens inside its fusions). A sub-layer's twenty Sinkhorn iterations are
+    ONE op, the repo's kernel (ops/sinkhorn.py), not two reduces an iteration
+    nor a loop of fusions; with the norm, the maps' product and the rest,
+    ``resid.hc.pre`` is under a dozen ops a sub-layer, and ``resid.hc.post`` a
+    fusion and a layout copy."""
+    hlo = compiled_text(servers("xing4"), program, v5e)
+    ops = entry_ops(hlo)
+    rows = 32 if program == "decode_step" else 256
+    # (the routed rows [t, top_k = 4, 3584] are float32 by design: not a stream)
+    wide = [op for op in ops if op[1] == "f32" and "moe.experts" not in op[4]
+            and (op[2][-2:] == (4, 3584) or op[2][-1:] == (4 * 3584,))]
+    assert wide == [], wide[:3]
+    # (a while's result is a tuple, which entry_ops does not read: the text)
+    assert not re.search(r" while\(.*resid\.hc", hlo)
+    for layer in (0, 1):
+        for sub in ("attention_hc", "ffn_hc"):
+            pre = [op for op in ops if f"layer_{layer}/{sub}/resid.hc.pre" in op[4]
+                   and op[3] in ("fusion", "convolution", "copy", "custom-call")]
+            assert 4 <= len(pre) <= 12, (layer, sub, [op[0] for op in pre])
+            chain = [op for op in pre if op[3] == "custom-call"]
+            assert [op[0].split(".")[0] for op in chain] == ["sinkhorn_hc"], chain
+        post = [op for op in ops if f"layer_{layer}/" in op[4] and "resid.hc.post" in op[4]
+                and op[3] in ("fusion", "copy")]
+        assert 2 <= len(post) <= 8, [op[0] for op in post]
+    streams = [op for op in ops if op[2][-2:] == (4, 3584) and rows in op[2]
+               and "moe.experts" not in op[4]]
+    assert streams and all(op[1] == "bf16" for op in streams)
+    # the named scopes the benchmark's readers look for are in the program
+    for scope in ("resid.hc.pre", "resid.hc.post", "attn.latent.q", "attn.latent.read", "moe.route"):
+        assert any(scope in op[4] for op in ops), scope

@@ -234,6 +234,44 @@ def _mla_server():
         return _STATE["mla_server"]
 
 
+# hyper-connections (ISSUE 31): Xing4.0's block at test dims: the same latent
+# MoE with compressed queries, the sigmoid / selection-bias router and several
+# residual streams mixed around every sub-layer (THREE here, not the model's
+# four: MOE_TOP_K is 4, and the routed rows [t, top_k, dim] are float32 by
+# design, which a signature of four streams could not be told from)
+HC_STREAMS = 3
+
+
+def _xing4_server():
+    with _STATE_LOCK:
+        if "xing4_server" not in _STATE:
+            ensure_platform()
+            from seldon_core_tpu.servers.llmserver import LLMServer
+
+            s = LLMServer(
+                model="transformer",
+                model_kwargs=dict(
+                    vocab_size=96, dim=MOE_DIM, n_layers=2, n_heads=MLA_HEADS,
+                    n_kv_heads=MLA_HEADS, ffn_dim=MOE_WIDTH,
+                    max_seq_len=PAGES_PER_SLOT * PAGE_SIZE,
+                    n_experts=MOE_EXPERTS, n_experts_per_token=MOE_TOP_K,
+                    router_renormalize=True, routed_scaling_factor=2.0,
+                    router_score="sigmoid", router_bias=True,
+                    first_dense_layers=1, dense_ffn_dim=96, n_shared_experts=1,
+                    kv_lora_rank=MLA_LATENT, qk_nope_head_dim=MLA_NOPE,
+                    qk_rope_head_dim=LATENT_ROW - MLA_LATENT, v_head_dim=MLA_NOPE,
+                    q_lora_rank=24, hc_mult=HC_STREAMS,
+                    rope_scaling={"type": "yarn", "factor": 64,
+                                  "original_max_position_embeddings": 16,
+                                  "mscale": 1, "mscale_all_dim": 1},
+                    dtype="bfloat16"),
+                quantize="int8", init_random=True, len_buckets=(PLEN,),
+                seed=7)
+            s.load()
+            _STATE["xing4_server"] = s
+        return _STATE["xing4_server"]
+
+
 def _paged_batcher():
     with _STATE_LOCK:  # nests into _base_server's hold: RLock
         if "paged_batcher" not in _STATE:
@@ -329,6 +367,17 @@ MLA_EXPANDED_KV = (
     "absorbed read multiplies the latent rows as they are cached")
 
 
+# the streams are bf16 in HBM: float32 is for the mixing's arithmetic, one
+# stream at a time inside a fusion, and for the [.., n x dim] vector the maps
+# are read from; a float32 [.., n, dim] array is the whole stream widened
+HC_FLOAT32_STREAMS = (
+    rf"tensor<(\d+x)+{HC_STREAMS}x{MOE_DIM}xf32>",
+    "a float32 [.., streams, dim] array: the residual streams are held in the "
+    "model's dtype and widened one stream at a time inside the mixing's "
+    "fusions; the whole stream in float32 is twice its bytes written and "
+    "re-read around every sub-layer")
+
+
 def _pool_specs_of(server):
     import jax
 
@@ -374,6 +423,23 @@ def _build_mla_paged_decode_step():
 
 def _build_mla_prefill_chunk():
     s = _mla_server()
+    fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
+    return fn, (s._params, _pool_specs_of(s),
+                _sds((1, PAGES_PER_SLOT), "int32"),
+                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"))
+
+
+def _build_xing4_paged_decode_step():
+    s = _xing4_server()
+    fn = s._get_decode_step_paged(SLOTS, PAGES_PER_SLOT, 1)
+    return fn, (s._params, _pool_specs_of(s), _sds((SLOTS,), "int32"),
+                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
+                _sds((), "float32"),
+                _sds((SLOTS, PAGES_PER_SLOT), "int32"))
+
+
+def _build_xing4_prefill_chunk():
+    s = _xing4_server()
     fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
@@ -753,6 +819,32 @@ def all_contracts() -> List[Contract]:
             build=_build_mla_prefill_chunk,
             donated=(1,),
             forbid_dtypes=(MLA_EXPANDED_KV, MOE_DENSE_FORM, MOE_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.xing4_paged_decode_step_s4",
+            description="PAGED decode step of a several-stream latent MoE "
+                        "(Xing4.0's block: hyper-connections around both "
+                        "sub-layers, compressed queries, sigmoid scores with "
+                        "a selection bias): the streams stay in the model's "
+                        "dtype, the latents unexpanded, the stacks int8",
+            build=_build_xing4_paged_decode_step,
+            donated=(1, 3, 4),
+            forbid_dtypes=(HC_FLOAT32_STREAMS, MLA_EXPANDED_KV, MOE_DENSE_FORM,
+                           MOE_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.xing4_prefill_chunk_c8",
+            description="chunked admission prefill of the same model",
+            build=_build_xing4_prefill_chunk,
+            donated=(1,),
+            forbid_dtypes=(HC_FLOAT32_STREAMS, MLA_EXPANDED_KV, MOE_DENSE_FORM,
+                           MOE_FLOAT_STACK),
             lowering_platform="tpu",
             collectives={},
             cost=True,
