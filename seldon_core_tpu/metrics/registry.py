@@ -390,7 +390,11 @@ class MetricsRegistry:
                 ("context_tokens",
                  "Cached rows those calls' attention had to read: the live "
                  "context of each row of the call, the row it wrote included "
-                 "(not the block-table view's length)"))}
+                 "(not the block-table view's length)"),
+                ("rows_read",
+                 "Cached rows those calls' attention read visited: the whole "
+                 "block-table view, or whole visits of latent attention's "
+                 "live-page kernel; over context_tokens it is the over-read"))}
         # An MoE model's routing (runtime/batcher.py MoECounters,
         # docs/observability.md "Expert routing"): counted on the loop from
         # arrays that leave the step programs beside their tokens, absent

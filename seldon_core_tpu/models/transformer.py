@@ -647,6 +647,17 @@ def latent_attention_scale(cfg: TransformerConfig) -> float:
     return scale
 
 
+def absorbed_query_rows(q_nope: jnp.ndarray, q_rope: jnp.ndarray, w_uk: jnp.ndarray,
+                        width: int) -> jnp.ndarray:
+    """The query rows of the absorbed read, as wide as a cached row:
+    ``[q~_h ; q^R_h ; zeros]`` with ``q~_h = W_UK,h^T q^N_h``. [b, s, H, width]."""
+    dt = q_nope.dtype
+    q_lat = jnp.einsum("bshn,hnc->bshc", q_nope, w_uk.astype(dt))
+    pad = width - q_lat.shape[-1] - q_rope.shape[-1]
+    return jnp.concatenate(
+        [q_lat, q_rope] + ([jnp.zeros(q_rope.shape[:-1] + (pad,), dt)] if pad else []), axis=-1)
+
+
 def absorbed_latent_attention(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
                               w_uk: jnp.ndarray, w_uv: jnp.ndarray,
                               rows: jnp.ndarray, mask: jnp.ndarray,
@@ -668,19 +679,34 @@ def absorbed_latent_attention(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     One expression for any [b, s]: the decode step (s = 1), a prefill chunk
     over the gathered view (b = 1), the speculative verify and the dense
     cache of generate(). bf16 operands, float32 logits and softmax; masked
-    positions get ``finfo.min`` and contribute exact zeros."""
+    positions get ``finfo.min`` and contribute exact zeros. (The paged pool's
+    read on a TPU walks the live pages instead: ops/latent_attention.py, the
+    same query rows, predicate and W_UV around it.)"""
     dt = q_nope.dtype
     dc = w_uk.shape[-1]
     rows = rows.astype(dt)
-    q_lat = jnp.einsum("bshn,hnc->bshc", q_nope, w_uk.astype(dt))
-    pad = rows.shape[-1] - dc - q_rope.shape[-1]
-    q_cat = jnp.concatenate(
-        [q_lat, q_rope] + ([jnp.zeros(q_rope.shape[:-1] + (pad,), dt)] if pad else []), axis=-1)
+    q_cat = absorbed_query_rows(q_nope, q_rope, w_uk, rows.shape[-1])
     logits = jnp.einsum("bshc,blc->bhsl", q_cat, rows).astype(jnp.float32) * scale
     logits = jnp.where(mask[:, None, :, :], logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(dt)
     ctx = jnp.einsum("bhsl,blc->bshc", probs, rows[..., :dc])
     return jnp.einsum("bshc,hcv->bshv", ctx, w_uv.astype(dt))
+
+
+def latent_read_walk(cfg: "TransformerConfig", s: int, n_pages: int, page_size: int,
+                     pool_dtype) -> Optional[Any]:
+    """How the paged pool's latent read of a call with ``s`` query tokens a
+    sequence is walked by the repo's kernel IN A PROGRAM LOWERED FOR A TPU
+    (ops/latent_attention.py ``Plan``), or None where it is the expression over
+    the whole view there too: no latent attention, a mesh (the kernel is one
+    device's program), a pool in another dtype than the model's, a call shape
+    the kernel does not take. From static facts alone, so ``LatentAttention``
+    and the loop's ``seldon_llm_attn_rows_read_total`` agree by construction."""
+    from seldon_core_tpu.ops.latent_attention import plan
+
+    if not cfg.kv_lora_rank or cfg.mesh is not None or jnp.dtype(pool_dtype) != jnp.dtype(cfg.dtype):
+        return None
+    return plan(s, cfg.n_heads, n_pages, page_size, cfg.latent_row_dim, cfg.kv_lora_rank)
 
 
 def _dense_stack(w, dtype):
@@ -709,8 +735,12 @@ class LatentAttention(nn.Module):
     whole 128-lane tiles, zeros behind) — the same (values...,
     positions) layer shape the batcher's page operations are generic over,
     written and read under the same rules as ``Attention``'s (PAD_POS marks
-    empty rows; unallocated pages redirect to TRASH_PAGE). Without a cache:
-    full causal attention, returns (out, (rows,))."""
+    empty rows; unallocated pages redirect to TRASH_PAGE). The read is one
+    expression over the whole view (``absorbed_latent_attention``); for the
+    paged pool in a program LOWERED for a TPU it is the repo's kernel that
+    walks each sequence's live pages (ops/latent_attention.py), chosen by
+    ``jax.lax.platform_dependent`` as ``MoEFFN`` chooses its grouped matmul.
+    Without a cache: full causal attention, returns (out, (rows,))."""
 
     cfg: TransformerConfig
 
@@ -772,7 +802,7 @@ class LatentAttention(nn.Module):
             if cache is not None and cfg.latent_row_dim > dc + dr:
                 row = jnp.pad(row, ((0, 0), (0, 0), (0, cfg.latent_row_dim - dc - dr)))
             if cache is None:
-                rows, pos_view, new_cache = row, positions, (row,)
+                new_cache = (row,)
             else:
                 pool, pos_pool = cache
                 row = row.astype(pool.dtype)
@@ -797,16 +827,41 @@ class LatentAttention(nn.Module):
                         pos_pool = pos_pool.at[at].set(newpos, mode="drop")
                 new_cache = (pool, pos_pool)
         with jax.named_scope("attn.latent.read"):
+            scale = latent_attention_scale(cfg)
+            w_uk, w_uv = _dense_stack(w_uk, dt), _dense_stack(w_uv, dt)
+
+            def read_expression():
+                if cache is not None and block_tables is not None:
+                    L = bt.shape[1] * pool.shape[1]
+                    rows, pos_view = pool[bt].reshape(b, L, -1), pos_pool[bt].reshape(b, L)
+                elif cache is not None:
+                    rows, pos_view = pool, pos_pool
+                else:
+                    rows, pos_view = row, positions
+                # one predicate for causality, empty rows (PAD_POS) and padding
+                mask = pos_view[:, None, :] <= positions[:, :, None]
+                return absorbed_latent_attention(
+                    q_nope, q_rope, w_uk, w_uv, rows, mask, scale)
+
+            def read_live_pages():
+                from seldon_core_tpu.ops.latent_attention import latent_page_attention
+
+                ctx = latent_page_attention(
+                    absorbed_query_rows(q_nope, q_rope, w_uk, pool.shape[-1]),
+                    pool, pos_pool, bt, positions, scale, dc, walk, interpret=False)
+                return jnp.einsum("bshc,hcv->bshv", ctx, w_uv)
+
+            # the paged pool on one TPU: the repo's kernel walks each
+            # sequence's live pages (ops/latent_attention.py); every other
+            # lowering, a mesh, the dense cache and a call shape the kernel
+            # does not take keep the expression over the whole view
+            walk = None
             if cache is not None and block_tables is not None:
-                L = bt.shape[1] * pool.shape[1]
-                rows, pos_view = pool[bt].reshape(b, L, -1), pos_pool[bt].reshape(b, L)
-            elif cache is not None:
-                rows, pos_view = pool, pos_pool
-            # one predicate for causality, empty rows (PAD_POS) and padding
-            mask = pos_view[:, None, :] <= positions[:, :, None]
-            out = absorbed_latent_attention(
-                q_nope, q_rope, _dense_stack(w_uk, dt), _dense_stack(w_uv, dt),
-                rows, mask, latent_attention_scale(cfg))
+                walk = latent_read_walk(cfg, s, bt.shape[1], pool.shape[1], pool.dtype)
+            if walk is not None:
+                out = jax.lax.platform_dependent(tpu=read_live_pages, default=read_expression)
+            else:
+                out = read_expression()
         return out.reshape(b, s, H * dv) @ wo.astype(dt), new_cache
 
 

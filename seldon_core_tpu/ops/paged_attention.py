@@ -6,7 +6,7 @@ on every backend, the v5e included. This kernel does not lower for a TPU
 compiler's words): the ``(1, page_size)`` position-pool block fails the
 "last two dimensions divisible by 8 and 128" rule, and past that
 ``einsum("hd,phd->hp")`` becomes a ``tpu.dot_dimension_numbers`` with no
-lhs non-contracting dimension. Until ROADMAP A3 rewrites or deletes it, it
+lhs non-contracting dimension. Until ROADMAP A3b rewrites or deletes it, it
 runs only under ``interpret=True`` (its parity test, tests/test_paged_kv.py);
 ``interpret=False`` hands it to the compiler and raises what the compiler
 says.

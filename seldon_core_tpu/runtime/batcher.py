@@ -583,6 +583,10 @@ class LoopPhases:
         # of its rows, written row included), from host integers at dispatch
         self.attn_calls = dict.fromkeys(MOE_PROGRAMS, 0)
         self.attn_context_tokens = dict.fromkeys(MOE_PROGRAMS, 0)
+        # ... and the rows the read VISITED for them (the whole block-table
+        # view, or whole visits of the live-page kernel): visited / context
+        # is the over-read
+        self.attn_rows_read = dict.fromkeys(MOE_PROGRAMS, 0)
         self._open: List[_Phase] = []
         self._turn: Optional[Any] = None   # the open turn's annotation
         self._turn_t0 = 0.0
@@ -628,11 +632,13 @@ class LoopPhases:
                 "slot_seconds": self.slot_seconds,
                 "first_token_reads": dict(self.first_token_reads),
                 "attn_calls": dict(self.attn_calls),
-                "attn_context_tokens": dict(self.attn_context_tokens)}
+                "attn_context_tokens": dict(self.attn_context_tokens),
+                "attn_rows_read": dict(self.attn_rows_read)}
 
-    def count_attention(self, program: str, context_tokens: int) -> None:
+    def count_attention(self, program: str, context_tokens: int, rows_read: int) -> None:
         self.attn_calls[program] += 1
         self.attn_context_tokens[program] += context_tokens
+        self.attn_rows_read[program] += rows_read
 
 
 def _in_phase(name: str):
@@ -1113,6 +1119,7 @@ class ContinuousBatcher:
         self._last_drain_t: Optional[float] = None
         # the loop's time budget (module docstring): always on
         self._phases = LoopPhases()
+        self._read_walks: Dict[int, Any] = {}   # query tokens a call -> how its read walks
         cfg = server._cfg
         self._moe = (MoECounters(cfg.n_experts, cfg.n_moe_layers)
                      if cfg.n_experts > 0 else None)
@@ -1185,18 +1192,19 @@ class ContinuousBatcher:
         self._cache_nbytes = sum(
             int(getattr(leaf, "nbytes", 0)) for leaf in jax.tree.leaves(self._caches)
         )
-        # the read is the XLA gather on every backend (models/
-        # transformer.py; the Pallas page-streaming kernel does not
-        # lower for a TPU) — said here so a server's log names it
+        # the read is the XLA gather of the whole view, except latent
+        # attention's on one TPU, which walks the live pages
+        # (ops/latent_attention.py) — said here so a server's log names it
         from seldon_core_tpu.models.transformer import kv_cache_bytes_per_token
 
         logger.info(
             "paged KV pool: %d pages x %d tokens, %s, %d B a token (%s), "
-            "%.2f GB; decode read: gather", self.pool_pages, self.page_size,
+            "%.2f GB; decode read: %s", self.pool_pages, self.page_size,
             server.kv_cache_dtype,
             kv_cache_bytes_per_token(cfg, server.kv_cache_dtype),
             "latent rows" if cfg.kv_lora_rank else "per-head K/V",
-            self._cache_nbytes / 1e9)
+            self._cache_nbytes / 1e9,
+            "gather" if self._read_walk(1) is None else "live_pages")
 
         # No insert: chunked prefill writes straight into the pool through
         # the slot's block-table row. The device block table (one row per
@@ -2302,7 +2310,7 @@ class ContinuousBatcher:
                 self.server._params, self._caches, job.bt_row,
                 jnp.asarray(toks), jnp.asarray(pos))
         job.next = start + n
-        self._phases.count_attention("chunk", start + n)
+        self._phases.count_attention("chunk", start + n, self._rows_read(C, [start + n], 1))
         event = None
         if self._flight is not None:
             # dispatch wall (enqueue-only)
@@ -2313,6 +2321,35 @@ class ContinuousBatcher:
             job.asides.append((aside, event, start, n))
         if job.next >= job.L:
             self._activate(job, logits, n - 1)
+
+    def _read_walk(self, s: int):
+        """How latent attention's kernel walks the live pages for calls of
+        ``s`` query tokens a sequence (ops/latent_attention.py ``Plan``), or
+        None where the read gathers the whole block-table view: the rule the
+        module itself takes (models/transformer.py ``latent_read_walk``), in
+        a process whose programs are compiled for a TPU."""
+        if s not in self._read_walks:
+            import jax
+
+            from seldon_core_tpu.models.transformer import latent_read_walk
+
+            self._read_walks[s] = None if jax.default_backend() != "tpu" else latent_read_walk(
+                self.server._cfg, s, self.n_pages, self.page_size, self._caches[0][0].dtype)
+        return self._read_walks[s]
+
+    def _rows_read(self, s: int, live_rows: Sequence[int], sequences: int) -> int:
+        """Cached rows the attention read of step-program calls visits, per
+        layer, from host integers: ``sequences`` reads of ``s`` query tokens
+        each, of which those with a live context reach ``live_rows`` rows.
+        The whole block-table view of every sequence, live or not (the
+        gather reads it and the products multiply it); where the kernel walks
+        the live pages, whole visits over the live rows, nothing for the rest."""
+        walk = self._read_walk(s)
+        if walk is None:
+            return sequences * self.n_pages * self.page_size
+        from seldon_core_tpu.ops.latent_attention import rows_visited
+
+        return sum(rows_visited(rows, self.page_size, walk) for rows in live_rows)
 
     def _count_chunks(self, asides: Sequence) -> None:
         """The routing tallies of an admission's chunks. They ran before
@@ -2797,12 +2834,14 @@ class ContinuousBatcher:
             self._next_pos, self._keys, self._temp,
             self._block_tables, *extra)
         snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
-        context = 0
+        context, live = 0, []
         for i, _ in snapshot:
             # micro-step j writes row pos + j and reads rows 0 .. pos + j
-            context += k * (self._slots[i].dispatched_pos() + 1) + k * (k - 1) // 2
+            pos = self._slots[i].dispatched_pos()
+            context += k * (pos + 1) + k * (k - 1) // 2
+            live.extend(pos + 1 + j for j in range(k))
             self._slots[i].disp_new += k
-        self._phases.count_attention("decode", context)
+        self._phases.count_attention("decode", context, self._rows_read(1, live, k * self.S))
         self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside))
         self._count_steps()
         return True

@@ -345,6 +345,24 @@ def test_stream_step_programs_keep_the_streams_in_the_models_dtype(name):
     assert reported == []
 
 
+def test_the_live_page_read_holds_no_gathered_view_for_a_tpu():
+    """ISSUE 32: at dims the kernel of ops/latent_attention.py takes, the step
+    lowered for a TPU has no floating array of the logical view's shape; the
+    SAME contract read from the module lowered for the CPU, where the
+    expression over the gathered view serves, reports it: the scan sees the
+    view when it is there."""
+    import dataclasses
+
+    from tools.hlolint.contracts import all_contracts
+
+    (contract,) = [c for c in all_contracts() if c.name == "llm.mla_live_page_read_s4"]
+    reported, *_ = run_one(contract, checks=("alias", "transfer", "dtype", "collective"))
+    assert reported == []
+    here = dataclasses.replace(contract, lowering_platform=None)
+    reported, *_ = run_one(here, checks=("dtype",))
+    assert len(reported) == 1 and "gathered into a copy of the whole logical view" in reported[0].message
+
+
 def test_stream_scan_sees_a_widened_stream_when_it_is_there():
     from tools.hlolint.contracts import HC_FLOAT32_STREAMS, HC_STREAMS, MOE_DIM, SLOTS
 

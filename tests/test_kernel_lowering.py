@@ -20,6 +20,7 @@ from seldon_core_tpu.models import get_model
 from seldon_core_tpu.models.transformer import init_paged_kv_caches
 from seldon_core_tpu.ops.fused_norm import fused_residual_rmsnorm
 from seldon_core_tpu.ops.grouped_matmul import grouped_matmul, make_visits, row_tile
+from seldon_core_tpu.ops.latent_attention import latent_page_attention, plan
 from seldon_core_tpu.ops.paged_attention import paged_attention
 from seldon_core_tpu.ops.pallas_int8 import int8_matmul
 from seldon_core_tpu.ops.sinkhorn import sinkhorn
@@ -90,6 +91,53 @@ def test_the_routed_experts_reach_the_kernel_on_a_tpu_and_ragged_dot_elsewhere()
     n_layers = model.cfg.n_layers
     assert tpu_mlir(forward, params, tokens).count(MOSAIC_CALL) == 3 * n_layers
     assert MOSAIC_CALL not in forward.lower(params, tokens).as_text()
+
+
+@pytest.mark.parametrize("slots,tokens,heads,pages", [
+    (8, 1, 16, 256), (1, 256, 16, 256), (32, 1, 32, 64), (1, 256, 32, 64), (8, 3, 16, 256)])
+def test_latent_page_attention_lowers_for_tpu(slots, tokens, heads, pages):
+    """The live-page read of latent attention at the two latent cells' step
+    and chunk (DeepSeek-V2-Lite: 8 slots x 16,384 rows, 16 heads; Xing4: 32 x
+    4,096, 32 heads) and at a speculative verify of two drafts: the 640-wide
+    row as the pool holds it, sixteen 64-row pages a visit."""
+    walk = plan(tokens, heads, pages, 64, 640, 512)
+    pool_pages = 2 + 2048
+    text = tpu_mlir(
+        lambda q, pool, pos, tables, positions: latent_page_attention(
+            q, pool, pos, tables, positions, 0.1, 512, walk, interpret=False),
+        S((slots, tokens, heads, 640), jnp.bfloat16), S((pool_pages, 64, 640), jnp.bfloat16),
+        S((pool_pages, 64), jnp.int32), S((slots, pages), jnp.int32), S((slots, tokens), jnp.int32))
+    assert text.count(MOSAIC_CALL) == 1
+
+
+def test_the_latent_read_reaches_the_kernel_on_a_tpu_and_the_expression_elsewhere():
+    """``LatentAttention`` chooses as ``MoEFFN`` does, for the paged pool: one
+    kernel a layer in a program lowered for a TPU, none in any other lowering,
+    none over the dense cache or without one, none for a call shape the
+    kernel does not take (a latent part that is no whole lane tile)."""
+    kwargs = dict(vocab_size=256, dim=64, n_layers=2, ffn_dim=128, max_seq_len=128,
+                  dtype="bfloat16", n_heads=16, n_kv_heads=16, qk_nope_head_dim=16,
+                  qk_rope_head_dim=16, v_head_dim=16)
+    tokens = jnp.zeros((2, 1), jnp.int32)
+
+    def lowerings(**more):
+        model = get_model("transformer", **{**kwargs, "kv_lora_rank": 128, **more})
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+        pools = jax.eval_shape(lambda: init_paged_kv_caches(model.cfg, 6, 64))
+
+        def step(params, pools, tokens, positions, block_tables):
+            return model.apply(params, tokens, positions=positions, caches=pools,
+                               block_tables=block_tables)
+
+        args = (params, pools, S((2, 1), jnp.int32), S((2, 1), jnp.int32), S((2, 2), jnp.int32))
+        plain = jax.jit(lambda params, tokens: model.apply(params, tokens)[0])
+        return (model.cfg, tpu_mlir(step, *args), jax.jit(step).lower(*args).as_text(),
+                tpu_mlir(plain, params, tokens))
+
+    cfg, on_tpu, elsewhere, no_cache = lowerings()
+    assert on_tpu.count(MOSAIC_CALL) == cfg.n_layers
+    assert MOSAIC_CALL not in elsewhere and MOSAIC_CALL not in no_cache
+    assert MOSAIC_CALL not in lowerings(kv_lora_rank=96)[1]
 
 
 def _paged_decode_mlir(**model_kwargs) -> str:

@@ -1,0 +1,298 @@
+"""Latent attention's live-page read (ops/latent_attention.py) under the Pallas
+interpreter, held to ``absorbed_latent_attention`` over the gathered view: the
+expression every lowering that is not for a TPU keeps. (That Mosaic takes the
+kernel at the served shapes is in tests/test_kernel_lowering.py and
+tests/test_tpu_program.py; what it costs on the chip is in PERF.md and
+docs/performance.md.)
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from seldon_core_tpu.models.transformer import (
+    NULL_PAGE, PAD_POS, TRASH_PAGE, absorbed_latent_attention, absorbed_query_rows)
+from seldon_core_tpu.ops.latent_attention import (
+    Plan, latent_page_attention, live_pages, make_visits, plan, rows_visited)
+
+PAGE, DN, DR, DC, DV, WIDTH, SCALE = 32, 32, 16, 128, 32, 256, 0.11
+NOBODY = -1   # a slot nobody holds: its table row is all TRASH_PAGE
+
+
+class Pool:
+    """A paged latent pool filled the way the batcher fills one: sequence i
+    holds ``rows[i]`` rows on pages in no order, each row's position cached
+    beside it; ``shared`` leading pages are the SAME pages in every sequence
+    (a radix-trie prefix); ``allocated`` table entries are backed by pages
+    (those behind the rows are reset: positions PAD_POS)."""
+
+    def __init__(self, rows, n_pages, allocated=None, shared=0, seed=0):
+        rng = np.random.default_rng(seed)
+        b = len(rows)
+        self.n = 2 + b * n_pages
+        self.rows = np.asarray(rng.normal(size=(self.n, PAGE, WIDTH)), np.float32)
+        self.rows[..., DC + DR:] = 0.0
+        self.pos = np.full((self.n, PAGE), PAD_POS, np.int32)
+        self.tables = np.full((b, n_pages), NULL_PAGE, np.int32)
+        free = iter(rng.permutation(np.arange(2, self.n)))
+        prefix = [next(free) for _ in range(shared)]
+        for i, held in enumerate(rows):
+            if held == NOBODY:
+                self.tables[i] = TRASH_PAGE
+                continue
+            backed = max(-(-held // PAGE), (allocated or [0] * b)[i])
+            for j in range(backed):
+                page = prefix[j] if j < shared else next(free)
+                self.tables[i, j] = page
+                n = int(np.clip(held - j * PAGE, 0, PAGE))
+                self.pos[page, :n] = j * PAGE + np.arange(n)
+
+    def arrays(self):
+        return (jnp.asarray(self.rows, jnp.bfloat16), jnp.asarray(self.pos),
+                jnp.asarray(self.tables))
+
+
+def queries(b, s, heads, seed=1):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    bf16 = jnp.bfloat16
+    return (jax.random.normal(keys[0], (b, s, heads, DN), jnp.float32).astype(bf16),
+            jax.random.normal(keys[1], (b, s, heads, DR), jnp.float32).astype(bf16),
+            (jax.random.normal(keys[2], (heads, DN, DC), jnp.float32) * DN ** -0.5).astype(bf16),
+            (jax.random.normal(keys[3], (heads, DC, DV), jnp.float32) * DC ** -0.5).astype(bf16))
+
+
+def by_expression(q, pool, pos_pool, tables, positions):
+    """The whole logical view gathered, then the one expression."""
+    b, n_pages = tables.shape
+    view = pool[tables].reshape(b, n_pages * PAGE, WIDTH)
+    pos_view = pos_pool[tables].reshape(b, n_pages * PAGE)
+    mask = pos_view[:, None, :] <= positions[:, :, None]
+    return absorbed_latent_attention(*q, view, mask, SCALE)
+
+
+def by_kernel(q, pool, pos_pool, tables, positions, walk):
+    q_nope, q_rope, w_uk, w_uv = q
+    ctx = latent_page_attention(
+        absorbed_query_rows(q_nope, q_rope, w_uk, WIDTH), pool, pos_pool, tables, positions,
+        SCALE, DC, walk, interpret=True)
+    return jnp.einsum("bshc,hcv->bshv", ctx, w_uv)
+
+
+def last_positions(rows, s):
+    """Each sequence's queries are its last ``s`` rows (a step: the row just
+    written), PAD_POS where it has fewer, 0 for a slot nobody holds."""
+    out = np.full((len(rows), s), PAD_POS, np.int32)
+    for i, held in enumerate(rows):
+        n = min(s, max(held, 0))
+        out[i, :n] = np.arange(held - n, held)
+        if held == NOBODY:
+            out[i] = 0
+    return jnp.asarray(out)
+
+
+CASES = {
+    # name: (heads, query tokens, rows each sequence holds, table entries, pool kwargs, walk)
+    "decode step, 16 heads, a visit of four pages": (16, 1, [100, 37, 1, 380], 12, {}, Plan(4, 16)),
+    "decode step, 32 heads": (32, 1, [100, 37, 1, 380], 12, {}, Plan(4, 32)),
+    "decode step by the rule's walk": (16, 1, [1500, 640, 2040], 64, {}, None),
+    "prefill chunk, 16 heads, four query tiles": (16, 16, [300], 12, {}, Plan(4, 64)),
+    "prefill chunk, 32 heads": (32, 16, [300], 12, {}, Plan(4, 128)),
+    "prefill chunk by the rule's walk": (16, 64, [1100], 40, {}, None),
+    "a prompt's last chunk: PAD_POS behind its tokens": (16, 16, [7], 12, {}, Plan(2, 64)),
+    "speculative verify, 16 heads": (16, 3, [100, 37, 2, 380], 12, {}, Plan(4, 48)),
+    "speculative verify, 32 heads": (32, 5, [100, 37, 380], 12, {}, Plan(4, 160)),
+    "a half-filled last page and a full one": (16, 1, [PAGE * 3 + 1, PAGE * 4], 12, {}, Plan(2, 16)),
+    "pages allocated ahead of the rows (PAD_POS rows)": (
+        16, 1, [50, 200], 12, dict(allocated=[6, 12]), Plan(4, 16)),
+    "a slot nobody holds between two that decode": (16, 1, [90, NOBODY, 260], 12, {}, Plan(4, 16)),
+    "nobody holds any slot": (16, 1, [NOBODY, NOBODY], 12, {}, Plan(4, 16)),
+    "trie-shared leading pages": (16, 1, [200, 170, 330], 12, dict(shared=5), Plan(4, 16)),
+    "table entries no visit divides": (16, 1, [100, 210], 7, {}, Plan(4, 16)),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_live_page_read_is_the_expression_over_the_gathered_view(case):
+    heads, s, rows, n_pages, pool_kwargs, walk = CASES[case]
+    walk = walk or plan(s, heads, n_pages, PAGE, WIDTH, DC)
+    pool, pos_pool, tables = Pool(rows, n_pages, **pool_kwargs).arrays()
+    positions = last_positions(rows, s)
+    q = queries(len(rows), s, heads)
+    want = np.asarray(by_expression(q, pool, pos_pool, tables, positions), np.float32)
+    got = np.asarray(by_kernel(q, pool, pos_pool, tables, positions, walk), np.float32)
+    visits = int(make_visits(tables, live_pages(tables, positions, PAGE), walk).count)
+    assert np.all(np.isfinite(got))
+    valid = np.asarray((positions < PAD_POS) & (tables[:, :1] != TRASH_PAGE))
+    np.testing.assert_allclose(got[valid], want[valid], atol=2e-2, rtol=2e-2)
+    # a slot with no valid query makes one visit, reads nothing, writes zeros
+    for i, held in enumerate(rows):
+        if held == NOBODY:
+            assert np.all(got[i] == 0.0)
+    per_visit = walk.pages * PAGE
+    assert visits == sum(max(-(-max(held, 0) // per_visit), 1) for held in rows)
+    assert visits * per_visit == sum(rows_visited(max(held, 0), PAGE, walk) for held in rows)
+
+
+@pytest.mark.parametrize("s,heads", [(1, 16), (16, 16), (3, 32)])
+def test_pages_behind_the_live_ones_are_never_read(s, heads):
+    """Whatever lies on a sequence's pages behind its queries' largest
+    position (pages allocated ahead, the rest of a longer table) changes
+    nothing, NaN included: they are not fetched."""
+    rows, n_pages, walk = [70, 200], 12, Plan(4, s * heads)
+    state = Pool(rows, n_pages, allocated=[9, 12])
+    q = queries(len(rows), s, heads)
+    positions = last_positions(rows, s)
+    clean = by_kernel(q, *state.arrays(), positions, walk)
+    for i, held in enumerate(rows):
+        for page in state.tables[i, -(-held // PAGE):]:
+            if page != NULL_PAGE:
+                state.rows[page] = np.nan
+    state.rows[TRASH_PAGE] = np.nan
+    dirty = by_kernel(q, *state.arrays(), positions, walk)
+    assert np.all(np.isfinite(np.asarray(dirty, np.float32)))
+    np.testing.assert_array_equal(np.asarray(clean, np.float32), np.asarray(dirty, np.float32))
+
+
+def test_the_visit_list():
+    """Three sequences over tables of ten entries, four a visit: one with
+    five live pages visits two groups, one nobody holds visits one (and
+    fetches NULL_PAGE alone), one with ten visits all three; entries behind
+    the live pages read as NULL_PAGE."""
+    tables = jnp.asarray(np.arange(2, 32).reshape(3, 10), jnp.int32).at[1].set(TRASH_PAGE)
+    positions = jnp.asarray([[PAGE * 4 + 3], [0], [PAGE * 10 - 1]], jnp.int32)
+    live = live_pages(tables, positions, PAGE)
+    assert live.tolist() == [5, 0, 10]
+    visits = make_visits(tables, live, Plan(4, 16))
+    n = int(visits.count)
+    assert n == 6
+    assert visits.seq[:n].tolist() == [0, 0, 1, 2, 2, 2]
+    assert visits.group[:n].tolist() == [0, 1, 0, 0, 1, 2]
+    assert visits.last[:n].tolist() == [0, 1, 1, 0, 0, 1]
+    table = np.asarray(visits.table).reshape(3, 12)
+    assert table[0].tolist() == [2, 3, 4, 5, 6] + [NULL_PAGE] * 7
+    assert table[1].tolist() == [NULL_PAGE] * 12
+    assert table[2].tolist() == list(range(22, 32)) + [NULL_PAGE] * 2
+    # padding and a position past the table count for nothing, a negative one neither
+    padded = jnp.asarray([[3, PAD_POS], [-1, PAD_POS], [PAGE * 40, 5]], jnp.int32)
+    assert live_pages(tables.at[1].set(7), padded, PAGE).tolist() == [1, 0, 10]
+
+
+def test_the_walk_at_the_served_shapes():
+    """A step's or a verify's query rows are one tile over 1,024 rows a visit
+    (sixteen 64-row pages); a chunk's are tiles of 512 over 512 rows a visit;
+    a shape the kernel does not take has no walk (the caller keeps the
+    expression)."""
+    for heads, slots_pages in ((16, 256), (32, 64)):
+        assert plan(1, heads, slots_pages, 64, 640, 512) == Plan(16, heads)
+        assert plan(256, heads, slots_pages, 64, 640, 512) == Plan(8, 512)
+        assert plan(128, heads, slots_pages, 64, 640, 512) == Plan(8, 512)
+        assert plan(3, heads, slots_pages, 64, 640, 512) == Plan(16, 3 * heads)
+    assert plan(1, 16, 5, 64, 640, 512) == Plan(6, 16)    # a short table: whole lane tiles
+    assert plan(1, 16, 256, 64, 576, 512) is None          # a row that is no whole lane tile
+    assert plan(1, 16, 256, 64, 640, 448) is None
+    assert plan(1, 16, 256, 16, 640, 512) is None          # a visit of 64 pages
+    assert plan(1, 2, 256, 64, 640, 512) is None           # two query rows
+    assert plan(40, 16, 256, 64, 640, 512) is None         # 640 query rows: no whole tiles
+    assert rows_visited(0, 64, Plan(16, 16)) == 1024 == rows_visited(1024, 64, Plan(16, 16))
+    assert rows_visited(1025, 64, Plan(16, 16)) == 2048
+
+
+LATENT_TOY = dict(vocab_size=96, dim=64, n_layers=2, n_heads=16, n_kv_heads=16, ffn_dim=64,
+                  max_seq_len=256, kv_lora_rank=128, qk_nope_head_dim=16, qk_rope_head_dim=16,
+                  v_head_dim=16, dtype="bfloat16")
+
+
+def test_latent_attention_through_the_kernel_is_latent_attention_through_the_expression(monkeypatch):
+    """``LatentAttention`` picks by the lowering platform (the kernel for a
+    TPU, the expression elsewhere). Here the TPU's branch is taken by hand,
+    its kernel under the interpreter, through a chunk of a prompt and two
+    decode steps of the paged pool: the same logits as the branch tier-1
+    otherwise runs, and the same pool."""
+    import seldon_core_tpu.ops.latent_attention as module
+    from seldon_core_tpu.models import get_model
+    from seldon_core_tpu.models.transformer import init_paged_kv_caches
+
+    model = get_model("transformer", **LATENT_TOY)
+    cfg = model.cfg
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 16), 0, cfg.vocab_size)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :4])
+    tables = jnp.asarray([[5, 2, 7, NULL_PAGE], [TRASH_PAGE] * 4], jnp.int32)
+
+    def serve():
+        pools = init_paged_kv_caches(cfg, 8, 32)
+        out = []
+        positions = jnp.stack([jnp.arange(16), jnp.full((16,), PAD_POS)]).astype(jnp.int32)
+        logits, pools = model.apply(params, tokens, positions=positions, caches=pools,
+                                    block_tables=tables)
+        out.append(logits[0])
+        for step in range(2):
+            positions = jnp.asarray([[16 + step], [0]], jnp.int32)
+            logits, pools = model.apply(params, tokens[:, step:step + 1], positions=positions,
+                                        caches=pools, block_tables=tables)
+            out.append(logits[0])
+        return np.concatenate([np.asarray(x, np.float32) for x in out]), pools
+
+    want, want_pools = serve()
+    kernel, calls = module.latent_page_attention, []
+
+    def interpreted(*args, interpret, **kw):
+        calls.append(args[0].shape)
+        return kernel(*args, interpret=True, **kw)
+
+    monkeypatch.setattr(module, "latent_page_attention", interpreted)
+    monkeypatch.setattr(jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
+    got, got_pools = serve()
+    assert len(calls) == 3 * cfg.n_layers
+    np.testing.assert_allclose(got, want, atol=3e-2, rtol=3e-2)
+    for (rows, pos), (want_rows, want_pos) in zip(got_pools, want_pools):
+        np.testing.assert_array_equal(np.asarray(pos), np.asarray(want_pos))
+        np.testing.assert_allclose(np.asarray(rows[2:], np.float32),
+                                   np.asarray(want_rows[2:], np.float32), atol=3e-2, rtol=3e-2)
+
+
+def test_the_loop_counts_the_rows_the_read_visited(monkeypatch):
+    """``seldon_llm_attn_rows_read_total``: the whole block-table view of every
+    sequence of a call where the expression serves (every lowering that is not
+    for a TPU, a model without latent attention, a mesh), whole visits over the
+    live rows where the kernel does, by the rule the module itself takes."""
+    from types import SimpleNamespace
+
+    from seldon_core_tpu.models.transformer import TransformerConfig, latent_read_walk
+    from seldon_core_tpu.runtime.batcher import ContinuousBatcher, LoopPhases
+
+    cfg = TransformerConfig(**{**LATENT_TOY, "kv_lora_rank": 512, "qk_rope_head_dim": 64})
+    assert cfg.latent_row_dim == 640
+
+    def loop(cfg):
+        return SimpleNamespace(server=SimpleNamespace(_cfg=cfg), n_pages=256, page_size=64,
+                               _caches=[(jnp.zeros((1,), jnp.bfloat16),)], _read_walks={})
+
+    def rows_read(loop, *args):
+        loop._read_walk = lambda s: ContinuousBatcher._read_walk(loop, s)
+        return ContinuousBatcher._rows_read(loop, *args)
+
+    view = 256 * 64
+    assert rows_read(loop(cfg), 1, [12000, 9000], 8) == 8 * view        # here: the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert latent_read_walk(cfg, 1, 256, 64, jnp.bfloat16) == Plan(16, 16)
+    assert rows_read(loop(cfg), 1, [12000, 9000], 8) == 12 * 1024 + 9 * 1024
+    assert rows_read(loop(cfg), 256, [6250], 1) == 13 * 512
+    assert rows_read(loop(cfg), 40, [6250], 1) == view                   # no walk for 640 query rows
+    for other in (dict(kv_lora_rank=0), dict(mesh=object()), dict(dtype="float32")):
+        assert rows_read(loop(dataclasses.replace(cfg, **other)), 1, [12000], 8) == 8 * view
+    phases = LoopPhases()
+    phases.count_attention("decode", 21000, 21 * 1024)
+    assert phases.stats()["attn_rows_read"] == {"chunk": 0, "decode": 21 * 1024}
+    # ... and leaves with the loop's other tallies: llm_stats -> sync_llm -> /metrics
+    from seldon_core_tpu.metrics.registry import MetricsRegistry
+
+    registry = MetricsRegistry()
+    registry.sync_llm(SimpleNamespace(llm_stats=phases.stats))
+    text = registry.expose().decode()
+    assert 'seldon_llm_attn_rows_read_total{' in text
+    (line,) = [ln for ln in text.splitlines()
+               if ln.startswith("seldon_llm_attn_rows_read_total{") and 'program="decode"' in ln]
+    assert float(line.rsplit(" ", 1)[1]) == 21 * 1024

@@ -331,6 +331,10 @@ def test_loop_counts_moe_layers_and_attention_context(served):
     assert steps >= max(len(o) for o in outs) - 1
     least = sum(len(p) + j + 1 for p, o in zip(prompts, outs) for j in range(len(o) - 1))
     assert least <= loop["attn_context_tokens"]["decode"] <= least + 2 * steps * 64
+    # ... and what the read visited for it: here (no TPU) the whole block-table
+    # view of every sequence of every call, live or not
+    view = batcher.n_pages * batcher.page_size
+    assert loop["attn_rows_read"] == {"chunk": 4 * view, "decode": steps * batcher.S * view}
 
 
 # -------------------------------------------- what is not built is refused by name

@@ -105,7 +105,11 @@ def servers():
     return get
 
 
-def compiled_text(server, program: str, sharding) -> str:
+def compiled(server, program: str, sharding, slots: int = 32, length: int = 0):
+    """The batcher's step program compiled for the described chip. By default
+    the chat cell's step (32 slots x 1024 tokens) and the docs cell's chunk
+    (256 tokens into a 4096-token slot) over a pool of POOL_PAGES; with
+    ``length``, ``slots`` slots of that many tokens over a fully provisioned pool."""
     from seldon_core_tpu.models.transformer import init_paged_kv_caches
 
     def sds(shape, dtype):
@@ -114,20 +118,24 @@ def compiled_text(server, program: str, sharding) -> str:
     def abstract(tree):
         return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
 
+    pages = (length or (1024 if program == "decode_step" else 4096)) // PAGE
     params = abstract(server._params)
-    pools = abstract(jax.eval_shape(
-        lambda: init_paged_kv_caches(server._cfg, POOL_PAGES, PAGE, "bf16")))
-    if program == "decode_step":  # the chat cell's: 32 slots x 1024 tokens
-        slots, pages = 32, 1024 // PAGE
+    pools = abstract(jax.eval_shape(lambda: init_paged_kv_caches(
+        server._cfg, slots * pages + 2 if length else POOL_PAGES, PAGE, "bf16")))
+    if program == "decode_step":
         lowered = server._get_decode_step_paged(slots, pages, 1).lower(
             params, pools, sds((slots,), "int32"), sds((slots,), "int32"),
             sds((slots, 2), "uint32"), sds((), "float32"), sds((slots, pages), "int32"))
-    else:                         # the docs cell's: 256 tokens into a 4096-token slot
-        chunk, pages = 256, 4096 // PAGE
+    else:
+        chunk = 256
         lowered = server._get_prefill_chunk(chunk, pages).lower(
             params, pools, sds((1, pages), "int32"), sds((1, chunk), "int32"),
             sds((1, chunk), "int32"))
-    return lowered.compile().as_text()
+    return lowered.compile()
+
+
+def compiled_text(server, program: str, sharding) -> str:
+    return compiled(server, program, sharding).as_text()
 
 
 _HEAD = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->\s+.*\{\s*$")
@@ -307,3 +315,43 @@ def test_the_streams_stay_narrow_and_the_sinkhorn_chain_is_one_kernel(
     # the named scopes the benchmark's readers look for are in the program
     for scope in ("resid.hc.pre", "resid.hc.post", "attn.latent.q", "attn.latent.read", "moe.route"):
         assert any(scope in op[4] for op in ops), scope
+
+
+# the two latent cells' servers (PERF.md section 4): slots x tokens a slot
+LATENT_CELLS = {"deepseek": (8, 16384), "xing4": (32, 4096)}
+
+
+@pytest.mark.parametrize("config,program", [
+    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk"),
+    ("xing4", "decode_step"), ("xing4", "prefill_chunk")])
+def test_the_latent_read_walks_the_live_pages_and_holds_no_view(v5e, servers, config, program):
+    """At the cells' own shapes the read under ``attn.latent.read`` is ONE
+    Mosaic kernel of the repo's a layer (ops/latent_attention.py), fed the pool
+    as it is held, sixteen pages a visit (eight under a chunk); the program holds no gathered copy of
+    the logical view (``fusion bf16[2048,64,640]``: 168 MB a layer, the largest
+    op of both cells' steps in PR 31's traces), in either shape, and its step
+    needs less scratch memory than one such view."""
+    from seldon_core_tpu.ops.latent_attention import KERNEL_NAME
+
+    server = servers(config)
+    cfg = server._cfg
+    slots, length = LATENT_CELLS[config]
+    pages = length // PAGE
+    exe = compiled(server, program, v5e, slots=slots, length=length)
+    hlo = exe.as_text()
+    calls = [line for line in hlo.splitlines() if re.match(rf"\s*%{KERNEL_NAME}[\w.]* = ", line)]
+    assert len(calls) == cfg.n_layers
+    assert all('custom_call_target="tpu_custom_call"' in line for line in calls)
+    assert all("attn.latent.read" in line for line in calls)
+    sequences = slots if program == "decode_step" else 1
+    rows = 1 if program == "decode_step" else 256
+    assert all(f"= bf16[{sequences},{rows * cfg.n_heads},512]" in line for line in calls)
+    # a visit's pages are operands of the pool itself, not of a copy
+    pool = f"bf16[{slots * pages + 2},{PAGE},640]"
+    visit = 16 if program == "decode_step" else 8
+    assert all(line.count(pool) == visit for line in calls), calls[0][:400]
+    for view in (f"[{sequences * pages},{PAGE},640]", f"[{sequences},{pages},{PAGE},640]",
+                 f"[{sequences},{pages * PAGE},640]"):
+        assert f"bf16{view}" not in hlo and f"f32{view}" not in hlo, view
+    if program == "decode_step":
+        assert exe.memory_analysis().temp_size_in_bytes < sequences * pages * PAGE * 640 * 2

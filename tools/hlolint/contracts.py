@@ -367,6 +367,49 @@ MLA_EXPANDED_KV = (
     "absorbed read multiplies the latent rows as they are cached")
 
 
+# ... and, for a TPU, the paged pool's read holds no gathered copy of the
+# logical view either (ISSUE 32): the kernel of ops/latent_attention.py takes
+# the pool as it is held. At dims the kernel takes (a latent part and a row
+# of whole 128-lane tiles, 64-row pages; the contracts above are under them
+# and keep the expression): a floating array of the view's rows a slot
+LIVE_READ_LATENT, LIVE_READ_ROW, LIVE_READ_PAGE, LIVE_READ_PAGES = 128, 256, 64, 4
+MLA_GATHERED_VIEW = (
+    rf"tensor<({SLOTS}x{LIVE_READ_PAGES * LIVE_READ_PAGE}|{SLOTS * LIVE_READ_PAGES}x{LIVE_READ_PAGE}"
+    rf"|{SLOTS}x{LIVE_READ_PAGES}x{LIVE_READ_PAGE})x{LIVE_READ_ROW}x(bf16|f16|f32)>",
+    "a floating [slots, view rows, row] array: the paged latent pool is being "
+    "gathered into a copy of the whole logical view (168 MB a layer at the "
+    "served shapes, read twice more by the products) where the live-page "
+    "kernel reads the pool's pages in place")
+
+
+def _build_mla_live_page_read():
+    """One decode step of a latent-attention block over the paged pool, at
+    the smallest dims the live-page kernel takes."""
+    ensure_platform()
+    import jax
+
+    from seldon_core_tpu.models import get_model
+    from seldon_core_tpu.models.transformer import init_paged_kv_caches
+
+    model = get_model(
+        "transformer", vocab_size=96, dim=MOE_DIM, n_layers=1, n_heads=16, n_kv_heads=16,
+        ffn_dim=MOE_WIDTH, max_seq_len=LIVE_READ_PAGES * LIVE_READ_PAGE,
+        kv_lora_rank=LIVE_READ_LATENT, qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16,
+        dtype="bfloat16")
+    assert model.cfg.latent_row_dim == LIVE_READ_ROW
+    tokens = _sds((SLOTS, 1), "int32")
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    pools = jax.eval_shape(lambda: init_paged_kv_caches(
+        model.cfg, 2 + SLOTS * LIVE_READ_PAGES, LIVE_READ_PAGE, "bf16"))
+
+    def step(params, pools, tokens, positions, block_tables):
+        return model.apply(params, tokens, positions=positions, caches=pools,
+                           block_tables=block_tables)
+
+    return jax.jit(step, donate_argnums=(1,)), (
+        params, pools, tokens, tokens, _sds((SLOTS, LIVE_READ_PAGES), "int32"))
+
+
 # the streams are bf16 in HBM: float32 is for the mixing's arithmetic, one
 # stream at a time inside a fusion, and for the [.., n x dim] vector the maps
 # are read from; a float32 [.., n, dim] array is the whole stream widened
@@ -822,6 +865,18 @@ def all_contracts() -> List[Contract]:
             lowering_platform="tpu",
             collectives={},
             cost=True,
+        ),
+        Contract(
+            name="llm.mla_live_page_read_s4",
+            description="PAGED decode step of a latent-attention block at "
+                        "dims the live-page kernel takes: lowered for a TPU "
+                        "the read walks the pool's pages in place, no "
+                        "gathered copy of the logical view",
+            build=_build_mla_live_page_read,
+            donated=(1,),
+            forbid_dtypes=(MLA_GATHERED_VIEW,),
+            lowering_platform="tpu",
+            collectives={},
         ),
         Contract(
             name="llm.xing4_paged_decode_step_s4",
