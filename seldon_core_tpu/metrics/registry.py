@@ -374,6 +374,31 @@ class MetricsRegistry:
             base,
             registry=self.registry,
         )
+        # below the phases, their parts (LoopPhases.part / .handoff): seconds
+        # that ALSO stand in their phase's total, so these never sum with
+        # seldon_llm_loop_seconds_total. hop's four parts are the exception
+        # that proves the budget: they add up to phase="hop"
+        self._loop_part_seconds = Counter(
+            "seldon_llm_loop_part_seconds_total",
+            "Batcher loop wall seconds by part of a phase (part="
+            "\"<phase>.<part>\"; inside the phase's own seconds, not beside "
+            "them)",
+            base + ["part"],
+            registry=self.registry,
+        )
+        self._loop_part = Counter(
+            "seldon_llm_loop_part_total",
+            "Occurrences of each part of a batcher loop phase",
+            base + ["part"],
+            registry=self.registry,
+        )
+        self._loop_handoffs = Counter(
+            "seldon_llm_loop_handoffs_total",
+            "Hand-offs of the batcher loop to a worker thread "
+            "(asyncio.to_thread out and back)",
+            base,
+            registry=self.registry,
+        )
         self._first_token_reads = Counter(
             "seldon_llm_first_token_reads_total",
             "Reads of a prompt's first token off the drain pipeline, by "
@@ -458,6 +483,24 @@ class MetricsRegistry:
             registry=self.registry,
         )
         self._emit_delay_bound: Any = None
+        # ... and the transport thread's busy time (transport/rest.py
+        # http_busy): it shares the GIL with the batcher's loop and workers,
+        # so its synchronous stretches are time they may wait for
+        self._http_busy_seconds = Counter(
+            "seldon_http_busy_seconds_total",
+            "Wall seconds the transport thread spent in its synchronous "
+            "stretches, by what (parse, sse_write, reply, scrape)",
+            base + ["what"],
+            registry=self.registry,
+        )
+        self._http_busy = Counter(
+            "seldon_http_busy_total",
+            "Occurrences of those stretches (one per request, SSE event, "
+            "reply, scrape)",
+            base + ["what"],
+            registry=self.registry,
+        )
+        self._http_busy_bound: Dict[str, tuple] = {}
         # Speculative decoding (runtime/batcher.py + runtime/spec.py): the
         # accept rate and tokens-per-forward pair is the whole story —
         # tokens/forward > 1 is the >1-accepted-token-per-KV-read
@@ -848,6 +891,17 @@ class MetricsRegistry:
             self._emit_delay_bound = self._emit_delay.labels(**self._base())
         self._emit_delay_bound.observe(seconds)
 
+    def observe_http_busy(self, what: str, seconds: float) -> None:
+        """Once per synchronous stretch of the transport thread; the labelled
+        children are bound once per ``what``, as the emit delay's is."""
+        bound = self._http_busy_bound.get(what)
+        if bound is None:
+            bound = self._http_busy_bound[what] = (
+                self._http_busy_seconds.labels(**self._base(), what=what),
+                self._http_busy.labels(**self._base(), what=what))
+        bound[0].inc(seconds)
+        bound[1].inc()
+
     def sync_controlplane(self, source: Any = None) -> None:
         """Refresh autoscaler / canary / shadow series at scrape time.
         ``source`` is an engine (its graph nodes are walked for canary and
@@ -987,6 +1041,12 @@ class MetricsRegistry:
         for phase, n in stats.get("loop_phase_counts", {}).items():
             self._counter_catch_up(self._loop_phase, n, phase=phase)
         self._counter_catch_up(self._loop_turns, stats.get("loop_turns", 0))
+        for part, seconds in stats.get("loop_part_seconds", {}).items():
+            self._counter_catch_up(self._loop_part_seconds, seconds, part=part)
+        for part, n in stats.get("loop_part_counts", {}).items():
+            self._counter_catch_up(self._loop_part, n, part=part)
+        self._counter_catch_up(self._loop_handoffs,
+                               stats.get("loop_handoffs", 0))
         for ready, n in stats.get("first_token_reads", {}).items():
             self._counter_catch_up(self._first_token_reads, n, ready=ready)
         for key, counter in self._attn_context.items():

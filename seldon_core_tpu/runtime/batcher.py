@@ -122,6 +122,15 @@ phase that covers them on the profiler's own clock, and adds its
 ``perf_counter`` wall to a per-phase accumulator that ``llm_stats`` exports
 (``seldon_llm_loop_seconds_total{phase}``). Always on: an inactive
 annotation and two clock reads per phase against a turn of milliseconds.
+Since PR 33 a second level, parts, names what a phase is made of without
+taking anything from it (``llm.<phase>.<part>``,
+``seldon_llm_loop_part_seconds_total{part}``: dispatch's page growth, jitted
+call and booking, the drain's reads beside the tokens, emit's slot loop and
+finishes, a chunk's build, call and activation), and ``hop`` is measured, not
+subtracted: every ``to_thread`` of the loop is stamped at submission, the
+worker's entry and exit, and resumption (``LoopPhases.handoff``), so the
+two waits for a thread, the worker's own Python and the loop coroutine's
+own code add up to it.
 
 Paged KV cache (PR 7): the slots' KV lives in a GLOBAL pool of
 fixed-size KV pages plus a device-resident per-slot block table — the
@@ -533,30 +542,91 @@ LOOP_PHASES = ("admit", "handoff", "dispatch", "prefill", "first_token_wait",
 
 
 class _Phase:
-    """One open phase: a context manager that times itself on
-    ``time.perf_counter`` and shows in a profiler trace as ``llm.<name>``.
-    ``t0``/``t1``/``seconds`` stay readable after exit, so a site that
-    needs its own timestamps takes them from here: one clock pair a site."""
+    """One open phase, or one open part of a phase: a context manager that
+    times itself on the owner's clock (``time.perf_counter``) and shows in a
+    profiler trace as ``llm.<name>``. Opened inside another of its level it
+    takes its time out of the outer one (``stack`` is that level's open
+    spans; ``book(name, own seconds)`` the owner's accumulator for it).
+    ``t0``/``t1``/``seconds`` stay readable after exit, so a site that needs
+    its own timestamps takes them from here: one clock pair a site."""
 
-    __slots__ = ("owner", "name", "t0", "t1", "seconds", "nested", "_ann")
+    __slots__ = ("owner", "name", "t0", "t1", "seconds", "nested", "_ann",
+                 "_stack", "_book")
 
-    def __init__(self, owner: "LoopPhases", name: str):
+    def __init__(self, owner: "LoopPhases", name: str, stack: list, book):
         self.owner = owner
         self.name = name
         self.nested = 0.0
+        self._stack, self._book = stack, book
 
     def __enter__(self) -> "_Phase":
         self._ann = self.owner._annotation("llm." + self.name)
         self._ann.__enter__()
-        self.owner._open.append(self)
-        self.t0 = time.perf_counter()
+        self._stack.append(self)
+        self.t0 = self.owner._clock()
         return self
 
     def __exit__(self, *exc) -> None:
-        self.t1 = time.perf_counter()
+        self.t1 = self.owner._clock()
         self.seconds = self.t1 - self.t0
-        self.owner._close(self)
+        self._stack.pop()       # self: spans close innermost first
+        self._book(self.name, self.seconds - self.nested)
+        if self._stack:
+            self._stack[-1].nested += self.seconds
         self._ann.__exit__(*exc)
+
+
+HOP_PARTS = ("hop.wake_worker", "hop.wake_loop", "hop.worker", "hop.loop")
+
+
+class _Handoff:
+    """``fn(*args)`` on its way through ``asyncio.to_thread``, with the
+    hand-off's four stamps on the owner's one clock: submission (here, on
+    the loop thread) and resumption (``resumed``), the worker's entry and
+    exit around ``fn`` (``__call__``). ``hop.wake_worker`` = entry -
+    submission and ``hop.wake_loop`` = resumption - exit are the two waits
+    for a thread to be woken, each also a span (``llm.hop.wake_worker`` /
+    ``llm.hop.wake_loop``: the profiler pairs an annotation entered on one
+    thread and left on another); ``hop.worker`` = exit - entry less the
+    phases ``fn`` opened (it may open several, or none): the worker's own
+    Python outside every phase."""
+
+    __slots__ = ("owner", "fn", "args", "t_submit", "t_in", "t_out",
+                 "in_phases", "_leg")
+
+    def __init__(self, owner: "LoopPhases", fn, args: tuple):
+        self.owner, self.fn, self.args = owner, fn, args
+        self.t_in: Optional[float] = None
+        self.t_submit = owner._clock()
+        owner._leave_own(self.t_submit)
+        self._leg = owner._annotation("llm.hop.wake_worker")
+        self._leg.__enter__()
+
+    def __call__(self):
+        owner = self.owner
+        self.t_in = owner._clock()
+        self._leg.__exit__(None, None, None)
+        before = owner._turn_phases
+        try:
+            return self.fn(*self.args)
+        finally:
+            self._leg = owner._annotation("llm.hop.wake_loop")
+            self.in_phases = owner._turn_phases - before
+            self.t_out = owner._clock()
+            self._leg.__enter__()
+
+    def resumed(self) -> None:
+        owner = self.owner
+        t_back = owner._clock()
+        if self.t_in is not None:   # else the await was cancelled before fn ran
+            self._leg.__exit__(None, None, None)
+            owner.handoffs += 1
+            owner._add_part("hop.wake_worker", self.t_in - self.t_submit)
+            owner._add_part("hop.worker",
+                            max(self.t_out - self.t_in - self.in_phases, 0.0))
+            owner._add_part("hop.wake_loop", t_back - self.t_out)
+        if owner._turn is not None:
+            owner._enter_own(t_back)
 
 
 class LoopPhases:
@@ -566,7 +636,18 @@ class LoopPhases:
     time), so there is no lock. A phase opened inside another takes its
     time out of the outer one (``drain_wait`` inside ``emit``): every
     second belongs to the innermost phase, and the phases of a turn plus its ``hop`` ARE the turn's wall.
-    Readers (``stats()`` at a scrape) may be one phase behind."""
+    Readers (``stats()`` at a scrape) may be one phase behind.
+
+    Below the phases, parts (``part``; docs/observability.md "Loop phases"
+    has the table): a named stretch inside a phase whose seconds are ALSO
+    counted in ``part_seconds``, the phase's own total untouched. ``hop``'s
+    parts are measured where it happens: ``handoff`` stamps each
+    ``asyncio.to_thread`` of the loop at submission, the worker's entry and
+    exit, and resumption (one clock for all threads), so that
+    ``hop.wake_worker + hop.wake_loop + hop.worker + hop.loop`` is ``hop``
+    again, every piece of a turn named."""
+
+    _clock = staticmethod(time.perf_counter)
 
     def __init__(self):
         from jax.profiler import TraceAnnotation
@@ -574,6 +655,11 @@ class LoopPhases:
         self._annotation = TraceAnnotation
         self.seconds: Dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
         self.counts: Dict[str, int] = dict.fromkeys(LOOP_PHASES, 0)
+        # "<phase>.<part>" -> wall seconds / occurrences; a key appears with
+        # the first part opened under it
+        self.part_seconds: Dict[str, float] = {}
+        self.part_counts: Dict[str, int] = {}
+        self.handoffs = 0
         self.turns = 0
         self.slot_seconds = 0.0
         # how each first_token_wait found its token: already computed
@@ -588,21 +674,36 @@ class LoopPhases:
         # is the over-read
         self.attn_rows_read = dict.fromkeys(MOE_PROGRAMS, 0)
         self._open: List[_Phase] = []
+        self._open_parts: List[_Phase] = []
         self._turn: Optional[Any] = None   # the open turn's annotation
         self._turn_t0 = 0.0
         self._turn_phases = 0.0            # phase seconds inside this turn
+        # the loop coroutine's own stretch of the turn (``hop.loop``): its
+        # annotation, when it began and the phase seconds booked by then
+        self._own: Optional[Any] = None
+        self._own_t0 = 0.0
+        self._own_phases = 0.0
 
     def phase(self, name: str) -> _Phase:
-        return _Phase(self, name)
+        return _Phase(self, name, self._open, self._add_phase)
 
-    def _close(self, ph: _Phase) -> None:
-        self._open.pop()        # ph: phases close innermost first
-        own = ph.seconds - ph.nested
-        self.seconds[ph.name] += own
-        self.counts[ph.name] += 1
-        self._turn_phases += own
-        if self._open:
-            self._open[-1].nested += ph.seconds
+    def part(self, name: str) -> _Phase:
+        """A part of the innermost open phase (one must be open):
+        ``llm.<phase>.<part>`` in a trace, ``<phase>.<part>`` in
+        ``part_seconds``. A second level that takes nothing from the first:
+        the phase's seconds stay its whole wall. Parts nest among themselves
+        as phases do (``emit.finish`` comes out of ``emit.slots``)."""
+        return _Phase(self, self._open[-1].name + "." + name,
+                      self._open_parts, self._add_part)
+
+    def _add_phase(self, name: str, seconds: float) -> None:
+        self.seconds[name] += seconds
+        self.counts[name] += 1
+        self._turn_phases += seconds
+
+    def _add_part(self, name: str, seconds: float) -> None:
+        self.part_seconds[name] = self.part_seconds.get(name, 0.0) + seconds
+        self.part_counts[name] = self.part_counts.get(name, 0) + 1
 
     def turn(self, active_slots: int) -> None:
         """Top of a loop turn: close the previous one (what its phases did
@@ -611,13 +712,16 @@ class LoopPhases:
         self.end_turn(active_slots)
         self._turn = self._annotation("llm.turn")
         self._turn.__enter__()
-        self._turn_t0 = time.perf_counter()
+        self._turn_t0 = self._clock()
         self._turn_phases = 0.0
+        self._enter_own(self._turn_t0)
 
     def end_turn(self, active_slots: int) -> None:
         if self._turn is None:
             return
-        wall = time.perf_counter() - self._turn_t0
+        now = self._clock()
+        self._leave_own(now)
+        wall = now - self._turn_t0
         self._turn.__exit__(None, None, None)
         self._turn = None
         self.seconds["hop"] += max(wall - self._turn_phases, 0.0)
@@ -625,9 +729,34 @@ class LoopPhases:
         self.turns += 1
         self.slot_seconds += active_slots * wall
 
+    def _enter_own(self, now: float) -> None:
+        """The loop coroutine runs its own code from ``now`` on."""
+        self._own = self._annotation("llm.hop.loop")
+        self._own.__enter__()
+        self._own_t0, self._own_phases = now, self._turn_phases
+
+    def _leave_own(self, now: float) -> None:
+        """... until ``now``: a hand-off's submission or the turn's end.
+        What it spent in no phase (``idle`` is opened here) is ``hop.loop``."""
+        if self._own is None:
+            return
+        self._own.__exit__(None, None, None)
+        self._own = None
+        self._add_part("hop.loop", max(
+            now - self._own_t0 - (self._turn_phases - self._own_phases), 0.0))
+
+    def handoff(self, fn, *args) -> "_Handoff":
+        """One hand-off of the loop coroutine to a worker thread, stamped:
+        call this at submission, run the result on the worker, and call its
+        ``resumed()`` back on the loop thread (``ContinuousBatcher._to_thread``)."""
+        return _Handoff(self, fn, args)
+
     def stats(self) -> dict:
         return {"loop_seconds": dict(self.seconds),
                 "loop_phase_counts": dict(self.counts),
+                "loop_part_seconds": dict(self.part_seconds),
+                "loop_part_counts": dict(self.part_counts),
+                "loop_handoffs": self.handoffs,
                 "loop_turns": self.turns,
                 "slot_seconds": self.slot_seconds,
                 "first_token_reads": dict(self.first_token_reads),
@@ -2290,25 +2419,27 @@ class ContinuousBatcher:
 
         C = job.chunk
         start = job.next
-        part = job.ids[start:start + C]
-        n = len(part)
-        toks = np.zeros((1, C), np.int32)
-        pos = np.full((1, C), PAD_POS, np.int32)
-        toks[0, :n] = part
-        pos[0, :n] = np.arange(start, start + n)
-        t0 = time.perf_counter()
-        if self._adapters is not None:
-            fn = self.server._get_prefill_chunk(C, self.n_pages, lora=True)
-            aid = job.req.adapter_id if job.req is not None else 0
-            logits, self._caches, aside = fn(
-                self.server._params, self._caches, job.bt_row,
-                jnp.asarray(toks), jnp.asarray(pos), self._adapters.pool(),
-                jnp.asarray([aid], jnp.int32))
-        else:
-            fn = self.server._get_prefill_chunk(C, self.n_pages)
-            logits, self._caches, aside = fn(
-                self.server._params, self._caches, job.bt_row,
-                jnp.asarray(toks), jnp.asarray(pos))
+        with self._phases.part("build"):
+            ids = job.ids[start:start + C]
+            n = len(ids)
+            toks = np.zeros((1, C), np.int32)
+            pos = np.full((1, C), PAD_POS, np.int32)
+            toks[0, :n] = ids
+            pos[0, :n] = np.arange(start, start + n)
+            t0 = time.perf_counter()
+            toks, pos = jnp.asarray(toks), jnp.asarray(pos)
+        with self._phases.part("call"):
+            if self._adapters is not None:
+                fn = self.server._get_prefill_chunk(C, self.n_pages, lora=True)
+                aid = job.req.adapter_id if job.req is not None else 0
+                logits, self._caches, aside = fn(
+                    self.server._params, self._caches, job.bt_row,
+                    toks, pos, self._adapters.pool(),
+                    jnp.asarray([aid], jnp.int32))
+            else:
+                fn = self.server._get_prefill_chunk(C, self.n_pages)
+                logits, self._caches, aside = fn(
+                    self.server._params, self._caches, job.bt_row, toks, pos)
         job.next = start + n
         self._phases.count_attention("chunk", start + n, self._rows_read(C, [start + n], 1))
         event = None
@@ -2320,7 +2451,8 @@ class ContinuousBatcher:
         if self._moe is not None:
             job.asides.append((aside, event, start, n))
         if job.next >= job.L:
-            self._activate(job, logits, n - 1)
+            with self._phases.part("activate"):
+                self._activate(job, logits, n - 1)
 
     def _read_walk(self, s: int):
         """How latent attention's kernel walks the live pages for calls of
@@ -2814,10 +2946,11 @@ class ContinuousBatcher:
         # shed inside the loop can deactivate a LATER slot of this
         # snapshot, so re-check activity before touching each one:
         # growing a released slot would allocate pages nothing owns.
-        for i in self._dispatch_eligible():
-            if self._slots[i].active:
-                self._ensure_slot_pages(
-                    i, self._slots[i].dispatched_pos() + k - 1)
+        with self._phases.part("pages"):
+            for i in self._dispatch_eligible():
+                if self._slots[i].active:
+                    self._ensure_slot_pages(
+                        i, self._slots[i].dispatched_pos() + k - 1)
         if not self._dispatch_eligible():
             return False
         # adapted steps (llm.lora_decode_step): the pool/id pair rides at
@@ -2826,24 +2959,26 @@ class ContinuousBatcher:
         lora = self._adapters is not None
         extra = () if not lora else (self._adapters.pool(),
                                      self._adapter_ids)
-        fn = self.server._get_decode_step_paged(
-            self.S, self.n_pages, k, lora=lora)
-        (self._caches, self._last_tok, self._next_pos, self._keys,
-         toks, aside) = fn(
-            self.server._params, self._caches, self._last_tok,
-            self._next_pos, self._keys, self._temp,
-            self._block_tables, *extra)
-        snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
-        context, live = 0, []
-        for i, _ in snapshot:
-            # micro-step j writes row pos + j and reads rows 0 .. pos + j
-            pos = self._slots[i].dispatched_pos()
-            context += k * (pos + 1) + k * (k - 1) // 2
-            live.extend(pos + 1 + j for j in range(k))
-            self._slots[i].disp_new += k
-        self._phases.count_attention("decode", context, self._rows_read(1, live, k * self.S))
-        self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside))
-        self._count_steps()
+        with self._phases.part("call"):
+            fn = self.server._get_decode_step_paged(
+                self.S, self.n_pages, k, lora=lora)
+            (self._caches, self._last_tok, self._next_pos, self._keys,
+             toks, aside) = fn(
+                self.server._params, self._caches, self._last_tok,
+                self._next_pos, self._keys, self._temp,
+                self._block_tables, *extra)
+        with self._phases.part("book"):
+            snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
+            context, live = 0, []
+            for i, _ in snapshot:
+                # micro-step j writes row pos + j and reads rows 0 .. pos + j
+                pos = self._slots[i].dispatched_pos()
+                context += k * (pos + 1) + k * (k - 1) // 2
+                live.extend(pos + 1 + j for j in range(k))
+                self._slots[i].disp_new += k
+            self._phases.count_attention("decode", context, self._rows_read(1, live, k * self.S))
+            self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside))
+            self._count_steps()
         return True
 
     def _dispatch_spec(self, t0: float) -> bool:
@@ -2870,42 +3005,44 @@ class ContinuousBatcher:
         # (next_pos + cap); an exhaustion shed inside the loop can
         # deactivate a later slot of this snapshot — re-check activity
         # (same discipline as the plain dispatch)
-        for i in self._dispatch_eligible():
-            if self._slots[i].active:
-                self._ensure_slot_pages(
-                    i, self._slots[i].dispatched_pos() + int(caps[i]))
+        with self._phases.part("pages"):
+            for i in self._dispatch_eligible():
+                if self._slots[i].active:
+                    self._ensure_slot_pages(
+                        i, self._slots[i].dispatched_pos() + int(caps[i]))
         if not self._dispatch_eligible():
             return False
-        fn = self.server._get_spec_step(
-            self.S, K, self.hist_len, mode=self.spec_mode,
-            n_pages=self.n_pages, lora=self._adapters is not None)
-        cap_dev = jnp.asarray(caps)
-        draft = self.spec_mode == "draft"
         # adapted verify (llm.lora_verify_step): the pool/id pair rides at
         # the end of either signature, un-donated
         extra = () if self._adapters is None else (
             self._adapters.pool(), self._adapter_ids)
-        if draft:
-            (self._caches, self._last_tok, self._next_pos, self._keys,
-             self._hist, toks, acc, self._draft_caches) = fn(
-                self.server._params, self._caches, self._last_tok,
-                self._next_pos, self._keys, self._temp, self._block_tables,
-                self._hist, cap_dev, self.server._draft_params,
-                self._draft_caches, *extra)
-        else:
-            (self._caches, self._last_tok, self._next_pos, self._keys,
-             self._hist, toks, acc) = fn(
-                self.server._params, self._caches, self._last_tok,
-                self._next_pos, self._keys, self._temp, self._block_tables,
-                self._hist, cap_dev, *extra)
-        snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
-        booked = {}
-        for i, _ in snapshot:
-            booked[i] = int(caps[i]) + 1
-            self._slots[i].disp_new += booked[i]
-        self._inflight.append(_InFlight(toks, 1, snapshot, t0, acc=acc,
-                                        booked=booked))
-        self._count_steps()
+        with self._phases.part("call"):
+            fn = self.server._get_spec_step(
+                self.S, K, self.hist_len, mode=self.spec_mode,
+                n_pages=self.n_pages, lora=self._adapters is not None)
+            cap_dev = jnp.asarray(caps)
+            if self.spec_mode == "draft":
+                (self._caches, self._last_tok, self._next_pos, self._keys,
+                 self._hist, toks, acc, self._draft_caches) = fn(
+                    self.server._params, self._caches, self._last_tok,
+                    self._next_pos, self._keys, self._temp, self._block_tables,
+                    self._hist, cap_dev, self.server._draft_params,
+                    self._draft_caches, *extra)
+            else:
+                (self._caches, self._last_tok, self._next_pos, self._keys,
+                 self._hist, toks, acc) = fn(
+                    self.server._params, self._caches, self._last_tok,
+                    self._next_pos, self._keys, self._temp, self._block_tables,
+                    self._hist, cap_dev, *extra)
+        with self._phases.part("book"):
+            snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
+            booked = {}
+            for i, _ in snapshot:
+                booked[i] = int(caps[i]) + 1
+                self._slots[i].disp_new += booked[i]
+            self._inflight.append(_InFlight(toks, 1, snapshot, t0, acc=acc,
+                                            booked=booked))
+            self._count_steps()
         return True
 
     def steps_in_flight(self) -> int:
@@ -2961,18 +3098,22 @@ class ContinuousBatcher:
         with self._phases.phase("drain_wait") as wait:
             # graftlint: allow-host-sync-in-hot-path(the consumer's deliberate drain sync: the host reads tokens one pipeline_depth BEHIND the device, so this blocks on the oldest step only while newer steps keep the chip busy — docs/performance.md)
             arr = np.asarray(rec.tokens)  # [S, k] — the only per-step host sync
-            if rec.acc is not None:
-                # graftlint: allow-host-sync-in-hot-path(part of the same drain sync: the verify step's per-slot accepted counts land with its tokens — the program already finished for the token read above)
-                accs = np.asarray(rec.acc)  # [S] accepted counts, 1..K+1
-            moe_tokens, moe_fields = None, {}
-            if self._moe is not None and rec.acc is None:
-                # graftlint: allow-host-sync-in-hot-path(part of the same drain sync: the step's routing tallies land with its tokens — the program already finished for the token read above)
-                moe_stats = np.asarray(rec.aside["moe_stats"])    # [k, 5]
-                # graftlint: allow-host-sync-in-hot-path(same: [k, S, n_experts] int32, 8 KB a step at 32 slots x 64 experts)
-                moe_tokens = np.asarray(rec.aside["moe_tokens"])
-                self._moe.add("decode", moe_stats)
-                moe_fields = self._moe.flight_fields(moe_stats[0])
-                delivered = np.zeros(moe_tokens.shape[:2], bool)   # [k, S]
+            # what is read beside the tokens, after them (nothing for a dense
+            # model's plain step): transfers of a program that has finished,
+            # told apart from the wait for it
+            with self._phases.part("asides"):
+                if rec.acc is not None:
+                    # graftlint: allow-host-sync-in-hot-path(part of the same drain sync: the verify step's per-slot accepted counts land with its tokens — the program already finished for the token read above)
+                    accs = np.asarray(rec.acc)  # [S] accepted counts, 1..K+1
+                moe_tokens, moe_fields = None, {}
+                if self._moe is not None and rec.acc is None:
+                    # graftlint: allow-host-sync-in-hot-path(part of the same drain sync: the step's routing tallies land with its tokens — the program already finished for the token read above)
+                    moe_stats = np.asarray(rec.aside["moe_stats"])    # [k, 5]
+                    # graftlint: allow-host-sync-in-hot-path(same: [k, S, n_experts] int32, 8 KB a step at 32 slots x 64 experts)
+                    moe_tokens = np.asarray(rec.aside["moe_tokens"])
+                    self._moe.add("decode", moe_stats)
+                    moe_fields = self._moe.flight_fields(moe_stats[0])
+                    delivered = np.zeros(moe_tokens.shape[:2], bool)   # [k, S]
         now = wait.t1
         self.server._decode_sync_times.append(wait.seconds)
         self.server.observe("decode_host_lag_steps", lag)
@@ -2989,51 +3130,53 @@ class ContinuousBatcher:
         if rec.acc is not None:
             self._credit_spec(rec, arr, accs)
             return
-        for i, gen in rec.snapshot:
-            slot = self._slots[i]
-            if not slot.active or slot.gen != gen:
-                # trailing run-ahead token for a finished (or already
-                # replaced) occupant — masked, never surfaced
-                continue
-            if slot.n_new >= slot.max_new:
-                continue  # budget-exhausted slot riding along
-            credited = 0
-            finish = False
-            for j in range(rec.k):
-                tok = int(arr[i, j])
-                slot.tokens.append(tok)
-                slot.n_new += 1
-                credited += 1
-                if slot.logits is not None:
-                    # graftlint: allow-host-sync-in-hot-path(a probe request only: one [vocab] row of a step that has finished)
-                    slot.logits.append(np.asarray(rec.aside["logits"][j, i]))
-                    if slot.routing is not None:
-                        # graftlint: allow-host-sync-in-hot-path(same probe: the [n_moe_layers, k] experts this step's token took)
-                        slot.routing.append(np.asarray(rec.aside["moe_choice"][j, i, 0]))
-                # inter-token gap at this drain (a fused block surfaces
-                # its k tokens in one burst: trailing tokens record ~0)
-                if slot.t_last is not None:
-                    self.server.observe("inter_token_s", now - slot.t_last)
-                slot.t_last = now
-                if slot.on_token is not None and tok != self.eos_id:
-                    slot.on_token(tok)
-                if (tok == self.eos_id or slot.n_new >= slot.max_new
-                        or slot.host_pos() >= self.max_len):
-                    finish = True
-                    break
-            if credited:
-                self._pending.count_tokens(slot.tenant, slot.slo_class,
-                                           credited)
-            if moe_tokens is not None:
-                delivered[:credited, i] = True
-            if self._flight is not None and credited:
-                # one step event per slot per drain, BEFORE any finish
-                # materializes the segment: tokens credited this drain plus
-                # the step's device dwell (dispatch -> drain)
-                self._flight.record(i, EV_STEP, tokens=credited,
-                                    t_dispatch=rec.t_dispatch, **moe_fields)
-            if finish:
-                self._finish(i)
+        with self._phases.part("slots"):
+            for i, gen in rec.snapshot:
+                slot = self._slots[i]
+                if not slot.active or slot.gen != gen:
+                    # trailing run-ahead token for a finished (or already
+                    # replaced) occupant — masked, never surfaced
+                    continue
+                if slot.n_new >= slot.max_new:
+                    continue  # budget-exhausted slot riding along
+                credited = 0
+                finish = False
+                for j in range(rec.k):
+                    tok = int(arr[i, j])
+                    slot.tokens.append(tok)
+                    slot.n_new += 1
+                    credited += 1
+                    if slot.logits is not None:
+                        # graftlint: allow-host-sync-in-hot-path(a probe request only: one [vocab] row of a step that has finished)
+                        slot.logits.append(np.asarray(rec.aside["logits"][j, i]))
+                        if slot.routing is not None:
+                            # graftlint: allow-host-sync-in-hot-path(same probe: the [n_moe_layers, k] experts this step's token took)
+                            slot.routing.append(np.asarray(rec.aside["moe_choice"][j, i, 0]))
+                    # inter-token gap at this drain (a fused block surfaces
+                    # its k tokens in one burst: trailing tokens record ~0)
+                    if slot.t_last is not None:
+                        self.server.observe("inter_token_s", now - slot.t_last)
+                    slot.t_last = now
+                    if slot.on_token is not None and tok != self.eos_id:
+                        slot.on_token(tok)
+                    if (tok == self.eos_id or slot.n_new >= slot.max_new
+                            or slot.host_pos() >= self.max_len):
+                        finish = True
+                        break
+                if credited:
+                    self._pending.count_tokens(slot.tenant, slot.slo_class,
+                                               credited)
+                if moe_tokens is not None:
+                    delivered[:credited, i] = True
+                if self._flight is not None and credited:
+                    # one step event per slot per drain, BEFORE any finish
+                    # materializes the segment: tokens credited this drain plus
+                    # the step's device dwell (dispatch -> drain)
+                    self._flight.record(i, EV_STEP, tokens=credited,
+                                        t_dispatch=rec.t_dispatch, **moe_fields)
+                if finish:
+                    with self._phases.part("finish"):
+                        self._finish(i)
         if moe_tokens is not None:
             self._moe.expert_tokens += moe_tokens[delivered].sum(axis=0)
 
@@ -3048,55 +3191,66 @@ class ContinuousBatcher:
         exactly like a trailing run-ahead step, and the leftover tokens
         are dropped, never surfaced."""
         now = time.perf_counter()
-        for i, gen in rec.snapshot:
-            slot = self._slots[i]
-            if not slot.active or slot.gen != gen:
-                # the occupant this step decoded for is gone; the new
-                # occupant's disp_new/controller state were reset at
-                # admission, so there is nothing to reconcile either
-                continue
-            adv = int(accs[i])
-            booked = rec.booked.get(i, 1)
-            # dispatch booked the all-accepted maximum (cap+1); the device
-            # actually advanced next_pos by adv — restore the invariant
-            # dispatched_pos() == device next_pos + later in-flight maxima
-            slot.disp_new -= booked - adv
-            offered = booked - 1
-            self._spec.observe(i, max(adv - 1, 0), offered, adv)
-            self.server._spec_accepted.append(adv)
-            if slot.n_new >= slot.max_new:
-                continue  # budget-exhausted slot riding along
-            credited = 0
-            finish = False
-            for j in range(adv):
-                tok = int(arr[i, j])
-                slot.tokens.append(tok)
-                slot.n_new += 1
-                credited += 1
-                # inter-token gap (an accepted block surfaces as a burst:
-                # its trailing tokens record ~0 gaps — the block's real
-                # cadence is the first token's gap)
-                if slot.t_last is not None:
-                    self.server.observe("inter_token_s", now - slot.t_last)
-                slot.t_last = now
-                if slot.on_token is not None and tok != self.eos_id:
-                    slot.on_token(tok)
-                if (tok == self.eos_id or slot.n_new >= slot.max_new
-                        or slot.host_pos() >= self.max_len):
-                    finish = True
-                    break
-            if credited:
-                self._pending.count_tokens(slot.tenant, slot.slo_class,
-                                           credited)
-            if self._flight is not None and credited:
-                # per-verify-step event: tokens surfaced, drafts offered,
-                # device-accepted count — the speculative half of the
-                # timeline's token accounting (recorded before any finish)
-                self._flight.record(i, EV_STEP, tokens=credited,
-                                    offered=offered, accepted=adv,
-                                    t_dispatch=rec.t_dispatch)
-            if finish:
-                self._finish(i)
+        with self._phases.part("slots"):
+            for i, gen in rec.snapshot:
+                slot = self._slots[i]
+                if not slot.active or slot.gen != gen:
+                    # the occupant this step decoded for is gone; the new
+                    # occupant's disp_new/controller state were reset at
+                    # admission, so there is nothing to reconcile either
+                    continue
+                adv = int(accs[i])
+                booked = rec.booked.get(i, 1)
+                # dispatch booked the all-accepted maximum (cap+1); the device
+                # actually advanced next_pos by adv — restore the invariant
+                # dispatched_pos() == device next_pos + later in-flight maxima
+                slot.disp_new -= booked - adv
+                offered = booked - 1
+                self._spec.observe(i, max(adv - 1, 0), offered, adv)
+                self.server._spec_accepted.append(adv)
+                if slot.n_new >= slot.max_new:
+                    continue  # budget-exhausted slot riding along
+                credited = 0
+                finish = False
+                for j in range(adv):
+                    tok = int(arr[i, j])
+                    slot.tokens.append(tok)
+                    slot.n_new += 1
+                    credited += 1
+                    # inter-token gap (an accepted block surfaces as a burst:
+                    # its trailing tokens record ~0 gaps — the block's real
+                    # cadence is the first token's gap)
+                    if slot.t_last is not None:
+                        self.server.observe("inter_token_s", now - slot.t_last)
+                    slot.t_last = now
+                    if slot.on_token is not None and tok != self.eos_id:
+                        slot.on_token(tok)
+                    if (tok == self.eos_id or slot.n_new >= slot.max_new
+                            or slot.host_pos() >= self.max_len):
+                        finish = True
+                        break
+                if credited:
+                    self._pending.count_tokens(slot.tenant, slot.slo_class,
+                                               credited)
+                if self._flight is not None and credited:
+                    # per-verify-step event: tokens surfaced, drafts offered,
+                    # device-accepted count — the speculative half of the
+                    # timeline's token accounting (recorded before any finish)
+                    self._flight.record(i, EV_STEP, tokens=credited,
+                                        offered=offered, accepted=adv,
+                                        t_dispatch=rec.t_dispatch)
+                if finish:
+                    with self._phases.part("finish"):
+                        self._finish(i)
+
+    async def _to_thread(self, fn, *args):
+        """``asyncio.to_thread(fn, *args)`` for the loop coroutine, the
+        hand-off stamped for ``hop``'s parts (``LoopPhases.handoff``)."""
+        hop = self._phases.handoff(fn, *args)
+        try:
+            return await asyncio.to_thread(hop)
+        finally:
+            hop.resumed()
 
     async def _run(self):
         self.crashed = None  # a restarted loop is a recovered loop
@@ -3118,7 +3272,9 @@ class ContinuousBatcher:
                 # admit as many pending requests as there are free slots
                 # (FIFO, peek-then-pop so a failed admit keeps the request);
                 # device work runs in a worker thread so the event loop (and
-                # co-hosted HTTP handlers) stays responsive during decode.
+                # co-hosted HTTP handlers) stays responsive during decode:
+                # every hand-off goes through ``phases.handoff``, which is
+                # ``asyncio.to_thread`` with the four stamps hop's parts need.
                 # Admission happens while earlier steps are STILL IN FLIGHT
                 # — the insert/set_slot queue behind them in device program
                 # order, and the gen counter masks their stale tokens.
@@ -3133,7 +3289,7 @@ class ContinuousBatcher:
                         # never active slots, at most once per request) —
                         # otherwise wait for its chunks to finish
                         if (req.slo_class == "interactive"
-                                and await asyncio.to_thread(
+                                and await self._to_thread(
                                     self._preempt_for_interactive)):
                             continue
                         break
@@ -3141,11 +3297,9 @@ class ContinuousBatcher:
                         # disaggregated: stage the job on the prefill
                         # slice — host-side only, so MULTIPLE admissions
                         # can be in flight while decode keeps dispatching
-                        admitted = await asyncio.to_thread(
-                            self._admit_remote, req)
+                        admitted = await self._to_thread(self._admit_remote, req)
                     else:
-                        admitted = await asyncio.to_thread(
-                            self._admit_begin, req)
+                        admitted = await self._to_thread(self._admit_begin, req)
                     if not admitted:
                         # deadline-aware preemption: an interactive head
                         # blocked on occupied slots may push ONE staged
@@ -3153,7 +3307,7 @@ class ContinuousBatcher:
                         # remote admission) back into the queue — never
                         # an active slot — then retry the same head
                         if (req.slo_class == "interactive"
-                                and await asyncio.to_thread(
+                                and await self._to_thread(
                                     self._preempt_for_interactive)):
                             continue
                         break  # no free slot/pages — decode frees them
@@ -3165,13 +3319,13 @@ class ContinuousBatcher:
                 # commit — one jitted scatter each, no prefill compute on
                 # this slice)
                 if self._transfer is not None and self._transfer.ready_depth():
-                    await asyncio.to_thread(self._consume_handoffs)
+                    await self._to_thread(self._consume_handoffs)
                     enqueued = True
                 # producer: keep the device pipeline_depth steps ahead of
                 # the host — dispatch is enqueue-only, no sync
                 while (self.steps_in_flight() < self.pipeline_depth
                        and self._dispatch_eligible()):
-                    if await asyncio.to_thread(self._dispatch):
+                    if await self._to_thread(self._dispatch):
                         enqueued = True
                 # chunked prefill interleaves: ONE chunk per loop turn, so a
                 # long admission prefill shares the device with the decode
@@ -3179,13 +3333,13 @@ class ContinuousBatcher:
                 # compile bucket (no chunk syncs: the last one ends in the
                 # slot's activation, read below like a step's tokens)
                 if self._prefill is not None:
-                    await asyncio.to_thread(self._prefill_step)
+                    await self._to_thread(self._prefill_step)
                     enqueued = True
                 # consumer: drain the oldest record one (or more) behind,
                 # unless it is a first token that the next turn's enqueues
                 # should not stand behind
                 if self._inflight and not self._first_token_can_wait(enqueued):
-                    await asyncio.to_thread(self._drain_one)
+                    await self._to_thread(self._drain_one)
                     continue
                 if enqueued:
                     # never fall through to the idle wait on a turn that
@@ -3199,7 +3353,7 @@ class ContinuousBatcher:
                     # (to_thread like every other _release_slot caller:
                     # page/block-table writers stay single-context)
                     if self._remote_jobs:
-                        await asyncio.to_thread(
+                        await self._to_thread(
                             self._fail_remote_jobs,
                             RuntimeError("batcher closed"))
                     return
@@ -3231,7 +3385,7 @@ class ContinuousBatcher:
                 # released, so the slot sweep below cannot double-resolve
                 # (to_thread keeps every _release_slot caller in the same
                 # offload context the page/block-table state is guarded by)
-                await asyncio.to_thread(self._fail_remote_jobs, e)
+                await self._to_thread(self._fail_remote_jobs, e)
             for slot in self._slots:
                 if slot.active or slot.prefilling:
                     if slot.on_token is not None:

@@ -2129,7 +2129,8 @@ class LLMServer(SeldonComponent):
             "kv_occupancy": occupancy,
             "slots_active": slots_active,
             # the batcher loop's time budget (runtime/batcher.py LoopPhases):
-            # loop_seconds / loop_phase_counts by phase, loop_turns,
+            # loop_seconds / loop_phase_counts by phase, loop_part_seconds /
+            # loop_part_counts by "<phase>.<part>", loop_handoffs, loop_turns,
             # slot_seconds (active slots x seconds, per turn)
             **loop_stats,
             # lifetime bucket tallies of the histograms counted on the loop

@@ -162,6 +162,42 @@ async def parse_framed_message(request: web.Request) -> SeldonMessage:
 # Microservice app: one component
 # ---------------------------------------------------------------------------
 
+class http_busy:
+    """One synchronous stretch of the transport thread, which shares the GIL
+    with the batcher's loop and its workers: ``http.<what>`` in a profiler
+    trace (a prefix of its own, so no reader of the loop's ``llm.*`` spans
+    takes it for a phase) and its wall added to
+    ``seldon_http_busy_seconds_total{what}`` / ``seldon_http_busy_total``.
+    A busy time, where ``seldon_llm_emit_delay_seconds`` is the delay the
+    client feels. While a profile is being captured (and only then) the
+    span carries the request's trace id, so a viewer can follow one request
+    from parse to reply. Always on, like the loop's phases: an inactive
+    annotation and two clock reads."""
+
+    __slots__ = ("metrics", "what", "trace", "_ann", "_t0")
+    _annotation: Any = None     # jax.profiler.TraceAnnotation, at first use
+
+    def __init__(self, metrics: MetricsRegistry, what: str, trace: Any = None):
+        self.metrics, self.what, self.trace = metrics, what, trace
+
+    def __enter__(self) -> "http_busy":
+        cls = http_busy._annotation
+        if cls is None:
+            from jax.profiler import TraceAnnotation
+
+            cls = http_busy._annotation = TraceAnnotation
+        self._ann = cls("http." + self.what)
+        self._ann.__enter__()
+        if self.trace is not None and cls.is_enabled():
+            self._ann.set_metadata(trace_id=self.trace.trace_id)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.metrics.observe_http_busy(self.what, time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
+
+
 def make_profile_handler() -> Callable:
     """POST /profile?seconds=N on both apps: capture a jax.profiler trace
     (device planes + the host plane, which carries the batcher's ``llm.*``
@@ -304,12 +340,13 @@ def make_component_app(
         return web.json_response(wrapper_spec())
 
     async def prom(request):
-        metrics.sync_resilience(admission=admission, transport="rest")
-        metrics.sync_llm(component)
-        metrics.sync_controlplane(component)
-        metrics.sync_framing()
-        metrics.sync_tracing()
-        return web.Response(body=metrics.expose(), content_type="text/plain")
+        with http_busy(metrics, "scrape"):
+            metrics.sync_resilience(admission=admission, transport="rest")
+            metrics.sync_llm(component)
+            metrics.sync_controlplane(component)
+            metrics.sync_framing()
+            metrics.sync_tracing()
+            return web.Response(body=metrics.expose(), content_type="text/plain")
 
     async def debug_timeline(request):
         """Recent per-request flight-recorder timelines + the scaling
@@ -318,8 +355,9 @@ def make_component_app(
         from seldon_core_tpu.observability.timeline import (
             parse_n, timeline_report)
 
-        return web.json_response(
-            timeline_report(component, n=parse_n(request.query.get("n"))))
+        with http_busy(metrics, "scrape"):
+            return web.json_response(
+                timeline_report(component, n=parse_n(request.query.get("n"))))
 
     app.router.add_get("/health/status", health)
     app.router.add_get("/ready", health)
@@ -333,6 +371,36 @@ def make_component_app(
     if hasattr(component, "generate"):
         _add_generate_routes(app, component, metrics)
     return app
+
+
+def _parse_generate(request: web.Request, text: str) -> tuple:
+    """The synchronous head of POST /v1/generate: the body's JSON and the
+    request's identity -> (body, max_new, tenant, slo_class, adapter,
+    deadline_s)."""
+    body = json.loads(text)
+    if not isinstance(body, dict):
+        raise SeldonError("body must be a JSON object", status_code=400)
+    max_new = body.get("max_new_tokens")
+    # multi-tenant identity (docs/multitenancy.md): tenant + SLO
+    # class ride headers (body fields win when both are present,
+    # for clients that cannot set headers); the LoRA adapter name
+    # is a body field like the sampling knobs. The deadline header
+    # doubles as the scheduler's EDF key.
+    tenant = body.get("tenant") or request.headers.get("Seldon-Tenant")
+    slo_class = (body.get("slo_class")
+                 or request.headers.get("Seldon-SLO-Class"))
+    # a typo'd class fails loudly on EVERY path — the non-batched
+    # branches (prompts batch, per-request temperature) never reach
+    # the batcher's own validation
+    from seldon_core_tpu.runtime.scheduler import normalize_slo_class
+
+    try:
+        normalize_slo_class(slo_class)
+    except ValueError as e:
+        raise SeldonError(str(e), status_code=400)
+    dl = deadline_from_headers(request)
+    return (body, max_new, tenant, slo_class, body.get("adapter"),
+            dl.remaining_s() if dl is not None else None)
 
 
 def _add_generate_routes(app: web.Application, component: Any,
@@ -369,30 +437,13 @@ def _add_generate_routes(app: web.Application, component: Any,
                               request.headers.get("traceparent"),
                               "rest:/v1/generate")
         try:
-            body = await request.json()
-            if not isinstance(body, dict):
-                raise SeldonError("body must be a JSON object", status_code=400)
-            max_new = body.get("max_new_tokens")
-            # multi-tenant identity (docs/multitenancy.md): tenant + SLO
-            # class ride headers (body fields win when both are present,
-            # for clients that cannot set headers); the LoRA adapter name
-            # is a body field like the sampling knobs. The deadline header
-            # doubles as the scheduler's EDF key.
-            tenant = body.get("tenant") or request.headers.get("Seldon-Tenant")
-            slo_class = (body.get("slo_class")
-                         or request.headers.get("Seldon-SLO-Class"))
-            # a typo'd class fails loudly on EVERY path — the non-batched
-            # branches below (prompts batch, per-request temperature)
-            # never reach the batcher's own validation
-            from seldon_core_tpu.runtime.scheduler import normalize_slo_class
-
-            try:
-                normalize_slo_class(slo_class)
-            except ValueError as e:
-                raise SeldonError(str(e), status_code=400)
-            adapter = body.get("adapter")
-            dl = deadline_from_headers(request)
-            deadline_s = dl.remaining_s() if dl is not None else None
+            raw = await request.text()
+            # http.parse: the body's JSON and the request's identity, on the
+            # transport thread (the tokenizer runs where the batcher's
+            # submit does, on its loop thread)
+            with http_busy(metrics, "parse", trace):
+                body, max_new, tenant, slo_class, adapter, deadline_s = \
+                    _parse_generate(request, raw)
             if "prompts" in body:
                 if adapter:
                     raise SeldonError(
@@ -474,29 +525,30 @@ def _add_generate_routes(app: web.Application, component: Any,
                         # but the client still gets a stable correlation id
                         resp_body["trace_id"] = trace.trace_id
                     return web.json_response(resp_body)
-                text = decode.decode(toks) if (decode is not None
-                                               and isinstance(prompt, str)) else None
-                metrics.observe_api_call("generate", "200", time.perf_counter() - t0)
-                out = {"tokens": toks, "text": text}
-                if trace is not None:
-                    out["trace_id"] = trace.trace_id
-                if info.get("truncated_prompt"):
-                    out["truncated_prompt"] = info["truncated_prompt"]
-                if info.get("logits"):
-                    rows = np.stack(info["logits"]).astype("<f4")
-                    out["logits"] = {
-                        "shape": list(rows.shape), "dtype": "float32",
-                        "base64": base64.b64encode(rows.tobytes()).decode()}
-                if info.get("routing"):
-                    # an MoE model: the experts each processed token took
-                    # (every token but the last one sampled), for a reference
-                    # that follows the served choices
-                    took = np.stack(info["routing"]).astype("<i4")
-                    out["routing"] = {
-                        "first_token": info["routing_start"],
-                        "shape": list(took.shape), "dtype": "int32",
-                        "base64": base64.b64encode(took.tobytes()).decode()}
-                return web.json_response(out)
+                with http_busy(metrics, "reply", trace):
+                    text = decode.decode(toks) if (decode is not None
+                                                   and isinstance(prompt, str)) else None
+                    metrics.observe_api_call("generate", "200", time.perf_counter() - t0)
+                    out = {"tokens": toks, "text": text}
+                    if trace is not None:
+                        out["trace_id"] = trace.trace_id
+                    if info.get("truncated_prompt"):
+                        out["truncated_prompt"] = info["truncated_prompt"]
+                    if info.get("logits"):
+                        rows = np.stack(info["logits"]).astype("<f4")
+                        out["logits"] = {
+                            "shape": list(rows.shape), "dtype": "float32",
+                            "base64": base64.b64encode(rows.tobytes()).decode()}
+                    if info.get("routing"):
+                        # an MoE model: the experts each processed token took
+                        # (every token but the last one sampled), for a reference
+                        # that follows the served choices
+                        took = np.stack(info["routing"]).astype("<i4")
+                        out["routing"] = {
+                            "first_token": info["routing_start"],
+                            "shape": list(took.shape), "dtype": "int32",
+                            "base64": base64.b64encode(took.tobytes()).decode()}
+                    return web.json_response(out)
 
             if custom_sampling:
                 raise SeldonError(
@@ -570,10 +622,11 @@ def _add_generate_routes(app: web.Application, component: Any,
                         await resp.write(
                             f"data: {json.dumps({'resumed': True, 'tokens_delivered': tok.tokens_delivered})}\n\n".encode())
                         return
-                    piece = (decode.decode([tok]) if decode is not None
-                             and isinstance(prompt, str) else None)
-                    await resp.write(
-                        f"data: {json.dumps({'token': tok, 'text': piece})}\n\n".encode())
+                    with http_busy(metrics, "sse_write"):
+                        piece = (decode.decode([tok]) if decode is not None
+                                 and isinstance(prompt, str) else None)
+                        await resp.write(
+                            f"data: {json.dumps({'token': tok, 'text': piece})}\n\n".encode())
                     metrics.observe_emit_delay(time.perf_counter() - surfaced)
 
                 while True:
@@ -611,15 +664,16 @@ def _add_generate_routes(app: web.Application, component: Any,
                         await write_tok(*item)
                     break
                 toks = await fut
-                text = decode.decode(toks) if (decode is not None
-                                               and isinstance(prompt, str)) else None
-                done_evt = {"done": True, "tokens": toks, "text": text}
-                if trace is not None:
-                    done_evt["trace_id"] = trace.trace_id
-                if info.get("truncated_prompt"):
-                    done_evt["truncated_prompt"] = info["truncated_prompt"]
-                await resp.write(
-                    f"data: {json.dumps(done_evt)}\n\n".encode())
+                with http_busy(metrics, "reply", trace):
+                    text = decode.decode(toks) if (decode is not None
+                                                   and isinstance(prompt, str)) else None
+                    done_evt = {"done": True, "tokens": toks, "text": text}
+                    if trace is not None:
+                        done_evt["trace_id"] = trace.trace_id
+                    if info.get("truncated_prompt"):
+                        done_evt["truncated_prompt"] = info["truncated_prompt"]
+                    await resp.write(
+                        f"data: {json.dumps(done_evt)}\n\n".encode())
                 await resp.write_eof()
                 metrics.observe_api_call("generate", "200", time.perf_counter() - t0)
                 return resp
@@ -805,13 +859,14 @@ def make_engine_app(
         return web.Response(text="unpaused")
 
     async def prom(request):
-        metrics.sync_resilience(engine=engine, admission=admission, transport="rest")
-        for comp in getattr(engine, "_components", {}).values():
-            metrics.sync_llm(comp)
-        metrics.sync_controlplane(engine)
-        metrics.sync_framing()
-        metrics.sync_tracing()
-        return web.Response(body=metrics.expose(), content_type="text/plain")
+        with http_busy(metrics, "scrape"):
+            metrics.sync_resilience(engine=engine, admission=admission, transport="rest")
+            for comp in getattr(engine, "_components", {}).values():
+                metrics.sync_llm(comp)
+            metrics.sync_controlplane(engine)
+            metrics.sync_framing()
+            metrics.sync_tracing()
+            return web.Response(body=metrics.expose(), content_type="text/plain")
 
     async def debug_timeline(request):
         """Per-component flight-recorder timelines + scaling snapshots for
@@ -819,11 +874,12 @@ def make_engine_app(
         from seldon_core_tpu.observability.timeline import (
             parse_n, timeline_report)
 
-        n = parse_n(request.query.get("n"))
-        return web.json_response({
-            name: timeline_report(comp, n=n)
-            for name, comp in getattr(engine, "_components", {}).items()
-        })
+        with http_busy(metrics, "scrape"):
+            n = parse_n(request.query.get("n"))
+            return web.json_response({
+                name: timeline_report(comp, n=n)
+                for name, comp in getattr(engine, "_components", {}).items()
+            })
 
     async def openapi(request):
         from seldon_core_tpu.transport.openapi import engine_spec

@@ -26,6 +26,7 @@ import pytest
 from seldon_core_tpu.metrics.local import LATENCY_BUCKETS, HistogramAccumulator
 from seldon_core_tpu.metrics.registry import MetricsRegistry
 from seldon_core_tpu.runtime.batcher import (
+    HOP_PARTS,
     LOOP_PHASES,
     BatcherService,
     ContinuousBatcher,
@@ -372,3 +373,293 @@ def test_step_programs_lower_identically_inside_and_outside_a_phase(server):
     b._phases.end_turn(0)
     assert inside == outside
     assert "llm." not in outside[0] and "llm." not in outside[1]
+
+
+# ------------------------------------------- parts: a second level (ISSUE 33)
+class FakeClock:
+    """An injected clock: every read is what the test last set."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def __call__(self) -> float:
+        return self.now
+
+
+def clocked_phases():
+    phases, clock = LoopPhases(), FakeClock()
+    phases._clock = clock
+    return phases, clock
+
+
+@pytest.mark.parametrize("with_parts", [False, True])
+def test_a_part_takes_nothing_from_its_phase(with_parts):
+    phases, clock = clocked_phases()
+    phases.turn(1)
+    clock.now += 1.0                            # the loop's own code
+    with phases.phase("dispatch") as ph:
+        clock.now += 0.25                       # the phase's own head
+        if with_parts:
+            with phases.part("pages"):
+                clock.now += 0.5
+            with phases.part("call"):
+                clock.now += 2.0
+        else:
+            clock.now += 2.5
+        clock.now += 0.125
+    clock.now += 0.5
+    phases.end_turn(1)
+    st = phases.stats()
+    # the phase's seconds are its wall whether or not parts were opened in it
+    assert ph.seconds == st["loop_seconds"]["dispatch"] == 2.875
+    assert st["loop_seconds"]["hop"] == 1.5
+    assert sum(st["loop_seconds"].values()) == 4.375 == st["slot_seconds"]
+    named = {k: v for k, v in st["loop_part_seconds"].items() if not k.startswith("hop.")}
+    assert named == ({"dispatch.pages": 0.5, "dispatch.call": 2.0} if with_parts else {})
+    assert st["loop_part_seconds"]["hop.loop"] == 1.5 == st["loop_seconds"]["hop"]
+    assert st["loop_part_counts"]["hop.loop"] == 1
+
+
+def test_parts_nest_among_themselves_and_a_phase_without_parts_reports_none():
+    phases, clock = clocked_phases()
+    phases.turn(2)
+    with phases.phase("emit"):
+        with phases.phase("drain_wait"):
+            clock.now += 3.0                    # no part opened in this one
+        with phases.part("slots"):
+            clock.now += 1.0
+            for _ in range(2):
+                with phases.part("finish"):
+                    clock.now += 0.25
+    phases.end_turn(2)
+    st = phases.stats()
+    assert st["loop_seconds"]["drain_wait"] == 3.0 and st["loop_seconds"]["emit"] == 1.5
+    # emit.finish comes out of emit.slots, as drain_wait comes out of emit
+    assert st["loop_part_seconds"]["emit.slots"] == 1.0
+    assert st["loop_part_seconds"]["emit.finish"] == 0.5
+    assert st["loop_part_counts"]["emit.slots"] == 1 and st["loop_part_counts"]["emit.finish"] == 2
+    assert not [k for k in st["loop_part_seconds"] if k.startswith("drain_wait.")]
+    assert set(st["loop_part_counts"]) == set(st["loop_part_seconds"])
+
+
+def test_a_handoffs_four_stamps_name_every_piece_of_the_turn():
+    """Under the injected clock: submission, the worker's entry and exit and
+    resumption cut a hand-off into wake_worker, the phases the worker opened,
+    the worker's own Python outside them, and wake_loop."""
+    phases, clock = clocked_phases()
+
+    def work():
+        clock.now += 0.5            # the worker's own Python, before its first phase
+        with phases.phase("dispatch"):
+            clock.now += 2.0
+        clock.now += 0.25           # ... and between two phases
+        with phases.phase("emit"):
+            clock.now += 1.0
+        return "done"
+
+    async def go():
+        phases.turn(1)
+        clock.now += 1.0                        # hop.loop before the hand-off
+        hop = phases.handoff(work)
+        got = await asyncio.to_thread(hop)
+        hop.resumed()
+        clock.now += 0.125                      # hop.loop after it
+        phases.end_turn(1)
+        return got
+
+    assert asyncio.run(go()) == "done"
+    st = phases.stats()
+    parts = st["loop_part_seconds"]
+    assert st["loop_handoffs"] == 1 == st["loop_part_counts"]["hop.wake_worker"]
+    assert parts["hop.wake_worker"] == 0.0 and parts["hop.wake_loop"] == 0.0
+    assert parts["hop.worker"] == pytest.approx(0.75)     # 0.5 + 0.25 outside the phases
+    assert parts["hop.loop"] == pytest.approx(1.125)
+    assert st["loop_part_counts"]["hop.loop"] == 2
+    assert st["loop_seconds"]["dispatch"] == 2.0 and st["loop_seconds"]["emit"] == 1.0
+    assert sum(parts[k] for k in HOP_PARTS) == pytest.approx(st["loop_seconds"]["hop"]) \
+        == pytest.approx(1.875)
+
+
+def test_hop_is_its_four_measured_parts_over_200_turns():
+    s = make_server()
+    svc = open_service(s)
+    try:
+        while svc.batcher._phases.turns < 200:
+            drive(svc, PROMPTS)
+    finally:
+        svc.close()
+    st = s.llm_stats()
+    parts, counts = st["loop_part_seconds"], st["loop_part_counts"]
+    assert st["loop_turns"] >= 200
+    assert all(parts[k] > 0.0 for k in HOP_PARTS)
+    # no piece of a turn is still unnamed: the four parts ARE hop
+    assert sum(parts[k] for k in HOP_PARTS) == pytest.approx(st["loop_seconds"]["hop"], rel=0.01)
+    # one of each leg a hand-off, one stretch of the loop's own between them
+    assert counts["hop.wake_worker"] == counts["hop.wake_loop"] == counts["hop.worker"] \
+        == st["loop_handoffs"] > st["loop_turns"]
+    assert counts["hop.loop"] == st["loop_handoffs"] + st["loop_turns"]
+    # the phases' own budget is what it was: the parts stand inside it
+    for part, phase in (("dispatch.pages", "dispatch"), ("dispatch.call", "dispatch"),
+                        ("dispatch.book", "dispatch"), ("drain_wait.asides", "drain_wait"),
+                        ("emit.slots", "emit"), ("emit.finish", "emit"),
+                        ("prefill.build", "prefill"), ("prefill.call", "prefill"),
+                        ("prefill.activate", "prefill")):
+        assert 0.0 < parts[part] <= st["loop_seconds"][phase] * (1 + 1e-9), part
+    assert sum(parts[p] for p in ("dispatch.pages", "dispatch.call", "dispatch.book")) \
+        <= st["loop_seconds"]["dispatch"]
+    assert counts["dispatch.call"] == st["loop_phase_counts"]["dispatch"]
+    assert counts["drain_wait.asides"] == st["loop_phase_counts"]["drain_wait"]
+    assert counts["prefill.build"] == counts["prefill.call"] == st["loop_phase_counts"]["prefill"]
+    # one activation and one finish a request (six tokens each: none ends in first_token)
+    assert counts["prefill.activate"] == counts["emit.finish"] \
+        == st["loop_phase_counts"]["first_token"]
+    # no part inside the phases whose idle time the benchmark reads by name
+    assert not [k for k in parts if k.split(".")[0] in ("admit", "first_token_wait", "first_token")]
+
+
+def test_parts_and_handoffs_are_exported_under_the_documented_names(server):
+    svc = serve(server, PROMPTS)
+    try:
+        reg = MetricsRegistry(deployment="d", predictor="p")
+        reg.sync_llm(server)
+        text = reg.expose().decode()
+        st = server.llm_stats()
+    finally:
+        svc.close()
+    for part in ("dispatch.pages", "dispatch.call", "dispatch.book", "drain_wait.asides",
+                 "emit.slots", "emit.finish", "prefill.build", "prefill.call",
+                 "prefill.activate") + HOP_PARTS:
+        label = f'part="{part}"'
+        assert series(text, "seldon_llm_loop_part_seconds_total", label) \
+            == pytest.approx(st["loop_part_seconds"][part], rel=0.5), part
+        assert series(text, "seldon_llm_loop_part_total", label) > 0
+    assert series(text, "seldon_llm_loop_handoffs_total") > series(text, "seldon_llm_loop_turns_total")
+    # a second scrape catches up by difference, and the phases' series are untouched
+    reg.sync_llm(server)
+    again = reg.expose().decode()
+    assert series(again, "seldon_llm_loop_part_total", 'part="dispatch.call"') \
+        == series(again, "seldon_llm_loop_phase_total", 'phase="dispatch"')
+    assert series(again, "seldon_llm_loop_seconds_total") == pytest.approx(
+        sum(server.llm_stats()["loop_seconds"].values()))
+
+
+# ----------------------------------------- the transport thread's busy time
+def test_http_busy_counts_once_per_request_sse_event_reply_and_scrape():
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from seldon_core_tpu.transport.rest import make_component_app
+
+    s = make_server()
+    reg = MetricsRegistry(deployment="d", predictor="p")
+    app = make_component_app(s, metrics=reg)
+
+    async def go():
+        async with TestClient(TestServer(app)) as client:
+            first = await (await client.get("/metrics")).text()
+            for prompt in (PROMPTS[0], PROMPTS[3]):
+                resp = await client.post("/v1/generate", json={
+                    "prompt": prompt, "stream": True, "max_new_tokens": 6})
+                assert resp.status == 200
+                await resp.read()
+            plain = await client.post("/v1/generate", json={"prompt": PROMPTS[2],
+                                                            "max_new_tokens": 5})
+            assert plain.status == 200 and len((await plain.json())["tokens"]) == 5
+            bad = await client.post("/v1/generate", data=b"[1, 2]")
+            assert bad.status == 400
+            assert (await client.get("/debug/timeline")).status == 200
+            return first, await (await client.get("/metrics")).text()
+
+    try:
+        first, text = asyncio.run(go())
+    finally:
+        svc = getattr(s, "_batcher_service", None)
+        if svc is not None:
+            svc.close()
+    # counted where the stretch ends: the first scrape's own text does not hold it
+    assert "seldon_http_busy_total{" not in first
+    for what, n in (("parse", 4), ("sse_write", 12), ("reply", 3), ("scrape", 2)):
+        label = f'what="{what}"'
+        assert series(text, "seldon_http_busy_total", label) == n, what
+        assert series(text, "seldon_http_busy_seconds_total", label) > 0.0, what
+    # busy times of one thread: together they cannot pass the run's wall
+    assert series(text, "seldon_http_busy_seconds_total") < 60.0
+    assert series(text, "seldon_llm_emit_delay_seconds_count") == 12
+
+
+def test_profiler_capture_holds_parts_hop_legs_and_http_spans_with_the_trace_id(tmp_path):
+    """A capture's host plane carries the second level inside the first, the
+    hop's legs as spans of their own (entered on one thread, left on another)
+    and the transport's ``http.*`` spans; parse and reply carry the request's
+    trace id while a capture runs."""
+    import jax
+    from aiohttp.test_utils import TestClient, TestServer
+    from jax.profiler import ProfileData
+
+    from seldon_core_tpu.transport.rest import make_component_app
+
+    old = get_tracer()
+    set_tracer(Tracer(enabled=True))
+    s = make_server()
+    app = make_component_app(s, metrics=MetricsRegistry(deployment="d", predictor="p"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+
+    async def go():
+        async with TestClient(TestServer(app)) as client:
+            body = {"prompt": PROMPTS[1], "max_new_tokens": 6}
+            await client.post("/v1/generate", json=body)       # compiled and warm
+            jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+            try:
+                plain = await (await client.post("/v1/generate", json=body)).json()
+                resp = await client.post("/v1/generate", json={**body, "stream": True})
+                await resp.read()
+                await client.get("/metrics")
+            finally:
+                jax.profiler.stop_trace()
+            return plain["trace_id"], resp.headers["X-Trace-Id"]
+
+    try:
+        plain_id, stream_id = asyncio.run(go())
+    finally:
+        set_tracer(old)
+        svc = getattr(s, "_batcher_service", None)
+        if svc is not None:
+            svc.close()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)[-1]
+    events = [(e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats))
+              for plane in ProfileData.from_file(path).planes if plane.name == "/host:CPU"
+              for line in plane.lines for e in line.events
+              if e.name.startswith(("llm.", "http."))]
+    names = {n for _s, _e, n, _st in events}
+    assert {"llm.dispatch.pages", "llm.dispatch.call", "llm.dispatch.book",
+            "llm.drain_wait.asides", "llm.emit.slots", "llm.emit.finish", "llm.prefill.build",
+            "llm.prefill.call", "llm.prefill.activate", "llm.hop.loop", "llm.hop.wake_worker",
+            "llm.hop.wake_loop", "http.parse", "http.sse_write", "http.reply",
+            "http.scrape"} <= names
+    assert not [n for n in names if n.startswith(("llm.admit.", "llm.first_token"))
+                and n.count(".") > 1]
+
+    def spans(name):
+        return [(s0, e0) for s0, e0, n, _st in events if n == name]
+
+    def nested(inner, outer):
+        got = [any(o0 <= s0 and e0 <= o1 for o0, o1 in spans(outer)) for s0, e0 in spans(inner)]
+        return sum(got), len(got)
+
+    # a part lies inside its phase; the legs and the loop's own stretch inside a turn
+    # (one cut by the capture's edge may miss its parent)
+    for inner, outer in (("llm.dispatch.call", "llm.dispatch"), ("llm.emit.slots", "llm.emit"),
+                         ("llm.drain_wait.asides", "llm.drain_wait"),
+                         ("llm.prefill.call", "llm.prefill"), ("llm.hop.loop", "llm.turn"),
+                         ("llm.hop.wake_worker", "llm.turn"), ("llm.hop.wake_loop", "llm.turn")):
+        inside, n = nested(inner, outer)
+        assert inside >= n - 2 > 0, (inner, inside, n)
+    # a leg ends where the worker's phase begins: wake_worker never overlaps a phase
+    phase_spans = sorted(spans("llm.dispatch") + spans("llm.emit") + spans("llm.prefill"))
+    for s0, e0 in spans("llm.hop.wake_worker"):
+        assert not any(p0 < e0 and s0 < p1 for p0, p1 in phase_spans)
+    ids = {what: {st.get("trace_id") for _s, _e, n, st in events if n == what}
+           for what in ("http.parse", "http.reply", "http.sse_write", "http.scrape")}
+    assert ids["http.parse"] == ids["http.reply"] == {plain_id, stream_id}
+    assert ids["http.sse_write"] == {None} == ids["http.scrape"]

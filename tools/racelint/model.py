@@ -408,7 +408,9 @@ def _spawn_targets(call: ast.Call, mm: ModuleModel):
             yield call.args[1], CTX_THREAD
     elif term == "submit" and isinstance(f, ast.Attribute) and call.args:
         yield call.args[0], CTX_THREAD
-    elif d == "asyncio.to_thread" and call.args:
+    elif (d == "asyncio.to_thread" or term == "_to_thread") and call.args:
+        # asyncio's, or a class's own wrapper of it under its name
+        # (runtime/batcher.py ContinuousBatcher._to_thread stamps the hand-off)
         yield call.args[0], CTX_THREAD
     elif term == "run_in_executor" and len(call.args) >= 2:
         yield call.args[1], CTX_THREAD
@@ -448,7 +450,7 @@ def _is_spawn_call(call: ast.Call, mm: ModuleModel) -> bool:
             or d in ("futures.ThreadPoolExecutor",
                      "concurrent.futures.ThreadPoolExecutor")
     return d in ("asyncio.to_thread", "asyncio.run_coroutine_threadsafe") \
-        or term in ("run_in_executor", "submit")
+        or term in ("run_in_executor", "submit", "_to_thread")
 
 
 class _FunctionWalker:
