@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import dataclasses
 import gc
 import json
 import os
@@ -75,8 +76,8 @@ def four_bits(params):
         if not isinstance(leaf, QuantizedTensor):
             return leaf
         q = np.clip(np.rint(np.asarray(leaf.q, np.float32) * (7.0 / 127.0)), -8, 7).astype(np.int8)
-        return QuantizedTensor(q, np.asarray(leaf.scale) * np.float32(127.0 / 7.0),
-                               leaf.orig_dtype, leaf.out_major)
+        return dataclasses.replace(
+            leaf, q=q, scale=np.asarray(leaf.scale) * np.float32(127.0 / 7.0))
 
     return jax.tree.map(visit, params, is_leaf=lambda x: isinstance(x, QuantizedTensor))
 

@@ -1325,6 +1325,8 @@ class Transformer(nn.Module):
         each layer slices its own factors out of the pool and applies one
         gather+einsum pair per adapted projection (``lora_delta``).
         adapter id 0 is the reserved zero-delta identity."""
+        from seldon_core_tpu.ops.quantize import QuantizedTensor, dequantize_array, lookup_rows
+
         cfg = self.cfg
         b, s = tokens.shape
         if positions is None:
@@ -1336,7 +1338,9 @@ class Transformer(nn.Module):
             "tok_embeddings", nn.initializers.normal(stddev=0.02), (cfg.vocab_size, cfg.dim),
             jnp.float32, axes=("vocab", "embed"),
         )
-        x = emb.astype(cfg.dtype)[tokens]
+        # an int8 table arrives as it is held: its rows are gathered, then
+        # dequantized (ops/quantize.py, "a row lookup")
+        x = lookup_rows(emb, tokens, cfg.dtype)
         x = enter_streams(with_sharding_constraint(x, ("batch", "seq", "embed")), cfg)
         valid = None
         if cfg.n_experts > 0:
@@ -1363,7 +1367,8 @@ class Transformer(nn.Module):
         hidden = leave_streams(x, cfg)
         x = RMSNorm(cfg.dim, cfg.norm_eps, name="norm")(hidden)
         if cfg.tie_embeddings:
-            head = emb.T
+            # a plain matmul wants the floating matrix (test configs only)
+            head = (dequantize_array(emb) if isinstance(emb, QuantizedTensor) else emb).T
         else:
             head = param_with_axes(
                 "lm_head", nn.initializers.normal(stddev=0.02), (cfg.dim, cfg.vocab_size),
@@ -1375,7 +1380,8 @@ class Transformer(nn.Module):
             if caches is not None:
                 raise ValueError("the MTP module runs cache-less: serving from it is not wired")
             after = tokens if next_tokens is None else next_tokens
-            mtp = MTPModule(cfg, name="mtp")(hidden, emb.astype(cfg.dtype)[after], positions, valid)
+            mtp = MTPModule(cfg, name="mtp")(
+                hidden, lookup_rows(emb, after, cfg.dtype), positions, valid)
             if next_tokens is not None:
                 return logits, new_caches, mtp.astype(jnp.float32) @ head
         return logits, new_caches

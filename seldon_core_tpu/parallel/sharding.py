@@ -9,6 +9,7 @@ reference's replica-per-pod scaling (SURVEY.md §2 parallelism note).
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 from typing import Any, Callable, Optional, Sequence, Tuple
 
@@ -64,6 +65,8 @@ def logical_axes_of(abstract):
 
 # an output axis that the consumer splits into attention heads
 HEAD_SPLIT_AXES = ("heads", "kv_heads")
+# a first axis whose rows the consumer looks up by index (token ids)
+ROW_LOOKUP_AXES = ("vocab",)
 
 
 def _leaves_whose_spec(params: Any, logical_specs: Any, holds) -> Any:
@@ -87,6 +90,17 @@ def head_split_outputs(params: Any, logical_specs: Any):
     the module names no axes."""
     return _leaves_whose_spec(
         params, logical_specs, lambda s: len(s) == 2 and s[-1] in HEAD_SPLIT_AXES)
+
+
+def row_lookups(params: Any, logical_specs: Any):
+    """Per leaf of ``params``: is it a table whose ROWS are looked up by index
+    (models/transformer.py's token embeddings, ``[vocab, embed]``; the head,
+    ``[embed, vocab]``, is a matmul and is not)? No compiler pushes a dequant
+    through a gather, so such a leaf reaches its module int8 and the rows are
+    dequantized after the lookup (ops/quantize.py ``lookup_rows``). No leaf
+    where the module names no axes."""
+    return _leaves_whose_spec(
+        params, logical_specs, lambda s: len(s) == 2 and s[0] in ROW_LOOKUP_AXES)
 
 
 def float32_leaves(params: Any, logical_specs: Any):
@@ -150,12 +164,9 @@ def shard_params(params: Any, mesh, logical_specs: Any, rules=DEFAULT_LOGICAL_RU
                     mesh, P(mesh_spec[0], last) if p.stacked else P(last))
             else:
                 wsh = ssh = replicated
-            out.append(QuantizedTensor(
-                q=jax.device_put(p.q, wsh),
-                scale=jax.device_put(p.scale, ssh),
-                orig_dtype=p.orig_dtype,
-                out_major=p.out_major,
-            ))
+            # (replace: the static metadata rides along, whatever it holds)
+            out.append(dataclasses.replace(
+                p, q=jax.device_put(p.q, wsh), scale=jax.device_put(p.scale, ssh)))
         else:
             out.append(jax.device_put(p, to_sharding(s) if s is not None else replicated))
     return jax.tree.unflatten(treedef_p, out)

@@ -775,23 +775,27 @@ class LLMServer(SeldonComponent):
                 raise SeldonError(f"unsupported quantize={self.quantize!r} (int8 only)", status_code=500)
             from seldon_core_tpu.ops.quantize import (
                 QuantizedTensor, dequantize_params, quantize_params)
-            from seldon_core_tpu.parallel.sharding import head_split_outputs
+            from seldon_core_tpu.parallel.sharding import head_split_outputs, row_lookups
 
             # the projections that feed the head split are held output-major,
-            # the order their consumer reads (ops/quantize.py)
+            # the order their consumer reads; the embedding table is marked as
+            # the row lookup it is (ops/quantize.py)
             if streamed:
                 params = self._streamed_quantized_init()
             else:
-                params = quantize_params(params, out_major=head_split_outputs(
-                    params, self._logical_axes()), keep=keep)
+                axes = self._logical_axes()
+                params = quantize_params(
+                    params, out_major=head_split_outputs(params, axes), keep=keep,
+                    lookup=row_lookups(params, axes))
             is_q = lambda x: isinstance(x, QuantizedTensor)  # noqa: E731
             held = sum(is_q(leaf) and leaf.out_major
                        for leaf in jax.tree.leaves(params, is_leaf=is_q))
             logger.info("int8 weights: %d leaves held output-major "
                         "(the q/k/v projections)", held)
-            # expert stacks stay int8 inside the programs: MoEFFN's grouped
-            # matmul takes them as they are (ops/quantize.py)
-            self._dequant = partial(dequantize_params, keep_stacks=True)
+            # expert stacks and the embedding table stay int8 inside the
+            # programs: MoEFFN's grouped matmul and the token lookup take them
+            # as they are (ops/quantize.py)
+            self._dequant = partial(dequantize_params, keep_consumed=True)
 
         if self.mesh is not None:
             from seldon_core_tpu.parallel.sharding import shard_params
@@ -923,25 +927,28 @@ class LLMServer(SeldonComponent):
 
         from seldon_core_tpu.models.transformer import draw_small_leaf
         from seldon_core_tpu.ops.quantize import _register_pytree, quantize_array
-        from seldon_core_tpu.parallel.sharding import float32_leaves, head_split_outputs
+        from seldon_core_tpu.parallel.sharding import (
+            float32_leaves, head_split_outputs, row_lookups)
 
         _register_pytree()  # jit returns QuantizedTensor leaves
         target = jnp.dtype(self._cfg.dtype) if self.param_dtype == "auto" else (
             jnp.dtype(self.param_dtype) if self.param_dtype else jnp.float32
         )
 
-        @_partial(jax.jit, static_argnums=(1, 2, 3))
-        def make_quantized(key, shape, std, out_major):
+        @_partial(jax.jit, static_argnums=(1, 2, 3, 4))
+        def make_quantized(key, shape, std, out_major, lookup):
             w = jax.random.normal(key, shape, jnp.float32) * std
-            return quantize_array(w.astype(target), out_major=out_major)
+            return quantize_array(w.astype(target), out_major=out_major, lookup=lookup)
 
         shapes = self._init_shapes()
         flat, treedef = tree_flatten_with_path(shapes)
-        transposed = jax.tree.leaves(head_split_outputs(shapes, self._logical_axes()))
-        kept = jax.tree.leaves(float32_leaves(shapes, self._logical_axes()))
+        axes = self._logical_axes()
+        transposed = jax.tree.leaves(head_split_outputs(shapes, axes))
+        kept = jax.tree.leaves(float32_leaves(shapes, axes))
+        indexed = jax.tree.leaves(row_lookups(shapes, axes))
         root = jax.random.PRNGKey(self.seed)
         leaves = []
-        for (path, spec), out_major, keep in zip(flat, transposed, kept):
+        for (path, spec), out_major, keep, lookup in zip(flat, transposed, kept, indexed):
             name = keystr(path)
             key = jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
             if keep:
@@ -955,7 +962,7 @@ class LLMServer(SeldonComponent):
                     # is k = W_UK c, so its fan-in is the latent axis
                     fan_in = spec.shape[-1]
                 leaves.append(make_quantized(
-                    key, spec.shape, 1.0 / float(fan_in) ** 0.5, out_major))
+                    key, spec.shape, 1.0 / float(fan_in) ** 0.5, out_major, lookup))
             elif jnp.issubdtype(spec.dtype, jnp.floating):
                 fill = 1.0 if ("norm" in name.lower() or "scale" in name.lower()
                                or name.lower().endswith("weight']")) else 0.0

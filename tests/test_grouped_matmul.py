@@ -131,7 +131,7 @@ def test_moeffn_through_the_kernel_is_moeffn_through_ragged_dot(monkeypatch):
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 64), jnp.float32).astype(jnp.bfloat16)
     valid = jnp.arange(12)[None, :] < jnp.asarray([12, 5])[:, None]   # 7 rows are padding
     params = dequantize_params(quantize_params(ffn.init(jax.random.PRNGKey(1), x)),
-                               keep_stacks=True)
+                               keep_consumed=True)
     assert params["params"]["w1"].q.dtype == jnp.int8
     want, sown = ffn.apply(params, x, valid, mutable=["moe"])
     assert int(sown["moe"]["tile_rows"][0]) == 0

@@ -257,9 +257,9 @@ def test_stack_keeps_a_scale_per_matrix():
         step = np.abs(w).max(axis=0) / 127.0
         assert (np.abs(back[e] - w) <= step / 2 + 1e-9).all()
     assert np.abs(back[1] - small).max() < 3e-5       # not the big one's 1e-2
-    # keep_stacks: the stack stays int8 for a consumer that takes it so
+    # keep_consumed: the stack stays int8 for a consumer that takes it so
     kept = dequantize_params({"w": qt, "m": quantize_params({"m": jnp.asarray(big)})["m"]},
-                             keep_stacks=True)
+                             keep_consumed=True)
     assert isinstance(kept["w"], QuantizedTensor) and kept["m"].dtype == jnp.float32
 
 
@@ -391,7 +391,7 @@ def test_checkpoint_and_streamed_init_hold_the_same_tree(monkeypatch, caplog):
     import logging
 
     import seldon_core_tpu.servers.llmserver as llmserver_mod
-    from seldon_core_tpu.parallel.sharding import head_split_outputs
+    from seldon_core_tpu.parallel.sharding import head_split_outputs, row_lookups
 
     monkeypatch.setattr(llmserver_mod, "STREAM_INIT_THRESHOLD_BYTES", 0)
     with caplog.at_level(logging.INFO, logger="seldon_core_tpu.servers.llmserver"):
@@ -400,7 +400,8 @@ def test_checkpoint_and_streamed_init_hold_the_same_tree(monkeypatch, caplog):
     is_q = lambda x: isinstance(x, QuantizedTensor)  # noqa: E731
     floats = dequantize_params(streamed._params)  # the checkpoint these weights would be
     from_checkpoint = quantize_params(
-        floats, out_major=head_split_outputs(floats, streamed._logical_axes()))
+        floats, out_major=head_split_outputs(floats, streamed._logical_axes()),
+        lookup=row_lookups(floats, streamed._logical_axes()))
     assert (jax.tree.structure(from_checkpoint) == jax.tree.structure(streamed._params))
     for (path, a), b in zip(
             jax.tree_util.tree_flatten_with_path(streamed._params, is_leaf=is_q)[0],
@@ -410,6 +411,7 @@ def test_checkpoint_and_streamed_init_hold_the_same_tree(monkeypatch, caplog):
             assert np.array_equal(np.asarray(a), np.asarray(b)), name
             continue
         assert a.out_major == b.out_major == (path[-1].key in HELD_OUT_MAJOR), name
+        assert a.lookup == b.lookup == (path[-1].key == "tok_embeddings"), name
         assert np.array_equal(np.asarray(a.q), np.asarray(b.q)), name
         np.testing.assert_allclose(np.asarray(a.scale), np.asarray(b.scale), rtol=1e-6)
 
@@ -495,3 +497,135 @@ def test_int8_dense_takes_an_out_major_leaf():
     a = int8_dense(x, quantize_array(w))
     b = int8_dense(x, quantize_array(w, out_major=True))
     assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# a table whose rows are looked up (the token embeddings): it reaches the module
+# int8, the int8 rows are gathered and those alone dequantized; the same values
+# as the rows of the dequantized table, to the bit
+# ---------------------------------------------------------------------------
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("held", ["bfloat16", "float32"])
+def test_gathered_rows_equal_the_dequantized_tables_rows(held, dtype):
+    """Gather-then-dequantize is dequantize-then-gather bit for bit, whatever
+    dtype the table was quantized from and whatever the module computes in:
+    repeated tokens, the first and the last row, a [b, s] batch; eagerly and
+    inside a jit; and a floating table is indexed as it always was."""
+    from seldon_core_tpu.ops.quantize import dequantize_array, lookup_rows, quantize_array
+
+    vocab, dim = 97, 48
+    w = jnp.asarray(np.random.default_rng(7).normal(0, 0.3, size=(vocab, dim)), held)
+    table = quantize_array(w, lookup=True)
+    assert table.lookup and table.consumed_int8 and not table.stacked and not table.out_major
+    assert table.q.shape == (vocab, dim) and table.scale.shape == (dim,)
+    assert jax.jit(lambda t: t)(table).lookup and jax.tree.map(lambda x: x, table).lookup
+    def whole_table(t, index):       # the parent's expression
+        return dequantize_array(t).astype(dtype)[index]
+
+    def gathered(t, index):
+        return lookup_rows(t, index, dtype)
+
+    for index in ([3, 3, 3, 3], [0], [vocab - 1], [0, vocab - 1, 0, vocab - 1, 41],
+                  [[5, 9, 9, 0], [vocab - 1, 1, 5, 5]]):
+        index = jnp.asarray(index, jnp.int32)
+        # like with like: inside a jit XLA may keep the product's excess
+        # precision through the cast on both sides, which no eager op does
+        for run in (lambda f: f, jax.jit):
+            got, want = run(gathered)(table, index), run(whole_table)(table, index)
+            assert got.shape == index.shape + (dim,) and got.dtype == want.dtype == jnp.dtype(dtype)
+            assert np.array_equal(_bits(got), _bits(want))
+        assert np.array_equal(_bits(lookup_rows(w, index, dtype)), _bits(w.astype(dtype)[index]))
+    with pytest.raises(ValueError):
+        quantize_array(jnp.zeros((2, 4, 4)), lookup=True)
+    with pytest.raises(ValueError):
+        quantize_array(w, out_major=True, lookup=True)
+
+
+def test_the_lookup_table_is_read_off_the_logical_axes():
+    """Which leaf: the one whose FIRST axis is the vocabulary (rows that token
+    ids index), by the module's own axis names: the embeddings, not the head
+    (``[embed, vocab]``, a matmul) and no leaf of a module that names no axes.
+    ``dequantize_params(keep_consumed=True)`` keeps it and the stacks int8 and
+    dequantizes the rest; without the flag everything comes back floating."""
+    from seldon_core_tpu.parallel.sharding import logical_axis_tree, row_lookups
+
+    module = get_model("transformer", n_experts=4, n_experts_per_token=2, **TINY)
+    shapes = jax.eval_shape(module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    axes = logical_axis_tree(module, jax.ShapeDtypeStruct((1, 8), jnp.int32))
+    chosen = row_lookups(shapes, axes)
+    flat = {jax.tree_util.keystr(path): v
+            for path, v in jax.tree_util.tree_flatten_with_path(chosen)[0]}
+    assert len(flat) == len(jax.tree.leaves(shapes))
+    assert {k for k, v in flat.items() if v} == {"['params']['tok_embeddings']"}
+    assert not any(jax.tree.leaves(row_lookups(shapes, None)))
+
+    params = module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    held = quantize_params(params, lookup=row_lookups(params, axes))
+    kept = dequantize_params(held, keep_consumed=True)["params"]
+    assert isinstance(kept["tok_embeddings"], QuantizedTensor) and kept["tok_embeddings"].lookup
+    assert isinstance(kept["layer_0"]["moe"]["w1"], QuantizedTensor)
+    assert not isinstance(kept["lm_head"], QuantizedTensor)
+    assert not isinstance(kept["layer_0"]["attention"]["wo"], QuantizedTensor)
+    assert not any(isinstance(leaf, QuantizedTensor) for leaf in jax.tree.leaves(
+        dequantize_params(held), is_leaf=lambda x: isinstance(x, QuantizedTensor)))
+
+
+@pytest.mark.parametrize("case,extra", [
+    ("untied", {}), ("tied", {"tie_embeddings": True}), ("mtp", {"mtp_layers": 1})])
+def test_served_logits_equal_the_whole_table_dequants(case, extra):
+    """A quantized LLMServer's prefill then decode, the table gathered int8 (as
+    load() marks it), against the parent's expression on the same tree (the
+    table dequantized whole ahead of the module, then indexed): the same
+    logits and the same caches exactly, with an untied head, a tied one (which
+    dequantizes the table for its matmul inside the module) and, cache-less,
+    the MTP module's second lookup."""
+    import dataclasses
+
+    from seldon_core_tpu.servers.llmserver import LLMServer
+
+    server = LLMServer(model="transformer", model_kwargs=dict(TINY, **extra),
+                       init_random=True, quantize="int8", seed=5, len_buckets=(16,),
+                       batch_buckets=(1,), temperature=0.0, eos_id=-1, max_new_tokens=6)
+    server.load()
+    is_q = lambda x: isinstance(x, QuantizedTensor)  # noqa: E731
+    table = server._params["params"]["tok_embeddings"]
+    assert table.lookup and ("lm_head" in server._params["params"]) == (case != "tied")
+    assert is_q(server._dequant(server._params)["params"]["tok_embeddings"])
+    as_before = jax.tree.map(
+        lambda t: dataclasses.replace(t, lookup=False) if is_q(t) else t,
+        server._params, is_leaf=is_q)
+    assert not is_q(server._dequant(as_before)["params"]["tok_embeddings"])
+
+    def same(got, want):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+            assert a.dtype == b.dtype and np.array_equal(np.asarray(a), np.asarray(b))
+
+    vocab = TINY["vocab_size"]
+    prompt = [5, 9, 9, 0, vocab - 1, 7]     # a repeat, the first row, the last
+    tokens = jnp.asarray([prompt], jnp.int32)
+    positions = jnp.arange(len(prompt))[None, :]
+    prefill = server._get_prefill(1, len(prompt), 16)
+    got, got_cache = prefill(server._params, tokens, positions)
+    want, want_cache = prefill(as_before, tokens, positions)
+    assert float(jnp.abs(want).max()) > 0.1
+    same((got, got_cache), (want, want_cache))
+    step = server._get_extend(1, 1, 16)
+    last = jnp.argmax(got[:, -1:], axis=-1).astype(jnp.int32)
+    at = jnp.asarray([[len(prompt)]], jnp.int32)
+    same(step(server._params, got_cache, last, at, jnp.asarray([len(prompt)])),
+         step(as_before, want_cache, last, at, jnp.asarray([len(prompt)])))
+    if case == "mtp":
+        whole = jax.jit(lambda p: server._module.apply(
+            server._dequant(p), tokens[:, :-1], next_tokens=tokens[:, 1:]))
+        served = whole(server._params)
+        assert len(served) == 3 and served[2].shape == (1, len(prompt) - 1, vocab)
+        same(served, whole(as_before))
+    out = server.generate([prompt], max_new_tokens=6)["tokens"][0]
+    server._params = as_before
+    assert server.generate([prompt], max_new_tokens=6)["tokens"][0] == out

@@ -15,6 +15,13 @@ Second (PR 30): the routed experts of OLMoE and DeepSeek-V2-Lite run the repo's
 own grouped-matmul kernel (ops/grouped_matmul.py), three calls an MoE layer, on
 the int8 stacks as they are held; XLA's own ``ragged-dot-none`` kernel (a 256-row
 tile, 40.6 ms of a 61.9 ms DeepSeek chunk) is in no step program.
+
+Third (PR 34): no step or chunk program writes a floating copy of the embedding
+table. Dequantized ahead of the token lookup, the whole ``[vocab, dim]`` table
+was converted and written out in bf16 to read 32 rows of it
+(``multiply_convert_fusion bf16[131072,3584]``: 2.4 ms of a 9 ms Xing4.0 step,
+booked as "the head's copy" until the operands were read here); the table
+reaches the module int8 and the int8 rows are gathered (ops/quantize.py).
 """
 
 import re
@@ -53,8 +60,11 @@ XING4 = dict(vocab_size=256, dim=3584, n_layers=2, n_heads=32, n_kv_heads=32, ff
              v_head_dim=128, norm_eps=1e-6, hc_mult=4,
              rope_scaling={"type": "yarn", "factor": 64, "original_max_position_embeddings": 4096,
                            "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1})
+# ... and Mistral's own vocabulary for what IS looked at there: a table of a few
+# thousand rows is prefetched whole into on-chip memory and shows nothing
+MISTRAL_VOCAB = dict(MISTRAL, vocab_size=32000)
 CONFIGS = {"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
-           "xing4": XING4}
+           "xing4": XING4, "mistral_vocab": MISTRAL_VOCAB}
 PAGE, POOL_PAGES = 64, 514
 
 
@@ -143,11 +153,11 @@ _INSTR = re.compile(
     r"^\s*(ROOT\s+)?%?([\w.\-]+)\s+=\s+(\w+)\[([\d,]*)\]\S*\s+([\w\-]+)\((.*)$")
 
 
-def weight_copies(hlo: str, weight_shapes) -> list:
-    """Instructions that are executed as ops of their own (those of the entry
-    computation and of loop bodies, not the insides of a fusion), are a copy or
-    a transpose, or a fusion whose root is one, and whose result is a floating
-    array of a weight's shape."""
+def own_ops(hlo: str) -> list:
+    """(name, dtype, shape, opcode, operands and the rest of the line, the
+    computation it is in) of the instructions that are executed as ops of their
+    own: those of the entry computation and of loop bodies, not the insides of
+    a fusion. A fusion's opcode is its root's."""
     computations, name = {}, None
     for line in hlo.splitlines():
         head = _HEAD.match(line)
@@ -167,14 +177,26 @@ def weight_copies(hlo: str, weight_shapes) -> list:
         if name in fused:
             continue
         for _, instr, dtype, shape, opcode, rest in instrs:
-            if dtype not in FLOATS or shape not in weight_shapes:
-                continue
             if opcode == "fusion":
                 called = re.search(r"calls=%?([\w.\-]+)", rest)
                 opcode = roots.get(called.group(1)) if called else None
-            if opcode in ("copy", "transpose"):
-                found.append(f"{instr} = {dtype}{list(shape)} in {name}")
+            found.append((instr, dtype, shape, opcode, rest, name))
     return found
+
+
+def floating_arrays(hlo: str, shapes) -> list:
+    """The ops of their own whose result is a floating array of one of
+    ``shapes``, whatever computes it."""
+    return [f"{instr} = {dtype}{list(shape)} in {name}"
+            for instr, dtype, shape, _, _, name in own_ops(hlo)
+            if dtype in FLOATS and shape in shapes]
+
+
+def weight_copies(hlo: str, weight_shapes) -> list:
+    """Of those, the copies and transposes, and the fusions whose root is one."""
+    return [f"{instr} = {dtype}{list(shape)} in {name}"
+            for instr, dtype, shape, opcode, _, name in own_ops(hlo)
+            if dtype in FLOATS and shape in weight_shapes and opcode in ("copy", "transpose")]
 
 
 def test_the_parser_sees_a_weight_copy():
@@ -355,3 +377,70 @@ def test_the_latent_read_walks_the_live_pages_and_holds_no_view(v5e, servers, co
         assert f"bf16{view}" not in hlo and f"f32{view}" not in hlo, view
     if program == "decode_step":
         assert exe.memory_analysis().temp_size_in_bytes < sequences * pages * PAGE * 640 * 2
+
+
+def test_the_parser_sees_a_dequantized_table():
+    """The check itself, on the parent's own lines (PR 33's Mistral step at
+    ``vocab_size=32000``): the table's dequant, an op of its own whose operands
+    are ``tok_embeddings``' values and scales, is found; the head's dequant, a
+    fusion INSIDE the matmul's ``kOutput`` fusion that reads ``lm_head``'s int8
+    parameter, is no op of its own and is not."""
+    hlo = """
+%fused_computation (param_0.2: bf16[32000,4096], param_1.34: s32[1024]) -> bf16[32,4096] {
+  ROOT %gather.15 = bf16[32,4096]{1,0:T(8,128)(2,1)} gather(%param_0.2, %param_1.34), offset_dims={1}
+}
+%fused_computation.12 (param_0.32: s8[32000,4096], param_1.50: f32[4096]) -> bf16[32000,4096] {
+  ROOT %convert.9 = bf16[32000,4096]{1,0:T(8,128)(2,1)} convert(%mul)
+}
+%fused_computation.10 (param_0.31: s8[4096,32000], param_1.49: f32[32000]) -> f32[4096,32000] {
+  ROOT %multiply.4 = f32[4096,32000]{1,0:T(8,128)} multiply(%c, %b)
+}
+%fused_computation.9 (param_0.217: s8[4096,32000], param_1.226: f32[32000], param_2.148: bf16[32,4096]) -> f32[32,32000] {
+  %fusion.10 = f32[4096,32000]{1,0:T(8,128)} fusion(%param_0.217, %param_1.226), kind=kLoop, calls=%fused_computation.10
+  ROOT %convolution.8 = f32[32,32000]{1,0:T(8,128)S(1)} convolution(%fusion.59, %fusion.10), dim_labels=bf_io->bf
+}
+ENTRY %main.34 (e: s8[32000,4096], s: f32[4096], h: s8[4096,32000]) -> f32[32,32000] {
+  %multiply_convert_fusion = bf16[32000,4096]{1,0:T(8,128)(2,1)} fusion(%params__params____tok_embeddings___0_.1, %params__params____tok_embeddings___1_.1), kind=kLoop, calls=%fused_computation.12
+  %fusion = bf16[32,4096]{1,0:T(8,128)(2,1)S(1)} fusion(%multiply_convert_fusion, %pad_clamp_fusion.1), kind=kCustom, calls=%fused_computation
+  ROOT %fusion.9 = f32[32,32000]{1,0:T(8,128)S(1)} fusion(%params__params____lm_head___0_.1, %copy-done.14), kind=kOutput, calls=%fused_computation.9
+}
+"""
+    found = floating_arrays(hlo, {(32000, 4096), (4096, 32000)})
+    assert [f.split(" ")[0] for f in found] == ["multiply_convert_fusion"]
+    assert weight_copies(hlo, {(32000, 4096), (4096, 32000)}) == []
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_the_lookup_gathers_int8_rows_and_no_op_writes_the_table(v5e, servers, program):
+    """At Mistral's vocabulary no op of the step or the chunk yields a floating
+    array of the table's shape or the head's (``multiply_convert_fusion
+    bf16[32000,4096]``, 0.61 ms of every Mistral step and chunk; 262 MB of
+    scratch): the lookup is a gather out of ``tok_embeddings``' int8 values into
+    int8 rows, which the consumers dequantize, and the head's matmul is the
+    ``kOutput`` fusion that reads ``lm_head``'s int8 parameter, as it was."""
+    server = servers("mistral_vocab")
+    cfg = server._cfg
+    vocab, dim = cfg.vocab_size, cfg.dim
+    table, head = server._params["params"]["tok_embeddings"], server._params["params"]["lm_head"]
+    assert table.lookup and table.q.shape == (vocab, dim) and table.scale.shape == (dim,)
+    assert not head.lookup and head.q.shape == (dim, vocab)
+    exe = compiled(server, program, v5e)
+    hlo = exe.as_text()
+    assert floating_arrays(hlo, {(vocab, dim), (dim, vocab)}) == []
+    assert exe.memory_analysis().temp_size_in_bytes < vocab * dim   # no copy in scratch either
+    rows = 32 if program == "decode_step" else 256
+    ops = own_ops(hlo)
+    lookups = [op for op in ops if "tok_embeddings___0_" in op[4] and op[3] != "parameter"]
+    assert [(op[1], op[2]) for op in lookups] == [("s8", (rows, dim))], lookups
+    def body(op):   # the text of the computation a fusion calls
+        called = re.search(r"calls=%?([\w.\-]+)", op[4]).group(1)
+        text = hlo[hlo.index(f"%{called} ("):]
+        return text[:text.index("\n}")]
+
+    gather = body(lookups[0])
+    assert f"s8[{vocab},{dim}]" in gather.splitlines()[0] and re.search(r" gather\(", gather)
+    heads = [op for op in ops if "lm_head___0_" in op[4] and op[3] != "parameter"]
+    assert [(op[1], op[2][-2:]) for op in heads] == [("f32", (rows, vocab))], heads
+    matmul = body(heads[0])
+    assert "kind=kOutput" in heads[0][4] and re.search(r" convolution\(", matmul)
+    assert f"s8[{dim},{vocab}]" in matmul.splitlines()[0]
