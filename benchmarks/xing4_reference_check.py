@@ -4,6 +4,8 @@ configuration's `reference_tolerance` is set from (perf/configs/<config>.json).
 
     chiprun -- python benchmarks/xing4_reference_check.py \
         --workload xing4-reasoning-decode --probes 12 --wrong-probes 2 --out chiprun_out/pr31r/reference_check.json
+    chiprun -- python benchmarks/xing4_reference_check.py \
+        --workload lfm2-rag-mixed --probes 12 --wrong-probes 2 --out chiprun_out/pr35/reference_check.json
     (then once more with --probes 2 --wrong-probes 2 --only-low: the 4-bit tree in a call of its
     own, because the machine's host holds 40 GiB and a 15-layer tree is 11.5 GB of it)
 
@@ -47,6 +49,23 @@ WRONGS = {
     "no_q_norm": {"q_norm": False}, "no_shared_expert": {"shared": False},
     "largest_expert_left_out": {"leave_out_rank": 0}, "no_mscale_squared": {"scale_mscale": False},
 }
+
+
+def lfm2_wrongs(prompt_tokens: int, chunk: int) -> dict:
+    """The wrong references of a configuration with conv layers (its `work` is
+    "lfm2"), for a probe of ``prompt_tokens`` prefilled in chunks of ``chunk``:
+    the two that break the state's hand-over at a chunk's edge are placed where
+    the probe's chunks end."""
+    padded = -(-prompt_tokens // chunk) * chunk
+    return {
+        "state_zeroed_at_a_chunk_start": {"conv_reset_every": chunk},
+        "state_from_the_chunks_last_row": {"conv_state_pad": (prompt_tokens, padded)},
+        "taps_reversed": {"taps_reversed": True},
+        "gate_b_left_out": {"gate_b": False}, "gate_c_left_out": {"gate_c": False},
+        "qk_norm_over_the_whole_projection": {"qk_norm": "whole"}, "qk_norm_left_out": {"qk_norm": False},
+        "softmax_scores": {"router_score": "softmax"}, "largest_expert_left_out": {"leave_out_rank": 0},
+        "no_selection_bias": {"select_bias": False},
+    }
 
 
 def merge(base: dict, over: dict) -> dict:
@@ -193,8 +212,11 @@ def main() -> None:
               f"{r['behind_max']:.4f}", flush=True)
         save()
 
+    wrongs = WRONGS
+    if cfg.get("work") == "lfm2":
+        wrongs = lfm2_wrongs(probe["prompt_tokens"], server_kw.get("prefill_chunk") or 256)
     for i in range(0 if args.only_low else min(args.wrong_probes, args.probes)):
-        for name, wrong in WRONGS.items():
+        for name, wrong in wrongs.items():
             against(name, params, i, **wrong)
     if args.wrong_probes:
         # a second tree on the host: made late and dropped before the one-off,

@@ -157,6 +157,13 @@ class MetricsRegistry:
             base,
             registry=self.registry,
         )
+        self._state_bytes = Gauge(
+            "seldon_llm_state_bytes",
+            "Bytes of per-slot conv state resident beside the page pool "
+            "(a model with conv layers; fixed, whatever the sequences' lengths)",
+            base,
+            registry=self.registry,
+        )
         self._kv_page_fragmentation = Gauge(
             "seldon_llm_kv_page_fragmentation",
             "Internal fragmentation of allocated KV pages "
@@ -420,6 +427,16 @@ class MetricsRegistry:
                  "Cached rows those calls' attention read visited: the whole "
                  "block-table view, or whole visits of latent attention's "
                  "live-page kernel; over context_tokens it is the over-read"))}
+        # A model with conv layers (models/transformer.py ShortConv): what
+        # went through them, counted on the loop from host integers; absent
+        # for every other model
+        self._conv = {
+            key: Counter(f"seldon_llm_conv_{key}_total", text,
+                         base + ["program"], registry=self.registry)
+            for key, text in (
+                ("rows", "Live rows (tokens) of the step-program calls of a "
+                         "model with conv layers: what EACH conv layer mixed"),
+                ("layer_calls", "Conv layers x step-program calls"))}
         # An MoE model's routing (runtime/batcher.py MoECounters,
         # docs/observability.md "Expert routing"): counted on the loop from
         # arrays that leave the step programs beside their tokens, absent
@@ -993,6 +1010,7 @@ class MetricsRegistry:
         self._kv_page_fragmentation.labels(**self._base()).set(
             stats.get("kv_page_fragmentation", 0.0)
         )
+        self._state_bytes.labels(**self._base()).set(stats.get("state_bytes", 0))
         # counter catch-up from the allocator's own tally (sheds happen on
         # the decode hot path, counted locally — same idiom as
         # seldon_resilience_shed_total)
@@ -1051,6 +1069,9 @@ class MetricsRegistry:
             self._counter_catch_up(self._first_token_reads, n, ready=ready)
         for key, counter in self._attn_context.items():
             for program, n in stats.get(f"attn_{key}", {}).items():
+                self._counter_catch_up(counter, n, program=program)
+        for key, counter in self._conv.items():
+            for program, n in stats.get(f"conv_{key}", {}).items():
                 self._counter_catch_up(counter, n, program=program)
         for program, tally in stats.get("moe_by_program", {}).items():
             for field, n in tally.items():

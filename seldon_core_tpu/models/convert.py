@@ -80,6 +80,30 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
             "qk_norm": True,
         }
     ffn_dim = hf_config.intermediate_size
+    lfm2 = model_type in ("lfm2", "lfm2_moe")
+    if lfm2:
+        # gated short convolutions beside GQA layers, a norm per q / k head; the
+        # dense sibling (lfm2) is what the installed transformers can check
+        if getattr(hf_config, "conv_bias", False):
+            raise ValueError("conv_bias=true is not supported by the native transformer")
+        if model_type == "lfm2" and getattr(hf_config, "block_auto_adjust_ff_dim", False):
+            raise ValueError(
+                "block_auto_adjust_ff_dim=true derives the FFN width from intermediate_size; "
+                "give the config the width itself (block_auto_adjust_ff_dim=false)")
+        moe = {"layer_types": tuple(hf_config.layer_types),
+               "conv_L_cache": int(hf_config.conv_L_cache), "qk_norm": "head"}
+        if model_type == "lfm2_moe":
+            # ASSUMED names (no lfm2_moe in the installed transformers): the
+            # published config.json's keys, read as its model card describes
+            ffn_dim = hf_config.moe_intermediate_size
+            moe.update(
+                n_experts=hf_config.num_experts,
+                n_experts_per_token=hf_config.num_experts_per_tok,
+                router_score="sigmoid", router_bias=bool(hf_config.use_expert_bias),
+                router_renormalize=bool(hf_config.norm_topk_prob), router_renormalize_eps=1e-6,
+                routed_scaling_factor=float(hf_config.routed_scaling_factor),
+                first_dense_layers=int(hf_config.num_dense_layers),
+                dense_ffn_dim=hf_config.intermediate_size)
     if deepseek:
         # V3's router is sigmoid scores + a selection bias (noaux_tc); its
         # config class carries neither key, V2-shaped configs name both
@@ -128,7 +152,7 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
         "ffn_dim": ffn_dim,
         "max_seq_len": hf_config.max_position_embeddings,
         "rope_theta": getattr(hf_config, "rope_theta", 10000.0),
-        "norm_eps": hf_config.rms_norm_eps,
+        "norm_eps": hf_config.norm_eps if lfm2 else hf_config.rms_norm_eps,
         "tie_embeddings": bool(getattr(hf_config, "tie_word_embeddings", False)),
         **({"rope_scaling": rope_scaling} if rope_scaling else {}),
         **moe,
@@ -225,6 +249,66 @@ def convert_llama_state_dict(
         raise ValueError(
             f"unmapped weights in state dict (conversion would drop them): {leftover[:8]}"
         )
+    return {"params": params}
+
+
+def convert_lfm2_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str, Any],
+                            dtype: str = "float32") -> Dict[str, Any]:
+    """HF Lfm2ForCausalLM (and, by ASSUMED names, Lfm2MoeForCausalLM) state
+    dict -> our flax param tree. ``kwargs`` are ``config_kwargs_from_hf``'s.
+    A conv layer: ``conv.in_proj`` [3 dim, dim] transposes to the ONE
+    [dim, 3 dim] product whose thirds are B, C, X in that order; the depthwise
+    ``conv.conv.weight`` [dim, 1, L] drops its middle axis to the taps
+    [dim, L] (torch's order: the last tap weighs the row itself, ours too);
+    ``operator_norm`` is the block's first norm whatever the operator,
+    ``embedding_norm`` the model's LAST. The q / k norms are one weight
+    [head_dim] each. The MoE layers' names (``feed_forward.gate``,
+    ``feed_forward.expert_bias``, ``feed_forward.experts.N.w1/w2/w3``) are
+    ASSUMED: no lfm2_moe is installed to check them against."""
+    t, consumed = _tensor_reader(state_dict, dtype)
+
+    def swiglu(prefix: str) -> Dict[str, Any]:
+        return {name: t(f"{prefix}.{name}.weight").T for name in ("w1", "w2", "w3")}
+
+    params: Dict[str, Any] = {
+        "tok_embeddings": t("model.embed_tokens.weight"),
+        "norm": {"weight": t("model.embedding_norm.weight")},
+    }
+    n_experts = kwargs.get("n_experts", 0)
+    for i, kind in enumerate(kwargs["layer_types"]):
+        hf = f"model.layers.{i}"
+        layer = params[f"layer_{i}"] = {"ffn_norm": {"weight": t(f"{hf}.ffn_norm.weight")}}
+        if kind == "conv":
+            layer["operator_norm"] = {"weight": t(f"{hf}.operator_norm.weight")}
+            layer["conv"] = {"in_proj": t(f"{hf}.conv.in_proj.weight").T,
+                             "taps": t(f"{hf}.conv.conv.weight")[:, 0, :],
+                             "out_proj": t(f"{hf}.conv.out_proj.weight").T}
+        else:
+            layer["attention_norm"] = {"weight": t(f"{hf}.operator_norm.weight")}
+            layer["attention"] = {
+                "wq": t(f"{hf}.self_attn.q_proj.weight").T,
+                "wk": t(f"{hf}.self_attn.k_proj.weight").T,
+                "wv": t(f"{hf}.self_attn.v_proj.weight").T,
+                "wo": t(f"{hf}.self_attn.out_proj.weight").T,
+                "q_norm": {"weight": t(f"{hf}.self_attn.q_layernorm.weight")},
+                "k_norm": {"weight": t(f"{hf}.self_attn.k_layernorm.weight")},
+            }
+        if n_experts and i >= kwargs["first_dense_layers"]:
+            layer["moe"] = {"router": t(f"{hf}.feed_forward.gate.weight").T}
+            if kwargs.get("router_bias"):
+                layer["moe"]["router_bias"] = t(f"{hf}.feed_forward.expert_bias")
+            experts = [swiglu(f"{hf}.feed_forward.experts.{e}") for e in range(n_experts)]
+            for name in ("w1", "w2", "w3"):
+                layer["moe"][name] = np.stack([e[name] for e in experts])
+        else:
+            layer["ffn"] = swiglu(f"{hf}.feed_forward")
+    if not kwargs["tie_embeddings"]:
+        params["lm_head"] = t("lm_head.weight").T
+    leftover = [k for k in state_dict if k not in consumed and not k.endswith("inv_freq")
+                and not (kwargs["tie_embeddings"] and k == "lm_head.weight")]
+    if leftover:
+        raise ValueError(
+            f"unmapped weights in state dict (conversion would drop them): {leftover[:8]}")
     return {"params": params}
 
 
@@ -344,6 +428,8 @@ def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
         kwargs["mtp_layers"] *= has_mtp_weights(state_dict, kwargs)
         variables = convert_deepseek_v2_state_dict(
             state_dict, kwargs, rope_interleaved=getattr(hf_model.config, "rope_interleave", True))
+    elif kwargs.get("layer_types"):
+        variables = convert_lfm2_state_dict(hf_model.state_dict(), kwargs)
     else:
         variables = convert_llama_state_dict(
             hf_model.state_dict(), n_layers=kwargs["n_layers"],
@@ -371,6 +457,8 @@ def convert_checkpoint(hf_path: str, out_dir: str, dtype: str = "bfloat16") -> s
         kwargs["mtp_layers"] *= has_mtp_weights(model.state_dict(), kwargs)
         variables = convert_deepseek_v2_state_dict(
             model.state_dict(), kwargs, dtype, getattr(hf_config, "rope_interleave", True))
+    elif kwargs.get("layer_types"):
+        variables = convert_lfm2_state_dict(model.state_dict(), kwargs, dtype)
     else:
         variables = convert_llama_state_dict(
             model.state_dict(), n_layers=kwargs["n_layers"], dtype=dtype,

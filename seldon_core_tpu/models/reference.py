@@ -84,6 +84,32 @@ the same weights, ``sinkhorn_iters=1`` stops after one iteration,
 ``select_bias=False`` / ``router_score="softmax"`` / ``q_norm=False`` leave
 one piece of the V3 forms out: WRONG models, for showing a tolerance is tight.
 
+LFM2 (``cfg.layer_types``; ``transformers/models/lfm2/modeling_lfm2.py``, the
+dense sibling whose ``Lfm2ShortConv``, ``Lfm2Attention`` and block order are
+LFM2-8B-A1B's too: tests/test_reference_lfm2.py holds this file to
+``Lfm2ForCausalLM`` on converted weights) gives each layer one of two token
+mixers, and the attention a norm per head:
+
+    conv  : [B ; C ; X] = W_in n1 (split in THAT order);  z_t = B_t * X_t
+            v_t = sum_j w_j z_{t-(L-1)+j}, z_{<0} = 0    (the LAST tap weighs row t:
+            torch Conv1d's weight order), an explicit shifted sum over the sequence
+            h = x + W_out (C_t * v_t)
+    attn  : q, k <- RMSNorm over EACH head's values (cfg.qk_norm == "head"; one
+            weight [head_dim] for q, one for k), THEN RoPE; the rest as above
+    router: s = sigmoid(Wr n2); chosen = top-k(s + b); weights s_e / (sum + eps),
+            eps = cfg.router_renormalize_eps (1e-6; DeepSeek-V3's 1e-20 where None)
+
+What this file fixes where LFM2-8B-A1B's config does not: the final norm is the
+tree's ``norm`` (published as ``embedding_norm``, applied after the last
+layer); the head is a leaf of its own (the served tree holds an int8 head beside
+the int8 table; a tied checkpoint converts to two copies). WRONG models of the
+conv layer: ``taps_reversed``, ``gate_b=False`` / ``gate_c=False`` (a gate left
+out), ``conv_reset_every=N`` (the taps do not look back over a multiple of N: the
+state zeroed at every chunk's start), ``conv_state_pad=(n, m)`` (rows from n on
+see, in place of z_{n-1}, z_{n-2}, what the LAST rows of the prompt padded with
+token 0 to m rows hold: the state taken from a padded chunk's last row, not its
+last valid one); of the attention: ``qk_norm="whole"`` / ``qk_norm=False``.
+
 ``follow=`` makes the forward take the experts the SERVED path took (a logits
 probe's ``routing``): where this router's last chosen and first unchosen expert
 score within the served arithmetic's noise of each other, which one is taken is
@@ -105,6 +131,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _f32(leaf, index: Optional[int] = None):
@@ -169,15 +196,23 @@ def _rope(x, theta: float, scaling: Optional[dict] = None):
     return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], axis=-1)
 
 
-def _attention(p: dict, x, cfg):
+def _attention(p: dict, x, cfg, qk_norm=None):
     s = x.shape[0]
     hd = cfg.dim // cfg.n_heads
     q, k, v = x @ _f32(p["wq"]), x @ _f32(p["wk"]), x @ _f32(p["wv"])
-    if cfg.qk_norm:
+    qk_norm = cfg.qk_norm if qk_norm is None else qk_norm
+    if qk_norm == "whole":      # a WRONG model of per-head norms: one norm, the head's weight tiled
+        q = _rms_norm(q, jnp.tile(_f32(p["q_norm"]["weight"]), cfg.n_heads), cfg.norm_eps)
+        k = _rms_norm(k, jnp.tile(_f32(p["k_norm"]["weight"]), cfg.n_kv_heads), cfg.norm_eps)
+    elif qk_norm is True:
         q = _rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
         k = _rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
-    q = _rope(q.reshape(s, cfg.n_heads, hd), cfg.rope_theta)
-    k = _rope(k.reshape(s, cfg.n_kv_heads, hd), cfg.rope_theta)
+    q, k = q.reshape(s, cfg.n_heads, hd), k.reshape(s, cfg.n_kv_heads, hd)
+    if qk_norm == "head":
+        q = _rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
+        k = _rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
+    q = _rope(q, cfg.rope_theta)
+    k = _rope(k, cfg.rope_theta)
     v = v.reshape(s, cfg.n_kv_heads, hd)
     group = cfg.n_heads // cfg.n_kv_heads
     k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
@@ -226,8 +261,53 @@ def _latent_attention(p: dict, x, cfg, scale_mscale: bool = True, block: int = 5
     return jnp.concatenate(out) @ _f32(p["wo"])
 
 
+def _short_conv(p: dict, x, cfg, wrong: dict, seen: Optional[list] = None):
+    """LFM2's gated short convolution over the whole sequence ``x`` [s, C]:
+    the causal taps as an explicit shifted sum. ``seen`` collects z [s, C]
+    (what a served path keeps the last rows of, as its state)."""
+    s, d = x.shape
+    bcx = x @ _f32(p["in_proj"])
+    gate_b, gate_c, xs = bcx[:, :d], bcx[:, d:2 * d], bcx[:, 2 * d:]
+    z = gate_b * xs if wrong["gate_b"] else xs
+    taps = _f32(p["taps"])                      # [C, L]; the last tap weighs the row itself
+    if wrong["taps_reversed"]:
+        taps = taps[:, ::-1]
+    taps_n = taps.shape[1]
+    t = jnp.arange(s)
+    v = jnp.zeros_like(z)
+    for j in range(taps_n):
+        back = taps_n - 1 - j                   # this tap reads z_{t - back}
+        shifted = jnp.concatenate([jnp.zeros((back, d)), z[:s - back]])[:s]
+        if wrong["conv_reset_every"]:           # WRONG: nothing crosses a chunk's start
+            every = wrong["conv_reset_every"]
+            shifted = jnp.where((t // every == (t - back) // every)[:, None], shifted, 0.0)
+        if wrong["conv_state_pad"] is not None and back:
+            # WRONG: rows from n on read, across n, a padded chunk's last rows
+            n, pad_rows = wrong["conv_state_pad"][0], wrong["conv_state_pad"][2][len(seen)]
+            stale = pad_rows[jnp.clip(pad_rows.shape[0] - (n - (t - back)), 0, pad_rows.shape[0] - 1)]
+            shifted = jnp.where(((t >= n) & (t - back < n))[:, None], stale, shifted)
+        v = v + taps[:, j] * shifted
+    if seen is not None:
+        seen.append(z)
+    return ((gate_c * v) if wrong["gate_c"] else v) @ _f32(p["out_proj"])
+
+
+EXPERT_ROWS = 64     # an expert's tokens are computed in whole buckets of this many rows
+
+
 def _swiglu(x, w1, w2, w3):
     return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
+
+
+@jax.jit
+def _add_expert(out, x, share, took, n, w1, w2, w3, e):
+    """``out`` plus expert ``e`` of the stacks on rows ``took`` of ``x`` (the
+    first ``n`` of them count; the rest pad a bucket), each weighed by its
+    ``share``: ONE compiled piece a bucket size, where the eager ops would be
+    a dozen compiles each."""
+    weight = jnp.where(jnp.arange(took.shape[0]) < n, share[took], 0.0)
+    return out.at[took].add(weight[:, None] * _swiglu(
+        x[took], _f32(w1, e), _f32(w2, e), _f32(w3, e)))
 
 
 def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True,
@@ -260,15 +340,21 @@ def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True
     experts = jnp.take_along_axis(took, by_weight, axis=-1)
     weights = jnp.take_along_axis(weights, by_weight, axis=-1)
     if cfg.router_renormalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + (1e-20 if sigmoid else 0.0))
+        eps = getattr(cfg, "router_renormalize_eps", None)
+        eps = (1e-20 if sigmoid else 0.0) if eps is None else eps
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     weights = weights * getattr(cfg, "routed_scaling_factor", 1.0)
     used = weights if leave_out_rank is None else weights.at[:, leave_out_rank].set(0.0)
     out = jnp.zeros_like(x)
     for e in range(n):
         share = jnp.sum(jnp.where(experts == e, used, 0.0), axis=-1)  # [s]; 0 = not chosen
-        if bool(jnp.any(share > 0)):
-            out = out + share[:, None] * _swiglu(
-                x, _f32(p["w1"], e), _f32(p["w2"], e), _f32(p["w3"], e))
+        # the tokens that took expert e, and no other: in whole buckets of
+        # EXPERT_ROWS (the last repeated at weight 0), so that a forward
+        # compiles a handful of shapes and not one an expert a layer
+        rows = np.flatnonzero(np.asarray(share) > 0)
+        if len(rows):
+            took = np.pad(rows, (0, -len(rows) % EXPERT_ROWS), mode="edge")
+            out = _add_expert(out, x, share, took, len(rows), p["w1"], p["w2"], p["w3"], e)
     if "shared" in p and shared:
         f = p["shared"]
         out = out + _swiglu(x, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
@@ -293,9 +379,11 @@ def _mix(p: dict, X, cfg, iters: int):
     return jnp.einsum("si,sic->sc", h_pre, X), h_post, mat
 
 
-def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=None):
+def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=None,
+           seen: Optional[list] = None):
     """One decoder block on the residual ``x`` [s, C], or on the streams
-    [s, n, C] where the layer has mixing parameters and ``streams`` is on."""
+    [s, n, C] where the layer has mixing parameters and ``streams`` is on. A
+    layer that holds a ``conv`` (LFM2) mixes tokens by it, not by attention."""
     latent = getattr(cfg, "kv_lora_rank", 0) > 0
     mixed = x.ndim == 3
     iters = wrong["sinkhorn_iters"] if wrong["sinkhorn_iters"] is not None else getattr(
@@ -312,7 +400,7 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
         if latent:
             return _latent_attention(layer["attention"], n1, cfg, wrong["scale_mscale"],
                                      q_norm=wrong["q_norm"])
-        return _attention(layer["attention"], n1, cfg)
+        return _attention(layer["attention"], n1, cfg, wrong["qk_norm"])
 
     def ffn(n2):
         if moe:
@@ -324,7 +412,11 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
         f = layer["ffn"]
         return _swiglu(n2, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
 
-    x = sub_layer(x, "attention_hc", "attention_norm", attention)
+    if "conv" in layer:
+        x = sub_layer(x, None, "operator_norm",
+                      lambda n1: _short_conv(layer["conv"], n1, cfg, wrong, seen))
+    else:
+        x = sub_layer(x, "attention_hc", "attention_norm", attention)
     return sub_layer(x, "ffn_hc", "ffn_norm", ffn)
 
 
@@ -338,12 +430,25 @@ def _leave(x):
 
 
 WRONG = {"leave_out_rank": None, "scale_mscale": True, "shared": True, "streams": True,
-         "sinkhorn_iters": None, "select_bias": True, "router_score": None, "q_norm": True}
+         "sinkhorn_iters": None, "select_bias": True, "router_score": None, "q_norm": True,
+         "taps_reversed": False, "gate_b": True, "gate_c": True, "conv_reset_every": None,
+         "conv_state_pad": None, "qk_norm": None}
 
 
-def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None):
+def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[list] = None):
     """The main model's hidden state [s, C] after the last block (and the
     streams' exit), before the final norm; and the routing."""
+    if wrong["conv_state_pad"] is not None and len(wrong["conv_state_pad"]) == 2:
+        # what each conv layer's LAST rows hold when the first n tokens are
+        # padded with token 0 to m rows: a pass of its own
+        n, m = wrong["conv_state_pad"]
+        padded: list = []
+        _hidden(p, cfg, list(tokens[:n]) + [0] * (m - n), {**wrong, "conv_state_pad": None},
+                None if follow is None else follow[:0], padded)
+        taps_n = next(_f32(p[f"layer_{i}"]["conv"]["taps"]).shape[1] for i in range(cfg.n_layers)
+                      if "conv" in p[f"layer_{i}"])
+        wrong = {**wrong, "conv_state_pad": (n, m, [z[-(taps_n - 1):] for z in padded])}
+        seen = []
     latent = getattr(cfg, "kv_lora_rank", 0) > 0
     if getattr(cfg, "rope_scaling", None) and not latent:
         raise NotImplementedError("the reference has scaled RoPE for latent attention only")
@@ -352,7 +457,7 @@ def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None):
     x = _enter(_f32(p["tok_embeddings"])[jnp.asarray(tokens, jnp.int32)], cfg, wrong)
     for i in range(cfg.n_layers):
         x = _block(p[f"layer_{i}"], x, cfg, cfg.n_experts > 0 and i >= first_dense, routing, wrong,
-                   follow)
+                   follow, seen)
     return _leave(x), routing
 
 

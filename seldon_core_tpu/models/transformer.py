@@ -96,6 +96,12 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
     return q.astype(dtype) * scale[..., None].astype(dtype)
 
 
+LAYER_KINDS = ("full_attention", "conv")
+STATE_LAYERS_COMPOSE_REFUSAL = (
+    "a 'conv' layer (layer_types) does not compose with latent attention "
+    "(kv_lora_rank > 0), hyper-connections (hc_mult > 1) or an MTP module: "
+    "no model pairs them and no test holds them")
+
 FUSED_NORM_STREAMS_REFUSAL = (
     "fused_norm does not compose with hyper-connections (hc_mult > 1): the "
     "kernel fuses the residual ADD with the ffn norm, and with several streams "
@@ -131,9 +137,10 @@ class TransformerConfig:
     n_experts: int = 0
     n_experts_per_token: int = 2
     router_renormalize: bool = True
-    # RMSNorm on the q and k projections, over the WHOLE projection before
-    # the split into heads (OLMoE), not per head.
-    qk_norm: bool = False
+    # RMSNorm on the q and k projections, before RoPE: True = over the WHOLE
+    # projection before the split into heads (OLMoE); "head" = over EACH
+    # head's values, one weight [head_dim] for q and one for k (LFM2).
+    qk_norm: Any = False
     # The first ``first_dense_layers`` layers of an MoE model keep a dense
     # SwiGLU of width ``dense_ffn_dim`` (DeepSeek's first_k_dense_replace);
     # ``n_shared_experts`` adds to every MoE layer one dense SwiGLU of width
@@ -161,6 +168,17 @@ class TransformerConfig:
     # scores for the top-k CHOICE only and never enters a weight (noaux_tc).
     router_score: str = "softmax"
     router_bias: bool = False
+    # What the renormalisation adds to the k weights' sum: None = 1e-20 under
+    # sigmoid scores (DeepSeek-V3) and nothing under softmax; LFM2 says 1e-6.
+    router_renormalize_eps: Optional[float] = None
+    # The token mixer of each layer (``layer_kind``): None = attention in every
+    # layer; else n_layers of "full_attention" | "conv". A "conv" layer is
+    # LFM2's gated short convolution (ShortConv below): it caches no keys and
+    # values, and keeps conv_L_cache - 1 rows of [dim] a sequence whatever its
+    # length, in the cache tree as a 1-tuple ``(state,)`` beside the attention
+    # layers' (values..., positions) tuples.
+    layer_types: Any = None
+    conv_L_cache: int = 3
     # Hyper-connections (HyperConnection below): hc_mult > 1 residual streams,
     # mixed per token around every sub-layer; the residual matrix is made
     # doubly stochastic by hc_sinkhorn_iters Sinkhorn iterations over
@@ -199,10 +217,46 @@ class TransformerConfig:
             raise ValueError(
                 f"mtp_layers={self.mtp_layers} is not built: one multi-token-prediction "
                 "module (DeepSeek-V3's depth 1) is")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(
+                f"unknown qk_norm {self.qk_norm!r}: expected False, True (over the whole "
+                "projection) or 'head' (over each head)")
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)
+            if len(kinds) != self.n_layers or set(kinds) - set(LAYER_KINDS):
+                raise ValueError(
+                    f"layer_types must name n_layers={self.n_layers} layers, each one of "
+                    f"{LAYER_KINDS}; got {len(kinds)}: {sorted(set(kinds))}")
+            if "conv" in kinds and (self.kv_lora_rank or self.hc_mult > 1 or self.mtp_layers):
+                raise ValueError(STATE_LAYERS_COMPOSE_REFUSAL)
+            if "conv" in kinds and self.conv_L_cache < 2:
+                raise ValueError(f"conv_L_cache={self.conv_L_cache} must be >= 2 (taps)")
 
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    def layer_kind(self, layer: int) -> str:
+        """"conv" or "full_attention"; a layer past the list (the MTP block)
+        is attention."""
+        kinds = self.layer_types
+        return kinds[layer] if kinds is not None and layer < len(kinds) else "full_attention"
+
+    @property
+    def conv_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.n_layers) if self.layer_kind(i) == "conv")
+
+    @property
+    def kv_rows_flat(self) -> bool:
+        """Does the paged bf16 pool hold a token's K (and V) as ONE row of
+        n_kv_heads * head_dim values, [pages, page_size, kvh * hd], the heads
+        split at the read? Where a head is narrower than a 128-lane tile and
+        the row is whole tiles (LFM2: 8 x 64 = 512). Held [.., 8, 64] the
+        chip's compiler lays the pool out pages-minor and copies the whole pool
+        several times a layer a call (compiled for a described v5e, PR 35:
+        ``bf16[2050,64,8,64]{0,3,2,1}``, five 134 MB copies a step); a head of
+        128 (every other configuration) keeps [.., kvh, hd]."""
+        return self.head_dim % 128 != 0 and (self.n_kv_heads * self.head_dim) % 128 == 0
 
     @property
     def n_moe_layers(self) -> int:
@@ -345,7 +399,7 @@ def paged_write_targets(block_tables: jnp.ndarray, positions: jnp.ndarray,
     return entry, p % page_size
 
 
-def gather_paged_view(cache, block_tables: jnp.ndarray, dtype):
+def gather_paged_view(cache, block_tables: jnp.ndarray, dtype, n_kv_heads: int = 0):
     """Gather a paged pool back into the per-sequence logical view:
     (k_all, v_all, pos_view) of [b, n_pages*page_size, kvh, hd] / [b, L].
 
@@ -360,6 +414,11 @@ def gather_paged_view(cache, block_tables: jnp.ndarray, dtype):
     b = bt.shape[0]
     ps = cache[0].shape[1]
     L = bt.shape[1] * ps
+    if cache[0].ndim == 3:
+        # flat rows (TransformerConfig.kv_rows_flat): the heads split here
+        k_pool, v_pool, pos_pool = cache
+        return (k_pool[bt].reshape(b, L, n_kv_heads, -1), v_pool[bt].reshape(b, L, n_kv_heads, -1),
+                pos_pool[bt].reshape(b, L))
     if len(cache) == 5:
         kq_pool, ks_pool, vq_pool, vs_pool, pos_pool = cache
         kvh, hd = kq_pool.shape[2], kq_pool.shape[3]
@@ -402,6 +461,43 @@ def grouped_query_attention(q: jnp.ndarray, k_all: jnp.ndarray,
     probs = jax.nn.softmax(logits, axis=-1).astype(dt)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_all.astype(dt))
     return out.reshape(b, s, n_heads, hd)
+
+
+# Up to this many query rows (tokens x heads) a sequence, the flat-row pool is
+# read as it lies (``flat_rows_attention``); above it the heads are split out
+# of the gathered view. The read over whole rows multiplies n_kv_heads times
+# the products it needs, and splitting narrow heads out of the view re-tiles
+# it (two 134 MB passes a pool a step at 32 slots x 4,096: 5.2 of a 19 ms LFM2
+# step on a v5e, PR 35): by that count the products are the cheaper of the two
+# below about twenty query tokens a sequence.
+FLAT_READ_QUERY_ROWS = 512
+
+
+def flat_rows_attention(q: jnp.ndarray, cache, block_tables: jnp.ndarray,
+                        positions: jnp.ndarray, n_kv_heads: int) -> jnp.ndarray:
+    """``grouped_query_attention`` over a paged pool of FLAT rows (k, v
+    [pages, page_size, n_kv_heads * hd], ``TransformerConfig.kv_rows_flat``)
+    without ever making head_dim the minor axis of the context: a query head
+    of group g is laid into the g-th hd-wide slot of a row-wide vector of
+    zeros, scores contract over the WHOLE row (the zeros add nothing), the
+    context comes back row-wide and head h keeps its group's slot. The same
+    sums as the per-head chain but for their order; float32 logits and
+    softmax, masked positions exact zeros. q [b, s, H, hd] -> [b, s, H, hd]."""
+    k_pool, v_pool, pos_pool = cache
+    b, s, n_heads, hd = q.shape
+    dt = q.dtype
+    L = block_tables.shape[1] * k_pool.shape[1]
+    k_rows = k_pool[block_tables].reshape(b, L, -1).astype(dt)
+    v_rows = v_pool[block_tables].reshape(b, L, -1).astype(dt)
+    mask = pos_pool[block_tables].reshape(b, L)[:, None, :] <= positions[:, :, None]
+    in_group = (jnp.arange(n_heads)[:, None] // (n_heads // n_kv_heads)
+                == jnp.arange(n_kv_heads)[None, :]).astype(dt)               # [H, g]
+    q_rows = (q[:, :, :, None, :] * in_group[None, None, :, :, None]).reshape(b, s, n_heads, -1)
+    logits = jnp.einsum("bshc,bkc->bhsk", q_rows, k_rows).astype(jnp.float32) * hd**-0.5
+    logits = jnp.where(mask[:, None, :, :], logits, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(dt)
+    ctx = jnp.einsum("bhsk,bkc->bshc", probs, v_rows).reshape(b, s, n_heads, n_kv_heads, hd)
+    return jnp.einsum("bshgd,hg->bshd", ctx, in_group)
 
 
 def lora_delta(x: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
@@ -490,17 +586,23 @@ class Attention(nn.Module):
             q_flat = q_flat + lora_delta(x, *adapters["wq"], adapter_ids,
                                          adapters["scale"])
         k_flat = x @ wk.astype(dt)
-        if cfg.qk_norm:
+        if cfg.qk_norm is True:
             q_flat = RMSNorm(cfg.n_heads * hd, cfg.norm_eps, "heads", name="q_norm")(q_flat)
             k_flat = RMSNorm(cfg.n_kv_heads * hd, cfg.norm_eps, "kv_heads", name="k_norm")(k_flat)
         q = q_flat.reshape(b, s, cfg.n_heads, hd)
         k = k_flat.reshape(b, s, cfg.n_kv_heads, hd)
+        if cfg.qk_norm == "head":
+            # one weight [head_dim] for every query head and one for every KV
+            # head, float32 in every tree (FLOAT32_AXES)
+            q = RMSNorm(hd, cfg.norm_eps, "head_norm", name="q_norm")(q)
+            k = RMSNorm(hd, cfg.norm_eps, "head_norm", name="k_norm")(k)
         v = (x @ wv.astype(dt)).reshape(b, s, cfg.n_kv_heads, hd)
 
         cos, sin = rotary_embedding(positions, hd, cfg.rope_theta, cfg.rope_scaling)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
 
+        out = None
         if cache is not None and block_tables is not None:
             # Paged pool: write each token's K/V at the (page, offset) its
             # block table maps its position to; read by gathering the pages
@@ -521,8 +623,9 @@ class Attention(nn.Module):
                 new_cache = (kq_pool, ks_pool, vq_pool, vs_pool, pos_pool)
             else:
                 k_pool, v_pool, pos_pool = cache
-                k_pool = k_pool.at[entry, off].set(k.astype(k_pool.dtype))
-                v_pool = v_pool.at[entry, off].set(v.astype(v_pool.dtype))
+                rows = (b, s, -1) if k_pool.ndim == 3 else k.shape   # flat rows: kv_rows_flat
+                k_pool = k_pool.at[entry, off].set(k.astype(k_pool.dtype).reshape(rows))
+                v_pool = v_pool.at[entry, off].set(v.astype(v_pool.dtype).reshape(rows))
                 pos_pool = pos_pool.at[entry, off].set(
                     positions.astype(pos_pool.dtype))
                 new_cache = (k_pool, v_pool, pos_pool)
@@ -533,8 +636,12 @@ class Attention(nn.Module):
             # view keeps the pool's n_kv_heads. The Pallas page-streaming
             # kernel (ops/paged_attention.py) does not lower for a TPU and
             # is not reachable from here.
-            k_all, v_all, pos_view = gather_paged_view(new_cache, bt, dt)
-            mask = pos_view[:, None, :] <= positions[:, :, None]
+            if new_cache[0].ndim == 3 and s * cfg.n_heads <= FLAT_READ_QUERY_ROWS:
+                # flat rows, few query rows (a decode step): read them as they lie
+                out = flat_rows_attention(q, new_cache, bt, positions, cfg.n_kv_heads)
+            else:
+                k_all, v_all, pos_view = gather_paged_view(new_cache, bt, dt, cfg.n_kv_heads)
+                mask = pos_view[:, None, :] <= positions[:, :, None]
         elif cache is not None and len(cache) == 5:
             # int8 cache: (k_q, k_scale, v_q, v_scale, pos). Quantize-on-write
             # (new K/V rows become int8 + per-head scales before the scatter),
@@ -624,8 +731,9 @@ class Attention(nn.Module):
             out = ring_attention(
                 q, k_all.astype(dt), v_all.astype(dt), positions, positions, mesh=cfg.mesh
             )
-        else:
-            # every cache layout ends here: K/V stay n_kv_heads wide
+        elif out is None:
+            # every cache layout but the flat rows' own read ends here: K/V
+            # stay n_kv_heads wide
             out = grouped_query_attention(q, k_all, v_all, mask)
         out = out.reshape(b, s, cfg.n_heads * hd)
         proj = out @ wo.astype(dt)
@@ -973,7 +1081,10 @@ class MoEFFN(nn.Module):
                 gates, chosen = jax.lax.top_k(probs, k)  # [t, k]
             if cfg.router_renormalize:
                 total = jnp.sum(gates, axis=-1, keepdims=True)
-                gates = gates / (total + 1e-20 if cfg.router_score == "sigmoid" else total)
+                eps = cfg.router_renormalize_eps
+                if eps is None:
+                    eps = 1e-20 if cfg.router_score == "sigmoid" else 0.0
+                gates = gates / (total + eps if eps else total)
             if cfg.routed_scaling_factor != 1.0:
                 gates = gates * cfg.routed_scaling_factor
             sowing = self.is_mutable_collection("moe") and not self.is_initializing()
@@ -1089,10 +1200,13 @@ def moe_routing_stats(sown: dict, cfg: TransformerConfig):
 # stochastic matrix far from both I and 1/n, whose rows sum to 1 within 2e-6
 # after 20 iterations; at a spread of 1.4 three tokens in a hundred are still
 # 1e-4 off), a selection bias about the spread of the top sigmoid scores.
-FLOAT32_AXES = ("hc_maps", "expert_select")
+# The short convolution's taps are normal(0, 1/sqrt(taps)), so that a conv
+# layer's output has its input's size; a per-head q / k norm weight is ones.
+FLOAT32_AXES = ("hc_maps", "expert_select", "conv_taps", "head_norm")
 SMALL_LEAF_INIT = {
     "phi": (0.0, None), "alpha": (0.7, 0.05), "b_pre": (0.0, 0.5), "b_post": (0.0, 0.5),
-    "b_res": (0.0, 0.5), "router_bias": (0.0, 0.1),
+    "b_res": (0.0, 0.5), "router_bias": (0.0, 0.1), "taps": (0.0, 3 ** -0.5),
+    "weight": (1.0, 0.0),
 }
 
 
@@ -1214,28 +1328,123 @@ def hc_write_back(X, y, h_post, h_res):
               + h_post[i][..., None] * y32).astype(X.dtype) for i in range(n)], axis=2)
 
 
+def short_conv(z: jnp.ndarray, taps: jnp.ndarray, state: Optional[jnp.ndarray],
+               positions: jnp.ndarray, valid: jnp.ndarray):
+    """The depthwise causal taps of LFM2's short convolution over the rows of
+    one call, and the state each sequence leaves behind. ONE function for
+    every call shape: a decode step (s = 1), a prefill chunk (one sequence,
+    padded) and the cache-less forward.
+
+    ``z`` [b, s, d] (the gated input B * X); ``taps`` [d, L] float32, tap j
+    weighs z_{t - (L-1) + j} (so the LAST tap weighs the row itself: the order
+    of ``torch.nn.Conv1d``'s weight); ``state`` [b, L-1, d] = the sequence's
+    last L-1 values of z before this call, oldest first (None = none);
+    ``positions`` [b, s] absolute positions; ``valid`` [b, s] bool, the rows
+    that are tokens, which are a PREFIX of each sequence's rows (prompts are
+    right-padded; a step's one row is a token or is not).
+
+    A state row that would lie before position 0 reads as zero
+    (``position - j >= 0``), so a sequence that starts needs no reset of its
+    slot. Returns (v [b, s, d] float32, new_state [b, L-1, d] in z's dtype):
+    the last L-1 values of z up to the last VALID row; a sequence with no
+    valid row keeps its state as it came."""
+    b, s, d = z.shape
+    K = taps.shape[1] - 1
+    if state is None:
+        state = jnp.zeros((b, K, d), z.dtype)
+    state = state.astype(z.dtype)
+    # state row i is z at position p0 - K + i
+    before_start = positions[:, :1] < (K - jnp.arange(K))[None, :]
+    zz = jnp.concatenate([jnp.where(before_start[..., None], 0, state), z], axis=1)
+    w = taps.astype(jnp.float32)
+    v = sum(w[:, j] * zz[:, j:j + s].astype(jnp.float32) for j in range(K + 1))
+    n = jnp.sum(valid, axis=1, dtype=jnp.int32)                              # [b]
+    last = jnp.take_along_axis(zz, (n[:, None] + jnp.arange(K)[None, :])[..., None], axis=1)
+    return v, jnp.where((n > 0)[:, None, None], last, state)
+
+
+class ShortConv(nn.Module):
+    """LFM2's gated short convolution (``transformers`` ``Lfm2ShortConv``), the
+    token mixer of a "conv" layer:
+
+        [B ; C ; X] = W_in u            W_in [dim, 3 dim], split in THAT order
+        z_t = B_t * X_t ;  v_t = sum_j w_j z_{t-(L-1)+j} ;  out = W_out (C_t * v_t)
+
+    W_in is ONE [dim, 3 dim] product. What a sequence keeps between calls is
+    its last L-1 values of z, [L-1, dim], whatever its length: the cache entry
+    of a conv layer is a 1-tuple ``(state,)`` with state [rows, L-1, dim] in
+    the serving dtype, rows = the dense cache's batch or the batcher's slots.
+    ``state_slots`` [b] int32 says which state row each sequence of the call
+    continues (a prefill chunk's one sequence: its slot); None = row i is
+    sequence i's (the decode step over every slot, the dense cache). Without
+    a cache: from zeros, returns (out, (state,)) as well."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions, valid=None, cache=None, state_slots=None):
+        cfg = self.cfg
+        d, dt = cfg.dim, cfg.dtype
+        w_in = param_with_axes("in_proj", nn.initializers.lecun_normal(), (d, 3 * d), jnp.float32,
+                               axes=("embed", "conv_gates"))
+        taps = param_with_axes("taps", small_leaf_init("taps"), (d, cfg.conv_L_cache), jnp.float32,
+                               axes=("conv_channel", "conv_taps"))
+        w_out = param_with_axes("out_proj", nn.initializers.lecun_normal(), (d, d), jnp.float32,
+                                axes=("conv_channel", "embed"))
+        if valid is None:
+            valid = positions < PAD_POS
+        with jax.named_scope("mix.conv.in"):
+            bcx = x @ w_in.astype(dt)
+        with jax.named_scope("mix.conv.taps"):
+            gate_b, gate_c, xs = bcx[..., :d], bcx[..., d:2 * d], bcx[..., 2 * d:]
+            pool = None if cache is None else cache[0]
+            if pool is None or state_slots is None:
+                state = pool
+            else:
+                state = pool[state_slots]
+            v, new_state = short_conv(gate_b * xs, taps, state, positions, valid)
+            if pool is None:
+                new_cache = (new_state,)
+            elif state_slots is None:
+                new_cache = (new_state.astype(pool.dtype),)
+            else:
+                new_cache = (pool.at[state_slots].set(new_state.astype(pool.dtype)),)
+            y = (gate_c.astype(jnp.float32) * v).astype(dt)
+        with jax.named_scope("mix.conv.out"):
+            return y @ w_out.astype(dt), new_cache
+
+
 class TransformerBlock(nn.Module):
     """``x`` is the residual [b, s, dim] or, with cfg.hc_mult > 1, the residual
     streams [b, s, hc_mult, dim]: each sub-layer then reads a mix of the
     streams and writes back through its HyperConnection."""
 
     cfg: TransformerConfig
-    layer: int = 0   # decides the FFN's kind (cfg.first_dense_layers)
+    # decides the FFN's kind (cfg.first_dense_layers) and the token mixer's
+    # (cfg.layer_kind)
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, positions, cache=None, cache_index=None,
                  block_tables=None, adapters=None, adapter_ids=None,
-                 valid=None):
+                 valid=None, state_slots=None):
         cfg = self.cfg
         attention = LatentAttention if cfg.kv_lora_rank else Attention
         streams = cfg.hc_mult > 1
         if streams:
             X, (x, h_post, h_res) = x, HyperConnection(cfg, name="attention_hc")(x)
-        with jax.named_scope("attn"):
-            h, new_cache = attention(cfg, name="attention")(
-                RMSNorm(cfg.dim, cfg.norm_eps, name="attention_norm")(x), positions, cache, cache_index,
-                block_tables, adapters, adapter_ids,
-            )
+        if cfg.layer_kind(self.layer) == "conv":
+            # the scopes are the operator's own (mix.conv.*): "attn" stays the
+            # attention layers'
+            h, new_cache = ShortConv(cfg, name="conv")(
+                RMSNorm(cfg.dim, cfg.norm_eps, name="operator_norm")(x), positions, valid,
+                cache, state_slots)
+        else:
+            with jax.named_scope("attn"):
+                h, new_cache = attention(cfg, name="attention")(
+                    RMSNorm(cfg.dim, cfg.norm_eps, name="attention_norm")(x), positions, cache,
+                    cache_index, block_tables, adapters, adapter_ids,
+                )
         ffn_norm = RMSNorm(cfg.dim, cfg.norm_eps, name="ffn_norm")
         if streams:
             X = hc_write_back(X, h, h_post, h_res)
@@ -1310,7 +1519,8 @@ class Transformer(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, caches=None, cache_index=None,
-                 block_tables=None, adapters=None, adapter_ids=None, next_tokens=None):
+                 block_tables=None, adapters=None, adapter_ids=None, next_tokens=None,
+                 state_slots=None):
         """tokens: [b, s] int32. Returns (logits [b, s, vocab], new_caches).
         With ``next_tokens`` ([b, s] int32: each position's NEXT token) and
         cfg.mtp_layers, a cache-less forward also returns the MTP module's
@@ -1324,7 +1534,10 @@ class Transformer(nn.Module):
         per-sequence batched low-rank deltas on the q/o/FFN projections —
         each layer slices its own factors out of the pool and applies one
         gather+einsum pair per adapted projection (``lora_delta``).
-        adapter id 0 is the reserved zero-delta identity."""
+        adapter id 0 is the reserved zero-delta identity.
+
+        ``state_slots`` ([b] int32) names the row of the conv layers' state
+        each sequence continues (ShortConv); None = row i is sequence i's."""
         from seldon_core_tpu.ops.quantize import QuantizedTensor, dequantize_array, lookup_rows
 
         cfg = self.cfg
@@ -1343,9 +1556,11 @@ class Transformer(nn.Module):
         x = lookup_rows(emb, tokens, cfg.dtype)
         x = enter_streams(with_sharding_constraint(x, ("batch", "seq", "embed")), cfg)
         valid = None
-        if cfg.n_experts > 0:
+        if cfg.n_experts > 0 or cfg.conv_layers:
             # rows that are tokens: not the padding of a chunk (PAD_POS), and
             # not a slot nobody holds, whose block-table row is all TRASH_PAGE
+            # (a slot that is prefilling is such a row of the decode step: its
+            # state is the chunks' to write)
             valid = positions < PAD_POS
             if block_tables is not None:
                 valid &= (jnp.asarray(block_tables)[:, :1] != TRASH_PAGE)
@@ -1362,7 +1577,7 @@ class Transformer(nn.Module):
                 layer_adapters["scale"] = adapters["scale"]
             x, nc = TransformerBlock(cfg, i, name=f"layer_{i}")(
                 x, positions, layer_cache, cache_index, block_tables,
-                layer_adapters, adapter_ids, valid)
+                layer_adapters, adapter_ids, valid, state_slots)
             new_caches.append(nc)
         hidden = leave_streams(x, cfg)
         x = RMSNorm(cfg.dim, cfg.norm_eps, name="norm")(hidden)
@@ -1393,6 +1608,38 @@ LATENT_INT8_REFUSAL = (
     "over 512 + 64 mixed values is untested; serve it with the bf16 cache")
 
 
+def is_state_entry(layer) -> bool:
+    """Is this layer's entry of a cache tree a conv layer's ``(state,)``
+    (a fixed block a sequence, no pages, no positions) and not an attention
+    layer's ``(values..., positions)``? The page operations skip it."""
+    return len(layer) == 1
+
+
+def conv_state_bytes(cfg: TransformerConfig) -> int:
+    """Bytes of conv state ONE sequence keeps over all conv layers, whatever
+    its length (0 for a model without them)."""
+    return (len(cfg.conv_layers) * (cfg.conv_L_cache - 1) * cfg.dim
+            * jnp.dtype(cfg.dtype).itemsize)
+
+
+def _with_state_entries(cfg: TransformerConfig, attention_entries: list, rows: int):
+    """The cache tree over ALL layers: a conv layer's ``(state,)`` entry,
+    [rows, conv_L_cache - 1, dim] zeros in the serving dtype, where
+    cfg.layer_types says so, the attention entries in order elsewhere."""
+    if not cfg.conv_layers:
+        return attention_entries
+    if rows <= 0:
+        raise ValueError(
+            "a model with conv layers needs the number of sequences its state "
+            "block serves (init_paged_kv_caches(..., state_slots=))")
+    entries = iter(attention_entries)
+    return [
+        (jnp.zeros((rows, cfg.conv_L_cache - 1, cfg.dim), cfg.dtype),)
+        if cfg.layer_kind(i) == "conv" else next(entries)
+        for i in range(cfg.n_layers)
+    ]
+
+
 def _init_latent_caches(cfg: TransformerConfig, lead: Tuple[int, int], kvd: str):
     """(rows, pos) per layer with leading dims ``lead``: [b, max_len] dense
     or [pages, page_size] paged."""
@@ -1405,6 +1652,37 @@ def _init_latent_caches(cfg: TransformerConfig, lead: Tuple[int, int], kvd: str)
     ]
 
 
+def _init_head_caches(cfg: TransformerConfig, lead: Tuple[int, int], kvd: str,
+                      flat: bool = False):
+    """Per-head K/V entries with leading dims ``lead``, one per ATTENTION
+    layer: (k, v, pos), or the int8 5-tuple. ``flat``: a token's heads as one
+    row (the paged bf16 pool of a cfg.kv_rows_flat model)."""
+    shape = lead + (cfg.n_kv_heads, cfg.head_dim)
+    if flat and kvd != "int8":
+        shape = lead + (cfg.n_kv_heads * cfg.head_dim,)
+    n = cfg.n_layers - len(cfg.conv_layers)
+    if kvd == "int8":
+        scale_shape = lead + (cfg.n_kv_heads,)
+        return [
+            (
+                jnp.zeros(shape, dtype=jnp.int8),
+                jnp.ones(scale_shape, dtype=jnp.float32),
+                jnp.zeros(shape, dtype=jnp.int8),
+                jnp.ones(scale_shape, dtype=jnp.float32),
+                jnp.full(lead, PAD_POS, dtype=jnp.int32),
+            )
+            for _ in range(n)
+        ]
+    return [
+        (
+            jnp.zeros(shape, dtype=cfg.dtype),
+            jnp.zeros(shape, dtype=cfg.dtype),
+            jnp.full(lead, PAD_POS, dtype=jnp.int32),
+        )
+        for _ in range(n)
+    ]
+
+
 def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
                    kv_cache_dtype: Optional[str] = None):
     """Static-shape KV caches: one (k, v, pos) triple per layer —
@@ -1413,35 +1691,18 @@ def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
     layer is a (k_q, k_scale, v_q, v_scale, pos) 5-tuple: int8 values plus
     f32 [b, max_len, kvh] per-head per-position scales (initialised to 1 so
     empty slots dequantize to exact zeros). A latent-attention layer
-    (cfg.kv_lora_rank) is a (rows, pos) pair: [b, max_len, latent_row_dim]."""
+    (cfg.kv_lora_rank) is a (rows, pos) pair: [b, max_len, latent_row_dim];
+    a conv layer (cfg.layer_types) a ``(state,)`` 1-tuple [b, L-1, dim]."""
     kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
     if cfg.kv_lora_rank:
         return _init_latent_caches(cfg, (batch, max_len), kvd)
-    shape = (batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    if kvd == "int8":
-        scale_shape = (batch, max_len, cfg.n_kv_heads)
-        return [
-            (
-                jnp.zeros(shape, dtype=jnp.int8),
-                jnp.ones(scale_shape, dtype=jnp.float32),
-                jnp.zeros(shape, dtype=jnp.int8),
-                jnp.ones(scale_shape, dtype=jnp.float32),
-                jnp.full((batch, max_len), PAD_POS, dtype=jnp.int32),
-            )
-            for _ in range(cfg.n_layers)
-        ]
-    return [
-        (
-            jnp.zeros(shape, dtype=cfg.dtype),
-            jnp.zeros(shape, dtype=cfg.dtype),
-            jnp.full((batch, max_len), PAD_POS, dtype=jnp.int32),
-        )
-        for _ in range(cfg.n_layers)
-    ]
+    return _with_state_entries(
+        cfg, _init_head_caches(cfg, (batch, max_len), kvd), batch)
 
 
 def init_paged_kv_caches(cfg: TransformerConfig, num_pages: int,
-                         page_size: int, kv_cache_dtype: Optional[str] = None):
+                         page_size: int, kv_cache_dtype: Optional[str] = None,
+                         state_slots: int = 0):
     """Paged KV pools: one (k, v, pos) triple per layer with leading dims
     [num_pages, page_size] instead of [batch, max_len] — pages are shared by
     every sequence through per-sequence block tables. Pages 0 and 1 are
@@ -1451,7 +1712,11 @@ def init_paged_kv_caches(cfg: TransformerConfig, num_pages: int,
     int8 pools carry f32 [num_pages, page_size, kvh] scale planes
     initialised to 1 (empty slots dequantize to exact zeros). A
     latent-attention layer is a (rows, pos) pair: [num_pages, page_size,
-    latent_row_dim] with no head axis."""
+    latent_row_dim] with no head axis. A conv layer (cfg.layer_types) has no
+    pages: its entry is ``(state,)``, [state_slots, conv_L_cache - 1, dim],
+    one block a sequence the pool serves (``is_state_entry``). Heads narrower
+    than a lane tile are held as one row a token, [num_pages, page_size,
+    kvh * hd] (``TransformerConfig.kv_rows_flat``; the bf16 pool only)."""
     if num_pages <= RESERVED_PAGES:
         raise ValueError(
             f"paged KV pool needs > {RESERVED_PAGES} pages "
@@ -1459,27 +1724,8 @@ def init_paged_kv_caches(cfg: TransformerConfig, num_pages: int,
     kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
     if cfg.kv_lora_rank:
         return _init_latent_caches(cfg, (num_pages, page_size), kvd)
-    shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-    if kvd == "int8":
-        scale_shape = (num_pages, page_size, cfg.n_kv_heads)
-        return [
-            (
-                jnp.zeros(shape, dtype=jnp.int8),
-                jnp.ones(scale_shape, dtype=jnp.float32),
-                jnp.zeros(shape, dtype=jnp.int8),
-                jnp.ones(scale_shape, dtype=jnp.float32),
-                jnp.full((num_pages, page_size), PAD_POS, dtype=jnp.int32),
-            )
-            for _ in range(cfg.n_layers)
-        ]
-    return [
-        (
-            jnp.zeros(shape, dtype=cfg.dtype),
-            jnp.zeros(shape, dtype=cfg.dtype),
-            jnp.full((num_pages, page_size), PAD_POS, dtype=jnp.int32),
-        )
-        for _ in range(cfg.n_layers)
-    ]
+    return _with_state_entries(
+        cfg, _init_head_caches(cfg, (num_pages, page_size), kvd, cfg.kv_rows_flat), state_slots)
 
 
 def kv_cache_bytes_per_token(cfg: TransformerConfig,
@@ -1497,7 +1743,8 @@ def kv_cache_bytes_per_token(cfg: TransformerConfig,
         per_layer = 2 * (per_pos * 1 + cfg.n_kv_heads * 4)  # int8 + f32 scale
     else:
         per_layer = 2 * per_pos * jnp.dtype(cfg.dtype).itemsize
-    return cfg.n_layers * (per_layer + 4)  # + int32 pos slot
+    # a conv layer caches nothing a token (conv_state_bytes a sequence)
+    return (cfg.n_layers - len(cfg.conv_layers)) * (per_layer + 4)  # + int32 pos slot
 
 
 @register_model("transformer")
@@ -1506,6 +1753,8 @@ def make_transformer(**kwargs):
     scaling = kwargs.pop("rope_scaling", None)
     if isinstance(scaling, dict):  # normalize to a hashable config field
         scaling = tuple(sorted(scaling.items()))
+    if isinstance(kwargs.get("layer_types"), list):
+        kwargs["layer_types"] = tuple(kwargs["layer_types"])
     kvd = normalize_kv_cache_dtype(kwargs.pop("kv_cache_dtype", "bf16"))
     cfg = TransformerConfig(dtype=jnp.dtype(dtype), rope_scaling=scaling,
                             kv_cache_dtype=kvd, **kwargs)

@@ -163,6 +163,7 @@ from seldon_core_tpu.models.transformer import (
     PAD_POS,
     RESERVED_PAGES,
     TRASH_PAGE,
+    is_state_entry,
 )
 from seldon_core_tpu.runtime.flight import (
     EV_FIRST_TOKEN,
@@ -223,9 +224,13 @@ def _page_table_ops():
     # garbage. page_ids is padded to a fixed length with TRASH_PAGE
     # (re-masking trash is harmless), so one compile serves every
     # allocation size.
+    # A conv layer's entry of the pool tree is a fixed block a slot, with no
+    # pages and no positions (models/transformer.py ``is_state_entry``): every
+    # page operation below hands it on as it is.
     @partial(jax.jit, donate_argnums=(0,))
     def reset_pages(caches, page_ids):
         return [
+            layer if is_state_entry(layer) else
             layer[:-1] + (layer[-1].at[page_ids].set(PAD_POS),)
             for layer in caches
         ]
@@ -271,6 +276,9 @@ def _page_table_ops():
 
         out = []
         for layer in caches:
+            if is_state_entry(layer):
+                out.append(layer)
+                continue
             vals = tuple(pool.at[dst].set(pool[src]) for pool in layer[:-1])
             pos = layer[-1]
             row = jnp.where(jnp.arange(pos.shape[1]) < n_valid,
@@ -287,7 +295,8 @@ def _page_table_ops():
     # contract (zero host transfers, bucket-not-pool bytes).
     @jax.jit
     def export_pages(caches, idx):
-        return [tuple(pool[idx] for pool in layer) for layer in caches]
+        return [tuple(pool[idx] for pool in layer) for layer in caches
+                if not is_state_entry(layer)]
 
     ops = (set_block_row, set_block_entry, reset_pages, set_slot,
            set_hist_row, cow_page_copy, export_pages, set_adapter_id)
@@ -673,6 +682,10 @@ class LoopPhases:
         # view, or whole visits of the live-page kernel): visited / context
         # is the over-read
         self.attn_rows_read = dict.fromkeys(MOE_PROGRAMS, 0)
+        # live rows through the conv layers (a model with layer_types), and
+        # conv layers x calls, from host integers at dispatch
+        self.conv_rows = dict.fromkeys(MOE_PROGRAMS, 0)
+        self.conv_layer_calls = dict.fromkeys(MOE_PROGRAMS, 0)
         self._open: List[_Phase] = []
         self._open_parts: List[_Phase] = []
         self._turn: Optional[Any] = None   # the open turn's annotation
@@ -752,7 +765,12 @@ class LoopPhases:
         return _Handoff(self, fn, args)
 
     def stats(self) -> dict:
-        return {"loop_seconds": dict(self.seconds),
+        conv = {}
+        if any(self.conv_layer_calls.values()):
+            conv = {"conv_rows": dict(self.conv_rows),
+                    "conv_layer_calls": dict(self.conv_layer_calls)}
+        return {**conv,
+                "loop_seconds": dict(self.seconds),
                 "loop_phase_counts": dict(self.counts),
                 "loop_part_seconds": dict(self.part_seconds),
                 "loop_part_counts": dict(self.part_counts),
@@ -768,6 +786,10 @@ class LoopPhases:
         self.attn_calls[program] += 1
         self.attn_context_tokens[program] += context_tokens
         self.attn_rows_read[program] += rows_read
+
+    def count_conv(self, program: str, live_rows: int, layer_calls: int) -> None:
+        self.conv_rows[program] += live_rows
+        self.conv_layer_calls[program] += layer_calls
 
 
 def _in_phase(name: str):
@@ -1314,13 +1336,24 @@ class ContinuousBatcher:
         # benchmarks/DECODE_NOTES.md)
         from seldon_core_tpu.models.transformer import init_paged_kv_caches
 
+        # the ONE tree the step programs thread and donate: a page pool for
+        # every attention layer and, for a conv layer (cfg.layer_types), a
+        # fixed [slots, taps - 1, dim] state block with no pages
         self._caches = jax.jit(
             lambda: init_paged_kv_caches(
-                cfg, self.pool_pages, self.page_size, server.kv_cache_dtype)
+                cfg, self.pool_pages, self.page_size, server.kv_cache_dtype,
+                state_slots=self.S)
         )()
         self._cache_nbytes = sum(
             int(getattr(leaf, "nbytes", 0)) for leaf in jax.tree.leaves(self._caches)
         )
+        self._conv_layers = sum(is_state_entry(layer) for layer in self._caches)
+        self.state_nbytes = sum(
+            int(layer[0].nbytes) for layer in self._caches if is_state_entry(layer))
+        # which state row a chunk's one sequence continues: its slot, as a
+        # device array made once (no transfer a chunk)
+        self._state_slot = [jnp.asarray([i], jnp.int32) for i in range(self.S)
+                            ] if self._conv_layers else None
         # the read is the XLA gather of the whole view, except latent
         # attention's on one TPU, which walks the live pages
         # (ops/latent_attention.py) — said here so a server's log names it
@@ -1334,6 +1367,11 @@ class ContinuousBatcher:
             "latent rows" if cfg.kv_lora_rank else "per-head K/V",
             self._cache_nbytes / 1e9,
             "gather" if self._read_walk(1) is None else "live_pages")
+        if self._conv_layers:
+            logger.info(
+                "conv state: %d layers x %d slots, %d B a slot, %.1f MB resident",
+                self._conv_layers, self.S, self.state_nbytes // self.S,
+                self.state_nbytes / 1e6)
 
         # No insert: chunked prefill writes straight into the pool through
         # the slot's block-table row. The device block table (one row per
@@ -2438,10 +2476,14 @@ class ContinuousBatcher:
                     jnp.asarray([aid], jnp.int32))
             else:
                 fn = self.server._get_prefill_chunk(C, self.n_pages)
+                # a model with conv layers: the chunk continues ITS slot's state
+                extra = () if self._state_slot is None else (self._state_slot[job.slot],)
                 logits, self._caches, aside = fn(
-                    self.server._params, self._caches, job.bt_row, toks, pos)
+                    self.server._params, self._caches, job.bt_row, toks, pos, *extra)
         job.next = start + n
         self._phases.count_attention("chunk", start + n, self._rows_read(C, [start + n], 1))
+        if self._conv_layers:
+            self._phases.count_conv("chunk", n, self._conv_layers)
         event = None
         if self._flight is not None:
             # dispatch wall (enqueue-only)
@@ -2818,6 +2860,7 @@ class ContinuousBatcher:
             "kv_page_size": self.page_size,
             "kv_page_fragmentation": max(0.0, min(1.0, frag)),
             "kv_page_sheds": sheds,
+            "state_bytes": self.state_nbytes,
         }
 
     def spec_stats(self) -> dict:
@@ -2977,6 +3020,8 @@ class ContinuousBatcher:
                 live.extend(pos + 1 + j for j in range(k))
                 self._slots[i].disp_new += k
             self._phases.count_attention("decode", context, self._rows_read(1, live, k * self.S))
+            if self._conv_layers:
+                self._phases.count_conv("decode", k * len(snapshot), k * self._conv_layers)
             self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside))
             self._count_steps()
         return True

@@ -736,6 +736,9 @@ class LLMServer(SeldonComponent):
         self._module = get_model(name, **cfg_kwargs)
         self._cfg = self._module.cfg
         self._abstract_init = None  # _init_shapes(), of this module
+        refusal = self._state_layers_refusal()
+        if refusal:
+            raise ValueError(refusal)
 
         # Big-config random init (e.g. Llama-2-7B dims for capacity/perf
         # work): whole-tree f32 init is 4 bytes/param — 27 GB at 7B, over
@@ -863,6 +866,32 @@ class LLMServer(SeldonComponent):
         self.eos_id = self._eos_override if self._eos_override is not None else self._tokenizer.eos_id
         self.ready = True
         logger.info("LLMServer loaded %s (vocab=%d)", name, self._cfg.vocab_size)
+
+    def _state_layers_refusal(self) -> Optional[str]:
+        """What is not built over a layer that carries STATE (a conv layer,
+        cfg.layer_types), by name; None where nothing asked for is missing.
+        Each of the first three would restart a sequence mid-way, and needs the
+        state AT a token boundary, which pages do not hold."""
+        if not getattr(self._cfg, "conv_layers", ()):
+            return None
+        what = None
+        if self.prefix_cache_size > 0:
+            what = ("prefix_cache_size > 0: a prefix hit (the radix trie's shared pages, "
+                    "generate()'s stored caches) restarts a sequence behind tokens it did "
+                    "not run, and the state at that boundary is not kept")
+        elif self.spec_mode != "off":
+            what = (f"spec_mode={self.spec_mode!r}: a rejected draft rolls the cache back "
+                    "by positions, and the state cannot be rolled back")
+        elif self.disaggregation != "off":
+            what = ("disaggregation='remote_prefill': the hand-off exports and imports "
+                    "pages, and the state a prefill worker leaves is not among them")
+        elif self.tensor_parallel > 1 or self.sequence_parallel > 1 or self.mesh is not None:
+            what = ("tensor / sequence parallelism or a mesh: no sharding of the per-slot "
+                    "state block, nor of the gates' [dim, 3 dim] projection, is built")
+        elif self.lora_rank > 0:
+            what = "lora_rank > 0: adapters target the attention and dense-FFN projections"
+        return what and ("a model with conv layers (layer_types) keeps per-sequence state "
+                         "beside the paged cache, and does not compose with " + what)
 
     def _params_on(self, device):
         """Committed copy of the serving params on ``device`` (cached —
@@ -1368,11 +1397,14 @@ class LLMServer(SeldonComponent):
                     adapter_ids=adapter_ids,
                 )
         else:
+            # ``state_slots``: a model with conv layers (cfg.layer_types) holds
+            # their per-slot state in the pool tree beside the pages, and its
+            # chunk is told WHICH slot's state it continues and leaves behind
             @partial(jax.jit, donate_argnums=(1,))
-            def prefill_chunk(params, pools, block_row, tokens, positions):
+            def prefill_chunk(params, pools, block_row, tokens, positions, state_slots=None):
                 return forward(
                     params, tokens, positions=positions, caches=pools,
-                    block_tables=block_row,
+                    block_tables=block_row, state_slots=state_slots,
                 )
 
         self._prefill_cache[key] = prefill_chunk
@@ -2063,7 +2095,7 @@ class LLMServer(SeldonComponent):
         fuse = self.decode_fuse_steps
         page_stats = {"kv_pages_total": 0, "kv_pages_in_use": 0,
                       "kv_page_size": 0, "kv_page_fragmentation": 0.0,
-                      "kv_page_sheds": 0}
+                      "kv_page_sheds": 0, "state_bytes": 0}
         spec_stats = {"spec_mode": self.spec_mode, "spec_k": self.spec_k,
                       "spec_accept_rate": 0.0,
                       "spec_tokens_per_forward": 0.0,

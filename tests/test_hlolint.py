@@ -330,6 +330,21 @@ def test_latent_step_programs_never_expand_the_cached_view(name):
     assert reported == []
 
 
+@pytest.mark.parametrize("name", ["llm.hybrid_paged_decode_step_s4",
+                                  "llm.hybrid_prefill_chunk_c8"])
+def test_hybrid_step_programs_donate_the_state_and_keep_the_pool_flat(name):
+    """LFM2's block at test dims (ISSUE 35): the conv layers' per-slot state
+    blocks are donated and aliased with the page pools (a leaf that is not
+    shows as an alias finding), never widened whole to float32; the pool of
+    64-wide heads stays flat rows; the MoE promises hold; no transfer."""
+    from tools.hlolint.contracts import all_contracts
+
+    (contract,) = [c for c in all_contracts() if c.name == name]
+    reported, *_ = run_one(contract, checks=("alias", "transfer", "dtype",
+                                             "collective"))
+    assert reported == []
+
+
 @pytest.mark.parametrize("name", ["llm.xing4_paged_decode_step_s4",
                                   "llm.xing4_prefill_chunk_c8"])
 def test_stream_step_programs_keep_the_streams_in_the_models_dtype(name):

@@ -63,8 +63,17 @@ XING4 = dict(vocab_size=256, dim=3584, n_layers=2, n_heads=32, n_kv_heads=32, ff
 # ... and Mistral's own vocabulary for what IS looked at there: a table of a few
 # thousand rows is prefetched whole into on-chip memory and shows nothing
 MISTRAL_VOCAB = dict(MISTRAL, vocab_size=32000)
+# LFM2-8B-A1B's kinds of layer at published widths: a dense conv layer, an MoE
+# conv layer (32 sigmoid-routed experts top-4), an MoE attention layer (GQA,
+# heads of 64, a norm per head)
+LFM2 = dict(vocab_size=256, dim=2048, n_layers=4, n_heads=32, n_kv_heads=8, ffn_dim=1792,
+            max_seq_len=4096, dtype="bfloat16", n_experts=32, n_experts_per_token=4,
+            router_renormalize=True, router_renormalize_eps=1e-6, router_score="sigmoid",
+            router_bias=True, first_dense_layers=2, dense_ffn_dim=7168, qk_norm="head",
+            norm_eps=1e-5, rope_theta=1e6,
+            layer_types=("conv", "conv", "full_attention", "conv"))
 CONFIGS = {"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
-           "xing4": XING4, "mistral_vocab": MISTRAL_VOCAB}
+           "xing4": XING4, "mistral_vocab": MISTRAL_VOCAB, "lfm2": LFM2}
 PAGE, POOL_PAGES = 64, 514
 
 
@@ -131,16 +140,19 @@ def compiled(server, program: str, sharding, slots: int = 32, length: int = 0):
     pages = (length or (1024 if program == "decode_step" else 4096)) // PAGE
     params = abstract(server._params)
     pools = abstract(jax.eval_shape(lambda: init_paged_kv_caches(
-        server._cfg, slots * pages + 2 if length else POOL_PAGES, PAGE, "bf16")))
+        server._cfg, slots * pages + 2 if length else POOL_PAGES, PAGE, "bf16",
+        state_slots=slots)))
     if program == "decode_step":
         lowered = server._get_decode_step_paged(slots, pages, 1).lower(
             params, pools, sds((slots,), "int32"), sds((slots,), "int32"),
             sds((slots, 2), "uint32"), sds((), "float32"), sds((slots, pages), "int32"))
     else:
         chunk = 256
+        # a model with conv layers is told which slot's state the chunk continues
+        state_slot = (sds((1,), "int32"),) if server._cfg.conv_layers else ()
         lowered = server._get_prefill_chunk(chunk, pages).lower(
             params, pools, sds((1, pages), "int32"), sds((1, chunk), "int32"),
-            sds((1, chunk), "int32"))
+            sds((1, chunk), "int32"), *state_slot)
     return lowered.compile()
 
 
@@ -444,3 +456,77 @@ def test_the_lookup_gathers_int8_rows_and_no_op_writes_the_table(v5e, servers, p
     matmul = body(heads[0])
     assert "kind=kOutput" in heads[0][4] and re.search(r" convolution\(", matmul)
     assert f"s8[{dim},{vocab}]" in matmul.splitlines()[0]
+
+
+LFM2_CELL = (32, 4096)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_the_hybrid_programs_donate_state_and_pool_and_copy_neither(v5e, servers, program):
+    """LFM2-8B-A1B's three kinds of layer at published widths and the cell's
+    own shapes (32 slots x 4,096 tokens): every leaf of the cache tree, the
+    conv layers' ``[slots, 2, 2048]`` state blocks as well as the attention
+    layer's K, V and position pools, is an aliased output of its parameter;
+    the K / V pool is held as FLAT rows ``[pages, 64, 512]`` row-major (held
+    ``[pages, 64, 8, 64]``, with a minor dimension of half a lane tile, the
+    compiler laid it out pages-minor and copied all 134 MB five times a step,
+    PR 35); no op of its own copies or transposes a K / V pool or a state
+    block; the bytes donated are the tree's as computed; nothing is sent to or
+    fetched from the host; and the conv operator's three scopes are there."""
+    from seldon_core_tpu.models.transformer import (
+        conv_state_bytes, init_paged_kv_caches, is_state_entry, kv_cache_bytes_per_token)
+
+    server = servers("lfm2")
+    cfg = server._cfg
+    slots, length = LFM2_CELL
+    pages = slots * length // PAGE + 2
+    assert cfg.kv_rows_flat and cfg.conv_layers == (0, 1, 3)
+    # the int8 tree: both projections quantized, W_in ONE [dim, 3 dim] matrix; the
+    # taps, the two head norms and the selection bias float32 leaves no tree quantizes
+    from seldon_core_tpu.ops.quantize import QuantizedTensor
+
+    layers = server._params["params"]
+    conv, attention = layers["layer_0"]["conv"], layers["layer_2"]["attention"]
+    assert isinstance(conv["in_proj"], QuantizedTensor) and conv["in_proj"].q.shape == (2048, 6144)
+    assert isinstance(conv["out_proj"], QuantizedTensor) and conv["out_proj"].q.shape == (2048, 2048)
+    small = {"taps": conv["taps"], "q_norm": attention["q_norm"]["weight"],
+             "k_norm": attention["k_norm"]["weight"], "bias": layers["layer_2"]["moe"]["router_bias"]}
+    assert {k: (v.dtype.name, v.shape) for k, v in small.items()} == {
+        "taps": ("float32", (2048, 3)), "q_norm": ("float32", (64,)), "k_norm": ("float32", (64,)),
+        "bias": ("float32", (32,))}
+    tree = jax.eval_shape(lambda: init_paged_kv_caches(cfg, pages, PAGE, "bf16", state_slots=slots))
+    assert [is_state_entry(layer) for layer in tree] == [True, True, False, True]
+    row = cfg.n_kv_heads * cfg.head_dim
+    assert tree[2][0].shape == tree[2][1].shape == (pages, PAGE, row) and row == 512
+    tree_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(tree))
+    assert tree_bytes == (pages * PAGE * kv_cache_bytes_per_token(cfg, "bf16")
+                          + slots * conv_state_bytes(cfg))
+    exe = compiled(server, program, v5e, slots=slots, length=length)
+    hlo = exe.as_text()
+    entry = hlo[hlo.index("\nENTRY"):]
+    # the six leaves, each a parameter that an output aliases
+    leaves = {m.group(2): int(m.group(3)) for m in re.finditer(
+        r"%(pools_(\d__\d)_)[\w.]* = \S+ parameter\((\d+)\)", entry)}
+    assert sorted(leaves) == ["0__0", "1__0", "2__0", "2__1", "2__2", "3__0"]
+    aliased = {int(n) for n in re.findall(r"\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo)}
+    assert set(leaves.values()) <= aliased, (leaves, aliased)
+    stats = exe.memory_analysis()
+    assert tree_bytes <= stats.alias_size_in_bytes < tree_bytes + (1 << 20)
+    # row-major flat rows, as they are held
+    assert re.search(rf"%pools_2__0_[\w.]* = bf16\[{pages},{PAGE},{row}\]\{{2,1,0:", entry)
+    state = (slots, cfg.conv_L_cache - 1, cfg.dim)
+    # (a copy INSIDE a several-output fusion is no op of its own, and the parser
+    # does not follow those: the step's state READ is one such fusion, which
+    # takes the three blocks into the taps' layout beside the start mask)
+    copies = weight_copies(hlo, {(pages, PAGE, row), (pages, PAGE, cfg.n_kv_heads, cfg.head_dim),
+                                 state})
+    assert [c for c in copies if " in fused_computation" not in c] == []
+    assert f"[{pages},{PAGE},{cfg.n_kv_heads},{cfg.head_dim}]" not in hlo
+    assert not re.search(r"\b(infeed|outfeed|send|recv|send-done|recv-done)\(", hlo)
+    assert "HostCompute" not in hlo and "host_compute" not in hlo
+    for scope in ("mix.conv.in", "mix.conv.taps", "mix.conv.out"):
+        assert all(f"layer_{i}/conv/{scope}/" in hlo for i in cfg.conv_layers), scope
+    assert "layer_2/attn/attention" in hlo and "/conv/attn" not in hlo and "attn/mix.conv" not in hlo
+    if program == "decode_step":
+        # one gathered view of K and of V for the one attention layer, no more
+        assert stats.temp_size_in_bytes < 3 * slots * length * row * 2

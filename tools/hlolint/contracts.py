@@ -272,6 +272,39 @@ def _xing4_server():
         return _STATE["xing4_server"]
 
 
+# a second kind of state (ISSUE 35): LFM2's block at test dims: gated short
+# convolutions with a fixed [taps - 1, dim] block a slot beside paged GQA
+# layers whose heads are narrower than a lane tile (two heads of 64 = one flat
+# row of 128 a token), a norm per head, the sigmoid router behind a dense layer
+HYBRID_DIM = 256
+HYBRID_KV_ROW = 128
+
+
+def _hybrid_server():
+    with _STATE_LOCK:
+        if "hybrid_server" not in _STATE:
+            ensure_platform()
+            from seldon_core_tpu.servers.llmserver import LLMServer
+
+            s = LLMServer(
+                model="transformer",
+                model_kwargs=dict(
+                    vocab_size=96, dim=HYBRID_DIM, n_layers=3, n_heads=4,
+                    n_kv_heads=2, ffn_dim=MOE_WIDTH,
+                    max_seq_len=PAGES_PER_SLOT * PAGE_SIZE,
+                    n_experts=MOE_EXPERTS, n_experts_per_token=MOE_TOP_K,
+                    router_renormalize=True, router_renormalize_eps=1e-6,
+                    router_score="sigmoid", router_bias=True,
+                    first_dense_layers=1, dense_ffn_dim=96, qk_norm="head",
+                    layer_types=("conv", "full_attention", "conv"),
+                    dtype="bfloat16"),
+                quantize="int8", init_random=True, len_buckets=(PLEN,),
+                seed=7)
+            s.load()
+            _STATE["hybrid_server"] = s
+        return _STATE["hybrid_server"]
+
+
 def _paged_batcher():
     with _STATE_LOCK:  # nests into _base_server's hold: RLock
         if "paged_batcher" not in _STATE:
@@ -421,13 +454,30 @@ HC_FLOAT32_STREAMS = (
     "re-read around every sub-layer")
 
 
+HYBRID_FLOAT32_STATE = (
+    rf"tensor<{SLOTS}x2x{HYBRID_DIM}xf32>",
+    "a float32 [slots, taps - 1, dim] array: the conv layers' per-slot state is "
+    "held, read and written in the model's dtype; the whole block in float32 "
+    "is a widened copy of every slot's state a layer a call")
+HYBRID_FLOAT_STACK = (
+    rf"tensor<{MOE_EXPERTS}x({HYBRID_DIM}x{MOE_WIDTH}|{MOE_WIDTH}x{HYBRID_DIM})"
+    r"x(bf16|f16|f32)>", MOE_FLOAT_STACK[1])
+HYBRID_SPLIT_POOL = (
+    rf"tensor<{POOL_PAGES}x{PAGE_SIZE}x2x{HYBRID_KV_ROW // 2}x(bf16|f32)>",
+    "the page pool with its narrow heads split out, [pages, page, kv heads, "
+    "64]: the pool is held as flat rows [pages, page, kv heads * 64] because a "
+    "minor dimension of half a lane tile makes the chip's compiler re-lay and "
+    "copy the whole pool (PR 35); the heads are split in the gathered view")
+
+
 def _pool_specs_of(server):
     import jax
 
     from seldon_core_tpu.models.transformer import init_paged_kv_caches
 
     return jax.eval_shape(
-        lambda: init_paged_kv_caches(server._cfg, POOL_PAGES, PAGE_SIZE, "bf16"))
+        lambda: init_paged_kv_caches(server._cfg, POOL_PAGES, PAGE_SIZE, "bf16",
+                                     state_slots=SLOTS))
 
 
 def _moe_pool_specs():
@@ -487,6 +537,25 @@ def _build_xing4_prefill_chunk():
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
                 _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"))
+
+
+def _build_hybrid_paged_decode_step():
+    s = _hybrid_server()
+    fn = s._get_decode_step_paged(SLOTS, PAGES_PER_SLOT, 1)
+    return fn, (s._params, _pool_specs_of(s), _sds((SLOTS,), "int32"),
+                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
+                _sds((), "float32"),
+                _sds((SLOTS, PAGES_PER_SLOT), "int32"))
+
+
+def _build_hybrid_prefill_chunk():
+    """The chunk is told WHICH slot's state it continues (its last operand)."""
+    s = _hybrid_server()
+    fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
+    return fn, (s._params, _pool_specs_of(s),
+                _sds((1, PAGES_PER_SLOT), "int32"),
+                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
+                _sds((1,), "int32"))
 
 
 def _build_prefill():
@@ -900,6 +969,34 @@ def all_contracts() -> List[Contract]:
             donated=(1,),
             forbid_dtypes=(HC_FLOAT32_STREAMS, MLA_EXPANDED_KV, MOE_DENSE_FORM,
                            MOE_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.hybrid_paged_decode_step_s4",
+            description="PAGED decode step of a model with conv layers "
+                        "(LFM2's block: a fixed state block a slot beside "
+                        "the pages of GQA layers with narrow heads): the "
+                        "state blocks are donated with the pools and stay "
+                        "in the model's dtype, the pool stays flat rows",
+            build=_build_hybrid_paged_decode_step,
+            donated=(1, 3, 4),
+            forbid_dtypes=(HYBRID_FLOAT32_STATE, HYBRID_SPLIT_POOL,
+                           MOE_DENSE_FORM, HYBRID_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.hybrid_prefill_chunk_c8",
+            description="chunked admission prefill of the same model: the "
+                        "chunk continues ONE slot's state and writes it "
+                        "back into the donated block",
+            build=_build_hybrid_prefill_chunk,
+            donated=(1,),
+            forbid_dtypes=(HYBRID_FLOAT32_STATE, HYBRID_SPLIT_POOL,
+                           MOE_DENSE_FORM, HYBRID_FLOAT_STACK),
             lowering_platform="tpu",
             collectives={},
             cost=True,
