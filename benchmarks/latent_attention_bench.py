@@ -28,6 +28,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from seldon_core_tpu.models.transformer import NULL_PAGE, PAD_POS, TRASH_PAGE  # noqa: E402
 from seldon_core_tpu.ops import latent_attention as la  # noqa: E402
+from seldon_core_tpu.ops import page_walk  # noqa: E402
 
 PAGE, WIDTH, LATENT = 64, 640, 512
 SHALLOW, DEEP, REPEATS = 4, 24, 7
@@ -124,12 +125,12 @@ def main():
                               jnp.float32).astype(jnp.bfloat16)
         live_rows = int(sum(n for n in lens if n > 0))
         live_us = live_rows * width * 2 / 819e9 * 1e6
-        planned = la.plan(s, heads, n_pages, page, width, latent)
+        planned = page_walk.plan(s, heads, n_pages, page, width, latent)
         variants = [("expression", None)]
         for rows, tile in walks:
             if s * heads < tile and (rows, tile) != walks[0] and tile != 512:
                 continue   # a step has one query tile whatever the rule says
-            walk = la.Plan(pages=min(rows // page, n_pages), q_tile=min(s * heads, tile))
+            walk = page_walk.Plan(pages=min(rows // page, n_pages), q_tile=min(s * heads, tile))
             variants.append((f"kernel {walk.pages * page} x {walk.q_tile}"
                              + (" (the rule)" if walk == planned else ""), walk))
         reference = None
@@ -148,7 +149,7 @@ def main():
             us = (seconds(fn, deep, call) - seconds(fn, shallow, call)) / (deep - shallow) * 1e6
             visits = 0
             if walk is not None:
-                visits = int(la.make_visits(bt, la.live_pages(bt, positions, page),
+                visits = int(page_walk.make_visits(bt, page_walk.live_pages(bt, positions, page),
                                             walk).count) * (s * heads // walk.q_tile)
             row = dict(shape=name, slots=slots, s=s, heads=heads, live_rows=live_rows, variant=variant,
                        visits=visits, read_us=round(us, 1), live_bytes_us=round(live_us, 1),

@@ -249,14 +249,15 @@ class TransformerConfig:
     @property
     def kv_rows_flat(self) -> bool:
         """Does the paged bf16 pool hold a token's K (and V) as ONE row of
-        n_kv_heads * head_dim values, [pages, page_size, kvh * hd], the heads
-        split at the read? Where a head is narrower than a 128-lane tile and
-        the row is whole tiles (LFM2: 8 x 64 = 512). Held [.., 8, 64] the
-        chip's compiler lays the pool out pages-minor and copies the whole pool
-        several times a layer a call (compiled for a described v5e, PR 35:
-        ``bf16[2050,64,8,64]{0,3,2,1}``, five 134 MB copies a step); a head of
-        128 (every other configuration) keeps [.., kvh, hd]."""
-        return self.head_dim % 128 != 0 and (self.n_kv_heads * self.head_dim) % 128 == 0
+        n_kv_heads * head_dim values, [pages, page_size, kvh * hd]? On one
+        device, always: the decode step's live-page read (ops/gqa_attention.py)
+        fetches pages of such rows as they lie, and the expression splits the
+        heads out of its gathered view. Under a mesh the pool keeps its
+        [.., kvh, hd] axes for the partitioner, except where a head is narrower
+        than a 128-lane tile: held [.., 8, 64], LFM2's pool came out
+        pages-minor and was copied whole five times a layer a call (compiled
+        for a described v5e, PR 35), so such a pool is flat wherever it is."""
+        return self.mesh is None or self.head_dim % 128 != 0
 
     @property
     def n_moe_layers(self) -> int:
@@ -399,26 +400,23 @@ def paged_write_targets(block_tables: jnp.ndarray, positions: jnp.ndarray,
     return entry, p % page_size
 
 
-def gather_paged_view(cache, block_tables: jnp.ndarray, dtype, n_kv_heads: int = 0):
+def gather_paged_view(cache, block_tables: jnp.ndarray, dtype, n_kv_heads: int):
     """Gather a paged pool back into the per-sequence logical view:
     (k_all, v_all, pos_view) of [b, n_pages*page_size, kvh, hd] / [b, L].
 
-    The ONE copy of the block-table read semantics: both the attention
-    read below and ops/paged_attention.py's ``paged_attention_ref``
-    (the kernel's parity oracle) address the pool through this gather, so
-    a change to the page addressing can never desynchronize them. int8
-    pools (5-tuple) dequantize here — the gather moves bytes, never
-    arithmetic, so the view feeds ``grouped_query_attention`` exactly as
-    the dense layout would, n_kv_heads wide."""
+    The ONE copy of the block-table read semantics of the expression: the
+    attention read below and ``paged_attention_ref`` (the live-page kernel's
+    oracle) both address the pool through this gather. A bf16 pool
+    of flat rows [pages, page_size, kvh * hd] (``cfg.kv_rows_flat``) has its
+    heads split here; int8 pools (5-tuple,
+    [.., kvh, hd] values beside [.., kvh] scales) dequantize here. The gather
+    moves bytes, never arithmetic, so the view feeds
+    ``grouped_query_attention`` exactly as the dense layout would, n_kv_heads
+    wide."""
     bt = jnp.asarray(block_tables, jnp.int32)
     b = bt.shape[0]
     ps = cache[0].shape[1]
     L = bt.shape[1] * ps
-    if cache[0].ndim == 3:
-        # flat rows (TransformerConfig.kv_rows_flat): the heads split here
-        k_pool, v_pool, pos_pool = cache
-        return (k_pool[bt].reshape(b, L, n_kv_heads, -1), v_pool[bt].reshape(b, L, n_kv_heads, -1),
-                pos_pool[bt].reshape(b, L))
     if len(cache) == 5:
         kq_pool, ks_pool, vq_pool, vs_pool, pos_pool = cache
         kvh, hd = kq_pool.shape[2], kq_pool.shape[3]
@@ -428,10 +426,22 @@ def gather_paged_view(cache, block_tables: jnp.ndarray, dtype, n_kv_heads: int =
                               vs_pool[bt].reshape(b, L, kvh), dtype)
     else:
         k_pool, v_pool, pos_pool = cache
-        kvh, hd = k_pool.shape[2], k_pool.shape[3]
-        k_all = k_pool[bt].reshape(b, L, kvh, hd)
-        v_all = v_pool[bt].reshape(b, L, kvh, hd)
+        k_all = k_pool[bt].reshape(b, L, n_kv_heads, -1)
+        v_all = v_pool[bt].reshape(b, L, n_kv_heads, -1)
     return k_all, v_all, pos_pool[bt].reshape(b, L)
+
+
+def paged_attention_ref(q, cache, block_tables, positions, n_kv_heads: int):
+    """The paged read as an expression: gather the logical view through the
+    block table and run the one masked-softmax chain on it, K/V kept
+    n_kv_heads wide. What ``Attention`` computes on every lowering that keeps
+    the expression, and the oracle tests/test_gqa_page_attention.py holds the
+    live-page kernel to. q: [b, s, h, hd]; cache: the paged 3-tuple (bf16) or
+    5-tuple (int8) pool; positions: [b, s]. Returns [b, s, h, hd] in q.dtype."""
+    k_all, v_all, pos_view = gather_paged_view(cache, block_tables, q.dtype, n_kv_heads)
+    # one predicate for causality, empty rows (PAD_POS) and padding
+    mask = pos_view[:, None, :] <= positions[:, :, None]
+    return grouped_query_attention(q, k_all, v_all, mask)
 
 
 def grouped_query_attention(q: jnp.ndarray, k_all: jnp.ndarray,
@@ -448,8 +458,8 @@ def grouped_query_attention(q: jnp.ndarray, k_all: jnp.ndarray,
     positions get ``finfo.min`` and contribute exact zeros.
 
     The ONE copy of the chain: ``Attention`` (every cache layout but the
-    ring) and ops/paged_attention.py's ``paged_attention_ref`` both call
-    it, so the kernel's oracle cannot drift from what serves."""
+    ring) and ``paged_attention_ref`` both call it, so the live-page kernel's
+    oracle cannot drift from what serves."""
     b, s, n_heads, hd = q.shape
     kvh = k_all.shape[2]
     dt = q.dtype
@@ -463,41 +473,21 @@ def grouped_query_attention(q: jnp.ndarray, k_all: jnp.ndarray,
     return out.reshape(b, s, n_heads, hd)
 
 
-# Up to this many query rows (tokens x heads) a sequence, the flat-row pool is
-# read as it lies (``flat_rows_attention``); above it the heads are split out
-# of the gathered view. The read over whole rows multiplies n_kv_heads times
-# the products it needs, and splitting narrow heads out of the view re-tiles
-# it (two 134 MB passes a pool a step at 32 slots x 4,096: 5.2 of a 19 ms LFM2
-# step on a v5e, PR 35): by that count the products are the cheaper of the two
-# below about twenty query tokens a sequence.
-FLAT_READ_QUERY_ROWS = 512
+def paged_live_read(q, cache, block_tables, positions, *, n_kv_heads: int, walk):
+    """The paged bf16 pool's read of a call shape the live-page kernel takes
+    (``walk`` = ``paged_read_walk(...)``, not None): the kernel
+    (ops/gqa_attention.py) in a program lowered for a TPU, the expression
+    (``paged_attention_ref``) in every other."""
+    def read_expression():
+        return paged_attention_ref(q, cache, block_tables, positions, n_kv_heads)
 
+    def read_live_pages():
+        from seldon_core_tpu.ops.gqa_attention import gqa_page_attention
 
-def flat_rows_attention(q: jnp.ndarray, cache, block_tables: jnp.ndarray,
-                        positions: jnp.ndarray, n_kv_heads: int) -> jnp.ndarray:
-    """``grouped_query_attention`` over a paged pool of FLAT rows (k, v
-    [pages, page_size, n_kv_heads * hd], ``TransformerConfig.kv_rows_flat``)
-    without ever making head_dim the minor axis of the context: a query head
-    of group g is laid into the g-th hd-wide slot of a row-wide vector of
-    zeros, scores contract over the WHOLE row (the zeros add nothing), the
-    context comes back row-wide and head h keeps its group's slot. The same
-    sums as the per-head chain but for their order; float32 logits and
-    softmax, masked positions exact zeros. q [b, s, H, hd] -> [b, s, H, hd]."""
-    k_pool, v_pool, pos_pool = cache
-    b, s, n_heads, hd = q.shape
-    dt = q.dtype
-    L = block_tables.shape[1] * k_pool.shape[1]
-    k_rows = k_pool[block_tables].reshape(b, L, -1).astype(dt)
-    v_rows = v_pool[block_tables].reshape(b, L, -1).astype(dt)
-    mask = pos_pool[block_tables].reshape(b, L)[:, None, :] <= positions[:, :, None]
-    in_group = (jnp.arange(n_heads)[:, None] // (n_heads // n_kv_heads)
-                == jnp.arange(n_kv_heads)[None, :]).astype(dt)               # [H, g]
-    q_rows = (q[:, :, :, None, :] * in_group[None, None, :, :, None]).reshape(b, s, n_heads, -1)
-    logits = jnp.einsum("bshc,bkc->bhsk", q_rows, k_rows).astype(jnp.float32) * hd**-0.5
-    logits = jnp.where(mask[:, None, :, :], logits, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-    ctx = jnp.einsum("bhsk,bkc->bshc", probs, v_rows).reshape(b, s, n_heads, n_kv_heads, hd)
-    return jnp.einsum("bshgd,hg->bshd", ctx, in_group)
+        return gqa_page_attention(q, *cache, block_tables, positions, n_kv_heads, walk,
+                                  interpret=False)
+
+    return jax.lax.platform_dependent(tpu=read_live_pages, default=read_expression)
 
 
 def lora_delta(x: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
@@ -605,43 +595,51 @@ class Attention(nn.Module):
         out = None
         if cache is not None and block_tables is not None:
             # Paged pool: write each token's K/V at the (page, offset) its
-            # block table maps its position to; read by gathering the pages
-            # back into the per-sequence logical [b, n_pages*ps, ...] view.
+            # block table maps its position to.
             bt = jnp.asarray(block_tables, jnp.int32)
             ps = cache[0].shape[1]
-            entry, off = paged_write_targets(bt, positions, ps)
-            if len(cache) == 5:
-                kq_pool, ks_pool, vq_pool, vs_pool, pos_pool = cache
-                kq, ks = quantize_kv(k)
-                vq, vs = quantize_kv(v)
-                kq_pool = kq_pool.at[entry, off].set(kq)
-                ks_pool = ks_pool.at[entry, off].set(ks)
-                vq_pool = vq_pool.at[entry, off].set(vq)
-                vs_pool = vs_pool.at[entry, off].set(vs)
-                pos_pool = pos_pool.at[entry, off].set(
-                    positions.astype(pos_pool.dtype))
-                new_cache = (kq_pool, ks_pool, vq_pool, vs_pool, pos_pool)
-            else:
-                k_pool, v_pool, pos_pool = cache
-                rows = (b, s, -1) if k_pool.ndim == 3 else k.shape   # flat rows: kv_rows_flat
-                k_pool = k_pool.at[entry, off].set(k.astype(k_pool.dtype).reshape(rows))
-                v_pool = v_pool.at[entry, off].set(v.astype(v_pool.dtype).reshape(rows))
-                pos_pool = pos_pool.at[entry, off].set(
-                    positions.astype(pos_pool.dtype))
-                new_cache = (k_pool, v_pool, pos_pool)
-            # The read on every backend, the TPU included: gather the
-            # logical view and fall through to the SAME chain the dense
-            # layout uses (grouped_query_attention) — paged == dense
-            # bit-for-bit (masked positions contribute exact zeros). The
-            # view keeps the pool's n_kv_heads. The Pallas page-streaming
-            # kernel (ops/paged_attention.py) does not lower for a TPU and
-            # is not reachable from here.
-            if new_cache[0].ndim == 3 and s * cfg.n_heads <= FLAT_READ_QUERY_ROWS:
-                # flat rows, few query rows (a decode step): read them as they lie
-                out = flat_rows_attention(q, new_cache, bt, positions, cfg.n_kv_heads)
-            else:
-                k_all, v_all, pos_view = gather_paged_view(new_cache, bt, dt, cfg.n_kv_heads)
-                mask = pos_view[:, None, :] <= positions[:, :, None]
+            with jax.named_scope("attn.gqa.write"):
+                entry, off = paged_write_targets(bt, positions, ps)
+                if len(cache) == 5:
+                    kq_pool, ks_pool, vq_pool, vs_pool, pos_pool = cache
+                    kq, ks = quantize_kv(k)
+                    vq, vs = quantize_kv(v)
+                    kq_pool = kq_pool.at[entry, off].set(kq)
+                    ks_pool = ks_pool.at[entry, off].set(ks)
+                    vq_pool = vq_pool.at[entry, off].set(vq)
+                    vs_pool = vs_pool.at[entry, off].set(vs)
+                    pos_pool = pos_pool.at[entry, off].set(
+                        positions.astype(pos_pool.dtype))
+                    new_cache = (kq_pool, ks_pool, vq_pool, vs_pool, pos_pool)
+                else:
+                    # a token's heads as the pool holds them: ONE row, or [kvh, hd]
+                    k_pool, v_pool, pos_pool = cache
+                    row = (b, s) + k_pool.shape[2:]
+                    k_pool = k_pool.at[entry, off].set(k.astype(k_pool.dtype).reshape(row))
+                    v_pool = v_pool.at[entry, off].set(v.astype(v_pool.dtype).reshape(row))
+                    pos_pool = pos_pool.at[entry, off].set(
+                        positions.astype(pos_pool.dtype))
+                    new_cache = (k_pool, v_pool, pos_pool)
+
+            # The read as an expression gathers the logical [b, n_pages*ps,
+            # ...] view and runs the SAME chain the dense layout uses
+            # (grouped_query_attention): paged == dense bit-for-bit (masked
+            # positions contribute exact zeros). For the bf16 pool on one TPU,
+            # a call of few query rows a sequence (the decode step, the
+            # speculative verify) walks each sequence's live pages with the
+            # repo's kernel instead (``paged_live_read``); every other
+            # lowering, a mesh, the int8 pool and a call shape the kernel does
+            # not take (a chunk's full query tiles among them) keep the
+            # expression over the whole view.
+            walk = None
+            if len(new_cache) == 3:
+                walk = paged_read_walk(cfg, s, bt.shape[1], ps, k_pool.dtype)
+            with jax.named_scope("attn.gqa.read"):
+                if walk is not None:
+                    out = paged_live_read(q, new_cache, bt, positions,
+                                          n_kv_heads=cfg.n_kv_heads, walk=walk)
+                else:
+                    out = paged_attention_ref(q, new_cache, bt, positions, cfg.n_kv_heads)
         elif cache is not None and len(cache) == 5:
             # int8 cache: (k_q, k_scale, v_q, v_scale, pos). Quantize-on-write
             # (new K/V rows become int8 + per-head scales before the scatter),
@@ -732,9 +730,10 @@ class Attention(nn.Module):
                 q, k_all.astype(dt), v_all.astype(dt), positions, positions, mesh=cfg.mesh
             )
         elif out is None:
-            # every cache layout but the flat rows' own read ends here: K/V
-            # stay n_kv_heads wide
-            out = grouped_query_attention(q, k_all, v_all, mask)
+            # every cache layout but the paged pool's ends here: K/V stay
+            # n_kv_heads wide
+            with jax.named_scope("attn.gqa.read"):
+                out = grouped_query_attention(q, k_all, v_all, mask)
         out = out.reshape(b, s, cfg.n_heads * hd)
         proj = out @ wo.astype(dt)
         if adapters is not None:
@@ -801,20 +800,34 @@ def absorbed_latent_attention(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     return jnp.einsum("bshc,hcv->bshv", ctx, w_uv.astype(dt))
 
 
-def latent_read_walk(cfg: "TransformerConfig", s: int, n_pages: int, page_size: int,
-                     pool_dtype) -> Optional[Any]:
-    """How the paged pool's latent read of a call with ``s`` query tokens a
+def paged_read_walk(cfg: "TransformerConfig", s: int, n_pages: int, page_size: int,
+                    pool_dtype) -> Optional[Any]:
+    """How the paged pool's attention read of a call with ``s`` query tokens a
     sequence is walked by the repo's kernel IN A PROGRAM LOWERED FOR A TPU
-    (ops/latent_attention.py ``Plan``), or None where it is the expression over
-    the whole view there too: no latent attention, a mesh (the kernel is one
-    device's program), a pool in another dtype than the model's, a call shape
-    the kernel does not take. From static facts alone, so ``LatentAttention``
-    and the loop's ``seldon_llm_attn_rows_read_total`` agree by construction."""
-    from seldon_core_tpu.ops.latent_attention import plan
+    (ops/page_walk.py ``Plan``), or None where it is the expression over the
+    whole view there too: a mesh (the kernel is one device's program), a pool
+    in another dtype than the model's (the int8 KV pool), a call shape the
+    kernel does not take. Latent attention's one pool of latent rows
+    (ops/latent_attention.py) takes every shape the plan does; per-head K and V
+    rows (ops/gqa_attention.py), where the pool holds them flat
+    (``cfg.kv_rows_flat``), those whose query rows are under one tile: the
+    decode step and the speculative verify. (A visit there multiplies
+    n_kv_heads times the products it needs, each query head against the whole
+    row, which is free while the rows' bytes bound it and is not under a
+    chunk's 256 x H query rows, whose read is MXU-shaped and wants a lane block
+    a head: ROADMAP.md A3b.) From static facts alone, so ``Attention``,
+    ``LatentAttention`` and the loop's ``seldon_llm_attn_rows_read_total``
+    agree by construction."""
+    from seldon_core_tpu.ops.gqa_attention import gqa_plan
+    from seldon_core_tpu.ops.page_walk import QUERY_TILE, plan
 
-    if not cfg.kv_lora_rank or cfg.mesh is not None or jnp.dtype(pool_dtype) != jnp.dtype(cfg.dtype):
+    if cfg.mesh is not None or jnp.dtype(pool_dtype) != jnp.dtype(cfg.dtype):
         return None
-    return plan(s, cfg.n_heads, n_pages, page_size, cfg.latent_row_dim, cfg.kv_lora_rank)
+    if cfg.kv_lora_rank:
+        return plan(s, cfg.n_heads, n_pages, page_size, cfg.latent_row_dim, cfg.kv_lora_rank)
+    if not cfg.kv_rows_flat or s * cfg.n_heads >= QUERY_TILE:
+        return None
+    return gqa_plan(s, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, n_pages, page_size)
 
 
 def _dense_stack(w, dtype):
@@ -965,7 +978,7 @@ class LatentAttention(nn.Module):
             # does not take keep the expression over the whole view
             walk = None
             if cache is not None and block_tables is not None:
-                walk = latent_read_walk(cfg, s, bt.shape[1], pool.shape[1], pool.dtype)
+                walk = paged_read_walk(cfg, s, bt.shape[1], pool.shape[1], pool.dtype)
             if walk is not None:
                 out = jax.lax.platform_dependent(tpu=read_live_pages, default=read_expression)
             else:
@@ -1714,9 +1727,13 @@ def init_paged_kv_caches(cfg: TransformerConfig, num_pages: int,
     latent-attention layer is a (rows, pos) pair: [num_pages, page_size,
     latent_row_dim] with no head axis. A conv layer (cfg.layer_types) has no
     pages: its entry is ``(state,)``, [state_slots, conv_L_cache - 1, dim],
-    one block a sequence the pool serves (``is_state_entry``). Heads narrower
-    than a lane tile are held as one row a token, [num_pages, page_size,
-    kvh * hd] (``TransformerConfig.kv_rows_flat``; the bf16 pool only)."""
+    one block a sequence the pool serves (``is_state_entry``).
+
+    Where ``cfg.kv_rows_flat`` (one device, or narrow heads) the bf16
+    pool holds a token's heads as ONE row, [num_pages, page_size, kvh * hd];
+    elsewhere, and the int8 pool beside its [.., kvh] scales, [.., kvh, hd]
+    (the split of a whole view of flat rows is two to three times the step's
+    read as an expression: v5e, PR 36)."""
     if num_pages <= RESERVED_PAGES:
         raise ValueError(
             f"paged KV pool needs > {RESERVED_PAGES} pages "
@@ -1724,8 +1741,8 @@ def init_paged_kv_caches(cfg: TransformerConfig, num_pages: int,
     kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
     if cfg.kv_lora_rank:
         return _init_latent_caches(cfg, (num_pages, page_size), kvd)
-    return _with_state_entries(
-        cfg, _init_head_caches(cfg, (num_pages, page_size), kvd, cfg.kv_rows_flat), state_slots)
+    pools = _init_head_caches(cfg, (num_pages, page_size), kvd, flat=cfg.kv_rows_flat)
+    return _with_state_entries(cfg, pools, state_slots)
 
 
 def kv_cache_bytes_per_token(cfg: TransformerConfig,

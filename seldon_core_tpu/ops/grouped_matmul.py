@@ -83,6 +83,7 @@ def make_visits(group_sizes, m: int, rows: int) -> Visits:
     """``group_sizes`` [e] int32 (their sum may be under ``m``), ``m`` sorted
     rows, ``rows`` a tile: the visit list. A group visits every tile it has
     a row in; an empty group visits none."""
+    import jax
     import jax.numpy as jnp
 
     e = group_sizes.shape[0]
@@ -94,13 +95,16 @@ def make_visits(group_sizes, m: int, rows: int) -> Visits:
     first = starts // rows
     n_visits = jnp.where(sizes > 0, (ends - 1) // rows - first + 1, 0)
     # "group e": the tiles no group has a row in
-    dead_first = (total + rows - 1) // rows
+    dead_first = jax.lax.div(total + (rows - 1), jnp.int32(rows))   # total >= 0: no floor's sign chain
     first = jnp.concatenate([first, dead_first[None]])
     visit_ends = jnp.cumsum(jnp.concatenate([n_visits, (tiles - dead_first)[None]]))
     visit_starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), visit_ends[:-1]])
     # a live group's visits are at most tiles + e - 1, the dead tiles' the rest
     i = jnp.arange(tiles + e, dtype=jnp.int32)
-    g = jnp.minimum(jnp.searchsorted(visit_ends, i, side="right"), e).astype(jnp.int32)
+    # (all comparisons at once: the default's binary search is a device loop,
+    # seven trips of eight ops a call)
+    g = jnp.minimum(jnp.searchsorted(visit_ends, i, side="right", method="compare_all"),
+                    e).astype(jnp.int32)
     live = g < e
     # a visit without rows keeps the weights that are there: no block moves
     last_live = jnp.max(jnp.where(sizes > 0, jnp.arange(e, dtype=jnp.int32), 0))

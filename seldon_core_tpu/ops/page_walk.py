@@ -1,0 +1,317 @@
+"""Pallas TPU kernel: an attention read of the paged pool that walks each
+sequence's LIVE pages once, for both kinds of cached row.
+
+The pool caches one row a token a layer in pages addressed through a block
+table: latent attention's ``[c_t ; k^R_t]`` (one pool, no head axis:
+ops/latent_attention.py) or grouped-query attention's K row and V row of
+``n_kv_heads * head_dim`` values (two pools: ops/gqa_attention.py). The read as
+an expression gathers ``pool[block_tables]`` into a copy of the WHOLE logical
+view and multiplies all of it twice, whatever is live. This kernel visits what
+a sequence has live, straight from the pool, once, under a running softmax:
+
+- a VISIT is ``plan.pages`` consecutive entries of one sequence's block table
+  (1,024 cached rows at the served 64-row page, 512 under a chunk's full
+  query tiles) against one tile of that sequence's query rows. A page is a
+  block of the pool as it lies, fetched by its table entry (the table and the
+  visit list are scalar-prefetch operands); no copy of the view exists.
+- a sequence's LIVE pages are those up to its queries' largest valid
+  position. Entries behind them are read as NULL_PAGE (whose positions are
+  PAD_POS forever), so a page behind the live ones is never fetched. A
+  sequence with no valid query (an empty slot of the static-shape step) makes
+  no visit and its rows come out zero. The grid's length is the number of
+  visits, computed on the device from ``positions``. (An empty slot's visit
+  fetched nothing but cost a grid step over all the page operands, ~2 us
+  with thirty-two of them: 54 of the 80 us a layer of a Mistral chat step
+  with 27 of 32 slots empty, v5e, PR 36.)
+- the mask is the expression's ONE predicate, ``pos <= position``, on the
+  positions cached beside the rows: causality, empty rows (PAD_POS), a
+  half-filled page, rows a rejected draft left behind.
+- the queries of a sequence are its ``s x H`` rows, each as wide as a cached
+  row, against one shared row a token: the decode step (s = 1, every slot a
+  sequence), the prefill chunk (one sequence, 256 x H rows in tiles of
+  ``plan.q_tile``) and the speculative verify are one kernel. Scores contract
+  over the whole row of the FIRST pool; the output is ``sum_t p_t v_t`` over
+  the first ``out_dim`` values of the LAST pool's rows, a query row.
+
+Numerics are the expression's: bf16 operands, float32 scores, softmax
+statistics and accumulator; nothing is approximated and no row the mask admits
+is skipped. The probabilities are rounded to bf16 before their sum is divided
+out (the expression rounds them after), so the two differ by bf16 roundings.
+
+Mosaic compiles the kernel on a TPU; other backends run the same body under
+the Pallas interpreter, which is how tier-1 holds it to the expressions over
+the gathered view (tests/test_latent_attention.py, tests/test_gqa_page_attention.py).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple, Optional
+
+# cached rows one visit carries, and the query rows (tokens x heads) it
+# multiplies them by. A step's visit (16-32 query rows) costs ~2.2 us whatever
+# it carries up to 1,024 latent rows (1.3 MB = 1.6 us of HBM time), so it
+# carries that many; where the query rows fill a tile (a chunk) the visit is
+# MXU-bound, half as many rows cost the same a row, and the last visit rounds
+# less (docs/performance.md has the chip's table)
+VISIT_ROWS = 1024
+TILED_VISIT_ROWS = 512
+QUERY_TILE = 512
+# the pages of a visit are operands of their own: no more of them than this
+MAX_VISIT_PAGES = 32
+# ... and no more bytes of rows than this, all pools together (on-chip memory
+# holds them three times: both buffers of each page operand and the rows side
+# by side). Mistral's K + V rows are 4 MB a 1,024-row visit, OLMoE's 8 MB
+VISIT_BYTES = 8 << 20
+LANES = 128
+
+
+class Plan(NamedTuple):
+    """How one call shape is walked: ``pages`` block-table entries a visit,
+    ``q_tile`` query rows a visit. From the call's static shapes alone."""
+
+    pages: int
+    q_tile: int
+
+    def groups(self, n_pages: int) -> int:
+        """Visits that cover a whole block-table row."""
+        return -(-n_pages // self.pages)
+
+
+def plan(s: int, heads: int, n_pages: int, page_size: int, row_dim: int,
+         out_dim: int, pools: int = 1) -> Optional[Plan]:
+    """The walk of a call with ``s`` query tokens of ``heads`` heads a sequence
+    over ``n_pages`` table entries of ``page_size`` rows ``row_dim`` wide in
+    each of ``pools`` pools, or None where the kernel does not take the shape
+    (the caller keeps the expression): the row and the ``out_dim`` values of it
+    that are summed are whole 128-lane tiles, a page is whole bf16 sublane
+    tiles and a visit's rows whole lane tiles, and the query rows divide into
+    tiles."""
+    q_rows = s * heads
+    if row_dim % LANES or out_dim % LANES or not 0 < out_dim <= row_dim:
+        return None
+    visit_rows = min(TILED_VISIT_ROWS if q_rows >= QUERY_TILE else VISIT_ROWS,
+                     VISIT_BYTES // (pools * row_dim * 2))
+    per_visit = visit_rows // page_size
+    if page_size % 16 or not 1 <= per_visit <= MAX_VISIT_PAGES:
+        return None
+    if q_rows % 16 or (q_rows > QUERY_TILE and q_rows % QUERY_TILE):
+        return None
+    # whole lane tiles of rows (two 64-row pages make one), a short table too
+    unit = LANES // math.gcd(LANES, page_size)
+    if per_visit < unit:
+        return None
+    return Plan(pages=min(per_visit // unit * unit, -(-n_pages // unit) * unit),
+                q_tile=min(q_rows, QUERY_TILE))
+
+
+def live_pages(block_tables, positions, page_size: int):
+    """[b] int32: the table entries a sequence's read has to visit, those up
+    to its queries' largest valid position (0 where no query is valid:
+    padding, or a slot nobody holds, whose table row is all TRASH_PAGE)."""
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models.transformer import PAD_POS, TRASH_PAGE
+
+    p = positions.astype(jnp.int32)
+    valid = (p >= 0) & (p < PAD_POS) & (block_tables[:, :1] != TRASH_PAGE)
+    top = jnp.max(jnp.where(valid, p, -1), axis=1)
+    return jnp.minimum((top + page_size) // page_size, block_tables.shape[1])
+
+
+def rows_visited(live_rows: int, page_size: int, walk: Plan) -> int:
+    """Cached rows the kernel multiplies for one sequence whose queries reach
+    ``live_rows`` rows: whole visits."""
+    rows = walk.pages * page_size
+    return -(-live_rows // rows) * rows
+
+
+class Visits(NamedTuple):
+    """The (sequence, page group) pairs one call walks, in order, as the
+    kernel's scalar-prefetch operands; ``count`` of them (the grid's length:
+    at least one, which finishes nothing where no sequence has a live page).
+    ``table`` is the block table with the entries behind each sequence's live
+    pages read as NULL_PAGE, padded to whole visits, flat."""
+
+    seq: "jax.Array"
+    group: "jax.Array"
+    last: "jax.Array"
+    live: "jax.Array"
+    table: "jax.Array"
+    count: "jax.Array"
+
+
+def make_visits(block_tables, live, walk: Plan) -> Visits:
+    """``block_tables`` [b, n_pages] int32, ``live`` [b] (``live_pages``): a
+    sequence visits the groups of ``walk.pages`` entries that hold a live
+    page."""
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models.transformer import NULL_PAGE
+
+    b, n_pages = block_tables.shape
+    groups = walk.groups(n_pages)
+    padded = groups * walk.pages
+    entry = jnp.arange(padded, dtype=jnp.int32)[None]
+    table = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, padded - n_pages)),
+                    constant_values=NULL_PAGE)
+    table = jnp.where(entry < live[:, None], table, NULL_PAGE)
+    n_visits = (-(-live // walk.pages)).astype(jnp.int32)
+    ends = jnp.cumsum(n_visits)
+    i = jnp.arange(b * groups, dtype=jnp.int32)
+    # (all comparisons at once: the default's binary search is a device loop)
+    seq = jnp.minimum(jnp.searchsorted(ends, i, side="right", method="compare_all"),
+                      b - 1).astype(jnp.int32)
+    group = jnp.clip(i - (ends - n_visits)[seq], 0, groups - 1)
+    return Visits(seq=seq, group=group, last=(group == n_visits[seq] - 1).astype(jnp.int32),
+                  live=live.astype(jnp.int32), table=table.reshape(-1),
+                  count=jnp.maximum(ends[-1], 1))
+
+
+def _kernel(walk: Plan, page_size: int, pools: int, out_dim: int, scale: float,
+            seq, group, last, live, table, q_ref, qpos_ref, pos_ref, *refs):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    del table   # the index maps' (which pages are in the page refs)
+    n_pages = pools * walk.pages
+    page_refs = refs[:n_pages]
+    out_ref = refs[n_pages]
+    rows_refs = refs[n_pages + 1:n_pages + 1 + pools]
+    m_ref, l_ref, acc_ref = refs[n_pages + 1 + pools:]
+    v = pl.program_id(1)
+    lowest = jnp.finfo(jnp.float32).min
+
+    @pl.when(group[v] == 0)
+    def _start():
+        m_ref[...] = jnp.full_like(m_ref, lowest)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live[seq[v]] > group[v] * walk.pages)
+    def _visit():
+        # the visit's pages side by side, as the products read them
+        for i, page in enumerate(page_refs):
+            j = i % walk.pages
+            rows_refs[i // walk.pages][j * page_size:(j + 1) * page_size, :] = page[...]
+        keys = rows_refs[0][...]
+        scores = jax.lax.dot_general(
+            q_ref[...], keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        # the one predicate: causality, empty rows (PAD_POS), padding
+        admitted = pos_ref[...] <= qpos_ref[...]
+        scores = jnp.where(admitted, scores, lowest)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, jnp.max(scores, axis=1, keepdims=True))
+        shrink = jnp.exp(m_old - m_new)
+        p = jnp.where(admitted, jnp.exp(scores - m_new), 0.0)
+        l_ref[...] = shrink * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = shrink * acc_ref[...] + jnp.dot(
+            p.astype(keys.dtype), rows_refs[-1][:, :out_dim], preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(last[v] == 1)
+    def _finish():   # a row that admitted nothing comes out zero
+        total = l_ref[...]
+        out_ref[...] = (acc_ref[...] / jnp.where(total > 0.0, total, 1.0)).astype(out_ref.dtype)
+
+
+def page_walk_attention(q, pools, pos_pool, block_tables, positions, scale: float,
+                        out_dim: int, walk: Plan, name: str, interpret: bool | None = None):
+    """``q`` [b, s, H, W] query rows as wide as a cached row, in the pools'
+    dtype; ``pools`` one or two arrays [pages, page_size, W] as held (the first
+    scored, the last summed) / ``pos_pool`` [pages, page_size] int32;
+    ``block_tables`` [b, n_pages]; ``positions`` [b, s] -> [b, s, H, out_dim] =
+    softmax(scale q . rows, pos <= position) rows'[:, :out_dim] over the rows
+    the tables name, in ``q``'s dtype. ``walk`` = ``plan(...)`` of the same
+    shapes; ``name`` is the op's in a device trace. ``interpret=None`` compiles
+    the kernel on a TPU and interprets it on any other backend; pass a bool to
+    force either.
+
+    The call is a jitted function of its own, so a program of many layers
+    traces the kernel and its index maps (one a page operand) ONCE and not once
+    a layer: 0.9 s a layer on the chip's host with Mistral's 64 page operands,
+    33 s of every start of a 32-layer step program, which no compile cache
+    serves (v5e, PR 36)."""
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops import pallas_interpret_default
+
+    if interpret is None:
+        interpret = pallas_interpret_default()
+    return _jitted_walk()(q, tuple(pools), pos_pool, jnp.asarray(block_tables, jnp.int32),
+                          positions, scale=scale, out_dim=out_dim, walk=walk, name=name,
+                          interpret=interpret)
+
+
+@functools.cache
+def _jitted_walk():
+    import jax
+
+    return jax.jit(_walk_pages,
+                   static_argnames=("scale", "out_dim", "walk", "name", "interpret"))
+
+
+def _walk_pages(q, pools, pos_pool, bt, positions, *, scale, out_dim, walk, name, interpret):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, heads, width = q.shape
+    page_size = pos_pool.shape[1]
+    n_pages = bt.shape[1]
+    groups, rows = walk.groups(n_pages), walk.pages * page_size
+    q_rows, tq = s * heads, walk.q_tile
+    assert all(pool.shape[1:] == (page_size, width) for pool in pools), (q.shape, walk)
+    assert q_rows % tq == 0, (q.shape, walk)
+
+    visits = make_visits(bt, live_pages(bt, positions, page_size), walk)
+    # the positions cached beside the rows the visits fetch: 256 B a page
+    pos_view = pos_pool[visits.table].reshape(b * groups, 1, rows)
+    qpos = jnp.repeat(positions.astype(jnp.int32), heads, axis=1)[..., None]
+
+    def of_sequence(t, v, seq, *_):
+        return (seq[v], t, 0)
+
+    def page_spec(j):
+        return pl.BlockSpec(
+            (None, page_size, width),
+            lambda t, v, seq, group, last, live, table:
+                (table[(seq[v] * groups + group[v]) * walk.pages + j], 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_kernel, walk, page_size, len(pools), out_dim, scale),
+        out_shape=jax.ShapeDtypeStruct((b, q_rows, out_dim), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            in_specs=[
+                pl.BlockSpec((None, tq, width), of_sequence),
+                pl.BlockSpec((None, tq, 1), of_sequence),
+                pl.BlockSpec((None, 1, rows),
+                             lambda t, v, seq, group, *_: (seq[v] * groups + group[v], 0, 0)),
+                *[page_spec(j) for _ in pools for j in range(walk.pages)]],
+            out_specs=pl.BlockSpec((None, tq, out_dim), of_sequence),
+            grid=(q_rows // tq, visits.count),
+            scratch_shapes=[
+                *[pltpu.VMEM((rows, width), pool.dtype) for pool in pools],  # the visit's rows
+                pltpu.VMEM((tq, 1), jnp.float32),            # running maximum
+                pltpu.VMEM((tq, 1), jnp.float32),            # running sum
+                pltpu.VMEM((tq, out_dim), jnp.float32)]),    # running products
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name=name,
+    )(visits.seq, visits.group, visits.last, visits.live, visits.table,
+      q.reshape(b, q_rows, width), qpos, pos_view,
+      *[pool for pool in pools for _ in range(walk.pages)])
+    # a sequence no visit finished was never written
+    out = jnp.where((visits.live > 0)[:, None, None], out, 0)
+    return out.reshape(b, s, heads, out_dim)
+
+
+__all__ = ["Plan", "Visits", "live_pages", "make_visits", "page_walk_attention", "plan",
+           "rows_visited"]

@@ -1354,9 +1354,9 @@ class ContinuousBatcher:
         # device array made once (no transfer a chunk)
         self._state_slot = [jnp.asarray([i], jnp.int32) for i in range(self.S)
                             ] if self._conv_layers else None
-        # the read is the XLA gather of the whole view, except latent
-        # attention's on one TPU, which walks the live pages
-        # (ops/latent_attention.py) — said here so a server's log names it
+        # the step's read is the XLA gather of the whole view, except on one
+        # TPU, where a kernel walks the live pages (ops/page_walk.py) — said
+        # here so a server's log names it
         from seldon_core_tpu.models.transformer import kv_cache_bytes_per_token
 
         logger.info(
@@ -2497,18 +2497,20 @@ class ContinuousBatcher:
                 self._activate(job, logits, n - 1)
 
     def _read_walk(self, s: int):
-        """How latent attention's kernel walks the live pages for calls of
-        ``s`` query tokens a sequence (ops/latent_attention.py ``Plan``), or
-        None where the read gathers the whole block-table view: the rule the
-        module itself takes (models/transformer.py ``latent_read_walk``), in
-        a process whose programs are compiled for a TPU."""
+        """How the attention read's kernel walks the live pages for calls of
+        ``s`` query tokens a sequence (ops/page_walk.py ``Plan``), or None
+        where the read gathers the whole block-table view: the ONE rule
+        ``Attention`` and ``LatentAttention`` themselves take
+        (models/transformer.py ``paged_read_walk``), in a process whose
+        programs are compiled for a TPU."""
         if s not in self._read_walks:
             import jax
 
-            from seldon_core_tpu.models.transformer import latent_read_walk
+            from seldon_core_tpu.models.transformer import paged_read_walk
 
-            self._read_walks[s] = None if jax.default_backend() != "tpu" else latent_read_walk(
-                self.server._cfg, s, self.n_pages, self.page_size, self._caches[0][0].dtype)
+            pool = next(layer[0] for layer in self._caches if not is_state_entry(layer))
+            self._read_walks[s] = None if jax.default_backend() != "tpu" else paged_read_walk(
+                self.server._cfg, s, self.n_pages, self.page_size, pool.dtype)
         return self._read_walks[s]
 
     def _rows_read(self, s: int, live_rows: Sequence[int], sequences: int) -> int:
@@ -2521,7 +2523,7 @@ class ContinuousBatcher:
         walk = self._read_walk(s)
         if walk is None:
             return sequences * self.n_pages * self.page_size
-        from seldon_core_tpu.ops.latent_attention import rows_visited
+        from seldon_core_tpu.ops.page_walk import rows_visited
 
         return sum(rows_visited(rows, self.page_size, walk) for rows in live_rows)
 

@@ -727,14 +727,18 @@ class LLMServer(SeldonComponent):
                     status_code=500,
                 )
             self.mesh = topo.mesh({"data": -1, "seq": sp, "model": tp})
-        if self.mesh is not None and int(cfg_kwargs.get("n_experts", 0) or 0) > 0:
-            # MoEFFN's grouped-matmul kernel is one device's program: with the
-            # expert stacks sharded over a mesh it keeps jax.lax.ragged_dot,
-            # which GSPMD partitions (models/transformer.py)
-            cfg_kwargs.setdefault("mesh", self.mesh)
+        module = get_model(name, **cfg_kwargs)
+        if self.mesh is not None and module.cfg.mesh is None:
+            # the modules' kernels are one device's programs: sharded over a
+            # mesh, MoEFFN keeps jax.lax.ragged_dot, the Sinkhorn chain and
+            # the paged attention read keep their expressions, which GSPMD
+            # partitions, and a K / V pool of heads of 128 keeps its kv_heads
+            # axis (models/transformer.py: kv_rows_flat, paged_read_walk)
+            import dataclasses
 
-        self._module = get_model(name, **cfg_kwargs)
-        self._cfg = self._module.cfg
+            module = module.clone(cfg=dataclasses.replace(module.cfg, mesh=self.mesh))
+        self._module = module
+        self._cfg = module.cfg
         self._abstract_init = None  # _init_shapes(), of this module
         refusal = self._state_layers_refusal()
         if refusal:

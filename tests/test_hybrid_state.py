@@ -97,28 +97,23 @@ def test_chunked_prefill_and_decode_equal_the_full_forward(server, length):
     assert stats["conv_layer_calls"] == {"chunk": 4 * -(-length // CHUNK), "decode": 4 * 4}
 
 
-# heads of 64 in a row of 128 values: the bf16 pool holds a token's heads as ONE
-# row (TransformerConfig.kv_rows_flat), a chunk splits them out of the gathered
-# view and a step reads the rows as they lie (flat_rows_attention)
+# the bf16 pool holds a token's heads as ONE row whatever a head's width (64 in a
+# row of 128 values, LFM2's kind; 128 in a row of 256); the expression splits
+# them out of the gathered view, in a chunk and in a step
 FLAT_KW = dict(KW, dim=256, n_heads=4, n_kv_heads=2, layer_types=["conv", "full_attention", "conv"],
                n_layers=3, first_dense_layers=1)
 
 
-@pytest.mark.parametrize("query_rows", [512, 4], ids=["chunk_reads_rows", "chunk_splits_the_view"])
+@pytest.mark.parametrize("head_dim", [64, 128])
 @pytest.mark.parametrize("length", [1, CHUNK + 2, 2 * CHUNK + 3])
-def test_narrow_heads_are_held_as_flat_rows_and_read_both_ways(monkeypatch, length, query_rows):
-    import seldon_core_tpu.models.transformer as transformer_mod
-
-    # 4 heads: a step's 4 query rows read the rows as they lie under either
-    # threshold; a chunk's 32 do under the first and split the view under the second
-    monkeypatch.setattr(transformer_mod, "FLAT_READ_QUERY_ROWS", query_rows)
-    flat_server = make_server(model_kwargs=FLAT_KW)
+def test_heads_are_held_as_flat_rows_whatever_their_width(length, head_dim):
+    flat_server = make_server(model_kwargs=dict(FLAT_KW, dim=4 * head_dim))
     cfg = flat_server._cfg
-    assert cfg.kv_rows_flat and not get_model("transformer", **KW).cfg.kv_rows_flat
+    assert cfg.head_dim == head_dim
     pool = init_paged_kv_caches(cfg, 10, 4, state_slots=3)[1]
-    assert pool[0].shape == pool[1].shape == (10, 4, 128)
-    assert init_paged_kv_caches(cfg, 10, 4, "int8", state_slots=3)[1][0].shape == (10, 4, 2, 64)
-    assert init_kv_caches(cfg, 2, 16)[1][0].shape == (2, 16, 2, 64)
+    assert pool[0].shape == pool[1].shape == (10, 4, 2 * head_dim)
+    assert init_paged_kv_caches(cfg, 10, 4, "int8", state_slots=3)[1][0].shape == (10, 4, 2, head_dim)
+    assert init_kv_caches(cfg, 2, 16)[1][0].shape == (2, 16, 2, head_dim)
     prompt = LONG[:length]
 
     async def go():
@@ -293,7 +288,7 @@ def test_the_cache_trees_hold_two_kinds_of_entry(server):
     paged = init_paged_kv_caches(cfg, 10, 4, state_slots=3)
     assert [is_state_entry(layer) for layer in paged] == [True, True, False, True, True]
     assert dense[0][0].shape == (2, 2, 32) and paged[0][0].shape == (3, 2, 32)
-    assert len(paged[2]) == 3 and paged[2][0].shape == (10, 4, 2, 8)
+    assert len(paged[2]) == 3 and paged[2][0].shape == (10, 4, 2 * 8)
     with pytest.raises(ValueError, match="state_slots"):
         init_paged_kv_caches(cfg, 10, 4)
     from seldon_core_tpu.models.transformer import conv_state_bytes, kv_cache_bytes_per_token

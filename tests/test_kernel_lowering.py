@@ -20,8 +20,8 @@ from seldon_core_tpu.models import get_model
 from seldon_core_tpu.models.transformer import init_paged_kv_caches
 from seldon_core_tpu.ops.fused_norm import fused_residual_rmsnorm
 from seldon_core_tpu.ops.grouped_matmul import grouped_matmul, make_visits, row_tile
+from seldon_core_tpu.ops.gqa_attention import gqa_page_attention, gqa_plan
 from seldon_core_tpu.ops.latent_attention import latent_page_attention, plan
-from seldon_core_tpu.ops.paged_attention import paged_attention
 from seldon_core_tpu.ops.pallas_int8 import int8_matmul
 from seldon_core_tpu.ops.sinkhorn import sinkhorn
 
@@ -110,6 +110,63 @@ def test_latent_page_attention_lowers_for_tpu(slots, tokens, heads, pages):
     assert text.count(MOSAIC_CALL) == 1
 
 
+@pytest.mark.parametrize("slots,tokens,heads,kv_heads,head_dim,pages", [
+    (32, 1, 32, 8, 128, 16), (8, 1, 32, 8, 128, 64), (32, 1, 16, 16, 128, 16),
+    (32, 1, 32, 8, 64, 64), (8, 4, 32, 8, 128, 64), (8, 1, 32, 32, 128, 17)])
+def test_gqa_page_attention_lowers_for_tpu(slots, tokens, heads, kv_heads, head_dim, pages):
+    """The live-page read of grouped-query attention at the GQA cells' steps
+    (Mistral 32 slots x 1,024 rows and 8 x 4,096; OLMoE 32 x 1,024, 16 heads of
+    their own; LFM2 32 x 4,096, heads of 64), at a speculative verify of three
+    drafts, and at Llama-2-7B's 32 KV heads (chip_smoke.py: a 4,096-wide row,
+    half as many rows a visit): K and V rows as the pools hold them."""
+    walk = gqa_plan(tokens, heads, kv_heads, head_dim, pages, 64)
+    row = kv_heads * head_dim
+    assert walk.pages * 64 * row * 4 <= 8 << 20
+    pool_pages = 2 + slots * pages
+    text = tpu_mlir(
+        lambda q, k, v, pos, tables, positions: gqa_page_attention(
+            q, k, v, pos, tables, positions, kv_heads, walk, interpret=False),
+        S((slots, tokens, heads, head_dim), jnp.bfloat16), S((pool_pages, 64, row), jnp.bfloat16),
+        S((pool_pages, 64, row), jnp.bfloat16), S((pool_pages, 64), jnp.int32),
+        S((slots, pages), jnp.int32), S((slots, tokens), jnp.int32))
+    assert text.count(MOSAIC_CALL) == 1
+
+
+def test_the_gqa_read_reaches_the_kernel_on_a_tpu_and_the_expression_elsewhere():
+    """``Attention`` chooses as ``LatentAttention`` does, for the bf16 paged
+    pool: one kernel a layer in a step lowered for a TPU, none in any other
+    lowering, none over the int8 pool, the dense cache or without a cache, none
+    for a chunk's query rows, none for a row that is no whole lane tile."""
+    kwargs = dict(vocab_size=256, dim=256, n_layers=2, ffn_dim=128, max_seq_len=128,
+                  dtype="bfloat16", n_heads=16, n_kv_heads=8)
+
+    def lowerings(tokens_a_call=1, kv_cache_dtype="bf16", **more):
+        model = get_model("transformer", **{**kwargs, **more})
+        tokens = jnp.zeros((2, tokens_a_call), jnp.int32)
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+        pools = jax.eval_shape(lambda: init_paged_kv_caches(model.cfg, 6, 64, kv_cache_dtype))
+
+        def step(params, pools, tokens, positions, block_tables):
+            return model.apply(params, tokens, positions=positions, caches=pools,
+                               block_tables=block_tables)
+
+        args = (params, pools, S(tokens.shape, jnp.int32), S(tokens.shape, jnp.int32),
+                S((2, 2), jnp.int32))
+        plain = jax.jit(lambda params, tokens: model.apply(params, tokens)[0])
+        return (model.cfg, tpu_mlir(step, *args), jax.jit(step).lower(*args).as_text(),
+                tpu_mlir(plain, params, tokens))
+
+    # (the walk is a jitted function of its own: the layers call ONE lowering of it)
+    cfg, on_tpu, elsewhere, no_cache = lowerings()
+    assert on_tpu.count(MOSAIC_CALL) == 1
+    assert on_tpu.count("call @_walk_pages(") == cfg.n_layers
+    assert MOSAIC_CALL not in elsewhere and MOSAIC_CALL not in no_cache
+    assert lowerings(tokens_a_call=4)[1].count("call @_walk_pages(") == cfg.n_layers   # a verify
+    assert MOSAIC_CALL not in lowerings(tokens_a_call=32)[1]                  # a chunk
+    assert MOSAIC_CALL not in lowerings(kv_cache_dtype="int8")[1]
+    assert MOSAIC_CALL not in lowerings(n_kv_heads=4, dim=192, n_heads=16)[1]   # 4 x 12 = 48
+
+
 def test_the_latent_read_reaches_the_kernel_on_a_tpu_and_the_expression_elsewhere():
     """``LatentAttention`` chooses as ``MoEFFN`` does, for the paged pool: one
     kernel a layer in a program lowered for a TPU, none in any other lowering,
@@ -134,8 +191,10 @@ def test_the_latent_read_reaches_the_kernel_on_a_tpu_and_the_expression_elsewher
         return (model.cfg, tpu_mlir(step, *args), jax.jit(step).lower(*args).as_text(),
                 tpu_mlir(plain, params, tokens))
 
+    # (the walk is a jitted function of its own: the layers call ONE lowering of it)
     cfg, on_tpu, elsewhere, no_cache = lowerings()
-    assert on_tpu.count(MOSAIC_CALL) == cfg.n_layers
+    assert on_tpu.count(MOSAIC_CALL) == 1
+    assert on_tpu.count("call @_walk_pages(") == cfg.n_layers
     assert MOSAIC_CALL not in elsewhere and MOSAIC_CALL not in no_cache
     assert MOSAIC_CALL not in lowerings(kv_lora_rank=96)[1]
 
@@ -157,9 +216,8 @@ def _paged_decode_mlir(**model_kwargs) -> str:
 
 
 def test_paged_decode_reaches_no_pallas_kernel_by_default():
-    """The paged read is the XLA gather on a TPU too: the page-streaming
-    kernel is unreachable from the compiled path (it does not lower —
-    below), and fused_norm is off by default."""
+    """At toy dims (4 heads: query rows under a sublane tile) the paged read is
+    the XLA gather on a TPU too, and fused_norm is off by default."""
     assert MOSAIC_CALL not in _paged_decode_mlir()
 
 
@@ -168,20 +226,6 @@ def test_fused_norm_flag_puts_the_kernel_on_the_tpu_path(monkeypatch):
     Mosaic kernel — there is no reference to fall to"""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert MOSAIC_CALL in _paged_decode_mlir(fused_norm=True)
-
-
-def test_paged_attention_kernel_does_not_lower_for_tpu():
-    """The finding ROADMAP A3 starts from, kept executable: at the 7B
-    serving shapes the kernel is refused before Mosaic sees it. When this
-    test fails the kernel lowers — put it on the chip, then decide."""
-    pools = (S((138, 64, 32, 128), jnp.bfloat16),
-             S((138, 64, 32, 128), jnp.bfloat16), S((138, 64), jnp.int32))
-    with pytest.raises(ValueError, match="divisible by 8 and 128"):
-        tpu_mlir(
-            lambda q, k, v, p, bt, pos: paged_attention(
-                q, (k, v, p), bt, pos, interpret=False),
-            S((8, 1, 32, 128), jnp.bfloat16), *pools, S((8, 17), jnp.int32),
-            S((8, 1), jnp.int32))
 
 
 def test_kernel_choice_is_the_same_inside_and_outside_a_trace():

@@ -15,8 +15,8 @@ import pytest
 
 from seldon_core_tpu.models.transformer import (
     NULL_PAGE, PAD_POS, TRASH_PAGE, absorbed_latent_attention, absorbed_query_rows)
-from seldon_core_tpu.ops.latent_attention import (
-    Plan, latent_page_attention, live_pages, make_visits, plan, rows_visited)
+from seldon_core_tpu.ops.latent_attention import latent_page_attention
+from seldon_core_tpu.ops.page_walk import Plan, live_pages, make_visits, plan, rows_visited
 
 PAGE, DN, DR, DC, DV, WIDTH, SCALE = 32, 32, 16, 128, 32, 256, 0.11
 NOBODY = -1   # a slot nobody holds: its table row is all TRASH_PAGE
@@ -127,13 +127,14 @@ def test_the_live_page_read_is_the_expression_over_the_gathered_view(case):
     assert np.all(np.isfinite(got))
     valid = np.asarray((positions < PAD_POS) & (tables[:, :1] != TRASH_PAGE))
     np.testing.assert_allclose(got[valid], want[valid], atol=2e-2, rtol=2e-2)
-    # a slot with no valid query makes one visit, reads nothing, writes zeros
+    # a slot with no valid query makes no visit and comes out zero
     for i, held in enumerate(rows):
         if held == NOBODY:
             assert np.all(got[i] == 0.0)
     per_visit = walk.pages * PAGE
-    assert visits == sum(max(-(-max(held, 0) // per_visit), 1) for held in rows)
-    assert visits * per_visit == sum(rows_visited(max(held, 0), PAGE, walk) for held in rows)
+    assert visits == max(sum(-(-max(held, 0) // per_visit) for held in rows), 1)
+    assert visits * per_visit == max(sum(rows_visited(max(held, 0), PAGE, walk) for held in rows),
+                                     per_visit)
 
 
 @pytest.mark.parametrize("s,heads", [(1, 16), (16, 16), (3, 32)])
@@ -158,19 +159,21 @@ def test_pages_behind_the_live_ones_are_never_read(s, heads):
 
 def test_the_visit_list():
     """Three sequences over tables of ten entries, four a visit: one with
-    five live pages visits two groups, one nobody holds visits one (and
-    fetches NULL_PAGE alone), one with ten visits all three; entries behind
-    the live pages read as NULL_PAGE."""
+    five live pages visits two groups, one nobody holds visits none, one with
+    ten visits all three; entries behind the live pages read as NULL_PAGE.
+    Where nobody holds any slot the grid is one visit that finishes nothing."""
     tables = jnp.asarray(np.arange(2, 32).reshape(3, 10), jnp.int32).at[1].set(TRASH_PAGE)
     positions = jnp.asarray([[PAGE * 4 + 3], [0], [PAGE * 10 - 1]], jnp.int32)
     live = live_pages(tables, positions, PAGE)
     assert live.tolist() == [5, 0, 10]
     visits = make_visits(tables, live, Plan(4, 16))
     n = int(visits.count)
-    assert n == 6
-    assert visits.seq[:n].tolist() == [0, 0, 1, 2, 2, 2]
-    assert visits.group[:n].tolist() == [0, 1, 0, 0, 1, 2]
-    assert visits.last[:n].tolist() == [0, 1, 1, 0, 0, 1]
+    assert n == 5
+    assert visits.seq[:n].tolist() == [0, 0, 2, 2, 2]
+    assert visits.group[:n].tolist() == [0, 1, 0, 1, 2]
+    assert visits.last[:n].tolist() == [0, 1, 0, 0, 1]
+    nobody = make_visits(tables.at[:].set(TRASH_PAGE), jnp.zeros((3,), jnp.int32), Plan(4, 16))
+    assert int(nobody.count) == 1 and int(nobody.last[0]) == 0 and int(nobody.live[nobody.seq[0]]) == 0
     table = np.asarray(visits.table).reshape(3, 12)
     assert table[0].tolist() == [2, 3, 4, 5, 6] + [NULL_PAGE] * 7
     assert table[1].tolist() == [NULL_PAGE] * 12
@@ -196,7 +199,7 @@ def test_the_walk_at_the_served_shapes():
     assert plan(1, 16, 256, 16, 640, 512) is None          # a visit of 64 pages
     assert plan(1, 2, 256, 64, 640, 512) is None           # two query rows
     assert plan(40, 16, 256, 64, 640, 512) is None         # 640 query rows: no whole tiles
-    assert rows_visited(0, 64, Plan(16, 16)) == 1024 == rows_visited(1024, 64, Plan(16, 16))
+    assert rows_visited(0, 64, Plan(16, 16)) == 0 and rows_visited(1024, 64, Plan(16, 16)) == 1024
     assert rows_visited(1025, 64, Plan(16, 16)) == 2048
 
 
@@ -256,11 +259,11 @@ def test_latent_attention_through_the_kernel_is_latent_attention_through_the_exp
 def test_the_loop_counts_the_rows_the_read_visited(monkeypatch):
     """``seldon_llm_attn_rows_read_total``: the whole block-table view of every
     sequence of a call where the expression serves (every lowering that is not
-    for a TPU, a model without latent attention, a mesh), whole visits over the
+    for a TPU, a mesh, a shape the kernel does not take), whole visits over the
     live rows where the kernel does, by the rule the module itself takes."""
     from types import SimpleNamespace
 
-    from seldon_core_tpu.models.transformer import TransformerConfig, latent_read_walk
+    from seldon_core_tpu.models.transformer import TransformerConfig, paged_read_walk
     from seldon_core_tpu.runtime.batcher import ContinuousBatcher, LoopPhases
 
     cfg = TransformerConfig(**{**LATENT_TOY, "kv_lora_rank": 512, "qk_rope_head_dim": 64})
@@ -268,7 +271,7 @@ def test_the_loop_counts_the_rows_the_read_visited(monkeypatch):
 
     def loop(cfg):
         return SimpleNamespace(server=SimpleNamespace(_cfg=cfg), n_pages=256, page_size=64,
-                               _caches=[(jnp.zeros((1,), jnp.bfloat16),)], _read_walks={})
+                               _caches=[(jnp.zeros((1,), jnp.bfloat16), None)], _read_walks={})
 
     def rows_read(loop, *args):
         loop._read_walk = lambda s: ContinuousBatcher._read_walk(loop, s)
@@ -277,7 +280,7 @@ def test_the_loop_counts_the_rows_the_read_visited(monkeypatch):
     view = 256 * 64
     assert rows_read(loop(cfg), 1, [12000, 9000], 8) == 8 * view        # here: the CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert latent_read_walk(cfg, 1, 256, 64, jnp.bfloat16) == Plan(16, 16)
+    assert paged_read_walk(cfg, 1, 256, 64, jnp.bfloat16) == Plan(16, 16)
     assert rows_read(loop(cfg), 1, [12000, 9000], 8) == 12 * 1024 + 9 * 1024
     assert rows_read(loop(cfg), 256, [6250], 1) == 13 * 512
     assert rows_read(loop(cfg), 40, [6250], 1) == view                   # no walk for 640 query rows

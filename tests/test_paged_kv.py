@@ -443,52 +443,7 @@ def test_pool_too_small_for_one_sequence_rejected(server):
                           page_size=8, pool_pages=3)
 
 
-# ------------------------------------------------------------- kernel
-@pytest.mark.pallas
-@pytest.mark.parametrize("kvd", ["bf16", "int8"])
-def test_paged_attention_kernel_interpret_parity(kvd):
-    """The Pallas paged-attention decode kernel (interpret mode) matches
-    the gather reference across multiple pages, GQA head groups, NULL-page
-    table tails and mixed per-sequence lengths."""
-    import jax
-    import jax.numpy as jnp
-
-    from seldon_core_tpu.models.transformer import (
-        PAD_POS, quantize_kv)
-    from seldon_core_tpu.ops.paged_attention import (
-        paged_attention, paged_attention_ref)
-
-    b, h, kvh, hd, ps, n_pages, pool = 3, 4, 2, 16, 8, 3, 12
-    rng = np.random.default_rng(0)
-    lens = [5, 17, 23]  # wildly different; page tails masked
-    k_vals = jnp.asarray(rng.standard_normal((pool, ps, kvh, hd)), jnp.float32)
-    v_vals = jnp.asarray(rng.standard_normal((pool, ps, kvh, hd)), jnp.float32)
-    pos = np.full((pool, ps), PAD_POS, np.int32)
-    bt = np.zeros((b, n_pages), np.int32)  # NULL-page tails
-    nxt = 2
-    for i, L in enumerate(lens):
-        for pg in range(-(-L // ps)):
-            bt[i, pg] = nxt
-            fill = min(ps, L - pg * ps)
-            pos[nxt, :fill] = np.arange(pg * ps, pg * ps + fill)
-            nxt += 1
-    pos = jnp.asarray(pos)
-    bt = jnp.asarray(bt)
-    q = jnp.asarray(rng.standard_normal((b, 1, h, hd)), jnp.float32)
-    qpos = jnp.asarray([[L - 1] for L in lens], jnp.int32)
-
-    if kvd == "int8":
-        kq, ks = quantize_kv(k_vals)
-        vq, vs = quantize_kv(v_vals)
-        cache = (kq, ks, vq, vs, pos)
-    else:
-        cache = (k_vals, v_vals, pos)
-    ref = paged_attention_ref(q, cache, bt, qpos)
-    ker = paged_attention(q, cache, bt, qpos, interpret=True)
-    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
+# ------------------------------------------------------------- writes
 @pytest.mark.pallas
 def test_paged_write_targets_redirect_garbage():
     """Device-side write-safety invariants: NULL table entries and

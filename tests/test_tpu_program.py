@@ -22,6 +22,10 @@ was converted and written out in bf16 to read 32 rows of it
 (``multiply_convert_fusion bf16[131072,3584]``: 2.4 ms of a 9 ms Xing4.0 step,
 booked as "the head's copy" until the operands were read here); the table
 reaches the module int8 and the int8 rows are gathered (ops/quantize.py).
+
+Fourth (PRs 32, 36): the paged pool's attention read of a decode step walks each
+sequence's live pages with the repo's kernel (ops/page_walk.py), fed the pool as
+it is held; no step program holds a gathered copy of the logical view.
 """
 
 import re
@@ -134,8 +138,9 @@ def compiled(server, program: str, sharding, slots: int = 32, length: int = 0):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
 
-    def abstract(tree):
-        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    def abstract(tree):   # (a shape that is placed already keeps its placement)
+        return jax.tree.map(lambda x: x if isinstance(x, jax.ShapeDtypeStruct)
+                            else sds(x.shape, x.dtype), tree)
 
     pages = (length or (1024 if program == "decode_step" else 4096)) // PAGE
     params = abstract(server._params)
@@ -391,6 +396,131 @@ def test_the_latent_read_walks_the_live_pages_and_holds_no_view(v5e, servers, co
         assert exe.memory_analysis().temp_size_in_bytes < sequences * pages * PAGE * 640 * 2
 
 
+# the GQA cells' servers (PERF.md section 4): the configuration here, slots x
+# tokens a slot, and the K / V row (n_kv_heads x head_dim)
+GQA_CELLS = {"mistral chat": ("mistral", 32, 1024, 1024), "mistral docs": ("mistral", 8, 4096, 1024),
+             "olmoe chat": ("olmoe", 32, 1024, 2048), "lfm2 rag": ("lfm2", 32, 4096, 512)}
+
+
+@pytest.mark.parametrize("cell", list(GQA_CELLS))
+def test_the_gqa_step_walks_the_live_pages_and_holds_no_view(v5e, servers, cell):
+    """At the cells' own shapes the decode step's read under ``attn.gqa.read``
+    is ONE Mosaic kernel of the repo's an attention layer (ops/gqa_attention.py),
+    fed the K and the V pool as they are held (flat rows ``[pages, 64, kvh x
+    hd]``, row-major), sixteen pages of each a visit; the program holds no
+    gathered copy of the logical view in any of its shapes (``fusion
+    bf16[512,64,8,128]`` / ``[512,64,16,128]`` / ``[2048,64,512]``: the largest
+    ops of these cells' steps in PR 35's traces), no copy of a pool, and the
+    write is under ``attn.gqa.write``."""
+    from seldon_core_tpu.ops.gqa_attention import KERNEL_NAME
+
+    config, slots, length, row = GQA_CELLS[cell]
+    server = servers(config)
+    cfg = server._cfg
+    assert cfg.n_kv_heads * cfg.head_dim == row
+    pages = length // PAGE
+    layers = cfg.n_layers - len(cfg.conv_layers)
+    hlo = compiled(server, "decode_step", v5e, slots=slots, length=length).as_text()
+    calls = [line for line in hlo.splitlines() if re.match(rf"\s*%{KERNEL_NAME}[\w.]* = ", line)]
+    assert len(calls) == layers
+    assert all('custom_call_target="tpu_custom_call"' in line for line in calls)
+    assert all("attn.gqa.read" in line for line in calls)
+    assert all(f"= bf16[{slots},{cfg.n_heads},{row}]" in line for line in calls)
+    # a visit's pages are operands of the pools themselves, not of a copy
+    pool_pages = slots * pages + 2
+    pool = f"bf16[{pool_pages},{PAGE},{row}]"
+    assert all(line.count(pool) == 2 * 16 for line in calls), calls[0][:400]
+    entry = hlo[hlo.index("\nENTRY"):]
+    assert len(re.findall(rf"%pools_\d__[01]_[\w.]* = bf16\[{pool_pages},{PAGE},{row}\]\{{2,1,0:", entry)
+               ) == 2 * layers
+    views = [(slots * pages, PAGE), (slots, pages, PAGE), (slots, pages * PAGE)]
+    rows = [(row,), (cfg.n_kv_heads, cfg.head_dim)]
+    for view in (lead + tail for lead in views for tail in rows):
+        text = ",".join(str(n) for n in view)
+        assert f"bf16[{text}]" not in hlo and f"f32[{text}]" not in hlo, view
+    assert weight_copies(hlo, {(pool_pages, PAGE) + tail for tail in rows}) == []
+    assert "attn.gqa.write" in hlo
+
+
+# attention of LFM2's widths (32 heads of 64 over 8 KV heads) around a dense
+# FFN: what a mesh serves of narrow heads (conv layers refuse one)
+NARROW_HEADS = dict(vocab_size=256, dim=2048, n_layers=1, n_heads=32, n_kv_heads=8,
+                    ffn_dim=7168, max_seq_len=1024, dtype="bfloat16", qk_norm="head")
+
+
+def test_on_a_mesh_narrow_heads_keep_flat_rows_and_no_pool_is_copied():
+    """Sharded over a described v5e 2x2 ('model' = 4, the weights placed by the
+    repo's own rules), the step keeps the expression over the gathered view:
+    no kernel (one device's program) under ``attn.gqa.read``. A pool of heads
+    of 128 keeps its ``[.., kvh, hd]`` axes for the partitioner; one of heads
+    of 64 is flat rows there too (held ``[.., 8, 64]`` it came out pages-minor
+    and was copied whole, PR 35). Either way a chip holds its KV heads' share
+    of every page, row-major, and neither program copies a pool."""
+    import dataclasses
+
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from seldon_core_tpu.ops.gqa_attention import KERNEL_NAME
+    from seldon_core_tpu.parallel import sharding as sharding_mod
+
+    try:
+        topology = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:
+        pytest.skip(f"libtpu cannot describe a v5e 2x2 here: {type(exc).__name__}: {exc}")
+    mesh = Mesh(np.array(topology.devices).reshape(1, 1, 4), ("data", "seq", "model"))
+    replicated = NamedSharding(mesh, PartitionSpec())
+
+    def place(x, sharding):   # nothing can be put on a described device
+        return jax.tree.map(
+            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sharding), x)
+
+    for kwargs, flat in ((NARROW_HEADS, True), (MISTRAL, False)):
+        server = _served(kwargs)
+        server.mesh = mesh
+        server._cfg = dataclasses.replace(server._cfg, mesh=mesh)
+        server._module = server._module.clone(cfg=server._cfg)
+        cfg = server._cfg
+        assert cfg.kv_rows_flat is flat
+        # a chip's share of the pool: the KV heads are what 'model' divides
+        tail = ((cfg.n_kv_heads * cfg.head_dim // 4,) if flat
+                else (cfg.n_kv_heads // 4, cfg.head_dim))
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "device_put", place)   # the repo's rules place shapes
+            server._params = sharding_mod.shard_params(
+                server._params, mesh, server._logical_axes())
+        placed = {str(leaf.sharding.spec) for leaf in jax.tree.leaves(server._params)}
+        assert any("model" in spec for spec in placed), placed
+        hlo = compiled(server, "decode_step", replicated).as_text()
+        assert not re.search(rf"^\s*%{KERNEL_NAME}[\w.]* = ", hlo, re.M)
+        assert "all-reduce" in hlo or "all-gather" in hlo, "the program is partitioned"
+        held = (f"bf16[{POOL_PAGES},{PAGE}," + ",".join(str(n) for n in tail) + "]{"
+                + ",".join(str(n) for n in reversed(range(2 + len(tail)))) + ":")
+        pools = [line for line in hlo.splitlines()
+                 if re.search(r'parameter\(\d+\).*op_name="pools\[0\]\[[01]\]"', line)]
+        assert len(pools) == 2 and all(held in line and "devices=[" in line for line in pools), pools
+        assert weight_copies(hlo, {(POOL_PAGES, PAGE) + tail}) == []
+        assert "attn.gqa.read" in hlo and "attn.gqa.write" in hlo
+
+
+def test_the_gqa_chunk_keeps_the_expression_over_its_view(v5e, servers):
+    """Mistral's prefill chunk (256 tokens x 32 heads: query rows past one tile)
+    reads the gathered view as it did: no kernel under ``attn.gqa.read``, the
+    view of the chunk's ONE sequence gathered from the flat pools
+    (``bf16[64,64,1024]``, once for K and once for V), the heads split out of
+    it, and no copy of a pool."""
+    from seldon_core_tpu.ops.gqa_attention import KERNEL_NAME
+
+    hlo = compiled(servers("mistral"), "prefill_chunk", v5e, slots=8, length=4096).as_text()
+    assert not re.search(rf"^\s*%{KERNEL_NAME}[\w.]* = ", hlo, re.M)
+    assert 'custom_call_target="tpu_custom_call"' not in hlo
+    views = [op for op in own_ops(hlo) if op[1] == "bf16" and op[2] == (64, PAGE, 1024)]
+    assert len(views) == 2, views
+    assert weight_copies(hlo, {(8 * 64 + 2, PAGE, 1024), (8 * 64 + 2, PAGE, 8, 128)}) == []
+    assert "attn.gqa.read" in hlo and "attn.gqa.write" in hlo
+
+
 def test_the_parser_sees_a_dequantized_table():
     """The check itself, on the parent's own lines (PR 33's Mistral step at
     ``vocab_size=32000``): the table's dequant, an op of its own whose operands
@@ -480,7 +610,7 @@ def test_the_hybrid_programs_donate_state_and_pool_and_copy_neither(v5e, servers
     cfg = server._cfg
     slots, length = LFM2_CELL
     pages = slots * length // PAGE + 2
-    assert cfg.kv_rows_flat and cfg.conv_layers == (0, 1, 3)
+    assert cfg.conv_layers == (0, 1, 3)
     # the int8 tree: both projections quantized, W_in ONE [dim, 3 dim] matrix; the
     # taps, the two head norms and the selection bias float32 leaves no tree quantizes
     from seldon_core_tpu.ops.quantize import QuantizedTensor
@@ -528,5 +658,5 @@ def test_the_hybrid_programs_donate_state_and_pool_and_copy_neither(v5e, servers
         assert all(f"layer_{i}/conv/{scope}/" in hlo for i in cfg.conv_layers), scope
     assert "layer_2/attn/attention" in hlo and "/conv/attn" not in hlo and "attn/mix.conv" not in hlo
     if program == "decode_step":
-        # one gathered view of K and of V for the one attention layer, no more
-        assert stats.temp_size_in_bytes < 3 * slots * length * row * 2
+        # no gathered view of K or of V: the step walks the live pages
+        assert stats.temp_size_in_bytes < slots * length * row * 2
