@@ -6,6 +6,11 @@ configuration's `reference_tolerance` is set from (perf/configs/<config>.json).
         --workload xing4-reasoning-decode --probes 12 --wrong-probes 2 --out chiprun_out/pr31r/reference_check.json
     chiprun -- python benchmarks/xing4_reference_check.py \
         --workload lfm2-rag-mixed --probes 12 --wrong-probes 2 --out chiprun_out/pr35/reference_check.json
+    chiprun -- python benchmarks/xing4_reference_check.py --workload qwen3next-longctx-mixed \
+        --probes 12 --wrong-probes 2 --long 2 --long-size 6144+64 --out chiprun_out/pr38/reference_check.json
+    chiprun -- python benchmarks/xing4_reference_check.py --workload qwen3next-longctx-mixed \
+        --probes 2 --wrong-probes 2 --only-wrongs state_held_in_bf16 --long 2 --long-size 6144+64 \
+        --long-wrongs state_held_in_bf16,weights_at_4_bits --out chiprun_out/pr38/reference_check_long.json
     (then once more with --probes 2 --wrong-probes 2 --only-low: the 4-bit tree in a call of its
     own, because the machine's host holds 40 GiB and a 15-layer tree is 11.5 GB of it)
 
@@ -13,8 +18,8 @@ In one process on the chip: the server the cell's files describe (the
 configuration's `weights_seed`, the cell's slots and cache length) and its
 batcher serve `--probes` seeded probes of the cell's probe size (a prompt that
 crosses a chunk boundary + decoded rows, logits asked, out of the step programs
-that serve every request) and, with `--long 1`, one request at the cell's longest
-prompt and answer. Then the server is dropped, the int8 tree is taken to the
+that serve every request) and, with `--long N`, N one-off requests at the cell's longest
+prompt and answer (or of `--long-size PROMPT+NEW`). Then the server is dropped, the int8 tree is taken to the
 host, and seldon_core_tpu/models/reference.py computes each comparison on the
 chip in float32 at highest matmul precision, a leaf at a time: the right
 reference for every probe, FOLLOWING the experts the served path took (the
@@ -68,6 +73,25 @@ def lfm2_wrongs(prompt_tokens: int, chunk: int) -> dict:
     }
 
 
+def qwen3_next_wrongs(prompt_tokens: int, chunk: int) -> dict:
+    """The wrong references of a configuration with linear-attention layers (its
+    `work` is "qwen3_next"), placed like ``lfm2_wrongs``' where the probe's
+    chunks end."""
+    padded = -(-prompt_tokens // chunk) * chunk
+    return {
+        "decay_left_out": {"gdn_decay": False}, "beta_one": {"gdn_beta": False},
+        "no_l2_norm_on_q_and_k": {"gdn_l2norm": False},
+        "state_zeroed_at_a_chunk_start": {"gdn_reset_every": chunk},
+        "state_from_the_chunks_last_row": {"conv_state_pad": (prompt_tokens, padded)},
+        "taps_reversed": {"taps_reversed": True}, "no_silu_after_the_taps": {"gdn_silu": False},
+        "z_gate_left_out": {"gdn_z_gate": False}, "attention_gate_left_out": {"attn_gate": False},
+        "rotary_over_the_whole_head": {"rotary_all": True},
+        "no_shared_expert": {"shared": False}, "shared_gate_left_out": {"shared_gate": False},
+        "largest_held_expert_left_out": {"leave_out_held": True},
+        "state_held_in_bf16": {"gdn_state_bf16": True},
+    }
+
+
 def merge(base: dict, over: dict) -> dict:
     out = dict(base)
     for k, v in over.items():
@@ -106,7 +130,14 @@ def main() -> None:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--probes", type=int, default=6)
     ap.add_argument("--long", type=int, default=0)
+    ap.add_argument("--long-size", default="", metavar="PROMPT+NEW",
+                    help="the one-off requests' size (default: the cell's longest prompt and answer)")
     ap.add_argument("--wrong-probes", type=int, default=1)
+    ap.add_argument("--only-wrongs", default="", metavar="NAME,NAME",
+                    help="of the wrong references (and weights_at_4_bits), these alone")
+    ap.add_argument("--long-wrongs", default="", metavar="NAME,NAME",
+                    help="wrong references (and weights_at_4_bits) read against every one-off too: "
+                         "those whose fault grows with the length")
     ap.add_argument("--only-low", action="store_true",
                     help="of the wrong references, the 4-bit weights alone")
     ap.add_argument("--free-probes", type=int, default=2,
@@ -143,7 +174,10 @@ def main() -> None:
     sizes = [(probe["prompt_tokens"], probe["output_tokens"])] * args.probes
     if args.long:
         request = cell["traffic"]["request"]
-        sizes.append((request["prompt_tokens"]["max"], request["output_tokens"]["max"]))
+        size = (request["prompt_tokens"]["max"], request["output_tokens"]["max"])
+        if args.long_size:
+            size = tuple(int(n) for n in args.long_size.split("+"))
+        sizes.extend([size] * args.long)
     asks = [(rng.integers(97, 123, size=n).tolist(), new) for n, new in sizes]
 
     async def serve():
@@ -171,9 +205,12 @@ def main() -> None:
         params = four_bits(params)
         gc.collect()
 
-    def reading(tree, prompt, out, got, took, **wrong) -> dict:
+    def reading(tree, prompt, out, got, took, sound=None, **wrong) -> tuple:
         """``took`` [tokens, MoE layers, k]: the served experts, which the
-        reference follows (None = it chooses for itself, "free")."""
+        reference follows (None = it chooses for itself, "free"). ``sound``: the
+        right reference's logits for the same rows; a wrong one then says how
+        far it lies from THEM too (`from_sound`: the fault's own size, with no
+        served arithmetic in it). -> (the numbers, the reference's logits)."""
         first = len(prompt) - 1
         t1 = time.monotonic()
         ref, routing = reference.forward(tree, model_cfg, prompt + out,
@@ -183,11 +220,13 @@ def main() -> None:
         per_row = np.abs(got - ref).max(axis=1) / scale
         margins = np.stack([np.asarray(layer["margin"]) for layer in routing])
         behind = np.stack([np.asarray(layer["behind"]) for layer in routing])[:, :first + len(out)]
-        return {"over_scale": float(per_row.max()), "scale": scale,
+        apart = {} if sound is None else {
+            "from_sound": float(np.abs(ref - sound).max() / np.abs(sound).max())}
+        return {"over_scale": float(per_row.max()), "scale": scale, **apart,
                 "first_row": float(per_row[0]), "rows_mean": float(per_row.mean()),
                 "margins_under_1e-3": int((margins < 1e-3).sum()), "margins": int(margins.size),
                 "behind_max": float(behind.max()), "fell_the_other_way": int((behind > 0).sum()),
-                "seconds": time.monotonic() - t1}
+                "seconds": time.monotonic() - t1}, ref
 
     result = {"workload": args.workload, "layers": model_cfg.n_layers, "set": args.set,
               "peak_bytes_in_use": stats.get("peak_bytes_in_use"), "probes": [], "wrong": {}, "long": None}
@@ -198,27 +237,30 @@ def main() -> None:
             json.dump(result, f, indent=1)
 
     for i, (prompt, out, got, took) in enumerate(served[:0 if args.only_low else args.probes]):
-        right = reading(params, prompt, out, got, took)
+        right, _ = reading(params, prompt, out, got, took)
         if i < args.free_probes:
-            right["free"] = reading(params, prompt, out, got, None)["over_scale"]
+            right["free"] = reading(params, prompt, out, got, None)[0]["over_scale"]
         print(f"probe {i}: {len(prompt)} + {len(out)}: {json.dumps(right)}", flush=True)
         result["probes"].append(right)
         save()
     def against(name: str, tree, i: int, **wrong) -> None:
         prompt, out, got, took = served[i]
-        r = reading(tree, prompt, out, got, took, **wrong)
+        r, _ = reading(tree, prompt, out, got, took, **wrong)
         result["wrong"].setdefault(name, []).append([r["over_scale"], r["behind_max"]])
         print(f"probe {i} against {name}: logits {r['over_scale']:.4f}, furthest choice behind "
               f"{r['behind_max']:.4f}", flush=True)
         save()
 
     wrongs = WRONGS
-    if cfg.get("work") == "lfm2":
-        wrongs = lfm2_wrongs(probe["prompt_tokens"], server_kw.get("prefill_chunk") or 256)
+    placed = {"lfm2": lfm2_wrongs, "qwen3_next": qwen3_next_wrongs}.get(cfg.get("work"))
+    if placed:   # a configuration with state layers: some wrongs lie where the probe's chunks end
+        wrongs = placed(probe["prompt_tokens"], server_kw.get("prefill_chunk") or 256)
+    only = [name for name in args.only_wrongs.split(",") if name]
     for i in range(0 if args.only_low else min(args.wrong_probes, args.probes)):
         for name, wrong in wrongs.items():
-            against(name, params, i, **wrong)
-    if args.wrong_probes:
+            if not only or name in only:
+                against(name, params, i, **wrong)
+    if args.wrong_probes and (not only or "weights_at_4_bits" in only):
         # a second tree on the host: made late and dropped before the one-off,
         # whose 2,048 rows of logits are 1 GB a copy (the machine has 40 GiB)
         low = params if args.only_low else four_bits(params)
@@ -226,10 +268,17 @@ def main() -> None:
             against("weights_at_4_bits", low, i)
         del low
         gc.collect()
+    long_wrongs = [name for name in args.long_wrongs.split(",") if name]
+    low = four_bits(params) if "weights_at_4_bits" in long_wrongs else None
     for prompt, out, got, took in served[args.probes:]:
-        right = reading(params, prompt, out, got, took)
+        right, sound = reading(params, prompt, out, got, took)
+        for name in long_wrongs:
+            tree, wrong = (low, {}) if name == "weights_at_4_bits" else (params, wrongs[name])
+            r, _ = reading(tree, prompt, out, got, took, sound=sound, **wrong)
+            right.setdefault("wrong", {})[name] = [r["over_scale"], r["behind_max"], r["from_sound"]]
         print(f"one-off: {len(prompt)} + {len(out)}: {json.dumps(right)}", flush=True)
-        result["long"] = {"prompt": len(prompt), "decoded": len(out), **right}
+        result["long"] = (result["long"] or []) + [
+            {"prompt": len(prompt), "decoded": len(out), **right}]
         save()
     print(json.dumps(result))
 

@@ -159,8 +159,10 @@ class MetricsRegistry:
         )
         self._state_bytes = Gauge(
             "seldon_llm_state_bytes",
-            "Bytes of per-slot conv state resident beside the page pool "
-            "(a model with conv layers; fixed, whatever the sequences' lengths)",
+            "Bytes of per-slot state resident beside the page pool, over every "
+            "array of every state layer's entry (a conv layer's rows, a "
+            "linear-attention layer's conv rows and float32 matrix state; "
+            "fixed, whatever the sequences' lengths)",
             base,
             registry=self.registry,
         )
@@ -430,13 +432,15 @@ class MetricsRegistry:
         # A model with conv layers (models/transformer.py ShortConv): what
         # went through them, counted on the loop from host integers; absent
         # for every other model
-        self._conv = {
-            key: Counter(f"seldon_llm_conv_{key}_total", text,
-                         base + ["program"], registry=self.registry)
+        # ... likewise "gdn" for linear-attention layers (GatedDeltaNet)
+        self._state_layers = {
+            f"{kind}_{key}": Counter(f"seldon_llm_{kind}_{key}_total", text.format(what=what),
+                                     base + ["program"], registry=self.registry)
+            for kind, what in (("conv", "conv"), ("gdn", "linear-attention (Gated DeltaNet)"))
             for key, text in (
                 ("rows", "Live rows (tokens) of the step-program calls of a "
-                         "model with conv layers: what EACH conv layer mixed"),
-                ("layer_calls", "Conv layers x step-program calls"))}
+                         "model with {what} layers: what EACH such layer mixed"),
+                ("layer_calls", "{what} layers x step-program calls"))}
         # An MoE model's routing (runtime/batcher.py MoECounters,
         # docs/observability.md "Expert routing"): counted on the loop from
         # arrays that leave the step programs beside their tokens, absent
@@ -446,7 +450,12 @@ class MetricsRegistry:
             "calls": "Step-program calls of an MoE model",
             "live_rows": "Rows of those calls that were tokens (not a dead "
                          "slot, not a chunk's padding)",
-            "routed_pairs": "(token, expert) pairs routed, over all layers",
+            "routed_pairs": "(token, expert) pairs routed to an expert HELD "
+                            "here (all of them, without a share), over all layers",
+            "pairs_elsewhere": "(token, expert) pairs whose expert lies on another "
+                               "chip of the expert-parallel deployment (a model that "
+                               "holds a share of its experts), over all layers: they "
+                               "join no group here and add nothing",
             "experts_touched": "Distinct experts with at least one row, "
                                "summed over layer-calls",
             "max_group": "Rows of the largest expert group, summed over "
@@ -1070,8 +1079,8 @@ class MetricsRegistry:
         for key, counter in self._attn_context.items():
             for program, n in stats.get(f"attn_{key}", {}).items():
                 self._counter_catch_up(counter, n, program=program)
-        for key, counter in self._conv.items():
-            for program, n in stats.get(f"conv_{key}", {}).items():
+        for key, counter in self._state_layers.items():
+            for program, n in stats.get(key, {}).items():
                 self._counter_catch_up(counter, n, program=program)
         for program, tally in stats.get("moe_by_program", {}).items():
             for field, n in tally.items():
@@ -1079,7 +1088,8 @@ class MetricsRegistry:
             self._counter_catch_up(
                 self._moe["layer_calls"], tally["calls"] * stats["moe_layers"],
                 program=program)
-        for expert, n in enumerate(stats.get("moe_expert_tokens", ())):
+        for expert, n in enumerate(stats.get("moe_expert_tokens", ()),
+                                   stats.get("moe_expert_first", 0)):
             self._counter_catch_up(self._moe_expert_tokens, n,
                                    expert=str(expert))
         self._counter_catch_up(self._slot_seconds,

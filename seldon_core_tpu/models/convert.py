@@ -57,7 +57,8 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
             )
     head_dim = getattr(hf_config, "head_dim", None)
     derived = hf_config.hidden_size // hf_config.num_attention_heads
-    if head_dim is not None and head_dim != derived and not deepseek:
+    qwen3_next = model_type == "qwen3_next"
+    if head_dim is not None and head_dim != derived and not deepseek and not qwen3_next:
         raise ValueError(
             f"explicit head_dim={head_dim} != hidden_size/num_heads={derived}; "
             "the native transformer derives head_dim from dim//n_heads"
@@ -104,6 +105,32 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
                 routed_scaling_factor=float(hf_config.routed_scaling_factor),
                 first_dense_layers=int(hf_config.num_dense_layers),
                 dense_ffn_dim=hf_config.intermediate_size)
+    if qwen3_next:
+        # Gated DeltaNet layers beside gated GQA layers of explicit head_dim, a
+        # partial rotary, a norm per q / k head; every layer MoE with one gated
+        # shared expert
+        if getattr(hf_config, "mlp_only_layers", None) or hf_config.decoder_sparse_step != 1:
+            raise ValueError("qwen3_next with dense layers (mlp_only_layers / "
+                             "decoder_sparse_step != 1) is not supported by the native transformer")
+        ffn_dim = hf_config.moe_intermediate_size
+        if hf_config.shared_expert_intermediate_size % ffn_dim:
+            raise ValueError("shared_expert_intermediate_size must be a multiple of "
+                             "moe_intermediate_size (the shared expert is n_shared_experts wide)")
+        moe = {
+            "layer_types": tuple(hf_config.layer_types), "head_dim": int(hf_config.head_dim),
+            "attn_gate": True, "qk_norm": "head",
+            "partial_rotary_factor": float(getattr(hf_config, "partial_rotary_factor", 1.0)),
+            "linear_num_key_heads": hf_config.linear_num_key_heads,
+            "linear_num_value_heads": hf_config.linear_num_value_heads,
+            "linear_key_head_dim": hf_config.linear_key_head_dim,
+            "linear_value_head_dim": hf_config.linear_value_head_dim,
+            "linear_conv_kernel_dim": hf_config.linear_conv_kernel_dim,
+            "n_experts": hf_config.num_experts,
+            "n_experts_per_token": hf_config.num_experts_per_tok,
+            "router_renormalize": bool(hf_config.norm_topk_prob),
+            "n_shared_experts": hf_config.shared_expert_intermediate_size // ffn_dim,
+            "shared_expert_gate": True,
+        }
     if deepseek:
         # V3's router is sigmoid scores + a selection bias (noaux_tc); its
         # config class carries neither key, V2-shaped configs name both
@@ -312,6 +339,93 @@ def convert_lfm2_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str, Any],
     return {"params": params}
 
 
+def convert_qwen3_next_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str, Any],
+                                  dtype: str = "float32") -> Dict[str, Any]:
+    """HF Qwen3NextForCausalLM state dict -> our flax param tree. ``kwargs``
+    are ``config_kwargs_from_hf``'s, to which ``experts_first`` /
+    ``experts_held`` may be added: the stacks then hold that share alone.
+
+    - ``Qwen3NextRMSNorm`` multiplies by ``1 + w``; the tree's RMSNorm
+      multiplies by its weight, so ``1 + w`` is what is written (a departure of
+      layout, not of mathematics). The gated norm of a linear-attention layer
+      multiplies by ``w`` there too.
+    - ``linear_attn.in_proj_qkvz`` / ``in_proj_ba`` interleave their outputs BY
+      KEY HEAD ([q | k | its value heads' v | their z] a key head; [b | a]);
+      the tree holds [q ; k ; v ; z] and [b ; a], each over all heads in order
+      (value head h belongs to key head h // (Hv / Hk) in both).
+    - ``self_attn.q_proj`` makes [query | gate] a head: split into ``wq`` and
+      ``wq_gate``.
+    - ``linear_attn.conv1d.weight`` [channels, 1, taps] drops its middle axis
+      (torch's order: the last tap weighs the row itself, ours too)."""
+    t, consumed = _tensor_reader(state_dict, dtype)
+
+    def one_plus(key: str) -> Dict[str, Any]:
+        return {"weight": (1.0 + t(key).astype(np.float32)).astype(_np_dtype(dtype))}
+
+    def swiglu(prefix: str) -> Dict[str, Any]:
+        return {ours: t(f"{prefix}.{theirs}.weight").T for ours, theirs in
+                (("w1", "gate_proj"), ("w2", "down_proj"), ("w3", "up_proj"))}
+
+    hk, hv = kwargs["linear_num_key_heads"], kwargs["linear_num_value_heads"]
+    dk, dv, rep = kwargs["linear_key_head_dim"], kwargs["linear_value_head_dim"], hv // hk
+    heads, hd = kwargs["n_heads"], kwargs["head_dim"]
+    first = kwargs.get("experts_first", 0)
+    held = kwargs.get("experts_held", 0) or kwargs["n_experts"]
+    params: Dict[str, Any] = {
+        "tok_embeddings": t("model.embed_tokens.weight"),
+        "norm": one_plus("model.norm.weight"),
+    }
+    for i, kind in enumerate(kwargs["layer_types"]):
+        hf = f"model.layers.{i}"
+        layer = params[f"layer_{i}"] = {
+            "ffn_norm": one_plus(f"{hf}.post_attention_layernorm.weight")}
+        if kind == "linear_attention":
+            layer["operator_norm"] = one_plus(f"{hf}.input_layernorm.weight")
+            qkvz = t(f"{hf}.linear_attn.in_proj_qkvz.weight")
+            qkvz = qkvz.reshape(hk, 2 * dk + 2 * rep * dv, -1)
+            ba = t(f"{hf}.linear_attn.in_proj_ba.weight").reshape(hk, 2 * rep, -1)
+            dim = qkvz.shape[-1]
+            cuts = (0, dk, 2 * dk, 2 * dk + rep * dv, 2 * dk + 2 * rep * dv)
+            layer["linear_attn"] = {
+                "in_proj_qkvz": np.concatenate(
+                    [qkvz[:, lo:hi].reshape(-1, dim) for lo, hi in zip(cuts, cuts[1:])]).T,
+                "in_proj_ba": np.concatenate(
+                    [ba[:, :rep].reshape(-1, dim), ba[:, rep:].reshape(-1, dim)]).T,
+                "conv1d": t(f"{hf}.linear_attn.conv1d.weight")[:, 0, :],
+                "A_log": t(f"{hf}.linear_attn.A_log"),
+                "dt_bias": t(f"{hf}.linear_attn.dt_bias"),
+                "norm": {"weight": t(f"{hf}.linear_attn.norm.weight")},
+                "out_proj": t(f"{hf}.linear_attn.out_proj.weight").T,
+            }
+        else:
+            layer["attention_norm"] = one_plus(f"{hf}.input_layernorm.weight")
+            q_proj = t(f"{hf}.self_attn.q_proj.weight").reshape(heads, 2 * hd, -1)
+            layer["attention"] = {
+                "wq": q_proj[:, :hd].reshape(heads * hd, -1).T,
+                "wq_gate": q_proj[:, hd:].reshape(heads * hd, -1).T,
+                "wk": t(f"{hf}.self_attn.k_proj.weight").T,
+                "wv": t(f"{hf}.self_attn.v_proj.weight").T,
+                "wo": t(f"{hf}.self_attn.o_proj.weight").T,
+                "q_norm": one_plus(f"{hf}.self_attn.q_norm.weight"),
+                "k_norm": one_plus(f"{hf}.self_attn.k_norm.weight"),
+            }
+        experts = [swiglu(f"{hf}.mlp.experts.{e}") for e in range(kwargs["n_experts"])]
+        layer["moe"] = {
+            "router": t(f"{hf}.mlp.gate.weight").T,
+            "shared": swiglu(f"{hf}.mlp.shared_expert"),
+            "shared_gate": t(f"{hf}.mlp.shared_expert_gate.weight").T,
+            **{name: np.stack([e[name] for e in experts[first:first + held]])
+               for name in ("w1", "w2", "w3")}}
+    if not kwargs["tie_embeddings"]:
+        params["lm_head"] = t("lm_head.weight").T
+    leftover = [k for k in state_dict if k not in consumed and not k.endswith("inv_freq")
+                and not (kwargs["tie_embeddings"] and k == "lm_head.weight")]
+    if leftover:
+        raise ValueError(
+            f"unmapped weights in state dict (conversion would drop them): {leftover[:8]}")
+    return {"params": params}
+
+
 def _half_split(w: np.ndarray, rope: int) -> np.ndarray:
     """The last ``rope`` columns of ``w`` from interleaved pairs (2i, 2i+1)
     (DeepSeek's apply_rotary_emb multiplies them as complex numbers) to the
@@ -419,7 +533,8 @@ def convert_deepseek_v2_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str,
 
 def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
     """In-memory transformers LlamaForCausalLM, OlmoeForCausalLM,
-    DeepseekV2ForCausalLM or DeepseekV3ForCausalLM -> (our module, variables)."""
+    DeepseekV2ForCausalLM, DeepseekV3ForCausalLM, Lfm2ForCausalLM or
+    Qwen3NextForCausalLM -> (our module, variables)."""
     from seldon_core_tpu.models import get_model
 
     kwargs = config_kwargs_from_hf(hf_model.config)
@@ -428,6 +543,8 @@ def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
         kwargs["mtp_layers"] *= has_mtp_weights(state_dict, kwargs)
         variables = convert_deepseek_v2_state_dict(
             state_dict, kwargs, rope_interleaved=getattr(hf_model.config, "rope_interleave", True))
+    elif kwargs.get("attn_gate"):      # qwen3_next
+        variables = convert_qwen3_next_state_dict(hf_model.state_dict(), kwargs)
     elif kwargs.get("layer_types"):
         variables = convert_lfm2_state_dict(hf_model.state_dict(), kwargs)
     else:
@@ -457,6 +574,8 @@ def convert_checkpoint(hf_path: str, out_dir: str, dtype: str = "bfloat16") -> s
         kwargs["mtp_layers"] *= has_mtp_weights(model.state_dict(), kwargs)
         variables = convert_deepseek_v2_state_dict(
             model.state_dict(), kwargs, dtype, getattr(hf_config, "rope_interleave", True))
+    elif kwargs.get("attn_gate"):      # qwen3_next
+        variables = convert_qwen3_next_state_dict(model.state_dict(), kwargs, dtype)
     elif kwargs.get("layer_types"):
         variables = convert_lfm2_state_dict(model.state_dict(), kwargs, dtype)
     else:

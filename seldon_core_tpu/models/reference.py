@@ -110,6 +110,37 @@ see, in place of z_{n-1}, z_{n-2}, what the LAST rows of the prompt padded with
 token 0 to m rows hold: the state taken from a padded chunk's last row, not its
 last valid one); of the attention: ``qk_norm="whole"`` / ``qk_norm=False``.
 
+Qwen3-Next (``transformers/models/qwen3_next/modeling_qwen3_next.py``, which
+tests/test_reference_qwen3_next.py holds this file to on converted weights) has
+a third token mixer, gates its attention, and holds a SHARE of its experts:
+
+    gdn   : [q ; k ; v ; z] = W_qkvz n1, [b ; a] = W_ba n1 (the tree's order; the
+            checkpoint interleaves by key head); [q ; k ; v] <- SiLU(causal depthwise
+            taps, the LAST weighing the row itself); q, k L2-normalised a head (eps
+            1e-6), q * dk^-1/2, a key head serving Hv / Hk value heads;
+            beta = sigmoid(b), g = -exp(A_log) softplus(a + dt_bias) a value head;
+            a ``lax.scan`` over the tokens from S = 0, S [dk, dv] a value head:
+              S <- e^{g_t} S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;  o_t = S^T q_t
+            h = x + W_out (RMSNorm_dv(o_t) w * SiLU(z_t))   (no chunking, no cache)
+    attn  : wq makes the query, wq_gate a gate a head; q, k normed a head; RoPE over
+            the FIRST cfg.partial_rotary_factor of a head's values (rotate-half among
+            them); heads of cfg.head_dim; the heads' output * sigmoid(gate) before wo
+    share : the router is cfg.n_experts wide, top-k and renormalisation over all k; the
+            stacks hold experts [cfg.experts_first, + cfg.experts_held) and a chosen
+            expert that lies elsewhere adds NOTHING (what its chip would add is left
+            out here as in the served path); the shared expert, behind
+            sigmoid(w_g . n2), is computed whole
+    norms : the tree's weights multiply as they are: the converter writes 1 + w for
+            Qwen3NextRMSNorm (the gated norm's weight is plain w there too)
+
+WRONG models of these: ``gdn_decay=False`` (g = 0), ``gdn_beta=False`` (beta = 1),
+``gdn_l2norm=False``, ``gdn_reset_every=N`` (S zeroed at every multiple of N: a
+chunk that does not carry S), ``conv_state_pad`` / ``taps_reversed`` as LFM2's (over
+the rows of [q ; k ; v] before the taps), ``gdn_silu=False``, ``gdn_z_gate=False``,
+``gdn_state_bf16=True`` (S rounded to bf16 after every token), ``attn_gate=False``,
+``rotary_all=True`` (RoPE over the whole head), ``shared_gate=False``,
+``leave_out_held=True`` (every token loses the HELD expert it weighs most).
+
 ``follow=`` makes the forward take the experts the SERVED path took (a logits
 probe's ``routing``): where this router's last chosen and first unchosen expert
 score within the served arithmetic's noise of each other, which one is taken is
@@ -127,6 +158,7 @@ path's distance from it is its activation arithmetic alone.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Optional
 
 import jax
@@ -196,9 +228,12 @@ def _rope(x, theta: float, scaling: Optional[dict] = None):
     return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], axis=-1)
 
 
-def _attention(p: dict, x, cfg, qk_norm=None):
+def _attention(p: dict, x, cfg, qk_norm=None, gate: bool = True, rotary_all: bool = False,
+               block: int = 512):
+    """Queries go in blocks of ``block`` rows (a 6 k context needs
+    [heads, block, s] of scores at a time; the arithmetic is the same)."""
     s = x.shape[0]
-    hd = cfg.dim // cfg.n_heads
+    hd = getattr(cfg, "head_dim", 0) or cfg.dim // cfg.n_heads
     q, k, v = x @ _f32(p["wq"]), x @ _f32(p["wk"]), x @ _f32(p["wv"])
     qk_norm = cfg.qk_norm if qk_norm is None else qk_norm
     if qk_norm == "whole":      # a WRONG model of per-head norms: one norm, the head's weight tiled
@@ -211,15 +246,23 @@ def _attention(p: dict, x, cfg, qk_norm=None):
     if qk_norm == "head":
         q = _rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
         k = _rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
-    q = _rope(q, cfg.rope_theta)
-    k = _rope(k, cfg.rope_theta)
+    rotary = hd if rotary_all else int(getattr(cfg, "partial_rotary_factor", 1.0) * hd)
+    q = jnp.concatenate([_rope(q[..., :rotary], cfg.rope_theta), q[..., rotary:]], axis=-1)
+    k = jnp.concatenate([_rope(k[..., :rotary], cfg.rope_theta), k[..., rotary:]], axis=-1)
     v = v.reshape(s, cfg.n_kv_heads, hd)
     group = cfg.n_heads // cfg.n_kv_heads
     k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    scores = jnp.einsum("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.float32(hd))
-    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
-    probs = jax.nn.softmax(jnp.where(causal[None], scores, -jnp.inf), axis=-1)
-    return jnp.einsum("hqk,khd->qhd", probs, v).reshape(s, cfg.n_heads * hd) @ _f32(p["wo"])
+    out = []
+    for start in range(0, s, block):
+        rows = jnp.arange(start, min(start + block, s))
+        scores = jnp.einsum("qhd,khd->hqk", q[rows], k) / jnp.sqrt(jnp.float32(hd))
+        causal = jnp.arange(s)[None, :] <= rows[:, None]
+        probs = jax.nn.softmax(jnp.where(causal[None], scores, -jnp.inf), axis=-1)
+        out.append(jnp.einsum("hqk,khd->qhd", probs, v).reshape(len(rows), cfg.n_heads * hd))
+    out = jnp.concatenate(out)
+    if "wq_gate" in p and gate:
+        out = out * jax.nn.sigmoid(x @ _f32(p["wq_gate"]))
+    return out @ _f32(p["wo"])
 
 
 def _latent_attention(p: dict, x, cfg, scale_mscale: bool = True, block: int = 512,
@@ -261,15 +304,12 @@ def _latent_attention(p: dict, x, cfg, scale_mscale: bool = True, block: int = 5
     return jnp.concatenate(out) @ _f32(p["wo"])
 
 
-def _short_conv(p: dict, x, cfg, wrong: dict, seen: Optional[list] = None):
-    """LFM2's gated short convolution over the whole sequence ``x`` [s, C]:
-    the causal taps as an explicit shifted sum. ``seen`` collects z [s, C]
-    (what a served path keeps the last rows of, as its state)."""
-    s, d = x.shape
-    bcx = x @ _f32(p["in_proj"])
-    gate_b, gate_c, xs = bcx[:, :d], bcx[:, d:2 * d], bcx[:, 2 * d:]
-    z = gate_b * xs if wrong["gate_b"] else xs
-    taps = _f32(p["taps"])                      # [C, L]; the last tap weighs the row itself
+def _causal_taps(z, taps, wrong: dict, seen: Optional[list] = None):
+    """Depthwise causal taps over the whole sequence ``z`` [s, C] as an
+    explicit shifted sum; ``taps`` [C, L], the last tap weighs the row itself.
+    ``seen`` collects z (what a served path keeps the last rows of, as its
+    state)."""
+    s, d = z.shape
     if wrong["taps_reversed"]:
         taps = taps[:, ::-1]
     taps_n = taps.shape[1]
@@ -289,7 +329,68 @@ def _short_conv(p: dict, x, cfg, wrong: dict, seen: Optional[list] = None):
         v = v + taps[:, j] * shifted
     if seen is not None:
         seen.append(z)
+    return v
+
+
+def _short_conv(p: dict, x, cfg, wrong: dict, seen: Optional[list] = None):
+    """LFM2's gated short convolution over the whole sequence ``x`` [s, C]."""
+    d = x.shape[1]
+    bcx = x @ _f32(p["in_proj"])
+    gate_b, gate_c, xs = bcx[:, :d], bcx[:, d:2 * d], bcx[:, 2 * d:]
+    v = _causal_taps(gate_b * xs if wrong["gate_b"] else xs, _f32(p["taps"]), wrong, seen)
     return ((gate_c * v) if wrong["gate_c"] else v) @ _f32(p["out_proj"])
+
+
+@partial(jax.jit, static_argnames=("round_bf16",))
+def _delta_scan(q, k, v, g, beta, reset, round_bf16: bool = False):
+    """The gated delta rule as a scan over tokens from S = 0: ``q`` / ``k``
+    [s, H, dk], ``v`` [s, H, dv], ``g`` / ``beta`` [s, H], ``reset`` [s] bool
+    (a WRONG model zeroes S before such a token) -> o [s, H, dv]."""
+    def step(S, row):
+        q_t, k_t, v_t, g_t, b_t, reset_t = row
+        S = jnp.where(reset_t, 0.0, S) * jnp.exp(g_t)[:, None, None]
+        d = b_t[:, None] * (v_t - jnp.einsum("hkv,hk->hv", S, k_t))
+        S = S + k_t[:, :, None] * d[:, None, :]
+        if round_bf16:
+            # not ``.astype(bfloat16).astype(float32)``: the TPU compiler keeps the
+            # excess precision of such a pair, and the wrong model would be the right one
+            S = jax.lax.reduce_precision(S, exponent_bits=8, mantissa_bits=7)
+        return S, jnp.einsum("hkv,hk->hv", S, q_t)
+
+    with jax.default_matmul_precision("highest"):
+        S0 = jnp.zeros((k.shape[1], k.shape[2], v.shape[2]), jnp.float32)
+        return jax.lax.scan(step, S0, (q, k, v, g, beta, reset))[1]
+
+
+def _gated_delta_net(p: dict, x, cfg, wrong: dict, seen: Optional[list] = None):
+    """Qwen3-Next's Gated DeltaNet over the whole sequence ``x`` [s, C]: the
+    recurrence token by token (the served path runs a chunked form in its
+    prefill and keeps S and three rows of [q ; k ; v] between calls)."""
+    s = x.shape[0]
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    key_dim, value_dim = hk * dk, hv * dv
+    qkvz, ba = x @ _f32(p["in_proj_qkvz"]), x @ _f32(p["in_proj_ba"])
+    mixed = _causal_taps(qkvz[:, :2 * key_dim + value_dim], _f32(p["conv1d"]), wrong, seen)
+    if wrong["gdn_silu"]:
+        mixed = jax.nn.silu(mixed)
+    q = mixed[:, :key_dim].reshape(s, hk, dk)
+    k = mixed[:, key_dim:2 * key_dim].reshape(s, hk, dk)
+    v = mixed[:, 2 * key_dim:].reshape(s, hv, dv)
+    if wrong["gdn_l2norm"]:
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6)
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+    q, k = jnp.repeat(q * dk ** -0.5, hv // hk, axis=1), jnp.repeat(k, hv // hk, axis=1)
+    beta = jax.nn.sigmoid(ba[:, :hv]) if wrong["gdn_beta"] else jnp.ones((s, hv))
+    g = (-jnp.exp(_f32(p["A_log"])) * jax.nn.softplus(ba[:, hv:] + _f32(p["dt_bias"]))
+         if wrong["gdn_decay"] else jnp.zeros((s, hv)))
+    every = wrong["gdn_reset_every"]
+    reset = (jnp.arange(s) % every == 0) if every else jnp.zeros((s,), bool)
+    o = _delta_scan(q, k, v, g, beta, reset, round_bf16=bool(wrong["gdn_state_bf16"]))
+    o = _rms_norm(o, p["norm"]["weight"], cfg.norm_eps)
+    if wrong["gdn_z_gate"]:
+        o = o * jax.nn.silu(qkvz[:, 2 * key_dim + value_dim:].reshape(s, hv, dv))
+    return o.reshape(s, value_dim) @ _f32(p["out_proj"])
 
 
 EXPERT_ROWS = 64     # an expert's tokens are computed in whole buckets of this many rows
@@ -311,7 +412,8 @@ def _add_expert(out, x, share, took, n, w1, w2, w3, e):
 
 
 def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True,
-             select_bias: bool = True, router_score: Optional[str] = None, follow=None):
+             select_bias: bool = True, router_score: Optional[str] = None, follow=None,
+             shared_gate: bool = True, leave_out_held: bool = False):
     """The expert FFN of one layer, and what the router chose: ``experts``
     [s, k] largest weight first, their ``weights`` [s, k], and ``margin`` [s],
     by how much the last chosen score (with its selection bias) beats the
@@ -345,19 +447,37 @@ def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     weights = weights * getattr(cfg, "routed_scaling_factor", 1.0)
     used = weights if leave_out_rank is None else weights.at[:, leave_out_rank].set(0.0)
+    # the experts whose weights are here (all, without a share): a chosen
+    # expert that lies elsewhere adds nothing
+    first = getattr(cfg, "experts_first", 0)
+    held = getattr(cfg, "experts_held", 0) or n
+    here = (experts >= first) & (experts < first + held)
+    if leave_out_held:      # WRONG: the held expert each token weighs most (they are sorted)
+        top = jnp.argmax(here, axis=-1)
+        used = jnp.where(jnp.arange(k)[None, :] == top[:, None], 0.0, used)
     out = jnp.zeros_like(x)
-    for e in range(n):
-        share = jnp.sum(jnp.where(experts == e, used, 0.0), axis=-1)  # [s]; 0 = not chosen
+    # who took which expert, read ONCE (a read an expert is a wait an expert:
+    # 1,536 of them in a 12-layer forward over 128 held experts)
+    chosen, weight = np.asarray(experts), np.asarray(used)
+    # the layer's stacks on the device ONCE (a tree taken to the host, as the
+    # chip check holds it, would cross again for every expert)
+    w1, w2, w3 = jax.device_put((p["w1"], p["w2"], p["w3"]))
+    for e in range(first, first + held):
+        # [s]; 0 = not chosen (a token takes an expert at most once: no sum is rounded)
+        share = np.where(chosen == e, weight, np.float32(0.0)).sum(axis=-1)
         # the tokens that took expert e, and no other: in whole buckets of
         # EXPERT_ROWS (the last repeated at weight 0), so that a forward
         # compiles a handful of shapes and not one an expert a layer
-        rows = np.flatnonzero(np.asarray(share) > 0)
+        rows = np.flatnonzero(share > 0)
         if len(rows):
             took = np.pad(rows, (0, -len(rows) % EXPERT_ROWS), mode="edge")
-            out = _add_expert(out, x, share, took, len(rows), p["w1"], p["w2"], p["w3"], e)
+            out = _add_expert(out, x, share, took, len(rows), w1, w2, w3, e - first)
     if "shared" in p and shared:
         f = p["shared"]
-        out = out + _swiglu(x, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
+        y = _swiglu(x, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
+        if "shared_gate" in p and shared_gate:
+            y = jax.nn.sigmoid(x @ _f32(p["shared_gate"])) * y
+        out = out + y
     return out, {"experts": experts, "weights": weights, "margin": margin, "behind": behind}
 
 
@@ -383,7 +503,8 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
            seen: Optional[list] = None):
     """One decoder block on the residual ``x`` [s, C], or on the streams
     [s, n, C] where the layer has mixing parameters and ``streams`` is on. A
-    layer that holds a ``conv`` (LFM2) mixes tokens by it, not by attention."""
+    layer that holds a ``conv`` (LFM2) or a ``linear_attn`` (Qwen3-Next) mixes
+    tokens by it, not by attention."""
     latent = getattr(cfg, "kv_lora_rank", 0) > 0
     mixed = x.ndim == 3
     iters = wrong["sinkhorn_iters"] if wrong["sinkhorn_iters"] is not None else getattr(
@@ -400,13 +521,15 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
         if latent:
             return _latent_attention(layer["attention"], n1, cfg, wrong["scale_mscale"],
                                      q_norm=wrong["q_norm"])
-        return _attention(layer["attention"], n1, cfg, wrong["qk_norm"])
+        return _attention(layer["attention"], n1, cfg, wrong["qk_norm"], wrong["attn_gate"],
+                          wrong["rotary_all"])
 
     def ffn(n2):
         if moe:
             out, chose = _experts(layer["moe"], n2, cfg, wrong["leave_out_rank"], wrong["shared"],
                                   wrong["select_bias"], wrong["router_score"],
-                                  None if follow is None else follow[:, len(routing)])
+                                  None if follow is None else follow[:, len(routing)],
+                                  wrong["shared_gate"], wrong["leave_out_held"])
             routing.append(chose)
             return out
         f = layer["ffn"]
@@ -415,6 +538,9 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
     if "conv" in layer:
         x = sub_layer(x, None, "operator_norm",
                       lambda n1: _short_conv(layer["conv"], n1, cfg, wrong, seen))
+    elif "linear_attn" in layer:
+        x = sub_layer(x, None, "operator_norm",
+                      lambda n1: _gated_delta_net(layer["linear_attn"], n1, cfg, wrong, seen))
     else:
         x = sub_layer(x, "attention_hc", "attention_norm", attention)
     return sub_layer(x, "ffn_hc", "ffn_norm", ffn)
@@ -432,7 +558,10 @@ def _leave(x):
 WRONG = {"leave_out_rank": None, "scale_mscale": True, "shared": True, "streams": True,
          "sinkhorn_iters": None, "select_bias": True, "router_score": None, "q_norm": True,
          "taps_reversed": False, "gate_b": True, "gate_c": True, "conv_reset_every": None,
-         "conv_state_pad": None, "qk_norm": None}
+         "conv_state_pad": None, "qk_norm": None,
+         "gdn_decay": True, "gdn_beta": True, "gdn_l2norm": True, "gdn_reset_every": None,
+         "gdn_silu": True, "gdn_z_gate": True, "gdn_state_bf16": False, "attn_gate": True,
+         "rotary_all": False, "shared_gate": True, "leave_out_held": False}
 
 
 def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[list] = None):
@@ -445,8 +574,10 @@ def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[
         padded: list = []
         _hidden(p, cfg, list(tokens[:n]) + [0] * (m - n), {**wrong, "conv_state_pad": None},
                 None if follow is None else follow[:0], padded)
-        taps_n = next(_f32(p[f"layer_{i}"]["conv"]["taps"]).shape[1] for i in range(cfg.n_layers)
-                      if "conv" in p[f"layer_{i}"])
+        taps_n = next(_f32(leaf).shape[1] for i in range(cfg.n_layers)
+                      for leaf in (p[f"layer_{i}"].get("conv", {}).get("taps"),
+                                   p[f"layer_{i}"].get("linear_attn", {}).get("conv1d"))
+                      if leaf is not None)
         wrong = {**wrong, "conv_state_pad": (n, m, [z[-(taps_n - 1):] for z in padded])}
         seen = []
     latent = getattr(cfg, "kv_lora_rank", 0) > 0

@@ -96,11 +96,13 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
     return q.astype(dtype) * scale[..., None].astype(dtype)
 
 
-LAYER_KINDS = ("full_attention", "conv")
+LAYER_KINDS = ("full_attention", "conv", "linear_attention")
+# the kinds whose layer keeps a fixed block of STATE a sequence, not pages
+STATE_LAYER_KINDS = ("conv", "linear_attention")
 STATE_LAYERS_COMPOSE_REFUSAL = (
-    "a 'conv' layer (layer_types) does not compose with latent attention "
-    "(kv_lora_rank > 0), hyper-connections (hc_mult > 1) or an MTP module: "
-    "no model pairs them and no test holds them")
+    "a 'conv' or 'linear_attention' layer (layer_types) does not compose with "
+    "latent attention (kv_lora_rank > 0), hyper-connections (hc_mult > 1) or an "
+    "MTP module: no model pairs them and no test holds them")
 
 FUSED_NORM_STREAMS_REFUSAL = (
     "fused_norm does not compose with hyper-connections (hc_mult > 1): the "
@@ -179,6 +181,38 @@ class TransformerConfig:
     # layers' (values..., positions) tuples.
     layer_types: Any = None
     conv_L_cache: int = 3
+    # A "linear_attention" layer is Qwen3-Next's Gated DeltaNet (GatedDeltaNet
+    # below): linear_num_key_heads heads of q and k (linear_key_head_dim wide),
+    # each serving linear_num_value_heads / linear_num_key_heads value heads
+    # (linear_value_head_dim wide); a depthwise causal convolution of
+    # linear_conv_kernel_dim taps over [q; k; v]. It keeps, a sequence, the
+    # last taps - 1 rows of [q; k; v] and one float32 matrix [key dim, value
+    # dim] a value head, whatever the length: the cache entry is the 2-tuple
+    # ``(conv_state, S)``.
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    # Attention's head width (0 = dim // n_heads, resolved in __post_init__);
+    # ``attn_gate``: the query projection makes a gate a head beside the query
+    # and the heads' output is multiplied by sigmoid(gate) before wo
+    # (Qwen3-Next); ``partial_rotary_factor``: RoPE turns the FIRST
+    # factor * head_dim values of a head (rotate-half among them) and passes
+    # the rest.
+    head_dim: int = 0
+    attn_gate: bool = False
+    partial_rotary_factor: float = 1.0
+    # The expert share (one rank of an expert-parallel deployment): this chip
+    # holds experts [experts_first, experts_first + experts_held) of n_experts
+    # (0 held = all). The router stays n_experts wide, with its top-k and its
+    # renormalisation over all k; a pair whose expert lies elsewhere joins no
+    # group and adds nothing; the stacks are [experts_held, ...]; the partial
+    # sum goes on to the next layer (no exchange is built).
+    experts_first: int = 0
+    experts_held: int = 0
+    # sigmoid(w_g . x), a scalar gate a token on the shared expert's output
+    shared_expert_gate: bool = False
     # Hyper-connections (HyperConnection below): hc_mult > 1 residual streams,
     # mixed per token around every sub-layer; the residual matrix is made
     # doubly stochastic by hc_sinkhorn_iters Sinkhorn iterations over
@@ -207,6 +241,8 @@ class TransformerConfig:
     mesh: Any = None
 
     def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.dim // self.n_heads)
         # what is not built is refused where the config is made: at load()
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(
@@ -227,24 +263,64 @@ class TransformerConfig:
                 raise ValueError(
                     f"layer_types must name n_layers={self.n_layers} layers, each one of "
                     f"{LAYER_KINDS}; got {len(kinds)}: {sorted(set(kinds))}")
-            if "conv" in kinds and (self.kv_lora_rank or self.hc_mult > 1 or self.mtp_layers):
+            if set(kinds) & set(STATE_LAYER_KINDS) and (
+                    self.kv_lora_rank or self.hc_mult > 1 or self.mtp_layers):
                 raise ValueError(STATE_LAYERS_COMPOSE_REFUSAL)
             if "conv" in kinds and self.conv_L_cache < 2:
                 raise ValueError(f"conv_L_cache={self.conv_L_cache} must be >= 2 (taps)")
-
-    @property
-    def head_dim(self) -> int:
-        return self.dim // self.n_heads
+            if "linear_attention" in kinds:
+                hk, hv = self.linear_num_key_heads, self.linear_num_value_heads
+                if (min(hk, hv, self.linear_key_head_dim, self.linear_value_head_dim) <= 0
+                        or hv % hk or self.linear_conv_kernel_dim < 2):
+                    raise ValueError(
+                        "a 'linear_attention' layer needs linear_num_key_heads, "
+                        "linear_num_value_heads (a multiple of the key heads), "
+                        "linear_key_head_dim, linear_value_head_dim and "
+                        "linear_conv_kernel_dim >= 2")
+        if self.partial_rotary_factor != 1.0:
+            rotary = self.partial_rotary_factor * self.head_dim
+            if self.kv_lora_rank or not 0 < rotary <= self.head_dim or rotary % 2:
+                raise ValueError(
+                    f"partial_rotary_factor={self.partial_rotary_factor} must turn an even "
+                    f"number of a head's {self.head_dim} values (per-head K/V attention)")
+        if self.experts_held or self.experts_first:
+            if not (0 < self.experts_held
+                    and 0 <= self.experts_first <= self.n_experts - self.experts_held):
+                raise ValueError(
+                    f"the expert share [{self.experts_first}, {self.experts_first} + "
+                    f"{self.experts_held}) does not lie within n_experts={self.n_experts}")
+            if self.mesh is not None:
+                raise ValueError(
+                    "an expert share (experts_held) is ONE rank's part of an "
+                    "expert-parallel deployment: it is not sharded over a mesh")
 
     def layer_kind(self, layer: int) -> str:
-        """"conv" or "full_attention"; a layer past the list (the MTP block)
-        is attention."""
+        """One of LAYER_KINDS; a layer past the list (the MTP block) is
+        attention."""
         kinds = self.layer_types
         return kinds[layer] if kinds is not None and layer < len(kinds) else "full_attention"
 
+    def layers_of(self, kind: str) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.n_layers) if self.layer_kind(i) == kind)
+
     @property
     def conv_layers(self) -> Tuple[int, ...]:
-        return tuple(i for i in range(self.n_layers) if self.layer_kind(i) == "conv")
+        return self.layers_of("conv")
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """The layers that keep a fixed state block a sequence (no pages)."""
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_kind(i) in STATE_LAYER_KINDS)
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.partial_rotary_factor * self.head_dim)
+
+    @property
+    def n_experts_held(self) -> int:
+        """Experts whose weights this tree holds (all, without a share)."""
+        return self.experts_held or self.n_experts
 
     @property
     def kv_rows_flat(self) -> bool:
@@ -363,6 +439,16 @@ def apply_rotary(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndar
     out1 = x1 * cos - x2 * sin
     out2 = x2 * cos + x1 * sin
     return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
+
+
+def apply_partial_rotary(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """``apply_rotary`` over the FIRST 2 * cos.shape[-1] values of each head
+    (rotate-half among those), the rest passed as they are; the whole head
+    where the tables are as wide as it."""
+    rotary = 2 * cos.shape[-1]
+    if rotary == x.shape[-1]:
+        return apply_rotary(x, cos, sin)
+    return jnp.concatenate([apply_rotary(x[..., :rotary], cos, sin), x[..., rotary:]], axis=-1)
 
 
 class RMSNorm(nn.Module):
@@ -568,6 +654,14 @@ class Attention(nn.Module):
 
         dt = cfg.dtype
         q_flat = x @ wq.astype(dt)
+        if cfg.attn_gate:
+            # a gate a head beside its query (the published q_proj makes both;
+            # models/convert.py splits it): sigmoid(gate) weighs the heads'
+            # output before wo
+            wq_gate = param_with_axes(
+                "wq_gate", nn.initializers.lecun_normal(), (cfg.dim, cfg.n_heads * hd),
+                jnp.float32, axes=("embed", "heads"))
+            gate = x @ wq_gate.astype(dt)
         if adapters is not None:
             # batched LoRA (runtime/adapters.py): per-slot low-rank delta
             # on q and (below) o — NEVER on k/v, so the KV written from a
@@ -588,9 +682,9 @@ class Attention(nn.Module):
             k = RMSNorm(hd, cfg.norm_eps, "head_norm", name="k_norm")(k)
         v = (x @ wv.astype(dt)).reshape(b, s, cfg.n_kv_heads, hd)
 
-        cos, sin = rotary_embedding(positions, hd, cfg.rope_theta, cfg.rope_scaling)
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
+        cos, sin = rotary_embedding(positions, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling)
+        q = apply_partial_rotary(q, cos, sin)
+        k = apply_partial_rotary(k, cos, sin)
 
         out = None
         if cache is not None and block_tables is not None:
@@ -735,6 +829,8 @@ class Attention(nn.Module):
             with jax.named_scope("attn.gqa.read"):
                 out = grouped_query_attention(q, k_all, v_all, mask)
         out = out.reshape(b, s, cfg.n_heads * hd)
+        if cfg.attn_gate:
+            out = (out * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
         proj = out @ wo.astype(dt)
         if adapters is not None:
             proj = proj + lora_delta(out, *adapters["wo"], adapter_ids,
@@ -1060,17 +1156,18 @@ class MoEFFN(nn.Module):
         from seldon_core_tpu.ops.quantize import QuantizedTensor
 
         cfg = self.cfg
-        e = cfg.n_experts
+        e = cfg.n_experts             # the router's width: every expert of the model
+        held, first = cfg.n_experts_held, cfg.experts_first   # those whose weights are here
         dt = cfg.dtype
         router = param_with_axes("router", nn.initializers.lecun_normal(), (cfg.dim, e), jnp.float32,
                                  axes=("embed", "expert"))
-        # a stack of e matrices: the fan-in is one matrix's, not the stack's
+        # a stack of matrices: the fan-in is one matrix's, not the stack's
         stack_init = nn.initializers.lecun_normal(batch_axis=(0,))
-        w1 = param_with_axes("w1", stack_init, (e, cfg.dim, cfg.ffn_dim), jnp.float32,
+        w1 = param_with_axes("w1", stack_init, (held, cfg.dim, cfg.ffn_dim), jnp.float32,
                              axes=("expert", "embed", "mlp"))
-        w2 = param_with_axes("w2", stack_init, (e, cfg.ffn_dim, cfg.dim), jnp.float32,
+        w2 = param_with_axes("w2", stack_init, (held, cfg.ffn_dim, cfg.dim), jnp.float32,
                              axes=("expert", "mlp", "embed"))
-        w3 = param_with_axes("w3", stack_init, (e, cfg.dim, cfg.ffn_dim), jnp.float32,
+        w3 = param_with_axes("w3", stack_init, (held, cfg.dim, cfg.ffn_dim), jnp.float32,
                              axes=("expert", "embed", "mlp"))
         b, s, d = x.shape
         t = b * s
@@ -1104,18 +1201,23 @@ class MoEFFN(nn.Module):
             if sowing:
                 # which experts each row took: what a logits probe's reference follows
                 self.sow("moe", "choice", chosen.reshape(b, s, k).astype(jnp.int32))
-            if valid is not None:
-                # group e does not exist: its rows sort behind every expert's
-                chosen = jnp.where(valid.reshape(t, 1), chosen, e)
-            pair_expert = chosen.reshape(t * k)
+            # the group of each pair among the HELD experts; group ``held`` does
+            # not exist: the pairs of a row that is no token, and those whose
+            # expert lies on another chip, sort behind every expert's
+            here = (chosen >= first) & (chosen < first + held)
+            live = here if valid is None else here & valid.reshape(t, 1)
+            group = jnp.where(live, chosen - first, held)
+            pair_expert = group.reshape(t * k)
             order = jnp.argsort(pair_expert)
             row_expert = pair_expert[order]
             by_token = jnp.sum(
-                chosen[:, :, None] == jnp.arange(e, dtype=chosen.dtype), axis=1,
-                dtype=jnp.int32)  # [t, e]
+                group[:, :, None] == jnp.arange(held, dtype=group.dtype), axis=1,
+                dtype=jnp.int32)  # [t, held]
             group_sizes = jnp.sum(by_token, axis=0)
             if sowing:
-                self.sow("moe", "tokens", jnp.sum(by_token.reshape(b, s, e), axis=1))
+                self.sow("moe", "tokens", jnp.sum(by_token.reshape(b, s, held), axis=1))
+                routed = jnp.int32(t * k) if valid is None else k * jnp.sum(valid, dtype=jnp.int32)
+                self.sow("moe", "pairs", jnp.stack([jnp.sum(group_sizes), routed]))
 
         with jax.named_scope("moe.experts"):
             rows = xf[order // k].astype(dt)  # [t*k, d], sorted by expert
@@ -1131,13 +1233,19 @@ class MoEFFN(nn.Module):
                 from seldon_core_tpu.ops.grouped_matmul import (
                     grouped_matmul, make_visits, row_tile)
 
+                # the tile follows the mean group, rows over ALL the router's
+                # experts; the rows behind the last held group (with a share,
+                # three quarters of them) get the kernel's zeros, a visit a
+                # tile that multiplies nothing: leaving those visits out and
+                # masking the rows instead measured 2 us a layer of a step and
+                # 13 us of a chunk SLOWER at Qwen3-Next's shapes (PR 38)
                 visits = make_visits(group_sizes, t * k, row_tile(t * k, e))
                 y = swiglu(lambda lhs, w, scale: grouped_matmul(
                     lhs, w, visits, scale, interpret=False))
                 return y, visits.count * visits.rows
 
             def experts_ragged_dot():
-                row_scale = jnp.minimum(row_expert, e - 1)
+                row_scale = jnp.minimum(row_expert, held - 1)
 
                 def grouped(lhs, w, scale):
                     if scale is None:   # a floating stack, in the rows' dtype
@@ -1148,7 +1256,7 @@ class MoEFFN(nn.Module):
                         preferred_element_type=jnp.float32) * scale[row_scale]
 
                 # what lies behind the last group is not ragged_dot's to define
-                y = jnp.where((row_expert < e)[:, None], swiglu(grouped), 0.0)
+                y = jnp.where((row_expert < held)[:, None], swiglu(grouped), 0.0)
                 return y, jnp.zeros((), jnp.int32)
 
             # the kernel is one device's program, compiled by Mosaic: stacks
@@ -1165,10 +1273,17 @@ class MoEFFN(nn.Module):
             out = jnp.einsum("tkd,tk->td", y, gates)
         out = out.reshape(b, s, d).astype(x.dtype)
         if cfg.n_shared_experts > 0:
-            # the shared experts are one dense SwiGLU every token takes
+            # the shared experts are one dense SwiGLU every token takes (and
+            # every chip of an expert-parallel deployment computes alike)
             with jax.named_scope("moe.shared"):
-                out = out + DenseFFN(cfg, cfg.n_shared_experts * cfg.ffn_dim,
-                                     name="shared")(x)
+                shared = DenseFFN(cfg, cfg.n_shared_experts * cfg.ffn_dim, name="shared")(x)
+                if cfg.shared_expert_gate:
+                    w_gate = param_with_axes(
+                        "shared_gate", small_leaf_init("shared_gate"), (cfg.dim, 1),
+                        jnp.float32, axes=("embed", "expert_gate"))
+                    scalar = jax.nn.sigmoid(x.astype(jnp.float32) @ w_gate)
+                    shared = (scalar * shared).astype(x.dtype)
+                out = out + shared
         return out
 
 
@@ -1183,20 +1298,23 @@ def moe_choices(sown: dict, cfg: TransformerConfig) -> jnp.ndarray:
 def moe_routing_stats(sown: dict, cfg: TransformerConfig):
     """Reduce what the MoE layers of one forward sowed (the "moe" collection
     of ``Transformer.apply(..., mutable=["moe"])``) to ``(tokens, stats)``:
-    ``tokens`` [b, e] int32, tokens of each sequence routed to each expert,
-    summed over layers; ``stats`` [5] int32 = live rows of the call, routed
-    (token, expert) pairs, distinct experts touched, the largest expert
-    group, and the rows the grouped-matmul kernel multiplied (visits x row
-    tile; 0 where ``ragged_dot`` served), the last four summed over the
-    ``n_moe_layers`` layer-calls."""
+    ``tokens`` [b, held] int32, tokens of each sequence routed to each expert
+    HELD here, summed over layers; ``stats`` [6] int32 = live rows of the
+    call, routed (token, expert) pairs whose expert is held, distinct held
+    experts touched, the largest expert group, the rows the grouped-matmul
+    kernel multiplied (visits x row tile; 0 where ``ragged_dot`` served) and
+    the pairs routed to experts that lie ELSEWHERE (0 without a share), the
+    last five summed over the ``n_moe_layers`` layer-calls."""
     layers = [sown[f"layer_{i}"]["moe"] for i in range(cfg.first_dense_layers, cfg.n_layers)]
-    per_layer = jnp.stack([layer["tokens"][0] for layer in layers])  # [L, b, e]
-    groups = jnp.sum(per_layer, axis=1)  # [L, e]
+    per_layer = jnp.stack([layer["tokens"][0] for layer in layers])  # [L, b, held]
+    groups = jnp.sum(per_layer, axis=1)  # [L, held]
+    pairs = jnp.stack([layer["pairs"][0] for layer in layers])   # [L, 2]: held, routed
     k = min(cfg.n_experts_per_token, cfg.n_experts)
     stats = jnp.stack([
-        jnp.sum(per_layer[0]) // k, jnp.sum(groups),
+        pairs[0, 1] // k, jnp.sum(groups),
         jnp.sum(groups > 0, dtype=jnp.int32), jnp.sum(jnp.max(groups, axis=1)),
-        sum(layer["tile_rows"][0] for layer in layers)])
+        sum(layer["tile_rows"][0] for layer in layers),
+        jnp.sum(pairs[:, 1] - pairs[:, 0])])
     return jnp.sum(per_layer, axis=0), stats.astype(jnp.int32)
 
 
@@ -1215,16 +1333,26 @@ def moe_routing_stats(sown: dict, cfg: TransformerConfig):
 # 1e-4 off), a selection bias about the spread of the top sigmoid scores.
 # The short convolution's taps are normal(0, 1/sqrt(taps)), so that a conv
 # layer's output has its input's size; a per-head q / k norm weight is ones.
-FLOAT32_AXES = ("hc_maps", "expert_select", "conv_taps", "head_norm")
+# Gated DeltaNet's (Qwen3-Next's published init where it has one): the four
+# taps a channel normal(0, 1/2) likewise, ``dt_bias`` ones, the gated norm's
+# weight ones, ``A_log`` = log of uniform(0, 16) (LOG_UNIFORM: the pair is the
+# range); the shared expert's scalar gate normal(0, 1/sqrt(dim)).
+FLOAT32_AXES = ("hc_maps", "expert_select", "conv_taps", "head_norm", "gdn_scalar", "expert_gate")
+LOG_UNIFORM = "log of uniform"
 SMALL_LEAF_INIT = {
     "phi": (0.0, None), "alpha": (0.7, 0.05), "b_pre": (0.0, 0.5), "b_post": (0.0, 0.5),
     "b_res": (0.0, 0.5), "router_bias": (0.0, 0.1), "taps": (0.0, 3 ** -0.5),
-    "weight": (1.0, 0.0),
+    "weight": (1.0, 0.0), "conv1d": (0.0, 0.5), "dt_bias": (1.0, 0.0),
+    "A_log": (LOG_UNIFORM, (0.0, 16.0)), "shared_gate": (0.0, None),
 }
 
 
 def draw_small_leaf(name: str, key, shape) -> jnp.ndarray:
     mean, std = SMALL_LEAF_INIT[name]
+    if mean == LOG_UNIFORM:
+        low, high = std
+        return jnp.log(jnp.maximum(
+            jax.random.uniform(key, shape, jnp.float32, low, high), 1e-6))
     if std is None:
         std = float(shape[0]) ** -0.5
     return mean + std * jax.random.normal(key, shape, jnp.float32)
@@ -1417,13 +1545,237 @@ class ShortConv(nn.Module):
                 state = pool[state_slots]
             v, new_state = short_conv(gate_b * xs, taps, state, positions, valid)
             if pool is None:
-                new_cache = (new_state,)
+                new_cache = StateEntry((new_state,))
             elif state_slots is None:
-                new_cache = (new_state.astype(pool.dtype),)
+                new_cache = StateEntry((new_state.astype(pool.dtype),))
             else:
-                new_cache = (pool.at[state_slots].set(new_state.astype(pool.dtype)),)
+                new_cache = StateEntry((pool.at[state_slots].set(new_state.astype(pool.dtype)),))
             y = (gate_c.astype(jnp.float32) * v).astype(dt)
         with jax.named_scope("mix.conv.out"):
+            return y @ w_out.astype(dt), new_cache
+
+
+GDN_CHUNK = 64   # rows of a sub-chunk of the delta rule's chunked form
+
+
+def _unit_lower_inverse(a: jnp.ndarray) -> jnp.ndarray:
+    """(I + A)^-1 for strictly lower triangular ``a`` [..., c, c]: A is
+    nilpotent (A^c = 0), so the inverse is the finite product
+    (I - A)(I + A^2)(I + A^4)... of log2(c) factors, batched matmuls in place
+    of the c sequential rows of a forward substitution."""
+    c = a.shape[-1]
+    eye = jnp.eye(c, dtype=a.dtype)
+    hp = jax.lax.Precision.HIGHEST
+    inv, power, span = eye - a, a, 1
+    while 2 * span < c:
+        power = jnp.matmul(power, power, precision=hp)
+        inv = jnp.matmul(inv, eye + power, precision=hp)
+        span *= 2
+    return inv
+
+
+def gated_delta_rule(q, k, v, g, beta, state, starts=None, kernel: bool = True):
+    """The gated delta rule over the rows of one call, and the state each
+    sequence leaves. ONE function for every call shape: a decode step
+    (s = 1), a prefill chunk (one sequence, padded) and the cache-less forward.
+
+    ``q`` / ``k`` [b, s, H, dk] float32 (L2-normalised a head, q scaled, the
+    key heads repeated to the H value heads); ``v`` [b, s, H, dv]; ``g``
+    [b, s, H] the LOG of each row's decay (<= 0); ``beta`` [b, s, H];
+    ``state`` [b, H, dk, dv] float32, S before the call's first row;
+    ``starts`` [b] bool or None: the sequences whose S reads as ZEROS whatever
+    ``state`` holds (a sequence that starts has no past). Per row:
+
+        S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;  o_t = S^T q_t
+
+    A row with ``beta = 0`` and ``g = 0`` leaves S as it came (a padded row).
+    Returns (o [b, s, H, dv] float32, S after the last row).
+
+    Computed in the CHUNKED form (the WY / UT transform of
+    ``torch_chunk_gated_delta_rule`` and the flash-linear-attention kernel):
+    sub-chunks of GDN_CHUNK rows; inside one, with G the running sum of g, the
+    triangular system T = (I + tril(beta_i (k_i . k_j) e^{G_i - G_j}, -1))^-1
+    gives every row's correction at once (W = T (beta k e^G), U = T (beta v));
+    between sub-chunks S goes on in float32:
+
+        V' = U - W S;  O = (q e^G) S + tril((q . k^T) e^{G_i - G_j}) V'
+        S <- e^{G_last} S + (k e^{G_last - G})^T V'
+
+    At s = 1 this IS the recurrence (T = 1), written so that S is multiplied
+    elementwise and reduced, not handed to the MXU a [128, 128] block a head:
+    the step is bound by S's bytes. In a program LOWERED for a TPU (and where
+    ``kernel``: not on a mesh) it is the repo's kernel (ops/gated_delta.py: S
+    read once and written once, in its own buffer), chosen by
+    ``jax.lax.platform_dependent`` as ``MoEFFN`` chooses its grouped matmul;
+    the expression, which XLA makes two passes over S, everywhere else and for
+    a [dk, dv] that is not whole tiles. float32 throughout, the matmuls at the
+    highest precision: they are a thousandth of a chunk's FLOPs."""
+    b, s, H, dk = k.shape
+    hp = jax.lax.Precision.HIGHEST
+    if starts is None:
+        starts = jnp.zeros((b,), bool)
+    if s == 1:
+        from seldon_core_tpu.ops.gated_delta import gated_delta_step, plan
+
+        def step_expression():
+            S = jnp.where(starts[:, None, None, None], 0.0, state)
+            q1, k1, v1 = q[:, 0], k[:, 0], v[:, 0]                       # [b, H, d]
+            decay = jnp.exp(g[:, 0])[..., None]                          # [b, H, 1]
+            # S^T k and S^T q out of ONE pass over S; o = e^g S^T q + (k . q) d
+            sk = jnp.sum(S * k1[..., None], axis=-2)
+            sq = jnp.sum(S * q1[..., None], axis=-2)
+            d = beta[:, 0][..., None] * (v1 - decay * sk)
+            new_state = S * decay[..., None] + k1[..., None] * d[..., None, :]
+            return decay * sq + jnp.sum(k1 * q1, axis=-1, keepdims=True) * d, new_state
+
+        walk = plan(H, dk, v.shape[-1]) if kernel else None
+
+        def step_kernel():
+            return gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state,
+                                    starts, walk, interpret=False)
+
+        if walk is None:
+            o, new_state = step_expression()
+        else:
+            o, new_state = jax.lax.platform_dependent(tpu=step_kernel, default=step_expression)
+        return o[:, None], new_state
+    state = jnp.where(starts[:, None, None, None], 0.0, state)
+    c = min(GDN_CHUNK, s)
+    pad = -s % c
+    if pad:   # rows that change nothing
+        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v))
+        g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (g, beta))
+    n = (s + pad) // c
+
+    def chunks(x):   # [b, n * c, H, ...] -> [n, b, H, c, ...]
+        x = x.reshape((b, n, c) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=-1)                                       # [n, b, H, c]
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    # e^{G_i - G_j} for j <= i (the exponent is <= 0 there; masked BEFORE the
+    # exponential, so nothing overflows above the diagonal)
+    decay = jnp.exp(jnp.where(lower, G[..., :, None] - G[..., None, :], -jnp.inf))
+    k_beta = k * beta[..., None]
+    a = jnp.einsum("...ik,...jk->...ij", k_beta, k, precision=hp) * decay
+    T = _unit_lower_inverse(jnp.where(jnp.tril(lower, -1), a, 0.0))
+    U = jnp.matmul(T, v * beta[..., None], precision=hp)
+    W = jnp.matmul(T, k_beta * jnp.exp(G)[..., None], precision=hp)
+    qk = jnp.einsum("...ik,...jk->...ij", q, k, precision=hp) * decay
+    q_in = q * jnp.exp(G)[..., None]
+    k_out = k * jnp.exp(G[..., -1:] - G)[..., None]
+    last = jnp.exp(G[..., -1])[..., None, None]
+
+    def sub_chunk(S, xs):
+        U_i, W_i, qk_i, q_i, k_i, last_i = xs
+        v_new = U_i - jnp.matmul(W_i, S, precision=hp)
+        o = jnp.matmul(q_i, S, precision=hp) + jnp.matmul(qk_i, v_new, precision=hp)
+        S = last_i * S + jnp.einsum("...ck,...cv->...kv", k_i, v_new, precision=hp)
+        return S, o
+
+    new_state, o = jax.lax.scan(sub_chunk, state, (U, W, qk, q_in, k_out, last),
+                                unroll=min(n, 4))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2).reshape(b, n * c, H, -1)   # [b, s, H, dv]
+    return o[:, :s], new_state
+
+
+def l2_normalize(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+class GatedDeltaNet(nn.Module):
+    """Qwen3-Next's Gated DeltaNet (``transformers`` ``Qwen3NextGatedDeltaNet``),
+    the token mixer of a "linear_attention" layer. With Hk key heads of dk and
+    Hv value heads of dv (each key head serves Hv / Hk value heads):
+
+        [q ; k ; v ; z] = W_qkvz u     [b ; a] = W_ba u      (held in THAT order:
+                                       the checkpoint interleaves them by key head)
+        [q ; k ; v] <- SiLU(causal depthwise taps over the channels of [q ; k ; v])
+        q, k <- L2-normalised a head (eps 1e-6), q * dk^-1/2
+        beta = sigmoid(b)     g = -exp(A_log) * softplus(a + dt_bias)     a value head
+        o = gated_delta_rule(q, k, v, g, beta, S)
+        out = W_out (RMSNorm_dv(o) * w * SiLU(z))       a value head
+
+    What a sequence keeps between calls, whatever its length: the last
+    taps - 1 rows of [q ; k ; v] BEFORE the convolution, in the serving dtype
+    (``short_conv``'s state and rule), and S [Hv, dk, dv] in float32, as the
+    published implementation holds it: the cache entry is the 2-tuple
+    ``(conv_state [rows, taps - 1, 2 Hk dk + Hv dv], S [rows, Hv, dk, dv])``.
+    ``state_slots`` is ShortConv's. A sequence that starts (its first row at
+    position 0) reads S as zeros, so admission resets nothing; a row that is
+    no token has beta = 0 and g = 0 and leaves S as it came. Without a cache:
+    from zeros, returns (out, (conv_state, S)) as well."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions, valid=None, cache=None, state_slots=None):
+        cfg = self.cfg
+        d, dt = cfg.dim, cfg.dtype
+        hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        key_dim, value_dim = hk * dk, hv * dv
+        channels = 2 * key_dim + value_dim
+        w_qkvz = param_with_axes("in_proj_qkvz", nn.initializers.lecun_normal(),
+                                 (d, channels + value_dim), jnp.float32,
+                                 axes=("embed", "gdn_proj"))
+        w_ba = param_with_axes("in_proj_ba", nn.initializers.lecun_normal(), (d, 2 * hv),
+                               jnp.float32, axes=("embed", "gdn_gates"))
+        taps = param_with_axes("conv1d", small_leaf_init("conv1d"),
+                               (channels, cfg.linear_conv_kernel_dim), jnp.float32,
+                               axes=("gdn_channel", "conv_taps"))
+        a_log = param_with_axes("A_log", small_leaf_init("A_log"), (hv,), jnp.float32,
+                                axes=("gdn_scalar",))
+        dt_bias = param_with_axes("dt_bias", small_leaf_init("dt_bias"), (hv,), jnp.float32,
+                                  axes=("gdn_scalar",))
+        w_out = param_with_axes("out_proj", nn.initializers.lecun_normal(), (value_dim, d),
+                                jnp.float32, axes=("gdn_value", "embed"))
+        b, s, _ = x.shape
+        if valid is None:
+            valid = positions < PAD_POS
+        with jax.named_scope("mix.gdn.in"):
+            qkvz = x @ w_qkvz.astype(dt)
+            # the gates' 2 Hv values a row stay float32 out of the product: g is
+            # -A softplus(a + dt_bias) with A up to 16, and a rounding of a to
+            # bf16 is a rounding of the DECAY
+            ba = jnp.matmul(x, w_ba.astype(dt), preferred_element_type=jnp.float32)
+        conv_pool, s_pool = (None, None) if cache is None else cache
+        if conv_pool is None or state_slots is None:
+            conv_state, state = conv_pool, s_pool
+        else:
+            conv_state, state = conv_pool[state_slots], s_pool[state_slots]
+        with jax.named_scope("mix.gdn.conv"):
+            mixed, new_conv = short_conv(qkvz[..., :channels], taps, conv_state, positions, valid)
+            mixed = jax.nn.silu(mixed)                                   # float32
+        with jax.named_scope("mix.gdn.rule"):
+            rep = hv // hk
+            q = l2_normalize(mixed[..., :key_dim].reshape(b, s, hk, dk)) * dk ** -0.5
+            k = l2_normalize(mixed[..., key_dim:2 * key_dim].reshape(b, s, hk, dk))
+            q, k = jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2)
+            v = mixed[..., 2 * key_dim:].reshape(b, s, hv, dv)
+            live = valid[..., None]
+            beta = jnp.where(live, jax.nn.sigmoid(ba[..., :hv]), 0.0)
+            g = jnp.where(live, -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias), 0.0)
+            if state is None:
+                state = jnp.zeros((b, hv, dk, dv), jnp.float32)
+            # a sequence that starts here has no past (a row that is no token
+            # starts nothing: a slot's S may be a chunk's to write meanwhile)
+            starts = (positions[:, 0] == 0) & valid[:, 0]
+            o, new_state = gated_delta_rule(q, k, v, g, beta, state, starts,
+                                            kernel=cfg.mesh is None)
+        if conv_pool is None:
+            new_cache = StateEntry((new_conv, new_state))
+        elif state_slots is None:
+            new_cache = StateEntry((new_conv.astype(conv_pool.dtype), new_state))
+        else:
+            new_cache = StateEntry((
+                conv_pool.at[state_slots].set(new_conv.astype(conv_pool.dtype)),
+                s_pool.at[state_slots].set(new_state)))
+        with jax.named_scope("mix.gdn.out"):
+            normed = RMSNorm(dv, cfg.norm_eps, "head_norm", name="norm")(o)
+            z = qkvz[..., channels:].reshape(b, s, hv, dv).astype(jnp.float32)
+            y = (normed * jax.nn.silu(z)).astype(dt).reshape(b, s, value_dim)
             return y @ w_out.astype(dt), new_cache
 
 
@@ -1446,10 +1798,16 @@ class TransformerBlock(nn.Module):
         streams = cfg.hc_mult > 1
         if streams:
             X, (x, h_post, h_res) = x, HyperConnection(cfg, name="attention_hc")(x)
-        if cfg.layer_kind(self.layer) == "conv":
+        kind = cfg.layer_kind(self.layer)
+        if kind == "conv":
             # the scopes are the operator's own (mix.conv.*): "attn" stays the
             # attention layers'
             h, new_cache = ShortConv(cfg, name="conv")(
+                RMSNorm(cfg.dim, cfg.norm_eps, name="operator_norm")(x), positions, valid,
+                cache, state_slots)
+        elif kind == "linear_attention":
+            # likewise mix.gdn.*
+            h, new_cache = GatedDeltaNet(cfg, name="linear_attn")(
                 RMSNorm(cfg.dim, cfg.norm_eps, name="operator_norm")(x), positions, valid,
                 cache, state_slots)
         else:
@@ -1549,8 +1907,9 @@ class Transformer(nn.Module):
         gather+einsum pair per adapted projection (``lora_delta``).
         adapter id 0 is the reserved zero-delta identity.
 
-        ``state_slots`` ([b] int32) names the row of the conv layers' state
-        each sequence continues (ShortConv); None = row i is sequence i's."""
+        ``state_slots`` ([b] int32) names the row of the state layers' blocks
+        each sequence continues (ShortConv, GatedDeltaNet); None = row i is
+        sequence i's."""
         from seldon_core_tpu.ops.quantize import QuantizedTensor, dequantize_array, lookup_rows
 
         cfg = self.cfg
@@ -1569,7 +1928,7 @@ class Transformer(nn.Module):
         x = lookup_rows(emb, tokens, cfg.dtype)
         x = enter_streams(with_sharding_constraint(x, ("batch", "seq", "embed")), cfg)
         valid = None
-        if cfg.n_experts > 0 or cfg.conv_layers:
+        if cfg.n_experts > 0 or cfg.state_layers:
             # rows that are tokens: not the padding of a chunk (PAD_POS), and
             # not a slot nobody holds, whose block-table row is all TRASH_PAGE
             # (a slot that is prefilling is such a row of the decode step: its
@@ -1621,34 +1980,61 @@ LATENT_INT8_REFUSAL = (
     "over 512 + 64 mixed values is untested; serve it with the bf16 cache")
 
 
+class StateEntry(tuple):
+    """A state layer's entry of a cache tree (a fixed block a sequence, no
+    pages, no positions: a conv layer's ``(state,)``, a linear-attention
+    layer's ``(conv_state, S)``), told from an attention layer's
+    ``(values..., positions)`` by its TYPE: the initialisers below make one
+    where ``cfg.layer_kind`` names a state layer, the two state modules return
+    one, and every tree operation keeps it (a registered pytree node)."""
+
+
+jax.tree_util.register_pytree_node(
+    StateEntry, lambda entry: (tuple(entry), None), lambda _aux, leaves: StateEntry(leaves))
+
+
 def is_state_entry(layer) -> bool:
-    """Is this layer's entry of a cache tree a conv layer's ``(state,)``
-    (a fixed block a sequence, no pages, no positions) and not an attention
-    layer's ``(values..., positions)``? The page operations skip it."""
-    return len(layer) == 1
+    """Is this layer's entry of a cache tree a state layer's? The page
+    operations skip it."""
+    return isinstance(layer, StateEntry)
 
 
-def conv_state_bytes(cfg: TransformerConfig) -> int:
-    """Bytes of conv state ONE sequence keeps over all conv layers, whatever
+def _state_entry_shapes(cfg: TransformerConfig, kind: str) -> Tuple[Tuple[Tuple[int, ...], Any], ...]:
+    """(shape a sequence, dtype) of each array of a state layer's entry."""
+    if kind == "conv":
+        return (((cfg.conv_L_cache - 1, cfg.dim), cfg.dtype),)
+    channels = (2 * cfg.linear_num_key_heads * cfg.linear_key_head_dim
+                + cfg.linear_num_value_heads * cfg.linear_value_head_dim)
+    return (((cfg.linear_conv_kernel_dim - 1, channels), cfg.dtype),
+            ((cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+              cfg.linear_value_head_dim), jnp.float32))
+
+
+def state_bytes(cfg: TransformerConfig) -> int:
+    """Bytes of state ONE sequence keeps over all its state layers, whatever
     its length (0 for a model without them)."""
-    return (len(cfg.conv_layers) * (cfg.conv_L_cache - 1) * cfg.dim
-            * jnp.dtype(cfg.dtype).itemsize)
+    import math
+
+    return sum(math.prod(shape) * jnp.dtype(dtype).itemsize
+               for i in cfg.state_layers
+               for shape, dtype in _state_entry_shapes(cfg, cfg.layer_kind(i)))
 
 
 def _with_state_entries(cfg: TransformerConfig, attention_entries: list, rows: int):
-    """The cache tree over ALL layers: a conv layer's ``(state,)`` entry,
-    [rows, conv_L_cache - 1, dim] zeros in the serving dtype, where
-    cfg.layer_types says so, the attention entries in order elsewhere."""
-    if not cfg.conv_layers:
+    """The cache tree over ALL layers: a state layer's entry (zeros,
+    ``rows`` sequences) where cfg.layer_types says so, the attention entries
+    in order elsewhere."""
+    if not cfg.state_layers:
         return attention_entries
     if rows <= 0:
         raise ValueError(
-            "a model with conv layers needs the number of sequences its state "
-            "block serves (init_paged_kv_caches(..., state_slots=))")
+            "a model with state layers needs the number of sequences its state "
+            "blocks serve (init_paged_kv_caches(..., state_slots=))")
     entries = iter(attention_entries)
     return [
-        (jnp.zeros((rows, cfg.conv_L_cache - 1, cfg.dim), cfg.dtype),)
-        if cfg.layer_kind(i) == "conv" else next(entries)
+        StateEntry(jnp.zeros((rows,) + shape, dtype)
+                   for shape, dtype in _state_entry_shapes(cfg, cfg.layer_kind(i)))
+        if cfg.layer_kind(i) in STATE_LAYER_KINDS else next(entries)
         for i in range(cfg.n_layers)
     ]
 
@@ -1673,7 +2059,7 @@ def _init_head_caches(cfg: TransformerConfig, lead: Tuple[int, int], kvd: str,
     shape = lead + (cfg.n_kv_heads, cfg.head_dim)
     if flat and kvd != "int8":
         shape = lead + (cfg.n_kv_heads * cfg.head_dim,)
-    n = cfg.n_layers - len(cfg.conv_layers)
+    n = cfg.n_layers - len(cfg.state_layers)
     if kvd == "int8":
         scale_shape = lead + (cfg.n_kv_heads,)
         return [
@@ -1705,7 +2091,8 @@ def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
     f32 [b, max_len, kvh] per-head per-position scales (initialised to 1 so
     empty slots dequantize to exact zeros). A latent-attention layer
     (cfg.kv_lora_rank) is a (rows, pos) pair: [b, max_len, latent_row_dim];
-    a conv layer (cfg.layer_types) a ``(state,)`` 1-tuple [b, L-1, dim]."""
+    a conv layer (cfg.layer_types) a ``(state,)`` 1-tuple [b, L-1, dim], a
+    linear-attention layer ``(conv_state, S)`` (GatedDeltaNet)."""
     kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
     if cfg.kv_lora_rank:
         return _init_latent_caches(cfg, (batch, max_len), kvd)
@@ -1725,8 +2112,10 @@ def init_paged_kv_caches(cfg: TransformerConfig, num_pages: int,
     int8 pools carry f32 [num_pages, page_size, kvh] scale planes
     initialised to 1 (empty slots dequantize to exact zeros). A
     latent-attention layer is a (rows, pos) pair: [num_pages, page_size,
-    latent_row_dim] with no head axis. A conv layer (cfg.layer_types) has no
-    pages: its entry is ``(state,)``, [state_slots, conv_L_cache - 1, dim],
+    latent_row_dim] with no head axis. A state layer (cfg.layer_types) has no
+    pages: a conv layer's entry is ``(state,)``, [state_slots,
+    conv_L_cache - 1, dim], a linear-attention layer's ``(conv_state, S)``,
+    [state_slots, taps - 1, channels] and float32 [state_slots, Hv, dk, dv]:
     one block a sequence the pool serves (``is_state_entry``).
 
     Where ``cfg.kv_rows_flat`` (one device, or narrow heads) the bf16
@@ -1760,8 +2149,8 @@ def kv_cache_bytes_per_token(cfg: TransformerConfig,
         per_layer = 2 * (per_pos * 1 + cfg.n_kv_heads * 4)  # int8 + f32 scale
     else:
         per_layer = 2 * per_pos * jnp.dtype(cfg.dtype).itemsize
-    # a conv layer caches nothing a token (conv_state_bytes a sequence)
-    return (cfg.n_layers - len(cfg.conv_layers)) * (per_layer + 4)  # + int32 pos slot
+    # a state layer caches nothing a token (state_bytes a sequence)
+    return (cfg.n_layers - len(cfg.state_layers)) * (per_layer + 4)  # + int32 pos slot
 
 
 @register_model("transformer")

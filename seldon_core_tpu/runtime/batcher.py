@@ -224,9 +224,10 @@ def _page_table_ops():
     # garbage. page_ids is padded to a fixed length with TRASH_PAGE
     # (re-masking trash is harmless), so one compile serves every
     # allocation size.
-    # A conv layer's entry of the pool tree is a fixed block a slot, with no
-    # pages and no positions (models/transformer.py ``is_state_entry``): every
-    # page operation below hands it on as it is.
+    # A state layer's entry of the pool tree (a conv layer's one array, a
+    # linear-attention layer's two) is fixed blocks a slot, with no pages and
+    # no positions (models/transformer.py ``is_state_entry``): every page
+    # operation below hands it on as it is.
     @partial(jax.jit, donate_argnums=(0,))
     def reset_pages(caches, page_ids):
         return [
@@ -545,6 +546,9 @@ class _Slot:
 
 
 MOE_PROGRAMS = ("decode", "chunk")   # the step programs the loop counts by
+# the counters' names for the kinds of state layer (models/transformer.py
+# STATE_LAYER_KINDS): seldon_llm_<name>_rows_total / _layer_calls_total
+STATE_COUNTERS = {"conv": "conv", "gdn": "linear_attention"}
 
 LOOP_PHASES = ("admit", "handoff", "dispatch", "prefill", "first_token_wait",
                "first_token", "drain_wait", "emit", "idle", "hop")
@@ -682,10 +686,11 @@ class LoopPhases:
         # view, or whole visits of the live-page kernel): visited / context
         # is the over-read
         self.attn_rows_read = dict.fromkeys(MOE_PROGRAMS, 0)
-        # live rows through the conv layers (a model with layer_types), and
-        # conv layers x calls, from host integers at dispatch
-        self.conv_rows = dict.fromkeys(MOE_PROGRAMS, 0)
-        self.conv_layer_calls = dict.fromkeys(MOE_PROGRAMS, 0)
+        # live rows through the state layers of each kind (a model with
+        # layer_types: "conv" the short convolutions, "gdn" the linear-attention
+        # layers), and such layers x calls, from host integers at dispatch
+        self.state_rows = {kind: dict.fromkeys(MOE_PROGRAMS, 0) for kind in STATE_COUNTERS}
+        self.state_layer_calls = {kind: dict.fromkeys(MOE_PROGRAMS, 0) for kind in STATE_COUNTERS}
         self._open: List[_Phase] = []
         self._open_parts: List[_Phase] = []
         self._turn: Optional[Any] = None   # the open turn's annotation
@@ -765,11 +770,12 @@ class LoopPhases:
         return _Handoff(self, fn, args)
 
     def stats(self) -> dict:
-        conv = {}
-        if any(self.conv_layer_calls.values()):
-            conv = {"conv_rows": dict(self.conv_rows),
-                    "conv_layer_calls": dict(self.conv_layer_calls)}
-        return {**conv,
+        state = {}
+        for kind in STATE_COUNTERS:     # absent for a model without such layers
+            if any(self.state_layer_calls[kind].values()):
+                state[f"{kind}_rows"] = dict(self.state_rows[kind])
+                state[f"{kind}_layer_calls"] = dict(self.state_layer_calls[kind])
+        return {**state,
                 "loop_seconds": dict(self.seconds),
                 "loop_phase_counts": dict(self.counts),
                 "loop_part_seconds": dict(self.part_seconds),
@@ -787,9 +793,13 @@ class LoopPhases:
         self.attn_context_tokens[program] += context_tokens
         self.attn_rows_read[program] += rows_read
 
-    def count_conv(self, program: str, live_rows: int, layer_calls: int) -> None:
-        self.conv_rows[program] += live_rows
-        self.conv_layer_calls[program] += layer_calls
+    def count_state_layers(self, program: str, live_rows: int, calls: int,
+                           layers: Dict[str, int]) -> None:
+        """``calls`` calls of ``program`` with ``live_rows`` live rows in all,
+        through ``layers`` = {kind: state layers of that kind}."""
+        for kind, n in layers.items():
+            self.state_rows[kind][program] += live_rows
+            self.state_layer_calls[kind][program] += calls * n
 
 
 def _in_phase(name: str):
@@ -817,28 +827,33 @@ class MoECounters:
     credited to a request, by expert, summed over layers."""
 
     FIELDS = ("calls", "live_rows", "routed_pairs", "experts_touched",
-              "max_group", "tile_rows")
+              "max_group", "tile_rows", "pairs_elsewhere")
 
-    def __init__(self, n_experts: int, n_layers: int):
+    def __init__(self, n_experts: int, n_layers: int, first: int = 0):
+        """``n_experts`` experts HELD here, global ids from ``first`` (a model
+        that holds a share of its experts counts those: ``routed_pairs`` /
+        ``experts_touched`` / ``max_group`` are of held pairs and experts, and
+        ``pairs_elsewhere`` the pairs whose expert lies on another chip)."""
         self.n_layers = n_layers
+        self.first = first
         self.by_program = {kind: dict.fromkeys(self.FIELDS, 0)
                            for kind in MOE_PROGRAMS}
         self.expert_tokens = np.zeros((n_experts,), np.int64)
 
     def add(self, kind: str, stats: np.ndarray) -> None:
-        """``stats`` [calls, 5]: moe_routing_stats of each call."""
+        """``stats`` [calls, 6]: moe_routing_stats of each call."""
         tally = self.by_program[kind]
         tally["calls"] += len(stats)
         for name, total in zip(self.FIELDS[1:], stats.sum(axis=0)):
             tally[name] += int(total)
 
     def flight_fields(self, stats: np.ndarray) -> dict:
-        """One call's ``stats`` [5] as the fields its flight events carry."""
+        """One call's ``stats`` [6] as the fields its flight events carry."""
         return {"moe_live": int(stats[0]),
                 "moe_touched": round(float(stats[2]) / self.n_layers, 2)}
 
     def stats(self) -> dict:
-        return {"moe_layers": self.n_layers,
+        return {"moe_layers": self.n_layers, "moe_expert_first": self.first,
                 "moe_by_program": {k: dict(v) for k, v in self.by_program.items()},
                 "moe_expert_tokens": self.expert_tokens.tolist()}
 
@@ -1272,7 +1287,7 @@ class ContinuousBatcher:
         self._phases = LoopPhases()
         self._read_walks: Dict[int, Any] = {}   # query tokens a call -> how its read walks
         cfg = server._cfg
-        self._moe = (MoECounters(cfg.n_experts, cfg.n_moe_layers)
+        self._moe = (MoECounters(cfg.n_experts_held, cfg.n_moe_layers, cfg.experts_first)
                      if cfg.n_experts > 0 else None)
         # Disaggregated prefill/decode (module docstring): remote-prefill
         # admission stages jobs on prefill-slice workers and consumes
@@ -1337,8 +1352,9 @@ class ContinuousBatcher:
         from seldon_core_tpu.models.transformer import init_paged_kv_caches
 
         # the ONE tree the step programs thread and donate: a page pool for
-        # every attention layer and, for a conv layer (cfg.layer_types), a
-        # fixed [slots, taps - 1, dim] state block with no pages
+        # every attention layer and, for a state layer (cfg.layer_types), fixed
+        # blocks a slot with no pages ([slots, taps - 1, dim] for a conv layer;
+        # conv state and a float32 matrix a head for a linear-attention layer)
         self._caches = jax.jit(
             lambda: init_paged_kv_caches(
                 cfg, self.pool_pages, self.page_size, server.kv_cache_dtype,
@@ -1347,13 +1363,19 @@ class ContinuousBatcher:
         self._cache_nbytes = sum(
             int(getattr(leaf, "nbytes", 0)) for leaf in jax.tree.leaves(self._caches)
         )
-        self._conv_layers = sum(is_state_entry(layer) for layer in self._caches)
+        # state layers by the kind their counters name, and the bytes of ALL
+        # the arrays of their entries (a conv layer's one, a linear-attention
+        # layer's conv state and float32 S)
+        self._state_layers = {
+            name: len(cfg.layers_of(kind)) for name, kind in STATE_COUNTERS.items()
+            if cfg.layers_of(kind)}
         self.state_nbytes = sum(
-            int(layer[0].nbytes) for layer in self._caches if is_state_entry(layer))
+            int(leaf.nbytes) for layer in self._caches if is_state_entry(layer)
+            for leaf in layer)
         # which state row a chunk's one sequence continues: its slot, as a
         # device array made once (no transfer a chunk)
         self._state_slot = [jnp.asarray([i], jnp.int32) for i in range(self.S)
-                            ] if self._conv_layers else None
+                            ] if self._state_layers else None
         # the step's read is the XLA gather of the whole view, except on one
         # TPU, where a kernel walks the live pages (ops/page_walk.py) — said
         # here so a server's log names it
@@ -1367,10 +1389,10 @@ class ContinuousBatcher:
             "latent rows" if cfg.kv_lora_rank else "per-head K/V",
             self._cache_nbytes / 1e9,
             "gather" if self._read_walk(1) is None else "live_pages")
-        if self._conv_layers:
+        if self._state_layers:
             logger.info(
-                "conv state: %d layers x %d slots, %d B a slot, %.1f MB resident",
-                self._conv_layers, self.S, self.state_nbytes // self.S,
+                "per-slot state: %s layers x %d slots, %d B a slot, %.1f MB resident",
+                self._state_layers, self.S, self.state_nbytes // self.S,
                 self.state_nbytes / 1e6)
 
         # No insert: chunked prefill writes straight into the pool through
@@ -2482,8 +2504,8 @@ class ContinuousBatcher:
                     self.server._params, self._caches, job.bt_row, toks, pos, *extra)
         job.next = start + n
         self._phases.count_attention("chunk", start + n, self._rows_read(C, [start + n], 1))
-        if self._conv_layers:
-            self._phases.count_conv("chunk", n, self._conv_layers)
+        if self._state_layers:
+            self._phases.count_state_layers("chunk", n, 1, self._state_layers)
         event = None
         if self._flight is not None:
             # dispatch wall (enqueue-only)
@@ -2508,9 +2530,11 @@ class ContinuousBatcher:
 
             from seldon_core_tpu.models.transformer import paged_read_walk
 
-            pool = next(layer[0] for layer in self._caches if not is_state_entry(layer))
+            cfg = self.server._cfg
+            # the first PAGED layer's pool (a state layer's entry has no pages)
+            paged = next(i for i in range(cfg.n_layers) if i not in cfg.state_layers)
             self._read_walks[s] = None if jax.default_backend() != "tpu" else paged_read_walk(
-                self.server._cfg, s, self.n_pages, self.page_size, pool.dtype)
+                cfg, s, self.n_pages, self.page_size, self._caches[paged][0].dtype)
         return self._read_walks[s]
 
     def _rows_read(self, s: int, live_rows: Sequence[int], sequences: int) -> int:
@@ -3022,8 +3046,9 @@ class ContinuousBatcher:
                 live.extend(pos + 1 + j for j in range(k))
                 self._slots[i].disp_new += k
             self._phases.count_attention("decode", context, self._rows_read(1, live, k * self.S))
-            if self._conv_layers:
-                self._phases.count_conv("decode", k * len(snapshot), k * self._conv_layers)
+            if self._state_layers:
+                self._phases.count_state_layers(
+                    "decode", k * len(snapshot), k, self._state_layers)
             self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside))
             self._count_steps()
         return True
@@ -3155,7 +3180,7 @@ class ContinuousBatcher:
                 moe_tokens, moe_fields = None, {}
                 if self._moe is not None and rec.acc is None:
                     # graftlint: allow-host-sync-in-hot-path(part of the same drain sync: the step's routing tallies land with its tokens — the program already finished for the token read above)
-                    moe_stats = np.asarray(rec.aside["moe_stats"])    # [k, 5]
+                    moe_stats = np.asarray(rec.aside["moe_stats"])    # [k, 6]
                     # graftlint: allow-host-sync-in-hot-path(same: [k, S, n_experts] int32, 8 KB a step at 32 slots x 64 experts)
                     moe_tokens = np.asarray(rec.aside["moe_tokens"])
                     self._moe.add("decode", moe_stats)

@@ -872,12 +872,15 @@ class LLMServer(SeldonComponent):
         logger.info("LLMServer loaded %s (vocab=%d)", name, self._cfg.vocab_size)
 
     def _state_layers_refusal(self) -> Optional[str]:
-        """What is not built over a layer that carries STATE (a conv layer,
-        cfg.layer_types), by name; None where nothing asked for is missing.
-        Each of the first three would restart a sequence mid-way, and needs the
-        state AT a token boundary, which pages do not hold."""
-        if not getattr(self._cfg, "conv_layers", ()):
+        """What is not built over a layer that carries STATE (a conv or a
+        linear-attention layer, cfg.layer_types), by name; None where nothing
+        asked for is missing. Each of the first three would restart a sequence
+        mid-way, and needs the state AT a token boundary, which pages do not
+        hold."""
+        state_layers = getattr(self._cfg, "state_layers", ())
+        if not state_layers:
             return None
+        kinds = " and ".join(sorted({self._cfg.layer_kind(i) for i in state_layers}))
         what = None
         if self.prefix_cache_size > 0:
             what = ("prefix_cache_size > 0: a prefix hit (the radix trie's shared pages, "
@@ -891,10 +894,10 @@ class LLMServer(SeldonComponent):
                     "pages, and the state a prefill worker leaves is not among them")
         elif self.tensor_parallel > 1 or self.sequence_parallel > 1 or self.mesh is not None:
             what = ("tensor / sequence parallelism or a mesh: no sharding of the per-slot "
-                    "state block, nor of the gates' [dim, 3 dim] projection, is built")
+                    "state blocks, nor of the layers' input projections, is built")
         elif self.lora_rank > 0:
             what = "lora_rank > 0: adapters target the attention and dense-FFN projections"
-        return what and ("a model with conv layers (layer_types) keeps per-sequence state "
+        return what and (f"a model with {kinds} layers (layer_types) keeps per-sequence state "
                          "beside the paged cache, and does not compose with " + what)
 
     def _params_on(self, device):
@@ -949,9 +952,11 @@ class LLMServer(SeldonComponent):
         matrix's d: counted over the stack, every expert's output would be
         sqrt(e) too small, and a wrong expert layer too faint to notice
         (latent attention's W_UK is held output-first: its own line below).
-        jit caches by (shape, std), so the 32 identical layers of a 7B
-        config cost ~a dozen compiles, not ~200."""
+        One program a distinct (shape, std, layout), compiled side by side:
+        the 32 identical layers of a 7B config cost ~a dozen compiles, not
+        ~200, and a cold start waits for the longest, not their sum."""
         import zlib
+        from concurrent.futures import ThreadPoolExecutor
         from functools import partial as _partial
 
         import jax
@@ -970,7 +975,13 @@ class LLMServer(SeldonComponent):
 
         @_partial(jax.jit, static_argnums=(1, 2, 3, 4))
         def make_quantized(key, shape, std, out_major, lookup):
-            w = jax.random.normal(key, shape, jnp.float32) * std
+            # a stack is drawn as ONE matrix of its rows and reshaped: the same
+            # values (the generator counts elements row-major whatever the
+            # shape), and the TPU compiler takes 3 s over it where the 3-D draw
+            # of an expert stack took 10-30 s, a minute of every cold start of
+            # an MoE model (PR 38)
+            rows = int(np.prod(shape[:-1]))
+            w = jax.random.normal(key, (rows, shape[-1]), jnp.float32).reshape(shape) * std
             return quantize_array(w.astype(target), out_major=out_major, lookup=lookup)
 
         shapes = self._init_shapes()
@@ -980,22 +991,37 @@ class LLMServer(SeldonComponent):
         kept = jax.tree.leaves(float32_leaves(shapes, axes))
         indexed = jax.tree.leaves(row_lookups(shapes, axes))
         root = jax.random.PRNGKey(self.seed)
+
+        def quantized(name, spec, keep, out_major, lookup):
+            """make_quantized's static arguments for a leaf it draws, else None."""
+            if keep or spec.ndim < 2 or not jnp.issubdtype(spec.dtype, jnp.floating):
+                return None
+            fan_in = int(np.prod(spec.shape[1 if spec.ndim == 3 else 0:-1]))
+            if name.endswith("['w_uk']"):
+                # latent attention's key expansion is held [H, nope,
+                # latent], the order q~ = W_UK^T q reads it; as a map it
+                # is k = W_UK c, so its fan-in is the latent axis
+                fan_in = spec.shape[-1]
+            return spec.shape, 1.0 / float(fan_in) ** 0.5, out_major, lookup
+
+        plan = [(keystr(path), path[-1].key, spec, keep,
+                 quantized(keystr(path), spec, keep, out_major, lookup))
+                for (path, spec), out_major, keep, lookup in zip(flat, transposed, kept, indexed)]
+        # the distinct draws are compiled side by side: one after another they
+        # were 73 s of a cold start on the chip's host (eleven programs of a
+        # 12-layer Qwen3-Next, PR 38), and none waits for another
+        draws = list(dict.fromkeys(draw for *_, draw in plan if draw is not None))
+        with ThreadPoolExecutor(max(len(draws), 1)) as pool:
+            compiled = dict(zip(draws, pool.map(
+                lambda draw: make_quantized.lower(root, *draw).compile(), draws)))
         leaves = []
-        for (path, spec), out_major, keep, lookup in zip(flat, transposed, kept, indexed):
-            name = keystr(path)
+        for name, leaf_name, spec, keep, draw in plan:
             key = jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
             if keep:
                 # float32 as drawn, by the module's own rule for the leaf
-                leaves.append(draw_small_leaf(path[-1].key, key, spec.shape))
-            elif jnp.issubdtype(spec.dtype, jnp.floating) and spec.ndim >= 2:
-                fan_in = int(np.prod(spec.shape[1 if spec.ndim == 3 else 0:-1]))
-                if name.endswith("['w_uk']"):
-                    # latent attention's key expansion is held [H, nope,
-                    # latent], the order q~ = W_UK^T q reads it; as a map it
-                    # is k = W_UK c, so its fan-in is the latent axis
-                    fan_in = spec.shape[-1]
-                leaves.append(make_quantized(
-                    key, spec.shape, 1.0 / float(fan_in) ** 0.5, out_major, lookup))
+                leaves.append(draw_small_leaf(leaf_name, key, spec.shape))
+            elif draw is not None:
+                leaves.append(compiled[draw](key))
             elif jnp.issubdtype(spec.dtype, jnp.floating):
                 fill = 1.0 if ("norm" in name.lower() or "scale" in name.lower()
                                or name.lower().endswith("weight']")) else 0.0
@@ -1351,7 +1377,7 @@ class LLMServer(SeldonComponent):
         """``module.apply`` for the batcher's step programs: (logits, caches,
         aside). ``aside`` is what leaves a program beside its tokens and costs
         no sync of its own (the host reads it after the tokens have landed):
-        for an MoE model ``moe_tokens`` [b, n_experts] and ``moe_stats`` [5]
+        for an MoE model ``moe_tokens`` [b, experts held] and ``moe_stats`` [6]
         (models/transformer.py moe_routing_stats) and ``moe_choice``
         [b, s, n_moe_layers, k], the experts every row took, which only a
         logits probe reads (its reference follows them); empty for a dense one."""
@@ -1401,9 +1427,10 @@ class LLMServer(SeldonComponent):
                     adapter_ids=adapter_ids,
                 )
         else:
-            # ``state_slots``: a model with conv layers (cfg.layer_types) holds
-            # their per-slot state in the pool tree beside the pages, and its
-            # chunk is told WHICH slot's state it continues and leaves behind
+            # ``state_slots``: a model with state layers (cfg.layer_types: conv,
+            # linear attention) holds their per-slot state in the pool tree
+            # beside the pages, and its chunk is told WHICH slot's state it
+            # continues and leaves behind
             @partial(jax.jit, donate_argnums=(1,))
             def prefill_chunk(params, pools, block_row, tokens, positions, state_slots=None):
                 return forward(

@@ -147,3 +147,21 @@ def test_moeffn_through_the_kernel_is_moeffn_through_ragged_dot(monkeypatch):
     pairs, tile = 17 * 2, row_tile(24 * 2, 8)
     tile_rows = int(sown["moe"]["tile_rows"][0])
     assert tile_rows % tile == 0 and pairs <= tile_rows <= (48 // tile + 8) * tile
+
+
+@pytest.mark.parametrize("sizes", [[3, 0, 7, 2], [0, 0, 0, 0], [16, 0, 0, 16], [1, 1, 1, 1]])
+def test_the_rows_behind_the_last_group_come_out_zero(sizes):
+    """A caller that holds a SHARE of the experts has most of its rows behind
+    the last group and does not mask them: every tile wholly behind it gets
+    one visit that multiplies nothing and writes zeros, the tail of the last
+    group's tile is zero too, and the groups' rows are ``ragged_dot``'s."""
+    m, tile = 96, 16
+    lhs, rhs, scale = operands(m, 128, 128, len(sizes))
+    visits = make_visits(jnp.asarray(sizes, jnp.int32), m, tile)
+    total = sum(sizes)
+    live = sum(-(-(sum(sizes[:i + 1])) // tile) - sum(sizes[:i]) // tile for i, n in enumerate(sizes) if n)
+    assert int(visits.count) == live + m // tile - -(-total // tile)
+    got = np.asarray(grouped_matmul(lhs, rhs, visits, scale, interpret=True))
+    np.testing.assert_allclose(got[:total], by_ragged_dot(lhs, rhs, scale, sizes)[:total],
+                               atol=1e-3, rtol=1e-3)
+    assert np.all(got[total:] == 0.0)

@@ -345,6 +345,22 @@ def test_hybrid_step_programs_donate_the_state_and_keep_the_pool_flat(name):
     assert reported == []
 
 
+@pytest.mark.parametrize("name", ["llm.gdn_paged_decode_step_s4",
+                                  "llm.gdn_prefill_chunk_c8"])
+def test_linear_attention_step_programs_donate_both_state_arrays(name):
+    """Qwen3-Next's block at test dims (ISSUE 38): a linear-attention layer's
+    conv rows AND its float32 matrix state are donated and aliased with the
+    page pools (a leaf that is not shows as an alias finding); S is never
+    narrowed to 16 bits; no floating copy of an expert stack, held or whole;
+    the MoE promises hold; no transfer."""
+    from tools.hlolint.contracts import all_contracts
+
+    (contract,) = [c for c in all_contracts() if c.name == name]
+    reported, *_ = run_one(contract, checks=("alias", "transfer", "dtype",
+                                             "collective"))
+    assert reported == []
+
+
 @pytest.mark.parametrize("name", ["llm.xing4_paged_decode_step_s4",
                                   "llm.xing4_prefill_chunk_c8"])
 def test_stream_step_programs_keep_the_streams_in_the_models_dtype(name):

@@ -291,16 +291,16 @@ def test_the_cache_trees_hold_two_kinds_of_entry(server):
     assert len(paged[2]) == 3 and paged[2][0].shape == (10, 4, 2 * 8)
     with pytest.raises(ValueError, match="state_slots"):
         init_paged_kv_caches(cfg, 10, 4)
-    from seldon_core_tpu.models.transformer import conv_state_bytes, kv_cache_bytes_per_token
+    from seldon_core_tpu.models.transformer import state_bytes, kv_cache_bytes_per_token
 
-    assert conv_state_bytes(cfg) == 4 * 2 * 32 * 4          # float32 here
+    assert state_bytes(cfg) == 4 * 2 * 32 * 4          # float32 here
     assert kv_cache_bytes_per_token(cfg) == 1 * (2 * 2 * 8 * 4 + 4)
 
 
 def test_the_page_operations_hand_a_state_entry_on(server):
     _, _, reset_pages, _, _, cow_page_copy, export_pages, _ = _page_table_ops()
     tree = init_paged_kv_caches(server._cfg, 10, 4, state_slots=3)
-    tree = [(layer[0] + 1.5,) if is_state_entry(layer) else layer for layer in tree]
+    tree = [type(layer)((layer[0] + 1.5,)) if is_state_entry(layer) else layer for layer in tree]
     state = [np.asarray(layer[0]) for layer in tree if is_state_entry(layer)]
     tree = reset_pages(tree, jnp.asarray([2, 3, 1, 1]))
     tree = cow_page_copy(tree, jnp.asarray(2), jnp.asarray(3), jnp.asarray(2))

@@ -61,6 +61,26 @@ def test_grouped_matmul_lowers_for_tpu(rows, dim, width):
     assert text.count(MOSAIC_CALL) == 2
 
 
+@pytest.mark.parametrize("rows,tile", [(64 * 10, 32), (256 * 10, 64)])
+def test_grouped_matmul_lowers_for_tpu_over_a_share_of_the_experts(rows, tile):
+    """Qwen3-Next's step and chunk: ``tokens x 10`` sorted rows of which the
+    pairs of the 128 HELD experts come first, the tile of the mean group over
+    all 512 the router chooses among, the kernel's zeros behind the last held
+    group; a [2048, 512] int8 block is 1 MB."""
+    assert row_tile(rows, 512) == tile
+
+    def swiglu(x, sizes, w1, s1, w2, s2):
+        visits = make_visits(sizes, rows, row_tile(rows, 512))
+        h = grouped_matmul(x, w1, visits, s1, interpret=False)
+        return grouped_matmul(h.astype(x.dtype), w2, visits, s2, interpret=False)
+
+    text = tpu_mlir(
+        swiglu, S((rows, 2048), jnp.bfloat16), S((128,), jnp.int32),
+        S((128, 2048, 512), jnp.int8), S((128, 512), jnp.float32),
+        S((128, 512, 2048), jnp.int8), S((128, 2048), jnp.float32))
+    assert text.count(MOSAIC_CALL) == 2
+
+
 @pytest.mark.parametrize("tokens", [(32, 1), (1, 256), (1, 4096)])
 def test_sinkhorn_lowers_for_tpu(tokens):
     """the hyper-connections' Sinkhorn chain at a decode step's rows, a
@@ -112,13 +132,16 @@ def test_latent_page_attention_lowers_for_tpu(slots, tokens, heads, pages):
 
 @pytest.mark.parametrize("slots,tokens,heads,kv_heads,head_dim,pages", [
     (32, 1, 32, 8, 128, 16), (8, 1, 32, 8, 128, 64), (32, 1, 16, 16, 128, 16),
-    (32, 1, 32, 8, 64, 64), (8, 4, 32, 8, 128, 64), (8, 1, 32, 32, 128, 17)])
+    (32, 1, 32, 8, 64, 64), (8, 4, 32, 8, 128, 64), (8, 1, 32, 32, 128, 17),
+    (64, 1, 16, 2, 256, 128)])
 def test_gqa_page_attention_lowers_for_tpu(slots, tokens, heads, kv_heads, head_dim, pages):
     """The live-page read of grouped-query attention at the GQA cells' steps
     (Mistral 32 slots x 1,024 rows and 8 x 4,096; OLMoE 32 x 1,024, 16 heads of
     their own; LFM2 32 x 4,096, heads of 64), at a speculative verify of three
-    drafts, and at Llama-2-7B's 32 KV heads (chip_smoke.py: a 4,096-wide row,
-    half as many rows a visit): K and V rows as the pools hold them."""
+    drafts, at Llama-2-7B's 32 KV heads (chip_smoke.py: a 4,096-wide row,
+    half as many rows a visit), and at Qwen3-Next's step (64 slots x 8,192
+    rows of 2 KV heads of 256, eight query heads a group): K and V rows as the
+    pools hold them."""
     walk = gqa_plan(tokens, heads, kv_heads, head_dim, pages, 64)
     row = kv_heads * head_dim
     assert walk.pages * 64 * row * 4 <= 8 << 20

@@ -76,8 +76,20 @@ LFM2 = dict(vocab_size=256, dim=2048, n_layers=4, n_heads=32, n_kv_heads=8, ffn_
             router_bias=True, first_dense_layers=2, dense_ffn_dim=7168, qk_norm="head",
             norm_eps=1e-5, rope_theta=1e6,
             layer_types=("conv", "conv", "full_attention", "conv"))
+# Qwen3-Next-80B-A3B's period at published widths: three Gated DeltaNet layers
+# (16 key / 32 value heads of 128, four taps over 8,192 channels) and one gated
+# GQA layer (16 query / 2 KV heads of 256, rotary over the first 64), every layer
+# 512 softmax-routed experts top-10 of which 128 are HELD here, one gated shared
+QWEN3NEXT = dict(vocab_size=256, dim=2048, n_layers=4, n_heads=16, n_kv_heads=2, head_dim=256,
+                 ffn_dim=512, max_seq_len=8192, dtype="bfloat16", n_experts=512,
+                 n_experts_per_token=10, experts_first=0, experts_held=128,
+                 router_renormalize=True, n_shared_experts=1, shared_expert_gate=True,
+                 qk_norm="head", attn_gate=True, partial_rotary_factor=0.25, norm_eps=1e-6,
+                 rope_theta=1e7, linear_num_key_heads=16, linear_num_value_heads=32,
+                 linear_key_head_dim=128, linear_value_head_dim=128, linear_conv_kernel_dim=4,
+                 layer_types=("linear_attention",) * 3 + ("full_attention",))
 CONFIGS = {"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
-           "xing4": XING4, "mistral_vocab": MISTRAL_VOCAB, "lfm2": LFM2}
+           "xing4": XING4, "mistral_vocab": MISTRAL_VOCAB, "lfm2": LFM2, "qwen3next": QWEN3NEXT}
 PAGE, POOL_PAGES = 64, 514
 
 
@@ -153,8 +165,8 @@ def compiled(server, program: str, sharding, slots: int = 32, length: int = 0):
             sds((slots, 2), "uint32"), sds((), "float32"), sds((slots, pages), "int32"))
     else:
         chunk = 256
-        # a model with conv layers is told which slot's state the chunk continues
-        state_slot = (sds((1,), "int32"),) if server._cfg.conv_layers else ()
+        # a model with state layers is told which slot's state the chunk continues
+        state_slot = (sds((1,), "int32"),) if server._cfg.state_layers else ()
         lowered = server._get_prefill_chunk(chunk, pages).lower(
             params, pools, sds((1, pages), "int32"), sds((1, chunk), "int32"),
             sds((1, chunk), "int32"), *state_slot)
@@ -604,7 +616,7 @@ def test_the_hybrid_programs_donate_state_and_pool_and_copy_neither(v5e, servers
     block; the bytes donated are the tree's as computed; nothing is sent to or
     fetched from the host; and the conv operator's three scopes are there."""
     from seldon_core_tpu.models.transformer import (
-        conv_state_bytes, init_paged_kv_caches, is_state_entry, kv_cache_bytes_per_token)
+        state_bytes, init_paged_kv_caches, is_state_entry, kv_cache_bytes_per_token)
 
     server = servers("lfm2")
     cfg = server._cfg
@@ -630,7 +642,7 @@ def test_the_hybrid_programs_donate_state_and_pool_and_copy_neither(v5e, servers
     assert tree[2][0].shape == tree[2][1].shape == (pages, PAGE, row) and row == 512
     tree_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(tree))
     assert tree_bytes == (pages * PAGE * kv_cache_bytes_per_token(cfg, "bf16")
-                          + slots * conv_state_bytes(cfg))
+                          + slots * state_bytes(cfg))
     exe = compiled(server, program, v5e, slots=slots, length=length)
     hlo = exe.as_text()
     entry = hlo[hlo.index("\nENTRY"):]
@@ -660,3 +672,103 @@ def test_the_hybrid_programs_donate_state_and_pool_and_copy_neither(v5e, servers
     if program == "decode_step":
         # no gathered view of K or of V: the step walks the live pages
         assert stats.temp_size_in_bytes < slots * length * row * 2
+
+
+QWEN3NEXT_CELL = (64, 8192)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_the_linear_attention_programs_donate_both_state_arrays_and_hold_no_floating_stack(
+        v5e, servers, program):
+    """Qwen3-Next's period at published widths (three Gated DeltaNet layers, one
+    gated GQA layer, 128 of 512 experts held) and the cell's own shapes (64
+    slots x 8,192 tokens): every leaf of the cache tree, each linear-attention
+    layer's ``[slots, 3, 8192]`` conv rows AND its float32 ``[slots, 32, 128,
+    128]`` matrix state as well as the attention layer's K, V and position
+    pools, is an aliased output of its parameter; no op of its own copies or
+    transposes a state array or a pool; no op yields a floating copy of an
+    expert stack (``[128, 2048, 512]`` / ``[128, 512, 2048]``: the grouped
+    matmul takes the int8 stacks as held, 128 experts, not 512); the live-page
+    kernel reads the K / V pool of flat ``[pages, 64, 512]`` rows (2 heads of
+    256) in the step, and every slot's S goes through the rule's kernel alone
+    (ops/gated_delta.py); the temporaries stay a small part of one S; nothing is
+    sent to or fetched from the host; and the operator's four scopes are there,
+    outside ``attn``."""
+    from seldon_core_tpu.models.transformer import (
+        init_paged_kv_caches, is_state_entry, kv_cache_bytes_per_token, state_bytes)
+    from seldon_core_tpu.ops.quantize import QuantizedTensor
+
+    server = servers("qwen3next")
+    cfg = server._cfg
+    slots, length = QWEN3NEXT_CELL
+    pages = slots * length // PAGE + 2
+    assert cfg.kv_rows_flat and cfg.state_layers == (0, 1, 2) and cfg.head_dim == 256
+    layers = server._params["params"]
+    gdn, attention, moe = (layers["layer_0"]["linear_attn"], layers["layer_3"]["attention"],
+                           layers["layer_0"]["moe"])
+    # the int8 tree: the projections quantized, the stacks [128, ...] of a router 512 wide
+    for name, shape in (("in_proj_qkvz", (2048, 12288)), ("in_proj_ba", (2048, 64)),
+                        ("out_proj", (4096, 2048))):
+        assert isinstance(gdn[name], QuantizedTensor) and gdn[name].q.shape == shape, name
+    assert moe["w1"].q.shape == (128, 2048, 512) and moe["w2"].q.shape == (128, 512, 2048)
+    assert moe["router"].q.shape[-2:] in ((2048, 512), (512, 2048))
+    assert attention["wq_gate"].q.size == attention["wq"].q.size == 2048 * 4096
+    # ... and the float32 leaves no tree quantizes or casts
+    small = {"conv1d": gdn["conv1d"], "A_log": gdn["A_log"], "dt_bias": gdn["dt_bias"],
+             "norm": gdn["norm"]["weight"], "q_norm": attention["q_norm"]["weight"],
+             "shared_gate": moe["shared_gate"]}
+    assert {k: (v.dtype.name, v.shape) for k, v in small.items()} == {
+        "conv1d": ("float32", (8192, 4)), "A_log": ("float32", (32,)),
+        "dt_bias": ("float32", (32,)), "norm": ("float32", (128,)), "q_norm": ("float32", (256,)),
+        "shared_gate": ("float32", (2048, 1))}
+    tree = jax.eval_shape(lambda: init_paged_kv_caches(cfg, pages, PAGE, "bf16", state_slots=slots))
+    assert [is_state_entry(layer) for layer in tree] == [True, True, True, False]
+    row = cfg.n_kv_heads * cfg.head_dim
+    assert tree[3][0].shape == tree[3][1].shape == (pages, PAGE, row) and row == 512
+    conv_rows, matrix = (slots, 3, 8192), (slots, 32, 128, 128)
+    assert [leaf.shape for leaf in tree[0]] == [conv_rows, matrix]
+    assert tree[0][1].dtype == jnp.float32 and tree[0][0].dtype == jnp.bfloat16
+    tree_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(tree))
+    assert tree_bytes == (pages * PAGE * kv_cache_bytes_per_token(cfg, "bf16")
+                          + slots * state_bytes(cfg))
+    exe = compiled(server, program, v5e, slots=slots, length=length)
+    hlo = exe.as_text()
+    entry = hlo[hlo.index("\nENTRY"):]
+    # the nine leaves, each a parameter that an output aliases
+    leaves = {m.group(2): int(m.group(3)) for m in re.finditer(
+        r"%(pools_(\d__\d)_)[\w.]* = \S+ parameter\((\d+)\)", entry)}
+    assert sorted(leaves) == ["0__0", "0__1", "1__0", "1__1", "2__0", "2__1", "3__0", "3__1", "3__2"]
+    aliased = {int(n) for n in re.findall(r"\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo)}
+    assert set(leaves.values()) <= aliased, (leaves, aliased)
+    stats = exe.memory_analysis()
+    assert tree_bytes <= stats.alias_size_in_bytes < tree_bytes + (1 << 20)
+    assert re.search(rf"%pools_3__0_[\w.]* = bf16\[{pages},{PAGE},{row}\]\{{2,1,0:", entry)
+    assert re.search(r"%pools_0__1_[\w.]* = f32\[64,32,128,128\]\{3,2,1,0:", entry)
+    # (the 3 MB of conv rows a layer ARE re-laid at the step's loop boundary:
+    # the compiler holds [64, 3, 8192] slots-minor-but-one, whole sublane tiles
+    # of slots and not three rows padded to a tile; 134 MB of S never is)
+    copies = weight_copies(hlo, {(pages, PAGE, row), matrix, (128, 2048, 512), (128, 512, 2048)})
+    assert [c for c in copies if " in fused_computation" not in c] == []
+    # no floating expert stack, held or whole, as an op's output
+    stacks = {(e, 2048, 512) for e in (128, 512)} | {(e, 512, 2048) for e in (128, 512)}
+    assert [op for op in own_ops(hlo) if op[1] in FLOATS and op[2] in stacks] == []
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 3 * 4     # three projections a layer
+    assert "ragged_dot_int8" in hlo and "ragged-dot-none" not in hlo
+    # one S is 134 MB: the program's scratch is a fraction of it (no second copy of a state array)
+    assert stats.temp_size_in_bytes < 64 * 32 * 128 * 128 * 4 // 2
+    assert not re.search(r"\b(infeed|outfeed|send|recv|send-done|recv-done)\(", hlo)
+    assert "HostCompute" not in hlo and "host_compute" not in hlo
+    for scope in ("mix.gdn.in", "mix.gdn.conv", "mix.gdn.rule", "mix.gdn.out"):
+        assert all(f"layer_{i}/linear_attn/{scope}/" in hlo for i in cfg.state_layers), scope
+    assert "layer_3/attn/attention" in hlo and "/linear_attn/attn/" not in hlo
+    assert "/attn/mix.gdn" not in hlo and "/attn/linear_attn" not in hlo
+    if program == "decode_step":
+        assert "gqa_page_attention" in hlo
+        # every slot's S goes through the repo's kernel (ops/gated_delta.py) and
+        # through nothing else: no fusion of the step reads or writes a whole
+        # [64, 32, 128, 128] (as XLA ops the rule was two passes over it)
+        assert len(re.findall(r"= \([^=]*\) custom-call\([^\n]*gated_delta_step", hlo)) == 3
+        through = [op for op in own_ops(hlo) if op[1] == "f32" and op[2] in (
+            matrix, (slots, 2, 16, 128, 128)) and op[3] not in ("parameter", "bitcast",
+                                                               "get-tuple-element")]
+        assert through == [], through

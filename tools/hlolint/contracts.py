@@ -305,6 +305,46 @@ def _hybrid_server():
         return _STATE["hybrid_server"]
 
 
+# a state entry of two arrays (ISSUE 38): Qwen3-Next's block at test dims: Gated
+# DeltaNet layers whose per-slot state is three conv rows AND a float32 matrix a
+# value head, beside a gated GQA layer of explicit head_dim and partial rotary;
+# every layer MoE with a SHARE of the experts held (GDN_HELD of MOE_EXPERTS)
+# behind a router over all of them, one gated shared expert
+GDN_DIM = 256
+GDN_HEADS = (2, 4, 32, 32)      # key heads, value heads, key dim, value dim
+GDN_HELD = 8
+
+
+def _gdn_server():
+    with _STATE_LOCK:
+        if "gdn_server" not in _STATE:
+            ensure_platform()
+            from seldon_core_tpu.servers.llmserver import LLMServer
+
+            hk, hv, dk, dv = GDN_HEADS
+            s = LLMServer(
+                model="transformer",
+                model_kwargs=dict(
+                    vocab_size=96, dim=GDN_DIM, n_layers=3, n_heads=4,
+                    n_kv_heads=2, head_dim=128, ffn_dim=MOE_WIDTH,
+                    max_seq_len=PAGES_PER_SLOT * PAGE_SIZE,
+                    n_experts=MOE_EXPERTS, n_experts_per_token=MOE_TOP_K,
+                    experts_first=4, experts_held=GDN_HELD,
+                    router_renormalize=True, n_shared_experts=1,
+                    shared_expert_gate=True, qk_norm="head", attn_gate=True,
+                    partial_rotary_factor=0.25,
+                    linear_num_key_heads=hk, linear_num_value_heads=hv,
+                    linear_key_head_dim=dk, linear_value_head_dim=dv,
+                    layer_types=("linear_attention", "full_attention",
+                                 "linear_attention"),
+                    dtype="bfloat16"),
+                quantize="int8", init_random=True, len_buckets=(PLEN,),
+                seed=7)
+            s.load()
+            _STATE["gdn_server"] = s
+        return _STATE["gdn_server"]
+
+
 def _paged_batcher():
     with _STATE_LOCK:  # nests into _base_server's hold: RLock
         if "paged_batcher" not in _STATE:
@@ -470,6 +510,17 @@ HYBRID_SPLIT_POOL = (
     "copy the whole pool (PR 35); the heads are split in the gathered view")
 
 
+GDN_NARROW_STATE = (
+    rf"tensor<{SLOTS}x{GDN_HEADS[1]}x{GDN_HEADS[2]}x{GDN_HEADS[3]}x(bf16|f16)>",
+    "the linear-attention layers' matrix state [slots, value heads, key dim, "
+    "value dim] in a 16-bit type: S is held, decayed and corrected in float32 "
+    "(as the published implementation holds it); a narrowed copy is a rounding "
+    "of every slot's state a layer a call")
+GDN_FLOAT_STACK = (
+    rf"tensor<({GDN_HELD}|{MOE_EXPERTS})x({GDN_DIM}x{MOE_WIDTH}|{MOE_WIDTH}x{GDN_DIM})"
+    r"x(bf16|f16|f32)>", MOE_FLOAT_STACK[1])
+
+
 def _pool_specs_of(server):
     import jax
 
@@ -551,6 +602,25 @@ def _build_hybrid_paged_decode_step():
 def _build_hybrid_prefill_chunk():
     """The chunk is told WHICH slot's state it continues (its last operand)."""
     s = _hybrid_server()
+    fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
+    return fn, (s._params, _pool_specs_of(s),
+                _sds((1, PAGES_PER_SLOT), "int32"),
+                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
+                _sds((1,), "int32"))
+
+
+def _build_gdn_paged_decode_step():
+    s = _gdn_server()
+    fn = s._get_decode_step_paged(SLOTS, PAGES_PER_SLOT, 1)
+    return fn, (s._params, _pool_specs_of(s), _sds((SLOTS,), "int32"),
+                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
+                _sds((), "float32"),
+                _sds((SLOTS, PAGES_PER_SLOT), "int32"))
+
+
+def _build_gdn_prefill_chunk():
+    """The chunk is told WHICH slot's state it continues (its last operand)."""
+    s = _gdn_server()
     fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
@@ -997,6 +1067,34 @@ def all_contracts() -> List[Contract]:
             donated=(1,),
             forbid_dtypes=(HYBRID_FLOAT32_STATE, HYBRID_SPLIT_POOL,
                            MOE_DENSE_FORM, HYBRID_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.gdn_paged_decode_step_s4",
+            description="PAGED decode step of a model with linear-attention "
+                        "layers (Qwen3-Next's block: conv rows and a float32 "
+                        "matrix state a slot beside the pages of a gated GQA "
+                        "layer; a share of the experts held): BOTH state "
+                        "arrays are donated with the pools, S stays float32, "
+                        "no floating copy of an expert stack",
+            build=_build_gdn_paged_decode_step,
+            donated=(1, 3, 4),
+            forbid_dtypes=(GDN_NARROW_STATE, MOE_DENSE_FORM, GDN_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.gdn_prefill_chunk_c8",
+            description="chunked admission prefill of the same model: the "
+                        "chunk runs the delta rule's chunked form, continues "
+                        "ONE slot's two state arrays and writes them back "
+                        "into the donated blocks",
+            build=_build_gdn_prefill_chunk,
+            donated=(1,),
+            forbid_dtypes=(GDN_NARROW_STATE, MOE_DENSE_FORM, GDN_FLOAT_STACK),
             lowering_platform="tpu",
             collectives={},
             cost=True,
