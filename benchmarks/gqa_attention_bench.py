@@ -1,6 +1,6 @@
 """One layer's paged GQA read on the chip: the expression over the gathered
-view against the live-page kernel, by rows a visit; and a chunk's read over
-both pool layouts.
+view against the live-page kernel, a step's by rows a visit, a chunk's in the
+rule's head-block form.
 
     chiprun -- python benchmarks/gqa_attention_bench.py [out.json]
 
@@ -10,12 +10,16 @@ the view's gather out of the loop) at the call shapes the GQA configurations
 serve (PERF.md section 4): Mistral's chat step (32 slots x 1,024 rows, a few
 live) and docs step (8 x 4,096, all live), OLMoE's chat step (16 KV heads of
 their own), LFM2's step (32 x 4,096, heads of 64), Llama-2-7B's (32 KV heads:
-chip_smoke.py), and a prefill chunk of 256 tokens over a 4,096- and a 1,024-row
-view. Variants: ``expression [kvh, hd]`` is the whole-view read over pools held
-``[pages, 64, kvh, hd]`` (what served before PR 36 and still does on a mesh),
-``expression flat`` the same over flat rows ``[pages, 64, kvh x hd]`` (what a
-chunk runs, and every lowering that is not for a TPU), ``kernel N`` the
-live-page walk at N rows a visit. Prints one line per (shape, variant) and
+chip_smoke.py), and the prefill chunks: Mistral's 256 tokens at 256, 1,792 and
+3,584 live rows of a 4,096-row view (a rerank prompt's first, middle and last
+chunk), its 128 and 256 tokens over the chat server's 1,024-row view, and
+OLMoE's, LFM2's and Qwen3-Next's 256 tokens over theirs. Variants: ``expression
+[kvh, hd]`` is the whole-view read over pools held ``[pages, 64, kvh, hd]``
+(what served before PR 36 and still does on a mesh), ``expression flat`` the
+same over flat rows ``[pages, 64, kvh x hd]`` (every lowering that is not for
+a TPU), ``kernel N`` the live-page walk at N rows a visit: a step's row-wide
+by rows a visit, a chunk's a lane block a KV head as ``gqa_plan`` walks it.
+Prints one line per (shape, variant) and
 writes them all as JSON. A time is the median of ``REPEATS`` calls of a jitted
 program that runs the read ``DEPTH`` times in a chain on the device, two depths'
 difference divided by the depths'; a kernel's time includes making its visit
@@ -48,9 +52,14 @@ SHAPES = [
     ("olmoe chat step", 32, 1, 16, 16, 128, 16, [300, 150, 420, 260, 90, 333, 500, 200] + [-1] * 24),
     ("lfm2 step", 32, 1, 32, 8, 64, 64, list(np.linspace(1100, 3400, 32).astype(int))),
     ("llama2-7b step", 8, 1, 32, 32, 128, 17, [600, 300] + [-1] * 6),
-    ("mistral chunk at 2.6k", 1, 256, 32, 8, 128, 64, [2600]),
-    ("mistral chunk at 0.5k", 1, 256, 32, 8, 128, 16, [512]),
-    ("olmoe chunk at 0.5k", 1, 256, 16, 16, 128, 16, [512]),
+    ("mistral chunk at 256", 1, 256, 32, 8, 128, 64, [256]),
+    ("mistral chunk at 1,792", 1, 256, 32, 8, 128, 64, [1792]),
+    ("mistral chunk at 3,584", 1, 256, 32, 8, 128, 64, [3584]),
+    ("mistral chat chunk 128", 1, 128, 32, 8, 128, 16, [128]),
+    ("mistral chat chunk 256", 1, 256, 32, 8, 128, 16, [512]),
+    ("olmoe chunk at 512", 1, 256, 16, 16, 128, 16, [512]),
+    ("lfm2 chunk at 2,048", 1, 256, 32, 8, 64, 64, [2048]),
+    ("qwen3next chunk at 4,096", 1, 256, 16, 2, 256, 128, [4096]),
 ]
 VISIT_ROWS = [512, 1024, 2048]
 
@@ -85,7 +94,8 @@ def main():
     os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
     tiny = "--tiny" in sys.argv
     page = 16 if tiny else PAGE
-    shapes = [("tiny step", 3, 1, 16, 4, 32, 12, [100, -1, 40])] if tiny else SHAPES
+    shapes = [("tiny step", 3, 1, 16, 4, 32, 12, [100, -1, 40]),
+              ("tiny chunk", 1, 32, 16, 4, 128, 12, [100])] if tiny else SHAPES
     visit_rows = [64] if tiny else VISIT_ROWS
     shallow, deep = (1, 2) if tiny else (SHALLOW, DEEP)
     device = jax.devices()[0]
@@ -100,10 +110,13 @@ def main():
                                    ).astype(jnp.bfloat16) for i in (1, 2)]
         live_rows = int(sum(n for n in lens if n > 0))
         live_us = live_rows * row * 2 * 2 / 819e9 * 1e6
-        planned = None if tiny else gqa_attention.gqa_plan(s, heads, kvh, hd, n_pages, page)
+        planned = gqa_attention.gqa_plan(s, heads, kvh, hd, n_pages, page)
         variants = [("expression [kvh, hd]", None, (pages, page, kvh, hd)),
                     ("expression flat", None, (pages, page, row))]
-        if s * heads < page_walk.QUERY_TILE:
+        if planned is not None and planned.blocks > 1:
+            variants.append((f"kernel {planned.pages * page} a head block (the rule)", planned,
+                             (pages, page, row)))
+        elif s * heads < page_walk.QUERY_TILE:
             for rows in visit_rows:
                 walk = page_walk.Plan(pages=min(rows // page, -(-n_pages // 2) * 2), q_tile=s * heads)
                 if walk.pages * page * row * 4 <= 2 * page_walk.VISIT_BYTES:

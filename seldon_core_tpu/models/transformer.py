@@ -20,6 +20,7 @@ at all; this is the native model family the TPU build adds (SURVEY.md §5
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
 from typing import Any, Optional, Tuple
 
@@ -559,11 +560,14 @@ def grouped_query_attention(q: jnp.ndarray, k_all: jnp.ndarray,
     return out.reshape(b, s, n_heads, hd)
 
 
+@functools.partial(jax.jit, static_argnames=("n_kv_heads", "walk"))
 def paged_live_read(q, cache, block_tables, positions, *, n_kv_heads: int, walk):
     """The paged bf16 pool's read of a call shape the live-page kernel takes
     (``walk`` = ``paged_read_walk(...)``, not None): the kernel
     (ops/gqa_attention.py) in a program lowered for a TPU, the expression
-    (``paged_attention_ref``) in every other."""
+    (``paged_attention_ref``) in every other. A jitted function of its own, so
+    a program's layers share ONE trace of both branches (a trace a layer is
+    start-up time no compile cache serves: PERF.md section 6, PR 41)."""
     def read_expression():
         return paged_attention_ref(q, cache, block_tables, positions, n_kv_heads)
 
@@ -718,12 +722,12 @@ class Attention(nn.Module):
             # The read as an expression gathers the logical [b, n_pages*ps,
             # ...] view and runs the SAME chain the dense layout uses
             # (grouped_query_attention): paged == dense bit-for-bit (masked
-            # positions contribute exact zeros). For the bf16 pool on one TPU,
-            # a call of few query rows a sequence (the decode step, the
-            # speculative verify) walks each sequence's live pages with the
-            # repo's kernel instead (``paged_live_read``); every other
-            # lowering, a mesh, the int8 pool and a call shape the kernel does
-            # not take (a chunk's full query tiles among them) keep the
+            # positions contribute exact zeros). For the bf16 pool of flat
+            # rows on one TPU the call walks each sequence's live pages with
+            # the repo's kernel instead (``paged_live_read``: the decode step
+            # and the speculative verify row-wide, a chunk's full query tiles
+            # a lane block a KV head); every other lowering, a mesh, the int8
+            # pool and a call shape the kernel does not take keep the
             # expression over the whole view.
             walk = None
             if len(new_cache) == 3:
@@ -904,24 +908,24 @@ def paged_read_walk(cfg: "TransformerConfig", s: int, n_pages: int, page_size: i
     whole view there too: a mesh (the kernel is one device's program), a pool
     in another dtype than the model's (the int8 KV pool), a call shape the
     kernel does not take. Latent attention's one pool of latent rows
-    (ops/latent_attention.py) takes every shape the plan does; per-head K and V
-    rows (ops/gqa_attention.py), where the pool holds them flat
-    (``cfg.kv_rows_flat``), those whose query rows are under one tile: the
-    decode step and the speculative verify. (A visit there multiplies
-    n_kv_heads times the products it needs, each query head against the whole
-    row, which is free while the rows' bytes bound it and is not under a
-    chunk's 256 x H query rows, whose read is MXU-shaped and wants a lane block
-    a head: ROADMAP.md A3b.) From static facts alone, so ``Attention``,
-    ``LatentAttention`` and the loop's ``seldon_llm_attn_rows_read_total``
-    agree by construction."""
+    (ops/latent_attention.py) and per-head K and V rows where the pool holds
+    them flat (``cfg.kv_rows_flat``: ops/gqa_attention.py) take every shape
+    their plan does. The K and V read has two forms of the one walk, by the
+    call's query rows a sequence: under one tile (the decode step, the
+    speculative verify) each query head against the whole row, n_kv_heads times
+    the products it needs, which is free while the rows' bytes bound the read;
+    from one tile on (a chunk's 256 x H rows, MXU-shaped) a lane block a KV
+    head, the per-head chain's own products (``gqa_plan``). From static facts
+    alone, so ``Attention``, ``LatentAttention`` and the loop's
+    ``seldon_llm_attn_rows_read_total`` agree by construction."""
     from seldon_core_tpu.ops.gqa_attention import gqa_plan
-    from seldon_core_tpu.ops.page_walk import QUERY_TILE, plan
+    from seldon_core_tpu.ops.page_walk import plan
 
     if cfg.mesh is not None or jnp.dtype(pool_dtype) != jnp.dtype(cfg.dtype):
         return None
     if cfg.kv_lora_rank:
         return plan(s, cfg.n_heads, n_pages, page_size, cfg.latent_row_dim, cfg.kv_lora_rank)
-    if not cfg.kv_rows_flat or s * cfg.n_heads >= QUERY_TILE:
+    if not cfg.kv_rows_flat:
         return None
     return gqa_plan(s, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, n_pages, page_size)
 

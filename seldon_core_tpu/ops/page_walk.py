@@ -11,9 +11,10 @@ a sequence has live, straight from the pool, once, under a running softmax:
 
 - a VISIT is ``plan.pages`` consecutive entries of one sequence's block table
   (1,024 cached rows at the served 64-row page, 512 under a chunk's full
-  query tiles) against one tile of that sequence's query rows. A page is a
-  block of the pool as it lies, fetched by its table entry (the table and the
-  visit list are scalar-prefetch operands); no copy of the view exists.
+  query tiles, 128 where the products are a lane block's) against one tile of
+  that sequence's query rows. A page is a block of the pool as it lies,
+  fetched by its table entry (the table and the visit list are
+  scalar-prefetch operands); no copy of the view exists.
 - a sequence's LIVE pages are those up to its queries' largest valid
   position. Entries behind them are read as NULL_PAGE (whose positions are
   PAD_POS forever), so a page behind the live ones is never fetched. A
@@ -32,6 +33,23 @@ a sequence has live, straight from the pool, once, under a running softmax:
   ``plan.q_tile``) and the speculative verify are one kernel. Scores contract
   over the whole row of the FIRST pool; the output is ``sum_t p_t v_t`` over
   the first ``out_dim`` values of the LAST pool's rows, a query row.
+- a row of several LANE BLOCKS (``plan.blocks``: grouped-query attention's
+  chunk, a block a KV head) is the same walk with one product a block INSIDE a
+  visit: the visit's pages are fetched once, as whole rows, and each block's
+  lanes of them meet that block's own query heads alone, under a running
+  softmax a block. The blocks are never operands nor grid steps (a grid step a
+  block is ``blocks`` times the page fetches, each 256-byte pieces of a row,
+  and ~20 index maps for 0.7 us of products: 324 us a Mistral chunk's read at
+  3,584 live rows against 146, v5e, PR 41). A block's query heads lie side by
+  side in a token's row of the query operand as the projection made them, go
+  one under another into the product and come back the same way: ``[b, s, H x
+  W]`` in and out, no re-tiling copy around the call. The scores of such a
+  product lie ``[cached rows, query rows]``, so a query row's statistics are
+  lane-dense vectors and its maximum and sum run down the sublanes; and a
+  visit whose every cached row every query row of the tile admits (all but
+  those that hold the chunk's own rows) computes no predicate: the same sums,
+  a third of the softmax's VPU work less. The row-wide form is the one block
+  as wide as the row, its heads rows already, its kernel as it was.
 
 Numerics are the expression's: bf16 operands, float32 scores, softmax
 statistics and accumulator; nothing is approximated and no row the mask admits
@@ -58,6 +76,13 @@ from typing import NamedTuple, Optional
 VISIT_ROWS = 1024
 TILED_VISIT_ROWS = 512
 QUERY_TILE = 512
+# ... and where a visit multiplies a lane block a KV head by that head's query
+# rows alone (``Plan.blocks``): the products of a block are small beside its
+# softmax, so a visit takes all of a block's query rows it can (the rows are
+# fetched once a tile of them) and few cached rows (a chunk's last visit is the
+# one whose predicate is computed, and what it over-reads is multiplied too)
+BLOCK_VISIT_ROWS = 128
+BLOCK_QUERY_TILE = 2048
 # the pages of a visit are operands of their own: no more of them than this
 MAX_VISIT_PAGES = 32
 # ... and no more bytes of rows than this, all pools together (on-chip memory
@@ -69,10 +94,13 @@ LANES = 128
 
 class Plan(NamedTuple):
     """How one call shape is walked: ``pages`` block-table entries a visit,
-    ``q_tile`` query rows a visit. From the call's static shapes alone."""
+    ``q_tile`` query rows a visit, a cached row read as ``blocks`` lane blocks
+    (each walked with its own query heads). From the call's static shapes
+    alone."""
 
     pages: int
     q_tile: int
+    blocks: int = 1
 
     def groups(self, n_pages: int) -> int:
         """Visits that cover a whole block-table row."""
@@ -80,30 +108,40 @@ class Plan(NamedTuple):
 
 
 def plan(s: int, heads: int, n_pages: int, page_size: int, row_dim: int,
-         out_dim: int, pools: int = 1) -> Optional[Plan]:
+         out_dim: int, pools: int = 1, blocks: int = 1) -> Optional[Plan]:
     """The walk of a call with ``s`` query tokens of ``heads`` heads a sequence
     over ``n_pages`` table entries of ``page_size`` rows ``row_dim`` wide in
     each of ``pools`` pools, or None where the kernel does not take the shape
     (the caller keeps the expression): the row and the ``out_dim`` values of it
     that are summed are whole 128-lane tiles, a page is whole bf16 sublane
     tiles and a visit's rows whole lane tiles, and the query rows divide into
-    tiles."""
-    q_rows = s * heads
+    tiles. With ``blocks`` the row is that many lane blocks, each read by
+    ``heads // blocks`` query heads of its own and summed whole (``out_dim`` is
+    the row): the query rows of a product are one block's (whole bf16 sublane
+    tiles of tokens a head, up to ``BLOCK_QUERY_TILE``) and a visit is
+    ``BLOCK_VISIT_ROWS``."""
+    tiled = blocks > 1
+    if tiled and (row_dim % (blocks * LANES) or out_dim != row_dim or heads % blocks):
+        return None
+    q_rows = s * heads // blocks
     if row_dim % LANES or out_dim % LANES or not 0 < out_dim <= row_dim:
         return None
-    visit_rows = min(TILED_VISIT_ROWS if q_rows >= QUERY_TILE else VISIT_ROWS,
-                     VISIT_BYTES // (pools * row_dim * 2))
+    tile = BLOCK_QUERY_TILE if tiled else QUERY_TILE
+    visit_rows = BLOCK_VISIT_ROWS if tiled else TILED_VISIT_ROWS if q_rows >= tile else VISIT_ROWS
+    visit_rows = min(visit_rows, VISIT_BYTES // (pools * row_dim * 2))
     per_visit = visit_rows // page_size
     if page_size % 16 or not 1 <= per_visit <= MAX_VISIT_PAGES:
         return None
-    if q_rows % 16 or (q_rows > QUERY_TILE and q_rows % QUERY_TILE):
+    if q_rows % 16 or (q_rows > tile and q_rows % tile):
+        return None
+    if tiled and min(q_rows, tile) % (16 * heads // blocks):
         return None
     # whole lane tiles of rows (two 64-row pages make one), a short table too
     unit = LANES // math.gcd(LANES, page_size)
     if per_visit < unit:
         return None
     return Plan(pages=min(per_visit // unit * unit, -(-n_pages // unit) * unit),
-                q_tile=min(q_rows, QUERY_TILE))
+                q_tile=min(q_rows, tile), blocks=blocks)
 
 
 def live_pages(block_tables, positions, page_size: int):
@@ -169,13 +207,16 @@ def make_visits(block_tables, live, walk: Plan) -> Visits:
                   count=jnp.maximum(ends[-1], 1))
 
 
-def _kernel(walk: Plan, page_size: int, pools: int, out_dim: int, scale: float,
-            seq, group, last, live, table, q_ref, qpos_ref, pos_ref, *refs):
+def _kernel(walk: Plan, page_size: int, pools: int, fold: int, out_dim: int, scale: float,
+            groups: int, seq, group, last, live, table, *refs):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     del table   # the index maps' (which pages are in the page refs)
+    if walk.blocks > 1:
+        top, low, *refs = refs
+    q_ref, qpos_ref, pos_ref, *refs = refs
     n_pages = pools * walk.pages
     page_refs = refs[:n_pages]
     out_ref = refs[n_pages]
@@ -190,32 +231,104 @@ def _kernel(walk: Plan, page_size: int, pools: int, out_dim: int, scale: float,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(live[seq[v]] > group[v] * walk.pages)
-    def _visit():
-        # the visit's pages side by side, as the products read them
+    def fetched():   # the visit's pages side by side, as the products read them
         for i, page in enumerate(page_refs):
             j = i % walk.pages
             rows_refs[i // walk.pages][j * page_size:(j + 1) * page_size, :] = page[...]
-        keys = rows_refs[0][...]
+
+    def product(at, queries, keys, values, admitted, across: int):
+        """One product of the visit into the running softmax ``at`` (all of it,
+        or a block's): ``across`` is the axis of the scores the cached rows lie
+        along; ``admitted`` makes the predicate's answer, or is None where the
+        whole visit is admitted. Every operand is a function that reads it: each
+        is read where it is used."""
+        keys = keys()
+        a, b = (queries(), keys) if across else (keys, queries())
         scores = jax.lax.dot_general(
-            q_ref[...], keys, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        # the one predicate: causality, empty rows (PAD_POS), padding
-        admitted = pos_ref[...] <= qpos_ref[...]
-        scores = jnp.where(admitted, scores, lowest)
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(scores, axis=1, keepdims=True))
+            a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        if admitted is not None:
+            admitted = admitted()
+            scores = jnp.where(admitted, scores, lowest)
+        m_old = m_ref[at]
+        m_new = jnp.maximum(m_old, jnp.max(scores, axis=across, keepdims=True))
         shrink = jnp.exp(m_old - m_new)
-        p = jnp.where(admitted, jnp.exp(scores - m_new), 0.0)
-        l_ref[...] = shrink * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = shrink * acc_ref[...] + jnp.dot(
-            p.astype(keys.dtype), rows_refs[-1][:, :out_dim], preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        p = jnp.exp(scores - m_new)
+        if admitted is not None:
+            p = jnp.where(admitted, p, 0.0)
+        l_ref[at] = shrink * l_ref[at] + jnp.sum(p, axis=across, keepdims=True)
+        acc_ref[at] = shrink * acc_ref[at] + (
+            jnp.dot(p.astype(keys.dtype), values(), preferred_element_type=jnp.float32) if across
+            else jax.lax.dot_general(values(), p.astype(keys.dtype), (((0,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32))
+        m_ref[at] = m_new
+
+    def context(at):   # a row that admitted nothing comes out zero
+        total = l_ref[at]
+        return acc_ref[at] / jnp.where(total > 0.0, total, 1.0)
+
+    def predicate():   # the ONE: causality, empty rows (PAD_POS), padding
+        return pos_ref[...] <= qpos_ref[...]
+
+    here = live[seq[v]] > group[v] * walk.pages
+    if walk.blocks == 1:   # every query head a row as wide as the cached rows
+        @pl.when(here)
+        def _visit():
+            fetched()
+            product(..., lambda: q_ref[...], lambda: rows_refs[0][...],
+                    lambda: rows_refs[-1][:, :out_dim], predicate, 1)
+
+        @pl.when(last[v] == 1)
+        def _finish():
+            out_ref[...] = context(...).astype(out_ref.dtype)
+        return
+
+    # a row of lane blocks: one product a block, its ``fold`` query heads (side
+    # by side in a token's row of q_ref) one under another. The scores lie
+    # [cached rows, query rows]: a query row's maximum and sum run down the
+    # sublanes and its statistics are lane-dense [1, q_tile] vectors (held the
+    # other way every 8 query rows are a cross-lane reduce a statistic a visit,
+    # more than the products cost). The blocks are a loop whose body is traced
+    # ONCE and unrolled as it is lowered (one pass over eight blocks a fifth
+    # slower, v5e, PR 41): tracing a body a block is start-up time of every
+    # program that carries the kernel, which no compile cache serves.
+    width = rows_refs[0].shape[1] // walk.blocks
+    tokens = q_ref.shape[0]
+
+    def lanes(g, of: int):
+        return pl.ds(pl.multiple_of(g * of, of), of)
+
+    def visit(masked: bool):
+        fetched()
+        admitted = predicate() if masked else None
+
+        def block(g, carry):
+            product(g,
+                    lambda: jnp.concatenate(
+                        [q_ref[:, lanes(g * fold + i, width)] for i in range(fold)], axis=0),
+                    lambda: rows_refs[0][:, lanes(g, width)],
+                    lambda: rows_refs[-1][:, lanes(g, width)],   # (summed whole)
+                    (lambda: admitted) if masked else None, 0)
+            return carry
+
+        jax.lax.fori_loop(0, walk.blocks, block, 0, unroll=True)
+
+    # a visit whose every cached row every query row of the tile admits (its
+    # largest cached position no larger than the tile's smallest: all but a
+    # chunk's last visits) computes no predicate: the same sums, a third of the
+    # softmax's VPU work less
+    whole = top[seq[v] * groups + group[v]] <= low[seq[v] * pl.num_programs(0) + pl.program_id(0)]
+    pl.when(here & whole)(functools.partial(visit, False))
+    pl.when(here & jnp.logical_not(whole))(functools.partial(visit, True))
 
     @pl.when(last[v] == 1)
-    def _finish():   # a row that admitted nothing comes out zero
-        total = l_ref[...]
-        out_ref[...] = (acc_ref[...] / jnp.where(total > 0.0, total, 1.0)).astype(out_ref.dtype)
+    def _finish():
+        def block(g, carry):
+            laid = context(g).T.astype(out_ref.dtype)
+            for i in range(fold):
+                out_ref[:, lanes(g * fold + i, out_dim)] = laid[i * tokens:(i + 1) * tokens]
+            return carry
+
+        jax.lax.fori_loop(0, walk.blocks, block, 0)
 
 
 def page_walk_attention(q, pools, pos_pool, block_tables, positions, scale: float,
@@ -263,51 +376,78 @@ def _walk_pages(q, pools, pos_pool, bt, positions, *, scale, out_dim, walk, name
     b, s, heads, width = q.shape
     page_size = pos_pool.shape[1]
     n_pages = bt.shape[1]
+    row = walk.blocks * width
     groups, rows = walk.groups(n_pages), walk.pages * page_size
-    q_rows, tq = s * heads, walk.q_tile
-    assert all(pool.shape[1:] == (page_size, width) for pool in pools), (q.shape, walk)
-    assert q_rows % tq == 0, (q.shape, walk)
+    tiled = walk.blocks > 1
+    # the row-wide form's query rows are (token, head), each against the whole
+    # row; a lane block's are its tokens', its ``fold`` heads side by side in
+    # each (the row of the operand is a token's heads as the projection made them)
+    fold = heads // walk.blocks if tiled else 1
+    tq, tokens = walk.q_tile, walk.q_tile // fold
+    together = heads if tiled else 1    # heads a row of the query operand holds
+    q = q.reshape(b, s * heads // together, together * width)
+    q_tiles = q.shape[1] // tokens
+    assert all(pool.shape[1:] == (page_size, row) for pool in pools), (q.shape, walk)
+    assert q.shape[1] % tokens == 0 and tq == tokens * fold, (q.shape, walk)
+    assert not tiled or out_dim == width, (q.shape, out_dim, walk)
 
-    visits = make_visits(bt, live_pages(bt, positions, page_size), walk)
-    # the positions cached beside the rows the visits fetch: 256 B a page
-    pos_view = pos_pool[visits.table].reshape(b * groups, 1, rows)
-    qpos = jnp.repeat(positions.astype(jnp.int32), heads, axis=1)[..., None]
+    stats = (walk.blocks,) if tiled else ()   # a running softmax a block
 
     def of_sequence(t, v, seq, *_):
         return (seq[v], t, 0)
 
+    def shaped(down, along):   # a lane block's scores lie [cached rows, query rows]
+        return (along, down) if tiled else (down, along)
+
+    visits = make_visits(bt, live_pages(bt, positions, page_size), walk)
+    # the positions cached beside the rows the visits fetch: 256 B a page
+    pos_view = pos_pool[visits.table].reshape((b * groups,) + shaped(1, rows))
+    if tiled:   # a tile's query rows are head-major: its tokens' positions once a head
+        qpos = jnp.broadcast_to(positions.astype(jnp.int32).reshape(b, q_tiles, 1, tokens),
+                                (b, q_tiles, fold, tokens)).reshape(b * q_tiles, 1, tq)
+        qpos_spec = pl.BlockSpec((None, 1, tq), lambda t, v, seq, *_: (seq[v] * q_tiles + t, 0, 0))
+    else:
+        qpos = jnp.repeat(positions.astype(jnp.int32), heads, axis=1)[..., None]
+        qpos_spec = pl.BlockSpec((None, tq, 1), of_sequence)
+    # the largest position cached in a visit's rows, the smallest a tile's query
+    # rows hold: where the one is no larger than the other the predicate admits
+    # every pair of the visit
+    bounds = ()
+    if tiled:
+        bounds = (jnp.max(pos_view.reshape(b * groups, rows), axis=1),
+                  jnp.min(qpos.reshape(b * q_tiles, tq), axis=1))
+
     def page_spec(j):
         return pl.BlockSpec(
-            (None, page_size, width),
-            lambda t, v, seq, group, last, live, table:
+            (None, page_size, row),
+            lambda t, v, seq, group, last, live, table, *_:
                 (table[(seq[v] * groups + group[v]) * walk.pages + j], 0, 0))
 
     out = pl.pallas_call(
-        functools.partial(_kernel, walk, page_size, len(pools), out_dim, scale),
-        out_shape=jax.ShapeDtypeStruct((b, q_rows, out_dim), q.dtype),
+        functools.partial(_kernel, walk, page_size, len(pools), fold, out_dim, scale, groups),
+        out_shape=jax.ShapeDtypeStruct((b, q.shape[1], together * out_dim), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=5 + len(bounds),
             in_specs=[
-                pl.BlockSpec((None, tq, width), of_sequence),
-                pl.BlockSpec((None, tq, 1), of_sequence),
-                pl.BlockSpec((None, 1, rows),
+                pl.BlockSpec((None, tokens, q.shape[2]), of_sequence),
+                qpos_spec,
+                pl.BlockSpec((None,) + shaped(1, rows),
                              lambda t, v, seq, group, *_: (seq[v] * groups + group[v], 0, 0)),
                 *[page_spec(j) for _ in pools for j in range(walk.pages)]],
-            out_specs=pl.BlockSpec((None, tq, out_dim), of_sequence),
-            grid=(q_rows // tq, visits.count),
+            out_specs=pl.BlockSpec((None, tokens, together * out_dim), of_sequence),
+            grid=(q_tiles, visits.count),
             scratch_shapes=[
-                *[pltpu.VMEM((rows, width), pool.dtype) for pool in pools],  # the visit's rows
-                pltpu.VMEM((tq, 1), jnp.float32),            # running maximum
-                pltpu.VMEM((tq, 1), jnp.float32),            # running sum
-                pltpu.VMEM((tq, out_dim), jnp.float32)]),    # running products
+                *[pltpu.VMEM((rows, row), pool.dtype) for pool in pools],  # the visit's rows
+                pltpu.VMEM(stats + shaped(tq, 1), jnp.float32),            # running maximum
+                pltpu.VMEM(stats + shaped(tq, 1), jnp.float32),            # running sum
+                pltpu.VMEM(stats + shaped(tq, out_dim), jnp.float32)]),    # running products
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 << 20),
         interpret=interpret,
         name=name,
-    )(visits.seq, visits.group, visits.last, visits.live, visits.table,
-      q.reshape(b, q_rows, width), qpos, pos_view,
-      *[pool for pool in pools for _ in range(walk.pages)])
+    )(visits.seq, visits.group, visits.last, visits.live, visits.table, *bounds,
+      q, qpos, pos_view, *[pool for pool in pools for _ in range(walk.pages)])
     # a sequence no visit finished was never written
     out = jnp.where((visits.live > 0)[:, None, None], out, 0)
     return out.reshape(b, s, heads, out_dim)

@@ -28,24 +28,24 @@ class Pool:
     position cached beside it; ``allocated`` table entries are backed by pages
     (those behind the rows are reset: positions PAD_POS)."""
 
-    def __init__(self, rows, n_pages, width, allocated=None, seed=0):
+    def __init__(self, rows, n_pages, width, allocated=None, seed=0, page_size=PAGE):
         rng = np.random.default_rng(seed)
         b = len(rows)
         self.n = 2 + b * n_pages
-        self.k = np.asarray(rng.normal(size=(self.n, PAGE, width)), np.float32)
-        self.v = np.asarray(rng.normal(size=(self.n, PAGE, width)), np.float32)
-        self.pos = np.full((self.n, PAGE), PAD_POS, np.int32)
+        self.k = np.asarray(rng.normal(size=(self.n, page_size, width)), np.float32)
+        self.v = np.asarray(rng.normal(size=(self.n, page_size, width)), np.float32)
+        self.pos = np.full((self.n, page_size), PAD_POS, np.int32)
         self.tables = np.full((b, n_pages), NULL_PAGE, np.int32)
         free = iter(rng.permutation(np.arange(2, self.n)))
         for i, held in enumerate(rows):
             if held == NOBODY:
                 self.tables[i] = TRASH_PAGE
                 continue
-            backed = max(-(-held // PAGE), (allocated or [0] * b)[i])
+            backed = max(-(-held // page_size), (allocated or [0] * b)[i])
             for j in range(backed):
                 page = self.tables[i, j] = next(free)
-                n = int(np.clip(held - j * PAGE, 0, PAGE))
-                self.pos[page, :n] = j * PAGE + np.arange(n)
+                n = int(np.clip(held - j * page_size, 0, page_size))
+                self.pos[page, :n] = j * page_size + np.arange(n)
 
     def arrays(self):
         return ((jnp.asarray(self.k, jnp.bfloat16), jnp.asarray(self.v, jnp.bfloat16),
@@ -185,13 +185,106 @@ def test_rows_no_visit_wrote_are_zeroed_outside_the_kernel(monkeypatch, pools):
     np.testing.assert_array_equal(clean, dirty)
 
 
+# the served chunk shapes (PERF.md section 4): heads, KV heads, head_dim,
+# tokens a chunk, table entries of 64-row pages, the plan's walk
+CHUNKS = {
+    "mistral docs / rerank": (32, 8, 128, 256, 64, Plan(2, 1024, 8)),
+    "mistral chat, 128 tokens": (32, 8, 128, 128, 16, Plan(2, 512, 8)),
+    "mistral chat, 256 tokens": (32, 8, 128, 256, 16, Plan(2, 1024, 8)),
+    "olmoe: heads of their own": (16, 16, 128, 256, 16, Plan(2, 256, 16)),
+    "lfm2: two heads of 64 a block": (32, 8, 64, 256, 64, Plan(2, 2048, 4)),
+    "qwen3-next: heads of 256": (16, 2, 256, 256, 128, Plan(2, 2048, 2)),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNKS))
+def test_a_chunks_read_a_head_block_is_the_chain_over_the_gathered_view(monkeypatch, case):
+    """The prefill chunk's form (a lane block a KV head, one product a block
+    inside a visit of whole rows, its group's query heads the lane slices of a
+    token's row) at every served chunk shape, as a
+    prompt's LAST chunk behind an offset: its second half padding (PAD_POS),
+    beside a slot nobody holds. Held to the expression on every valid row;
+    then again with everything the walk must not touch poisoned, NaN on the
+    pages behind the live ones and on TRASH_PAGE, in K and in V, and the
+    output blocks no visit wrote left dirty as the chip may leave them (the
+    interpreter hands the kernel zeros): nothing changes, the padded rows stay
+    finite, the slot nobody holds comes out zero."""
+    from jax.experimental import pallas as pl
+
+    from seldon_core_tpu.ops import page_walk
+
+    heads, kvh, hd, s, n_pages, walk = CHUNKS[case]
+    assert gqa_plan(s, heads, kvh, hd, n_pages, 64) == walk
+    offset = min(3 * s, n_pages * 64 - s) + 7 if n_pages > 16 else s // 2
+    valid_rows = s // 2 + 5
+    held = offset + valid_rows
+    state = Pool([held, NOBODY], n_pages, kvh * hd, allocated=[n_pages, 0], page_size=64)
+    positions = np.full((2, s), PAD_POS, np.int32)
+    positions[0, :valid_rows] = offset + np.arange(valid_rows)
+    positions[1] = 0
+    positions = jnp.asarray(positions)
+    q = queries(2, s, heads, hd)
+
+    cache, tables = state.arrays()
+    want = np.asarray(paged_attention_ref(q, cache, tables, positions, kvh), np.float32)
+    clean = np.asarray(by_kernel(q, cache, tables, positions, kvh, walk), np.float32)
+    assert clean.shape == want.shape == (2, s, heads, hd)
+    np.testing.assert_allclose(clean[0, :valid_rows], want[0, :valid_rows], atol=2e-2, rtol=2e-2)
+    visits = make_visits(tables, live_pages(tables, positions, 64), walk)
+    assert int(visits.count) * 128 == rows_visited(held, 64, walk) == -(-held // 128) * 128
+
+    for page in state.tables[0, -(-held // 64):]:
+        state.k[page] = state.v[page] = np.nan
+    state.k[TRASH_PAGE] = state.v[TRASH_PAGE] = np.nan
+    real = pl.pallas_call
+
+    def leaving_unwritten_blocks_dirty(kernel, **kwargs):
+        call = real(kernel, **kwargs)
+
+        def run(seq, group, last, live, table, *operands):
+            out = call(seq, group, last, live, table, *operands)
+            return jnp.where((live > 0)[:, None, None], out, jnp.nan)
+
+        return run
+
+    monkeypatch.setattr(pl, "pallas_call", leaving_unwritten_blocks_dirty)
+    page_walk._jitted_walk.cache_clear()     # (a trace of its own, not the clean one's)
+    try:
+        dirty = np.asarray(by_kernel(q, *state.arrays(), positions, kvh, walk), np.float32)
+    finally:
+        page_walk._jitted_walk.cache_clear()
+    assert np.all(np.isfinite(dirty))
+    assert np.all(dirty[1] == 0.0)
+    np.testing.assert_array_equal(clean, dirty)
+
+
+def test_a_chunks_first_tile_of_a_long_prompt_and_a_whole_chunk():
+    """A chunk with every row valid at an offset deep in a 4,096-row view
+    (twenty-eight visits, the last two under the predicate and the others
+    without) and the prompt's FIRST chunk (two visits): the walk's rows follow
+    the live rows, not the view."""
+    heads, kvh, hd, s, n_pages, walk = CHUNKS["mistral docs / rerank"]
+    for offset in (0, 3328):
+        held = offset + s
+        cache, tables = Pool([held], n_pages, kvh * hd, page_size=64).arrays()
+        positions = jnp.asarray(offset + np.arange(s, dtype=np.int32))[None]
+        q = queries(1, s, heads, hd)
+        want = np.asarray(paged_attention_ref(q, cache, tables, positions, kvh), np.float32)
+        got = np.asarray(by_kernel(q, cache, tables, positions, kvh, walk), np.float32)
+        np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+        visits = make_visits(tables, live_pages(tables, positions, 64), walk)
+        assert int(visits.count) == -(-held // 128)
+
+
 def test_the_walk_at_the_served_shapes():
     """A step's or a verify's query rows are one tile over 1,024 K and V rows
     a visit (sixteen 64-row pages); rows wider than 2,048 values take as many
-    as 8 MB hold; plain multi-head attention (OLMoE) is the same walk. A
-    chunk's query rows, the int8 pool, a mesh (whose pool of heads of 128
-    keeps its head axis: ``TransformerConfig.kv_rows_flat``) and a row that is
-    no whole lane tile have no walk (``Attention`` keeps the expression)."""
+    as 8 MB hold; plain multi-head attention (OLMoE) is the same walk. From one
+    tile of query rows on (a chunk) the walk is a lane block a KV head, 128
+    rows a visit against up to 2,048 query rows of whole tokens a block. The int8
+    pool, a mesh (whose pool of heads of 128 keeps its head axis:
+    ``TransformerConfig.kv_rows_flat``) and a row that is no whole lane tile
+    have no walk (``Attention`` keeps the expression)."""
     from seldon_core_tpu.models.transformer import TransformerConfig, paged_read_walk
 
     bf16 = jnp.bfloat16
@@ -207,8 +300,14 @@ def test_the_walk_at_the_served_shapes():
     assert paged_read_walk(lfm2, 1, 64, 64, bf16) == Plan(16, 32)
     assert paged_read_walk(wide, 1, 64, 64, bf16) == Plan(8, 64)           # 4,096-wide rows
     assert paged_read_walk(mistral, 1, 5, 64, bf16) == Plan(6, 32)         # a short table
-    assert paged_read_walk(mistral, 256, 64, 64, bf16) is None             # a chunk
-    assert paged_read_walk(mistral, 16, 64, 64, bf16) is None              # 512 query rows
+    assert paged_read_walk(mistral, 256, 64, 64, bf16) == Plan(2, 1024, blocks=8)  # a chunk
+    assert paged_read_walk(mistral, 128, 16, 64, bf16) == Plan(2, 512, blocks=8)   # chat's other one
+    assert paged_read_walk(mistral, 16, 64, 64, bf16) == Plan(2, 64, blocks=8)     # 512 query rows
+    assert paged_read_walk(olmoe, 256, 16, 64, bf16) == Plan(2, 256, blocks=16)
+    assert paged_read_walk(lfm2, 256, 64, 64, bf16) == Plan(2, 2048, blocks=4)     # two heads a block
+    qwen = TransformerConfig(dim=2048, n_heads=16, n_kv_heads=2, head_dim=256, dtype=bf16)
+    assert paged_read_walk(qwen, 256, 128, 64, bf16) == Plan(2, 2048, blocks=2)
+    assert paged_read_walk(mistral, 24, 64, 64, bf16) is None              # 96 rows a block: no whole tiles
     assert paged_read_walk(mistral, 1, 64, 64, jnp.int8) is None           # the int8 pool
     on_mesh = [dataclasses.replace(cfg, mesh=object()) for cfg in (mistral, lfm2)]
     assert [cfg.kv_rows_flat for cfg in on_mesh] == [False, True]          # narrow heads stay flat
@@ -223,18 +322,23 @@ GQA_TOY = dict(vocab_size=96, dim=512, n_layers=2, n_heads=16, n_kv_heads=4, ffn
                max_seq_len=256, dtype="bfloat16")
 
 
-@pytest.mark.parametrize("more", [{}, dict(n_kv_heads=8), dict(qk_norm="head")],
-                         ids=["rep 4", "rep 2", "a norm per head"])
-def test_attention_through_the_kernel_is_attention_through_the_expression(monkeypatch, more):
+@pytest.mark.parametrize("more,chunk_walks", [
+    ({}, False), (dict(n_kv_heads=8), True), (dict(qk_norm="head"), False),
+    (dict(head_dim=128), True), (dict(head_dim=128, n_kv_heads=16, attn_gate=True), True)],
+    ids=["rep 4", "rep 2: four KV heads a block", "a norm per head", "heads of 128",
+         "heads of their own, gated"])
+def test_attention_through_the_kernel_is_attention_through_the_expression(monkeypatch, more,
+                                                                          chunk_walks):
     """``Attention`` picks by the lowering platform (the kernel for a TPU, the
     expression elsewhere). Here the TPU's branch is taken by hand, its kernel
-    under the interpreter, through a chunk of a prompt (whose query rows keep
-    the expression either way) and two decode steps of the paged pool, beside
-    a slot nobody holds: the same logits as the branch tier-1 otherwise runs,
-    and the same pool."""
+    under the interpreter, through a chunk of a prompt (a lane block a KV head
+    where its query rows divide into whole tiles; 768 rows against one block do
+    not, and keep the expression) and two decode steps of the paged pool,
+    beside a slot nobody holds: the same logits as the branch tier-1 otherwise
+    runs, and the same pool."""
     import seldon_core_tpu.ops.gqa_attention as module
     from seldon_core_tpu.models import get_model
-    from seldon_core_tpu.models.transformer import init_paged_kv_caches
+    from seldon_core_tpu.models.transformer import init_paged_kv_caches, paged_live_read
 
     model = get_model("transformer", **{**GQA_TOY, **more})
     cfg = model.cfg
@@ -265,22 +369,31 @@ def test_attention_through_the_kernel_is_attention_through_the_expression(monkey
 
     monkeypatch.setattr(module, "gqa_page_attention", interpreted)
     monkeypatch.setattr(jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
-    got, got_pools = serve()
-    assert calls == [(2, 1, 16, 32)] * 4   # two layers of two steps; the chunk has no walk
+    paged_live_read.clear_cache()     # (the read is a jitted function: a trace of its own)
+    try:
+        got, got_pools = serve()
+    finally:
+        paged_live_read.clear_cache()
+    # the chunk where it has a walk, the step: a trace a call shape (the layers
+    # and the steps share the jitted read's)
+    assert calls == [(2, 48, 16, cfg.head_dim)] * chunk_walks + [(2, 1, 16, cfg.head_dim)]
     np.testing.assert_allclose(got, want, atol=3e-2, rtol=3e-2)
     for got_layer, want_layer in zip(got_pools, want_pools):
         assert got_layer[0].shape == (8, 32, cfg.n_kv_heads * cfg.head_dim)
         np.testing.assert_array_equal(np.asarray(got_layer[2]), np.asarray(want_layer[2]))
+        # (the second layer's rows come through the first layer's read: a few bf16
+        # steps of a value of 2-4 where the chunk's sums were taken in another order)
         for rows, want_rows in zip(got_layer[:2], want_layer[:2]):
             np.testing.assert_allclose(np.asarray(rows[2:], np.float32),
-                                       np.asarray(want_rows[2:], np.float32), atol=3e-2, rtol=3e-2)
+                                       np.asarray(want_rows[2:], np.float32), atol=1e-1, rtol=3e-2)
 
 
 def test_the_loop_counts_whole_visits_over_live_rows_for_a_gqa_model(monkeypatch):
     """``seldon_llm_attn_rows_read_total`` for a model that runs ``Attention``:
     the whole block-table view of every sequence where the expression serves
-    (here on the CPU; a chunk; the int8 pool; a mesh), whole visits over the
-    live rows where the kernel does, by the ONE rule the module itself takes. A
+    (here on the CPU; the int8 pool; a mesh), whole visits over the live rows
+    where the kernel does (a step's of 1,024 rows, a chunk's of 128), by the
+    ONE rule the module itself takes. A
     model with conv layers asks it of its first PAGED layer."""
     from types import SimpleNamespace
 
@@ -306,6 +419,8 @@ def test_the_loop_counts_whole_visits_over_live_rows_for_a_gqa_model(monkeypatch
     # 30 slots nobody holds count nothing: their visit fetches nothing
     assert rows_read(loop(cfg), 1, [3000, 900], 32) == 3 * 1024 + 1024
     assert rows_read(loop(cfg), 1, [1024, 1025, 1], 32) == 1024 + 2048 + 1024
-    assert rows_read(loop(cfg), 256, [3000], 1) == view                  # a chunk: the view
+    assert rows_read(loop(cfg), 256, [3000], 1) == 24 * 128              # a chunk: 128-row visits
+    assert rows_read(loop(cfg), 256, [256], 1) == 256                    # a prompt's first chunk
+    assert rows_read(loop(cfg, jnp.int8), 256, [3000], 1) == view        # the int8 pool: the view
     assert rows_read(loop(cfg, jnp.int8), 1, [3000, 900], 32) == 32 * view
     assert rows_read(loop(dataclasses.replace(cfg, mesh=object())), 1, [3000], 32) == 32 * view

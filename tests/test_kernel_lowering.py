@@ -133,15 +133,19 @@ def test_latent_page_attention_lowers_for_tpu(slots, tokens, heads, pages):
 @pytest.mark.parametrize("slots,tokens,heads,kv_heads,head_dim,pages", [
     (32, 1, 32, 8, 128, 16), (8, 1, 32, 8, 128, 64), (32, 1, 16, 16, 128, 16),
     (32, 1, 32, 8, 64, 64), (8, 4, 32, 8, 128, 64), (8, 1, 32, 32, 128, 17),
-    (64, 1, 16, 2, 256, 128)])
+    (64, 1, 16, 2, 256, 128),
+    (1, 256, 32, 8, 128, 64), (1, 128, 32, 8, 128, 16), (1, 256, 32, 8, 128, 16),
+    (1, 256, 16, 16, 128, 16), (1, 256, 32, 8, 64, 64), (1, 256, 16, 2, 256, 128)])
 def test_gqa_page_attention_lowers_for_tpu(slots, tokens, heads, kv_heads, head_dim, pages):
     """The live-page read of grouped-query attention at the GQA cells' steps
     (Mistral 32 slots x 1,024 rows and 8 x 4,096; OLMoE 32 x 1,024, 16 heads of
     their own; LFM2 32 x 4,096, heads of 64), at a speculative verify of three
     drafts, at Llama-2-7B's 32 KV heads (chip_smoke.py: a 4,096-wide row,
     half as many rows a visit), and at Qwen3-Next's step (64 slots x 8,192
-    rows of 2 KV heads of 256, eight query heads a group): K and V rows as the
-    pools hold them."""
+    rows of 2 KV heads of 256, eight query heads a group), and at the prefill
+    chunks of the same four configurations (Mistral's three: 256 tokens into a
+    4,096-row slot, 128 and 256 into a 1,024-row one), a lane block a KV head:
+    K and V rows as the pools hold them."""
     walk = gqa_plan(tokens, heads, kv_heads, head_dim, pages, 64)
     row = kv_heads * head_dim
     assert walk.pages * 64 * row * 4 <= 8 << 20
@@ -159,7 +163,7 @@ def test_the_gqa_read_reaches_the_kernel_on_a_tpu_and_the_expression_elsewhere()
     """``Attention`` chooses as ``LatentAttention`` does, for the bf16 paged
     pool: one kernel a layer in a step lowered for a TPU, none in any other
     lowering, none over the int8 pool, the dense cache or without a cache, none
-    for a chunk's query rows, none for a row that is no whole lane tile."""
+    for a row that is no whole lane tile; a chunk's query rows take it too."""
     kwargs = dict(vocab_size=256, dim=256, n_layers=2, ffn_dim=128, max_seq_len=128,
                   dtype="bfloat16", n_heads=16, n_kv_heads=8)
 
@@ -182,12 +186,71 @@ def test_the_gqa_read_reaches_the_kernel_on_a_tpu_and_the_expression_elsewhere()
     # (the walk is a jitted function of its own: the layers call ONE lowering of it)
     cfg, on_tpu, elsewhere, no_cache = lowerings()
     assert on_tpu.count(MOSAIC_CALL) == 1
-    assert on_tpu.count("call @_walk_pages(") == cfg.n_layers
+    # (... and so is the read that chooses it: the layers call ONE lowering of both)
+    assert on_tpu.count("call @paged_live_read(") == cfg.n_layers
+    assert on_tpu.count("call @_walk_pages(") == 1
     assert MOSAIC_CALL not in elsewhere and MOSAIC_CALL not in no_cache
-    assert lowerings(tokens_a_call=4)[1].count("call @_walk_pages(") == cfg.n_layers   # a verify
-    assert MOSAIC_CALL not in lowerings(tokens_a_call=32)[1]                  # a chunk
+    assert lowerings(tokens_a_call=4)[1].count("call @paged_live_read(") == cfg.n_layers   # a verify
+    assert lowerings(tokens_a_call=32)[1].count("call @paged_live_read(") == cfg.n_layers  # a chunk
     assert MOSAIC_CALL not in lowerings(kv_cache_dtype="int8")[1]
     assert MOSAIC_CALL not in lowerings(n_kv_heads=4, dim=192, n_heads=16)[1]   # 4 x 12 = 48
+
+
+def test_a_chunk_program_traces_the_walk_once_and_a_page_operand_is_no_head(monkeypatch):
+    """What a chunk program costs to START is a count of traces: ``pallas_call``
+    traces the kernel and one index map a block operand wherever it is called,
+    and no compile cache serves that (PERF.md section 6, PRs 36 and 41: ~1-2 s
+    of every warm start a program on the chip's host). So a two-layer model's
+    chunk lowered for a TPU traces ``_walk_pages`` ONCE (the walk is a jitted
+    function of its own; its layers call one lowering of it), and the kernel's
+    page operands are ``plan.pages`` of the K pool and as many of the V pool,
+    whatever the number of KV heads: the head blocks are a loop inside a visit
+    of whole rows, its body traced once. A change that turns heads into
+    operands, or un-jits the walk, fails here and not on
+    ``mistral7b-chat-short``'s ``setup_s``."""
+    import functools
+    import re
+
+    from seldon_core_tpu.models.transformer import paged_read_walk
+    from seldon_core_tpu.ops import page_walk
+
+    model = get_model("transformer", vocab_size=256, dim=512, n_layers=2, ffn_dim=128,
+                      max_seq_len=1024, dtype="bfloat16", n_heads=16, n_kv_heads=4, head_dim=128)
+    cfg = model.cfg
+    chunk, n_pages, pool_pages = 64, 16, 34
+    walk = paged_read_walk(cfg, chunk, n_pages, 64, jnp.bfloat16)
+    assert walk == page_walk.Plan(pages=2, q_tile=256, blocks=4)
+    traces = []
+    real = page_walk._walk_pages
+
+    @functools.wraps(real)    # (the lowering is named after the function)
+    def counted(*args, **kwargs):
+        traces.append(kwargs["walk"])
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(page_walk, "_walk_pages", counted)
+    page_walk._jitted_walk.cache_clear()
+    try:
+        tokens = jnp.zeros((1, chunk), jnp.int32)
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+        pools = jax.eval_shape(lambda: init_paged_kv_caches(cfg, pool_pages, 64, "bf16"))
+        text = tpu_mlir(
+            lambda params, pools, tokens, positions, block_tables: model.apply(
+                params, tokens, positions=positions, caches=pools, block_tables=block_tables),
+            params, pools, S(tokens.shape, jnp.int32), S(tokens.shape, jnp.int32),
+            S((1, n_pages), jnp.int32))
+    finally:
+        page_walk._jitted_walk.cache_clear()
+    assert traces == [walk]
+    assert text.count("call @paged_live_read(") == cfg.n_layers and text.count("call @_walk_pages(") == 1
+    kernels = [line for line in text.splitlines() if MOSAIC_CALL in line and "custom_call" in line]
+    assert len(kernels) == 1
+    operands = re.search(r"\} : \((.*)\) -> ", kernels[0]).group(1)
+    pool = f"tensor<{pool_pages}x64x{cfg.n_kv_heads * cfg.head_dim}xbf16>"
+    assert operands.count(pool) == 2 * walk.pages
+    # and nothing else of the call grows with the visit: the grid's length, seven
+    # scalar-prefetch operands, the queries, their positions and the cached positions
+    assert operands.count("tensor<") == 2 * walk.pages + 11
 
 
 def test_the_latent_read_reaches_the_kernel_on_a_tpu_and_the_expression_elsewhere():

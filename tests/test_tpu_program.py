@@ -23,9 +23,10 @@ was converted and written out in bf16 to read 32 rows of it
 booked as "the head's copy" until the operands were read here); the table
 reaches the module int8 and the int8 rows are gathered (ops/quantize.py).
 
-Fourth (PRs 32, 36): the paged pool's attention read of a decode step walks each
-sequence's live pages with the repo's kernel (ops/page_walk.py), fed the pool as
-it is held; no step program holds a gathered copy of the logical view.
+Fourth (PRs 32, 36, 41): the paged pool's attention read of a decode step and of
+a prefill chunk walks each sequence's live pages with the repo's kernel
+(ops/page_walk.py), fed the pool as it is held; no step or chunk program holds a
+gathered copy of the logical view.
 """
 
 import re
@@ -140,7 +141,7 @@ def servers():
     return get
 
 
-def compiled(server, program: str, sharding, slots: int = 32, length: int = 0):
+def compiled(server, program: str, sharding, slots: int = 32, length: int = 0, chunk: int = 256):
     """The batcher's step program compiled for the described chip. By default
     the chat cell's step (32 slots x 1024 tokens) and the docs cell's chunk
     (256 tokens into a 4096-token slot) over a pool of POOL_PAGES; with
@@ -164,7 +165,6 @@ def compiled(server, program: str, sharding, slots: int = 32, length: int = 0):
             params, pools, sds((slots,), "int32"), sds((slots,), "int32"),
             sds((slots, 2), "uint32"), sds((), "float32"), sds((slots, pages), "int32"))
     else:
-        chunk = 256
         # a model with state layers is told which slot's state the chunk continues
         state_slot = (sds((1,), "int32"),) if server._cfg.state_layers else ()
         lowered = server._get_prefill_chunk(chunk, pages).lower(
@@ -516,21 +516,49 @@ def test_on_a_mesh_narrow_heads_keep_flat_rows_and_no_pool_is_copied():
         assert "attn.gqa.read" in hlo and "attn.gqa.write" in hlo
 
 
-def test_the_gqa_chunk_keeps_the_expression_over_its_view(v5e, servers):
-    """Mistral's prefill chunk (256 tokens x 32 heads: query rows past one tile)
-    reads the gathered view as it did: no kernel under ``attn.gqa.read``, the
-    view of the chunk's ONE sequence gathered from the flat pools
-    (``bf16[64,64,1024]``, once for K and once for V), the heads split out of
-    it, and no copy of a pool."""
+# the GQA configurations' chunk programs: the configuration here, tokens a
+# chunk, tokens a slot, the lane block a KV head (or two of LFM2's) and the
+# block's query heads; Mistral's chat server runs two (perf/configs)
+GQA_CHUNKS = {"mistral docs": ("mistral", 256, 4096, 128, 4), "mistral chat 128": ("mistral", 128, 1024, 128, 4),
+              "mistral chat 256": ("mistral", 256, 1024, 128, 4), "olmoe chat": ("olmoe", 256, 1024, 128, 1),
+              "lfm2 rag": ("lfm2", 256, 4096, 128, 8), "qwen3next longctx": ("qwen3next", 256, 8192, 256, 8)}
+
+
+@pytest.mark.parametrize("cell", list(GQA_CHUNKS))
+def test_the_gqa_chunk_walks_the_live_pages_a_head_block_and_holds_no_view(v5e, servers, cell):
+    """A prefill chunk's read under ``attn.gqa.read`` (256 x H query rows: past
+    one tile) is ONE Mosaic kernel of the repo's a GQA layer, fed the K and the
+    V pool as they are held, two pages of each a visit whatever the number of
+    KV heads (the head blocks are a loop inside the visit, not operands), the queries
+    and the context ``[1, s, H x block]`` as the projections hold them; the
+    program holds no gathered view of the chunk's sequence (``bf16[64,64,1024]``
+    twice a layer before PR 41), no array with the heads split out of one, and
+    no copy of a pool."""
     from seldon_core_tpu.ops.gqa_attention import KERNEL_NAME
 
-    hlo = compiled(servers("mistral"), "prefill_chunk", v5e, slots=8, length=4096).as_text()
-    assert not re.search(rf"^\s*%{KERNEL_NAME}[\w.]* = ", hlo, re.M)
-    assert 'custom_call_target="tpu_custom_call"' not in hlo
-    views = [op for op in own_ops(hlo) if op[1] == "bf16" and op[2] == (64, PAGE, 1024)]
-    assert len(views) == 2, views
-    assert weight_copies(hlo, {(8 * 64 + 2, PAGE, 1024), (8 * 64 + 2, PAGE, 8, 128)}) == []
-    assert "attn.gqa.read" in hlo and "attn.gqa.write" in hlo
+    config, chunk, length, block, slices = GQA_CHUNKS[cell]
+    server = servers(config)
+    cfg = server._cfg
+    pages = length // PAGE
+    layers = cfg.n_layers - len(cfg.state_layers)
+    hlo = compiled(server, "prefill_chunk", v5e, slots=2, length=length, chunk=chunk).as_text()
+    calls = [line for line in hlo.splitlines() if re.match(rf"\s*%{KERNEL_NAME}[\w.]* = ", line)]
+    assert len(calls) == layers
+    assert all('custom_call_target="tpu_custom_call"' in line for line in calls)
+    assert all("attn.gqa.read" in line for line in calls)
+    row = cfg.n_kv_heads * cfg.head_dim
+    assert cfg.n_heads * block == slices * row
+    assert all(f"= bf16[1,{chunk},{slices * row}]" in line for line in calls), calls[0][:300]
+    pool_pages = 2 * pages + 2
+    pool = f"bf16[{pool_pages},{PAGE},{row}]"
+    assert all(line.count(pool) == 2 * 2 for line in calls), calls[0][:400]
+    views = [(pages, PAGE), (1, pages, PAGE), (1, pages * PAGE)]
+    rows = [(row,), (cfg.n_kv_heads, cfg.head_dim)]
+    for view in (lead + tail for lead in views for tail in rows):
+        text = ",".join(str(n) for n in view)
+        assert f"bf16[{text}]" not in hlo and f"f32[{text}]" not in hlo, view
+    assert weight_copies(hlo, {(pool_pages, PAGE) + tail for tail in rows}) == []
+    assert "attn.gqa.write" in hlo
 
 
 def test_the_parser_sees_a_dequantized_table():
