@@ -429,6 +429,19 @@ class MetricsRegistry:
                  "Cached rows those calls' attention read visited: the whole "
                  "block-table view, or whole visits of latent attention's "
                  "live-page kernel; over context_tokens it is the over-read"))}
+        # how the prefill chunks' K / V (latent) rows reached the paged pool
+        # (models/transformer.py paged_write_by_page), counted on the loop
+        self._kv_writes = {
+            key: Counter(f"seldon_llm_kv_{key}_total", text,
+                         base + ["path"], registry=self.registry)
+            for key, text in (
+                ("chunk_writes",
+                 "Prefill chunks by how a paged layer's write reached the "
+                 "pool: path=page whole pages, path=token one scatter row a token"),
+                ("pages_written",
+                 "Pool pages a paged layer's write of those chunks wrote: "
+                 "the whole pages read and written back (page), the pages "
+                 "the live rows lie in (token)"))}
         # A model with conv layers (models/transformer.py ShortConv): what
         # went through them, counted on the loop from host integers; absent
         # for every other model
@@ -1079,6 +1092,9 @@ class MetricsRegistry:
         for key, counter in self._attn_context.items():
             for program, n in stats.get(f"attn_{key}", {}).items():
                 self._counter_catch_up(counter, n, program=program)
+        for key, counter in self._kv_writes.items():
+            for path, n in stats.get(f"kv_{key}", {}).items():
+                self._counter_catch_up(counter, n, path=path)
         for key, counter in self._state_layers.items():
             for program, n in stats.get(key, {}).items():
                 self._counter_catch_up(counter, n, program=program)

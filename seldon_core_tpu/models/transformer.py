@@ -487,6 +487,72 @@ def paged_write_targets(block_tables: jnp.ndarray, positions: jnp.ndarray,
     return entry, p % page_size
 
 
+def paged_write_by_page(cache, b: int, s: int) -> bool:
+    """Whether a call's rows land in the paged pool as whole pages
+    (``paged_write_pages``) or one scatter row a token, from what the call
+    shows alone: a pool of flat rows [pages, page_size, width] beside its
+    positions (the bf16 K / V 3-tuple, the latent 2-tuple) and ONE sequence's
+    run of at least a page — the batcher's prefill chunk.
+    The decode step (a token a slot), the speculative verify (a few tokens a
+    slot), the int8 5-tuple pool and a pool with its head axes split out
+    (``[.., kvh, hd]``: a mesh) keep the token scatter. ``Attention``,
+    ``LatentAttention`` and the loop's ``seldon_llm_kv_pages_written_total``
+    read this one rule."""
+    return len(cache) in (2, 3) and cache[0].ndim == 3 and b == 1 and s >= cache[0].shape[1]
+
+
+def pages_a_run_writes(s: int, page_size: int) -> int:
+    """Whole pages ``paged_write_pages`` reads and writes back for a run of
+    ``s`` rows: those a run that starts anywhere in a page can reach."""
+    return -(-s // page_size) + 1
+
+
+@jax.jit
+def paged_write_pages(pools, pos_pool: jnp.ndarray, block_tables: jnp.ndarray,
+                      positions: jnp.ndarray, rows):
+    """Write ONE sequence's run of rows into pools of flat rows
+    [pages, page_size, width] (``pools``, one of ``rows`` [s, width] each) and
+    its positions into ``pos_pool`` [pages, page_size], a page at a time.
+
+    ``positions`` [1, s] is what a prefill chunk carries: column ``j`` holds
+    ``positions[0, 0] + j`` or PAD_POS (padding), so the run lies in the
+    ``s // page_size + 1`` (rounded up) consecutive pages of the sequence from
+    the one that holds its start, wherever in that page it starts (a
+    copy-on-write prefix hit starts mid-page). Those pages are read, the live
+    rows laid into them at their offsets, and written back whole: a row that is
+    padding or lies outside the run keeps its old value and position, so every
+    page the sequence holds is bit for bit what the token scatter
+    (``paged_write_targets``) leaves. The pages' pool entries come from that
+    same function: a page past the table or one whose entry is NULL_PAGE lands
+    on TRASH_PAGE, the only page that may differ. Returns (pools, pos_pool).
+    A jitted function of its own, so a program's layers share ONE trace of it
+    (a trace a layer was +0.5 s of every chunk program's start on the chip's
+    host, PR 42); XLA inlines the call, and the donated pools are still
+    updated in place."""
+    ps = pos_pool.shape[1]
+    n = pages_a_run_writes(positions.shape[1], ps)
+    p = positions[0].astype(jnp.int32)
+    first, off = p[0] // ps, p[0] % ps
+    entry = paged_write_targets(block_tables[:1], ((first + jnp.arange(n)) * ps)[None], ps)[0][0]
+    # the run's positions at their rows of the n pages; PAD_POS = keep the old row
+    laid_pos = jax.lax.dynamic_update_slice(jnp.full((n * ps,), PAD_POS, jnp.int32), p, (off,))
+    live = laid_pos < PAD_POS
+
+    def read(pool):   # the n pages as one run of rows
+        return pool[entry].reshape((n * ps,) + pool.shape[2:])
+
+    def write(pool, run):
+        return pool.at[entry].set(run.reshape((n,) + pool.shape[1:]))
+
+    written = []
+    for pool, new in zip(pools, rows):
+        old = read(pool)
+        laid = jax.lax.dynamic_update_slice(old, new, (off, 0))
+        written.append(write(pool, jnp.where(live[:, None], laid, old)))
+    old_pos = read(pos_pool)
+    return tuple(written), write(pos_pool, jnp.where(live, laid_pos.astype(old_pos.dtype), old_pos))
+
+
 def gather_paged_view(cache, block_tables: jnp.ndarray, dtype, n_kv_heads: int):
     """Gather a paged pool back into the per-sequence logical view:
     (k_all, v_all, pos_view) of [b, n_pages*page_size, kvh, hd] / [b, L].
@@ -709,6 +775,14 @@ class Attention(nn.Module):
                     pos_pool = pos_pool.at[entry, off].set(
                         positions.astype(pos_pool.dtype))
                     new_cache = (kq_pool, ks_pool, vq_pool, vs_pool, pos_pool)
+                elif paged_write_by_page(cache, b, s):
+                    # a chunk: whole pages of flat rows
+                    k_pool, v_pool, pos_pool = cache
+                    (k_pool, v_pool), pos_pool = paged_write_pages(
+                        (k_pool, v_pool), pos_pool, bt, positions,
+                        (k.astype(k_pool.dtype).reshape(s, -1),
+                         v.astype(v_pool.dtype).reshape(s, -1)))
+                    new_cache = (k_pool, v_pool, pos_pool)
                 else:
                     # a token's heads as the pool holds them: ONE row, or [kvh, hd]
                     k_pool, v_pool, pos_pool = cache
@@ -1030,8 +1104,12 @@ class LatentAttention(nn.Module):
                 wpos = positions.astype(pos_pool.dtype)
                 if block_tables is not None:
                     bt = jnp.asarray(block_tables, jnp.int32)
-                    at = paged_write_targets(bt, positions, pool.shape[1])
-                    pool, pos_pool = pool.at[at].set(row), pos_pool.at[at].set(wpos)
+                    if paged_write_by_page(cache, b, s):   # a chunk: whole pages
+                        (pool,), pos_pool = paged_write_pages(
+                            (pool,), pos_pool, bt, positions, (row[0],))
+                    else:
+                        at = paged_write_targets(bt, positions, pool.shape[1])
+                        pool, pos_pool = pool.at[at].set(row), pos_pool.at[at].set(wpos)
                 else:
                     idx = jnp.asarray(cache_index, dtype=jnp.int32)
                     if idx.ndim == 0:   # one offset for the batch: prefill

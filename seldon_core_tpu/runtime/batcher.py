@@ -154,7 +154,7 @@ import functools
 import logging
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -549,6 +549,12 @@ MOE_PROGRAMS = ("decode", "chunk")   # the step programs the loop counts by
 # the counters' names for the kinds of state layer (models/transformer.py
 # STATE_LAYER_KINDS): seldon_llm_<name>_rows_total / _layer_calls_total
 STATE_COUNTERS = {"conv": "conv", "gdn": "linear_attention"}
+KV_WRITE_PATHS = ("page", "token")   # how a chunk's rows reach the paged pool
+
+
+def _first_paged_pool(cfg, caches):
+    """The first PAGED layer's pool (a state layer's entry has no pages)."""
+    return caches[next(i for i in range(cfg.n_layers) if i not in cfg.state_layers)]
 
 LOOP_PHASES = ("admit", "handoff", "dispatch", "prefill", "first_token_wait",
                "first_token", "drain_wait", "emit", "idle", "hop")
@@ -686,6 +692,10 @@ class LoopPhases:
         # view, or whole visits of the live-page kernel): visited / context
         # is the over-read
         self.attn_rows_read = dict.fromkeys(MOE_PROGRAMS, 0)
+        # how the chunks' rows reached the paged pool (whole pages, or one
+        # scatter row a token) and the pool pages a layer's write wrote
+        self.kv_chunk_writes = dict.fromkeys(KV_WRITE_PATHS, 0)
+        self.kv_pages_written = dict.fromkeys(KV_WRITE_PATHS, 0)
         # live rows through the state layers of each kind (a model with
         # layer_types: "conv" the short convolutions, "gdn" the linear-attention
         # layers), and such layers x calls, from host integers at dispatch
@@ -786,12 +796,18 @@ class LoopPhases:
                 "first_token_reads": dict(self.first_token_reads),
                 "attn_calls": dict(self.attn_calls),
                 "attn_context_tokens": dict(self.attn_context_tokens),
-                "attn_rows_read": dict(self.attn_rows_read)}
+                "attn_rows_read": dict(self.attn_rows_read),
+                "kv_chunk_writes": dict(self.kv_chunk_writes),
+                "kv_pages_written": dict(self.kv_pages_written)}
 
     def count_attention(self, program: str, context_tokens: int, rows_read: int) -> None:
         self.attn_calls[program] += 1
         self.attn_context_tokens[program] += context_tokens
         self.attn_rows_read[program] += rows_read
+
+    def count_chunk_write(self, path: str, pages: int) -> None:
+        self.kv_chunk_writes[path] += 1
+        self.kv_pages_written[path] += pages
 
     def count_state_layers(self, program: str, live_rows: int, calls: int,
                            layers: Dict[str, int]) -> None:
@@ -2504,6 +2520,7 @@ class ContinuousBatcher:
                     self.server._params, self._caches, job.bt_row, toks, pos, *extra)
         job.next = start + n
         self._phases.count_attention("chunk", start + n, self._rows_read(C, [start + n], 1))
+        self._phases.count_chunk_write(*self._chunk_write(C, start, n))
         if self._state_layers:
             self._phases.count_state_layers("chunk", n, 1, self._state_layers)
         event = None
@@ -2518,6 +2535,18 @@ class ContinuousBatcher:
             with self._phases.part("activate"):
                 self._activate(job, logits, n - 1)
 
+    def _chunk_write(self, s: int, start: int, n: int) -> Tuple[str, int]:
+        """(path, pages) of a chunk of ``s`` rows, ``n`` of them live from
+        position ``start``: how a paged layer's write reaches the pool, by the
+        ONE rule the modules take (models/transformer.py
+        ``paged_write_by_page``), and the pool pages it writes: the whole
+        pages it reads and writes back, or the pages its live rows lie in."""
+        from seldon_core_tpu.models.transformer import paged_write_by_page, pages_a_run_writes
+
+        if paged_write_by_page(_first_paged_pool(self.server._cfg, self._caches), 1, s):
+            return "page", pages_a_run_writes(s, self.page_size)
+        return "token", (start + n - 1) // self.page_size - start // self.page_size + 1
+
     def _read_walk(self, s: int):
         """How the attention read's kernel walks the live pages for calls of
         ``s`` query tokens a sequence (ops/page_walk.py ``Plan``), or None
@@ -2530,11 +2559,9 @@ class ContinuousBatcher:
 
             from seldon_core_tpu.models.transformer import paged_read_walk
 
-            cfg = self.server._cfg
-            # the first PAGED layer's pool (a state layer's entry has no pages)
-            paged = next(i for i in range(cfg.n_layers) if i not in cfg.state_layers)
             self._read_walks[s] = None if jax.default_backend() != "tpu" else paged_read_walk(
-                cfg, s, self.n_pages, self.page_size, self._caches[paged][0].dtype)
+                self.server._cfg, s, self.n_pages, self.page_size,
+                _first_paged_pool(self.server._cfg, self._caches)[0].dtype)
         return self._read_walks[s]
 
     def _rows_read(self, s: int, live_rows: Sequence[int], sequences: int) -> int:

@@ -302,7 +302,8 @@ def _moe_contracts():
 
 
 @pytest.mark.parametrize("name", ["llm.moe_paged_decode_step_s4",
-                                  "llm.moe_prefill_chunk_c8"])
+                                  "llm.moe_prefill_chunk_c8",
+                                  "llm.moe_prefill_chunk_c16"])
 def test_moe_step_programs_hold_no_dense_form_and_no_float_stack(name):
     """The paged decode step and chunk of a small OLMoE shape with int8
     weights, lowered for a TPU: no result shaped [rows, n_experts,

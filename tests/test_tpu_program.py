@@ -27,6 +27,12 @@ Fourth (PRs 32, 36, 41): the paged pool's attention read of a decode step and of
 a prefill chunk walks each sequence's live pages with the repo's kernel
 (ops/page_walk.py), fed the pool as it is held; no step or chunk program holds a
 gathered copy of the logical view.
+
+Fifth (PR 42): a prefill chunk's K / V (latent) rows and their positions reach
+the pool as whole pages, a handful of page-sized windows a scatter, in place;
+the scatter fed one index pair a token (``fusion bf16[514,64,1024]`` +
+``fusion s32[514,64]``: 11.8 % of a rerank chunk in PR 41's traces) is in no
+chunk program, and the step's one-row writes are what they were.
 """
 
 import re
@@ -146,6 +152,17 @@ def compiled(server, program: str, sharding, slots: int = 32, length: int = 0, c
     the chat cell's step (32 slots x 1024 tokens) and the docs cell's chunk
     (256 tokens into a 4096-token slot) over a pool of POOL_PAGES; with
     ``length``, ``slots`` slots of that many tokens over a fully provisioned pool."""
+    # (several tests read one program: compiled once a module)
+    key = (id(server), program, slots, length, chunk)
+    if key not in _COMPILED:
+        _COMPILED[key] = _compile(server, program, sharding, slots, length, chunk)
+    return _COMPILED[key]
+
+
+_COMPILED = {}
+
+
+def _compile(server, program, sharding, slots, length, chunk):
     from seldon_core_tpu.models.transformer import init_paged_kv_caches
 
     def sds(shape, dtype):
@@ -559,6 +576,84 @@ def test_the_gqa_chunk_walks_the_live_pages_a_head_block_and_holds_no_view(v5e, 
         assert f"bf16[{text}]" not in hlo and f"f32[{text}]" not in hlo, view
     assert weight_copies(hlo, {(pool_pages, PAGE) + tail for tail in rows}) == []
     assert "attn.gqa.write" in hlo
+
+
+# the latent cells' chunk programs beside the GQA ones: the scope of the write,
+# and the pool leaves a paged layer writes (K, V, positions / rows, positions)
+CHUNK_WRITES = {**{cell: ("attn.gqa.write", 3) for cell in GQA_CHUNKS},
+                **{cell: ("attn.latent.write", 2) for cell in LATENT_CELLS}}
+
+
+def pool_scatters(hlo: str, scope: str, pool_pages: int) -> list:
+    """(result shape, [operand shapes]) of the scatters traced under ``scope``
+    whose result is an array of ``pool_pages`` pages of PAGE rows."""
+    ops = own_ops(hlo)
+    shape_of = {instr: shape for instr, _, shape, _, _, _ in ops}
+    found = []
+    for _, _, shape, opcode, rest, _ in ops:
+        if opcode == "scatter" and shape[:2] == (pool_pages, PAGE) and scope in rest:
+            operands = re.findall(r"%([\w.\-]+)", rest.split(")", 1)[0])
+            found.append((shape, [shape_of[name] for name in operands if name in shape_of]))
+    return found
+
+
+@pytest.mark.parametrize("cell", list(CHUNK_WRITES))
+def test_the_chunk_writes_whole_pages_in_place(v5e, servers, cell):
+    """Under ``attn.gqa.write`` / ``attn.latent.write`` a chunk program's every
+    pool leaf (K, V / the latent rows, and the positions) is written by ONE
+    scatter a layer of ``chunk / 64 + 1`` page-sized windows
+    (models/transformer.py ``paged_write_pages``): no operand of it has an entry
+    a token (the 256 ``(page, offset)`` pairs that fed ``fusion
+    bf16[514,64,1024]`` / ``[2050,64,640]`` before PR 42). The K / V (latent)
+    pools are donated, aliased and held as they arrive: no copy of one. (The
+    positions' ``s32[pages, 64]`` lives pages-minor on the device; the program
+    turns it rows-minor for the read's kernel as the parent did, and back.) The
+    scopes keep their names: the benchmark's ``mla_chunk_attn_*`` readers find
+    their ops by them."""
+    from seldon_core_tpu.models.transformer import pages_a_run_writes
+
+    scope, leaves = CHUNK_WRITES[cell]
+    if cell in GQA_CHUNKS:
+        config, chunk, length = GQA_CHUNKS[cell][:3]
+        slots = 2
+    else:
+        config, chunk, (slots, length) = cell, 256, LATENT_CELLS[cell]
+    server = servers(config)
+    cfg = server._cfg
+    row = cfg.latent_row_dim or cfg.n_kv_heads * cfg.head_dim
+    layers = cfg.n_layers - len(cfg.state_layers)
+    pool_pages = slots * (length // PAGE) + 2
+    exe = compiled(server, "prefill_chunk", v5e, slots=slots, length=length, chunk=chunk)
+    hlo = exe.as_text()
+    writes = pool_scatters(hlo, scope, pool_pages)
+    assert sorted(shape for shape, _ in writes) == sorted(
+        [(pool_pages, PAGE, row)] * (leaves - 1) * layers + [(pool_pages, PAGE)] * layers)
+    windows = pages_a_run_writes(chunk, PAGE)
+    for shape, operands in writes:
+        assert not any(chunk in operand for operand in operands), (shape, operands)
+        assert any(operand[:1] == (windows,) for operand in operands), (shape, operands)
+    assert weight_copies(hlo, {(pool_pages, PAGE, row)}) == []
+    memory = exe.memory_analysis()
+    pool_bytes = pool_pages * PAGE * row * 2
+    assert memory.alias_size_in_bytes >= (leaves - 1) * layers * pool_bytes
+    assert scope in hlo and scope.replace("write", "read") in hlo
+
+
+@pytest.mark.parametrize("cell", ["mistral chat", "deepseek"])
+def test_the_steps_write_is_one_row_a_slot_as_it_was(v5e, servers, cell):
+    """The decode step (a token a slot) keeps the token scatter: every pool
+    leaf's write takes ``slots`` index pairs, under the same scope."""
+    if cell in GQA_CELLS:
+        (config, slots, length, _), scope, leaves = GQA_CELLS[cell], "attn.gqa.write", 3
+    else:
+        config, (slots, length), scope, leaves = cell, LATENT_CELLS[cell], "attn.latent.write", 2
+    server = servers(config)
+    cfg = server._cfg
+    hlo = compiled(server, "decode_step", v5e, slots=slots, length=length).as_text()
+    writes = pool_scatters(hlo, scope, slots * (length // PAGE) + 2)
+    assert len(writes) == leaves * (cfg.n_layers - len(cfg.state_layers))
+    for shape, operands in writes:
+        assert any(operand[:1] == (slots,) for operand in operands), (shape, operands)
 
 
 def test_the_parser_sees_a_dequantized_table():

@@ -548,12 +548,17 @@ def _build_moe_paged_decode_step():
                 _sds((SLOTS, PAGES_PER_SLOT), "int32"))
 
 
-def _build_moe_prefill_chunk():
+def _build_moe_prefill_chunk(chunk: int = PAGE_SIZE):
     s = _moe_server()
-    fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
+    fn = s._get_prefill_chunk(chunk, PAGES_PER_SLOT)
     return fn, (s._params, _moe_pool_specs(),
                 _sds((1, PAGES_PER_SLOT), "int32"),
-                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"))
+                _sds((1, chunk), "int32"), _sds((1, chunk), "int32"))
+
+
+def _build_moe_prefill_chunk_pages():
+    """A chunk of whole pages (two of 8 rows): ``paged_write_pages``."""
+    return _build_moe_prefill_chunk(2 * PAGE_SIZE)
 
 
 def _build_mla_paged_decode_step():
@@ -981,6 +986,20 @@ def all_contracts() -> List[Contract]:
             cost=True,
         ),
         Contract(
+            name="llm.moe_prefill_chunk_c16",
+            description="the same model's chunk of WHOLE PAGES (two pages "
+                        "of 8): K, V and the positions reach the bf16 pool "
+                        "as three page-sized windows a leaf "
+                        "(paged_write_pages); the scatter must update the "
+                        "donated pool in place",
+            build=_build_moe_prefill_chunk_pages,
+            donated=(1,),
+            forbid_dtypes=(MOE_DENSE_FORM, MOE_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
             name="llm.mla_paged_decode_step_s4",
             description="PAGED decode step of a latent-attention MoE "
                         "(DeepSeek-V2's block: one cached row a token for "
@@ -997,7 +1016,9 @@ def all_contracts() -> List[Contract]:
             name="llm.mla_prefill_chunk_c8",
             description="chunked admission prefill of the same model: the "
                         "chunk's rows read the latent view absorbed, and "
-                        "the scatter updates the latent pool in place",
+                        "the scatter (one page-sized window a page the "
+                        "chunk can reach: a chunk of 8 is a page here) "
+                        "updates the latent pool in place",
             build=_build_mla_prefill_chunk,
             donated=(1,),
             forbid_dtypes=(MLA_EXPANDED_KV, MOE_DENSE_FORM, MOE_FLOAT_STACK),
@@ -1102,8 +1123,9 @@ def all_contracts() -> List[Contract]:
         Contract(
             name="llm.prefill_chunk_c8",
             description="chunked admission prefill (chunk=8 tokens into "
-                        "the paged pool through a block-table row): the "
-                        "scatter must update the pool in place",
+                        "the paged int8 pool through a block-table row: "
+                        "the five-leaf pool keeps one scatter row a "
+                        "token): the scatter must update the pool in place",
             build=_build_prefill_chunk,
             donated=(1,),
             forbid_dtypes=((_f32_pool_sig(), F32_CACHE_WHY),),
