@@ -26,7 +26,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from seldon_core_tpu.models.transformer import NULL_PAGE, PAD_POS, TRASH_PAGE  # noqa: E402
+from seldon_core_tpu.models.cache import NULL_PAGE, PAD_POS, TRASH_PAGE  # noqa: E402
 from seldon_core_tpu.ops import latent_attention as la  # noqa: E402
 from seldon_core_tpu.ops import page_walk  # noqa: E402
 

@@ -157,7 +157,7 @@ def main() -> None:
     # per-token KV bytes alongside tok/s (ISSUE 2 satellite): bytes/step of
     # KV read = batch * cache_len * bytes_per_token, the term DECODE_NOTES
     # round 5 measured growing 2.71x from b1 to b8
-    from seldon_core_tpu.models.transformer import kv_cache_bytes_per_token
+    from seldon_core_tpu.models.cache import kv_cache_bytes_per_token
 
     kv_per_tok = kv_cache_bytes_per_token(server._cfg, server.kv_cache_dtype)
     report["kv_cache"] = {
@@ -255,7 +255,7 @@ def _paged_arm(server, report, rng, vocab, plen, max_new, on_tpu) -> None:
     """
     import asyncio
 
-    from seldon_core_tpu.models.transformer import kv_cache_bytes_per_token
+    from seldon_core_tpu.models.cache import kv_cache_bytes_per_token
     from seldon_core_tpu.runtime.batcher import ContinuousBatcher
 
     page_size = int(os.environ.get("KV_PAGE_SIZE", "0")) or (64 if on_tpu else 8)
@@ -801,7 +801,7 @@ def _prefix_multi_turn(server, report, rng, vocab, plen, max_new) -> None:
     import jax
     import jax.numpy as jnp
 
-    from seldon_core_tpu.models.transformer import PAD_POS
+    from seldon_core_tpu.models.cache import PAD_POS
 
     def med_call(fn, *a, repeats=15):
         fn(*a)  # warm
@@ -875,7 +875,7 @@ def _prefix_long_system(server, report, rng, vocab, on_tpu) -> None:
     import jax
     import jax.numpy as jnp
 
-    from seldon_core_tpu.models.transformer import PAD_POS
+    from seldon_core_tpu.models.cache import PAD_POS
     from seldon_core_tpu.utils import bucket as _bucket_fn
 
     # the long-prefix shape: past the top len_bucket on purpose (that is

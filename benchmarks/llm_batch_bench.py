@@ -414,7 +414,7 @@ def main() -> None:
     # per-token KV bytes alongside tok/s so BENCH rounds can attribute
     # bandwidth regressions (decode attention streams the whole static
     # cache each step: bytes/step ~= slots * cache_len * bytes_per_token)
-    from seldon_core_tpu.models.transformer import kv_cache_bytes_per_token
+    from seldon_core_tpu.models.cache import kv_cache_bytes_per_token
 
     kv_per_tok = kv_cache_bytes_per_token(server._cfg, server.kv_cache_dtype)
     entry = {

@@ -430,7 +430,7 @@ class MetricsRegistry:
                  "block-table view, or whole visits of latent attention's "
                  "live-page kernel; over context_tokens it is the over-read"))}
         # how the prefill chunks' K / V (latent) rows reached the paged pool
-        # (models/transformer.py paged_write_by_page), counted on the loop
+        # (models/cache.py paged_write_by_page), counted on the loop
         self._kv_writes = {
             key: Counter(f"seldon_llm_kv_{key}_total", text,
                          base + ["path"], registry=self.registry)

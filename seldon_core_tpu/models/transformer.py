@@ -7,8 +7,9 @@ TPU-first:
   maps them onto a device mesh (tp over 'model', dp over 'data', sequence
   parallel over 'seq') — XLA/GSPMD inserts the collectives over ICI.
 - GQA attention, rotary embeddings, RMSNorm, SwiGLU — bfloat16 on the MXU.
-- decode path uses a static-shape KV cache (scatter at position index), so
-  jit compiles one program per bucketed cache length.
+- the cache tree (dense for ``generate()``, a page pool for the batcher) is
+  models/cache.py's: the token mixers here make a call's rows, hand them to
+  ``write_rows`` / ``put_state`` and choose how to READ (expression or kernel).
 - optional mixture-of-experts FFN: sparse (each token computes its top-k
   experts only, a grouped matmul over the routed rows sorted by expert).
 
@@ -20,7 +21,6 @@ at all; this is the native model family the TPU build adds (SURVEY.md §5
 from __future__ import annotations
 
 import dataclasses
-import functools
 from functools import partial
 from typing import Any, Optional, Tuple
 
@@ -29,73 +29,22 @@ import jax
 import jax.numpy as jnp
 from flax.linen import partitioning as nn_partitioning
 
+from seldon_core_tpu.models.cache import (
+    PAD_POS,
+    TRASH_PAGE,
+    dense_view,
+    entry_is_int8,
+    gather_paged_view,
+    normalize_kv_cache_dtype,
+    put_state,
+    quantize_kv,
+    state_rows,
+    write_rows,
+)
 from seldon_core_tpu.models.registry import register_model
 
 param_with_axes = nn_partitioning.param_with_axes
 with_sharding_constraint = nn_partitioning.with_sharding_constraint
-
-# Sentinel position for empty/padded cache slots and padded prompt tokens:
-# larger than any real position, so causal masks (key_pos <= query_pos)
-# exclude them; small enough that rotary angles stay finite.
-PAD_POS = 1 << 28
-
-# KV-cache storage formats. "bf16" stores K/V in the model compute dtype
-# (the historical layout, named for the production config); "int8" stores
-# symmetric per-head, per-position int8 values plus f32 scales — the decode
-# attention read then streams half the bytes (benchmarks/DECODE_NOTES.md:
-# KV reads are the term that grows 2.71x from b1 to b8).
-KV_CACHE_DTYPES = ("bf16", "int8")
-_KV_QMAX = 127.0
-
-# Two cache structures, told apart by what the caller passes: a dense
-# per-sequence [b, max_len, ...] cache (init_kv_caches: generate(), the
-# draft model) and a global pool of fixed-size pages ([pages, page_size,
-# ...]) addressed through per-sequence block tables (init_paged_kv_caches:
-# the continuous batcher) — the vLLM/PagedAttention design (Kwon et al.,
-# SOSP 2023), which bills HBM for pages actually written.
-
-# Reserved page ids in every paged pool. NULL_PAGE backs unallocated
-# block-table tail entries: its position row is PAD_POS forever (writes
-# through a NULL entry are redirected device-side), so gathering it always
-# reads as "masked, never attended". TRASH_PAGE absorbs garbage writes —
-# inactive batcher slots ride along in the static-shape decode step, and
-# their stale writes must land somewhere no live block table points.
-NULL_PAGE = 0
-TRASH_PAGE = 1
-RESERVED_PAGES = 2
-
-
-def normalize_kv_cache_dtype(value) -> str:
-    """Canonical kv_cache_dtype ("bf16" or "int8"); raises ValueError on
-    anything else so misconfiguration fails at load() time, not inside jit."""
-    v = str(value or "bf16").strip().lower()
-    if v in ("bf16", "bfloat16", "model", "default"):
-        return "bf16"
-    if v == "int8":
-        return "int8"
-    raise ValueError(
-        f"unknown kv_cache_dtype {value!r}: expected one of {KV_CACHE_DTYPES}"
-    )
-
-
-def quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Symmetric int8 quantization over the last (head_dim) axis:
-    x [..., hd] float -> (q int8 [..., hd], scale f32 [...]). One scale per
-    head per position — finer than per-tensor, so attention logits survive
-    outlier keys; zero vectors get scale 1 (dequantize to exact zeros)."""
-    x32 = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x32), axis=-1)
-    scale = jnp.where(amax > 0, amax / _KV_QMAX, 1.0).astype(jnp.float32)
-    q = jnp.clip(jnp.round(x32 / scale[..., None]), -128, 127).astype(jnp.int8)
-    return q, scale
-
-
-def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
-    """Inverse of quantize_kv, used INSIDE the attention read so XLA fuses
-    the convert+multiply into the consuming einsum (int8 stays the HBM
-    format; dequant happens on the fly in VMEM)."""
-    return q.astype(dtype) * scale[..., None].astype(dtype)
-
 
 LAYER_KINDS = ("full_attention", "conv", "linear_attention")
 # the kinds whose layer keeps a fixed block of STATE a sequence, not pages
@@ -467,123 +416,6 @@ class RMSNorm(nn.Module):
         return rms_norm(x, w, self.eps)
 
 
-def paged_write_targets(block_tables: jnp.ndarray, positions: jnp.ndarray,
-                        page_size: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(page, offset) pool coordinates for writing each token's KV.
-
-    ``block_tables``: [b, n_pages] page ids; ``positions``: [b, s] absolute
-    token positions (PAD_POS for padding). Tokens whose position falls past
-    the table, or whose table entry is NULL_PAGE (unallocated — the host
-    failed to provision, or an inactive batcher slot riding along in the
-    static-shape step), are redirected to TRASH_PAGE: the null page's
-    PAD_POS position row is a device-side invariant no write may break."""
-    p = positions.astype(jnp.int32)
-    n_pages = block_tables.shape[1]
-    page_idx = p // page_size
-    valid = (p >= 0) & (page_idx < n_pages)
-    entry = jnp.take_along_axis(
-        block_tables, jnp.clip(page_idx, 0, n_pages - 1), axis=1)
-    entry = jnp.where(valid & (entry != NULL_PAGE), entry, TRASH_PAGE)
-    return entry, p % page_size
-
-
-def paged_write_by_page(cache, b: int, s: int) -> bool:
-    """Whether a call's rows land in the paged pool as whole pages
-    (``paged_write_pages``) or one scatter row a token, from what the call
-    shows alone: a pool of flat rows [pages, page_size, width] beside its
-    positions (the bf16 K / V 3-tuple, the latent 2-tuple) and ONE sequence's
-    run of at least a page — the batcher's prefill chunk.
-    The decode step (a token a slot), the speculative verify (a few tokens a
-    slot), the int8 5-tuple pool and a pool with its head axes split out
-    (``[.., kvh, hd]``: a mesh) keep the token scatter. ``Attention``,
-    ``LatentAttention`` and the loop's ``seldon_llm_kv_pages_written_total``
-    read this one rule."""
-    return len(cache) in (2, 3) and cache[0].ndim == 3 and b == 1 and s >= cache[0].shape[1]
-
-
-def pages_a_run_writes(s: int, page_size: int) -> int:
-    """Whole pages ``paged_write_pages`` reads and writes back for a run of
-    ``s`` rows: those a run that starts anywhere in a page can reach."""
-    return -(-s // page_size) + 1
-
-
-@jax.jit
-def paged_write_pages(pools, pos_pool: jnp.ndarray, block_tables: jnp.ndarray,
-                      positions: jnp.ndarray, rows):
-    """Write ONE sequence's run of rows into pools of flat rows
-    [pages, page_size, width] (``pools``, one of ``rows`` [s, width] each) and
-    its positions into ``pos_pool`` [pages, page_size], a page at a time.
-
-    ``positions`` [1, s] is what a prefill chunk carries: column ``j`` holds
-    ``positions[0, 0] + j`` or PAD_POS (padding), so the run lies in the
-    ``s // page_size + 1`` (rounded up) consecutive pages of the sequence from
-    the one that holds its start, wherever in that page it starts (a
-    copy-on-write prefix hit starts mid-page). Those pages are read, the live
-    rows laid into them at their offsets, and written back whole: a row that is
-    padding or lies outside the run keeps its old value and position, so every
-    page the sequence holds is bit for bit what the token scatter
-    (``paged_write_targets``) leaves. The pages' pool entries come from that
-    same function: a page past the table or one whose entry is NULL_PAGE lands
-    on TRASH_PAGE, the only page that may differ. Returns (pools, pos_pool).
-    A jitted function of its own, so a program's layers share ONE trace of it
-    (a trace a layer was +0.5 s of every chunk program's start on the chip's
-    host, PR 42); XLA inlines the call, and the donated pools are still
-    updated in place."""
-    ps = pos_pool.shape[1]
-    n = pages_a_run_writes(positions.shape[1], ps)
-    p = positions[0].astype(jnp.int32)
-    first, off = p[0] // ps, p[0] % ps
-    entry = paged_write_targets(block_tables[:1], ((first + jnp.arange(n)) * ps)[None], ps)[0][0]
-    # the run's positions at their rows of the n pages; PAD_POS = keep the old row
-    laid_pos = jax.lax.dynamic_update_slice(jnp.full((n * ps,), PAD_POS, jnp.int32), p, (off,))
-    live = laid_pos < PAD_POS
-
-    def read(pool):   # the n pages as one run of rows
-        return pool[entry].reshape((n * ps,) + pool.shape[2:])
-
-    def write(pool, run):
-        return pool.at[entry].set(run.reshape((n,) + pool.shape[1:]))
-
-    written = []
-    for pool, new in zip(pools, rows):
-        old = read(pool)
-        laid = jax.lax.dynamic_update_slice(old, new, (off, 0))
-        written.append(write(pool, jnp.where(live[:, None], laid, old)))
-    old_pos = read(pos_pool)
-    return tuple(written), write(pos_pool, jnp.where(live, laid_pos.astype(old_pos.dtype), old_pos))
-
-
-def gather_paged_view(cache, block_tables: jnp.ndarray, dtype, n_kv_heads: int):
-    """Gather a paged pool back into the per-sequence logical view:
-    (k_all, v_all, pos_view) of [b, n_pages*page_size, kvh, hd] / [b, L].
-
-    The ONE copy of the block-table read semantics of the expression: the
-    attention read below and ``paged_attention_ref`` (the live-page kernel's
-    oracle) both address the pool through this gather. A bf16 pool
-    of flat rows [pages, page_size, kvh * hd] (``cfg.kv_rows_flat``) has its
-    heads split here; int8 pools (5-tuple,
-    [.., kvh, hd] values beside [.., kvh] scales) dequantize here. The gather
-    moves bytes, never arithmetic, so the view feeds
-    ``grouped_query_attention`` exactly as the dense layout would, n_kv_heads
-    wide."""
-    bt = jnp.asarray(block_tables, jnp.int32)
-    b = bt.shape[0]
-    ps = cache[0].shape[1]
-    L = bt.shape[1] * ps
-    if len(cache) == 5:
-        kq_pool, ks_pool, vq_pool, vs_pool, pos_pool = cache
-        kvh, hd = kq_pool.shape[2], kq_pool.shape[3]
-        k_all = dequantize_kv(kq_pool[bt].reshape(b, L, kvh, hd),
-                              ks_pool[bt].reshape(b, L, kvh), dtype)
-        v_all = dequantize_kv(vq_pool[bt].reshape(b, L, kvh, hd),
-                              vs_pool[bt].reshape(b, L, kvh), dtype)
-    else:
-        k_pool, v_pool, pos_pool = cache
-        k_all = k_pool[bt].reshape(b, L, n_kv_heads, -1)
-        v_all = v_pool[bt].reshape(b, L, n_kv_heads, -1)
-    return k_all, v_all, pos_pool[bt].reshape(b, L)
-
-
 def paged_attention_ref(q, cache, block_tables, positions, n_kv_heads: int):
     """The paged read as an expression: gather the logical view through the
     block table and run the one masked-softmax chain on it, K/V kept
@@ -626,7 +458,7 @@ def grouped_query_attention(q: jnp.ndarray, k_all: jnp.ndarray,
     return out.reshape(b, s, n_heads, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("n_kv_heads", "walk"))
+@partial(jax.jit, static_argnames=("n_kv_heads", "walk"))
 def paged_live_read(q, cache, block_tables, positions, *, n_kv_heads: int, walk):
     """The paged bf16 pool's read of a call shape the live-page kernel takes
     (``walk`` = ``paged_read_walk(...)``, not None): the kernel
@@ -677,29 +509,20 @@ class Attention(nn.Module):
                  block_tables: Optional[jnp.ndarray] = None,
                  adapters: Optional[dict] = None,
                  adapter_ids: Optional[jnp.ndarray] = None):
-        """x: [b, s, d]. With cache=(k_cache, v_cache, pos_cache) of
-        [b, max_len, kvh, hd] / [b, max_len] — or the int8 layout
-        (k_q, k_scale, v_q, v_scale, pos_cache) with int8 values and
-        f32 [b, max_len, kvh] scales — runs incremental decode and
-        returns (out, new_cache). cache_index is the write offset: a scalar
-        (same slot for the whole batch — prefill) or a [b] vector
-        (per-sequence slots — continuous batching decode; s == 1 writes at
-        the vector index, while s > 1 — the speculative K-token verify —
-        writes every token at its own ``positions`` entry, dropping PAD_POS
-        columns). pos_cache holds each slot's absolute position (PAD_POS
-        when empty), so causal masking is exact under right-padding:
-        empty/pad slots are never attended.
-
-        With ``block_tables`` ([b, n_pages] int32) the cache tuple is a PAGED
-        pool — [pages, page_size, kvh, hd] buffers (same bf16 3-tuple / int8
-        5-tuple structure, leading dims [pages, page_size] instead of
-        [b, max_len]) shared by all sequences. Each token writes at the pool
-        coordinate its block table maps its position to, and attention reads
-        gather the per-sequence logical view back through the table — the
-        gathered view feeds the IDENTICAL chain (grouped_query_attention)
-        as the dense path, so paged and dense decode are bit-exact
-        (tests/test_paged_kv.py).
-        cache_index is ignored (positions alone address the pool).
+        """x: [b, s, d]; returns (out, new_cache). ``cache`` is this layer's
+        entry of the cache tree (models/cache.py: bf16 ``(k, v, pos)`` or int8
+        ``(kq, ks, vq, vs, pos)``, dense [b, max_len, ...] or, with
+        ``block_tables`` [b, n_pages] int32, a pool of pages [pages,
+        page_size, ...] shared by all sequences). The call's K/V rows go in
+        through ``write_rows`` (``cache_index`` addresses a dense cache: a
+        scalar offset, a [b] vector of offsets, or with s > 1 each row's own
+        position; ``positions`` alone address a pool), and the read masks by
+        the cached positions (PAD_POS = empty), so it is exact under
+        right-padding. The paged read gathers the sequence's logical view
+        through the table and feeds the IDENTICAL chain as the dense one
+        (grouped_query_attention), so paged and dense decode are bit-exact
+        (tests/test_paged_kv.py), or walks the live pages with the repo's
+        kernel where ``paged_read_walk`` says so.
         Without a cache: full causal attention, returns (out, (k, v))."""
         cfg = self.cfg
         b, s, _ = x.shape
@@ -757,142 +580,34 @@ class Attention(nn.Module):
         k = apply_partial_rotary(k, cos, sin)
 
         out = None
-        if cache is not None and block_tables is not None:
-            # Paged pool: write each token's K/V at the (page, offset) its
-            # block table maps its position to.
-            bt = jnp.asarray(block_tables, jnp.int32)
-            ps = cache[0].shape[1]
-            with jax.named_scope("attn.gqa.write"):
-                entry, off = paged_write_targets(bt, positions, ps)
-                if len(cache) == 5:
-                    kq_pool, ks_pool, vq_pool, vs_pool, pos_pool = cache
-                    kq, ks = quantize_kv(k)
-                    vq, vs = quantize_kv(v)
-                    kq_pool = kq_pool.at[entry, off].set(kq)
-                    ks_pool = ks_pool.at[entry, off].set(ks)
-                    vq_pool = vq_pool.at[entry, off].set(vq)
-                    vs_pool = vs_pool.at[entry, off].set(vs)
-                    pos_pool = pos_pool.at[entry, off].set(
-                        positions.astype(pos_pool.dtype))
-                    new_cache = (kq_pool, ks_pool, vq_pool, vs_pool, pos_pool)
-                elif paged_write_by_page(cache, b, s):
-                    # a chunk: whole pages of flat rows
-                    k_pool, v_pool, pos_pool = cache
-                    (k_pool, v_pool), pos_pool = paged_write_pages(
-                        (k_pool, v_pool), pos_pool, bt, positions,
-                        (k.astype(k_pool.dtype).reshape(s, -1),
-                         v.astype(v_pool.dtype).reshape(s, -1)))
-                    new_cache = (k_pool, v_pool, pos_pool)
-                else:
-                    # a token's heads as the pool holds them: ONE row, or [kvh, hd]
-                    k_pool, v_pool, pos_pool = cache
-                    row = (b, s) + k_pool.shape[2:]
-                    k_pool = k_pool.at[entry, off].set(k.astype(k_pool.dtype).reshape(row))
-                    v_pool = v_pool.at[entry, off].set(v.astype(v_pool.dtype).reshape(row))
-                    pos_pool = pos_pool.at[entry, off].set(
-                        positions.astype(pos_pool.dtype))
-                    new_cache = (k_pool, v_pool, pos_pool)
-
-            # The read as an expression gathers the logical [b, n_pages*ps,
-            # ...] view and runs the SAME chain the dense layout uses
-            # (grouped_query_attention): paged == dense bit-for-bit (masked
-            # positions contribute exact zeros). For the bf16 pool of flat
-            # rows on one TPU the call walks each sequence's live pages with
-            # the repo's kernel instead (``paged_live_read``: the decode step
-            # and the speculative verify row-wide, a chunk's full query tiles
-            # a lane block a KV head); every other lowering, a mesh, the int8
-            # pool and a call shape the kernel does not take keep the
-            # expression over the whole view.
-            walk = None
-            if len(new_cache) == 3:
-                walk = paged_read_walk(cfg, s, bt.shape[1], ps, k_pool.dtype)
-            with jax.named_scope("attn.gqa.read"):
-                if walk is not None:
-                    out = paged_live_read(q, new_cache, bt, positions,
-                                          n_kv_heads=cfg.n_kv_heads, walk=walk)
-                else:
-                    out = paged_attention_ref(q, new_cache, bt, positions, cfg.n_kv_heads)
-        elif cache is not None and len(cache) == 5:
-            # int8 cache: (k_q, k_scale, v_q, v_scale, pos). Quantize-on-write
-            # (new K/V rows become int8 + per-head scales before the scatter),
-            # dequant fused into the attention read below.
-            kq_cache, ks_cache, vq_cache, vs_cache, pos_cache = cache
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            idx = jnp.asarray(cache_index, dtype=jnp.int32)
-            if idx.ndim == 0:
-                kq_cache = jax.lax.dynamic_update_slice(kq_cache, kq, (0, idx, 0, 0))
-                ks_cache = jax.lax.dynamic_update_slice(ks_cache, ks, (0, idx, 0))
-                vq_cache = jax.lax.dynamic_update_slice(vq_cache, vq, (0, idx, 0, 0))
-                vs_cache = jax.lax.dynamic_update_slice(vs_cache, vs, (0, idx, 0))
-                pos_cache = jax.lax.dynamic_update_slice(
-                    pos_cache, positions.astype(pos_cache.dtype), (0, idx)
-                )
-            elif s == 1:
-                # per-sequence write offsets (continuous batching): s == 1
-                bidx = jnp.arange(b)
-                kq_cache = kq_cache.at[bidx, idx].set(kq[:, 0])
-                ks_cache = ks_cache.at[bidx, idx].set(ks[:, 0])
-                vq_cache = vq_cache.at[bidx, idx].set(vq[:, 0])
-                vs_cache = vs_cache.at[bidx, idx].set(vs[:, 0])
-                pos_cache = pos_cache.at[bidx, idx].set(positions[:, 0].astype(pos_cache.dtype))
-            else:
-                # per-sequence K-token writes (speculative verify): every
-                # token scatters at its own absolute position. Padded draft
-                # columns carry PAD_POS positions — far past max_len — and
-                # mode="drop" discards those writes, so a short draft never
-                # touches the cache (the dense analog of the paged layout's
-                # TRASH_PAGE redirect).
-                bidx2 = jnp.arange(b)[:, None]
-                wp = positions.astype(jnp.int32)
-                kq_cache = kq_cache.at[bidx2, wp].set(kq, mode="drop")
-                ks_cache = ks_cache.at[bidx2, wp].set(ks, mode="drop")
-                vq_cache = vq_cache.at[bidx2, wp].set(vq, mode="drop")
-                vs_cache = vs_cache.at[bidx2, wp].set(vs, mode="drop")
-                pos_cache = pos_cache.at[bidx2, wp].set(
-                    positions.astype(pos_cache.dtype), mode="drop")
-            # the int8 buffers are what streams from HBM; XLA fuses this
-            # convert+multiply into the attention einsums (VMEM dequant)
-            k_all = dequantize_kv(kq_cache, ks_cache, dt)
-            v_all = dequantize_kv(vq_cache, vs_cache, dt)
-            mask = pos_cache[:, None, :] <= positions[:, :, None]  # [b, s, kv]
-            new_cache = (kq_cache, ks_cache, vq_cache, vs_cache, pos_cache)
-        elif cache is not None:
-            k_cache, v_cache, pos_cache = cache
-            idx = jnp.asarray(cache_index, dtype=jnp.int32)
-            if idx.ndim == 0:
-                k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, idx, 0, 0))
-                v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, idx, 0, 0))
-                pos_cache = jax.lax.dynamic_update_slice(
-                    pos_cache, positions.astype(pos_cache.dtype), (0, idx)
-                )
-            elif s == 1:
-                # per-sequence write offsets (continuous batching): s == 1
-                bidx = jnp.arange(b)
-                k_cache = k_cache.at[bidx, idx].set(k[:, 0].astype(k_cache.dtype))
-                v_cache = v_cache.at[bidx, idx].set(v[:, 0].astype(v_cache.dtype))
-                pos_cache = pos_cache.at[bidx, idx].set(positions[:, 0].astype(pos_cache.dtype))
-            else:
-                # per-sequence K-token writes (speculative verify): see the
-                # int8 branch above — positions address the cache directly,
-                # PAD_POS columns drop.
-                bidx2 = jnp.arange(b)[:, None]
-                wp = positions.astype(jnp.int32)
-                k_cache = k_cache.at[bidx2, wp].set(
-                    k.astype(k_cache.dtype), mode="drop")
-                v_cache = v_cache.at[bidx2, wp].set(
-                    v.astype(v_cache.dtype), mode="drop")
-                pos_cache = pos_cache.at[bidx2, wp].set(
-                    positions.astype(pos_cache.dtype), mode="drop")
-            k_all, v_all = k_cache, v_cache
-            # pos_cache marks empty slots with PAD_POS, so one predicate covers
-            # causality, the unfilled suffix, and right-padding garbage.
-            mask = pos_cache[:, None, :] <= positions[:, :, None]  # [b, s, kv]
-            new_cache = (k_cache, v_cache, pos_cache)
+        if cache is None:
+            k_all, v_all, pos_view, new_cache = k, v, positions, (k, v)
         else:
-            k_all, v_all = k, v
-            mask = positions[:, None, :] <= positions[:, :, None]  # [b, s, kv]
-            new_cache = (k, v)
+            # the entry's rows: int8 quantizes on write (values + a scale a
+            # head), and the read dequantizes fused into its einsums
+            with jax.named_scope("attn.gqa.write"):
+                rows = (*quantize_kv(k), *quantize_kv(v)) if entry_is_int8(cache) else (k, v)
+                new_cache = write_rows(cache, rows, positions, block_tables=block_tables,
+                                       cache_index=cache_index)
+            if block_tables is None:
+                k_all, v_all, pos_view = dense_view(new_cache, dt)
+            else:
+                # The read as an expression gathers the logical view and runs
+                # the SAME chain the dense layout uses: paged == dense
+                # bit-for-bit. For the bf16 pool of flat rows on one TPU the
+                # call walks each sequence's live pages with the repo's kernel
+                # instead (``paged_live_read``); every other lowering, a mesh,
+                # the int8 pool and a call shape the kernel does not take keep
+                # the expression.
+                bt = jnp.asarray(block_tables, jnp.int32)
+                pool = new_cache[0]
+                walk = paged_read_walk(cfg, s, bt.shape[1], pool.shape[1], pool.dtype)
+                with jax.named_scope("attn.gqa.read"):
+                    if walk is not None:
+                        out = paged_live_read(q, new_cache, bt, positions,
+                                              n_kv_heads=cfg.n_kv_heads, walk=walk)
+                    else:
+                        out = paged_attention_ref(q, new_cache, bt, positions, cfg.n_kv_heads)
 
         if cache is None and cfg.attention_impl == "ring":
             from seldon_core_tpu.ops.ring_attention import ring_attention
@@ -903,7 +618,9 @@ class Attention(nn.Module):
             )
         elif out is None:
             # every cache layout but the paged pool's ends here: K/V stay
-            # n_kv_heads wide
+            # n_kv_heads wide. Empty rows hold PAD_POS, so one predicate covers
+            # causality, the unfilled suffix and right-padding garbage
+            mask = pos_view[:, None, :] <= positions[:, :, None]  # [b, s, kv]
             with jax.named_scope("attn.gqa.read"):
                 out = grouped_query_attention(q, k_all, v_all, mask)
         out = out.reshape(b, s, cfg.n_heads * hd)
@@ -1024,13 +741,10 @@ class LatentAttention(nn.Module):
         row_t   = [c_t ; k^R_t]        the ONE thing cached a token, no head axis
         out     = wo concat_h absorbed_latent_attention(...)_h
 
-    The cache is a 2-tuple ``(rows, pos)``: [b, max_len, W] / [b, max_len]
-    dense, or a paged pool [pages, page_size, W] / [pages, page_size]
-    addressed through ``block_tables``, W = cfg.latent_row_dim (dc + dr in
-    whole 128-lane tiles, zeros behind) — the same (values...,
-    positions) layer shape the batcher's page operations are generic over,
-    written and read under the same rules as ``Attention``'s (PAD_POS marks
-    empty rows; unallocated pages redirect to TRASH_PAGE). The read is one
+    The cache entry is ``(rows, pos)`` (models/cache.py), dense or a paged pool
+    addressed through ``block_tables``, a row W = cfg.latent_row_dim wide (dc +
+    dr in whole 128-lane tiles, zeros behind), written by ``write_rows`` under
+    the same rules as ``Attention``'s. The read is one
     expression over the whole view (``absorbed_latent_attention``); for the
     paged pool in a program LOWERED for a TPU it is the repo's kernel that
     walks each sequence's live pages (ops/latent_attention.py), chosen by
@@ -1099,32 +813,11 @@ class LatentAttention(nn.Module):
             if cache is None:
                 new_cache = (row,)
             else:
-                pool, pos_pool = cache
-                row = row.astype(pool.dtype)
-                wpos = positions.astype(pos_pool.dtype)
+                new_cache = write_rows(cache, (row,), positions, block_tables=block_tables,
+                                       cache_index=cache_index)
+                pool, pos_pool = new_cache
                 if block_tables is not None:
                     bt = jnp.asarray(block_tables, jnp.int32)
-                    if paged_write_by_page(cache, b, s):   # a chunk: whole pages
-                        (pool,), pos_pool = paged_write_pages(
-                            (pool,), pos_pool, bt, positions, (row[0],))
-                    else:
-                        at = paged_write_targets(bt, positions, pool.shape[1])
-                        pool, pos_pool = pool.at[at].set(row), pos_pool.at[at].set(wpos)
-                else:
-                    idx = jnp.asarray(cache_index, dtype=jnp.int32)
-                    if idx.ndim == 0:   # one offset for the batch: prefill
-                        pool = jax.lax.dynamic_update_slice(pool, row, (0, idx, 0))
-                        pos_pool = jax.lax.dynamic_update_slice(pos_pool, wpos, (0, idx))
-                    else:
-                        # per-sequence offsets: one token at its slot's index,
-                        # or (speculative verify) each at its own position,
-                        # PAD_POS columns dropped
-                        at = ((jnp.arange(b), idx) if s == 1 else
-                              (jnp.arange(b)[:, None], positions.astype(jnp.int32)))
-                        new, newpos = (row[:, 0], wpos[:, 0]) if s == 1 else (row, wpos)
-                        pool = pool.at[at].set(new, mode="drop")
-                        pos_pool = pos_pool.at[at].set(newpos, mode="drop")
-                new_cache = (pool, pos_pool)
         with jax.named_scope("attn.latent.read"):
             scale = latent_attention_scale(cfg)
             w_uk, w_uv = _dense_stack(w_uk, dt), _dense_stack(w_uv, dt)
@@ -1595,12 +1288,11 @@ class ShortConv(nn.Module):
 
     W_in is ONE [dim, 3 dim] product. What a sequence keeps between calls is
     its last L-1 values of z, [L-1, dim], whatever its length: the cache entry
-    of a conv layer is a 1-tuple ``(state,)`` with state [rows, L-1, dim] in
-    the serving dtype, rows = the dense cache's batch or the batcher's slots.
-    ``state_slots`` [b] int32 says which state row each sequence of the call
-    continues (a prefill chunk's one sequence: its slot); None = row i is
-    sequence i's (the decode step over every slot, the dense cache). Without
-    a cache: from zeros, returns (out, (state,)) as well."""
+    of a conv layer is a ``StateEntry`` ``(state,)`` with state [rows, L-1,
+    dim] in the serving dtype, rows = the dense cache's batch or the batcher's
+    slots, read and written through models/cache.py ``state_rows`` /
+    ``put_state`` (``state_slots``: which row each sequence continues).
+    Without a cache: from zeros, returns (out, (state,)) as well."""
 
     cfg: TransformerConfig
 
@@ -1620,18 +1312,9 @@ class ShortConv(nn.Module):
             bcx = x @ w_in.astype(dt)
         with jax.named_scope("mix.conv.taps"):
             gate_b, gate_c, xs = bcx[..., :d], bcx[..., d:2 * d], bcx[..., 2 * d:]
-            pool = None if cache is None else cache[0]
-            if pool is None or state_slots is None:
-                state = pool
-            else:
-                state = pool[state_slots]
+            state, = state_rows(cache, state_slots, 1)
             v, new_state = short_conv(gate_b * xs, taps, state, positions, valid)
-            if pool is None:
-                new_cache = StateEntry((new_state,))
-            elif state_slots is None:
-                new_cache = StateEntry((new_state.astype(pool.dtype),))
-            else:
-                new_cache = StateEntry((pool.at[state_slots].set(new_state.astype(pool.dtype)),))
+            new_cache = put_state(cache, state_slots, (new_state,))
             y = (gate_c.astype(jnp.float32) * v).astype(dt)
         with jax.named_scope("mix.conv.out"):
             return y @ w_out.astype(dt), new_cache
@@ -1822,11 +1505,7 @@ class GatedDeltaNet(nn.Module):
             # -A softplus(a + dt_bias) with A up to 16, and a rounding of a to
             # bf16 is a rounding of the DECAY
             ba = jnp.matmul(x, w_ba.astype(dt), preferred_element_type=jnp.float32)
-        conv_pool, s_pool = (None, None) if cache is None else cache
-        if conv_pool is None or state_slots is None:
-            conv_state, state = conv_pool, s_pool
-        else:
-            conv_state, state = conv_pool[state_slots], s_pool[state_slots]
+        conv_state, state = state_rows(cache, state_slots, 2)
         with jax.named_scope("mix.gdn.conv"):
             mixed, new_conv = short_conv(qkvz[..., :channels], taps, conv_state, positions, valid)
             mixed = jax.nn.silu(mixed)                                   # float32
@@ -1846,14 +1525,7 @@ class GatedDeltaNet(nn.Module):
             starts = (positions[:, 0] == 0) & valid[:, 0]
             o, new_state = gated_delta_rule(q, k, v, g, beta, state, starts,
                                             kernel=cfg.mesh is None)
-        if conv_pool is None:
-            new_cache = StateEntry((new_conv, new_state))
-        elif state_slots is None:
-            new_cache = StateEntry((new_conv.astype(conv_pool.dtype), new_state))
-        else:
-            new_cache = StateEntry((
-                conv_pool.at[state_slots].set(new_conv.astype(conv_pool.dtype)),
-                s_pool.at[state_slots].set(new_state)))
+        new_cache = put_state(cache, state_slots, (new_conv, new_state))
         with jax.named_scope("mix.gdn.out"):
             normed = RMSNorm(dv, cfg.norm_eps, "head_norm", name="norm")(o)
             z = qkvz[..., channels:].reshape(b, s, hv, dv).astype(jnp.float32)
@@ -2054,185 +1726,6 @@ class Transformer(nn.Module):
             if next_tokens is not None:
                 return logits, new_caches, mtp.astype(jnp.float32) @ head
         return logits, new_caches
-
-
-LATENT_INT8_REFUSAL = (
-    "kv_cache_dtype='int8' is not built for latent attention (kv_lora_rank "
-    "> 0): a latent row has no head axis to scale by, and a per-row scale "
-    "over 512 + 64 mixed values is untested; serve it with the bf16 cache")
-
-
-class StateEntry(tuple):
-    """A state layer's entry of a cache tree (a fixed block a sequence, no
-    pages, no positions: a conv layer's ``(state,)``, a linear-attention
-    layer's ``(conv_state, S)``), told from an attention layer's
-    ``(values..., positions)`` by its TYPE: the initialisers below make one
-    where ``cfg.layer_kind`` names a state layer, the two state modules return
-    one, and every tree operation keeps it (a registered pytree node)."""
-
-
-jax.tree_util.register_pytree_node(
-    StateEntry, lambda entry: (tuple(entry), None), lambda _aux, leaves: StateEntry(leaves))
-
-
-def is_state_entry(layer) -> bool:
-    """Is this layer's entry of a cache tree a state layer's? The page
-    operations skip it."""
-    return isinstance(layer, StateEntry)
-
-
-def _state_entry_shapes(cfg: TransformerConfig, kind: str) -> Tuple[Tuple[Tuple[int, ...], Any], ...]:
-    """(shape a sequence, dtype) of each array of a state layer's entry."""
-    if kind == "conv":
-        return (((cfg.conv_L_cache - 1, cfg.dim), cfg.dtype),)
-    channels = (2 * cfg.linear_num_key_heads * cfg.linear_key_head_dim
-                + cfg.linear_num_value_heads * cfg.linear_value_head_dim)
-    return (((cfg.linear_conv_kernel_dim - 1, channels), cfg.dtype),
-            ((cfg.linear_num_value_heads, cfg.linear_key_head_dim,
-              cfg.linear_value_head_dim), jnp.float32))
-
-
-def state_bytes(cfg: TransformerConfig) -> int:
-    """Bytes of state ONE sequence keeps over all its state layers, whatever
-    its length (0 for a model without them)."""
-    import math
-
-    return sum(math.prod(shape) * jnp.dtype(dtype).itemsize
-               for i in cfg.state_layers
-               for shape, dtype in _state_entry_shapes(cfg, cfg.layer_kind(i)))
-
-
-def _with_state_entries(cfg: TransformerConfig, attention_entries: list, rows: int):
-    """The cache tree over ALL layers: a state layer's entry (zeros,
-    ``rows`` sequences) where cfg.layer_types says so, the attention entries
-    in order elsewhere."""
-    if not cfg.state_layers:
-        return attention_entries
-    if rows <= 0:
-        raise ValueError(
-            "a model with state layers needs the number of sequences its state "
-            "blocks serve (init_paged_kv_caches(..., state_slots=))")
-    entries = iter(attention_entries)
-    return [
-        StateEntry(jnp.zeros((rows,) + shape, dtype)
-                   for shape, dtype in _state_entry_shapes(cfg, cfg.layer_kind(i)))
-        if cfg.layer_kind(i) in STATE_LAYER_KINDS else next(entries)
-        for i in range(cfg.n_layers)
-    ]
-
-
-def _init_latent_caches(cfg: TransformerConfig, lead: Tuple[int, int], kvd: str):
-    """(rows, pos) per layer with leading dims ``lead``: [b, max_len] dense
-    or [pages, page_size] paged."""
-    if kvd == "int8":
-        raise ValueError(LATENT_INT8_REFUSAL)
-    return [
-        (jnp.zeros(lead + (cfg.latent_row_dim,), dtype=cfg.dtype),
-         jnp.full(lead, PAD_POS, dtype=jnp.int32))
-        for _ in range(cfg.n_layers)
-    ]
-
-
-def _init_head_caches(cfg: TransformerConfig, lead: Tuple[int, int], kvd: str,
-                      flat: bool = False):
-    """Per-head K/V entries with leading dims ``lead``, one per ATTENTION
-    layer: (k, v, pos), or the int8 5-tuple. ``flat``: a token's heads as one
-    row (the paged bf16 pool of a cfg.kv_rows_flat model)."""
-    shape = lead + (cfg.n_kv_heads, cfg.head_dim)
-    if flat and kvd != "int8":
-        shape = lead + (cfg.n_kv_heads * cfg.head_dim,)
-    n = cfg.n_layers - len(cfg.state_layers)
-    if kvd == "int8":
-        scale_shape = lead + (cfg.n_kv_heads,)
-        return [
-            (
-                jnp.zeros(shape, dtype=jnp.int8),
-                jnp.ones(scale_shape, dtype=jnp.float32),
-                jnp.zeros(shape, dtype=jnp.int8),
-                jnp.ones(scale_shape, dtype=jnp.float32),
-                jnp.full(lead, PAD_POS, dtype=jnp.int32),
-            )
-            for _ in range(n)
-        ]
-    return [
-        (
-            jnp.zeros(shape, dtype=cfg.dtype),
-            jnp.zeros(shape, dtype=cfg.dtype),
-            jnp.full(lead, PAD_POS, dtype=jnp.int32),
-        )
-        for _ in range(n)
-    ]
-
-
-def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
-                   kv_cache_dtype: Optional[str] = None):
-    """Static-shape KV caches: one (k, v, pos) triple per layer —
-    [b, max_len, kvh, hd] buffers plus a [b, max_len] position map whose empty
-    slots hold PAD_POS (never attended). With kv_cache_dtype="int8" each
-    layer is a (k_q, k_scale, v_q, v_scale, pos) 5-tuple: int8 values plus
-    f32 [b, max_len, kvh] per-head per-position scales (initialised to 1 so
-    empty slots dequantize to exact zeros). A latent-attention layer
-    (cfg.kv_lora_rank) is a (rows, pos) pair: [b, max_len, latent_row_dim];
-    a conv layer (cfg.layer_types) a ``(state,)`` 1-tuple [b, L-1, dim], a
-    linear-attention layer ``(conv_state, S)`` (GatedDeltaNet)."""
-    kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
-    if cfg.kv_lora_rank:
-        return _init_latent_caches(cfg, (batch, max_len), kvd)
-    return _with_state_entries(
-        cfg, _init_head_caches(cfg, (batch, max_len), kvd), batch)
-
-
-def init_paged_kv_caches(cfg: TransformerConfig, num_pages: int,
-                         page_size: int, kv_cache_dtype: Optional[str] = None,
-                         state_slots: int = 0):
-    """Paged KV pools: one (k, v, pos) triple per layer with leading dims
-    [num_pages, page_size] instead of [batch, max_len] — pages are shared by
-    every sequence through per-sequence block tables. Pages 0 and 1 are
-    reserved (NULL_PAGE / TRASH_PAGE; see module constants), so a pool of
-    ``num_pages`` serves ``num_pages - RESERVED_PAGES`` tokens' worth of
-    allocatable KV. Position rows initialise to PAD_POS (never attended);
-    int8 pools carry f32 [num_pages, page_size, kvh] scale planes
-    initialised to 1 (empty slots dequantize to exact zeros). A
-    latent-attention layer is a (rows, pos) pair: [num_pages, page_size,
-    latent_row_dim] with no head axis. A state layer (cfg.layer_types) has no
-    pages: a conv layer's entry is ``(state,)``, [state_slots,
-    conv_L_cache - 1, dim], a linear-attention layer's ``(conv_state, S)``,
-    [state_slots, taps - 1, channels] and float32 [state_slots, Hv, dk, dv]:
-    one block a sequence the pool serves (``is_state_entry``).
-
-    Where ``cfg.kv_rows_flat`` (one device, or narrow heads) the bf16
-    pool holds a token's heads as ONE row, [num_pages, page_size, kvh * hd];
-    elsewhere, and the int8 pool beside its [.., kvh] scales, [.., kvh, hd]
-    (the split of a whole view of flat rows is two to three times the step's
-    read as an expression: v5e, PR 36)."""
-    if num_pages <= RESERVED_PAGES:
-        raise ValueError(
-            f"paged KV pool needs > {RESERVED_PAGES} pages "
-            f"(got {num_pages}; pages 0/1 are reserved)")
-    kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
-    if cfg.kv_lora_rank:
-        return _init_latent_caches(cfg, (num_pages, page_size), kvd)
-    pools = _init_head_caches(cfg, (num_pages, page_size), kvd, flat=cfg.kv_rows_flat)
-    return _with_state_entries(cfg, pools, state_slots)
-
-
-def kv_cache_bytes_per_token(cfg: TransformerConfig,
-                             kv_cache_dtype: Optional[str] = None) -> int:
-    """HBM bytes one cached token position costs across all layers (K + V
-    values, int8 scales when quantized, and the int32 position map). Decode
-    attention reads the whole static cache every step, so
-    bytes/step ~= batch * cache_len * this. Reported by the LLM benches so
-    BENCH rounds can attribute bandwidth regressions."""
-    kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
-    if cfg.kv_lora_rank:   # one latent row for all heads, in the model dtype
-        return cfg.n_layers * (cfg.latent_row_dim * jnp.dtype(cfg.dtype).itemsize + 4)
-    per_pos = cfg.n_kv_heads * cfg.head_dim
-    if kvd == "int8":
-        per_layer = 2 * (per_pos * 1 + cfg.n_kv_heads * 4)  # int8 + f32 scale
-    else:
-        per_layer = 2 * per_pos * jnp.dtype(cfg.dtype).itemsize
-    # a state layer caches nothing a token (state_bytes a sequence)
-    return (cfg.n_layers - len(cfg.state_layers)) * (per_layer + 4)  # + int32 pos slot
 
 
 @register_model("transformer")

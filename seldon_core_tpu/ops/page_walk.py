@@ -150,7 +150,7 @@ def live_pages(block_tables, positions, page_size: int):
     padding, or a slot nobody holds, whose table row is all TRASH_PAGE)."""
     import jax.numpy as jnp
 
-    from seldon_core_tpu.models.transformer import PAD_POS, TRASH_PAGE
+    from seldon_core_tpu.models.cache import PAD_POS, TRASH_PAGE
 
     p = positions.astype(jnp.int32)
     valid = (p >= 0) & (p < PAD_POS) & (block_tables[:, :1] != TRASH_PAGE)
@@ -186,7 +186,7 @@ def make_visits(block_tables, live, walk: Plan) -> Visits:
     page."""
     import jax.numpy as jnp
 
-    from seldon_core_tpu.models.transformer import NULL_PAGE
+    from seldon_core_tpu.models.cache import NULL_PAGE
 
     b, n_pages = block_tables.shape
     groups = walk.groups(n_pages)

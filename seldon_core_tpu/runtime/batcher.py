@@ -8,17 +8,22 @@ program serves overlapping requests at arbitrary arrival times — no
 head-of-line blocking on the longest generation, no recompilation.
 
 Design (all shapes static):
-- one slot-batched KV cache [S, max_len, ...] lives on device;
-- admission: a single-prompt prefill (compiled per length bucket) produces a
-  1-sequence cache which is written into a free slot (jitted insert);
-- every step runs ONE jitted decode over all S slots with per-slot cache
-  offsets (models/transformer.py vector ``cache_index``); inactive slots
-  compute garbage into their own slot, which the next insert overwrites;
-- completion: EOS or per-request max_new_tokens frees the slot.
+- ONE cache tree lives on the device (models/cache.py owns its form): for
+  every attention layer a global pool of fixed-size pages shared by all S
+  slots through a device-resident block table [S, n_pages], and for every
+  state layer (conv, linear attention) a fixed block a slot, no pages;
+- admission: the prompt is prefilled in fixed-size chunks (one compiled
+  program a chunk size) that write STRAIGHT into the slot's own pages and its
+  state block, interleaved with decode steps; nothing is copied afterwards;
+- every step runs ONE jitted decode over all S slots, each at its own
+  position; a slot nobody holds rides along with a block-table row of
+  TRASH_PAGE, so what it writes lands on the trash page and it reads nothing;
+- completion: EOS or per-request max_new_tokens frees the slot and its pages
+  (a page's positions are reset when it is handed out again).
 
-The transformer's position-tracked cache (PAD_POS masking) is what makes the
-mixed-occupancy batch exact: each slot only attends to its own written
-positions.
+The positions cached beside every row (PAD_POS = empty) are what makes the
+mixed-occupancy batch exact: a slot attends only to rows its own block table
+names and whose position is real and not after the query's.
 
 Pipelined decode (PR 3): the decode loop is device-resident. Per-slot token,
 position and rng-key state live in device arrays threaded through the
@@ -32,7 +37,8 @@ EOS semantics under the lag: the device may run up to ``pipeline_depth``
 run-ahead steps past a sequence's EOS before the host sees it. Those
 trailing tokens are masked by a per-slot generation counter (a slot freed
 and re-admitted between dispatch and drain fails the ``gen`` check), and the
-trailing KV writes land in a slot that the next insert overwrites whole —
+trailing KV writes land in pages whose positions are reset (``reset_pages``)
+before their next owner reads them, or on the trash page —
 the lag can only cost wasted compute, never wrong output
 (tests/test_batcher_pipeline.py holds token parity against ``generate()``).
 The masking is advance-agnostic: a dispatched step may land 1 token
@@ -132,19 +138,16 @@ worker's entry and exit, and resumption (``LoopPhases.handoff``), so the
 two waits for a thread, the worker's own Python and the loop coroutine's
 own code add up to it.
 
-Paged KV cache (PR 7): the slots' KV lives in a GLOBAL pool of
-fixed-size KV pages plus a device-resident per-slot block table — the
-vLLM/PagedAttention design (Kwon et al., SOSP 2023). HBM is billed for
-pages actually written, so a deliberately undersized pool
-(``kv_pool_pages``) oversubscribes: more concurrent slots per HBM byte,
+Paged KV cache (PR 7; the vLLM/PagedAttention design, Kwon et al., SOSP
+2023): HBM is billed for pages actually written, so a deliberately undersized
+pool (``kv_pool_pages``) oversubscribes: more concurrent slots per HBM byte,
 with page-exhaustion shedding (503 + Retry-After, runtime/resilience.py
-ShedError) as the relief valve — the decode loop never raises. Admission
-prefill runs in fixed-size chunks (``prefill_chunk``) interleaved with
-decode dispatches (Sarathi-Serve; Agrawal et al., OSDI 2024), so a
-2k-token prompt never stalls in-flight decodes for its whole compile
-bucket. Page bookkeeping is host-side (PageAllocator, lock-guarded);
-block-table updates are jitted device ops that serialize behind in-flight
-steps in device program order.
+ShedError) as the relief valve — the decode loop never raises. The chunked
+admission (``prefill_chunk``; Sarathi-Serve, Agrawal et al., OSDI 2024) means
+a 2k-token prompt never stalls in-flight decodes for a whole prompt's forward.
+Page bookkeeping is host-side (PageAllocator, lock-guarded); block-table
+updates are jitted device ops that serialize behind in-flight steps in device
+program order.
 """
 
 from __future__ import annotations
@@ -158,13 +161,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from seldon_core_tpu.models.transformer import (
-    NULL_PAGE,
-    PAD_POS,
-    RESERVED_PAGES,
-    TRASH_PAGE,
-    is_state_entry,
-)
+from seldon_core_tpu.models import cache as kvcache
+from seldon_core_tpu.models.cache import NULL_PAGE, PAD_POS, RESERVED_PAGES, TRASH_PAGE
 from seldon_core_tpu.runtime.flight import (
     EV_FIRST_TOKEN,
     EV_HANDOFF_IMPORT,
@@ -218,23 +216,10 @@ def _page_table_ops():
     def set_block_entry(bt, slot, idx, page):
         return bt.at[slot, idx].set(page)
 
-    # Reset the POSITION rows of newly-allocated pages to PAD_POS: a page
-    # off the free list still holds its previous owner's positions, and a
-    # stale real position would make another sequence's mask attend
-    # garbage. page_ids is padded to a fixed length with TRASH_PAGE
-    # (re-masking trash is harmless), so one compile serves every
-    # allocation size.
-    # A state layer's entry of the pool tree (a conv layer's one array, a
-    # linear-attention layer's two) is fixed blocks a slot, with no pages and
-    # no positions (models/transformer.py ``is_state_entry``): every page
-    # operation below hands it on as it is.
-    @partial(jax.jit, donate_argnums=(0,))
-    def reset_pages(caches, page_ids):
-        return [
-            layer if is_state_entry(layer) else
-            layer[:-1] + (layer[-1].at[page_ids].set(PAD_POS),)
-            for layer in caches
-        ]
+    # The operations over the whole pool tree are models/cache.py's (what an
+    # entry is made of is its business); decided HERE are the jit and the
+    # donation. Newly-allocated pages' stale positions reset, in place:
+    reset_pages = jax.jit(kvcache.reset_pages, donate_argnums=(0,))
 
     # Per-slot admission update for the device-resident decode state (both
     # layouts; slot index is traced, so one compile serves every slot). The
@@ -261,43 +246,16 @@ def _page_table_ops():
     def set_adapter_id(ids, slot, aid):
         return ids.at[slot].set(aid)
 
-    # Copy-on-write page copy (radix prefix cache, runtime/radix.py): a
-    # slot that must WRITE into a shared cached page gets a fresh page
-    # plus this one donated copy — values move whole-page, but the
-    # position row is masked to the source's VALID length (offsets past
-    # n_valid go PAD_POS: the source page may carry a previous occupant's
-    # run-ahead positions past its credited history, and copying those
-    # live would make the new slot attend another sequence's tail). The
-    # compiled form is pinned by the batcher.cow_page_copy hlolint
-    # contract (pool donated in place, zero host transfers, budgeted
-    # bytes — ONE page, not a prefix gather).
-    @partial(jax.jit, donate_argnums=(0,))
-    def cow_page_copy(caches, src, dst, n_valid):
-        import jax.numpy as jnp
+    # Copy-on-write page copy (radix prefix cache, runtime/radix.py): ONE
+    # page, the pool donated in place; pinned by the batcher.cow_page_copy
+    # hlolint contract (zero host transfers, budgeted bytes, no prefix gather).
+    cow_page_copy = jax.jit(kvcache.cow_page_copy, donate_argnums=(0,))
 
-        out = []
-        for layer in caches:
-            if is_state_entry(layer):
-                out.append(layer)
-                continue
-            vals = tuple(pool.at[dst].set(pool[src]) for pool in layer[:-1])
-            pos = layer[-1]
-            row = jnp.where(jnp.arange(pos.shape[1]) < n_valid,
-                            pos[src], PAD_POS)
-            out.append(vals + (pos.at[dst].set(row),))
-        return out
-
-    # Page export (disaggregated prefix reuse): gather the decode pool's
-    # cached-prefix pages into a staged handoff-shaped bucket, so a
-    # prefill worker can import them into its staging pool and compute
-    # ONLY the uncached suffix. NOT donated — the pool (and the trie's
-    # pages in it) stays live; the bucket is a transient the worker
-    # device_puts away. Pinned by the disagg.prefix_export hlolint
-    # contract (zero host transfers, bucket-not-pool bytes).
-    @jax.jit
-    def export_pages(caches, idx):
-        return [tuple(pool[idx] for pool in layer) for layer in caches
-                if not is_state_entry(layer)]
+    # Page export (disaggregated prefix reuse): the decode pool's cached-prefix
+    # pages into a staged bucket, so a prefill worker computes ONLY the
+    # uncached suffix. NOT donated — the pool (and the trie's pages in it)
+    # stays live. Pinned by the disagg.prefix_export hlolint contract.
+    export_pages = jax.jit(kvcache.export_pages)
 
     ops = (set_block_row, set_block_entry, reset_pages, set_slot,
            set_hist_row, cow_page_copy, export_pages, set_adapter_id)
@@ -309,7 +267,7 @@ class PageAllocator:
     """Host-side refcounted free-list allocator over the global KV page
     pool.
 
-    Pages 0/1 are reserved (NULL/TRASH — models/transformer.py); the rest
+    Pages 0/1 are reserved (NULL/TRASH — models/cache.py); the rest
     are handed out lowest-id-first, all-or-nothing, at refcount 1. The
     radix prefix cache (runtime/radix.py) shares live pages between the
     trie and slot block tables by growing the refcount (``retain``);
@@ -551,10 +509,6 @@ MOE_PROGRAMS = ("decode", "chunk")   # the step programs the loop counts by
 STATE_COUNTERS = {"conv": "conv", "gdn": "linear_attention"}
 KV_WRITE_PATHS = ("page", "token")   # how a chunk's rows reach the paged pool
 
-
-def _first_paged_pool(cfg, caches):
-    """The first PAGED layer's pool (a state layer's entry has no pages)."""
-    return caches[next(i for i in range(cfg.n_layers) if i not in cfg.state_layers)]
 
 LOOP_PHASES = ("admit", "handoff", "dispatch", "prefill", "first_token_wait",
                "first_token", "drain_wait", "emit", "idle", "hop")
@@ -1275,13 +1229,11 @@ class ContinuousBatcher:
         # blocks back in place.
         self._radix = None
         if int(getattr(server, "prefix_cache_size", 0)) > 0:
-            from seldon_core_tpu.models.transformer import \
-                kv_cache_bytes_per_token
             from seldon_core_tpu.runtime.radix import RadixPrefixCache
 
             self._radix = RadixPrefixCache(
                 self._allocator, self.page_size,
-                bytes_per_block=self.page_size * kv_cache_bytes_per_token(
+                bytes_per_block=self.page_size * kvcache.kv_cache_bytes_per_token(
                     cfg, server.kv_cache_dtype))
         self._prefill: Optional[_PrefillJob] = None
         self._admit_seq = 0
@@ -1357,37 +1309,25 @@ class ContinuousBatcher:
         import jax
         import jax.numpy as jnp
 
-        from seldon_core_tpu.models.transformer import init_kv_caches
-
         from functools import partial
 
         server, cfg = self.server, self.server._cfg
-        # the pool inherits the server's KV storage format (int8 halves
-        # the per-step attention read traffic — the dominant b8 term in
-        # benchmarks/DECODE_NOTES.md)
-        from seldon_core_tpu.models.transformer import init_paged_kv_caches
-
-        # the ONE tree the step programs thread and donate: a page pool for
-        # every attention layer and, for a state layer (cfg.layer_types), fixed
-        # blocks a slot with no pages ([slots, taps - 1, dim] for a conv layer;
-        # conv state and a float32 matrix a head for a linear-attention layer)
+        # the ONE tree the step programs thread and donate (models/cache.py),
+        # in the server's KV storage format: a page pool for every attention
+        # layer and, for a state layer (cfg.layer_types), fixed blocks a slot
         self._caches = jax.jit(
-            lambda: init_paged_kv_caches(
+            lambda: kvcache.init_paged_kv_caches(
                 cfg, self.pool_pages, self.page_size, server.kv_cache_dtype,
                 state_slots=self.S)
         )()
         self._cache_nbytes = sum(
             int(getattr(leaf, "nbytes", 0)) for leaf in jax.tree.leaves(self._caches)
         )
-        # state layers by the kind their counters name, and the bytes of ALL
-        # the arrays of their entries (a conv layer's one, a linear-attention
-        # layer's conv state and float32 S)
+        # state layers by the kind their counters name, and their entries' bytes
         self._state_layers = {
             name: len(cfg.layers_of(kind)) for name, kind in STATE_COUNTERS.items()
             if cfg.layers_of(kind)}
-        self.state_nbytes = sum(
-            int(leaf.nbytes) for layer in self._caches if is_state_entry(layer)
-            for leaf in layer)
+        self.state_nbytes = kvcache.state_nbytes(self._caches)
         # which state row a chunk's one sequence continues: its slot, as a
         # device array made once (no transfer a chunk)
         self._state_slot = [jnp.asarray([i], jnp.int32) for i in range(self.S)
@@ -1395,13 +1335,11 @@ class ContinuousBatcher:
         # the step's read is the XLA gather of the whole view, except on one
         # TPU, where a kernel walks the live pages (ops/page_walk.py) — said
         # here so a server's log names it
-        from seldon_core_tpu.models.transformer import kv_cache_bytes_per_token
-
         logger.info(
             "paged KV pool: %d pages x %d tokens, %s, %d B a token (%s), "
             "%.2f GB; decode read: %s", self.pool_pages, self.page_size,
             server.kv_cache_dtype,
-            kv_cache_bytes_per_token(cfg, server.kv_cache_dtype),
+            kvcache.kv_cache_bytes_per_token(cfg, server.kv_cache_dtype),
             "latent rows" if cfg.kv_lora_rank else "per-head K/V",
             self._cache_nbytes / 1e9,
             "gather" if self._read_walk(1) is None else "live_pages")
@@ -1452,7 +1390,7 @@ class ContinuousBatcher:
                 # (the batcher.insert contract in tools/hlolint).
                 dcfg = server._draft_cfg
                 self._draft_caches = jax.jit(
-                    lambda: init_kv_caches(dcfg, self.S, self.max_len))()
+                    lambda: kvcache.init_kv_caches(dcfg, self.S, self.max_len))()
 
                 @partial(jax.jit, donate_argnums=(0,))
                 def draft_insert(big, small, slot):
@@ -2538,13 +2476,11 @@ class ContinuousBatcher:
     def _chunk_write(self, s: int, start: int, n: int) -> Tuple[str, int]:
         """(path, pages) of a chunk of ``s`` rows, ``n`` of them live from
         position ``start``: how a paged layer's write reaches the pool, by the
-        ONE rule the modules take (models/transformer.py
+        ONE rule the modules take (models/cache.py
         ``paged_write_by_page``), and the pool pages it writes: the whole
         pages it reads and writes back, or the pages its live rows lie in."""
-        from seldon_core_tpu.models.transformer import paged_write_by_page, pages_a_run_writes
-
-        if paged_write_by_page(_first_paged_pool(self.server._cfg, self._caches), 1, s):
-            return "page", pages_a_run_writes(s, self.page_size)
+        if kvcache.paged_write_by_page(kvcache.first_paged(self._caches), 1, s):
+            return "page", kvcache.pages_a_run_writes(s, self.page_size)
         return "token", (start + n - 1) // self.page_size - start // self.page_size + 1
 
     def _read_walk(self, s: int):
@@ -2561,7 +2497,7 @@ class ContinuousBatcher:
 
             self._read_walks[s] = None if jax.default_backend() != "tpu" else paged_read_walk(
                 self.server._cfg, s, self.n_pages, self.page_size,
-                _first_paged_pool(self.server._cfg, self._caches)[0].dtype)
+                kvcache.first_paged(self._caches)[0].dtype)
         return self._read_walks[s]
 
     def _rows_read(self, s: int, live_rows: Sequence[int], sequences: int) -> int:

@@ -361,7 +361,7 @@ class PrefillWorker:
         if self._params is None:
             self._params = self.server._params_on(self.device)
         if self._staging is None:
-            from seldon_core_tpu.models.transformer import RESERVED_PAGES
+            from seldon_core_tpu.models.cache import RESERVED_PAGES
 
             # server-cached compile: M workers share one staging-init
             # program; each executes it once onto its own device
@@ -509,8 +509,7 @@ class PrefillWorker:
         import jax
         import jax.numpy as jnp
 
-        from seldon_core_tpu.models.transformer import (
-            NULL_PAGE, PAD_POS, RESERVED_PAGES, TRASH_PAGE)
+        from seldon_core_tpu.models.cache import NULL_PAGE, PAD_POS, RESERVED_PAGES, TRASH_PAGE
         from seldon_core_tpu.runtime.batcher import _page_table_ops
 
         reset_pages = _page_table_ops()[2]

@@ -142,7 +142,7 @@ def replica_load(component: Any) -> Tuple[float, float]:
     b = svc.batcher
     queued = len(b._pending) + sum(
         1 for s in b._slots if s.active or s.prefilling)
-    from seldon_core_tpu.models.transformer import RESERVED_PAGES
+    from seldon_core_tpu.models.cache import RESERVED_PAGES
 
     total, in_use, _ = b._allocator.stats()
     usable = max(total - RESERVED_PAGES, 1)

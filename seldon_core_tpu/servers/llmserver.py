@@ -8,7 +8,7 @@ TPU build's native extension, designed around XLA static shapes:
 - prompts are bucketed to (batch_bucket, len_bucket) so there is ONE compiled
   prefill program per bucket pair and ONE decode program per batch bucket;
 - prefill writes the prompt into the position-tracked KV cache in one pass
-  (padded slots carry PAD_POS and are never attended — models/transformer.py);
+  (padded slots carry PAD_POS and are never attended — models/cache.py);
 - decode is a single ``lax.scan`` over steps: per-sequence cache offsets,
   greedy or temperature/top-k sampling, EOS masking inside the scan — no
   per-token Python dispatch;
@@ -567,7 +567,7 @@ class LLMServer(SeldonComponent):
         import jax.numpy as jnp
 
         from seldon_core_tpu.models import get_model
-        from seldon_core_tpu.models.transformer import normalize_kv_cache_dtype
+        from seldon_core_tpu.models.cache import normalize_kv_cache_dtype
         from seldon_core_tpu.parallel.topology import get_topology
 
         # Resolve the device-world view ONCE; everything below (mesh
@@ -657,7 +657,7 @@ class LLMServer(SeldonComponent):
         if int(self.model_kwargs.get("kv_lora_rank", 0) or 0) > 0:
             # latent attention (models/transformer.py LatentAttention): what
             # is not built is refused here, by name (ROADMAP C1)
-            from seldon_core_tpu.models.transformer import LATENT_INT8_REFUSAL
+            from seldon_core_tpu.models.cache import LATENT_INT8_REFUSAL
 
             if self.kv_cache_dtype == "int8":
                 raise ValueError(LATENT_INT8_REFUSAL)
@@ -1236,7 +1236,7 @@ class LLMServer(SeldonComponent):
             return fn
         import jax
 
-        from seldon_core_tpu.models.transformer import init_kv_caches
+        from seldon_core_tpu.models.cache import init_kv_caches
 
         module, cfg = self._module, self._cfg
         deq = self._dequant
@@ -1467,23 +1467,12 @@ class LLMServer(SeldonComponent):
         if fn is not None:
             return fn
         import jax
-        import jax.numpy as jnp
 
-        from seldon_core_tpu.models.transformer import (NULL_PAGE,
-                                                        RESERVED_PAGES,
-                                                        TRASH_PAGE)
+        from seldon_core_tpu.models import cache as kvcache
 
         @partial(jax.jit, donate_argnums=(0,))
         def import_pages(pools, staged, block_row, n_valid):
-            src = jnp.arange(m) + RESERVED_PAGES
-            tgt = jnp.where(
-                (jnp.arange(m) < n_valid) & (block_row[:m] != NULL_PAGE),
-                block_row[:m], TRASH_PAGE)
-            return [
-                tuple(pool.at[tgt].set(st[src])
-                      for pool, st in zip(pool_layer, staged_layer))
-                for pool_layer, staged_layer in zip(pools, staged)
-            ]
+            return kvcache.import_pages(pools, staged, block_row, n_valid, m)
 
         self._prefill_cache[key] = import_pages
         return import_pages
@@ -1499,7 +1488,7 @@ class LLMServer(SeldonComponent):
             return fn
         import jax
 
-        from seldon_core_tpu.models.transformer import init_paged_kv_caches
+        from seldon_core_tpu.models.cache import init_paged_kv_caches
 
         fn = jax.jit(lambda: init_paged_kv_caches(
             self._cfg, pool_pages, page_size, self.kv_cache_dtype))
@@ -1602,7 +1591,7 @@ class LLMServer(SeldonComponent):
             return fn
         import jax
 
-        from seldon_core_tpu.models.transformer import init_kv_caches
+        from seldon_core_tpu.models.cache import init_kv_caches
 
         module, cfg = self._draft_module, self._draft_cfg
         deq = self._draft_dequant
@@ -1676,8 +1665,7 @@ class LLMServer(SeldonComponent):
         import jax
         import jax.numpy as jnp
 
-        from seldon_core_tpu.models.transformer import (
-            PAD_POS, paged_write_targets)
+        from seldon_core_tpu.models.cache import PAD_POS, forget_positions
 
         module = self._module
         top_k_cfg = self.top_k
@@ -1805,19 +1793,9 @@ class LLMServer(SeldonComponent):
             rej = rcols[None, :] >= a[:, None]
             rpos = jnp.where(rej, next_pos[:, None] + rcols[None, :], PAD_POS)
 
-            def repair(cs, tables):
-                if tables is None:
-                    return [layer[:-1] + (
-                        layer[-1].at[rows, rpos].set(PAD_POS, mode="drop"),)
-                        for layer in cs]
-                ps = cs[0][0].shape[1]
-                entry, off = paged_write_targets(tables, rpos, ps)
-                return [layer[:-1] + (layer[-1].at[entry, off].set(PAD_POS),)
-                        for layer in cs]
-
-            caches = repair(caches, bt)
+            caches = forget_positions(caches, rpos, bt)
             if draft_mode:
-                dcaches = repair(dcaches, None)  # draft cache is dense
+                dcaches = forget_positions(dcaches, rpos)  # draft cache is dense
                 return (caches, new_last, next_pos + a, cur_keys, hist,
                         toks, a, dcaches)
             return (caches, new_last, next_pos + a, cur_keys, hist, toks, a)
@@ -1876,7 +1854,7 @@ class LLMServer(SeldonComponent):
         import jax
         import jax.numpy as jnp
 
-        from seldon_core_tpu.models.transformer import PAD_POS
+        from seldon_core_tpu.models.cache import PAD_POS
 
         max_new = int(max_new_tokens or self.max_new_tokens)
         temp = self.temperature if temperature is None else float(temperature)

@@ -18,16 +18,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from seldon_core_tpu.models.transformer import (
+from seldon_core_tpu.models.cache import (
     PAD_POS,
     RESERVED_PAGES,
-    Attention,
-    TransformerConfig,
-    apply_rotary,
     dequantize_kv,
     init_kv_caches,
     init_paged_kv_caches,
     quantize_kv,
+)
+from seldon_core_tpu.models.transformer import (
+    Attention,
+    TransformerConfig,
+    apply_rotary,
     rotary_embedding,
 )
 

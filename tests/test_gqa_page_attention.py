@@ -13,8 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from seldon_core_tpu.models.transformer import (
-    NULL_PAGE, PAD_POS, TRASH_PAGE, paged_attention_ref)
+from seldon_core_tpu.models.cache import NULL_PAGE, PAD_POS, TRASH_PAGE
+from seldon_core_tpu.models.transformer import paged_attention_ref
 from seldon_core_tpu.ops.gqa_attention import gqa_page_attention, gqa_plan
 from seldon_core_tpu.ops.page_walk import Plan, live_pages, make_visits, rows_visited
 
@@ -338,7 +338,8 @@ def test_attention_through_the_kernel_is_attention_through_the_expression(monkey
     runs, and the same pool."""
     import seldon_core_tpu.ops.gqa_attention as module
     from seldon_core_tpu.models import get_model
-    from seldon_core_tpu.models.transformer import init_paged_kv_caches, paged_live_read
+    from seldon_core_tpu.models.cache import init_paged_kv_caches
+    from seldon_core_tpu.models.transformer import paged_live_read
 
     model = get_model("transformer", **{**GQA_TOY, **more})
     cfg = model.cfg
@@ -397,6 +398,7 @@ def test_the_loop_counts_whole_visits_over_live_rows_for_a_gqa_model(monkeypatch
     model with conv layers asks it of its first PAGED layer."""
     from types import SimpleNamespace
 
+    from seldon_core_tpu.models.cache import StateEntry
     from seldon_core_tpu.models.transformer import TransformerConfig
     from seldon_core_tpu.runtime.batcher import ContinuousBatcher
 
@@ -406,7 +408,8 @@ def test_the_loop_counts_whole_visits_over_live_rows_for_a_gqa_model(monkeypatch
     def loop(cfg, pool_dtype=jnp.bfloat16):
         pool = jnp.zeros((1,), pool_dtype)
         return SimpleNamespace(server=SimpleNamespace(_cfg=cfg), n_pages=64, page_size=64,
-                               _caches=[(jnp.zeros((1,), jnp.float32),), (pool, pool, None)],
+                               _caches=[StateEntry((jnp.zeros((1,), jnp.float32),)),
+                                        (pool, pool, None)],
                                _read_walks={})
 
     def rows_read(loop, *args):

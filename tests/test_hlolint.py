@@ -263,10 +263,7 @@ def test_paged_decode_step_holds_no_expanded_kv():
     expanded copy was 54 ms of a 71 ms step (PERF.md, PR 24)."""
     import jax
 
-    from seldon_core_tpu.models.transformer import (
-        RESERVED_PAGES,
-        init_paged_kv_caches,
-    )
+    from seldon_core_tpu.models.cache import RESERVED_PAGES, init_paged_kv_caches
     from seldon_core_tpu.servers.llmserver import LLMServer
 
     s = LLMServer(

@@ -19,8 +19,13 @@ import numpy as np
 import pytest
 
 from seldon_core_tpu.models import get_model, reference
-from seldon_core_tpu.models.transformer import (
-    PAD_POS, init_kv_caches, init_paged_kv_caches, is_state_entry, short_conv)
+from seldon_core_tpu.models.cache import (
+    PAD_POS,
+    init_kv_caches,
+    init_paged_kv_caches,
+    is_state_entry,
+)
+from seldon_core_tpu.models.transformer import short_conv
 from seldon_core_tpu.runtime.batcher import ContinuousBatcher, _page_table_ops
 from seldon_core_tpu.runtime.resilience import ShedError
 from seldon_core_tpu.servers.llmserver import LLMServer
@@ -291,7 +296,7 @@ def test_the_cache_trees_hold_two_kinds_of_entry(server):
     assert len(paged[2]) == 3 and paged[2][0].shape == (10, 4, 2 * 8)
     with pytest.raises(ValueError, match="state_slots"):
         init_paged_kv_caches(cfg, 10, 4)
-    from seldon_core_tpu.models.transformer import state_bytes, kv_cache_bytes_per_token
+    from seldon_core_tpu.models.cache import state_bytes, kv_cache_bytes_per_token
 
     assert state_bytes(cfg) == 4 * 2 * 32 * 4          # float32 here
     assert kv_cache_bytes_per_token(cfg) == 1 * (2 * 2 * 8 * 4 + 4)

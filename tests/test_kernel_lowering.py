@@ -17,7 +17,7 @@ from jax import export
 
 from seldon_core_tpu import ops
 from seldon_core_tpu.models import get_model
-from seldon_core_tpu.models.transformer import init_paged_kv_caches
+from seldon_core_tpu.models.cache import init_paged_kv_caches
 from seldon_core_tpu.ops.fused_norm import fused_residual_rmsnorm
 from seldon_core_tpu.ops.grouped_matmul import grouped_matmul, make_visits, row_tile
 from seldon_core_tpu.ops.gqa_attention import gqa_page_attention, gqa_plan

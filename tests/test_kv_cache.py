@@ -12,14 +12,14 @@ import jax
 import jax.numpy as jnp
 
 from seldon_core_tpu.models import get_model
-from seldon_core_tpu.models.transformer import (
-    TransformerConfig,
+from seldon_core_tpu.models.cache import (
     dequantize_kv,
     init_kv_caches,
     kv_cache_bytes_per_token,
     normalize_kv_cache_dtype,
     quantize_kv,
 )
+from seldon_core_tpu.models.transformer import TransformerConfig
 from seldon_core_tpu.servers.llmserver import LLMServer
 
 

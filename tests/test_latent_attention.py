@@ -13,8 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from seldon_core_tpu.models.transformer import (
-    NULL_PAGE, PAD_POS, TRASH_PAGE, absorbed_latent_attention, absorbed_query_rows)
+from seldon_core_tpu.models.cache import NULL_PAGE, PAD_POS, TRASH_PAGE
+from seldon_core_tpu.models.transformer import absorbed_latent_attention, absorbed_query_rows
 from seldon_core_tpu.ops.latent_attention import latent_page_attention
 from seldon_core_tpu.ops.page_walk import Plan, live_pages, make_visits, plan, rows_visited
 
@@ -216,7 +216,7 @@ def test_latent_attention_through_the_kernel_is_latent_attention_through_the_exp
     otherwise runs, and the same pool."""
     import seldon_core_tpu.ops.latent_attention as module
     from seldon_core_tpu.models import get_model
-    from seldon_core_tpu.models.transformer import init_paged_kv_caches
+    from seldon_core_tpu.models.cache import init_paged_kv_caches
 
     model = get_model("transformer", **LATENT_TOY)
     cfg = model.cfg

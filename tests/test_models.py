@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from seldon_core_tpu.models import get_model
-from seldon_core_tpu.models.transformer import init_kv_caches
+from seldon_core_tpu.models.cache import init_kv_caches
 
 
 def test_registry_unknown():

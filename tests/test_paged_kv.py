@@ -452,8 +452,7 @@ def test_paged_write_targets_redirect_garbage():
     cannot be corrupted by any host bug."""
     import jax.numpy as jnp
 
-    from seldon_core_tpu.models.transformer import (
-        NULL_PAGE, PAD_POS, TRASH_PAGE, paged_write_targets)
+    from seldon_core_tpu.models.cache import NULL_PAGE, PAD_POS, TRASH_PAGE, paged_write_targets
 
     bt = jnp.asarray([[2, 3, NULL_PAGE]], jnp.int32)
     positions = jnp.asarray(

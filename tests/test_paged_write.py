@@ -18,9 +18,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from seldon_core_tpu.models.transformer import (NULL_PAGE, PAD_POS, TRASH_PAGE,
-                                                paged_attention_ref, paged_write_by_page,
-                                                paged_write_pages, paged_write_targets)
+from seldon_core_tpu.models.cache import (
+    NULL_PAGE,
+    PAD_POS,
+    TRASH_PAGE,
+    paged_write_by_page,
+    paged_write_pages,
+    paged_write_targets,
+)
+from seldon_core_tpu.models.transformer import paged_attention_ref
 
 PAGE, POOL_PAGES, TABLE = 64, 24, 12
 WIDTHS, CHUNKS = (512, 640, 1024, 2048), (128, 256)

@@ -19,7 +19,7 @@ import threading
 
 import pytest
 
-from seldon_core_tpu.models.transformer import RESERVED_PAGES
+from seldon_core_tpu.models.cache import RESERVED_PAGES
 from seldon_core_tpu.runtime.batcher import ContinuousBatcher, PageAllocator
 from seldon_core_tpu.runtime.radix import RadixPrefixCache
 from seldon_core_tpu.servers.llmserver import LLMServer

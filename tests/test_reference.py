@@ -22,8 +22,8 @@ import numpy as np
 import pytest
 
 from seldon_core_tpu.models import get_model, reference
-from seldon_core_tpu.models.transformer import (
-    PAD_POS, TRASH_PAGE, init_paged_kv_caches, moe_routing_stats)
+from seldon_core_tpu.models.cache import PAD_POS, TRASH_PAGE, init_paged_kv_caches
+from seldon_core_tpu.models.transformer import moe_routing_stats
 
 OLMOE = dict(vocab_size=128, dim=64, n_layers=2, n_heads=4, n_kv_heads=4,
              ffn_dim=32, max_seq_len=128, n_experts=16, n_experts_per_token=4,

@@ -80,7 +80,7 @@ def test_reference_and_served_forward_match_transformers_lfm2(pattern):
 def test_a_converted_lfm2_serves_transformers_logits_through_the_batcher(tied):
     """The published layout -> this tree -> LLMServer's dense path: prefill
     into the cache and a decoded row give ``Lfm2ForCausalLM``'s logits."""
-    from seldon_core_tpu.models.transformer import PAD_POS, init_kv_caches
+    from seldon_core_tpu.models.cache import PAD_POS, init_kv_caches
 
     model, torch = hf_model(PATTERNS["published_head"])
     if tied:

@@ -21,9 +21,14 @@ import numpy as np
 import pytest
 
 from seldon_core_tpu.models import get_model, reference
-from seldon_core_tpu.models.transformer import (
-    PAD_POS, TRASH_PAGE, init_kv_caches, init_paged_kv_caches,
-    kv_cache_bytes_per_token, rotary_embedding)
+from seldon_core_tpu.models.cache import (
+    PAD_POS,
+    TRASH_PAGE,
+    init_kv_caches,
+    init_paged_kv_caches,
+    kv_cache_bytes_per_token,
+)
+from seldon_core_tpu.models.transformer import rotary_embedding
 
 YARN = {"type": "yarn", "factor": 40, "original_max_position_embeddings": 16,
         "beta_fast": 32, "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707}

@@ -30,9 +30,13 @@ import pytest
 from seldon_core_tpu.models import get_model, reference
 from seldon_core_tpu.models.convert import (
     config_kwargs_from_hf, convert_hf_model, convert_qwen3_next_state_dict)
-from seldon_core_tpu.models.transformer import (
-    GDN_CHUNK, PAD_POS, gated_delta_rule, init_kv_caches, init_paged_kv_caches, is_state_entry,
-    l2_normalize)
+from seldon_core_tpu.models.cache import (
+    PAD_POS,
+    init_kv_caches,
+    init_paged_kv_caches,
+    is_state_entry,
+)
+from seldon_core_tpu.models.transformer import GDN_CHUNK, gated_delta_rule, l2_normalize
 from seldon_core_tpu.runtime.batcher import ContinuousBatcher, _page_table_ops
 from seldon_core_tpu.servers.llmserver import LLMServer
 
@@ -235,7 +239,7 @@ def test_rows_that_are_no_tokens_leave_both_state_arrays_untouched(served, shape
 
 
 def test_the_cache_trees_hold_a_state_entry_of_two_arrays(served):
-    from seldon_core_tpu.models.transformer import kv_cache_bytes_per_token, state_bytes
+    from seldon_core_tpu.models.cache import kv_cache_bytes_per_token, state_bytes
 
     cfg = served[0].cfg
     dense = init_kv_caches(cfg, 2, 16)

@@ -26,9 +26,8 @@ import numpy as np
 import pytest
 
 from seldon_core_tpu.models import get_model, reference
-from seldon_core_tpu.models.transformer import (
-    FUSED_NORM_STREAMS_REFUSAL, PAD_POS, TRASH_PAGE, HyperConnection, init_kv_caches,
-    init_paged_kv_caches)
+from seldon_core_tpu.models.cache import PAD_POS, TRASH_PAGE, init_kv_caches, init_paged_kv_caches
+from seldon_core_tpu.models.transformer import FUSED_NORM_STREAMS_REFUSAL, HyperConnection
 
 YARN = {"type": "yarn", "factor": 64, "original_max_position_embeddings": 16,
         "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}

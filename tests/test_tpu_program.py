@@ -163,7 +163,7 @@ _COMPILED = {}
 
 
 def _compile(server, program, sharding, slots, length, chunk):
-    from seldon_core_tpu.models.transformer import init_paged_kv_caches
+    from seldon_core_tpu.models.cache import init_paged_kv_caches
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
@@ -610,7 +610,7 @@ def test_the_chunk_writes_whole_pages_in_place(v5e, servers, cell):
     turns it rows-minor for the read's kernel as the parent did, and back.) The
     scopes keep their names: the benchmark's ``mla_chunk_attn_*`` readers find
     their ops by them."""
-    from seldon_core_tpu.models.transformer import pages_a_run_writes
+    from seldon_core_tpu.models.cache import pages_a_run_writes
 
     scope, leaves = CHUNK_WRITES[cell]
     if cell in GQA_CHUNKS:
@@ -738,8 +738,12 @@ def test_the_hybrid_programs_donate_state_and_pool_and_copy_neither(v5e, servers
     PR 35); no op of its own copies or transposes a K / V pool or a state
     block; the bytes donated are the tree's as computed; nothing is sent to or
     fetched from the host; and the conv operator's three scopes are there."""
-    from seldon_core_tpu.models.transformer import (
-        state_bytes, init_paged_kv_caches, is_state_entry, kv_cache_bytes_per_token)
+    from seldon_core_tpu.models.cache import (
+        state_bytes,
+        init_paged_kv_caches,
+        is_state_entry,
+        kv_cache_bytes_per_token,
+    )
 
     server = servers("lfm2")
     cfg = server._cfg
@@ -817,8 +821,12 @@ def test_the_linear_attention_programs_donate_both_state_arrays_and_hold_no_floa
     (ops/gated_delta.py); the temporaries stay a small part of one S; nothing is
     sent to or fetched from the host; and the operator's four scopes are there,
     outside ``attn``."""
-    from seldon_core_tpu.models.transformer import (
-        init_paged_kv_caches, is_state_entry, kv_cache_bytes_per_token, state_bytes)
+    from seldon_core_tpu.models.cache import (
+        init_paged_kv_caches,
+        is_state_entry,
+        kv_cache_bytes_per_token,
+        state_bytes,
+    )
     from seldon_core_tpu.ops.quantize import QuantizedTensor
 
     server = servers("qwen3next")

@@ -362,7 +362,7 @@ def _cache_specs(batch: int):
     need shapes/dtypes, so nothing is materialized."""
     import jax
 
-    from seldon_core_tpu.models.transformer import init_kv_caches
+    from seldon_core_tpu.models.cache import init_kv_caches
 
     s = _base_server()
     return jax.eval_shape(
@@ -374,7 +374,7 @@ def _paged_cache_specs():
     tokens) — shapes/dtypes only, nothing materialized."""
     import jax
 
-    from seldon_core_tpu.models.transformer import init_paged_kv_caches
+    from seldon_core_tpu.models.cache import init_paged_kv_caches
 
     s = _base_server()
     return jax.eval_shape(
@@ -462,7 +462,7 @@ def _build_mla_live_page_read():
     import jax
 
     from seldon_core_tpu.models import get_model
-    from seldon_core_tpu.models.transformer import init_paged_kv_caches
+    from seldon_core_tpu.models.cache import init_paged_kv_caches
 
     model = get_model(
         "transformer", vocab_size=96, dim=MOE_DIM, n_layers=1, n_heads=16, n_kv_heads=16,
@@ -524,7 +524,7 @@ GDN_FLOAT_STACK = (
 def _pool_specs_of(server):
     import jax
 
-    from seldon_core_tpu.models.transformer import init_paged_kv_caches
+    from seldon_core_tpu.models.cache import init_paged_kv_caches
 
     return jax.eval_shape(
         lambda: init_paged_kv_caches(server._cfg, POOL_PAGES, PAGE_SIZE, "bf16",
@@ -659,7 +659,7 @@ def _build_decode_scan_tp2():
 
     s = _tp_server()
     fn = s._get_decode(1, MAX_LEN, donate=True)
-    from seldon_core_tpu.models.transformer import init_kv_caches
+    from seldon_core_tpu.models.cache import init_kv_caches
 
     caches = jax.eval_shape(
         lambda: init_kv_caches(s._cfg, 1, MAX_LEN, s.kv_cache_dtype))
@@ -672,7 +672,7 @@ def _build_batcher_insert():
     landing in its dense [S, max_len] cache (spec_mode='draft')."""
     import jax
 
-    from seldon_core_tpu.models.transformer import init_kv_caches
+    from seldon_core_tpu.models.cache import init_kv_caches
     from seldon_core_tpu.runtime.batcher import ContinuousBatcher
 
     s = _draft_server()
@@ -739,8 +739,7 @@ def _build_handoff_import():
     worker's single-sequence shape: RESERVED_PAGES + pages-per-slot."""
     import jax
 
-    from seldon_core_tpu.models.transformer import (RESERVED_PAGES,
-                                                    init_paged_kv_caches)
+    from seldon_core_tpu.models.cache import RESERVED_PAGES, init_paged_kv_caches
 
     b = _paged_batcher()
     fn = b._get_handoff_import()
@@ -772,7 +771,7 @@ def _build_draft_verify_step_k4():
     dense cache donated through the program alongside the pool."""
     import jax
 
-    from seldon_core_tpu.models.transformer import init_kv_caches
+    from seldon_core_tpu.models.cache import init_kv_caches
 
     s = _draft_server()
     fn = s._get_spec_step(SLOTS, SPEC_K, MAX_LEN, mode="draft",

@@ -202,7 +202,7 @@ def check_decode_cell(topo, model_parallel: int, shape_name: str,
     (donation aliasing: the mid-stream snapshot layout)."""
     import jax
 
-    from seldon_core_tpu.models.transformer import init_kv_caches
+    from seldon_core_tpu.models.cache import init_kv_caches
     from seldon_core_tpu.servers.llmserver import LLMServer
 
     s = LLMServer(
