@@ -92,6 +92,25 @@ def qwen3_next_wrongs(prompt_tokens: int, chunk: int) -> dict:
     }
 
 
+def olmo_hybrid_wrongs(prompt_tokens: int, chunk: int) -> dict:
+    """The wrong references of Olmo-Hybrid (its `work` is "olmo_hybrid"): a dense
+    model, so nothing is followed; the two that break the state's hand-over lie
+    where the probe's chunks end."""
+    padded = -(-prompt_tokens // chunk) * chunk
+    return {
+        "beta_not_doubled": {"gdn_beta_doubled": False}, "decay_left_out": {"gdn_decay": False},
+        "no_l2_norm_on_q_and_k": {"gdn_l2norm": False},
+        "state_zeroed_at_a_chunk_start": {"gdn_reset_every": chunk},
+        "state_from_the_chunks_last_row": {"conv_state_pad": (prompt_tokens, padded)},
+        "taps_reversed": {"taps_reversed": True}, "output_gate_left_out": {"gdn_z_gate": False},
+        "q_not_scaled": {"gdn_q_scale": False},
+        "pre_norm_in_place_of_branch_norm": {"norm_placement": "pre"},
+        "qk_norm_a_head": {"qk_norm": "head_tiled"},
+        "rope_at_theta_500000": {"rope_theta_wrong": 500000.0},
+        "state_held_in_bf16": {"gdn_state_bf16": True},
+    }
+
+
 def merge(base: dict, over: dict) -> dict:
     out = dict(base)
     for k, v in over.items():
@@ -187,8 +206,10 @@ def main() -> None:
             t1 = time.monotonic()
             out = await batcher.submit(prompt, new, info=info, seed=1234 + i)
             print(f"served {len(prompt)} + {len(out)} in {time.monotonic() - t1:.1f}s", flush=True)
-            assert info["routing_start"] == 0
-            served.append((prompt, out, np.stack(info["logits"]), np.stack(info["routing"])))
+            # a dense model routes nothing: there is nothing to follow
+            assert info.get("routing_start", 0) == 0
+            took = np.stack(info["routing"]) if info.get("routing") else None
+            served.append((prompt, out, np.stack(info["logits"]), took))
         await batcher.close()
         return served
 
@@ -218,8 +239,11 @@ def main() -> None:
         ref = np.asarray(ref)
         scale = float(np.abs(ref).max())
         per_row = np.abs(got - ref).max(axis=1) / scale
-        margins = np.stack([np.asarray(layer["margin"]) for layer in routing])
-        behind = np.stack([np.asarray(layer["behind"]) for layer in routing])[:, :first + len(out)]
+        if routing:
+            margins = np.stack([np.asarray(layer["margin"]) for layer in routing])
+            behind = np.stack([np.asarray(layer["behind"]) for layer in routing])[:, :first + len(out)]
+        else:       # a dense model
+            margins, behind = np.ones((0,)), np.zeros((1,))
         apart = {} if sound is None else {
             "from_sound": float(np.abs(ref - sound).max() / np.abs(sound).max())}
         return {"over_scale": float(per_row.max()), "scale": scale, **apart,
@@ -252,7 +276,8 @@ def main() -> None:
         save()
 
     wrongs = WRONGS
-    placed = {"lfm2": lfm2_wrongs, "qwen3_next": qwen3_next_wrongs}.get(cfg.get("work"))
+    placed = {"lfm2": lfm2_wrongs, "qwen3_next": qwen3_next_wrongs,
+              "olmo_hybrid": olmo_hybrid_wrongs}.get(cfg.get("work"))
     if placed:   # a configuration with state layers: some wrongs lie where the probe's chunks end
         wrongs = placed(probe["prompt_tokens"], server_kw.get("prefill_chunk") or 256)
     only = [name for name in args.only_wrongs.split(",") if name]

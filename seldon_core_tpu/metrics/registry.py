@@ -166,6 +166,18 @@ class MetricsRegistry:
             base,
             registry=self.registry,
         )
+        self._state_matrix_bytes = {
+            key: Gauge(f"seldon_llm_state_matrix{suffix}_bytes", text, base,
+                       registry=self.registry)
+            for key, suffix, text in (
+                ("state_matrix_bytes", "",
+                 "Of seldon_llm_state_bytes, the float32 MATRIX state of the "
+                 "linear-attention layers alone: the arrays' own bytes"),
+                ("state_matrix_tiled_bytes", "_tiled",
+                 "What the chip holds for those arrays: their last two axes "
+                 "rounded up to (8, 128) float32 tiles; over "
+                 "seldon_llm_state_matrix_bytes it is the padding every decode "
+                 "step reads and writes (1.0 = none)"))}
         self._kv_page_fragmentation = Gauge(
             "seldon_llm_kv_page_fragmentation",
             "Internal fragmentation of allocated KV pages "
@@ -454,6 +466,15 @@ class MetricsRegistry:
                 ("rows", "Live rows (tokens) of the step-program calls of a "
                          "model with {what} layers: what EACH such layer mixed"),
                 ("layer_calls", "{what} layers x step-program calls"))}
+        # how a decode step program's delta rule runs: the repo's kernel (S
+        # read once and written once) or the expression's two passes over S: a
+        # silent fall-back shows here; absent without linear-attention layers
+        self._gdn_step_path = Counter(
+            "seldon_llm_gdn_step_path",
+            "Decode step programs built over linear-attention layers, by how "
+            "the delta rule's read-modify-write of the matrix state runs: "
+            "path=kernel (ops/gated_delta.py) or path=expression",
+            base + ["path"], registry=self.registry)
         # An MoE model's routing (runtime/batcher.py MoECounters,
         # docs/observability.md "Expert routing"): counted on the loop from
         # arrays that leave the step programs beside their tokens, absent
@@ -1033,6 +1054,8 @@ class MetricsRegistry:
             stats.get("kv_page_fragmentation", 0.0)
         )
         self._state_bytes.labels(**self._base()).set(stats.get("state_bytes", 0))
+        for key, gauge in self._state_matrix_bytes.items():
+            gauge.labels(**self._base()).set(stats.get(key, 0))
         # counter catch-up from the allocator's own tally (sheds happen on
         # the decode hot path, counted locally — same idiom as
         # seldon_resilience_shed_total)
@@ -1098,6 +1121,8 @@ class MetricsRegistry:
         for key, counter in self._state_layers.items():
             for program, n in stats.get(key, {}).items():
                 self._counter_catch_up(counter, n, program=program)
+        for path, n in stats.get("gdn_step_path", {}).items():
+            self._counter_catch_up(self._gdn_step_path, n, path=path)
         for program, tally in stats.get("moe_by_program", {}).items():
             for field, n in tally.items():
                 self._counter_catch_up(self._moe[field], n, program=program)

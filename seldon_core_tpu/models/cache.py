@@ -104,15 +104,45 @@ def is_state_entry(layer) -> bool:
     return isinstance(layer, StateEntry)
 
 
+def pack_state(S: jnp.ndarray, side: int) -> jnp.ndarray:
+    """A linear-attention layer's matrix state as the cache holds it:
+    [b, H, dk, dv] -> [b, H / side, dk, side * dv], ``side`` heads side by side
+    along the lanes (``state_lane_heads``), so that a head whose dv is no whole
+    128-lane tile leaves no padded lane in HBM. At side 1 the array as it is."""
+    if side == 1:
+        return S
+    b, H, dk, dv = S.shape
+    return jnp.swapaxes(S.reshape(b, H // side, side, dk, dv), 2, 3).reshape(
+        b, H // side, dk, side * dv)
+
+
+def unpack_state(S: jnp.ndarray, side: int) -> jnp.ndarray:
+    """``pack_state``'s inverse: [b, H / side, dk, side * dv] -> [b, H, dk, dv]."""
+    if side == 1:
+        return S
+    b, units, dk, lanes = S.shape
+    return jnp.swapaxes(S.reshape(b, units, dk, side, lanes // side), 2, 3).reshape(
+        b, units * side, dk, lanes // side)
+
+
+def state_lane_heads(cfg) -> int:
+    """How many value heads' [dk, dv] share a lane row of the matrix state: the
+    step kernel's rule (ops/gated_delta.py reads the array as it lies)."""
+    from seldon_core_tpu.ops.gated_delta import heads_a_lane_row
+
+    return heads_a_lane_row(cfg.linear_num_value_heads, cfg.linear_value_head_dim)
+
+
 def _state_entry_shapes(cfg, kind: str) -> Tuple[Tuple[Tuple[int, ...], Any], ...]:
     """(shape a sequence, dtype) of each array of a state layer's entry."""
     if kind == "conv":
         return (((cfg.conv_L_cache - 1, cfg.dim), cfg.dtype),)
     channels = (2 * cfg.linear_num_key_heads * cfg.linear_key_head_dim
                 + cfg.linear_num_value_heads * cfg.linear_value_head_dim)
+    side = state_lane_heads(cfg)
     return (((cfg.linear_conv_kernel_dim - 1, channels), cfg.dtype),
-            ((cfg.linear_num_value_heads, cfg.linear_key_head_dim,
-              cfg.linear_value_head_dim), jnp.float32))
+            ((cfg.linear_num_value_heads // side, cfg.linear_key_head_dim,
+              side * cfg.linear_value_head_dim), jnp.float32))
 
 
 def state_bytes(cfg) -> int:
@@ -435,6 +465,26 @@ def first_paged(tree):
 def state_nbytes(tree) -> int:
     """Bytes of ALL the arrays of a tree's state entries."""
     return sum(int(leaf.nbytes) for layer in tree if is_state_entry(layer) for leaf in layer)
+
+
+def tiled_nbytes(shape: Tuple[int, ...], dtype) -> int:
+    """What the chip holds for an array of ``shape``: its last two axes rounded
+    up to whole tiles, (8, 128) of 32-bit values ((16, 128) of 16-bit ones)."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sublanes = 8 * 4 // item
+    return (math.prod(lead) * (-(-rows // sublanes) * sublanes)
+            * (-(-lanes // 128) * 128) * item)
+
+
+def matrix_state_nbytes(tree) -> Tuple[int, int]:
+    """(the arrays' own bytes, the bytes the chip holds for them as it tiles
+    them) of the float32 MATRIX state alone: the second array of every
+    linear-attention entry of a tree (a conv entry has one array, rows). Equal
+    where no lane or sublane of S is padding."""
+    mats = [layer[1] for layer in tree if is_state_entry(layer) and len(layer) == 2]
+    return (sum(math.prod(m.shape) * jnp.dtype(m.dtype).itemsize for m in mats),
+            sum(tiled_nbytes(m.shape, m.dtype) for m in mats))
 
 
 def _attention_entries(tree, fn):

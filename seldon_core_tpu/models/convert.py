@@ -131,6 +131,33 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
             "n_shared_experts": hf_config.shared_expert_intermediate_size // ffn_dim,
             "shared_expert_gate": True,
         }
+    olmo = model_type in ("olmo3", "olmo_hybrid")
+    if olmo:
+        # the Olmo 2 / 3 block: the two norms on the BRANCHES (x + norm(f(x))),
+        # q / k RMSNorms over the whole projection. olmo3 is what the installed
+        # transformers can check (every layer full attention: the native
+        # transformer has no windowed attention). olmo_hybrid (no modeling file
+        # installed: ASSUMED names and readings, models/reference.py) gives 3 of 4
+        # layers a Gated DeltaNet with beta in (0, 2) and takes no rotary
+        # embedding where rope_theta is null
+        kinds = tuple(getattr(hf_config, "layer_types", None) or ())
+        if "sliding_attention" in kinds:
+            raise ValueError(
+                "layer_types with 'sliding_attention' is not supported by the native "
+                "transformer (it has no windowed attention): only where every layer's "
+                "window covers max_position_embeddings is a conversion the same model")
+        moe = {"norm_placement": "branch", "qk_norm": True}
+        if model_type == "olmo_hybrid":
+            rope = getattr(hf_config, "rope_parameters", None) or {}
+            moe.update(
+                layer_types=kinds, rope_theta=rope.get("rope_theta"),
+                linear_num_key_heads=hf_config.linear_num_key_heads,
+                linear_num_value_heads=hf_config.linear_num_value_heads,
+                linear_key_head_dim=hf_config.linear_key_head_dim,
+                linear_value_head_dim=hf_config.linear_value_head_dim,
+                linear_conv_kernel_dim=hf_config.linear_conv_kernel_dim,
+                linear_allow_neg_eigval=bool(hf_config.linear_allow_neg_eigval),
+                linear_dt_bias="range")
     if deepseek:
         # V3's router is sigmoid scores + a selection bias (noaux_tc); its
         # config class carries neither key, V2-shaped configs name both
@@ -182,7 +209,7 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
         "norm_eps": hf_config.norm_eps if lfm2 else hf_config.rms_norm_eps,
         "tie_embeddings": bool(getattr(hf_config, "tie_word_embeddings", False)),
         **({"rope_scaling": rope_scaling} if rope_scaling else {}),
-        **moe,
+        **moe,      # (olmo_hybrid's rope_theta, None, overrides the default above)
     }
 
 
@@ -276,6 +303,67 @@ def convert_llama_state_dict(
         raise ValueError(
             f"unmapped weights in state dict (conversion would drop them): {leftover[:8]}"
         )
+    return {"params": params}
+
+
+def convert_olmo_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str, Any],
+                            dtype: str = "float32") -> Dict[str, Any]:
+    """HF Olmo3ForCausalLM (or, by ASSUMED names, an Olmo-Hybrid) state dict
+    -> our flax param tree. The block's two norms stand on the branches:
+    ``post_attention_layernorm`` is the tree's ``attention_norm`` (or
+    ``operator_norm`` of a linear-attention layer), ``post_feedforward_layernorm``
+    its ``ffn_norm`` (the same two weights a layer, ``cfg.norm_placement``
+    "branch"); ``q_norm`` / ``k_norm`` span the whole projection.
+
+    A linear-attention layer (``linear_attn.*``, the flash-linear-attention
+    GatedDeltaNet layer's names, ASSUMED: no ``olmo_hybrid`` modeling file is
+    installed) has SEPARATE ``q_proj`` / ``k_proj`` / ``v_proj`` / ``g_proj``,
+    ``b_proj`` / ``a_proj`` and ``q_conv1d`` / ``k_conv1d`` / ``v_conv1d``
+    [channels, 1, taps]; the tree holds them stacked, [q ; k ; v ; g] as
+    ``in_proj_qkvz``, [b ; a] as ``in_proj_ba`` and the taps over the channels
+    of [q ; k ; v] as ``conv1d`` (a depthwise convolution over stacked channels
+    is the separate convolutions); ``o_norm`` is the per-head gated norm."""
+    t, consumed = _tensor_reader(state_dict, dtype)
+    params: Dict[str, Any] = {
+        "tok_embeddings": t("model.embed_tokens.weight"),
+        "norm": {"weight": t("model.norm.weight")},
+    }
+    kinds = kwargs.get("layer_types") or ("full_attention",) * kwargs["n_layers"]
+    for i, kind in enumerate(kinds):
+        hf = f"model.layers.{i}"
+        layer = params[f"layer_{i}"] = {
+            "ffn_norm": {"weight": t(f"{hf}.post_feedforward_layernorm.weight")},
+            "ffn": {ours: t(f"{hf}.mlp.{theirs}.weight").T for ours, theirs in
+                    (("w1", "gate_proj"), ("w2", "down_proj"), ("w3", "up_proj"))}}
+        if kind == "linear_attention":
+            la = f"{hf}.linear_attn"
+            layer["operator_norm"] = {"weight": t(f"{hf}.post_attention_layernorm.weight")}
+            layer["linear_attn"] = {
+                "in_proj_qkvz": np.concatenate(
+                    [t(f"{la}.{name}_proj.weight") for name in "qkvg"]).T,
+                "in_proj_ba": np.concatenate([t(f"{la}.b_proj.weight"), t(f"{la}.a_proj.weight")]).T,
+                "conv1d": np.concatenate(
+                    [t(f"{la}.{name}_conv1d.weight")[:, 0, :] for name in "qkv"]),
+                "A_log": t(f"{la}.A_log"),
+                "dt_bias": t(f"{la}.dt_bias"),
+                "norm": {"weight": t(f"{la}.o_norm.weight")},
+                "out_proj": t(f"{la}.o_proj.weight").T,
+            }
+        else:
+            layer["attention_norm"] = {"weight": t(f"{hf}.post_attention_layernorm.weight")}
+            layer["attention"] = {
+                **{ours: t(f"{hf}.self_attn.{theirs}.weight").T for ours, theirs in
+                   (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"), ("wo", "o_proj"))},
+                "q_norm": {"weight": t(f"{hf}.self_attn.q_norm.weight")},
+                "k_norm": {"weight": t(f"{hf}.self_attn.k_norm.weight")},
+            }
+    if not kwargs["tie_embeddings"]:
+        params["lm_head"] = t("lm_head.weight").T
+    leftover = [k for k in state_dict if k not in consumed and not k.endswith("inv_freq")
+                and not (kwargs["tie_embeddings"] and k == "lm_head.weight")]
+    if leftover:
+        raise ValueError(
+            f"unmapped weights in state dict (conversion would drop them): {leftover[:8]}")
     return {"params": params}
 
 
@@ -533,8 +621,8 @@ def convert_deepseek_v2_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str,
 
 def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
     """In-memory transformers LlamaForCausalLM, OlmoeForCausalLM,
-    DeepseekV2ForCausalLM, DeepseekV3ForCausalLM, Lfm2ForCausalLM or
-    Qwen3NextForCausalLM -> (our module, variables)."""
+    DeepseekV2ForCausalLM, DeepseekV3ForCausalLM, Lfm2ForCausalLM,
+    Qwen3NextForCausalLM or Olmo3ForCausalLM -> (our module, variables)."""
     from seldon_core_tpu.models import get_model
 
     kwargs = config_kwargs_from_hf(hf_model.config)
@@ -545,6 +633,8 @@ def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
             state_dict, kwargs, rope_interleaved=getattr(hf_model.config, "rope_interleave", True))
     elif kwargs.get("attn_gate"):      # qwen3_next
         variables = convert_qwen3_next_state_dict(hf_model.state_dict(), kwargs)
+    elif kwargs.get("norm_placement") == "branch":      # olmo3, olmo_hybrid
+        variables = convert_olmo_state_dict(hf_model.state_dict(), kwargs)
     elif kwargs.get("layer_types"):
         variables = convert_lfm2_state_dict(hf_model.state_dict(), kwargs)
     else:
@@ -576,6 +666,8 @@ def convert_checkpoint(hf_path: str, out_dir: str, dtype: str = "bfloat16") -> s
             model.state_dict(), kwargs, dtype, getattr(hf_config, "rope_interleave", True))
     elif kwargs.get("attn_gate"):      # qwen3_next
         variables = convert_qwen3_next_state_dict(model.state_dict(), kwargs, dtype)
+    elif kwargs.get("norm_placement") == "branch":      # olmo3, olmo_hybrid
+        variables = convert_olmo_state_dict(model.state_dict(), kwargs, dtype)
     elif kwargs.get("layer_types"):
         variables = convert_lfm2_state_dict(model.state_dict(), kwargs, dtype)
     else:

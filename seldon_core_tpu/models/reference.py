@@ -141,6 +141,39 @@ the rows of [q ; k ; v] before the taps), ``gdn_silu=False``, ``gdn_z_gate=False
 ``rotary_all=True`` (RoPE over the whole head), ``shared_gate=False``,
 ``leave_out_held=True`` (every token loses the HELD expert it weighs most).
 
+Olmo-Hybrid (``model_type`` ``olmo_hybrid``; no modeling file is installed:
+``transformers`` 4.57.6 has ``olmo3`` and ``qwen3_next``, which
+tests/test_reference_olmo_hybrid.py holds the attention block and the rule to;
+every reading its config.json does not settle is marked *assumed*). With u the
+residual [s, 3840], every matrix without bias:
+
+    block : h = u + RMSNorm_w1(Mixer(u));  out = h + RMSNorm_w2(FFN(h))     (both kinds of
+            layer; cfg.norm_placement "branch": the Olmo 2 / 3 placement, NOTHING normed
+            before the mixer or the FFN; *assumed*, config.json has no key for it)
+            FFN(h) = W_down (SiLU(W_gate h) * (W_up h)); final RMSNorm, untied head
+    attn  : q = RMSNorm(W_q u), k = RMSNorm(W_k u) over the WHOLE projection (cfg.qk_norm
+            True, as OLMoE's and Olmo 3's), v = W_v u; 30 heads of 128, NO rotation
+            (cfg.rope_theta None: *assumed* from ``rope_theta`` null; the 24 recurrent
+            layers carry the order), causal softmax at 128^-1/2, W_o
+    gdn   : the flash-linear-attention GatedDeltaNet layer, whose option names the
+            config's linear_* keys carry: q, k = W_q u, W_k u (30 x 96), v = W_v u
+            (30 x 192), each through its own causal depthwise taps (4) then SiLU; q, k
+            L2-normalised a head, q * 96^-1/2; beta = 2 sigmoid(W_b u)
+            (cfg.linear_allow_neg_eigval: the eigenvalues of I - beta k k^T in (-1, 1]);
+            g = -exp(A_log) softplus(W_a u + dt_bias) a head; the rule above with S
+            [96, 192] float32 a head; o = RMSNorm_192(o) w * SiLU(W_g u) a head; W_o
+            [5760, 3840] (*assumed*: the gate and the per-head gated norm as Qwen3-Next's
+            z; the separate projections are held as the tree's two stacked leaves
+            W_qkvz = [W_q ; W_k ; W_v ; W_g] and W_ba = [W_b ; W_a], and a depthwise
+            convolution over stacked channels IS the separate convolutions: layout,
+            not mathematics)
+
+WRONG models of these: ``gdn_beta_doubled=False`` (beta = sigmoid(b)), ``gdn_q_scale=
+False`` (q not scaled by dk^-1/2), ``norm_placement="pre"`` (x + f(norm(x)) on the same
+weights), ``qk_norm="head_tiled"`` (a norm over EACH head, the whole projection's weight
+cut into the heads' parts), ``rope_theta_wrong=500000.0`` (RoPE where the model has
+none), and Qwen3-Next's ``gdn_*`` / ``taps_reversed`` / ``conv_state_pad`` above.
+
 ``follow=`` makes the forward take the experts the SERVED path took (a logits
 probe's ``routing``): where this router's last chosen and first unchosen expert
 score within the served arithmetic's noise of each other, which one is taken is
@@ -229,7 +262,7 @@ def _rope(x, theta: float, scaling: Optional[dict] = None):
 
 
 def _attention(p: dict, x, cfg, qk_norm=None, gate: bool = True, rotary_all: bool = False,
-               block: int = 512):
+               block: int = 512, rope_theta_wrong: Optional[float] = None):
     """Queries go in blocks of ``block`` rows (a 6 k context needs
     [heads, block, s] of scores at a time; the arithmetic is the same)."""
     s = x.shape[0]
@@ -246,9 +279,14 @@ def _attention(p: dict, x, cfg, qk_norm=None, gate: bool = True, rotary_all: boo
     if qk_norm == "head":
         q = _rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
         k = _rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
-    rotary = hd if rotary_all else int(getattr(cfg, "partial_rotary_factor", 1.0) * hd)
-    q = jnp.concatenate([_rope(q[..., :rotary], cfg.rope_theta), q[..., rotary:]], axis=-1)
-    k = jnp.concatenate([_rope(k[..., :rotary], cfg.rope_theta), k[..., rotary:]], axis=-1)
+    elif qk_norm == "head_tiled":   # a WRONG model of the whole-projection norm: a norm a head
+        q = _rms_norm(q, _f32(p["q_norm"]["weight"]).reshape(cfg.n_heads, hd), cfg.norm_eps)
+        k = _rms_norm(k, _f32(p["k_norm"]["weight"]).reshape(cfg.n_kv_heads, hd), cfg.norm_eps)
+    theta = cfg.rope_theta if rope_theta_wrong is None else rope_theta_wrong
+    if theta is not None:           # None: no rotary embedding at all
+        rotary = hd if rotary_all else int(getattr(cfg, "partial_rotary_factor", 1.0) * hd)
+        q = jnp.concatenate([_rope(q[..., :rotary], theta), q[..., rotary:]], axis=-1)
+        k = jnp.concatenate([_rope(k[..., :rotary], theta), k[..., rotary:]], axis=-1)
     v = v.reshape(s, cfg.n_kv_heads, hd)
     group = cfg.n_heads // cfg.n_kv_heads
     k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
@@ -380,8 +418,12 @@ def _gated_delta_net(p: dict, x, cfg, wrong: dict, seen: Optional[list] = None):
     if wrong["gdn_l2norm"]:
         q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6)
         k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
-    q, k = jnp.repeat(q * dk ** -0.5, hv // hk, axis=1), jnp.repeat(k, hv // hk, axis=1)
+    if wrong["gdn_q_scale"]:
+        q = q * dk ** -0.5
+    q, k = jnp.repeat(q, hv // hk, axis=1), jnp.repeat(k, hv // hk, axis=1)
     beta = jax.nn.sigmoid(ba[:, :hv]) if wrong["gdn_beta"] else jnp.ones((s, hv))
+    if getattr(cfg, "linear_allow_neg_eigval", False) and wrong["gdn_beta_doubled"]:
+        beta = 2.0 * beta
     g = (-jnp.exp(_f32(p["A_log"])) * jax.nn.softplus(ba[:, hv:] + _f32(p["dt_bias"]))
          if wrong["gdn_decay"] else jnp.zeros((s, hv)))
     every = wrong["gdn_reset_every"]
@@ -510,7 +552,11 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
     iters = wrong["sinkhorn_iters"] if wrong["sinkhorn_iters"] is not None else getattr(
         cfg, "hc_sinkhorn_iters", 0)
 
+    placement = wrong["norm_placement"] or getattr(cfg, "norm_placement", "pre")
+
     def sub_layer(x, name, norm, f):
+        if placement == "branch":   # Olmo 2 / 3: the norm on the branch, none before it
+            return x + _rms_norm(f(x), layer[norm]["weight"], cfg.norm_eps)
         if not mixed:
             return x + f(_rms_norm(x, layer[norm]["weight"], cfg.norm_eps))
         u, h_post, h_res = _mix(layer[name], x, cfg, iters)
@@ -522,7 +568,7 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
             return _latent_attention(layer["attention"], n1, cfg, wrong["scale_mscale"],
                                      q_norm=wrong["q_norm"])
         return _attention(layer["attention"], n1, cfg, wrong["qk_norm"], wrong["attn_gate"],
-                          wrong["rotary_all"])
+                          wrong["rotary_all"], rope_theta_wrong=wrong["rope_theta_wrong"])
 
     def ffn(n2):
         if moe:
@@ -561,7 +607,9 @@ WRONG = {"leave_out_rank": None, "scale_mscale": True, "shared": True, "streams"
          "conv_state_pad": None, "qk_norm": None,
          "gdn_decay": True, "gdn_beta": True, "gdn_l2norm": True, "gdn_reset_every": None,
          "gdn_silu": True, "gdn_z_gate": True, "gdn_state_bf16": False, "attn_gate": True,
-         "rotary_all": False, "shared_gate": True, "leave_out_held": False}
+         "rotary_all": False, "shared_gate": True, "leave_out_held": False,
+         "gdn_beta_doubled": True, "gdn_q_scale": True, "norm_placement": None,
+         "rope_theta_wrong": None}
 
 
 def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[list] = None):

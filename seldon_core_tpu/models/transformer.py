@@ -21,12 +21,14 @@ at all; this is the native model family the TPU build adds (SURVEY.md §5
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax.linen import partitioning as nn_partitioning
 
 from seldon_core_tpu.models.cache import (
@@ -36,9 +38,12 @@ from seldon_core_tpu.models.cache import (
     entry_is_int8,
     gather_paged_view,
     normalize_kv_cache_dtype,
+    pack_state,
     put_state,
     quantize_kv,
+    state_lane_heads,
     state_rows,
+    unpack_state,
     write_rows,
 )
 from seldon_core_tpu.models.registry import register_model
@@ -70,7 +75,9 @@ class TransformerConfig:
     n_kv_heads: int = 32
     ffn_dim: int = 11008
     max_seq_len: int = 4096
-    rope_theta: float = 10000.0
+    # None = NO rotary embedding: the attention layers see no position (a model
+    # whose recurrent layers carry the order; per-head K/V attention only)
+    rope_theta: Optional[float] = 10000.0
     # RoPE frequency rescaling: tuple of (key, value) pairs (hashable
     # frozen-dataclass field); None = plain RoPE. Llama-3.x: factor /
     # low_freq_factor / high_freq_factor / original_max_position_embeddings.
@@ -144,6 +151,17 @@ class TransformerConfig:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
+    # beta = 2 sigmoid(b) in place of sigmoid(b): the eigenvalues of
+    # I - beta k k^T then lie in (-1, 1] (flash-linear-attention's
+    # allow_neg_eigval; Olmo-Hybrid). ``linear_dt_bias``: how a seeded dt_bias
+    # is drawn, "ones" (Qwen3-Next's published init) or "range" (the layer's own:
+    # softplus^-1 of exp(U(log 0.001, log 0.1)), a decay a token of e^-1.6 .. ~1).
+    linear_allow_neg_eigval: bool = False
+    linear_dt_bias: str = "ones"
+    # Where a block's two RMSNorms stand: "pre" = x + f(norm(x)) (Llama and
+    # every other family served); "branch" = x + norm(f(x)), nothing normed
+    # before the mixer or the FFN (Olmo 2 / 3). The same two weights a layer.
+    norm_placement: str = "pre"
     # Attention's head width (0 = dim // n_heads, resolved in __post_init__);
     # ``attn_gate``: the query projection makes a gate a head beside the query
     # and the heads' output is multiplied by sigmoid(gate) before wo
@@ -199,6 +217,22 @@ class TransformerConfig:
                 f"unknown router_score {self.router_score!r}: expected 'softmax' or 'sigmoid'")
         if self.fused_norm and self.hc_mult > 1:
             raise ValueError(FUSED_NORM_STREAMS_REFUSAL)
+        if self.norm_placement not in ("pre", "branch"):
+            raise ValueError(
+                f"unknown norm_placement {self.norm_placement!r}: expected 'pre' or 'branch'")
+        if self.norm_placement == "branch" and (
+                self.hc_mult > 1 or self.fused_norm or self.mtp_layers):
+            raise ValueError(
+                "norm_placement='branch' (x + norm(f(x))) is built for the plain residual "
+                "alone: not with hc_mult > 1, fused_norm or an MTP module")
+        if self.rope_theta is None and (self.kv_lora_rank or self.rope_scaling
+                                        or self.partial_rotary_factor != 1.0):
+            raise ValueError(
+                "rope_theta=None (no rotary embedding) is built for per-head K/V attention "
+                "alone: not with latent attention, rope_scaling or partial_rotary_factor")
+        if self.linear_dt_bias not in ("ones", "range"):
+            raise ValueError(
+                f"unknown linear_dt_bias {self.linear_dt_bias!r}: expected 'ones' or 'range'")
         if self.mtp_layers > 1:
             raise ValueError(
                 f"mtp_layers={self.mtp_layers} is not built: one multi-token-prediction "
@@ -249,6 +283,10 @@ class TransformerConfig:
         attention."""
         kinds = self.layer_types
         return kinds[layer] if kinds is not None and layer < len(kinds) else "full_attention"
+
+    def small_leaf(self, name: str) -> str:
+        """The SMALL_LEAF_INIT rule a seeded float32 leaf ``name`` is drawn by."""
+        return "dt_bias_range" if name == "dt_bias" and self.linear_dt_bias == "range" else name
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
         return tuple(i for i in range(self.n_layers) if self.layer_kind(i) == kind)
@@ -575,9 +613,10 @@ class Attention(nn.Module):
             k = RMSNorm(hd, cfg.norm_eps, "head_norm", name="k_norm")(k)
         v = (x @ wv.astype(dt)).reshape(b, s, cfg.n_kv_heads, hd)
 
-        cos, sin = rotary_embedding(positions, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling)
-        q = apply_partial_rotary(q, cos, sin)
-        k = apply_partial_rotary(k, cos, sin)
+        if cfg.rope_theta is not None:
+            cos, sin = rotary_embedding(positions, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling)
+            q = apply_partial_rotary(q, cos, sin)
+            k = apply_partial_rotary(k, cos, sin)
 
         out = None
         if cache is None:
@@ -1111,14 +1150,18 @@ def moe_routing_stats(sown: dict, cfg: TransformerConfig):
 # Gated DeltaNet's (Qwen3-Next's published init where it has one): the four
 # taps a channel normal(0, 1/2) likewise, ``dt_bias`` ones, the gated norm's
 # weight ones, ``A_log`` = log of uniform(0, 16) (LOG_UNIFORM: the pair is the
-# range); the shared expert's scalar gate normal(0, 1/sqrt(dim)).
+# range); the shared expert's scalar gate normal(0, 1/sqrt(dim)). The layer's
+# own dt_bias (flash-linear-attention's GatedDeltaNet; ``cfg.linear_dt_bias``
+# "range"): softplus^-1 of a step dt = exp(U(log 0.001, log 0.1)) (DT_RANGE).
 FLOAT32_AXES = ("hc_maps", "expert_select", "conv_taps", "head_norm", "gdn_scalar", "expert_gate")
 LOG_UNIFORM = "log of uniform"
+DT_RANGE = "softplus inverse of a log-uniform step"
 SMALL_LEAF_INIT = {
     "phi": (0.0, None), "alpha": (0.7, 0.05), "b_pre": (0.0, 0.5), "b_post": (0.0, 0.5),
     "b_res": (0.0, 0.5), "router_bias": (0.0, 0.1), "taps": (0.0, 3 ** -0.5),
     "weight": (1.0, 0.0), "conv1d": (0.0, 0.5), "dt_bias": (1.0, 0.0),
     "A_log": (LOG_UNIFORM, (0.0, 16.0)), "shared_gate": (0.0, None),
+    "dt_bias_range": (DT_RANGE, (1e-3, 1e-1)),
 }
 
 
@@ -1128,6 +1171,10 @@ def draw_small_leaf(name: str, key, shape) -> jnp.ndarray:
         low, high = std
         return jnp.log(jnp.maximum(
             jax.random.uniform(key, shape, jnp.float32, low, high), 1e-6))
+    if mean == DT_RANGE:
+        low, high = std
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, math.log(low), math.log(high)))
+        return dt + jnp.log(-jnp.expm1(-dt))
     if std is None:
         std = float(shape[0]) ** -0.5
     return mean + std * jax.random.normal(key, shape, jnp.float32)
@@ -1324,18 +1371,31 @@ GDN_CHUNK = 64   # rows of a sub-chunk of the delta rule's chunked form
 
 
 def _unit_lower_inverse(a: jnp.ndarray) -> jnp.ndarray:
-    """(I + A)^-1 for strictly lower triangular ``a`` [..., c, c]: A is
-    nilpotent (A^c = 0), so the inverse is the finite product
-    (I - A)(I + A^2)(I + A^4)... of log2(c) factors, batched matmuls in place
-    of the c sequential rows of a forward substitution."""
+    """(I + A)^-1 for strictly lower triangular ``a`` [..., c, c], by halves:
+    the inverse of [[P, 0], [C, Q]] is [[P^-1, 0], [-Q^-1 C P^-1, Q^-1]], so
+    from the 1 x 1 diagonal blocks (inverse 1) up, each of log2(c) levels fills
+    the lower-left quarter of every diagonal block of twice the size, for all
+    blocks at once as ``inv - inv (A . mask) inv`` (inv is block diagonal so
+    far): 2 (log2(c) - 1) batched matmuls, as many as the product below took,
+    in place of the c sequential rows of a forward substitution, and every
+    product IS a block of the answer. (The product
+    (I - A)(I + A^2)(I + A^4)... that stood here is the same in exact
+    arithmetic, but its powers of A grow like binomials before they cancel:
+    with keys that resemble each other and a decay near 1 it lost every digit
+    in float32, PR 45.)"""
     c = a.shape[-1]
-    eye = jnp.eye(c, dtype=a.dtype)
     hp = jax.lax.Precision.HIGHEST
-    inv, power, span = eye - a, a, 1
-    while 2 * span < c:
-        power = jnp.matmul(power, power, precision=hp)
-        inv = jnp.matmul(inv, eye + power, precision=hp)
-        span *= 2
+    i = np.arange(c)
+
+    def quarters(m):   # the lower-left quarters of the diagonal blocks of 2m rows
+        return jnp.where((i[:, None] // (2 * m) == i[None, :] // (2 * m))
+                         & (i[:, None] % (2 * m) >= m) & (i[None, :] % (2 * m) < m), a, 0.0)
+
+    # blocks of two rows need no product: [[1, 0], [a, 1]]^-1 = [[1, 0], [-a, 1]]
+    inv, m = jnp.eye(c, dtype=a.dtype) - quarters(1), 2
+    while m < c:
+        inv = inv - jnp.matmul(jnp.matmul(inv, quarters(m), precision=hp), inv, precision=hp)
+        m *= 2
     return inv
 
 
@@ -1347,14 +1407,17 @@ def gated_delta_rule(q, k, v, g, beta, state, starts=None, kernel: bool = True):
     ``q`` / ``k`` [b, s, H, dk] float32 (L2-normalised a head, q scaled, the
     key heads repeated to the H value heads); ``v`` [b, s, H, dv]; ``g``
     [b, s, H] the LOG of each row's decay (<= 0); ``beta`` [b, s, H];
-    ``state`` [b, H, dk, dv] float32, S before the call's first row;
-    ``starts`` [b] bool or None: the sequences whose S reads as ZEROS whatever
+    ``state`` float32, S before the call's first row, in the CACHE's layout
+    (models/cache.py ``pack_state``: [b, H / side, dk, side * dv], ``side``
+    heads side by side along the lanes, read off the array's own shape;
+    [b, H, dk, dv] where dv is whole lane tiles); ``starts`` [b] bool or None:
+    the sequences whose S reads as ZEROS whatever
     ``state`` holds (a sequence that starts has no past). Per row:
 
         S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;  o_t = S^T q_t
 
     A row with ``beta = 0`` and ``g = 0`` leaves S as it came (a padded row).
-    Returns (o [b, s, H, dv] float32, S after the last row).
+    Returns (o [b, s, H, dv] float32, S after the last row, laid out as it came).
 
     Computed in the CHUNKED form (the WY / UT transform of
     ``torch_chunk_gated_delta_rule`` and the flash-linear-attention kernel):
@@ -1373,17 +1436,21 @@ def gated_delta_rule(q, k, v, g, beta, state, starts=None, kernel: bool = True):
     read once and written once, in its own buffer), chosen by
     ``jax.lax.platform_dependent`` as ``MoEFFN`` chooses its grouped matmul;
     the expression, which XLA makes two passes over S, everywhere else and for
-    a [dk, dv] that is not whole tiles. float32 throughout, the matmuls at the
+    a state that is not whole tiles (``plan``). The step's kernel reads and
+    writes the state AS IT LIES; the expression and the chunked form unpack it
+    (a chunk's one sequence: 2 MB a layer) and pack what they leave. float32
+    throughout, the matmuls at the
     highest precision: they are a thousandth of a chunk's FLOPs."""
     b, s, H, dk = k.shape
     hp = jax.lax.Precision.HIGHEST
+    side = H // state.shape[1]
     if starts is None:
         starts = jnp.zeros((b,), bool)
     if s == 1:
         from seldon_core_tpu.ops.gated_delta import gated_delta_step, plan
 
         def step_expression():
-            S = jnp.where(starts[:, None, None, None], 0.0, state)
+            S = jnp.where(starts[:, None, None, None], 0.0, unpack_state(state, side))
             q1, k1, v1 = q[:, 0], k[:, 0], v[:, 0]                       # [b, H, d]
             decay = jnp.exp(g[:, 0])[..., None]                          # [b, H, 1]
             # S^T k and S^T q out of ONE pass over S; o = e^g S^T q + (k . q) d
@@ -1391,7 +1458,8 @@ def gated_delta_rule(q, k, v, g, beta, state, starts=None, kernel: bool = True):
             sq = jnp.sum(S * q1[..., None], axis=-2)
             d = beta[:, 0][..., None] * (v1 - decay * sk)
             new_state = S * decay[..., None] + k1[..., None] * d[..., None, :]
-            return decay * sq + jnp.sum(k1 * q1, axis=-1, keepdims=True) * d, new_state
+            return (decay * sq + jnp.sum(k1 * q1, axis=-1, keepdims=True) * d,
+                    pack_state(new_state, side))
 
         walk = plan(H, dk, v.shape[-1]) if kernel else None
 
@@ -1404,7 +1472,7 @@ def gated_delta_rule(q, k, v, g, beta, state, starts=None, kernel: bool = True):
         else:
             o, new_state = jax.lax.platform_dependent(tpu=step_kernel, default=step_expression)
         return o[:, None], new_state
-    state = jnp.where(starts[:, None, None, None], 0.0, state)
+    state = jnp.where(starts[:, None, None, None], 0.0, unpack_state(state, side))
     c = min(GDN_CHUNK, s)
     pad = -s % c
     if pad:   # rows that change nothing
@@ -1442,7 +1510,20 @@ def gated_delta_rule(q, k, v, g, beta, state, starts=None, kernel: bool = True):
     new_state, o = jax.lax.scan(sub_chunk, state, (U, W, qk, q_in, k_out, last),
                                 unroll=min(n, 4))
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2).reshape(b, n * c, H, -1)   # [b, s, H, dv]
-    return o[:, :s], new_state
+    return o[:, :s], pack_state(new_state, side)
+
+
+def gdn_step_walk(cfg: "TransformerConfig") -> Optional[Any]:
+    """How the decode step's delta rule goes through the repo's kernel IN A
+    PROGRAM LOWERED FOR A TPU (ops/gated_delta.py ``Plan``), or None where it is
+    the expression (two passes over S) there too: a mesh, a state that is not
+    whole tiles. The facts ``gated_delta_rule`` itself decides by, for the
+    loop's ``seldon_llm_gdn_step_path``."""
+    from seldon_core_tpu.ops.gated_delta import plan
+
+    if cfg.mesh is not None or not cfg.layers_of("linear_attention"):
+        return None
+    return plan(cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim)
 
 
 def l2_normalize(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
@@ -1459,14 +1540,19 @@ class GatedDeltaNet(nn.Module):
         [q ; k ; v] <- SiLU(causal depthwise taps over the channels of [q ; k ; v])
         q, k <- L2-normalised a head (eps 1e-6), q * dk^-1/2
         beta = sigmoid(b)     g = -exp(A_log) * softplus(a + dt_bias)     a value head
+               (2 sigmoid(b) where cfg.linear_allow_neg_eigval: Olmo-Hybrid, whose
+               separate q / k / v / gate and b / a projections are held as these
+               two stacked leaves, Hk = Hv and dk != dv)
         o = gated_delta_rule(q, k, v, g, beta, S)
         out = W_out (RMSNorm_dv(o) * w * SiLU(z))       a value head
 
     What a sequence keeps between calls, whatever its length: the last
     taps - 1 rows of [q ; k ; v] BEFORE the convolution, in the serving dtype
-    (``short_conv``'s state and rule), and S [Hv, dk, dv] in float32, as the
-    published implementation holds it: the cache entry is the 2-tuple
-    ``(conv_state [rows, taps - 1, 2 Hk dk + Hv dv], S [rows, Hv, dk, dv])``.
+    (``short_conv``'s state and rule), and S [dk, dv] a value head in float32,
+    as the published implementation holds it: the cache entry is the 2-tuple
+    ``(conv_state [rows, taps - 1, 2 Hk dk + Hv dv], S)``, S in the layout
+    models/cache.py gives it (``pack_state``: [rows, Hv, dk, dv], or heads side
+    by side along the lanes where dv is no whole lane tile).
     ``state_slots`` is ShortConv's. A sequence that starts (its first row at
     position 0) reads S as zeros, so admission resets nothing; a row that is
     no token has beta = 0 and g = 0 and leaves S as it came. Without a cache:
@@ -1492,8 +1578,8 @@ class GatedDeltaNet(nn.Module):
                                axes=("gdn_channel", "conv_taps"))
         a_log = param_with_axes("A_log", small_leaf_init("A_log"), (hv,), jnp.float32,
                                 axes=("gdn_scalar",))
-        dt_bias = param_with_axes("dt_bias", small_leaf_init("dt_bias"), (hv,), jnp.float32,
-                                  axes=("gdn_scalar",))
+        dt_bias = param_with_axes("dt_bias", small_leaf_init(cfg.small_leaf("dt_bias")), (hv,),
+                                  jnp.float32, axes=("gdn_scalar",))
         w_out = param_with_axes("out_proj", nn.initializers.lecun_normal(), (value_dim, d),
                                 jnp.float32, axes=("gdn_value", "embed"))
         b, s, _ = x.shape
@@ -1517,14 +1603,17 @@ class GatedDeltaNet(nn.Module):
             v = mixed[..., 2 * key_dim:].reshape(b, s, hv, dv)
             live = valid[..., None]
             beta = jnp.where(live, jax.nn.sigmoid(ba[..., :hv]), 0.0)
+            if cfg.linear_allow_neg_eigval:
+                beta = 2.0 * beta
             g = jnp.where(live, -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias), 0.0)
             if state is None:
-                state = jnp.zeros((b, hv, dk, dv), jnp.float32)
+                side = state_lane_heads(cfg)
+                state = jnp.zeros((b, hv // side, dk, side * dv), jnp.float32)
             # a sequence that starts here has no past (a row that is no token
             # starts nothing: a slot's S may be a chunk's to write meanwhile)
             starts = (positions[:, 0] == 0) & valid[:, 0]
             o, new_state = gated_delta_rule(q, k, v, g, beta, state, starts,
-                                            kernel=cfg.mesh is None)
+                                            kernel=gdn_step_walk(cfg) is not None)
         new_cache = put_state(cache, state_slots, (new_conv, new_state))
         with jax.named_scope("mix.gdn.out"):
             normed = RMSNorm(dv, cfg.norm_eps, "head_norm", name="norm")(o)
@@ -1553,23 +1642,32 @@ class TransformerBlock(nn.Module):
         if streams:
             X, (x, h_post, h_res) = x, HyperConnection(cfg, name="attention_hc")(x)
         kind = cfg.layer_kind(self.layer)
+        # the SAME two weights a layer stand before a sub-layer (x + f(norm(x)))
+        # or on its branch (x + norm(f(x)): cfg.norm_placement "branch")
+        branch = cfg.norm_placement == "branch"
+        mixer_norm = RMSNorm(cfg.dim, cfg.norm_eps, name="attention_norm"
+                             if kind == "full_attention" else "operator_norm")
+
+        def mixer_in():
+            return x if branch else mixer_norm(x)
+
         if kind == "conv":
             # the scopes are the operator's own (mix.conv.*): "attn" stays the
             # attention layers'
             h, new_cache = ShortConv(cfg, name="conv")(
-                RMSNorm(cfg.dim, cfg.norm_eps, name="operator_norm")(x), positions, valid,
-                cache, state_slots)
+                mixer_in(), positions, valid, cache, state_slots)
         elif kind == "linear_attention":
             # likewise mix.gdn.*
             h, new_cache = GatedDeltaNet(cfg, name="linear_attn")(
-                RMSNorm(cfg.dim, cfg.norm_eps, name="operator_norm")(x), positions, valid,
-                cache, state_slots)
+                mixer_in(), positions, valid, cache, state_slots)
         else:
             with jax.named_scope("attn"):
                 h, new_cache = attention(cfg, name="attention")(
-                    RMSNorm(cfg.dim, cfg.norm_eps, name="attention_norm")(x), positions, cache,
+                    mixer_in(), positions, cache,
                     cache_index, block_tables, adapters, adapter_ids,
                 )
+        if branch:
+            h = mixer_norm(h)
         ffn_norm = RMSNorm(cfg.dim, cfg.norm_eps, name="ffn_norm")
         if streams:
             X = hc_write_back(X, h, h_post, h_res)
@@ -1585,7 +1683,7 @@ class TransformerBlock(nn.Module):
             x, ffn_in = fused_residual_rmsnorm(x, h, ffn_norm(), cfg.norm_eps)
         else:
             x = x + h
-            ffn_in = ffn_norm(x)
+            ffn_in = x if branch else ffn_norm(x)
         if cfg.n_experts > 0 and self.layer >= cfg.first_dense_layers:
             f = MoEFFN(cfg, name="moe")(ffn_in, valid)
         else:
@@ -1593,7 +1691,7 @@ class TransformerBlock(nn.Module):
             f = DenseFFN(cfg, width, name="ffn")(ffn_in, adapters, adapter_ids)
         if streams:
             return hc_write_back(X, f, h_post, h_res), new_cache
-        return x + f, new_cache
+        return x + (ffn_norm(f) if branch else f), new_cache
 
 
 def enter_streams(x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:

@@ -45,10 +45,21 @@ the gathered view under the Pallas interpreter.
 
 from __future__ import annotations
 
+import math
+
 from seldon_core_tpu.ops.page_walk import LANES, QUERY_TILE, Plan, page_walk_attention, plan
 
 # the name the device trace shows for the kernel
 KERNEL_NAME = "gqa_page_attention"
+
+
+def _row_wide_heads(s: int, heads: int) -> int:
+    """The query heads a sequence's row-wide operand holds: ``heads`` and as
+    many rows of zeros as make ``s`` of them whole bf16 sublane tiles (30 heads
+    a token are 32 rows: two of nothing, read and dropped); fewer heads than one
+    tile are left as they are (``plan`` refuses them)."""
+    unit = 16 // math.gcd(16, s)
+    return heads if heads < unit else -(-heads // unit) * unit
 
 
 def gqa_plan(s: int, heads: int, n_kv_heads: int, head_dim: int, n_pages: int,
@@ -58,7 +69,7 @@ def gqa_plan(s: int, heads: int, n_kv_heads: int, head_dim: int, n_pages: int,
     (or ``128 // head_dim`` neighbouring ones) from there."""
     row = n_kv_heads * head_dim
     if s * heads < QUERY_TILE:
-        return plan(s, heads, n_pages, page_size, row, row, pools=2)
+        return plan(s, _row_wide_heads(s, heads), n_pages, page_size, row, row, pools=2)
     block = max(head_dim, LANES)
     if row % block or block % head_dim:
         return None
@@ -86,8 +97,11 @@ def gqa_page_attention(q, k_pool, v_pool, pos_pool, block_tables, positions,
         in_slot = (jnp.arange(heads)[:, None] // (heads // n_kv_heads) % held
                    == jnp.arange(held)[None, :])                                 # [H, slot]
         ctx = jnp.where(in_slot[:, :, None], q[:, :, :, None, :], 0).reshape(b, s, heads, block)
+    pad = _row_wide_heads(s, heads) - heads if walk.blocks == 1 else 0
+    if pad:
+        ctx = jnp.pad(ctx, ((0, 0), (0, 0), (0, pad), (0, 0)))
     ctx = page_walk_attention(ctx, (k_pool, v_pool), pos_pool, block_tables, positions,
-                              hd**-0.5, block, walk, KERNEL_NAME, interpret)
+                              hd**-0.5, block, walk, KERNEL_NAME, interpret)[:, :, :heads]
     if held > 1:
         ctx = jnp.sum(jnp.where(in_slot[:, :, None], ctx.reshape(b, s, heads, held, hd), 0), axis=3)
     return ctx

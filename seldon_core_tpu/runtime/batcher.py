@@ -508,6 +508,7 @@ MOE_PROGRAMS = ("decode", "chunk")   # the step programs the loop counts by
 # STATE_LAYER_KINDS): seldon_llm_<name>_rows_total / _layer_calls_total
 STATE_COUNTERS = {"conv": "conv", "gdn": "linear_attention"}
 KV_WRITE_PATHS = ("page", "token")   # how a chunk's rows reach the paged pool
+GDN_STEP_PATHS = ("kernel", "expression")   # how a decode step's delta rule runs
 
 
 LOOP_PHASES = ("admit", "handoff", "dispatch", "prefill", "first_token_wait",
@@ -655,6 +656,9 @@ class LoopPhases:
         # layers), and such layers x calls, from host integers at dispatch
         self.state_rows = {kind: dict.fromkeys(MOE_PROGRAMS, 0) for kind in STATE_COUNTERS}
         self.state_layer_calls = {kind: dict.fromkeys(MOE_PROGRAMS, 0) for kind in STATE_COUNTERS}
+        # decode step programs built over linear-attention layers, by how their
+        # delta rule runs (the kernel, or the expression's two passes over S)
+        self.gdn_step_path = dict.fromkeys(GDN_STEP_PATHS, 0)
         self._open: List[_Phase] = []
         self._open_parts: List[_Phase] = []
         self._turn: Optional[Any] = None   # the open turn's annotation
@@ -739,6 +743,8 @@ class LoopPhases:
             if any(self.state_layer_calls[kind].values()):
                 state[f"{kind}_rows"] = dict(self.state_rows[kind])
                 state[f"{kind}_layer_calls"] = dict(self.state_layer_calls[kind])
+        if any(self.gdn_step_path.values()):
+            state["gdn_step_path"] = dict(self.gdn_step_path)
         return {**state,
                 "loop_seconds": dict(self.seconds),
                 "loop_phase_counts": dict(self.counts),
@@ -1328,6 +1334,13 @@ class ContinuousBatcher:
             name: len(cfg.layers_of(kind)) for name, kind in STATE_COUNTERS.items()
             if cfg.layers_of(kind)}
         self.state_nbytes = kvcache.state_nbytes(self._caches)
+        # ... of which the float32 matrix state, as the arrays count it and as
+        # the chip tiles it (equal where S holds no padded lane or sublane)
+        self.state_matrix_nbytes, self.state_matrix_tiled_nbytes = (
+            kvcache.matrix_state_nbytes(self._caches))
+        # the decode step programs seen so far (a new one is counted by the
+        # path its delta rule takes: seldon_llm_gdn_step_path)
+        self._step_programs: set = set()
         # which state row a chunk's one sequence continues: its slot, as a
         # device array made once (no transfer a chunk)
         self._state_slot = [jnp.asarray([i], jnp.int32) for i in range(self.S)
@@ -2500,6 +2513,17 @@ class ContinuousBatcher:
                 kvcache.first_paged(self._caches)[0].dtype)
         return self._read_walks[s]
 
+    def _gdn_step_path(self) -> str:
+        """How a decode step's delta rule runs in this process: the repo's
+        kernel (S read once and written once) where the programs are compiled
+        for a TPU and ``gdn_step_walk`` has a plan, the expression elsewhere."""
+        import jax
+
+        from seldon_core_tpu.models.transformer import gdn_step_walk
+
+        kernel = jax.default_backend() == "tpu" and gdn_step_walk(self.server._cfg) is not None
+        return "kernel" if kernel else "expression"
+
     def _rows_read(self, s: int, live_rows: Sequence[int], sequences: int) -> int:
         """Cached rows the attention read of step-program calls visits, per
         layer, from host integers: ``sequences`` reads of ``s`` query tokens
@@ -2850,6 +2874,8 @@ class ContinuousBatcher:
             "kv_page_fragmentation": max(0.0, min(1.0, frag)),
             "kv_page_sheds": sheds,
             "state_bytes": self.state_nbytes,
+            "state_matrix_bytes": self.state_matrix_nbytes,
+            "state_matrix_tiled_bytes": self.state_matrix_tiled_nbytes,
         }
 
     def spec_stats(self) -> dict:
@@ -2994,6 +3020,9 @@ class ContinuousBatcher:
         with self._phases.part("call"):
             fn = self.server._get_decode_step_paged(
                 self.S, self.n_pages, k, lora=lora)
+            if "gdn" in self._state_layers and (k, lora) not in self._step_programs:
+                self._step_programs.add((k, lora))
+                self._phases.gdn_step_path[self._gdn_step_path()] += 1
             (self._caches, self._last_tok, self._next_pos, self._keys,
              toks, aside) = fn(
                 self.server._params, self._caches, self._last_tok,

@@ -1019,7 +1019,7 @@ class LLMServer(SeldonComponent):
             key = jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
             if keep:
                 # float32 as drawn, by the module's own rule for the leaf
-                leaves.append(draw_small_leaf(leaf_name, key, spec.shape))
+                leaves.append(draw_small_leaf(self._cfg.small_leaf(leaf_name), key, spec.shape))
             elif draw is not None:
                 leaves.append(compiled[draw](key))
             elif jnp.issubdtype(spec.dtype, jnp.floating):
@@ -2104,7 +2104,8 @@ class LLMServer(SeldonComponent):
         fuse = self.decode_fuse_steps
         page_stats = {"kv_pages_total": 0, "kv_pages_in_use": 0,
                       "kv_page_size": 0, "kv_page_fragmentation": 0.0,
-                      "kv_page_sheds": 0, "state_bytes": 0}
+                      "kv_page_sheds": 0, "state_bytes": 0,
+                      "state_matrix_bytes": 0, "state_matrix_tiled_bytes": 0}
         spec_stats = {"spec_mode": self.spec_mode, "spec_k": self.spec_k,
                       "spec_accept_rate": 0.0,
                       "spec_tokens_per_forward": 0.0,

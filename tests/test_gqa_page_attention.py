@@ -92,6 +92,9 @@ CASES = {
         16, 4, 128, 1, [90, NOBODY, 260], 12, {}, Plan(4, 16)),
     "nobody holds any slot": (16, 4, 128, 1, [NOBODY, NOBODY], 12, {}, Plan(4, 16)),
     "table entries no visit divides": (16, 4, 128, 1, [100, 210], 7, {}, Plan(4, 16)),
+    # 30 heads of their own (Olmo-Hybrid): 32 query rows, two of zeros, read and dropped
+    "decode step, 30 heads of 128, rep 1": (30, 30, 128, 1, [100, 37, 380], 12, {}, None),
+    "speculative verify, 30 heads": (30, 30, 128, 3, [100, 2, 380], 12, {}, None),
 }
 
 
@@ -316,6 +319,10 @@ def test_the_walk_at_the_served_shapes():
     narrow = TransformerConfig(dim=192, n_heads=16, n_kv_heads=4, dtype=bf16)   # 4 x 12 = 48
     assert paged_read_walk(narrow, 1, 64, 64, bf16) is None
     assert gqa_plan(1, 4, 2, 128, 64, 64) is None                          # four query rows
+    # 30 heads a token are two bf16 sublane tiles of query rows, two rows of zeros
+    olmo_hybrid = TransformerConfig(dim=3840, n_heads=30, n_kv_heads=30, dtype=bf16)
+    assert paged_read_walk(olmo_hybrid, 1, 16, 64, bf16) == Plan(8, 32)     # 3,840-wide rows
+    assert paged_read_walk(olmo_hybrid, 256, 16, 64, bf16) == Plan(2, 256, blocks=30)
 
 
 GQA_TOY = dict(vocab_size=96, dim=512, n_layers=2, n_heads=16, n_kv_heads=4, ffn_dim=64,

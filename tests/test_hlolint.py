@@ -359,6 +359,27 @@ def test_linear_attention_step_programs_donate_both_state_arrays(name):
     assert reported == []
 
 
+@pytest.mark.parametrize("name", ["llm.gdn_rect_paged_decode_step_s4",
+                                  "llm.gdn_rect_prefill_chunk_c8"])
+def test_a_state_that_is_not_square_stays_float32_in_the_caches_layout(name):
+    """Olmo-Hybrid's block at test dims (ISSUE 45): 6 heads of [32, 64] held
+    two side by side along the lanes, [slots, 3, 32, 128]; both state arrays
+    are donated and aliased with the page pool; S is never narrowed to 16 bits
+    in either layout, and no program holds every slot's S a head a row (the
+    step's kernel reads it as it lies; the chunk unpacks ONE slot's); no
+    transfer."""
+    from tools.hlolint.contracts import all_contracts
+
+    (contract,) = [c for c in all_contracts() if c.name == name]
+    reported, *_ = run_one(contract, checks=("alias", "transfer", "dtype",
+                                             "collective"))
+    assert reported == []
+    if "decode" in name:    # the step carries the kernel, not the expression
+        fn, args = contract.build()
+        assert "gated_delta_step" in fn.trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+
 @pytest.mark.parametrize("name", ["llm.xing4_paged_decode_step_s4",
                                   "llm.xing4_prefill_chunk_c8"])
 def test_stream_step_programs_keep_the_streams_in_the_models_dtype(name):

@@ -95,7 +95,17 @@ QWEN3NEXT = dict(vocab_size=256, dim=2048, n_layers=4, n_heads=16, n_kv_heads=2,
                  rope_theta=1e7, linear_num_key_heads=16, linear_num_value_heads=32,
                  linear_key_head_dim=128, linear_value_head_dim=128, linear_conv_kernel_dim=4,
                  layer_types=("linear_attention",) * 3 + ("full_attention",))
-CONFIGS = {"mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
+# Olmo-Hybrid-7B's period at published widths: three Gated DeltaNet layers (30
+# heads, a state [96, 192] a head, beta in (0, 2)) and one multi-head attention
+# layer (30 heads of 128, no rotary embedding), the norms on the branches, a
+# dense FFN of 11,008
+OLMOHYBRID = dict(vocab_size=256, dim=3840, n_layers=4, n_heads=30, n_kv_heads=30, head_dim=128,
+                  ffn_dim=11008, max_seq_len=1024, dtype="bfloat16", qk_norm=True, norm_eps=1e-6,
+                  rope_theta=None, norm_placement="branch", linear_allow_neg_eigval=True,
+                  linear_dt_bias="range", linear_num_key_heads=30, linear_num_value_heads=30,
+                  linear_key_head_dim=96, linear_value_head_dim=192, linear_conv_kernel_dim=4,
+                  layer_types=("linear_attention",) * 3 + ("full_attention",))
+CONFIGS = {"olmohybrid": OLMOHYBRID, "mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
            "xing4": XING4, "mistral_vocab": MISTRAL_VOCAB, "lfm2": LFM2, "qwen3next": QWEN3NEXT}
 PAGE, POOL_PAGES = 64, 514
 
@@ -903,3 +913,76 @@ def test_the_linear_attention_programs_donate_both_state_arrays_and_hold_no_floa
             matrix, (slots, 2, 16, 128, 128)) and op[3] not in ("parameter", "bitcast",
                                                                "get-tuple-element")]
         assert through == [], through
+
+
+OLMOHYBRID_CELL = (32, 1024)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_a_state_that_is_not_square_lies_unpadded_and_goes_through_the_kernel(
+        v5e, servers, program):
+    """Olmo-Hybrid's period at published widths and the cell's own shapes (32
+    slots x 1,024 tokens): each linear-attention layer's float32 matrix state is
+    ``[32, 15, 96, 384]`` (two heads of [96, 192] side by side along the lanes:
+    whole (8, 128) tiles, where ``[32, 30, 96, 192]`` would be tiled to 256
+    lanes), a parameter that an output aliases; the STEP carries the rule's
+    kernel (ops/gated_delta.py), once a linear layer, and no op of its own
+    yields a whole S in either layout (the expression was two passes over it);
+    the step's attention read of 30 heads walks the live pages (32 query rows,
+    two of zeros); the chunk unpacks ONE slot's S; S is float32 everywhere;
+    the float32 leaves stay float32."""
+    from seldon_core_tpu.models.cache import init_paged_kv_caches, matrix_state_nbytes
+    from seldon_core_tpu.ops.quantize import QuantizedTensor
+
+    server = servers("olmohybrid")
+    cfg = server._cfg
+    slots, length = OLMOHYBRID_CELL
+    pages = slots * length // PAGE + 2
+    assert cfg.state_layers == (0, 1, 2) and cfg.rope_theta is None
+    assert cfg.norm_placement == "branch" and cfg.linear_allow_neg_eigval
+    gdn = server._params["params"]["layer_0"]["linear_attn"]
+    for name, shape in (("in_proj_qkvz", (3840, 17280)), ("in_proj_ba", (3840, 60)),
+                        ("out_proj", (5760, 3840))):
+        assert isinstance(gdn[name], QuantizedTensor) and gdn[name].q.shape == shape, name
+    small = {"conv1d": gdn["conv1d"], "A_log": gdn["A_log"], "dt_bias": gdn["dt_bias"],
+             "norm": gdn["norm"]["weight"]}
+    assert {k: (v.dtype.name, v.shape) for k, v in small.items()} == {
+        "conv1d": ("float32", (11520, 4)), "A_log": ("float32", (30,)),
+        "dt_bias": ("float32", (30,)), "norm": ("float32", (192,))}
+    tree = jax.eval_shape(lambda: init_paged_kv_caches(cfg, pages, PAGE, "bf16", state_slots=slots))
+    packed, a_head = (slots, 15, 96, 384), (slots, 30, 96, 192)
+    assert [leaf.shape for leaf in tree[0]] == [(slots, 3, 11520), packed]
+    assert tree[0][1].dtype == jnp.float32
+    own, tiled = matrix_state_nbytes(tree)
+    assert own == tiled == 3 * slots * 30 * 96 * 192 * 4
+    exe = compiled(server, program, v5e, slots=slots, length=length)
+    hlo = exe.as_text()
+    entry = hlo[hlo.index("\nENTRY"):]
+    assert re.search(r"%pools_0__1_[\w.]* = f32\[32,15,96,384\]\{3,2,1,0:T\(8,128\)", entry)
+    leaves = {m.group(2): int(m.group(3)) for m in re.finditer(
+        r"%(pools_(\d__\d)_)[\w.]* = \S+ parameter\((\d+)\)", entry)}
+    aliased = {int(n) for n in re.findall(r"\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo)}
+    assert len(leaves) == 9 and set(leaves.values()) <= aliased, (leaves, aliased)
+    # S in 16 bits, in either layout, nowhere
+    assert [op for op in own_ops(hlo) if op[1] in ("bf16", "f16") and op[2][1:] in (
+        packed[1:], a_head[1:])] == []
+    assert not re.search(r"\b(infeed|outfeed|send|recv|send-done|recv-done)\(", hlo)
+    for scope in ("mix.gdn.in", "mix.gdn.conv", "mix.gdn.rule", "mix.gdn.out"):
+        assert all(f"layer_{i}/linear_attn/{scope}/" in hlo for i in cfg.state_layers), scope
+    assert "gqa_page_attention" in hlo
+    if program == "decode_step":
+        assert len(re.findall(r"= \([^=]*\) custom-call\([^\n]*gated_delta_step", hlo)) == 3
+        # (32 slots' S a layer are 70.8 MB: the compiler may stage the array
+        # through on-chip memory around the kernel, by asynchronous copies in
+        # slices that a ConcatBitcast joins: movement once each way, no arithmetic)
+        through = [op for op in own_ops(hlo) if op[1] == "f32" and op[2] in (
+            packed, a_head, (slots, 3, 5, 96, 384)) and op[3] not in (
+                "parameter", "bitcast", "get-tuple-element", "copy-start", "copy-done")
+            and "ConcatBitcast" not in op[4]]
+        assert through == [], through
+        # no gathered view of K or V (30 heads a token are 32 query rows)
+        assert exe.memory_analysis().temp_size_in_bytes < slots * length * 3840 * 2
+    else:
+        assert "gated_delta_step" not in hlo
+        # the chunk's one sequence: no op yields every slot's S a head a row
+        assert [op for op in own_ops(hlo) if op[2] == a_head] == []

@@ -345,6 +345,41 @@ def _gdn_server():
         return _STATE["gdn_server"]
 
 
+# Olmo-Hybrid's block at test dims: Gated DeltaNet layers whose state a head is
+# NOT square and no whole lane tile ([32, 64]: two heads side by side along the
+# lanes, models/cache.py pack_state), beta in (0, 2), beside a multi-head
+# attention layer without a rotary embedding; the norms on the branches; a dense FFN
+GDN_RECT_HEADS = (6, 32, 64)    # heads (key = value), key dim, value dim
+GDN_RECT_SIDE = 2               # heads a lane row: 2 x 64 = one lane tile
+
+
+def _gdn_rect_server():
+    with _STATE_LOCK:
+        if "gdn_rect_server" not in _STATE:
+            ensure_platform()
+            from seldon_core_tpu.servers.llmserver import LLMServer
+
+            heads, dk, dv = GDN_RECT_HEADS
+            s = LLMServer(
+                model="transformer",
+                model_kwargs=dict(
+                    vocab_size=96, dim=GDN_DIM, n_layers=3, n_heads=2,
+                    n_kv_heads=2, head_dim=128, ffn_dim=MOE_WIDTH,
+                    max_seq_len=PAGES_PER_SLOT * PAGE_SIZE, qk_norm=True,
+                    rope_theta=None, norm_placement="branch",
+                    linear_allow_neg_eigval=True, linear_dt_bias="range",
+                    linear_num_key_heads=heads, linear_num_value_heads=heads,
+                    linear_key_head_dim=dk, linear_value_head_dim=dv,
+                    layer_types=("linear_attention", "full_attention",
+                                 "linear_attention"),
+                    dtype="bfloat16"),
+                quantize="int8", init_random=True, len_buckets=(PLEN,),
+                seed=7)
+            s.load()
+            _STATE["gdn_rect_server"] = s
+        return _STATE["gdn_rect_server"]
+
+
 def _paged_batcher():
     with _STATE_LOCK:  # nests into _base_server's hold: RLock
         if "paged_batcher" not in _STATE:
@@ -521,6 +556,23 @@ GDN_FLOAT_STACK = (
     r"x(bf16|f16|f32)>", MOE_FLOAT_STACK[1])
 
 
+_RECT_PACKED = (f"{GDN_RECT_HEADS[0] // GDN_RECT_SIDE}x{GDN_RECT_HEADS[1]}"
+                f"x{GDN_RECT_SIDE * GDN_RECT_HEADS[2]}")
+_RECT_A_HEAD = f"{GDN_RECT_HEADS[0]}x{GDN_RECT_HEADS[1]}x{GDN_RECT_HEADS[2]}"
+GDN_RECT_NARROW_STATE = (
+    rf"tensor<({SLOTS}|1)x({_RECT_PACKED}|{_RECT_A_HEAD})x(bf16|f16)>",
+    GDN_NARROW_STATE[1] + " (a state [key dim, value dim] that is not square, two "
+    "heads side by side along the lanes or a head a row)")
+GDN_RECT_UNPACKED_STATE = (
+    rf"tensor<{SLOTS}x{_RECT_A_HEAD}xf32>",
+    "every slot's matrix state a head a row, [slots, heads, key dim, value dim] "
+    "with a value dim of half a lane tile: the cache holds it two heads side by "
+    "side along the lanes ([slots, heads / 2, key dim, 2 x value dim]: no padded "
+    "lane in HBM) and the step's kernel reads and writes it as it lies; this "
+    "array is a re-laid copy of every slot's state a layer a step (the "
+    "expression's two passes over S, or an unpack around the kernel)")
+
+
 def _pool_specs_of(server):
     import jax
 
@@ -626,6 +678,24 @@ def _build_gdn_paged_decode_step():
 def _build_gdn_prefill_chunk():
     """The chunk is told WHICH slot's state it continues (its last operand)."""
     s = _gdn_server()
+    fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
+    return fn, (s._params, _pool_specs_of(s),
+                _sds((1, PAGES_PER_SLOT), "int32"),
+                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
+                _sds((1,), "int32"))
+
+
+def _build_gdn_rect_paged_decode_step():
+    s = _gdn_rect_server()
+    fn = s._get_decode_step_paged(SLOTS, PAGES_PER_SLOT, 1)
+    return fn, (s._params, _pool_specs_of(s), _sds((SLOTS,), "int32"),
+                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
+                _sds((), "float32"),
+                _sds((SLOTS, PAGES_PER_SLOT), "int32"))
+
+
+def _build_gdn_rect_prefill_chunk():
+    s = _gdn_rect_server()
     fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
@@ -1115,6 +1185,34 @@ def all_contracts() -> List[Contract]:
             build=_build_gdn_prefill_chunk,
             donated=(1,),
             forbid_dtypes=(GDN_NARROW_STATE, MOE_DENSE_FORM, GDN_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.gdn_rect_paged_decode_step_s4",
+            description="PAGED decode step of a model whose linear-attention "
+                        "state a head is not square and no whole lane tile "
+                        "(Olmo-Hybrid's block: beta in (0, 2), branch norms, "
+                        "attention without a rotary embedding, a dense FFN): "
+                        "S is donated, stays float32 in the cache's layout "
+                        "(two heads side by side along the lanes) and goes "
+                        "through the rule's kernel as it lies",
+            build=_build_gdn_rect_paged_decode_step,
+            donated=(1, 3, 4),
+            forbid_dtypes=(GDN_RECT_NARROW_STATE, GDN_RECT_UNPACKED_STATE),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.gdn_rect_prefill_chunk_c8",
+            description="chunked admission prefill of the same model: the "
+                        "chunked form unpacks ONE slot's state, continues it "
+                        "in float32 and packs it back into the donated block",
+            build=_build_gdn_rect_prefill_chunk,
+            donated=(1,),
+            forbid_dtypes=(GDN_RECT_NARROW_STATE, GDN_RECT_UNPACKED_STATE),
             lowering_platform="tpu",
             collectives={},
             cost=True,
