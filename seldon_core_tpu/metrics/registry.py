@@ -454,6 +454,12 @@ class MetricsRegistry:
                  "Pool pages a paged layer's write of those chunks wrote: "
                  "the whole pages read and written back (page), the pages "
                  "the live rows lie in (token)"))}
+        self._chunk_head = Counter(
+            "seldon_llm_chunk_head_total",
+            "Prefill chunks by whether the chunk program ran the head: ran=1 a "
+            "prompt's last chunk (for its one last row), ran=0 every chunk "
+            "before (no byte of the head read, no logits written)",
+            base + ["ran"], registry=self.registry)
         # A model with conv layers (models/transformer.py ShortConv): what
         # went through them, counted on the loop from host integers; absent
         # for every other model
@@ -1118,6 +1124,8 @@ class MetricsRegistry:
         for key, counter in self._kv_writes.items():
             for path, n in stats.get(f"kv_{key}", {}).items():
                 self._counter_catch_up(counter, n, path=path)
+        for ran, n in stats.get("chunk_head", {}).items():
+            self._counter_catch_up(self._chunk_head, n, ran=ran)
         for key, counter in self._state_layers.items():
             for program, n in stats.get(key, {}).items():
                 self._counter_catch_up(counter, n, program=program)

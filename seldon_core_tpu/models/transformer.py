@@ -1743,7 +1743,7 @@ class Transformer(nn.Module):
     @nn.compact
     def __call__(self, tokens, positions=None, caches=None, cache_index=None,
                  block_tables=None, adapters=None, adapter_ids=None, next_tokens=None,
-                 state_slots=None):
+                 state_slots=None, head_row=None):
         """tokens: [b, s] int32. Returns (logits [b, s, vocab], new_caches).
         With ``next_tokens`` ([b, s] int32: each position's NEXT token) and
         cfg.mtp_layers, a cache-less forward also returns the MTP module's
@@ -1761,7 +1761,13 @@ class Transformer(nn.Module):
 
         ``state_slots`` ([b] int32) names the row of the state layers' blocks
         each sequence continues (ShortConv, GatedDeltaNet); None = row i is
-        sequence i's."""
+        sequence i's.
+
+        ``head_row`` (int32 scalar, traced) is the ONE row of ``s`` whose logits
+        the caller reads: the head runs for that row alone and the logits are
+        [b, 1, vocab]; negative = the caller reads none, and the head does not
+        run (zeros of that shape come back). A prefill chunk's
+        (servers/llmserver.py ``_get_prefill_chunk``)."""
         from seldon_core_tpu.ops.quantize import QuantizedTensor, dequantize_array, lookup_rows
 
         cfg = self.cfg
@@ -1804,16 +1810,31 @@ class Transformer(nn.Module):
                 layer_adapters, adapter_ids, valid, state_slots)
             new_caches.append(nc)
         hidden = leave_streams(x, cfg)
-        x = RMSNorm(cfg.dim, cfg.norm_eps, name="norm")(hidden)
-        if cfg.tie_embeddings:
-            # a plain matmul wants the floating matrix (test configs only)
-            head = (dequantize_array(emb) if isinstance(emb, QuantizedTensor) else emb).T
-        else:
-            head = param_with_axes(
-                "lm_head", nn.initializers.normal(stddev=0.02), (cfg.dim, cfg.vocab_size),
-                jnp.float32, axes=("embed", "vocab"),
-            )
-        logits = x.astype(jnp.float32) @ head
+        x = RMSNorm(cfg.dim, cfg.norm_eps, name="norm")(hidden).astype(jnp.float32)
+        head = emb if cfg.tie_embeddings else param_with_axes(
+            "lm_head", nn.initializers.normal(stddev=0.02), (cfg.dim, cfg.vocab_size),
+            jnp.float32, axes=("embed", "vocab"),
+        )
+
+        def logits_of(rows):
+            # a matrix that arrives int8 (a served head, a tied table) is
+            # dequantized HERE, where it is multiplied
+            w = dequantize_array(head) if isinstance(head, QuantizedTensor) else head
+            return rows @ (w.T if cfg.tie_embeddings else w)
+
+        if head_row is not None:
+            # the row is taken BEFORE the head (the product is a row's own), and
+            # a caller that reads none pays for no head. The norm stays ahead of
+            # the conditional, over all rows as everywhere (no dearer than inside
+            # it: PERF.md section 7): its row is then the all-rows form's to the
+            # bit, which the sampler's parity with generate() rests on. An int8 head arrives as it is held
+            # (servers/llmserver.py load()): dequantized ahead of the conditional,
+            # the whole matrix would be written out every call
+            row = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(head_row, 0), 1, axis=1)
+            return jax.lax.cond(
+                head_row >= 0, logits_of,
+                lambda rows: jnp.zeros((b, 1, cfg.vocab_size), jnp.float32), row), new_caches
+        logits = logits_of(x)
         if cfg.mtp_layers and (next_tokens is not None or self.is_initializing()):
             # the embedding and the head are the main model's, the norms its own
             if caches is not None:
@@ -1822,7 +1843,7 @@ class Transformer(nn.Module):
             mtp = MTPModule(cfg, name="mtp")(
                 hidden, lookup_rows(emb, after, cfg.dtype), positions, valid)
             if next_tokens is not None:
-                return logits, new_caches, mtp.astype(jnp.float32) @ head
+                return logits, new_caches, logits_of(mtp.astype(jnp.float32))
         return logits, new_caches
 
 

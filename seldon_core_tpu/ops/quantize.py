@@ -27,7 +27,9 @@ ops without a chip"):
 - the head (``lm_head``, a float32 matmul) is the first case: its dequant is
   inside the matmul's fusion, which reads the int8 parameter (at xing4's
   width 470 MB in 0.62 ms a step, 92 % of the HBM's peak: PERF.md section 6,
-  PR 34);
+  PR 34). The server hands it to the module int8 and the module dequantizes
+  it where it multiplies by it (the same fusion in a step; in a prefill chunk
+  that is inside a conditional, and no dequant is moved into a branch);
 - a ROW LOOKUP (the embedding table ``tok_embeddings [vocab, dim]``): the
   dequant is NOT pushed through the gather. Dequantized before the lookup, the
   whole table was converted and written out in bf16 (``vocab x dim x 3``

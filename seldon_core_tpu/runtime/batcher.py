@@ -651,6 +651,9 @@ class LoopPhases:
         # scatter row a token) and the pool pages a layer's write wrote
         self.kv_chunk_writes = dict.fromkeys(KV_WRITE_PATHS, 0)
         self.kv_pages_written = dict.fromkeys(KV_WRITE_PATHS, 0)
+        # prefill chunks by whether the program's conditional ran the head:
+        # "1" a prompt's last chunk (for its one last row), "0" the rest
+        self.chunk_head = {"1": 0, "0": 0}
         # live rows through the state layers of each kind (a model with
         # layer_types: "conv" the short convolutions, "gdn" the linear-attention
         # layers), and such layers x calls, from host integers at dispatch
@@ -758,7 +761,8 @@ class LoopPhases:
                 "attn_context_tokens": dict(self.attn_context_tokens),
                 "attn_rows_read": dict(self.attn_rows_read),
                 "kv_chunk_writes": dict(self.kv_chunk_writes),
-                "kv_pages_written": dict(self.kv_pages_written)}
+                "kv_pages_written": dict(self.kv_pages_written),
+                "chunk_head": dict(self.chunk_head)}
 
     def count_attention(self, program: str, context_tokens: int, rows_read: int) -> None:
         self.attn_calls[program] += 1
@@ -1826,10 +1830,10 @@ class ContinuousBatcher:
                 self.max_len - plen, max_new, self.max_len, plen)
         return ids[-plen:], plen
 
-    def _sample_first(self, logits, idx: int, seed: Optional[int],
+    def _sample_first(self, logits, seed: Optional[int],
                       resume_tokens: int = 0):
-        """The prompt's first token, drawn ON THE DEVICE from
-        ``logits[0, idx]`` by the sampler every decode step uses
+        """The prompt's first token, drawn ON THE DEVICE from the one row
+        ``logits`` [1, 1, vocab] holds by the sampler every decode step uses
         (``LLMServer._get_first_token``: split -> lax.top_k descending ->
         categorical -> gather, argmax under temperature <= 0), on exactly
         generate()'s rng chain (PRNGKey -> one split per emitted token, the
@@ -1846,7 +1850,6 @@ class ContinuousBatcher:
         ``resume_tokens`` splits and drawing through the one sampler
         reproduces it bit-exactly (greedy takes no notice of the key)."""
         import jax
-        import jax.numpy as jnp
 
         # Per-request rng: an explicit seed reproduces generate(seed=...)'s
         # exact chain; otherwise derive an independent key from the batcher
@@ -1859,10 +1862,9 @@ class ContinuousBatcher:
             key = fast_forward_key(seed, resume_tokens)
         else:
             key = jax.random.PRNGKey(int(seed))
-        return self.server._get_first_token()(
-            logits, jnp.asarray(idx, jnp.int32), key, self._temp)
+        return self.server._get_first_token()(logits, key, self._temp)
 
-    def _commit_slot(self, i: int, logits, idx: int, seed: Optional[int],
+    def _commit_slot(self, i: int, logits, seed: Optional[int],
                      L: int, max_new: int, fut: asyncio.Future,
                      on_token: Optional[Any],
                      ids: Optional[List[int]] = None,
@@ -1870,8 +1872,9 @@ class ContinuousBatcher:
                      req: Optional[Any] = None,
                      info: Optional[dict] = None, asides: Sequence = ()):
         """Activation, shared by local admission (``_activate``) and a
-        consumed handoff: draw the first token from ``logits[0, idx]`` on
-        the device, thread it and the new occupant's state into the device
+        consumed handoff: draw the first token from the prompt's last row,
+        ``logits`` [1, 1, vocab] (the last chunk's, or a worker's), on the
+        device, thread it and the new occupant's state into the device
         arrays, and queue a ``_FirstToken`` record behind the steps already
         in flight. NO host read: the slot joins the decode batch at the
         next dispatch, and its first token is surfaced when the record
@@ -1884,7 +1887,7 @@ class ContinuousBatcher:
         import jax.numpy as jnp
 
         resume_tokens = req.resume_tokens if req is not None else 0
-        first, key, row = self._sample_first(logits, idx, seed, resume_tokens)
+        first, key, row = self._sample_first(logits, seed, resume_tokens)
         slot = self._slots[i]
         slot.active = True
         slot.prefilling = False
@@ -2243,7 +2246,7 @@ class ContinuousBatcher:
             # the worker read the logits row on its own slice; it goes
             # through the same sampler and the same record as a local one
             self._commit_slot(job.slot, jnp.asarray(h.first_logits[None, None]),
-                              0, job.seed, job.L, job.max_new, job.fut,
+                              job.seed, job.L, job.max_new, job.fut,
                               job.on_token, ids=job.ids,
                               t_arrival=job.t_arrival, req=job.req,
                               info=job.info)
@@ -2453,23 +2456,28 @@ class ContinuousBatcher:
             pos = np.full((1, C), PAD_POS, np.int32)
             toks[0, :n] = ids
             pos[0, :n] = np.arange(start, start + n)
+            # the one row somebody reads: the prompt's last, in its last chunk
+            last = start + n >= job.L
             t0 = time.perf_counter()
             toks, pos = jnp.asarray(toks), jnp.asarray(pos)
+            head_row = np.int32(n - 1 if last else -1)   # (goes over with the call)
         with self._phases.part("call"):
             if self._adapters is not None:
                 fn = self.server._get_prefill_chunk(C, self.n_pages, lora=True)
                 aid = job.req.adapter_id if job.req is not None else 0
                 logits, self._caches, aside = fn(
                     self.server._params, self._caches, job.bt_row,
-                    toks, pos, self._adapters.pool(),
+                    toks, pos, head_row, self._adapters.pool(),
                     jnp.asarray([aid], jnp.int32))
             else:
                 fn = self.server._get_prefill_chunk(C, self.n_pages)
                 # a model with conv layers: the chunk continues ITS slot's state
                 extra = () if self._state_slot is None else (self._state_slot[job.slot],)
                 logits, self._caches, aside = fn(
-                    self.server._params, self._caches, job.bt_row, toks, pos, *extra)
+                    self.server._params, self._caches, job.bt_row, toks, pos,
+                    head_row, *extra)
         job.next = start + n
+        self._phases.chunk_head[str(int(last))] += 1
         self._phases.count_attention("chunk", start + n, self._rows_read(C, [start + n], 1))
         self._phases.count_chunk_write(*self._chunk_write(C, start, n))
         if self._state_layers:
@@ -2479,12 +2487,12 @@ class ContinuousBatcher:
             # dispatch wall (enqueue-only)
             event = self._flight.record(
                 job.slot, EV_PREFILL_CHUNK, start=start, tokens=n,
-                dur_s=time.perf_counter() - t0)
+                head=int(last), dur_s=time.perf_counter() - t0)
         if self._moe is not None:
             job.asides.append((aside, event, start, n))
-        if job.next >= job.L:
+        if last:
             with self._phases.part("activate"):
-                self._activate(job, logits, n - 1)
+                self._activate(job, logits)
 
     def _chunk_write(self, s: int, start: int, n: int) -> Tuple[str, int]:
         """(path, pages) of a chunk of ``s`` rows, ``n`` of them live from
@@ -2551,13 +2559,13 @@ class ContinuousBatcher:
             if event is not None:
                 event.update(self._moe.flight_fields(stats))
 
-    def _activate(self, job: _PrefillJob, logits, idx: int):
+    def _activate(self, job: _PrefillJob, logits):
         """Paged admission, final phase, all of it enqueued: point the
         slot's DEVICE block-table row at the real pages (decode writes
         route through it from the next dispatch; in-flight steps still see
         the trash row in program order) and commit the slot into the decode
-        batch with its first token drawn from the last chunk's
-        ``logits[0, idx]`` on the device. The job is done with its last
+        batch with its first token drawn from the last chunk's one row of
+        ``logits`` on the device. The job is done with its last
         chunk's enqueue: the next admission can start on the next turn."""
         import jax.numpy as jnp
 
@@ -2565,7 +2573,7 @@ class ContinuousBatcher:
             self._block_tables, jnp.asarray(job.slot, jnp.int32),
             job.bt_row[0])
         self._prefill = None
-        self._commit_slot(job.slot, logits, idx, job.seed, job.L,
+        self._commit_slot(job.slot, logits, job.seed, job.L,
                           job.max_new, job.fut, job.on_token, ids=job.ids,
                           t_arrival=job.t_arrival, req=job.req,
                           info=job.info, asides=job.asides)

@@ -540,7 +540,6 @@ class PrefillWorker:
         fn = self.server._get_prefill_chunk(C, self.n_pages)
         L = len(req.ids)
         logits = None
-        n = 0
         start = n_pre * self.page_size if n_pre else 0
         while start < L:
             part = req.ids[start:start + C]
@@ -549,11 +548,13 @@ class PrefillWorker:
             pos = np.full((1, C), PAD_POS, np.int32)
             toks[0, :n] = part
             pos[0, :n] = np.arange(start, start + n)
-            logits, self._staging, _ = fn(self._params, self._staging, bt_row,
-                                          jnp.asarray(toks), jnp.asarray(pos))
             start += n
+            # the head runs for the prompt's last row alone, in its last chunk
+            head_row = np.int32(n - 1 if start >= L else -1)
+            logits, self._staging, _ = fn(self._params, self._staging, bt_row,
+                                          jnp.asarray(toks), jnp.asarray(pos), head_row)
         # graftlint: allow-host-sync-in-hot-path(admission-time sync on the PREFILL worker thread, once per request: the LAST chunk's logits seed the first sampled token; the decode slice never blocks on it)
-        first_logits = np.asarray(logits[0, n - 1]).astype(np.float32)
+        first_logits = np.asarray(logits[0, 0]).astype(np.float32)
         # Ship only a power-of-two page bucket covering the pages THIS
         # worker wrote (the suffix — imported prefix pages never travel
         # back: the decode side still holds their originals), not the

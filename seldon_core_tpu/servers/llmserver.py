@@ -801,8 +801,18 @@ class LLMServer(SeldonComponent):
                         "(the q/k/v projections)", held)
             # expert stacks and the embedding table stay int8 inside the
             # programs: MoEFFN's grouped matmul and the token lookup take them
-            # as they are (ops/quantize.py)
-            self._dequant = partial(dequantize_params, keep_consumed=True)
+            # as they are (ops/quantize.py). So does the head, in every program:
+            # Transformer dequantizes it where it multiplies by it, which in a
+            # prefill chunk is inside a conditional, and the compiler moves no
+            # dequant into a branch (tests/test_tpu_program.py)
+            def dequant(params):
+                weights = dequantize_params(params, keep_consumed=True)
+                if "lm_head" in params["params"]:
+                    weights = {**weights, "params": {
+                        **weights["params"], "lm_head": params["params"]["lm_head"]}}
+                return weights
+
+            self._dequant = dequant
 
         if self.mesh is not None:
             from seldon_core_tpu.parallel.sharding import shard_params
@@ -1334,14 +1344,14 @@ class LLMServer(SeldonComponent):
 
     def _get_first_token(self):
         """Compiled first-token draw for the ContinuousBatcher's activation:
-        ``(logits [1, T, vocab], idx, key [2], temperature)`` ->
-        ``(token, key', row)``, all on the device. ``row`` is
-        ``logits[0, idx]`` in float32 (what a probe that asked for logits
-        gets), the token is `_slot_sampler`'s draw on that one row with the
-        request's key, and ``key'`` is the key the slot decodes on: the
-        prompt's first token leaves the device through the batcher's drain
-        like any step's, never by a sync of its own. ``idx`` is traced, so
-        one compile serves every prompt length of a chunk shape."""
+        ``(logits [1, 1, vocab], key [2], temperature)`` ->
+        ``(token, key', row)``, all on the device. ``logits`` is the one row
+        the last chunk ran its head for (``_get_prefill_chunk``), ``row`` is
+        that row in float32 (what a probe that asked for logits gets), the
+        token is `_slot_sampler`'s draw on it with the request's key, and
+        ``key'`` is the key the slot decodes on: the prompt's first token
+        leaves the device through the batcher's drain like any step's, never
+        by a sync of its own."""
         key = ("first_token",)
         fn = self._decode_cache.get(key)
         if fn is not None:
@@ -1352,9 +1362,8 @@ class LLMServer(SeldonComponent):
         sample = _slot_sampler(self.top_k)
 
         @jax.jit
-        def first_token(logits, idx, key, temperature):
-            row = jax.lax.dynamic_index_in_dim(
-                logits[0], idx, axis=0, keepdims=False).astype(jnp.float32)
+        def first_token(logits, key, temperature):
+            row = logits[0, 0].astype(jnp.float32)
             keys, tok = sample(key[None], row[None], temperature)
             return tok[0], keys[0], row
 
@@ -1382,8 +1391,7 @@ class LLMServer(SeldonComponent):
         [b, s, n_moe_layers, k], the experts every row took, which only a
         logits probe reads (its reference follows them); empty for a dense one."""
         if self._cfg.n_experts == 0:
-            logits, caches = self._module.apply(
-                self._dequant(params), tokens, **kwargs)
+            logits, caches = self._module.apply(self._dequant(params), tokens, **kwargs)
             return logits, caches, {}
         from seldon_core_tpu.models.transformer import moe_choices, moe_routing_stats
 
@@ -1403,7 +1411,16 @@ class LLMServer(SeldonComponent):
         its whole compile bucket (Sarathi-Serve-style chunked prefill;
         Agrawal et al., OSDI 2024). The pool pytree is donated: the scatter
         updates in place, and the batcher threads the returned pool into
-        the next dispatch. Returns (logits [1, chunk, vocab], pools, aside):
+        the next dispatch.
+
+        ``head_row`` (int32 scalar, traced) is the row whose logits the caller
+        will read: the prompt's last, in the chunk that holds it, and negative
+        in every chunk before. The head runs for that one row (taken after the
+        final norm, which stays over all rows: the row is then the all-rows
+        form's bit for bit), under a ``lax.cond`` on
+        ``head_row >= 0``: a chunk that is not a prompt's last reads no byte of
+        the head and writes no logits (zeros come back; nobody reads them). ONE program a chunk shape either way.
+        Returns (logits [1, 1, vocab] float32, pools, aside):
         ``_forward_with_aside``'s entries, for the chunk's live rows."""
         key = ("pchunk", chunk, n_pages, lora)
         fn = self._prefill_cache.get(key)
@@ -1419,12 +1436,12 @@ class LLMServer(SeldonComponent):
             # states its KV is computed FROM (the k/v projections stay
             # base — runtime/adapters.py, the KV-purity invariant)
             @partial(jax.jit, donate_argnums=(1,))
-            def prefill_chunk(params, pools, block_row, tokens, positions,
+            def prefill_chunk(params, pools, block_row, tokens, positions, head_row,
                               adapter_pool, adapter_ids):
                 return forward(
                     params, tokens, positions=positions, caches=pools,
-                    block_tables=block_row, adapters=adapter_pool,
-                    adapter_ids=adapter_ids,
+                    block_tables=block_row, head_row=head_row,
+                    adapters=adapter_pool, adapter_ids=adapter_ids,
                 )
         else:
             # ``state_slots``: a model with state layers (cfg.layer_types: conv,
@@ -1432,10 +1449,11 @@ class LLMServer(SeldonComponent):
             # beside the pages, and its chunk is told WHICH slot's state it
             # continues and leaves behind
             @partial(jax.jit, donate_argnums=(1,))
-            def prefill_chunk(params, pools, block_row, tokens, positions, state_slots=None):
+            def prefill_chunk(params, pools, block_row, tokens, positions, head_row,
+                              state_slots=None):
                 return forward(
                     params, tokens, positions=positions, caches=pools,
-                    block_tables=block_row, state_slots=state_slots,
+                    block_tables=block_row, head_row=head_row, state_slots=state_slots,
                 )
 
         self._prefill_cache[key] = prefill_chunk

@@ -330,13 +330,11 @@ def test_first_token_program_is_the_step_sampler_on_one_row(sampled):
 
     row = np.zeros((96,), np.float32)
     row[[3, 50, 51, 90]] = 2.0          # four-way tie at the top
-    logits = np.zeros((1, 4, 96), np.float32)
-    logits[0, 2] = row
     temp = jnp.asarray(0.8, jnp.float32)
     for seed in range(8):
         key = jax.random.PRNGKey(seed)
         tok, key_out, row_out = sampled._get_first_token()(
-            jnp.asarray(logits, jnp.bfloat16), jnp.asarray(2, jnp.int32), key, temp)
+            jnp.asarray(row[None, None], jnp.bfloat16), key, temp)
         keys, want = _slot_sampler(sampled.top_k)(key[None], jnp.asarray(row)[None], temp)
         assert int(tok) == int(want[0])
         assert np.array_equal(np.asarray(key_out), np.asarray(keys[0]))

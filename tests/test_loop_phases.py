@@ -364,7 +364,8 @@ def test_step_programs_lower_identically_inside_and_outside_a_phase(server):
         return (
             decode.lower(server._params, b._caches, b._last_tok, b._next_pos,
                          b._keys, b._temp, b._block_tables).as_text(),
-            chunk.lower(server._params, b._caches, bt_row, toks, toks).as_text())
+            chunk.lower(server._params, b._caches, bt_row, toks, toks,
+                        jnp.asarray(7, jnp.int32)).as_text())
 
     outside = lowered()
     b._phases.turn(0)

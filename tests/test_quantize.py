@@ -577,14 +577,16 @@ def test_the_lookup_table_is_read_off_the_logical_axes():
 
 
 @pytest.mark.parametrize("case,extra", [
-    ("untied", {}), ("tied", {"tie_embeddings": True}), ("mtp", {"mtp_layers": 1})])
+    ("untied", {}), ("tied", {"tie_embeddings": True}), ("mtp", {"mtp_layers": 1}),
+    ("tied-mtp", {"tie_embeddings": True, "mtp_layers": 1})])
 def test_served_logits_equal_the_whole_table_dequants(case, extra):
     """A quantized LLMServer's prefill then decode, the table gathered int8 (as
     load() marks it), against the parent's expression on the same tree (the
     table dequantized whole ahead of the module, then indexed): the same
     logits and the same caches exactly, with an untied head, a tied one (which
     dequantizes the table for its matmul inside the module) and, cache-less,
-    the MTP module's second lookup."""
+    the MTP module's second lookup, before an untied head and before a tied one
+    (the table transposed and dequantized where the MTP's rows multiply by it)."""
     import dataclasses
 
     from seldon_core_tpu.servers.llmserver import LLMServer
@@ -595,7 +597,7 @@ def test_served_logits_equal_the_whole_table_dequants(case, extra):
     server.load()
     is_q = lambda x: isinstance(x, QuantizedTensor)  # noqa: E731
     table = server._params["params"]["tok_embeddings"]
-    assert table.lookup and ("lm_head" in server._params["params"]) == (case != "tied")
+    assert table.lookup and ("lm_head" in server._params["params"]) == ("tie_embeddings" not in extra)
     assert is_q(server._dequant(server._params)["params"]["tok_embeddings"])
     as_before = jax.tree.map(
         lambda t: dataclasses.replace(t, lookup=False) if is_q(t) else t,
@@ -620,7 +622,7 @@ def test_served_logits_equal_the_whole_table_dequants(case, extra):
     at = jnp.asarray([[len(prompt)]], jnp.int32)
     same(step(server._params, got_cache, last, at, jnp.asarray([len(prompt)])),
          step(as_before, want_cache, last, at, jnp.asarray([len(prompt)])))
-    if case == "mtp":
+    if "mtp_layers" in extra:
         whole = jax.jit(lambda p: server._module.apply(
             server._dequant(p), tokens[:, :-1], next_tokens=tokens[:, 1:]))
         served = whole(server._params)

@@ -605,7 +605,8 @@ def _build_moe_prefill_chunk(chunk: int = PAGE_SIZE):
     fn = s._get_prefill_chunk(chunk, PAGES_PER_SLOT)
     return fn, (s._params, _moe_pool_specs(),
                 _sds((1, PAGES_PER_SLOT), "int32"),
-                _sds((1, chunk), "int32"), _sds((1, chunk), "int32"))
+                _sds((1, chunk), "int32"), _sds((1, chunk), "int32"),
+                _sds((), "int32"))
 
 
 def _build_moe_prefill_chunk_pages():
@@ -627,7 +628,8 @@ def _build_mla_prefill_chunk():
     fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
-                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"))
+                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
+                _sds((), "int32"))
 
 
 def _build_xing4_paged_decode_step():
@@ -644,7 +646,8 @@ def _build_xing4_prefill_chunk():
     fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
-                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"))
+                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
+                _sds((), "int32"))
 
 
 def _build_hybrid_paged_decode_step():
@@ -663,7 +666,7 @@ def _build_hybrid_prefill_chunk():
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
                 _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
-                _sds((1,), "int32"))
+                _sds((), "int32"), _sds((1,), "int32"))
 
 
 def _build_gdn_paged_decode_step():
@@ -682,7 +685,7 @@ def _build_gdn_prefill_chunk():
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
                 _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
-                _sds((1,), "int32"))
+                _sds((), "int32"), _sds((1,), "int32"))
 
 
 def _build_gdn_rect_paged_decode_step():
@@ -700,7 +703,7 @@ def _build_gdn_rect_prefill_chunk():
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
                 _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
-                _sds((1,), "int32"))
+                _sds((), "int32"), _sds((1,), "int32"))
 
 
 def _build_prefill():
@@ -767,8 +770,8 @@ def _build_first_token():
     that replaced the host-side draw must not reach the host either."""
     s = _base_server()
     return s._get_first_token(), (
-        _sds((1, PAGE_SIZE, s._cfg.vocab_size), "bfloat16"),
-        _sds((), "int32"), _sds((2,), "uint32"), _sds((), "float32"))
+        _sds((1, 1, s._cfg.vocab_size), "float32"),
+        _sds((2,), "uint32"), _sds((), "float32"))
 
 
 def _build_paged_decode_step():
@@ -785,7 +788,8 @@ def _build_prefill_chunk():
     fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
     return fn, (s._params, _paged_cache_specs(),
                 _sds((1, PAGES_PER_SLOT), "int32"),
-                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"))
+                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
+                _sds((), "int32"))
 
 
 def _build_set_block_row():
