@@ -460,6 +460,13 @@ class MetricsRegistry:
             "prompt's last chunk (for its one last row), ran=0 every chunk "
             "before (no byte of the head read, no logits written)",
             base + ["ran"], registry=self.registry)
+        self._chunk_rows = Counter(
+            "seldon_llm_chunk_rows_total",
+            "Prompt rows (tokens) prefilled by chunks, by the width of the chunk "
+            "program that took them: the batcher's prefill_chunk, or its wide "
+            "chunk's rows while more than those were left of a prompt and no "
+            "other live slot streamed (always full: rows over width is chunks)",
+            base + ["width"], registry=self.registry)
         # A model with conv layers (models/transformer.py ShortConv): what
         # went through them, counted on the loop from host integers; absent
         # for every other model
@@ -1126,6 +1133,8 @@ class MetricsRegistry:
                 self._counter_catch_up(counter, n, path=path)
         for ran, n in stats.get("chunk_head", {}).items():
             self._counter_catch_up(self._chunk_head, n, ran=ran)
+        for width, n in stats.get("chunk_rows", {}).items():
+            self._counter_catch_up(self._chunk_rows, n, width=width)
         for key, counter in self._state_layers.items():
             for program, n in stats.get(key, {}).items():
                 self._counter_catch_up(counter, n, program=program)

@@ -144,7 +144,9 @@ pool (``kv_pool_pages``) oversubscribes: more concurrent slots per HBM byte,
 with page-exhaustion shedding (503 + Retry-After, runtime/resilience.py
 ShedError) as the relief valve — the decode loop never raises. The chunked
 admission (``prefill_chunk``; Sarathi-Serve, Agrawal et al., OSDI 2024) means
-a 2k-token prompt never stalls in-flight decodes for a whole prompt's forward.
+a 2k-token prompt never stalls in-flight decodes for a whole prompt's forward
+(a chunk is wider while no other slot streams, for a model that routes
+experts: ``_chunk_width``).
 Page bookkeeping is host-side (PageAllocator, lock-guarded); block-table
 updates are jitted device ops that serialize behind in-flight steps in device
 program order.
@@ -180,6 +182,13 @@ logger = logging.getLogger(__name__)
 
 DEFAULT_PAGE_SIZE = 64
 DEFAULT_PREFILL_CHUNK = 256
+# ... and the rows of a WIDE chunk, which a prompt's next chunk is while more
+# than that many of its rows are left and no other live slot streams
+# (``ContinuousBatcher._chunk_width``): a chunk streams every weight it touches
+# once whatever its rows, and a routed expert sees a few dozen of 256 rows
+# (docs/performance.md "Chunked prefill"). One constant, so two chunk programs
+# a server; a multiple of the delta rule's 64-row sub-chunks and of the page
+WIDE_PREFILL_CHUNK = 512
 
 
 def pow2_bucket(n: int, cap: int) -> int:
@@ -193,6 +202,19 @@ def pow2_bucket(n: int, cap: int) -> int:
     while b < n:
         b <<= 1
     return min(b, cap)
+
+
+def _load_program(lowered, after: Optional[threading.Thread]) -> None:
+    """Compile a lowered step program, or load it from the compile cache, once
+    ``after`` has ended (two loads side by side took as long as one after the
+    other, and slowed whatever else loaded then: v5e, PR 48): a thread's whole
+    job (``ContinuousBatcher._build_chunk_programs``); it touches no batcher."""
+    if after is not None:
+        after.join()
+    try:
+        lowered.compile()
+    except Exception:   # the call that needs the program builds it, and raises what is wrong
+        logger.warning("a step program was not built ahead of its first call", exc_info=True)
 
 
 def _page_table_ops():
@@ -654,6 +676,9 @@ class LoopPhases:
         # prefill chunks by whether the program's conditional ran the head:
         # "1" a prompt's last chunk (for its one last row), "0" the rest
         self.chunk_head = {"1": 0, "0": 0}
+        # live rows (prompt tokens) by the width of the chunk program that took
+        # them; a wide chunk is always full, so its rows over its width are chunks
+        self.chunk_rows: Dict[str, int] = {}
         # live rows through the state layers of each kind (a model with
         # layer_types: "conv" the short convolutions, "gdn" the linear-attention
         # layers), and such layers x calls, from host integers at dispatch
@@ -762,7 +787,8 @@ class LoopPhases:
                 "attn_rows_read": dict(self.attn_rows_read),
                 "kv_chunk_writes": dict(self.kv_chunk_writes),
                 "kv_pages_written": dict(self.kv_pages_written),
-                "chunk_head": dict(self.chunk_head)}
+                "chunk_head": dict(self.chunk_head),
+                "chunk_rows": dict(self.chunk_rows)}
 
     def count_attention(self, program: str, context_tokens: int, rows_read: int) -> None:
         self.attn_calls[program] += 1
@@ -1230,6 +1256,15 @@ class ContinuousBatcher:
         chunk = int(prefill_chunk if prefill_chunk is not None else
                     getattr(server, "prefill_chunk", 0) or 0)
         self.prefill_chunk = chunk or DEFAULT_PREFILL_CHUNK
+        # a width somebody gave is every chunk's; the default widens where the
+        # model routes experts: a second chunk program costs a server's start
+        # seconds (a Mistral server's warm start 5 of 33), which a model whose
+        # chunk deals its rows out over many experts' weights buys back, and
+        # which was not asked of the dense servers (PERF.md section 6, PR 48)
+        self.prefill_wide = 0 if chunk or not cfg.n_experts else WIDE_PREFILL_CHUNK
+        # rows -> the thread that loads that chunk program (``_build_chunk_programs``);
+        # None before the first chunk
+        self._chunk_loads: Optional[Dict[int, threading.Thread]] = None
         self._allocator = PageAllocator(self.pool_pages, ps)
         # Radix prefix cache (runtime/radix.py, docs/performance.md "Radix
         # prefix cache"): prefix caching opted in. The trie
@@ -1786,6 +1821,8 @@ class ContinuousBatcher:
         self._wakeup.set()
         if self._task is not None:
             await self._task
+        for load in (self._chunk_loads or {}).values():
+            await asyncio.to_thread(load.join)
         if self._remote is not None:
             # bounded worker joins (runtime/disagg.py close uses timeouts);
             # workers first — their last frames must land before the
@@ -2447,7 +2484,7 @@ class ContinuousBatcher:
             return
         import time
 
-        C = job.chunk
+        C = self._chunk_width(job)
         start = job.next
         with self._phases.part("build"):
             ids = job.ids[start:start + C]
@@ -2473,11 +2510,16 @@ class ContinuousBatcher:
                 fn = self.server._get_prefill_chunk(C, self.n_pages)
                 # a model with conv layers: the chunk continues ITS slot's state
                 extra = () if self._state_slot is None else (self._state_slot[job.slot],)
-                logits, self._caches, aside = fn(
-                    self.server._params, self._caches, job.bt_row, toks, pos,
-                    head_row, *extra)
+                args = (self.server._params, self._caches, job.bt_row, toks, pos,
+                        head_row, *extra)
+                if self._chunk_loads is None and self.max_len - 1 > self.prefill_wide > 0:
+                    self._build_chunk_programs(C, job.chunk, args)
+                if self._chunk_loads and (load := self._chunk_loads.pop(C, None)):
+                    load.join()     # its thread loads it: not this call too
+                logits, self._caches, aside = fn(*args)
         job.next = start + n
         self._phases.chunk_head[str(int(last))] += 1
+        self._phases.chunk_rows[str(C)] = self._phases.chunk_rows.get(str(C), 0) + n
         self._phases.count_attention("chunk", start + n, self._rows_read(C, [start + n], 1))
         self._phases.count_chunk_write(*self._chunk_write(C, start, n))
         if self._state_layers:
@@ -2493,6 +2535,55 @@ class ContinuousBatcher:
         if last:
             with self._phases.part("activate"):
                 self._activate(job, logits)
+
+    def _build_chunk_programs(self, width: int, narrow: int, args: tuple) -> None:
+        """A batcher's FIRST chunk (``width`` rows, called with ``args``) builds
+        both of its chunk programs, the wide one and the ``narrow`` one, so that
+        the second costs a start next to nothing: this thread traces and lowers
+        one after the other (Python, which threads do not share), and a thread
+        each compiles them, or loads them from the compile cache, one after the
+        other (the compiler's and the runtime's C++): the second program is
+        traced while the first one loads, and loads while the first request
+        goes on to its first token and the decode step's trace. The calls that
+        follow find trace, lowering and executable in jax's own caches (shapes
+        and placements are the calls' own); a call whose program still loads
+        waits for it (``_chunk_loads``). Built when first called, one behind the
+        other, the second program cost a warm start of the Mistral servers
+        4.9-5.4 s of their 33-39 s (trace 1.8, to MLIR 0.5, load 2.5-3.1:
+        PERF.md section 6, PR 48). Nothing runs here and no array is read.
+        ``close()`` joins the threads."""
+        import jax
+
+        def shape(x):   # (an array nobody placed resolves like a bare shape)
+            placed = isinstance(x, jax.Array) and x.committed
+            return jax.ShapeDtypeStruct(np.shape(x), np.result_type(x),
+                                        sharding=x.sharding if placed else None)
+
+        shapes = jax.tree.map(shape, args)
+        self._chunk_loads, before = {}, None
+        for rows in (width, narrow if width == self.prefill_wide else self.prefill_wide):
+            rows_of = jax.ShapeDtypeStruct((1, rows), np.int32)
+            lowered = self.server._get_prefill_chunk(rows, self.n_pages).lower(
+                *shapes[:3], rows_of, rows_of, *shapes[5:])
+            before = threading.Thread(target=_load_program, args=(lowered, before),
+                                      name=f"chunk-{rows}-load", daemon=True)
+            before.start()
+            self._chunk_loads[rows] = before
+
+    def _chunk_width(self, job: _PrefillJob) -> int:
+        """Rows of the job's NEXT chunk, chosen when it is built from what the
+        loop sees: the wide program while more than its rows are left of the
+        prompt (so a wide chunk is always full, and the prompt's last chunk,
+        whose row the head reads, is the narrow program) and no OTHER live
+        slot streams (a stream's gap stays a step and one narrow chunk; the
+        job's own stream only gets its first token sooner); the job's own
+        width otherwise."""
+        wide = self.prefill_wide
+        if job.L - job.next > wide > 0 and not any(
+                s.active and s.on_token is not None
+                for i, s in enumerate(self._slots) if i != job.slot):
+            return wide
+        return job.chunk
 
     def _chunk_write(self, s: int, start: int, n: int) -> Tuple[str, int]:
         """(path, pages) of a chunk of ``s`` rows, ``n`` of them live from

@@ -314,7 +314,8 @@ def test_moe_step_programs_hold_no_dense_form_and_no_float_stack(name):
 
 
 @pytest.mark.parametrize("name", ["llm.mla_paged_decode_step_s4",
-                                  "llm.mla_prefill_chunk_c8"])
+                                  "llm.mla_prefill_chunk_c8",
+                                  "llm.mla_prefill_chunk_c32"])
 def test_latent_step_programs_never_expand_the_cached_view(name):
     """DeepSeek-V2's block at test dims (ISSUE 29): the absorbed read leaves
     no floating [view rows, heads, head width] array, the MoE promises hold

@@ -38,6 +38,12 @@ Sixth (PR 47): a prefill chunk runs the head for the ONE row
 its caller reads, inside a conditional on that row's index: a chunk that is
 not a prompt's last reads no byte of the head, and none writes ``[1, chunk,
 vocab]`` logits.
+
+Seventh (PR 48): the WIDE chunk program (``WIDE_PREFILL_CHUNK`` rows, which a
+long prompt's chunks are while no other slot streams) is held to all of the
+above at DeepSeek-V2-Lite's and LFM2's cell shapes (a model that routes experts
+runs it), and the head's conditional at Mistral's vocabulary: the same tests
+take it as one more case.
 """
 
 import re
@@ -45,6 +51,8 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+
+from seldon_core_tpu.runtime.batcher import WIDE_PREFILL_CHUNK as WIDE
 
 FLOATS = ("bf16", "f16", "f32")
 # one layer at the published widths; the vocabulary is not what is looked at
@@ -168,6 +176,8 @@ def compiled(server, program: str, sharding, slots: int = 32, length: int = 0, c
     (256 tokens into a 4096-token slot) over a pool of POOL_PAGES; with
     ``length``, ``slots`` slots of that many tokens over a fully provisioned pool."""
     # (several tests read one program: compiled once a module)
+    if program == "wide_chunk":
+        program, chunk = "prefill_chunk", WIDE
     key = (id(server), program, slots, length, chunk)
     if key not in _COMPILED:
         _COMPILED[key] = _compile(server, program, sharding, slots, length, chunk)
@@ -344,7 +354,7 @@ def test_no_transposed_copy_of_a_weight(v5e, servers, config, program):
 
 @pytest.mark.parametrize("config,program", [
     ("olmoe_moe", "decode_step"), ("olmoe_moe", "prefill_chunk"),
-    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk"),
+    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk"), ("deepseek", "wide_chunk"),
     ("xing4", "decode_step"), ("xing4", "prefill_chunk")])
 def test_the_routed_experts_run_the_repos_grouped_matmul(v5e, servers, config, program):
     """Three Mosaic kernels of the repo's own an MoE layer (gate, up, down),
@@ -360,7 +370,8 @@ def test_the_routed_experts_run_the_repos_grouped_matmul(v5e, servers, config, p
     calls = [line for line in hlo.splitlines()
              if re.match(rf"\s*%{KERNEL_NAME}[\w.]* = ", line)]
     assert len(calls) == 3 * cfg.n_moe_layers
-    rows = (32 if program == "decode_step" else 256) * cfg.n_experts_per_token
+    rows = {"decode_step": 32, "prefill_chunk": 256, "wide_chunk": WIDE}[program]
+    rows *= cfg.n_experts_per_token
     shapes = sorted(re.search(r"= (\w+\[[\d,]+\])", line).group(1) for line in calls)
     assert shapes == sorted([f"f32[{rows},{cfg.ffn_dim}]"] * 2 + [f"f32[{rows},{cfg.dim}]"])
     assert all('custom_call_target="tpu_custom_call"' in line for line in calls)
@@ -427,7 +438,7 @@ LATENT_CELLS = {"deepseek": (8, 16384), "xing4": (32, 4096)}
 
 
 @pytest.mark.parametrize("config,program", [
-    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk"),
+    ("deepseek", "decode_step"), ("deepseek", "prefill_chunk"), ("deepseek", "wide_chunk"),
     ("xing4", "decode_step"), ("xing4", "prefill_chunk")])
 def test_the_latent_read_walks_the_live_pages_and_holds_no_view(v5e, servers, config, program):
     """At the cells' own shapes the read under ``attn.latent.read`` is ONE
@@ -449,7 +460,7 @@ def test_the_latent_read_walks_the_live_pages_and_holds_no_view(v5e, servers, co
     assert all('custom_call_target="tpu_custom_call"' in line for line in calls)
     assert all("attn.latent.read" in line for line in calls)
     sequences = slots if program == "decode_step" else 1
-    rows = 1 if program == "decode_step" else 256
+    rows = {"decode_step": 1, "prefill_chunk": 256, "wide_chunk": WIDE}[program]
     assert all(f"= bf16[{sequences},{rows * cfg.n_heads},512]" in line for line in calls)
     # a visit's pages are operands of the pool itself, not of a copy
     pool = f"bf16[{slots * pages + 2},{PAGE},640]"
@@ -575,7 +586,8 @@ def test_on_a_mesh_narrow_heads_keep_flat_rows_and_no_pool_is_copied():
 # block's query heads; Mistral's chat server runs two (perf/configs)
 GQA_CHUNKS = {"mistral docs": ("mistral", 256, 4096, 128, 4), "mistral chat 128": ("mistral", 128, 1024, 128, 4),
               "mistral chat 256": ("mistral", 256, 1024, 128, 4), "olmoe chat": ("olmoe", 256, 1024, 128, 1),
-              "lfm2 rag": ("lfm2", 256, 4096, 128, 8), "qwen3next longctx": ("qwen3next", 256, 8192, 256, 8)}
+              "lfm2 rag": ("lfm2", 256, 4096, 128, 8), "qwen3next longctx": ("qwen3next", 256, 8192, 256, 8),
+              "lfm2 rag wide": ("lfm2", WIDE, 4096, 128, 8)}
 
 
 @pytest.mark.parametrize("cell", list(GQA_CHUNKS))
@@ -617,8 +629,10 @@ def test_the_gqa_chunk_walks_the_live_pages_a_head_block_and_holds_no_view(v5e, 
 
 # the latent cells' chunk programs beside the GQA ones: the scope of the write,
 # and the pool leaves a paged layer writes (K, V, positions / rows, positions)
+LATENT_CHUNKS = {"deepseek": ("deepseek", 256), "xing4": ("xing4", 256),
+                 "deepseek wide": ("deepseek", WIDE)}
 CHUNK_WRITES = {**{cell: ("attn.gqa.write", 3) for cell in GQA_CHUNKS},
-                **{cell: ("attn.latent.write", 2) for cell in LATENT_CELLS}}
+                **{cell: ("attn.latent.write", 2) for cell in LATENT_CHUNKS}}
 
 
 def pool_scatters(hlo: str, scope: str, pool_pages: int) -> list:
@@ -654,7 +668,8 @@ def test_the_chunk_writes_whole_pages_in_place(v5e, servers, cell):
         config, chunk, length = GQA_CHUNKS[cell][:3]
         slots = 2
     else:
-        config, chunk, (slots, length) = cell, 256, LATENT_CELLS[cell]
+        config, chunk = LATENT_CHUNKS[cell]
+        slots, length = LATENT_CELLS[config]
     server = servers(config)
     cfg = server._cfg
     row = cfg.latent_row_dim or cfg.n_kv_heads * cfg.head_dim
@@ -667,7 +682,8 @@ def test_the_chunk_writes_whole_pages_in_place(v5e, servers, cell):
         [(pool_pages, PAGE, row)] * (leaves - 1) * layers + [(pool_pages, PAGE)] * layers)
     windows = pages_a_run_writes(chunk, PAGE)
     for shape, operands in writes:
-        assert not any(chunk in operand for operand in operands), (shape, operands)
+        # (an entry a token leads with the chunk's rows; LFM2's K row is 512 wide too)
+        assert not any(operand[:1] == (chunk,) for operand in operands), (shape, operands)
         assert any(operand[:1] == (windows,) for operand in operands), (shape, operands)
     assert weight_copies(hlo, {(pool_pages, PAGE, row)}) == []
     memory = exe.memory_analysis()
@@ -763,7 +779,8 @@ def test_the_lookup_gathers_int8_rows_and_no_op_writes_the_table(v5e, servers, p
     assert f"s8[{dim},{vocab}]" in matmul.splitlines()[0]
 
 
-def test_the_chunks_head_runs_for_one_row_inside_the_conditional(v5e, servers):
+@pytest.mark.parametrize("program,rows", [("prefill_chunk", 256), ("wide_chunk", WIDE)])
+def test_the_chunks_head_runs_for_one_row_inside_the_conditional(v5e, servers, program, rows):
     """Sixth (PR 47): the chunk's program yields logits ``[1, 1, vocab]`` out of
     a conditional on the row it is handed. At Mistral's vocabulary the ONE op
     that computes a vocabulary-wide array is in the conditional's true branch,
@@ -773,10 +790,12 @@ def test_the_chunks_head_runs_for_one_row_inside_the_conditional(v5e, servers):
     scratch a chunk, read off this program before the head was handed in
     int8); the other branch broadcasts zeros; no op yields ``[256, vocab]``
     (``fusion f32[1,256,32000]``, 1.5 % of a rerank chunk's device time and
-    33 MB of output a program in flight, ledger PR 45)."""
+    33 MB of output a program in flight, ledger PR 45). The wide program
+    (PR 48) is never a prompt's last chunk as the batcher runs it, and is the
+    same program but for its rows: no ``[512, vocab]`` either."""
     server = servers("mistral_vocab")
     vocab, dim = server._cfg.vocab_size, server._cfg.dim
-    exe = compiled(server, "prefill_chunk", v5e)
+    exe = compiled(server, program, v5e)
     hlo = exe.as_text()
     zeros, true = conditional_branches(hlo)
     assert vocabulary_wide(hlo, vocab) == sorted([
@@ -784,9 +803,9 @@ def test_the_chunks_head_runs_for_one_row_inside_the_conditional(v5e, servers):
     product = next(op for op in own_ops(hlo) if op[2:4] == ((vocab,), "reduce"))
     called = re.search(r"calls=%?([\w.\-]+)", product[4]).group(1)
     assert f"s8[{dim},{vocab}]" in hlo[hlo.index(f"%{called} ("):].splitlines()[0]
-    assert floating_arrays(hlo, {(256, vocab), (1, 256, vocab), (dim, vocab)}) == []
+    assert floating_arrays(hlo, {(rows, vocab), (1, rows, vocab), (dim, vocab)}) == []
     shapes = [out.shape for out in jax.tree.leaves(exe.out_info)]
-    assert (1, 1, vocab) in shapes and (1, 256, vocab) not in shapes
+    assert (1, 1, vocab) in shapes and (1, rows, vocab) not in shapes
     assert exe.memory_analysis().temp_size_in_bytes < 256 * vocab * 4
 
 

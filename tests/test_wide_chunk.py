@@ -1,0 +1,341 @@
+"""A long prompt prefills in wide chunks while no other slot streams (ISSUE 48).
+
+The width of a prompt's NEXT chunk is chosen when the chunk is built
+(``ContinuousBatcher._chunk_width``): the wide program (``WIDE_PREFILL_CHUNK``
+rows) while more than that many rows of the prompt are left and no other live
+slot has ``on_token``; the job's own width otherwise. Held here, on the CPU at
+rehearsal widths (8-row chunks, 16-row wide chunks: the batcher's attribute is
+set by the test, there is no option for it):
+
+(a) for the four token mixers (GQA pages, latent rows, a conv state, a
+    delta-rule state) a prompt prefilled as wide + narrow chunks gives the
+    tokens of the all-narrow run and its logits within the tolerance the
+    chunk-against-whole tests use; the last chunk is narrow and runs the head;
+(b) the rule itself from host state alone;
+(c) under a streaming neighbour no chunk is wide, the job's own stream does not
+    count, and ``seldon_llm_chunk_rows_total{width}`` says so on ``/metrics``.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import threading
+
+import numpy as np
+import pytest
+from test_chunk_head import MODELS as CHUNK_HEAD_MODELS
+
+from seldon_core_tpu.runtime.batcher import (
+    DEFAULT_PREFILL_CHUNK,
+    WIDE_PREFILL_CHUNK,
+    ContinuousBatcher,
+    _PrefillJob,
+)
+from seldon_core_tpu.servers.llmserver import LLMServer
+
+CHUNK, WIDE, PAGE, MAX_LEN = 8, 16, 4, 64
+MODELS = {
+    "gqa_pages": CHUNK_HEAD_MODELS["dense_gqa"],
+    "latent_rows": CHUNK_HEAD_MODELS["latent_moe"],
+    "conv_state": CHUNK_HEAD_MODELS["state_layers"],
+    # Qwen3-Next's period in small (tests/test_reference_qwen3_next.py): three
+    # Gated DeltaNet layers, whose matrix state a chunk hands to the next, and
+    # one gated GQA layer
+    "delta_rule_state": dict(
+        vocab_size=96, dim=32, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16, ffn_dim=16,
+        n_experts=16, n_experts_per_token=4, router_renormalize=True, n_shared_experts=1,
+        shared_expert_gate=True, qk_norm="head", attn_gate=True, partial_rotary_factor=0.25,
+        max_seq_len=96, norm_eps=1e-6, rope_theta=1e7, dtype="float32",
+        layer_types=["linear_attention"] * 3 + ["full_attention"], linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=8,
+        linear_conv_kernel_dim=4),
+}
+# two wide chunks (16 + 16), then 13 rows that are no more than a wide chunk:
+# a full narrow one and the last, 5 rows long
+PROMPT = np.random.default_rng(48).integers(1, 96, size=45).tolist()
+
+
+@pytest.fixture(scope="module")
+def servers():
+    made = {}
+
+    def get(model):
+        if model not in made:
+            s = LLMServer(model="transformer", model_kwargs=MODELS[model], init_random=True,
+                          max_new_tokens=8, len_buckets=(16,), batch_buckets=(1, 4),
+                          eos_id=-1, seed=3, temperature=0.0)
+            s.load()
+            made[model] = s
+        return made[model]
+
+    return get
+
+
+def make_batcher(server, wide=WIDE, **kw) -> ContinuousBatcher:
+    base = dict(max_slots=3, max_len=MAX_LEN, len_buckets=(CHUNK,), page_size=PAGE,
+                prefill_chunk=CHUNK)
+    base.update(kw)
+    b = ContinuousBatcher(server, **base)
+    assert b.prefill_wide == 0      # an explicit width is every chunk's ...
+    b.prefill_wide = wide           # ... so the rehearsal sets the wide one by hand
+    return b
+
+
+def chunk_events(timelines) -> list:
+    """[(start, tokens, head)] of the chunks of the one request recorded."""
+    return sorted((e["start"], e["tokens"], e["head"]) for t in timelines for e in t["events"]
+                  if e["kind"] == "prefill_chunk")
+
+
+# ------------------------------------------- (a) the same answer
+@pytest.mark.parametrize("model", MODELS)
+def test_wide_then_narrow_chunks_give_the_all_narrow_runs_answer(servers, model):
+    server = servers(model)
+
+    async def go(wide):
+        b = make_batcher(server, wide=wide, tracing=True)
+        info = {"logits": []}
+        out = await b.submit(PROMPT, 4, info=info)
+        stats, timelines = b._phases.stats(), b._flight.timelines()
+        await b.close()
+        return out, np.stack(info["logits"]), stats, chunk_events(timelines)
+
+    out, logits, stats, chunks = asyncio.run(go(WIDE))
+    narrow_out, narrow_logits, narrow_stats, narrow_chunks = asyncio.run(go(0))
+    assert chunks == [(0, 16, 0), (16, 16, 0), (32, 8, 0), (40, 5, 1)]
+    assert narrow_chunks == [(s, min(8, 45 - s), int(s == 40)) for s in range(0, 45, 8)]
+    assert stats["chunk_rows"] == {"16": 32, "8": 13}
+    assert narrow_stats["chunk_rows"] == {"8": 45}
+    assert stats["chunk_head"] == {"1": 1, "0": 3}
+    # two chunk programs, no ladder
+    assert sorted(k[1] for k in server._prefill_cache if k[0] == "pchunk") == [CHUNK, WIDE]
+    assert out == narrow_out
+    assert out == server.generate([PROMPT], max_new_tokens=4)["tokens"][0]
+    np.testing.assert_allclose(logits, narrow_logits, atol=3e-5, rtol=0)
+
+
+# ------------------------------------------- (b) the rule, from host state
+def job_of(slot: int, length: int, done: int, chunk: int = CHUNK, on_token=None) -> _PrefillJob:
+    job = _PrefillJob(slot, list(range(length)), 0, chunk, 4, None, on_token, None, None, None, [])
+    job.next = done
+    return job
+
+
+def stream(tok):
+    """A caller's ``on_token``."""
+
+
+# rows left of the prompt, who else holds a slot (live?, streams?) -> the width
+RULE = {
+    "more than a wide chunk left, alone": (WIDE + 1, [], WIDE),
+    "a whole prompt left, alone": (45, [], WIDE),
+    "exactly a wide chunk left: the last chunks are narrow": (WIDE, [], CHUNK),
+    "less than a wide chunk left": (5, [], CHUNK),
+    "a live neighbour that streams": (45, [(True, True)], CHUNK),
+    "a live neighbour that waits for a plain reply": (45, [(True, False)], WIDE),
+    "a slot whose stream has ended (not live)": (45, [(False, True)], WIDE),
+    "one neighbour streams, one does not": (45, [(True, False), (True, True)], CHUNK),
+}
+
+
+@pytest.mark.parametrize("case", RULE)
+@pytest.mark.parametrize("own_stream", [False, True])
+def test_the_width_follows_rows_left_and_the_other_slots_streams(servers, case, own_stream):
+    left, neighbours, want = RULE[case]
+    b = make_batcher(servers("gqa_pages"), max_slots=4)
+    for slot, (live, streams) in zip(b._slots[1:], neighbours):
+        slot.active, slot.on_token = live, stream if streams else None
+    job = job_of(0, 50, 50 - left, on_token=stream if own_stream else None)
+    # the job's own slot holds the caller's on_token from admission on
+    b._slots[0].prefilling, b._slots[0].on_token = True, job.on_token
+    assert b._chunk_width(job) == want
+
+
+def test_an_explicit_prefill_chunk_is_every_chunks_and_the_default_widens(servers):
+    server = servers("latent_rows")
+    explicit = ContinuousBatcher(server, max_slots=2, max_len=MAX_LEN, page_size=PAGE,
+                                 prefill_chunk=CHUNK)
+    assert (explicit.prefill_chunk, explicit.prefill_wide) == (CHUNK, 0)
+    assert explicit._chunk_width(job_of(0, 50, 0)) == CHUNK
+    default = ContinuousBatcher(server, max_slots=2, max_len=MAX_LEN, page_size=PAGE)
+    assert (default.prefill_chunk, default.prefill_wide) == (
+        DEFAULT_PREFILL_CHUNK, WIDE_PREFILL_CHUNK) == (256, 512)
+    # every width is whole pages and whole sub-chunks of the delta rule
+    assert WIDE_PREFILL_CHUNK % DEFAULT_PREFILL_CHUNK == 0 and DEFAULT_PREFILL_CHUNK % 64 == 0
+    # a job's own width is its bucket where that is smaller: it cannot be wide
+    assert default._chunk_width(job_of(0, 40, 0, chunk=64)) == 64
+    assert default._chunk_width(job_of(0, 513, 0, chunk=256)) == 512
+    assert default._chunk_width(job_of(0, 513, 512, chunk=256)) == 256
+    # the server's own prefill_chunk is an explicit one too
+    server.prefill_chunk = CHUNK
+    try:
+        assert ContinuousBatcher(server, max_slots=2, max_len=MAX_LEN,
+                                 page_size=PAGE).prefill_wide == 0
+    finally:
+        server.prefill_chunk = 0
+
+
+@pytest.mark.parametrize("model,widens", [("gqa_pages", False), ("latent_rows", True),
+                                          ("conv_state", True), ("delta_rule_state", True)])
+def test_the_default_widens_where_the_model_routes_experts(servers, model, widens):
+    """A second chunk program costs a start seconds: a model without routed
+    experts keeps its one program (the three with state or latent rows here
+    are MoE models, as the served ones are)."""
+    b = ContinuousBatcher(servers(model), max_slots=2, max_len=MAX_LEN, page_size=PAGE)
+    assert b.prefill_wide == (WIDE_PREFILL_CHUNK if widens else 0)
+    assert b._chunk_width(job_of(0, 600, 0, chunk=256)) == (512 if widens else 256)
+
+
+def test_every_chunk_of_an_explicit_width_is_that_wide(servers):
+    async def go():
+        b = ContinuousBatcher(servers("gqa_pages"), max_slots=2, max_len=MAX_LEN,
+                              len_buckets=(CHUNK,), page_size=PAGE, prefill_chunk=CHUNK)
+        out = await b.submit(PROMPT, 2)
+        stats = b._phases.stats()
+        await b.close()
+        return out, stats
+
+    _, stats = asyncio.run(go())
+    assert stats["chunk_rows"] == {"8": 45}
+    assert stats["chunk_head"] == {"1": 1, "0": 5}
+
+
+# ------------------------------------------- (c) streams, and the counter
+def exposed_chunk_rows(stats) -> dict:
+    from types import SimpleNamespace
+
+    from seldon_core_tpu.metrics.registry import MetricsRegistry
+
+    registry = MetricsRegistry()
+    registry.sync_llm(SimpleNamespace(llm_stats=lambda: stats))
+    found = {}
+    for line in registry.expose().decode().splitlines():
+        if line.startswith("seldon_llm_chunk_rows_total{"):
+            found[line.split('width="')[1].split('"')[0]] = float(line.rsplit(" ", 1)[1])
+    return found
+
+
+def test_no_wide_chunk_under_a_streaming_neighbour_and_the_counter_says_so(servers):
+    """A streams 40 tokens; B's 45-row prompt arrives once A's first token is
+    out and is prefilled in narrow chunks alone (six turns beside A's steps);
+    when A is done, the same prompt takes wide chunks again."""
+    server = servers("gqa_pages")
+
+    async def go():
+        b = make_batcher(server)
+        loop = asyncio.get_running_loop()
+        streaming, streamed = asyncio.Event(), []
+
+        def on_token(tok):     # (the batcher's worker thread)
+            streamed.append(tok)
+            loop.call_soon_threadsafe(streaming.set)
+
+        a = asyncio.ensure_future(b.submit(PROMPT[:5], 40, on_token=on_token))
+        await streaming.wait()
+        b_out = await b.submit(PROMPT, 2)
+        a_still_streams = not a.done()
+        beside = b._phases.stats()
+        a_out = await a
+        alone_out = await b.submit(PROMPT, 2)
+        after = b._phases.stats()
+        await b.close()
+        return a_out, streamed, a_still_streams, b_out, alone_out, beside, after
+
+    a_out, streamed, a_still_streams, b_out, alone_out, beside, after = asyncio.run(go())
+    assert a_still_streams, "the neighbour has to stream through all of the prefill"
+    assert streamed == a_out + [None]
+    # A's own 5 rows and B's 45, none of them through the wide program
+    assert beside["chunk_rows"] == {"8": 50}
+    assert exposed_chunk_rows(beside) == {"8": 50.0}
+    assert after["chunk_rows"] == {"8": 63, "16": 32}
+    assert exposed_chunk_rows(after) == {"8": 63.0, "16": 32.0}
+    assert b_out == alone_out
+
+
+def test_the_jobs_own_stream_does_not_count_and_agrees_with_the_plain_reply(servers):
+    """The seeded probe of perf/planes/llm_rest.py: a plain reply and a stream
+    of one prompt take the same chunks and give the same tokens."""
+    server = LLMServer(model="transformer", model_kwargs=MODELS["gqa_pages"], init_random=True,
+                       max_new_tokens=8, len_buckets=(16,), eos_id=-1, seed=3,
+                       temperature=0.8, top_k=20)
+    server.load()
+
+    async def go():
+        b = make_batcher(server)
+        plain = await b.submit(PROMPT, 4, seed=1234)
+        after_plain = b._phases.stats()["chunk_rows"]
+        streamed, lock = [], threading.Lock()
+
+        def on_token(tok):
+            with lock:
+                streamed.append(tok)
+
+        stream_out = await b.submit(PROMPT, 4, seed=1234, on_token=on_token)
+        rows = b._phases.stats()["chunk_rows"]
+        await b.close()
+        return plain, after_plain, stream_out, streamed, rows
+
+    plain, after_plain, stream_out, streamed, rows = asyncio.run(go())
+    assert after_plain == {"16": 32, "8": 13}
+    assert rows == {"16": 64, "8": 26}
+    assert stream_out == plain and streamed == plain + [None]
+
+
+# ------------------------------------------- (d) a server's start
+def serve(max_len):
+    from seldon_core_tpu.runtime.batcher import BatcherService
+
+    server = LLMServer(model="transformer",
+                       model_kwargs=dict(MODELS["latent_rows"], max_seq_len=704),
+                       init_random=True, max_new_tokens=4, eos_id=-1, seed=3, temperature=0.0,
+                       continuous_batching=2, continuous_batching_max_len=max_len)
+    server.load()
+    return server, BatcherService(server, max_slots=2)
+
+
+LONG = np.random.default_rng(5).integers(1, 96, size=600).tolist()
+
+
+@pytest.mark.parametrize("first,then", [(300, 600), (600, 300)],
+                         ids=["a narrow chunk is the first", "a wide chunk is the first"])
+def test_the_first_chunk_builds_both_chunk_programs_and_neither_is_built_again(
+        caplog, first, then):
+    """A batcher of the default widths (256 and 512) whose slots can hold a
+    wide chunk: its first chunk, whichever program it needs, traces and lowers
+    both chunk programs and hands each to a thread to compile; that chunk's own
+    call and the other program's first call, whenever it comes, then trace,
+    lower and compile nothing (jax's own caches hold all three)."""
+    import logging
+
+    def built(what):
+        return [r.getMessage() for r in caplog.records if what + "prefill_chunk" in r.getMessage()]
+
+    server, svc = serve(704)
+    with caplog.at_level(logging.DEBUG, logger="jax._src.dispatch"):
+        one = svc.submit_sync(LONG[:first], 2)
+        for load in svc.batcher._chunk_loads.values():
+            load.join(120)
+            assert not load.is_alive()
+        assert len(built("Finished XLA compilation of jit(")) == 2
+        assert len(built("Finished jaxpr to MLIR module conversion jit(")) == 2
+        caplog.clear()
+        other = svc.submit_sync(LONG[:then], 2)
+        assert built("Finished XLA compilation of jit(") == []
+        assert built("Finished jaxpr to MLIR module conversion jit(") == []
+        traces = [float(m.split(" in ")[1].split()[0])
+                  for m in built("Finished tracing + transforming ")]
+        assert max(traces, default=0) < 0.01, traces     # found, not traced
+    stats = svc.batcher._phases.stats()
+    svc.close()
+    assert stats["chunk_rows"] == {"512": 512, "256": 388}
+    assert sorted(k[1] for k in server._prefill_cache if k[0] == "pchunk") == [256, 512]
+    assert one == server.generate([LONG[:first]], max_new_tokens=2)["tokens"][0]
+    assert other == server.generate([LONG[:then]], max_new_tokens=2)["tokens"][0]
+
+
+def test_a_batcher_that_cannot_reach_a_wide_chunk_builds_its_one_program_when_called():
+    # no prompt of a 512-token slot has more than 512 rows left
+    _, svc = serve(512)
+    svc.submit_sync(LONG[:300], 2)
+    assert svc.batcher._chunk_loads is None
+    svc.close()

@@ -623,13 +623,19 @@ def _build_mla_paged_decode_step():
                 _sds((SLOTS, PAGES_PER_SLOT), "int32"))
 
 
-def _build_mla_prefill_chunk():
+def _build_mla_prefill_chunk(chunk: int = PAGE_SIZE):
     s = _mla_server()
-    fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
+    fn = s._get_prefill_chunk(chunk, PAGES_PER_SLOT)
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
-                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
+                _sds((1, chunk), "int32"), _sds((1, chunk), "int32"),
                 _sds((), "int32"))
+
+
+def _build_mla_prefill_chunk_wide():
+    """The same server's second chunk program, four pages wide (the batcher's
+    wide chunk, runtime/batcher.py ``_chunk_width``, at test dims)."""
+    return _build_mla_prefill_chunk(4 * PAGE_SIZE)
 
 
 def _build_xing4_paged_decode_step():
@@ -1093,6 +1099,20 @@ def all_contracts() -> List[Contract]:
                         "chunk can reach: a chunk of 8 is a page here) "
                         "updates the latent pool in place",
             build=_build_mla_prefill_chunk,
+            donated=(1,),
+            forbid_dtypes=(MLA_EXPANDED_KV, MOE_DENSE_FORM, MOE_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.mla_prefill_chunk_c32",
+            description="the same model's WIDE chunk (four pages of 8: what "
+                        "a long prompt's chunks are while no other slot "
+                        "streams), held to what the narrow one is: the "
+                        "latent view read absorbed, the pool updated in "
+                        "place, the experts from int8",
+            build=_build_mla_prefill_chunk_wide,
             donated=(1,),
             forbid_dtypes=(MLA_EXPANDED_KV, MOE_DENSE_FORM, MOE_FLOAT_STACK),
             lowering_platform="tpu",
