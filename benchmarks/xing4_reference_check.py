@@ -11,6 +11,8 @@ configuration's `reference_tolerance` is set from (perf/configs/<config>.json).
     chiprun -- python benchmarks/xing4_reference_check.py --workload qwen3next-longctx-mixed \
         --probes 2 --wrong-probes 2 --only-wrongs state_held_in_bf16 --long 2 --long-size 6144+64 \
         --long-wrongs state_held_in_bf16,weights_at_4_bits --out chiprun_out/pr38/reference_check_long.json
+    chiprun -- python benchmarks/xing4_reference_check.py --workload smallthinker-longqa-mixed \
+        --probes 12 --wrong-probes 2 --long 1 --long-size 14336+64 --out chiprun_out/pr49/reference_check.json
     (then once more with --probes 2 --wrong-probes 2 --only-low: the 4-bit tree in a call of its
     own, because the machine's host holds 40 GiB and a 15-layer tree is 11.5 GB of it)
 
@@ -108,6 +110,20 @@ def olmo_hybrid_wrongs(prompt_tokens: int, chunk: int) -> dict:
         "qk_norm_a_head": {"qk_norm": "head_tiled"},
         "rope_at_theta_500000": {"rope_theta_wrong": 500000.0},
         "state_held_in_bf16": {"gdn_state_bf16": True},
+    }
+
+
+def smallthinker_wrongs(prompt_tokens: int, chunk: int) -> dict:
+    """The wrong references of SmallThinker (its `work` is "smallthinker"): each
+    of ISSUE 49's readings of a layer taken the other way (the weights at 4 bits
+    are every configuration's)."""
+    return {
+        "no_window": {"window_off": True}, "window_a_page_wide_of_the_mark": {"window_wrong": 4096 + 64},
+        "rope_on_the_global_layers_too": {"rope_on_global": True},
+        "no_rope_on_the_window_layers": {"rope_on_window": False},
+        "router_fed_the_ffn_input": {"router_input": "ffn_input"},
+        "silu_for_relu": {"ffn_act": "silu"}, "top_6_not_renormalised": {"renormalize": False},
+        "one_expert_a_token_left_out": {"leave_out_rank": 0},
     }
 
 
@@ -277,7 +293,8 @@ def main() -> None:
 
     wrongs = WRONGS
     placed = {"lfm2": lfm2_wrongs, "qwen3_next": qwen3_next_wrongs,
-              "olmo_hybrid": olmo_hybrid_wrongs}.get(cfg.get("work"))
+              "olmo_hybrid": olmo_hybrid_wrongs,
+              "smallthinker": smallthinker_wrongs}.get(cfg.get("work"))
     if placed:   # a configuration with state layers: some wrongs lie where the probe's chunks end
         wrongs = placed(probe["prompt_tokens"], server_kw.get("prefill_chunk") or 256)
     only = [name for name in args.only_wrongs.split(",") if name]

@@ -145,16 +145,28 @@ class MetricsRegistry:
         # to bite; fragmentation is the slack between tokens written and
         # page tokens held (the page-size knob's overhead term) —
         # docs/performance.md "Paged KV cache"
+        # one series a page CLASS (runtime/batcher.py): "full", and "window" for
+        # a model with sliding-attention layers, whose pages behind the window
+        # are given back while the request lives; summed over the label they
+        # are what they always were, every page of every pool
         self._kv_pages_in_use = Gauge(
             "seldon_llm_kv_pages_in_use",
-            "KV pages currently allocated to slots (paged layout)",
-            base,
+            "KV pages currently allocated to slots (paged layout), by page class",
+            base + ["class"],
             registry=self.registry,
         )
         self._kv_pages_total = Gauge(
             "seldon_llm_kv_pages_total",
-            "Total KV pages in the global pool (incl. the 2 reserved pages)",
-            base,
+            "Total KV pages in the pool of each page class (incl. its 2 reserved pages)",
+            base + ["class"],
+            registry=self.registry,
+        )
+        self._kv_pages_released = Counter(
+            "seldon_llm_kv_pages_released_total",
+            "KV pages given back to their pool while the request that held them lived: "
+            "reason=window a sliding-attention layer's page wholly behind the window of the "
+            "next call's first query",
+            base + ["reason"],
             registry=self.registry,
         )
         self._state_bytes = Gauge(
@@ -428,9 +440,12 @@ class MetricsRegistry:
             base + ["ready"],
             registry=self.registry,
         )
+        # kind: "full" every layer that attends its whole sequence; "window" the
+        # sliding-attention layers of a model that has them (a row a layer of
+        # each kind: the two differ in what a query may see)
         self._attn_context = {
             key: Counter(f"seldon_llm_attn_{key}_total", text,
-                         base + ["program"], registry=self.registry)
+                         base + ["program", "kind"], registry=self.registry)
             for key, text in (
                 ("calls", "Step-program calls whose attention read the cache"),
                 ("context_tokens",
@@ -440,7 +455,11 @@ class MetricsRegistry:
                 ("rows_read",
                  "Cached rows those calls' attention read visited: the whole "
                  "block-table view, or whole visits of latent attention's "
-                 "live-page kernel; over context_tokens it is the over-read"))}
+                 "live-page kernel; over context_tokens it is the over-read"),
+                ("context_tokens_unwindowed",
+                 "kind=window alone: the cached rows a FULL layer would have had to "
+                 "read for the same queries (context_tokens counts the rows inside "
+                 "the window)"))}
         # how the prefill chunks' K / V (latent) rows reached the paged pool
         # (models/cache.py paged_write_by_page), counted on the loop
         self._kv_writes = {
@@ -1057,12 +1076,15 @@ class MetricsRegistry:
         self._kv_bytes_per_step.labels(**self._base()).set(
             stats.get("kv_bytes_per_step", 0)
         )
-        self._kv_pages_in_use.labels(**self._base()).set(
-            stats.get("kv_pages_in_use", 0)
-        )
-        self._kv_pages_total.labels(**self._base()).set(
-            stats.get("kv_pages_total", 0)
-        )
+        by_class = stats.get("kv_pages_by_class") or {"full": {
+            "in_use": stats.get("kv_pages_in_use", 0), "total": stats.get("kv_pages_total", 0)}}
+        for page_class, pages in by_class.items():
+            self._kv_pages_in_use.labels(**self._base(), **{"class": page_class}).set(
+                pages["in_use"])
+            self._kv_pages_total.labels(**self._base(), **{"class": page_class}).set(
+                pages["total"])
+        for reason, n in stats.get("kv_pages_released", {}).items():
+            self._counter_catch_up(self._kv_pages_released, n, reason=reason)
         self._kv_page_fragmentation.labels(**self._base()).set(
             stats.get("kv_page_fragmentation", 0.0)
         )
@@ -1126,8 +1148,9 @@ class MetricsRegistry:
         for ready, n in stats.get("first_token_reads", {}).items():
             self._counter_catch_up(self._first_token_reads, n, ready=ready)
         for key, counter in self._attn_context.items():
-            for program, n in stats.get(f"attn_{key}", {}).items():
-                self._counter_catch_up(counter, n, program=program)
+            for kind, prefix in (("full", "attn_"), ("window", "attn_window_")):
+                for program, n in stats.get(prefix + key, {}).items():
+                    self._counter_catch_up(counter, n, program=program, kind=kind)
         for key, counter in self._kv_writes.items():
             for path, n in stats.get(f"kv_{key}", {}).items():
                 self._counter_catch_up(counter, n, path=path)

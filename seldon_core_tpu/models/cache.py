@@ -10,6 +10,12 @@ block tables, the vLLM / PagedAttention design: ``init_paged_kv_caches``, the
 continuous batcher). ``positions`` is PAD_POS where a row is empty, so ONE
 predicate (``pos <= query position``) is causality, the unwritten rest and
 padding. A STATE layer's entry is a ``StateEntry``: fixed blocks a sequence.
+A paged pool's pages are of one of two CLASSES, each with its own pool length,
+block tables and allocator (runtime/batcher.py): ``full`` (a page for every 64
+tokens of the sequence, kept while the request lives) and ``window`` (a
+sliding-attention layer's, a ``WindowEntry``: the pages behind the window are
+given back while the request lives, so a slot needs a window, a chunk and a
+page of them whatever its length).
 
 The reads (models/transformer.py ``paged_live_read``, ops/page_walk.py) choose
 a kernel, not a form, and stay there. Nothing is imported here from
@@ -96,6 +102,34 @@ class StateEntry(tuple):
 
 jax.tree_util.register_pytree_node(
     StateEntry, lambda entry: (tuple(entry), None), lambda _aux, leaves: StateEntry(leaves))
+
+
+class WindowEntry(tuple):
+    """A sliding-attention layer's entry of a PAGED tree: the arrays of an
+    attention entry, ``(values..., positions)``, whose pages are of the WINDOW
+    class (a pool of its own length, addressed through the window block tables).
+    Told from a full layer's plain tuple by its TYPE, as a state entry is; every
+    write and tree operation keeps it."""
+
+
+jax.tree_util.register_pytree_node(
+    WindowEntry, lambda entry: (tuple(entry), None), lambda _aux, leaves: WindowEntry(leaves))
+
+def is_window_entry(layer) -> bool:
+    return isinstance(layer, WindowEntry)
+
+
+def _like(entry, values):
+    """``values`` as an entry of ``entry``'s page class."""
+    return WindowEntry(values) if isinstance(entry, WindowEntry) else tuple(values)
+
+
+def window_slot_pages(window: int, widest_call: int, page_size: int) -> int:
+    """Pages of the window class ONE sequence can hold at once: its window, the
+    widest call's rows (a prefill chunk's) and one page of rounding, in whole
+    pages. Before a call whose first query is at p0 the pages wholly behind
+    p0 - window + 1 are given back, and the call writes up to p0 + widest - 1."""
+    return -(-(window + widest_call) // page_size) + 1
 
 
 def is_state_entry(layer) -> bool:
@@ -185,23 +219,28 @@ def _init_latent_caches(cfg, lead: Tuple[int, int], kvd: str):
 
 
 def _init_head_caches(cfg, lead: Tuple[int, int], kvd: str,
-                      flat: bool = False):
+                      flat: bool = False, window_lead: Optional[Tuple[int, int]] = None):
     """Per-head K/V entries with leading dims ``lead``, one per ATTENTION
     layer: (k, v, pos), or the int8 5-tuple. ``flat``: a token's heads as one
-    row (the paged bf16 pool of a cfg.kv_rows_flat model)."""
-    shape = lead + (cfg.n_kv_heads, cfg.head_dim)
-    if flat and kvd != "int8":
-        shape = lead + (cfg.n_kv_heads * cfg.head_dim,)
-    n = cfg.n_layers - len(cfg.state_layers)
+    row (the paged bf16 pool of a cfg.kv_rows_flat model). ``window_lead``: a
+    paged tree's sliding-attention layers are ``WindowEntry``s of that many
+    pages (the window class's pool)."""
+    def entry(lead):
+        shape = lead + (cfg.n_kv_heads, cfg.head_dim)
+        if flat and kvd != "int8":
+            shape = lead + (cfg.n_kv_heads * cfg.head_dim,)
 
-    def values():   # of K, and again of V
-        if kvd == "int8":
-            return (jnp.zeros(shape, dtype=jnp.int8),
-                    jnp.ones(lead + (cfg.n_kv_heads,), dtype=jnp.float32))
-        return (jnp.zeros(shape, dtype=cfg.dtype),)
+        def values():   # of K, and again of V
+            if kvd == "int8":
+                return (jnp.zeros(shape, dtype=jnp.int8),
+                        jnp.ones(lead + (cfg.n_kv_heads,), dtype=jnp.float32))
+            return (jnp.zeros(shape, dtype=cfg.dtype),)
 
-    return [values() + values() + (jnp.full(lead, PAD_POS, dtype=jnp.int32),)
-            for _ in range(n)]
+        return values() + values() + (jnp.full(lead, PAD_POS, dtype=jnp.int32),)
+
+    windowed = set(getattr(cfg, "window_layers", ())) if window_lead is not None else ()
+    return [WindowEntry(entry(window_lead)) if i in windowed else entry(lead)
+            for i in range(cfg.n_layers) if i not in cfg.state_layers]
 
 
 def init_kv_caches(cfg, batch: int, max_len: int,
@@ -219,12 +258,14 @@ def init_kv_caches(cfg, batch: int, max_len: int,
 
 def init_paged_kv_caches(cfg, num_pages: int,
                          page_size: int, kv_cache_dtype: Optional[str] = None,
-                         state_slots: int = 0):
+                         state_slots: int = 0, window_pages: int = 0):
     """The PAGED tree (the continuous batcher): leading dims [num_pages,
     page_size], pages shared by every sequence through block tables. Pages 0
     and 1 are reserved (NULL_PAGE / TRASH_PAGE), so the pool serves
     ``num_pages - RESERVED_PAGES`` pages of tokens. A state layer has no
     pages: fixed blocks [state_slots, ...], one a sequence the pool serves.
+    A sliding-attention layer's pool is ``window_pages`` long (the window
+    class, with its own two reserved pages), a ``WindowEntry``.
 
     Where ``cfg.kv_rows_flat`` (one device, or narrow heads) the bf16
     pool holds a token's heads as ONE row, [num_pages, page_size, kvh * hd];
@@ -238,13 +279,24 @@ def init_paged_kv_caches(cfg, num_pages: int,
     kvd = normalize_kv_cache_dtype(kv_cache_dtype or cfg.kv_cache_dtype)
     if cfg.kv_lora_rank:
         return _init_latent_caches(cfg, (num_pages, page_size), kvd)
-    pools = _init_head_caches(cfg, (num_pages, page_size), kvd, flat=cfg.kv_rows_flat)
+    window_lead = None
+    if getattr(cfg, "window_layers", ()):
+        if window_pages <= RESERVED_PAGES:
+            raise ValueError(
+                f"a model with sliding-attention layers needs a window-class pool of > "
+                f"{RESERVED_PAGES} pages (init_paged_kv_caches(..., window_pages=); got "
+                f"{window_pages})")
+        window_lead = (window_pages, page_size)
+    pools = _init_head_caches(cfg, (num_pages, page_size), kvd, flat=cfg.kv_rows_flat,
+                              window_lead=window_lead)
     return _with_state_entries(cfg, pools, state_slots)
 
 
-def kv_cache_bytes_per_token(cfg,
-                             kv_cache_dtype: Optional[str] = None) -> int:
-    """HBM bytes one cached token position costs across all layers (K + V
+def kv_cache_bytes_per_token(cfg, kv_cache_dtype: Optional[str] = None,
+                             page_class: Optional[str] = None) -> int:
+    """HBM bytes one cached token position costs across all layers, or across
+    the layers of one ``page_class`` ("full" | "window"; a window layer holds a
+    token only while it lies inside the window) (K + V
     values, int8 scales when quantized, and the int32 position map): what a
     page pool of N tokens is billed, and times a sequence's LIVE rows (in
     whole visits) what a decode step's read of it streams where the kernel
@@ -259,7 +311,11 @@ def kv_cache_bytes_per_token(cfg,
     else:
         per_layer = 2 * per_pos * jnp.dtype(cfg.dtype).itemsize
     # a state layer caches nothing a token (state_bytes a sequence)
-    return (cfg.n_layers - len(cfg.state_layers)) * (per_layer + 4)  # + int32 pos slot
+    layers = cfg.n_layers - len(cfg.state_layers)
+    if page_class is not None:
+        windowed = len(getattr(cfg, "window_layers", ()))
+        layers = windowed if page_class == "window" else layers - windowed
+    return layers * (per_layer + 4)  # + int32 pos slot
 
 
 def paged_write_targets(block_tables: jnp.ndarray, positions: jnp.ndarray,
@@ -408,7 +464,7 @@ def write_rows(entry, rows, positions, *, block_tables=None, cache_index=None):
         if paged_write_by_page(entry, b, s):
             written, pos = paged_write_pages(
                 tuple(arrays), pos, bt, positions, tuple(r[0] for r in new[:-1]))
-            return written + (pos,)
+            return _like(entry, written + (pos,))
         at = paged_write_targets(bt, positions, pos.shape[1])
     else:
         idx = jnp.asarray(cache_index, dtype=jnp.int32)
@@ -420,7 +476,7 @@ def write_rows(entry, rows, positions, *, block_tables=None, cache_index=None):
             at, new = (jnp.arange(b), idx), [r[:, 0] for r in new]
         else:
             at = (jnp.arange(b)[:, None], positions.astype(jnp.int32))
-    return tuple(a.at[at].set(r, mode="drop") for a, r in zip(entry, new))
+    return _like(entry, (a.at[at].set(r, mode="drop") for a, r in zip(entry, new)))
 
 
 def dense_view(entry, dtype):
@@ -491,18 +547,35 @@ def _attention_entries(tree, fn):
     return [layer if is_state_entry(layer) else fn(layer) for layer in tree]
 
 
-def reset_pages(tree, page_ids):
+def reset_pages(tree, page_ids, window_page_ids=None):
     """The position rows of pages ``page_ids`` back to PAD_POS: a page off the
     free list still holds its last owner's positions. ``page_ids`` is padded
-    with TRASH_PAGE to a fixed length, so one compile serves every size."""
-    return _attention_entries(
-        tree, lambda layer: layer[:-1] + (layer[-1].at[page_ids].set(PAD_POS),))
+    with TRASH_PAGE to a fixed length, so one compile serves every size.
+    ``window_page_ids``: the same for the window class's pools (a
+    ``WindowEntry``'s page ids are its own class's; None leaves them alone)."""
+    def reset(layer):
+        ids = window_page_ids if is_window_entry(layer) else page_ids
+        if ids is None:
+            return layer
+        return _like(layer, layer[:-1] + (layer[-1].at[ids].set(PAD_POS),))
+    return _attention_entries(tree, reset)
+
+
+WINDOW_RESTART_REFUSAL = (
+    "is not built over sliding-attention layers: what restarts a sequence mid-way needs "
+    "the window's pages at the restart boundary, and pages behind a window were given back")
+
+
+def _no_window_entries(tree, what: str) -> None:
+    if any(is_window_entry(layer) for layer in tree):
+        raise ValueError(f"{what} {WINDOW_RESTART_REFUSAL}")
 
 
 def forget_positions(tree, positions, block_tables=None):
     """The rows at ``positions`` [b, k] made unattendable (position back to
     PAD_POS: the speculative step's repair of rejected drafts). A PAD_POS
     entry names no row (dense: dropped; a pool: TRASH_PAGE)."""
+    _no_window_entries(tree, "forget_positions (the speculative step's rollback)")
     if block_tables is None:
         at = (jnp.arange(positions.shape[0])[:, None], positions)
     else:
@@ -516,6 +589,8 @@ def cow_page_copy(tree, src, dst, n_valid):
     values whole, the position row only up to the source's VALID length (it
     may carry a previous occupant's run-ahead positions past its credited
     history; copied live, the new slot would attend another sequence's tail)."""
+    _no_window_entries(tree, "cow_page_copy (the radix cache's copy-on-write)")
+
     def copy(layer):
         *vals, pos = layer
         row = jnp.where(jnp.arange(pos.shape[1]) < n_valid, pos[src], PAD_POS)
@@ -526,6 +601,7 @@ def cow_page_copy(tree, src, dst, n_valid):
 def export_pages(tree, idx):
     """Pages ``idx`` of every attention entry as a staged handoff-shaped
     bucket (no state entry travels: state is a slot's)."""
+    _no_window_entries(tree, "export_pages (the disaggregated hand-off)")
     return [tuple(pool[idx] for pool in layer) for layer in tree
             if not is_state_entry(layer)]
 
@@ -535,6 +611,7 @@ def import_pages(tree, staged, block_row, n_valid, m: int):
     form, behind its RESERVED_PAGES rows) scattered into the pool pages
     ``block_row`` names; rows past ``n_valid`` and NULL entries go to
     TRASH_PAGE, so one compile serves every prompt length inside a bucket."""
+    _no_window_entries(tree, "import_pages (the disaggregated hand-off)")
     src = jnp.arange(m) + RESERVED_PAGES
     tgt = jnp.where(
         (jnp.arange(m) < n_valid) & (block_row[:m] != NULL_PAGE),

@@ -135,18 +135,23 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
     if olmo:
         # the Olmo 2 / 3 block: the two norms on the BRANCHES (x + norm(f(x))),
         # q / k RMSNorms over the whole projection. olmo3 is what the installed
-        # transformers can check (every layer full attention: the native
-        # transformer has no windowed attention). olmo_hybrid (no modeling file
-        # installed: ASSUMED names and readings, models/reference.py) gives 3 of 4
-        # layers a Gated DeltaNet with beta in (0, 2) and takes no rotary
-        # embedding where rope_theta is null
+        # transformers can check, its sliding_attention layers too (a query
+        # sees sliding_window keys, itself included: the native transformer's
+        # own bound, held to Olmo3ForCausalLM with windows shorter than the
+        # sequence in tests/test_reference_smallthinker.py). olmo_hybrid (no
+        # modeling file installed: ASSUMED names and readings,
+        # models/reference.py) gives 3 of 4 layers a Gated DeltaNet with beta in
+        # (0, 2) and takes no rotary embedding where rope_theta is null
         kinds = tuple(getattr(hf_config, "layer_types", None) or ())
+        windowed = {}
         if "sliding_attention" in kinds:
-            raise ValueError(
-                "layer_types with 'sliding_attention' is not supported by the native "
-                "transformer (it has no windowed attention): only where every layer's "
-                "window covers max_position_embeddings is a conversion the same model")
-        moe = {"norm_placement": "branch", "qk_norm": True}
+            if getattr(hf_config, "rope_scaling", None):
+                raise ValueError(
+                    "olmo3 with sliding_attention layers AND rope_scaling is not supported by "
+                    "the native transformer: the scaling applies to the full-attention layers "
+                    "alone there, and rope scaling a layer is not built")
+            windowed = {"layer_types": kinds, "sliding_window": int(hf_config.sliding_window)}
+        moe = {"norm_placement": "branch", "qk_norm": True, **windowed}
         if model_type == "olmo_hybrid":
             rope = getattr(hf_config, "rope_parameters", None) or {}
             moe.update(

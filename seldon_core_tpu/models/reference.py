@@ -174,6 +174,31 @@ weights), ``qk_norm="head_tiled"`` (a norm over EACH head, the whole projection'
 cut into the heads' parts), ``rope_theta_wrong=500000.0`` (RoPE where the model has
 none), and Qwen3-Next's ``gdn_*`` / ``taps_reversed`` / ``conv_state_pad`` above.
 
+SmallThinker (``model_type`` ``smallthinker``; no modeling file is installed, so every
+reading its config.json does not settle is *assumed*, and the window's bound is held
+to the installed ``Olmo3ForCausalLM`` through models/convert.py:
+tests/test_reference_smallthinker.py). With x the residual [s, 2560], layer l, every
+matrix without bias:
+
+    route : r = x W_r, the router's logits from the layer's INPUT, ahead of the first
+            norm and of attention (cfg.router_input "layer_input"; *assumed* from the
+            catalog's "router placed before attention")
+    attn  : a = RMSNorm(x); q, k, v = a W_q, a W_k, a W_v (28 / 4 / 4 heads of 128, no
+            QK norm); where cfg.rope_layout[l] == 1 rotate-half RoPE over the whole
+            head, where 0 NO position at all; scores q k^T / sqrt(128), k_pos <= q_pos
+            and, in a "sliding_attention" layer, k_pos > q_pos - cfg.sliding_window (a
+            query sees that many keys, itself included); h = x + softmax(.) v W_o
+    ffn   : m = RMSNorm(h); top-k of r, weights softmax over ALL experts then divided by
+            the k's sum (cfg.router_renormalize); expert e: (relu(m W1_e) * (m W3_e)) W2_e
+            (cfg.ffn_act "relu": ReGLU); out = h + sum_e w_e y_e
+
+WRONG models of these: ``window_off=True`` (every layer attends everything),
+``window_wrong=N`` (a window of N), ``rope_on_global=True`` (RoPE in the layers the
+layout gives none), ``rope_on_window=False`` (none where it gives one),
+``router_input="ffn_input"`` (the router fed the normed FFN input), ``ffn_act="silu"``,
+``renormalize=False`` (the k weights as the softmax over all left them), and
+``leave_out_rank`` above.
+
 ``follow=`` makes the forward take the experts the SERVED path took (a logits
 probe's ``routing``): where this router's last chosen and first unchosen expert
 score within the served arithmetic's noise of each other, which one is taken is
@@ -191,6 +216,9 @@ path's distance from it is its activation arithmetic alone.
 from __future__ import annotations
 
 import math
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import Any, Optional
 
@@ -212,6 +240,48 @@ def _f32(leaf, index: Optional[int] = None):
             scale = scale[:, None, :]
         return jnp.asarray(q, jnp.float32) * jnp.asarray(scale, jnp.float32)
     return jnp.asarray(leaf if index is None else leaf[index], jnp.float32)
+
+
+# threads of ``_in_threads``: the host's cores, up to sixteen
+THREADS = min(16, os.cpu_count() or 1)
+
+
+def _in_threads(f, items: list) -> list:
+    """``f`` over ``items`` on a few threads, in order. The CPU backend runs one
+    op at a time and finds little parallelism inside an op that memory bounds
+    (a block's softmax, an expert's scatter), so INDEPENDENT pieces overlap: a
+    6 k-token forward of sixteen layers took 343 s on the chip's host before
+    and a quarter of it after (PERF.md section 6, PR 49). The matmul precision
+    is a thread's own setting: each worker sets the module's."""
+    workers = min(THREADS, len(items))
+    if workers <= 1:
+        return [f(item) for item in items]
+
+    def run(item):
+        with jax.default_matmul_precision("highest"):
+            return f(item)
+
+    with ThreadPoolExecutor(workers) as pool:
+        return list(pool.map(run, items))
+
+
+_SEEN_SHAPES: set = set()
+_FIRST_CALL = threading.Lock()
+
+
+def _one_compile_at_a_time(key, call):
+    """``call()``, a jitted piece, from one of ``_in_threads``' workers: the
+    FIRST call of each ``key`` (the piece and its shapes) runs alone, later ones
+    freely. A first call compiles, and reads or writes the persistent compile
+    cache, whose directory is locked a file at a time: thirteen workers
+    compiling at once waited on that lock for most of a 213 s forward that is
+    72-88 s otherwise (the chip's host, PR 49)."""
+    if key in _SEEN_SHAPES:
+        return call()
+    with _FIRST_CALL:
+        out = jax.block_until_ready(call())
+        _SEEN_SHAPES.add(key)
+    return out
 
 
 def _rms_norm(x, weight, eps: float):
@@ -261,10 +331,39 @@ def _rope(x, theta: float, scaling: Optional[dict] = None):
     return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], axis=-1)
 
 
+@partial(jax.jit, static_argnames=("window",))
+def _attend_block(q, k, v, start, lo, window: int = 0):
+    """Causal softmax attention of the query rows ``q`` [b, H, hd] at positions
+    ``start`` .. over the keys and values ``k`` / ``v`` [w, G, hd] at positions
+    ``lo`` .. (a KV head serves its H / G query heads, never repeated); with
+    ``window`` a row sees that many keys, itself included. -> [b, H * hd].
+    ONE compiled piece a (b, w): a long context's dozen blocks are a dozen
+    shapes, where the eager ops were a pass over [H, b, s] scores each."""
+    b, heads, hd = q.shape
+    w, groups = k.shape[:2]
+    rep = heads // groups
+    # (the scale on the queries and the sum divided out of the products: the
+    # same softmax with two passes fewer over [H, b, w])
+    qg = (q / jnp.sqrt(jnp.float32(hd))).reshape(b, groups, rep, hd).transpose(1, 2, 0, 3)
+    scores = jnp.matmul(qg.reshape(groups, rep * b, hd), k.transpose(1, 2, 0))
+    rows, keys = start + jnp.arange(b), lo + jnp.arange(w)
+    seen = keys[None, :] <= rows[:, None]
+    if window:
+        seen &= keys[None, :] > rows[:, None] - window
+    scores = jnp.where(seen[None, None], scores.reshape(groups, rep, b, w), -jnp.inf)
+    weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+    out = jnp.matmul(weights.reshape(groups, rep * b, w), v.transpose(1, 0, 2))
+    out = out / jnp.sum(weights, axis=-1).reshape(groups, rep * b, 1)
+    return out.reshape(groups, rep, b, hd).transpose(2, 0, 1, 3).reshape(b, heads * hd)
+
+
 def _attention(p: dict, x, cfg, qk_norm=None, gate: bool = True, rotary_all: bool = False,
-               block: int = 512, rope_theta_wrong: Optional[float] = None):
-    """Queries go in blocks of ``block`` rows (a 6 k context needs
-    [heads, block, s] of scores at a time; the arithmetic is the same)."""
+               block: int = 256, rope_theta_wrong: Optional[float] = None, window: int = 0,
+               rotary: bool = True):
+    """Queries go in blocks of ``block`` rows, each against the keys its rows
+    may see and no others (a 6 k context needs [heads, block, <= s] of scores
+    at a time; the arithmetic is the same). ``window`` > 0: a query sees that
+    many keys, itself included; ``rotary`` False: this layer sees no position."""
     s = x.shape[0]
     hd = getattr(cfg, "head_dim", 0) or cfg.dim // cfg.n_heads
     q, k, v = x @ _f32(p["wq"]), x @ _f32(p["wk"]), x @ _f32(p["wv"])
@@ -283,20 +382,21 @@ def _attention(p: dict, x, cfg, qk_norm=None, gate: bool = True, rotary_all: boo
         q = _rms_norm(q, _f32(p["q_norm"]["weight"]).reshape(cfg.n_heads, hd), cfg.norm_eps)
         k = _rms_norm(k, _f32(p["k_norm"]["weight"]).reshape(cfg.n_kv_heads, hd), cfg.norm_eps)
     theta = cfg.rope_theta if rope_theta_wrong is None else rope_theta_wrong
-    if theta is not None:           # None: no rotary embedding at all
+    if theta is not None and rotary:    # None: no rotary embedding at all
         rotary = hd if rotary_all else int(getattr(cfg, "partial_rotary_factor", 1.0) * hd)
         q = jnp.concatenate([_rope(q[..., :rotary], theta), q[..., rotary:]], axis=-1)
         k = jnp.concatenate([_rope(k[..., :rotary], theta), k[..., rotary:]], axis=-1)
     v = v.reshape(s, cfg.n_kv_heads, hd)
-    group = cfg.n_heads // cfg.n_kv_heads
-    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    out = []
-    for start in range(0, s, block):
-        rows = jnp.arange(start, min(start + block, s))
-        scores = jnp.einsum("qhd,khd->hqk", q[rows], k) / jnp.sqrt(jnp.float32(hd))
-        causal = jnp.arange(s)[None, :] <= rows[:, None]
-        probs = jax.nn.softmax(jnp.where(causal[None], scores, -jnp.inf), axis=-1)
-        out.append(jnp.einsum("hqk,khd->qhd", probs, v).reshape(len(rows), cfg.n_heads * hd))
+
+    def attend(start):
+        end = min(start + block, s)
+        # the keys a row of the block may see: none behind the first row's window
+        lo = max(start - window + 1, 0) if window else 0
+        return _one_compile_at_a_time(
+            ("attend", end - start, end - lo, q.shape[1:], k.shape[1:], window),
+            lambda: _attend_block(q[start:end], k[lo:end], v[lo:end], start, lo, window=window))
+
+    out = _in_threads(attend, list(range(0, s, block)))
     out = jnp.concatenate(out)
     if "wq_gate" in p and gate:
         out = out * jax.nn.sigmoid(x @ _f32(p["wq_gate"]))
@@ -438,24 +538,27 @@ def _gated_delta_net(p: dict, x, cfg, wrong: dict, seen: Optional[list] = None):
 EXPERT_ROWS = 64     # an expert's tokens are computed in whole buckets of this many rows
 
 
-def _swiglu(x, w1, w2, w3):
-    return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
+def _swiglu(x, w1, w2, w3, relu: bool = False):
+    """act(x W1) * (x W3), then W2: SiLU (SwiGLU), or ReLU (ReGLU: cfg.ffn_act "relu")."""
+    return ((jax.nn.relu if relu else jax.nn.silu)(x @ w1) * (x @ w3)) @ w2
 
 
-@jax.jit
-def _add_expert(out, x, share, took, n, w1, w2, w3, e):
+@partial(jax.jit, static_argnames=("relu",), donate_argnums=(0,))
+def _add_expert(out, x, share, took, n, w1, w2, w3, e, relu: bool = False):
     """``out`` plus expert ``e`` of the stacks on rows ``took`` of ``x`` (the
     first ``n`` of them count; the rest pad a bucket), each weighed by its
     ``share``: ONE compiled piece a bucket size, where the eager ops would be
-    a dozen compiles each."""
+    a dozen compiles each. ``out`` is donated: the caller holds the sum alone,
+    and a copy of [s, dim] an expert is most of a long forward's expert time."""
     weight = jnp.where(jnp.arange(took.shape[0]) < n, share[took], 0.0)
     return out.at[took].add(weight[:, None] * _swiglu(
-        x[took], _f32(w1, e), _f32(w2, e), _f32(w3, e)))
+        x[took], _f32(w1, e), _f32(w2, e), _f32(w3, e), relu))
 
 
 def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True,
              select_bias: bool = True, router_score: Optional[str] = None, follow=None,
-             shared_gate: bool = True, leave_out_held: bool = False):
+             shared_gate: bool = True, leave_out_held: bool = False, router_x=None,
+             relu: bool = False, renormalize: Optional[bool] = None):
     """The expert FFN of one layer, and what the router chose: ``experts``
     [s, k] largest weight first, their ``weights`` [s, k], and ``margin`` [s],
     by how much the last chosen score (with its selection bias) beats the
@@ -465,9 +568,11 @@ def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True
     scores, and ``behind`` [s] says by how much this side's last choice beats
     the worst expert followed (0 where both chose the same four; the size of
     the served noise where a near-tie fell the other way; more is a served
-    path that chooses by another rule)."""
+    path that chooses by another rule). ``router_x``: what the router
+    multiplies where it is not ``x`` (the block's input); ``relu``: ReGLU
+    experts; ``renormalize``: cfg.router_renormalize overruled."""
     n, k = cfg.n_experts, min(cfg.n_experts_per_token, cfg.n_experts)
-    logits = x @ _f32(p["router"])
+    logits = (x if router_x is None else router_x) @ _f32(p["router"])
     sigmoid = (router_score or getattr(cfg, "router_score", "softmax")) == "sigmoid"
     probs = jax.nn.sigmoid(logits) if sigmoid else jax.nn.softmax(logits, axis=-1)
     pick = probs
@@ -483,7 +588,7 @@ def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True
     by_weight = jnp.argsort(-weights, axis=-1)
     experts = jnp.take_along_axis(took, by_weight, axis=-1)
     weights = jnp.take_along_axis(weights, by_weight, axis=-1)
-    if cfg.router_renormalize:
+    if cfg.router_renormalize if renormalize is None else renormalize:
         eps = getattr(cfg, "router_renormalize_eps", None)
         eps = (1e-20 if sigmoid else 0.0) if eps is None else eps
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
@@ -497,26 +602,38 @@ def _experts(p: dict, x, cfg, leave_out_rank: Optional[int], shared: bool = True
     if leave_out_held:      # WRONG: the held expert each token weighs most (they are sorted)
         top = jnp.argmax(here, axis=-1)
         used = jnp.where(jnp.arange(k)[None, :] == top[:, None], 0.0, used)
-    out = jnp.zeros_like(x)
     # who took which expert, read ONCE (a read an expert is a wait an expert:
     # 1,536 of them in a 12-layer forward over 128 held experts)
     chosen, weight = np.asarray(experts), np.asarray(used)
     # the layer's stacks on the device ONCE (a tree taken to the host, as the
     # chip check holds it, would cross again for every expert)
     w1, w2, w3 = jax.device_put((p["w1"], p["w2"], p["w3"]))
-    for e in range(first, first + held):
-        # [s]; 0 = not chosen (a token takes an expert at most once: no sum is rounded)
-        share = np.where(chosen == e, weight, np.float32(0.0)).sum(axis=-1)
-        # the tokens that took expert e, and no other: in whole buckets of
-        # EXPERT_ROWS (the last repeated at weight 0), so that a forward
-        # compiles a handful of shapes and not one an expert a layer
-        rows = np.flatnonzero(share > 0)
-        if len(rows):
-            took = np.pad(rows, (0, -len(rows) % EXPERT_ROWS), mode="edge")
-            out = _add_expert(out, x, share, took, len(rows), w1, w2, w3, e - first)
+
+    def experts_of(lane):
+        """The sum of every ``lanes``-th expert from ``lane`` on, a loop."""
+        out = jnp.zeros_like(x)
+        for e in range(first + lane, first + held, lanes):
+            # [s]; 0 = not chosen (a token takes an expert at most once: no sum is rounded)
+            share = np.where(chosen == e, weight, np.float32(0.0)).sum(axis=-1)
+            # the tokens that took expert e, and no other: in whole buckets of
+            # EXPERT_ROWS (the last repeated at weight 0), so that a forward
+            # compiles a handful of shapes and not one an expert a layer
+            rows = np.flatnonzero(share > 0)
+            if len(rows):
+                took = np.pad(rows, (0, -len(rows) % EXPERT_ROWS), mode="edge")
+                sums = out      # (the donated sum: bound here, not in the closure's cell)
+                out = _one_compile_at_a_time(
+                    ("expert", x.shape, len(took), relu, jax.tree.structure(w1)),
+                    lambda: _add_expert(sums, x, share, took, len(rows), w1, w2, w3, e - first,
+                                        relu=relu))
+        return out
+
+    # a short sequence's experts are one loop; a long one's go a few loops side by side
+    lanes = 8 if x.shape[0] >= 1024 else 1
+    out = sum(_in_threads(experts_of, list(range(lanes))))
     if "shared" in p and shared:
         f = p["shared"]
-        y = _swiglu(x, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
+        y = _swiglu(x, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]), relu)
         if "shared_gate" in p and shared_gate:
             y = jax.nn.sigmoid(x @ _f32(p["shared_gate"])) * y
         out = out + y
@@ -542,7 +659,7 @@ def _mix(p: dict, X, cfg, iters: int):
 
 
 def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=None,
-           seen: Optional[list] = None):
+           seen: Optional[list] = None, index: int = 0):
     """One decoder block on the residual ``x`` [s, C], or on the streams
     [s, n, C] where the layer has mixing parameters and ``streams`` is on. A
     layer that holds a ``conv`` (LFM2) or a ``linear_attn`` (Qwen3-Next) mixes
@@ -553,6 +670,18 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
         cfg, "hc_sinkhorn_iters", 0)
 
     placement = wrong["norm_placement"] or getattr(cfg, "norm_placement", "pre")
+    # what this layer's attention sees (cfg.layer_types / cfg.rope_layout), and
+    # the wrong readings of each
+    kinds, layout = getattr(cfg, "layer_types", None), getattr(cfg, "rope_layout", None)
+    windowed = kinds is not None and index < len(kinds) and kinds[index] == "sliding_attention"
+    window = 0 if wrong["window_off"] or not windowed else (
+        wrong["window_wrong"] or cfg.sliding_window)
+    laid = layout is None or index >= len(layout) or bool(layout[index])
+    rotary = (laid and (wrong["rope_on_window"] or not windowed)) or (
+        not laid and wrong["rope_on_global"])
+    relu = (wrong["ffn_act"] or getattr(cfg, "ffn_act", "silu")) == "relu"
+    router_x = x if (wrong["router_input"] or getattr(cfg, "router_input", "ffn_input")
+                     ) == "layer_input" and not mixed else None
 
     def sub_layer(x, name, norm, f):
         if placement == "branch":   # Olmo 2 / 3: the norm on the branch, none before it
@@ -568,18 +697,20 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
             return _latent_attention(layer["attention"], n1, cfg, wrong["scale_mscale"],
                                      q_norm=wrong["q_norm"])
         return _attention(layer["attention"], n1, cfg, wrong["qk_norm"], wrong["attn_gate"],
-                          wrong["rotary_all"], rope_theta_wrong=wrong["rope_theta_wrong"])
+                          wrong["rotary_all"], rope_theta_wrong=wrong["rope_theta_wrong"],
+                          window=window, rotary=rotary)
 
     def ffn(n2):
         if moe:
             out, chose = _experts(layer["moe"], n2, cfg, wrong["leave_out_rank"], wrong["shared"],
                                   wrong["select_bias"], wrong["router_score"],
                                   None if follow is None else follow[:, len(routing)],
-                                  wrong["shared_gate"], wrong["leave_out_held"])
+                                  wrong["shared_gate"], wrong["leave_out_held"],
+                                  router_x, relu, wrong["renormalize"])
             routing.append(chose)
             return out
         f = layer["ffn"]
-        return _swiglu(n2, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
+        return _swiglu(n2, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]), relu)
 
     if "conv" in layer:
         x = sub_layer(x, None, "operator_norm",
@@ -609,7 +740,9 @@ WRONG = {"leave_out_rank": None, "scale_mscale": True, "shared": True, "streams"
          "gdn_silu": True, "gdn_z_gate": True, "gdn_state_bf16": False, "attn_gate": True,
          "rotary_all": False, "shared_gate": True, "leave_out_held": False,
          "gdn_beta_doubled": True, "gdn_q_scale": True, "norm_placement": None,
-         "rope_theta_wrong": None}
+         "rope_theta_wrong": None,
+         "window_off": False, "window_wrong": None, "rope_on_global": False,
+         "rope_on_window": True, "router_input": None, "ffn_act": None, "renormalize": None}
 
 
 def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[list] = None):
@@ -636,7 +769,7 @@ def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[
     x = _enter(_f32(p["tok_embeddings"])[jnp.asarray(tokens, jnp.int32)], cfg, wrong)
     for i in range(cfg.n_layers):
         x = _block(p[f"layer_{i}"], x, cfg, cfg.n_experts > 0 and i >= first_dense, routing, wrong,
-                   follow, seen)
+                   follow, seen, i)
     return _leave(x), routing
 
 

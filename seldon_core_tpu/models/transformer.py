@@ -51,13 +51,22 @@ from seldon_core_tpu.models.registry import register_model
 param_with_axes = nn_partitioning.param_with_axes
 with_sharding_constraint = nn_partitioning.with_sharding_constraint
 
-LAYER_KINDS = ("full_attention", "conv", "linear_attention")
+LAYER_KINDS = ("full_attention", "sliding_attention", "conv", "linear_attention")
+# the kinds that are ATTENTION (pages of K and V rows): a "sliding_attention"
+# layer's mask has a lower bound too (``sliding_window``) and its pages are of
+# the window class (models/cache.py WindowEntry), given back behind the window
+ATTENTION_LAYER_KINDS = ("full_attention", "sliding_attention")
 # the kinds whose layer keeps a fixed block of STATE a sequence, not pages
 STATE_LAYER_KINDS = ("conv", "linear_attention")
 STATE_LAYERS_COMPOSE_REFUSAL = (
     "a 'conv' or 'linear_attention' layer (layer_types) does not compose with "
     "latent attention (kv_lora_rank > 0), hyper-connections (hc_mult > 1) or an "
     "MTP module: no model pairs them and no test holds them")
+WINDOW_LAYERS_COMPOSE_REFUSAL = (
+    "a 'sliding_attention' layer (layer_types; sliding_window) is built for per-head K/V "
+    "attention on one device with the bf16 cache: not with latent attention "
+    "(kv_lora_rank > 0), hyper-connections (hc_mult > 1), an MTP module, a mesh, ring "
+    "attention or kv_cache_dtype='int8': no model pairs them and no test holds them")
 
 FUSED_NORM_STREAMS_REFUSAL = (
     "fused_norm does not compose with hyper-connections (hc_mult > 1): the "
@@ -138,6 +147,24 @@ class TransformerConfig:
     # layers' (values..., positions) tuples.
     layer_types: Any = None
     conv_L_cache: int = 3
+    # A "sliding_attention" layer attends the last ``sliding_window`` positions,
+    # the query's own included: k_pos <= q_pos and k_pos > q_pos - sliding_window
+    # (transformers' sliding-window overlay, llama.cpp's standard SWA). Its K/V
+    # pages behind the window are given back while the request lives
+    # (runtime/batcher.py: the window page class).
+    sliding_window: int = 0
+    # Rotary embedding a LAYER: None = ``rope_theta`` decides for all; else
+    # n_layers of 0 | 1, a 0 layer sees no position at all (the rope_theta=None
+    # path, for that layer alone).
+    rope_layout: Any = None
+    # The gate's activation in every gated FFN (the dense one, the experts, the
+    # shared expert): act(x W1) * (x W3). "silu" = SwiGLU; "relu" = ReGLU.
+    ffn_act: str = "silu"
+    # What the router multiplies: "ffn_input" = the FFN's own input (the normed
+    # residual behind attention; every family served before); "layer_input" =
+    # the block's INPUT, ahead of the first norm and of attention, so that the
+    # routing depends on nothing attention produces (SmallThinker).
+    router_input: str = "ffn_input"
     # A "linear_attention" layer is Qwen3-Next's Gated DeltaNet (GatedDeltaNet
     # below): linear_num_key_heads heads of q and k (linear_key_head_dim wide),
     # each serving linear_num_value_heads / linear_num_key_heads value heads
@@ -250,6 +277,15 @@ class TransformerConfig:
             if set(kinds) & set(STATE_LAYER_KINDS) and (
                     self.kv_lora_rank or self.hc_mult > 1 or self.mtp_layers):
                 raise ValueError(STATE_LAYERS_COMPOSE_REFUSAL)
+            if "sliding_attention" in kinds:
+                if self.sliding_window <= 0:
+                    raise ValueError(
+                        "a 'sliding_attention' layer needs sliding_window > 0 (the keys a "
+                        f"query sees, itself included); got {self.sliding_window}")
+                if (self.kv_lora_rank or self.hc_mult > 1 or self.mtp_layers
+                        or self.mesh is not None or self.attention_impl == "ring"
+                        or normalize_kv_cache_dtype(self.kv_cache_dtype) == "int8"):
+                    raise ValueError(WINDOW_LAYERS_COMPOSE_REFUSAL)
             if "conv" in kinds and self.conv_L_cache < 2:
                 raise ValueError(f"conv_L_cache={self.conv_L_cache} must be >= 2 (taps)")
             if "linear_attention" in kinds:
@@ -261,6 +297,28 @@ class TransformerConfig:
                         "linear_num_value_heads (a multiple of the key heads), "
                         "linear_key_head_dim, linear_value_head_dim and "
                         "linear_conv_kernel_dim >= 2")
+        if self.rope_layout is not None:
+            layout = tuple(self.rope_layout)
+            if len(layout) != self.n_layers or set(layout) - {0, 1}:
+                raise ValueError(
+                    f"rope_layout must give n_layers={self.n_layers} layers a 0 (no position) "
+                    f"or a 1 (rotary); got {len(layout)}: {sorted(set(layout))}")
+            if self.rope_theta is None or self.kv_lora_rank:
+                raise ValueError(
+                    "rope_layout chooses the layers rope_theta turns: it needs a rope_theta, "
+                    "and per-head K/V attention (not latent attention)")
+        if self.ffn_act not in ("silu", "relu"):
+            raise ValueError(f"unknown ffn_act {self.ffn_act!r}: expected 'silu' or 'relu'")
+        if self.router_input not in ("ffn_input", "layer_input"):
+            raise ValueError(
+                f"unknown router_input {self.router_input!r}: expected 'ffn_input' or "
+                "'layer_input'")
+        if self.router_input == "layer_input" and (
+                not self.n_experts or self.hc_mult > 1 or self.mtp_layers):
+            raise ValueError(
+                "router_input='layer_input' (the router reads the block's input) is built for "
+                "an MoE model with the plain residual: not with hc_mult > 1 (a block's input "
+                "is several streams) or an MTP module")
         if self.partial_rotary_factor != 1.0:
             rotary = self.partial_rotary_factor * self.head_dim
             if self.kv_lora_rank or not 0 < rotary <= self.head_dim or rotary % 2:
@@ -283,6 +341,22 @@ class TransformerConfig:
         attention."""
         kinds = self.layer_types
         return kinds[layer] if kinds is not None and layer < len(kinds) else "full_attention"
+
+    def layer_rotary(self, layer: int) -> bool:
+        """Does ``layer``'s attention turn q and k by their positions?"""
+        if self.rope_theta is None:
+            return False
+        layout = self.rope_layout
+        return layout is None or layer >= len(layout) or bool(layout[layer])
+
+    def layer_window(self, layer: int) -> int:
+        """The keys a query of ``layer`` sees, itself included; 0 = all before it."""
+        return self.sliding_window if self.layer_kind(layer) == "sliding_attention" else 0
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        """The attention layers whose pages are of the window class."""
+        return self.layers_of("sliding_attention")
 
     def small_leaf(self, name: str) -> str:
         """The SMALL_LEAF_INIT rule a seeded float32 leaf ``name`` is drawn by."""
@@ -454,17 +528,30 @@ class RMSNorm(nn.Module):
         return rms_norm(x, w, self.eps)
 
 
-def paged_attention_ref(q, cache, block_tables, positions, n_kv_heads: int):
+def attention_mask(key_pos, query_pos, window: int = 0):
+    """[b, s, L] bool: the ONE predicate of every attention read. ``key_pos``
+    [b, L] are the CACHED positions (PAD_POS = empty), ``query_pos`` [b, s]:
+    ``k <= q`` is causality, the unwritten rest and padding; with ``window``
+    also ``k > q - window`` (a query sees ``window`` keys, itself included), on
+    the positions, so a row still lying in a page behind the window is masked
+    like one whose page was given back."""
+    mask = key_pos[:, None, :] <= query_pos[:, :, None]
+    if window:
+        mask &= key_pos[:, None, :] > query_pos[:, :, None] - window
+    return mask
+
+
+def paged_attention_ref(q, cache, block_tables, positions, n_kv_heads: int, window: int = 0):
     """The paged read as an expression: gather the logical view through the
     block table and run the one masked-softmax chain on it, K/V kept
     n_kv_heads wide. What ``Attention`` computes on every lowering that keeps
     the expression, and the oracle tests/test_gqa_page_attention.py holds the
     live-page kernel to. q: [b, s, h, hd]; cache: the paged 3-tuple (bf16) or
-    5-tuple (int8) pool; positions: [b, s]. Returns [b, s, h, hd] in q.dtype."""
+    5-tuple (int8) pool; positions: [b, s]; ``window``: a sliding-attention
+    layer's. Returns [b, s, h, hd] in q.dtype."""
     k_all, v_all, pos_view = gather_paged_view(cache, block_tables, q.dtype, n_kv_heads)
     # one predicate for causality, empty rows (PAD_POS) and padding
-    mask = pos_view[:, None, :] <= positions[:, :, None]
-    return grouped_query_attention(q, k_all, v_all, mask)
+    return grouped_query_attention(q, k_all, v_all, attention_mask(pos_view, positions, window))
 
 
 def grouped_query_attention(q: jnp.ndarray, k_all: jnp.ndarray,
@@ -496,8 +583,9 @@ def grouped_query_attention(q: jnp.ndarray, k_all: jnp.ndarray,
     return out.reshape(b, s, n_heads, hd)
 
 
-@partial(jax.jit, static_argnames=("n_kv_heads", "walk"))
-def paged_live_read(q, cache, block_tables, positions, *, n_kv_heads: int, walk):
+@partial(jax.jit, static_argnames=("n_kv_heads", "walk", "window"))
+def paged_live_read(q, cache, block_tables, positions, *, n_kv_heads: int, walk,
+                    window: int = 0):
     """The paged bf16 pool's read of a call shape the live-page kernel takes
     (``walk`` = ``paged_read_walk(...)``, not None): the kernel
     (ops/gqa_attention.py) in a program lowered for a TPU, the expression
@@ -505,13 +593,13 @@ def paged_live_read(q, cache, block_tables, positions, *, n_kv_heads: int, walk)
     a program's layers share ONE trace of both branches (a trace a layer is
     start-up time no compile cache serves: PERF.md section 6, PR 41)."""
     def read_expression():
-        return paged_attention_ref(q, cache, block_tables, positions, n_kv_heads)
+        return paged_attention_ref(q, cache, block_tables, positions, n_kv_heads, window)
 
     def read_live_pages():
         from seldon_core_tpu.ops.gqa_attention import gqa_page_attention
 
         return gqa_page_attention(q, *cache, block_tables, positions, n_kv_heads, walk,
-                                  interpret=False)
+                                  interpret=False, window=window)
 
     return jax.lax.platform_dependent(tpu=read_live_pages, default=read_expression)
 
@@ -540,6 +628,11 @@ def lora_delta(x: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    # the layer's own (TransformerBlock reads them off cfg.layer_types /
+    # cfg.rope_layout): the keys a query sees (0 = every one before it) and
+    # whether q and k are turned by their positions
+    window: int = 0
+    rotary: bool = True
 
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
@@ -613,18 +706,21 @@ class Attention(nn.Module):
             k = RMSNorm(hd, cfg.norm_eps, "head_norm", name="k_norm")(k)
         v = (x @ wv.astype(dt)).reshape(b, s, cfg.n_kv_heads, hd)
 
-        if cfg.rope_theta is not None:
+        if cfg.rope_theta is not None and self.rotary:
             cos, sin = rotary_embedding(positions, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling)
             q = apply_partial_rotary(q, cos, sin)
             k = apply_partial_rotary(k, cos, sin)
 
+        window = self.window
+        # a window layer's read and write are scopes of their own (attn.window.*)
+        scope = "attn.window" if window else "attn.gqa"
         out = None
         if cache is None:
             k_all, v_all, pos_view, new_cache = k, v, positions, (k, v)
         else:
             # the entry's rows: int8 quantizes on write (values + a scale a
             # head), and the read dequantizes fused into its einsums
-            with jax.named_scope("attn.gqa.write"):
+            with jax.named_scope(scope + ".write"):
                 rows = (*quantize_kv(k), *quantize_kv(v)) if entry_is_int8(cache) else (k, v)
                 new_cache = write_rows(cache, rows, positions, block_tables=block_tables,
                                        cache_index=cache_index)
@@ -641,12 +737,13 @@ class Attention(nn.Module):
                 bt = jnp.asarray(block_tables, jnp.int32)
                 pool = new_cache[0]
                 walk = paged_read_walk(cfg, s, bt.shape[1], pool.shape[1], pool.dtype)
-                with jax.named_scope("attn.gqa.read"):
+                with jax.named_scope(scope + ".read"):
                     if walk is not None:
                         out = paged_live_read(q, new_cache, bt, positions,
-                                              n_kv_heads=cfg.n_kv_heads, walk=walk)
+                                              n_kv_heads=cfg.n_kv_heads, walk=walk, window=window)
                     else:
-                        out = paged_attention_ref(q, new_cache, bt, positions, cfg.n_kv_heads)
+                        out = paged_attention_ref(q, new_cache, bt, positions, cfg.n_kv_heads,
+                                                  window)
 
         if cache is None and cfg.attention_impl == "ring":
             from seldon_core_tpu.ops.ring_attention import ring_attention
@@ -659,8 +756,8 @@ class Attention(nn.Module):
             # every cache layout but the paged pool's ends here: K/V stay
             # n_kv_heads wide. Empty rows hold PAD_POS, so one predicate covers
             # causality, the unfilled suffix and right-padding garbage
-            mask = pos_view[:, None, :] <= positions[:, :, None]  # [b, s, kv]
-            with jax.named_scope("attn.gqa.read"):
+            mask = attention_mask(pos_view, positions, window)  # [b, s, kv]
+            with jax.named_scope(scope + ".read"):
                 out = grouped_query_attention(q, k_all, v_all, mask)
         out = out.reshape(b, s, cfg.n_heads * hd)
         if cfg.attn_gate:
@@ -896,6 +993,11 @@ class LatentAttention(nn.Module):
         return out.reshape(b, s, H * dv) @ wo.astype(dt), new_cache
 
 
+def ffn_activation(cfg: TransformerConfig):
+    """The gate's activation of every gated FFN (``cfg.ffn_act``)."""
+    return jax.nn.relu if cfg.ffn_act == "relu" else jax.nn.silu
+
+
 class DenseFFN(nn.Module):
     cfg: TransformerConfig
     width: int = 0   # 0 = cfg.ffn_dim
@@ -919,7 +1021,7 @@ class DenseFFN(nn.Module):
                                  adapters["scale"])
             gate = gate + lora_delta(x, *adapters["w3"], adapter_ids,
                                      adapters["scale"])
-        h = jax.nn.silu(up) * gate
+        h = ffn_activation(cfg)(up) * gate
         down = h @ w2.astype(dt)
         if adapters is not None:
             down = down + lora_delta(h, *adapters["w2"], adapter_ids,
@@ -966,7 +1068,11 @@ class MoEFFN(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, valid: Optional[jnp.ndarray] = None):
+    def __call__(self, x, valid: Optional[jnp.ndarray] = None,
+                 router_in: Optional[jnp.ndarray] = None):
+        """``router_in`` [b, s, dim]: the array the router multiplies where it is
+        not the FFN's own input ``x`` (cfg.router_input "layer_input": the
+        block's input, handed in by TransformerBlock)."""
         from seldon_core_tpu.ops.quantize import QuantizedTensor
 
         cfg = self.cfg
@@ -992,7 +1098,8 @@ class MoEFFN(nn.Module):
             select_bias = param_with_axes("router_bias", small_leaf_init("router_bias"), (e,),
                                           jnp.float32, axes=("expert_select",))
         with jax.named_scope("moe.route"):
-            logits = xf.astype(jnp.float32) @ router
+            routed = xf if router_in is None else router_in.reshape(t, d)
+            logits = routed.astype(jnp.float32) @ router
             if cfg.router_score == "sigmoid":
                 probs = jax.nn.sigmoid(logits)
             else:
@@ -1039,8 +1146,10 @@ class MoEFFN(nn.Module):
             def split(w):  # an int8 stack goes in as int8, with its scales
                 return (w.q, w.scale) if isinstance(w, QuantizedTensor) else (w, None)
 
+            act = ffn_activation(cfg)
+
             def swiglu(grouped):
-                h = jax.nn.silu(grouped(rows, *split(w1))) * grouped(rows, *split(w3))
+                h = act(grouped(rows, *split(w1))) * grouped(rows, *split(w3))
                 return grouped(h.astype(dt), *split(w2))
 
             def experts_kernel():
@@ -1642,11 +1751,14 @@ class TransformerBlock(nn.Module):
         if streams:
             X, (x, h_post, h_res) = x, HyperConnection(cfg, name="attention_hc")(x)
         kind = cfg.layer_kind(self.layer)
+        # what the router multiplies where it reads the block's INPUT: nothing
+        # is computed here, MoEFFN is handed the array
+        router_in = x if cfg.router_input == "layer_input" else None
         # the SAME two weights a layer stand before a sub-layer (x + f(norm(x)))
         # or on its branch (x + norm(f(x)): cfg.norm_placement "branch")
         branch = cfg.norm_placement == "branch"
         mixer_norm = RMSNorm(cfg.dim, cfg.norm_eps, name="attention_norm"
-                             if kind == "full_attention" else "operator_norm")
+                             if kind in ATTENTION_LAYER_KINDS else "operator_norm")
 
         def mixer_in():
             return x if branch else mixer_norm(x)
@@ -1661,8 +1773,13 @@ class TransformerBlock(nn.Module):
             h, new_cache = GatedDeltaNet(cfg, name="linear_attn")(
                 mixer_in(), positions, valid, cache, state_slots)
         else:
+            # a pair of tables (full, window): each layer reads its class's
+            if isinstance(block_tables, tuple):
+                block_tables = block_tables[kind == "sliding_attention"]
+            own = {} if cfg.kv_lora_rank else {
+                "window": cfg.layer_window(self.layer), "rotary": cfg.layer_rotary(self.layer)}
             with jax.named_scope("attn"):
-                h, new_cache = attention(cfg, name="attention")(
+                h, new_cache = attention(cfg, name="attention", **own)(
                     mixer_in(), positions, cache,
                     cache_index, block_tables, adapters, adapter_ids,
                 )
@@ -1685,7 +1802,7 @@ class TransformerBlock(nn.Module):
             x = x + h
             ffn_in = x if branch else ffn_norm(x)
         if cfg.n_experts > 0 and self.layer >= cfg.first_dense_layers:
-            f = MoEFFN(cfg, name="moe")(ffn_in, valid)
+            f = MoEFFN(cfg, name="moe")(ffn_in, valid, router_in)
         else:
             width = cfg.dense_ffn_dim if cfg.n_experts > 0 else 0
             f = DenseFFN(cfg, width, name="ffn")(ffn_in, adapters, adapter_ids)
@@ -1748,8 +1865,10 @@ class Transformer(nn.Module):
         With ``next_tokens`` ([b, s] int32: each position's NEXT token) and
         cfg.mtp_layers, a cache-less forward also returns the MTP module's
         logits [b, s, vocab] (position t: token t + 2), as a third value.
-        ``block_tables`` ([b, n_pages] int32, shared by every layer) switches
-        the caches to the paged-pool layout — see Attention.
+        ``block_tables`` ([b, n_pages] int32, shared by every layer; or a pair
+        of such tables ``(full, window)`` where cfg.window_layers: each layer
+        reads the table of its page class) switches the caches to the
+        paged-pool layout — see Attention.
 
         ``adapters`` (the dense LoRA pool pytree from
         runtime/adapters.py: {proj: (A [N, L, d_in, r], B [N, L, r,
@@ -1793,7 +1912,8 @@ class Transformer(nn.Module):
             # state is the chunks' to write)
             valid = positions < PAD_POS
             if block_tables is not None:
-                valid &= (jnp.asarray(block_tables)[:, :1] != TRASH_PAGE)
+                full = block_tables[0] if isinstance(block_tables, tuple) else block_tables
+                valid &= (jnp.asarray(full)[:, :1] != TRASH_PAGE)
         new_caches = []
         for i in range(cfg.n_layers):
             layer_cache = caches[i] if caches is not None else None
@@ -1853,8 +1973,9 @@ def make_transformer(**kwargs):
     scaling = kwargs.pop("rope_scaling", None)
     if isinstance(scaling, dict):  # normalize to a hashable config field
         scaling = tuple(sorted(scaling.items()))
-    if isinstance(kwargs.get("layer_types"), list):
-        kwargs["layer_types"] = tuple(kwargs["layer_types"])
+    for listed in ("layer_types", "rope_layout"):
+        if isinstance(kwargs.get(listed), list):
+            kwargs[listed] = tuple(kwargs[listed])
     kvd = normalize_kv_cache_dtype(kwargs.pop("kv_cache_dtype", "bf16"))
     cfg = TransformerConfig(dtype=jnp.dtype(dtype), rope_scaling=scaling,
                             kv_cache_dtype=kvd, **kwargs)

@@ -77,13 +77,16 @@ def gqa_plan(s: int, heads: int, n_kv_heads: int, head_dim: int, n_pages: int,
 
 
 def gqa_page_attention(q, k_pool, v_pool, pos_pool, block_tables, positions,
-                       n_kv_heads: int, walk: Plan, interpret: bool | None = None):
+                       n_kv_heads: int, walk: Plan, interpret: bool | None = None,
+                       window: int = 0):
     """``q`` [b, s, H, hd] rotated queries in the pools' dtype; ``k_pool`` /
     ``v_pool`` [pages, page_size, n_kv_heads * hd] / ``pos_pool`` [pages,
     page_size] int32 as held; ``block_tables`` [b, n_pages]; ``positions``
     [b, s] -> [b, s, H, hd] = softmax(hd^-0.5 q_h . k_g, pos <= position) v_g
     over the rows the tables name, head h reading KV head g = h // (H //
-    n_kv_heads), in ``q``'s dtype. ``walk`` = ``gqa_plan(...)`` of the same
+    n_kv_heads), in ``q``'s dtype; a sliding-attention layer's ``window`` > 0
+    bounds the predicate below too (position - window < pos) and starts the
+    walk at the first live page. ``walk`` = ``gqa_plan(...)`` of the same
     shapes. ``interpret=None`` compiles the kernel on a TPU and interprets it
     on any other backend; pass a bool to force either."""
     import jax.numpy as jnp
@@ -101,7 +104,8 @@ def gqa_page_attention(q, k_pool, v_pool, pos_pool, block_tables, positions,
     if pad:
         ctx = jnp.pad(ctx, ((0, 0), (0, 0), (0, pad), (0, 0)))
     ctx = page_walk_attention(ctx, (k_pool, v_pool), pos_pool, block_tables, positions,
-                              hd**-0.5, block, walk, KERNEL_NAME, interpret)[:, :, :heads]
+                              hd**-0.5, block, walk, KERNEL_NAME, interpret,
+                              window)[:, :, :heads]
     if held > 1:
         ctx = jnp.sum(jnp.where(in_slot[:, :, None], ctx.reshape(b, s, heads, held, hd), 0), axis=3)
     return ctx

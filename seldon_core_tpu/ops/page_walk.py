@@ -27,6 +27,13 @@ a sequence has live, straight from the pool, once, under a running softmax:
 - the mask is the expression's ONE predicate, ``pos <= position``, on the
   positions cached beside the rows: causality, empty rows (PAD_POS), a
   half-filled page, rows a rejected draft left behind.
+- a sliding-attention layer (``window`` > 0) has a FIRST live page as well:
+  the one that holds the smallest position any query of the call may see
+  (its smallest valid position - window + 1). Entries before it read
+  NULL_PAGE too (the batcher gave those pages back) and no visit lies wholly
+  before it; the predicate gains ``pos > position - window``, on the cached
+  positions, so a row in a page not yet given back is masked as well. With
+  ``window`` 0 the call is, operand for operand, the one it always was.
 - the queries of a sequence are its ``s x H`` rows, each as wide as a cached
   row, against one shared row a token: the decode step (s = 1, every slot a
   sequence), the prefill chunk (one sequence, 256 x H rows in tiles of
@@ -127,6 +134,12 @@ def plan(s: int, heads: int, n_pages: int, page_size: int, row_dim: int,
     if row_dim % LANES or out_dim % LANES or not 0 < out_dim <= row_dim:
         return None
     tile = BLOCK_QUERY_TILE if tiled else QUERY_TILE
+    if tiled and q_rows > tile and q_rows % tile:
+        # a block's query heads do not fill the tile a whole number of times (7
+        # heads a KV head: 3,584 rows of a 512-token chunk): the largest tile
+        # under it that does, in whole bf16 sublane tiles of tokens a head
+        unit = 16 * heads // blocks
+        tile = next((t for t in range(tile // unit * unit, 0, -unit) if q_rows % t == 0), tile)
     visit_rows = BLOCK_VISIT_ROWS if tiled else TILED_VISIT_ROWS if q_rows >= tile else VISIT_ROWS
     visit_rows = min(visit_rows, VISIT_BYTES // (pools * row_dim * 2))
     per_visit = visit_rows // page_size
@@ -150,19 +163,39 @@ def live_pages(block_tables, positions, page_size: int):
     padding, or a slot nobody holds, whose table row is all TRASH_PAGE)."""
     import jax.numpy as jnp
 
+    top = jnp.max(jnp.where(_valid_queries(block_tables, positions), positions, -1), axis=1)
+    return jnp.minimum((top.astype(jnp.int32) + page_size) // page_size, block_tables.shape[1])
+
+
+def _valid_queries(block_tables, positions):
+    import jax.numpy as jnp
+
     from seldon_core_tpu.models.cache import PAD_POS, TRASH_PAGE
 
     p = positions.astype(jnp.int32)
-    valid = (p >= 0) & (p < PAD_POS) & (block_tables[:, :1] != TRASH_PAGE)
-    top = jnp.max(jnp.where(valid, p, -1), axis=1)
-    return jnp.minimum((top + page_size) // page_size, block_tables.shape[1])
+    return (p >= 0) & (p < PAD_POS) & (block_tables[:, :1] != TRASH_PAGE)
 
 
-def rows_visited(live_rows: int, page_size: int, walk: Plan) -> int:
+def first_live_pages(block_tables, positions, page_size: int, window: int):
+    """[b] int32: the FIRST table entry a sliding-attention layer's read has to
+    visit, the page that holds the smallest position any valid query of the
+    sequence may see (its smallest valid position - ``window`` + 1); 0 where no
+    query is valid."""
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models.cache import PAD_POS
+
+    low = jnp.min(jnp.where(_valid_queries(block_tables, positions), positions, PAD_POS), axis=1)
+    low = jnp.where(low < PAD_POS, low.astype(jnp.int32), 0)
+    return jnp.maximum(low - window + 1, 0) // page_size
+
+
+def rows_visited(live_rows: int, page_size: int, walk: Plan, first_row: int = 0) -> int:
     """Cached rows the kernel multiplies for one sequence whose queries reach
-    ``live_rows`` rows: whole visits."""
+    ``live_rows`` rows: whole visits (from the one that holds row ``first_row``:
+    a sliding-attention layer's first row inside the window)."""
     rows = walk.pages * page_size
-    return -(-live_rows // rows) * rows
+    return -(-live_rows // rows) * rows - first_row // rows * rows
 
 
 class Visits(NamedTuple):
@@ -170,7 +203,10 @@ class Visits(NamedTuple):
     kernel's scalar-prefetch operands; ``count`` of them (the grid's length:
     at least one, which finishes nothing where no sequence has a live page).
     ``table`` is the block table with the entries behind each sequence's live
-    pages read as NULL_PAGE, padded to whole visits, flat."""
+    pages read as NULL_PAGE, padded to whole visits, flat. With a first live
+    page (a sliding-attention layer) ``start`` flags each sequence's first
+    visit, which is then not its group 0, and the entries before the first
+    live page read NULL_PAGE too."""
 
     seq: "jax.Array"
     group: "jax.Array"
@@ -178,12 +214,14 @@ class Visits(NamedTuple):
     live: "jax.Array"
     table: "jax.Array"
     count: "jax.Array"
+    start: Optional["jax.Array"] = None
 
 
-def make_visits(block_tables, live, walk: Plan) -> Visits:
+def make_visits(block_tables, live, walk: Plan, first=None) -> Visits:
     """``block_tables`` [b, n_pages] int32, ``live`` [b] (``live_pages``): a
     sequence visits the groups of ``walk.pages`` entries that hold a live
-    page."""
+    page; with ``first`` [b] (``first_live_pages``) those from the group that
+    holds its first live page on."""
     import jax.numpy as jnp
 
     from seldon_core_tpu.models.cache import NULL_PAGE
@@ -196,26 +234,39 @@ def make_visits(block_tables, live, walk: Plan) -> Visits:
                     constant_values=NULL_PAGE)
     table = jnp.where(entry < live[:, None], table, NULL_PAGE)
     n_visits = (-(-live // walk.pages)).astype(jnp.int32)
+    if first is not None:
+        table = jnp.where(entry >= first[:, None], table, NULL_PAGE)
+        first_group = jnp.minimum(first // walk.pages, n_visits).astype(jnp.int32)
+        n_visits = n_visits - first_group
     ends = jnp.cumsum(n_visits)
     i = jnp.arange(b * groups, dtype=jnp.int32)
     # (all comparisons at once: the default's binary search is a device loop)
     seq = jnp.minimum(jnp.searchsorted(ends, i, side="right", method="compare_all"),
                       b - 1).astype(jnp.int32)
-    group = jnp.clip(i - (ends - n_visits)[seq], 0, groups - 1)
-    return Visits(seq=seq, group=group, last=(group == n_visits[seq] - 1).astype(jnp.int32),
+    nth = jnp.clip(i - (ends - n_visits)[seq], 0, groups - 1)
+    if first is None:
+        return Visits(seq=seq, group=nth, last=(nth == n_visits[seq] - 1).astype(jnp.int32),
+                      live=live.astype(jnp.int32), table=table.reshape(-1),
+                      count=jnp.maximum(ends[-1], 1))
+    return Visits(seq=seq, group=jnp.minimum(nth + first_group[seq], groups - 1),
+                  last=(nth == n_visits[seq] - 1).astype(jnp.int32),
                   live=live.astype(jnp.int32), table=table.reshape(-1),
-                  count=jnp.maximum(ends[-1], 1))
+                  count=jnp.maximum(ends[-1], 1), start=(nth == 0).astype(jnp.int32))
 
 
 def _kernel(walk: Plan, page_size: int, pools: int, fold: int, out_dim: int, scale: float,
-            groups: int, seq, group, last, live, table, *refs):
+            groups: int, window: int, seq, group, last, live, table, *refs):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     del table   # the index maps' (which pages are in the page refs)
+    if window:
+        start, *refs = refs
     if walk.blocks > 1:
         top, low, *refs = refs
+        if window:
+            bottom, high, *refs = refs
     q_ref, qpos_ref, pos_ref, *refs = refs
     n_pages = pools * walk.pages
     page_refs = refs[:n_pages]
@@ -225,7 +276,7 @@ def _kernel(walk: Plan, page_size: int, pools: int, fold: int, out_dim: int, sca
     v = pl.program_id(1)
     lowest = jnp.finfo(jnp.float32).min
 
-    @pl.when(group[v] == 0)
+    @pl.when(start[v] == 1 if window else group[v] == 0)
     def _start():
         m_ref[...] = jnp.full_like(m_ref, lowest)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -267,6 +318,8 @@ def _kernel(walk: Plan, page_size: int, pools: int, fold: int, out_dim: int, sca
         return acc_ref[at] / jnp.where(total > 0.0, total, 1.0)
 
     def predicate():   # the ONE: causality, empty rows (PAD_POS), padding
+        if window:     # ... and a sliding-attention layer's lower bound
+            return (pos_ref[...] <= qpos_ref[...]) & (pos_ref[...] > qpos_ref[...] - window)
         return pos_ref[...] <= qpos_ref[...]
 
     here = live[seq[v]] > group[v] * walk.pages
@@ -317,6 +370,9 @@ def _kernel(walk: Plan, page_size: int, pools: int, fold: int, out_dim: int, sca
     # chunk's last visits) computes no predicate: the same sums, a third of the
     # softmax's VPU work less
     whole = top[seq[v] * groups + group[v]] <= low[seq[v] * pl.num_programs(0) + pl.program_id(0)]
+    if window:   # ... and its smallest cached position inside the window of the tile's LAST row
+        whole &= (bottom[seq[v] * groups + group[v]]
+                  > high[seq[v] * pl.num_programs(0) + pl.program_id(0)] - window)
     pl.when(here & whole)(functools.partial(visit, False))
     pl.when(here & jnp.logical_not(whole))(functools.partial(visit, True))
 
@@ -332,13 +388,16 @@ def _kernel(walk: Plan, page_size: int, pools: int, fold: int, out_dim: int, sca
 
 
 def page_walk_attention(q, pools, pos_pool, block_tables, positions, scale: float,
-                        out_dim: int, walk: Plan, name: str, interpret: bool | None = None):
+                        out_dim: int, walk: Plan, name: str, interpret: bool | None = None,
+                        window: int = 0):
     """``q`` [b, s, H, W] query rows as wide as a cached row, in the pools'
     dtype; ``pools`` one or two arrays [pages, page_size, W] as held (the first
     scored, the last summed) / ``pos_pool`` [pages, page_size] int32;
     ``block_tables`` [b, n_pages]; ``positions`` [b, s] -> [b, s, H, out_dim] =
     softmax(scale q . rows, pos <= position) rows'[:, :out_dim] over the rows
-    the tables name, in ``q``'s dtype. ``walk`` = ``plan(...)`` of the same
+    the tables name, in ``q``'s dtype (with ``window`` > 0 the predicate is
+    position - window < pos <= position and the walk starts at each sequence's
+    first live page). ``walk`` = ``plan(...)`` of the same
     shapes; ``name`` is the op's in a device trace. ``interpret=None`` compiles
     the kernel on a TPU and interprets it on any other backend; pass a bool to
     force either.
@@ -356,7 +415,7 @@ def page_walk_attention(q, pools, pos_pool, block_tables, positions, scale: floa
         interpret = pallas_interpret_default()
     return _jitted_walk()(q, tuple(pools), pos_pool, jnp.asarray(block_tables, jnp.int32),
                           positions, scale=scale, out_dim=out_dim, walk=walk, name=name,
-                          interpret=interpret)
+                          interpret=interpret, window=window)
 
 
 @functools.cache
@@ -364,10 +423,11 @@ def _jitted_walk():
     import jax
 
     return jax.jit(_walk_pages,
-                   static_argnames=("scale", "out_dim", "walk", "name", "interpret"))
+                   static_argnames=("scale", "out_dim", "walk", "name", "interpret", "window"))
 
 
-def _walk_pages(q, pools, pos_pool, bt, positions, *, scale, out_dim, walk, name, interpret):
+def _walk_pages(q, pools, pos_pool, bt, positions, *, scale, out_dim, walk, name, interpret,
+                window=0):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -399,7 +459,8 @@ def _walk_pages(q, pools, pos_pool, bt, positions, *, scale, out_dim, walk, name
     def shaped(down, along):   # a lane block's scores lie [cached rows, query rows]
         return (along, down) if tiled else (down, along)
 
-    visits = make_visits(bt, live_pages(bt, positions, page_size), walk)
+    first = first_live_pages(bt, positions, page_size, window) if window else None
+    visits = make_visits(bt, live_pages(bt, positions, page_size), walk, first)
     # the positions cached beside the rows the visits fetch: 256 B a page
     pos_view = pos_pool[visits.table].reshape((b * groups,) + shaped(1, rows))
     if tiled:   # a tile's query rows are head-major: its tokens' positions once a head
@@ -416,6 +477,16 @@ def _walk_pages(q, pools, pos_pool, bt, positions, *, scale, out_dim, walk, name
     if tiled:
         bounds = (jnp.max(pos_view.reshape(b * groups, rows), axis=1),
                   jnp.min(qpos.reshape(b * q_tiles, tq), axis=1))
+        if window:
+            # the smallest position cached in a visit's rows, the largest a tile's
+            # VALID query rows hold (a padded row's result is nobody's)
+            from seldon_core_tpu.models.cache import PAD_POS
+
+            flat_q = qpos.reshape(b * q_tiles, tq)
+            bounds += (jnp.min(pos_view.reshape(b * groups, rows), axis=1),
+                       jnp.max(jnp.where(flat_q < PAD_POS, flat_q, -1), axis=1))
+    if window:
+        bounds = (visits.start,) + bounds
 
     def page_spec(j):
         return pl.BlockSpec(
@@ -424,7 +495,8 @@ def _walk_pages(q, pools, pos_pool, bt, positions, *, scale, out_dim, walk, name
                 (table[(seq[v] * groups + group[v]) * walk.pages + j], 0, 0))
 
     out = pl.pallas_call(
-        functools.partial(_kernel, walk, page_size, len(pools), fold, out_dim, scale, groups),
+        functools.partial(_kernel, walk, page_size, len(pools), fold, out_dim, scale, groups,
+                          window),
         out_shape=jax.ShapeDtypeStruct((b, q.shape[1], together * out_dim), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5 + len(bounds),
@@ -453,5 +525,5 @@ def _walk_pages(q, pools, pos_pool, bt, positions, *, scale, out_dim, walk, name
     return out.reshape(b, s, heads, out_dim)
 
 
-__all__ = ["Plan", "Visits", "live_pages", "make_visits", "page_walk_attention", "plan",
-           "rows_visited"]
+__all__ = ["Plan", "Visits", "first_live_pages", "live_pages", "make_visits",
+           "page_walk_attention", "plan", "rows_visited"]

@@ -318,6 +318,9 @@ class PageAllocator:
         self._free = list(range(self.total - 1, RESERVED_PAGES - 1, -1))
         self._refs: Dict[int, int] = {}   # page -> refcount (allocated only)
         self.shed_total = 0
+        # pages given back while the request that held them lived (the window
+        # class's, behind each slot's window: ``give_back``)
+        self.released_total = 0
 
     @property
     def capacity(self) -> int:
@@ -360,6 +363,13 @@ class PageAllocator:
                     del self._refs[p]
                     self._free.append(p)
 
+    def give_back(self, pages: Sequence[int]) -> None:
+        """``free`` for pages a LIVE request lets go of (a window layer's,
+        behind its window), counted under the same lock as the free list."""
+        self.free(pages)
+        with self._lock:
+            self.released_total += len(pages)
+
     def refs_of(self, page: int) -> int:
         """Current refcount (0 = free) — the trie's evictability probe."""
         with self._lock:
@@ -396,7 +406,7 @@ class _PrefillJob:
 
     __slots__ = ("slot", "ids", "L", "start", "next", "chunk", "max_new", "fut",
                  "on_token", "info", "seed", "bt_row", "pages", "t_arrival",
-                 "req", "asides")
+                 "req", "asides", "wrow")
 
     def __init__(self, slot, ids, start, chunk, max_new, fut, on_token,
                  info, seed, bt_row, pages, t_arrival=None, req=None):
@@ -415,6 +425,10 @@ class _PrefillJob:
         self.info = info
         self.seed = seed
         self.bt_row = bt_row         # device [1, n_pages] int32
+        # the WINDOW class's row (a model with sliding-attention layers): host
+        # [n_pages] int32, booked chunk by chunk (``_window_book``): NULL_PAGE
+        # behind the window and ahead of the rows written so far
+        self.wrow: Optional[np.ndarray] = None
         self.pages = pages           # host mirror of the allocated pages
         self.t_arrival = t_arrival   # submit() wall clock, for TTFT
         # the scheduler's PendingRequest: tenant/SLO identity, adapter id,
@@ -462,7 +476,7 @@ class _Slot:
     __slots__ = ("future", "tokens", "true_len", "n_new", "max_new", "active",
                  "on_token", "gen", "disp_new", "pages", "shared", "ids",
                  "prefilling", "admit_seq", "t_last", "tenant", "slo_class",
-                 "adapter_id", "logits", "routing")
+                 "adapter_id", "logits", "routing", "wpages")
 
     def __init__(self):
         self.active = False
@@ -508,6 +522,10 @@ class _Slot:
         # can insert prompt+generated blocks back into the trie.
         self.pages: List[int] = []
         self.shared: List[int] = []
+        # the WINDOW class's pages it holds, by the table entry each backs (a
+        # model with sliding-attention layers): only the entries from the
+        # window's first page on; those behind were given back
+        self.wpages: Dict[int, int] = {}
         self.ids: Optional[List[int]] = None
         self.prefilling = False
         self.admit_seq = 0
@@ -669,6 +687,13 @@ class LoopPhases:
         # view, or whole visits of the live-page kernel): visited / context
         # is the over-read
         self.attn_rows_read = dict.fromkeys(MOE_PROGRAMS, 0)
+        # the same three for the sliding-attention layers of a model that has
+        # them (kind="window"; the three above are then its full layers'):
+        # ``context_tokens`` the rows INSIDE the window, and ``unwindowed``
+        # what a full layer would have had to read for the same queries
+        self.attn_window = {key: dict.fromkeys(MOE_PROGRAMS, 0)
+                            for key in ("calls", "context_tokens", "rows_read",
+                                        "context_tokens_unwindowed")}
         # how the chunks' rows reached the paged pool (whole pages, or one
         # scatter row a token) and the pool pages a layer's write wrote
         self.kv_chunk_writes = dict.fromkeys(KV_WRITE_PATHS, 0)
@@ -785,6 +810,8 @@ class LoopPhases:
                 "attn_calls": dict(self.attn_calls),
                 "attn_context_tokens": dict(self.attn_context_tokens),
                 "attn_rows_read": dict(self.attn_rows_read),
+                **({f"attn_window_{key}": dict(tally) for key, tally in self.attn_window.items()}
+                   if any(self.attn_window["calls"].values()) else {}),
                 "kv_chunk_writes": dict(self.kv_chunk_writes),
                 "kv_pages_written": dict(self.kv_pages_written),
                 "chunk_head": dict(self.chunk_head),
@@ -794,6 +821,14 @@ class LoopPhases:
         self.attn_calls[program] += 1
         self.attn_context_tokens[program] += context_tokens
         self.attn_rows_read[program] += rows_read
+
+    def count_window_attention(self, program: str, context_tokens: int, rows_read: int,
+                               unwindowed: int) -> None:
+        tally = self.attn_window
+        tally["calls"][program] += 1
+        tally["context_tokens"][program] += context_tokens
+        tally["rows_read"][program] += rows_read
+        tally["context_tokens_unwindowed"][program] += unwindowed
 
     def count_chunk_write(self, path: str, pages: int) -> None:
         self.kv_chunk_writes[path] += 1
@@ -1266,6 +1301,20 @@ class ContinuousBatcher:
         # None before the first chunk
         self._chunk_loads: Optional[Dict[int, threading.Thread]] = None
         self._allocator = PageAllocator(self.pool_pages, ps)
+        # The WINDOW class (a model with sliding-attention layers,
+        # cfg.window_layers): a second pool, allocator and block table a slot,
+        # for the layers whose pages behind the window are given back while the
+        # request lives. Always fully provisioned: a slot holds at most a
+        # window, the widest chunk and a page of it (``window_slot_pages``),
+        # whatever its length, so the class cannot run out and sheds nobody;
+        # ``kv_pool_pages`` oversubscribes the full class alone.
+        self.window = int(cfg.sliding_window) if cfg.window_layers else 0
+        self._window_allocator: Optional[PageAllocator] = None
+        if self.window:
+            self.window_slot_pages = min(self.n_pages, kvcache.window_slot_pages(
+                self.window, max(self.prefill_chunk, self.prefill_wide), ps))
+            self.window_pool_pages = self.S * self.window_slot_pages + RESERVED_PAGES
+            self._window_allocator = PageAllocator(self.window_pool_pages, ps)
         # Radix prefix cache (runtime/radix.py, docs/performance.md "Radix
         # prefix cache"): prefix caching opted in. The trie
         # shares pool pages between cached prefixes and live slots
@@ -1363,7 +1412,8 @@ class ContinuousBatcher:
         self._caches = jax.jit(
             lambda: kvcache.init_paged_kv_caches(
                 cfg, self.pool_pages, self.page_size, server.kv_cache_dtype,
-                state_slots=self.S)
+                state_slots=self.S,
+                **({"window_pages": self.window_pool_pages} if self.window else {}))
         )()
         self._cache_nbytes = sum(
             int(getattr(leaf, "nbytes", 0)) for leaf in jax.tree.leaves(self._caches)
@@ -1395,6 +1445,13 @@ class ContinuousBatcher:
             "latent rows" if cfg.kv_lora_rank else "per-head K/V",
             self._cache_nbytes / 1e9,
             "gather" if self._read_walk(1) is None else "live_pages")
+        if self.window:
+            logger.info(
+                "window page class: %d sliding-attention layers (window %d) on a pool of %d "
+                "pages, %d a slot (window + widest chunk + one page), beside %d full layers "
+                "on %d pages", len(cfg.window_layers), self.window, self.window_pool_pages,
+                self.window_slot_pages,
+                cfg.n_layers - len(cfg.state_layers) - len(cfg.window_layers), self.pool_pages)
         if self._state_layers:
             logger.info(
                 "per-slot state: %s layers x %d slots, %d B a slot, %.1f MB resident",
@@ -1411,6 +1468,10 @@ class ContinuousBatcher:
         self._block_tables = jnp.full(
             (self.S, self.n_pages), TRASH_PAGE, jnp.int32)
         self._trash_row = jnp.full((self.n_pages,), TRASH_PAGE, jnp.int32)
+        # the window class's table: the same logical entries (entry j backs
+        # positions j * page_size ..), NULL_PAGE behind each slot's window
+        self._window_tables = jnp.full(
+            (self.S, self.n_pages), TRASH_PAGE, jnp.int32) if self.window else None
 
         # jitted table/slot-state ops are process-shared singletons
         # (_page_table_ops): a fresh batcher reuses the compiled code of
@@ -2383,6 +2444,17 @@ class ContinuousBatcher:
             # their whole prompt and never insert (docs/multitenancy.md).
             k0, shared, cow = self._radix.match_and_pin(ids, limit=L - 1)
         n_fresh = n0 - len(shared) - (1 if cow is not None else 0)
+        if self.window:
+            # the window class is counted too: what the request can hold of it at
+            # once (a fully provisioned class always has them: see __init__)
+            need = min(-(-(L + req.max_new) // self.page_size), self.window_slot_pages)
+            if self._window_allocator.free_count() < need:
+                if not any(s.active or s.prefilling for s in self._slots):
+                    self._shed_queued_request(
+                        req, f"admission needs {need} window-class KV pages "
+                        f"({self._window_allocator.free_count()} free)")
+                    return True
+                return False
         fresh = self._alloc_pages(n_fresh + (1 if cow is not None else 0))
         if fresh is None and cow is not None:
             # the cow pin itself can be what starves the pool: its source
@@ -2466,6 +2538,8 @@ class ContinuousBatcher:
                           req.max_new, req.fut, req.on_token, req.info,
                           req.seed, bt_row, slot.pages,
                           t_arrival=req.t_arrival, req=req)
+        if self.window:
+            job.wrow = np.full((self.n_pages,), NULL_PAGE, np.int32)
         self._prefill = job
         return True
 
@@ -2498,19 +2572,28 @@ class ContinuousBatcher:
             t0 = time.perf_counter()
             toks, pos = jnp.asarray(toks), jnp.asarray(pos)
             head_row = np.int32(n - 1 if last else -1)   # (goes over with the call)
+        block_row = job.bt_row
+        if self.window:
+            # the window class's pages for THIS chunk: those wholly behind its
+            # first row's window are given back, those its rows reach are booked
+            with self._phases.part("pages"):
+                self._window_book(self._slots[job.slot], start, start + n - 1, row=job.wrow)
+                # (a COPY goes over: the row is booked in place again before the
+                # next chunk, while this one may still be queued)
+                block_row = (job.bt_row, jnp.asarray(job.wrow[None, :].copy()))
         with self._phases.part("call"):
             if self._adapters is not None:
                 fn = self.server._get_prefill_chunk(C, self.n_pages, lora=True)
                 aid = job.req.adapter_id if job.req is not None else 0
                 logits, self._caches, aside = fn(
-                    self.server._params, self._caches, job.bt_row,
+                    self.server._params, self._caches, block_row,
                     toks, pos, head_row, self._adapters.pool(),
                     jnp.asarray([aid], jnp.int32))
             else:
                 fn = self.server._get_prefill_chunk(C, self.n_pages)
                 # a model with conv layers: the chunk continues ITS slot's state
                 extra = () if self._state_slot is None else (self._state_slot[job.slot],)
-                args = (self.server._params, self._caches, job.bt_row, toks, pos,
+                args = (self.server._params, self._caches, block_row, toks, pos,
                         head_row, *extra)
                 if self._chunk_loads is None and self.max_len - 1 > self.prefill_wide > 0:
                     self._build_chunk_programs(C, job.chunk, args)
@@ -2521,6 +2604,11 @@ class ContinuousBatcher:
         self._phases.chunk_head[str(int(last))] += 1
         self._phases.chunk_rows[str(C)] = self._phases.chunk_rows.get(str(C), 0) + n
         self._phases.count_attention("chunk", start + n, self._rows_read(C, [start + n], 1))
+        if self.window:
+            first = max(start - self.window + 1, 0)
+            self._phases.count_window_attention(
+                "chunk", start + n - first, self._rows_read(C, [start + n], 1, [first]),
+                start + n)
         self._phases.count_chunk_write(*self._chunk_write(C, start, n))
         if self._state_layers:
             self._phases.count_state_layers("chunk", n, 1, self._state_layers)
@@ -2623,19 +2711,24 @@ class ContinuousBatcher:
         kernel = jax.default_backend() == "tpu" and gdn_step_walk(self.server._cfg) is not None
         return "kernel" if kernel else "expression"
 
-    def _rows_read(self, s: int, live_rows: Sequence[int], sequences: int) -> int:
+    def _rows_read(self, s: int, live_rows: Sequence[int], sequences: int,
+                   first_rows: Optional[Sequence[int]] = None) -> int:
         """Cached rows the attention read of step-program calls visits, per
         layer, from host integers: ``sequences`` reads of ``s`` query tokens
         each, of which those with a live context reach ``live_rows`` rows.
         The whole block-table view of every sequence, live or not (the
         gather reads it and the products multiply it); where the kernel walks
-        the live pages, whole visits over the live rows, nothing for the rest."""
+        the live pages, whole visits over the live rows, nothing for the rest.
+        ``first_rows``: a sliding-attention layer's read, whose visits start at
+        the one that holds each sequence's first row inside the window."""
         walk = self._read_walk(s)
         if walk is None:
             return sequences * self.n_pages * self.page_size
         from seldon_core_tpu.ops.page_walk import rows_visited
 
-        return sum(rows_visited(rows, self.page_size, walk) for rows in live_rows)
+        firsts = first_rows if first_rows is not None else [0] * len(live_rows)
+        return sum(rows_visited(rows, self.page_size, walk, first)
+                   for rows, first in zip(live_rows, firsts))
 
     def _count_chunks(self, asides: Sequence) -> None:
         """The routing tallies of an admission's chunks. They ran before
@@ -2663,6 +2756,9 @@ class ContinuousBatcher:
         self._block_tables = self._set_block_row(
             self._block_tables, jnp.asarray(job.slot, jnp.int32),
             job.bt_row[0])
+        if self.window:
+            self._window_tables = self._set_block_row(
+                self._window_tables, jnp.asarray(job.slot, jnp.int32), jnp.asarray(job.wrow.copy()))
         self._prefill = None
         self._commit_slot(job.slot, logits, job.seed, job.L,
                           job.max_new, job.fut, job.on_token, ids=job.ids,
@@ -2736,7 +2832,63 @@ class ContinuousBatcher:
             self._flight.record(i, EV_PAGE_GROW,
                                 pages=slot.covered_pages() - n0_pages,
                                 dur_s=time.perf_counter() - t0_grow)
+        if self.window:
+            # the dispatch's first query sits at the slot's next position
+            self._window_book(slot, slot.dispatched_pos(), last_write_pos, slot_index=i)
         return True
+
+    def _tables(self):
+        """What a step program takes as ``block_tables``: the one table, or for a
+        model with sliding-attention layers the pair (full, window), of which
+        each layer reads its class's (models/transformer.py)."""
+        return (self._block_tables, self._window_tables) if self.window else self._block_tables
+
+    def _window_book(self, slot: _Slot, p0: int, last_write_pos: int,
+                     row: Optional[np.ndarray] = None, slot_index: int = -1) -> None:
+        """The window class's pages of ``slot`` for the call about to be
+        dispatched, whose first query row is at position ``p0`` and whose rows
+        write up to ``last_write_pos``. Every page whose LAST position lies below
+        ``p0 - window + 1`` (the smallest position any query of the call may
+        see) is given back, once, and its table entry reads NULL_PAGE; the pages
+        from there to the last written position are booked, their stale
+        positions reset. Either into ``row`` (a prefill job's host row, which
+        goes over with its chunk) or into the slot's row of the device table
+        (a decode step's: enqueued behind the steps in flight, which still read
+        the page they were dispatched with; the reset and the next owner's
+        writes come behind them too, in device program order). A page given
+        back here may be the next one booked, by this slot or another."""
+        import jax.numpy as jnp
+
+        ps = self.page_size
+        first = max(p0 - self.window + 1, 0) // ps
+        end = min(last_write_pos, self.max_len - 1) // ps + 1
+        behind = [j for j in slot.wpages if j < first]
+        ahead = [j for j in range(first, end) if j not in slot.wpages]
+        if not behind and not ahead:
+            return
+        changes = []
+        if behind:
+            self._window_allocator.give_back([slot.wpages.pop(j) for j in behind])
+            changes += [(j, NULL_PAGE) for j in behind]
+        if ahead:
+            got = self._window_allocator.alloc(len(ahead))
+            if got is None:     # cannot happen in a fully provisioned class (__init__)
+                raise RuntimeError(
+                    f"window page class exhausted: {len(ahead)} pages asked, "
+                    f"{self._window_allocator.free_count()} free")
+            ids_np = np.full((self.n_pages,), TRASH_PAGE, np.int32)
+            ids_np[:len(got)] = got
+            self._caches = self._reset_pages(self._caches, None, jnp.asarray(ids_np))
+            slot.wpages.update(zip(ahead, got))
+            changes += list(zip(ahead, got))
+        if row is not None:
+            for j, page in changes:
+                row[j] = page
+            return
+        for j, page in changes:
+            self._window_tables = self._set_block_entry(
+                self._window_tables, jnp.asarray(slot_index, jnp.int32),
+                jnp.asarray(j, jnp.int32), jnp.asarray(page, jnp.int32))
 
     def _pick_page_victim(self):
         """LIFO shed order on page exhaustion: the globally NEWEST tenant
@@ -2933,6 +3085,12 @@ class ContinuousBatcher:
 
         self._block_tables = self._set_block_row(
             self._block_tables, jnp.asarray(i, jnp.int32), self._trash_row)
+        if self.window:
+            if slot.wpages:
+                self._window_allocator.free(list(slot.wpages.values()))
+                slot.wpages = {}
+            self._window_tables = self._set_block_row(
+                self._window_tables, jnp.asarray(i, jnp.int32), self._trash_row)
 
     def page_stats(self, radix_stats: Optional[dict] = None) -> dict:
         """Pool gauges for llm_stats/metrics: in-use/total pages plus
@@ -2944,6 +3102,10 @@ class ContinuousBatcher:
         (pass a precomputed ``RadixPrefixCache.stats()`` snapshot to
         avoid a second O(nodes) walk per scrape)."""
         total, in_use, sheds = self._allocator.stats()
+        by_class = {"full": {"total": total, "in_use": in_use}}
+        if self.window:
+            w_total, w_in_use, _ = self._window_allocator.stats()
+            by_class["window"] = {"total": w_total, "in_use": w_in_use}
         ps = self.page_size
         used_tokens = 0
         for s in self._slots:
@@ -2967,8 +3129,13 @@ class ContinuousBatcher:
         if in_use > 0:
             frag = 1.0 - used_tokens / float(in_use * self.page_size)
         return {
-            "kv_pages_total": total,
-            "kv_pages_in_use": in_use,
+            # every class summed (a model without sliding-attention layers has
+            # the full class alone); fragmentation is the full class's
+            "kv_pages_total": sum(c["total"] for c in by_class.values()),
+            "kv_pages_in_use": sum(c["in_use"] for c in by_class.values()),
+            "kv_pages_by_class": by_class,
+            "kv_pages_released": {
+                "window": self._window_allocator.released_total if self.window else 0},
             "kv_page_size": self.page_size,
             "kv_page_fragmentation": max(0.0, min(1.0, frag)),
             "kv_page_sheds": sheds,
@@ -3126,7 +3293,7 @@ class ContinuousBatcher:
              toks, aside) = fn(
                 self.server._params, self._caches, self._last_tok,
                 self._next_pos, self._keys, self._temp,
-                self._block_tables, *extra)
+                self._tables(), *extra)
         with self._phases.part("book"):
             snapshot = [(i, s.gen) for i, s in enumerate(self._slots) if s.active]
             context, live = 0, []
@@ -3137,6 +3304,11 @@ class ContinuousBatcher:
                 live.extend(pos + 1 + j for j in range(k))
                 self._slots[i].disp_new += k
             self._phases.count_attention("decode", context, self._rows_read(1, live, k * self.S))
+            if self.window:
+                firsts = [max(rows - self.window, 0) for rows in live]
+                self._phases.count_window_attention(
+                    "decode", context - sum(firsts),
+                    self._rows_read(1, live, k * self.S, firsts), context)
             if self._state_layers:
                 self._phases.count_state_layers(
                     "decode", k * len(snapshot), k, self._state_layers)

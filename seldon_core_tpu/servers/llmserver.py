@@ -740,7 +740,7 @@ class LLMServer(SeldonComponent):
         self._module = module
         self._cfg = module.cfg
         self._abstract_init = None  # _init_shapes(), of this module
-        refusal = self._state_layers_refusal()
+        refusal = self._state_layers_refusal() or self._window_layers_refusal()
         if refusal:
             raise ValueError(refusal)
 
@@ -1381,6 +1381,39 @@ class LLMServer(SeldonComponent):
 
             fn = self._decode_cache[key] = jax.jit(_batch_sampler(self.top_k))
         return fn
+
+    def _window_layers_refusal(self) -> Optional[str]:
+        """What is not built over SLIDING-ATTENTION layers (cfg.layer_types), by
+        name; None where nothing asked for is missing. Their pages behind the
+        window are given back while a request lives (runtime/batcher.py: the
+        window page class), so whatever restarts a sequence mid-way, or rolls
+        it back, would need pages that are gone; the rest is what no test
+        holds over the window's mask."""
+        if not getattr(self._cfg, "window_layers", ()):
+            return None
+        what = None
+        if self.prefix_cache_size > 0:
+            what = ("prefix_cache_size > 0: a prefix hit (the radix trie's shared pages and "
+                    "its copy-on-write page copy, generate()'s stored caches) restarts a "
+                    "sequence behind tokens it did not run, and a window layer's pages "
+                    "behind the window were given back")
+        elif self.spec_mode != "off":
+            what = (f"spec_mode={self.spec_mode!r}: a rejected draft rolls the cache back by "
+                    "positions, over pages that may have been given back meanwhile")
+        elif self.disaggregation != "off":
+            what = ("disaggregation='remote_prefill': the hand-off exports and imports a "
+                    "sequence's pages (export_pages), and a window layer keeps only those "
+                    "inside its window")
+        elif self.tensor_parallel > 1 or self.sequence_parallel > 1 or self.mesh is not None:
+            what = ("tensor / sequence parallelism or a mesh: the window's walk is one "
+                    "device's kernel, and no sharding of the window class's pools is built")
+        elif self.kv_cache_dtype == "int8":
+            what = ("kv_cache_dtype='int8': the int8 pool reads by the expression over the "
+                    "whole block-table view, which no test holds to the window's bound")
+        elif self.lora_rank > 0:
+            what = "lora_rank > 0: no test holds adapters over the two page classes"
+        return what and ("a model with sliding_attention layers (layer_types; sliding_window) "
+                         "serves from two page classes, and does not compose with " + what)
 
     def _forward_with_aside(self, params, tokens, **kwargs):
         """``module.apply`` for the batcher's step programs: (logits, caches,

@@ -252,6 +252,80 @@ def test_tree_operations_hand_a_state_entry_on():
     assert RESERVED_PAGES == 2
 
 
+def _window_cfg(**more):
+    base = dict(n_layers=4, n_kv_heads=KVH, head_dim=HD, dtype=BF16, kv_lora_rank=0,
+                kv_cache_dtype="bf16", kv_rows_flat=True, state_layers=(),
+                window_layers=(1, 2, 3), sliding_window=8)
+    base.update(more)
+    return types.SimpleNamespace(**base)
+
+
+@pytest.mark.parametrize("window_layers,kvd,full,window", [
+    # (K + V rows of KVH x HD values + an int32 position) a layer of the class
+    ((1, 2, 3), "bf16", 1 * (2 * W * 2 + 4), 3 * (2 * W * 2 + 4)),
+    ((1, 2, 3), "int8", 1 * (2 * (W + KVH * 4) + 4), 3 * (2 * (W + KVH * 4) + 4)),
+    ((), "bf16", 4 * (2 * W * 2 + 4), 0),
+])
+def test_bytes_a_token_and_pool_lengths_by_page_class(window_layers, kvd, full, window):
+    cfg = _window_cfg(window_layers=window_layers)
+    per_token = kvcache.kv_cache_bytes_per_token
+    assert per_token(cfg, kvd, "full") == full and per_token(cfg, kvd, "window") == window
+    assert per_token(cfg, kvd) == full + window       # every class, as it always was
+    if kvd == "int8":
+        return
+    tree = kvcache.init_paged_kv_caches(cfg, PAGES, PS, kvd, window_pages=6)
+    for i, layer in enumerate(tree):
+        assert kvcache.is_window_entry(layer) == (i in window_layers)
+        assert layer[0].shape == ((6 if i in window_layers else PAGES), PS, W)
+        assert np.all(np.asarray(layer[-1]) == PAD_POS)
+    if window_layers:
+        with pytest.raises(ValueError, match="window_pages"):
+            kvcache.init_paged_kv_caches(cfg, PAGES, PS, kvd)
+    # the DENSE tree has no classes: every row stays, the mask alone has the bound
+    assert not any(kvcache.is_window_entry(e) for e in kvcache.init_kv_caches(cfg, 2, MAX_LEN))
+
+
+@pytest.mark.parametrize("window,widest,page,pages", [
+    (4096, 512, 64, 73), (4096, 256, 64, 69), (12, 8, 4, 6), (32, 16, 8, 7), (10, 8, 4, 6)])
+def test_pages_of_the_window_class_a_slot_can_hold(window, widest, page, pages):
+    """A window, the widest call's rows and one page of rounding, in whole pages:
+    no call can need more at once, wherever its first row lies in a page."""
+    assert kvcache.window_slot_pages(window, widest, page) == pages
+    for p0 in range(0, 3 * page):       # the pages [p0 - window + 1, p0 + widest - 1] touches
+        first, last = max(p0 - window + 1, 0) // page, (p0 + widest - 1) // page
+        assert last - first + 1 <= pages
+
+
+def test_the_writes_and_the_reset_keep_a_window_entry_and_its_class():
+    rng = np.random.default_rng(6)
+    full = _entry("bf16_flat", (PAGES, PS), rng)
+    windowed = kvcache.WindowEntry(_entry("bf16_flat", (6, PS), rng))
+    tree = [full, windowed]
+    # each class's ids name ITS pool's pages
+    reset = kvcache.reset_pages(tree, jnp.asarray([4, TRASH_PAGE]), jnp.asarray([5, TRASH_PAGE]))
+    assert kvcache.is_window_entry(reset[1]) and not kvcache.is_window_entry(reset[0])
+    for got, old, ids in ((reset[0], full, [4, TRASH_PAGE]), (reset[1], windowed, [5, TRASH_PAGE])):
+        want = np.array(old[-1])
+        want[ids] = PAD_POS
+        np.testing.assert_array_equal(np.asarray(got[-1]), want)
+    alone = kvcache.reset_pages(tree, None, jnp.asarray([3]))
+    assert alone[0] is full and np.all(np.asarray(alone[1][-1][3]) == PAD_POS)
+    untouched = kvcache.reset_pages(tree, jnp.asarray([3]))
+    assert untouched[1] is windowed
+    # a token scatter and a whole-page write both hand a WindowEntry back
+    for positions, tables in ((np.array([[3, 4, 9]]), np.array([[5, 3, NULL_PAGE]])),
+                              (np.array([[4, 5, 6, 7, 8, 9, PAD_POS, PAD_POS]]),
+                               np.array([[NULL_PAGE, 3, 4, NULL_PAGE]]))):
+        b, s = positions.shape
+        rows = _rows("bf16_flat", b, s, rng)
+        got = write_rows(windowed, rows, jnp.asarray(positions), block_tables=tables)
+        assert kvcache.is_window_entry(got)
+        want = _oracle(windowed, rows, positions, block_tables=tables)
+        keep = np.arange(6) != TRASH_PAGE
+        for a, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a)[keep], w[keep])
+
+
 def test_cache_module_imports_point_one_way():
     source = pathlib.Path(kvcache.__file__).read_text()
     imported = set()

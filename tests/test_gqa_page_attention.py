@@ -434,3 +434,91 @@ def test_the_loop_counts_whole_visits_over_live_rows_for_a_gqa_model(monkeypatch
     assert rows_read(loop(cfg, jnp.int8), 256, [3000], 1) == view        # the int8 pool: the view
     assert rows_read(loop(cfg, jnp.int8), 1, [3000, 900], 32) == 32 * view
     assert rows_read(loop(dataclasses.replace(cfg, mesh=object())), 1, [3000], 32) == 32 * view
+
+
+# ---- a sliding-attention layer's read: a FIRST live page as well as a last ------
+WINDOW_CASES = {
+    # name: (heads, KV heads, head_dim, query tokens, rows each sequence holds,
+    #        table entries, window, pages behind the window given back?, walk)
+    "decode step, the pages behind the window given back": (
+        16, 4, 128, 1, [100, 37, 1, 380, NOBODY, 300], 12, 96, True, Plan(4, 16)),
+    "decode step, 7 query heads a KV head (28 / 4), by the rule's walk": (
+        28, 4, 128, 1, [100, 37, 380, 300], 12, 100, True, None),
+    "decode step, stale rows behind the window still on their pages": (
+        16, 4, 128, 1, [100, 380, 300], 12, 70, False, Plan(4, 16)),
+    "decode step, a window wider than every sequence": (
+        16, 4, 128, 1, [100, 37, 380], 12, 4096, True, Plan(4, 16)),
+    "chunk, a lane block a KV head, 7 heads a block": (28, 4, 128, 64, [380], 12, 96, True, None),
+    "chunk, stale rows behind the window, a window of two pages": (
+        28, 4, 128, 128, [300], 12, 64, False, None),
+    "chunk, rep 4, the window's edge inside the chunk's own rows": (
+        16, 4, 128, 256, [384], 12, 70, True, None),
+}
+
+
+def give_back(pool, rows, positions, window):
+    """What the batcher does before the call: every page whose last position
+    lies below the first query's window is freed (NaN: it is another's now) and
+    its table entry reads NULL_PAGE."""
+    for i, held in enumerate(rows):
+        if held == NOBODY:
+            continue
+        valid = np.asarray(positions[i])
+        first = (int(valid[valid < PAD_POS].min()) - window + 1) // PAGE
+        for j in range(max(first, 0)):
+            page = pool.tables[i, j]
+            pool.k[page] = pool.v[page] = np.nan
+            pool.pos[page] = PAD_POS
+            pool.tables[i, j] = NULL_PAGE
+
+
+@pytest.mark.parametrize("case", list(WINDOW_CASES))
+def test_the_window_read_is_the_chain_with_the_lower_bound(case):
+    from seldon_core_tpu.ops.page_walk import first_live_pages
+
+    heads, kvh, hd, s, rows, n_pages, window, freed, walk = WINDOW_CASES[case]
+    walk = walk or gqa_plan(s, heads, kvh, hd, n_pages, PAGE)
+    pool = Pool(rows, n_pages, kvh * hd)
+    positions = last_positions(rows, s)
+    if freed:
+        give_back(pool, rows, positions, window)
+    cache, tables = pool.arrays()
+    q = queries(len(rows), s, heads, hd)
+    # the oracle multiplies the whole view: the freed pages' NaN as zeros there
+    clean = tuple(jnp.nan_to_num(a) if a.dtype != jnp.int32 else a for a in cache)
+    want = np.asarray(paged_attention_ref(q, clean, tables, positions, kvh, window), np.float32)
+    got = np.asarray(gqa_page_attention(q, *cache, tables, positions, kvh, walk, interpret=True,
+                                        window=window), np.float32)
+    assert np.all(np.isfinite(got))     # no page behind the first live one was fetched
+    valid = np.asarray((positions < PAD_POS) & (tables[:, :1] != TRASH_PAGE))
+    np.testing.assert_allclose(got[valid], want[valid], atol=2e-2, rtol=2e-2)
+    if window < max(rows):      # ... and the bound decides something
+        unbounded = np.asarray(paged_attention_ref(q, clean, tables, positions, kvh), np.float32)
+        assert np.abs(unbounded[valid] - want[valid]).max() > 0.1
+    for i, held in enumerate(rows):
+        if held == NOBODY:
+            assert np.all(got[i] == 0.0)
+    # the visits: from the group that holds each sequence's first live page
+    first = first_live_pages(tables, positions, PAGE, window)
+    visits = make_visits(tables, live_pages(tables, positions, PAGE), walk, first)
+    per_visit = walk.pages * PAGE
+    expected = 0
+    for held in rows:
+        if held > 0:
+            low = max(max(held - s, 0) - window + 1, 0)
+            assert rows_visited(held, PAGE, walk, low // PAGE * PAGE) == (
+                -(-held // per_visit) - low // per_visit) * per_visit
+            expected += -(-held // per_visit) - low // per_visit
+    assert int(visits.count) == max(expected, 1)
+    assert int(visits.start.sum()) >= min(expected, 1)
+
+
+def test_a_chunk_of_seven_heads_a_kv_head_gets_a_tile_that_divides_its_rows():
+    """28 query heads over 4 KV heads (SmallThinker): 512 tokens are 3,584 query
+    rows a lane block, which 2,048 does not divide; the walk takes the largest
+    tile under it that does, in whole sublane tiles of tokens a head."""
+    assert gqa_plan(256, 28, 4, 128, 256, 64) == Plan(pages=2, q_tile=1792, blocks=4)
+    assert gqa_plan(512, 28, 4, 128, 256, 64) == Plan(pages=2, q_tile=1792, blocks=4)
+    assert gqa_plan(1024, 28, 4, 128, 256, 64) == Plan(pages=2, q_tile=1792, blocks=4)
+    # ... and a shape whose rows 2,048 divides walks as it did
+    assert gqa_plan(512, 32, 8, 128, 64, 64) == Plan(pages=2, q_tile=2048, blocks=8)

@@ -381,6 +381,26 @@ def test_a_state_that_is_not_square_stays_float32_in_the_caches_layout(name):
             lowering_platforms=("tpu",)).as_text()
 
 
+@pytest.mark.parametrize("name", ["llm.swa_paged_decode_step_s4",
+                                  "llm.swa_prefill_chunk_c64"])
+def test_window_layer_step_programs_donate_both_page_classes_and_hold_no_view(name):
+    """SmallThinker's block at dims the live-page kernel takes (ISSUE 49): the
+    pools of BOTH page classes are donated and aliased (a leaf that is not shows
+    as an alias finding); lowered for a TPU neither the full layer's read nor
+    the window layer's holds an array of a whole block-table view's shape (both
+    walk their live pages with the one kernel); the MoE promises hold with the
+    router fed the block's input; no transfer."""
+    from tools.hlolint.contracts import all_contracts
+
+    (contract,) = [c for c in all_contracts() if c.name == name]
+    reported, *_ = run_one(contract, checks=("alias", "transfer", "dtype",
+                                             "collective"))
+    assert reported == []
+    fn, args = contract.build()
+    assert fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text().count(
+        "gqa_page_attention") >= 2
+
+
 @pytest.mark.parametrize("name", ["llm.xing4_paged_decode_step_s4",
                                   "llm.xing4_prefill_chunk_c8"])
 def test_stream_step_programs_keep_the_streams_in_the_models_dtype(name):

@@ -151,8 +151,11 @@ def test_the_branch_norm_attention_block_is_olmo3s():
     # pre-norm on the same weights is another model
     pre, _ = reference.forward(variables, cfg, tokens.tolist(), norm_placement="pre")
     assert np.abs(np.asarray(pre) - want).max() > 0.05 * scale
+    # (a window layer converts since PR 49: tests/test_reference_smallthinker.py holds it)
     config.layer_types = ["full_attention", "sliding_attention", "full_attention"]
-    with pytest.raises(ValueError, match="sliding_attention"):
+    assert config_kwargs_from_hf(config)["layer_types"] == tuple(config.layer_types)
+    config.rope_scaling = {"rope_type": "linear", "factor": 2.0}
+    with pytest.raises(ValueError, match="rope_scaling"):
         config_kwargs_from_hf(config)
 
 

@@ -529,6 +529,54 @@ def test_page_allocator_exhaustion_exactly_one_grant():
                      max_schedules=60, stall_s=STALL) is None
 
 
+def test_two_page_classes_window_release_exact_under_exploration():
+    """Two page classes (ISSUE 49): the full class keeps a slot's pages while it
+    lives, the WINDOW class gives the page behind each slot's window back and
+    books the next one, call after call, on the loop's worker threads while a
+    /metrics scrape reads both gauges. Whatever the interleaving: every page is
+    given back exactly once (a second free raises, and so does a free of a page
+    handed to two), the fully provisioned window class never runs out although
+    a page one slot gave back may be the other's next, the gauges stay within
+    each class's pool, and after the release both classes are empty."""
+    from seldon_core_tpu.runtime.batcher import PageAllocator
+
+    def scenario(sched):
+        full = PageAllocator(total_pages=8, page_size=4)      # 6 usable
+        window = PageAllocator(total_pages=6, page_size=4)    # 2 slots x 2 pages
+        log = {"errors": [], "released": {"a": 0, "b": 0}, "seen": []}
+
+        def slot(name):
+            try:
+                kept = full.alloc(3)                 # admission: the whole prompt's
+                held = window.alloc(2)               # ... and a window's worth
+                for _ in range(3):                   # three calls, each a page on
+                    window.free([held.pop(0)])       # the page behind the window
+                    log["released"][name] += 1
+                    held += window.alloc(1)          # the page the call's rows reach
+                    assert len(set(held)) == 2
+                window.free(held)
+                full.free(kept)
+            except Exception as exc:     # a double free, an exhausted class
+                log["errors"].append((name, repr(exc)))
+
+        def scrape():
+            log["seen"].append((full.stats()[1], window.stats()[1]))
+
+        sched.spawn(lambda: slot("a"), name="slot-a")
+        sched.spawn(lambda: slot("b"), name="slot-b")
+        sched.spawn(scrape, name="scrape")
+        return full, window, log
+
+    def ok(state):
+        full, window, log = state
+        return (not log["errors"] and log["released"] == {"a": 3, "b": 3}
+                and full.stats()[1] == 0 and window.stats()[1] == 0
+                and all(f <= 6 and w <= 4 for f, w in log["seen"]))
+
+    assert find_race(scenario, ok, granularity="line",
+                     max_schedules=80, stall_s=STALL) is None
+
+
 def test_breaker_single_probe_under_exploration():
     """Half-open must admit exactly one probe no matter how allow() calls
     interleave (the _probe_inflight slot)."""

@@ -118,7 +118,17 @@ OLMOHYBRID = dict(vocab_size=256, dim=3840, n_layers=4, n_heads=30, n_kv_heads=3
                   linear_dt_bias="range", linear_num_key_heads=30, linear_num_value_heads=30,
                   linear_key_head_dim=96, linear_value_head_dim=192, linear_conv_kernel_dim=4,
                   layer_types=("linear_attention",) * 3 + ("full_attention",))
-CONFIGS = {"olmohybrid": OLMOHYBRID, "mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
+# SmallThinker-21BA3B's period at published widths: one full-attention layer
+# without position and three sliding-attention layers (window 4,096, rotary), 28
+# query / 4 KV heads of 128, 64 ReGLU experts of 768 top-6, the router fed the
+# block's input
+SMALLTHINKER = dict(vocab_size=256, dim=2560, n_layers=4, n_heads=28, n_kv_heads=4, head_dim=128,
+                    ffn_dim=768, max_seq_len=16384, dtype="bfloat16", n_experts=64,
+                    n_experts_per_token=6, router_renormalize=True, norm_eps=1e-6,
+                    rope_theta=1.5e6, rope_layout=(0, 1, 1, 1), sliding_window=4096,
+                    layer_types=("full_attention",) + ("sliding_attention",) * 3,
+                    ffn_act="relu", router_input="layer_input")
+CONFIGS = {"smallthinker": SMALLTHINKER, "olmohybrid": OLMOHYBRID, "mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
            "xing4": XING4, "mistral_vocab": MISTRAL_VOCAB, "lfm2": LFM2, "qwen3next": QWEN3NEXT}
 PAGE, POOL_PAGES = 64, 514
 
@@ -199,20 +209,36 @@ def _compile(server, program, sharding, slots, length, chunk):
 
     pages = (length or (1024 if program == "decode_step" else 4096)) // PAGE
     params = abstract(server._params)
+    # a model with sliding-attention layers is served from two page classes: the
+    # window class's pool as the batcher provisions it, and a pair of tables
+    windowed = {"window_pages": window_pool_pages(server._cfg, slots)} if server._cfg.window_layers else {}
     pools = abstract(jax.eval_shape(lambda: init_paged_kv_caches(
         server._cfg, slots * pages + 2 if length else POOL_PAGES, PAGE, "bf16",
-        state_slots=slots)))
+        state_slots=slots, **windowed)))
+
+    def tables(rows):
+        table = sds((rows, pages), "int32")
+        return (table, table) if windowed else table
+
     if program == "decode_step":
         lowered = server._get_decode_step_paged(slots, pages, 1).lower(
             params, pools, sds((slots,), "int32"), sds((slots,), "int32"),
-            sds((slots, 2), "uint32"), sds((), "float32"), sds((slots, pages), "int32"))
+            sds((slots, 2), "uint32"), sds((), "float32"), tables(slots))
     else:
         # a model with state layers is told which slot's state the chunk continues
         state_slot = (sds((1,), "int32"),) if server._cfg.state_layers else ()
         lowered = server._get_prefill_chunk(chunk, pages).lower(
-            params, pools, sds((1, pages), "int32"), sds((1, chunk), "int32"),
+            params, pools, tables(1), sds((1, chunk), "int32"),
             sds((1, chunk), "int32"), sds((), "int32"), *state_slot)
     return lowered.compile()
+
+
+def window_pool_pages(cfg, slots: int) -> int:
+    """The window class's pool as runtime/batcher.py provisions it: a window, the
+    widest chunk and a page a slot, and the two reserved pages."""
+    from seldon_core_tpu.models.cache import window_slot_pages
+
+    return slots * window_slot_pages(cfg.sliding_window, WIDE, PAGE) + 2
 
 
 def compiled_text(server, program: str, sharding) -> str:
@@ -517,6 +543,76 @@ def test_the_gqa_step_walks_the_live_pages_and_holds_no_view(v5e, servers, cell)
         assert f"bf16[{text}]" not in hlo and f"f32[{text}]" not in hlo, view
     assert weight_copies(hlo, {(pool_pages, PAGE) + tail for tail in rows}) == []
     assert "attn.gqa.write" in hlo
+
+
+@pytest.mark.parametrize("program,rows", [("decode_step", 1), ("prefill_chunk", 256),
+                                          ("wide_chunk", WIDE)])
+def test_the_window_layers_walk_from_their_first_live_page_and_hold_no_view(
+        v5e, servers, program, rows):
+    """SmallThinker's period at the cell's shapes (24 slots x 16,384, two page
+    classes): every attention layer's read is ONE Mosaic kernel of the repo's,
+    the three sliding-attention layers' under ``attn.window.read`` over the
+    WINDOW class's pools (74 pages a slot, not 256) and the full layer's under
+    ``attn.gqa.read`` over the full class's; a window layer's kernel takes the
+    first-visit flags beside the visit list (its walk starts at the first live
+    page: no page behind a window is an operand's block), and the program holds
+    no array of a whole block-table view's shape, of either class. The chunk of
+    512 rows (3,584 query rows a lane block of 7 heads) is walked too, in tiles
+    of 1,792."""
+    from seldon_core_tpu.ops.gqa_attention import KERNEL_NAME
+
+    server = servers("smallthinker")
+    cfg = server._cfg
+    slots, length, row = 24, 16384, cfg.n_kv_heads * cfg.head_dim
+    pages = length // PAGE
+    hlo = compiled(server, program, v5e, slots=slots, length=length).as_text()
+    calls = [line for line in hlo.splitlines() if re.match(rf"\s*%{KERNEL_NAME}[\w.]* = ", line)]
+    assert len(calls) == 4 and all('custom_call_target="tpu_custom_call"' in c for c in calls)
+    window = [c for c in calls if "attn.window.read" in c]
+    full = [c for c in calls if "attn.gqa.read" in c]
+    assert (len(window), len(full)) == (3, 1)
+    full_pool = f"bf16[{slots * pages + 2},{PAGE},{row}]"
+    window_pool = f"bf16[{window_pool_pages(cfg, slots)},{PAGE},{row}]"
+    per_visit = 16 if program == "decode_step" else 2
+    assert all(c.count(window_pool) == 2 * per_visit and full_pool not in c for c in window)
+    assert all(c.count(full_pool) == 2 * per_visit and window_pool not in c for c in full)
+    # the first-visit flags: one more scalar-prefetch operand than the full layer's
+    # (and, in a chunk's block form, the two bounds of the window's fast path)
+    more = 1 if program == "decode_step" else 3
+    operands = [c[c.index("custom-call(") :].count("s32[") for c in calls]
+    assert {operands[calls.index(c)] for c in window} == {operands[calls.index(full[0])] + more}
+    sequences = slots if program == "decode_step" else 1
+    views = [(sequences * pages, PAGE), (sequences, pages, PAGE), (sequences, pages * PAGE)]
+    tails = [(row,), (cfg.n_kv_heads, cfg.head_dim)]
+    for view in (lead + tail for lead in views for tail in tails):
+        text = ",".join(str(n) for n in view)
+        assert f"bf16[{text}]" not in hlo and f"f32[{text}]" not in hlo, view
+    assert "attn.window.write" in hlo and "attn.gqa.write" in hlo
+
+
+def test_where_the_routing_lands_beside_attention_in_the_compiled_step(v5e, servers):
+    """The router reads the layer's INPUT, so nothing of ``moe.route`` depends on
+    what attention produces and the compiler is free to place it beside the
+    read. Nothing is forced: this reads what the compiled step DOES with it
+    (PERF.md section 6, PR 49, has the finding) and holds only that the routing
+    is there once a layer and that no route op takes the attention kernel's
+    output."""
+    from seldon_core_tpu.ops.gqa_attention import KERNEL_NAME
+
+    server = servers("smallthinker")
+    hlo = compiled(server, "decode_step", v5e, slots=24, length=16384).as_text()
+    entry = hlo[hlo.index("\nENTRY"):].splitlines()
+    kernels = [i for i, line in enumerate(entry) if re.match(rf"\s*%{KERNEL_NAME}[\w.]* = ", line)]
+    routes = [i for i, line in enumerate(entry) if "moe.route" in line and "top_k" in line.lower()]
+    assert len(kernels) == 4
+    assert routes, "no op of moe.route in the step's entry computation"
+    ahead = [sum(1 for r in routes if r < k) for k in kernels]
+    print(f"moe.route top-k ops scheduled ahead of each layer's attention kernel: {ahead} "
+          f"(of {len(routes)})")
+    names = [re.match(r"\s*%([\w.]+) = ", entry[k]).group(1) for k in kernels]
+    for line in entry:
+        if "moe.route" in line and "moe.experts" not in line:
+            assert not any(f"%{name}" in line.split("=", 1)[1] for name in names), line[:300]
 
 
 # attention of LFM2's widths (32 heads of 64 over 8 KV heads) around a dense

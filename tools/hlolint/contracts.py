@@ -573,6 +573,76 @@ GDN_RECT_UNPACKED_STATE = (
     "expression's two passes over S, or an unpack around the kernel)")
 
 
+# SmallThinker's block at dims the live-page kernel takes (16 query / 2 KV heads
+# of 128: a K row of 256; 64-row pages, four a slot): a full-attention layer
+# without position beside a sliding-attention layer (window 128 = two pages,
+# rotary) served from the WINDOW page class, the router fed the block's input,
+# ReGLU experts
+SWA_PAGE, SWA_PAGES, SWA_WINDOW, SWA_CHUNK = 64, 4, 128, 64
+SWA_HEADS = (16, 2, 128)        # query heads, KV heads, head dim
+SWA_POOL_PAGES = 2 + SLOTS * SWA_PAGES
+SWA_GATHERED_VIEW = (
+    rf"tensor<({SLOTS}|1)x{SWA_PAGES * SWA_PAGE}x({SWA_HEADS[1]}x{SWA_HEADS[2]}|"
+    rf"{SWA_HEADS[1] * SWA_HEADS[2]})x(bf16|f16|f32)>",
+    "a floating [sequences, view rows, K / V row] array: a layer's paged pool is "
+    "being gathered into a copy of the whole block-table view (a window layer's "
+    "view is mostly NULL_PAGE: its pages behind the window were given back) where "
+    "the live-page kernel walks the pages from the first live one to the last")
+
+
+def _swa_server():
+    with _STATE_LOCK:
+        if "swa_server" not in _STATE:
+            ensure_platform()
+            from seldon_core_tpu.servers.llmserver import LLMServer
+
+            heads, kv_heads, hd = SWA_HEADS
+            s = LLMServer(
+                model="transformer",
+                model_kwargs=dict(
+                    vocab_size=96, dim=MOE_DIM, n_layers=2, n_heads=heads,
+                    n_kv_heads=kv_heads, head_dim=hd, ffn_dim=MOE_WIDTH,
+                    max_seq_len=SWA_PAGES * SWA_PAGE, rope_theta=1.5e6,
+                    layer_types=("full_attention", "sliding_attention"),
+                    rope_layout=(0, 1), sliding_window=SWA_WINDOW,
+                    n_experts=MOE_EXPERTS, n_experts_per_token=MOE_TOP_K,
+                    router_renormalize=True, ffn_act="relu",
+                    router_input="layer_input", dtype="bfloat16"),
+                quantize="int8", init_random=True, len_buckets=(PLEN,),
+                seed=7)
+            s.load()
+            _STATE["swa_server"] = s
+        return _STATE["swa_server"]
+
+
+def _swa_pool_specs():
+    import jax
+
+    from seldon_core_tpu.models.cache import init_paged_kv_caches, window_slot_pages
+
+    window_pages = 2 + SLOTS * window_slot_pages(SWA_WINDOW, SWA_CHUNK, SWA_PAGE)
+    return jax.eval_shape(lambda: init_paged_kv_caches(
+        _swa_server()._cfg, SWA_POOL_PAGES, SWA_PAGE, "bf16", window_pages=window_pages))
+
+
+def _build_swa_paged_decode_step():
+    s = _swa_server()
+    fn = s._get_decode_step_paged(SLOTS, SWA_PAGES, 1)
+    tables = (_sds((SLOTS, SWA_PAGES), "int32"),) * 2     # (full, window)
+    return fn, (s._params, _swa_pool_specs(), _sds((SLOTS,), "int32"),
+                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
+                _sds((), "float32"), tables)
+
+
+def _build_swa_prefill_chunk():
+    s = _swa_server()
+    fn = s._get_prefill_chunk(SWA_CHUNK, SWA_PAGES)
+    rows = (_sds((1, SWA_PAGES), "int32"),) * 2
+    return fn, (s._params, _swa_pool_specs(), rows,
+                _sds((1, SWA_CHUNK), "int32"), _sds((1, SWA_CHUNK), "int32"),
+                _sds((), "int32"))
+
+
 def _pool_specs_of(server):
     import jax
 
@@ -1237,6 +1307,35 @@ def all_contracts() -> List[Contract]:
             build=_build_gdn_rect_prefill_chunk,
             donated=(1,),
             forbid_dtypes=(GDN_RECT_NARROW_STATE, GDN_RECT_UNPACKED_STATE),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.swa_paged_decode_step_s4",
+            description="PAGED decode step of a model with a sliding-attention "
+                        "layer beside a full one (SmallThinker's block: RoPE a "
+                        "layer, the router fed the block's input, ReGLU "
+                        "experts), served from TWO page classes: the pools of "
+                        "both classes are donated, each layer reads the table "
+                        "of its class, and neither read holds an array of a "
+                        "whole block-table view's shape",
+            build=_build_swa_paged_decode_step,
+            donated=(1, 3, 4),
+            forbid_dtypes=(SWA_GATHERED_VIEW, MOE_DENSE_FORM, MOE_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.swa_prefill_chunk_c64",
+            description="chunked admission prefill of the same model (one "
+                        "sequence's 64 rows through both tables): whole-page "
+                        "writes into the donated pools of both classes, the "
+                        "window layer's walk from its first live page",
+            build=_build_swa_prefill_chunk,
+            donated=(1,),
+            forbid_dtypes=(SWA_GATHERED_VIEW, MOE_DENSE_FORM, MOE_FLOAT_STACK),
             lowering_platform="tpu",
             collectives={},
             cost=True,
