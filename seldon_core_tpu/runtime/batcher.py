@@ -188,7 +188,7 @@ DEFAULT_PREFILL_CHUNK = 256
 # once whatever its rows, and a routed expert sees a few dozen of 256 rows
 # (docs/performance.md "Chunked prefill"). One constant, so two chunk programs
 # a server; a multiple of the delta rule's 64-row sub-chunks and of the page
-WIDE_PREFILL_CHUNK = 512
+WIDE_PREFILL_CHUNK = 1024
 
 
 def pow2_bucket(n: int, cap: int) -> int:

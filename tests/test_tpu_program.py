@@ -41,9 +41,9 @@ vocab]`` logits.
 
 Seventh (PR 48): the WIDE chunk program (``WIDE_PREFILL_CHUNK`` rows, which a
 long prompt's chunks are while no other slot streams) is held to all of the
-above at DeepSeek-V2-Lite's and LFM2's cell shapes (a model that routes experts
-runs it), and the head's conditional at Mistral's vocabulary: the same tests
-take it as one more case.
+above at DeepSeek-V2-Lite's, LFM2's, Qwen3-Next's and SmallThinker's cell shapes
+(a model that routes experts runs it), and the head's conditional at Mistral's
+vocabulary: the same tests take it as one more case. 1,024 rows since PR 50.
 """
 
 import re
@@ -465,7 +465,7 @@ LATENT_CELLS = {"deepseek": (8, 16384), "xing4": (32, 4096)}
 
 @pytest.mark.parametrize("config,program", [
     ("deepseek", "decode_step"), ("deepseek", "prefill_chunk"), ("deepseek", "wide_chunk"),
-    ("xing4", "decode_step"), ("xing4", "prefill_chunk")])
+    ("xing4", "decode_step"), ("xing4", "prefill_chunk"), ("xing4", "wide_chunk")])
 def test_the_latent_read_walks_the_live_pages_and_holds_no_view(v5e, servers, config, program):
     """At the cells' own shapes the read under ``attn.latent.read`` is ONE
     Mosaic kernel of the repo's a layer (ops/latent_attention.py), fed the pool
@@ -492,9 +492,19 @@ def test_the_latent_read_walks_the_live_pages_and_holds_no_view(v5e, servers, co
     pool = f"bf16[{slots * pages + 2},{PAGE},640]"
     visit = 16 if program == "decode_step" else 8
     assert all(line.count(pool) == visit for line in calls), calls[0][:400]
-    for view in (f"[{sequences * pages},{PAGE},640]", f"[{sequences},{pages},{PAGE},640]",
-                 f"[{sequences},{pages * PAGE},640]"):
-        assert f"bf16{view}" not in hlo and f"f32{view}" not in hlo, view
+    for view in ((sequences * pages, PAGE, 640), (sequences, pages, PAGE, 640),
+                 (sequences, pages * PAGE, 640)):
+        if view == (sequences, rows * cfg.n_heads, 640):
+            # DeepSeek's wide chunk: 1,024 x 16 query rows are as many as a slot's
+            # 16,384 cached ones, so the flat view's shape is the kernel's QUERY
+            # operand's, once a layer, and nothing else's
+            named = [instr for instr, dtype, shape, _, _, _ in own_ops(hlo)
+                     if dtype in FLOATS and shape == view]
+            assert len(named) == len(calls), named
+            assert all(any(f"%{instr}," in line for line in calls) for instr in named), named
+            continue
+        text = ",".join(str(n) for n in view)
+        assert f"bf16[{text}]" not in hlo and f"f32[{text}]" not in hlo, view
     if program == "decode_step":
         assert exe.memory_analysis().temp_size_in_bytes < sequences * pages * PAGE * 640 * 2
 
@@ -552,13 +562,13 @@ def test_the_window_layers_walk_from_their_first_live_page_and_hold_no_view(
     """SmallThinker's period at the cell's shapes (24 slots x 16,384, two page
     classes): every attention layer's read is ONE Mosaic kernel of the repo's,
     the three sliding-attention layers' under ``attn.window.read`` over the
-    WINDOW class's pools (74 pages a slot, not 256) and the full layer's under
+    WINDOW class's pools (81 pages a slot, not 256) and the full layer's under
     ``attn.gqa.read`` over the full class's; a window layer's kernel takes the
     first-visit flags beside the visit list (its walk starts at the first live
     page: no page behind a window is an operand's block), and the program holds
-    no array of a whole block-table view's shape, of either class. The chunk of
-    512 rows (3,584 query rows a lane block of 7 heads) is walked too, in tiles
-    of 1,792."""
+    no array of a whole block-table view's shape, of either class. The wide
+    chunk (1,024 rows: 7,168 query rows a lane block of 7 heads) is walked too,
+    in tiles of 1,792."""
     from seldon_core_tpu.ops.gqa_attention import KERNEL_NAME
 
     server = servers("smallthinker")
@@ -683,7 +693,8 @@ def test_on_a_mesh_narrow_heads_keep_flat_rows_and_no_pool_is_copied():
 GQA_CHUNKS = {"mistral docs": ("mistral", 256, 4096, 128, 4), "mistral chat 128": ("mistral", 128, 1024, 128, 4),
               "mistral chat 256": ("mistral", 256, 1024, 128, 4), "olmoe chat": ("olmoe", 256, 1024, 128, 1),
               "lfm2 rag": ("lfm2", 256, 4096, 128, 8), "qwen3next longctx": ("qwen3next", 256, 8192, 256, 8),
-              "lfm2 rag wide": ("lfm2", WIDE, 4096, 128, 8)}
+              "lfm2 rag wide": ("lfm2", WIDE, 4096, 128, 8),
+              "qwen3next longctx wide": ("qwen3next", WIDE, 8192, 256, 8)}
 
 
 @pytest.mark.parametrize("cell", list(GQA_CHUNKS))
@@ -888,7 +899,7 @@ def test_the_chunks_head_runs_for_one_row_inside_the_conditional(v5e, servers, p
     (``fusion f32[1,256,32000]``, 1.5 % of a rerank chunk's device time and
     33 MB of output a program in flight, ledger PR 45). The wide program
     (PR 48) is never a prompt's last chunk as the batcher runs it, and is the
-    same program but for its rows: no ``[512, vocab]`` either."""
+    same program but for its rows: no ``[1024, vocab]`` either."""
     server = servers("mistral_vocab")
     vocab, dim = server._cfg.vocab_size, server._cfg.dim
     exe = compiled(server, program, v5e)

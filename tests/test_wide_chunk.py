@@ -26,6 +26,7 @@ import pytest
 from test_chunk_head import MODELS as CHUNK_HEAD_MODELS
 
 from seldon_core_tpu.runtime.batcher import (
+    DEFAULT_PAGE_SIZE,
     DEFAULT_PREFILL_CHUNK,
     WIDE_PREFILL_CHUNK,
     ContinuousBatcher,
@@ -125,27 +126,35 @@ def stream(tok):
     """A caller's ``on_token``."""
 
 
-# rows left of the prompt, who else holds a slot (live?, streams?) -> the width
+# rows left of the prompt, who else holds a slot (live?, streams?), the job's
+# own width and the wide one (the rehearsal's or the served ones) -> the width
+TOY, SERVED = (CHUNK, WIDE), (DEFAULT_PREFILL_CHUNK, WIDE_PREFILL_CHUNK)
 RULE = {
-    "more than a wide chunk left, alone": (WIDE + 1, [], WIDE),
-    "a whole prompt left, alone": (45, [], WIDE),
-    "exactly a wide chunk left: the last chunks are narrow": (WIDE, [], CHUNK),
-    "less than a wide chunk left": (5, [], CHUNK),
-    "a live neighbour that streams": (45, [(True, True)], CHUNK),
-    "a live neighbour that waits for a plain reply": (45, [(True, False)], WIDE),
-    "a slot whose stream has ended (not live)": (45, [(False, True)], WIDE),
-    "one neighbour streams, one does not": (45, [(True, False), (True, True)], CHUNK),
+    "more than a wide chunk left, alone": (WIDE + 1, [], TOY, WIDE),
+    "a whole prompt left, alone": (45, [], TOY, WIDE),
+    "exactly a wide chunk left: the last chunks are narrow": (WIDE, [], TOY, CHUNK),
+    "less than a wide chunk left": (5, [], TOY, CHUNK),
+    "a live neighbour that streams": (45, [(True, True)], TOY, CHUNK),
+    "a live neighbour that waits for a plain reply": (45, [(True, False)], TOY, WIDE),
+    "a slot whose stream has ended (not live)": (45, [(False, True)], TOY, WIDE),
+    "one neighbour streams, one does not": (45, [(True, False), (True, True)], TOY, CHUNK),
+    "served widths: a row more than a wide chunk left": (
+        WIDE_PREFILL_CHUNK + 1, [], SERVED, WIDE_PREFILL_CHUNK),
+    "served widths: exactly a wide chunk left": (
+        WIDE_PREFILL_CHUNK, [], SERVED, DEFAULT_PREFILL_CHUNK),
+    "served widths: a live neighbour that streams": (
+        4 * WIDE_PREFILL_CHUNK, [(True, True)], SERVED, DEFAULT_PREFILL_CHUNK),
 }
 
 
 @pytest.mark.parametrize("case", RULE)
 @pytest.mark.parametrize("own_stream", [False, True])
 def test_the_width_follows_rows_left_and_the_other_slots_streams(servers, case, own_stream):
-    left, neighbours, want = RULE[case]
-    b = make_batcher(servers("gqa_pages"), max_slots=4)
+    left, neighbours, (chunk, wide), want = RULE[case]
+    b = make_batcher(servers("gqa_pages"), wide=wide, max_slots=4)
     for slot, (live, streams) in zip(b._slots[1:], neighbours):
         slot.active, slot.on_token = live, stream if streams else None
-    job = job_of(0, 50, 50 - left, on_token=stream if own_stream else None)
+    job = job_of(0, left + 5, 5, chunk=chunk, on_token=stream if own_stream else None)
     # the job's own slot holds the caller's on_token from admission on
     b._slots[0].prefilling, b._slots[0].on_token = True, job.on_token
     assert b._chunk_width(job) == want
@@ -159,13 +168,16 @@ def test_an_explicit_prefill_chunk_is_every_chunks_and_the_default_widens(server
     assert explicit._chunk_width(job_of(0, 50, 0)) == CHUNK
     default = ContinuousBatcher(server, max_slots=2, max_len=MAX_LEN, page_size=PAGE)
     assert (default.prefill_chunk, default.prefill_wide) == (
-        DEFAULT_PREFILL_CHUNK, WIDE_PREFILL_CHUNK) == (256, 512)
+        DEFAULT_PREFILL_CHUNK, WIDE_PREFILL_CHUNK) == (256, 1024)
     # every width is whole pages and whole sub-chunks of the delta rule
     assert WIDE_PREFILL_CHUNK % DEFAULT_PREFILL_CHUNK == 0 and DEFAULT_PREFILL_CHUNK % 64 == 0
     # a job's own width is its bucket where that is smaller: it cannot be wide
     assert default._chunk_width(job_of(0, 40, 0, chunk=64)) == 64
-    assert default._chunk_width(job_of(0, 513, 0, chunk=256)) == 512
-    assert default._chunk_width(job_of(0, 513, 512, chunk=256)) == 256
+    wide = WIDE_PREFILL_CHUNK
+    assert default._chunk_width(job_of(0, wide + 1, 0, chunk=256)) == wide
+    assert default._chunk_width(job_of(0, wide + 1, 1, chunk=256)) == 256
+    assert default._chunk_width(job_of(0, 2 * wide + 1, wide, chunk=256)) == wide
+    assert default._chunk_width(job_of(0, 2 * wide + 1, 2 * wide, chunk=256)) == 256
     # the server's own prefill_chunk is an explicit one too
     server.prefill_chunk = CHUNK
     try:
@@ -183,7 +195,8 @@ def test_the_default_widens_where_the_model_routes_experts(servers, model, widen
     are MoE models, as the served ones are)."""
     b = ContinuousBatcher(servers(model), max_slots=2, max_len=MAX_LEN, page_size=PAGE)
     assert b.prefill_wide == (WIDE_PREFILL_CHUNK if widens else 0)
-    assert b._chunk_width(job_of(0, 600, 0, chunk=256)) == (512 if widens else 256)
+    assert b._chunk_width(job_of(0, WIDE_PREFILL_CHUNK + 88, 0, chunk=256)) == (
+        WIDE_PREFILL_CHUNK if widens else 256)
 
 
 def test_every_chunk_of_an_explicit_width_is_that_wide(servers):
@@ -286,21 +299,23 @@ def serve(max_len):
     from seldon_core_tpu.runtime.batcher import BatcherService
 
     server = LLMServer(model="transformer",
-                       model_kwargs=dict(MODELS["latent_rows"], max_seq_len=704),
+                       model_kwargs=dict(MODELS["latent_rows"], max_seq_len=SLOT),
                        init_random=True, max_new_tokens=4, eos_id=-1, seed=3, temperature=0.0,
                        continuous_batching=2, continuous_batching_max_len=max_len)
     server.load()
     return server, BatcherService(server, max_slots=2)
 
 
-LONG = np.random.default_rng(5).integers(1, 96, size=600).tolist()
+# a slot that holds a wide chunk and 192 rows more; a prompt of a wide chunk and 88
+SLOT, FEW, MANY = WIDE_PREFILL_CHUNK + 192, 300, WIDE_PREFILL_CHUNK + 88
+LONG = np.random.default_rng(5).integers(1, 96, size=MANY).tolist()
 
 
-@pytest.mark.parametrize("first,then", [(300, 600), (600, 300)],
+@pytest.mark.parametrize("first,then", [(FEW, MANY), (MANY, FEW)],
                          ids=["a narrow chunk is the first", "a wide chunk is the first"])
 def test_the_first_chunk_builds_both_chunk_programs_and_neither_is_built_again(
         caplog, first, then):
-    """A batcher of the default widths (256 and 512) whose slots can hold a
+    """A batcher of the default widths (256 and 1,024) whose slots can hold a
     wide chunk: its first chunk, whichever program it needs, traces and lowers
     both chunk programs and hands each to a thread to compile; that chunk's own
     call and the other program's first call, whenever it comes, then trace,
@@ -310,7 +325,7 @@ def test_the_first_chunk_builds_both_chunk_programs_and_neither_is_built_again(
     def built(what):
         return [r.getMessage() for r in caplog.records if what + "prefill_chunk" in r.getMessage()]
 
-    server, svc = serve(704)
+    server, svc = serve(SLOT)
     with caplog.at_level(logging.DEBUG, logger="jax._src.dispatch"):
         one = svc.submit_sync(LONG[:first], 2)
         for load in svc.batcher._chunk_loads.values():
@@ -327,15 +342,84 @@ def test_the_first_chunk_builds_both_chunk_programs_and_neither_is_built_again(
         assert max(traces, default=0) < 0.01, traces     # found, not traced
     stats = svc.batcher._phases.stats()
     svc.close()
-    assert stats["chunk_rows"] == {"512": 512, "256": 388}
-    assert sorted(k[1] for k in server._prefill_cache if k[0] == "pchunk") == [256, 512]
+    assert stats["chunk_rows"] == {str(WIDE_PREFILL_CHUNK): WIDE_PREFILL_CHUNK,
+                                   "256": FEW + MANY - WIDE_PREFILL_CHUNK}
+    assert sorted(k[1] for k in server._prefill_cache if k[0] == "pchunk") == [
+        256, WIDE_PREFILL_CHUNK]
     assert one == server.generate([LONG[:first]], max_new_tokens=2)["tokens"][0]
     assert other == server.generate([LONG[:then]], max_new_tokens=2)["tokens"][0]
 
 
 def test_a_batcher_that_cannot_reach_a_wide_chunk_builds_its_one_program_when_called():
-    # no prompt of a 512-token slot has more than 512 rows left
-    _, svc = serve(512)
-    svc.submit_sync(LONG[:300], 2)
+    # no prompt of a slot of a wide chunk's rows has more than those left
+    _, svc = serve(WIDE_PREFILL_CHUNK)
+    svc.submit_sync(LONG[:FEW], 2)
     assert svc.batcher._chunk_loads is None
     svc.close()
+
+
+# ------------------------------------------- (e) the served configurations' wide chunk
+def served_moe_cells() -> dict:
+    """cell -> (model kwargs, slots, tokens a slot) of the benchmark's cells
+    whose server routes experts and can reach a wide chunk, as perf/planes/
+    llm_rest.py builds them from perf/configs and perf/workloads."""
+    import glob
+    import json
+    import os
+
+    perf = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "perf")
+    cells = {}
+    for path in sorted(glob.glob(os.path.join(perf, "workloads", "*.json"))):
+        with open(path) as f:
+            cell = json.load(f)
+        with open(os.path.join(perf, "configs", cell["config"] + ".json")) as f:
+            config = json.load(f)
+        server = {**config.get("server", {}), **cell.get("server", {})}
+        kwargs = {ours: config[theirs]
+                  for ours, theirs in config.get("model_kwargs_from", {}).items()}
+        slot = server.get("continuous_batching_max_len", 0)
+        if kwargs.get("n_experts") and slot - 1 > WIDE_PREFILL_CHUNK:
+            cells[cell["name"]] = (kwargs, server["continuous_batching"], slot)
+    return cells
+
+
+MOE_CELLS = served_moe_cells()
+
+
+def test_the_cells_that_reach_a_wide_chunk_are_the_five():
+    assert sorted(MOE_CELLS) == [
+        "dsv2lite-longdocs-batch", "lfm2-rag-mixed", "qwen3next-longctx-mixed",
+        "smallthinker-longqa-mixed", "xing4-reasoning-decode"]
+
+
+@pytest.mark.parametrize("cell", sorted(MOE_CELLS))
+def test_a_served_wide_chunk_takes_the_kernels_read_and_the_page_wise_write(cell):
+    """Static facts alone, no program: at the cell's slot length the read of a
+    chunk of ``WIDE_PREFILL_CHUNK`` rows has a walk (``paged_read_walk`` gives a
+    ``Plan``; None would gather the slot's whole block-table view a layer a
+    chunk) in the tiles the narrow chunk's has, its rows reach the pool as
+    whole pages, and the window class holds a wide chunk behind its window."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models import cache as kvcache
+    from seldon_core_tpu.models import get_model
+    from seldon_core_tpu.models.transformer import paged_read_walk
+    from seldon_core_tpu.ops.page_walk import Plan
+
+    kwargs, slots, slot = MOE_CELLS[cell]
+    cfg = get_model("transformer", **kwargs).cfg
+    page = DEFAULT_PAGE_SIZE
+    assert WIDE_PREFILL_CHUNK % page == 0 and slot % page == 0
+    wide = paged_read_walk(cfg, WIDE_PREFILL_CHUNK, slot // page, page, jnp.bfloat16)
+    narrow = paged_read_walk(cfg, DEFAULT_PREFILL_CHUNK, slot // page, page, jnp.bfloat16)
+    assert isinstance(wide, Plan) and wide == narrow, (wide, narrow)
+    # the tile divides the wide chunk's query rows a block: only the grid grows
+    assert WIDE_PREFILL_CHUNK * cfg.n_heads % (wide.q_tile * wide.blocks) == 0
+    windowed = {"window_pages": 8} if cfg.window_layers else {}
+    pools = jax.eval_shape(lambda: kvcache.init_paged_kv_caches(
+        cfg, 8, page, "bf16", state_slots=slots, **windowed))
+    assert kvcache.paged_write_by_page(kvcache.first_paged(pools), 1, WIDE_PREFILL_CHUNK)
+    if cfg.window_layers:
+        held = kvcache.window_slot_pages(cfg.sliding_window, WIDE_PREFILL_CHUNK, page)
+        assert cfg.sliding_window + WIDE_PREFILL_CHUNK <= held * page < slot
