@@ -432,6 +432,34 @@ class MetricsRegistry:
             base,
             registry=self.registry,
         )
+        # The start ledger (tracing/start.py, docs/observability.md
+        # "Start-up"): where the wall from the process's creation to the first
+        # /ready went, and what every build of a program cost, the start's and
+        # every later one's. Build seconds are thread-seconds of JAX's own
+        # legs; nested="1" lies INSIDE a nested="0" leg of the same thread.
+        self._start_stage = Gauge(
+            "seldon_start_stage_seconds",
+            "Wall seconds of each stage of the server's start (import, "
+            "construct, load.weights, load.rest, listen partition process "
+            "creation -> first /ready; batcher.build follows)",
+            base + ["stage"],
+            registry=self.registry,
+        )
+        self._program_build_seconds = Counter(
+            "seldon_program_build_seconds_total",
+            "Seconds JAX spent building programs, by program, leg (trace, "
+            "lower, compile, cache_load) and whether the leg lay inside "
+            "another on its thread",
+            base + ["program", "leg", "nested"],
+            registry=self.registry,
+        )
+        self._program_builds = Counter(
+            "seldon_program_builds_total",
+            "Executables made or loaded, by program and the persistent "
+            "compile cache's verdict (hit, miss, off)",
+            base + ["program", "cache"],
+            registry=self.registry,
+        )
         self._first_token_reads = Counter(
             "seldon_llm_first_token_reads_total",
             "Reads of a prompt's first token off the drain pipeline, by "
@@ -945,6 +973,22 @@ class MetricsRegistry:
             delta = total - retained._value.get()
             if delta > 0:
                 retained.inc(delta)
+
+    def sync_start(self) -> None:
+        """Refresh the start ledger's series (tracing/start.py) at scrape
+        time: the stage gauges as they stand, the build counters caught up
+        from the ledger's lifetime tallies (listeners book on whatever
+        thread builds)."""
+        from seldon_core_tpu.tracing.start import get_ledger
+
+        stages, seconds, builds = get_ledger().series()
+        for stage, s in stages.items():
+            self._start_stage.labels(**self._base(), stage=stage).set(s)
+        for (program, leg, nested), s in seconds.items():
+            self._counter_catch_up(self._program_build_seconds, s,
+                                   program=program, leg=leg, nested=nested)
+        for (program, cache), n in builds.items():
+            self._counter_catch_up(self._program_builds, n, program=program, cache=cache)
 
     # ------------------------------------------------------------------
     # Elastic control plane observability (controlplane/autoscaler.py +

@@ -177,6 +177,7 @@ from seldon_core_tpu.runtime.flight import (
     EV_STEP,
 )
 from seldon_core_tpu.servers.llmserver import LLMServer, _bucket
+from seldon_core_tpu.tracing.start import get_ledger
 
 logger = logging.getLogger(__name__)
 
@@ -204,17 +205,18 @@ def pow2_bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
-def _load_program(lowered, after: Optional[threading.Thread]) -> None:
-    """Compile a lowered step program, or load it from the compile cache, once
-    ``after`` has ended (two loads side by side took as long as one after the
-    other, and slowed whatever else loaded then: v5e, PR 48): a thread's whole
-    job (``ContinuousBatcher._build_chunk_programs``); it touches no batcher."""
+def _load_program(lowered, what: str, after: Optional[threading.Thread]) -> None:
+    """Compile a lowered step program (``what``, for the log), or load it from
+    the compile cache, once ``after`` has ended (two loads side by side took as
+    long as one after the other, and slowed whatever else loaded then: v5e,
+    PR 48): a thread's whole job (``ContinuousBatcher._build_chunk_programs``);
+    it touches no batcher."""
     if after is not None:
         after.join()
     try:
         lowered.compile()
     except Exception:   # the call that needs the program builds it, and raises what is wrong
-        logger.warning("a step program was not built ahead of its first call", exc_info=True)
+        logger.warning("%s was not built ahead of its first call", what, exc_info=True)
 
 
 def _page_table_ops():
@@ -910,10 +912,14 @@ class _InFlight:
     side credited to ``disp_new``; the drain reconciles the difference)."""
 
     __slots__ = ("tokens", "k", "snapshot", "t_dispatch", "acc", "booked",
-                 "aside")
+                 "aside", "built")
 
     def __init__(self, tokens, k, snapshot, t_dispatch, acc=None,
-                 booked=None, aside=None):
+                 booked=None, aside=None, built=None):
+        # {"built": program} where the dispatching call had to build the
+        # program, or load it from the compile cache (tracing/start.py): for
+        # the step's flight event; empty for every call but a first
+        self.built = built or {}
         # what left the step beside its tokens (LLMServer._get_decode_step):
         # device arrays the drain reads only after the tokens have landed
         self.aside = aside or {}
@@ -971,7 +977,10 @@ class BatcherService:
             return ContinuousBatcher(server, max_slots=max_slots,
                                      max_len=max_len)
 
-        self.batcher = asyncio.run_coroutine_threadsafe(make(), self._loop).result()
+        # the pool's and the tables' allocation and their zero-fill programs:
+        # the start's one stage after /ready (tracing/start.py)
+        with get_ledger().stage("batcher.build"):
+            self.batcher = asyncio.run_coroutine_threadsafe(make(), self._loop).result()
         self.submitted = 0
         # Requests handed to the loop whose futures have not resolved yet.
         # This covers the drain blind window the batcher itself cannot see:
@@ -1385,6 +1394,7 @@ class ContinuousBatcher:
         from seldon_core_tpu.tracing import get_tracer, tail_thresholds
 
         self._tracer = get_tracer()
+        self._ledger = get_ledger()
         enabled = self._tracer.enabled if tracing is None else bool(tracing)
         if enabled:
             from seldon_core_tpu.runtime.flight import FlightRecorder
@@ -2581,6 +2591,7 @@ class ContinuousBatcher:
                 # (a COPY goes over: the row is booked in place again before the
                 # next chunk, while this one may still be queued)
                 block_row = (job.bt_row, jnp.asarray(job.wrow[None, :].copy()))
+        builds = self._ledger.thread_builds()
         with self._phases.part("call"):
             if self._adapters is not None:
                 fn = self.server._get_prefill_chunk(C, self.n_pages, lora=True)
@@ -2617,12 +2628,20 @@ class ContinuousBatcher:
             # dispatch wall (enqueue-only)
             event = self._flight.record(
                 job.slot, EV_PREFILL_CHUNK, start=start, tokens=n,
-                head=int(last), dur_s=time.perf_counter() - t0)
+                head=int(last), dur_s=time.perf_counter() - t0,
+                **self._built_since(builds))
         if self._moe is not None:
             job.asides.append((aside, event, start, n))
         if last:
             with self._phases.part("activate"):
                 self._activate(job, logits)
+
+    def _built_since(self, mark: int) -> dict:
+        """``{"built": program}`` for the flight event of a call that had to
+        build its program, or load it, since ``mark`` (the start ledger's count
+        of this thread's builds, read before the call); nothing for any other."""
+        program = self._ledger.built_since(mark)
+        return {"built": program} if program else {}
 
     def _build_chunk_programs(self, width: int, narrow: int, args: tuple) -> None:
         """A batcher's FIRST chunk (``width`` rows, called with ``args``) builds
@@ -2633,8 +2652,11 @@ class ContinuousBatcher:
         other (the compiler's and the runtime's C++): the second program is
         traced while the first one loads, and loads while the first request
         goes on to its first token and the decode step's trace. The calls that
-        follow find trace, lowering and executable in jax's own caches (shapes
-        and placements are the calls' own); a call whose program still loads
+        follow find lowering and executable in jax's own caches (shapes and
+        placements are the calls' own) but NOT the trace: a program's first
+        call traces it once more (the start ledger's count, PR 51: two chunk
+        programs, four ``trace`` legs, two ``lower``, two loads; 2.7 s a trace
+        in dsv2lite, 7.9 s in xing4); a call whose program still loads
         waits for it (``_chunk_loads``). Built when first called, one behind the
         other, the second program cost a warm start of the Mistral servers
         4.9-5.4 s of their 33-39 s (trace 1.8, to MLIR 0.5, load 2.5-3.1:
@@ -2653,8 +2675,9 @@ class ContinuousBatcher:
             rows_of = jax.ShapeDtypeStruct((1, rows), np.int32)
             lowered = self.server._get_prefill_chunk(rows, self.n_pages).lower(
                 *shapes[:3], rows_of, rows_of, *shapes[5:])
-            before = threading.Thread(target=_load_program, args=(lowered, before),
-                                      name=f"chunk-{rows}-load", daemon=True)
+            before = threading.Thread(
+                target=_load_program, args=(lowered, f"prefill_chunk of {rows} rows", before),
+                name=f"chunk-{rows}-load", daemon=True)
             before.start()
             self._chunk_loads[rows] = before
 
@@ -3283,6 +3306,7 @@ class ContinuousBatcher:
         lora = self._adapters is not None
         extra = () if not lora else (self._adapters.pool(),
                                      self._adapter_ids)
+        builds = self._ledger.thread_builds()
         with self._phases.part("call"):
             fn = self.server._get_decode_step_paged(
                 self.S, self.n_pages, k, lora=lora)
@@ -3312,7 +3336,8 @@ class ContinuousBatcher:
             if self._state_layers:
                 self._phases.count_state_layers(
                     "decode", k * len(snapshot), k, self._state_layers)
-            self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside))
+            self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside,
+                                            built=self._built_since(builds)))
             self._count_steps()
         return True
 
@@ -3351,6 +3376,7 @@ class ContinuousBatcher:
         # the end of either signature, un-donated
         extra = () if self._adapters is None else (
             self._adapters.pool(), self._adapter_ids)
+        builds = self._ledger.thread_builds()
         with self._phases.part("call"):
             fn = self.server._get_spec_step(
                 self.S, K, self.hist_len, mode=self.spec_mode,
@@ -3375,8 +3401,8 @@ class ContinuousBatcher:
             for i, _ in snapshot:
                 booked[i] = int(caps[i]) + 1
                 self._slots[i].disp_new += booked[i]
-            self._inflight.append(_InFlight(toks, 1, snapshot, t0, acc=acc,
-                                            booked=booked))
+            self._inflight.append(_InFlight(toks, 1, snapshot, t0, acc=acc, booked=booked,
+                                            built=self._built_since(builds)))
             self._count_steps()
         return True
 
@@ -3508,7 +3534,8 @@ class ContinuousBatcher:
                     # materializes the segment: tokens credited this drain plus
                     # the step's device dwell (dispatch -> drain)
                     self._flight.record(i, EV_STEP, tokens=credited,
-                                        t_dispatch=rec.t_dispatch, **moe_fields)
+                                        t_dispatch=rec.t_dispatch, **moe_fields,
+                                        **rec.built)
                 if finish:
                     with self._phases.part("finish"):
                         self._finish(i)
@@ -3573,7 +3600,8 @@ class ContinuousBatcher:
                     # timeline's token accounting (recorded before any finish)
                     self._flight.record(i, EV_STEP, tokens=credited,
                                         offered=offered, accepted=adv,
-                                        t_dispatch=rec.t_dispatch)
+                                        t_dispatch=rec.t_dispatch,
+                                        **rec.built)
                 if finish:
                     with self._phases.part("finish"):
                         self._finish(i)

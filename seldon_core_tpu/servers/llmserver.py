@@ -39,6 +39,7 @@ import numpy as np
 
 from seldon_core_tpu.components.component import SeldonComponent
 from seldon_core_tpu.contracts.payload import SeldonError
+from seldon_core_tpu.tracing.start import get_ledger, name_programs
 
 logger = logging.getLogger(__name__)
 
@@ -269,6 +270,27 @@ class _PrefixTrieIndex:
 # f32 init trees above this stream leaf-by-leaf through the quantizer
 # instead of materializing whole (27 GB at 7B vs 16 GB single-chip HBM).
 STREAM_INIT_THRESHOLD_BYTES = 2 << 30
+
+#: What the start ledger's build book (tracing/start.py) calls each program, by
+#: the names of the jitted functions the ``_get_*`` builders below define (and
+#: runtime/batcher.py ``_page_table_ops``, models/, ops/ for the last rows): a
+#: build of any other function is ``other``. The last three are jitted
+#: functions of their own that a step program traces INSIDE itself, so their
+#: legs are booked ``nested="1"`` under their own names.
+BUILD_PROGRAMS = (
+    ("decode_step", ("decode_step",)),
+    ("prefill_chunk", ("prefill_chunk",)),
+    ("first_token", ("first_token",)),
+    ("spec_step", ("spec_step",)),
+    ("handoff_import", ("import_pages",)),
+    ("page_ops", ("set_block_row", "set_block_entry", "reset_pages", "set_slot",
+                  "set_hist_row", "cow_page_copy", "export_pages", "set_adapter_id")),
+    ("weights", ("make_quantized", "init")),
+    ("paged_live_read", ("paged_live_read",)),
+    ("_walk_pages", ("_walk_pages",)),
+    ("paged_write_pages", ("paged_write_pages",)),
+)
+name_programs(BUILD_PROGRAMS)
 
 
 class LLMServer(SeldonComponent):
@@ -701,6 +723,9 @@ class LLMServer(SeldonComponent):
                 "disaggregation='remote_prefill' (there is no KV handoff "
                 "without a prefill/decode split)")
 
+        # (the start's stages, tracing/start.py: the checks above are the last
+        # of `construct`)
+        get_ledger().advance("load.weights")
         cfg_kwargs = dict(self.model_kwargs)
         name = self.model_name
         params = None
@@ -823,6 +848,7 @@ class LLMServer(SeldonComponent):
             # uploads every weight again on every call (servers/jaxserver.py)
             params = jax.device_put(params)
         self._params = params
+        get_ledger().advance("load.rest")
 
         # Draft model for spec_mode="draft": loaded alongside the target,
         # replicated (it is small by construction — sharding it would cost

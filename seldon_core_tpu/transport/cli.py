@@ -52,24 +52,33 @@ def setup_logging() -> None:
 
 def _start_serving() -> None:
     """First thing in every serving command: logging, then the compile
-    cache — before anything can compile."""
+    cache and the start ledger's build listeners (tracing/start.py) — before
+    anything can compile."""
+    from seldon_core_tpu.tracing import start
     from seldon_core_tpu.utils import configure_compile_cache
 
     setup_logging()
     configure_compile_cache()
+    start.get_ledger().listen()
 
 
 @contextlib.contextmanager
 def _device_footprint_on_exit():
     """Wraps the serve call of the commands chip_smoke.py drives
     (microservice, edge): on the way out — while what was served is still
-    alive — each device's memory goes into the log."""
+    alive — each device's memory goes into the log, and the start ledger:
+    the stages of the start and, by program, what every build of the
+    process's life cost (docs/observability.md "Start-up")."""
     from seldon_core_tpu.parallel.topology import log_device_memory
+    from seldon_core_tpu.tracing import start
 
     try:
         yield
     finally:
         log_device_memory()
+        stages, builds = start.get_ledger().snapshot()
+        logger.info("start ledger: %s", json.dumps(stages))
+        logger.info("program builds: %s", json.dumps(builds))
 
 
 def import_interface(name: str):
@@ -91,8 +100,14 @@ def build_component(interface_name: str, persistence: bool = False):
         restore_component,
     )
 
+    from seldon_core_tpu.tracing import start
+
     klass = import_interface(interface_name)
     parameters = parse_parameters()
+    # the start's stages (tracing/start.py): `import` ends here, the
+    # component's own load() moves on to `load.weights` and `load.rest`
+    ledger = start.get_ledger()
+    ledger.advance("construct")
     component = None
     restored_shared = False
     if persistence:
@@ -126,6 +141,7 @@ def build_component(interface_name: str, persistence: bool = False):
             import atexit
 
             atexit.register(sync.stop)  # final publish on shutdown
+    ledger.advance("listen")
     return component, threads
 
 
@@ -172,9 +188,13 @@ def run_engine(args: argparse.Namespace) -> None:
 
     # Spec from file, ENGINE_PREDICTOR env, or the default SIMPLE_MODEL the
     # reference engine uses when unconfigured (`EnginePredictor.java:122-141`).
+    from seldon_core_tpu.tracing import start
+
     spec = _load_spec(args.spec)
     annotations = load_annotations()
+    start.get_ledger().advance("construct")
     engine = GraphEngine(spec, annotations=annotations)
+    start.get_ledger().advance("listen")
     metrics = MetricsRegistry(predictor=spec.name)
     port = args.port or int(os.environ.get("ENGINE_SERVER_PORT", "8000"))
     logger.info("engine serving predictor %r on port %d", spec.name, port)

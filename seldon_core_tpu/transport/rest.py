@@ -53,6 +53,7 @@ from seldon_core_tpu.runtime.resilience import (
     deadline_scope,
 )
 from seldon_core_tpu.tracing import get_tracer
+from seldon_core_tpu.tracing.start import get_ledger
 
 logger = logging.getLogger(__name__)
 
@@ -334,6 +335,10 @@ def make_component_app(
     async def health(request):
         return web.json_response({"status": "ok"})
 
+    async def ready(request):
+        get_ledger().ready()    # the first one ends the start's `listen` stage
+        return await health(request)
+
     async def openapi(request):
         from seldon_core_tpu.transport.openapi import wrapper_spec
 
@@ -346,6 +351,7 @@ def make_component_app(
             metrics.sync_controlplane(component)
             metrics.sync_framing()
             metrics.sync_tracing()
+            metrics.sync_start()
             return web.Response(body=metrics.expose(), content_type="text/plain")
 
     async def debug_timeline(request):
@@ -360,7 +366,7 @@ def make_component_app(
                 timeline_report(component, n=parse_n(request.query.get("n"))))
 
     app.router.add_get("/health/status", health)
-    app.router.add_get("/ready", health)
+    app.router.add_get("/ready", ready)
     app.router.add_get("/live", health)
     app.router.add_get("/seldon.json", openapi)
     app.router.add_get("/metrics", prom)
@@ -841,6 +847,7 @@ def make_engine_app(
 
     async def ready(request):
         if state["ready"] and not state["paused"]:
+            get_ledger().ready()    # the first one ends the start's `listen` stage
             return web.Response(text="ready")
         return web.Response(status=503, text="not ready")
 
@@ -866,6 +873,7 @@ def make_engine_app(
             metrics.sync_controlplane(engine)
             metrics.sync_framing()
             metrics.sync_tracing()
+            metrics.sync_start()
             return web.Response(body=metrics.expose(), content_type="text/plain")
 
     async def debug_timeline(request):

@@ -229,6 +229,47 @@ def test_install_from_env_wires_exporter():
     assert install_from_env(Tracer(enabled=True), {}) is None
 
 
+# ------------------------------------------------- the start ledger's series
+def test_the_start_ledgers_three_series_are_exposed_under_the_documented_names():
+    from seldon_core_tpu.metrics.registry import MetricsRegistry
+    from seldon_core_tpu.tracing import start
+
+    ledger = start.StartLedger(age_s=2.0)
+    ledger.advance("construct")
+    ledger.advance("load.weights")
+    ledger.advance("load.rest")
+    ledger.advance("listen")
+    ledger.ready()
+    # what the listeners book (tests/test_start_ledger.py drives them through JAX)
+    ledger.build_seconds = {("decode_step", "trace", "0"): [3.0, 1],
+                            ("decode_step", "cache_load", "0"): [2.5, 1],
+                            ("paged_live_read", "trace", "1"): [0.75, 2]}
+    ledger.builds = {("decode_step", "hit"): 1, ("other", "off"): 3}
+    old = start.get_ledger()
+    start.set_ledger(ledger)
+    try:
+        reg = MetricsRegistry(deployment="d", predictor="p")
+        reg.sync_start()
+        text = reg.expose().decode()
+    finally:
+        start.set_ledger(old)
+    base = {"deployment_name": "d", "predictor_name": "p"}
+    get = reg.registry.get_sample_value
+    assert [get("seldon_start_stage_seconds", {**base, "stage": s}) is not None
+            for s in start.STAGES] == [True] * 5 + [False]      # no batcher was built
+    assert get("seldon_start_stage_seconds", {**base, "stage": "import"}) >= 2.0
+    assert get("seldon_program_build_seconds_total",
+               {**base, "program": "decode_step", "leg": "cache_load", "nested": "0"}) == 2.5
+    assert get("seldon_program_build_seconds_total",
+               {**base, "program": "paged_live_read", "leg": "trace", "nested": "1"}) == 0.75
+    assert get("seldon_program_builds_total", {**base, "program": "decode_step", "cache": "hit"}) == 1
+    assert get("seldon_program_builds_total", {**base, "program": "other", "cache": "off"}) == 3
+    for name, kind in (("seldon_start_stage_seconds", "gauge"),
+                       ("seldon_program_build_seconds_total", "counter"),
+                       ("seldon_program_builds_total", "counter")):
+        assert f"# TYPE {name} {kind}" in text, name
+
+
 # ------------------------------------------------- dashboards + alert rules
 def test_analytics_artifacts_use_live_metric_names(tmp_path):
     """Rules and dashboard queries must reference metrics the registry

@@ -431,3 +431,38 @@ def test_grpc_unsampled_metadata_not_recorded(fresh_tracer):
     finally:
         server.stop(None)
     assert fresh_tracer.drain() == []
+
+
+# ---------------------------------------------------------------------------
+# The start's span tree (tracing/start.py)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_the_start_is_one_tree_under_server_start_only_when_tracing_is_on(enabled):
+    from seldon_core_tpu.tracing.start import StartLedger
+
+    old = get_tracer()
+    tracer = Tracer(enabled=enabled)
+    set_tracer(tracer)
+    try:
+        ledger = StartLedger(age_s=1.5)
+        ledger.advance("construct")
+        ledger.advance("listen")
+        ledger.ready()
+        with ledger.stage("batcher.build"):      # after /ready: a late child of the same root
+            pass
+        spans = {s.name: s for s in tracer.drain()}
+    finally:
+        set_tracer(old)
+    assert ledger.stage_seconds["import"] >= 1.5    # the stages are booked either way
+    if not enabled:
+        assert spans == {}
+        return
+    assert set(spans) == {"server.start", "start.import", "start.construct", "start.listen",
+                          "start.batcher.build"}
+    root = spans["server.start"]
+    assert root.parent_id is None and root.sampled
+    assert root.end - root.start == pytest.approx(ledger.ready_s) and ledger.ready_s >= 1.5
+    assert all((s.trace_id, s.parent_id) == (root.trace_id, root.span_id)
+               for name, s in spans.items() if name != "server.start")
+    assert spans["start.batcher.build"].start >= root.end
