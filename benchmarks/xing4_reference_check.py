@@ -13,6 +13,9 @@ configuration's `reference_tolerance` is set from (perf/configs/<config>.json).
         --long-wrongs state_held_in_bf16,weights_at_4_bits --out chiprun_out/pr38/reference_check_long.json
     chiprun -- python benchmarks/xing4_reference_check.py --workload smallthinker-longqa-mixed \
         --probes 12 --wrong-probes 2 --long 1 --long-size 14336+64 --out chiprun_out/pr49/reference_check.json
+    chiprun -- python benchmarks/xing4_reference_check.py --workload granite4h-sessions-decode \
+        --probes 12 --wrong-probes 2 --long 2 --long-size 512+768 \
+        --long-wrongs state_held_in_bf16,weights_at_4_bits --out chiprun_out/pr53/reference_check.json
     (then once more with --probes 2 --wrong-probes 2 --only-low: the 4-bit tree in a call of its
     own, because the machine's host holds 40 GiB and a 15-layer tree is 11.5 GB of it)
 
@@ -110,6 +113,23 @@ def olmo_hybrid_wrongs(prompt_tokens: int, chunk: int) -> dict:
         "qk_norm_a_head": {"qk_norm": "head_tiled"},
         "rope_at_theta_500000": {"rope_theta_wrong": 500000.0},
         "state_held_in_bf16": {"gdn_state_bf16": True},
+    }
+
+
+def granite_hybrid_wrongs(prompt_tokens: int, chunk: int) -> dict:
+    """The wrong references of granite-4.0-h (its `work` is "granite_hybrid"): a
+    dense model, so nothing is followed; the two that break the state's hand-over
+    lie where the probe's chunks end."""
+    padded = -(-prompt_tokens // chunk) * chunk
+    return {
+        "state_held_in_bf16": {"ssd_state_bf16": True},
+        "gate_after_the_norm": {"ssd_gate_after_norm": True},
+        "attention_scaled_by_head_dim": {"attention_multiplier_off": True},
+        "residual_multiplier_one": {"residual_multiplier_off": True},
+        "skip_left_out": {"ssd_skip": False}, "conv_bias_left_out": {"ssd_conv_bias": False},
+        "b_and_c_not_convolved": {"ssd_conv_bc": False},
+        "state_zeroed_at_a_chunk_start": {"ssd_reset_every": chunk},
+        "state_from_the_chunks_last_row": {"conv_state_pad": (prompt_tokens, padded)},
     }
 
 
@@ -298,7 +318,7 @@ def main() -> None:
 
     wrongs = WRONGS
     placed = {"lfm2": lfm2_wrongs, "qwen3_next": qwen3_next_wrongs,
-              "olmo_hybrid": olmo_hybrid_wrongs,
+              "olmo_hybrid": olmo_hybrid_wrongs, "granite_hybrid": granite_hybrid_wrongs,
               "smallthinker": smallthinker_wrongs}.get(cfg.get("work"))
     if placed:   # a configuration with state layers: some wrongs lie where the probe's chunks end
         wrongs = placed(probe["prompt_tokens"], server_kw.get("prefill_chunk") or 256)

@@ -184,7 +184,7 @@ class MetricsRegistry:
             for key, suffix, text in (
                 ("state_matrix_bytes", "",
                  "Of seldon_llm_state_bytes, the float32 MATRIX state of the "
-                 "linear-attention layers alone: the arrays' own bytes"),
+                 "linear-attention (S) and mamba (h) layers alone: the arrays' own bytes"),
                 ("state_matrix_tiled_bytes", "_tiled",
                  "What the chip holds for those arrays: their last two axes "
                  "rounded up to (8, 128) float32 tiles; over "
@@ -519,11 +519,13 @@ class MetricsRegistry:
         # A model with conv layers (models/transformer.py ShortConv): what
         # went through them, counted on the loop from host integers; absent
         # for every other model
-        # ... likewise "gdn" for linear-attention layers (GatedDeltaNet)
+        # ... likewise "gdn" for linear-attention layers (GatedDeltaNet) and
+        # "ssd" for mamba layers (Mamba2Mixer)
         self._state_layers = {
             f"{kind}_{key}": Counter(f"seldon_llm_{kind}_{key}_total", text.format(what=what),
                                      base + ["program"], registry=self.registry)
-            for kind, what in (("conv", "conv"), ("gdn", "linear-attention (Gated DeltaNet)"))
+            for kind, what in (("conv", "conv"), ("gdn", "linear-attention (Gated DeltaNet)"),
+                               ("ssd", "mamba (Mamba-2 state-space)"))
             for key, text in (
                 ("rows", "Live rows (tokens) of the step-program calls of a "
                          "model with {what} layers: what EACH such layer mixed"),
@@ -531,12 +533,20 @@ class MetricsRegistry:
         # how a decode step program's delta rule runs: the repo's kernel (S
         # read once and written once) or the expression's two passes over S: a
         # silent fall-back shows here; absent without linear-attention layers
-        self._gdn_step_path = Counter(
-            "seldon_llm_gdn_step_path",
-            "Decode step programs built over linear-attention layers, by how "
-            "the delta rule's read-modify-write of the matrix state runs: "
-            "path=kernel (ops/gated_delta.py) or path=expression",
-            base + ["path"], registry=self.registry)
+        self._step_path = {
+            "gdn": Counter(
+                "seldon_llm_gdn_step_path",
+                "Decode step programs built over linear-attention layers, by how "
+                "the delta rule's read-modify-write of the matrix state runs: "
+                "path=kernel (ops/gated_delta.py) or path=expression",
+                base + ["path"], registry=self.registry),
+            # ... and over mamba layers: the expression reads h a second time for h C
+            "ssd": Counter(
+                "seldon_llm_ssd_step_path",
+                "Decode step programs built over mamba layers, by how the "
+                "state-space recurrence's read-modify-write of h runs: "
+                "path=kernel (ops/ssd.py) or path=expression",
+                base + ["path"], registry=self.registry)}
         # An MoE model's routing (runtime/batcher.py MoECounters,
         # docs/observability.md "Expert routing"): counted on the loop from
         # arrays that leave the step programs beside their tokens, absent
@@ -1213,8 +1223,9 @@ class MetricsRegistry:
         for key, counter in self._state_layers.items():
             for program, n in stats.get(key, {}).items():
                 self._counter_catch_up(counter, n, program=program)
-        for path, n in stats.get("gdn_step_path", {}).items():
-            self._counter_catch_up(self._gdn_step_path, n, path=path)
+        for kind, counter in self._step_path.items():
+            for path, n in stats.get(f"{kind}_step_path", {}).items():
+                self._counter_catch_up(counter, n, path=path)
         for program, tally in stats.get("moe_by_program", {}).items():
             for field, n in tally.items():
                 self._counter_catch_up(self._moe[field], n, program=program)

@@ -31,6 +31,9 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+# the matrix state's layout is the step kernels' (they read the array as it lies)
+from seldon_core_tpu.ops.gated_delta import heads_a_lane_row, pack_state, unpack_state
+
 # Sentinel position for empty/padded cache slots and padded prompt tokens:
 # larger than any real position, so causal masks (key_pos <= query_pos)
 # exclude them; small enough that rotary angles stay finite.
@@ -138,32 +141,9 @@ def is_state_entry(layer) -> bool:
     return isinstance(layer, StateEntry)
 
 
-def pack_state(S: jnp.ndarray, side: int) -> jnp.ndarray:
-    """A linear-attention layer's matrix state as the cache holds it:
-    [b, H, dk, dv] -> [b, H / side, dk, side * dv], ``side`` heads side by side
-    along the lanes (``state_lane_heads``), so that a head whose dv is no whole
-    128-lane tile leaves no padded lane in HBM. At side 1 the array as it is."""
-    if side == 1:
-        return S
-    b, H, dk, dv = S.shape
-    return jnp.swapaxes(S.reshape(b, H // side, side, dk, dv), 2, 3).reshape(
-        b, H // side, dk, side * dv)
-
-
-def unpack_state(S: jnp.ndarray, side: int) -> jnp.ndarray:
-    """``pack_state``'s inverse: [b, H / side, dk, side * dv] -> [b, H, dk, dv]."""
-    if side == 1:
-        return S
-    b, units, dk, lanes = S.shape
-    return jnp.swapaxes(S.reshape(b, units, dk, side, lanes // side), 2, 3).reshape(
-        b, units * side, dk, lanes // side)
-
-
 def state_lane_heads(cfg) -> int:
     """How many value heads' [dk, dv] share a lane row of the matrix state: the
     step kernel's rule (ops/gated_delta.py reads the array as it lies)."""
-    from seldon_core_tpu.ops.gated_delta import heads_a_lane_row
-
     return heads_a_lane_row(cfg.linear_num_value_heads, cfg.linear_value_head_dim)
 
 
@@ -171,6 +151,17 @@ def _state_entry_shapes(cfg, kind: str) -> Tuple[Tuple[Tuple[int, ...], Any], ..
     """(shape a sequence, dtype) of each array of a state layer's entry."""
     if kind == "conv":
         return (((cfg.conv_L_cache - 1, cfg.dim), cfg.dtype),)
+    if kind == "mamba":
+        # (the rows of [x ; B ; C] before the taps; h float32, a head's TRANSPOSED
+        # [d_state, d_head], ``side`` heads side by side along the lanes as the
+        # delta rule's S is (``pack_state``): the step kernel's layout,
+        # ops/ssd.py; granite's 64 heads of [64, 128] are 32 units of [128, 128])
+        channels = (cfg.mamba_n_heads * cfg.mamba_d_head
+                    + 2 * cfg.mamba_n_groups * cfg.mamba_d_state)
+        side = heads_a_lane_row(cfg.mamba_n_heads, cfg.mamba_d_head)
+        return (((cfg.mamba_d_conv - 1, channels), cfg.dtype),
+                ((cfg.mamba_n_heads // side, cfg.mamba_d_state, side * cfg.mamba_d_head),
+                 jnp.float32))
     channels = (2 * cfg.linear_num_key_heads * cfg.linear_key_head_dim
                 + cfg.linear_num_value_heads * cfg.linear_value_head_dim)
     side = state_lane_heads(cfg)
@@ -509,6 +500,20 @@ def put_state(entry, state_slots, new_arrays) -> StateEntry:
                       for a, n in zip(entry, new_arrays))
 
 
+def matrix_state_layer(cfg) -> Optional[int]:
+    """The first mamba layer (the layer whose h a probe may read back), or None."""
+    return next(iter(cfg.layers_of("mamba")), None)
+
+
+def read_matrix_state(cfg, tree, state_slots):
+    """The h that layer holds for the sequences ``state_slots`` [b] names, a head
+    at a time and TRANSPOSED as the cache holds it, [b, H, d_state, d_head]
+    float32, whatever the lanes' layout: what a probe that asked for "state" is
+    sent (runtime/batcher.py ``_read_state``)."""
+    h = tree[matrix_state_layer(cfg)][1][state_slots]
+    return unpack_state(h, heads_a_lane_row(cfg.mamba_n_heads, cfg.mamba_d_head))
+
+
 # --- over a whole tree: plain functions the loop jits, donates and caches ----
 # (runtime/batcher.py ``_page_table_ops``, servers/llmserver.py); each hands a
 # state entry on as it is.
@@ -536,7 +541,7 @@ def tiled_nbytes(shape: Tuple[int, ...], dtype) -> int:
 def matrix_state_nbytes(tree) -> Tuple[int, int]:
     """(the arrays' own bytes, the bytes the chip holds for them as it tiles
     them) of the float32 MATRIX state alone: the second array of every
-    linear-attention entry of a tree (a conv entry has one array, rows). Equal
+    linear-attention or mamba entry of a tree (a conv entry has one array, rows). Equal
     where no lane or sublane of S is padding."""
     mats = [layer[1] for layer in tree if is_state_entry(layer) and len(layer) == 2]
     return (sum(math.prod(m.shape) * jnp.dtype(m.dtype).itemsize for m in mats),

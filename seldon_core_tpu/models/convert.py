@@ -163,6 +163,37 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
                 linear_conv_kernel_dim=hf_config.linear_conv_kernel_dim,
                 linear_allow_neg_eigval=bool(hf_config.linear_allow_neg_eigval),
                 linear_dt_bias="range")
+    if model_type == "granitemoehybrid":
+        # Mamba-2 layers beside position-free (or rotary) GQA layers, four
+        # scalar multipliers, ONE dense gated MLP a layer (the "shared" one).
+        # The dense members alone: a checkpoint with experts is refused by name
+        if getattr(hf_config, "num_local_experts", 0):
+            raise ValueError(
+                f"granitemoehybrid with num_local_experts={hf_config.num_local_experts} is not "
+                "supported by the native transformer: the family's members with experts "
+                "(block_sparse_moe beside the shared MLP) are not built")
+        inner = int(hf_config.mamba_expand * hf_config.hidden_size)
+        if (getattr(hf_config, "mamba_proj_bias", False) or inner % hf_config.mamba_n_heads
+                or hf_config.mamba_d_head not in ("auto", inner // hf_config.mamba_n_heads)):
+            raise ValueError(
+                "granitemoehybrid with mamba_proj_bias, or a mamba_d_head that is not "
+                "mamba_expand * hidden_size / mamba_n_heads, is not supported by the native "
+                "transformer")
+        ffn_dim = hf_config.shared_intermediate_size
+        rotary = getattr(hf_config, "position_embedding_type", None) == "rope"
+        moe = {
+            "layer_types": tuple("mamba" if kind == "mamba" else "full_attention"
+                                 for kind in hf_config.layers_block_type),
+            "rope_theta": float(hf_config.rope_theta) if rotary else None,
+            "mamba_n_heads": hf_config.mamba_n_heads,
+            "mamba_d_head": inner // hf_config.mamba_n_heads,
+            "mamba_d_state": hf_config.mamba_d_state, "mamba_n_groups": hf_config.mamba_n_groups,
+            "mamba_d_conv": hf_config.mamba_d_conv,
+            "mamba_conv_bias": bool(hf_config.mamba_conv_bias),
+            "embedding_multiplier": float(hf_config.embedding_multiplier),
+            "attention_multiplier": float(hf_config.attention_multiplier),
+            "residual_multiplier": float(hf_config.residual_multiplier),
+            "logits_scaling": float(hf_config.logits_scaling)}
     if deepseek:
         # V3's router is sigmoid scores + a selection bias (noaux_tc); its
         # config class carries neither key, V2-shaped configs name both
@@ -214,7 +245,7 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
         "norm_eps": hf_config.norm_eps if lfm2 else hf_config.rms_norm_eps,
         "tie_embeddings": bool(getattr(hf_config, "tie_word_embeddings", False)),
         **({"rope_scaling": rope_scaling} if rope_scaling else {}),
-        **moe,      # (olmo_hybrid's rope_theta, None, overrides the default above)
+        **moe,      # (olmo_hybrid's and granitemoehybrid's rope_theta overrides the default above)
     }
 
 
@@ -362,6 +393,58 @@ def convert_olmo_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str, Any],
                 "q_norm": {"weight": t(f"{hf}.self_attn.q_norm.weight")},
                 "k_norm": {"weight": t(f"{hf}.self_attn.k_norm.weight")},
             }
+    if not kwargs["tie_embeddings"]:
+        params["lm_head"] = t("lm_head.weight").T
+    leftover = [k for k in state_dict if k not in consumed and not k.endswith("inv_freq")
+                and not (kwargs["tie_embeddings"] and k == "lm_head.weight")]
+    if leftover:
+        raise ValueError(
+            f"unmapped weights in state dict (conversion would drop them): {leftover[:8]}")
+    return {"params": params}
+
+
+def convert_granite_hybrid_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str, Any],
+                                      dtype: str = "float32") -> Dict[str, Any]:
+    """HF GraniteMoeHybridForCausalLM (a DENSE member: ``num_local_experts`` 0)
+    state dict -> our flax param tree. A mamba layer: ``mamba.in_proj``
+    [d_inner + conv_dim + heads, dim] transposes to the ONE product whose
+    columns are [z ; xBC ; dt] in that order; the depthwise ``mamba.conv1d.weight``
+    [conv_dim, 1, taps] drops its middle axis (torch's order: the last tap
+    weighs the row itself, ours too) and its bias is ``conv_bias``; ``A_log``,
+    ``dt_bias`` and ``D`` a head stack to the ONE leaf ``heads`` [3, heads];
+    ``mamba.norm`` the gated norm over d_inner.
+    ``input_layernorm`` is the block's first norm (``operator_norm`` of a mamba
+    layer), ``post_attention_layernorm`` its second; ``shared_mlp.input_linear``
+    [2 width, dim] is [gate ; up] (``chunk(2)``: SiLU on the FIRST half)."""
+    t, consumed = _tensor_reader(state_dict, dtype)
+    params: Dict[str, Any] = {
+        "tok_embeddings": t("model.embed_tokens.weight"),
+        "norm": {"weight": t("model.norm.weight")},
+    }
+    width = kwargs["ffn_dim"]
+    for i, kind in enumerate(kwargs["layer_types"]):
+        hf = f"model.layers.{i}"
+        gate_up = t(f"{hf}.shared_mlp.input_linear.weight")
+        layer = params[f"layer_{i}"] = {
+            "ffn_norm": {"weight": t(f"{hf}.post_attention_layernorm.weight")},
+            "ffn": {"w1": gate_up[:width].T, "w3": gate_up[width:].T,
+                    "w2": t(f"{hf}.shared_mlp.output_linear.weight").T}}
+        if kind == "mamba":
+            layer["operator_norm"] = {"weight": t(f"{hf}.input_layernorm.weight")}
+            layer["mamba"] = {
+                "in_proj": t(f"{hf}.mamba.in_proj.weight").T,
+                "conv1d": t(f"{hf}.mamba.conv1d.weight")[:, 0, :],
+                **({"conv_bias": t(f"{hf}.mamba.conv1d.bias")} if kwargs["mamba_conv_bias"] else {}),
+                "heads": np.stack([t(f"{hf}.mamba.A_log"), t(f"{hf}.mamba.dt_bias"),
+                                   t(f"{hf}.mamba.D")]),
+                "norm": {"weight": t(f"{hf}.mamba.norm.weight")},
+                "out_proj": t(f"{hf}.mamba.out_proj.weight").T,
+            }
+        else:
+            layer["attention_norm"] = {"weight": t(f"{hf}.input_layernorm.weight")}
+            layer["attention"] = {
+                ours: t(f"{hf}.self_attn.{theirs}.weight").T for ours, theirs in
+                (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"), ("wo", "o_proj"))}
     if not kwargs["tie_embeddings"]:
         params["lm_head"] = t("lm_head.weight").T
     leftover = [k for k in state_dict if k not in consumed and not k.endswith("inv_freq")
@@ -627,7 +710,8 @@ def convert_deepseek_v2_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str,
 def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
     """In-memory transformers LlamaForCausalLM, OlmoeForCausalLM,
     DeepseekV2ForCausalLM, DeepseekV3ForCausalLM, Lfm2ForCausalLM,
-    Qwen3NextForCausalLM or Olmo3ForCausalLM -> (our module, variables)."""
+    Qwen3NextForCausalLM, Olmo3ForCausalLM or (dense) GraniteMoeHybridForCausalLM
+    -> (our module, variables)."""
     from seldon_core_tpu.models import get_model
 
     kwargs = config_kwargs_from_hf(hf_model.config)
@@ -640,6 +724,8 @@ def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
         variables = convert_qwen3_next_state_dict(hf_model.state_dict(), kwargs)
     elif kwargs.get("norm_placement") == "branch":      # olmo3, olmo_hybrid
         variables = convert_olmo_state_dict(hf_model.state_dict(), kwargs)
+    elif kwargs.get("mamba_n_heads"):      # granitemoehybrid
+        variables = convert_granite_hybrid_state_dict(hf_model.state_dict(), kwargs)
     elif kwargs.get("layer_types"):
         variables = convert_lfm2_state_dict(hf_model.state_dict(), kwargs)
     else:
@@ -673,6 +759,8 @@ def convert_checkpoint(hf_path: str, out_dir: str, dtype: str = "bfloat16") -> s
         variables = convert_qwen3_next_state_dict(model.state_dict(), kwargs, dtype)
     elif kwargs.get("norm_placement") == "branch":      # olmo3, olmo_hybrid
         variables = convert_olmo_state_dict(model.state_dict(), kwargs, dtype)
+    elif kwargs.get("mamba_n_heads"):      # granitemoehybrid
+        variables = convert_granite_hybrid_state_dict(model.state_dict(), kwargs, dtype)
     elif kwargs.get("layer_types"):
         variables = convert_lfm2_state_dict(model.state_dict(), kwargs, dtype)
     else:

@@ -199,6 +199,38 @@ layout gives none), ``rope_on_window=False`` (none where it gives one),
 ``renormalize=False`` (the k weights as the softmax over all left them), and
 ``leave_out_rank`` above.
 
+granite-4.0-h (``model_type`` ``granitemoehybrid``, a DENSE member; the published
+modeling file IS installed: ``transformers/models/granitemoehybrid``, whose
+``GraniteMoeHybridMambaLayer.torch_forward``, ``GraniteMoeHybridRMSNormGated`` and
+``GraniteMoeHybridDecoderLayer`` tests/test_reference_granite_hybrid.py holds this file
+to at 1e-5 through models/convert.py). With u the block's input [s, 2048], every matrix
+without bias, H = 64 heads of P = 64 (d_inner 4096), one group, a state of N = 128:
+
+    block : h = u + r Mixer(RMSNorm(u));  out = h + r MLP(RMSNorm(h))    r = cfg.residual_multiplier
+            MLP(h) = W_o (SiLU(a) * b), [a ; b] = W_i h                 (the tree's w1, w3, w2)
+    mamba : [z ; xBC ; dt] = W_in RMSNorm(u)       (4096 + 4352 + 64 columns)
+            xBC <- SiLU(causal depthwise taps (4) over the 4352 channels + conv bias)
+            [x ; B ; C] = xBC;  Delta_t = softplus(dt_t + dt_bias), A = -exp(A_log)  a head
+            (the tree's leaf ``heads`` [3, H] is [A_log ; dt_bias ; D])
+            a ``lax.scan`` over the tokens from h = 0, h [P, N] a head:
+              h_t = e^{Delta_t A} h_{t-1} + (Delta_t x_t) B_t^T;   y_t = h_t C_t + D x_t
+            Mixer = W_out (w * RMSNorm_4096(y * SiLU(z)))   the gate BEFORE the norm, ONE
+            norm over all d_inner channels (no chunked form, no cache)
+    attn  : 32 query / 8 KV heads of 64, NO position (cfg.rope_theta None:
+            ``position_embedding_type`` "nope"), softmax(q k^T * cfg.attention_multiplier)
+            (0.015625, NOT 64^-1/2)
+    model : the table's rows * cfg.embedding_multiplier; logits = RMSNorm(h_last) E^T /
+            cfg.logits_scaling; the table and the head are ONE leaf (tied)
+
+WRONG models of these: ``ssd_state_bf16=True`` (h rounded to bf16 after every token),
+``ssd_gate_after_norm=True`` (w * RMSNorm(y) * SiLU(z)), ``attention_multiplier_off=True``
+(scores * head_dim^-1/2), ``residual_multiplier_off=True`` (r = 1), ``ssd_skip=False`` (D
+left out), ``ssd_conv_bias=False``, ``ssd_conv_bc=False`` (the taps over x alone: B and C
+as projected), ``ssd_reset_every=N`` (h zeroed at every multiple of N: a chunk that does
+not carry h), and ``conv_state_pad`` as LFM2's (over the rows of xBC before the taps).
+``ssd_state`` is the h one mamba layer holds after a sequence, for a probe that reads
+the served cache's back (transport/rest.py "state").
+
 ``follow=`` makes the forward take the experts the SERVED path took (a logits
 probe's ``routing``): where this router's last chosen and first unchosen expert
 score within the served arithmetic's noise of each other, which one is taken is
@@ -359,14 +391,17 @@ def _attend_block(q, k, v, start, lo, window: int = 0):
 
 def _attention(p: dict, x, cfg, qk_norm=None, gate: bool = True, rotary_all: bool = False,
                block: int = 256, rope_theta_wrong: Optional[float] = None, window: int = 0,
-               rotary: bool = True):
+               rotary: bool = True, multiplier: Optional[float] = None):
     """Queries go in blocks of ``block`` rows, each against the keys its rows
     may see and no others (a 6 k context needs [heads, block, <= s] of scores
     at a time; the arithmetic is the same). ``window`` > 0: a query sees that
-    many keys, itself included; ``rotary`` False: this layer sees no position."""
+    many keys, itself included; ``rotary`` False: this layer sees no position;
+    ``multiplier``: the softmax scale in place of head_dim^-1/2."""
     s = x.shape[0]
     hd = getattr(cfg, "head_dim", 0) or cfg.dim // cfg.n_heads
     q, k, v = x @ _f32(p["wq"]), x @ _f32(p["wk"]), x @ _f32(p["wv"])
+    if multiplier is not None:      # (``_attend_block`` divides by sqrt(hd))
+        q = q * (multiplier * math.sqrt(hd))
     qk_norm = cfg.qk_norm if qk_norm is None else qk_norm
     if qk_norm == "whole":      # a WRONG model of per-head norms: one norm, the head's weight tiled
         q = _rms_norm(q, jnp.tile(_f32(p["q_norm"]["weight"]), cfg.n_heads), cfg.norm_eps)
@@ -535,6 +570,64 @@ def _gated_delta_net(p: dict, x, cfg, wrong: dict, seen: Optional[list] = None):
     return o.reshape(s, value_dim) @ _f32(p["out_proj"])
 
 
+@partial(jax.jit, static_argnames=("round_bf16",))
+def _ssd_scan(x, dt, A, B, C, reset, round_bf16: bool = False):
+    """Mamba-2's recurrence as a scan over tokens from h = 0: ``x`` [s, H, P],
+    ``dt`` [s, H], ``A`` [H], ``B`` / ``C`` [s, H, N] (a group's row at each of
+    its heads), ``reset`` [s] bool (a WRONG model zeroes h before such a token)
+    -> (h after the last token [H, P, N], h_t C_t [s, H, P])."""
+    def step(h, row):
+        x_t, dt_t, b_t, c_t, reset_t = row
+        h = (jnp.where(reset_t, 0.0, h) * jnp.exp(dt_t * A)[:, None, None]
+             + (dt_t[:, None] * x_t)[:, :, None] * b_t[:, None, :])
+        if round_bf16:   # (``lax.reduce_precision``: see ``_delta_scan``)
+            h = jax.lax.reduce_precision(h, exponent_bits=8, mantissa_bits=7)
+        return h, jnp.einsum("hpn,hn->hp", h, c_t)
+
+    with jax.default_matmul_precision("highest"):
+        h0 = jnp.zeros((x.shape[1], x.shape[2], B.shape[2]), jnp.float32)
+        return jax.lax.scan(step, h0, (x, dt, B, C, reset))
+
+
+def _mamba2(p: dict, u, cfg, wrong: dict, seen: Optional[list] = None,
+            left: Optional[list] = None):
+    """granite-4.0-h's Mamba-2 mixer over the whole sequence ``u`` [s, C]: the
+    recurrence token by token (the served path runs a chunked form in its
+    prefill and keeps h and three rows of xBC between calls). ``left``, a list:
+    the h [H, P, N] the last token leaves is appended to it."""
+    s = u.shape[0]
+    H, P, N, G = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.mamba_n_groups
+    inner = H * P
+    channels = inner + 2 * G * N
+    zxbcdt = u @ _f32(p["in_proj"])
+    z, xbc, dt = zxbcdt[:, :inner], zxbcdt[:, inner:inner + channels], zxbcdt[:, inner + channels:]
+    mixed = _causal_taps(xbc, _f32(p["conv1d"]), wrong, seen)
+    if "conv_bias" in p and wrong["ssd_conv_bias"]:
+        mixed = mixed + _f32(p["conv_bias"])
+    mixed = jax.nn.silu(mixed)
+    if not wrong["ssd_conv_bc"]:     # WRONG: B and C as projected, the taps over x alone
+        mixed = jnp.concatenate([mixed[:, :inner], xbc[:, inner:]], axis=1)
+    x = mixed[:, :inner].reshape(s, H, P)
+    B = jnp.repeat(mixed[:, inner:inner + G * N].reshape(s, G, N), H // G, axis=1)
+    C = jnp.repeat(mixed[:, inner + G * N:].reshape(s, G, N), H // G, axis=1)
+    a_log, dt_bias, skip = _f32(p["heads"])     # the tree's one leaf [3, H]
+    dt = jax.nn.softplus(dt + dt_bias)
+    every = wrong["ssd_reset_every"]
+    reset = (jnp.arange(s) % every == 0) if every else jnp.zeros((s,), bool)
+    h, y = _ssd_scan(x, dt, -jnp.exp(a_log), B, C, reset,
+                     round_bf16=bool(wrong["ssd_state_bf16"]))
+    if left is not None:
+        left.append(h)
+    if wrong["ssd_skip"]:
+        y = y + skip[:, None] * x
+    y = y.reshape(s, inner)
+    if wrong["ssd_gate_after_norm"]:
+        y = _rms_norm(y, p["norm"]["weight"], cfg.norm_eps) * jax.nn.silu(z)
+    else:
+        y = _rms_norm(y * jax.nn.silu(z), p["norm"]["weight"], cfg.norm_eps)
+    return y @ _f32(p["out_proj"])
+
+
 EXPERT_ROWS = 64     # an expert's tokens are computed in whole buckets of this many rows
 
 
@@ -663,7 +756,8 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
     """One decoder block on the residual ``x`` [s, C], or on the streams
     [s, n, C] where the layer has mixing parameters and ``streams`` is on. A
     layer that holds a ``conv`` (LFM2) or a ``linear_attn`` (Qwen3-Next) mixes
-    tokens by it, not by attention."""
+    tokens by it, not by attention; one that holds a ``mamba`` (granite-4.0-h)
+    by Mamba-2's recurrence."""
     latent = getattr(cfg, "kv_lora_rank", 0) > 0
     mixed = x.ndim == 3
     iters = wrong["sinkhorn_iters"] if wrong["sinkhorn_iters"] is not None else getattr(
@@ -683,11 +777,14 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
     router_x = x if (wrong["router_input"] or getattr(cfg, "router_input", "ffn_input")
                      ) == "layer_input" and not mixed else None
 
+    # every branch times this before it joins the residual (Granite)
+    joins = 1.0 if wrong["residual_multiplier_off"] else getattr(cfg, "residual_multiplier", 1.0)
+
     def sub_layer(x, name, norm, f):
         if placement == "branch":   # Olmo 2 / 3: the norm on the branch, none before it
-            return x + _rms_norm(f(x), layer[norm]["weight"], cfg.norm_eps)
+            return x + joins * _rms_norm(f(x), layer[norm]["weight"], cfg.norm_eps)
         if not mixed:
-            return x + f(_rms_norm(x, layer[norm]["weight"], cfg.norm_eps))
+            return x + joins * f(_rms_norm(x, layer[norm]["weight"], cfg.norm_eps))
         u, h_post, h_res = _mix(layer[name], x, cfg, iters)
         y = f(_rms_norm(u, layer[norm]["weight"], cfg.norm_eps))
         return jnp.einsum("sij,sjc->sic", h_res, x) + h_post[:, :, None] * y[:, None, :]
@@ -698,7 +795,9 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
                                      q_norm=wrong["q_norm"])
         return _attention(layer["attention"], n1, cfg, wrong["qk_norm"], wrong["attn_gate"],
                           wrong["rotary_all"], rope_theta_wrong=wrong["rope_theta_wrong"],
-                          window=window, rotary=rotary)
+                          window=window, rotary=rotary,
+                          multiplier=None if wrong["attention_multiplier_off"] else getattr(
+                              cfg, "attention_multiplier", None))
 
     def ffn(n2):
         if moe:
@@ -718,6 +817,9 @@ def _block(layer: dict, x, cfg, moe: bool, routing: list, wrong: dict, follow=No
     elif "linear_attn" in layer:
         x = sub_layer(x, None, "operator_norm",
                       lambda n1: _gated_delta_net(layer["linear_attn"], n1, cfg, wrong, seen))
+    elif "mamba" in layer:
+        x = sub_layer(x, None, "operator_norm",
+                      lambda n1: _mamba2(layer["mamba"], n1, cfg, wrong, seen))
     else:
         x = sub_layer(x, "attention_hc", "attention_norm", attention)
     return sub_layer(x, "ffn_hc", "ffn_norm", ffn)
@@ -742,12 +844,17 @@ WRONG = {"leave_out_rank": None, "scale_mscale": True, "shared": True, "streams"
          "gdn_beta_doubled": True, "gdn_q_scale": True, "norm_placement": None,
          "rope_theta_wrong": None,
          "window_off": False, "window_wrong": None, "rope_on_global": False,
-         "rope_on_window": True, "router_input": None, "ffn_act": None, "renormalize": None}
+         "rope_on_window": True, "router_input": None, "ffn_act": None, "renormalize": None,
+         "ssd_state_bf16": False, "ssd_gate_after_norm": False, "attention_multiplier_off": False,
+         "residual_multiplier_off": False, "ssd_skip": True, "ssd_conv_bias": True,
+         "ssd_conv_bc": True, "ssd_reset_every": None}
 
 
-def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[list] = None):
-    """The main model's hidden state [s, C] after the last block (and the
-    streams' exit), before the final norm; and the routing."""
+def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[list] = None,
+            blocks: Optional[int] = None):
+    """The main model's hidden state [s, C] after the last block (after the
+    first ``blocks`` of them, where given) and the streams' exit, before the
+    final norm; and the routing."""
     if wrong["conv_state_pad"] is not None and len(wrong["conv_state_pad"]) == 2:
         # what each conv layer's LAST rows hold when the first n tokens are
         # padded with token 0 to m rows: a pass of its own
@@ -757,7 +864,8 @@ def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[
                 None if follow is None else follow[:0], padded)
         taps_n = next(_f32(leaf).shape[1] for i in range(cfg.n_layers)
                       for leaf in (p[f"layer_{i}"].get("conv", {}).get("taps"),
-                                   p[f"layer_{i}"].get("linear_attn", {}).get("conv1d"))
+                                   p[f"layer_{i}"].get("linear_attn", {}).get("conv1d"),
+                                   p[f"layer_{i}"].get("mamba", {}).get("conv1d"))
                       if leaf is not None)
         wrong = {**wrong, "conv_state_pad": (n, m, [z[-(taps_n - 1):] for z in padded])}
         seen = []
@@ -766,8 +874,9 @@ def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[
         raise NotImplementedError("the reference has scaled RoPE for latent attention only")
     first_dense = getattr(cfg, "first_dense_layers", 0)
     routing: list = []
-    x = _enter(_f32(p["tok_embeddings"])[jnp.asarray(tokens, jnp.int32)], cfg, wrong)
-    for i in range(cfg.n_layers):
+    x = _enter(_f32(p["tok_embeddings"])[jnp.asarray(tokens, jnp.int32)]
+               * getattr(cfg, "embedding_multiplier", 1.0), cfg, wrong)
+    for i in range(cfg.n_layers if blocks is None else blocks):
         x = _block(p[f"layer_{i}"], x, cfg, cfg.n_experts > 0 and i >= first_dense, routing, wrong,
                    follow, seen, i)
     return _leave(x), routing
@@ -804,7 +913,23 @@ def forward(params: Any, cfg: Any, tokens, rows=slice(None), follow=None, **wron
     with jax.default_matmul_precision("highest"):
         x, routing = _hidden(p, cfg, tokens, wrong, follow)
         x = _rms_norm(x, p["norm"]["weight"], cfg.norm_eps)
-        return x[rows] @ _head(p, cfg), routing
+        return x[rows] @ _head(p, cfg) / getattr(cfg, "logits_scaling", 1.0), routing
+
+
+def ssd_state(params: Any, cfg: Any, tokens, layer: int, **wrong):
+    """The h [H, P, N] float32 that mamba layer ``layer`` holds after ``tokens``
+    [s], from zeros, token by token: what the served path's cache is compared
+    with where a probe reads it back (transport/rest.py "state"). The keywords
+    are ``forward``'s: ``ssd_state_bf16=True`` rounds h to bf16 after every
+    token, which is what a cache that held it in bf16 would do."""
+    wrong = {**WRONG, **wrong}
+    p = params.get("params", params)
+    with jax.default_matmul_precision("highest"):
+        x, _ = _hidden(p, cfg, tokens, wrong, blocks=layer)
+        mixer, left = p[f"layer_{layer}"], []
+        _mamba2(mixer["mamba"], _rms_norm(x, mixer["operator_norm"]["weight"], cfg.norm_eps),
+                cfg, wrong, left=left)
+        return left[0]
 
 
 def forward_mtp(params: Any, cfg: Any, tokens):

@@ -51,17 +51,21 @@ from seldon_core_tpu.models.registry import register_model
 param_with_axes = nn_partitioning.param_with_axes
 with_sharding_constraint = nn_partitioning.with_sharding_constraint
 
-LAYER_KINDS = ("full_attention", "sliding_attention", "conv", "linear_attention")
+LAYER_KINDS = ("full_attention", "sliding_attention", "conv", "linear_attention", "mamba")
 # the kinds that are ATTENTION (pages of K and V rows): a "sliding_attention"
 # layer's mask has a lower bound too (``sliding_window``) and its pages are of
 # the window class (models/cache.py WindowEntry), given back behind the window
 ATTENTION_LAYER_KINDS = ("full_attention", "sliding_attention")
 # the kinds whose layer keeps a fixed block of STATE a sequence, not pages
-STATE_LAYER_KINDS = ("conv", "linear_attention")
+STATE_LAYER_KINDS = ("conv", "linear_attention", "mamba")
 STATE_LAYERS_COMPOSE_REFUSAL = (
-    "a 'conv' or 'linear_attention' layer (layer_types) does not compose with "
+    "a 'conv', 'linear_attention' or 'mamba' layer (layer_types) does not compose with "
     "latent attention (kv_lora_rank > 0), hyper-connections (hc_mult > 1) or an "
     "MTP module: no model pairs them and no test holds them")
+MAMBA_LAYERS_COMPOSE_REFUSAL = (
+    "a 'mamba' layer (layer_types; Mamba2Mixer) is built for one device beside attention and "
+    "a dense FFN: not with a mesh, experts (n_experts > 0: the family's members with experts "
+    "are not built) or 'linear_attention' layers: no model pairs them and no test holds them")
 WINDOW_LAYERS_COMPOSE_REFUSAL = (
     "a 'sliding_attention' layer (layer_types; sliding_window) is built for per-head K/V "
     "attention on one device with the bf16 cache: not with latent attention "
@@ -185,6 +189,29 @@ class TransformerConfig:
     # softplus^-1 of exp(U(log 0.001, log 0.1)), a decay a token of e^-1.6 .. ~1).
     linear_allow_neg_eigval: bool = False
     linear_dt_bias: str = "ones"
+    # A "mamba" layer is Mamba-2's selective state-space mixer (Mamba2Mixer
+    # below; granite-4.0-h's GraniteMoeHybridMambaLayer): mamba_n_heads heads of
+    # mamba_d_head (d_inner = their product), mamba_n_groups groups of heads
+    # sharing a B and a C of mamba_d_state values, a depthwise causal convolution
+    # of mamba_d_conv taps (with a bias where mamba_conv_bias) over
+    # [x ; B ; C]. It keeps, a sequence, the last taps - 1 rows of [x ; B ; C]
+    # and one float32 matrix [mamba_d_head, mamba_d_state] a head: the cache
+    # entry is the 2-tuple ``(conv_state, h)``.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_conv_bias: bool = True
+    # Four scalars (Granite's): the embedding rows x embedding_multiplier; the
+    # softmax scale attention_multiplier in place of head_dim^-1/2 (None);
+    # every branch x residual_multiplier before it joins the residual; the
+    # logits / logits_scaling. The defaults are what every other model
+    # computes, and multiply nothing.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # Where a block's two RMSNorms stand: "pre" = x + f(norm(x)) (Llama and
     # every other family served); "branch" = x + norm(f(x)), nothing normed
     # before the mixer or the FFN (Olmo 2 / 3). The same two weights a layer.
@@ -257,6 +284,10 @@ class TransformerConfig:
             raise ValueError(
                 "rope_theta=None (no rotary embedding) is built for per-head K/V attention "
                 "alone: not with latent attention, rope_scaling or partial_rotary_factor")
+        if self.attention_multiplier is not None and self.kv_lora_rank:
+            raise ValueError(
+                "attention_multiplier (a softmax scale of the config's own) is built for per-head "
+                "K/V attention: latent attention's scale is latent_attention_scale")
         if self.linear_dt_bias not in ("ones", "range"):
             raise ValueError(
                 f"unknown linear_dt_bias {self.linear_dt_bias!r}: expected 'ones' or 'range'")
@@ -288,6 +319,15 @@ class TransformerConfig:
                     raise ValueError(WINDOW_LAYERS_COMPOSE_REFUSAL)
             if "conv" in kinds and self.conv_L_cache < 2:
                 raise ValueError(f"conv_L_cache={self.conv_L_cache} must be >= 2 (taps)")
+            if "mamba" in kinds:
+                if (min(self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state,
+                        self.mamba_n_groups) <= 0 or self.mamba_n_heads % self.mamba_n_groups
+                        or self.mamba_d_conv < 2):
+                    raise ValueError(
+                        "a 'mamba' layer needs mamba_n_heads (a multiple of mamba_n_groups), "
+                        "mamba_d_head, mamba_d_state and mamba_d_conv >= 2")
+                if self.mesh is not None or self.n_experts or "linear_attention" in kinds:
+                    raise ValueError(MAMBA_LAYERS_COMPOSE_REFUSAL)
             if "linear_attention" in kinds:
                 hk, hv = self.linear_num_key_heads, self.linear_num_value_heads
                 if (min(hk, hv, self.linear_key_head_dim, self.linear_value_head_dim) <= 0
@@ -705,6 +745,11 @@ class Attention(nn.Module):
             q = RMSNorm(hd, cfg.norm_eps, "head_norm", name="q_norm")(q)
             k = RMSNorm(hd, cfg.norm_eps, "head_norm", name="k_norm")(k)
         v = (x @ wv.astype(dt)).reshape(b, s, cfg.n_kv_heads, hd)
+        if cfg.attention_multiplier is not None:
+            # every read below scales the scores by head_dim^-1/2: the config's
+            # own scale reaches them on the queries (Granite's 1/64 at heads of
+            # 64 is q / 8, exact in any float)
+            q = q * (cfg.attention_multiplier * hd ** 0.5)
 
         if cfg.rope_theta is not None and self.rotary:
             cos, sin = rotary_embedding(positions, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling)
@@ -1297,8 +1342,16 @@ def moe_routing_stats(sown: dict, cfg: TransformerConfig):
 # range); the shared expert's scalar gate normal(0, 1/sqrt(dim)). The layer's
 # own dt_bias (flash-linear-attention's GatedDeltaNet; ``cfg.linear_dt_bias``
 # "range"): softplus^-1 of a step dt = exp(U(log 0.001, log 0.1)) (DT_RANGE).
-FLOAT32_AXES = ("hc_maps", "expert_select", "conv_taps", "head_norm", "gdn_scalar", "expert_gate")
+# Mamba-2's (Mamba2Mixer; the layer's PUBLISHED initialisation, ``Mamba2``'s, not
+# the installed modeling file's placeholders, whose dt_bias ones forgets within
+# two tokens), the three values a head as the ONE leaf ``heads`` [3, heads]
+# (SSD_HEADS): ``A_log`` = log(1 .. heads) (no draw), ``dt_bias`` by DT_RANGE over
+# (0.001, 0.1) (the file's time_step_min / _max), ``D`` ones; the taps as Gated
+# DeltaNet's and the convolution's bias normal(0, 1/2) likewise.
+FLOAT32_AXES = ("hc_maps", "expert_select", "conv_taps", "head_norm", "gdn_scalar", "expert_gate",
+                "ssd_scalar")
 LOG_UNIFORM = "log of uniform"
+SSD_HEADS = "A_log, dt_bias and D a head, stacked"
 DT_RANGE = "softplus inverse of a log-uniform step"
 SMALL_LEAF_INIT = {
     "phi": (0.0, None), "alpha": (0.7, 0.05), "b_pre": (0.0, 0.5), "b_post": (0.0, 0.5),
@@ -1306,6 +1359,7 @@ SMALL_LEAF_INIT = {
     "weight": (1.0, 0.0), "conv1d": (0.0, 0.5), "dt_bias": (1.0, 0.0),
     "A_log": (LOG_UNIFORM, (0.0, 16.0)), "shared_gate": (0.0, None),
     "dt_bias_range": (DT_RANGE, (1e-3, 1e-1)),
+    "heads": (SSD_HEADS, (1e-3, 1e-1)), "conv_bias": (0.0, 0.5),
 }
 
 
@@ -1315,6 +1369,11 @@ def draw_small_leaf(name: str, key, shape) -> jnp.ndarray:
         low, high = std
         return jnp.log(jnp.maximum(
             jax.random.uniform(key, shape, jnp.float32, low, high), 1e-6))
+    if mean == SSD_HEADS:
+        heads = shape[1]
+        return jnp.stack([jnp.log(jnp.arange(1, heads + 1, dtype=jnp.float32)),
+                          draw_small_leaf("dt_bias_range", key, (heads,)),
+                          jnp.ones((heads,), jnp.float32)])
     if mean == DT_RANGE:
         low, high = std
         dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, math.log(low), math.log(high)))
@@ -1766,6 +1825,108 @@ class GatedDeltaNet(nn.Module):
             return y @ w_out.astype(dt), new_cache
 
 
+def ssd_step_blocks(cfg: "TransformerConfig") -> Optional[Any]:
+    """How the decode step's state-space recurrence goes through the repo's
+    kernel IN A PROGRAM LOWERED FOR A TPU (ops/ssd.py ``Plan``), or None where
+    it is the expression (a further pass over h for h C) there too: a state
+    that is not whole tiles. ``ssd``'s own ``plan`` at the model's sizes, for
+    the loop's ``seldon_llm_ssd_step_path``."""
+    from seldon_core_tpu.ops.ssd import plan
+
+    if not cfg.layers_of("mamba"):
+        return None
+    return plan(cfg.mamba_n_heads, cfg.mamba_n_groups, cfg.mamba_d_head, cfg.mamba_d_state)
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2's selective state-space mixer as granite-4.0-h computes it
+    (``transformers`` ``GraniteMoeHybridMambaLayer.torch_forward``,
+    ``GraniteMoeHybridRMSNormGated``), the token mixer of a "mamba" layer. With H
+    heads of P (d_inner = H P), G groups of heads and a state of N:
+
+        [z ; xBC ; dt] = W_in u              d_inner + (d_inner + 2 G N) + H columns, no bias
+        xBC <- SiLU(causal depthwise taps over the channels of xBC + conv bias)
+        [x ; B ; C] = xBC                     x [H, P]; B, C [G, N]
+        Delta = softplus(dt + dt_bias)        A = -exp(A_log)            a head; the leaf
+                                              ``heads`` [3, H] = [A_log ; dt_bias ; D]
+        h <- e^(Delta A) h + (Delta x) B^T    y = h C + D x              ops/ssd.py ``ssd``
+        out = W_out (w * RMSNorm_{d_inner}(y * SiLU(z)))     the gate BEFORE the norm, and
+                                                             ONE norm over all d_inner channels
+
+    The whole projection leaves the product float32 (dt is a decay's exponent
+    and z goes into float32 arithmetic; xBC is rounded to the serving dtype
+    where it meets the taps, as the rows a sequence keeps are). What a sequence
+    keeps between calls, whatever its length: the last taps - 1 rows of xBC
+    BEFORE the convolution, in the serving dtype (``short_conv``'s state and
+    rule), and h [P, N] a head in float32, as the published implementation
+    holds it: the cache entry is the 2-tuple ``(conv_state [rows, taps - 1,
+    d_inner + 2 G N], h)``, h in the layout models/cache.py gives it (a head's
+    h TRANSPOSED, heads side by side along the lanes: [rows, H / side, N,
+    side * P], the step kernel's; granite's [rows, 32, 128, 128]).
+    ``state_slots`` is ShortConv's. A sequence that starts
+    (its first row at position 0) reads h as zeros, so admission resets
+    nothing; a row that is no token has Delta = 0 and leaves h as it came.
+    Without a cache: from zeros, returns (out, (conv_state, h)) as well."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions, valid=None, cache=None, state_slots=None):
+        from seldon_core_tpu.ops.gated_delta import heads_a_lane_row
+        from seldon_core_tpu.ops.ssd import ssd
+
+        cfg = self.cfg
+        d, dt = cfg.dim, cfg.dtype
+        H, P, N, G = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.mamba_n_groups
+        inner, channels = H * P, H * P + 2 * G * N
+        w_in = param_with_axes("in_proj", nn.initializers.lecun_normal(),
+                               (d, inner + channels + H), jnp.float32,
+                               axes=("embed", "ssd_proj"))
+        taps = param_with_axes("conv1d", small_leaf_init("conv1d"), (channels, cfg.mamba_d_conv),
+                               jnp.float32, axes=("ssd_channel", "conv_taps"))
+        conv_bias = param_with_axes("conv_bias", small_leaf_init("conv_bias"), (channels,),
+                                    jnp.float32, axes=("ssd_scalar",)) if cfg.mamba_conv_bias else 0.0
+        # A_log, dt_bias and D a head as ONE leaf [3, H]: a leaf of 64 values is
+        # a copy into on-chip memory a layer a step, and each waited ~16 us
+        # behind the weights' prefetches (1.7 ms of a 33.4 ms step of 36 layers;
+        # with one leaf most of that wait moved on to the next small copy in the
+        # queue, the norm's weight: PERF.md section 6, PR 53)
+        a_log, dt_bias, skip = param_with_axes(
+            "heads", small_leaf_init("heads"), (3, H), jnp.float32, axes=("ssd_scalar", "ssd_head"))
+        w_out = param_with_axes("out_proj", nn.initializers.lecun_normal(), (inner, d),
+                                jnp.float32, axes=("ssd_inner", "embed"))
+        b, s, _ = x.shape
+        if valid is None:
+            valid = positions < PAD_POS
+        with jax.named_scope("mix.ssd.in"):
+            zxbcdt = jnp.matmul(x, w_in.astype(dt), preferred_element_type=jnp.float32)
+        conv_state, state = state_rows(cache, state_slots, 2)
+        with jax.named_scope("mix.ssd.conv"):
+            # the taps read xBC in the serving dtype, as the rows a sequence
+            # keeps are held: a row reads the same whichever call it is read in
+            mixed, new_conv = short_conv(zxbcdt[..., inner:inner + channels].astype(dt), taps,
+                                         conv_state, positions, valid)
+            mixed = jax.nn.silu(mixed + conv_bias)                       # float32
+        with jax.named_scope("mix.ssd.rule"):
+            step = jnp.where(valid[..., None],
+                             jax.nn.softplus(zxbcdt[..., inner + channels:] + dt_bias), 0.0)
+            if state is None:
+                side = heads_a_lane_row(H, P)
+                state = jnp.zeros((b, H // side, N, side * P), jnp.float32)
+            # a sequence that starts here has no past (a row that is no token
+            # starts nothing: a slot's h may be a chunk's to write meanwhile)
+            starts = (positions[:, 0] == 0) & valid[:, 0]
+            y, new_state = ssd(mixed[..., :inner].reshape(b, s, H, P), step, -jnp.exp(a_log),
+                               mixed[..., inner:inner + G * N].reshape(b, s, G, N),
+                               mixed[..., inner + G * N:].reshape(b, s, G, N), skip, state,
+                               starts)
+        new_cache = put_state(cache, state_slots, (new_conv, new_state))
+        with jax.named_scope("mix.ssd.out"):
+            gated = y.reshape(b, s, inner) * jax.nn.silu(zxbcdt[..., :inner])
+            normed = RMSNorm(inner, cfg.norm_eps, "head_norm", name="norm")(gated)
+            return normed.astype(dt) @ w_out.astype(dt), new_cache
+
+
 class TransformerBlock(nn.Module):
     """``x`` is the residual [b, s, dim] or, with cfg.hc_mult > 1, the residual
     streams [b, s, hc_mult, dim]: each sub-layer then reads a mix of the
@@ -1807,6 +1968,10 @@ class TransformerBlock(nn.Module):
             # likewise mix.gdn.*
             h, new_cache = GatedDeltaNet(cfg, name="linear_attn")(
                 mixer_in(), positions, valid, cache, state_slots)
+        elif kind == "mamba":
+            # likewise mix.ssd.*
+            h, new_cache = Mamba2Mixer(cfg, name="mamba")(
+                mixer_in(), positions, valid, cache, state_slots)
         else:
             # a pair of tables (full, window): each layer reads its class's
             if isinstance(block_tables, tuple):
@@ -1820,6 +1985,8 @@ class TransformerBlock(nn.Module):
                 )
         if branch:
             h = mixer_norm(h)
+        if cfg.residual_multiplier != 1.0:   # every branch, before it joins the residual
+            h = h * cfg.residual_multiplier
         ffn_norm = RMSNorm(cfg.dim, cfg.norm_eps, name="ffn_norm")
         if streams:
             X = hc_write_back(X, h, h_post, h_res)
@@ -1841,9 +2008,13 @@ class TransformerBlock(nn.Module):
         else:
             width = cfg.dense_ffn_dim if cfg.n_experts > 0 else 0
             f = DenseFFN(cfg, width, name="ffn")(ffn_in, adapters, adapter_ids)
+        if branch:
+            f = ffn_norm(f)
+        if cfg.residual_multiplier != 1.0:
+            f = f * cfg.residual_multiplier
         if streams:
             return hc_write_back(X, f, h_post, h_res), new_cache
-        return x + (ffn_norm(f) if branch else f), new_cache
+        return x + f, new_cache
 
 
 def enter_streams(x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
@@ -1937,7 +2108,10 @@ class Transformer(nn.Module):
         )
         # an int8 table arrives as it is held: its rows are gathered, then
         # dequantized (ops/quantize.py, "a row lookup")
-        x = lookup_rows(emb, tokens, cfg.dtype)
+        if cfg.embedding_multiplier != 1.0:   # multiplied in float32, rounded once
+            x = (lookup_rows(emb, tokens, jnp.float32) * cfg.embedding_multiplier).astype(cfg.dtype)
+        else:
+            x = lookup_rows(emb, tokens, cfg.dtype)
         x = enter_streams(with_sharding_constraint(x, ("batch", "seq", "embed")), cfg)
         valid = None
         if cfg.n_experts > 0 or cfg.state_layers:
@@ -1975,7 +2149,8 @@ class Transformer(nn.Module):
             # a matrix that arrives int8 (a served head, a tied table) is
             # dequantized HERE, where it is multiplied
             w = dequantize_array(head) if isinstance(head, QuantizedTensor) else head
-            return rows @ (w.T if cfg.tie_embeddings else w)
+            logits = rows @ (w.T if cfg.tie_embeddings else w)
+            return logits / cfg.logits_scaling if cfg.logits_scaling != 1.0 else logits
 
         if head_row is not None:
             # the row is taken BEFORE the head (the product is a row's own), and

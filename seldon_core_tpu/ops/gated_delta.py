@@ -19,7 +19,7 @@ MXU pass would load a 128 x 128 block of S as weights to multiply ONE row by
 it): k and q arrive as COLUMNS ([dk, heads of the block], made outside, a
 megabyte) and are broadcast along the lanes, v / d / o are rows.
 
-THE STATE'S LAYOUT is the cache's (models/cache.py ``pack_state``): where a
+THE STATE'S LAYOUT is the cache's (``pack_state`` below): where a
 head's dv is no whole number of 128-lane tiles, ``heads_a_lane_row`` heads lie
 SIDE BY SIDE along the lanes, [slots, H / side, dk, side * dv], so that the
 array holds no padded lane in HBM (Olmo-Hybrid's 30 heads of [96, 192]: 15
@@ -67,6 +67,27 @@ def heads_a_lane_row(heads: int, dv: int) -> int:
     head a row, padded as the chip tiles it)."""
     side = 128 // math.gcd(dv, 128)
     return side if heads % side == 0 else 1
+
+
+def pack_state(S, side: int):
+    """A layer's matrix state as the cache holds it: [b, H, dk, dv] ->
+    [b, H / side, dk, side * dv], ``side`` heads side by side along the lanes
+    (``heads_a_lane_row``), so that a head whose dv is no whole 128-lane tile
+    leaves no padded lane in HBM. At side 1 the array as it is."""
+    if side == 1:
+        return S
+    b, H, dk, dv = S.shape
+    return S.reshape(b, H // side, side, dk, dv).swapaxes(2, 3).reshape(
+        b, H // side, dk, side * dv)
+
+
+def unpack_state(S, side: int):
+    """``pack_state``'s inverse: [b, H / side, dk, side * dv] -> [b, H, dk, dv]."""
+    if side == 1:
+        return S
+    b, units, dk, lanes = S.shape
+    return S.reshape(b, units, dk, side, lanes // side).swapaxes(2, 3).reshape(
+        b, units * side, dk, lanes // side)
 
 
 def plan(heads: int, dk: int, dv: int) -> Optional[Plan]:
@@ -181,4 +202,5 @@ def gated_delta_step(q, k, v, g, beta, state, starts, walk: Plan,
     return o.reshape(b, H, dv), new_state.reshape(b, H // side, dk, lanes)
 
 
-__all__ = ["KERNEL_NAME", "Plan", "gated_delta_step", "heads_a_lane_row", "plan"]
+__all__ = ["KERNEL_NAME", "Plan", "gated_delta_step", "heads_a_lane_row", "pack_state", "plan",
+           "unpack_state"]

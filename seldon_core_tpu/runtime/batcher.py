@@ -478,10 +478,13 @@ class _Slot:
     __slots__ = ("future", "tokens", "true_len", "n_new", "max_new", "active",
                  "on_token", "gen", "disp_new", "pages", "shared", "ids",
                  "prefilling", "admit_seq", "t_last", "tenant", "slo_class",
-                 "adapter_id", "logits", "routing", "wpages")
+                 "adapter_id", "logits", "routing", "state", "wpages")
 
     def __init__(self):
         self.active = False
+        # a probe request's own ``info["state"]``, filled where it finishes
+        # with the matrix state the sequence leaves (``_finish``); else None
+        self.state: Optional[dict] = None
         # a probe request's float32 logits, one row per generated token (the
         # distribution it was sampled from): the request's own ``info``
         # list, or None for every request that did not ask
@@ -548,9 +551,12 @@ class _Slot:
 MOE_PROGRAMS = ("decode", "chunk")   # the step programs the loop counts by
 # the counters' names for the kinds of state layer (models/transformer.py
 # STATE_LAYER_KINDS): seldon_llm_<name>_rows_total / _layer_calls_total
-STATE_COUNTERS = {"conv": "conv", "gdn": "linear_attention"}
+STATE_COUNTERS = {"conv": "conv", "gdn": "linear_attention", "ssd": "mamba"}
 KV_WRITE_PATHS = ("page", "token")   # how a chunk's rows reach the paged pool
-GDN_STEP_PATHS = ("kernel", "expression")   # how a decode step's delta rule runs
+# how a decode step's read-modify-write of a matrix state runs, for the kinds
+# that have a kernel of the repo's own: seldon_llm_<name>_step_path{path}
+STEP_PATHS = ("kernel", "expression")
+STEP_PATH_KINDS = ("gdn", "ssd")
 
 
 LOOP_PHASES = ("admit", "handoff", "dispatch", "prefill", "first_token_wait",
@@ -713,12 +719,14 @@ class LoopPhases:
         self.chunk_rows: Dict[str, int] = {}
         # live rows through the state layers of each kind (a model with
         # layer_types: "conv" the short convolutions, "gdn" the linear-attention
-        # layers), and such layers x calls, from host integers at dispatch
+        # layers, "ssd" the mamba layers), and such layers x calls, from host
+        # integers at dispatch
         self.state_rows = {kind: dict.fromkeys(MOE_PROGRAMS, 0) for kind in STATE_COUNTERS}
         self.state_layer_calls = {kind: dict.fromkeys(MOE_PROGRAMS, 0) for kind in STATE_COUNTERS}
-        # decode step programs built over linear-attention layers, by how their
-        # delta rule runs (the kernel, or the expression's two passes over S)
-        self.gdn_step_path = dict.fromkeys(GDN_STEP_PATHS, 0)
+        # decode step programs built over linear-attention ("gdn") or mamba
+        # ("ssd") layers, by how their rule runs (the kernel, or the
+        # expression's further pass over the state)
+        self.step_path = {kind: dict.fromkeys(STEP_PATHS, 0) for kind in STEP_PATH_KINDS}
         self._open: List[_Phase] = []
         self._open_parts: List[_Phase] = []
         self._turn: Optional[Any] = None   # the open turn's annotation
@@ -803,8 +811,9 @@ class LoopPhases:
             if any(self.state_layer_calls[kind].values()):
                 state[f"{kind}_rows"] = dict(self.state_rows[kind])
                 state[f"{kind}_layer_calls"] = dict(self.state_layer_calls[kind])
-        if any(self.gdn_step_path.values()):
-            state["gdn_step_path"] = dict(self.gdn_step_path)
+        for kind, paths in self.step_path.items():
+            if any(paths.values()):
+                state[f"{kind}_step_path"] = dict(paths)
         return {**state,
                 "loop_seconds": dict(self.seconds),
                 "loop_phase_counts": dict(self.counts),
@@ -1450,12 +1459,13 @@ class ContinuousBatcher:
         self.state_matrix_nbytes, self.state_matrix_tiled_nbytes = (
             kvcache.matrix_state_nbytes(self._caches))
         # the decode step programs seen so far (a new one is counted by the
-        # path its delta rule takes: seldon_llm_gdn_step_path)
+        # path its rule takes: seldon_llm_gdn_step_path / seldon_llm_ssd_step_path)
         self._step_programs: set = set()
         # which state row a chunk's one sequence continues: its slot, as a
         # device array made once (no transfer a chunk)
         self._state_slot = [jnp.asarray([i], jnp.int32) for i in range(self.S)
                             ] if self._state_layers else None
+        self._matrix_state = None    # the program a probe's "state" is read by, once asked
         # the step's read is the XLA gather of the whole view, except on one
         # TPU, where a kernel walks the live pages (ops/page_walk.py) — said
         # here so a server's log names it
@@ -2106,6 +2116,8 @@ class ContinuousBatcher:
                         # graftlint: allow-host-sync-in-hot-path(a probe request only: [chunk, n_moe_layers, k] int32 of a chunk that has finished)
                         took.extend(np.asarray(aside["moe_choice"])[0, :n])
                     slot.routing = rec.info["routing"] = took
+            if rec.info is not None and "state" in rec.info:
+                slot.state = rec.info["state"]
             slot.n_new = 1
             slot.tokens = [first]
             # first token surfaced NOW: time-to-first-token from submit(),
@@ -2744,15 +2756,18 @@ class ContinuousBatcher:
 
         return read_form(self._read_walk(s))
 
-    def _gdn_step_path(self) -> str:
-        """How a decode step's delta rule runs in this process: the repo's
-        kernel (S read once and written once) where the programs are compiled
-        for a TPU and ``gdn_step_walk`` has a plan, the expression elsewhere."""
+    def _state_step_path(self, kind: str) -> str:
+        """How a decode step's delta rule (``kind`` "gdn") or state-space
+        recurrence ("ssd") runs in this process: the repo's kernel (the state
+        read once and written once) where the programs are compiled for a TPU
+        and ``gdn_step_walk`` / ``ssd_step_blocks`` has a plan, the expression
+        elsewhere."""
         import jax
 
-        from seldon_core_tpu.models.transformer import gdn_step_walk
+        from seldon_core_tpu.models.transformer import gdn_step_walk, ssd_step_blocks
 
-        kernel = jax.default_backend() == "tpu" and gdn_step_walk(self.server._cfg) is not None
+        plan = {"gdn": gdn_step_walk, "ssd": ssd_step_blocks}[kind]
+        kernel = jax.default_backend() == "tpu" and plan(self.server._cfg) is not None
         return "kernel" if kernel else "expression"
 
     def _rows_read(self, s: int, live_rows: Sequence[int], sequences: int,
@@ -3103,6 +3118,7 @@ class ContinuousBatcher:
         slot.on_token = None
         slot.logits = None
         slot.routing = None
+        slot.state = None
         slot.ids = None
         slot.tenant = ""
         slot.slo_class = "interactive"
@@ -3248,11 +3264,30 @@ class ContinuousBatcher:
             if consumed:
                 # adopted/deduped pages are no longer this slot's to free
                 slot.pages = [p for p in slot.pages if p not in consumed]
+        if slot.state is not None:
+            self._read_state(i)
         self._release_slot(i)
         if on_token is not None:
             on_token(None)  # stream end sentinel
         if fut is not None:
             self._resolve(fut, result=toks)
+
+    def _read_state(self, i: int):
+        """A probe asked for the state its sequence leaves (transport/rest.py
+        "state"): the first mamba layer's h, as the cache holds it after
+        every program dispatched so far, which have fed the prompt and the
+        first ``disp_new - 1`` sampled tokens: ``tokens`` of them in all (the
+        read is enqueued behind those programs and ahead of any later one)."""
+        import jax
+
+        slot, cfg = self._slots[i], self.server._cfg
+        if self._matrix_state is None:
+            self._matrix_state = jax.jit(
+                lambda caches, rows: kvcache.read_matrix_state(cfg, caches, rows)[0])
+        # graftlint: allow-host-sync-in-hot-path(a probe request only: one sequence's state of one layer, read where the request finishes)
+        state = np.asarray(self._matrix_state(self._caches, self._state_slot[i]))
+        slot.state.update(layer=kvcache.matrix_state_layer(cfg), tokens=slot.dispatched_pos(),
+                          array=state)
 
     # ------------------------------------------------------------------
     # Pipelined decode: dispatch (producer) / drain (consumer)
@@ -3331,9 +3366,11 @@ class ContinuousBatcher:
         with self._phases.part("call"):
             fn = self.server._get_decode_step_paged(
                 self.S, self.n_pages, k, lora=lora)
-            if "gdn" in self._state_layers and (k, lora) not in self._step_programs:
+            if (k, lora) not in self._step_programs:
                 self._step_programs.add((k, lora))
-                self._phases.gdn_step_path[self._gdn_step_path()] += 1
+                for kind in STEP_PATH_KINDS:
+                    if kind in self._state_layers:
+                        self._phases.step_path[kind][self._state_step_path(kind)] += 1
             (self._caches, self._last_tok, self._next_pos, self._keys,
              toks, aside) = fn(
                 self.server._params, self._caches, self._last_tok,

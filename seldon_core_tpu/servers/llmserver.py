@@ -908,8 +908,8 @@ class LLMServer(SeldonComponent):
         logger.info("LLMServer loaded %s (vocab=%d)", name, self._cfg.vocab_size)
 
     def _state_layers_refusal(self) -> Optional[str]:
-        """What is not built over a layer that carries STATE (a conv or a
-        linear-attention layer, cfg.layer_types), by name; None where nothing
+        """What is not built over a layer that carries STATE (a conv, a
+        linear-attention or a mamba layer, cfg.layer_types), by name; None where nothing
         asked for is missing. Each of the first three would restart a sequence
         mid-way, and needs the state AT a token boundary, which pages do not
         hold."""

@@ -425,7 +425,13 @@ def _add_generate_routes(app: web.Application, component: Any,
           processed token took in every MoE layer, out of the same programs:
           {"first_token": i, "shape": [tokens, moe_layers, k], "dtype":
           "int32", "base64": ...} (a reference that follows them is held to
-          the arithmetic and not to how a near-tie fell).
+          the arithmetic and not to how a near-tie fell). "state": true (a
+          probe likewise, of a model with mamba layers) adds the h the first
+          of them holds for the sequence where the request finishes: {"layer":
+          i, "tokens": n, "shape": [H, d_state, d_head], "dtype": "float32",
+          "base64": ...}, a head's h transposed, after the first n tokens of
+          prompt + reply; n is past the reply's last token where steps
+          dispatched ahead for other requests fed tokens nobody was sent.
       {"prompts": [...], ...} — explicit batch, served by one generate().
     No reference counterpart (its servers are request/response classifiers);
     this is the BASELINE.json LLM stretch surface."""
@@ -510,6 +516,17 @@ def _add_generate_routes(app: web.Application, component: Any,
                         "continuous batching, no per-request temperature "
                         "and no speculation", status_code=400)
                 info["logits"] = []   # the batcher appends a row per token
+            if body.get("state"):
+                from seldon_core_tpu.models.cache import matrix_state_layer
+
+                if stream or svc is None or svc.batcher.spec_mode != "off" \
+                        or matrix_state_layer(svc.batcher.server._cfg) is None:
+                    raise SeldonError(
+                        "'state' is a probe of the batched path of a model "
+                        "with mamba layers: a plain (not streamed) request to "
+                        "a server with continuous batching and no speculation",
+                        status_code=400)
+                info["state"] = {}    # the batcher fills it where the request finishes
             if not stream:
                 if svc is not None:
                     toks = await svc.submit(prompt, max_new, info=info,
@@ -554,6 +571,11 @@ def _add_generate_routes(app: web.Application, component: Any,
                             "first_token": info["routing_start"],
                             "shape": list(took.shape), "dtype": "int32",
                             "base64": base64.b64encode(took.tobytes()).decode()}
+                    if info.get("state"):
+                        held = info["state"].pop("array").astype("<f4")
+                        out["state"] = {
+                            **info["state"], "shape": list(held.shape), "dtype": "float32",
+                            "base64": base64.b64encode(held.tobytes()).decode()}
                     return web.json_response(out)
 
             if custom_sampling:

@@ -381,6 +381,33 @@ def test_a_state_that_is_not_square_stays_float32_in_the_caches_layout(name):
             lowering_platforms=("tpu",)).as_text()
 
 
+@pytest.mark.parametrize("name", ["llm.ssd_paged_decode_step_s4",
+                                  "llm.ssd_prefill_chunk_c8"])
+def test_mamba_step_programs_donate_the_state_and_the_step_passes_over_it_once(name):
+    """granite-4.0-h's block at test dims (ISSUE 53): 8 heads of [64, 128]
+    float32 a slot, held transposed two side by side along the lanes,
+    [slots, 4, 128, 128]: whole (8, 128) tiles; the conv rows and h are donated and
+    aliased with the page pool; h is never narrowed to 16 bits; the step lowered
+    for a TPU carries the kernel and NO XLA op over every slot's h (the SAME
+    contract read from the module lowered for the CPU, where the expression
+    serves, reports its passes: the scan sees them when they are there); no
+    transfer."""
+    import dataclasses
+
+    from tools.hlolint.contracts import all_contracts
+
+    (contract,) = [c for c in all_contracts() if c.name == name]
+    reported, *_ = run_one(contract, checks=("alias", "transfer", "dtype",
+                                             "collective"))
+    assert reported == []
+    if "decode" in name:
+        fn, args = contract.build()
+        assert "ssd_step" in fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+        here = dataclasses.replace(contract, lowering_platform=None)
+        reported, *_ = run_one(here, checks=("dtype",))
+        assert len(reported) == 1 and "the expression's further pass" in reported[0].message
+
+
 @pytest.mark.parametrize("name", ["llm.swa_paged_decode_step_s4",
                                   "llm.swa_prefill_chunk_c64"])
 def test_window_layer_step_programs_donate_both_page_classes_and_hold_no_view(name):

@@ -342,3 +342,110 @@ def test_the_state_gauge_and_the_conv_counters_reach_the_registry():
                and line.endswith(f" {CHUNK + 2}.0") for line in lines)
     assert any(line.startswith("seldon_llm_conv_layer_calls_total") and 'program="decode"' in line
                and line.endswith(" 12.0") for line in lines)
+
+
+# ---- a third kind of state: "mamba" layers (granite-4.0-h's Mamba-2 mixer) ----
+# conv rows of [x ; B ; C] and a float32 h a head (held transposed) a slot; the
+# logits against the reference are tests/test_reference_granite_hybrid.py's
+MAMBA_KW = dict(vocab_size=96, dim=32, n_layers=4, n_heads=4, n_kv_heads=2, ffn_dim=48,
+                max_seq_len=96, norm_eps=1e-5, rope_theta=None, dtype="float32",
+                tie_embeddings=True, layer_types=["mamba", "mamba", "full_attention", "mamba"],
+                mamba_n_heads=8, mamba_d_head=8, mamba_d_state=16, embedding_multiplier=12.0,
+                attention_multiplier=0.0625, residual_multiplier=0.22, logits_scaling=8.0)
+
+
+@pytest.fixture(scope="module")
+def mamba_server():
+    return make_server(model_kwargs=MAMBA_KW)
+
+
+async def ask_logits(b, prompt, n=5):
+    info = {"logits": []}
+    out = await b.submit(prompt, max_new_tokens=n, info=info)
+    return out, np.stack(info["logits"])
+
+
+def test_a_reused_slot_of_a_mamba_model_reads_h_as_zeros(mamba_server):
+    """One slot: a long request, then a short one in the same slot. The short
+    one reads neither the h nor the conv rows the long one left, and nothing
+    was reset at admission."""
+    long_, short = LONG[:2 * CHUNK + 5], LONG[20:23]
+
+    async def go(first):
+        b = batcher(mamba_server, max_slots=1)
+        if first:
+            await b.submit(first, max_new_tokens=9)
+        got = await ask_logits(b, short)
+        await b.close()
+        return got
+
+    fresh, reused = asyncio.run(go(None)), asyncio.run(go(long_))
+    assert fresh[0] == reused[0]
+    np.testing.assert_array_equal(fresh[1], reused[1])
+
+
+@pytest.mark.parametrize("kv_cache_dtype", ["bf16", "int8"])
+def test_batcher_tokens_equal_generate_over_mamba_layers(kv_cache_dtype):
+    s = make_server(model_kwargs=MAMBA_KW, kv_cache_dtype=kv_cache_dtype, temperature=0.8,
+                    top_k=20, seed=5)
+    prompts = [LONG[:3], LONG[5:5 + CHUNK + 2], [7], LONG[1:1 + 2 * CHUNK + 3]]
+    seeds = [42, 1234, 7, 99]
+    expected = [s.generate([p], max_new_tokens=8, seed=sd)["tokens"][0]
+                for p, sd in zip(prompts, seeds)]
+
+    async def go():
+        b = batcher(s)
+        outs = await asyncio.gather(*[b.submit(p, max_new_tokens=8, seed=sd)
+                                      for p, sd in zip(prompts, seeds)])
+        await b.close()
+        return outs
+
+    assert asyncio.run(go()) == expected
+
+
+def test_a_shed_request_over_mamba_layers_sent_again_repeats_its_tokens(mamba_server):
+    p1, p2 = LONG[:4], LONG[8:12]
+    want = mamba_server.generate([p2], max_new_tokens=24)["tokens"][0]
+
+    async def go():
+        b = batcher(mamba_server, max_slots=2, max_len=32, pool_pages=10)
+        t1 = asyncio.ensure_future(b.submit(p1, max_new_tokens=24))
+        await asyncio.sleep(0)
+        t2 = asyncio.ensure_future(b.submit(p2, max_new_tokens=24))
+        first = await asyncio.gather(t1, t2, return_exceptions=True)
+        again = await b.submit(p2, max_new_tokens=24)
+        await b.close()
+        return first, again
+
+    (r1, r2), again = asyncio.run(go())
+    assert isinstance(r2, ShedError) and r2.status_code == 503
+    assert r1 == mamba_server.generate([p1], max_new_tokens=24)["tokens"][0]
+    assert again == want
+
+
+@pytest.mark.parametrize("what", sorted(REFUSALS))
+def test_what_is_not_built_over_a_mamba_layer_is_refused_at_load(what):
+    kwargs, names = REFUSALS[what]
+    s = LLMServer(**{**dict(model="transformer", model_kwargs=MAMBA_KW, init_random=True), **kwargs})
+    # (a mesh is refused where the config is made, before load() looks: the same "no")
+    match = "'mamba' layer.*a mesh" if what == "tensor_parallel" else "mamba layers.*" + names
+    with pytest.raises(ValueError, match=match):
+        s.load()
+
+
+def test_the_cache_trees_hold_a_mamba_layers_two_arrays_and_the_page_operations_hand_them_on(
+        mamba_server):
+    cfg = mamba_server._cfg
+    _, _, reset_pages, _, _, cow_page_copy, export_pages, _ = _page_table_ops()
+    tree = init_paged_kv_caches(cfg, 10, 4, state_slots=3)
+    assert [is_state_entry(layer) for layer in tree] == [True, True, False, True]
+    assert [a.shape for a in tree[0]] == [(3, 3, 96), (3, 8, 16, 8)]     # h a head transposed
+    assert [a.dtype for a in tree[0]] == [jnp.float32, jnp.float32]      # (the serving dtype here)
+    tree = [type(layer)(a + 1.5 for a in layer) if is_state_entry(layer) else layer for layer in tree]
+    state = [np.asarray(a) for layer in tree if is_state_entry(layer) for a in layer]
+    tree = reset_pages(tree, jnp.asarray([2, 3, 1, 1]))
+    tree = cow_page_copy(tree, jnp.asarray(2), jnp.asarray(3), jnp.asarray(2))
+    after = [np.asarray(a) for layer in tree if is_state_entry(layer) for a in layer]
+    assert all((a == b).all() for a, b in zip(after, state))
+    exported = export_pages(tree, jnp.asarray([2, 3]))
+    assert len(exported) == 1 and exported[0][0].shape[0] == 2      # the attention layer's pages alone

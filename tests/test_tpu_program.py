@@ -128,7 +128,17 @@ SMALLTHINKER = dict(vocab_size=256, dim=2560, n_layers=4, n_heads=28, n_kv_heads
                     rope_theta=1.5e6, rope_layout=(0, 1, 1, 1), sliding_window=4096,
                     layer_types=("full_attention",) + ("sliding_attention",) * 3,
                     ffn_act="relu", router_input="layer_input")
-CONFIGS = {"smallthinker": SMALLTHINKER, "olmohybrid": OLMOHYBRID, "mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
+# granite-4.0-h-micro's kinds of layer at published widths: three Mamba-2 layers
+# (64 heads of 64, a float32 state [64, 128] a head, one group, four taps + a
+# bias over 4,352 channels) and one GQA layer (32 query / 8 KV heads of 64, no
+# position, softmax scale 1/64), the four scalar multipliers, the table tied
+GRANITE4H = dict(vocab_size=256, dim=2048, n_layers=4, n_heads=32, n_kv_heads=8, ffn_dim=8192,
+                 max_seq_len=2048, dtype="bfloat16", norm_eps=1e-5, rope_theta=None,
+                 tie_embeddings=True, mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+                 embedding_multiplier=12.0, attention_multiplier=0.015625,
+                 residual_multiplier=0.22, logits_scaling=8.0,
+                 layer_types=("mamba", "mamba", "full_attention", "mamba"))
+CONFIGS = {"granite4h": GRANITE4H, "smallthinker": SMALLTHINKER, "olmohybrid": OLMOHYBRID, "mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
            "xing4": XING4, "mistral_vocab": MISTRAL_VOCAB, "lfm2": LFM2, "qwen3next": QWEN3NEXT}
 PAGE, POOL_PAGES = 64, 514
 
@@ -1173,3 +1183,76 @@ def test_a_state_that_is_not_square_lies_unpadded_and_goes_through_the_kernel(
         assert "gated_delta_step" not in hlo
         # the chunk's one sequence: no op yields every slot's S a head a row
         assert [op for op in own_ops(hlo) if op[2] == a_head] == []
+
+
+GRANITE4H_CELL = (96, 2048)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_a_mamba_layers_state_goes_through_the_kernel_once_a_layer_in_its_own_buffer(
+        v5e, servers, program):
+    """granite-4.0-h-micro's kinds of layer at published widths and the cell's
+    own shapes (96 slots x 2,048 tokens): each mamba layer's float32 state is
+    ``[96, 32, 128, 128]`` (a head's [64, 128] transposed, two heads side by side
+    along the lanes: 201 MB, whole (8, 128) tiles, the model's own bytes), a
+    parameter that an output aliases; the STEP carries the recurrence's kernel
+    (ops/ssd.py), once a mamba layer, and no op of its own yields a whole h (the
+    expression read it again for h C); the chunk continues ONE slot's h; h is
+    float32 everywhere; the float32 leaves stay float32; the attention read of
+    heads of 64 walks the live pages."""
+    from seldon_core_tpu.models.cache import init_paged_kv_caches, matrix_state_nbytes
+    from seldon_core_tpu.ops.quantize import QuantizedTensor
+
+    server = servers("granite4h")
+    cfg = server._cfg
+    slots, length = GRANITE4H_CELL
+    pages = slots * length // PAGE + 2
+    assert cfg.state_layers == (0, 1, 3) and cfg.rope_theta is None and cfg.tie_embeddings
+    mamba = server._params["params"]["layer_0"]["mamba"]
+    for name, shape in (("in_proj", (2048, 8512)), ("out_proj", (4096, 2048))):
+        assert isinstance(mamba[name], QuantizedTensor) and mamba[name].q.shape == shape, name
+    small = {name: mamba[name] for name in ("conv1d", "conv_bias", "heads")}
+    small["norm"] = mamba["norm"]["weight"]
+    assert {k: (v.dtype.name, v.shape) for k, v in small.items()} == {
+        "conv1d": ("float32", (4352, 4)), "conv_bias": ("float32", (4352,)),
+        "heads": ("float32", (3, 64)), "norm": ("float32", (4096,))}
+    assert "lm_head" not in server._params["params"]
+    tree = jax.eval_shape(lambda: init_paged_kv_caches(cfg, pages, PAGE, "bf16", state_slots=slots))
+    state, a_head = (slots, 32, 128, 128), ((slots, 64, 128, 64), (slots, 64, 64, 128))
+    assert [leaf.shape for leaf in tree[0]] == [(slots, 3, 4352), state]
+    assert tree[0][1].dtype == jnp.float32
+    own, tiled = matrix_state_nbytes(tree)
+    assert own == tiled == 3 * slots * 64 * 64 * 128 * 4
+    exe = compiled(server, program, v5e, slots=slots, length=length)
+    hlo = exe.as_text()
+    entry = hlo[hlo.index("\nENTRY"):]
+    assert re.search(r"%pools_0__1_[\w.]* = f32\[96,32,128,128\]\{3,2,1,0:T\(8,128\)", entry)
+    leaves = {m.group(2): int(m.group(3)) for m in re.finditer(
+        r"%(pools_(\d__\d)_)[\w.]* = \S+ parameter\((\d+)\)", entry)}
+    aliased = {int(n) for n in re.findall(r"\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo)}
+    assert len(leaves) == 9 and set(leaves.values()) <= aliased, (leaves, aliased)
+    # h in 16 bits nowhere
+    assert [op for op in own_ops(hlo) if op[1] in ("bf16", "f16") and op[2][1:] in (
+        state[1:], a_head[0][1:], a_head[1][1:])] == []
+    assert not re.search(r"\b(infeed|outfeed|send|recv|send-done|recv-done)\(", hlo)
+    for scope in ("mix.ssd.in", "mix.ssd.conv", "mix.ssd.rule", "mix.ssd.out"):
+        assert all(f"layer_{i}/mamba/{scope}/" in hlo for i in cfg.state_layers), scope
+    assert "gqa_page_attention" in hlo
+    if program == "decode_step":
+        assert len(re.findall(r"= \([^=]*\) custom-call\([^\n]*ssd_step", hlo)) == 3
+        # no arithmetic over every slot's h outside the kernel (201 MB a layer:
+        # nothing stages it through on-chip memory either)
+        through = [op for op in own_ops(hlo) if op[1] == "f32" and op[2] in (
+            state, (slots, 2, 16, 128, 128), *a_head) and op[3] not in (
+                "parameter", "bitcast", "get-tuple-element", "copy-start", "copy-done")
+            and "ConcatBitcast" not in op[4]]
+        assert through == [], through
+        # no gathered view of K or V, and no second copy of a layer's h
+        assert exe.memory_analysis().temp_size_in_bytes < slots * 64 * 64 * 128 * 4
+    else:
+        assert not re.search(r"custom-call\([^\n]*ssd_step", hlo)
+        # the chunk's one sequence: the only op over every slot's h is the
+        # in-place update of ONE slot's rows, and none holds it a head a row
+        assert {op[3] for op in own_ops(hlo) if op[1] == "f32" and op[2] == state} <= {
+            "parameter", "bitcast", "get-tuple-element", "dynamic-update-slice"}
+        assert [op for op in own_ops(hlo) if op[2] in a_head] == []

@@ -380,6 +380,41 @@ def _gdn_rect_server():
         return _STATE["gdn_rect_server"]
 
 
+# granite-4.0-h's block at test dims: Mamba-2 layers whose state a head is
+# [64, 128] as the published model's (8 heads in one group; held transposed,
+# two heads side by side along the lanes: four units of [128, 128], whole
+# (8, 128) float32 tiles, models/cache.py), beside a GQA layer without position at a softmax scale of the
+# config's own; the four scalar multipliers, the tied table, a dense FFN
+SSD_HEADS = (8, 64, 128)        # heads, head dim, state dim
+SSD_SIDE = 2                    # heads a lane row: 2 x 64 = one lane tile
+
+
+def _ssd_server():
+    with _STATE_LOCK:
+        if "ssd_server" not in _STATE:
+            ensure_platform()
+            from seldon_core_tpu.servers.llmserver import LLMServer
+
+            heads, p, n = SSD_HEADS
+            s = LLMServer(
+                model="transformer",
+                model_kwargs=dict(
+                    vocab_size=96, dim=GDN_DIM, n_layers=3, n_heads=4,
+                    n_kv_heads=2, ffn_dim=MOE_WIDTH,
+                    max_seq_len=PAGES_PER_SLOT * PAGE_SIZE, rope_theta=None,
+                    tie_embeddings=True, embedding_multiplier=12.0,
+                    attention_multiplier=0.015625, residual_multiplier=0.22,
+                    logits_scaling=8.0, mamba_n_heads=heads, mamba_d_head=p,
+                    mamba_d_state=n,
+                    layer_types=("mamba", "full_attention", "mamba"),
+                    dtype="bfloat16"),
+                quantize="int8", init_random=True, len_buckets=(PLEN,),
+                seed=7)
+            s.load()
+            _STATE["ssd_server"] = s
+        return _STATE["ssd_server"]
+
+
 def _paged_batcher():
     with _STATE_LOCK:  # nests into _base_server's hold: RLock
         if "paged_batcher" not in _STATE:
@@ -571,6 +606,27 @@ GDN_RECT_UNPACKED_STATE = (
     "lane in HBM) and the step's kernel reads and writes it as it lies; this "
     "array is a re-laid copy of every slot's state a layer a step (the "
     "expression's two passes over S, or an unpack around the kernel)")
+
+
+_SSD_STATE = f"{SSD_HEADS[0] // SSD_SIDE}x{SSD_HEADS[2]}x{SSD_SIDE * SSD_HEADS[1]}"
+_SSD_A_HEAD = (f"({SSD_HEADS[0]}x{SSD_HEADS[2]}x{SSD_HEADS[1]}|"
+               f"{SSD_HEADS[0]}x{SSD_HEADS[1]}x{SSD_HEADS[2]})")
+SSD_NARROW_STATE = (
+    rf"tensor<({SLOTS}|1)x({_SSD_STATE}|{_SSD_A_HEAD})x(bf16|f16)>",
+    "the mamba layers' matrix state (in the cache's layout or a head a row) in a 16-bit "
+    "type: h is held, decayed and added to in float32 (as the published "
+    "implementation holds it); a narrowed copy is a rounding of every slot's state a "
+    "layer a call")
+SSD_STATE_PASS = (
+    rf"stablehlo\.(multiply|add|select|reduce|gather|dynamic_slice|dynamic_update_slice)\b"
+    rf"[^\n]*tensor<{SLOTS}x({_SSD_STATE}|{_SSD_A_HEAD})xf32>",
+    "an XLA op over every slot's h (as the cache lays it, [slots, heads / 2, state "
+    "dim, 2 x head dim], or a head a row): in a step lowered for a TPU the recurrence "
+    "is the repo's kernel (ops/ssd.py), which reads each slot's h once and writes it "
+    "once in its own buffer AS IT LIES; a multiply, an add, a reduction or a gather "
+    "over the whole block is the expression's further pass (h C read again), an "
+    "unpack around the kernel or a gathered copy of the state, 2 MB a slot a layer "
+    "a step")
 
 
 # SmallThinker's block at dims the live-page kernel takes (16 query / 2 KV heads
@@ -775,6 +831,24 @@ def _build_gdn_rect_paged_decode_step():
 
 def _build_gdn_rect_prefill_chunk():
     s = _gdn_rect_server()
+    fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
+    return fn, (s._params, _pool_specs_of(s),
+                _sds((1, PAGES_PER_SLOT), "int32"),
+                _sds((1, PAGE_SIZE), "int32"), _sds((1, PAGE_SIZE), "int32"),
+                _sds((), "int32"), _sds((1,), "int32"))
+
+
+def _build_ssd_paged_decode_step():
+    s = _ssd_server()
+    fn = s._get_decode_step_paged(SLOTS, PAGES_PER_SLOT, 1)
+    return fn, (s._params, _pool_specs_of(s), _sds((SLOTS,), "int32"),
+                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
+                _sds((), "float32"),
+                _sds((SLOTS, PAGES_PER_SLOT), "int32"))
+
+
+def _build_ssd_prefill_chunk():
+    s = _ssd_server()
     fn = s._get_prefill_chunk(PAGE_SIZE, PAGES_PER_SLOT)
     return fn, (s._params, _pool_specs_of(s),
                 _sds((1, PAGES_PER_SLOT), "int32"),
@@ -1307,6 +1381,36 @@ def all_contracts() -> List[Contract]:
             build=_build_gdn_rect_prefill_chunk,
             donated=(1,),
             forbid_dtypes=(GDN_RECT_NARROW_STATE, GDN_RECT_UNPACKED_STATE),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.ssd_paged_decode_step_s4",
+            description="PAGED decode step of a model with mamba layers "
+                        "(granite-4.0-h's block: conv rows and a float32 "
+                        "[64, 128] state a head a slot beside the pages of a "
+                        "position-free GQA layer; four scalar multipliers, a "
+                        "tied int8 table): both state arrays are donated with "
+                        "the pools; lowered for a TPU the recurrence is the "
+                        "kernel, h read once and written once a layer, no XLA "
+                        "op and no gathered copy over the whole block",
+            build=_build_ssd_paged_decode_step,
+            donated=(1, 3, 4),
+            forbid_dtypes=(SSD_NARROW_STATE, SSD_STATE_PASS),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.ssd_prefill_chunk_c8",
+            description="chunked admission prefill of the same model: the "
+                        "chunk runs the recurrence's chunked form, continues "
+                        "ONE slot's two state arrays in float32 and writes "
+                        "them back into the donated blocks",
+            build=_build_ssd_prefill_chunk,
+            donated=(1,),
+            forbid_dtypes=(SSD_NARROW_STATE,),
             lowering_platform="tpu",
             collectives={},
             cost=True,
