@@ -23,7 +23,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -77,6 +77,15 @@ FUSED_NORM_STREAMS_REFUSAL = (
     "kernel fuses the residual ADD with the ffn norm, and with several streams "
     "the block has no such add (TransformerBlock writes back through H_res / "
     "H_post); serve it with fused_norm off")
+
+
+class LayerReads(NamedTuple):
+    """``TransformerConfig.layer_reads``: what a block reads of its number."""
+
+    kind: str      # the token mixer's: one of LAYER_KINDS
+    window: int    # the keys a query sees; 0 = all before it
+    rotary: bool   # q and k are turned by their positions
+    routed: bool   # the FFN routes experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -392,6 +401,21 @@ class TransformerConfig:
     def layer_window(self, layer: int) -> int:
         """The keys a query of ``layer`` sees, itself included; 0 = all before it."""
         return self.sliding_window if self.layer_kind(layer) == "sliding_attention" else 0
+
+    def layer_reads(self, layer: int) -> "LayerReads":
+        """ALL that ``TransformerBlock`` reads of its number: the token mixer's
+        kind, the window and the rotary embedding of its attention, and whether
+        its FFN routes experts (dense under ``first_dense_layers``)."""
+        return LayerReads(self.layer_kind(layer), self.layer_window(layer),
+                          self.layer_rotary(layer),
+                          self.n_experts > 0 and layer >= self.first_dense_layers)
+
+    def layer_class(self, layer: int) -> int:
+        """The FIRST layer that ``TransformerBlock`` builds as it builds
+        ``layer`` (the same ``layer_reads``). A program traces a block once a
+        class (``SharedBlock``): Mistral has one, a hybrid two or three."""
+        mine = self.layer_reads(layer)
+        return next(i for i in range(layer + 1) if self.layer_reads(i) == mine)
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
@@ -1933,8 +1957,8 @@ class TransformerBlock(nn.Module):
     streams and writes back through its HyperConnection."""
 
     cfg: TransformerConfig
-    # decides the FFN's kind (cfg.first_dense_layers) and the token mixer's
-    # (cfg.layer_kind)
+    # read through cfg.layer_reads alone: the token mixer's kind, its window
+    # and rotary embedding, the FFN's kind
     layer: int = 0
 
     @nn.compact
@@ -1946,7 +1970,7 @@ class TransformerBlock(nn.Module):
         streams = cfg.hc_mult > 1
         if streams:
             X, (x, h_post, h_res) = x, HyperConnection(cfg, name="attention_hc")(x)
-        kind = cfg.layer_kind(self.layer)
+        kind, window, rotary, routed = cfg.layer_reads(self.layer)
         # what the router multiplies where it reads the block's INPUT: nothing
         # is computed here, MoEFFN is handed the array
         router_in = x if cfg.router_input == "layer_input" else None
@@ -1976,8 +2000,7 @@ class TransformerBlock(nn.Module):
             # a pair of tables (full, window): each layer reads its class's
             if isinstance(block_tables, tuple):
                 block_tables = block_tables[kind == "sliding_attention"]
-            own = {} if cfg.kv_lora_rank else {
-                "window": cfg.layer_window(self.layer), "rotary": cfg.layer_rotary(self.layer)}
+            own = {} if cfg.kv_lora_rank else {"window": window, "rotary": rotary}
             with jax.named_scope("attn"):
                 h, new_cache = attention(cfg, name="attention", **own)(
                     mixer_in(), positions, cache,
@@ -2003,7 +2026,7 @@ class TransformerBlock(nn.Module):
         else:
             x = x + h
             ffn_in = x if branch else ffn_norm(x)
-        if cfg.n_experts > 0 and self.layer >= cfg.first_dense_layers:
+        if routed:
             f = MoEFFN(cfg, name="moe")(ffn_in, valid, router_in)
         else:
             width = cfg.dense_ffn_dim if cfg.n_experts > 0 else 0
@@ -2015,6 +2038,47 @@ class TransformerBlock(nn.Module):
         if streams:
             return hc_write_back(X, f, h_post, h_res), new_cache
         return x + f, new_cache
+
+
+# the collections a block's modules sow into (MoEFFN's routing counters)
+BLOCK_SOWS = ("moe",)
+
+
+@partial(jax.jit, static_argnames=("cfg", "layer", "sowing", "rules"))
+def transformer_block(params, *args, cfg: TransformerConfig, layer: int, sowing: tuple,
+                      rules: tuple):
+    """``TransformerBlock(cfg, layer)`` over ONE layer's parameter subtree:
+    ``((x, new_cache), sown)``. A jitted function of its own, as
+    ``paged_live_read`` and ``ssd`` are one level down, so the layers of a
+    class (``cfg.layer_class``) share ONE trace of it in whatever program calls
+    them, and that program lowers to calls of one function which the compiler
+    inlines: a Mistral program's 32 blocks cost the Python of one. ``rules`` are
+    flax's logical axis rules as the caller's thread has them (they are no part
+    of jax's own key for a trace)."""
+    with nn_partitioning.axis_rules(rules):
+        # (a module of its own tree: made inside a module's method, it would be a child)
+        return TransformerBlock(cfg, layer, parent=None).apply(
+            {"params": params}, *args, mutable=list(sowing))
+
+
+class SharedBlock(nn.Module):
+    """Layer ``layer_{i}`` of a model that is not being initialised: its
+    parameters, where ``TransformerBlock`` of that name put them, go through
+    ``transformer_block`` with ``layer`` = the layer's CLASS, and what the block
+    sowed is put where the block would have sown it (``layer_{i}/moe/...``)."""
+
+    cfg: TransformerConfig
+    layer: int = 0
+
+    def __call__(self, *args):
+        sowing = tuple(c for c in BLOCK_SOWS if self.is_mutable_collection(c))
+        out, sown = transformer_block(
+            self.variables["params"], *args, cfg=self.cfg, layer=self.layer, sowing=sowing,
+            rules=tuple(nn_partitioning.get_axis_rules()))
+        for collection, modules in sown.items():
+            for name, leaves in modules.items():
+                self.put_variable(collection, name, leaves)
+        return out
 
 
 def enter_streams(x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
@@ -2134,9 +2198,11 @@ class Transformer(nn.Module):
                     for proj, ab in adapters.items() if proj != "scale"
                 }
                 layer_adapters["scale"] = adapters["scale"]
-            x, nc = TransformerBlock(cfg, i, name=f"layer_{i}")(
-                x, positions, layer_cache, cache_index, block_tables,
-                layer_adapters, adapter_ids, valid, state_slots)
+            # (an initialisation makes each layer's parameters where they lie)
+            block = (TransformerBlock(cfg, i, name=f"layer_{i}") if self.is_initializing()
+                     else SharedBlock(cfg, cfg.layer_class(i), name=f"layer_{i}"))
+            x, nc = block(x, positions, layer_cache, cache_index, block_tables,
+                          layer_adapters, adapter_ids, valid, state_slots)
             new_caches.append(nc)
         hidden = leave_streams(x, cfg)
         x = RMSNorm(cfg.dim, cfg.norm_eps, name="norm")(hidden).astype(jnp.float32)

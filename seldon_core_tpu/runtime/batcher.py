@@ -145,8 +145,8 @@ with page-exhaustion shedding (503 + Retry-After, runtime/resilience.py
 ShedError) as the relief valve — the decode loop never raises. The chunked
 admission (``prefill_chunk``; Sarathi-Serve, Agrawal et al., OSDI 2024) means
 a 2k-token prompt never stalls in-flight decodes for a whole prompt's forward
-(a chunk is wider while no other slot streams, for a model that routes
-experts: ``_chunk_width``).
+(a chunk is wider while more than a wide chunk is left of the prompt and no
+other live slot streams: ``_chunk_width``).
 Page bookkeeping is host-side (PageAllocator, lock-guarded); block-table
 updates are jitted device ops that serialize behind in-flight steps in device
 program order.
@@ -186,9 +186,11 @@ DEFAULT_PREFILL_CHUNK = 256
 # ... and the rows of a WIDE chunk, which a prompt's next chunk is while more
 # than that many of its rows are left and no other live slot streams
 # (``ContinuousBatcher._chunk_width``): a chunk streams every weight it touches
-# once whatever its rows, and a routed expert sees a few dozen of 256 rows
-# (docs/performance.md "Chunked prefill"). One constant, so two chunk programs
-# a server; a multiple of the delta rule's 64-row sub-chunks and of the page
+# once whatever its rows, a routed expert sees a few dozen of 256 rows, and the
+# decode step that follows each chunk streams them all once more for the few
+# slots that decode beside it (docs/performance.md "Chunked prefill"). One
+# constant, so two chunk programs a server whose slots are longer than it; a
+# multiple of the delta rule's 64-row sub-chunks and of the page
 WIDE_PREFILL_CHUNK = 1024
 
 
@@ -205,16 +207,12 @@ def pow2_bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
-def _load_program(lowered, what: str, after: Optional[threading.Thread]) -> None:
-    """Compile a lowered step program (``what``, for the log), or load it from
-    the compile cache, once ``after`` has ended (two loads side by side took as
-    long as one after the other, and slowed whatever else loaded then: v5e,
-    PR 48): a thread's whole job (``ContinuousBatcher._build_chunk_programs``);
-    it touches no batcher."""
-    if after is not None:
-        after.join()
+def _build_program(fn, shapes: tuple, what: str) -> None:
+    """Trace, lower and compile a step program (``what``, for the log) for
+    ``shapes``, or load it from the compile cache: a thread's whole job
+    (``ContinuousBatcher._build_wide_program``); it touches no batcher."""
     try:
-        lowered.compile()
+        fn.lower(*shapes).compile()
     except Exception:   # the call that needs the program builds it, and raises what is wrong
         logger.warning("%s was not built ahead of its first call", what, exc_info=True)
 
@@ -1321,15 +1319,18 @@ class ContinuousBatcher:
         chunk = int(prefill_chunk if prefill_chunk is not None else
                     getattr(server, "prefill_chunk", 0) or 0)
         self.prefill_chunk = chunk or DEFAULT_PREFILL_CHUNK
-        # a width somebody gave is every chunk's; the default widens where the
-        # model routes experts: a second chunk program costs a server's start
-        # seconds (a Mistral server's warm start 5 of 33), which a model whose
-        # chunk deals its rows out over many experts' weights buys back, and
-        # which was not asked of the dense servers (PERF.md section 6, PR 48)
-        self.prefill_wide = 0 if chunk or not cfg.n_experts else WIDE_PREFILL_CHUNK
-        # rows -> the thread that loads that chunk program (``_build_chunk_programs``);
-        # None before the first chunk
-        self._chunk_loads: Optional[Dict[int, threading.Thread]] = None
+        # a width somebody gave is every chunk's; the default widens, whatever
+        # the model (PRs 48-53: only where it routes experts, for what a second
+        # chunk program cost a dense server's start; PERF.md section 6, PR 54)
+        self.prefill_wide = 0 if chunk else WIDE_PREFILL_CHUNK
+        # the wide program is the one program no request needs (a narrow chunk
+        # does what it does), so no start pays for it: a thread of its own
+        # builds it once a request has FINISHED, so behind every program a
+        # request waits for (``_build_wide_program``), and a chunk is wide once
+        # it is there (a seeded request's waits for it). The abstract arguments
+        # of a chunk call, kept from the first chunk, and the thread
+        self._chunk_shapes: Optional[tuple] = None
+        self._wide_build: Optional[threading.Thread] = None
         self._allocator = PageAllocator(self.pool_pages, ps)
         # The WINDOW class (a model with sliding-attention layers,
         # cfg.window_layers): a second pool, allocator and block table a slot,
@@ -1914,8 +1915,8 @@ class ContinuousBatcher:
         self._wakeup.set()
         if self._task is not None:
             await self._task
-        for load in (self._chunk_loads or {}).values():
-            await asyncio.to_thread(load.join)
+        if self._wide_build is not None:
+            await asyncio.to_thread(self._wide_build.join)
         if self._remote is not None:
             # bounded worker joins (runtime/disagg.py close uses timeouts);
             # workers first — their last frames must land before the
@@ -2617,24 +2618,20 @@ class ContinuousBatcher:
                 block_row = (job.bt_row, jnp.asarray(job.wrow[None, :].copy()))
         builds = self._ledger.thread_builds()
         with self._phases.part("call"):
+            if C == self.prefill_wide and self._wide_build is not None:
+                self._wide_build.join()     # (a seeded request's: ``_chunk_width``; ended for any other)
             if self._adapters is not None:
                 fn = self.server._get_prefill_chunk(C, self.n_pages, lora=True)
                 aid = job.req.adapter_id if job.req is not None else 0
-                logits, self._caches, aside = fn(
-                    self.server._params, self._caches, block_row,
-                    toks, pos, head_row, self._adapters.pool(),
-                    jnp.asarray([aid], jnp.int32))
+                extra = (self._adapters.pool(), jnp.asarray([aid], jnp.int32))
             else:
                 fn = self.server._get_prefill_chunk(C, self.n_pages)
                 # a model with conv layers: the chunk continues ITS slot's state
                 extra = () if self._state_slot is None else (self._state_slot[job.slot],)
-                args = (self.server._params, self._caches, block_row, toks, pos,
-                        head_row, *extra)
-                if self._chunk_loads is None and self.max_len - 1 > self.prefill_wide > 0:
-                    self._build_chunk_programs(C, job.chunk, args)
-                if self._chunk_loads and (load := self._chunk_loads.pop(C, None)):
-                    load.join()     # its thread loads it: not this call too
-                logits, self._caches, aside = fn(*args)
+            args = (self.server._params, self._caches, block_row, toks, pos, head_row, *extra)
+            if self._chunk_shapes is None:
+                self._chunk_shapes = self._shapes_of(args)
+            logits, self._caches, aside = fn(*args)
         job.next = start + n
         self._phases.chunk_head[str(int(last))] += 1
         self._phases.chunk_rows[str(C)] = self._phases.chunk_rows.get(str(C), 0) + n
@@ -2668,25 +2665,9 @@ class ContinuousBatcher:
         program = self._ledger.built_since(mark)
         return {"built": program} if program else {}
 
-    def _build_chunk_programs(self, width: int, narrow: int, args: tuple) -> None:
-        """A batcher's FIRST chunk (``width`` rows, called with ``args``) builds
-        both of its chunk programs, the wide one and the ``narrow`` one, so that
-        the second costs a start next to nothing: this thread traces and lowers
-        one after the other (Python, which threads do not share), and a thread
-        each compiles them, or loads them from the compile cache, one after the
-        other (the compiler's and the runtime's C++): the second program is
-        traced while the first one loads, and loads while the first request
-        goes on to its first token and the decode step's trace. The calls that
-        follow find lowering and executable in jax's own caches (shapes and
-        placements are the calls' own) but NOT the trace: a program's first
-        call traces it once more (the start ledger's count, PR 51: two chunk
-        programs, four ``trace`` legs, two ``lower``, two loads; 2.7 s a trace
-        in dsv2lite, 7.9 s in xing4); a call whose program still loads
-        waits for it (``_chunk_loads``). Built when first called, one behind the
-        other, the second program cost a warm start of the Mistral servers
-        4.9-5.4 s of their 33-39 s (trace 1.8, to MLIR 0.5, load 2.5-3.1:
-        PERF.md section 6, PR 48). Nothing runs here and no array is read.
-        ``close()`` joins the threads."""
+    @staticmethod
+    def _shapes_of(args: tuple) -> tuple:
+        """The abstract form of a call's arguments (nothing is read)."""
         import jax
 
         def shape(x):   # (an array nobody placed resolves like a bare shape)
@@ -2694,30 +2675,60 @@ class ContinuousBatcher:
             return jax.ShapeDtypeStruct(np.shape(x), np.result_type(x),
                                         sharding=x.sharding if placed else None)
 
-        shapes = jax.tree.map(shape, args)
-        self._chunk_loads, before = {}, None
-        for rows in (width, narrow if width == self.prefill_wide else self.prefill_wide):
-            rows_of = jax.ShapeDtypeStruct((1, rows), np.int32)
-            lowered = self.server._get_prefill_chunk(rows, self.n_pages).lower(
-                *shapes[:3], rows_of, rows_of, *shapes[5:])
-            before = threading.Thread(
-                target=_load_program, args=(lowered, f"prefill_chunk of {rows} rows", before),
-                name=f"chunk-{rows}-load", daemon=True)
-            before.start()
-            self._chunk_loads[rows] = before
+        return jax.tree.map(shape, args)
+
+    def _build_wide_program(self) -> None:
+        """Start the build of the WIDE chunk program, once, when a request has
+        finished (``_finish``): a thread traces, lowers and compiles it (or
+        loads it from the compile cache) for the shapes ``_prefill_step`` calls
+        it with, while the loop goes on with narrow chunks; jax's own caches
+        then serve its calls (lowering and executable: shapes and placements
+        are the calls' own; a program's first call traces it once more, which
+        costs a program whose layers share one trace of their block a few
+        tenths of a second).
+
+        WHEN, and why not at the first chunk (PRs 48-53 built both programs
+        there): a program's load from the compile cache is seconds, 5.5 for a
+        Mistral chunk program on a v5e's host, and two loads side by side take
+        as long as one behind the other, so whatever this one stands ahead of
+        in that queue waits for it: built at the first chunk it put 5-8 s into
+        the start of a server whose first prompt was long (rerank: +14 to +24 %
+        of 35 s), and it would stand ahead of the decode step's, a start's
+        longest load (granite: 71 s of loads). Nobody waits for this program, so
+        it goes LAST: a request that has finished has had every program it
+        needs built, whether the server decodes or not (PERF.md section 6,
+        PR 54). ``close()`` joins the thread. Nothing runs here and no array is
+        read."""
+        import jax
+
+        if (self._wide_build is not None or self._chunk_shapes is None
+                or not self.max_len - 1 > self.prefill_wide > 0):
+            return
+        rows, shapes = self.prefill_wide, self._chunk_shapes
+        rows_of = jax.ShapeDtypeStruct((1, rows), np.int32)
+        fn = self.server._get_prefill_chunk(rows, self.n_pages, lora=self._adapters is not None)
+        self._wide_build = threading.Thread(
+            target=_build_program, name=f"chunk-{rows}-build", daemon=True,
+            args=(fn, (*shapes[:3], rows_of, rows_of, *shapes[5:]), f"prefill_chunk of {rows} rows"))
+        self._wide_build.start()
 
     def _chunk_width(self, job: _PrefillJob) -> int:
         """Rows of the job's NEXT chunk, chosen when it is built from what the
         loop sees: the wide program while more than its rows are left of the
         prompt (so a wide chunk is always full, and the prompt's last chunk,
-        whose row the head reads, is the narrow program) and no OTHER live
-        slot streams (a stream's gap stays a step and one narrow chunk; the
-        job's own stream only gets its first token sooner); the job's own
-        width otherwise."""
-        wide = self.prefill_wide
-        if job.L - job.next > wide > 0 and not any(
-                s.active and s.on_token is not None
-                for i, s in enumerate(self._slots) if i != job.slot):
+        whose row the head reads, is the narrow program), no OTHER live slot
+        streams (a stream's gap stays a step and one narrow chunk; the job's
+        own stream only gets its first token sooner) and the wide program is
+        THERE (``_build_wide_program``); the job's own width otherwise. While
+        the program is being built a request takes narrow chunks, unless it
+        came with a SEED: that one asks for the same tokens whenever it comes,
+        a chunk's width is in its roundings, so its wide chunk waits for the
+        program (``_prefill_step``)."""
+        wide, build = self.prefill_wide, self._wide_build
+        if (job.L - job.next > wide > 0 and build is not None
+                and (job.seed is not None or not build.is_alive())
+                and not any(s.active and s.on_token is not None
+                            for i, s in enumerate(self._slots) if i != job.slot)):
             return wide
         return job.chunk
 
@@ -3238,6 +3249,7 @@ class ContinuousBatcher:
         leaked/held — releasing before ``_resolve`` makes completion
         observable only after the pool is consistent."""
         slot = self._slots[i]
+        self._build_wide_program()   # (once: every program a request needs is built by now)
         toks = slot.tokens
         if self.eos_id in toks:
             toks = toks[: toks.index(self.eos_id)]

@@ -346,7 +346,7 @@ def test_attention_through_the_kernel_is_attention_through_the_expression(monkey
     import seldon_core_tpu.ops.gqa_attention as module
     from seldon_core_tpu.models import get_model
     from seldon_core_tpu.models.cache import init_paged_kv_caches
-    from seldon_core_tpu.models.transformer import paged_live_read
+    from seldon_core_tpu.models.transformer import paged_live_read, transformer_block
 
     model = get_model("transformer", **{**GQA_TOY, **more})
     cfg = model.cfg
@@ -377,11 +377,14 @@ def test_attention_through_the_kernel_is_attention_through_the_expression(monkey
 
     monkeypatch.setattr(module, "gqa_page_attention", interpreted)
     monkeypatch.setattr(jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
-    paged_live_read.clear_cache()     # (the read is a jitted function: a trace of its own)
+    # (the read is a jitted function, and so is the block that calls it: traces of their own)
+    paged_live_read.clear_cache()
+    transformer_block.clear_cache()
     try:
         got, got_pools = serve()
     finally:
         paged_live_read.clear_cache()
+        transformer_block.clear_cache()
     # the chunk where it has a walk, the step: a trace a call shape (the layers
     # and the steps share the jitted read's)
     assert calls == [(2, 48, 16, cfg.head_dim)] * chunk_walks + [(2, 1, 16, cfg.head_dim)]

@@ -18,6 +18,7 @@ from jax import export
 from seldon_core_tpu import ops
 from seldon_core_tpu.models import get_model
 from seldon_core_tpu.models.cache import init_paged_kv_caches
+from seldon_core_tpu.models.transformer import transformer_block
 from seldon_core_tpu.ops.fused_norm import fused_residual_rmsnorm
 from seldon_core_tpu.ops.grouped_matmul import grouped_matmul, make_visits, row_tile
 from seldon_core_tpu.ops.gqa_attention import gqa_page_attention, gqa_plan
@@ -29,6 +30,8 @@ from seldon_core_tpu.ops.sinkhorn import sinkhorn
 
 S = jax.ShapeDtypeStruct
 MOSAIC_CALL = "tpu_custom_call"  # how a lowered Pallas TPU kernel appears
+# a layer's call of its class's block (models/transformer.py ``transformer_block``)
+BLOCK_CALL = "call @transformer_block("
 
 
 def tpu_mlir(fn, *specs) -> str:
@@ -94,12 +97,15 @@ def test_sinkhorn_lowers_for_tpu(tokens):
 
 def test_the_sinkhorn_chain_reaches_the_kernel_on_a_tpu_and_the_loop_elsewhere():
     """``HyperConnection`` chooses as ``MoEFFN`` does: one kernel a sub-layer
-    in a program lowered for a TPU, none in any other."""
+    in a program lowered for a TPU, none in any other. (The layers of a class
+    call ONE lowering of their block, ``transformer_block``: what is counted
+    in a program's text is a block's.)"""
     model = get_model("llama-tiny", dtype="bfloat16", hc_mult=4)
     tokens = jnp.zeros((2, 4), jnp.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
     forward = jax.jit(lambda params, tokens: model.apply(params, tokens)[0])
-    assert tpu_mlir(forward, params, tokens).count(MOSAIC_CALL) == 2 * model.cfg.n_layers
+    on_tpu = tpu_mlir(forward, params, tokens)
+    assert on_tpu.count(MOSAIC_CALL) == 2 and on_tpu.count(BLOCK_CALL) == model.cfg.n_layers
     assert MOSAIC_CALL not in forward.lower(params, tokens).as_text()
 
 
@@ -110,8 +116,9 @@ def test_the_routed_experts_reach_the_kernel_on_a_tpu_and_ragged_dot_elsewhere()
     tokens = jnp.zeros((2, 4), jnp.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
     forward = jax.jit(lambda params, tokens: model.apply(params, tokens)[0])
-    n_layers = model.cfg.n_layers
-    assert tpu_mlir(forward, params, tokens).count(MOSAIC_CALL) == 3 * n_layers
+    on_tpu = tpu_mlir(forward, params, tokens)
+    # (three in the ONE block the layers call)
+    assert on_tpu.count(MOSAIC_CALL) == 3 and on_tpu.count(BLOCK_CALL) == model.cfg.n_layers
     assert MOSAIC_CALL not in forward.lower(params, tokens).as_text()
 
 
@@ -204,15 +211,17 @@ def test_the_gqa_read_reaches_the_kernel_on_a_tpu_and_the_expression_elsewhere()
         return (model.cfg, tpu_mlir(step, *args), jax.jit(step).lower(*args).as_text(),
                 tpu_mlir(plain, params, tokens))
 
-    # (the walk is a jitted function of its own: the layers call ONE lowering of it)
+    # (the block is a jitted function of its own, and so are the read that
+    # chooses the walk and the walk: the layers call ONE lowering of all three)
     cfg, on_tpu, elsewhere, no_cache = lowerings()
     assert on_tpu.count(MOSAIC_CALL) == 1
-    # (... and so is the read that chooses it: the layers call ONE lowering of both)
-    assert on_tpu.count("call @paged_live_read(") == cfg.n_layers
+    assert on_tpu.count(BLOCK_CALL) == cfg.n_layers
+    assert on_tpu.count("call @paged_live_read(") == 1
     assert on_tpu.count("call @_walk_pages(") == 1
     assert MOSAIC_CALL not in elsewhere and MOSAIC_CALL not in no_cache
-    assert lowerings(tokens_a_call=4)[1].count("call @paged_live_read(") == cfg.n_layers   # a verify
-    assert lowerings(tokens_a_call=32)[1].count("call @paged_live_read(") == cfg.n_layers  # a chunk
+    for tokens_a_call in (4, 32):    # a verify, a chunk
+        text = lowerings(tokens_a_call=tokens_a_call)[1]
+        assert text.count(BLOCK_CALL) == cfg.n_layers and text.count("call @paged_live_read(") == 1
     assert MOSAIC_CALL not in lowerings(kv_cache_dtype="int8")[1]
     assert MOSAIC_CALL not in lowerings(n_kv_heads=4, dim=192, n_heads=16)[1]   # 4 x 12 = 48
 
@@ -251,6 +260,7 @@ def test_a_chunk_program_traces_the_walk_once_and_a_page_operand_is_no_head(monk
 
     monkeypatch.setattr(page_walk, "_walk_pages", counted)
     page_walk._jitted_walk.cache_clear()
+    transformer_block.clear_cache()     # (a block traced before holds its walk's trace)
     try:
         tokens = jnp.zeros((1, chunk), jnp.int32)
         params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
@@ -262,8 +272,10 @@ def test_a_chunk_program_traces_the_walk_once_and_a_page_operand_is_no_head(monk
             S((1, n_pages), jnp.int32))
     finally:
         page_walk._jitted_walk.cache_clear()
+        transformer_block.clear_cache()
     assert traces == [walk]
-    assert text.count("call @paged_live_read(") == cfg.n_layers and text.count("call @_walk_pages(") == 1
+    assert text.count(BLOCK_CALL) == cfg.n_layers
+    assert text.count("call @paged_live_read(") == 1 and text.count("call @_walk_pages(") == 1
     kernels = [line for line in text.splitlines() if MOSAIC_CALL in line and "custom_call" in line]
     assert len(kernels) == 1
     operands = re.search(r"\} : \((.*)\) -> ", kernels[0]).group(1)
@@ -298,10 +310,10 @@ def test_the_latent_read_reaches_the_kernel_on_a_tpu_and_the_expression_elsewher
         return (model.cfg, tpu_mlir(step, *args), jax.jit(step).lower(*args).as_text(),
                 tpu_mlir(plain, params, tokens))
 
-    # (the walk is a jitted function of its own: the layers call ONE lowering of it)
+    # (the block is a jitted function of its own: the layers call ONE lowering of it)
     cfg, on_tpu, elsewhere, no_cache = lowerings()
     assert on_tpu.count(MOSAIC_CALL) == 1
-    assert on_tpu.count("call @_walk_pages(") == cfg.n_layers
+    assert on_tpu.count(BLOCK_CALL) == cfg.n_layers and on_tpu.count("call @_walk_pages(") == 1
     assert MOSAIC_CALL not in elsewhere and MOSAIC_CALL not in no_cache
     assert MOSAIC_CALL not in lowerings(kv_lora_rank=96)[1]
 
@@ -334,6 +346,7 @@ def test_a_wide_latent_chunk_traces_the_expanded_read_once_and_the_rest_stay_abs
 
     monkeypatch.setattr(latent_attention, "_read_expanded", counted)
     latent_attention._jitted_expanded.cache_clear()
+    transformer_block.clear_cache()
 
     def lowered(sequences, tokens):
         shape = (sequences, tokens)
@@ -348,8 +361,10 @@ def test_a_wide_latent_chunk_traces_the_expanded_read_once_and_the_rest_stay_abs
         wide, narrow, step = lowered(1, 1024), lowered(1, 256), lowered(8, 1)
     finally:
         latent_attention._jitted_expanded.cache_clear()
+        transformer_block.clear_cache()
     assert traces == [ExpandedWalk(8, 1024)]
-    assert wide.count("call @_read_expanded(") == cfg.n_layers and "call @_walk_pages(" not in wide
+    assert wide.count(BLOCK_CALL) == cfg.n_layers
+    assert wide.count("call @_read_expanded(") == 1 and "call @_walk_pages(" not in wide
     kernels = [line for line in wide.splitlines() if MOSAIC_CALL in line and "custom_call" in line]
     assert len(kernels) == 1
     operands = re.search(r"\} : \((.*)\) -> ", kernels[0]).group(1)
@@ -361,7 +376,8 @@ def test_a_wide_latent_chunk_traces_the_expanded_read_once_and_the_rest_stay_abs
     # scalar-prefetch operands, the queries' positions and the cached positions
     assert operands.count("tensor<") == 8 + 3 + 10
     for other in (narrow, step):
-        assert other.count("call @_walk_pages(") == cfg.n_layers and other.count(MOSAIC_CALL) == 1
+        assert other.count(BLOCK_CALL) == cfg.n_layers
+        assert other.count("call @_walk_pages(") == 1 and other.count(MOSAIC_CALL) == 1
         assert "call @_read_expanded(" not in other
 
 

@@ -42,8 +42,14 @@ vocab]`` logits.
 Seventh (PR 48): the WIDE chunk program (``WIDE_PREFILL_CHUNK`` rows, which a
 long prompt's chunks are while no other slot streams) is held to all of the
 above at DeepSeek-V2-Lite's, LFM2's, Qwen3-Next's and SmallThinker's cell shapes
-(a model that routes experts runs it), and the head's conditional at Mistral's
-vocabulary: the same tests take it as one more case. 1,024 rows since PR 50.
+and, since PR 54 (every model runs it), at Mistral's docs cell's, and the head's
+conditional at Mistral's vocabulary: the same tests take it as one more case.
+1,024 rows since PR 50.
+
+Eighth (PR 54): the layers of a class call ONE lowering of their block
+(models/transformer.py ``transformer_block``), which the compiler inlines: a
+compiled program holds no ``call``, and a layer's ops carry the scope path of
+their own call (``block_scope``).
 """
 
 import re
@@ -251,6 +257,14 @@ def window_pool_pages(cfg, slots: int) -> int:
     return slots * window_slot_pages(cfg.sliding_window, WIDE, PAGE) + 2
 
 
+def block_scope(layer: int) -> str:
+    """The scope path ``layer``'s ops carry in a compiled program's text: the
+    layers of a class call ONE lowering of their block (models/transformer.py
+    ``transformer_block``), whose ops are named from the block down, and the
+    compiler inlines each call under that call's own path."""
+    return f"layer_{layer}/jit(transformer_block)/TransformerBlock/"
+
+
 def compiled_text(server, program: str, sharding) -> str:
     return compiled(server, program, sharding).as_text()
 
@@ -431,6 +445,28 @@ def entry_ops(hlo: str) -> list:
     return out
 
 
+@pytest.mark.parametrize("config", ["mistral", "lfm2"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk", "wide_chunk"])
+def test_the_layers_calls_of_their_block_are_inlined(v5e, servers, config, program):
+    """The layers of a class call one lowering of ``transformer_block``; the
+    compiled program holds no ``call`` (a call left standing would keep the
+    compiler from fusing and scheduling across a layer's edge, and from
+    aliasing a donated pool through it), every layer's ops are there under its
+    OWN path (whatever reads a trace by layer still can), and the pool a layer
+    writes is still the donated one."""
+    server = servers(config)
+    cfg = server._cfg
+    exe = compiled(server, program, v5e)
+    hlo = exe.as_text()
+    assert not re.search(r"= \S+ call\(", hlo) and "to_apply=%transformer_block" not in hlo
+    for layer in range(cfg.n_layers):
+        assert block_scope(layer) in hlo
+    # the K, V and position pools (and a state layer's blocks) alias their inputs
+    pools = re.findall(r"%pools_[\w.]* = \S+ parameter\((\d+)\)", hlo[hlo.index("\nENTRY"):])
+    aliased = set(re.findall(r"\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo))
+    assert pools and set(pools) <= aliased
+
+
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
 def test_the_streams_stay_narrow_and_the_sinkhorn_chain_is_one_kernel(
         v5e, servers, program):
@@ -453,7 +489,7 @@ def test_the_streams_stay_narrow_and_the_sinkhorn_chain_is_one_kernel(
     assert not re.search(r" while\(.*resid\.hc", hlo)
     for layer in (0, 1):
         for sub in ("attention_hc", "ffn_hc"):
-            pre = [op for op in ops if f"layer_{layer}/{sub}/resid.hc.pre" in op[4]
+            pre = [op for op in ops if f"{block_scope(layer)}{sub}/resid.hc.pre" in op[4]
                    and op[3] in ("fusion", "convolution", "copy", "custom-call")]
             assert 4 <= len(pre) <= 12, (layer, sub, [op[0] for op in pre])
             chain = [op for op in pre if op[3] == "custom-call"]
@@ -705,7 +741,9 @@ GQA_CHUNKS = {"mistral docs": ("mistral", 256, 4096, 128, 4), "mistral chat 128"
               "mistral chat 256": ("mistral", 256, 1024, 128, 4), "olmoe chat": ("olmoe", 256, 1024, 128, 1),
               "lfm2 rag": ("lfm2", 256, 4096, 128, 8), "qwen3next longctx": ("qwen3next", 256, 8192, 256, 8),
               "lfm2 rag wide": ("lfm2", WIDE, 4096, 128, 8),
-              "qwen3next longctx wide": ("qwen3next", WIDE, 8192, 256, 8)}
+              "qwen3next longctx wide": ("qwen3next", WIDE, 8192, 256, 8),
+              # a dense server's, since PR 54: the docs and rerank cells' prompts take it
+              "mistral docs wide": ("mistral", WIDE, 4096, 128, 4)}
 
 
 @pytest.mark.parametrize("cell", list(GQA_CHUNKS))
@@ -998,8 +1036,8 @@ def test_the_hybrid_programs_donate_state_and_pool_and_copy_neither(v5e, servers
     assert not re.search(r"\b(infeed|outfeed|send|recv|send-done|recv-done)\(", hlo)
     assert "HostCompute" not in hlo and "host_compute" not in hlo
     for scope in ("mix.conv.in", "mix.conv.taps", "mix.conv.out"):
-        assert all(f"layer_{i}/conv/{scope}/" in hlo for i in cfg.conv_layers), scope
-    assert "layer_2/attn/attention" in hlo and "/conv/attn" not in hlo and "attn/mix.conv" not in hlo
+        assert all(f"{block_scope(i)}conv/{scope}/" in hlo for i in cfg.conv_layers), scope
+    assert f"{block_scope(2)}attn/attention" in hlo and "/conv/attn" not in hlo and "attn/mix.conv" not in hlo
     if program == "decode_step":
         # no gathered view of K or of V: the step walks the live pages
         assert stats.temp_size_in_bytes < slots * length * row * 2
@@ -1097,8 +1135,8 @@ def test_the_linear_attention_programs_donate_both_state_arrays_and_hold_no_floa
     assert not re.search(r"\b(infeed|outfeed|send|recv|send-done|recv-done)\(", hlo)
     assert "HostCompute" not in hlo and "host_compute" not in hlo
     for scope in ("mix.gdn.in", "mix.gdn.conv", "mix.gdn.rule", "mix.gdn.out"):
-        assert all(f"layer_{i}/linear_attn/{scope}/" in hlo for i in cfg.state_layers), scope
-    assert "layer_3/attn/attention" in hlo and "/linear_attn/attn/" not in hlo
+        assert all(f"{block_scope(i)}linear_attn/{scope}/" in hlo for i in cfg.state_layers), scope
+    assert f"{block_scope(3)}attn/attention" in hlo and "/linear_attn/attn/" not in hlo
     assert "/attn/mix.gdn" not in hlo and "/attn/linear_attn" not in hlo
     if program == "decode_step":
         assert "gqa_page_attention" in hlo
@@ -1165,7 +1203,7 @@ def test_a_state_that_is_not_square_lies_unpadded_and_goes_through_the_kernel(
         packed[1:], a_head[1:])] == []
     assert not re.search(r"\b(infeed|outfeed|send|recv|send-done|recv-done)\(", hlo)
     for scope in ("mix.gdn.in", "mix.gdn.conv", "mix.gdn.rule", "mix.gdn.out"):
-        assert all(f"layer_{i}/linear_attn/{scope}/" in hlo for i in cfg.state_layers), scope
+        assert all(f"{block_scope(i)}linear_attn/{scope}/" in hlo for i in cfg.state_layers), scope
     assert "gqa_page_attention" in hlo
     if program == "decode_step":
         assert len(re.findall(r"= \([^=]*\) custom-call\([^\n]*gated_delta_step", hlo)) == 3
@@ -1236,7 +1274,7 @@ def test_a_mamba_layers_state_goes_through_the_kernel_once_a_layer_in_its_own_bu
         state[1:], a_head[0][1:], a_head[1][1:])] == []
     assert not re.search(r"\b(infeed|outfeed|send|recv|send-done|recv-done)\(", hlo)
     for scope in ("mix.ssd.in", "mix.ssd.conv", "mix.ssd.rule", "mix.ssd.out"):
-        assert all(f"layer_{i}/mamba/{scope}/" in hlo for i in cfg.state_layers), scope
+        assert all(f"{block_scope(i)}mamba/{scope}/" in hlo for i in cfg.state_layers), scope
     assert "gqa_page_attention" in hlo
     if program == "decode_step":
         assert len(re.findall(r"= \([^=]*\) custom-call\([^\n]*ssd_step", hlo)) == 3
