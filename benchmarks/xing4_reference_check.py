@@ -16,6 +16,9 @@ configuration's `reference_tolerance` is set from (perf/configs/<config>.json).
     chiprun -- python benchmarks/xing4_reference_check.py --workload granite4h-sessions-decode \
         --probes 12 --wrong-probes 2 --long 2 --long-size 512+768 \
         --long-wrongs state_held_in_bf16,weights_at_4_bits --out chiprun_out/pr53/reference_check.json
+    chiprun -- python benchmarks/xing4_reference_check.py --workload phi4flash-longtrace-decode \
+        --probes 12 --wrong-probes 2 --long 2 --long-size 2400+64 \
+        --long-wrongs state_held_in_bf16,weights_at_4_bits --out chiprun_out/pr55/reference_check.json
     (then once more with --probes 2 --wrong-probes 2 --only-low: the 4-bit tree in a call of its
     own, because the machine's host holds 40 GiB and a 15-layer tree is 11.5 GB of it)
 
@@ -130,6 +133,28 @@ def granite_hybrid_wrongs(prompt_tokens: int, chunk: int) -> dict:
         "b_and_c_not_convolved": {"ssd_conv_bc": False},
         "state_zeroed_at_a_chunk_start": {"ssd_reset_every": chunk},
         "state_from_the_chunks_last_row": {"conv_state_pad": (prompt_tokens, padded)},
+    }
+
+
+def sambay_wrongs(prompt_tokens: int, chunk: int) -> dict:
+    """The wrong references of Phi-4-mini-flash (its `work` is "sambay"): a dense
+    model, so nothing is followed; each of ISSUE 55's readings of a layer taken
+    the other way (the weights at 4 bits are every configuration's)."""
+    return {
+        "state_held_in_bf16": {"s6_state_bf16": True},
+        "state_zeroed_at_a_chunk_start": {"s6_reset_every": chunk},
+        "memory_taken_after_the_gate": {"gmu_memory_gated": True},
+        "memory_from_an_earlier_layer": {"gmu_memory_layer": 14},
+        "skip_left_out_of_the_memory": {"gmu_memory_skip": False},
+        "cross_layers_read_their_own_input": {"cross_kv_own": True},
+        "pairs_by_halves": {"diff_pairs": "halves"},
+        "lambda_init_of_layer_0": {"lambda_init_layer0": True},
+        "sub_norm_left_out": {"diff_subln": False},
+        "one_minus_lambda_init_left_out": {"diff_scale": False},
+        "window_4_rows_off": {"window_wrong": 508},
+        "layer_norm_without_the_mean": {"layer_norm_mean": False},
+        "projection_biases_left_out": {"bias_off": "attention"},
+        "norm_biases_left_out": {"bias_off": "norm"},
     }
 
 
@@ -319,7 +344,7 @@ def main() -> None:
     wrongs = WRONGS
     placed = {"lfm2": lfm2_wrongs, "qwen3_next": qwen3_next_wrongs,
               "olmo_hybrid": olmo_hybrid_wrongs, "granite_hybrid": granite_hybrid_wrongs,
-              "smallthinker": smallthinker_wrongs}.get(cfg.get("work"))
+              "smallthinker": smallthinker_wrongs, "sambay": sambay_wrongs}.get(cfg.get("work"))
     if placed:   # a configuration with state layers: some wrongs lie where the probe's chunks end
         wrongs = placed(probe["prompt_tokens"], server_kw.get("prefill_chunk") or 256)
     only = [name for name in args.only_wrongs.split(",") if name]

@@ -470,7 +470,9 @@ class MetricsRegistry:
         )
         # kind: "full" every layer that attends its whole sequence; "window" the
         # sliding-attention layers of a model that has them (a row a layer of
-        # each kind: the two differ in what a query may see). form: "expanded"
+        # each kind: the two differ in what a query may see); "shared" the
+        # cross-attention layers of a model that has them (a row a layer: each
+        # reads the pool of ANOTHER layer, cfg.kv_source, in place). form: "expanded"
         # a call whose latent read makes the context's K and V once a visit (a
         # wide chunk's, ops/latent_attention.py), "absorbed" every other
         self._attn_context = {
@@ -519,17 +521,33 @@ class MetricsRegistry:
         # A model with conv layers (models/transformer.py ShortConv): what
         # went through them, counted on the loop from host integers; absent
         # for every other model
-        # ... likewise "gdn" for linear-attention layers (GatedDeltaNet) and
-        # "ssd" for mamba layers (Mamba2Mixer)
+        # ... likewise "gdn" for linear-attention layers (GatedDeltaNet),
+        # "ssd" for mamba layers (Mamba2Mixer) and "s6" for s6 layers (Mamba1Mixer)
         self._state_layers = {
             f"{kind}_{key}": Counter(f"seldon_llm_{kind}_{key}_total", text.format(what=what),
                                      base + ["program"], registry=self.registry)
             for kind, what in (("conv", "conv"), ("gdn", "linear-attention (Gated DeltaNet)"),
-                               ("ssd", "mamba (Mamba-2 state-space)"))
+                               ("ssd", "mamba (Mamba-2 state-space)"),
+                               ("s6", "s6 (Mamba-1 selective scan)"))
             for key, text in (
                 ("rows", "Live rows (tokens) of the step-program calls of a "
                          "model with {what} layers: what EACH such layer mixed"),
                 ("layer_calls", "{what} layers x step-program calls"))}
+        # A model whose layers past cfg.kv_source cache nothing (SambaY's
+        # cross-decoder): the rows that ran the layers up to it and the rows that
+        # ran the rest: a prompt's chunk runs the rest on ONE row, or on none
+        # (two names: a ratio's two counters carry one set of labels)
+        self._decoder_rows = {
+            half: Counter(
+                f"seldon_llm_{half}_decoder_rows_total",
+                f"Live rows (tokens) of the step-program calls that ran the {what}",
+                base + ["program"], registry=self.registry)
+            for half, what in (
+                ("self", "layers up to cfg.kv_source (the self-decoder and the layer whose "
+                         "K/V the cross-attention layers read)"),
+                ("cross", "layers past cfg.kv_source (the cross-decoder): every row of a "
+                          "decode step, the ONE row read of a prompt's last chunk, none of "
+                          "any chunk before it"))}
         # how a decode step program's delta rule runs: the repo's kernel (S
         # read once and written once) or the expression's two passes over S: a
         # silent fall-back shows here; absent without linear-attention layers
@@ -1204,7 +1222,8 @@ class MetricsRegistry:
         for ready, n in stats.get("first_token_reads", {}).items():
             self._counter_catch_up(self._first_token_reads, n, ready=ready)
         for key, counter in self._attn_context.items():
-            for kind, prefix in (("full", "attn_"), ("window", "attn_window_")):
+            for kind, prefix in (("full", "attn_"), ("window", "attn_window_"),
+                                 ("shared", "attn_shared_")):
                 expanded = stats.get("attn_expanded_" + key, {}) if kind == "full" else {}
                 for program, n in stats.get(prefix + key, {}).items():
                     there = expanded.get(program, 0)
@@ -1222,6 +1241,9 @@ class MetricsRegistry:
             self._counter_catch_up(self._chunk_rows, n, width=width)
         for key, counter in self._state_layers.items():
             for program, n in stats.get(key, {}).items():
+                self._counter_catch_up(counter, n, program=program)
+        for half, counter in self._decoder_rows.items():
+            for program, n in stats.get(f"{half}_decoder_rows", {}).items():
                 self._counter_catch_up(counter, n, program=program)
         for kind, counter in self._step_path.items():
             for path, n in stats.get(f"{kind}_step_path", {}).items():

@@ -97,7 +97,9 @@ LATENT_INT8_REFUSAL = (
 class StateEntry(tuple):
     """A state layer's entry of a cache tree (a fixed block a sequence, no
     pages, no positions: a conv layer's ``(state,)``, a linear-attention
-    layer's ``(conv_state, S)``), told from an attention layer's
+    layer's ``(conv_state, S)``, the EMPTY entry of a layer that keeps nothing
+    of its own: a gated memory unit, a cross-attention layer, which reads
+    another layer's pages in place), told from an attention layer's
     ``(values..., positions)`` by its TYPE: the initialisers below make one
     where ``cfg.layer_kind`` names a state layer, ``put_state`` returns one,
     and every tree operation keeps it (a registered pytree node)."""
@@ -151,6 +153,16 @@ def _state_entry_shapes(cfg, kind: str) -> Tuple[Tuple[Tuple[int, ...], Any], ..
     """(shape a sequence, dtype) of each array of a state layer's entry."""
     if kind == "conv":
         return (((cfg.conv_L_cache - 1, cfg.dim), cfg.dtype),)
+    if kind in ("gmu", "cross_attention"):
+        # nothing of its own: a "gmu" layer reads the call's rows of another
+        # layer's scan output, a "cross_attention" layer another layer's pages
+        return ()
+    if kind == "s6":
+        # (the rows of x before the taps; h float32 [d_state, d_inner]: the
+        # states along the sublanes, the channels along the lanes, the layout
+        # of ops/selective_scan.py's kernel)
+        return (((cfg.mamba_d_conv - 1, cfg.mamba_d_inner), cfg.dtype),
+                ((cfg.mamba_d_state, cfg.mamba_d_inner), jnp.float32))
     if kind == "mamba":
         # (the rows of [x ; B ; C] before the taps; h float32, a head's TRANSPOSED
         # [d_state, d_head], ``side`` heads side by side along the lanes as the
@@ -501,16 +513,23 @@ def put_state(entry, state_slots, new_arrays) -> StateEntry:
 
 
 def matrix_state_layer(cfg) -> Optional[int]:
-    """The first mamba layer (the layer whose h a probe may read back), or None."""
-    return next(iter(cfg.layers_of("mamba")), None)
+    """The first mamba or s6 layer (the layer whose h a probe may read back), or None."""
+    return next((i for i in range(cfg.n_layers) if cfg.layer_kind(i) in ("mamba", "s6")), None)
 
 
 def read_matrix_state(cfg, tree, state_slots):
     """The h that layer holds for the sequences ``state_slots`` [b] names, a head
     at a time and TRANSPOSED as the cache holds it, [b, H, d_state, d_head]
     float32, whatever the lanes' layout: what a probe that asked for "state" is
-    sent (runtime/batcher.py ``_read_state``)."""
-    h = tree[matrix_state_layer(cfg)][1][state_slots]
+    sent (runtime/batcher.py ``_read_state``). An s6 layer has no heads: its
+    [d_state, d_inner] goes out as blocks of 128 channels (all of them where
+    they are no whole number of such blocks) standing where heads do."""
+    layer = matrix_state_layer(cfg)
+    h = tree[layer][1][state_slots]
+    if cfg.layer_kind(layer) == "s6":
+        b, states, channels = h.shape
+        lanes = 128 if channels % 128 == 0 else channels
+        return jnp.swapaxes(h.reshape(b, states, channels // lanes, lanes), 1, 2)
     return unpack_state(h, heads_a_lane_row(cfg.mamba_n_heads, cfg.mamba_d_head))
 
 

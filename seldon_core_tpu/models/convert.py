@@ -194,6 +194,8 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
             "attention_multiplier": float(hf_config.attention_multiplier),
             "residual_multiplier": float(hf_config.residual_multiplier),
             "logits_scaling": float(hf_config.logits_scaling)}
+    if model_type == "phi4flash":
+        return phi4flash_kwargs(hf_config)
     if deepseek:
         # V3's router is sigmoid scores + a selection bias (noaux_tc); its
         # config class carries neither key, V2-shaped configs name both
@@ -247,6 +249,118 @@ def config_kwargs_from_hf(hf_config: Any) -> Dict[str, Any]:
         **({"rope_scaling": rope_scaling} if rope_scaling else {}),
         **moe,      # (olmo_hybrid's and granitemoehybrid's rope_theta overrides the default above)
     }
+
+
+def sambay_layer_types(n_layers: int, mb_per_layer: int = 2) -> Tuple[str, ...]:
+    """SambaY's layer plan (Phi4FlashConfig / SambaYDecoderLayer, as read:
+    perf/configs/phi-4-mini-flash-reasoning-int8.json ``assumed``), from the
+    depth: the self-decoder's n / 2 layers alternate Mamba-1 and window
+    attention; layer n / 2 is the Mamba-1 layer whose scan output the gated
+    memory units read; layer n / 2 + 1 attends everything and its K/V are the
+    shared cache; the cross-decoder alternates gated memory units and
+    cross-attention over that cache."""
+    if n_layers % 4 or n_layers < 4 or mb_per_layer != 2:
+        raise ValueError(
+            f"phi4flash with num_hidden_layers={n_layers} (a multiple of 4 is built) or "
+            f"mb_per_layer={mb_per_layer} (2 is) is not supported by the native transformer")
+    half = n_layers // 2
+    return tuple(
+        ("s6" if i % 2 == 0 else "sliding_attention") if i < half
+        else "s6" if i == half else "full_attention" if i == half + 1
+        else ("gmu" if i % 2 == 0 else "cross_attention")
+        for i in range(n_layers))
+
+
+def phi4flash_kwargs(hf_config: Any) -> Dict[str, Any]:
+    """``Phi4FlashConfig`` (an object with the published keys as attributes;
+    the class is not installed) -> the native transformer's kwargs. What the
+    published config leaves to its class's defaults is read as the family's:
+    mamba_d_state 16, mamba_d_conv 4, mamba_expand 2, mamba_dt_rank "auto" =
+    ceil(hidden / 16)."""
+    def get(key, default):
+        return getattr(hf_config, key, default)
+
+    dim, n = hf_config.hidden_size, hf_config.num_hidden_layers
+    rank = get("mamba_dt_rank", "auto")
+    return {
+        "vocab_size": hf_config.vocab_size, "dim": dim, "n_layers": n,
+        "n_heads": hf_config.num_attention_heads,
+        "n_kv_heads": get("num_key_value_heads", None) or hf_config.num_attention_heads,
+        "ffn_dim": hf_config.intermediate_size,
+        "max_seq_len": hf_config.max_position_embeddings,
+        "rope_theta": None, "norm_eps": get("layer_norm_eps", 1e-5),
+        "tie_embeddings": bool(get("tie_word_embeddings", True)),
+        "layer_types": sambay_layer_types(n, get("mb_per_layer", 2)),
+        "sliding_window": int(get("sliding_window", 0) or 0),
+        "mamba_d_inner": int(get("mamba_expand", 2) * dim),
+        "mamba_d_state": get("mamba_d_state", 16), "mamba_d_conv": get("mamba_d_conv", 4),
+        "mamba_dt_rank": -(-dim // 16) if rank == "auto" else int(rank),
+        "mamba_conv_bias": bool(get("mamba_conv_bias", True)),
+        "memory_source": n // 2, "kv_source": n // 2 + 1,
+        "differential": True, "attention_bias": True, "norm": "layer"}
+
+
+def convert_phi4flash_state_dict(state_dict: Dict[str, Any], kwargs: Dict[str, Any],
+                                 dtype: str = "float32") -> Dict[str, Any]:
+    """A Phi4FlashForCausalLM state dict, in the published names as read
+    (``model.layers.{i}.attn.*``), -> our flax param tree. ``Wqkv`` [(H + 2 G)
+    d, dim] with its bias splits into q, k and v in that order (a
+    cross-attention layer's holds q alone); ``out_proj`` with its bias;
+    ``lambda_q1, lambda_k1, lambda_q2, lambda_k2`` stack to the ONE leaf
+    ``lambdas`` [4, d] in THAT order; ``subln`` the norm over 2 d. A Mamba-1
+    layer: ``in_proj`` [2 E, dim] transposes to the ONE product [x ; z];
+    ``conv1d`` drops its middle axis; ``x_proj`` [R + 2 N, E], ``dt_proj`` [E,
+    R] with its bias ``b_dt``; ``A_log`` [E, N] is held TRANSPOSED
+    (``A_log_t``, the state's layout); ``D``. A gated memory unit: ``in_proj``
+    [E, dim] and ``out_proj``. ``mlp.fc1`` [2 width, dim] is [gate ; up]
+    (``chunk(2)``: SiLU on the FIRST half); every LayerNorm has a bias."""
+    t, consumed = _tensor_reader(state_dict, dtype)
+
+    def norm(key):
+        return {"weight": t(key + ".weight"), "bias": t(key + ".bias")}
+
+    params: Dict[str, Any] = {"tok_embeddings": t("model.embed_tokens.weight"),
+                              "norm": norm("model.final_layernorm")}
+    width = kwargs["ffn_dim"]
+    hd = kwargs.get("head_dim") or kwargs["dim"] // kwargs["n_heads"]
+    q_rows, kv_rows = kwargs["n_heads"] * hd, kwargs["n_kv_heads"] * hd
+    for i, kind in enumerate(kwargs["layer_types"]):
+        hf = f"model.layers.{i}"
+        fc1 = t(f"{hf}.mlp.fc1.weight")
+        layer = params[f"layer_{i}"] = {
+            "ffn_norm": norm(f"{hf}.post_attention_layernorm"),
+            "ffn": {"w1": fc1[:width].T, "w3": fc1[width:].T, "w2": t(f"{hf}.mlp.fc2.weight").T}}
+        at = f"{hf}.attn"
+        if kind == "s6":
+            layer["operator_norm"] = norm(f"{hf}.input_layernorm")
+            layer["s6"] = {
+                "in_proj": t(f"{at}.in_proj.weight").T,
+                "conv1d": t(f"{at}.conv1d.weight")[:, 0, :],
+                **({"conv_bias": t(f"{at}.conv1d.bias")} if kwargs["mamba_conv_bias"] else {}),
+                "x_proj": t(f"{at}.x_proj.weight").T, "dt_proj": t(f"{at}.dt_proj.weight").T,
+                "b_dt": t(f"{at}.dt_proj.bias"), "A_log_t": t(f"{at}.A_log").T,
+                "D": t(f"{at}.D"), "out_proj": t(f"{at}.out_proj.weight").T}
+        elif kind == "gmu":
+            layer["operator_norm"] = norm(f"{hf}.input_layernorm")
+            layer["gmu"] = {"in_proj": t(f"{at}.in_proj.weight").T,
+                            "out_proj": t(f"{at}.out_proj.weight").T}
+        else:
+            layer["attention_norm"] = norm(f"{hf}.input_layernorm")
+            w, bias = t(f"{at}.Wqkv.weight"), t(f"{at}.Wqkv.bias")
+            attention = layer["attention"] = {
+                "wq": w[:q_rows].T, "bq": bias[:q_rows],
+                "wo": t(f"{at}.out_proj.weight").T, "bo": t(f"{at}.out_proj.bias"),
+                "lambdas": np.stack([t(f"{at}.lambda_{name}") for name in ("q1", "k1", "q2", "k2")]),
+                "subln": {"weight": t(f"{at}.subln.weight")}}
+            if kind != "cross_attention":
+                attention.update(
+                    wk=w[q_rows:q_rows + kv_rows].T, bk=bias[q_rows:q_rows + kv_rows],
+                    wv=w[q_rows + kv_rows:].T, bv=bias[q_rows + kv_rows:])
+    leftover = [k for k in state_dict if k not in consumed and k != "lm_head.weight"]
+    if leftover:
+        raise ValueError(
+            f"unmapped weights in state dict (conversion would drop them): {leftover[:8]}")
+    return {"params": params}
 
 
 def _np_dtype(name: str):
@@ -726,6 +840,8 @@ def convert_hf_model(hf_model: Any) -> Tuple[Any, Dict[str, Any]]:
         variables = convert_olmo_state_dict(hf_model.state_dict(), kwargs)
     elif kwargs.get("mamba_n_heads"):      # granitemoehybrid
         variables = convert_granite_hybrid_state_dict(hf_model.state_dict(), kwargs)
+    elif kwargs.get("kv_source") is not None:      # phi4flash
+        variables = convert_phi4flash_state_dict(hf_model.state_dict(), kwargs)
     elif kwargs.get("layer_types"):
         variables = convert_lfm2_state_dict(hf_model.state_dict(), kwargs)
     else:
@@ -761,6 +877,8 @@ def convert_checkpoint(hf_path: str, out_dir: str, dtype: str = "bfloat16") -> s
         variables = convert_olmo_state_dict(model.state_dict(), kwargs, dtype)
     elif kwargs.get("mamba_n_heads"):      # granitemoehybrid
         variables = convert_granite_hybrid_state_dict(model.state_dict(), kwargs, dtype)
+    elif kwargs.get("kv_source") is not None:      # phi4flash
+        variables = convert_phi4flash_state_dict(model.state_dict(), kwargs, dtype)
     elif kwargs.get("layer_types"):
         variables = convert_lfm2_state_dict(model.state_dict(), kwargs, dtype)
     else:

@@ -231,6 +231,41 @@ not carry h), and ``conv_state_pad`` as LFM2's (over the rows of xBC before the 
 ``ssd_state`` is the h one mamba layer holds after a sequence, for a probe that reads
 the served cache's back (transport/rest.py "state").
 
+Phi-4-mini-flash-reasoning (``model_type`` ``phi4flash``; SambaY, arXiv 2507.06607; the
+modeling file is NOT installed, so the model as a whole is held to this reading, and two of
+its layers to installed implementations: tests/test_reference_phi4flash.py holds ``_mamba1``
+to ``transformers`` ``MambaMixer.slow_forward`` and ``_diff_attention`` to ``DiffLlamaAttention``
+under a permutation of heads, at 1e-5). n layers, every one h = u + Mixer(LN(u)); out = h +
+MLP(LN(h)) with LN = LayerNorm (weight AND bias) and MLP = W_2 (SiLU(W_1 h) * W_3 h); the
+mixer by ``cfg.layer_types``:
+
+    s6    : [x ; z] = W_in u;  x <- SiLU(taps (4) over the E channels + conv bias)
+            [dt ; B ; C] = W_x x;  Delta = softplus(W_dt dt + b_dt);  A = -exp(A_log)
+            a ``lax.scan`` over the tokens from h = 0, h [E, N]:
+              h_t = e^{Delta_t A} h_{t-1} + (Delta_t x_t) B_t^T;   y_t = h_t C_t + D x_t
+            Mixer = W_out (y * SiLU(z));  the layer cfg.memory_source also hands up m = y
+    gmu   : Mixer = W_2 (SiLU(W_1 u) * m)              m the SAME token's row
+    attn  : [q ; k ; v] = W u + b, H / G / G heads of d, paired in STRIPES: q1_j = q[2j],
+            q2_j = q[2j+1], k1_g = k[2g], k2_g = k[2g+1], v_g = [v[2g] ; v[2g+1]], pair j
+            reads group j // (H / G);  a_i = softmax(q_i k_i^T d^-1/2) v  (the same mask);
+            lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init,
+            lambda_init = 0.8 - 0.6 exp(-0.3 layer);
+            o_j = (1 - lambda_init) w * RMSNorm_2d(a1_j - lambda a2_j);  Mixer = W_o [o] + b_o
+            "sliding_attention": a query sees itself and the cfg.sliding_window - 1 rows
+            before it; "full_attention": everything before it; "cross_attention": queries
+            of its own over the k and v that the layer cfg.kv_source made of ITS input
+    model : logits = LN_f(h_last) E^T, the table and the head tied; NO position anywhere
+
+every layer on every row, no cache. WRONG models of these: ``s6_state_bf16=True``,
+``s6_reset_every=N`` (h zeroed at every multiple of N), ``gmu_memory_gated=True`` (m taken
+AFTER the gate), ``gmu_memory_layer=i`` (m from s6 layer i), ``gmu_memory_skip=False`` (D x
+left out of m), ``cross_kv_own=True`` (a cross layer reads k and v made of its OWN input by
+the source layer's weights), ``diff_pairs="halves"`` (q1_j = q[j], q2_j = q[j + H/2], and
+k, v likewise), ``lambda_init_layer0=True``, ``diff_subln=False``, ``diff_scale=False``
+((1 - lambda_init) left out), ``window_wrong=N``, ``layer_norm_mean=False`` (LayerNorm
+without the mean), ``bias_off="attention" | "norm"`` (those biases left out).
+``s6_state`` is the h one s6 layer holds after a sequence (transport/rest.py "state").
+
 ``follow=`` makes the forward take the experts the SERVED path took (a logits
 probe's ``routing``): where this router's last chosen and first unchosen expert
 score within the served arithmetic's noise of each other, which one is taken is
@@ -386,7 +421,8 @@ def _attend_block(q, k, v, start, lo, window: int = 0):
     weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
     out = jnp.matmul(weights.reshape(groups, rep * b, w), v.transpose(1, 0, 2))
     out = out / jnp.sum(weights, axis=-1).reshape(groups, rep * b, 1)
-    return out.reshape(groups, rep, b, hd).transpose(2, 0, 1, 3).reshape(b, heads * hd)
+    wide = v.shape[2]      # (a value head may be wider than a key head: a differential pair's)
+    return out.reshape(groups, rep, b, wide).transpose(2, 0, 1, 3).reshape(b, heads * wide)
 
 
 def _attention(p: dict, x, cfg, qk_norm=None, gate: bool = True, rotary_all: bool = False,
@@ -628,6 +664,235 @@ def _mamba2(p: dict, u, cfg, wrong: dict, seen: Optional[list] = None,
     return y @ _f32(p["out_proj"])
 
 
+# SambaY's pieces are jitted a piece a layer KIND (a mixer, the gated MLP behind its
+# norm), not run op by op: an eager forward compiles every op of every shape on its
+# first call, ~250 small compiles that took 164 s beside a server that was compiling
+# too (the chip's host, PR 55), where seven pieces take a quarter of it. The
+# arithmetic and its order are what is written.
+def _table_rows(leaf, tokens):
+    """The table's rows ``tokens`` in float32: an int8 table's rows are gathered
+    first and those alone dequantized (the same values as ``_f32(leaf)[tokens]``,
+    without 2 GB of float32 table)."""
+    tokens = jnp.asarray(tokens, jnp.int32)
+    if hasattr(leaf, "q") and not leaf.out_major and leaf.scale.ndim == 1:
+        return jnp.asarray(leaf.q)[tokens].astype(jnp.float32) * jnp.asarray(leaf.scale, jnp.float32)
+    return _f32(leaf)[tokens]
+
+
+def _layer_norm_rows(p: dict, x, eps, mean: bool, biased: bool):
+    if mean:
+        x = x - jnp.mean(x, axis=-1, keepdims=True)
+    out = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * _f32(p["weight"])
+    return out + _f32(p["bias"]) if biased else out
+
+
+_layer_norm_of = jax.jit(_layer_norm_rows, static_argnames=("mean", "biased"))
+
+
+def _layer_norm(x, p: dict, eps: float, wrong: dict):
+    """LayerNorm: the mean taken out, a weight and a bias."""
+    return _layer_norm_of(p, x, eps, mean=bool(wrong["layer_norm_mean"]),
+                          biased=wrong["bias_off"] != "norm")
+
+
+@partial(jax.jit, static_argnames=("round_bf16",))
+def _s6_scan(x, delta, A, B, C, reset, round_bf16: bool = False):
+    """Mamba-1's recurrence as a scan over tokens from h = 0: ``x`` / ``delta`` [s, E],
+    ``A`` [E, N], ``B`` / ``C`` [s, N], ``reset`` [s] bool (a WRONG model zeroes h before
+    such a token) -> (h after the last token [E, N], h_t C_t [s, E])."""
+    def step(h, row):
+        x_t, d_t, b_t, c_t, reset_t = row
+        h = (jnp.where(reset_t, 0.0, h) * jnp.exp(d_t[:, None] * A)
+             + (d_t * x_t)[:, None] * b_t[None, :])
+        if round_bf16:   # (``lax.reduce_precision``: see ``_delta_scan``)
+            h = jax.lax.reduce_precision(h, exponent_bits=8, mantissa_bits=7)
+        return h, h @ c_t
+
+    with jax.default_matmul_precision("highest"):
+        return jax.lax.scan(step, jnp.zeros(A.shape, jnp.float32), (x, delta, B, C, reset))
+
+
+@partial(jax.jit, static_argnames=("dims", "every", "round_bf16"))
+def _mamba1_parts(p: dict, u, dims: tuple, every: Optional[int], round_bf16: bool):
+    """-> (the mixer's output, h [E, N] after the last token, y + D x, y, the gated
+    (y + D x) SiLU(z)): every reading of "the scan's output" a caller may want."""
+    with jax.default_matmul_precision("highest"):
+        s = u.shape[0]
+        E, N, R = dims
+        xz = u @ _f32(p["in_proj"])
+        x, z = xz[:, :E], xz[:, E:]
+        taps = _f32(p["conv1d"])
+        back = taps.shape[1] - 1
+        padded = jnp.concatenate([jnp.zeros((back, E), jnp.float32), x])
+        x = sum(taps[:, j] * padded[j:j + s] for j in range(back + 1))
+        if "conv_bias" in p:
+            x = x + _f32(p["conv_bias"])
+        x = jax.nn.silu(x)
+        dbc = x @ _f32(p["x_proj"])
+        delta = jax.nn.softplus(dbc[:, :R] @ _f32(p["dt_proj"]) + _f32(p["b_dt"]))
+        reset = (jnp.arange(s) % every == 0) if every else jnp.zeros((s,), bool)
+        h, y = _s6_scan(x, delta, -jnp.exp(_f32(p["A_log_t"])).T, dbc[:, R:R + N],
+                        dbc[:, R + N:], reset, round_bf16=round_bf16)
+        skipped = y + _f32(p["D"]) * x
+        gated = skipped * jax.nn.silu(z)
+        return gated @ _f32(p["out_proj"]), h, skipped, y, gated
+
+
+def _mamba1(p: dict, u, cfg, wrong: dict, left: Optional[list] = None,
+            handed: Optional[list] = None):
+    """Mamba-1's mixer over the whole sequence ``u`` [s, C], the recurrence token by token.
+    ``left``, a list: the h [E, N] the last token leaves is appended to it; ``handed``, a
+    list: the m a gated memory unit reads (the scan's output before the gate)."""
+    out, h, skipped, y, gated = _mamba1_parts(
+        p, u, (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank),
+        wrong["s6_reset_every"], bool(wrong["s6_state_bf16"]))
+    if left is not None:
+        left.append(h)
+    if handed is not None:
+        handed.append(gated if wrong["gmu_memory_gated"]
+                      else (skipped if wrong["gmu_memory_skip"] else y))
+    return out
+
+
+@partial(jax.jit, static_argnames=("heads", "biased"))
+def _project_heads(x, w, b, heads: int, biased: bool):
+    """(x W + b) as [s, heads, d]."""
+    with jax.default_matmul_precision("highest"):
+        out = x @ _f32(w)
+        if biased and b is not None:
+            out = out + _f32(b)
+        return out.reshape(x.shape[0], heads, -1)
+
+
+@partial(jax.jit, static_argnames=("subln", "scaled", "biased"))
+def _diff_epilogue(p: dict, a1, a2, init, eps, subln: bool, scaled: bool, biased: bool):
+    """W_o [(1 - lambda_init) w * RMSNorm(a1 - lambda a2)] + b_o; ``init`` (lambda_init) is
+    an argument, so the layers share the piece."""
+    with jax.default_matmul_precision("highest"):
+        lq1, lk1, lq2, lk2 = _f32(p["lambdas"])
+        o = a1 - (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + init) * a2
+        if subln:
+            o = _rms_norm(o, p["subln"]["weight"], eps)
+        if scaled:
+            o = o * (1.0 - init)
+        out = o.reshape(o.shape[0], -1) @ _f32(p["wo"])
+        return out + _f32(p["bo"]) if biased and "bo" in p else out
+
+
+def _diff_attention(p: dict, x, cfg, layer: int, wrong: dict, window: int = 0, kv=None,
+                    made: Optional[list] = None, block: int = 256):
+    """Differential attention over the whole sequence ``x`` [s, C] by the equations, two
+    softmaxes a pair of heads. ``kv``: the (k, v) [s, G, d] of ANOTHER layer (a cross
+    layer: it makes none); ``made``, a list: this layer's own (k, v) are appended."""
+    s = x.shape[0]
+    H, G, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    biased = wrong["bias_off"] != "attention"
+    q = _project_heads(x, p["wq"], p.get("bq"), H, biased)
+    if kv is None:
+        kv = (_project_heads(x, p["wk"], p.get("bk"), G, biased),
+              _project_heads(x, p["wv"], p.get("bv"), G, biased))
+        if made is not None:
+            made.append(kv)
+    k, v = kv
+    if wrong["diff_pairs"] == "halves":     # WRONG: head j with head j + half
+        q1, q2, k1, k2 = q[:, :H // 2], q[:, H // 2:], k[:, :G // 2], k[:, G // 2:]
+        v = jnp.concatenate([v[:, :G // 2], v[:, G // 2:]], axis=-1)
+    else:
+        q1, q2, k1, k2 = q[:, 0::2], q[:, 1::2], k[:, 0::2], k[:, 1::2]
+        v = jnp.concatenate([v[:, 0::2], v[:, 1::2]], axis=-1)
+
+    def attend(job):
+        qs, ks, start = job
+        end = min(start + block, s)
+        lo = max(start - window + 1, 0) if window else 0
+        return _one_compile_at_a_time(
+            ("attend", end - start, end - lo, qs.shape[1:], ks.shape[1:], v.shape[1:], window),
+            lambda: _attend_block(qs[start:end], ks[lo:end], v[lo:end], start, lo, window=window))
+
+    starts = list(range(0, s, block))
+    out = _in_threads(attend, [(q1, k1, t) for t in starts] + [(q2, k2, t) for t in starts])
+    a1 = jnp.concatenate(out[:len(starts)]).reshape(s, H // 2, 2 * d)
+    a2 = jnp.concatenate(out[len(starts):]).reshape(s, H // 2, 2 * d)
+    init = 0.8 - 0.6 * math.exp(-0.3 * (0 if wrong["lambda_init_layer0"] else layer))
+    return _diff_epilogue(p, a1, a2, jnp.float32(init), cfg.norm_eps, bool(wrong["diff_subln"]),
+                          bool(wrong["diff_scale"]), biased)
+
+
+@jax.jit
+def _gmu(p: dict, u, memory):
+    with jax.default_matmul_precision("highest"):
+        return (jax.nn.silu(u @ _f32(p["in_proj"])) * memory) @ _f32(p["out_proj"])
+
+
+@partial(jax.jit, static_argnames=("mean", "biased"))
+def _join_and_ffn(layer: dict, x, mixed, eps, mean: bool, biased: bool):
+    """h = x + mixed;  h + MLP(LayerNorm(h))."""
+    with jax.default_matmul_precision("highest"):
+        x = x + mixed
+        f = layer["ffn"]
+        normed = _layer_norm_rows(layer["ffn_norm"], x, eps, mean, biased)
+        return x + _swiglu(normed, _f32(f["w1"]), _f32(f["w2"]), _f32(f["w3"]))
+
+
+def _sambay_hidden(p: dict, cfg: Any, tokens, wrong: dict, blocks: Optional[int] = None,
+                   left: Optional[list] = None):
+    """SambaY's stack (cfg.layer_types with "s6" / "gmu" / "cross_attention" layers) over
+    the whole sequence: the hidden state [s, C] after the last block (after the first
+    ``blocks``, where given; ``left`` then takes the h of THAT layer, an s6 one, and the
+    block's output is not computed)."""
+    # (an int8 tree's leaves go into the jitted pieces as they are: a pytree node)
+    from seldon_core_tpu.ops.quantize import _register_pytree
+
+    _register_pytree()
+    x = _table_rows(p["tok_embeddings"], tokens)
+    memory_layer = (cfg.memory_source if wrong["gmu_memory_layer"] is None
+                    else wrong["gmu_memory_layer"])
+    memory = source_kv = None
+    window = wrong["window_wrong"] or cfg.sliding_window
+    mean, biased = bool(wrong["layer_norm_mean"]), wrong["bias_off"] != "norm"
+    for i, kind in enumerate(cfg.layer_types):
+        layer = p[f"layer_{i}"]
+        normed = _layer_norm(
+            x, layer["operator_norm" if kind in ("s6", "gmu") else "attention_norm"],
+            cfg.norm_eps, wrong)
+        if blocks is not None and i == blocks:
+            _mamba1(layer["s6"], normed, cfg, wrong, left=left)
+            return x
+        if kind == "s6":
+            handed = [] if i == memory_layer else None
+            mixed = _mamba1(layer["s6"], normed, cfg, wrong, handed=handed)
+            if handed:
+                memory, = handed
+        elif kind == "gmu":
+            mixed = _gmu(layer["gmu"], normed, memory)
+        elif kind == "cross_attention":
+            kv = source_kv
+            if wrong["cross_kv_own"]:       # WRONG: of the layer's OWN input
+                source = p[f"layer_{cfg.kv_source}"]["attention"]
+                kv = tuple(_project_heads(normed, source[w], source[b], cfg.n_kv_heads, True)
+                           for w, b in (("wk", "bk"), ("wv", "bv")))
+            mixed = _diff_attention(layer["attention"], normed, cfg, i, wrong, kv=kv)
+        else:
+            made = [] if i == cfg.kv_source else None
+            mixed = _diff_attention(layer["attention"], normed, cfg, i, wrong,
+                                    window if kind == "sliding_attention" else 0, made=made)
+            if made:
+                source_kv, = made
+        x = _join_and_ffn({"ffn": layer["ffn"], "ffn_norm": layer["ffn_norm"]}, x, mixed,
+                          cfg.norm_eps, mean, biased)
+    return x
+
+
+def _is_sambay(cfg: Any) -> bool:
+    return bool(set(getattr(cfg, "layer_types", None) or ()) & {"s6", "gmu", "cross_attention"})
+
+
+def _final_norm(p: dict, cfg: Any, x, wrong: dict):
+    if getattr(cfg, "norm", "rms") == "layer":
+        return _layer_norm(x, p["norm"], cfg.norm_eps, wrong)
+    return _rms_norm(x, p["norm"]["weight"], cfg.norm_eps)
+
+
 EXPERT_ROWS = 64     # an expert's tokens are computed in whole buckets of this many rows
 
 
@@ -847,7 +1112,11 @@ WRONG = {"leave_out_rank": None, "scale_mscale": True, "shared": True, "streams"
          "rope_on_window": True, "router_input": None, "ffn_act": None, "renormalize": None,
          "ssd_state_bf16": False, "ssd_gate_after_norm": False, "attention_multiplier_off": False,
          "residual_multiplier_off": False, "ssd_skip": True, "ssd_conv_bias": True,
-         "ssd_conv_bc": True, "ssd_reset_every": None}
+         "ssd_conv_bc": True, "ssd_reset_every": None,
+         "s6_state_bf16": False, "s6_reset_every": None, "gmu_memory_gated": False,
+         "gmu_memory_layer": None, "gmu_memory_skip": True, "cross_kv_own": False,
+         "diff_pairs": "stripes", "lambda_init_layer0": False, "diff_subln": True,
+         "diff_scale": True, "layer_norm_mean": True, "bias_off": None}
 
 
 def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[list] = None,
@@ -855,6 +1124,8 @@ def _hidden(p: dict, cfg: Any, tokens, wrong: dict, follow=None, seen: Optional[
     """The main model's hidden state [s, C] after the last block (after the
     first ``blocks`` of them, where given) and the streams' exit, before the
     final norm; and the routing."""
+    if _is_sambay(cfg):
+        return _sambay_hidden(p, cfg, tokens, wrong, blocks), []
     if wrong["conv_state_pad"] is not None and len(wrong["conv_state_pad"]) == 2:
         # what each conv layer's LAST rows hold when the first n tokens are
         # padded with token 0 to m rows: a pass of its own
@@ -912,8 +1183,8 @@ def forward(params: Any, cfg: Any, tokens, rows=slice(None), follow=None, **wron
     p = params.get("params", params)
     with jax.default_matmul_precision("highest"):
         x, routing = _hidden(p, cfg, tokens, wrong, follow)
-        x = _rms_norm(x, p["norm"]["weight"], cfg.norm_eps)
-        return x[rows] @ _head(p, cfg) / getattr(cfg, "logits_scaling", 1.0), routing
+        x = _final_norm(p, cfg, x[rows], wrong)
+        return x @ _head(p, cfg) / getattr(cfg, "logits_scaling", 1.0), routing
 
 
 def ssd_state(params: Any, cfg: Any, tokens, layer: int, **wrong):
@@ -929,6 +1200,18 @@ def ssd_state(params: Any, cfg: Any, tokens, layer: int, **wrong):
         mixer, left = p[f"layer_{layer}"], []
         _mamba2(mixer["mamba"], _rms_norm(x, mixer["operator_norm"]["weight"], cfg.norm_eps),
                 cfg, wrong, left=left)
+        return left[0]
+
+
+def s6_state(params: Any, cfg: Any, tokens, layer: int, **wrong):
+    """The h [E, N] float32 that s6 layer ``layer`` holds after ``tokens`` [s], from zeros,
+    token by token (``ssd_state``'s counterpart): ``s6_state_bf16=True`` rounds h to bf16
+    after every token, which is what a cache that held it in bf16 would do."""
+    wrong = {**WRONG, **wrong}
+    p = params.get("params", params)
+    with jax.default_matmul_precision("highest"):
+        left: list = []
+        _sambay_hidden(p, cfg, tokens, wrong, blocks=layer, left=left)
         return left[0]
 
 

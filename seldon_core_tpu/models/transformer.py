@@ -51,13 +51,28 @@ from seldon_core_tpu.models.registry import register_model
 param_with_axes = nn_partitioning.param_with_axes
 with_sharding_constraint = nn_partitioning.with_sharding_constraint
 
-LAYER_KINDS = ("full_attention", "sliding_attention", "conv", "linear_attention", "mamba")
+LAYER_KINDS = ("full_attention", "sliding_attention", "conv", "linear_attention", "mamba",
+               "s6", "gmu", "cross_attention")
 # the kinds that are ATTENTION (pages of K and V rows): a "sliding_attention"
 # layer's mask has a lower bound too (``sliding_window``) and its pages are of
 # the window class (models/cache.py WindowEntry), given back behind the window
 ATTENTION_LAYER_KINDS = ("full_attention", "sliding_attention")
-# the kinds whose layer keeps a fixed block of STATE a sequence, not pages
-STATE_LAYER_KINDS = ("conv", "linear_attention", "mamba")
+# the kinds whose layer holds NO pages of its own: a fixed block of STATE a
+# sequence, which may be empty (a "gmu" reads the call's own rows of another
+# layer's scan output, a "cross_attention" layer another layer's pages:
+# ``memory_source`` / ``kv_source``)
+STATE_LAYER_KINDS = ("conv", "linear_attention", "mamba", "s6", "gmu", "cross_attention")
+# SambaY's three (Phi-4-mini-flash-reasoning): Mamba-1's selective scan, the
+# gated memory unit and cross-attention over ONE layer's K/V
+SAMBAY_LAYER_KINDS = ("s6", "gmu", "cross_attention")
+SAMBAY_LAYERS_COMPOSE_REFUSAL = (
+    "an 's6', 'gmu' or 'cross_attention' layer (layer_types; Mamba1Mixer, GatedMemoryUnit, "
+    "differential cross-attention), differential attention, norm='layer' and attention_bias are "
+    "built for one device beside per-head K/V attention with the bf16 cache and a dense FFN: not "
+    "with a mesh, experts (n_experts > 0), latent attention (kv_lora_rank > 0), "
+    "hyper-connections (hc_mult > 1), an MTP module, LoRA adapters, fused_norm, "
+    "norm_placement='branch', qk_norm, attn_gate, a rotary embedding or kv_cache_dtype='int8': "
+    "no model pairs them and no test holds them")
 STATE_LAYERS_COMPOSE_REFUSAL = (
     "a 'conv', 'linear_attention' or 'mamba' layer (layer_types) does not compose with "
     "latent attention (kv_lora_rank > 0), hyper-connections (hc_mult > 1) or an "
@@ -86,6 +101,7 @@ class LayerReads(NamedTuple):
     window: int    # the keys a query sees; 0 = all before it
     rotary: bool   # q and k are turned by their positions
     routed: bool   # the FFN routes experts
+    hands_up: bool = False   # an "s6" layer that also returns its scan output (``memory_source``)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -221,6 +237,36 @@ class TransformerConfig:
     attention_multiplier: Optional[float] = None
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # SambaY's decoder-hybrid-decoder (Phi-4-mini-flash-reasoning, arXiv
+    # 2507.06607). An "s6" layer is Mamba-1's selective-scan mixer (Mamba1Mixer
+    # below): mamba_d_inner channels, a state of mamba_d_state a channel, a
+    # depthwise causal convolution of mamba_d_conv taps (mamba_conv_bias),
+    # Delta through a bottleneck of mamba_dt_rank; it keeps, a sequence, the
+    # last taps - 1 rows of x and one float32 h [mamba_d_state, mamba_d_inner]:
+    # the cache entry is the 2-tuple ``(conv_state, h)``. ``memory_source``: the
+    # "s6" layer that also hands its scan output m (before the gate) up the
+    # stack, which every "gmu" layer (GatedMemoryUnit) reads: the SAME token's
+    # row in the SAME call, so a "gmu" layer caches nothing. ``kv_source``: the
+    # "full_attention" layer whose K and V every "cross_attention" layer reads
+    # in place (queries of its own; it projects, writes and keeps none). Every
+    # layer past ``kv_source`` is one of those two, so a prompt's chunk runs
+    # the layers up to it on its rows and the rest on the ONE row whose logits
+    # are read (``Transformer.__call__`` ``head_row``).
+    mamba_d_inner: int = 0
+    mamba_dt_rank: int = 0
+    memory_source: Optional[int] = None
+    kv_source: Optional[int] = None
+    # Differential attention (arXiv 2410.05258) in EVERY attention layer: the
+    # heads pair in stripes (q[2j], q[2j+1]; k[2g], k[2g+1]; v_g = [v[2g] ;
+    # v[2g+1]]), two softmaxes over the same mask are subtracted under a
+    # learned scalar lambda a layer, and a norm over 2 head_dim lanes follows
+    # (``Attention``). ``attention_bias``: a bias on the q, k, v and output
+    # projections. ``norm``: "rms" (RMSNorm, a weight) or "layer" (LayerNorm:
+    # the mean taken out too, a weight AND a bias) for the two norms of every
+    # block and the final one.
+    differential: bool = False
+    attention_bias: bool = False
+    norm: str = "rms"
     # Where a block's two RMSNorms stand: "pre" = x + f(norm(x)) (Llama and
     # every other family served); "branch" = x + norm(f(x)), nothing normed
     # before the mixer or the FFN (Olmo 2 / 3). The same two weights a layer.
@@ -275,6 +321,21 @@ class TransformerConfig:
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
         # what is not built is refused where the config is made: at load()
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"unknown norm {self.norm!r}: expected 'rms' or 'layer'")
+        sambay = (self.differential or self.attention_bias or self.norm == "layer"
+                  or set(self.layer_types or ()) & set(SAMBAY_LAYER_KINDS))
+        if sambay and (self.mesh is not None or self.n_experts or self.kv_lora_rank
+                       or self.hc_mult > 1 or self.mtp_layers or self.fused_norm
+                       or self.norm_placement != "pre" or self.qk_norm or self.attn_gate
+                       or self.rope_theta is not None or self.attention_impl == "ring"
+                       or normalize_kv_cache_dtype(self.kv_cache_dtype) == "int8"):
+            raise ValueError(SAMBAY_LAYERS_COMPOSE_REFUSAL)
+        if self.differential and (self.n_heads % 2 or self.n_kv_heads % 2
+                                  or (self.n_heads // 2) % (self.n_kv_heads // 2)):
+            raise ValueError(
+                "differential attention pairs the heads: n_heads and n_kv_heads must be even, "
+                "and the pairs of queries a multiple of the pairs of keys")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"unknown router_score {self.router_score!r}: expected 'softmax' or 'sigmoid'")
@@ -337,6 +398,25 @@ class TransformerConfig:
                         "mamba_d_head, mamba_d_state and mamba_d_conv >= 2")
                 if self.mesh is not None or self.n_experts or "linear_attention" in kinds:
                     raise ValueError(MAMBA_LAYERS_COMPOSE_REFUSAL)
+            if "s6" in kinds and (min(self.mamba_d_inner, self.mamba_d_state, self.mamba_dt_rank)
+                                  <= 0 or self.mamba_d_conv < 2):
+                raise ValueError(
+                    "an 's6' layer needs mamba_d_inner, mamba_d_state, mamba_dt_rank and "
+                    "mamba_d_conv >= 2")
+            for source, kind, reader in (("memory_source", "s6", "gmu"),
+                                         ("kv_source", "full_attention", "cross_attention")):
+                at, readers = getattr(self, source), [i for i, k in enumerate(kinds) if k == reader]
+                if at is None and not readers:
+                    continue
+                if at is None or not 0 <= at < len(kinds) or kinds[at] != kind or (
+                        readers and min(readers) < at):
+                    raise ValueError(
+                        f"{source}={at} must name a {kind!r} layer before every {reader!r} layer")
+            if self.kv_source is not None and set(kinds[self.kv_source + 1:]) - {
+                    "gmu", "cross_attention"}:
+                raise ValueError(
+                    "every layer past kv_source is a 'gmu' or a 'cross_attention' layer (the "
+                    "cross-decoder: a prompt's chunk runs it on one row)")
             if "linear_attention" in kinds:
                 hk, hv = self.linear_num_key_heads, self.linear_num_value_heads
                 if (min(hk, hv, self.linear_key_head_dim, self.linear_value_head_dim) <= 0
@@ -408,7 +488,8 @@ class TransformerConfig:
         its FFN routes experts (dense under ``first_dense_layers``)."""
         return LayerReads(self.layer_kind(layer), self.layer_window(layer),
                           self.layer_rotary(layer),
-                          self.n_experts > 0 and layer >= self.first_dense_layers)
+                          self.n_experts > 0 and layer >= self.first_dense_layers,
+                          layer == self.memory_source)
 
     def layer_class(self, layer: int) -> int:
         """The FIRST layer that ``TransformerBlock`` builds as it builds
@@ -418,12 +499,22 @@ class TransformerConfig:
         return next(i for i in range(layer + 1) if self.layer_reads(i) == mine)
 
     @property
+    def cross_decoder_layers(self) -> Tuple[int, ...]:
+        """The layers past cfg.kv_source (none without one): a prompt's chunk
+        runs them inside the conditional that skips the head, so a server that
+        holds int8 weights hands THEIR matrices on as they are held, as it does
+        the head (``Transformer`` dequantizes them where they are multiplied)."""
+        return tuple(range(self.kv_source + 1, self.n_layers)) if self.kv_source is not None else ()
+
+    @property
     def window_layers(self) -> Tuple[int, ...]:
         """The attention layers whose pages are of the window class."""
         return self.layers_of("sliding_attention")
 
     def small_leaf(self, name: str) -> str:
         """The SMALL_LEAF_INIT rule a seeded float32 leaf ``name`` is drawn by."""
+        if name in ("bq", "bk", "bv", "bo"):       # a projection's bias: the one rule of every bias
+            return "bias"
         return "dt_bias_range" if name == "dt_bias" and self.linear_dt_bias == "range" else name
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
@@ -438,6 +529,21 @@ class TransformerConfig:
         """The layers that keep a fixed state block a sequence (no pages)."""
         return tuple(i for i in range(self.n_layers)
                      if self.layer_kind(i) in STATE_LAYER_KINDS)
+
+    def lambda_init(self, layer: int) -> float:
+        """Differential attention's lambda_init of ``layer`` (arXiv 2410.05258)."""
+        return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+    @property
+    def read_heads(self) -> Tuple[int, int, int]:
+        """(query heads, KV heads, head width) as the attention READS see them.
+        Differential attention's pairs read as plain GQA over KV heads of
+        [k1_g ; k2_g] and [v1_g ; v2_g], 2 head_dim lanes each (a token's flat
+        row as it lies), by queries zero-padded to that width ([q1 ; 0], [0 ;
+        q2]): half the KV heads at twice the width."""
+        if self.differential:
+            return self.n_heads, self.n_kv_heads // 2, 2 * self.head_dim
+        return self.n_heads, self.n_kv_heads, self.head_dim
 
     @property
     def rotary_dim(self) -> int:
@@ -592,6 +698,49 @@ class RMSNorm(nn.Module):
         return rms_norm(x, w, self.eps)
 
 
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray, eps: float) -> jnp.ndarray:
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    norm = centred * jax.lax.rsqrt(jnp.mean(centred * centred, axis=-1, keepdims=True) + eps)
+    return (norm * weight + bias).astype(x.dtype)
+
+
+class LayerNorm(nn.Module):
+    """LayerNorm over the last axis: the mean taken out, a weight AND a bias
+    (cfg.norm "layer": Phi-4-mini-flash). The bias is a float32 leaf of every
+    tree (FLOAT32_AXES "norm_bias"), seeded normal(0, 0.02) and not zeros, so
+    that a bias left out is seen."""
+
+    dim: int
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x=None):
+        """x=None returns the bare (weight, bias), as ``RMSNorm``'s."""
+        w = param_with_axes("weight", nn.initializers.ones_init(), (self.dim,), jnp.float32,
+                            axes=("embed",))
+        bias = param_with_axes("bias", small_leaf_init("bias"), (self.dim,), jnp.float32,
+                               axes=("norm_bias",))
+        if x is None:
+            return w, bias
+        return layer_norm(x, w, bias, self.eps)
+
+
+def block_norm(cfg: "TransformerConfig", name: str):
+    """The norm of a block's sub-layer or the model's last: cfg.norm's."""
+    if cfg.norm == "layer":
+        return LayerNorm(cfg.dim, cfg.norm_eps, name=name)
+    return RMSNorm(cfg.dim, cfg.norm_eps, name=name)
+
+
+def normed_by(cfg: "TransformerConfig", weights, x: jnp.ndarray) -> jnp.ndarray:
+    """``block_norm(cfg, name)(x)`` from the module's bare ``weights`` (its
+    ``__call__()``): where no module may be made (a conditional's branch)."""
+    if cfg.norm == "layer":
+        return layer_norm(x, *weights, cfg.norm_eps)
+    return rms_norm(x, weights, cfg.norm_eps)
+
+
 def attention_mask(key_pos, query_pos, window: int = 0):
     """[b, s, L] bool: the ONE predicate of every attention read. ``key_pos``
     [b, L] are the CACHED positions (PAD_POS = empty), ``query_pos`` [b, s]:
@@ -693,17 +842,22 @@ def lora_delta(x: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
 class Attention(nn.Module):
     cfg: TransformerConfig
     # the layer's own (TransformerBlock reads them off cfg.layer_types /
-    # cfg.rope_layout): the keys a query sees (0 = every one before it) and
-    # whether q and k are turned by their positions
+    # cfg.rope_layout): the keys a query sees (0 = every one before it),
+    # whether q and k are turned by their positions, and whether the layer is a
+    # "cross_attention" one: queries of its own over ANOTHER layer's K and V
+    # (``cache`` is then that layer's entry as its write left it, read in place;
+    # the layer projects no k and v, writes nothing and keeps nothing)
     window: int = 0
     rotary: bool = True
+    cross: bool = False
 
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
                  cache_index: Optional[jnp.ndarray] = None,
                  block_tables: Optional[jnp.ndarray] = None,
                  adapters: Optional[dict] = None,
-                 adapter_ids: Optional[jnp.ndarray] = None):
+                 adapter_ids: Optional[jnp.ndarray] = None,
+                 lambda_init: Optional[jnp.ndarray] = None):
         """x: [b, s, d]; returns (out, new_cache). ``cache`` is this layer's
         entry of the cache tree (models/cache.py: bf16 ``(k, v, pos)`` or int8
         ``(kq, ks, vq, vs, pos)``, dense [b, max_len, ...] or, with
@@ -718,7 +872,22 @@ class Attention(nn.Module):
         (grouped_query_attention), so paged and dense decode are bit-exact
         (tests/test_paged_kv.py), or walks the live pages with the repo's
         kernel where ``paged_read_walk`` says so.
-        Without a cache: full causal attention, returns (out, (k, v))."""
+        Without a cache: full causal attention, returns (out, (k, v)).
+
+        DIFFERENTIAL attention (cfg.differential; ``lambda_init`` the layer's,
+        a traced float32 scalar, so that the layers of a class share a trace):
+        with the heads paired in stripes,
+
+            a1_j = softmax(q[2j] k[2g]^T d^-1/2) v_g   a2_j = softmax(q[2j+1] k[2g+1]^T d^-1/2) v_g
+            lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init
+            o_j = (1 - lambda_init) w * RMSNorm_2d(a1_j - lambda a2_j)
+
+        v_g = [v[2g] ; v[2g+1]], g = j // (pairs of queries a pair of keys). Both
+        softmaxes are ONE read of plain GQA over KV heads 2d wide
+        (cfg.read_heads): a token's cached row as it lies is [k[2g] ; k[2g+1]]
+        a group, and the queries go in zero-padded, [q[2j] ; 0] and [0 ;
+        q[2j+1]] (the zeros add nothing to a score); the subtraction, the norm
+        and the scale are an epilogue under ``attn.diff``."""
         cfg = self.cfg
         b, s, _ = x.shape
         hd = cfg.head_dim
@@ -727,21 +896,33 @@ class Attention(nn.Module):
             "wq", nn.initializers.lecun_normal(), (cfg.dim, cfg.n_heads * hd), jnp.float32,
             axes=("embed", "heads"),
         )
-        wk = param_with_axes(
-            "wk", nn.initializers.lecun_normal(), (cfg.dim, cfg.n_kv_heads * hd), jnp.float32,
-            axes=("embed", "kv_heads"),
-        )
-        wv = param_with_axes(
-            "wv", nn.initializers.lecun_normal(), (cfg.dim, cfg.n_kv_heads * hd), jnp.float32,
-            axes=("embed", "kv_heads"),
-        )
+        if not self.cross:
+            wk = param_with_axes(
+                "wk", nn.initializers.lecun_normal(), (cfg.dim, cfg.n_kv_heads * hd), jnp.float32,
+                axes=("embed", "kv_heads"),
+            )
+            wv = param_with_axes(
+                "wv", nn.initializers.lecun_normal(), (cfg.dim, cfg.n_kv_heads * hd), jnp.float32,
+                axes=("embed", "kv_heads"),
+            )
         wo = param_with_axes(
             "wo", nn.initializers.lecun_normal(), (cfg.n_heads * hd, cfg.dim), jnp.float32,
             axes=("heads", "embed"),
         )
 
         dt = cfg.dtype
-        q_flat = x @ wq.astype(dt)
+        biased = cfg.attention_bias
+
+        def project(w, name, width):
+            """x W (+ the projection's bias: the product left float32, the bias
+            added there, rounded once)."""
+            if not biased:
+                return x @ w.astype(dt)
+            bias = param_with_axes(name, small_leaf_init("bias"), (width,), jnp.float32,
+                                   axes=("attn_bias",))
+            return jnp.matmul(x, w.astype(dt), preferred_element_type=jnp.float32) + bias
+
+        q_flat = project(wq, "bq", cfg.n_heads * hd)
         if cfg.attn_gate:
             # a gate a head beside its query (the published q_proj makes both;
             # models/convert.py splits it): sigmoid(gate) weighs the heads'
@@ -757,44 +938,68 @@ class Attention(nn.Module):
             # the paged pool/prefix machinery stays tenant-agnostic
             q_flat = q_flat + lora_delta(x, *adapters["wq"], adapter_ids,
                                          adapters["scale"])
-        k_flat = x @ wk.astype(dt)
-        if cfg.qk_norm is True:
-            q_flat = RMSNorm(cfg.n_heads * hd, cfg.norm_eps, "heads", name="q_norm")(q_flat)
-            k_flat = RMSNorm(cfg.n_kv_heads * hd, cfg.norm_eps, "kv_heads", name="k_norm")(k_flat)
+        if not self.cross:
+            k_flat = project(wk, "bk", cfg.n_kv_heads * hd).astype(dt)
+            if cfg.qk_norm is True:
+                q_flat = RMSNorm(cfg.n_heads * hd, cfg.norm_eps, "heads", name="q_norm")(q_flat)
+                k_flat = RMSNorm(cfg.n_kv_heads * hd, cfg.norm_eps, "kv_heads", name="k_norm")(k_flat)
+            k = k_flat.reshape(b, s, cfg.n_kv_heads, hd)
+            v = project(wv, "bv", cfg.n_kv_heads * hd).astype(dt).reshape(b, s, cfg.n_kv_heads, hd)
         q = q_flat.reshape(b, s, cfg.n_heads, hd)
-        k = k_flat.reshape(b, s, cfg.n_kv_heads, hd)
         if cfg.qk_norm == "head":
             # one weight [head_dim] for every query head and one for every KV
             # head, float32 in every tree (FLOAT32_AXES)
             q = RMSNorm(hd, cfg.norm_eps, "head_norm", name="q_norm")(q)
             k = RMSNorm(hd, cfg.norm_eps, "head_norm", name="k_norm")(k)
-        v = (x @ wv.astype(dt)).reshape(b, s, cfg.n_kv_heads, hd)
-        if cfg.attention_multiplier is not None:
-            # every read below scales the scores by head_dim^-1/2: the config's
-            # own scale reaches them on the queries (Granite's 1/64 at heads of
-            # 64 is q / 8, exact in any float)
-            q = q * (cfg.attention_multiplier * hd ** 0.5)
+        # the heads as the reads below see them (differential: pairs, 2 hd wide)
+        n_heads, kvh, width = cfg.read_heads
+        if cfg.attention_multiplier is not None or width != hd:
+            # every read below scales the scores by its heads' width^-1/2: the
+            # config's own scale (Granite's 1/64 at heads of 64 is q / 8, exact
+            # in any float), or a pair's (hd^-1/2 over reads 2 hd wide), reaches
+            # them on the queries
+            scale = hd ** -0.5 if cfg.attention_multiplier is None else cfg.attention_multiplier
+            q = q * (scale * width ** 0.5)
+        q = q.astype(dt)
 
         if cfg.rope_theta is not None and self.rotary:
             cos, sin = rotary_embedding(positions, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling)
             q = apply_partial_rotary(q, cos, sin)
             k = apply_partial_rotary(k, cos, sin)
+        if cfg.differential:
+            # [q[2j] ; 0] and [0 ; q[2j+1]]: 2 hd wide, the pair's two heads
+            pair = q.reshape(b, s, n_heads // 2, 2, hd)
+            nothing = jnp.zeros_like(pair[:, :, :, 0])
+            q = jnp.stack([jnp.concatenate([pair[:, :, :, 0], nothing], axis=-1),
+                           jnp.concatenate([nothing, pair[:, :, :, 1]], axis=-1)],
+                          axis=3).reshape(b, s, n_heads, width)
 
         window = self.window
-        # a window layer's read and write are scopes of their own (attn.window.*)
-        scope = "attn.window" if window else "attn.gqa"
+        # a window layer's read and write are scopes of their own (attn.window.*),
+        # a cross layer's read likewise (attn.cross.read)
+        scope = "attn.cross" if self.cross else "attn.window" if window else "attn.gqa"
         out = None
-        if cache is None:
-            k_all, v_all, pos_view, new_cache = k, v, positions, (k, v)
+
+        def as_read(rows):      # [b, L, ...] K or V rows as the reads see the heads
+            return rows.reshape(rows.shape[:2] + (kvh, width))
+
+        if cache is None or (self.cross and len(cache) == 2):
+            # no cache: the call's own rows (a cross layer: its source's, ``(k, v)``)
+            k_all, v_all = (k, v) if cache is None else cache
+            new_cache = (k_all, v_all)
+            k_all, v_all, pos_view = as_read(k_all), as_read(v_all), positions
         else:
-            # the entry's rows: int8 quantizes on write (values + a scale a
-            # head), and the read dequantizes fused into its einsums
-            with jax.named_scope(scope + ".write"):
-                rows = (*quantize_kv(k), *quantize_kv(v)) if entry_is_int8(cache) else (k, v)
-                new_cache = write_rows(cache, rows, positions, block_tables=block_tables,
-                                       cache_index=cache_index)
+            new_cache = cache       # (a cross layer reads its source's entry as it came)
+            if not self.cross:
+                # the entry's rows: int8 quantizes on write (values + a scale a
+                # head), and the read dequantizes fused into its einsums
+                with jax.named_scope(scope + ".write"):
+                    rows = (*quantize_kv(k), *quantize_kv(v)) if entry_is_int8(cache) else (k, v)
+                    new_cache = write_rows(cache, rows, positions, block_tables=block_tables,
+                                           cache_index=cache_index)
             if block_tables is None:
                 k_all, v_all, pos_view = dense_view(new_cache, dt)
+                k_all, v_all = as_read(k_all), as_read(v_all)
             else:
                 # The read as an expression gathers the logical view and runs
                 # the SAME chain the dense layout uses: paged == dense
@@ -809,11 +1014,9 @@ class Attention(nn.Module):
                 with jax.named_scope(scope + ".read"):
                     if walk is not None:
                         out = paged_live_read(q, new_cache, bt, positions,
-                                              n_kv_heads=cfg.n_kv_heads, walk=walk, window=window)
+                                              n_kv_heads=kvh, walk=walk, window=window)
                     else:
-                        out = paged_attention_ref(q, new_cache, bt, positions, cfg.n_kv_heads,
-                                                  window)
-
+                        out = paged_attention_ref(q, new_cache, bt, positions, kvh, window)
         if cache is None and cfg.attention_impl == "ring":
             from seldon_core_tpu.ops.ring_attention import ring_attention
 
@@ -828,14 +1031,31 @@ class Attention(nn.Module):
             mask = attention_mask(pos_view, positions, window)  # [b, s, kv]
             with jax.named_scope(scope + ".read"):
                 out = grouped_query_attention(q, k_all, v_all, mask)
+        if cfg.differential:
+            with jax.named_scope("attn.diff"):
+                lq1, lk1, lq2, lk2 = param_with_axes(
+                    "lambdas", small_leaf_init("lambdas"), (4, hd), jnp.float32,
+                    axes=("attn_lambda", "head_norm"))
+                lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lambda_init
+                a = out.astype(jnp.float32).reshape(b, s, n_heads // 2, 2, width)
+                normed = RMSNorm(width, cfg.norm_eps, "head_norm", name="subln")(
+                    a[:, :, :, 0] - lam * a[:, :, :, 1])
+                out = (normed * (1.0 - lambda_init)).astype(dt)
         out = out.reshape(b, s, cfg.n_heads * hd)
         if cfg.attn_gate:
             out = (out * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
-        proj = out @ wo.astype(dt)
+        if biased:
+            bo = param_with_axes("bo", small_leaf_init("bias"), (cfg.dim,), jnp.float32,
+                                 axes=("attn_bias",))
+            proj = (jnp.matmul(out, wo.astype(dt), preferred_element_type=jnp.float32)
+                    + bo).astype(dt)
+        else:
+            proj = out @ wo.astype(dt)
         if adapters is not None:
             proj = proj + lora_delta(out, *adapters["wo"], adapter_ids,
                                      adapters["scale"])
-        return proj, new_cache
+        # (a cross layer keeps nothing of its own: the block hands on an empty entry)
+        return proj, None if self.cross else new_cache
 
 
 def latent_attention_scale(cfg: TransformerConfig) -> float:
@@ -933,7 +1153,7 @@ def paged_read_walk(cfg: "TransformerConfig", s: int, n_pages: int, page_size: i
                 or plan(s, cfg.n_heads, n_pages, page_size, cfg.latent_row_dim, cfg.kv_lora_rank))
     if not cfg.kv_rows_flat:
         return None
-    return gqa_plan(s, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, n_pages, page_size)
+    return gqa_plan(s, *cfg.read_heads, n_pages, page_size)
 
 
 def read_form(walk) -> str:
@@ -1372,10 +1592,18 @@ def moe_routing_stats(sown: dict, cfg: TransformerConfig):
 # (SSD_HEADS): ``A_log`` = log(1 .. heads) (no draw), ``dt_bias`` by DT_RANGE over
 # (0.001, 0.1) (the file's time_step_min / _max), ``D`` ones; the taps as Gated
 # DeltaNet's and the convolution's bias normal(0, 1/2) likewise.
+# Mamba-1's (Mamba1Mixer; ``Mamba``'s published initialisation): ``A_log_t`` =
+# log(1 .. N) a channel (S6_A: no draw; held [N, channels], the state's layout),
+# ``b_dt`` by DT_RANGE over (0.001, 0.1), ``D`` ones, the taps and the
+# convolution's bias as Mamba-2's. Differential attention's four lambda vectors
+# (``lambdas`` [4, head_dim]) normal(0, 0.1); the sub-norm's weight ones. A
+# projection's or a LayerNorm's ``bias`` normal(0, 0.02), NOT zeros: a seeded
+# model whose biases are zeros cannot show a bias that is left out.
 FLOAT32_AXES = ("hc_maps", "expert_select", "conv_taps", "head_norm", "gdn_scalar", "expert_gate",
-                "ssd_scalar")
+                "ssd_scalar", "norm_bias", "attn_bias")
 LOG_UNIFORM = "log of uniform"
 SSD_HEADS = "A_log, dt_bias and D a head, stacked"
+S6_A = "log of 1 .. N, a channel"
 DT_RANGE = "softplus inverse of a log-uniform step"
 SMALL_LEAF_INIT = {
     "phi": (0.0, None), "alpha": (0.7, 0.05), "b_pre": (0.0, 0.5), "b_post": (0.0, 0.5),
@@ -1384,6 +1612,8 @@ SMALL_LEAF_INIT = {
     "A_log": (LOG_UNIFORM, (0.0, 16.0)), "shared_gate": (0.0, None),
     "dt_bias_range": (DT_RANGE, (1e-3, 1e-1)),
     "heads": (SSD_HEADS, (1e-3, 1e-1)), "conv_bias": (0.0, 0.5),
+    "A_log_t": (S6_A, None), "b_dt": (DT_RANGE, (1e-3, 1e-1)), "D": (1.0, 0.0),
+    "lambdas": (0.0, 0.1), "bias": (0.0, 0.02),
 }
 
 
@@ -1398,6 +1628,10 @@ def draw_small_leaf(name: str, key, shape) -> jnp.ndarray:
         return jnp.stack([jnp.log(jnp.arange(1, heads + 1, dtype=jnp.float32)),
                           draw_small_leaf("dt_bias_range", key, (heads,)),
                           jnp.ones((heads,), jnp.float32)])
+    if mean == S6_A:
+        states = shape[0]
+        return jnp.broadcast_to(
+            jnp.log(jnp.arange(1, states + 1, dtype=jnp.float32))[:, None], shape)
     if mean == DT_RANGE:
         low, high = std
         dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, math.log(low), math.log(high)))
@@ -1951,6 +2185,113 @@ class Mamba2Mixer(nn.Module):
             return normed.astype(dt) @ w_out.astype(dt), new_cache
 
 
+def dt_proj_init(key, shape, dtype=jnp.float32):
+    """Mamba-1's published init of the step's up-projection: U(+- rank^-1/2)."""
+    bound = float(shape[0]) ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+class Mamba1Mixer(nn.Module):
+    """Mamba-1's selective-scan mixer (arXiv 2312.00752; ``transformers``
+    ``MambaMixer.slow_forward``), the token mixer of an "s6" layer. With E =
+    cfg.mamba_d_inner channels, a state of N, a step bottleneck of R:
+
+        [x ; z] = W_in u                        W_in [dim, 2 E], no bias
+        x <- SiLU(causal depthwise taps over the E channels + conv bias)
+        [dt ; B ; C] = W_x x                    W_x [E, R + 2 N]
+        Delta = softplus(W_dt dt + b_dt)        W_dt [R, E], a channel
+        A = -exp(A_log)                         a (state, channel) pair; the leaf
+                                                ``A_log_t`` [N, E] is held as h is
+        h <- e^(Delta A) h + Delta B x;  y = sum_n C h + D x     ops/selective_scan.py
+        out = W_out (y * SiLU(z))               W_out [E, dim]
+
+    Every product leaves float32 (Delta is a decay's exponent; x is rounded to
+    the serving dtype where it meets the taps, as the rows a sequence keeps
+    are). What a sequence keeps between calls, whatever its length: the last
+    taps - 1 rows of x BEFORE the convolution, in the serving dtype
+    (``short_conv``'s state and rule), and h [N, E] in float32: the cache entry
+    is the 2-tuple ``(conv_state [rows, taps - 1, E], h [rows, N, E])``.
+    ``state_slots`` is ShortConv's. A sequence that starts (its first row at
+    position 0) reads h as zeros, so admission resets nothing; a row that is
+    no token has Delta = 0 and leaves h as it came. ``hands_up`` (the layer is
+    cfg.memory_source): also returns m = y, the scan's output BEFORE the gate
+    with D x in it, float32 [b, s, E]: what every "gmu" layer above reads.
+    Without a cache: from zeros, returns (out, (conv_state, h)[, m]) as well."""
+
+    cfg: TransformerConfig
+    hands_up: bool = False
+
+    @nn.compact
+    def __call__(self, x, positions, valid=None, cache=None, state_slots=None):
+        from seldon_core_tpu.ops.selective_scan import selective_scan
+
+        cfg = self.cfg
+        d, dt, f32 = cfg.dim, cfg.dtype, jnp.float32
+        E, N, R = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
+        lecun = nn.initializers.lecun_normal()
+        w_in = param_with_axes("in_proj", lecun, (d, 2 * E), f32, axes=("embed", "ssd_proj"))
+        taps = param_with_axes("conv1d", small_leaf_init("conv1d"), (E, cfg.mamba_d_conv), f32,
+                               axes=("ssd_channel", "conv_taps"))
+        conv_bias = param_with_axes("conv_bias", small_leaf_init("conv_bias"), (E,), f32,
+                                    axes=("ssd_scalar",)) if cfg.mamba_conv_bias else 0.0
+        w_x = param_with_axes("x_proj", lecun, (E, R + 2 * N), f32, axes=("ssd_inner", "ssd_proj"))
+        w_dt = param_with_axes("dt_proj", dt_proj_init, (R, E), f32, axes=("ssd_rank", "ssd_inner"))
+        b_dt = param_with_axes("b_dt", small_leaf_init("b_dt"), (E,), f32, axes=("ssd_scalar",))
+        a_log = param_with_axes("A_log_t", small_leaf_init("A_log_t"), (N, E), f32,
+                                axes=("ssd_scalar", "ssd_channel"))
+        skip = param_with_axes("D", small_leaf_init("D"), (E,), f32, axes=("ssd_scalar",))
+        w_out = param_with_axes("out_proj", lecun, (E, d), f32, axes=("ssd_inner", "embed"))
+        b, s, _ = x.shape
+        if valid is None:
+            valid = positions < PAD_POS
+        with jax.named_scope("mix.s6.in"):
+            xz = jnp.matmul(x, w_in.astype(dt), preferred_element_type=f32)
+        conv_state, state = state_rows(cache, state_slots, 2)
+        with jax.named_scope("mix.s6.conv"):
+            # the taps read x in the serving dtype, as the rows a sequence keeps
+            # are held: a row reads the same whichever call it is read in
+            mixed, new_conv = short_conv(xz[..., :E].astype(dt), taps, conv_state, positions, valid)
+            u = jax.nn.silu(mixed + conv_bias)                                # float32
+        with jax.named_scope("mix.s6.scan"):
+            dbc = jnp.matmul(u.astype(dt), w_x.astype(dt), preferred_element_type=f32)
+            step = jax.nn.softplus(
+                jnp.matmul(dbc[..., :R].astype(dt), w_dt.astype(dt), preferred_element_type=f32)
+                + b_dt)
+            step = jnp.where(valid[..., None], step, 0.0)
+            if state is None:
+                state = jnp.zeros((b, N, E), f32)
+            # a sequence that starts here has no past (a row that is no token
+            # starts nothing: a slot's h may be a chunk's to write meanwhile)
+            starts = (positions[:, 0] == 0) & valid[:, 0]
+            y, new_state = selective_scan(u, step, -jnp.exp(a_log), dbc[..., R:R + N],
+                                          dbc[..., R + N:], skip, state, starts)
+        new_cache = put_state(cache, state_slots, (new_conv, new_state))
+        with jax.named_scope("mix.s6.out"):
+            out = (y * jax.nn.silu(xz[..., E:])).astype(dt) @ w_out.astype(dt)
+        return (out, new_cache, y) if self.hands_up else (out, new_cache)
+
+
+class GatedMemoryUnit(nn.Module):
+    """SambaY's gated memory unit (arXiv 2507.06607), the token mixer of a "gmu"
+    layer: GMU(u, m) = W_2 (SiLU(W_1 u) * m), W_1 [dim, E], W_2 [E, dim], no
+    bias; ``memory`` m [b, s, E] float32 is the SAME rows' scan output of the
+    layer cfg.memory_source in the SAME call, so the layer keeps nothing."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, memory):
+        cfg = self.cfg
+        lecun = nn.initializers.lecun_normal()
+        w1 = param_with_axes("in_proj", lecun, (cfg.dim, cfg.mamba_d_inner), jnp.float32,
+                             axes=("embed", "ssd_proj"))
+        w2 = param_with_axes("out_proj", lecun, (cfg.mamba_d_inner, cfg.dim), jnp.float32,
+                             axes=("ssd_inner", "embed"))
+        with jax.named_scope("mix.gmu"):
+            gate = jnp.matmul(x, w1.astype(cfg.dtype), preferred_element_type=jnp.float32)
+            return (jax.nn.silu(gate) * memory).astype(cfg.dtype) @ w2.astype(cfg.dtype)
+
+
 class TransformerBlock(nn.Module):
     """``x`` is the residual [b, s, dim] or, with cfg.hc_mult > 1, the residual
     streams [b, s, hc_mult, dim]: each sub-layer then reads a mix of the
@@ -1964,21 +2305,26 @@ class TransformerBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions, cache=None, cache_index=None,
                  block_tables=None, adapters=None, adapter_ids=None,
-                 valid=None, state_slots=None):
+                 valid=None, state_slots=None, memory=None, lambda_init=None):
+        """-> (x, new_cache), or (x, new_cache, m) from the layer cfg.memory_source.
+        ``memory``: the m a "gmu" layer reads; ``lambda_init``: differential
+        attention's, the layer's own as a traced scalar; a "cross_attention"
+        layer's ``cache`` is the entry of cfg.kv_source as its write left it."""
         cfg = self.cfg
         attention = LatentAttention if cfg.kv_lora_rank else Attention
         streams = cfg.hc_mult > 1
         if streams:
             X, (x, h_post, h_res) = x, HyperConnection(cfg, name="attention_hc")(x)
-        kind, window, rotary, routed = cfg.layer_reads(self.layer)
+        kind, window, rotary, routed, hands_up = cfg.layer_reads(self.layer)
+        handed = None
         # what the router multiplies where it reads the block's INPUT: nothing
         # is computed here, MoEFFN is handed the array
         router_in = x if cfg.router_input == "layer_input" else None
         # the SAME two weights a layer stand before a sub-layer (x + f(norm(x)))
         # or on its branch (x + norm(f(x)): cfg.norm_placement "branch")
         branch = cfg.norm_placement == "branch"
-        mixer_norm = RMSNorm(cfg.dim, cfg.norm_eps, name="attention_norm"
-                             if kind in ATTENTION_LAYER_KINDS else "operator_norm")
+        mixer_norm = block_norm(cfg, "attention_norm" if kind in ATTENTION_LAYER_KINDS
+                                or kind == "cross_attention" else "operator_norm")
 
         def mixer_in():
             return x if branch else mixer_norm(x)
@@ -1996,21 +2342,34 @@ class TransformerBlock(nn.Module):
             # likewise mix.ssd.*
             h, new_cache = Mamba2Mixer(cfg, name="mamba")(
                 mixer_in(), positions, valid, cache, state_slots)
+        elif kind == "s6":
+            # likewise mix.s6.*
+            h, new_cache, *handed = Mamba1Mixer(cfg, hands_up, name="s6")(
+                mixer_in(), positions, valid, cache, state_slots)
+        elif kind == "gmu":
+            # mix.gmu; nothing is kept: an empty entry
+            h, new_cache = GatedMemoryUnit(cfg, name="gmu")(mixer_in(), memory), put_state(
+                None, None, ())
         else:
             # a pair of tables (full, window): each layer reads its class's
             if isinstance(block_tables, tuple):
                 block_tables = block_tables[kind == "sliding_attention"]
             own = {} if cfg.kv_lora_rank else {"window": window, "rotary": rotary}
+            if kind == "cross_attention":
+                own["cross"] = True
+            more = {"lambda_init": lambda_init} if cfg.differential else {}
             with jax.named_scope("attn"):
                 h, new_cache = attention(cfg, name="attention", **own)(
                     mixer_in(), positions, cache,
-                    cache_index, block_tables, adapters, adapter_ids,
+                    cache_index, block_tables, adapters, adapter_ids, **more,
                 )
+            if kind == "cross_attention":
+                new_cache = put_state(None, None, ())
         if branch:
             h = mixer_norm(h)
         if cfg.residual_multiplier != 1.0:   # every branch, before it joins the residual
             h = h * cfg.residual_multiplier
-        ffn_norm = RMSNorm(cfg.dim, cfg.norm_eps, name="ffn_norm")
+        ffn_norm = block_norm(cfg, "ffn_norm")
         if streams:
             X = hc_write_back(X, h, h_post, h_res)
             x, h_post, h_res = HyperConnection(cfg, name="ffn_hc")(X)
@@ -2037,7 +2396,7 @@ class TransformerBlock(nn.Module):
             f = f * cfg.residual_multiplier
         if streams:
             return hc_write_back(X, f, h_post, h_res), new_cache
-        return x + f, new_cache
+        return (x + f, new_cache, *handed) if handed else (x + f, new_cache)
 
 
 # the collections a block's modules sow into (MoEFFN's routing counters)
@@ -2157,7 +2516,8 @@ class Transformer(nn.Module):
         [b, 1, vocab]; negative = the caller reads none, and the head does not
         run (zeros of that shape come back). A prefill chunk's
         (servers/llmserver.py ``_get_prefill_chunk``)."""
-        from seldon_core_tpu.ops.quantize import QuantizedTensor, dequantize_array, lookup_rows
+        from seldon_core_tpu.ops.quantize import (
+            QuantizedTensor, dequantize_array, dequantize_params, lookup_rows)
 
         cfg = self.cfg
         b, s = tokens.shape
@@ -2188,8 +2548,17 @@ class Transformer(nn.Module):
                 full = block_tables[0] if isinstance(block_tables, tuple) else block_tables
                 valid &= (jnp.asarray(full)[:, :1] != TRASH_PAGE)
         new_caches = []
-        for i in range(cfg.n_layers):
-            layer_cache = caches[i] if caches is not None else None
+        # what goes up the stack beside the residual (SambaY): the scan output m
+        # of cfg.memory_source, and the entry of cfg.kv_source as its write left it
+        carried = cfg.differential or cfg.kv_source is not None or cfg.memory_source is not None
+        memory = shared = None
+        rules = tuple(nn_partitioning.get_axis_rules())
+
+        served = not self.is_initializing()
+
+        def run_layer(i, x, positions, valid, memory=None):
+            """Layer i over ``x``: (x, its new entry, m where it hands one up)."""
+            kind = cfg.layer_kind(i)
             layer_adapters = None
             if adapters is not None:
                 # slice this layer's factors: [N, L, ...] -> [N, ...]
@@ -2198,14 +2567,39 @@ class Transformer(nn.Module):
                     for proj, ab in adapters.items() if proj != "scale"
                 }
                 layer_adapters["scale"] = adapters["scale"]
+            layer_cache = shared if kind == "cross_attention" else (
+                caches[i] if caches is not None else None)
+            args = (x, positions, layer_cache, cache_index, block_tables,
+                    layer_adapters, adapter_ids, valid, state_slots)
+            if carried:
+                args += (memory if kind == "gmu" else None,
+                         jnp.float32(cfg.lambda_init(i)) if cfg.differential else None)
+            if served and i in cfg.cross_decoder_layers:
+                # a layer a chunk runs inside a conditional's branch: no module is
+                # made there (it sows nothing), and its int8 matrices arrive as
+                # they are held and are dequantized HERE, where they are multiplied
+                # (ahead of the conditional they would be written out whole every
+                # call); the same call in every program
+                return transformer_block(
+                    dequantize_params(self.variables["params"][f"layer_{i}"]), *args, cfg=cfg,
+                    layer=cfg.layer_class(i), sowing=(), rules=rules)[0]
             # (an initialisation makes each layer's parameters where they lie)
-            block = (TransformerBlock(cfg, i, name=f"layer_{i}") if self.is_initializing()
-                     else SharedBlock(cfg, cfg.layer_class(i), name=f"layer_{i}"))
-            x, nc = block(x, positions, layer_cache, cache_index, block_tables,
-                          layer_adapters, adapter_ids, valid, state_slots)
+            block = (SharedBlock(cfg, cfg.layer_class(i), name=f"layer_{i}") if served
+                     else TransformerBlock(cfg, i, name=f"layer_{i}"))
+            return block(*args)
+
+        # a prompt's chunk (``head_row``) of a model whose layers past
+        # cfg.kv_source cache nothing runs THOSE on the one row that is read
+        narrow_from = (cfg.kv_source + 1 if head_row is not None and served
+                       and cfg.cross_decoder_layers else cfg.n_layers)
+        for i in range(narrow_from):
+            x, nc, *handed = run_layer(i, x, positions, valid, memory)
             new_caches.append(nc)
-        hidden = leave_streams(x, cfg)
-        x = RMSNorm(cfg.dim, cfg.norm_eps, name="norm")(hidden).astype(jnp.float32)
+            if handed:
+                memory, = handed
+            if i == cfg.kv_source:
+                shared = nc
+        final_norm = block_norm(cfg, "norm")
         head = emb if cfg.tie_embeddings else param_with_axes(
             "lm_head", nn.initializers.normal(stddev=0.02), (cfg.dim, cfg.vocab_size),
             jnp.float32, axes=("embed", "vocab"),
@@ -2217,6 +2611,28 @@ class Transformer(nn.Module):
             w = dequantize_array(head) if isinstance(head, QuantizedTensor) else head
             logits = rows @ (w.T if cfg.tie_embeddings else w)
             return logits / cfg.logits_scaling if cfg.logits_scaling != 1.0 else logits
+
+        if narrow_from < cfg.n_layers:
+            # the cross-decoder (every layer past cfg.kv_source: they write no
+            # cache), the final norm and the head on ``head_row``'s row alone,
+            # all under the ONE conditional that skips them where no row is read
+            weights = final_norm()
+
+            def take(rows):
+                return jax.lax.dynamic_slice_in_dim(rows, jnp.maximum(head_row, 0), 1, axis=1)
+
+            def cross_decoder(x, memory, positions, valid):
+                for i in cfg.cross_decoder_layers:
+                    x, _ = run_layer(i, x, positions, valid, memory)
+                return logits_of(normed_by(cfg, weights, x).astype(jnp.float32))
+
+            new_caches += [put_state(None, None, ())] * (cfg.n_layers - narrow_from)
+            return jax.lax.cond(
+                head_row >= 0, cross_decoder,
+                lambda *_: jnp.zeros((b, 1, cfg.vocab_size), jnp.float32),
+                take(x), take(memory), take(positions), take(valid)), new_caches
+        hidden = leave_streams(x, cfg)
+        x = final_norm(hidden).astype(jnp.float32)
 
         if head_row is not None:
             # the row is taken BEFORE the head (the product is a row's own), and

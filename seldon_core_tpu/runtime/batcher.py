@@ -549,7 +549,7 @@ class _Slot:
 MOE_PROGRAMS = ("decode", "chunk")   # the step programs the loop counts by
 # the counters' names for the kinds of state layer (models/transformer.py
 # STATE_LAYER_KINDS): seldon_llm_<name>_rows_total / _layer_calls_total
-STATE_COUNTERS = {"conv": "conv", "gdn": "linear_attention", "ssd": "mamba"}
+STATE_COUNTERS = {"conv": "conv", "gdn": "linear_attention", "ssd": "mamba", "s6": "s6"}
 KV_WRITE_PATHS = ("page", "token")   # how a chunk's rows reach the paged pool
 # how a decode step's read-modify-write of a matrix state runs, for the kinds
 # that have a kernel of the repo's own: seldon_llm_<name>_step_path{path}
@@ -705,6 +705,14 @@ class LoopPhases:
         self.attn_window = {key: dict.fromkeys(MOE_PROGRAMS, 0)
                             for key in ("calls", "context_tokens", "rows_read",
                                         "context_tokens_unwindowed")}
+        # ... and the first three for the cross-attention layers of a model that
+        # has them (kind="shared": a row a layer; each reads the pool of the
+        # layer cfg.kv_source in place), with the rows that ran the layers up
+        # to cfg.kv_source and the rows that ran those past it (a prompt's
+        # chunk: one row, or none)
+        self.attn_shared = {key: dict.fromkeys(MOE_PROGRAMS, 0)
+                            for key in ("calls", "context_tokens", "rows_read")}
+        self.decoder_rows = {half: dict.fromkeys(MOE_PROGRAMS, 0) for half in ("self", "cross")}
         # how the chunks' rows reached the paged pool (whole pages, or one
         # scatter row a token) and the pool pages a layer's write wrote
         self.kv_chunk_writes = dict.fromkeys(KV_WRITE_PATHS, 0)
@@ -827,6 +835,11 @@ class LoopPhases:
                 **{f"attn_expanded_{key}": dict(tally) for key, tally in self.attn_expanded.items()},
                 **({f"attn_window_{key}": dict(tally) for key, tally in self.attn_window.items()}
                    if any(self.attn_window["calls"].values()) else {}),
+                **({**{f"attn_shared_{key}": dict(tally)
+                       for key, tally in self.attn_shared.items()},
+                    **{f"{half}_decoder_rows": dict(tally)
+                       for half, tally in self.decoder_rows.items()}}
+                   if any(self.decoder_rows["self"].values()) else {}),
                 "kv_chunk_writes": dict(self.kv_chunk_writes),
                 "kv_pages_written": dict(self.kv_pages_written),
                 "chunk_head": dict(self.chunk_head),
@@ -850,6 +863,19 @@ class LoopPhases:
         tally["context_tokens"][program] += context_tokens
         tally["rows_read"][program] += rows_read
         tally["context_tokens_unwindowed"][program] += unwindowed
+
+    def count_decoders(self, program: str, rows: int, cross_rows: int, context_tokens: int,
+                       rows_read: int) -> None:
+        """A call of ``program`` of a model with cross-attention layers:
+        ``rows`` live rows through the layers up to cfg.kv_source, ``cross_rows``
+        of them through the rest, whose cross-attention read (a layer) had
+        ``context_tokens`` of the shared pool to read and visited ``rows_read``."""
+        self.decoder_rows["self"][program] += rows
+        self.decoder_rows["cross"][program] += cross_rows
+        if cross_rows:
+            self.attn_shared["calls"][program] += 1
+            self.attn_shared["context_tokens"][program] += context_tokens
+            self.attn_shared["rows_read"][program] += rows_read
 
     def count_chunk_write(self, path: str, pages: int) -> None:
         self.kv_chunk_writes[path] += 1
@@ -1462,6 +1488,9 @@ class ContinuousBatcher:
         # the decode step programs seen so far (a new one is counted by the
         # path its rule takes: seldon_llm_gdn_step_path / seldon_llm_ssd_step_path)
         self._step_programs: set = set()
+        # a model whose layers past cfg.kv_source cache nothing and read that
+        # layer's pool (seldon_llm_*_decoder_rows_total, kind="shared")
+        self._cross_decoder = getattr(cfg, "kv_source", None) is not None
         # which state row a chunk's one sequence continues: its slot, as a
         # device array made once (no transfer a chunk)
         self._state_slot = [jnp.asarray([i], jnp.int32) for i in range(self.S)
@@ -2645,6 +2674,10 @@ class ContinuousBatcher:
         self._phases.count_chunk_write(*self._chunk_write(C, start, n))
         if self._state_layers:
             self._phases.count_state_layers("chunk", n, 1, self._state_layers)
+        if self._cross_decoder:
+            # the layers past cfg.kv_source ran on the one row read, or on none
+            self._phases.count_decoders("chunk", n, int(last), start + n,
+                                        self._rows_read(1, [start + n], 1))
         event = None
         if self._flight is not None:
             # dispatch wall (enqueue-only)
@@ -3406,6 +3439,9 @@ class ContinuousBatcher:
             if self._state_layers:
                 self._phases.count_state_layers(
                     "decode", k * len(snapshot), k, self._state_layers)
+            if self._cross_decoder:
+                self._phases.count_decoders("decode", k * len(snapshot), k * len(snapshot),
+                                            context, self._rows_read(1, live, k * self.S))
             self._inflight.append(_InFlight(toks, k, snapshot, t0, aside=aside,
                                             built=self._built_since(builds)))
             self._count_steps()

@@ -830,12 +830,15 @@ class LLMServer(SeldonComponent):
             # Transformer dequantizes it where it multiplies by it, which in a
             # prefill chunk is inside a conditional, and the compiler moves no
             # dequant into a branch (tests/test_tpu_program.py)
+            # ... and so do the layers a prompt's chunk runs inside that
+            # conditional (cfg.cross_decoder_layers: a decoder-hybrid-decoder's)
+            held = ["lm_head"] + [f"layer_{i}" for i in getattr(
+                self._cfg, "cross_decoder_layers", ())]
+
             def dequant(params):
                 weights = dequantize_params(params, keep_consumed=True)
-                if "lm_head" in params["params"]:
-                    weights = {**weights, "params": {
-                        **weights["params"], "lm_head": params["params"]["lm_head"]}}
-                return weights
+                kept = {name: params["params"][name] for name in held if name in params["params"]}
+                return {**weights, "params": {**weights["params"], **kept}} if kept else weights
 
             self._dequant = dequant
 
@@ -909,7 +912,8 @@ class LLMServer(SeldonComponent):
 
     def _state_layers_refusal(self) -> Optional[str]:
         """What is not built over a layer that carries STATE (a conv, a
-        linear-attention or a mamba layer, cfg.layer_types), by name; None where nothing
+        linear-attention, a mamba or an s6 layer, cfg.layer_types; a gmu or a
+        cross_attention layer beside them keeps none of its own), by name; None where nothing
         asked for is missing. Each of the first three would restart a sequence
         mid-way, and needs the state AT a token boundary, which pages do not
         hold."""
@@ -1038,6 +1042,11 @@ class LLMServer(SeldonComponent):
                 # latent], the order q~ = W_UK^T q reads it; as a map it
                 # is k = W_UK c, so its fan-in is the latent axis
                 fan_in = spec.shape[-1]
+            if name.endswith("['dt_proj']"):
+                # Mamba-1's step projection is published U(+- rank^-1/2): a
+                # normal of THAT spread (a third of the variance below), so that
+                # the seeded steps Delta are the sizes the layer is built for
+                fan_in *= 3
             return spec.shape, 1.0 / float(fan_in) ** 0.5, out_major, lookup
 
         plan = [(keystr(path), path[-1].key, spec, keep,

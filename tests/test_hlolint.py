@@ -428,6 +428,30 @@ def test_window_layer_step_programs_donate_both_page_classes_and_hold_no_view(na
         "gqa_page_attention") >= 2
 
 
+@pytest.mark.parametrize("name", ["llm.sambay_paged_decode_step_s4",
+                                  "llm.sambay_prefill_chunk_c64"])
+def test_decoder_hybrid_decoder_step_programs_read_the_shared_pool_in_place(name):
+    """Phi-4-mini-flash's plan at 8 layers and dims the live-page kernel takes
+    (ISSUE 55): every leaf of the tree (ONE full page class entry, two window-class
+    entries, three state blocks) is donated and aliased; lowered for a TPU the full
+    layer's read, the window layers' and the cross layer's read of the SAME pool
+    each walk the live pages (no array of a whole block-table
+    view's shape: nothing is copied or gathered); h is float32; the chunk's scan is
+    the repo's kernel, once an s6 layer; no transfer."""
+    from tools.hlolint.contracts import all_contracts
+
+    (contract,) = [c for c in all_contracts() if c.name == name]
+    reported, *_ = run_one(contract, checks=("alias", "transfer", "dtype",
+                                             "collective"))
+    assert reported == []
+    fn, args = contract.build()
+    text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    # (a read's jitted function is traced once a shape: the window layers', the
+    # full layer's and the cross layer's are two or three)
+    assert text.count("gqa_page_attention") >= 2
+    assert ("s6_chunk_scan" in text) == ("chunk" in name)
+
+
 @pytest.mark.parametrize("name", ["llm.xing4_paged_decode_step_s4",
                                   "llm.xing4_prefill_chunk_c8"])
 def test_stream_step_programs_keep_the_streams_in_the_models_dtype(name):

@@ -449,3 +449,95 @@ def test_the_cache_trees_hold_a_mamba_layers_two_arrays_and_the_page_operations_
     assert all((a == b).all() for a, b in zip(after, state))
     exported = export_pages(tree, jnp.asarray([2, 3]))
     assert len(exported) == 1 and exported[0][0].shape[0] == 2      # the attention layer's pages alone
+
+
+# ---- a fourth kind: "s6" layers (Mamba-1's selective scan) beside layers that keep
+# NOTHING of their own (a gated memory unit, a cross-attention layer that reads the
+# one full layer's pages) and window layers of the window page class: state slots,
+# the window book and an empty entry in ONE tree and ONE batcher; the logits
+# against the reference are tests/test_reference_phi4flash.py's
+SAMBAY_KW = dict(vocab_size=96, dim=32, n_layers=8, n_heads=4, n_kv_heads=2, head_dim=8,
+                 ffn_dim=48, max_seq_len=96, norm_eps=1e-5, rope_theta=None, dtype="float32",
+                 tie_embeddings=True, sliding_window=8,
+                 layer_types=["s6", "sliding_attention", "s6", "sliding_attention", "s6",
+                              "full_attention", "gmu", "cross_attention"],
+                 mamba_d_inner=64, mamba_d_state=8, mamba_dt_rank=2, memory_source=4,
+                 kv_source=5, differential=True, attention_bias=True, norm="layer")
+
+
+@pytest.fixture(scope="module")
+def sambay_server():
+    return make_server(model_kwargs=SAMBAY_KW)
+
+
+def test_a_reused_slot_of_a_model_with_s6_layers_reads_h_as_zeros(sambay_server):
+    """The second request takes the slot (its h, its conv rows and its window
+    pages) the first one left: its logits are those it gives in a fresh batcher."""
+    p1, p2 = LONG[:11], LONG[20:26]
+
+    async def go(prompts):
+        b = batcher(sambay_server, max_slots=1)
+        got = [await ask_logits(b, p) for p in prompts]
+        await b.close()
+        return got
+
+    (_, reused), (_, fresh) = asyncio.run(go([p1, p2]))[1], asyncio.run(go([p2]))[0]
+    np.testing.assert_allclose(reused, fresh, rtol=1e-5, atol=1e-6)
+
+
+def test_batcher_tokens_equal_generate_over_s6_and_cross_layers(sambay_server):
+    prompt = LONG[:19]
+    want = sambay_server.generate([prompt], max_new_tokens=9)["tokens"][0]
+
+    async def go():
+        b = batcher(sambay_server)
+        out = await b.submit(prompt, max_new_tokens=9)
+        await b.close()
+        return out
+
+    assert asyncio.run(go()) == want
+
+
+@pytest.mark.parametrize("what", sorted(REFUSALS))
+def test_what_is_not_built_over_an_s6_layer_is_refused_at_load(what):
+    kwargs, names = REFUSALS[what]
+    s = LLMServer(**{**dict(model="transformer", model_kwargs=SAMBAY_KW, init_random=True),
+                     **kwargs})
+    # (a mesh is refused where the config is made, before load() looks: the same "no")
+    match = "'s6', 'gmu' or 'cross_attention' layer.*a mesh" if what == "tensor_parallel" else (
+        "cross_attention and gmu and s6 layers.*" + names)
+    with pytest.raises(ValueError, match=match):
+        s.load()
+
+
+def test_the_cache_tree_holds_three_kinds_of_entry_and_the_page_operations_keep_each(
+        sambay_server):
+    """ONE full page class entry (layer 5), two window-class entries, three state
+    blocks (conv rows, h [N, E] float32) and two EMPTY entries; a token costs its
+    K and V in ONE full layer for the two layers that read it."""
+    from seldon_core_tpu.models.cache import (
+        is_window_entry, kv_cache_bytes_per_token, matrix_state_nbytes, state_bytes)
+
+    cfg = sambay_server._cfg
+    _, _, reset_pages, *_ = _page_table_ops()
+    tree = init_paged_kv_caches(cfg, 10, 4, state_slots=3, window_pages=7)
+    assert [is_state_entry(layer) for layer in tree] == [True, False, True, False, True, False,
+                                                         True, True]
+    assert [is_window_entry(layer) for layer in tree] == [False, True, False, True] + [False] * 4
+    assert [a.shape for a in tree[0]] == [(3, 3, 64), (3, 8, 64)]
+    assert tree[0][1].dtype == jnp.float32 and tree[6] == () and tree[7] == ()
+    assert [a.shape for a in tree[5]] == [(10, 4, 16), (10, 4, 16), (10, 4)]
+    assert tree[1][0].shape == (7, 4, 16)
+    assert state_bytes(cfg) == 3 * (3 * 64 * 4 + 8 * 64 * 4)
+    assert matrix_state_nbytes(tree)[0] == 3 * 3 * 8 * 64 * 4
+    row = 2 * 2 * 8 * 4 + 4            # K and V of 2 heads of 8 in float32, and a position
+    assert kv_cache_bytes_per_token(cfg, page_class="full") == row
+    assert kv_cache_bytes_per_token(cfg, page_class="window") == 2 * row
+    assert kv_cache_bytes_per_token(cfg) == 3 * row
+    tree = [type(layer)(a + 1.5 for a in layer) if is_state_entry(layer) else layer
+            for layer in tree]
+    state = [np.asarray(a) for layer in tree if is_state_entry(layer) for a in layer]
+    after = reset_pages(tree, jnp.asarray([2, 3, 1, 1]), jnp.asarray([2, 1, 1, 1]))
+    assert [type(layer) for layer in after] == [type(layer) for layer in tree]
+    assert all((a == b).all() for a, b in zip(
+        [np.asarray(a) for layer in after if is_state_entry(layer) for a in layer], state))

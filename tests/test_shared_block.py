@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from test_chunk_head import MODELS as CHUNK_HEAD_MODELS
-from test_hybrid_state import MAMBA_KW
+from test_hybrid_state import MAMBA_KW, SAMBAY_KW
 from test_reference_olmo_hybrid import KW as OLMO_HYBRID_KW
 from test_reference_smallthinker import KW as SMALLTHINKER_KW
 from test_reference_xing4 import XING4
@@ -97,6 +97,10 @@ TRACES = {
         [0, 1]),
     "full attention without position, windows with: two": (SMALLTHINKER_KW, [0, 1]),
     "mamba layers around an attention layer: two": (MAMBA_KW, [0, 2]),
+    # s6, window, s6, window | s6 that hands m up, full | gmu, cross: the layer that
+    # hands its scan output up is a class of its own; lambda_init is an ARGUMENT
+    # (a traced scalar), so the two window layers are one class
+    "a decoder-hybrid-decoder of eight layers: six": (SAMBAY_KW, [0, 1, 4, 5, 6, 7]),
     "dense and routed conv layers, a routed attention layer: three": (
         CHUNK_HEAD_MODELS["state_layers"], [0, 2, 3]),
 }
@@ -232,6 +236,7 @@ KINDS = {
     "linear_attention": WIDE_CHUNK_MODELS["delta_rule_state"],
     "linear_attention, norms on the branches": OLMO_HYBRID_KW,
     "mamba": MAMBA_KW,
+    "s6, gmu, cross_attention": SAMBAY_KW,
     "latent attention": CHUNK_HEAD_MODELS["latent_moe"],
     "latent attention in residual streams": XING4,
 }
@@ -295,6 +300,10 @@ def test_a_layers_class_is_what_the_block_reads_of_its_number():
     assert classes(layer_types=["full_attention"] + ["sliding_attention"] * 3,
                    sliding_window=8) == [0, 1, 1, 1]
     assert classes(rope_layout=[0, 1, 1, 0]) == [0, 1, 1, 0]
+    # an s6 layer that hands its scan output up is another block than one that does not
+    sambay = get_model("transformer", **SAMBAY_KW).cfg
+    assert [sambay.layer_class(i) for i in range(8)] == [0, 1, 0, 1, 4, 5, 6, 7]
+    assert sambay.layer_reads(4).hands_up and not sambay.layer_reads(2).hands_up
     assert classes(layer_types=["full_attention"] * 2 + ["sliding_attention"] * 2,
                    sliding_window=8, rope_layout=[0, 1, 1, 0]) == [0, 1, 2, 3]
     # the FFN's kind: dense under first_dense_layers where the model routes experts

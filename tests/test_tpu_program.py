@@ -144,7 +144,21 @@ GRANITE4H = dict(vocab_size=256, dim=2048, n_layers=4, n_heads=32, n_kv_heads=8,
                  embedding_multiplier=12.0, attention_multiplier=0.015625,
                  residual_multiplier=0.22, logits_scaling=8.0,
                  layer_types=("mamba", "mamba", "full_attention", "mamba"))
-CONFIGS = {"granite4h": GRANITE4H, "smallthinker": SMALLTHINKER, "olmohybrid": OLMOHYBRID, "mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
+# Phi-4-mini-flash-reasoning's plan at 8 layers and published widths (every kind
+# of layer once or twice: s6, window, s6, window | s6 handing m up, full = the
+# shared pool | gmu, cross): Mamba-1 over 5,120 channels with a float32 [16,
+# 5120] state, differential attention 40 query / 20 KV heads of 64 (20 pairs over
+# 10 groups: reads of 40 heads over 10 KV heads of 128), window 512, LayerNorm,
+# biases, a gated FFN of 10,240, no position, the table tied
+PHI4FLASH = dict(vocab_size=256, dim=2560, n_layers=8, n_heads=40, n_kv_heads=20, head_dim=64,
+                 ffn_dim=10240, max_seq_len=16384, dtype="bfloat16", norm_eps=1e-5,
+                 rope_theta=None, tie_embeddings=True, sliding_window=512,
+                 layer_types=("s6", "sliding_attention", "s6", "sliding_attention", "s6",
+                              "full_attention", "gmu", "cross_attention"),
+                 mamba_d_inner=5120, mamba_d_state=16, mamba_dt_rank=160, mamba_d_conv=4,
+                 memory_source=4, kv_source=5, differential=True, attention_bias=True,
+                 norm="layer")
+CONFIGS = {"phi4flash": PHI4FLASH, "granite4h": GRANITE4H, "smallthinker": SMALLTHINKER, "olmohybrid": OLMOHYBRID, "mistral": MISTRAL, "olmoe": OLMOE_ATTENTION, "olmoe_moe": OLMOE, "deepseek": DEEPSEEK,
            "xing4": XING4, "mistral_vocab": MISTRAL_VOCAB, "lfm2": LFM2, "qwen3next": QWEN3NEXT}
 PAGE, POOL_PAGES = 64, 514
 
@@ -1294,3 +1308,107 @@ def test_a_mamba_layers_state_goes_through_the_kernel_once_a_layer_in_its_own_bu
         assert {op[3] for op in own_ops(hlo) if op[1] == "f32" and op[2] == state} <= {
             "parameter", "bitcast", "get-tuple-element", "dynamic-update-slice"}
         assert [op for op in own_ops(hlo) if op[2] in a_head] == []
+
+
+PHI4FLASH_CELL = (32, 16384)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "wide_chunk"])
+def test_the_shared_pool_is_read_in_place_and_a_chunk_stops_its_rows_half_way_up(
+        v5e, servers, program):
+    """Phi-4-mini-flash-reasoning's kinds of layer at published widths and the
+    cell's own shapes (32 slots x 16,384 tokens). ONE full page class entry (layer
+    5's, 8,194 pages of flat rows of 1,280), two window-class entries, three state
+    blocks ``(conv rows [32, 3, 5120], h [32, 16, 5120] float32)`` and two EMPTY
+    entries; every leaf a parameter that an output aliases. The full layer and
+    the cross layer both walk the live pages of the one pool with the repo's
+    kernel (no array of a whole block-table view's shape); h is float32
+    everywhere. The wide chunk's scan is the repo's kernel, once an s6 layer,
+    and the layers past the shared pool's (the gated memory unit, the cross
+    layer), the final norm and the head run INSIDE the conditional that skips
+    the head, on one row: no op of theirs is outside it."""
+    from seldon_core_tpu.models.cache import init_paged_kv_caches, state_bytes
+    from seldon_core_tpu.ops.quantize import QuantizedTensor
+    from seldon_core_tpu.ops.selective_scan import KERNEL_NAME
+
+    server = servers("phi4flash")
+    cfg = server._cfg
+    slots, length = PHI4FLASH_CELL
+    pages = slots * length // PAGE + 2
+    assert cfg.state_layers == (0, 2, 4, 6, 7) and cfg.window_layers == (1, 3)
+    assert cfg.read_heads == (40, 10, 128) and cfg.kv_rows_flat
+    s6 = server._params["params"]["layer_0"]["s6"]
+    for name, shape in (("in_proj", (2560, 10240)), ("x_proj", (5120, 192)),
+                        ("dt_proj", (160, 5120)), ("out_proj", (5120, 2560))):
+        assert isinstance(s6[name], QuantizedTensor) and s6[name].q.shape == shape, name
+    assert {k: (s6[k].dtype.name, s6[k].shape) for k in ("conv1d", "conv_bias", "A_log_t",
+                                                          "b_dt", "D")} == {
+        "conv1d": ("float32", (5120, 4)), "conv_bias": ("float32", (5120,)),
+        "A_log_t": ("float32", (16, 5120)), "b_dt": ("float32", (5120,)),
+        "D": ("float32", (5120,))}
+    attention = server._params["params"]["layer_7"]["attention"]
+    assert sorted(attention) == ["bo", "bq", "lambdas", "subln", "wo", "wq"]
+    assert attention["lambdas"].shape == (4, 64) and attention["lambdas"].dtype == jnp.float32
+    tree = jax.eval_shape(lambda: init_paged_kv_caches(
+        cfg, pages, PAGE, "bf16", state_slots=slots,
+        window_pages=window_pool_pages(cfg, slots)))
+    assert [leaf.shape for leaf in tree[0]] == [(slots, 3, 5120), (slots, 16, 5120)]
+    assert tree[0][1].dtype == jnp.float32 and tree[6] == () and tree[7] == ()
+    assert [leaf.shape for leaf in tree[5]] == [(pages, PAGE, 1280)] * 2 + [(pages, PAGE)]
+    assert tree[1][0].shape == (window_pool_pages(cfg, slots), PAGE, 1280)
+    assert state_bytes(cfg) == 3 * (16 * 5120 * 4 + 3 * 5120 * 2)
+    exe = compiled(server, program, v5e, slots=slots, length=length)
+    hlo = exe.as_text()
+    entry = hlo[hlo.index("\nENTRY"):]
+    leaves = {m.group(2): int(m.group(3)) for m in re.finditer(
+        r"%(pools_(\d__\d)_)[\w.]* = \S+ parameter\((\d+)\)", entry)}
+    aliased = {int(n) for n in re.findall(r"\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo)}
+    assert len(leaves) == 3 * 2 + 3 * 3 and set(leaves.values()) <= aliased, (leaves, aliased)
+    assert not re.search(r"\b(infeed|outfeed|send|recv|send-done|recv-done)\(", hlo)
+    # no gathered view of the shared pool or of a window layer's, no h in 16 bits
+    view = [(1, length, 1280), (slots, length, 1280), (1, length, 10, 128),
+            (slots, length, 10, 128)]
+    assert floating_arrays(hlo, view) == []
+    assert [op for op in own_ops(hlo) if op[1] in ("bf16", "f16") and op[2][1:] == (16, 5120)] == []
+    kernels = len(re.findall(r"custom-call\([^\n]*gqa_page_attention", hlo))
+    assert kernels >= 4, kernels           # two window layers, the full layer, the cross layer
+    scans = len(re.findall(r"custom-call\([^\n]*" + KERNEL_NAME, hlo))
+    if program == "decode_step":
+        assert scans == 0
+        assert exe.memory_analysis().temp_size_in_bytes < 1 << 30
+    else:
+        assert scans == 3
+        # the cross-decoder is the conditional's: the one branch that computes
+        # holds the gated memory unit, the cross read and the head's product
+        def body(name):     # a computation's own lines
+            start = hlo.index(f"%{name} (")
+            return hlo[start:hlo.index("\n}", start)]
+
+        zeros, taken = conditional_branches(hlo)
+        assert "mix.gmu" in body(taken) and "attn.cross.read" in body(taken)
+        assert "mix.gmu" not in body(zeros) + entry and "attn.cross.read" not in entry
+        assert "mix.s6.scan" in entry and "mix.s6.scan" not in body(taken)
+        # the cross-decoder's int8 matrices go into the conditional as they are held
+        # (dequantized ahead of it, an FFN's three matrices a layer were written out
+        # whole every chunk: 8 ms of a 30 ms chunk on the chip, PR 55): no op of the
+        # entry computation yields a floating FFN matrix
+        assert not re.search(r"= (?:f32|bf16)\[(?:2560,10240|10240,2560)\]", entry)
+
+
+@pytest.mark.parametrize("rows", [256, WIDE])
+def test_the_scan_kernel_compiles_at_the_published_sizes(v5e, rows):
+    """Phi-4-mini-flash's [16, 5120] over a chunk's and a wide chunk's rows."""
+    from seldon_core_tpu.ops.selective_scan import KERNEL_NAME, plan, scan_kernel
+
+    d, n = 5120, 16
+    walk = plan(rows, d, n)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=v5e)
+
+    exe = jax.jit(
+        lambda x, delta, A, B, C, state: scan_kernel(x, delta, A, B, C, state, walk,
+                                                     interpret=False)
+    ).lower(shape(1, rows, d), shape(1, rows, d), shape(n, d), shape(1, rows, n),
+            shape(1, rows, n), shape(1, n, d)).compile()
+    assert KERNEL_NAME in exe.as_text()

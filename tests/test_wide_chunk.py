@@ -532,15 +532,17 @@ def served_wide_cells() -> dict:
 WIDE_CELLS = served_wide_cells()
 
 
-def test_the_cells_that_reach_a_wide_chunk_are_the_eight():
+def test_the_cells_that_reach_a_wide_chunk_are_the_nine():
     """The five whose model routes experts (since PR 48) and, since PR 54, the
-    three dense servers of slots longer than 1,025 tokens: Mistral's docs and
-    rerank cells, whose prompts of 1,536-3,584 tokens take it, and granite's
-    sessions, which build the program and never run it (prompts of 64-512)."""
+    dense servers of slots longer than 1,025 tokens: Mistral's docs and rerank
+    cells, whose prompts of 1,536-3,584 tokens take it, granite's sessions, which
+    build the program and never run it (prompts of 64-512), and (PR 55)
+    Phi-4-mini-flash's long traces, whose prompts of 4,096-12,288 are 4-12 wide
+    chunks through 18 of its 32 layers."""
     assert sorted(WIDE_CELLS) == [
         "dsv2lite-longdocs-batch", "granite4h-sessions-decode", "lfm2-rag-mixed",
-        "mistral7b-docs-batch", "mistral7b-rerank-prefill", "qwen3next-longctx-mixed",
-        "smallthinker-longqa-mixed", "xing4-reasoning-decode"]
+        "mistral7b-docs-batch", "mistral7b-rerank-prefill", "phi4flash-longtrace-decode",
+        "qwen3next-longctx-mixed", "smallthinker-longqa-mixed", "xing4-reasoning-decode"]
 
 
 @pytest.mark.parametrize("cell", sorted(WIDE_CELLS))

@@ -699,6 +699,80 @@ def _build_swa_prefill_chunk():
                 _sds((), "int32"))
 
 
+# Phi-4-mini-flash's stack at dims the live-page kernel takes (16 query / 4 KV
+# heads of 64: 8 pairs over 2 groups, a K row of 256 = two KV heads of 128 as
+# the reads see them; 64-row pages, four a slot; window 128): the plan of 8
+# layers (s6, window, s6, window | s6 handing m up, full = the shared pool |
+# gmu, cross), every kind of layer once or twice. ONE full page class entry
+# (layer 5), written by it alone and read in place by it and by the cross
+# layer; two window-class entries; three state blocks; two empty entries
+SAMBAY_DIM, SAMBAY_INNER = 256, 512
+SAMBAY_HEADS = (16, 4, 64)
+SAMBAY_GATHERED_VIEW = (
+    rf"tensor<({SLOTS}|1)x{SWA_PAGES * SWA_PAGE}x(2x128|4x64|256)x(bf16|f16|f32)>",
+    "a floating [sequences, view rows, K / V row] array: the SHARED pool (the one full "
+    "layer's, read again by every cross-attention layer) or a window layer's is being "
+    "gathered into a copy of the whole block-table view where the live-page kernel "
+    "reads the pool in place")
+SAMBAY_NARROW_STATE = (
+    rf"tensor<({SLOTS}|1)x8x{SAMBAY_INNER}x(bf16|f16)>",
+    "an s6 layer's h [slots, d_state, d_inner] narrowed to 16 bits: a rounding of h "
+    "after every token adds up over as many tokens as a channel remembers")
+
+
+def _sambay_server():
+    with _STATE_LOCK:
+        if "sambay_server" not in _STATE:
+            ensure_platform()
+            from seldon_core_tpu.models.convert import sambay_layer_types
+            from seldon_core_tpu.servers.llmserver import LLMServer
+
+            heads, kv_heads, hd = SAMBAY_HEADS
+            s = LLMServer(
+                model="transformer",
+                model_kwargs=dict(
+                    vocab_size=96, dim=SAMBAY_DIM, n_layers=8, n_heads=heads,
+                    n_kv_heads=kv_heads, head_dim=hd, ffn_dim=MOE_WIDTH,
+                    max_seq_len=SWA_PAGES * SWA_PAGE, rope_theta=None, tie_embeddings=True,
+                    layer_types=sambay_layer_types(8), sliding_window=SWA_WINDOW,
+                    mamba_d_inner=SAMBAY_INNER, mamba_d_state=8, mamba_dt_rank=16,
+                    memory_source=4, kv_source=5, differential=True, attention_bias=True,
+                    norm="layer", dtype="bfloat16"),
+                quantize="int8", init_random=True, len_buckets=(PLEN,), seed=7)
+            s.load()
+            _STATE["sambay_server"] = s
+        return _STATE["sambay_server"]
+
+
+def _sambay_pool_specs():
+    import jax
+
+    from seldon_core_tpu.models.cache import init_paged_kv_caches, window_slot_pages
+
+    window_pages = 2 + SLOTS * window_slot_pages(SWA_WINDOW, SWA_CHUNK, SWA_PAGE)
+    return jax.eval_shape(lambda: init_paged_kv_caches(
+        _sambay_server()._cfg, SWA_POOL_PAGES, SWA_PAGE, "bf16", state_slots=SLOTS,
+        window_pages=window_pages))
+
+
+def _build_sambay_paged_decode_step():
+    s = _sambay_server()
+    fn = s._get_decode_step_paged(SLOTS, SWA_PAGES, 1)
+    tables = (_sds((SLOTS, SWA_PAGES), "int32"),) * 2     # (full, window)
+    return fn, (s._params, _sambay_pool_specs(), _sds((SLOTS,), "int32"),
+                _sds((SLOTS,), "int32"), _sds((SLOTS, 2), "uint32"),
+                _sds((), "float32"), tables)
+
+
+def _build_sambay_prefill_chunk():
+    s = _sambay_server()
+    fn = s._get_prefill_chunk(SWA_CHUNK, SWA_PAGES)
+    rows = (_sds((1, SWA_PAGES), "int32"),) * 2
+    return fn, (s._params, _sambay_pool_specs(), rows,
+                _sds((1, SWA_CHUNK), "int32"), _sds((1, SWA_CHUNK), "int32"),
+                _sds((), "int32"), _sds((1,), "int32"))
+
+
 def _pool_specs_of(server):
     import jax
 
@@ -1440,6 +1514,38 @@ def all_contracts() -> List[Contract]:
             build=_build_swa_prefill_chunk,
             donated=(1,),
             forbid_dtypes=(SWA_GATHERED_VIEW, MOE_DENSE_FORM, MOE_FLOAT_STACK),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.sambay_paged_decode_step_s4",
+            description="PAGED decode step of a decoder-hybrid-decoder "
+                        "(Phi-4-mini-flash's plan at 8 layers: Mamba-1 state "
+                        "blocks, differential window layers of the window page "
+                        "class, ONE full layer whose pool a cross-attention "
+                        "layer reads again, a gated memory unit): the shared "
+                        "pool is donated, written by the full layer alone and "
+                        "read IN PLACE by every layer that reads it (no copy, "
+                        "no gathered [slots, view] array); h stays float32",
+            build=_build_sambay_paged_decode_step,
+            donated=(1, 3, 4),
+            forbid_dtypes=(SAMBAY_GATHERED_VIEW, SAMBAY_NARROW_STATE),
+            lowering_platform="tpu",
+            collectives={},
+            cost=True,
+        ),
+        Contract(
+            name="llm.sambay_prefill_chunk_c64",
+            description="chunked admission prefill of the same model (one "
+                        "sequence's 64 rows): the layers up to the shared "
+                        "pool's on the rows, the cross-decoder on ONE row "
+                        "inside the conditional that skips the head; the "
+                        "chunk's scan is the repo's kernel; whole-page "
+                        "writes into the donated pools of both classes",
+            build=_build_sambay_prefill_chunk,
+            donated=(1,),
+            forbid_dtypes=(SAMBAY_GATHERED_VIEW, SAMBAY_NARROW_STATE),
             lowering_platform="tpu",
             collectives={},
             cost=True,
