@@ -190,6 +190,17 @@ class MetricsRegistry:
                  "rounded up to (8, 128) float32 tiles; over "
                  "seldon_llm_state_matrix_bytes it is the padding every decode "
                  "step reads and writes (1.0 = none)"))}
+        self._sampler_topk_columns = Gauge(
+            "seldon_llm_sampler_topk_columns",
+            "Columns the sampler's last TopK runs over in a step program that "
+            "has been built (program=decode_step|spec_step|first_token): "
+            "top_k blocks of 128 columns named by their maxima (5,120 at "
+            "top_k 40, plus the columns behind the vocabulary's last whole "
+            "block) where the two-stage form engaged, the vocabulary where "
+            "the direct lax.top_k stands",
+            base + ["program"],
+            registry=self.registry,
+        )
         self._kv_page_fragmentation = Gauge(
             "seldon_llm_kv_page_fragmentation",
             "Internal fragmentation of allocated KV pages "
@@ -1162,6 +1173,8 @@ class MetricsRegistry:
         self._kv_page_fragmentation.labels(**self._base()).set(
             stats.get("kv_page_fragmentation", 0.0)
         )
+        for program, columns in stats.get("sampler_topk_columns", {}).items():
+            self._sampler_topk_columns.labels(**self._base(), program=program).set(columns)
         self._state_bytes.labels(**self._base()).set(stats.get("state_bytes", 0))
         for key, gauge in self._state_matrix_bytes.items():
             gauge.labels(**self._base()).set(stats.get(key, 0))

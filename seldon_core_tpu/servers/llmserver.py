@@ -110,16 +110,90 @@ def _cast_params(params, param_dtype: str, module_dtype, keep=None) -> Any:
     return jax.tree.map(cast, params, keep)
 
 
+#: Consecutive columns a block of `_top_k_candidates`' first stage spans: one
+#: lane row. The width that wins at [32, 32000] and loses at no served shape
+#: on a TPU v5e (benchmarks/sampler_topk_bench.py, docs/performance.md "The
+#: sampler's candidates"): a constant, not a knob.
+TOPK_BLOCK = 128
+
+
+def sampler_topk_columns(vocab: int, top_k: int) -> int:
+    """The columns `_top_k_candidates`' last ``lax.top_k`` runs over, from
+    the call's static shape alone: the vocabulary where the direct form
+    stands (``vocab <= 2 x k x TOPK_BLOCK``: the test models' 256 columns, a
+    ``top_k`` near ``vocab / TOPK_BLOCK``), else the ``k`` blocks the first
+    stage names plus the columns behind the last whole block."""
+    k = min(top_k, vocab)
+    if vocab <= 2 * k * TOPK_BLOCK:
+        return vocab
+    return k * TOPK_BLOCK + vocab % TOPK_BLOCK
+
+
 def _top_k_candidates(lg, top_k: int):
     """What every emitted token is chosen among: the greedy token and the
     top-k logits with their indices in ``lax.top_k``'s order (descending,
-    ties by index). ``lg`` [rows, vocab] float32."""
+    ties by index). ``lg`` [rows, vocab] float32.
+
+    Over a served vocabulary this is ``lax.top_k(lg, k)`` bit for bit
+    (values, indices, order) in two exact stages, so the step's TopK runs
+    over `sampler_topk_columns` columns and not the vocabulary (XLA's TopK
+    is bound by neither bytes nor FLOPs and grows with its columns). The
+    row is viewed as blocks of TOPK_BLOCK consecutive columns; the k blocks
+    with the largest maxima (ties by lower block) hold every one of the
+    row's top k: a block outside holds nothing above the k-th value, and one
+    outside whose maximum EQUALS it lies behind every block that holds a
+    chosen element of that value. Their columns, blocks ASCENDING (then the
+    columns behind the last whole block, which are always candidates), order
+    by position as they do by vocabulary index, so ``lax.top_k`` over them
+    breaks ties as it would over the row. The maxima are taken on
+    ``lax.top_k``'s own order (the total order of the float32 bit patterns,
+    +0.0 above -0.0: `_order_key`), and ``greedy`` is the first candidate:
+    the first occurrence of the row's maximum, ``jnp.argmax``'s answer (a
+    row whose maximum is zero in BOTH signs gets its first +0.0)."""
     import jax
     import jax.numpy as jnp
 
-    greedy = jnp.argmax(lg, axis=-1)
-    topv, topi = jax.lax.top_k(lg, min(top_k, lg.shape[-1]))
-    return greedy, topv, topi
+    rows, vocab = lg.shape
+    k = min(top_k, vocab)
+    if sampler_topk_columns(vocab, top_k) == vocab:
+        topv, topi = jax.lax.top_k(lg, k)
+        return topi[:, 0], topv, topi
+    blocks, tail = divmod(vocab, TOPK_BLOCK)
+    main = lg[:, :blocks * TOPK_BLOCK].reshape(rows, blocks, TOPK_BLOCK)
+    maxima = _order_key(jnp.max(_order_key(
+        jax.lax.bitcast_convert_type(main, jnp.int32)), axis=-1))
+    _, chosen = jax.lax.top_k(
+        jax.lax.bitcast_convert_type(maxima, jnp.float32), k)
+    chosen = jnp.sort(chosen, axis=-1)
+    # (block ids are in bounds by construction: no fill)
+    candidates = jnp.take_along_axis(
+        main, chosen[:, :, None], axis=1, mode="promise_in_bounds",
+    ).reshape(rows, k * TOPK_BLOCK)
+    # the first column of each candidate block (and of the tail, slot k)
+    starts = chosen * TOPK_BLOCK
+    if tail:
+        candidates = jnp.concatenate(
+            [candidates, lg[:, blocks * TOPK_BLOCK:]], axis=-1)
+        starts = jnp.concatenate(
+            [starts, jnp.full((rows, 1), blocks * TOPK_BLOCK, starts.dtype)], axis=-1)
+    topv, at = jax.lax.top_k(candidates, k)
+    # a winner's slot names its block's first column: a compare against every
+    # slot and a sum (one small fusion; as a [rows, k] gather of ``starts``
+    # the TPU runs it an element at a time, 10-30 us a call)
+    slot = at // TOPK_BLOCK
+    first = jnp.sum(jnp.where(
+        slot[:, :, None] == jnp.arange(starts.shape[-1]), starts[:, None, :], 0), axis=-1)
+    topi = first + at - slot * TOPK_BLOCK
+    return topi[:, 0], topv, topi
+
+
+def _order_key(bits):
+    """float32 bit patterns (int32) <-> integers that order as ``lax.top_k``
+    orders the floats (its comparator on the TPU and the CPU alike: the
+    total order, -0.0 below +0.0); its own inverse."""
+    import jax.numpy as jnp
+
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
 
 
 def _choose(greedy, topi, draw, temperature):
@@ -149,15 +223,17 @@ def _slot_sampler(top_k: int):
     import jax.numpy as jnp
 
     def sample(keys, lg, temperature):
-        greedy, topv, topi = _top_k_candidates(lg, top_k)
+        with jax.named_scope("sample.topk"):
+            greedy, topv, topi = _top_k_candidates(lg, top_k)
 
         def one(key, tv):
             key, sub = jax.random.split(key)
             return key, jax.random.categorical(
                 sub, tv / jnp.maximum(temperature, 1e-6))
 
-        keys, draw = jax.vmap(one)(keys, topv)
-        return keys, _choose(greedy, topi, draw, temperature)
+        with jax.named_scope("sample.draw"):
+            keys, draw = jax.vmap(one)(keys, topv)
+            return keys, _choose(greedy, topi, draw, temperature)
 
     return sample
 
@@ -170,10 +246,12 @@ def _batch_sampler(top_k: int):
     import jax.numpy as jnp
 
     def sample(logits, key, temperature):
-        greedy, topv, topi = _top_k_candidates(logits, top_k)
-        draw = jax.random.categorical(
-            key, topv / jnp.maximum(temperature, 1e-6))
-        return _choose(greedy, topi, draw, temperature)
+        with jax.named_scope("sample.topk"):
+            greedy, topv, topi = _top_k_candidates(logits, top_k)
+        with jax.named_scope("sample.draw"):
+            draw = jax.random.categorical(
+                key, topv / jnp.maximum(temperature, 1e-6))
+            return _choose(greedy, topi, draw, temperature)
 
     return sample
 
@@ -2254,8 +2332,16 @@ class LLMServer(SeldonComponent):
                 queue_by_class = sched.depths()
         with self._prefix_lock:
             prefix_bytes = self._prefix_bytes
+        # the columns the sampler's last TopK runs over in each step program
+        # built so far (static: `_top_k_candidates`' rule on vocab and top_k)
+        sampling = {"pagedstep": "decode_step", "specstep": "spec_step",
+                    "first_token": "first_token"}
+        sampler_columns = {
+            sampling[key[0]]: sampler_topk_columns(self._cfg.vocab_size, self.top_k)
+            for key in list(self._decode_cache) if key[0] in sampling}
         return {
             "kv_cache_dtype": self.kv_cache_dtype,
+            "sampler_topk_columns": sampler_columns,
             # page-pool accounting (zeros until a batcher is attached):
             # in-use/total page gauge pair plus internal fragmentation —
             # the slack between tokens written and pages held

@@ -50,6 +50,13 @@ Eighth (PR 54): the layers of a class call ONE lowering of their block
 (models/transformer.py ``transformer_block``), which the compiler inlines: a
 compiled program holds no ``call``, and a layer's ops carry the scope path of
 their own call (``block_scope``).
+
+Ninth (PR 56): the sampler's TopK runs over the columns of the blocks its
+first stage names, not over the vocabulary. XLA's ``TopK`` custom call is bound
+by neither bytes nor FLOPs and grows with its columns (``custom-call
+f32[32,40]`` over ``[32, 32000]``: as long as the head's whole weight stream in
+a chat step, ledger PR 55); at Mistral's vocabulary the step's one TopK reads
+``[32, 5120]``, and a test model's 256 columns keep the direct form.
 """
 
 import re
@@ -977,6 +984,38 @@ def test_the_chunks_head_runs_for_one_row_inside_the_conditional(v5e, servers, p
     shapes = [out.shape for out in jax.tree.leaves(exe.out_info)]
     assert (1, 1, vocab) in shapes and (1, rows, vocab) not in shapes
     assert exe.memory_analysis().temp_size_in_bytes < 256 * vocab * 4
+
+
+def top_k_operands(hlo: str) -> list:
+    """The shape of the array each ``top_k`` of the traced program reads as the
+    compiler left it: a ``TopK`` custom call's operand, or the first operand of
+    the stable descending ``sort`` it makes of a ``top_k`` over a few hundred
+    columns."""
+    shapes = {m.group(2): tuple(int(n) for n in m.group(4).split(",") if n)
+              for line in hlo.splitlines() if (m := _INSTR.match(line))}
+    return sorted(
+        (kind, shapes[m.group(1)]) for kind, pattern in (
+            ("TopK", r"custom-call\(%?([\w.\-]+)\), custom_call_target=\"TopK\""),
+            ("sort", r" sort\(%?([\w.\-]+), [^)]*\)[^\n]*sample\.topk/top_k"))
+        for m in re.finditer(pattern, hlo))
+
+
+@pytest.mark.parametrize("config,reads", [
+    ("mistral_vocab", [("TopK", (32, 5120)), ("sort", (32, 250))]),
+    ("mistral", [("sort", (32, 256))])])
+def test_the_samplers_top_k_reads_the_chosen_blocks_not_the_vocabulary(v5e, servers, config, reads):
+    """Ninth (PR 56): in the decode step compiled for the described v5e at a
+    served vocabulary (32,000) no ``TopK`` custom call's operand has the
+    vocabulary as a dimension: the one there is reads the 40 chosen blocks'
+    5,120 columns, under ``sample.topk`` (the block maxima's top 40 of 250 is
+    a sort of 250 columns). A vocabulary under the rule's threshold keeps the
+    direct ``top_k`` over its 256 columns (which the compiler makes a sort)."""
+    server = servers(config)
+    hlo = compiled_text(server, "decode_step", v5e)
+    assert top_k_operands(hlo) == reads
+    for line in hlo.splitlines():
+        if 'custom_call_target="TopK"' in line:
+            assert "sample.topk/" in line
 
 
 LFM2_CELL = (32, 4096)
