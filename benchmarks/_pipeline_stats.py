@@ -1,5 +1,5 @@
 """Shared bench-side formatter for the decode-pipeline fields of
-``LLMServer.llm_stats()`` (llm_batch_bench + llm_7b_serving_bench).
+``LLMServer.llm_stats()`` (llm_batch_bench).
 
 llm_stats() destructively DRAINS the dispatch/sync/lag deques (the same
 contract /metrics scraping relies on), so call this once per measurement

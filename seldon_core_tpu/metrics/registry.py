@@ -117,7 +117,7 @@ class MetricsRegistry:
         # LLM decode-bandwidth observability (servers/llmserver.py
         # llm_stats): resident KV bytes, slot occupancy, per-step KV read
         # bytes, and a decode step-time histogram — the knobs the
-        # kv_cache_dtype / fused_norm optimizations move, exposed so the
+        # kv_cache_dtype optimization moves, exposed so the
         # bandwidth win is visible at /metrics (benchmarks/DECODE_NOTES.md)
         self._kv_cache_bytes = Gauge(
             "seldon_llm_kv_cache_bytes",
@@ -529,7 +529,7 @@ class MetricsRegistry:
             "chunk's rows while more than those were left of a prompt and no "
             "other live slot streamed (always full: rows over width is chunks)",
             base + ["width"], registry=self.registry)
-        # A model with conv layers (models/transformer.py ShortConv): what
+        # A model with conv layers (models/state_mixers.py ShortConv): what
         # went through them, counted on the loop from host integers; absent
         # for every other model
         # ... likewise "gdn" for linear-attention layers (GatedDeltaNet),

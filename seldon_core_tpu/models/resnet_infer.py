@@ -6,7 +6,7 @@ path — with an optional Pallas tier: consecutive *identity* bottleneck
 blocks (the 12 of 16 blocks in ResNet-50 with no projection/stride) run as
 single fused kernels (`ops/fused_resnet.fused_identity_chain`), one HBM
 read + one write per chain instead of XLA's per-op elementwise round trips
-(`benchmarks/profile_summary.json` attributes ~79% of device time there).
+(the earlier harness's profile attributed ~79% of device time there).
 
 Numerics match the ``fused=True`` flax module: bf16 conv compute, bf16 bias
 adds, f32 head. Parity-tested against ``model.apply`` in

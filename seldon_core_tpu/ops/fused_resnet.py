@@ -1,7 +1,7 @@
 """Pallas-fused ResNet identity-residual chains for TPU serving.
 
-Why this kernel exists: the single-chip ResNet-50 serving profile
-(`benchmarks/profile_summary.json`) attributes ~79% of leaf device time to
+Why this kernel exists: the single-chip ResNet-50 serving profile of the
+earlier harness attributed ~79% of leaf device time to
 *elementwise* fusion clusters rooted at residual-add/relu over the 56x56
 activations — XLA on this backend leaves each relu / residual-add as its own
 HBM round trip instead of folding it into the conv epilogues. An identity

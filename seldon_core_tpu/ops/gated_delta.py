@@ -1,7 +1,7 @@
 """Pallas TPU kernel: one decode step of the gated delta rule, every slot's
 matrix state read ONCE and written ONCE.
 
-``GatedDeltaNet`` (models/transformer.py) keeps a float32 matrix S [dk, dv] a
+``GatedDeltaNet`` (models/state_mixers.py) keeps a float32 matrix S [dk, dv] a
 value head a sequence and, a decode step, a sequence:
 
     S <- e^g S;  d = beta (v - S^T k);  S <- S + k d^T;  o = S^T q

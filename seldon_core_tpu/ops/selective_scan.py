@@ -1,7 +1,7 @@
 """Mamba-1's selective scan (S6), and the Pallas TPU kernel of a chunk's scan:
 h stays on chip across the chunk's rows.
 
-``Mamba1Mixer`` (models/transformer.py) keeps a float32 h [N, d] a sequence (d
+``Mamba1Mixer`` (models/state_mixers.py) keeps a float32 h [N, d] a sequence (d
 the mixer's channels, N the state's: Phi-4-mini-flash's [16, 5120]) and, a
 token, for EVERY (channel, state) pair on its own:
 

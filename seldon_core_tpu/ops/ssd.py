@@ -1,7 +1,7 @@
 """Mamba-2's selective state-space recurrence (SSD), and the Pallas TPU kernel
 of its decode step: every slot's state read ONCE and written ONCE.
 
-``Mamba2Mixer`` (models/transformer.py) keeps a float32 matrix h [P, N] a head
+``Mamba2Mixer`` (models/state_mixers.py) keeps a float32 matrix h [P, N] a head
 a sequence (P the head's width, N the state's: granite-4.0-h-micro's [64, 128],
 64 heads) and, a token, a head:
 

@@ -104,13 +104,13 @@ def row_lookups(params: Any, logical_specs: Any):
 
 
 def float32_leaves(params: Any, logical_specs: Any):
-    """Per leaf of ``params``: does it name one of models/transformer.py's
+    """Per leaf of ``params``: does it name one of models/leaves.py's
     FLOAT32_AXES (the stream mixing's maps, scalars and biases, the router's
     selection bias, a convolution's taps, a per-head norm's weight, the delta
     rule's A_log / dt_bias, the shared expert's scalar gate)? Such a leaf is
     precision-critical and small: no tree holds it in int8 or casts it to the
     serving dtype."""
-    from seldon_core_tpu.models.transformer import FLOAT32_AXES
+    from seldon_core_tpu.models.leaves import FLOAT32_AXES
 
     return _leaves_whose_spec(
         params, logical_specs, lambda s: any(axis in FLOAT32_AXES for axis in s))

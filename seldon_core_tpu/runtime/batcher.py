@@ -2808,7 +2808,7 @@ class ContinuousBatcher:
         elsewhere."""
         import jax
 
-        from seldon_core_tpu.models.transformer import gdn_step_walk, ssd_step_blocks
+        from seldon_core_tpu.models.state_mixers import gdn_step_walk, ssd_step_blocks
 
         plan = {"gdn": gdn_step_walk, "ssd": ssd_step_blocks}[kind]
         kernel = jax.default_backend() == "tpu" and plan(self.server._cfg) is not None

@@ -869,7 +869,7 @@ class LLMServer(SeldonComponent):
             )
 
         # the stream mixing's leaves and the selection bias stay float32 in
-        # every tree (models/transformer.py FLOAT32_AXES)
+        # every tree (models/leaves.py FLOAT32_AXES)
         from seldon_core_tpu.parallel.sharding import float32_leaves
 
         if not streamed:
@@ -1081,7 +1081,7 @@ class LLMServer(SeldonComponent):
         import jax.numpy as jnp
         from jax.tree_util import keystr, tree_flatten_with_path
 
-        from seldon_core_tpu.models.transformer import draw_small_leaf
+        from seldon_core_tpu.models.leaves import draw_small_leaf
         from seldon_core_tpu.ops.quantize import _register_pytree, quantize_array
         from seldon_core_tpu.parallel.sharding import (
             float32_leaves, head_split_outputs, row_lookups)

@@ -18,7 +18,7 @@ COMPILE_CACHE_DIR = os.path.join(
 
 def configure_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; every entry point calls
-    this first (transport/cli.py serving commands, bench.py). With
+    this first (transport/cli.py serving commands). With
     ``JAX_COMPILATION_CACHE_DIR`` set JAX reads it itself and nothing is
     set in code — that is how the cache is moved; otherwise it lives at
     :data:`COMPILE_CACHE_DIR`. Returns the directory in use. A 7B program

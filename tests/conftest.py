@@ -1,6 +1,6 @@
 """Test config: run JAX on a virtual 8-device CPU mesh so parallelism tests
 exercise real shardings without TPU hardware (the driver separately dry-runs
-the multi-chip path; chip_smoke.py and bench.py use the real chip).
+the multi-chip path; chip_smoke.py and perf/run.py use the real chip).
 
 JAX_PLATFORMS and XLA_FLAGS are set here, before the first jax import —
 JAX reads both at backend start-up."""

@@ -1,6 +1,6 @@
 """ops/gated_delta.py: one decode step of the gated delta rule with every
 slot's matrix state read once and written once, held to the expression
-(models/transformer.py ``gated_delta_rule`` at s = 1, which is the written-out
+(models/state_mixers.py ``gated_delta_rule`` at s = 1, which is the written-out
 recurrence: tests/test_reference_qwen3_next.py) under the Pallas interpreter;
 the state as the cache lays it (models/cache.py ``pack_state``: heads side by
 side along the lanes where dv is no whole lane tile: Olmo-Hybrid's 30 heads of
@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from seldon_core_tpu.models.cache import pack_state, unpack_state
-from seldon_core_tpu.models.transformer import gated_delta_rule, l2_normalize
+from seldon_core_tpu.models.state_mixers import gated_delta_rule, l2_normalize
 from seldon_core_tpu.ops.gated_delta import Plan, gated_delta_step, heads_a_lane_row, plan
 
 

@@ -756,13 +756,13 @@ def test_cli_list_and_usage_errors():
 
 
 def test_cli_single_cheap_contract_enforcing():
-    """The fused_norm contract end-to-end through the CLI (no model load:
+    """The ring-attention contract end-to-end through the CLI (no model load:
     this is the fast smoke of the real gate; CI runs the full registry)."""
-    res = cli("--contracts", "ops.fused_norm", "--format", "json")
+    res = cli("--contracts", "ops.ring_attention_seq8", "--format", "json")
     assert res.returncode == 0, res.stdout + res.stderr
     payload = json.loads(res.stdout)
     assert payload["findings"] == []
-    assert "ops.fused_norm" in payload["budget_diff"]
+    assert "ops.ring_attention_seq8" in payload["budget_diff"]
 
 
 @pytest.mark.slow

@@ -1,5 +1,5 @@
 """A second kind of state in the cache tree: a model whose ``layer_types`` name
-"conv" layers (LFM2's gated short convolution, models/transformer.py
+"conv" layers (LFM2's gated short convolution, models/state_mixers.py
 ``ShortConv``) keeps a fixed [taps - 1, dim] block a sequence beside the paged
 K/V of its attention layers, and the batcher carries it across every chunk
 boundary and decode step, between other slots' programs on the same arrays.
@@ -25,7 +25,7 @@ from seldon_core_tpu.models.cache import (
     init_paged_kv_caches,
     is_state_entry,
 )
-from seldon_core_tpu.models.transformer import short_conv
+from seldon_core_tpu.models.state_mixers import short_conv
 from seldon_core_tpu.runtime.batcher import ContinuousBatcher, _page_table_ops
 from seldon_core_tpu.runtime.resilience import ShedError
 from seldon_core_tpu.servers.llmserver import LLMServer

@@ -19,7 +19,6 @@ from seldon_core_tpu import ops
 from seldon_core_tpu.models import get_model
 from seldon_core_tpu.models.cache import init_paged_kv_caches
 from seldon_core_tpu.models.transformer import transformer_block
-from seldon_core_tpu.ops.fused_norm import fused_residual_rmsnorm
 from seldon_core_tpu.ops.grouped_matmul import grouped_matmul, make_visits, row_tile
 from seldon_core_tpu.ops.gqa_attention import gqa_page_attention, gqa_plan
 from seldon_core_tpu.ops.latent_attention import (ExpandedWalk, expanded_walk,
@@ -36,16 +35,6 @@ BLOCK_CALL = "call @transformer_block("
 
 def tpu_mlir(fn, *specs) -> str:
     return export.export(jax.jit(fn), platforms=["tpu"])(*specs).mlir_module()
-
-
-@pytest.mark.parametrize("rows,dim", [(8, 4096), (8, 2048), (256, 4096)])
-def test_fused_norm_lowers_for_tpu(rows, dim):
-    """decode batch at 7B / 0.7B width, and a prefill chunk's rows"""
-    text = tpu_mlir(
-        lambda x, h, w: fused_residual_rmsnorm(x, h, w, 1e-5, interpret=False),
-        S((rows, dim), jnp.bfloat16), S((rows, dim), jnp.bfloat16),
-        S((dim,), jnp.float32))
-    assert MOSAIC_CALL in text
 
 
 @pytest.mark.parametrize("rows,dim,width", [
@@ -381,9 +370,11 @@ def test_a_wide_latent_chunk_traces_the_expanded_read_once_and_the_rest_stay_abs
         assert "call @_read_expanded(" not in other
 
 
-def _paged_decode_mlir(**model_kwargs) -> str:
-    """One paged decode step of the tiny transformer, lowered for a TPU."""
-    model = get_model("llama-tiny", dtype="bfloat16", **model_kwargs)
+def test_paged_decode_reaches_no_pallas_kernel_by_default():
+    """At toy dims (4 heads: query rows under a sublane tile) one paged decode
+    step of the tiny transformer, lowered for a TPU, reads through the XLA
+    gather."""
+    model = get_model("llama-tiny", dtype="bfloat16")
     cfg = model.cfg
     tokens = jnp.zeros((2, 1), jnp.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
@@ -393,21 +384,8 @@ def _paged_decode_mlir(**model_kwargs) -> str:
         return model.apply(params, tokens, positions=positions, caches=pools,
                            block_tables=block_tables)
 
-    return tpu_mlir(step, params, pools, S((2, 1), jnp.int32),
-                    S((2, 1), jnp.int32), S((2, 2), jnp.int32))
-
-
-def test_paged_decode_reaches_no_pallas_kernel_by_default():
-    """At toy dims (4 heads: query rows under a sublane tile) the paged read is
-    the XLA gather on a TPU too, and fused_norm is off by default."""
-    assert MOSAIC_CALL not in _paged_decode_mlir()
-
-
-def test_fused_norm_flag_puts_the_kernel_on_the_tpu_path(monkeypatch):
-    """flag on, in a process whose backend is a TPU: the step carries the
-    Mosaic kernel — there is no reference to fall to"""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert MOSAIC_CALL in _paged_decode_mlir(fused_norm=True)
+    assert MOSAIC_CALL not in tpu_mlir(step, params, pools, S((2, 1), jnp.int32),
+                                       S((2, 1), jnp.int32), S((2, 2), jnp.int32))
 
 
 def test_kernel_choice_is_the_same_inside_and_outside_a_trace():
@@ -427,15 +405,14 @@ def test_kernel_choice_is_the_same_inside_and_outside_a_trace():
 
 
 def test_compile_error_on_a_tpu_raises(monkeypatch):
-    """On a (monkey-patched) TPU platform the kernels are handed to the
-    compiler; this backend cannot compile them, and that error must come
+    """On a (monkey-patched) TPU platform the kernel is handed to the
+    compiler; this backend cannot compile it, and that error must come
     out — not the XLA expression the deleted gates used to return."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     x = jnp.ones((8, 128), jnp.float32)
     w = jnp.ones((128,), jnp.float32)
+    q = jnp.ones((128, 128), jnp.int8)
     with pytest.raises(ValueError, match="interpret mode"):
-        fused_residual_rmsnorm(x, x, w, 1e-5)
+        int8_matmul(x, q, w)
     with pytest.raises(ValueError, match="interpret mode"):
-        jax.jit(lambda x, w: fused_residual_rmsnorm(x, x, w, 1e-5))(x, w)
-    with pytest.raises(ValueError, match="interpret mode"):
-        int8_matmul(x, jnp.ones((128, 128), jnp.int8), w)
+        jax.jit(int8_matmul)(x, q, w)

@@ -1,4 +1,4 @@
-"""granite-4.0-h's block (models/transformer.py: ``Mamba2Mixer`` over ops/ssd.py,
+"""granite-4.0-h's block (models/state_mixers.py: ``Mamba2Mixer`` over ops/ssd.py,
 ``Attention`` with no position at the config's own softmax scale, the four
 scalar multipliers, the tied table) and its plain float32 reference
 (models/reference.py), what holds them, and what they hold. The published
@@ -33,10 +33,10 @@ from seldon_core_tpu.models.cache import (
     state_bytes,
 )
 from seldon_core_tpu.models.convert import config_kwargs_from_hf, convert_hf_model
+from seldon_core_tpu.models.leaves import draw_small_leaf
 from seldon_core_tpu.models.transformer import (
     MAMBA_LAYERS_COMPOSE_REFUSAL,
     STATE_LAYERS_COMPOSE_REFUSAL,
-    draw_small_leaf,
 )
 from seldon_core_tpu.runtime.batcher import ContinuousBatcher
 from seldon_core_tpu.servers.llmserver import LLMServer

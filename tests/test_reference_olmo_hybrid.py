@@ -39,10 +39,10 @@ from seldon_core_tpu.models.cache import (
     unpack_state,
 )
 from seldon_core_tpu.models.convert import config_kwargs_from_hf, convert_hf_model
-from seldon_core_tpu.models.transformer import (
+from seldon_core_tpu.models.leaves import draw_small_leaf
+from seldon_core_tpu.models.state_mixers import (
     GDN_CHUNK,
     _unit_lower_inverse,
-    draw_small_leaf,
     gated_delta_rule,
     l2_normalize,
 )
@@ -348,7 +348,6 @@ def test_a_model_without_linear_attention_exports_no_path_and_zero_matrix_bytes(
 # ---- what is refused, and how the seeded leaves are drawn ----------------------
 @pytest.mark.parametrize("more,match", [
     (dict(hc_mult=4, layer_types=None), "norm_placement"),
-    (dict(fused_norm=True), "norm_placement"),
     (dict(norm_placement="post"), "norm_placement"),
     (dict(partial_rotary_factor=0.5), "rope_theta"),
     (dict(rope_scaling={"factor": 8.0, "low_freq_factor": 1.0, "high_freq_factor": 4.0,

@@ -1,6 +1,6 @@
-"""Phi-4-mini-flash-reasoning's stack (SambaY: models/transformer.py ``Mamba1Mixer``
-over ops/selective_scan.py, ``GatedMemoryUnit``, differential ``Attention`` over
-window / full / cross reads of plain GQA, ``LayerNorm``, biases, a prompt's chunk
+"""Phi-4-mini-flash-reasoning's stack (SambaY: models/state_mixers.py ``Mamba1Mixer``
+over ops/selective_scan.py and ``GatedMemoryUnit``; models/transformer.py's differential
+``Attention`` over window / full / cross reads of plain GQA, ``LayerNorm``, biases, a prompt's chunk
 that stops its rows behind the layer whose K/V the cross layers read) and its plain
 float32 reference (models/reference.py), what holds them, and what they hold. The
 ``phi4flash`` modeling file is NOT installed, so the model as a whole is held to the
@@ -465,7 +465,7 @@ def test_the_counters_reach_the_registry():
 # ---- what is refused, and the seeded leaves ---------------------------------------
 @pytest.mark.parametrize("more", [
     dict(n_experts=4), dict(kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8),
-    dict(hc_mult=2), dict(mtp_layers=1), dict(fused_norm=True), dict(rope_theta=10000.0),
+    dict(hc_mult=2), dict(mtp_layers=1), dict(rope_theta=10000.0),
     dict(kv_cache_dtype="int8"), dict(qk_norm="head"), dict(mesh=object())])
 def test_the_combinations_nobody_built_are_refused_where_the_config_is_made(more):
     with pytest.raises(ValueError) as refusal:

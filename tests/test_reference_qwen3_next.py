@@ -1,4 +1,4 @@
-"""Qwen3-Next's block (models/transformer.py ``GatedDeltaNet``, the gated
+"""Qwen3-Next's block (models/state_mixers.py ``GatedDeltaNet``, the gated
 attention, the expert share of ``MoEFFN``) and its plain float32 reference
 (models/reference.py: the delta rule as a ``lax.scan`` over tokens, no chunking,
 no cache), what holds them, and what they hold:
@@ -36,7 +36,7 @@ from seldon_core_tpu.models.cache import (
     init_paged_kv_caches,
     is_state_entry,
 )
-from seldon_core_tpu.models.transformer import GDN_CHUNK, gated_delta_rule, l2_normalize
+from seldon_core_tpu.models.state_mixers import GDN_CHUNK, gated_delta_rule, l2_normalize
 from seldon_core_tpu.runtime.batcher import ContinuousBatcher, _page_table_ops
 from seldon_core_tpu.servers.llmserver import LLMServer
 
@@ -528,7 +528,7 @@ def test_the_gauges_and_counters_reach_the_registry():
 
 
 def test_the_small_leaves_are_drawn_by_the_published_rule():
-    from seldon_core_tpu.models.transformer import FLOAT32_AXES, draw_small_leaf
+    from seldon_core_tpu.models.leaves import FLOAT32_AXES, draw_small_leaf
 
     key = jax.random.PRNGKey(0)
     a_log = np.asarray(draw_small_leaf("A_log", key, (4096,)))

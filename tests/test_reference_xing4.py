@@ -27,7 +27,7 @@ import pytest
 
 from seldon_core_tpu.models import get_model, reference
 from seldon_core_tpu.models.cache import PAD_POS, TRASH_PAGE, init_kv_caches, init_paged_kv_caches
-from seldon_core_tpu.models.transformer import FUSED_NORM_STREAMS_REFUSAL, HyperConnection
+from seldon_core_tpu.models.transformer import HyperConnection
 
 YARN = {"type": "yarn", "factor": 64, "original_max_position_embeddings": 16,
         "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}
@@ -604,17 +604,6 @@ def test_loop_counts_this_models_routing_and_context_as_deepseeks(served):
 
 
 # -------------------------------------------- what is not built is refused by name
-def test_fused_norm_is_refused_with_streams_at_load():
-    from seldon_core_tpu.servers.llmserver import LLMServer
-
-    server = LLMServer(model="transformer", model_kwargs=xing4(4, fused_norm=True),
-                       init_random=True, tokenizer="bytes")
-    with pytest.raises(ValueError, match="fused_norm does not compose with hyper-connections"):
-        server.load()
-    assert "hc_mult > 1" in FUSED_NORM_STREAMS_REFUSAL
-    get_model("transformer", **xing4(0, fused_norm=True))      # the plain residual keeps it
-
-
 def test_lora_is_refused_for_this_model_at_load():
     from seldon_core_tpu.servers.llmserver import LLMServer
 

@@ -1169,17 +1169,6 @@ def _build_jaxserver_predict():
     return js._apply, (js._params, _sds((4, 8), "float32"))
 
 
-def _build_fused_norm():
-    ensure_platform()
-    import jax
-
-    from seldon_core_tpu.ops.fused_norm import fused_residual_rmsnorm
-
-    fn = jax.jit(lambda x, h, w: fused_residual_rmsnorm(x, h, w, 1e-5))
-    return fn, (_sds((8, 2048), "bfloat16"), _sds((8, 2048), "bfloat16"),
-                _sds((2048,), "float32"))
-
-
 def _build_ring_attention():
     ensure_platform()
     import jax
@@ -1727,18 +1716,6 @@ def all_contracts() -> List[Contract]:
             description="JAXServer jitted apply (tiny MLP checkpoint, "
                         "bucket=4): the generic predict hot path",
             build=_build_jaxserver_predict,
-            collectives={},
-            cost=True,
-        ),
-        Contract(
-            name="ops.fused_norm",
-            description="fused residual+RMSNorm ([8,2048] bf16): the decode "
-                        "block epilogue",
-            build=_build_fused_norm,
-            # both outputs (residual sum, normed activation) must stay in
-            # the model dtype — the f32 norm INTERNALS are the contract,
-            # f32 OUTPUTS would double the block's activation traffic
-            out_dtypes=((0, "bf16"), (1, "bf16")),
             collectives={},
             cost=True,
         ),
