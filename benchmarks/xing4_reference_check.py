@@ -21,6 +21,12 @@ configuration's `reference_tolerance` is set from (perf/configs/<config>.json).
         --long-wrongs state_held_in_bf16,weights_at_4_bits --out chiprun_out/pr55/reference_check.json
     (then once more with --probes 2 --wrong-probes 2 --only-low: the 4-bit tree in a call of its
     own, because the machine's host holds 40 GiB and a 15-layer tree is 11.5 GB of it)
+    chiprun -- python benchmarks/xing4_reference_check.py --workload lfm2-rag-mixed \
+        --probes 1 --wrong-probes 0 --long 1 --long-size 900+8,1924+8 --long-unseeded \
+        --out chiprun_out/pr58/ref_lfm2.json
+    (PR 58: the one-offs come WITHOUT a seed, so their tail of 900 rows is ONE padded chunk of
+    the wide program, whose row 899 the head reads; seeded, as every other call here, the same
+    tail is four narrow chunks)
 
 In one process on the chip: the server the cell's files describe (the
 configuration's `weights_seed`, the cell's slots and cache length) and its
@@ -210,8 +216,13 @@ def main() -> None:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--probes", type=int, default=6)
     ap.add_argument("--long", type=int, default=0)
-    ap.add_argument("--long-size", default="", metavar="PROMPT+NEW",
-                    help="the one-off requests' size (default: the cell's longest prompt and answer)")
+    ap.add_argument("--long-size", default="", metavar="PROMPT+NEW[,PROMPT+NEW]",
+                    help="the one-off requests' size, or sizes: --long of each "
+                         "(default: the cell's longest prompt and answer)")
+    ap.add_argument("--long-unseeded", action="store_true",
+                    help="the one-offs come WITHOUT a seed, once the wide chunk program is there "
+                         "(a first request nobody reads starts its build): a tail of 769-1,024 "
+                         "rows is then ONE padded wide chunk (runtime/batcher.py _chunk_width)")
     ap.add_argument("--wrong-probes", type=int, default=1)
     ap.add_argument("--only-wrongs", default="", metavar="NAME,NAME",
                     help="of the wrong references (and weights_at_4_bits), these alone")
@@ -254,21 +265,30 @@ def main() -> None:
     sizes = [(probe["prompt_tokens"], probe["output_tokens"])] * args.probes
     if args.long:
         if args.long_size:
-            size = tuple(int(n) for n in args.long_size.split("+"))
+            long_sizes = [tuple(int(n) for n in size.split("+")) for size in args.long_size.split(",")]
         else:   # (a fixed length has a "value" and no "max")
             request = cell["traffic"]["request"]
-            size = tuple(request[key].get("max", request[key].get("value"))
-                         for key in ("prompt_tokens", "output_tokens"))
-        sizes.extend([size] * args.long)
+            long_sizes = [tuple(request[key].get("max", request[key].get("value"))
+                                for key in ("prompt_tokens", "output_tokens"))]
+        sizes.extend(size for size in long_sizes for _ in range(args.long))
     asks = [(rng.integers(97, 123, size=n).tolist(), new) for n, new in sizes]
 
     async def serve():
         served = []
         for i, (prompt, new) in enumerate(asks):
+            seed = 1234 + i
+            if i >= args.probes and args.long_unseeded:
+                seed = None
+                if batcher._wide_build is None:   # no request has finished yet
+                    await batcher.submit(rng.integers(97, 123, size=64).tolist(), 2)
+                if batcher._wide_build is not None:   # (None: slots too short for a wide chunk)
+                    await asyncio.to_thread(batcher._wide_build.join)
             info = {"logits": []}
             t1 = time.monotonic()
-            out = await batcher.submit(prompt, new, info=info, seed=1234 + i)
-            print(f"served {len(prompt)} + {len(out)} in {time.monotonic() - t1:.1f}s", flush=True)
+            out = await batcher.submit(prompt, new, info=info, seed=seed)
+            print(f"served {len(prompt)} + {len(out)} in {time.monotonic() - t1:.1f}s "
+                  f"(seed {seed}); chunks so far by head and width: "
+                  f"{batcher._phases.stats()['chunk_head']}", flush=True)
             # a dense model routes nothing: there is nothing to follow
             assert info.get("routing_start", 0) == 0
             took = np.stack(info["routing"]) if info.get("routing") else None
@@ -319,7 +339,9 @@ def main() -> None:
                 "seconds": time.monotonic() - t1}, ref
 
     result = {"workload": args.workload, "layers": model_cfg.n_layers, "set": args.set,
-              "peak_bytes_in_use": stats.get("peak_bytes_in_use"), "probes": [], "wrong": {}, "long": None}
+              "peak_bytes_in_use": stats.get("peak_bytes_in_use"), "probes": [], "wrong": {}, "long": None,
+              "long_unseeded": args.long_unseeded, "chunk_rows": loop["chunk_rows"],
+              "chunk_head": loop["chunk_head"]}
 
     def save():
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)

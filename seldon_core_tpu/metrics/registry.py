@@ -518,16 +518,19 @@ class MetricsRegistry:
                  "the live rows lie in (token)"))}
         self._chunk_head = Counter(
             "seldon_llm_chunk_head_total",
-            "Prefill chunks by whether the chunk program ran the head: ran=1 a "
-            "prompt's last chunk (for its one last row), ran=0 every chunk "
-            "before (no byte of the head read, no logits written)",
-            base + ["ran"], registry=self.registry)
+            "Prefill chunks by whether the chunk program ran the head (ran=1 a "
+            "prompt's last chunk, for its one last row; ran=0 every chunk "
+            "before: no byte of the head read, no logits written) and by the "
+            "width of the chunk program that ran: ran=1 at the wide chunk's "
+            "width over ran=1 is the share of prompts whose tail went in one "
+            "padded wide call",
+            base + ["ran", "width"], registry=self.registry)
         self._chunk_rows = Counter(
             "seldon_llm_chunk_rows_total",
             "Prompt rows (tokens) prefilled by chunks, by the width of the chunk "
             "program that took them: the batcher's prefill_chunk, or its wide "
-            "chunk's rows while more than those were left of a prompt and no "
-            "other live slot streamed (always full: rows over width is chunks)",
+            "chunk's rows while the narrow program would have computed at least "
+            "those for what was left of a prompt and no other live slot streamed",
             base + ["width"], registry=self.registry)
         # A model with conv layers (models/state_mixers.py ShortConv): what
         # went through them, counted on the loop from host integers; absent
@@ -1248,8 +1251,9 @@ class MetricsRegistry:
         for key, counter in self._kv_writes.items():
             for path, n in stats.get(f"kv_{key}", {}).items():
                 self._counter_catch_up(counter, n, path=path)
-        for ran, n in stats.get("chunk_head", {}).items():
-            self._counter_catch_up(self._chunk_head, n, ran=ran)
+        for ran, widths in stats.get("chunk_head", {}).items():
+            for width, n in widths.items():
+                self._counter_catch_up(self._chunk_head, n, ran=ran, width=width)
         for width, n in stats.get("chunk_rows", {}).items():
             self._counter_catch_up(self._chunk_rows, n, width=width)
         for key, counter in self._state_layers.items():

@@ -145,8 +145,9 @@ with page-exhaustion shedding (503 + Retry-After, runtime/resilience.py
 ShedError) as the relief valve — the decode loop never raises. The chunked
 admission (``prefill_chunk``; Sarathi-Serve, Agrawal et al., OSDI 2024) means
 a 2k-token prompt never stalls in-flight decodes for a whole prompt's forward
-(a chunk is wider while more than a wide chunk is left of the prompt and no
-other live slot streams: ``_chunk_width``).
+(a chunk is wider while the narrow chunks of what is left of the prompt would
+compute a wide chunk's rows anyway, the last one padded, and no other live
+slot streams: ``_chunk_width``).
 Page bookkeeping is host-side (PageAllocator, lock-guarded); block-table
 updates are jitted device ops that serialize behind in-flight steps in device
 program order.
@@ -184,7 +185,9 @@ logger = logging.getLogger(__name__)
 DEFAULT_PAGE_SIZE = 64
 DEFAULT_PREFILL_CHUNK = 256
 # ... and the rows of a WIDE chunk, which a prompt's next chunk is while more
-# than that many of its rows are left and no other live slot streams
+# than that many LESS A NARROW CHUNK'S of its rows are left (the narrow program
+# would compute that many rows for them anyway, its last call padded; a
+# seeded request: more than that many) and no other live slot streams
 # (``ContinuousBatcher._chunk_width``): a chunk streams every weight it touches
 # once whatever its rows, a routed expert sees a few dozen of 256 rows, and the
 # decode step that follows each chunk streams them all once more for the few
@@ -717,11 +720,12 @@ class LoopPhases:
         # scatter row a token) and the pool pages a layer's write wrote
         self.kv_chunk_writes = dict.fromkeys(KV_WRITE_PATHS, 0)
         self.kv_pages_written = dict.fromkeys(KV_WRITE_PATHS, 0)
-        # prefill chunks by whether the program's conditional ran the head:
-        # "1" a prompt's last chunk (for its one last row), "0" the rest
-        self.chunk_head = {"1": 0, "0": 0}
+        # prefill chunks by whether the program's conditional ran the head
+        # ("1" a prompt's last chunk, for its one last row; "0" the rest) and
+        # the width of the chunk program that ran: {ran: {width: chunks}}
+        self.chunk_head: Dict[str, Dict[str, int]] = {"1": {}, "0": {}}
         # live rows (prompt tokens) by the width of the chunk program that took
-        # them; a wide chunk is always full, so its rows over its width are chunks
+        # them (a prompt's last chunk may be part full at either width)
         self.chunk_rows: Dict[str, int] = {}
         # live rows through the state layers of each kind (a model with
         # layer_types: "conv" the short convolutions, "gdn" the linear-attention
@@ -842,7 +846,7 @@ class LoopPhases:
                    if any(self.decoder_rows["self"].values()) else {}),
                 "kv_chunk_writes": dict(self.kv_chunk_writes),
                 "kv_pages_written": dict(self.kv_pages_written),
-                "chunk_head": dict(self.chunk_head),
+                "chunk_head": {ran: dict(widths) for ran, widths in self.chunk_head.items()},
                 "chunk_rows": dict(self.chunk_rows)}
 
     def count_attention(self, program: str, context_tokens: int, rows_read: int,
@@ -2662,8 +2666,9 @@ class ContinuousBatcher:
                 self._chunk_shapes = self._shapes_of(args)
             logits, self._caches, aside = fn(*args)
         job.next = start + n
-        self._phases.chunk_head[str(int(last))] += 1
-        self._phases.chunk_rows[str(C)] = self._phases.chunk_rows.get(str(C), 0) + n
+        width, heads = str(C), self._phases.chunk_head[str(int(last))]
+        heads[width] = heads.get(width, 0) + 1
+        self._phases.chunk_rows[width] = self._phases.chunk_rows.get(width, 0) + n
         self._phases.count_attention("chunk", start + n, self._rows_read(C, [start + n], 1),
                                      self._read_form(C))
         if self.window:
@@ -2747,19 +2752,27 @@ class ContinuousBatcher:
 
     def _chunk_width(self, job: _PrefillJob) -> int:
         """Rows of the job's NEXT chunk, chosen when it is built from what the
-        loop sees: the wide program while more than its rows are left of the
-        prompt (so a wide chunk is always full, and the prompt's last chunk,
-        whose row the head reads, is the narrow program), no OTHER live slot
-        streams (a stream's gap stays a step and one narrow chunk; the job's
-        own stream only gets its first token sooner) and the wide program is
-        THERE (``_build_wide_program``); the job's own width otherwise. While
-        the program is being built a request takes narrow chunks, unless it
-        came with a SEED: that one asks for the same tokens whenever it comes,
-        a chunk's width is in its roundings, so its wide chunk waits for the
-        program (``_prefill_step``)."""
+        loop sees: the wide program while the narrow one would compute at least
+        a wide chunk's rows for what is left of the prompt anyway (more than
+        ``wide - chunk`` rows left: the narrow plan's last call is padded up to
+        ``wide`` rows or past them, so ONE wide call computes no row more and
+        streams every weight it touches once where the narrow calls stream it
+        ``wide / chunk`` times; a prompt's last chunk is then the wide program's,
+        its rows behind the prompt's end padding and ``head_row`` inside it), no
+        OTHER live slot streams (a stream's gap stays a step and one narrow
+        chunk; the job's own stream only gets its first token sooner) and the
+        wide program is THERE (``_build_wide_program``); the job's own width
+        otherwise. While the program is being built a request takes narrow
+        chunks, unless it came with a SEED: that one asks for the same tokens
+        whenever it comes, a chunk's width is in its roundings, so its wide
+        chunk waits for the program (``_prefill_step``), and it takes the wide
+        program only while MORE than its rows are left (its wide chunks are
+        always full: it would wait for the program to save three calls, and
+        its widths stay what its length alone gave them before PR 58)."""
         wide, build = self.prefill_wide, self._wide_build
-        if (job.L - job.next > wide > 0 and build is not None
-                and (job.seed is not None or not build.is_alive())
+        seeded = job.seed is not None
+        if (wide > 0 and job.L - job.next > (wide if seeded else wide - job.chunk)
+                and build is not None and (seeded or not build.is_alive())
                 and not any(s.active and s.on_token is not None
                             for i, s in enumerate(self._slots) if i != job.slot)):
             return wide

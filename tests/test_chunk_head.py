@@ -16,20 +16,28 @@ zeros. Held here, on the CPU at toy widths:
     (tests/test_tpu_program.py holds the same of the program compiled for a
     v5e, the int8 head's dequant included);
 (c) one program a chunk shape;
-(d) ``chunk_head`` counts 1 ran / n - 1 not for a prompt of n chunks, and
-    reaches ``/metrics`` as ``seldon_llm_chunk_head_total{ran}``.
+(d) ``chunk_head`` counts 1 ran / n - 1 not for a prompt of n chunks, by the
+    width of the chunk program that ran (ISSUE 58), and reaches ``/metrics`` as
+    ``seldon_llm_chunk_head_total{ran,width}``.
 """
 
 from __future__ import annotations
 
 import asyncio
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from seldon_core_tpu.models.cache import NULL_PAGE, PAD_POS, RESERVED_PAGES, init_paged_kv_caches
+from seldon_core_tpu.models.cache import (
+    NULL_PAGE,
+    PAD_POS,
+    RESERVED_PAGES,
+    init_paged_kv_caches,
+    window_slot_pages,
+)
 from seldon_core_tpu.runtime.batcher import ContinuousBatcher
 from seldon_core_tpu.servers.llmserver import LLMServer, _slot_sampler
 
@@ -87,6 +95,35 @@ def make_batcher(server, **kw) -> ContinuousBatcher:
                 prefill_chunk=CHUNK)
     base.update(kw)
     return ContinuousBatcher(server, **base)
+
+
+def built() -> threading.Thread:
+    """What ``_wide_build`` holds once the wide program's build has ended."""
+    thread = threading.Thread(target=lambda: None)
+    thread.start()
+    thread.join()
+    return thread
+
+
+def set_wide_chunk(b: ContinuousBatcher, wide: int) -> ContinuousBatcher:
+    """A second chunk width at rehearsal sizes: a batcher made with an explicit
+    ``prefill_chunk`` has none (a width somebody gave is every chunk's), so a
+    test sets the wide one by hand and says its program is there (its first
+    call builds it). The window class is sized when the batcher is made, for
+    its widest chunk: a slot may hold a wide chunk's pages here, and the class
+    (every slot's worth at the narrow width) holds the one request of a test."""
+    assert b.prefill_wide == 0
+    b.prefill_wide, b._wide_build = wide, built()
+    if b.window and wide:
+        b.window_slot_pages = min(b.n_pages, window_slot_pages(b.window, wide, b.page_size))
+        assert b.window_slot_pages <= b.window_pool_pages - RESERVED_PAGES
+    return b
+
+
+def chunk_events(timelines) -> list:
+    """[(start, tokens, head)] of the chunks of the requests recorded."""
+    return sorted((e["start"], e["tokens"], e["head"]) for t in timelines for e in t["events"]
+                  if e["kind"] == "prefill_chunk")
 
 
 def chunks_of(prompt):
@@ -180,7 +217,7 @@ def test_first_token_and_probe_row_are_the_all_rows_forms(servers, model, sampli
     assert out[0] == int(want_tok[0])
     assert out == server.generate([PROMPT], max_new_tokens=3, seed=99)["tokens"][0]
     # (d) three chunks a prompt: the head ran in one
-    assert stats["chunk_head"] == {"1": 1, "0": 2}
+    assert stats["chunk_head"] == {"1": {"8": 1}, "0": {"8": 2}}
 
 
 # ------------------------------------------- (b) the program, lowered for the CPU
@@ -221,18 +258,31 @@ def test_one_program_a_chunk_shape(servers):
         return stats
 
     stats = asyncio.run(go())
-    assert stats["chunk_head"] == {"1": 3, "0": 2}
+    assert stats["chunk_head"] == {"1": {"8": 3}, "0": {"8": 2}}
     assert [k for k in server._prefill_cache if k[0] == "pchunk"] == [
         ("pchunk", CHUNK, PAGES, False)]
     assert server._get_prefill_chunk(CHUNK, PAGES)._cache_size() == 1
 
 
 # ------------------------------------------- (d) the counter
-def test_chunk_head_counts_one_ran_a_prompt_and_reaches_metrics(servers):
+def exposed_chunk_heads(stats) -> dict:
+    """{(ran, width): value} of ``seldon_llm_chunk_head_total`` on ``/metrics``."""
     from types import SimpleNamespace
 
     from seldon_core_tpu.metrics.registry import MetricsRegistry
 
+    registry = MetricsRegistry()
+    registry.sync_llm(SimpleNamespace(llm_stats=lambda: stats))
+    found = {}
+    for line in registry.expose().decode().splitlines():
+        if line.startswith("seldon_llm_chunk_head_total{"):
+            labels = {part.split("=")[0]: part.split('"')[1]
+                      for part in line[line.index("{") + 1:line.index("}")].split(",")}
+            found[labels["ran"], labels["width"]] = float(line.rsplit(" ", 1)[1])
+    return found
+
+
+def test_chunk_head_counts_one_ran_a_prompt_and_reaches_metrics(servers):
     server = servers("dense_gqa")
 
     async def go():
@@ -245,14 +295,32 @@ def test_chunk_head_counts_one_ran_a_prompt_and_reaches_metrics(servers):
         return stats, timelines
 
     stats, timelines = asyncio.run(go())
-    assert stats["chunk_head"] == {"1": 3, "0": 3}
+    assert stats["chunk_head"] == {"1": {"8": 3}, "0": {"8": 3}}
     # the flight recorder's chunk events say which chunk it was
     heads = sorted([e["head"] for e in t["events"] if e["kind"] == "prefill_chunk"]
                    for t in timelines)
     assert heads == [[0, 0, 1], [0, 1], [1]]
-    registry = MetricsRegistry()
-    registry.sync_llm(SimpleNamespace(llm_stats=lambda: stats))
-    lines = [ln for ln in registry.expose().decode().splitlines()
-             if ln.startswith("seldon_llm_chunk_head_total{")]
-    assert {ran: ln.rsplit(" ", 1)[1] for ln in lines for ran in "01" if f'ran="{ran}"' in ln} == {
-        "0": "3.0", "1": "3.0"}
+    assert exposed_chunk_heads(stats) == {("0", "8"): 3.0, ("1", "8"): 3.0}
+
+
+def test_chunk_head_says_which_programs_ran_the_head(servers):
+    """Two chunk widths a batcher (the wide one set by hand, as
+    tests/test_wide_chunk.py does): ``ran="1"`` at the wide width over ``ran="1"``
+    is the share of prompts whose tail went in ONE padded wide chunk. Here two of
+    four: 21 rows are a wide chunk and 5 rows, 13 rows one padded wide chunk, 29
+    rows a wide chunk and another of 13 rows, 6 rows a narrow one."""
+    server = servers("dense_gqa")
+
+    async def go():
+        b = set_wide_chunk(make_batcher(server), 16)
+        for n in (21, 13, 29, 6):
+            await b.submit((PROMPT + PROMPT)[:n], 2)
+        stats = b._phases.stats()
+        await b.close()
+        return stats
+
+    stats = asyncio.run(go())
+    assert stats["chunk_head"] == {"1": {"8": 2, "16": 2}, "0": {"16": 2}}
+    assert stats["chunk_rows"] == {"16": 16 + 13 + 29, "8": 5 + 6}
+    assert exposed_chunk_heads(stats) == {
+        ("0", "16"): 2.0, ("1", "8"): 2.0, ("1", "16"): 2.0}

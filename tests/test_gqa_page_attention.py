@@ -516,6 +516,37 @@ def test_the_window_read_is_the_chain_with_the_lower_bound(case):
     assert int(visits.start.sum()) >= min(expected, 1)
 
 
+@pytest.mark.parametrize("window", [0, 96], ids=["full layer", "window layer"])
+@pytest.mark.parametrize("valid_rows", [64 + 64 + 30, 64 + 9, 256 - 7],
+                         ids=["third tile part padding, fourth all", "two tiles all padding",
+                              "the last tile's last rows"])
+def test_a_padded_wide_chunks_query_tiles_behind_the_prompts_end(valid_rows, window):
+    """A prompt's tail in ONE wide chunk (PR 58): a chunk of SEVERAL query tiles
+    (here four of 64 tokens x 4 heads a lane block) behind a context, its rows
+    behind the prompt's end padding: the tile that holds the prompt's last row is
+    part padding and the tiles behind it are ALL padding (their smallest position
+    is PAD_POS: every visit of theirs is "whole", computes no predicate, and their
+    rows are nobody's). Every valid row is the expression's, every row finite."""
+    heads, kvh, hd, s, n_pages, walk = 16, 4, 128, 256, 24, Plan(2, 256, 4)
+    held = 300 + valid_rows
+    pool = Pool([held, NOBODY], n_pages, kvh * hd, allocated=[n_pages, 0])
+    positions = np.full((2, s), PAD_POS, np.int32)
+    positions[0, :valid_rows] = 300 + np.arange(valid_rows)
+    positions[1] = 0
+    positions = jnp.asarray(positions)
+    if window:
+        give_back(pool, [held, NOBODY], positions, window)
+    cache, tables = pool.arrays()
+    q = queries(2, s, heads, hd)
+    clean = tuple(jnp.nan_to_num(a) if a.dtype != jnp.int32 else a for a in cache)
+    want = np.asarray(paged_attention_ref(q, clean, tables, positions, kvh, window), np.float32)
+    got = np.asarray(gqa_page_attention(q, *cache, tables, positions, kvh, walk, interpret=True,
+                                        window=window), np.float32)
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got[0, :valid_rows], want[0, :valid_rows], atol=2e-2, rtol=2e-2)
+    assert np.all(got[1] == 0.0)
+
+
 def test_a_chunk_of_seven_heads_a_kv_head_gets_a_tile_that_divides_its_rows():
     """28 query heads over 4 KV heads (SmallThinker): 512 tokens are 3,584 query
     rows a lane block, which 2,048 does not divide; the walk takes the largest

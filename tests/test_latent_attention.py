@@ -134,6 +134,12 @@ CASES = {
         16, 128, [50], 12, dict(valid=50), ExpandedWalk(4, 128)),
     "expanded: two sequences and a slot nobody holds": (
         4, 128, [400, NOBODY, 150], 14, {}, ExpandedWalk(4, 128)),
+    # a prompt's tail in ONE wide chunk behind its context (PR 58): the rows behind
+    # the prompt's end are padding in the last token tile, or are the whole of it
+    "expanded: a padded wide chunk, its second tile part padding": (
+        16, 256, [500 + 200], 24, dict(valid=200), ExpandedWalk(4, 128)),
+    "expanded: a padded wide chunk, its second tile all padding": (
+        16, 256, [500 + 100], 24, dict(valid=100), ExpandedWalk(4, 128)),
 }
 
 

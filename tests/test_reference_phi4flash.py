@@ -338,12 +338,58 @@ def test_chunked_prefill_and_decode_equal_the_full_forward(server, length):
     # a prompt (its last chunk's) and every decoded row ran the rest
     assert stats["self_decoder_rows"] == {"chunk": length, "decode": 4}
     assert stats["cross_decoder_rows"] == {"chunk": 1, "decode": 4}
-    assert stats["chunk_head"] == {"1": 1, "0": chunks - 1}
+    assert stats["chunk_head"] == {"1": {"8": 1}, "0": {"8": chunks - 1} if chunks > 1 else {}}
     assert stats["attn_shared_calls"] == {"chunk": 1, "decode": 4}
     assert stats["attn_shared_context_tokens"] == {
         "chunk": length, "decode": sum(length + 1 + j for j in range(4))}
     assert stats["attn_window_calls"] == {"chunk": chunks, "decode": 4}
     assert "ssd_rows" not in stats and "ssd_step_path" not in stats
+
+
+# (rows of the prompt, its last chunk without a seed, its last chunks with one)
+@pytest.mark.parametrize("length,last,seeded_last", [
+    (13, (0, 13, 1), [(0, 8, 0), (8, 5, 1)]),
+    (29, (16, 13, 1), [(0, 16, 0), (16, 8, 0), (24, 5, 1)]),
+    (41, (32, 9, 1), [(16, 16, 0), (32, 8, 0), (40, 1, 1)]),
+])
+def test_a_padded_wide_last_chunk_narrows_to_the_head_row_and_a_seeded_one_stays_narrow(
+        server, length, last, seeded_last):
+    """Two chunk widths (8 and 16: the wide one set by hand, as
+    tests/test_wide_chunk.py does). WITHOUT a seed a prompt whose tail the narrow
+    program would pad to a wide chunk's rows takes it in ONE wide chunk, rows of
+    padding behind the prompt's end: the layers past cfg.kv_source, the norm and
+    the head run on ``head_row`` = its last VALID row (not the chunk's last), the
+    h and the conv rows are those at that row, the window class books the rows
+    the chunk holds, and the logits are the reference's. WITH a seed the same
+    prompt takes the chunks it took before PR 58 (its tail narrow) and gives the
+    same tokens."""
+    from test_chunk_head import chunk_events, set_wide_chunk
+
+    prompt, wide = LONG[:length], 2 * CHUNK
+
+    async def go(**kw):
+        b = set_wide_chunk(batcher(server, tracing=True), wide)
+        got = await ask(b, prompt, **kw)
+        stats = {**b._phases.stats(), **b.page_stats()}
+        chunks = chunk_events(b._flight.timelines())
+        await b.close()
+        return got, stats, chunks
+
+    (out, logits), stats, chunks = asyncio.run(go())
+    assert chunks[-1] == last and last[1] < wide
+    assert stats["chunk_head"]["1"] == {str(wide): 1}
+    assert stats["chunk_rows"] == {str(wide): length}
+    assert_close(logits, reference_logits(server, prompt, out), 3e-5)
+    # ONE row a prompt ran the cross-decoder, whatever the width of its last chunk
+    assert stats["self_decoder_rows"] == {"chunk": length, "decode": 4}
+    assert stats["cross_decoder_rows"] == {"chunk": 1, "decode": 4}
+    assert stats["attn_shared_context_tokens"]["chunk"] == length
+
+    (seeded_out, seeded_logits), seeded_stats, seeded_chunks = asyncio.run(go(seed=1234))
+    assert seeded_chunks[-len(seeded_last):] == seeded_last
+    assert seeded_stats["chunk_head"]["1"] == {str(CHUNK): 1}
+    assert seeded_out == out          # (greedy: the seed draws nothing)
+    assert_close(seeded_logits, logits, 3e-5)
 
 
 @pytest.mark.parametrize("length,new", [(CHUNK + 2, 1), (3, 9), (2 * CHUNK, 6)])

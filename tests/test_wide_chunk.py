@@ -2,15 +2,19 @@
 
 The width of a prompt's NEXT chunk is chosen when the chunk is built
 (``ContinuousBatcher._chunk_width``): the wide program (``WIDE_PREFILL_CHUNK``
-rows) while more than that many rows of the prompt are left and no other live
-slot has ``on_token``; the job's own width otherwise. Held here, on the CPU at
-rehearsal widths (8-row chunks, 16-row wide chunks: the batcher's attribute is
-set by the test, there is no option for it):
+rows) while the narrow program would compute at least that many rows for what
+is left of the prompt anyway (more than ``wide - narrow`` rows left, ISSUE 58;
+a SEEDED request: more than ``wide``, as every request before it) and no other
+live slot has ``on_token``; the job's own width otherwise. Held here, on the
+CPU at rehearsal widths (8-row chunks, 16-row wide chunks: the batcher's
+attribute is set by the test, there is no option for it):
 
-(a) for the four token mixers (GQA pages, latent rows, a conv state, a
-    delta-rule state) a prompt prefilled as wide + narrow chunks gives the
-    tokens of the all-narrow run and its logits within the tolerance the
-    chunk-against-whole tests use; the last chunk is narrow and runs the head;
+(a) for the seven token mixers (GQA pages, latent rows, a conv state, a
+    delta-rule state, a window page class, a state-space state, a selective
+    scan under a cross-decoder) a prompt prefilled in wide chunks, its tail ONE
+    padded wide chunk that runs the head or a narrow one, gives the tokens of
+    the all-narrow run, its logits within the tolerance the chunk-against-whole
+    tests use, and the same pool and state behind the prompt's last chunk;
 (b) the rule itself from host state alone;
 (c) under a streaming neighbour no chunk is wide, the job's own stream does not
     count, and ``seldon_llm_chunk_rows_total{width}`` says so on ``/metrics``;
@@ -25,9 +29,16 @@ from __future__ import annotations
 import asyncio
 import threading
 
+import jax
 import numpy as np
 import pytest
 from test_chunk_head import MODELS as CHUNK_HEAD_MODELS
+from test_chunk_head import built, chunk_events, set_wide_chunk
+from test_hybrid_state import MAMBA_KW, SAMBAY_KW
+from test_reference_smallthinker import KW as WINDOW_KW
+
+from seldon_core_tpu.models import cache as kvcache
+from seldon_core_tpu.models.cache import NULL_PAGE, PAD_POS
 
 from seldon_core_tpu.runtime.batcher import (
     DEFAULT_PAGE_SIZE,
@@ -54,10 +65,30 @@ MODELS = {
         layer_types=["linear_attention"] * 3 + ["full_attention"], linear_num_key_heads=2,
         linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=8,
         linear_conv_kernel_dim=4),
+    # SmallThinker's period in small (tests/test_reference_smallthinker.py): a
+    # global layer and three window layers, whose pages behind a window of 12
+    # rows are given back chunk by chunk
+    "window_pages": WINDOW_KW,
+    # granite-4.0-h's and Phi-4-mini-flash's in small (tests/test_hybrid_state.py):
+    # mamba layers (a float32 h a head), and s6 layers under window, full and
+    # cross-attention layers, the layers past cfg.kv_source run on ``head_row`` alone
+    "state_space": MAMBA_KW,
+    "selective_scan_cross_decoder": SAMBAY_KW,
 }
-# two wide chunks (16 + 16), then 13 rows that are no more than a wide chunk:
-# a full narrow one and the last, 5 rows long
+# two wide chunks (16 + 16), then 13 rows: more than a wide chunk less a narrow
+# one, which the narrow program would compute as 16 (8 + 5 padded to 8), so ONE
+# wide chunk of 13 rows and 3 of padding, whose row 12 the head reads
 PROMPT = np.random.default_rng(48).integers(1, 96, size=45).tolist()
+# the last chunk by the prompt's length: (rows of the prompt, its chunks as the
+# flight recorder has them, rows by the width of the program that took them)
+TAILS = {
+    "a tail of 13 rows is one padded wide chunk": (
+        45, [(0, 16, 0), (16, 16, 0), (32, 13, 1)], {"16": 45}),
+    "a tail of 8 rows, a narrow chunk's, stays narrow": (
+        40, [(0, 16, 0), (16, 16, 0), (32, 8, 1)], {"16": 32, "8": 8}),
+    "a tail of 6 rows stays narrow": (38, [(0, 16, 0), (16, 16, 0), (32, 6, 1)], {"16": 32, "8": 6}),
+    "a prompt of 13 rows is one padded wide chunk from row 0": (13, [(0, 13, 1)], {"16": 13}),
+}
 
 
 @pytest.fixture(scope="module")
@@ -76,61 +107,99 @@ def servers():
     return get
 
 
-def built() -> threading.Thread:
-    """What ``_wide_build`` holds once the wide program's build has ended."""
-    thread = threading.Thread(target=lambda: None)
-    thread.start()
-    thread.join()
-    return thread
-
-
 def make_batcher(server, wide=WIDE, **kw) -> ContinuousBatcher:
     base = dict(max_slots=3, max_len=MAX_LEN, len_buckets=(CHUNK,), page_size=PAGE,
                 prefill_chunk=CHUNK)
     base.update(kw)
-    b = ContinuousBatcher(server, **base)
-    assert b.prefill_wide == 0      # an explicit width is every chunk's ...
-    b.prefill_wide = wide           # ... so the rehearsal sets the wide one by hand
-    b._wide_build = built()         # ... and says its program is there (its first call builds it)
-    return b
-
-
-def chunk_events(timelines) -> list:
-    """[(start, tokens, head)] of the chunks of the one request recorded."""
-    return sorted((e["start"], e["tokens"], e["head"]) for t in timelines for e in t["events"]
-                  if e["kind"] == "prefill_chunk")
+    # an explicit width is every chunk's, so the rehearsal sets the wide one by hand
+    return set_wide_chunk(ContinuousBatcher(server, **base), wide)
 
 
 # ------------------------------------------- (a) the same answer
+def held_behind_the_last_chunk(b) -> dict:
+    """What the batcher holds when a prompt's last chunk has been dispatched
+    (``_activate`` is its last act), copied to the host: the cache tree, the
+    job's table rows of both page classes and its slot."""
+    seen = {}
+    activate = b._activate
+
+    def spy(job, logits):
+        seen.update(tree=jax.tree.map(np.asarray, b._caches), slot=job.slot,
+                    rows=(np.asarray(job.bt_row[0]), None if job.wrow is None else job.wrow.copy()))
+        return activate(job, logits)
+
+    b._activate = spy
+    return seen
+
+
+def assert_close_at_scale(got, want, rel):
+    """Within ``rel`` of the array's SCALE (max |want|, at least 1)."""
+    np.testing.assert_allclose(got, want, atol=rel * max(1.0, float(np.abs(want).max())), rtol=0)
+
+
+def assert_the_same_pool_and_state(cfg, got, want, rel):
+    """Layer by layer: a state layer's arrays of the slot, and a paged layer's
+    LIVE rows and every position on the pages both runs hold (the window class
+    has given back what lies behind the last chunk's first row, which is another
+    row at another width), read through each run's own table: a page's number is
+    the allocator's, and the trash page is nobody's."""
+    assert got["slot"] == want["slot"]
+    for i, (a, b) in enumerate(zip(got["tree"], want["tree"])):
+        if kvcache.is_state_entry(a):
+            for x, y in zip(a, b):
+                assert_close_at_scale(x[got["slot"]], y[want["slot"]], rel)
+            continue
+        row_a, row_b = (rows[int(i in cfg.window_layers)] for rows in (got["rows"], want["rows"]))
+        both = (row_a != NULL_PAGE) & (row_b != NULL_PAGE)
+        assert both.any()
+        pos_a, pos_b = a[-1][row_a[both]], b[-1][row_b[both]]
+        np.testing.assert_array_equal(pos_a, pos_b)
+        for x, y in zip(a[:-1], b[:-1]):
+            live = pos_a < PAD_POS
+            assert_close_at_scale(x[row_a[both]][live], y[row_b[both]][live], rel)
+
+
+@pytest.mark.parametrize("tail", TAILS)
 @pytest.mark.parametrize("model", MODELS)
-def test_wide_then_narrow_chunks_give_the_all_narrow_runs_answer(servers, model):
+def test_wide_then_narrow_chunks_give_the_all_narrow_runs_answer(servers, model, tail):
     server = servers(model)
+    length, want_chunks, want_rows = TAILS[tail]
+    prompt = PROMPT[:length]
 
     async def go(wide):
         b = make_batcher(server, wide=wide, tracing=True)
-        info = {"logits": []}
-        out = await b.submit(PROMPT, 4, info=info)
+        info, held = {"logits": []}, held_behind_the_last_chunk(b)
+        out = await b.submit(prompt, 4, info=info)
         stats, timelines = b._phases.stats(), b._flight.timelines()
         await b.close()
-        return out, np.stack(info["logits"]), stats, chunk_events(timelines)
+        return out, np.stack(info["logits"]), stats, chunk_events(timelines), held
 
-    out, logits, stats, chunks = asyncio.run(go(WIDE))
-    narrow_out, narrow_logits, narrow_stats, narrow_chunks = asyncio.run(go(0))
-    assert chunks == [(0, 16, 0), (16, 16, 0), (32, 8, 0), (40, 5, 1)]
-    assert narrow_chunks == [(s, min(8, 45 - s), int(s == 40)) for s in range(0, 45, 8)]
-    assert stats["chunk_rows"] == {"16": 32, "8": 13}
-    assert narrow_stats["chunk_rows"] == {"8": 45}
-    assert stats["chunk_head"] == {"1": 1, "0": 3}
+    out, logits, stats, chunks, held = asyncio.run(go(WIDE))
+    narrow_out, narrow_logits, narrow_stats, narrow_chunks, narrow_held = asyncio.run(go(0))
+    assert chunks == want_chunks
+    assert narrow_chunks == [(s, min(8, length - s), int(s + 8 >= length))
+                             for s in range(0, length, 8)]
+    assert stats["chunk_rows"] == want_rows
+    assert narrow_stats["chunk_rows"] == {"8": length}
+    # the last chunk ran the head, in the program of its width, part full or not
+    last = "8" if "8" in want_rows else "16"
+    assert chunks[-1][1] < WIDE and chunks[-1][2] == 1
+    assert stats["chunk_head"]["1"] == {last: 1}
+    assert stats["chunk_head"]["0"] == ({"16": len(chunks) - 1} if len(chunks) > 1 else {})
+    assert narrow_stats["chunk_head"] == {"1": {"8": 1}, "0": {"8": len(narrow_chunks) - 1}
+                                          if len(narrow_chunks) > 1 else {}}
     # two chunk programs, no ladder
     assert sorted(k[1] for k in server._prefill_cache if k[0] == "pchunk") == [CHUNK, WIDE]
     assert out == narrow_out
-    assert out == server.generate([PROMPT], max_new_tokens=4)["tokens"][0]
+    assert out == server.generate([prompt], max_new_tokens=4)["tokens"][0]
     np.testing.assert_allclose(logits, narrow_logits, atol=3e-5, rtol=0)
+    assert_the_same_pool_and_state(server._cfg, held, narrow_held, rel=3e-5)
 
 
 # ------------------------------------------- (b) the rule, from host state
-def job_of(slot: int, length: int, done: int, chunk: int = CHUNK, on_token=None) -> _PrefillJob:
-    job = _PrefillJob(slot, list(range(length)), 0, chunk, 4, None, on_token, None, None, None, [])
+def job_of(slot: int, length: int, done: int, chunk: int = CHUNK, on_token=None,
+           seed=None) -> _PrefillJob:
+    job = _PrefillJob(slot, list(range(length)), 0, chunk, 4, None, on_token, None, seed, None, [])
     job.next = done
     return job
 
@@ -140,34 +209,58 @@ def stream(tok):
 
 
 # rows left of the prompt, who else holds a slot (live?, streams?), the job's
-# own width and the wide one (the rehearsal's or the served ones) -> the width
+# own width and the wide one (the rehearsal's or the served ones), the seed the
+# request came with -> the width
 TOY, SERVED = (CHUNK, WIDE), (DEFAULT_PREFILL_CHUNK, WIDE_PREFILL_CHUNK)
+NARROW, WIDEST = DEFAULT_PREFILL_CHUNK, WIDE_PREFILL_CHUNK
 RULE = {
-    "more than a wide chunk left, alone": (WIDE + 1, [], TOY, WIDE),
-    "a whole prompt left, alone": (45, [], TOY, WIDE),
-    "exactly a wide chunk left: the last chunks are narrow": (WIDE, [], TOY, CHUNK),
-    "less than a wide chunk left": (5, [], TOY, CHUNK),
-    "a live neighbour that streams": (45, [(True, True)], TOY, CHUNK),
-    "a live neighbour that waits for a plain reply": (45, [(True, False)], TOY, WIDE),
-    "a slot whose stream has ended (not live)": (45, [(False, True)], TOY, WIDE),
-    "one neighbour streams, one does not": (45, [(True, False), (True, True)], TOY, CHUNK),
-    "served widths: a row more than a wide chunk left": (
-        WIDE_PREFILL_CHUNK + 1, [], SERVED, WIDE_PREFILL_CHUNK),
-    "served widths: exactly a wide chunk left": (
-        WIDE_PREFILL_CHUNK, [], SERVED, DEFAULT_PREFILL_CHUNK),
+    "more than a wide chunk left, alone": (WIDE + 1, [], TOY, None, WIDE),
+    "a whole prompt left, alone": (45, [], TOY, None, WIDE),
+    "exactly a wide chunk left: one wide chunk, full": (WIDE, [], TOY, None, WIDE),
+    "a row more than a wide chunk less a narrow one left: one wide chunk, padded": (
+        WIDE - CHUNK + 1, [], TOY, None, WIDE),
+    "a wide chunk less a narrow one left: the narrow program computes fewer rows": (
+        WIDE - CHUNK, [], TOY, None, CHUNK),
+    "less than a narrow chunk left": (5, [], TOY, None, CHUNK),
+    "a live neighbour that streams": (45, [(True, True)], TOY, None, CHUNK),
+    "a padded wide chunk's rows left, a live neighbour that streams": (
+        WIDE - 3, [(True, True)], TOY, None, CHUNK),
+    "a live neighbour that waits for a plain reply": (45, [(True, False)], TOY, None, WIDE),
+    "a padded wide chunk's rows left, a neighbour that waits for a plain reply": (
+        WIDE - 3, [(True, False)], TOY, None, WIDE),
+    "a slot whose stream has ended (not live)": (45, [(False, True)], TOY, None, WIDE),
+    "one neighbour streams, one does not": (45, [(True, False), (True, True)], TOY, None, CHUNK),
+    # a request that came with a seed keeps the widths its length gave it before PR 58
+    "seeded: more than a wide chunk left": (WIDE + 1, [], TOY, 7, WIDE),
+    "seeded: exactly a wide chunk left: the last chunks are narrow": (WIDE, [], TOY, 7, CHUNK),
+    "seeded: a row more than a wide chunk less a narrow one left": (
+        WIDE - CHUNK + 1, [], TOY, 7, CHUNK),
+    "seeded: a wide chunk less a narrow one left": (WIDE - CHUNK, [], TOY, 7, CHUNK),
+    "seeded, seed 0: exactly a wide chunk left": (WIDE, [], TOY, 0, CHUNK),
+    "served widths: a row more than a wide chunk left": (WIDEST + 1, [], SERVED, None, WIDEST),
+    "served widths: exactly a wide chunk left": (WIDEST, [], SERVED, None, WIDEST),
+    "served widths: 769 rows left": (WIDEST - NARROW + 1, [], SERVED, None, WIDEST),
+    "served widths: 768 rows left": (WIDEST - NARROW, [], SERVED, None, NARROW),
     "served widths: a live neighbour that streams": (
-        4 * WIDE_PREFILL_CHUNK, [(True, True)], SERVED, DEFAULT_PREFILL_CHUNK),
+        4 * WIDEST, [(True, True)], SERVED, None, NARROW),
+    "served widths: 900 rows left, a live neighbour that streams": (
+        900, [(True, True)], SERVED, None, NARROW),
+    "served widths, seeded: a row more than a wide chunk left": (
+        WIDEST + 1, [], SERVED, 1234, WIDEST),
+    "served widths, seeded: exactly a wide chunk left": (WIDEST, [], SERVED, 1234, NARROW),
+    "served widths, seeded: 769 rows left": (WIDEST - NARROW + 1, [], SERVED, 1234, NARROW),
+    "served widths, seeded: 768 rows left": (WIDEST - NARROW, [], SERVED, 1234, NARROW),
 }
 
 
 @pytest.mark.parametrize("case", RULE)
 @pytest.mark.parametrize("own_stream", [False, True])
 def test_the_width_follows_rows_left_and_the_other_slots_streams(servers, case, own_stream):
-    left, neighbours, (chunk, wide), want = RULE[case]
+    left, neighbours, (chunk, wide), seed, want = RULE[case]
     b = make_batcher(servers("gqa_pages"), wide=wide, max_slots=4)
     for slot, (live, streams) in zip(b._slots[1:], neighbours):
         slot.active, slot.on_token = live, stream if streams else None
-    job = job_of(0, left + 5, 5, chunk=chunk, on_token=stream if own_stream else None)
+    job = job_of(0, left + 5, 5, chunk=chunk, on_token=stream if own_stream else None, seed=seed)
     # the job's own slot holds the caller's on_token from admission on
     b._slots[0].prefilling, b._slots[0].on_token = True, job.on_token
     assert b._chunk_width(job) == want
@@ -191,9 +284,13 @@ def test_an_explicit_prefill_chunk_is_every_chunks_and_the_default_widens(server
     assert default._chunk_width(job_of(0, 40, 0, chunk=64)) == 64
     wide = WIDE_PREFILL_CHUNK
     assert default._chunk_width(job_of(0, wide + 1, 0, chunk=256)) == wide
-    assert default._chunk_width(job_of(0, wide + 1, 1, chunk=256)) == 256
+    assert default._chunk_width(job_of(0, wide + 1, 1, chunk=256)) == wide    # (full)
+    assert default._chunk_width(job_of(0, wide + 1, 1, chunk=256, seed=3)) == 256
     assert default._chunk_width(job_of(0, 2 * wide + 1, wide, chunk=256)) == wide
     assert default._chunk_width(job_of(0, 2 * wide + 1, 2 * wide, chunk=256)) == 256
+    # what is left against the two widths: 769 rows are four narrow calls or one wide
+    assert default._chunk_width(job_of(0, 2 * wide + 769, 2 * wide, chunk=256)) == wide
+    assert default._chunk_width(job_of(0, 2 * wide + 768, 2 * wide, chunk=256)) == 256
     # the server's own prefill_chunk is an explicit one too
     server.prefill_chunk = CHUNK
     try:
@@ -222,23 +319,40 @@ def test_the_default_widens_whatever_the_model(servers, model):
     assert b._chunk_width(job) == 256
     b._slots[1].on_token = None
     assert b._chunk_width(job) == WIDE_PREFILL_CHUNK
-    # narrow where no more than a wide chunk is left: every prompt of a 1,024-token slot
-    assert b._chunk_width(job_of(0, WIDE_PREFILL_CHUNK - 1, 0, chunk=256)) == 256
+    # narrow where three narrow chunks hold what is left, one padded wide chunk past that
+    assert b._chunk_width(job_of(0, WIDE_PREFILL_CHUNK - 256, 0, chunk=256)) == 256
+    assert b._chunk_width(job_of(0, WIDE_PREFILL_CHUNK - 1, 0, chunk=256)) == WIDE_PREFILL_CHUNK
+    assert b._chunk_width(job_of(0, WIDE_PREFILL_CHUNK - 1, 0, chunk=256, seed=1)) == 256
     # and a width somebody gave is every chunk's
     given = ContinuousBatcher(server, max_slots=2, max_len=MAX_LEN, page_size=PAGE,
                               prefill_chunk=CHUNK)
     assert given.prefill_wide == 0 and given._chunk_width(job_of(0, 4000, 0)) == CHUNK
 
 
-def test_a_dense_prompt_through_wide_and_narrow_chunks_gives_the_all_narrow_tokens():
-    """At the SERVED widths, a dense model: a prompt of 2,400 tokens takes two
-    chunks of 1,024 rows, a full one of 256 and the last of 96 (the head's), and
-    its tokens are those of the same prompt through ten chunks of 256."""
-    length = 2 * WIDE_PREFILL_CHUNK + DEFAULT_PREFILL_CHUNK + 96
-    slot = 4096 + 64     # (a prompt is admitted by its length bucket: 2,400 tokens are 4,096)
-    server = LLMServer(model="transformer", model_kwargs=dict(MODELS["gqa_pages"], max_seq_len=slot),
+@pytest.fixture(scope="module")
+def long_slot_server():
+    # (a prompt is admitted by its length bucket: 2,400 tokens are 4,096)
+    server = LLMServer(model="transformer",
+                       model_kwargs=dict(MODELS["gqa_pages"], max_seq_len=4096 + 64),
                        init_random=True, max_new_tokens=8, eos_id=-1, seed=3, temperature=0.0)
     server.load()
+    return server
+
+
+@pytest.mark.parametrize("tail,last_chunks,wide_rows", [
+    (DEFAULT_PREFILL_CHUNK + 96, [(2048, 256, 0), (2304, 96, 1)], 2048),
+    (900, [(2048, 900, 1)], 2048 + 900),
+], ids=["a tail of 352 rows is two narrow chunks", "a tail of 900 rows is one padded wide chunk"])
+def test_a_dense_prompt_through_wide_and_narrow_chunks_gives_the_all_narrow_tokens(
+        long_slot_server, tail, last_chunks, wide_rows):
+    """At the SERVED widths, a dense model: a prompt of 2,400 tokens takes two
+    chunks of 1,024 rows, a full one of 256 and the last of 96 (the head's); one
+    of 2,948 takes its last 900 rows in ONE chunk of 1,024 rows, 124 of them
+    padding, whose row 899 the head reads (the narrow program would have run
+    four times, the last call 132 rows and 124 of padding). The tokens are those
+    of the same prompt through chunks of 256 alone."""
+    server, slot = long_slot_server, 4096 + 64
+    length = 2 * WIDE_PREFILL_CHUNK + tail
     prompt = np.random.default_rng(54).integers(1, 96, size=length).tolist()
 
     async def go(**width):
@@ -250,13 +364,15 @@ def test_a_dense_prompt_through_wide_and_narrow_chunks_gives_the_all_narrow_toke
         out = await b.submit(prompt, 6)
         stats, chunks = b._phases.stats(), chunk_events(b._flight.timelines()[-1:])
         await b.close()
-        return out, stats["chunk_rows"], chunks
+        return out, stats, chunks
 
-    out, rows, chunks = asyncio.run(go())
-    narrow_out, narrow_rows, narrow_chunks = asyncio.run(go(prefill_chunk=DEFAULT_PREFILL_CHUNK))
-    assert chunks == [(0, 1024, 0), (1024, 1024, 0), (2048, 256, 0), (2304, 96, 1)]
-    assert rows == {"1024": 2048, "256": 352 + 200} and narrow_rows == {"256": length + 200}
-    assert len(narrow_chunks) == 10 and narrow_chunks[-1] == (2304, 96, 1)
+    out, stats, chunks = asyncio.run(go())
+    narrow_out, narrow_stats, narrow_chunks = asyncio.run(go(prefill_chunk=DEFAULT_PREFILL_CHUNK))
+    assert chunks == [(0, 1024, 0), (1024, 1024, 0)] + last_chunks
+    assert stats["chunk_rows"] == {"1024": wide_rows, "256": length - wide_rows + 200}
+    assert narrow_stats["chunk_rows"] == {"256": length + 200}
+    assert stats["chunk_head"]["1"] == ({"256": 1, "1024": 1} if tail == 900 else {"256": 2})
+    assert len(narrow_chunks) == -(-length // 256) and narrow_chunks[-1][2] == 1
     assert sorted(k[1] for k in server._prefill_cache if k[0] == "pchunk") == [
         DEFAULT_PREFILL_CHUNK, WIDE_PREFILL_CHUNK]
     assert out == narrow_out
@@ -273,7 +389,7 @@ def test_every_chunk_of_an_explicit_width_is_that_wide(servers):
 
     _, stats = asyncio.run(go())
     assert stats["chunk_rows"] == {"8": 45}
-    assert stats["chunk_head"] == {"1": 1, "0": 5}
+    assert stats["chunk_head"] == {"1": {"8": 1}, "0": {"8": 5}}
 
 
 # ------------------------------------------- (c) streams, and the counter
@@ -294,7 +410,8 @@ def exposed_chunk_rows(stats) -> dict:
 def test_no_wide_chunk_under_a_streaming_neighbour_and_the_counter_says_so(servers):
     """A streams 40 tokens; B's 45-row prompt arrives once A's first token is
     out and is prefilled in narrow chunks alone (six turns beside A's steps);
-    when A is done, the same prompt takes wide chunks again."""
+    when A is done, the same prompt takes wide chunks again, its 13-row tail
+    one of them."""
     server = servers("gqa_pages")
 
     async def go():
@@ -323,14 +440,16 @@ def test_no_wide_chunk_under_a_streaming_neighbour_and_the_counter_says_so(serve
     # A's own 5 rows and B's 45, none of them through the wide program
     assert beside["chunk_rows"] == {"8": 50}
     assert exposed_chunk_rows(beside) == {"8": 50.0}
-    assert after["chunk_rows"] == {"8": 63, "16": 32}
-    assert exposed_chunk_rows(after) == {"8": 63.0, "16": 32.0}
+    assert after["chunk_rows"] == {"8": 50, "16": 45}
+    assert exposed_chunk_rows(after) == {"8": 50.0, "16": 45.0}
+    assert after["chunk_head"]["1"] == {"8": 2, "16": 1}
     assert b_out == alone_out
 
 
 def test_the_jobs_own_stream_does_not_count_and_agrees_with_the_plain_reply(servers):
     """The seeded probe of perf/planes/llm_rest.py: a plain reply and a stream
-    of one prompt take the same chunks and give the same tokens."""
+    of one prompt take the same chunks and give the same tokens; with its seed
+    the prompt's 13-row tail is the two narrow chunks it was before PR 58."""
     server = LLMServer(model="transformer", model_kwargs=MODELS["gqa_pages"], init_random=True,
                        max_new_tokens=8, len_buckets=(16,), eos_id=-1, seed=3,
                        temperature=0.8, top_k=20)
@@ -436,8 +555,8 @@ def test_a_server_that_never_decodes_builds_it_when_its_first_request_has_finish
 def test_a_lora_servers_wide_program_is_built_on_the_thread_and_serves_the_narrow_tokens():
     """A server with an adapter pool calls its chunk program with the pool and
     the slot's adapter: the thread builds THAT program for those shapes, and an
-    adapted prompt through wide + narrow chunks gives the all-narrow tokens (at
-    rehearsal widths, the wide one set by hand as in (a))."""
+    adapted prompt through wide chunks, the last one padded, gives the
+    all-narrow tokens (at rehearsal widths, the wide one set by hand as in (a))."""
     from test_adapters import load_adapters, make_server
 
     server = make_server()
@@ -459,7 +578,7 @@ def test_a_lora_servers_wide_program_is_built_on_the_thread_and_serves_the_narro
 
     out, rows = asyncio.run(go(WIDE))
     narrow_out, narrow_rows = asyncio.run(go(0))
-    assert rows == {"16": 32, "8": 13 + 20} and narrow_rows == {"8": 65}
+    assert rows == {"16": 45, "8": 20} and narrow_rows == {"8": 65}
     # the programs that take the pool, and no other
     assert sorted(k[1:] for k in server._prefill_cache if k[0] == "pchunk") == [
         (CHUNK, MAX_LEN // PAGE, True), (WIDE, MAX_LEN // PAGE, True)]
@@ -473,8 +592,10 @@ def test_a_seeded_request_waits_for_the_wide_program_and_repeats_its_tokens():
     the program and takes the chunks it takes once the program is there (the
     benchmark's seeded probe before and after a window; found on the chip in
     `smallthinker-longqa-mixed`, whose probe is 6,146 tokens: PERF.md
-    section 6, PR 54)."""
-    _, svc = serve(SLOT)
+    section 6, PR 54). Its wide chunks are always full (PR 58): a seeded prompt
+    of 900 rows is four narrow chunks, as before, where the same prompt without
+    a seed is ONE padded wide chunk, and both give the tokens of ``generate()``."""
+    server, svc = serve(SLOT)
     b = svc.batcher
     svc.submit_sync(LONG[:FEW], 2)
     build = b._wide_build
@@ -490,9 +611,24 @@ def test_a_seeded_request_waits_for_the_wide_program_and_repeats_its_tokens():
     assert not build.is_alive()
     rows = dict(b._phases.stats()["chunk_rows"])
     again = svc.submit_sync(LONG, 4, seed=7)
-    svc.close()
     assert rows == {str(WIDE_PREFILL_CHUNK): WIDE_PREFILL_CHUNK, "256": FEW + MANY - WIDE_PREFILL_CHUNK}
     assert first == again
+    # a tail a wide chunk would hold, with a seed and without
+    before = b._phases.stats()
+    with_seed = svc.submit_sync(LONG[:900], 4, seed=7)
+    between = b._phases.stats()
+    without = svc.submit_sync(LONG[:900], 4)
+    after = b._phases.stats()
+    svc.close()
+
+    def since(new, old, key, width):
+        return new[key].get(width, 0) - old[key].get(width, 0)
+
+    wide = str(WIDE_PREFILL_CHUNK)
+    assert (since(between, before, "chunk_rows", "256"), since(between, before, "chunk_rows", wide)) == (900, 0)
+    assert (since(after, between, "chunk_rows", "256"), since(after, between, "chunk_rows", wide)) == (0, 900)
+    assert after["chunk_head"]["1"][wide] == 1 and between["chunk_head"]["1"].get(wide, 0) == 0
+    assert with_seed == without == server.generate([LONG[:900]], max_new_tokens=4)["tokens"][0]
 
 
 def test_a_batcher_that_cannot_reach_a_wide_chunk_builds_its_one_program_when_called():
