@@ -599,8 +599,9 @@ class MetricsRegistry:
             "max_group": "Rows of the largest expert group, summed over "
                          "layer-calls",
             "tile_rows": "Rows the grouped-matmul kernel multiplied (visits x "
-                         "row tile), over all layers: routed_pairs over this "
-                         "is the tile's fill; 0 where ragged_dot serves",
+                         "row tile; a 128-row tile's visits count their "
+                         "sub-block), over all layers: routed_pairs over this "
+                         "is the fill; 0 where ragged_dot serves",
             "layer_calls": "MoE layer executions (calls x layers)",
         }
         self._moe = {

@@ -1298,7 +1298,9 @@ class MoEFFN(nn.Module):
     kernel (ops/grouped_matmul.py): an expert's whole matrix is one block,
     and the sorted rows are walked in a row tile that follows the call's
     static shapes (rows / experts = the mean group; ``row_tile``), one visit
-    per (expert, row tile) pair, all three projections over one visit list.
+    per (expert, row tile) pair, all three projections over one visit list;
+    a visit of the 128-row tile (a 1,024-row chunk whose mean group is over 64
+    rows) multiplies the run of 32-row blocks that holds its rows.
     Everywhere else (tier-1 on the CPU, the float32 checks), and where the
     stacks are sharded over a mesh (``cfg.mesh``, which LLMServer sets when
     it shards: the kernel is one device's program), it is
@@ -1319,9 +1321,9 @@ class MoEFFN(nn.Module):
 
     When the "moe" collection is mutable the layer sows ``tokens`` [b, e]
     int32, how many tokens of each sequence went to each expert, and
-    ``tile_rows``, the rows the kernel multiplied (its visits x the row tile;
-    0 behind ``ragged_dot``): ``moe_routing_stats`` reduces them over the
-    layers."""
+    ``tile_rows``, the rows the kernel multiplied (its visits x the row tile,
+    a 128-row tile's visits their sub-block: ``Visits.multiplied``; 0 behind
+    ``ragged_dot``): ``moe_routing_stats`` reduces them over the layers."""
 
     cfg: TransformerConfig
 
@@ -1423,7 +1425,7 @@ class MoEFFN(nn.Module):
                 visits = make_visits(group_sizes, t * k, row_tile(t * k, e))
                 y = swiglu(lambda lhs, w, scale: grouped_matmul(
                     lhs, w, visits, scale, interpret=False))
-                return y, visits.count * visits.rows
+                return y, visits.multiplied
 
             def experts_ragged_dot():
                 row_scale = jnp.minimum(row_expert, held - 1)

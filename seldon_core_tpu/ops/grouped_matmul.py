@@ -24,7 +24,9 @@ served shapes want:
   block is what Mosaic refused).
 - rows that belong to no group (dead slots, padding: they sort behind the
   last group) are written as zeros, by visits that multiply nothing.
-- the row tile follows the call's static shapes (``row_tile``).
+- the row tile follows the call's static shapes (``row_tile``), and a visit
+  of the 128-row tile multiplies the run of aligned 32-row blocks that holds
+  the rows it owns, not the tile (``sub_block``).
 
 Numerics are ``ragged_dot``'s: int8 -> bf16 is exact, products accumulate in
 float32, the scale multiplies the product.
@@ -47,6 +49,9 @@ KERNEL_NAME = "ragged_dot_int8"
 ROW_TILES = (16, 32, 64, 128)
 # one weight block (an expert's [k, tn] strip as it is held) is at most this
 WEIGHT_BLOCK_BYTES = 4 << 20
+# the rows of an aligned block of a large tile (whole bf16 sublane tiles: a
+# multiple of 16); see ``sub_block``
+SUB_BLOCK = 32
 
 
 def row_tile(m: int, n_groups: int) -> int:
@@ -63,13 +68,42 @@ def row_tile(m: int, n_groups: int) -> int:
     return next((tile for tile in ROW_TILES if tile >= want), ROW_TILES[-1])
 
 
+def sub_block(rows: int) -> int:
+    """Rows of the aligned blocks a visit of a ``rows``-row tile multiplies in:
+    the run of them that holds the rows the visit owns. Up to 64 rows the
+    block is the tile (such a visit hides under the DMA of the next expert's
+    weights: every decode step and 256-row chunk keeps the one product it
+    had); in the 128-row tile of the 1,024-row chunks it is SUB_BLOCK, where a
+    collapsed router's small groups would each pay for 128 rows
+    (docs/performance.md "The grouped matmul": granules of 16, 32 and 64 and
+    nested blocks, on the chip)."""
+    return SUB_BLOCK if rows > 64 else rows
+
+
+def _blocks(rows: int, tile, lo, hi):
+    """(first, count) of the ``sub_block(rows)``-row blocks of tile ``tile``
+    that hold a row of ``[lo, hi)``: what a visit multiplies (none where it
+    owns no row). Scalars in the kernel, arrays beside the visit list."""
+    import jax
+    import jax.numpy as jnp
+
+    block = jnp.int32(sub_block(rows))
+    origin = tile * rows
+    r0, r1 = jnp.clip(lo - origin, 0, rows), jnp.clip(hi - origin, 0, rows)
+    first = jax.lax.div(r0, block)    # (nothing negative: no floor's sign chain)
+    return first, jax.lax.div(r1 + (block - 1), block) - first
+
+
 class Visits(NamedTuple):
     """The (group, row tile) pairs one call walks, in the order it walks
     them, as the kernel's scalar-prefetch operands: visit ``i`` multiplies
     row tile ``tile[i]`` by expert ``expert[i]`` and owns rows
     ``[lo[i], hi[i])`` of it. A tile wholly behind the last group gets one
     visit with no rows, which writes its zeros. ``count`` is how many there
-    are (the grid's length); ``rows`` is the static tile."""
+    are (the grid's length); ``rows`` is the static tile; ``multiplied`` is
+    how many rows the kernel multiplies over the list: ``count x rows`` where
+    a visit multiplies its tile (the visits without rows counted as they
+    always were), the live visits' blocks where it multiplies ``sub_block``s."""
 
     tile: "jax.Array"
     expert: "jax.Array"
@@ -77,6 +111,7 @@ class Visits(NamedTuple):
     hi: "jax.Array"
     count: "jax.Array"
     rows: int
+    multiplied: "jax.Array"
 
 
 def make_visits(group_sizes, m: int, rows: int) -> Visits:
@@ -109,11 +144,14 @@ def make_visits(group_sizes, m: int, rows: int) -> Visits:
     # a visit without rows keeps the weights that are there: no block moves
     last_live = jnp.max(jnp.where(sizes > 0, jnp.arange(e, dtype=jnp.int32), 0))
     gl = jnp.minimum(g, e - 1)
-    return Visits(
-        tile=jnp.minimum(first[g] + i - visit_starts[g], tiles - 1),
-        expert=jnp.where(live, gl, last_live),
-        lo=jnp.where(live, starts[gl], 0), hi=jnp.where(live, ends[gl], 0),
-        count=visit_ends[-1], rows=rows)
+    tile = jnp.minimum(first[g] + i - visit_starts[g], tiles - 1)
+    lo, hi = jnp.where(live, starts[gl], 0), jnp.where(live, ends[gl], 0)
+    count = visit_ends[-1]
+    multiplied = count * rows
+    if sub_block(rows) != rows:
+        multiplied = jnp.sum(_blocks(rows, tile, lo, hi)[1]) * sub_block(rows)
+    return Visits(tile=tile, expert=jnp.where(live, gl, last_live), lo=lo, hi=hi,
+                  count=count, rows=rows, multiplied=multiplied)
 
 
 def _kernel(rows: int, tile, expert, lo, hi, lhs_ref, w_ref, *refs):
@@ -129,17 +167,33 @@ def _kernel(rows: int, tile, expert, lo, hi, lhs_ref, w_ref, *refs):
     def _zero():      # rows of the tile that no visit owns stay zero
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(hi[i] > lo[i])
-    def _multiply():
+    def multiply(start=None, size=rows):
+        """The product over the tile, or over ``size`` of its rows from ``start``."""
+        at = ... if start is None else (pl.ds(start, size), slice(None))
         # the weight block is converted where it lies, in VMEM, by every
         # visit: the conversion hides under the MXU's passes, and a converted
         # copy kept for an expert's further visits measured 5-20 % slower
-        product = jnp.dot(lhs_ref[...], w_ref[...].astype(lhs_ref.dtype),
+        product = jnp.dot(lhs_ref[at], w_ref[...].astype(lhs_ref.dtype),
                           preferred_element_type=jnp.float32)
         if s_ref:
             product = product * s_ref[0][...]
-        row = tile[i] * rows + jax.lax.broadcasted_iota(jnp.int32, product.shape, 0)
-        out_ref[...] = jnp.where((row >= lo[i]) & (row < hi[i]), product, out_ref[...])
+        first_row = tile[i] * rows if start is None else tile[i] * rows + start
+        row = first_row + jax.lax.broadcasted_iota(jnp.int32, product.shape, 0)
+        out_ref[at] = jnp.where((row >= lo[i]) & (row < hi[i]), product, out_ref[at])
+
+    block = sub_block(rows)
+    if block == rows:
+        pl.when(hi[i] > lo[i])(multiply)
+        return
+    # the run of blocks that holds the visit's rows, one branch a length of
+    # the run: ONE product whatever the length (a product for each 64-row
+    # half that holds a row measured 3-4 % slower than a run of 64-row blocks,
+    # one for each 32-row quarter 25 % slower: each converts the weights again)
+    first, count = _blocks(rows, tile[i], lo[i], hi[i])
+    start = pl.multiple_of(first * block, block)
+    for n in range(1, rows // block):
+        pl.when(count == n)(functools.partial(multiply, start, n * block))
+    pl.when(count == rows // block)(multiply)
 
 
 def grouped_matmul(lhs, rhs, visits: Visits, scale=None, interpret: bool | None = None):

@@ -912,8 +912,9 @@ class MoECounters:
     their weights were read): calls, live rows, routed (token, expert) pairs,
     and, summed over the ``n_layers`` MoE layer-calls of each call, the distinct
     experts touched, the largest expert group and the rows the grouped-matmul
-    kernel multiplied (``tile_rows``: visits x row tile, so ``routed_pairs`` /
-    ``tile_rows`` is the tile's fill; 0 where ``ragged_dot`` serves).
+    kernel multiplied (``tile_rows``: visits x row tile, a 128-row tile's visits
+    count their sub-block, the run of 32-row blocks that holds their rows; so
+    ``routed_pairs`` / ``tile_rows`` is the fill; 0 where ``ragged_dot`` serves).
     ``expert_tokens`` [e] is
     what was DELIVERED: prompt tokens, and decode rows whose token was
     credited to a request, by expert, summed over layers."""

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from seldon_core_tpu.ops.grouped_matmul import (
-    ROW_TILES, grouped_matmul, make_visits, row_tile)
+    ROW_TILES, SUB_BLOCK, grouped_matmul, make_visits, row_tile, sub_block)
 
 
 def operands(m, k, n, e, seed=0, int8=True):
@@ -57,6 +57,14 @@ CASES = {
     "widths that are not whole 128-lane tiles": (48, 64, 32, [9, 0, 20, 11], 16, True),
     "a last expert that is empty": (64, 128, 128, [30, 30, 0], 32, True),
     **{f"row tile {tile}": (256, 128, 128, [100, 0, 56, 1, 70], tile, True) for tile in ROW_TILES},
+    # the 128-row tile, whose visits multiply the run of 32-row blocks that holds their rows
+    "tile 128: a group inside one 64-row half": (128, 128, 128, [40, 0, 88], 128, True),
+    "tile 128: a group that straddles the halves": (128, 128, 128, [50, 30, 48], 128, True),
+    "tile 128: groups that end exactly on a block's boundary": (256, 128, 128, [32, 32, 64, 96], 128, True),
+    "tile 128: four small groups sharing a tile": (128, 128, 128, [10, 20, 30, 40], 128, True),
+    "tile 128: a second half owned by nobody": (128, 128, 128, [20, 0, 30], 128, True),
+    "tile 128: rows behind the last group, within a tile and whole tiles": (384, 128, 128, [100, 0, 60], 128, True),
+    "tile 128: a floating stack, no scale": (256, 128, 256, [20, 0, 70, 100], 128, False),
 }
 
 
@@ -101,6 +109,40 @@ def test_the_visit_list():
     assert visits.tile.shape == (4 + 5,)
 
 
+def multiplied_by_hand(sizes, tile, block):
+    """Every (group, tile) pair with a row in common: the blocks of ``block``
+    rows of the tile, from the one that holds the pair's first row to the one
+    that holds its last."""
+    rows, start = 0, 0
+    for size in sizes:
+        for t in range(start // tile, -(-(start + size) // tile) if size else 0):
+            r0, r1 = max(start - t * tile, 0), min(start + size - t * tile, tile)
+            rows += (-(-r1 // block) - r0 // block) * block
+        start += size
+    return rows
+
+
+@pytest.mark.parametrize("sizes,by_hand", [
+    # all in tile 0: rows [0, 10) and [10, 22) a block each, [22, 42) two, [42, 45) one; tile 1 nobody's
+    ([10, 0, 12, 20, 3], 32 + 32 + 64 + 32),
+    # [0, 100) four blocks; [100, 156) the last block of tile 0 and the first of tile 1;
+    # [156, 157) one; [157, 227) = rows [29, 99) of tile 1, four
+    ([100, 0, 56, 1, 70], 128 + 32 + 32 + 32 + 128),
+    ([0, 0, 0, 0, 0], 0),
+    ([64, 64, 128, 0, 0], 256)])
+def test_the_rows_a_visit_list_multiplies(sizes, by_hand):
+    """``Visits.multiplied`` (what ``MoEFFN`` sows as ``tile_rows``): at the
+    128-row tile the live visits' runs of 32-row blocks, a visit without rows
+    nothing; at tiles of 64 rows or fewer every visit its tile, as before."""
+    assert (sub_block(128), SUB_BLOCK) == (32, 32)
+    assert multiplied_by_hand(sizes, 128, 32) == by_hand
+    assert int(make_visits(jnp.asarray(sizes, jnp.int32), 256, 128).multiplied) == by_hand
+    for tile in (16, 32, 64):
+        assert sub_block(tile) == tile
+        visits = make_visits(jnp.asarray(sizes, jnp.int32), 256, tile)
+        assert int(visits.multiplied) == int(visits.count) * tile
+
+
 def test_the_row_tile_at_the_six_served_shapes():
     """OLMoE (8 of 64 experts a token): a 32-slot step, chunks of 128 and
     256; DeepSeek-V2-Lite (6 of 64): an 8-slot step, chunks of 128 and 256.
@@ -113,13 +155,16 @@ def test_the_row_tile_at_the_six_served_shapes():
     assert all(row_tile(m, e) in ROW_TILES for m in (1, 48, 4096, 1 << 20) for e in (1, 8, 256))
 
 
-def test_moeffn_through_the_kernel_is_moeffn_through_ragged_dot(monkeypatch):
+@pytest.mark.parametrize("seq,lens,tile", [(12, [12, 5], 64), (300, [300, 170], 128)])
+def test_moeffn_through_the_kernel_is_moeffn_through_ragged_dot(monkeypatch, seq, lens, tile):
     """``MoEFFN`` picks by the lowering platform (the kernel for a TPU,
     ``ragged_dot`` elsewhere). Here the TPU's branch is taken by hand, its
     kernel under the interpreter: the same output as the branch tier-1
     otherwise runs, int8 stacks and dead rows included, and the rows the
     kernel multiplied are sown beside the routing (0 where ragged_dot
-    serves): visits x row tile, never under the routed pairs."""
+    serves): visits x row tile, never under the routed pairs; where the mean
+    group is over 64 rows and the tile 128, its visits' runs of 32-row blocks,
+    counted by hand from the groups the layer sowed."""
     import seldon_core_tpu.ops.grouped_matmul as module
     from seldon_core_tpu.models.transformer import MoEFFN, TransformerConfig
     from seldon_core_tpu.ops.quantize import dequantize_params, quantize_params
@@ -128,8 +173,8 @@ def test_moeffn_through_the_kernel_is_moeffn_through_ragged_dot(monkeypatch):
                             ffn_dim=32, max_seq_len=32, n_experts=8, n_experts_per_token=2,
                             router_renormalize=False, dtype=jnp.bfloat16)
     ffn = MoEFFN(cfg)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 64), jnp.float32).astype(jnp.bfloat16)
-    valid = jnp.arange(12)[None, :] < jnp.asarray([12, 5])[:, None]   # 7 rows are padding
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, seq, 64), jnp.float32).astype(jnp.bfloat16)
+    valid = jnp.arange(seq)[None, :] < jnp.asarray(lens)[:, None]   # the rest is padding
     params = dequantize_params(quantize_params(ffn.init(jax.random.PRNGKey(1), x)),
                                keep_consumed=True)
     assert params["params"]["w1"].q.dtype == jnp.int8
@@ -143,10 +188,16 @@ def test_moeffn_through_the_kernel_is_moeffn_through_ragged_dot(monkeypatch):
     got, sown = ffn.apply(params, x, valid, mutable=["moe"])
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
                                atol=2e-2, rtol=2e-2)
-    assert np.all(np.asarray(got[1, 5:], np.float32) == 0.0)
-    pairs, tile = 17 * 2, row_tile(24 * 2, 8)
+    assert np.all(np.asarray(got[1, lens[1]:], np.float32) == 0.0)
+    pairs = sum(lens) * 2
+    assert row_tile(2 * seq * 2, 8) == tile
     tile_rows = int(sown["moe"]["tile_rows"][0])
-    assert tile_rows % tile == 0 and pairs <= tile_rows <= (48 // tile + 8) * tile
+    if tile == 128:
+        sizes = np.asarray(sown["moe"]["tokens"][0]).sum(axis=0).tolist()
+        assert sum(sizes) == pairs
+        assert pairs <= tile_rows == multiplied_by_hand(sizes, 128, 32) < (1200 // tile + 8) * tile
+    else:
+        assert tile_rows % tile == 0 and pairs <= tile_rows <= (48 // tile + 8) * tile
 
 
 @pytest.mark.parametrize("sizes", [[3, 0, 7, 2], [0, 0, 0, 0], [16, 0, 0, 16], [1, 1, 1, 1]])

@@ -19,7 +19,8 @@ from seldon_core_tpu import ops
 from seldon_core_tpu.models import get_model
 from seldon_core_tpu.models.cache import init_paged_kv_caches
 from seldon_core_tpu.models.transformer import transformer_block
-from seldon_core_tpu.ops.grouped_matmul import grouped_matmul, make_visits, row_tile
+from seldon_core_tpu.ops.grouped_matmul import (SUB_BLOCK, grouped_matmul, make_visits,
+                                                 row_tile)
 from seldon_core_tpu.ops.gqa_attention import gqa_page_attention, gqa_plan
 from seldon_core_tpu.ops.latent_attention import (ExpandedWalk, expanded_walk,
                                                   latent_expanded_attention,
@@ -37,22 +38,54 @@ def tpu_mlir(fn, *specs) -> str:
     return export.export(jax.jit(fn), platforms=["tpu"])(*specs).mlir_module()
 
 
-@pytest.mark.parametrize("rows,dim,width", [
-    (32 * 8, 2048, 1024), (128 * 8, 2048, 1024), (256 * 8, 2048, 1024),   # OLMoE: step, chunks
-    (8 * 6, 2048, 1408), (128 * 6, 2048, 1408), (256 * 6, 2048, 1408)])   # DeepSeek-V2-Lite
-def test_grouped_matmul_lowers_for_tpu(rows, dim, width):
-    """the routed experts' two orientations (gate / up, down) at the six
-    served shapes, int8 stacks and per-expert scales, at the rule's row tile"""
+@pytest.mark.parametrize("rows,dim,width,experts,tile", [
+    (32 * 8, 2048, 1024, 64, 64), (128 * 8, 2048, 1024, 64, 64),     # OLMoE: step, chunks
+    (256 * 8, 2048, 1024, 64, 64),
+    (8 * 6, 2048, 1408, 64, 16), (128 * 6, 2048, 1408, 64, 64),      # DeepSeek-V2-Lite
+    (256 * 6, 2048, 1408, 64, 64),
+    # the 1,024-row chunks whose mean group is over 64 rows: the only served calls
+    # that walk the 128-row tile, whose visits multiply a run of its 32-row blocks
+    # (an aligned dynamic slice of the rows' sublanes)
+    (1024 * 6, 2048, 1408, 64, 128),    # DeepSeek-V2-Lite
+    (1024 * 4, 2048, 1792, 32, 128),    # LFM2
+    (1024 * 6, 2560, 768, 64, 128)])    # SmallThinker
+def test_grouped_matmul_lowers_for_tpu(rows, dim, width, experts, tile):
+    """the routed experts' two orientations (gate / up, down) at the served
+    shapes, int8 stacks and per-expert scales, at the rule's row tile"""
+    assert row_tile(rows, experts) == tile
+
     def swiglu(x, sizes, w1, s1, w2, s2):
-        visits = make_visits(sizes, rows, row_tile(rows, 64))
+        visits = make_visits(sizes, rows, tile)
         h = grouped_matmul(x, w1, visits, s1, interpret=False)
         return grouped_matmul(h.astype(x.dtype), w2, visits, s2, interpret=False)
 
     text = tpu_mlir(
-        swiglu, S((rows, dim), jnp.bfloat16), S((64,), jnp.int32),
-        S((64, dim, width), jnp.int8), S((64, width), jnp.float32),
-        S((64, width, dim), jnp.int8), S((64, dim), jnp.float32))
+        swiglu, S((rows, dim), jnp.bfloat16), S((experts,), jnp.int32),
+        S((experts, dim, width), jnp.int8), S((experts, width), jnp.float32),
+        S((experts, width, dim), jnp.int8), S((experts, dim), jnp.float32))
     assert text.count(MOSAIC_CALL) == 2
+
+
+@pytest.mark.parametrize("tile", [16, 32, 64, 128])
+def test_a_tile_of_64_rows_or_fewer_keeps_the_one_product_over_its_rows(tile):
+    """The kernel's traced body: up to 64 rows a visit is ONE ``dot_general``
+    over the whole row block, no index of it computed (what every decode step
+    and narrow chunk had before the 128-row tile got its blocks: their
+    programs did not change); at 128 one product a length of the run, over an
+    aligned slice that starts where the visit's first block does."""
+    def call(x, sizes, w, s):
+        return grouped_matmul(x, w, make_visits(sizes, 512, tile), s, interpret=False)
+
+    jaxpr = jax.make_jaxpr(call)(S((512, 128), jnp.bfloat16), S((4,), jnp.int32),
+                                 S((4, 128, 128), jnp.int8), S((4, 128), jnp.float32))
+    (kernel,) = [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+    body = str(kernel.params["jaxpr"])
+    if tile <= 64:
+        assert body.count("dot_general[") == 1 and "multiple_of" not in body
+        assert f"bf16[{tile},128] <-" in body and "+" not in body   # (a dynamic slice prints as a:a+n)
+    else:
+        assert body.count("dot_general[") == tile // SUB_BLOCK and "multiple_of" in body
+        assert all(f"bf16[{n * SUB_BLOCK},128] <-" in body for n in range(1, tile // SUB_BLOCK + 1))
 
 
 @pytest.mark.parametrize("rows,tile", [(64 * 10, 32), (256 * 10, 64)])
